@@ -46,7 +46,6 @@ import tempfile
 import threading
 import time
 
-os.environ.setdefault("ELASTICDL_TPU_PLATFORM", "cpu")
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import numpy as np  # noqa: E402
@@ -271,7 +270,6 @@ def run_drill(load_secs, light_secs):
     # -- serving fleet -------------------------------------------------
     env = dict(os.environ)
     env.update({"JAX_PLATFORMS": "cpu",
-                "ELASTICDL_TPU_PLATFORM": "cpu",
                 "OMP_NUM_THREADS": "1",
                 "OPENBLAS_NUM_THREADS": "1"})
     # An unfillable batch size + a real window: under CONCURRENT load

@@ -29,9 +29,9 @@ import subprocess
 import sys
 import time
 
-_PLATFORM = os.environ.get("ELASTICDL_TPU_PLATFORM") or "cpu"
-os.environ["ELASTICDL_TPU_PLATFORM"] = _PLATFORM
-os.environ["JAX_PLATFORMS"] = _PLATFORM
+# A host-side CPU bench (the PS path is numpy + gRPC; the jitted step is
+# tiny): pinned to the CPU so that it takes no chip on a TPU host.
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 BATCH_SIZE = 256
 VOCAB_SIZE = 50_000
@@ -321,8 +321,6 @@ def _frame_gate(pb_loop, frame_loop, pb_net, frame_net, bitid,
 def main(argv=None):
     import argparse
 
-    import jax
-
     parser = argparse.ArgumentParser("bench_ps_wire")
     parser.add_argument(
         "--rpc_delay_ms", type=float, default=10.0,
@@ -343,10 +341,6 @@ def main(argv=None):
     if args.frame_only:
         args.frame = True
 
-    if os.environ.get("ELASTICDL_TPU_PLATFORM"):
-        jax.config.update(
-            "jax_platforms", os.environ["ELASTICDL_TPU_PLATFORM"]
-        )
     if args.frame:
         # Frame-vs-TensorPB at equal (bf16) wire dtype: loopback shows
         # the CPU-side decode/encode savings, the emulated cross-host

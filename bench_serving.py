@@ -65,9 +65,9 @@ import tempfile
 import threading
 import time
 
-_PLATFORM = os.environ.get("ELASTICDL_TPU_PLATFORM") or "cpu"
-os.environ["ELASTICDL_TPU_PLATFORM"] = _PLATFORM
-os.environ["JAX_PLATFORMS"] = _PLATFORM
+# A host-side CPU bench (HTTP, batching and codec costs around a tiny
+# model): pinned to the CPU so that it takes no chip on a TPU host.
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import numpy as np  # noqa: E402
 
@@ -341,7 +341,6 @@ def _spawn_replica(base, port, ps_addrs="", cpu=None):
     env = dict(os.environ)
     env.update({
         "JAX_PLATFORMS": "cpu",
-        "ELASTICDL_TPU_PLATFORM": "cpu",
         "OMP_NUM_THREADS": "1",
         "OPENBLAS_NUM_THREADS": "1",
     })
@@ -976,8 +975,6 @@ def run_wire_bench(requests_per_client, max_batch_size,
 def main(argv=None):
     import argparse
 
-    import jax
-
     parser = argparse.ArgumentParser("bench_serving")
     parser.add_argument("--requests_per_client", type=int,
                         default=REQUESTS_PER_CLIENT)
@@ -1006,10 +1003,6 @@ def main(argv=None):
         return
 
     if args.wire:
-        if os.environ.get("ELASTICDL_TPU_PLATFORM"):
-            jax.config.update(
-                "jax_platforms",
-                os.environ["ELASTICDL_TPU_PLATFORM"])
         gates = run_wire_bench(args.requests_per_client,
                                args.max_batch_size,
                                args.batch_timeout_ms,
@@ -1017,10 +1010,6 @@ def main(argv=None):
         if not all(gates.values()):
             raise SystemExit("wire gates failed: %s" % gates)
         return
-
-    if os.environ.get("ELASTICDL_TPU_PLATFORM"):
-        jax.config.update(
-            "jax_platforms", os.environ["ELASTICDL_TPU_PLATFORM"])
 
     from elasticdl_tpu.serving.batcher import BatchConfig
 

@@ -8,8 +8,7 @@ data-parallel mesh:
      ~(N-1)/N reduction that is the point of ZeRO-1);
   2. **throughput** — steps/s, zero1 vs replicated, INTERLEAVED timed
      blocks (per-step K=1 and fused windows K=8) so machine-load drift
-     lands on both legs equally; each block closes with a value fetch
-     (the only real fence on this session's relay);
+     lands on both legs equally; each block closes with a value fetch;
   3. **exactness** — same-seed losses bit-identical with zero1 on vs
      off at K=1 and K=8, and an in-process elastic churn drill: Adam
      moments bit-exact through a live N -> N/2 device-to-device
@@ -43,20 +42,17 @@ if "xla_force_host_platform_device_count" not in _FLAGS:
 def _trainer(spec, mesh, batch_size, zero1, seed, accum=1):
     from elasticdl_tpu.worker.collective_trainer import CollectiveTrainer
 
+    # batch_size here is rows per device; the trainer wants the rows
+    # this process feeds a step.
     return CollectiveTrainer(
-        spec, batch_size=batch_size, mesh=mesh, rng_seed=seed,
-        zero1=zero1, accum_steps=accum,
+        spec, batch_size=batch_size * mesh.devices.size, mesh=mesh,
+        rng_seed=seed, zero1=zero1, accum_steps=accum,
     )
 
 
 def run_bench(blocks=5, steps_per_block=40, fused_steps=8,
               batch_size=8, bit_steps=40):
     import jax
-
-    if os.environ.get("ELASTICDL_TPU_PLATFORM"):
-        jax.config.update(
-            "jax_platforms", os.environ["ELASTICDL_TPU_PLATFORM"]
-        )
     import numpy as np
     from jax.sharding import Mesh
 

@@ -1,0 +1,362 @@
+#!/usr/bin/env python3
+"""Compile, run and compare every default-path Pallas kernel at the
+shapes the two flagship models use at full width.
+
+    python3 chip_check.py                  # on the chip, every case
+    python3 chip_check.py resnet_step      # cases whose name holds a word
+    python3 chip_check.py --tiny flash/    # CPU, interpret mode
+
+On the chip every kernel is jitted with ``interpret=False`` and compared,
+forward and gradient, against its reference (``_attention_ref``,
+``_partial_ref``, ``_group_norm_ref``) evaluated in float32 at highest
+matmul precision on the same bf16 values; then the full ResNet-50
+(batch 128, bf16) takes two ``CollectiveTrainer`` steps with the default
+``ELASTICDL_FUSED_GN=auto``, and two more with the model's GroupNorm
+swapped for the reference, and the losses must agree.  ``--tiny`` is the
+same code at toy shapes through the Pallas interpreter
+(tests/test_chip_bringup.py drives it, so the checker itself is
+exercised without chip time).  One JSON line per case, a summary line
+last; exit 1 if any case failed.  The process owns the chip for its
+whole life: run it alone (``chip_smoke.py``'s ``kernels`` leg does).
+"""
+
+import contextlib
+import json
+import os
+import sys
+import time
+import traceback
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from elasticdl_tpu.ops import flash_attention as fa
+from elasticdl_tpu.ops import group_norm as gn
+from elasticdl_tpu.utils.device import device_report, place_compile_cache
+
+# Errors are taken relative to the reference's largest value, so a
+# masking or tiling bug (an O(1) error) cannot hide under them.
+# Outputs in bf16 and gradients w.r.t. bf16 operands: bf16 rounds at
+# 2^-8, and p and ds are rounded once more before their matmuls
+# (measured 1.1e-3..5.1e-3 — my chip run, PR 21).
+_BF16_FWD, _BF16_GRAD = 2e-2, 4e-2
+# Quantities kernel and reference both accumulate in float32 from the
+# same bf16 values (measured 1.1e-7..8.1e-7, same run).  This is the
+# tolerance that sees a float32 contraction done in bf16 passes: that
+# defect put dscale off by 1e-3 and dbias by 1.7e-2.
+_F32 = 1e-4
+TOLERANCES = {
+    "fwd": _BF16_FWD, "o": _BF16_FWD,
+    "dq": _BF16_GRAD, "dk": _BF16_GRAD, "dv": _BF16_GRAD,
+    "dx": _BF16_GRAD,
+    "dscale": _F32, "dbias": _F32, "lse": _F32,
+}
+# ResNet step.  The head is zero-initialised, so the first loss is
+# ln(classes) whatever the kernels compute ...
+TOLERANCES["loss0_minus_ln_classes"] = 1e-2
+# ... and the second has been through every GroupNorm forward and
+# backward and one update: how far the loss moved with the fused
+# GroupNorm against the same update with the reference GroupNorm,
+# relative to the latter (measured 3.5e-3 on ResNet-50 b128 bf16:
+# 6.90775 -> 6.41835 fused, -> 6.42008 reference — my chip run, PR 21;
+# 5.7e-4 in --tiny on the CPU).
+TOLERANCES["step_vs_reference_gn"] = 2e-2
+
+
+def _rel_err(got, want):
+    """max|got - want| / max|want|, reduced on the device (the full-size
+    operands are hundreds of MB; only the scalar crosses to the host)."""
+    if got.shape != want.shape:
+        raise AssertionError("shape %s != %s" % (got.shape, want.shape))
+    got = got.astype(jnp.float32)
+    want = want.astype(jnp.float32)
+    if not bool(jnp.isfinite(got).all()):
+        raise AssertionError("non-finite values in kernel output")
+    return float(
+        jnp.abs(got - want).max() / jnp.maximum(jnp.abs(want).max(), 1e-6)
+    )
+
+
+def _f32(*arrays):
+    return tuple(a.astype(jnp.float32) for a in arrays)
+
+
+def _qkv(b, h, t, d, seed):
+    rng = np.random.RandomState(seed)
+    return tuple(
+        jnp.asarray(rng.randn(b, h, t, d), jnp.bfloat16) for _ in range(4)
+    )
+
+
+def _kernel_vs_corner(kernel, ref, loss, operands, ref_slice):
+    """Kernel at the full shape, reference on a [batch, head] corner of
+    the same values (attention is independent per head, and the dense
+    [T, T] reference at B8·H16·T2048 would not fit beside the kernel's
+    operands).  Returns (kernel out, reference out, gradient errors)."""
+    sb, sh = ref_slice
+    out = jax.jit(kernel)(*operands[:3])
+    grads = jax.jit(jax.grad(loss(kernel), argnums=(0, 1, 2)))(*operands)
+    corner = tuple(a[:sb, :sh] for a in _f32(*operands))
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(ref)(*corner[:3])
+        want_grads = jax.jit(
+            jax.grad(loss(ref), argnums=(0, 1, 2)))(*corner)
+    errs = {
+        name: _rel_err(g[:sb, :sh], wg)
+        for name, g, wg in zip(("dq", "dk", "dv"), grads, want_grads)
+    }
+    return out, want, errs
+
+
+def check_flash(b, h, t, d, window, interpret, ref_slice=(1, 2)):
+    scale = d ** -0.5
+    sb, sh = ref_slice
+
+    def loss(fn):
+        return lambda q, k, v, w: (
+            fn(q, k, v).astype(jnp.float32) * w.astype(jnp.float32)
+        ).sum()
+
+    out, want, errs = _kernel_vs_corner(
+        lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, scale=scale, interpret=interpret,
+            window=window),
+        lambda q, k, v: fa._attention_ref(q, k, v, True, scale,
+                                          window=window),
+        loss, _qkv(b, h, t, d, seed=t + d + window), ref_slice)
+    return {"fwd": _rel_err(out[:sb, :sh], want), **errs}
+
+
+def check_flash_partial(b, h, t, d, causal, interpret, ref_slice=(1, 2)):
+    """``flash_attention_partial`` the way ring attention calls it: the
+    diagonal block causal, lower blocks non-causal, (acc, l, m) out and
+    cotangents on all three coming back."""
+    scale = d ** -0.5
+    sb, sh = ref_slice
+
+    def loss(fn):
+        def f(q, k, v, w):
+            acc, l, m = fn(q, k, v)
+            # the ring's fold: every output reaches the loss
+            o = acc / jnp.maximum(l, 1e-30)[..., None]
+            return (o * w.astype(jnp.float32)).sum() + (
+                jnp.log(jnp.maximum(l, 1e-30)) + m).mean()
+        return f
+
+    (acc, l, m), (want_acc, want_l, want_m), errs = _kernel_vs_corner(
+        lambda q, k, v: fa.flash_attention_partial(
+            q, k, v, causal=causal, scale=scale, interpret=interpret),
+        lambda q, k, v: fa._partial_ref(q, k, v, causal, scale, 0),
+        loss, _qkv(b, h, t, d, seed=7 * t + d + causal), ref_slice)
+    # acc and l carry exp(s - m): compare the normalized output and the
+    # log-sum-exp, which do not depend on which row max was subtracted.
+    norm = lambda a, l: a / jnp.maximum(l, 1e-30)[..., None]
+    return {
+        "o": _rel_err(norm(acc, l)[:sb, :sh], norm(want_acc, want_l)),
+        "lse": _rel_err((jnp.log(l) + m)[:sb, :sh],
+                        jnp.log(want_l) + want_m),
+        **errs,
+    }
+
+
+def check_group_norm(batch, hw, channels, groups, relu, interpret,
+                     ref_batch=4):
+    rng = np.random.RandomState(hw + channels)
+    x = jnp.asarray(rng.randn(batch, hw, channels) * 2.0 + 0.5,
+                    jnp.bfloat16)
+    dy = jnp.asarray(rng.randn(batch, hw, channels), jnp.bfloat16)
+    scale = jnp.asarray(1.0 + 0.1 * rng.randn(channels), jnp.float32)
+    bias = jnp.asarray(0.1 * rng.randn(channels), jnp.float32)
+    eps = 1e-6
+
+    def loss(fn):
+        return lambda x, s, b, dy: (
+            fn(x, s, b).astype(jnp.float32) * dy.astype(jnp.float32)
+        ).sum()
+
+    kernel = lambda x, s, b: gn._fused(x, s, b, groups, eps, relu,
+                                       interpret)
+    ref = lambda x, s, b: gn._group_norm_ref(x, s, b, groups, eps, relu)
+    out = jax.jit(kernel)(x, scale, bias)
+    dx, dscale, dbias = jax.jit(
+        jax.grad(loss(kernel), argnums=(0, 1, 2)))(x, scale, bias, dy)
+    # dscale/dbias sum over the batch, so their reference needs the whole
+    # batch; in f32 that is 4 bytes x B·HW·C, which fits (<= 0.8 GB).
+    xf, dyf = _f32(x, dy)
+    want = jax.jit(ref)(xf[:ref_batch], scale, bias)
+    want_dx, want_dscale, want_dbias = jax.jit(
+        jax.grad(loss(ref), argnums=(0, 1, 2)))(xf, scale, bias, dyf)
+    if relu:
+        # An element whose pre-activation rounds to the other side of
+        # zero flips its ReLU mask, and kernel and reference associate
+        # the affine differently: of 1e8 elements a handful sit within
+        # rounding of zero.  Each flip is a legitimate O(1) difference in
+        # dx at that element, so dx is compared outside that band.
+        pre = jax.jit(lambda x, s, b: gn._group_norm_ref(
+            x, s, b, groups, eps, False))(xf, scale, bias)
+        settled = jnp.abs(pre) > 1e-4
+        dx = jnp.where(settled, dx, 0)
+        want_dx = jnp.where(settled, want_dx, 0)
+    return {
+        "fwd": _rel_err(out[:ref_batch], want),
+        "dx": _rel_err(dx, want_dx),
+        "dscale": _rel_err(dscale, want_dscale),
+        "dbias": _rel_err(dbias, want_dbias),
+    }
+
+
+@contextlib.contextmanager
+def _model_group_norm(fn):
+    """Swap the GroupNorm the ResNet model calls for ``fn``."""
+    from elasticdl_tpu.models import resnet
+
+    real = resnet.fused_group_norm
+    resnet.fused_group_norm = fn
+    try:
+        yield
+    finally:
+        resnet.fused_group_norm = real
+
+
+def _reference_group_norm(x, scale, bias, num_groups, eps=1e-6,
+                          relu=False):
+    return gn._group_norm_ref(x, scale, bias, num_groups, eps, relu)
+
+
+def resnet_group_norm_shapes(variant, image_size, batch):
+    """Every distinct (HW, C, groups, relu) the model hands to
+    ``fused_group_norm``, recorded from an abstract trace of the model
+    itself rather than listed by hand."""
+    from elasticdl_tpu.models import resnet
+
+    seen = []
+
+    def record(x, scale, bias, num_groups, eps=1e-6, relu=False):
+        key = (int(np.prod(x.shape[1:-1])), x.shape[-1], num_groups, relu)
+        if key not in seen:
+            seen.append(key)
+        return _reference_group_norm(x, scale, bias, num_groups, eps, relu)
+
+    spec = resnet.model_spec(variant=variant, image_size=image_size)
+    with _model_group_norm(record):
+        params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+        jax.eval_shape(
+            lambda p, x: spec.apply_fn(p, x, True), params,
+            jax.ShapeDtypeStruct(
+                (batch, image_size, image_size, 3), jnp.bfloat16),
+        )
+    return seen
+
+
+def _resnet_losses(variant, image_size, batch, num_classes):
+    """Two real trainer steps from the seeded init on seeded data."""
+    from elasticdl_tpu.models import resnet
+    from elasticdl_tpu.worker.collective_trainer import CollectiveTrainer
+
+    spec = resnet.model_spec(variant=variant, num_classes=num_classes,
+                             image_size=image_size, learning_rate=0.1)
+    trainer = CollectiveTrainer(spec, batch_size=batch,
+                                use_bf16_compute=True)
+    rng = np.random.RandomState(0)
+    xs = rng.rand(batch, image_size, image_size, 3).astype(np.float32)
+    ys = rng.randint(0, num_classes, size=batch).astype(np.int32)
+    losses = [float(trainer.train_minibatch(xs, ys)[0]) for _ in range(2)]
+    if not all(np.isfinite(losses)):
+        raise AssertionError("non-finite loss %s" % losses)
+    return losses
+
+
+def check_resnet_step(variant, image_size, batch, num_classes):
+    """The whole model through the trainer with whatever
+    ``fused_gn_mode()`` resolves to by default, then again with the
+    reference GroupNorm in the model."""
+    args = (variant, image_size, batch, num_classes)
+    fused = _resnet_losses(*args)
+    print(json.dumps({"resnet_step": "fused", "losses": fused,
+                      "fused_gn": gn.fused_gn_mode()}), flush=True)
+    with _model_group_norm(_reference_group_norm):
+        ref = _resnet_losses(*args)
+    moved, ref_moved = fused[1] - fused[0], ref[1] - ref[0]
+    return {
+        "loss0_minus_ln_classes": abs(fused[0] - np.log(num_classes)),
+        "loss1": fused[1],
+        "loss1_reference_gn": ref[1],
+        "step_vs_reference_gn": abs(moved - ref_moved) / abs(ref_moved),
+    }
+
+
+def _cases(tiny):
+    interpret = tiny
+    if tiny:
+        b, h, t = 1, 2, 256
+        resnet_args = ("resnet_small_cifar10", 32, 8, 10)
+    else:
+        b, h, t = 8, 16, 2048
+        resnet_args = ("resnet50", 224, 128, 1000)
+    for d in (64, 128):
+        for window in (0, t // 4):
+            yield ("flash/B%d.H%d.T%d.D%d.window%d" % (b, h, t, d, window),
+                   lambda d=d, window=window: check_flash(
+                       b, h, t, d, window, interpret))
+    for causal in (True, False):
+        yield ("flash_partial/B%d.H%d.T%d.D64.causal%d" % (b, h, t, causal),
+               lambda causal=causal: check_flash_partial(
+                   b, h, t, 64, causal, interpret))
+    variant, image_size, batch, classes = resnet_args
+    for hw, c, groups, relu in resnet_group_norm_shapes(
+            variant, image_size, batch):
+        yield ("group_norm/B%d.HW%d.C%d.G%d.relu%d"
+               % (batch, hw, c, groups, relu),
+               lambda hw=hw, c=c, groups=groups, relu=relu:
+               check_group_norm(batch, hw, c, groups, relu, interpret))
+    yield ("resnet_step/%s.b%d" % (variant, batch),
+           lambda: check_resnet_step(variant, image_size, batch, classes))
+
+
+def main(argv=None):
+    argv = sys.argv[1:] if argv is None else argv
+    tiny = "--tiny" in argv
+    wanted = [a for a in argv if not a.startswith("--")]
+    place_compile_cache()
+    if tiny:
+        os.environ["ELASTICDL_FUSED_GN"] = "interpret"
+    report = device_report()
+    print(json.dumps({"device": report}), flush=True)
+    if not tiny and report["platform"] != "tpu":
+        print("chip_check: platform is %r, not tpu (use --tiny for the "
+              "interpreter)" % report["platform"], file=sys.stderr)
+        return 1
+    failed, ran = [], 0
+    for name, run in _cases(tiny):
+        if wanted and not any(word in name for word in wanted):
+            continue
+        ran += 1
+        t0 = time.perf_counter()
+        row = {"case": name}
+        try:
+            errs = run()
+            row["errs"] = errs
+            # "not <=" so that a NaN is over tolerance too
+            bad = {k: e for k, e in errs.items()
+                   if not e <= TOLERANCES.get(k, float("inf"))}
+            if bad:
+                raise AssertionError("over tolerance: %s" % bad)
+            row["ok"] = True
+        except Exception as e:  # noqa: BLE001 — report every case
+            row["ok"] = False
+            row["error"] = "%s: %s" % (type(e).__name__, str(e)[-1500:])
+            traceback.print_exc()
+            failed.append(name)
+        row["secs"] = round(time.perf_counter() - t0, 1)
+        print(json.dumps(row), flush=True)
+    if not ran:
+        failed.append("no case matches %s" % wanted)
+    print(json.dumps({"chip_check": "kernels", "ok": not failed,
+                      "cases": ran, "failed": failed, "device": report}),
+          flush=True)
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

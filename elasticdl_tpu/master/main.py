@@ -93,7 +93,8 @@ def _build_worker_backend(args, worker_args):
             owner_ref=owner_ref_from_env(),
             volume=args.volume,
         )
-    return ProcessWorkerBackend(worker_args=worker_args)
+    return ProcessWorkerBackend(worker_args=worker_args,
+                                num_workers=args.num_workers)
 
 
 def build_master(args):

@@ -107,7 +107,9 @@ class PSManager:
 
     def _launch(self, ps_id, restore=False):
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # PS shards are host-side: an inherited JAX_PLATFORMS (or none,
+        # on a TPU host) must not let a shard take a chip from a worker.
+        env["JAX_PLATFORMS"] = "cpu"
         with self._lock:
             if self._stopped.is_set():
                 return

@@ -11,6 +11,7 @@ plug in under one interface:
  - TPU-VM/k8s backends slot in here later with the same event surface.
 """
 
+import glob
 import os
 import signal
 import subprocess
@@ -36,23 +37,92 @@ class WorkerHandle:
         self.relaunch_pending = False
 
 
-class ProcessWorkerBackend:
-    """Workers as local subprocesses of `python -m elasticdl_tpu.worker.main`."""
+# libtpu's TPU_CHIPS_PER_PROCESS_BOUNDS for a process that owns this many
+# consecutive chips of one host.  Two chips are "2,1,1": chips 0,1 and
+# 2,3 of a v5e 2x2 host are neighbours along x, and libtpu 0.0.34 exits 1
+# without a word on "1,2,1" (my chip run, PR 21).
+_CHIP_BOUNDS = {1: "1,1,1", 2: "2,1,1", 4: "2,2,1", 8: "2,4,1"}
 
-    def __init__(self, worker_args=None, env=None):
+
+def count_host_tpu_chips():
+    """TPU chips this host lets a process open: the device nodes libtpu
+    itself enumerates, ``/dev/accel<N>`` (through v4) or ``/dev/vfio/<N>``
+    (v5e and later).  Listing them opens nothing, so the master never
+    holds a chip.  (PCI sysfs is not the answer: a one-chip slot of a
+    four-chip host lists four functions and one vfio group — my chip
+    run, PR 21.)  0 on a host with none."""
+    return len(glob.glob("/dev/accel[0-9]*")) or len(
+        glob.glob("/dev/vfio/[0-9]*"))
+
+
+def chip_env_for_slot(slot, num_workers, num_chips):
+    """The libtpu environment that gives worker ``slot`` its own share
+    of the host's chips, disjoint from every other slot's.
+
+    A chip belongs to one process at a time, so N workers that all see
+    every chip cannot start: the first takes them all.  One worker keeps
+    libtpu's default (every chip, no variables); N > 1 workers get
+    ``num_chips / N`` consecutive chips each.  A request the host cannot
+    satisfy raises ValueError — at start-up, not from inside the
+    relaunch loop, where elasticity would turn it into a slow pass."""
+    if num_workers <= 1:
+        return {}
+    per_worker, left_over = divmod(num_chips, num_workers)
+    if per_worker == 0 or left_over or per_worker not in _CHIP_BOUNDS:
+        raise ValueError(
+            "cannot give %d workers disjoint chips on a host with %d TPU "
+            "chip(s): the chip count must be a multiple of --num_workers "
+            "(each worker process owns its chips exclusively)"
+            % (num_workers, num_chips)
+        )
+    first = slot * per_worker
+    return {
+        "TPU_VISIBLE_CHIPS": ",".join(
+            str(chip) for chip in range(first, first + per_worker)
+        ),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": _CHIP_BOUNDS[per_worker],
+        # Each worker is its own one-process slice; workers of a
+        # collective job meet through the master's rendezvous, not
+        # through libtpu's slice builder.
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        # libtpu otherwise refuses a second load on one host.
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+    }
+
+
+class ProcessWorkerBackend:
+    """Workers as local subprocesses of `python -m elasticdl_tpu.worker.main`.
+
+    Workers inherit the master's environment, ``JAX_PLATFORMS`` included:
+    on a TPU host they train on the TPU, and CPU drills and tests pass
+    ``JAX_PLATFORMS=cpu`` themselves.  Unless the job is held to the CPU
+    that way, ``num_workers`` > 1 splits the host's chips between the
+    worker slots (``chip_env_for_slot``)."""
+
+    def __init__(self, worker_args=None, env=None, num_workers=1):
         self._worker_args = worker_args or []
         self._env = env or {}
+        self._num_workers = num_workers
+        self._num_chips = 0
+        platforms = {**os.environ, **self._env}.get("JAX_PLATFORMS", "")
+        if num_workers > 1 and platforms.strip() != "cpu":
+            self._num_chips = count_host_tpu_chips()
+        if self._num_chips:
+            # Refuse an unsatisfiable request now, before any launch.
+            chip_env_for_slot(0, num_workers, self._num_chips)
 
     def launch(self, worker_id, master_addr, slot=None, extra_env=None):
-        del slot  # process workers have no service to re-point
         env = dict(os.environ)
         env.update(self._env)
+        if self._num_chips:
+            slot = worker_id if slot is None else slot
+            env.update(chip_env_for_slot(
+                slot % self._num_workers, self._num_workers,
+                self._num_chips,
+            ))
         env.update(extra_env or {})
         env["MASTER_ADDR"] = master_addr
         env["WORKER_ID"] = str(worker_id)
-        # Workers in drills run on CPU so N of them fit on one host.
-        env.setdefault("JAX_PLATFORMS", "cpu")
-        env.setdefault("ELASTICDL_TPU_PLATFORM", env["JAX_PLATFORMS"])
         proc = subprocess.Popen(
             [sys.executable, "-m", "elasticdl_tpu.worker.main"]
             + list(self._worker_args),

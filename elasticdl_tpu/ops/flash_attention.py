@@ -27,10 +27,14 @@ Layout: [batch, heads, seq, head_dim].  The caller-facing block sizes
 are a friendliness contract (seq divisible by them, 128-lane block_k);
 the kernel chooses its own internal tiling (up to 512-wide q blocks and
 K/V major tiles) to amortize per-grid-step overhead.  `flash_attention`
-falls back to the reference implementation for unfriendly shapes.
+falls back to the reference implementation for unfriendly shapes, and in
+the compiled mode says so once per shape (``announce_fallback``): on the
+chip a silent reference path is a slow path nobody asked for.
 Mode selection: ``ELASTICDL_FLASH=auto`` (default: compiled kernel on
-TPU — validated on the real chip 2026-07-29, see BENCHMARKS.md; jnp
-elsewhere), ``interpret`` (Pallas interpret mode, for tests), ``off``.
+TPU; jnp elsewhere), ``interpret`` (Pallas interpret mode, for tests),
+``off``.  Forward and Pallas backward compile and match the reference at
+B8·H16·T2048, D64 and D128, full causal and windowed, on a v5e (my chip
+run, PR 21; ``chip_check.py`` at the repo root repeats it).
 """
 
 import functools
@@ -41,7 +45,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from elasticdl_tpu.ops.batch_shard import per_batch_shard
+from elasticdl_tpu.utils.logging import get_logger
+
+logger = get_logger(__name__)
+
 NEG_INF = -1e30
+
+# chip_smoke.py fails an LM leg on this prefix.
+FALLBACK_PREFIX = "attention fallback:"
+
+
+@functools.lru_cache(maxsize=None)
+def announce_fallback(what, shape, why):
+    """Once per (call site, shape, reason): the compiled kernel was asked
+    for and a jnp path ran instead."""
+    logger.warning("%s %s for shape %s took the jnp reference path: %s",
+                   FALLBACK_PREFIX, what, shape, why)
 
 
 def flash_mode():
@@ -643,12 +663,19 @@ def _check_window(window, causal):
         raise ValueError("window must be >= 0, got %d" % window)
 
 
-def _friendly(t, d, block_q, block_k):
+def _unfriendly(t, d, block_q, block_k):
+    """Why the kernel cannot take this shape, or "" when it can."""
     # block_k must equal STATS_LANES so the kernel's [bq, bk] score tile
     # is lane-aligned with the [bq, STATS_LANES] running stats.
-    return block_k == STATS_LANES and not (
-        t % block_q or t % block_k or (d % 128 and d != 64)
-    )
+    if block_k != STATS_LANES:
+        return "block_k %d is not the %d-lane stats width" % (
+            block_k, STATS_LANES)
+    if t % block_q or t % block_k:
+        return "seq %d is not a multiple of the %dx%d blocks" % (
+            t, block_q, block_k)
+    if d % 128 and d != 64:
+        return "head_dim %d is neither 64 nor a multiple of 128" % d
+    return ""
 
 
 def flash_attention(q, k, v, causal=True, scale=None, block_q=128,
@@ -662,10 +689,16 @@ def flash_attention(q, k, v, causal=True, scale=None, block_q=128,
     d = q.shape[3]
     block_q = min(block_q, t)
     block_k = min(block_k, t)
-    if not _friendly(t, d, block_q, block_k):
+    why = _unfriendly(t, d, block_q, block_k)
+    if why:
+        if not interpret:
+            announce_fallback("flash_attention", q.shape, why)
         return _attention_ref(q, k, v, causal, scale, window=window)
-    return _flash(q, k, v, causal, scale, block_q, block_k, interpret,
-                  window)
+    return per_batch_shard(
+        lambda q, k, v: _flash(q, k, v, causal, scale, block_q, block_k,
+                               interpret, window),
+        (q, k, v),
+    )
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
@@ -902,7 +935,12 @@ def flash_attention_partial(q, k, v, causal=True, scale=None, k_offset=0,
     t, d = q.shape[2], q.shape[3]
     block_q = min(block_q, t)
     block_k = min(block_k, t)
-    if (causal and k_offset != 0) or not _friendly(t, d, block_q, block_k):
+    why = _unfriendly(t, d, block_q, block_k)
+    if causal and k_offset != 0:
+        why = "causal block with k_offset %d" % k_offset
+    if why:
+        if not interpret:
+            announce_fallback("flash_attention_partial", q.shape, why)
         return _partial_ref(q, k, v, causal, scale, k_offset,
                             window=window)
     return _flash_partial(q, k, v, causal, scale, block_q, block_k,

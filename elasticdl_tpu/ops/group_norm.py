@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from elasticdl_tpu.ops.batch_shard import per_batch_shard
+
 
 def fused_gn_mode():
     mode = os.environ.get("ELASTICDL_FUSED_GN", "auto")
@@ -78,10 +80,18 @@ def _membership(L, num_groups, logical_C):
 
 
 def _group_mean_c(row, memb, n):
-    """row [1, C] -> per-group mean broadcast back to [1, C]."""
+    """row [1, C] -> per-group mean broadcast back to [1, C].
+
+    HIGHEST precision: Mosaic's default contracts f32 operands in bf16
+    passes, which left the group mean and rstd good to ~1e-3 on the chip
+    (my chip run, PR 21: dscale off by 1e-3 against the f32 reference,
+    3e-7 with this).  The operands are one row, so the exact contraction
+    costs nothing."""
+    exact = jax.lax.Precision.HIGHEST
     return jnp.dot(
-        jnp.dot(row, memb, preferred_element_type=jnp.float32),
-        memb.T, preferred_element_type=jnp.float32,
+        jnp.dot(row, memb, preferred_element_type=jnp.float32,
+                precision=exact),
+        memb.T, preferred_element_type=jnp.float32, precision=exact,
     ) / n
 
 
@@ -350,6 +360,9 @@ def fused_group_norm(x, scale, bias, num_groups, eps=1e-6, relu=False):
         )
     mode = fused_gn_mode()
     if mode in ("tpu", "interpret"):
-        return _fused(x, scale, bias, num_groups, eps, relu,
-                      mode == "interpret")
+        return per_batch_shard(
+            lambda x, scale, bias: _fused(x, scale, bias, num_groups, eps,
+                                          relu, mode == "interpret"),
+            (x,), (scale, bias),
+        )
     return _group_norm_ref(x, scale, bias, num_groups, eps, relu)

@@ -97,9 +97,10 @@ class MasterCoordinationService:
     survivors of a membership change must detach from it with an
     explicit client shutdown, and that RPC is only safe while the old
     service is still up — the client's heartbeat/shutdown failure
-    paths TERMINATE the worker process from C++ (and this jaxlib's
-    missed_heartbeat_callback binding raises std::bad_cast for every
-    Python callable, so the fatal path cannot be intercepted).
+    paths TERMINATE the worker process from C++ (jaxlib 0.9.0 accepts
+    a Python ``missed_heartbeat_callback`` but throws std::bad_cast
+    when it fires — re-checked in PR 21 by killing the service under a
+    connected client — so the fatal path cannot be intercepted).
     ``reap_secs`` therefore must exceed the workers' worst-case
     epoch-discovery time; ``reap_secs=None`` derives it from the check
     cadence via ``derive_reap_secs`` (pass the job's actual
@@ -298,9 +299,23 @@ def initialize_from_rendezvous(rank, world_size, coordinator_addr):
         _client_disconnect()
         _discard_old_world()
         _client_connect(rank, world_size, host_port)
+        # The backend re-created under the new client must span the
+        # world the master committed.  A backend that takes its topology
+        # from elsewhere (libtpu, from its own slice) can come back
+        # seeing only this process; training on would be N disconnected
+        # jobs sharing one task queue and calling themselves a world.
+        if jax.process_count() != world_size:
+            raise RuntimeError(
+                "collective world of %d workers was committed, but this "
+                "process's %s backend spans %d process(es) and %d "
+                "device(s): it did not join the world"
+                % (world_size, jax.default_backend(),
+                   jax.process_count(), jax.device_count())
+            )
         logger.info(
-            "collective world joined (client-only): rank %d / %d via %s",
-            rank, world_size, host_port,
+            "collective world joined (client-only): rank %d / %d via %s "
+            "(%d global devices)",
+            rank, world_size, host_port, jax.device_count(),
         )
         return True
     try:

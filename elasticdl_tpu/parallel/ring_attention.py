@@ -28,6 +28,7 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from elasticdl_tpu.ops.flash_attention import (
+    announce_fallback,
     flash_attention_partial,
     flash_mode,
 )
@@ -212,9 +213,12 @@ def ring_attention(q, k, v, mesh, causal=True, scale=None,
             and q.shape[2] % tp == 0
         ):
             # The Pallas kernel must run INSIDE a manual shard_map over
-            # dp/tp: called under plain GSPMD, pallas_call is opaque to
-            # the partitioner, which all-gathers q/k/v and replicates
-            # the whole computation on every device.
+            # dp/tp: pallas_call is opaque to the partitioner, and under
+            # a multi-device GSPMD jit JAX 0.9.0 refuses to lower a
+            # Mosaic kernel at all ("cannot be automatically
+            # partitioned" — my chip run, PR 21).  ops/batch_shard.py is
+            # the same repair for the trainer's batch-only mesh; this one
+            # also splits heads over tp.
             spec = P(dp_axis, None, tp_axis, None)
             fn = shard_map(
                 functools.partial(
@@ -227,6 +231,13 @@ def ring_attention(q, k, v, mesh, causal=True, scale=None,
                 check_vma=False,
             )
             return fn(q, k, v)
+        if mode == "tpu":
+            announce_fallback(
+                "ring_attention", q.shape,
+                "batch %d / heads %d do not divide dp=%d / tp=%d, and "
+                "outside a shard_map the kernel cannot be partitioned"
+                % (q.shape[0], q.shape[2], dp, tp),
+            )
         return attention_local(
             q, k, v, causal=causal, scale=scale, mode="off",
             window=window,

@@ -25,6 +25,7 @@ import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from elasticdl_tpu.ops.batch_shard import batch_axis
 from elasticdl_tpu.utils import tracing
 from elasticdl_tpu.utils.logging import get_logger
 from elasticdl_tpu.utils.pytree import flatten_with_names, to_numpy
@@ -57,8 +58,8 @@ class _PadPlan:
     def __init__(self, leaves, n, local, accum, micro):
         if n > local:
             raise ValueError(
-                "minibatch has %d records > trainer's global batch %d"
-                % (n, local)
+                "minibatch has %d records > the %d rows this process "
+                "feeds a step" % (n, local)
             )
         pad = local - n
         self.local = local
@@ -92,8 +93,8 @@ def _pad_batch(tree, batch_size):
     n = leaves[0].shape[0]
     if n > batch_size:
         raise ValueError(
-            "minibatch has %d records > trainer's global batch %d"
-            % (n, batch_size)
+            "minibatch has %d records > the %d rows this process "
+            "feeds a step" % (n, batch_size)
         )
     weights = np.zeros((batch_size,), dtype=np.float32)
     weights[:n] = 1.0
@@ -109,6 +110,13 @@ def _pad_batch(tree, batch_size):
 
 
 class CollectiveTrainer(Trainer):
+    """``batch_size`` is the number of rows THIS PROCESS feeds one
+    (micro-)step — the figure the ``Worker`` streams minibatches of
+    (``--batch_size``), whatever the mesh.  The rows are sharded over
+    the process's own devices, so each device computes on
+    ``ceil(batch_size / local devices)`` of them; train, evaluate and
+    predict all pad a minibatch up to ``_process_rows()``."""
+
     def __init__(
         self,
         spec,
@@ -415,6 +423,13 @@ class CollectiveTrainer(Trainer):
             return 1
         return len({d.process_index for d in self._mesh.devices.flat})
 
+    def _process_rows(self):
+        """Rows this process contributes to one (micro-)step:
+        ``batch_size`` rounded up to a whole number of rows per local
+        device."""
+        local_devices = self.global_device_count // self.process_count
+        return -(-self._batch_size // local_devices) * local_devices
+
     def _globalize(self, tree, sharding):
         """Assemble per-process local batches into global arrays.
 
@@ -456,7 +471,10 @@ class CollectiveTrainer(Trainer):
                 )
                 p = jax.tree_util.tree_map(to_bf16, p)
                 x = jax.tree_util.tree_map(to_bf16, x)
-            out = apply_fn(p, x, True)
+            # Pallas kernels inside the model run per shard of the data
+            # axis instead of being replicated by the partitioner.
+            with batch_axis(self._mesh, self._data_axis):
+                out = apply_fn(p, x, True)
             per_example = loss_fn(out, labels).astype(jnp.float32)
             return _masked_mean(per_example, weights)
 
@@ -663,7 +681,8 @@ class CollectiveTrainer(Trainer):
         apply_fn = self._spec.apply_fn
 
         def step(params, features):
-            return apply_fn(params, features, False)
+            with batch_axis(self._mesh, self._data_axis):
+                return apply_fn(params, features, False)
 
         if self._mesh is None:
             return jax.jit(step)
@@ -714,10 +733,7 @@ class CollectiveTrainer(Trainer):
                    tuple(np.shape(leaf)[1:] for leaf in leaves))
             plan = self._pad_plans.get(key)
             if plan is None:
-                procs = self.process_count
-                micro = self._batch_size * (
-                    self.global_device_count // procs
-                )
+                micro = self._process_rows()
                 local = micro * self._accum_steps
                 plan = _PadPlan(
                     leaves, n, local, self._accum_steps, micro
@@ -906,28 +922,22 @@ class CollectiveTrainer(Trainer):
 
     def evaluate_minibatch(self, features, labels):
         n = jax.tree_util.tree_leaves(features)[0].shape[0]
+        features, _, _ = self._padded(
+            features, labels, self._process_rows())
         if self.process_count > 1:
-            features, _, _ = self._padded(
-                features, labels, self._batch_size)
             outputs = self._forward_local(features)
         else:
-            total = self._batch_size * self.global_device_count
-            features, _, _ = self._padded(features, labels, total)
             outputs = self._eval_step(self._params, features)
         outputs = np.asarray(outputs)[:n]
         return outputs, np.asarray(labels)
 
     def predict_minibatch(self, features):
         n = jax.tree_util.tree_leaves(features)[0].shape[0]
+        features, _ = _pad_batch(features, self._process_rows())
         if self.process_count > 1:
-            padded, _ = _pad_batch(features, self._batch_size)
-            return np.asarray(self._forward_local(padded))[:n]
-        total = self._batch_size * self.global_device_count
-        leaves = jax.tree_util.tree_leaves(features)
-        weights = None
-        if leaves[0].shape[0] != total:
-            features, weights = _pad_batch(features, total)
-        outputs = self._eval_step(self._params, features)
+            outputs = self._forward_local(features)
+        else:
+            outputs = self._eval_step(self._params, features)
         return np.asarray(outputs)[:n]
 
     # -- state --------------------------------------------------------------

@@ -174,6 +174,11 @@ class Worker:
         # single-thread discipline as _tele_mark.
         self._tele_hist_prev = None
 
+    @property
+    def steps_done(self):
+        """Optimizer steps this worker ran (its end-of-run report)."""
+        return self._steps
+
     def _telemetry_snapshot(self):
         """Telemetry dict for the next progress RPC: worker-local
         steps/s over the interval since the previous report,

@@ -141,8 +141,7 @@ def test_cli_serve_end_to_end(tmp_path):
         [sys.executable, "-m", "elasticdl_tpu.client.main", "serve",
          "--export_dir", str(tmp_path / "e"), "--port", str(port),
          "--host", "127.0.0.1"],
-        env={**os.environ, "ELASTICDL_TPU_PLATFORM": "cpu",
-             "JAX_PLATFORMS": "cpu"},
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
     base = "http://127.0.0.1:%d/v1/models/srv" % port
     try:

@@ -32,12 +32,42 @@ def test_mesh_step_matches_single_device(spec):
     xs, ys = mnist.synthetic_data(n=64, seed=3)
     single = CollectiveTrainer(spec, batch_size=64, rng_seed=0)
     mesh = make_mesh(8)
-    multi = CollectiveTrainer(spec, batch_size=8, mesh=mesh, rng_seed=0)
+    multi = CollectiveTrainer(spec, batch_size=64, mesh=mesh, rng_seed=0)
     # same global batch (64), same init seed -> same loss trajectory
     for _ in range(3):
         loss_s, _ = single.train_minibatch(xs, ys)
         loss_m, _ = multi.train_minibatch(xs, ys)
         np.testing.assert_allclose(loss_s, loss_m, rtol=2e-4)
+
+
+def test_batch_size_is_rows_per_process_on_every_path(spec, monkeypatch):
+    """``batch_size`` is what the Worker feeds this process per step;
+    the per-device share follows from the mesh.  Train prep, evaluate
+    and predict pad to the same figure — also in a world of 2 processes
+    x 2 devices, where evaluation runs process-locally."""
+    xs, ys = mnist.synthetic_data(n=16, seed=1)
+    trainer = CollectiveTrainer(spec, batch_size=16, mesh=make_mesh(4))
+    assert trainer._process_rows() == 16          # 4 rows per device
+    assert trainer.prepare_batch(xs, ys).weights.shape == (16,)
+    want, _ = trainer.evaluate_minibatch(xs, ys)
+    monkeypatch.setattr(CollectiveTrainer, "process_count",
+                        property(lambda self: 2))
+    assert trainer._process_rows() == 16          # 8 rows per device
+    got, labels = trainer.evaluate_minibatch(xs, ys)
+    assert got.shape[0] == 16 and labels.shape[0] == 16
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(
+        trainer.predict_minibatch(xs[:10]), want[:10],
+        rtol=1e-5, atol=1e-6)
+    # A batch that does not divide the local devices is rounded up.
+    odd = CollectiveTrainer(spec, batch_size=6, mesh=make_mesh(4))
+    assert odd._process_rows() == 6               # 2 local devices
+    monkeypatch.undo()
+    assert odd._process_rows() == 8               # 4 local devices
+    loss, _ = odd.train_minibatch(xs[:6], ys[:6])
+    assert np.isfinite(float(loss))
+    with pytest.raises(ValueError, match="rows this process feeds"):
+        odd.train_minibatch(xs[:9], ys[:9])
 
 
 def test_partial_batch_padding_no_recompile(spec):
@@ -61,7 +91,7 @@ def test_gradient_accumulation_matches_large_batch(spec):
 def test_elastic_mesh_rebuild(spec):
     """World resize: 8 -> 4 devices, training continues."""
     xs, ys = mnist.synthetic_data(n=32, seed=7)
-    trainer = CollectiveTrainer(spec, batch_size=4, mesh=make_mesh(8))
+    trainer = CollectiveTrainer(spec, batch_size=32, mesh=make_mesh(8))
     loss1, _ = trainer.train_minibatch(xs, ys)
     trainer.rebuild(make_mesh(4))  # lost half the world
     loss2, _ = trainer.train_minibatch(xs[:16], ys[:16])
@@ -145,7 +175,7 @@ def test_restore_on_mesh_resumes_trajectory(spec, tmp_path):
     t1.train_minibatch(xs, ys)
     t1.flush_checkpoints()
 
-    t2 = CollectiveTrainer(spec, batch_size=4, mesh=make_mesh(8),
+    t2 = CollectiveTrainer(spec, batch_size=32, mesh=make_mesh(8),
                            rng_seed=99, checkpoint_saver=saver)
     assert t2.init_from_checkpoint()
     losses_resumed = [t2.train_minibatch(xs, ys)[0] for _ in range(2)]
@@ -158,8 +188,8 @@ def test_zero1_matches_replicated_trajectory(spec):
     sharded over the data axis."""
     xs, ys = mnist.synthetic_data(n=64, seed=17)
     mesh = make_mesh(8)
-    base = CollectiveTrainer(spec, batch_size=8, mesh=mesh, rng_seed=3)
-    z1 = CollectiveTrainer(spec, batch_size=8, mesh=mesh, rng_seed=3,
+    base = CollectiveTrainer(spec, batch_size=64, mesh=mesh, rng_seed=3)
+    z1 = CollectiveTrainer(spec, batch_size=64, mesh=mesh, rng_seed=3,
                            zero1=True)
     for _ in range(3):
         loss_b, _ = base.train_minibatch(xs, ys)
@@ -180,15 +210,15 @@ def test_zero1_checkpoint_restore_roundtrip(spec, tmp_path):
     saver = CheckpointSaver(str(tmp_path))
     xs, ys = mnist.synthetic_data(n=32, seed=19)
     mesh = make_mesh(8)
-    t1 = CollectiveTrainer(spec, batch_size=4, mesh=mesh, rng_seed=5,
+    t1 = CollectiveTrainer(spec, batch_size=32, mesh=mesh, rng_seed=5,
                            zero1=True, checkpoint_saver=saver,
                            checkpoint_steps=2)
-    ref = CollectiveTrainer(spec, batch_size=4, mesh=mesh, rng_seed=5)
+    ref = CollectiveTrainer(spec, batch_size=32, mesh=mesh, rng_seed=5)
     losses_ref = [ref.train_minibatch(xs, ys)[0] for _ in range(4)]
     t1.train_minibatch(xs, ys)
     t1.train_minibatch(xs, ys)
     t1.flush_checkpoints()
-    t2 = CollectiveTrainer(spec, batch_size=4, mesh=mesh, rng_seed=9,
+    t2 = CollectiveTrainer(spec, batch_size=32, mesh=mesh, rng_seed=9,
                            zero1=True, checkpoint_saver=saver)
     assert t2.init_from_checkpoint()
     resumed = [t2.train_minibatch(xs, ys)[0] for _ in range(2)]

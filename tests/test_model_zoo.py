@@ -213,7 +213,6 @@ def test_transformer_lm_managed_job_e2e(tmp_path):
     log = tmp_path / "job.log"
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["ELASTICDL_TPU_PLATFORM"] = "cpu"
     with open(log, "w") as f:
         proc = subprocess.run(
             [sys.executable, "-m", "elasticdl_tpu.master.main",

@@ -168,7 +168,6 @@ class _ChurnBackend:
         env["MASTER_ADDR"] = master_addr
         env["WORKER_ID"] = str(worker_id)
         env["JAX_PLATFORMS"] = "cpu"
-        env["ELASTICDL_TPU_PLATFORM"] = "cpu"
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
         env["ELASTICDL_COLLECTIVE_HEARTBEAT"] = "5"
         # Generous: a replacement needs ~10 s to boot + join (double

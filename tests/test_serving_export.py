@@ -88,8 +88,7 @@ def test_servable_loads_without_framework(tmp_path):
             os.path.abspath(__file__))),
         "export_dir": export_dir,
     }
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               ELASTICDL_TPU_PLATFORM="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True,
         timeout=240, env=env,

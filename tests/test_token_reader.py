@@ -73,7 +73,6 @@ def test_managed_lm_training_from_token_file(tmp_path):
     _make_file(path, n_tokens=16 * 256, vocab=128)
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["ELASTICDL_TPU_PLATFORM"] = "cpu"
     proc = subprocess.run(
         [
             sys.executable, "-m", "elasticdl_tpu.master.main",

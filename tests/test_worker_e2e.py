@@ -139,7 +139,6 @@ def test_predict_job_writes_outputs(tmp_path):
 
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["ELASTICDL_TPU_PLATFORM"] = "cpu"
     ckpt = str(tmp_path / "ckpt")
     base = [
         sys.executable, "-m", "elasticdl_tpu.master.main",
@@ -183,7 +182,6 @@ def test_evaluate_job_reports_metrics(tmp_path):
 
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["ELASTICDL_TPU_PLATFORM"] = "cpu"
     ckpt = str(tmp_path / "ckpt")
     base = [
         sys.executable, "-m", "elasticdl_tpu.master.main",
@@ -223,7 +221,6 @@ def test_managed_collective_two_workers_form_world():
 
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["ELASTICDL_TPU_PLATFORM"] = "cpu"
     env["ELASTICDL_COLLECTIVE_HEARTBEAT"] = "5"
     proc = subprocess.run(
         [
@@ -260,7 +257,6 @@ def test_graceful_preemption_checkpoints_before_exit(tmp_path):
 
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["ELASTICDL_TPU_PLATFORM"] = "cpu"
     ckpt = str(tmp_path / "ckpt")
     job = "graceful-preempt-drill"
     proc = subprocess.Popen(
@@ -322,7 +318,6 @@ def test_managed_collective_lora_finetune():
 
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["ELASTICDL_TPU_PLATFORM"] = "cpu"
     env["ELASTICDL_COLLECTIVE_HEARTBEAT"] = "5"
     proc = subprocess.run(
         [

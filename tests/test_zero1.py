@@ -57,8 +57,8 @@ def test_zero1_per_step_bitwise_equivalence(spec):
     update were not numerically pinned."""
     xs, ys = mnist.synthetic_data(n=64, seed=21)
     mesh = make_mesh(8)
-    base = CollectiveTrainer(spec, batch_size=8, mesh=mesh, rng_seed=7)
-    z1 = CollectiveTrainer(spec, batch_size=8, mesh=mesh, rng_seed=7,
+    base = CollectiveTrainer(spec, batch_size=64, mesh=mesh, rng_seed=7)
+    z1 = CollectiveTrainer(spec, batch_size=64, mesh=mesh, rng_seed=7,
                            zero1=True)
     for _ in range(12):
         loss_b, _ = base.train_minibatch(xs, ys)
@@ -72,8 +72,8 @@ def test_zero1_fused_window_bitwise_equivalence(spec, window):
     1/N flat shards) reproduces the replicated window bit-for-bit."""
     xs, ys = mnist.synthetic_data(n=64, seed=23)
     mesh = make_mesh(8)
-    base = CollectiveTrainer(spec, batch_size=8, mesh=mesh, rng_seed=9)
-    z1 = CollectiveTrainer(spec, batch_size=8, mesh=mesh, rng_seed=9,
+    base = CollectiveTrainer(spec, batch_size=64, mesh=mesh, rng_seed=9)
+    z1 = CollectiveTrainer(spec, batch_size=64, mesh=mesh, rng_seed=9,
                            zero1=True)
     for _ in range(2):
         pb = [base.prepare_batch(xs, ys) for _ in range(window)]
@@ -88,9 +88,9 @@ def test_zero1_accum_bitwise_equivalence(spec):
     math) composes with the sharded update bit-exactly."""
     xs, ys = mnist.synthetic_data(n=64, seed=25)
     mesh = make_mesh(4)
-    base = CollectiveTrainer(spec, batch_size=8, mesh=mesh,
+    base = CollectiveTrainer(spec, batch_size=32, mesh=mesh,
                              accum_steps=2, rng_seed=11)
-    z1 = CollectiveTrainer(spec, batch_size=8, mesh=mesh,
+    z1 = CollectiveTrainer(spec, batch_size=32, mesh=mesh,
                            accum_steps=2, rng_seed=11, zero1=True)
     for _ in range(6):
         loss_b, _ = base.train_minibatch(xs, ys)
@@ -107,7 +107,7 @@ def test_zero1_full_coverage_every_nonscalar_leaf_sharded(spec):
     shards them ALL; only rank-0 scalars (Adam's step count) remain
     replicated."""
     mesh = make_mesh(8)
-    z1 = CollectiveTrainer(spec, batch_size=8, mesh=mesh, zero1=True)
+    z1 = CollectiveTrainer(spec, batch_size=64, mesh=mesh, zero1=True)
     xs, ys = mnist.synthetic_data(n=64, seed=27)
     z1.train_minibatch(xs, ys)
     replicated_nonscalar = [
@@ -160,7 +160,7 @@ def test_repartition_preserves_moments_bitwise(spec):
     view is bit-identical across every re-partition, and the moves are
     device-to-device (no host bounce counter)."""
     xs, ys = mnist.synthetic_data(n=64, seed=29)
-    z1 = CollectiveTrainer(spec, batch_size=8, mesh=make_mesh(8),
+    z1 = CollectiveTrainer(spec, batch_size=64, mesh=make_mesh(8),
                            zero1=True, rng_seed=13)
     for _ in range(3):
         z1.train_minibatch(xs, ys)
@@ -185,9 +185,9 @@ def test_same_size_reform_trajectory_bitwise(spec):
     trajectory bit-for-bit (the VirtualFlow-style exactness the churn
     drills verify)."""
     xs, ys = mnist.synthetic_data(n=64, seed=31)
-    ref = CollectiveTrainer(spec, batch_size=8, mesh=make_mesh(8),
+    ref = CollectiveTrainer(spec, batch_size=64, mesh=make_mesh(8),
                             zero1=True, rng_seed=15)
-    churn = CollectiveTrainer(spec, batch_size=8, mesh=make_mesh(8),
+    churn = CollectiveTrainer(spec, batch_size=64, mesh=make_mesh(8),
                               zero1=True, rng_seed=15)
     ref_losses = [float(ref.train_minibatch(xs, ys)[0])
                   for _ in range(6)]
@@ -204,9 +204,9 @@ def test_snapshot_to_host_gathers_sharded_state(spec):
     original-shape host numpy (the multi-controller-safe path), and a
     rebuild from that snapshot resumes the exact trajectory."""
     xs, ys = mnist.synthetic_data(n=64, seed=33)
-    ref = CollectiveTrainer(spec, batch_size=8, mesh=make_mesh(8),
+    ref = CollectiveTrainer(spec, batch_size=64, mesh=make_mesh(8),
                             zero1=True, rng_seed=17)
-    t = CollectiveTrainer(spec, batch_size=8, mesh=make_mesh(8),
+    t = CollectiveTrainer(spec, batch_size=64, mesh=make_mesh(8),
                           zero1=True, rng_seed=17)
     ref_losses = [float(ref.train_minibatch(xs, ys)[0])
                   for _ in range(4)]
@@ -233,11 +233,11 @@ def test_zero1_checkpoint_roundtrip_sharded(spec, tmp_path):
     saver = CheckpointSaver(str(tmp_path))
     xs, ys = mnist.synthetic_data(n=64, seed=35)
     mesh = make_mesh(8)
-    ref = CollectiveTrainer(spec, batch_size=8, mesh=mesh,
+    ref = CollectiveTrainer(spec, batch_size=64, mesh=mesh,
                             zero1=True, rng_seed=19)
     ref_losses = [float(ref.train_minibatch(xs, ys)[0])
                   for _ in range(4)]
-    t1 = CollectiveTrainer(spec, batch_size=8, mesh=mesh, zero1=True,
+    t1 = CollectiveTrainer(spec, batch_size=64, mesh=mesh, zero1=True,
                            rng_seed=19, checkpoint_saver=saver,
                            checkpoint_steps=2)
     t1.train_minibatch(xs, ys)
@@ -247,7 +247,7 @@ def test_zero1_checkpoint_roundtrip_sharded(spec, tmp_path):
     # checkpoint holds the UNPADDED original shapes (mode-portable)
     assert dense["opt/0/mu/Dense_0/kernel"].shape == (3136, 128)
     assert dense["opt/0/mu/Dense_1/bias"].shape == (10,)
-    t2 = CollectiveTrainer(spec, batch_size=8, mesh=mesh, zero1=True,
+    t2 = CollectiveTrainer(spec, batch_size=64, mesh=mesh, zero1=True,
                            rng_seed=99, checkpoint_saver=saver)
     assert t2.init_from_checkpoint() and t2.version == 2
     sharded = [
@@ -266,16 +266,16 @@ def test_zero1_checkpoint_portable_to_replicated(spec, tmp_path):
     saver = CheckpointSaver(str(tmp_path))
     xs, ys = mnist.synthetic_data(n=64, seed=37)
     mesh = make_mesh(8)
-    ref = CollectiveTrainer(spec, batch_size=8, mesh=mesh, rng_seed=20)
+    ref = CollectiveTrainer(spec, batch_size=64, mesh=mesh, rng_seed=20)
     ref_losses = [float(ref.train_minibatch(xs, ys)[0])
                   for _ in range(4)]
-    t1 = CollectiveTrainer(spec, batch_size=8, mesh=mesh, zero1=True,
+    t1 = CollectiveTrainer(spec, batch_size=64, mesh=mesh, zero1=True,
                            rng_seed=20, checkpoint_saver=saver,
                            checkpoint_steps=2)
     t1.train_minibatch(xs, ys)
     t1.train_minibatch(xs, ys)
     t1.flush_checkpoints()
-    t2 = CollectiveTrainer(spec, batch_size=8, mesh=mesh, rng_seed=99,
+    t2 = CollectiveTrainer(spec, batch_size=64, mesh=mesh, rng_seed=99,
                            checkpoint_saver=saver)
     assert t2.init_from_checkpoint()
     resumed = [float(t2.train_minibatch(xs, ys)[0]) for _ in range(2)]
@@ -289,7 +289,7 @@ def test_zero1_off_is_exact_old_layout(spec):
     """--zero1 false keeps the replicated layout: original leaf
     shapes, every leaf replicated, no partitioner, no zero1 counters."""
     mesh = make_mesh(8)
-    t = CollectiveTrainer(spec, batch_size=8, mesh=mesh)
+    t = CollectiveTrainer(spec, batch_size=64, mesh=mesh)
     xs, ys = mnist.synthetic_data(n=64, seed=39)
     t.train_minibatch(xs, ys)
     assert t._zero is None and not t._opt_is_flat
@@ -310,7 +310,7 @@ def test_zero1_timing_section_and_report(spec):
     counters surface as the ``zero1`` section of Timing.summary() and
     report() handles the mixed summary without crashing."""
     mesh = make_mesh(8)
-    z1 = CollectiveTrainer(spec, batch_size=8, mesh=mesh, zero1=True)
+    z1 = CollectiveTrainer(spec, batch_size=64, mesh=mesh, zero1=True)
     xs, ys = mnist.synthetic_data(n=64, seed=41)
     z1.train_minibatch(xs, ys)
     prepared = [z1.prepare_batch(xs, ys) for _ in range(3)]
