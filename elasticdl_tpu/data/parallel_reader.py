@@ -29,8 +29,10 @@ import time
 from types import SimpleNamespace
 
 from elasticdl_tpu.utils.logging import get_logger
+from elasticdl_tpu.utils.timing import Timing
 
 logger = get_logger(__name__)
+_NO_TIMING = Timing(enabled=False)
 
 # Per-process reader cache: one reader per pool process, built lazily
 # from the factory shipped with each job (factories must be picklable).
@@ -138,7 +140,7 @@ class ParallelShardReader:
         self.close()
 
 
-def prefetch_batches(batch_iter, depth=2, prepare=None):
+def prefetch_batches(batch_iter, depth=2, prepare=None, timing=None):
     """Run ``batch_iter`` in a background thread, keeping up to
     ``depth`` batches ready — host feed/decode overlaps device compute.
 
@@ -149,10 +151,18 @@ def prefetch_batches(batch_iter, depth=2, prepare=None):
     (docs/training_pipeline.md).  A prepare failure re-raises at the
     consumer like any producer error.
 
+    ``timing`` (optional, the worker's ``Timing``) takes the producer's
+    phase, which makes the reader layer visible: ``reader_batch``, the
+    read + decode + feed (+ ``prepare``) of one batch (once more than
+    there are batches: the last pull sees the stream's end).  The rest of
+    the producer's time it waits on the full queue, ahead of the trainer.
+
     Exceptions from the producer re-raise at the consumer's next pull,
     so failures surface in the training loop (where the minibatch retry
     machinery lives), not in a daemon thread.
     """
+    if timing is None:
+        timing = _NO_TIMING
     q = queue.Queue(maxsize=depth)
     _END = object()
     abandoned = threading.Event()
@@ -172,9 +182,14 @@ def prefetch_batches(batch_iter, depth=2, prepare=None):
 
     def produce():
         try:
-            for batch in batch_iter:
-                if prepare is not None:
-                    batch = prepare(batch)
+            it = iter(batch_iter)
+            while True:
+                with timing.timeit("reader_batch"):
+                    batch = next(it, _END)
+                    if batch is not _END and prepare is not None:
+                        batch = prepare(batch)
+                if batch is _END:
+                    break
                 if not _put(batch):
                     return
             _put(_END)

@@ -42,6 +42,7 @@ def device_report():
     from elasticdl_tpu.ops.group_norm import fused_gn_mode
 
     devices = jax.devices()
+    stats = [d.memory_stats() or {} for d in jax.local_devices()]
     return {
         "platform": devices[0].platform,
         "device_kind": devices[0].device_kind,
@@ -56,10 +57,13 @@ def device_report():
         "flash": flash_mode(),
         "fused_gn": fused_gn_mode(),
         # Per local device; 0 where the backend keeps no statistics.
+        # On the TPU a program's temporaries (activations, logits) are
+        # not in ``peak_bytes_in_use`` but in ``peak_bytes_reserved``
+        # (chip run, PR 23): the peak is the sum of the two.
         "peak_bytes_in_use": [
-            (d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-            for d in jax.local_devices()
-        ],
+            s.get("peak_bytes_in_use", 0) for s in stats],
+        "peak_bytes_reserved": [
+            s.get("peak_bytes_reserved", 0) for s in stats],
     }
 
 

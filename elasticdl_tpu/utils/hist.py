@@ -28,9 +28,7 @@ primitive behind all of them:
    consumer to re-derive it by probe-differencing.
 
 The histogram path has a global off-switch (``set_enabled(False)`` /
-``ELASTICDL_HIST=off``) so ``bench_tracing.py`` can gate its overhead
-(interleaved on/off legs, <= 2% steps/s) exactly like the tracing
-plane's switch.
+``ELASTICDL_HIST=off``), like the tracing plane's.
 """
 
 import os

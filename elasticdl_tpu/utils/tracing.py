@@ -35,8 +35,7 @@ Three pieces:
    link to its journal-replay trace, so the worker-side outage ride
    and the master-side recovery become ONE component.
 
-Disable with ``ELASTICDL_TRACING=off`` (the bench_tracing.py overhead
-leg compares against exactly this switch).
+Disable with ``ELASTICDL_TRACING=off``.
 """
 
 import atexit

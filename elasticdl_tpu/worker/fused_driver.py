@@ -277,78 +277,83 @@ class FusedStepDriver:
         # inside one program are not separately observable).
         t_prev = time.perf_counter()
         while cur:
-            if self._elastic is not None:
-                # One epoch check per window, counted as len(cur) steps
-                # so the check cadence matches the per-step loop's.
-                self._elastic.step_check(len(cur))
-            for callback in self._callbacks:
-                if hasattr(callback, "on_train_batch_begin"):
-                    for _ in cur:  # once per step, as the old loop did
-                        callback.on_train_batch_begin(trainer)
-            if self._prepare is not None:
-                # Post-epoch-check prep (elastic path): the batches are
-                # prepared against the CURRENT world's geometry.
-                cur = [self._prepare(item) for item in cur]
-            with timing.timeit("window_dispatch"):
-                losses, version = self._dispatch(cur, staged)
-            if self._step_throttle:
-                time.sleep(self._step_throttle * len(cur))
-            steps_done += len(cur)
-            timing.bump("fused_windows")
-            timing.bump("fused_steps_run", len(cur))
-            # Collect + stage the NEXT window while the current one is
-            # still executing on device: host feed and host→device
-            # transfer overlap the running step.
-            with timing.timeit("data_wait"):
-                nxt = self._collect(batch_iter,
-                                    self._window_limit(steps_done))
-            staged = self._stage(nxt)
-            self.loss_ring.push(steps_done, losses)
-            fetched = None
-            if not nxt:
-                # Task-final fence BEFORE the final report: the last
-                # window must verifiably complete before the shard
-                # protocol can auto-complete the task (same strictness
-                # the per-step loop had via its per-step sync).
-                fetched = self._fence()
-            # Coalesced progress accounting: one report_batch_done RPC
-            # per fused window (counts buffered per batch, flushed at
-            # the window boundary — and, structurally, at task
-            # boundaries inside DataShardService).
-            with timing.timeit("progress_rpc"):
-                for batch in cur:
-                    self._shard.report_batch_done(batch.count,
-                                                  defer=True)
-                self._shard.flush_batch_done()
-            # One bulk observation per window: this pass's wall time
-            # spread over its steps — the step-time distribution the
-            # master aggregates per job (and judges stragglers on).
-            t_now = time.perf_counter()
-            timing.observe("step_time",
-                           (t_now - t_prev) / len(cur), n=len(cur))
-            t_prev = t_now
-            if (
-                self._log_loss_steps
-                and steps_done % self._log_loss_steps == 0
-            ):
-                if fetched is None:
+            # One ``step`` phase per window pass (``steps`` of them in
+            # one program), holding the phases above: the per-step
+            # loop's vocabulary (worker.py), so one reader serves both.
+            with timing.timeit("step", step=steps_done + len(cur),
+                               steps=len(cur)):
+                if self._elastic is not None:
+                    # One epoch check per window, counted as len(cur) steps
+                    # so the check cadence matches the per-step loop's.
+                    self._elastic.step_check(len(cur))
+                for callback in self._callbacks:
+                    if hasattr(callback, "on_train_batch_begin"):
+                        for _ in cur:  # once per step, as the old loop did
+                            callback.on_train_batch_begin(trainer)
+                if self._prepare is not None:
+                    # Post-epoch-check prep (elastic path): the batches are
+                    # prepared against the CURRENT world's geometry.
+                    cur = [self._prepare(item) for item in cur]
+                with timing.timeit("window_dispatch"):
+                    losses, version = self._dispatch(cur, staged)
+                if self._step_throttle:
+                    time.sleep(self._step_throttle * len(cur))
+                steps_done += len(cur)
+                timing.bump("fused_windows")
+                timing.bump("fused_steps_run", len(cur))
+                # Collect + stage the NEXT window while the current one is
+                # still executing on device: host feed and host→device
+                # transfer overlap the running step.
+                with timing.timeit("data_wait"):
+                    nxt = self._collect(batch_iter,
+                                        self._window_limit(steps_done))
+                staged = self._stage(nxt)
+                self.loss_ring.push(steps_done, losses)
+                fetched = None
+                if not nxt:
+                    # Task-final fence BEFORE the final report: the last
+                    # window must verifiably complete before the shard
+                    # protocol can auto-complete the task (same strictness
+                    # the per-step loop had via its per-step sync).
                     fetched = self._fence()
-                if fetched is not None:
-                    logger.info(
-                        "step %d loss %.6f (version %d)",
-                        fetched[0], fetched[1], version,
-                    )
-            if self._stop_check is not None and self._stop_check():
-                # Graceful preemption between windows: fence the
-                # in-flight window, flush the (already reported) window
-                # counts, and hand back.  ``nxt`` was collected but
-                # never dispatched — the unconsumed remainder, never
-                # counted, requeued with the task.
-                self._fence()
-                self._shard.flush_batch_done()
-                tracing.event("worker.preempt_flush",
-                              steps_run=steps_done - start,
-                              undispatched=len(nxt))
-                return steps_done - start, True
+                # Coalesced progress accounting: one report_batch_done RPC
+                # per fused window (counts buffered per batch, flushed at
+                # the window boundary — and, structurally, at task
+                # boundaries inside DataShardService).
+                with timing.timeit("progress_rpc"):
+                    for batch in cur:
+                        self._shard.report_batch_done(batch.count,
+                                                      defer=True)
+                    self._shard.flush_batch_done()
+                # One bulk observation per window: this pass's wall time
+                # spread over its steps — the step-time distribution the
+                # master aggregates per job (and judges stragglers on).
+                t_now = time.perf_counter()
+                timing.observe("step_time",
+                               (t_now - t_prev) / len(cur), n=len(cur))
+                t_prev = t_now
+                if (
+                    self._log_loss_steps
+                    and steps_done % self._log_loss_steps == 0
+                ):
+                    if fetched is None:
+                        fetched = self._fence()
+                    if fetched is not None:
+                        logger.info(
+                            "step %d loss %.6f (version %d)",
+                            fetched[0], fetched[1], version,
+                        )
+                if self._stop_check is not None and self._stop_check():
+                    # Graceful preemption between windows: fence the
+                    # in-flight window, flush the (already reported) window
+                    # counts, and hand back.  ``nxt`` was collected but
+                    # never dispatched — the unconsumed remainder, never
+                    # counted, requeued with the task.
+                    self._fence()
+                    self._shard.flush_batch_done()
+                    tracing.event("worker.preempt_flush",
+                                  steps_run=steps_done - start,
+                                  undispatched=len(nxt))
+                    return steps_done - start, True
             cur = nxt
         return steps_done - start, False

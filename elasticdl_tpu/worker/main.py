@@ -4,6 +4,7 @@ Identity and topology arrive via env (``MASTER_ADDR``, ``WORKER_ID``) with
 flag overrides; the model comes from the zoo contract by module name.
 """
 
+import contextlib
 import os
 
 from elasticdl_tpu.data.factory import create_data_reader
@@ -287,6 +288,34 @@ def build_worker(args):
     return worker
 
 
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+@contextlib.contextmanager
+def xla_compiles_logged(steps_done):
+    """Make every XLA program this process builds visible from inside,
+    for as long as the block lasts: JAX reports one
+    ``backend_compile_duration`` per fresh jit (a program loaded from the
+    persistent cache included: the duration is then the load), and each
+    becomes one stamped log line, ``xla compile: secs=<s> step=<n>``.  A
+    compile after the first steps is the per-shape recompile that stalls
+    a step; counting new files in the cache directory misses every one
+    shorter than the persistent cache's minimum compile time.
+    ``steps_done()`` is the number of steps trained so far."""
+    import jax
+
+    def on_duration(event, secs, fun_name="", **_):
+        if event == _COMPILE_EVENT:
+            logger.info("xla compile: secs=%.3f step=%d fun=%s", secs,
+                        steps_done(), fun_name)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        yield
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+
+
 def main(argv=None):
     import signal
 
@@ -304,36 +333,39 @@ def main(argv=None):
     # parent to check (the master only sees the log and the exit code).
     logger.info("worker device: %s compile_cache=%s",
                 format_device_report(device_report()), cache_dir)
-    worker = build_worker(args)
+    worker = None
+    with xla_compiles_logged(
+            lambda: worker.steps_done if worker is not None else 0):
+        worker = build_worker(args)
 
-    def _graceful_preempt(_sig, _frame):
-        # Preemptible hosts deliver SIGTERM with a grace window: finish
-        # the in-flight minibatch, checkpoint, exit 143 (the manager
-        # relaunches a replacement).
-        logger.warning("SIGTERM received: graceful preemption")
-        worker.request_stop()
+        def _graceful_preempt(_sig, _frame):
+            # Preemptible hosts deliver SIGTERM with a grace window: finish
+            # the in-flight minibatch, checkpoint, exit 143 (the manager
+            # relaunches a replacement).
+            logger.warning("SIGTERM received: graceful preemption")
+            worker.request_stop()
 
-    try:
-        signal.signal(signal.SIGTERM, _graceful_preempt)
-    except ValueError:
-        pass  # not the main thread (embedded use)
-    # AFTER the preemption hook so the SIGTERM chain is
-    # dump-ring-then-graceful-preempt ($ELASTICDL_TRACE_DIR gates it).
-    tracing.arm_crash_dump()
-    if args.profile_dir:
-        from elasticdl_tpu.utils.timing import device_trace
+        try:
+            signal.signal(signal.SIGTERM, _graceful_preempt)
+        except ValueError:
+            pass  # not the main thread (embedded use)
+        # AFTER the preemption hook so the SIGTERM chain is
+        # dump-ring-then-graceful-preempt ($ELASTICDL_TRACE_DIR gates it).
+        tracing.arm_crash_dump()
+        if args.profile_dir:
+            from elasticdl_tpu.utils.timing import device_trace
 
-        with device_trace(args.profile_dir):
+            with device_trace(args.profile_dir):
+                worker.run()
+        else:
             worker.run()
-    else:
-        worker.run()
-    logger.info("worker end-of-run: steps=%d %s", worker.steps_done,
-                format_device_report(device_report()))
-    if worker.preempted:
-        logger.info("worker preempted (checkpointed)")
-        return PREEMPTED_EXIT_CODE
-    logger.info("worker done")
-    return 0
+        logger.info("worker end-of-run: steps=%d %s", worker.steps_done,
+                    format_device_report(device_report()))
+        if worker.preempted:
+            logger.info("worker preempted (checkpointed)")
+            return PREEMPTED_EXIT_CODE
+        logger.info("worker done")
+        return 0
 
 
 if __name__ == "__main__":

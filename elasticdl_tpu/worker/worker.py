@@ -441,6 +441,7 @@ class Worker:
             self._data_service.batch_stream(task, self._batch_size),
             depth=max(2, self._device_prefetch),
             prepare=prepare,
+            timing=self.timing,
         )
         ran, preempted = driver.run_task(
             batches, steps_done=self._steps
@@ -460,7 +461,8 @@ class Worker:
         prefetch_embeddings = getattr(
             self._trainer, "prefetch_embeddings", None
         )
-        with self.timing.timeit("task_process"):
+        timing = self.timing
+        with timing.timeit("task_process", task=task.id):
             try:
                 if driver is not None:
                     self._run_task_windowed(task, driver)
@@ -474,33 +476,46 @@ class Worker:
                         task, self._batch_size
                     ),
                     depth=2,
+                    timing=timing,
                 )
-                pending = next(batches, None)
+                # Step anatomy (docs/observability.md), the same phases
+                # the fused driver times: one ``step`` per pass, from
+                # pulling the batch to after the progress report, holding
+                # data_wait / batch_prep / step_dispatch / loss_sync /
+                # progress_rpc.  A task pulls once more than it has
+                # batches: the last pull sees the stream's end.
+                with timing.timeit("data_wait"):
+                    pending = next(batches, None)
                 t_prev = time.perf_counter()
                 while pending is not None:
-                    features, labels, count = pending
-                    pending = next(batches, None)
-                    if pending is not None and prefetch_embeddings:
-                        prefetch_embeddings(pending[0])
-                    loss = self._process_minibatch(features, labels)
-                    # Per-step wall time into the step-time histogram
-                    # (the fused path observes per window); feeds the
-                    # master's per-job p50/p99 via the telemetry
-                    # piggyback's hist delta.
-                    t_now = time.perf_counter()
-                    self.timing.observe("step_time", t_now - t_prev)
-                    t_prev = t_now
-                    if pending is None:
-                        # Task-final fence: the last report below can
-                        # auto-complete the task at the master, so the
-                        # last (lazy) step must verifiably finish
-                        # first — the completion guarantee the loop
-                        # used to get for free from per-step
-                        # float(loss); steps chain through params, so
-                        # fencing the last one proves them all.
-                        with self.timing.timeit("loss_sync"):
-                            float(loss)
-                    self._shard_service.report_batch_done(count)
+                    with timing.timeit("step", step=self._steps + 1,
+                                       task=task.id):
+                        features, labels, count = pending
+                        with timing.timeit("data_wait"):
+                            pending = next(batches, None)
+                        if pending is not None and prefetch_embeddings:
+                            prefetch_embeddings(pending[0])
+                        loss = self._process_minibatch(features, labels)
+                        # Per-step wall time into the step-time
+                        # histogram (the fused path observes per
+                        # window); feeds the master's per-job p50/p99
+                        # via the telemetry piggyback's hist delta.
+                        t_now = time.perf_counter()
+                        timing.observe("step_time", t_now - t_prev)
+                        t_prev = t_now
+                        if pending is None:
+                            # Task-final fence: the last report below
+                            # can auto-complete the task at the master,
+                            # so the last (lazy) step must verifiably
+                            # finish first — the completion guarantee
+                            # the loop used to get for free from
+                            # per-step float(loss); steps chain through
+                            # params, so fencing the last one proves
+                            # them all.
+                            with timing.timeit("loss_sync"):
+                                float(loss)
+                        with timing.timeit("progress_rpc"):
+                            self._shard_service.report_batch_done(count)
                     if self._preempt_requested:
                         raise PreemptedExit()
             except PreemptedExit:
@@ -626,10 +641,13 @@ class Worker:
             while True:
                 if self._preempt_requested:
                     raise PreemptedExit()
-                if self._elastic is not None:
-                    task = self._fetch_task_elastic()
-                else:
-                    task = self._shard_service.fetch_task()
+                # The bubble between two tasks: the get_task RPC and
+                # any wait for a task to exist.
+                with self.timing.timeit("task_fetch"):
+                    if self._elastic is not None:
+                        task = self._fetch_task_elastic()
+                    else:
+                        task = self._shard_service.fetch_task()
                 # The get_task that delivered this task may have been
                 # the scheduler's re-assignment handshake: rebuild the
                 # pipeline for the new job BEFORE processing the task.

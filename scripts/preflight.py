@@ -11,20 +11,21 @@ Runs, in order, each in a fresh subprocess with the CPU platform pinned:
      /metrics renderer vs the strict parser + metric registry)
   3. the full test suite (pytest tests -q)
   4. the driver's multi-chip dry run (__graft_entry__.dryrun_multichip(8))
-  5. bench_tracing.py with BOTH overhead gates (tracing <= 2%,
-     histogram path <= 2% steps/s)
-  6. bench_serving.py --wire: the binary serving data plane's gates
+  5. bench_serving.py --wire: the binary serving data plane's gates
      (e2e ratio within 25% of the endpoint-layer ratio, binary p99
      within 10% of JSON's, JSON-vs-binary bit-identity, router
      byte-identical pass-through)
-  7. bench_ps_wire.py --frame_only: the frame-native PS data plane's
+  6. bench_ps_wire.py --frame_only: the frame-native PS data plane's
      gates (decode-copy bytes >= 1.3x smaller than TensorPB at equal
      wire dtype, loopback steps/s >= 1.0x, same-seed serialized
      losses bit-identical frame-vs-pb)
 
 These are CPU gates.  The chip is checked separately, by sending
 ``python chip_smoke.py`` through the chip tool; ``bench.py`` measures the
-chip and is not a stage here (it exits non-zero without a TPU).
+chip and is not a stage here (it exits non-zero without a TPU).  What the
+observability plane costs is measured on the chip too, by the benchmark
+(``BENCHMARK.json``; PERF.md section 6 has the traced rate beside the
+untraced one), not by a steps/s ratio on this CPU.
 
 Exits nonzero on the FIRST failure with the failing stage named.  Run it
 before every end-of-round snapshot — round 2 shipped a broken HEAD
@@ -120,32 +121,6 @@ def main(argv=None):
     if not fast:
         sys.path.insert(0, REPO)
         from elasticdl_tpu.utils.jsonline import last_json_line
-
-        # Observability-plane overhead gates (ISSUE 14): tracing AND
-        # histogram-path legs must both sit within the 2% steps/s
-        # budget.
-        ok, out = run_stage(
-            "bench_tracing.py (overhead gates)",
-            [sys.executable, "bench_tracing.py"],
-            timeout=900,
-        )
-        if not ok:
-            return 1
-        parsed = last_json_line(out)
-        detail = (parsed or {}).get("detail", {})
-        if not detail.get("within_2pct"):
-            print("[preflight] FAIL bench_tracing: tracing leg over "
-                  "the 2%% gate (ratio %s)" % (parsed or {}).get(
-                      "value"))
-            return 1
-        hist_leg = detail.get("histogram_path", {})
-        if not hist_leg.get("within_2pct"):
-            print("[preflight] FAIL bench_tracing: histogram leg "
-                  "over the 2%% gate (ratio %s)"
-                  % hist_leg.get("steps_ratio"))
-            return 1
-        print("[preflight] overhead ratios: tracing %s, histogram %s"
-              % (parsed["value"], hist_leg.get("steps_ratio")))
 
         # Binary serving data plane (ISSUE 15): the e2e-approaches-
         # endpoint ratio gate, the serving.request p99 gate, JSON-vs-
