@@ -1,18 +1,37 @@
 """Pallas flash attention for TPU.
 
-The hot op of the long-context path.  One (batch*head, q-block) program
-holds its query tile in VMEM and streams K/V tiles of the same head
-through the MXU with the online-softmax accumulation, so the T x T score
-matrix never materializes in HBM.
+The hot op of the long-context path.  One (batch*head, live tile)
+program holds a query tile in VMEM and streams the K/V tiles of the same
+head through the MXU with the online-softmax accumulation, so the T x T
+score matrix never materializes in HBM.
 
-Forward emits the per-row softmax stats (l, m) alongside the output, and
-the backward is a Pallas kernel pair: a dq pass (q/dO tiles resident,
-K/V streamed) and a dk/dv pass (K/V resident, q/dO streamed), each
-rebuilding its probability tiles from the saved stats IN VMEM — unlike
-the older XLA ``lax.scan`` block-recompute (kept behind
-``ELASTICDL_FLASH_BWD=xla``), the [T, block] p/ds tiles never make an
-HBM round-trip between einsums.  Peak memory stays O(T·block), never
-the full T x T.
+Each kernel does per score only what the score's place in the band asks
+for.  The [T, T] square is cut into major tiles (``_major_tile``: 1024
+where it divides T) of 128 x 128 sub-tiles, and a sub-tile is *interior*
+(wholly inside the causal band or the window: no mask is built), *edge*
+(crossed by the diagonal or by the window's lower edge: masked) or
+*skipped* (no matmul, no vector work).  The class follows from the
+tile's offset ``qi - ki`` and the static (causal, window), so a kernel
+holds one straight-line branch per distinct class map (``_TilePlan``:
+the diagonal tile, the interior, the window's edge) and picks it by the
+offset; its grid runs over the live tiles alone, whose indices are
+scalar-prefetched, so a dead tile is neither stepped through nor
+fetched.  ``tile_census`` says how often each class engages for a
+shape, and the compiled mode logs it once per shape.
+
+Forward emits the per-row softmax stats (l, m) alongside the output as
+[1, T] rows, and the backward is a Pallas kernel pair: a dq pass (q/dO
+tiles resident, K/V streamed) and a dk/dv pass (K/V resident, q/dO
+streamed; scores built transposed, so nothing is transposed for its
+matmuls), each rebuilding its probability tiles IN VMEM as
+``exp(s - lse)`` from the one saved row constant ``lse = m + log l``:
+no divide per score, the scale applied once to the f32 accumulator, and
+``delta = sum(dO * O)`` made once outside both.  Unlike the older XLA
+``lax.scan`` block-recompute (kept behind ``ELASTICDL_FLASH_BWD=xla``),
+the [T, block] p/ds tiles never make an HBM round-trip between einsums.
+Peak memory stays O(T·block), never the full T x T.  Operands go into
+the MXU in their storage dtype (bf16), scores, stats and accumulators
+are f32, exp and the final division exact.
 
 ``flash_attention_partial`` exposes the same kernel without the final
 normalization, returning (acc, l, m) for one KV block — the building
@@ -25,16 +44,19 @@ O(T/sp x block_k) live, never the dense per-shard square.
 
 Layout: [batch, heads, seq, head_dim].  The caller-facing block sizes
 are a friendliness contract (seq divisible by them, 128-lane block_k);
-the kernel chooses its own internal tiling (up to 512-wide q blocks and
-K/V major tiles) to amortize per-grid-step overhead.  `flash_attention`
-falls back to the reference implementation for unfriendly shapes, and in
-the compiled mode says so once per shape (``announce_fallback``): on the
+the kernel chooses its own internal tiling.  `flash_attention` falls
+back to the reference implementation for unfriendly shapes, and in the
+compiled mode says so once per shape (``announce_fallback``): on the
 chip a silent reference path is a slow path nobody asked for.
 Mode selection: ``ELASTICDL_FLASH=auto`` (default: compiled kernel on
 TPU; jnp elsewhere), ``interpret`` (Pallas interpret mode, for tests),
 ``off``.  Forward and Pallas backward compile and match the reference at
-B8·H16·T2048, D64 and D128, full causal and windowed, on a v5e (my chip
-run, PR 21; ``chip_check.py`` at the repo root repeats it).
+B8·H16·T2048, D64 and D128, full causal and windowed, on a v5e
+(``chip_check.py`` at the repo root; my chip run, PR 25), where one
+call at D128 takes 1.21 (forward), 1.41 (dq) and 1.69 ms (dk-dv):
+58 / 75 / 83% of the MXU's time for the causal half
+(``tools/flash_kernels_on_chip.py``; the figures of 2026-07-29 in
+BENCHMARKS.md are superseded by these and by PERF_LEDGER.jsonl).
 """
 
 import functools
@@ -42,6 +64,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -91,8 +114,15 @@ def _attention_ref(q, k, v, causal, scale, window=0):
     ).astype(q.dtype)
 
 
-STATS_LANES = 128  # Mosaic wants >=(8,128) tiles; stats ride 128 lanes
-                   # broadcast, same layout as the in-tree TPU kernel.
+STATS_LANES = 128  # Mosaic wants >=(8,128) tiles; the running stats ride
+                   # 128 lanes broadcast inside the kernels.
+SUB = 128          # edge of a score sub-tile: the MXU's width
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b^T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+
+# What a [SUB, SUB] score sub-tile needs, from where it lies in the band.
+_DEAD, _EDGE, _FULL = "skipped", "edge", "interior"
 
 
 def _lanes_bcast(x, head_dim):
@@ -104,98 +134,269 @@ def _lanes_bcast(x, head_dim):
     return pltpu.repeat(x, head_dim // STATS_LANES, axis=1)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, m_ref, acc_scr,
-                  l_scr, m_scr, *, block_k, causal, scale, normalize,
-                  window=0):
-    # grid: (bh, num_q_blocks, num_k_blocks), K innermost.  Each grid
-    # step sees ONE [1, block_k, D] K/V tile — Pallas's automatic
-    # pipelining streams tiles HBM->VMEM overlapped with compute, so
-    # VMEM never holds the full sequence (the fori_loop-over-resident-KV
-    # variant OOMs scoped vmem at T=8k).  The running (acc, l, m) lives
-    # in VMEM scratch, persistent across the K grid dimension.
-    # Stats stay 2D [block_q, STATS_LANES] (every lane equal) so all
-    # vector ops live on full (8, 128) tiles — Mosaic rejects 1D or
-    # lane-1 output blocks.  Requires block_k == STATS_LANES so
-    # `s - m` stays lane-aligned.
-    block_q = q_ref.shape[1]
-    block_k_major = k_ref.shape[1]
-    head_dim = q_ref.shape[2]
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    num_k = pl.num_programs(2)
+def _major_tile(t, row_bytes):
+    """Shared fwd/bwd major-tile policy: the widest of 128 ... 1024 that
+    divides t.  A grid step costs ~0.3 us and every matmul reloads the
+    MXU's weights once per SUB of the streamed side, so the resident
+    side wants to be long: forward / dq / dk-dv take 3.19 / 3.27 / 3.23
+    ms a call at 256, 1.44 / 1.70 / 2.00 at 512, 1.21 / 1.41 / 1.69 at
+    1024 (bh=128, t=2048, d=128, bf16 — my chip run, PR 25).  Rows over
+    512 bytes (d=256 in f32) stop at 512: at 1024 the dk-dv kernel
+    wants 19.7 MB of the 16 MB of scoped VMEM."""
+    cap = 1024 if row_bytes <= 512 else 512
+    return max(bs for bs in (128, 256, 512, 1024)
+               if bs <= min(t, cap) and t % bs == 0)
 
-    @pl.when(ki == 0)
+
+def _sub_class(delta, causal, window):
+    """(class, offset) of the sub-tile whose first query lies ``delta``
+    sub-tiles below its first key.  q_pos - k_pos is offset + (row -
+    lane) inside it, so it spans offset -+ (SUB - 1); an edge keeps its
+    offset for the mask, the others need none."""
+    if not causal:
+        return _FULL, None
+    lo, hi = SUB * delta - (SUB - 1), SUB * delta + (SUB - 1)
+    if hi < 0 or (window and lo >= window):
+        return _DEAD, None
+    if lo >= 0 and (not window or hi < window):
+        return _FULL, None
+    return _EDGE, SUB * delta
+
+
+class _TilePlan:
+    """How the kernels walk the [t, t] score square: static, from
+    (t, tile, causal, window) alone.
+
+    ``branches``: [(class map, first offset, last offset)].  A major
+    tile's class map ``cmap[a][b]`` (query sub-block a, key sub-block b)
+    depends only on the tile's offset ``qi - ki``; a run of offsets with
+    one map (the interior of the band) shares one compiled branch.
+    ``q_major`` / ``k_major``: the live tiles (qi, ki) in the order the
+    forward and dq (resp. dk-dv) grids visit them; a tile with no live
+    sub-tile is no grid step.
+    """
+
+    def __init__(self, t, tile, causal, window):
+        self.tile, self.window = tile, window
+        self.n = n = tile // SUB
+        self.num = num = t // tile
+        self.branches = []
+        for dt in range(-(num - 1), num):
+            cmap = tuple(
+                tuple(_sub_class(dt * n + a - b, causal, window)
+                      for b in range(n))
+                for a in range(n))
+            if all(cls == _DEAD for row in cmap for cls, _ in row):
+                continue
+            if self.branches and self.branches[-1][0] == cmap:
+                self.branches[-1][2] = dt
+            else:
+                self.branches.append([cmap, dt, dt])
+        self.dt_min, self.dt_max = self.branches[0][1], self.branches[-1][2]
+        self.q_major = [(qi, ki) for qi in range(num) for ki in range(num)
+                        if self.dt_min <= qi - ki <= self.dt_max]
+        self.k_major = sorted(self.q_major, key=lambda qk: (qk[1], qk[0]))
+        subs = t // SUB
+        self.census = {cls: 0 for cls in (_FULL, _EDGE, _DEAD)}
+        for delta in range(-(subs - 1), subs):
+            self.census[_sub_class(delta, causal, window)[0]] += (
+                subs - abs(delta))
+
+    def tables(self, order):
+        """(qi, ki) of each grid step as two int32 arrays, for scalar
+        prefetch."""
+        return (jnp.asarray([qk[0] for qk in order], jnp.int32),
+                jnp.asarray([qk[1] for qk in order], jnp.int32))
+
+    def for_tile(self, qi, ki, body, keys_resident=False):
+        """Run ``body(cmap)`` for the class map of tile (qi, ki): one
+        compiled branch per distinct map, chosen by the tile's offset.
+        ``cmap[r][c]`` is indexed (resident sub-block, streamed chunk):
+        (query, key) unless ``keys_resident``."""
+        for cmap, first, last in self.branches:
+            if keys_resident:
+                cmap = tuple(zip(*cmap))
+            if len(self.branches) == 1:
+                body(cmap)
+            else:
+                pl.when((qi - ki >= first) & (qi - ki <= last))(
+                    functools.partial(body, cmap))
+
+
+_tile_plan = functools.lru_cache(maxsize=None)(_TilePlan)
+
+
+def tile_census(bh, t, d, tile, causal, window):
+    """The line that says how often the tile classes engage for a shape:
+    grid steps run of the full grid, and sub-tiles by class."""
+    plan = _tile_plan(t, tile, causal, window)
+    return (
+        "flash tiles: bh=%d t=%d d=%d %s window=%d tile=%d subtile=%d "
+        "steps=%d/%d sub=%d interior + %d edge + %d skipped" % (
+            bh, t, d, "causal" if causal else "full", window, tile, SUB,
+            len(plan.q_major), plan.num ** 2, plan.census[_FULL],
+            plan.census[_EDGE], plan.census[_DEAD]))
+
+
+@functools.lru_cache(maxsize=None)
+def announce_tiles(*shape):
+    """Once per compiled shape, beside ``announce_fallback``."""
+    logger.info(tile_census(*shape))
+
+
+def _live_span(cmap, c):
+    """[r0, r1): the resident sub-blocks with work against streamed
+    chunk ``c`` (contiguous: the band is), or None."""
+    live = [r for r, row in enumerate(cmap) if row[c][0] != _DEAD]
+    return (live[0], live[-1] + 1) if live else None
+
+
+def _mask_edges(s, cmap, c, window, keys_resident=False):
+    """NEG_INF outside the band, on the edge sub-blocks alone of ``s``,
+    the [live span, SUB] scores against streamed chunk ``c``.  Rows are
+    queries and lanes keys, or the reverse when ``keys_resident``."""
+    r0, r1 = _live_span(cmap, c)
+    if all(cmap[r][c][0] != _EDGE for r in range(r0, r1)):
+        return s
+    row = jax.lax.broadcasted_iota(jnp.int32, (SUB, SUB), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (SUB, SUB), 1)
+    diff = lane - row if keys_resident else row - lane
+    blocks = []
+    for r in range(r0, r1):
+        blk = _sub_blocks(s, r - r0, r - r0 + 1)
+        cls, off = cmap[r][c]
+        if cls == _EDGE:
+            keep = None                 # q_pos - k_pos = off + diff
+            if off - (SUB - 1) < 0:
+                keep = diff >= -off
+            if window and off + (SUB - 1) >= window:
+                below = diff < window - off
+                keep = below if keep is None else keep & below
+            blk = jnp.where(keep, blk, NEG_INF)
+        blocks.append(blk)
+    return _stack(blocks)
+
+
+def _sub_blocks(x, r0, r1):
+    """Rows [r0, r1) of ``x`` in sub-blocks of SUB."""
+    if (r0, r1 * SUB) == (0, x.shape[0]):
+        return x
+    return lax.slice(x, (r0 * SUB, 0), (r1 * SUB, x.shape[1]))
+
+
+def _stack(blocks):
+    return blocks[0] if len(blocks) == 1 else lax.concatenate(blocks, 0)
+
+
+def _accumulate(acc_scr, cmap, parts, w_ref):
+    """acc[live rows] += parts[c] @ w[chunk c], for each streamed chunk
+    c.  (One matmul over a run of chunks with the same live rows takes
+    exactly as long on the v5e: 1.2083 / 1.2077 / 1.2073 ms for the
+    forward at 1, 2, 8 chunks a matmul — my chip run, PR 25.)"""
+    for c, x in parts.items():
+        r0, r1 = _live_span(cmap, c)
+        rows = slice(r0 * SUB, r1 * SUB)
+        acc_scr[rows, :] = lax.add(acc_scr[rows, :], lax.dot_general(
+            x, w_ref[0, c * SUB:(c + 1) * SUB, :], _NN,
+            preferred_element_type=jnp.float32))
+
+
+# The kernel bodies below are Python-unrolled per sub-tile and traced on
+# every launch (the compile cache saves the compile, not the trace), the
+# forward twice under grad (as the custom_vjp's function and as its
+# forward rule).  Where both operands already have one shape
+# they use ``lax`` primitives, not operators: a jnp wrapper costs ~5x the
+# tracing of the primitive it binds, ~1,500 times a launch.
+
+# Key chunks of SUB the forward folds into its running stats at once:
+# 1.93 / 1.82 / 1.44 ms a call at 1 / 2 / 4 (tile 512), 1.25 / 1.21 / 1.33
+# at 2 / 4 / 8 (tile 1024) — my chip run, PR 25.
+_FWD_STATS_CHUNKS = 4
+# ... and the query sub-blocks with the same live chunks that fold as one
+# array: 1.208 ms a call at 1, 2 and 4, 1.230 at 8; at 4 the kernel traces
+# to 700 equations for 1,309 (start-up pays the trace on every launch).
+_FWD_ROW_RUN = 4
+
+
+def _flash_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, o_ref, l_ref,
+                  m_ref, acc_scr, l_scr, m_scr, *, plan, scale,
+                  normalize):
+    # grid: (bh, live tiles), the tiles of one query block in a row, K
+    # ascending.  Each grid step sees ONE [1, tile, D] K/V tile —
+    # Pallas's automatic pipelining streams tiles HBM->VMEM overlapped
+    # with compute, so VMEM never holds the full sequence.  The running
+    # (acc, l, m) lives in VMEM scratch, persistent across a query
+    # block's steps.  Stats stay 2D [tile, STATS_LANES] (every lane
+    # equal) so all vector ops live on full (8, 128) tiles, and leave
+    # as one [1, tile] row each.
+    head_dim = q_ref.shape[2]
+    step = pl.program_id(1)
+    qi, ki = qi_tab[step], ki_tab[step]
+
+    @pl.when(ki == jnp.maximum(0, qi - plan.dt_max))
     def _init():
         acc_scr[...] = jnp.zeros(acc_scr.shape, acc_scr.dtype)
         l_scr[...] = jnp.zeros(l_scr.shape, l_scr.dtype)
         m_scr[...] = jnp.full(m_scr.shape, NEG_INF, m_scr.dtype)
 
-    # Under causal masking, major blocks strictly above the diagonal
-    # contribute nothing — skip their matmuls entirely.  A sliding
-    # window additionally kills blocks entirely below the band.
-    live = (
-        ki * block_k_major <= qi * block_q + block_q - 1 if causal
-        else ki >= 0
-    )
-    if causal and window:
-        live &= (
-            ki * block_k_major + block_k_major - 1
-            >= qi * block_q - window + 1
-        )
+    def tile_body(cmap):
+        # Operands stay in their storage dtype (bf16 in the mixed-
+        # precision path) and accumulate in f32 via
+        # preferred_element_type; the scale folds into the f32 scores.
+        # Scores are taken a key chunk at a time over the query rows
+        # that are live against it (the MXU streams the long side), the
+        # stats a run of query sub-blocks at a time over a group of
+        # chunks.
+        n = plan.n
+        for g0 in range(0, n, _FWD_STATS_CHUNKS):
+            cols = range(g0, min(n, g0 + _FWD_STATS_CHUNKS))
+            s = {}              # b -> (first live row block, scores)
+            for b in cols:
+                span = _live_span(cmap, b)
+                if span is None:
+                    continue
+                sb = lax.dot_general(
+                    q_ref[0, span[0] * SUB:span[1] * SUB, :],
+                    k_ref[0, b * SUB:(b + 1) * SUB, :], _NT,
+                    preferred_element_type=jnp.float32,
+                ) * scale
+                s[b] = span[0], _mask_edges(sb, cmap, b, plan.window)
+            # Query sub-blocks with the same live chunks fold together.
+            runs = []           # [a0, a1, live chunks]
+            for a in range(n):
+                live = [b for b in s if cmap[a][b][0] != _DEAD]
+                if (runs and runs[-1][1:] == [a, live]
+                        and a - runs[-1][0] < _FWD_ROW_RUN):
+                    runs[-1][1] = a + 1
+                elif live:
+                    runs.append([a, a + 1, live])
+            p = {b: [] for b in s}          # b -> its live blocks, a up
+            for a0, a1, live in runs:
+                rows = slice(a0 * SUB, a1 * SUB)
+                blocks = [_sub_blocks(s[b][1], a0 - s[b][0], a1 - s[b][0])
+                          for b in live]
+                m_prev = m_scr[rows, :]
+                m_new = jnp.maximum(
+                    m_prev, functools.reduce(lax.max, blocks).max(
+                        axis=-1, keepdims=True))
+                alpha = lax.exp(lax.sub(m_prev, m_new))    # [rows, LANES]
+                ps = [lax.exp(lax.sub(blk, m_new)) for blk in blocks]
+                l_scr[rows, :] = lax.mul(l_scr[rows, :], alpha) + (
+                    functools.reduce(lax.add, ps).sum(
+                        axis=-1, keepdims=True))
+                m_scr[rows, :] = m_new
+                acc_scr[rows, :] = lax.mul(
+                    acc_scr[rows, :], _lanes_bcast(alpha, head_dim))
+                for b, pb in zip(live, ps):
+                    p[b].append(
+                        lax.convert_element_type(pb, v_ref.dtype))
+            _accumulate(acc_scr, cmap,
+                        {b: _stack(blocks) for b, blocks in p.items()},
+                        v_ref)
 
-    @pl.when(live)
-    def _major_step():
-        # Keep the operands in their storage dtype (bf16 in the mixed-
-        # precision path) and accumulate in f32 via preferred_element_type
-        # — upcasting before the dot would push the MXU onto the ~4x
-        # slower f32 path.  The scale folds into the f32 scores.
-        q = q_ref[0]                                   # [bq, D]
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
+    plan.for_tile(qi, ki, tile_body)
 
-        # One [1, block_k_major, D] K/V tile is streamed per grid step
-        # (enough work to amortize the per-step pipeline overhead); the
-        # online-softmax update walks it in lane-width chunks.
-        @pl.loop(0, block_k_major, step=block_k, unroll=True)
-        def _inner(start):
-            k = k_ref[0, pl.ds(start, block_k), :]     # [bk, D]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale                                  # [bq, bk] f32
-            if causal:
-                k_pos = (
-                    ki * block_k_major + start
-                    + jax.lax.broadcasted_iota(
-                        jnp.int32, (block_q, block_k), 1
-                    )
-                )
-                keep = q_pos >= k_pos
-                if window:
-                    keep &= q_pos - k_pos < window
-                s = jnp.where(keep, s, NEG_INF)
-            m_prev = m_scr[...]
-            l_prev = l_scr[...]
-            m_new = jnp.maximum(
-                m_prev, s.max(axis=-1)[:, None]
-            )                                          # [bq, LANES]
-            alpha = jnp.exp(m_prev - m_new)            # [bq, LANES]
-            p = jnp.exp(s - m_new)         # [bq, bk]; bk == STATS_LANES
-            l_scr[...] = l_prev * alpha + p.sum(axis=-1)[:, None]
-            m_scr[...] = m_new
-            pv = jax.lax.dot_general(
-                p.astype(v_ref.dtype),
-                v_ref[0, pl.ds(start, block_k), :],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            acc_scr[...] = (
-                acc_scr[...] * _lanes_bcast(alpha, head_dim) + pv
-            )
-
-    @pl.when(ki == num_k - 1)
+    @pl.when(ki == jnp.minimum(plan.num - 1, qi - plan.dt_min))
     def _finish():
         acc = acc_scr[...]
         l = l_scr[...]
@@ -205,8 +406,33 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, m_ref, acc_scr,
             ).astype(o_ref.dtype)
         else:
             o_ref[0] = acc.astype(o_ref.dtype)
-        l_ref[0] = l
-        m_ref[0] = m_scr[...]
+        l_ref[0] = l.T[0:1, :]
+        m_ref[0] = m_scr[...].T[0:1, :]
+
+
+def _tile_specs(tile, d, order_axis):
+    """(resident, streamed, resident-row stats, streamed-row stats)
+    BlockSpecs over a (bh, live tiles) grid whose step s works on tile
+    (qi_tab[s], ki_tab[s]); ``order_axis`` 0 keeps the query block
+    resident (forward, dq), 1 the key block (dk-dv)."""
+    def resident(i, s, qi_tab, ki_tab):
+        return (i, (qi_tab, ki_tab)[order_axis][s], 0)
+
+    def streamed(i, s, qi_tab, ki_tab):
+        return (i, (qi_tab, ki_tab)[1 - order_axis][s], 0)
+
+    def row_of(index):
+        def row(*args):
+            i, j, _ = index(*args)
+            return (i, 0, j)
+        return row
+
+    return (
+        pl.BlockSpec((1, tile, d), resident),
+        pl.BlockSpec((1, tile, d), streamed),
+        pl.BlockSpec((1, 1, tile), row_of(resident)),
+        pl.BlockSpec((1, 1, tile), row_of(streamed)),
+    )
 
 
 def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
@@ -218,73 +444,46 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
     kr = k.reshape(bh, t, d)
     vr = v.reshape(bh, t, d)
     # Work per grid step must amortize the per-step pipeline overhead:
-    # widen the q block and stream a major K/V tile (the kernel's inner
-    # loop walks it in block_k lane chunks), both capped by what
-    # divides t.  The caller's block_q/block_k are a friendliness
-    # contract (t divisible, 128 lanes) — the kernel owns its tiling.
-    block_q = block_k_major = _major_tile(t)
-    grid = (bh, t // block_q, t // block_k_major)
-    if causal:
-        # Dead blocks above the diagonal skip compute (pl.when in the
-        # kernel) — also skip their HBM->VMEM DMA by clamping the K/V
-        # index map to the last live block: a revisited block index is
-        # deduped by the pipeline into no copy.
-        def kv_index(i, j, ki):
-            last_live = (j * block_q + block_q - 1) // block_k_major
-            if window:
-                first_live = jnp.maximum(
-                    0, (j * block_q - window + 1) // block_k_major
-                )
-            else:
-                first_live = 0
-            return (i, jnp.clip(ki, first_live, last_live), 0)
-    else:
-        def kv_index(i, j, ki):
-            return (i, ki, 0)
+    # a wide q block and a major K/V tile of the same edge, both capped
+    # by what divides t.  The caller's block_q/block_k are a
+    # friendliness contract (t divisible, 128 lanes) — the kernel owns
+    # its tiling, and its grid holds the live tiles only (their indices
+    # are scalar-prefetched), so a dead tile costs neither a step nor a
+    # DMA.
+    tile = _major_tile(t, d * q.dtype.itemsize)
+    plan = _tile_plan(t, tile, causal, window)
+    if not interpret:
+        announce_tiles(bh, t, d, tile, causal, window)
+    q_spec, kv_spec, stat_spec, _ = _tile_specs(tile, d, 0)
     out_dtype = q.dtype if normalize else jnp.float32
     out, l, m = pl.pallas_call(
-        functools.partial(
-            _flash_kernel, block_k=block_k, causal=causal, scale=scale,
-            normalize=normalize, window=window,
-        ),
+        functools.partial(_flash_kernel, plan=plan, scale=scale,
+                          normalize=normalize),
         out_shape=(
             jax.ShapeDtypeStruct((bh, t, d), out_dtype),
-            jax.ShapeDtypeStruct((bh, t, STATS_LANES), jnp.float32),
-            jax.ShapeDtypeStruct((bh, t, STATS_LANES), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
         ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j, ki: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k_major, d), kv_index,
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k_major, d), kv_index,
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, block_q, d), lambda i, j, ki: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, STATS_LANES),
-                         lambda i, j, ki: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, STATS_LANES),
-                         lambda i, j, ki: (i, j, 0),
-                         memory_space=pltpu.VMEM),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(bh, len(plan.q_major)),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=(q_spec, stat_spec, stat_spec),
+            scratch_shapes=[
+                pltpu.VMEM((tile, d), jnp.float32),
+                pltpu.VMEM((tile, STATS_LANES), jnp.float32),
+                pltpu.VMEM((tile, STATS_LANES), jnp.float32),
+            ],
         ),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, STATS_LANES), jnp.float32),
-            pltpu.VMEM((block_q, STATS_LANES), jnp.float32),
-        ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(qr, kr, vr)
+    )(*plan.tables(plan.q_major), qr, kr, vr)
     return (
         out.reshape(b, h, t, d),
-        l[..., 0].reshape(b, h, t),
-        m[..., 0].reshape(b, h, t),
+        l.reshape(b, h, t),
+        m.reshape(b, h, t),
     )
 
 
@@ -323,10 +522,10 @@ def _masked_block_scores(qf, kf, ki, block_k, causal, scale, k_offset,
     return s, None
 
 
-def _blockwise_bwd(q, k, v, out, l, m, g, causal, scale, block_k,
+def _blockwise_bwd(q, k, v, out, lse, g, causal, scale, block_k,
                    window=0):
     """Block-recompute backward: scan over K blocks rebuilding each
-    [T, block_k] probability tile from the saved (l, m) stats.  Peak
+    [T, block_k] probability tile from the saved log-sum-exp.  Peak
     live memory O(B·H·T·block_k), never the T x T matrix."""
     _, _, tk, _ = k.shape
     qf = q.astype(jnp.float32)
@@ -334,7 +533,6 @@ def _blockwise_bwd(q, k, v, out, l, m, g, causal, scale, block_k,
     outf = out.astype(jnp.float32)
     # delta_i = sum_d dO_i O_i  (the usual flash-bwd row constant)
     delta = (gf * outf).sum(axis=-1)                    # [B,H,T]
-    l_safe = jnp.maximum(l, 1e-30)
     q_pos = jnp.arange(q.shape[2])
 
     num_k, k_blocks, v_blocks = _kv_blocks(k, v, block_k)
@@ -345,7 +543,7 @@ def _blockwise_bwd(q, k, v, out, l, m, g, causal, scale, block_k,
         s, _ = _masked_block_scores(
             qf, kf, ki, block_k, causal, scale, 0, q_pos, window=window
         )                                               # [B,H,T,bk]
-        p = jnp.exp(s - m[..., None]) / l_safe[..., None]
+        p = jnp.exp(s - lse[..., None])
         dv = jnp.einsum("bhqk,bhqd->bhkd", p, gf)
         dp = jnp.einsum("bhqd,bhkd->bhqk", gf, vf)
         ds = p * (dp - delta[..., None]) * scale
@@ -362,264 +560,170 @@ def _blockwise_bwd(q, k, v, out, l, m, g, causal, scale, block_k,
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-STATS_OUT = 8  # lanes for stats arrays fed back into the bwd kernels
+def _bwd_dq_kernel(qi_tab, ki_tab, q_ref, do_ref, k_ref, v_ref, lse_ref,
+                   delta_ref, dq_ref, dq_scr, lse_scr, delta_scr, *,
+                   plan, scale):
+    """dq = scale * sum_j ds_ij k_j, ds = p (dp - delta), p = exp(s -
+    lse).  Grid (bh, live tiles), a query block's tiles in a row: the
+    q/dO tiles and the row constants stay resident while K/V tiles
+    stream through VMEM; the probability/ds tiles never exist outside
+    VMEM.  lse and delta arrive as [1, tile] rows and are spread over
+    the lanes once a query block."""
+    step = pl.program_id(1)
+    qi, ki = qi_tab[step], ki_tab[step]
+    tile = plan.tile
 
-
-def _major_tile(t):
-    """Shared fwd/bwd major-tile policy: widest of 128/256/512 dividing t
-    (enough per-grid-step work to amortize pipeline overhead)."""
-    return max(bs for bs in (128, 256, 512) if bs <= t and t % bs == 0)
-
-
-def _bwd_dq_kernel(q_ref, o_ref, do_ref, k_ref, v_ref, l_ref, m_ref,
-                   dq_ref, dq_scr, *, block_k, causal, scale,
-                   window=0):
-    """dq = sum_j ds_ij k_j.  Grid (bh, NQ, NK), K innermost: the q/o/dO
-    tiles and stats stay resident while K/V tiles stream through VMEM;
-    the [bq, block_k] probability/ds tiles never exist outside VMEM."""
-    block_q = q_ref.shape[1]
-    block_k_major = k_ref.shape[1]
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    num_k = pl.num_programs(2)
-
-    @pl.when(ki == 0)
+    @pl.when(ki == jnp.maximum(0, qi - plan.dt_max))
     def _init():
         dq_scr[...] = jnp.zeros(dq_scr.shape, dq_scr.dtype)
+        lse_scr[...] = jnp.broadcast_to(
+            lse_ref[0], (STATS_LANES, tile)).T
+        delta_scr[...] = jnp.broadcast_to(
+            delta_ref[0], (STATS_LANES, tile)).T
 
-    live = (
-        ki * block_k_major <= qi * block_q + block_q - 1 if causal
-        else ki >= 0
-    )
-    if causal and window:
-        live &= (
-            ki * block_k_major + block_k_major - 1
-            >= qi * block_q - window + 1
-        )
-
-    @pl.when(live)
-    def _step():
-        q = q_ref[0]                                   # [bq, D]
-        do = do_ref[0]
-        delta = (
-            do.astype(jnp.float32) * o_ref[0].astype(jnp.float32)
-        ).sum(axis=-1)[:, None]                        # [bq, 1]
-        m = m_ref[0][:, 0:1]                           # [bq, 1]
-        l = jnp.maximum(l_ref[0][:, 0:1], 1e-30)
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-
-        @pl.loop(0, block_k_major, step=block_k, unroll=True)
-        def _inner(start):
-            k = k_ref[0, pl.ds(start, block_k), :]     # [bk, D]
-            v = v_ref[0, pl.ds(start, block_k), :]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
+    def tile_body(cmap):
+        ds = {}
+        for b in range(plan.n):
+            span = _live_span(cmap, b)
+            if span is None:
+                continue
+            rows = slice(span[0] * SUB, span[1] * SUB)
+            keys = slice(b * SUB, (b + 1) * SUB)
+            s = lax.dot_general(
+                q_ref[0, rows, :], k_ref[0, keys, :], _NT,
                 preferred_element_type=jnp.float32,
-            ) * scale                                  # [bq, bk]
-            if causal:
-                k_pos = (
-                    ki * block_k_major + start
-                    + jax.lax.broadcasted_iota(
-                        jnp.int32, (block_q, block_k), 1
-                    )
-                )
-                keep = q_pos >= k_pos
-                if window:
-                    keep &= q_pos - k_pos < window
-                s = jnp.where(keep, s, NEG_INF)
-            p = jnp.exp(s - m) / l                     # normalized
-            dp = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )                                          # [bq, bk]
-            ds = (p * (dp - delta) * scale).astype(k_ref.dtype)
-            dq_scr[...] += jax.lax.dot_general(
-                ds, k, (((1,), (0,)), ((), ())),
+            ) * scale                                  # [rows, SUB]
+            s = _mask_edges(s, cmap, b, plan.window)
+            p = lax.exp(lax.sub(s, lse_scr[rows, :]))
+            dp = lax.dot_general(
+                do_ref[0, rows, :], v_ref[0, keys, :], _NT,
                 preferred_element_type=jnp.float32,
             )
+            ds[b] = lax.convert_element_type(
+                lax.mul(p, lax.sub(dp, delta_scr[rows, :])), k_ref.dtype)
+        _accumulate(dq_scr, cmap, ds, k_ref)
 
-    @pl.when(ki == num_k - 1)
+    plan.for_tile(qi, ki, tile_body)
+
+    @pl.when(ki == jnp.minimum(plan.num - 1, qi - plan.dt_min))
     def _finish():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(k_ref, v_ref, q_ref, o_ref, do_ref, l_ref, m_ref,
-                    dk_ref, dv_ref, dk_scr, dv_scr,
-                    *, block_q, causal, scale, window=0):
-    """dk_j = sum_i ds_ij^T q_i, dv_j = sum_i p_ij^T dO_i.  Grid
-    (bh, NK, NQ), Q innermost: the K/V tiles and accumulators stay
-    resident while q/o/dO tiles (and their stats) stream through."""
-    block_k_major = k_ref.shape[1]
-    block_q_major = q_ref.shape[1]
-    kj = pl.program_id(1)
-    qi = pl.program_id(2)
-    num_q = pl.num_programs(2)
+def _bwd_dkv_kernel(qi_tab, ki_tab, k_ref, v_ref, q_ref, do_ref, lse_ref,
+                    delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, plan,
+                    scale):
+    """dk_j = scale * sum_i ds_ij q_i, dv_j = sum_i p_ij dO_i.  Grid
+    (bh, live tiles), a key block's tiles in a row: the K/V tiles and
+    accumulators stay resident while q/dO tiles and their row constants
+    stream through.  The score tiles are built transposed, [keys,
+    queries], so that every matmul contracts a minor dimension with a
+    major one (no transpose of p or ds) and lse, delta are [1, SUB] rows
+    spread over sublanes."""
+    step = pl.program_id(1)
+    qi, ki = qi_tab[step], ki_tab[step]
 
-    @pl.when(qi == 0)
+    @pl.when(qi == jnp.maximum(0, ki + plan.dt_min))
     def _init():
         dk_scr[...] = jnp.zeros(dk_scr.shape, dk_scr.dtype)
         dv_scr[...] = jnp.zeros(dv_scr.shape, dv_scr.dtype)
 
-    live = (
-        qi * block_q_major + block_q_major - 1 >= kj * block_k_major
-        if causal else qi >= 0
-    )
-    if causal and window:
-        live &= (
-            qi * block_q_major
-            <= kj * block_k_major + block_k_major - 1 + window - 1
-        )
-
-    @pl.when(live)
-    def _step():
-        k = k_ref[0]                                   # [bkM, D]
-        v = v_ref[0]
-        if causal:
-            k_pos = kj * block_k_major + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k_major), 1
+    def tile_body(cmap):
+        p_t, ds_t = {}, {}
+        for a in range(plan.n):
+            span = _live_span(cmap, a)
+            if span is None:
+                continue
+            rows = slice(span[0] * SUB, span[1] * SUB)     # keys
+            qs = slice(a * SUB, (a + 1) * SUB)
+            s = lax.dot_general(
+                k_ref[0, rows, :], q_ref[0, qs, :], _NT,
+                preferred_element_type=jnp.float32,
+            ) * scale                                  # [keys, SUB]
+            s = _mask_edges(s, cmap, a, plan.window, keys_resident=True)
+            p = lax.exp(s - lse_ref[0, :, qs])
+            dp = lax.dot_general(
+                v_ref[0, rows, :], do_ref[0, qs, :], _NT,
+                preferred_element_type=jnp.float32,
             )
+            p_t[a] = lax.convert_element_type(p, do_ref.dtype)
+            ds_t[a] = lax.convert_element_type(
+                lax.mul(p, dp - delta_ref[0, :, qs]), q_ref.dtype)
+        _accumulate(dv_scr, cmap, p_t, do_ref)
+        _accumulate(dk_scr, cmap, ds_t, q_ref)
 
-        @pl.loop(0, block_q_major, step=block_q, unroll=True)
-        def _inner(start):
-            q = q_ref[0, pl.ds(start, block_q), :]     # [qc, D]
-            o = o_ref[0, pl.ds(start, block_q), :]
-            do = do_ref[0, pl.ds(start, block_q), :]
-            m = m_ref[0, pl.ds(start, block_q), :][:, 0:1]
-            l = jnp.maximum(
-                l_ref[0, pl.ds(start, block_q), :][:, 0:1], 1e-30
-            )
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale                                  # [qc, bkM]
-            if causal:
-                q_pos = (
-                    qi * block_q_major + start
-                    + jax.lax.broadcasted_iota(
-                        jnp.int32, (block_q, block_k_major), 0
-                    )
-                )
-                keep = q_pos >= k_pos
-                if window:
-                    keep &= q_pos - k_pos < window
-                s = jnp.where(keep, s, NEG_INF)
-            p = jnp.exp(s - m) / l                     # [qc, bkM]
-            pb = p.astype(do_ref.dtype)
-            dv_scr[...] += jax.lax.dot_general(
-                pb, do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )                                          # [bkM, D]
-            dp = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )                                          # [qc, bkM]
-            delta = (
-                do.astype(jnp.float32) * o.astype(jnp.float32)
-            ).sum(axis=-1)[:, None]
-            ds = (p * (dp - delta) * scale).astype(q_ref.dtype)
-            dk_scr[...] += jax.lax.dot_general(
-                ds, q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )                                          # [bkM, D]
+    plan.for_tile(qi, ki, tile_body, keys_resident=True)
 
-    @pl.when(qi == num_q - 1)
+    @pl.when(qi == jnp.minimum(plan.num - 1, ki + plan.dt_max))
     def _finish():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _pallas_bwd(q, k, v, out, l, m, g, causal, scale, interpret,
+def _pallas_bwd(q, k, v, out, lse, g, causal, scale, interpret,
                 window=0):
     """Pallas backward: dq in one pass (K streamed), dk/dv in another
     (Q streamed).  Same FLOPs as the XLA block-recompute path but the
     probability/ds tiles live only in VMEM — no [B,H,T,block] HBM
-    round-trips between the einsums of a scan step."""
+    round-trips between the einsums of a scan step.  What is constant
+    along a row is made once, out here: lse came with the residuals,
+    delta_i = sum_d dO_i O_i is one pass over dO and O."""
     b, h, t, d = q.shape
     bh = b * h
-    tile = _major_tile(t)
-    num = t // tile
+    tile = _major_tile(t, d * q.dtype.itemsize)
+    plan = _tile_plan(t, tile, causal, window)
     qr = q.reshape(bh, t, d)
     kr = k.reshape(bh, t, d)
     vr = v.reshape(bh, t, d)
-    orr = out.reshape(bh, t, d)
     gr = g.astype(q.dtype).reshape(bh, t, d)
-    l8 = jnp.broadcast_to(
-        l.reshape(bh, t, 1), (bh, t, STATS_OUT)
-    ).astype(jnp.float32)
-    m8 = jnp.broadcast_to(
-        m.reshape(bh, t, 1), (bh, t, STATS_OUT)
-    ).astype(jnp.float32)
+    lse = lse.astype(jnp.float32).reshape(bh, 1, t)
+    delta = (
+        gr.astype(jnp.float32) * out.reshape(bh, t, d).astype(jnp.float32)
+    ).sum(axis=-1).reshape(bh, 1, t)
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"))
 
-    qo_spec = pl.BlockSpec((1, tile, d), lambda i, j, kk: (i, j, 0),
-                           memory_space=pltpu.VMEM)
-    st_spec = pl.BlockSpec((1, tile, STATS_OUT),
-                           lambda i, j, kk: (i, j, 0),
-                           memory_space=pltpu.VMEM)
-    if causal:
-        # Dead blocks skip compute; clamp the streamed-side index map so
-        # their HBM->VMEM copies dedupe away too.  (Equal fwd tile
-        # sizes, so tile index arithmetic is 1:1.)
-        win_tiles = (window + tile - 2) // tile if window else 0
-
-        def kv_index(i, j, kk):
-            lo = jnp.maximum(0, j - win_tiles) if window else 0
-            return (i, jnp.clip(kk, lo, j), 0)
-
-        def q_index(i, j, kk):
-            hi = j + win_tiles if window else num - 1
-            return (i, jnp.clip(kk, j, hi), 0)
-    else:
-        def kv_index(i, j, kk):
-            return (i, kk, 0)
-
-        def q_index(i, j, kk):
-            return (i, kk, 0)
-    kv_spec = pl.BlockSpec((1, tile, d), kv_index,
-                           memory_space=pltpu.VMEM)
+    q_spec, kv_spec, qstat_spec, _ = _tile_specs(tile, d, 0)
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, block_k=128, causal=causal,
-                          scale=scale, window=window),
+        functools.partial(_bwd_dq_kernel, plan=plan, scale=scale),
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-        grid=(bh, num, num),
-        in_specs=[qo_spec, qo_spec, qo_spec, kv_spec, kv_spec,
-                  st_spec, st_spec],
-        out_specs=qo_spec,
-        scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(bh, len(plan.q_major)),
+            in_specs=[q_spec, q_spec, kv_spec, kv_spec, qstat_spec,
+                      qstat_spec],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((tile, d), jnp.float32),
+                pltpu.VMEM((tile, STATS_LANES), jnp.float32),
+                pltpu.VMEM((tile, STATS_LANES), jnp.float32),
+            ],
         ),
+        compiler_params=params,
         interpret=interpret,
-    )(qr, orr, gr, kr, vr, l8, m8)
+    )(*plan.tables(plan.q_major), qr, gr, kr, vr, lse, delta)
 
-    kv_res_spec = pl.BlockSpec((1, tile, d), lambda i, j, kk: (i, j, 0),
-                               memory_space=pltpu.VMEM)
-    qs_spec = pl.BlockSpec((1, tile, d), q_index,
-                           memory_space=pltpu.VMEM)
-    sts_spec = pl.BlockSpec((1, tile, STATS_OUT), q_index,
-                            memory_space=pltpu.VMEM)
+    kv_spec, q_spec, _, qstat_spec = _tile_specs(tile, d, 1)
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, block_q=128, causal=causal,
-                          scale=scale, window=window),
+        functools.partial(_bwd_dkv_kernel, plan=plan, scale=scale),
         out_shape=(
             jax.ShapeDtypeStruct((bh, t, d), k.dtype),
             jax.ShapeDtypeStruct((bh, t, d), v.dtype),
         ),
-        grid=(bh, num, num),
-        in_specs=[kv_res_spec, kv_res_spec, qs_spec, qs_spec, qs_spec,
-                  sts_spec, sts_spec],
-        out_specs=(kv_res_spec, kv_res_spec),
-        scratch_shapes=[
-            pltpu.VMEM((tile, d), jnp.float32),
-            pltpu.VMEM((tile, d), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(bh, len(plan.k_major)),
+            in_specs=[kv_spec, kv_spec, q_spec, q_spec, qstat_spec,
+                      qstat_spec],
+            out_specs=(kv_spec, kv_spec),
+            scratch_shapes=[
+                pltpu.VMEM((tile, d), jnp.float32),
+                pltpu.VMEM((tile, d), jnp.float32),
+            ],
         ),
+        compiler_params=params,
         interpret=interpret,
-    )(kr, vr, qr, orr, gr, l8, m8)
+    )(*plan.tables(plan.k_major), kr, vr, qr, gr, lse, delta)
     return (
         dq.reshape(b, h, t, d),
         dk.reshape(b, h, t, d),
@@ -639,17 +743,19 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                window=0):
     out, l, m = _flash_forward(q, k, v, causal, scale, block_q, block_k,
                                interpret, window=window)
-    return out, (q, k, v, out, l, m)
+    # One row constant for the backward: p = exp(s - lse), no divide.
+    lse = m + jnp.log(jnp.maximum(l, 1e-30))
+    return out, (q, k, v, out, lse)
 
 
 def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res,
                g):
-    q, k, v, out, l, m = res
+    q, k, v, out, lse = res
     if os.environ.get("ELASTICDL_FLASH_BWD", "pallas") == "xla":
         # Escape hatch: the XLA block-recompute backward.
-        return _blockwise_bwd(q, k, v, out, l, m, g, causal, scale,
+        return _blockwise_bwd(q, k, v, out, lse, g, causal, scale,
                               block_k, window=window)
-    return _pallas_bwd(q, k, v, out, l, m, g, causal, scale, interpret,
+    return _pallas_bwd(q, k, v, out, lse, g, causal, scale, interpret,
                        window=window)
 
 

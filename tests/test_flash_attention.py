@@ -32,12 +32,17 @@ def test_flash_matches_reference(causal):
 
 
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_multi_k_block_grid(causal):
-    # t=1024 with the kernel's 512-max tiling makes the K grid dimension
-    # 2 — exercising the scratch carry across ki, the pl.when
-    # init/finish gating, the causal dead-block skip, and the clamped
-    # kv_index DMA dedup, none of which engage when the grid is 1x1.
-    q, k, v = make_qkv(b=1, h=1, t=1024, d=64, seed=3)
+@pytest.mark.parametrize("t", [1024, 1536, 2048])
+def test_flash_multi_k_block_grid(causal, t):
+    # Every tile class and the carry across grid steps, none of which
+    # engage when t is one sub-tile.  t=1024 is ONE major tile of 8x8
+    # sub-tiles (interior, edge and skipped inside one step); t=1536
+    # takes the 512 tile (3 does not divide by 1024): a 3x3 grid of
+    # which causal runs 6 steps, the diagonal ones in the branch with
+    # edges and the others in the interior branch, with the scratch
+    # carry and the init/finish gating across a query block's steps;
+    # t=2048 is the same on the 1024 tile (3 of 4 steps).
+    q, k, v = make_qkv(b=1, h=1, t=t, d=64, seed=3)
     ref = _attention_ref(q, k, v, causal, q.shape[-1] ** -0.5)
     out = flash_attention(q, k, v, causal=causal, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -82,15 +87,18 @@ def test_unfriendly_shapes_fall_back():
     assert out.shape == q.shape
 
 
-def test_partial_matches_reference_stats():
+@pytest.mark.parametrize("t", [128, 1536])
+def test_partial_matches_reference_stats(t):
     """flash_attention_partial returns (acc, l, m) that normalize to the
-    reference output — the ring-fold building block."""
+    reference output — the ring-fold building block.  t=1536: the
+    narrow [1, tile] stats rows of a 3x3 grid, causal (6 steps, edges)
+    and not (9 steps, every sub-tile interior)."""
     from elasticdl_tpu.ops.flash_attention import (
         _partial_ref,
         flash_attention_partial,
     )
 
-    q, k, v = make_qkv(t=128)
+    q, k, v = make_qkv(b=1, t=t)
     for causal in (True, False):
         acc, l, m = flash_attention_partial(
             q, k, v, causal=causal, interpret=True
@@ -98,6 +106,9 @@ def test_partial_matches_reference_stats():
         acc_r, l_r, m_r = _partial_ref(
             q, k, v, causal, q.shape[-1] ** -0.5, 0
         )
+        np.testing.assert_allclose(
+            np.asarray(m + jnp.log(l)), np.asarray(m_r + jnp.log(l_r)),
+            rtol=2e-5, atol=2e-5)
         out = acc / np.maximum(np.asarray(l), 1e-30)[..., None]
         out_r = acc_r / np.maximum(np.asarray(l_r), 1e-30)[..., None]
         np.testing.assert_allclose(np.asarray(out), np.asarray(out_r),
@@ -108,13 +119,17 @@ def test_partial_matches_reference_stats():
 
 
 @pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.parametrize("t", [256, 384])
-def test_pallas_bwd_matches_reference(causal, t, monkeypatch):
+@pytest.mark.parametrize("t,d", [(256, 64), (384, 64), (1536, 64),
+                                 (256, 128)])
+def test_pallas_bwd_matches_reference(causal, t, d, monkeypatch):
     """The default backward is the Pallas kernel pair (dq; dk/dv) —
     it must be the path taken and match reference gradients.  t=384
     forces tile=128 -> a 3x3 block grid, exercising the cross-step
-    scratch accumulation and the causal-clamped index maps (t=256 is
-    a single-block grid where init/finish coincide)."""
+    scratch accumulation and the live-tile tables (t=256 is a
+    single-block grid where init/finish coincide); t=1536 is a 3x3 grid
+    of 4x4 sub-tiles: the diagonal tiles' branch skips the sub-tiles
+    above the diagonal and masks the four on it, the others' branch
+    masks nothing, in dq and (transposed) in dk-dv."""
     import elasticdl_tpu.ops.flash_attention as fa
 
     called = {}
@@ -125,7 +140,7 @@ def test_pallas_bwd_matches_reference(causal, t, monkeypatch):
         return orig(*args, **kwargs)
 
     monkeypatch.setattr(fa, "_pallas_bwd", spy)
-    q, k, v = make_qkv(t=t)
+    q, k, v = make_qkv(b=1, t=t, d=d)
 
     def loss_flash(q, k, v):
         return (
@@ -256,3 +271,77 @@ def test_transformer_hits_flash_path(monkeypatch):
     logits_ref = tfm.forward(params, tokens, cfg, mesh=None)
     np.testing.assert_allclose(np.asarray(logits), np.asarray(logits_ref),
                                rtol=2e-4, atol=2e-4)
+
+
+def _closed_form_census(t, tile, causal, window):
+    """(steps, full grid, interior, edge, skipped) by counting whole
+    diagonals: sub-tile diagonal k (k sub-tiles below the main one) has
+    t/128 - k sub-tiles; it is an edge at k = 0 and, under a window of
+    w/128 = W whole sub-tiles, at k = W; interior strictly between;
+    skipped above the main diagonal and below the window's."""
+    n_sub, n_tile, per = t // 128, t // tile, tile // 128
+    if not causal:
+        return n_tile ** 2, n_tile ** 2, n_sub ** 2, 0, 0
+    last = window // 128 if window else n_sub    # last live diagonal
+    edge = n_sub + (n_sub - last if window else 0)
+    interior = sum(n_sub - k for k in range(1, min(last, n_sub)))
+    # a tile dt below the diagonal holds sub-tile diagonals
+    # dt*per - (per-1) .. dt*per + (per-1)
+    steps = sum(n_tile - dt for dt in range(n_tile)
+                if dt * per - (per - 1) <= last)
+    return (steps, n_tile ** 2, interior, edge,
+            n_sub ** 2 - interior - edge)
+
+
+@pytest.mark.parametrize("t,causal,window,expected", [
+    (2048, True, 0,
+     "flash tiles: bh=128 t=2048 d=128 causal window=0 tile=1024 "
+     "subtile=128 steps=3/4 sub=120 interior + 16 edge + 120 skipped"),
+    (2048, True, 512,
+     "flash tiles: bh=128 t=2048 d=128 causal window=512 tile=1024 "
+     "subtile=128 steps=3/4 sub=42 interior + 28 edge + 186 skipped"),
+    (1024, False, 0,
+     "flash tiles: bh=128 t=1024 d=128 full window=0 tile=1024 "
+     "subtile=128 steps=1/1 sub=64 interior + 0 edge + 0 skipped"),
+])
+def test_tile_census_is_the_closed_form(t, causal, window, expected):
+    """The census line the worker logs once per compiled shape, against
+    a count made a different way (whole diagonals), at the benchmark's
+    shape (bf16, d=128: the 1024 tile) and at the 512 tile."""
+    import elasticdl_tpu.ops.flash_attention as fa
+
+    tile = fa._major_tile(t, 128 * 2)
+    assert fa.tile_census(128, t, 128, tile, causal, window) == expected
+    for tile in (512, tile):
+        steps, grid, interior, edge, skipped = _closed_form_census(
+            t, tile, causal, window)
+        line = fa.tile_census(128, t, 128, tile, causal, window)
+        assert "tile=%d subtile=128 steps=%d/%d sub=%d interior + %d " \
+            "edge + %d skipped" % (tile, steps, grid, interior, edge,
+                                   skipped) in line, line
+        plan = fa._tile_plan(t, tile, causal, window)
+        assert len(plan.q_major) == len(plan.k_major) == steps
+        # the kernels' init / finish gates are the ends of each row
+        for qi in range(plan.num):
+            row = [ki for q_, ki in plan.q_major if q_ == qi]
+            assert row == list(range(max(0, qi - plan.dt_max),
+                                     min(plan.num - 1,
+                                         qi - plan.dt_min) + 1))
+        for ki in range(plan.num):
+            col = [qi for qi, k_ in plan.k_major if k_ == ki]
+            assert col == list(range(max(0, ki + plan.dt_min),
+                                     min(plan.num - 1,
+                                         ki + plan.dt_max) + 1))
+
+
+def test_tile_census_is_announced_once_per_shape():
+    import elasticdl_tpu.ops.flash_attention as fa
+
+    fa.announce_tiles.cache_clear()
+    with mock.patch.object(fa.logger, "info") as info:
+        for _ in range(3):
+            fa.announce_tiles(4, 1024, 64, 1024, True, 0)
+        fa.announce_tiles(4, 1024, 64, 1024, True, 256)
+    assert [c.args[0] for c in info.call_args_list] == [
+        fa.tile_census(4, 1024, 64, 1024, True, 0),
+        fa.tile_census(4, 1024, 64, 1024, True, 256)]

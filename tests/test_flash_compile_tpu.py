@@ -1,0 +1,68 @@
+"""The flash-attention kernels through the TPU's own compiler, for a v5e
+that is described and not attached.
+
+Interpret mode cannot see what Mosaic refuses (a slice off the tiling, a
+transpose it has no lowering for, more scoped VMEM than a kernel may
+use); this does, at the widths the benchmark's cells run, in a few
+seconds and without chip time.  Nothing runs, so no result or time is
+checked here: ``chip_check.py`` does that on the chip.  The topology is
+described inside a fixture, never at import: only the worker that is
+given this file loads the TPU library.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from elasticdl_tpu.ops import flash_attention as fa
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any refusal means no compiler
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep it out.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("t,d,dtype,window", [
+    (2048, 128, jnp.bfloat16, 0),     # the benchmark's cells: 1024 tile
+    (2048, 64, jnp.bfloat16, 512),    # both edges, narrow head
+    (1536, 256, jnp.float32, 0),      # wide f32 rows: the 512 tile
+])
+def test_flash_fwd_bwd_compile_for_v5e(one_chip, t, d, dtype, window):
+    x = jax.ShapeDtypeStruct((1, 16, t, d), dtype, sharding=one_chip)
+    static = (True, d ** -0.5, 128, 128, False, window)
+
+    def fwd_bwd(q, k, v, g):
+        out, res = fa._flash_fwd(q, k, v, *static)
+        return out, fa._flash_bwd(*static, res, g)
+
+    text = jax.jit(fwd_bwd).lower(x, x, x, x).compile().as_text()
+    # forward, dq, dk-dv: three Mosaic calls, told apart downstream by
+    # their result counts (3, 1, 2: benchmark/kernels/flash_attention.py)
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_partial_compiles_for_v5e(one_chip, causal):
+    x = jax.ShapeDtypeStruct((1, 16, 2048, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    text = jax.jit(
+        lambda q, k, v: fa._flash_forward(
+            q, k, v, causal, 0.125, 128, 128, False, normalize=False)
+    ).lower(x, x, x).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1

@@ -30,6 +30,13 @@ from elasticdl_tpu.parallel.ring_attention import (
 from elasticdl_tpu.parallel.ulysses import ulysses_attention
 
 WINDOWS = [64, 200, 1000]  # < tile; not a multiple of 128; >= t
+# (t, window) where the band's lower edge crosses major tiles of several
+# sub-tiles: t=1536 takes the 512 tile (3x3 grid of 4x4 sub-tiles) and a
+# window of 600 ends in the tile one below the diagonal, which so has
+# interior, edge and skipped sub-tiles in one branch, while the tile two
+# below is no grid step at all; t=1024 is one 8x8 major tile with both
+# edges in it.
+TILE_EDGE_WINDOWS = [(1536, 600), (1024, 200)]
 
 
 def make_bhtd(b=1, h=2, t=384, d=64, seed=0):
@@ -48,9 +55,10 @@ def make_bthd(b=2, t=64, h=4, d=16, seed=0):
     )
 
 
-@pytest.mark.parametrize("window", WINDOWS)
-def test_flash_window_forward(window):
-    q, k, v = make_bhtd()
+@pytest.mark.parametrize(
+    "t,window", [(384, w) for w in WINDOWS] + TILE_EDGE_WINDOWS)
+def test_flash_window_forward(t, window):
+    q, k, v = make_bhtd(h=1 if t > 384 else 2, t=t)
     ref = _attention_ref(q, k, v, True, q.shape[-1] ** -0.5,
                          window=window)
     out = flash_attention(q, k, v, causal=True, interpret=True,
@@ -131,10 +139,11 @@ def test_ring_window_blockwise_banded():
                                    rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("window", WINDOWS)
-def test_flash_window_pallas_bwd(window, monkeypatch):
-    """Both Pallas backward kernels under a window — the q_index /
-    kv_index clamping and the in-kernel band mask."""
+@pytest.mark.parametrize(
+    "t,window", [(384, w) for w in WINDOWS] + TILE_EDGE_WINDOWS)
+def test_flash_window_pallas_bwd(t, window, monkeypatch):
+    """Both Pallas backward kernels under a window — the live-tile
+    tables and the in-kernel band mask on the edge sub-tiles."""
     import elasticdl_tpu.ops.flash_attention as fa
 
     called = {}
@@ -145,7 +154,7 @@ def test_flash_window_pallas_bwd(window, monkeypatch):
         return orig(*args, **kwargs)
 
     monkeypatch.setattr(fa, "_pallas_bwd", spy)
-    q, k, v = make_bhtd(seed=window)
+    q, k, v = make_bhtd(h=1 if t > 384 else 2, t=t, seed=window)
 
     def loss_flash(q, k, v):
         return (

@@ -1,0 +1,111 @@
+#!/usr/bin/env python3
+"""Time the three flash-attention kernels alone, on the chip.
+
+    python3 tools/flash_kernels_on_chip.py [--bh 128] [--t 2048] [--d 128]
+        [--window 0] [--root DIR] [--set NAME=INT]
+
+Prints one JSON line: ms a call of the forward, dq and dk-dv kernels
+(device time of each ``custom-call`` event in a profiler trace, which is
+what ``kernel.flash_attention_roofline`` reads) and of the whole forward
+and backward on the host's clock (XLA glue around the kernels included).
+``--root DIR`` imports ``elasticdl_tpu`` from another checkout (the
+parent commit unpacked beside this one), so one call measures both.
+Exits 3 without a TPU: a CPU timing is no device number.
+"""
+
+import argparse
+import collections
+import json
+import os
+import sys
+import tempfile
+import time
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--bh", type=int, default=128)
+    ap.add_argument("--t", type=int, default=2048)
+    ap.add_argument("--d", type=int, default=128)
+    ap.add_argument("--window", type=int, default=0)
+    ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--set", action="append", default=[],
+                    metavar="NAME=INT", help="set a module constant of "
+                    "ops/flash_attention.py before tracing")
+    ap.add_argument("--root", default=os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))))
+    args = ap.parse_args()
+    sys.path.insert(0, args.root)
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    for item in args.set:
+        name, value = item.split("=")
+        assert hasattr(fa, name), name
+        setattr(fa, name, int(value))
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        print("flash_kernels_on_chip: platform is %r, not tpu"
+              % dev.platform, file=sys.stderr)
+        return 3
+    rng = np.random.RandomState(0)
+    shape = (1, args.bh, args.t, args.d)
+    q, k, v, g = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+                  for _ in range(4))
+    static = (True, args.d ** -0.5, 128, 128, False, args.window)
+    fwd = jax.jit(lambda q, k, v: fa._flash_fwd(q, k, v, *static))
+    bwd = jax.jit(lambda res, g: fa._flash_bwd(*static, res, g))
+    dq_only = jax.jit(lambda res, g: fa._flash_bwd(*static, res, g)[0])
+    dkv_only = jax.jit(lambda res, g: fa._flash_bwd(*static, res, g)[1:])
+
+    def host_ms(fn, *a):
+        jax.block_until_ready(fn(*a))
+        t0 = time.perf_counter()
+        for _ in range(args.iters):
+            out = fn(*a)
+        jax.block_until_ready(out)
+        return 1e3 * (time.perf_counter() - t0) / args.iters
+
+    _, res = fwd(q, k, v)
+    row = {"root": args.root, "set": args.set, "device": dev.device_kind,
+           "shape": [args.bh, args.t, args.d], "window": args.window,
+           "host_ms": {
+               "fwd": host_ms(fwd, q, k, v), "bwd": host_ms(bwd, res, g),
+               "dq": host_ms(dq_only, res, g),
+               "dkv": host_ms(dkv_only, res, g)}}
+
+    # Device time of each custom call, told apart by its result arity
+    # the way benchmark/kernels/flash_attention.py does.
+    from benchmark.lib import kernels, xplane
+    with tempfile.TemporaryDirectory(prefix="flash_trace_") as trace_dir:
+        with jax.profiler.trace(trace_dir):
+            for _ in range(5):
+                _, res = fwd(q, k, v)
+                out = bwd(res, g)
+            jax.block_until_ready(out)
+        reduced = xplane.load(trace_dir)
+    by_kind = collections.defaultdict(list)
+    other = collections.defaultdict(float)
+    for name, _, dur in next(iter(reduced["devices"].values())):
+        parsed = (kernels.parse_call(name) if "tpu_custom_call" in name
+                  else None)
+        if parsed:
+            kind = {3: "fwd", 1: "dq", 2: "dkv"}.get(len(parsed[0]), "?")
+            by_kind[kind].append(dur / 1e6)
+        else:
+            other[name.split(" = ")[0][:40]] += dur / 1e6 / 5
+    row["kernel_ms"] = {kind: round(float(np.median(ms)), 4)
+                        for kind, ms in sorted(by_kind.items())}
+    row["glue_ms_per_fwd_bwd"] = {
+        n: round(ms, 4) for n, ms in sorted(other.items(),
+                                            key=lambda kv: -kv[1])[:8]}
+    print(json.dumps(row), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())
