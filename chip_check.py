@@ -8,7 +8,8 @@ shapes the two flagship models use at full width.
 
 On the chip every kernel is jitted with ``interpret=False`` and compared,
 forward and gradient, against its reference (``_attention_ref``,
-``_partial_ref``, ``_group_norm_ref``) evaluated in float32 at highest
+``_partial_ref``, ``_group_norm_ref``, one matmul per group for the
+grouped matmul) evaluated in float32 at highest
 matmul precision on the same bf16 values; then the full ResNet-50
 (batch 128, bf16) takes two ``CollectiveTrainer`` steps with the default
 ``ELASTICDL_FUSED_GN=auto``, and two more with the model's GroupNorm
@@ -33,6 +34,7 @@ import numpy as np
 
 from elasticdl_tpu.ops import flash_attention as fa
 from elasticdl_tpu.ops import group_norm as gn
+from elasticdl_tpu.ops import grouped_matmul as gm
 from elasticdl_tpu.utils.device import device_report, place_compile_cache
 
 # Errors are taken relative to the reference's largest value, so a
@@ -50,6 +52,9 @@ TOLERANCES = {
     "fwd": _BF16_FWD, "o": _BF16_FWD,
     "dq": _BF16_GRAD, "dk": _BF16_GRAD, "dv": _BF16_GRAD,
     "dx": _BF16_GRAD,
+    # grouped matmul: bf16 results of float32 sums over the same bf16
+    # values, so one rounding of the result is all that may differ.
+    "dlhs": _BF16_FWD, "drhs": _BF16_FWD,
     "dscale": _F32, "dbias": _F32, "lse": _F32,
 }
 # ResNet step.  The head is zero-initialised, so the first loss is
@@ -126,6 +131,47 @@ def check_flash(b, h, t, d, window, interpret, ref_slice=(1, 2)):
                                           window=window),
         loss, _qkv(b, h, t, d, seed=t + d + window), ref_slice)
     return {"fwd": _rel_err(out[:sb, :sh], want), **errs}
+
+
+def check_grouped_matmul(rows, k, n, groups, skew, interpret):
+    """``grouped_matmul`` forward and both gradients against one plain
+    matmul per group on the same bf16 values, float32 at the highest
+    precision.  ``skew``: ``zipf`` (shares ~ rank ** -1.1, no group a
+    tile multiple) or ``empty`` (every other group empty, one holding
+    half the rows)."""
+    rng = np.random.RandomState(rows + k + groups)
+    if skew == "zipf":
+        share = np.arange(1, groups + 1, dtype=np.float64) ** -1.1
+    else:
+        share = np.where(np.arange(groups) % 2, 1.0, 0.0)
+        share[1] = share.sum()
+    sizes = np.floor(rng.permutation(share / share.sum()) * rows).astype(
+        np.int32)
+    sizes[np.argmax(sizes)] += rows - sizes.sum()
+    lhs, cot = (jnp.asarray(rng.randn(rows, w), jnp.bfloat16)
+                for w in (k, n))
+    rhs = jnp.asarray(rng.randn(groups, k, n) * k ** -0.5, jnp.bfloat16)
+    starts = np.concatenate([[0], np.cumsum(sizes)])
+
+    def kernel(lhs, rhs):
+        return gm.grouped_matmul(lhs, rhs, jnp.asarray(sizes),
+                                 interpret=interpret)
+
+    def reference(lhs, rhs):
+        return jnp.concatenate([
+            lhs[starts[g]:starts[g + 1]] @ rhs[g] for g in range(groups)])
+
+    loss = lambda fn: lambda lhs, rhs, cot: (
+        fn(lhs, rhs).astype(jnp.float32) * cot.astype(jnp.float32)).sum()
+    out = jax.jit(kernel)(lhs, rhs)
+    grads = jax.jit(jax.grad(loss(kernel), argnums=(0, 1)))(lhs, rhs, cot)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(reference)(*_f32(lhs, rhs))
+        want_grads = jax.jit(jax.grad(loss(reference), argnums=(0, 1)))(
+            *_f32(lhs, rhs, cot))
+    return {"fwd": _rel_err(out, want),
+            "dlhs": _rel_err(grads[0], want_grads[0]),
+            "drhs": _rel_err(grads[1], want_grads[1])}
 
 
 def check_flash_partial(b, h, t, d, causal, interpret, ref_slice=(1, 2)):
@@ -303,6 +349,15 @@ def _cases(tiny):
         yield ("flash_partial/B%d.H%d.T%d.D64.causal%d" % (b, h, t, causal),
                lambda causal=causal: check_flash_partial(
                    b, h, t, 64, causal, interpret))
+    # OLMoE's expert block (benchmark/configs/olmoe1b7b.json): 4 x 4096
+    # tokens x 8 choices sorted over 64 experts, up- and down-projection.
+    rows, hidden, width, experts = (
+        (512, 128, 256, 8) if tiny else (131072, 2048, 1024, 64))
+    for k, n, skew in ((hidden, width, "zipf"), (width, hidden, "empty")):
+        yield ("grouped_matmul/M%d.K%d.N%d.X%d.%s"
+               % (rows, k, n, experts, skew),
+               lambda k=k, n=n, skew=skew: check_grouped_matmul(
+                   rows, k, n, experts, skew, interpret))
     variant, image_size, batch, classes = resnet_args
     for hw, c, groups, relu in resnet_group_norm_shapes(
             variant, image_size, batch):

@@ -37,6 +37,12 @@ class ModelSpec:
     # of the reference's Keras-optimizer -> Go-PS-flags mapping
     # (model_utils.py:227-254).
     ps_optimizer: tuple = ("sgd", "learning_rate=0.1")
+    # Optional: ``outputs -> {name: array}`` of small additive per-step
+    # statistics taken from the training ``apply_fn``'s outputs (an
+    # MoE's router load).  CollectiveTrainer carries them out of the
+    # step beside the loss (summed over accumulation microbatches) as
+    # ``last_step_stats``; None adds nothing to the step program.
+    step_stats_fn: typing.Callable = None
 
 
 def load_model_spec(module_name, model_params="", **kwargs):

@@ -19,6 +19,7 @@ RoPE positions, pre-norm RMSNorm, SwiGLU MLP.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -41,13 +42,22 @@ class TransformerConfig:
     max_seq_len: int = 2048
     dtype: str = "bfloat16"
     tied_embeddings: bool = True
-    # Mixture-of-experts: 0 = dense FFN; >0 = top-k routing with experts
-    # sharded over the ``ep`` mesh axis and a Switch-style auxiliary
-    # load-balance loss (weight ``moe_aux_weight``) to stop router
-    # collapse.
+    # Width of the MLP (of one expert, in an MoE): 0 = dim * mlp_ratio.
+    ffn_dim: int = 0
+    # RMSNorm's epsilon, and a learned RMSNorm over the whole q and k
+    # projections before the split into heads (OLMoE).
+    norm_eps: float = 1e-6
+    qk_norm: bool = False
+    # Mixture-of-experts: 0 = dense FFN; >0 = dropless top-k routing
+    # (every chosen expert computes every token that chose it, whatever
+    # the routing) with experts sharded over the ``ep`` mesh axis and an
+    # auxiliary load-balance loss over all k choices (weight
+    # ``moe_aux_weight``) to stop router collapse.  ``moe_norm_topk``
+    # renormalizes the k > 1 chosen gates to sum to 1 (GShard); False
+    # keeps the softmax's own values (OLMoE's ``norm_topk_prob``).
     moe_experts: int = 0
     moe_top_k: int = 2
-    moe_capacity_factor: float = 2.0
+    moe_norm_topk: bool = True
     moe_aux_weight: float = 0.01
     # Rematerialize each scanned layer in the backward pass instead of
     # saving its activations — O(1)-layers activation memory for ~1/3
@@ -91,7 +101,7 @@ class TransformerConfig:
 
     @property
     def mlp_dim(self):
-        return self.dim * self.mlp_ratio
+        return self.ffn_dim or self.dim * self.mlp_ratio
 
 
 # -- parameters --------------------------------------------------------------
@@ -121,6 +131,9 @@ def init_params(rng, cfg):
         "wo": dense_init(keys[3], L, H * D, E),
         "ln2": norm_init(L, E),
     }
+    if cfg.qk_norm:
+        layers["q_norm"] = norm_init(L, H * D)
+        layers["k_norm"] = norm_init(L, G * D)
     if cfg.moe_experts:
         X = cfg.moe_experts
         layers["w_router"] = dense_init(keys[4], L, E, X, scale=0.02)
@@ -154,6 +167,9 @@ def param_specs(cfg):
         "wo": P("pp", "tp", None),
         "ln2": P("pp", None),
     }
+    if cfg.qk_norm:
+        layers["q_norm"] = P("pp", "tp")
+        layers["k_norm"] = P("pp", "tp")
     if cfg.moe_experts:
         layers["w_router"] = P("pp", None, None)
         layers["w_gate"] = P("pp", "ep", None, "tp")
@@ -185,10 +201,10 @@ def shard_params(params, mesh, cfg):
 # -- forward ------------------------------------------------------------------
 
 
-def _rmsnorm(x, scale):
+def _rmsnorm(x, scale, eps=1e-6):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
                    keepdims=True)
-    return (x * jax.lax.rsqrt(var + 1e-6)).astype(x.dtype) * scale
+    return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 
 def _rope(x, positions):
@@ -208,83 +224,133 @@ def _rope(x, positions):
     return rotated.astype(x.dtype)
 
 
-def _moe_ffn(h, w, cfg, mesh):
-    """Top-k MoE FFN (expert weights sharded over ``ep``).
-
-    Dense dispatch/combine einsum formulation (Mesh-TensorFlow style):
-    per-sequence expert capacity bounds compute; overflow tokens fall to
-    lower-priority choices or the residual.  Returns (out, aux) where
-    aux is the Switch-Transformer load-balance loss
-    X * sum_x fraction_top1(x) * mean_prob(x) — 1.0 at perfect balance,
-    approaching X under router collapse — so minimizing it pushes the
-    router toward uniform utilization.
-    """
-    B, T, E = h.shape
-    X = cfg.moe_experts
-    K = min(cfg.moe_top_k, X)
-    # K choices per token -> expected per-expert load is K*T/X.
-    capacity = max(
-        1, min(T, int(T * K * cfg.moe_capacity_factor / X) + 1)
-    )
-    logits = h @ w["w_router"].astype(h.dtype)            # [B,T,X]
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-
-    # Switch aux loss from the top-1 assignment (computed before
-    # capacity so it reflects router intent, not dispatch truncation).
-    # frac/mean_probs are the LINEAR sufficient statistics — callers
-    # that accumulate across microbatches (the pipeline) combine them
-    # at the end for the exact full-batch aux.
-    top1 = jax.nn.one_hot(jnp.argmax(probs, axis=-1), X,
-                          dtype=jnp.float32)
-    frac_tokens = top1.mean(axis=(0, 1))                  # [X]
-    mean_probs = probs.mean(axis=(0, 1))                  # [X]
-    stats = jnp.stack([frac_tokens, mean_probs])          # [2, X]
-    aux = X * jnp.sum(frac_tokens * mean_probs)
-
-    gate_vals, experts = jax.lax.top_k(probs, K)          # [B,T,K]
-    if K > 1:
+def moe_route(h, w_router, cfg):
+    """(probs [B, T, X] float32, gates [B, T, K] float32, experts
+    [B, T, K] int32).  The router's matmul and softmax run in float32
+    at the highest precision whatever the compute dtype (X*E
+    multiply-adds a token), so that near-ties alone can change which
+    experts a token gets."""
+    logits = jnp.einsum(
+        "bte,ex->btx", h.astype(jnp.float32),
+        w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, experts = jax.lax.top_k(
+        probs, min(cfg.moe_top_k, cfg.moe_experts))
+    if cfg.moe_norm_topk and gates.shape[-1] > 1:
         # GShard-style renormalization over the chosen experts.  Top-1
         # keeps the raw p_top1 gate (Switch): renormalizing would make
         # it identically 1.0 and cut the router out of the task loss.
-        gate_vals = gate_vals / jnp.maximum(
-            gate_vals.sum(axis=-1, keepdims=True), 1e-9
-        )
+        gates = gates / jnp.maximum(
+            gates.sum(axis=-1, keepdims=True), 1e-9)
+    return probs, gates, experts
 
-    # Per-expert capacity slots: choice 0 has priority; choice j's
-    # positions start after all previous choices' tokens for that expert.
-    onehots = [
-        jax.nn.one_hot(experts[..., j], X, dtype=jnp.float32)
-        for j in range(K)
-    ]
-    disp = 0.0      # 0/1 dispatch  [B,T,X,C]
-    combine = 0.0   # gate-weighted combine  [B,T,X,C]
-    offset = jnp.zeros((B, 1, X), jnp.float32)
-    for j in range(K):
-        pos = jnp.cumsum(onehots[j], axis=1) - 1.0 + offset   # [B,T,X]
-        keep = onehots[j] * (pos < capacity)
-        slot = keep[..., None] * jax.nn.one_hot(
-            jnp.clip(pos, 0, capacity - 1).astype(jnp.int32),
-            capacity, dtype=jnp.float32,
-        )
-        disp = disp + slot
-        combine = combine + gate_vals[..., j, None, None] * slot
-        offset = offset + onehots[j].sum(axis=1, keepdims=True)
-    if mesh is not None:
-        disp = _constrain(disp, mesh, P("dp", "sp", "ep", None))
-        combine = _constrain(combine, mesh, P("dp", "sp", "ep", None))
-    xin = jnp.einsum("btxc,bte->xbce", disp, h.astype(jnp.float32))
-    xin = xin.astype(h.dtype)
-    if mesh is not None:
-        xin = _constrain(xin, mesh, P("ep", "dp", None, None))
-    g = jax.nn.silu(
-        jnp.einsum("xbce,xef->xbcf", xin, w["w_gate"].astype(h.dtype))
-    )
-    u = jnp.einsum("xbce,xef->xbcf", xin, w["w_up"].astype(h.dtype))
-    y = jnp.einsum("xbcf,xfe->xbce", g * u,
-                   w["w_down"].astype(h.dtype))
-    out = jnp.einsum("btxc,xbce->bte", combine,
-                     y.astype(jnp.float32))
-    return out.astype(h.dtype), aux, stats
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _take_rows(k, x, order, inverse):
+    """``x[order // k]``: row i of the result is the row of x that
+    ``order[i]`` of the n * k (row, choice) assignments claims, and
+    ``inverse`` undoes ``order``, so the pullback is a gather and a sum
+    over a row's k claims, not a scatter-add.  With k = 1 it permutes
+    rows, by gathers both ways."""
+    return x[order // k]
+
+
+def _take_rows_bwd(k, inverse, g):
+    claims = g[inverse].reshape(-1, k, g.shape[-1])
+    return claims.astype(jnp.float32).sum(axis=1).astype(g.dtype), None, None
+
+
+_take_rows.defvjp(
+    lambda k, x, order, inverse: (x[order // k], inverse), _take_rows_bwd)
+
+
+def _moe_experts(h, gates, experts, w_gate, w_up, w_down, kernel):
+    """The routed FFN of the rows this device holds: sort the n * K
+    (token, choice) assignments by expert, gather their rows, three
+    grouped matmuls, un-sort and sum each token's K results weighted by
+    its gates.  O(n * K * width) memory, no capacity, nothing dropped.
+    Returns (out [b, T, E], load [1, X + 1]: rows per expert, then the
+    rows the grouped matmul computes beyond the real ones)."""
+    from elasticdl_tpu.ops import grouped_matmul as gm
+
+    b, t, e = h.shape
+    x, k = w_gate.shape[0], experts.shape[-1]
+    n, rows = b * t, b * t * k
+    if kernel != "interpret":
+        announce_dispatch(n, x, k, kernel)
+    flat = experts.reshape(rows)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    inverse = jnp.argsort(order).astype(jnp.int32)
+    sizes = (flat[:, None] == jnp.arange(x, dtype=flat.dtype)).sum(
+        axis=0, dtype=jnp.int32)
+    if kernel:
+        matmul = functools.partial(gm.grouped_matmul,
+                                   interpret=kernel == "interpret")
+        padded = gm.padded_rows(sizes, rows)
+    else:
+        matmul, padded = gm.grouped_matmul_ref, jnp.int32(0)
+    xs = _take_rows(k, h.reshape(n, e), order, inverse)
+    act = jax.nn.silu(matmul(xs, w_gate, sizes)) * matmul(xs, w_up, sizes)
+    ys = _take_rows(1, matmul(act, w_down, sizes), inverse, order)
+    out = jnp.einsum("nke,nk->ne", ys.reshape(n, k, e).astype(jnp.float32),
+                     gates.reshape(n, k))
+    load = jnp.concatenate([sizes, padded.reshape(1)])[None]
+    return out.astype(h.dtype).reshape(b, t, e), load
+
+
+@functools.lru_cache(maxsize=None)
+def announce_dispatch(tokens, experts, top_k, kernel):
+    """Once per compiled shape, by the logger ``announce_tiles`` uses:
+    what the dispatch hands the grouped matmul (of one shard of the
+    trainer's data axis, where there is one)."""
+    from elasticdl_tpu.ops import flash_attention, grouped_matmul as gm
+
+    rows = tokens * top_k
+    tile = gm.row_tile(rows)
+    flash_attention.logger.info(
+        "moe dispatch: tokens=%d experts=%d top_k=%d rows=%d row_tile=%d "
+        "groups_tiles<=%d kernel=%s", tokens, experts, top_k, rows, tile,
+        -(-rows // tile) + experts - 1, kernel or "off")
+
+
+def _moe_ffn(h, w, cfg, mesh, mode=None):
+    """Dropless top-k MoE FFN (expert weights sharded over ``ep``).
+
+    One dispatch (:func:`_moe_experts`) with two ways to multiply,
+    chosen by where the code runs: the Pallas grouped matmul, per shard
+    of the trainer's data axis, where ``flash_mode()`` allows a kernel;
+    ``lax.ragged_dot`` under a model-parallel mesh and everywhere else.
+
+    Returns (out, aux, stats, load).  ``aux`` is the load-balance loss
+    over all K choices, X * sum_x assigned(x) * mean_prob(x) with
+    ``assigned(x)`` the assignments to x over the tokens: K at perfect
+    balance, approaching X under router collapse.  ``stats`` [2, X] are
+    its LINEAR sufficient statistics (assigned, mean_prob): callers
+    that accumulate across microbatches (the pipeline) combine them at
+    the end for the exact full-batch aux.  ``load`` [X + 1] float32:
+    assignments per expert, and the grouped matmul's padded rows.
+    """
+    from elasticdl_tpu.ops.batch_shard import per_batch_shard
+    from elasticdl_tpu.ops.flash_attention import flash_mode
+
+    B, T = h.shape[:2]
+    X = cfg.moe_experts
+    if mode is None:
+        mode = flash_mode()
+    kernel = mode if mesh is None and mode in ("tpu", "interpret") else ""
+    probs, gates, experts = moe_route(h, w["w_router"], cfg)
+    weights = tuple(w[name].astype(h.dtype)
+                    for name in ("w_gate", "w_up", "w_down"))
+    fn = functools.partial(_moe_experts, kernel=kernel)
+    if kernel:
+        out, load = per_batch_shard(fn, (h, gates, experts), weights)
+    else:
+        out, load = fn(h, gates, experts, *weights)
+    load = load.sum(axis=0).astype(jnp.float32)
+    stats = jnp.stack([load[:X] / (B * T), probs.mean(axis=(0, 1))])
+    aux = X * jnp.sum(stats[0] * stats[1])
+    return out, aux, stats, load
 
 
 def _constrain(x, mesh, spec):
@@ -295,26 +361,52 @@ def _constrain(x, mesh, spec):
     return x
 
 
-def _layer_body(x, w, cfg, mesh, positions, attention_mode=None,
-                moe_stats=False, return_kv=False):
-    """One transformer block; shared by the scanned stack (forward) and
-    the per-stage slice scan (forward_pipelined).  ``moe_stats`` swaps
-    the scalar aux for the linear [2, X] router statistics (pipeline
-    accumulation).  ``return_kv`` additionally returns this layer's
-    post-RoPE, pre-GQA-expand (k, v) [B, T, G, D] — the decode prefill
-    captures them into the KV cache."""
+def _project_qkv(h, w, cfg, positions):
+    """q [B, T, H, D], k and v [B, T, G, D] of the normed input, RoPE
+    applied to q and k (after the QK norm where the model has one)."""
+    compute_dtype = jnp.dtype(cfg.dtype)
+    B, T = h.shape[0], h.shape[1]
+    H, D, G = cfg.num_heads, cfg.head_dim, cfg.kv_heads
+    q = h @ w["wq"].astype(compute_dtype)
+    if cfg.qk_norm:
+        q = _rmsnorm(q, w["q_norm"].astype(compute_dtype), cfg.norm_eps)
+    q = q.reshape(B, T, H, D)
+    k = h @ w["wk"].astype(compute_dtype)
+    if cfg.qk_norm:
+        k = _rmsnorm(k, w["k_norm"].astype(compute_dtype), cfg.norm_eps)
+    k = k.reshape(B, T, G, D)
+    v = (h @ w["wv"].astype(compute_dtype)).reshape(B, T, G, D)
+    return _rope(q, positions), _rope(k, positions), v
+
+
+def _ffn(x, w, cfg, mesh, mode=None):
+    """x + FFN(norm(x)) -> (x, aux, stats, load); the last three are
+    the MoE's (:func:`_moe_ffn`), zeros and None for a dense FFN."""
+    compute_dtype = jnp.dtype(cfg.dtype)
+    act_spec = P("dp", "sp", None)
+    h = _rmsnorm(x, w["ln2"].astype(compute_dtype), cfg.norm_eps)
+    if cfg.moe_experts:
+        out, aux, stats, load = _moe_ffn(h, w, cfg, mesh, mode)
+        return x + _constrain(out, mesh, act_spec), aux, stats, load
+    gate = jax.nn.silu(h @ w["w_gate"].astype(compute_dtype))
+    up = h @ w["w_up"].astype(compute_dtype)
+    x = x + _constrain(
+        (gate * up) @ w["w_down"].astype(compute_dtype), mesh, act_spec,
+    )
+    return x, jnp.float32(0.0), None, None
+
+
+def _attention(x, w, cfg, mesh, positions, attention_mode=None):
+    """x + Attention(norm(x)) -> (x, (k, v)): k, v post-RoPE and
+    pre-GQA-expand, [B, T, G, D]."""
     compute_dtype = jnp.dtype(cfg.dtype)
     act_spec = P("dp", "sp", None)
     B, T = x.shape[0], x.shape[1]
     H, D = cfg.num_heads, cfg.head_dim
     G = cfg.kv_heads
-    h = _rmsnorm(x, w["ln1"].astype(compute_dtype))
-    q = (h @ w["wq"].astype(compute_dtype)).reshape(B, T, H, D)
-    k = (h @ w["wk"].astype(compute_dtype)).reshape(B, T, G, D)
-    v = (h @ w["wv"].astype(compute_dtype)).reshape(B, T, G, D)
-    q = _rope(q, positions)
-    k = _rope(k, positions)
-    kv_out = (k, v) if return_kv else None
+    h = _rmsnorm(x, w["ln1"].astype(compute_dtype), cfg.norm_eps)
+    q, k, v = _project_qkv(h, w, cfg, positions)
+    kv_out = (k, v)
     if G != H:
         # GQA: expand K/V to the full head count for the (unchanged)
         # attention kernels.  jnp.repeat keeps group order consecutive,
@@ -349,23 +441,27 @@ def _layer_body(x, w, cfg, mesh, positions, attention_mode=None,
     from jax.ad_checkpoint import checkpoint_name
 
     attn = checkpoint_name(attn, "attn_out")
-    x = x + _constrain(
+    return x + _constrain(
         attn @ w["wo"].astype(compute_dtype), mesh, act_spec
-    )
-    h = _rmsnorm(x, w["ln2"].astype(compute_dtype))
-    if cfg.moe_experts:
-        moe_out, aux, stats = _moe_ffn(h, w, cfg, mesh)
-        x = x + _constrain(moe_out, mesh, act_spec)
-        if moe_stats:
-            return (x, (stats, kv_out)) if return_kv else (x, stats)
-    else:
-        gate = jax.nn.silu(h @ w["w_gate"].astype(compute_dtype))
-        up = h @ w["w_up"].astype(compute_dtype)
-        x = x + _constrain(
-            (gate * up) @ w["w_down"].astype(compute_dtype), mesh,
-            act_spec,
-        )
-        aux = jnp.float32(0.0)
+    ), kv_out
+
+
+def _layer_body(x, w, cfg, mesh, positions, attention_mode=None,
+                moe_stats=False, return_kv=False, moe_load=False):
+    """One transformer block; shared by the scanned stack (forward) and
+    the per-stage slice scan (forward_pipelined).  ``moe_stats`` swaps
+    the scalar aux for the linear [2, X] router statistics (pipeline
+    accumulation); ``moe_load`` returns (aux, load [X + 1]) for an MoE
+    (the step statistics).  ``return_kv`` additionally returns this
+    layer's (k, v) — the decode prefill captures them into the KV
+    cache.  ``attention_mode`` also chooses how the MoE multiplies
+    (``"off"``: no Pallas call)."""
+    x, kv_out = _attention(x, w, cfg, mesh, positions, attention_mode)
+    x, aux, stats, load = _ffn(x, w, cfg, mesh, attention_mode)
+    if moe_stats and cfg.moe_experts:
+        aux = stats
+    elif moe_load and cfg.moe_experts:
+        aux = (aux, load)
     if return_kv:
         return x, (aux, kv_out)
     return x, aux
@@ -373,16 +469,17 @@ def _layer_body(x, w, cfg, mesh, positions, attention_mode=None,
 
 def _head(params, x, cfg):
     compute_dtype = jnp.dtype(cfg.dtype)
-    x = _rmsnorm(x, params["ln_f"].astype(compute_dtype))
+    x = _rmsnorm(x, params["ln_f"].astype(compute_dtype), cfg.norm_eps)
     head = (
         params["embed"].T if cfg.tied_embeddings else params["lm_head"]
     ).astype(compute_dtype)
     return (x @ head).astype(jnp.float32)
 
 
-def forward_hidden(params, tokens, cfg, mesh=None):
+def forward_hidden(params, tokens, cfg, mesh=None, return_load=False):
     """tokens: [B, T] int32 -> (final hidden [B, T, dim] BEFORE the
-    ln_f/head, mean per-layer MoE aux).
+    ln_f/head, mean per-layer MoE aux); with ``return_load`` (an MoE)
+    also each layer's load [L, X + 1] (:func:`_moe_ffn`).
 
     Pair with :func:`next_token_loss_chunked` to train without ever
     materializing the [B, T, V] logits tensor (at the flagship config
@@ -396,8 +493,10 @@ def forward_hidden(params, tokens, cfg, mesh=None):
     x = _constrain(x, mesh, act_spec)
     positions = jnp.arange(tokens.shape[1])
 
+    with_load = bool(return_load and cfg.moe_experts)
+
     def layer(x, w):
-        return _layer_body(x, w, cfg, mesh, positions)
+        return _layer_body(x, w, cfg, mesh, positions, moe_load=with_load)
 
     if cfg.remat == "dots":
         layer = jax.checkpoint(
@@ -414,6 +513,9 @@ def forward_hidden(params, tokens, cfg, mesh=None):
     elif cfg.remat:
         layer = jax.checkpoint(layer)
     x, aux_per_layer = jax.lax.scan(layer, x, params["layers"])
+    if with_load:
+        aux_per_layer, load = aux_per_layer
+        return x, aux_per_layer.mean(), load
     return x, aux_per_layer.mean()
 
 
@@ -439,8 +541,9 @@ def forward_pipelined(params, tokens, cfg, mesh, num_microbatches,
     S = mesh.shape['pp'] stages compute concurrently on different
     microbatches, activations hopping stages via ppermute.  Bubble
     fraction is (S-1)/(M+S-1) — S=2, M=8 -> 11.1%.  With ``return_aux``
-    the MoE load-balance loss equals the EXACT full-batch Switch
-    statistic: stages accumulate the linear per-expert (frac, prob)
+    the MoE load-balance loss equals the EXACT full-batch statistic
+    (all K choices counted): stages accumulate the linear per-expert
+    (assigned, prob)
     sufficient statistics over real ticks (bubbles masked) and combine
     them after the loop, so the objective is identical to the scanned
     forward's and independent of the microbatch count.  Embedding
@@ -484,8 +587,8 @@ def forward_pipelined(params, tokens, cfg, mesh, num_microbatches,
     def finalize(stats, num_mb):
         # stats: [L_stage, 2, X] SUMS of per-microbatch (frac, prob)
         # means.  /M gives the full-batch means (equal microbatch
-        # sizes), so this stage's layers contribute their EXACT Switch
-        # aux — no dependence on M.
+        # sizes), so this stage's layers contribute their EXACT aux —
+        # no dependence on M.
         f = stats[:, 0] / num_mb
         p = stats[:, 1] / num_mb
         return (cfg.moe_experts * (f * p).sum(-1)).sum()
@@ -542,12 +645,8 @@ def _decode_layer(x, w, cfg, ck, cv, pos):
     H, D, G = cfg.num_heads, cfg.head_dim, cfg.kv_heads
     R = H // G
     positions = jnp.reshape(pos, (1,))
-    h = _rmsnorm(x, w["ln1"].astype(compute_dtype))
-    q = _rope((h @ w["wq"].astype(compute_dtype)).reshape(B, 1, H, D),
-              positions)
-    k = _rope((h @ w["wk"].astype(compute_dtype)).reshape(B, 1, G, D),
-              positions)
-    v = (h @ w["wv"].astype(compute_dtype)).reshape(B, 1, G, D)
+    h = _rmsnorm(x, w["ln1"].astype(compute_dtype), cfg.norm_eps)
+    q, k, v = _project_qkv(h, w, cfg, positions)
     ck = jax.lax.dynamic_update_slice(
         ck, k.astype(ck.dtype), (0, pos, 0, 0))
     cv = jax.lax.dynamic_update_slice(
@@ -568,14 +667,7 @@ def _decode_layer(x, w, cfg, ck, cv, pos):
     ).reshape(B, 1, H * D).astype(compute_dtype)
     x = x + attn @ w["wo"].astype(compute_dtype)
 
-    h = _rmsnorm(x, w["ln2"].astype(compute_dtype))
-    if cfg.moe_experts:
-        moe_out, _aux, _stats = _moe_ffn(h, w, cfg, None)
-        x = x + moe_out
-    else:
-        gate = jax.nn.silu(h @ w["w_gate"].astype(compute_dtype))
-        up = h @ w["w_up"].astype(compute_dtype)
-        x = x + (gate * up) @ w["w_down"].astype(compute_dtype)
+    x = _ffn(x, w, cfg, None)[0]
     return x, ck, cv
 
 
@@ -736,21 +828,40 @@ def next_token_loss_chunked(params, hidden, tokens, cfg, chunk=512):
 # -- zoo contract -------------------------------------------------------------
 
 
+def _flag(name, value):
+    """CLI model_params arrive as strings: "false" is not falsy."""
+    if isinstance(value, str):
+        parsed = {"true": True, "false": False}.get(value.strip().lower())
+        if parsed is None:
+            raise ValueError("%s must be true or false; got %r"
+                             % (name, value))
+        return parsed
+    return bool(value)
+
+
 def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
                seq_len=512, learning_rate=3e-4, mesh=None, dtype="bfloat16",
                pipeline_microbatches=0, moe_experts=0, moe_top_k=2,
                moe_aux_weight=0.01, remat=False, attention_impl="ring",
-               window=0, xent_chunk=0, num_kv_heads=0):
+               window=0, xent_chunk=0, num_kv_heads=0, ffn_dim=0,
+               norm_eps=1e-6, qk_norm=False, moe_norm_topk=True,
+               tied_embeddings=True):
     """Zoo entry for the flagship LM.
 
     ``remat`` (False | True | "dots" | "attn"), ``attention_impl``
     ("ring" | "ulysses"), ``window`` (sliding-window causal, 0 = full),
-    and ``num_kv_heads`` (grouped-query attention: 0 = MHA, G > 0
-    shares each K/V head across num_heads/G query heads) pass through
+    ``num_kv_heads`` (grouped-query attention: 0 = MHA, G > 0
+    shares each K/V head across num_heads/G query heads), ``ffn_dim``
+    (MLP or expert width, 0 = 4 * dim), ``norm_eps``, ``qk_norm``,
+    ``moe_norm_topk`` and ``tied_embeddings`` pass through
     to :class:`TransformerConfig`.  ``xent_chunk`` > 0 computes the
     loss via :func:`next_token_loss_chunked` — no [B, T, V] logits
     tensor, the memory-lean path for large vocab x seq (numerically
     identical, tested).
+
+    Training an MoE through the scanned stack, the spec also hands the
+    trainer its step statistics (``step_stats_fn``): each layer's
+    assignments per expert and padded rows, ``moe_load`` [L, X + 1].
     """
     cfg = TransformerConfig(
         vocab_size=vocab_size, dim=dim, num_heads=num_heads,
@@ -758,7 +869,10 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
         moe_experts=moe_experts, moe_top_k=moe_top_k,
         moe_aux_weight=moe_aux_weight, remat=remat,
         attention_impl=attention_impl, window=window,
-        num_kv_heads=num_kv_heads,
+        num_kv_heads=num_kv_heads, ffn_dim=ffn_dim,
+        norm_eps=float(norm_eps), qk_norm=_flag("qk_norm", qk_norm),
+        moe_norm_topk=_flag("moe_norm_topk", moe_norm_topk),
+        tied_embeddings=_flag("tied_embeddings", tied_embeddings),
     )
     cfg.kv_heads  # validate num_heads % num_kv_heads at spec build
     pipelined = (
@@ -800,51 +914,49 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
             params = shard_params(params, mesh, cfg)
         return params
 
+    moe = bool(cfg.moe_experts)
+
     def apply_fn(params, tokens, train):
-        if pipelined:
-            if xent_chunk and train:
-                hidden, aux = forward_pipelined(
+        """Logits; training an MoE or with ``xent_chunk``, a dict for
+        ``loss_fn``: ``logits`` or (``hidden``, ``params``: the head
+        runs inside the chunked loss, no [B, T, V] tensor), ``aux``
+        and, from the scanned stack, ``moe_load``."""
+        hidden_only = bool(xent_chunk and train)
+        if not (hidden_only or (moe and train)):
+            if pipelined:
+                return forward_pipelined(
                     params, tokens, cfg, mesh, pipeline_microbatches,
-                    remat=bool(cfg.remat), return_aux=True,
-                    return_hidden=True,
-                )
-                return ("hidden", hidden, aux, params)
-            return forward_pipelined(
+                    remat=bool(cfg.remat))
+            return forward(params, tokens, cfg, mesh=mesh)
+        out = {}
+        if pipelined:
+            x, out["aux"] = forward_pipelined(
                 params, tokens, cfg, mesh, pipeline_microbatches,
-                remat=bool(cfg.remat),
-                return_aux=bool(cfg.moe_experts and train),
-            )
-        if xent_chunk and train:
-            # Memory-lean loss path: hand the final hidden states (and
-            # the params, for the head matmul inside the chunked loss)
-            # to loss_fn instead of materializing [B, T, V] logits.
-            hidden, aux = forward_hidden(params, tokens, cfg, mesh=mesh)
-            return ("hidden", hidden, aux, params)
-        if cfg.moe_experts and train:
-            return forward(params, tokens, cfg, mesh=mesh,
-                           return_aux=True)
-        return forward(params, tokens, cfg, mesh=mesh)
+                remat=bool(cfg.remat), return_aux=True,
+                return_hidden=True)
+        elif moe:
+            x, out["aux"], out["moe_load"] = forward_hidden(
+                params, tokens, cfg, mesh=mesh, return_load=True)
+        else:
+            x, out["aux"] = forward_hidden(params, tokens, cfg, mesh=mesh)
+        if hidden_only:
+            out["hidden"], out["params"] = x, params
+        else:
+            out["logits"] = _head(params, x, cfg)
+        return out
 
     def loss_fn(outputs, tokens):
-        if (
-            isinstance(outputs, tuple)
-            and len(outputs) == 4
-            and outputs[0] == "hidden"
-        ):
-            _, hidden, aux, params = outputs
+        if not isinstance(outputs, dict):
+            return next_token_loss(outputs, tokens)
+        if "hidden" in outputs:
             loss = next_token_loss_chunked(
-                params, hidden, tokens, cfg, chunk=xent_chunk
-            )
-            if cfg.moe_experts:
-                loss = loss + cfg.moe_aux_weight * aux
-            return loss
-        if isinstance(outputs, tuple):  # MoE training: (logits, aux)
-            logits, aux = outputs
-            return (
-                next_token_loss(logits, tokens)
-                + cfg.moe_aux_weight * aux
-            )
-        return next_token_loss(outputs, tokens)
+                outputs["params"], outputs["hidden"], tokens, cfg,
+                chunk=xent_chunk)
+        else:
+            loss = next_token_loss(outputs["logits"], tokens)
+        if moe:
+            loss = loss + cfg.moe_aux_weight * outputs["aux"]
+        return loss
 
     def feed(records):
         toks = np.stack(
@@ -863,6 +975,9 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
         eval_metrics_fn=lambda: {
             "nll": metrics.Mean(lambda outputs, labels: outputs)
         },
+        step_stats_fn=(
+            (lambda outputs: {"moe_load": outputs["moe_load"]})
+            if moe and not pipelined else None),
     )
     spec.config = cfg
     return spec

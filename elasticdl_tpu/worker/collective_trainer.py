@@ -168,6 +168,10 @@ class CollectiveTrainer(Trainer):
         self._ckpt_future = None
         self._export_future = None
         self._example_features = None
+        # The last per-step program's step statistics (the spec's
+        # ``step_stats_fn``), lazy like the loss it left the step with:
+        # fetched after that loss it costs no sync.  () without one.
+        self.last_step_stats = ()
 
         params = spec.init_fn(jax.random.PRNGKey(rng_seed))
         self._opt_state = spec.optimizer.init(params)
@@ -458,6 +462,7 @@ class CollectiveTrainer(Trainer):
     def _loss_and_grads(self, params, features, labels, weights):
         apply_fn = self._spec.apply_fn
         loss_fn = self._spec.loss_fn
+        stats_fn = getattr(self._spec, "step_stats_fn", None)
 
         def f(p):
             x = features
@@ -476,9 +481,13 @@ class CollectiveTrainer(Trainer):
             with batch_axis(self._mesh, self._data_axis):
                 out = apply_fn(p, x, True)
             per_example = loss_fn(out, labels).astype(jnp.float32)
-            return _masked_mean(per_example, weights)
+            # The spec's step statistics ride out as value_and_grad's
+            # aux: an empty tuple (no such spec) is no output at all.
+            return _masked_mean(per_example, weights), (
+                stats_fn(out) if stats_fn else ())
 
-        return jax.value_and_grad(f)(params)
+        (loss, stats), grads = jax.value_and_grad(f, has_aux=True)(params)
+        return loss, grads, stats
 
     def _zero1_apply(self, tx, params, opt_state, grads):
         """ZeRO-1 weight update: reduce-scatter(grads) -> shard-local
@@ -532,25 +541,28 @@ class CollectiveTrainer(Trainer):
 
         def step(params, opt_state, features, labels, weights):
             if accum == 1:
-                loss, grads = self._loss_and_grads(
+                loss, grads, stats = self._loss_and_grads(
                     params, features, labels, weights
                 )
             else:
                 def body(carry, microbatch):
                     acc_grads, acc_loss = carry
                     f, l, w = microbatch
-                    loss, grads = self._loss_and_grads(params, f, l, w)
+                    loss, grads, stats = self._loss_and_grads(
+                        params, f, l, w)
                     acc_grads = jax.tree_util.tree_map(
                         jnp.add, acc_grads, grads
                     )
-                    return (acc_grads, acc_loss + loss), None
+                    return (acc_grads, acc_loss + loss), stats
 
                 zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
-                (grads, loss_sum), _ = jax.lax.scan(
+                (grads, loss_sum), stats = jax.lax.scan(
                     body, (zeros, 0.0), (features, labels, weights)
                 )
                 grads = jax.tree_util.tree_map(lambda g: g / accum, grads)
                 loss = loss_sum / accum
+                stats = jax.tree_util.tree_map(
+                    lambda s: s.sum(axis=0), stats)
             if zero is not None:
                 params, opt_state = self._zero1_apply(
                     tx, params, opt_state, grads
@@ -558,9 +570,10 @@ class CollectiveTrainer(Trainer):
             else:
                 updates, opt_state = tx.update(grads, opt_state, params)
                 params = optax.apply_updates(params, updates)
-            return params, opt_state, loss
+            return params, opt_state, loss, stats
 
-        self._raw_step = step
+        # The fused windows chain steps and keep the losses alone.
+        self._raw_step = lambda *args: step(*args)[:3]
         if self._mesh is None:
             return jax.jit(step, donate_argnums=(0, 1))
         rep = self._replicated
@@ -580,7 +593,7 @@ class CollectiveTrainer(Trainer):
             step,
             in_shardings=(rep, opt_sharding, batch_in, batch_in,
                           weights_in),
-            out_shardings=(rep, opt_sharding, rep),
+            out_shardings=(rep, opt_sharding, rep, rep),
             donate_argnums=(0, 1),
         )
 
@@ -769,7 +782,8 @@ class CollectiveTrainer(Trainer):
         ``float(loss)``; that fetch is the fence."""
         prepared = self.prepare_batch(features, labels)
         with self.timing.timeit("step_dispatch"):
-            self._params, self._opt_state, loss = self._train_step(
+            (self._params, self._opt_state, loss,
+             self.last_step_stats) = self._train_step(
                 self._params, self._opt_state,
                 prepared.features, prepared.labels, prepared.weights,
             )
@@ -864,7 +878,8 @@ class CollectiveTrainer(Trainer):
         at the window boundary."""
         if staged.size == 1:
             with self.timing.timeit("step_dispatch"):
-                self._params, self._opt_state, losses = self._train_step(
+                (self._params, self._opt_state, losses,
+                 self.last_step_stats) = self._train_step(
                     self._params, self._opt_state,
                     staged.features, staged.labels, staged.weights,
                 )

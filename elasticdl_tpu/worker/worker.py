@@ -28,6 +28,24 @@ DEFAULT_MAX_MINIBATCH_RETRY_NUM = 64
 PREEMPTED_EXIT_CODE = 143
 
 
+def _log_step_stats(step, stats):
+    """One line per logged loss for what the step program handed back
+    beside it (``ModelSpec.step_stats_fn``).  Called after the loss was
+    fetched: the same program made both, so this fetch waits for
+    nothing.  ``moe_load`` [layers, experts + 1]: assignments per
+    expert, then the rows the grouped matmul computed beyond them."""
+    if not stats or "moe_load" not in stats:
+        return
+    import numpy as np
+
+    load = np.asarray(stats["moe_load"])
+    counts = load[:, :-1]
+    logger.info(
+        "moe load: step=%d layers=%d rows=%d max=%d mean=%.1f "
+        "padded_rows=%d", step, counts.shape[0], counts.sum(),
+        counts.max(), counts.mean(), load[:, -1].sum())
+
+
 class PreemptedExit(Exception):
     """Raised inside the task loop when a graceful-preemption stop was
     requested (SIGTERM): unwind cleanly after the current minibatch."""
@@ -352,6 +370,8 @@ class Worker:
                         "step %d loss %.6f (version %d)",
                         self._steps, loss_value, version,
                     )
+                    _log_step_stats(self._steps, getattr(
+                        self._trainer, "last_step_stats", None))
                 if self._step_throttle:
                     # Drill knob (step_throttle_secs): a DELIBERATE
                     # per-step slowdown so churn drills can stage a

@@ -167,3 +167,23 @@ def test_chip_check_tiny_mode_runs_the_checker(monkeypatch, capsys,
     assert chip_check.TOLERANCES["dbias"] == 1e-4 > errs["group_norm"]["dbias"]
     assert chip_check.TOLERANCES["lse"] == 1e-4 > errs["flash_partial"]["lse"]
     assert chip_check.main(["--tiny", "no_such_case"]) == 1
+
+
+def test_chip_check_tiny_mode_has_a_grouped_matmul_leg(monkeypatch, capsys,
+                                                       tmp_path):
+    """Skewed and empty groups, forward and both gradients, at the same
+    bf16 tolerances as on the chip."""
+    import json
+
+    monkeypatch.syspath_prepend(REPO)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("ELASTICDL_FUSED_GN", "interpret")
+    import chip_check
+
+    assert chip_check.main(["--tiny", "grouped_matmul"]) == 0
+    rows = [json.loads(line)
+            for line in capsys.readouterr().out.splitlines()
+            if line.startswith('{"case"')]
+    assert [r["case"].rsplit(".", 1)[1] for r in rows] == ["zipf", "empty"]
+    for row in rows:
+        assert set(row["errs"]) == {"fwd", "dlhs", "drhs"} and row["ok"]

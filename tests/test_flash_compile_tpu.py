@@ -1,5 +1,5 @@
-"""The flash-attention kernels through the TPU's own compiler, for a v5e
-that is described and not attached.
+"""The flash-attention and grouped-matmul kernels through the TPU's own
+compiler, for a v5e that is described and not attached.
 
 Interpret mode cannot see what Mosaic refuses (a slice off the tiling, a
 transpose it has no lowering for, more scoped VMEM than a kernel may
@@ -16,6 +16,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from elasticdl_tpu.ops import flash_attention as fa
+from elasticdl_tpu.ops import grouped_matmul as gm
 
 
 @pytest.fixture(scope="module")
@@ -66,3 +67,35 @@ def test_flash_partial_compiles_for_v5e(one_chip, causal):
             q, k, v, causal, 0.125, 128, 128, False, normalize=False)
     ).lower(x, x, x).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("k,n,dtype", [
+    (2048, 1024, jnp.bfloat16),   # olmoe1b7b.seq4096: gate and up
+    (1024, 2048, jnp.bfloat16),   # ... and down
+    (512, 384, jnp.float32),      # float32 rows, a width that is 3 x 128
+])
+def test_grouped_matmul_fwd_bwd_compile_for_v5e(one_chip, k, n, dtype):
+    """M = 131,072 sorted rows over 64 groups: the forward, the input
+    gradient (the same kernel on transposed weights) and the weight
+    gradient, whole contraction resident, under the VMEM limit the
+    kernels ask for."""
+    rows, groups = 131072, 64
+    assert gm._unfriendly(k, n, gm.row_tile(rows),
+                          jnp.dtype(dtype).itemsize) == ""
+    lhs = jax.ShapeDtypeStruct((rows, k), dtype, sharding=one_chip)
+    rhs = jax.ShapeDtypeStruct((groups, k, n), dtype, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((groups,), jnp.int32, sharding=one_chip)
+    cot = jax.ShapeDtypeStruct((rows, n), dtype, sharding=one_chip)
+
+    def fwd_bwd(lhs, rhs, sizes, cot):
+        out, vjp = jax.vjp(
+            lambda lhs, rhs: gm.grouped_matmul(lhs, rhs, sizes), lhs, rhs)
+        return out, vjp(cot)
+
+    text = jax.jit(fwd_bwd).lower(lhs, rhs, sizes, cot).compile().as_text()
+    # told apart downstream by the names the calls carry
+    # (benchmark/kernels/grouped_matmul.py)
+    calls = [l.split(" = ")[0] for l in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l]
+    for name in ("gmm_nn", "gmm_nt", "gmm_tn"):
+        assert len([c for c in calls if name in c]) == 1, (name, calls)

@@ -191,8 +191,8 @@ def test_generate_matches_full_forward(variant):
 
 
 def test_generate_moe_and_sampling():
-    """MoE decode is finite/valid (exactness vs forward is not expected:
-    T=1 decode never hits expert-capacity truncation); temperature
+    """MoE decode is finite/valid (its exactness vs forward is
+    tests/test_moe_dropless.py's: the dispatch drops nothing); temperature
     sampling stays in-vocab and respects the prompt."""
     cfg = dataclasses.replace(CFG, moe_experts=2)
     params = tfm.init_params(jax.random.PRNGKey(9), cfg)
@@ -422,9 +422,10 @@ def test_loss_decreases_matches_unsharded_trajectory():
 
 
 def test_moe_aux_loss_signals_imbalance():
-    """The Switch load-balance aux: ~1.0 for a near-uniform router, ~X
-    under collapse (all tokens AND all probability mass on one expert)
-    — minimizing it pushes toward uniform utilization."""
+    """The load-balance aux over all K choices: ~K for a near-uniform
+    router, ~X under collapse (every token's first choice AND all
+    probability mass on one expert) — minimizing it pushes toward
+    uniform utilization."""
     rng = np.random.RandomState(0)
     B, T, E, X, F = 2, 16, 8, 4, 16
     cfg = tfm.TransformerConfig(
@@ -446,14 +447,14 @@ def test_moe_aux_loss_signals_imbalance():
         }
 
     balanced = expert_weights(rng.randn(E, X) * 0.02)
-    _, aux_balanced, _ = tfm._moe_ffn(h, balanced, cfg, None)
+    _, aux_balanced, _, _ = tfm._moe_ffn(h, balanced, cfg, None)
 
     w_collapse = np.zeros((E, X))
     w_collapse[:, 0] = 10.0  # every (positive) token votes expert 0
     collapsed = expert_weights(w_collapse)
-    _, aux_collapsed, _ = tfm._moe_ffn(h, collapsed, cfg, None)
+    _, aux_collapsed, _, _ = tfm._moe_ffn(h, collapsed, cfg, None)
 
-    assert float(aux_balanced) < 1.5, float(aux_balanced)
+    assert 1.9 < float(aux_balanced) < 2.3, float(aux_balanced)  # ~K=2
     assert float(aux_collapsed) > 3.0, float(aux_collapsed)  # ~X=4
 
 
