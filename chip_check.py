@@ -56,6 +56,9 @@ TOLERANCES = {
     # values, so one rounding of the result is all that may differ.
     "dlhs": _BF16_FWD, "drhs": _BF16_FWD,
     "dscale": _F32, "dbias": _F32, "lse": _F32,
+    # head_loss: a mean of losses over logits rounded to bf16, and the
+    # gradients of two bf16 matmuls fed a bf16 cotangent.
+    "loss": _BF16_FWD, "dhead": _BF16_GRAD,
 }
 # ResNet step.  The head is zero-initialised, so the first loss is
 # ln(classes) whatever the kernels compute ...
@@ -172,6 +175,37 @@ def check_grouped_matmul(rows, k, n, groups, skew, interpret):
     return {"fwd": _rel_err(out, want),
             "dlhs": _rel_err(grads[0], want_grads[0]),
             "drhs": _rel_err(grads[1], want_grads[1])}
+
+
+def check_head_loss(b, t, dim, vocab, tied):
+    """``head_loss`` (bf16 operands, bf16 logits) and its gradients
+    against ``next_token_loss`` of float32 logits at the highest matmul
+    precision on the same values; the last example weighs zero, as a
+    padded record does."""
+    from elasticdl_tpu.models.transformer import next_token_loss
+    from elasticdl_tpu.ops.head_loss import head_loss
+
+    rng = np.random.RandomState(t + vocab)
+    x = jnp.asarray(rng.randn(b, t, dim), jnp.bfloat16)
+    head = jnp.asarray(rng.randn(*((vocab, dim) if tied else (dim, vocab)))
+                       * dim ** -0.5, jnp.bfloat16)
+    tokens = jnp.asarray(rng.randint(0, vocab, (b, t)), jnp.int32)
+    weights = jnp.asarray([1.0] * (b - 1) + [0.0], jnp.float32)
+
+    def reference(x, head, tokens, tied):
+        return next_token_loss(x @ (head.T if tied else head), tokens)
+
+    def both(fn):
+        def f(x, head):
+            per_example = fn(x, head, tokens, tied)
+            return (per_example * weights).sum() / weights.sum(), per_example
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))
+
+    (_, loss), (dx, dhead) = both(head_loss)(x, head)
+    with jax.default_matmul_precision("highest"):
+        (_, want), (want_dx, want_dhead) = both(reference)(*_f32(x, head))
+    return {"loss": _rel_err(loss, want), "dx": _rel_err(dx, want_dx),
+            "dhead": _rel_err(dhead, want_dhead)}
 
 
 def check_flash_partial(b, h, t, d, causal, interpret, ref_slice=(1, 2)):
@@ -358,6 +392,14 @@ def _cases(tiny):
                % (rows, k, n, experts, skew),
                lambda k=k, n=n, skew=skew: check_grouped_matmul(
                    rows, k, n, experts, skew, interpret))
+    # The benchmark's two heads: OLMoE's untied, OLMo's tied embedding.
+    hdim, vocab, heads = (64, 256, ((2, 24, False), (2, 24, True))) if tiny \
+        else (2048, 50304, ((4, 4096, False), (8, 2048, True)))
+    for hb, ht, tied in heads:
+        yield ("head_loss/B%d.T%d.E%d.V%d.%s"
+               % (hb, ht, hdim, vocab, "tied" if tied else "untied"),
+               lambda hb=hb, ht=ht, tied=tied: check_head_loss(
+                   hb, ht, hdim, vocab, tied))
     variant, image_size, batch, classes = resnet_args
     for hw, c, groups, relu in resnet_group_norm_shapes(
             variant, image_size, batch):

@@ -476,6 +476,20 @@ def _head(params, x, cfg):
     return (x @ head).astype(jnp.float32)
 
 
+def head_loss(params, hidden, tokens, cfg):
+    """``next_token_loss(_head(params, hidden, cfg), tokens)`` for the
+    training path: the head and the loss as one op
+    (:mod:`elasticdl_tpu.ops.head_loss`), whose one [B, T, V] tensor is
+    the logits in the compute dtype."""
+    from elasticdl_tpu.ops import head_loss as op
+
+    compute_dtype = jnp.dtype(cfg.dtype)
+    x = _rmsnorm(hidden, params["ln_f"].astype(compute_dtype), cfg.norm_eps)
+    head = params["embed" if cfg.tied_embeddings else "lm_head"]
+    return op.head_loss(x, head.astype(compute_dtype), tokens,
+                        tied=cfg.tied_embeddings)
+
+
 def forward_hidden(params, tokens, cfg, mesh=None, return_load=False):
     """tokens: [B, T] int32 -> (final hidden [B, T, dim] BEFORE the
     ln_f/head, mean per-layer MoE aux); with ``return_load`` (an MoE)
@@ -917,43 +931,39 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
     moe = bool(cfg.moe_experts)
 
     def apply_fn(params, tokens, train):
-        """Logits; training an MoE or with ``xent_chunk``, a dict for
-        ``loss_fn``: ``logits`` or (``hidden``, ``params``: the head
-        runs inside the chunked loss, no [B, T, V] tensor), ``aux``
-        and, from the scanned stack, ``moe_load``."""
-        hidden_only = bool(xent_chunk and train)
-        if not (hidden_only or (moe and train)):
+        """Logits; training, a dict for ``loss_fn``, in which the head
+        runs: ``hidden`` (before ``ln_f``), ``params``, ``aux`` and,
+        from an MoE's scanned stack, ``moe_load``."""
+        if not train:
             if pipelined:
                 return forward_pipelined(
                     params, tokens, cfg, mesh, pipeline_microbatches,
                     remat=bool(cfg.remat))
             return forward(params, tokens, cfg, mesh=mesh)
-        out = {}
+        out = {"params": params}
         if pipelined:
-            x, out["aux"] = forward_pipelined(
+            out["hidden"], out["aux"] = forward_pipelined(
                 params, tokens, cfg, mesh, pipeline_microbatches,
                 remat=bool(cfg.remat), return_aux=True,
                 return_hidden=True)
         elif moe:
-            x, out["aux"], out["moe_load"] = forward_hidden(
+            out["hidden"], out["aux"], out["moe_load"] = forward_hidden(
                 params, tokens, cfg, mesh=mesh, return_load=True)
         else:
-            x, out["aux"] = forward_hidden(params, tokens, cfg, mesh=mesh)
-        if hidden_only:
-            out["hidden"], out["params"] = x, params
-        else:
-            out["logits"] = _head(params, x, cfg)
+            out["hidden"], out["aux"] = forward_hidden(
+                params, tokens, cfg, mesh=mesh)
         return out
 
     def loss_fn(outputs, tokens):
         if not isinstance(outputs, dict):
             return next_token_loss(outputs, tokens)
-        if "hidden" in outputs:
+        if xent_chunk:
             loss = next_token_loss_chunked(
                 outputs["params"], outputs["hidden"], tokens, cfg,
                 chunk=xent_chunk)
         else:
-            loss = next_token_loss(outputs["logits"], tokens)
+            loss = head_loss(
+                outputs["params"], outputs["hidden"], tokens, cfg)
         if moe:
             loss = loss + cfg.moe_aux_weight * outputs["aux"]
         return loss

@@ -35,19 +35,24 @@ def batch_axis(mesh, axis):
         _BATCH_AXIS.reset(token)
 
 
+def shards():
+    """How many shards the declared batch axis has (1 without one)."""
+    declared = _BATCH_AXIS.get()
+    return 1 if declared is None else declared[0].shape[declared[1]]
+
+
 def per_batch_shard(fn, batched, replicated=()):
     """``fn(*batched, *replicated)``, per shard of the declared batch
     axis when there is one.  ``batched`` arrays and every output lead
     with the batch dimension; ``replicated`` arrays (affine parameters)
     are whole on every shard, and their cotangents are summed over the
     axis by ``shard_map``'s transpose."""
-    declared = _BATCH_AXIS.get()
-    if declared is None or declared[0].shape[declared[1]] == 1:
+    count = shards()
+    if count == 1:
         return fn(*batched, *replicated)
-    mesh, axis = declared
-    shards = mesh.shape[axis]
+    mesh, axis = _BATCH_AXIS.get()
     rows = [a.shape[0] for a in batched]
-    if any(n % shards for n in rows):
+    if any(n % count for n in rows):
         # Called directly the kernel would reach the partitioner, whose
         # refusal ("Mosaic kernels cannot be automatically partitioned")
         # says nothing of the cause.
@@ -55,7 +60,7 @@ def per_batch_shard(fn, batched, replicated=()):
             "a Pallas kernel got leading dimensions %s under a batch "
             "axis %r of %d shards: they must be whole multiples of it "
             "(CollectiveTrainer pads every minibatch to one)"
-            % (rows, axis, shards)
+            % (rows, axis, count)
         )
     return shard_map(
         fn, mesh=mesh,

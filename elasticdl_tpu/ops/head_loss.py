@@ -1,0 +1,111 @@
+"""The LM head and the next-token loss as one op.
+
+``head_loss(x, head, tokens)`` is ``next_token_loss(x @ head, tokens)``
+with its own derivative, so that the ``[tokens, vocab]`` logits exist
+once, in the dtype the head's matmul rounds them to (bfloat16 operands
+give a bfloat16 ``dot_general``), where JAX's derivative of the two
+functions has the compiler write a float32 copy beside them (3.3 GB at
+16,384 tokens x 50,304) for the label's gather alone.  Every reduction
+upcasts in registers and accumulates in float32, as the two functions
+do; the backward pass forms the cotangent ``softmax - onehot`` from the
+saved logits and their log-sum-exp, rounds it to the logits' dtype and
+feeds the two matmuls JAX would emit.
+
+All T positions are computed and the last one weighs zero: the matmuls
+then run on whole tiles (4 x 4096 rows, not 4 x 4095).
+
+The op owns how its three matmuls are emitted, and holds them apart
+from their neighbours with ``optimization_barrier``: left alone, XLA
+computes the final norm again in the operands of both matmuls that read
+``x`` and folds the optimizer's update into the weight gradient's
+epilogue, and the matmuls lose more than the passes saved (PERF.md
+section 6, PR 27: -1.4% ``records_per_s`` in ``olmo1b.seq2048`` without
+the barriers, +0.5% with them; +1.3% and +3.5% in ``olmoe1b7b.seq4096``).
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from elasticdl_tpu.ops import batch_shard, flash_attention
+
+
+@functools.lru_cache(maxsize=None)
+def announce_head_loss(rows, vocab, dtype):
+    """Once per compiled shape, by the logger ``announce_tiles`` uses:
+    the one [tokens, vocab] buffer (of one shard of the trainer's data
+    axis, where there is one)."""
+    flash_attention.logger.info(
+        "head loss: tokens=%d vocab=%d logits=%s bytes=%d", rows, vocab,
+        dtype, rows * vocab * jnp.dtype(dtype).itemsize)
+
+
+def _targets(tokens):
+    """Next tokens, and which positions have one: all but the last."""
+    t = tokens.shape[1]
+    has_target = (jnp.arange(t) < t - 1).astype(jnp.float32)
+    return jnp.roll(tokens, -1, axis=1), has_target
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _head_loss(x, head, tokens, tied):
+    return _head_loss_fwd(x, head, tokens, tied)[0]
+
+
+def _head_loss_fwd(x, head, tokens, tied):
+    x = jax.lax.optimization_barrier(x)     # read, not recomputed
+    # no preferred_element_type: the result takes the operands' dtype,
+    # as ``x @ head`` does
+    logits = jnp.einsum("bte,ve->btv" if tied else "bte,ev->btv", x, head)
+    targets, has_target = _targets(tokens)
+    # optax.softmax_cross_entropy_with_integer_labels' arithmetic, on
+    # values upcast where they are read
+    top = logits.max(axis=-1).astype(jnp.float32)
+    label = jnp.take_along_axis(
+        logits, targets[..., None], axis=-1)[..., 0].astype(jnp.float32) - top
+    log_z = jnp.log(jnp.exp(
+        logits.astype(jnp.float32) - top[..., None]).sum(axis=-1))
+    loss = ((log_z - label) * has_target).sum(axis=-1) / (x.shape[1] - 1)
+    return loss, (logits, log_z + top, x, head, tokens)
+
+
+def _head_loss_bwd(tied, residuals, g):
+    logits, lse, x, head, tokens = residuals
+    targets, has_target = _targets(tokens)
+    scale = (g.astype(jnp.float32)[:, None] * has_target
+             / (x.shape[1] - 1))                              # [B, T]
+    vocab = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2)
+    softmax = jnp.exp(logits.astype(jnp.float32) - lse[..., None])
+    cot = ((softmax - (vocab == targets[..., None])) * scale[..., None]
+           ).astype(logits.dtype)
+    if tied:
+        # The embedding's gradient [V, E] contracts the cotangent
+        # transposed: computed in that matmul's operands it costs more
+        # than the one pass that writes it.  An untied head's [E, V]
+        # does not, and its cotangent never reaches memory.
+        cot = jax.lax.optimization_barrier(cot)
+        dx = jnp.einsum("btv,ve->bte", cot, head)
+        dhead = jnp.einsum("btv,bte->ve", cot, x)
+    else:
+        dx = jnp.einsum("btv,ev->bte", cot, head)
+        dhead = jnp.einsum("bte,btv->ev", x, cot)
+    dhead = jax.lax.optimization_barrier(dhead)  # the update: a pass apart
+    return dx.astype(x.dtype), dhead.astype(head.dtype), None
+
+
+_head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
+
+
+def head_loss(x, head, tokens, tied=False):
+    """Per-example mean next-token cross entropy ``[B]`` (float32).
+
+    ``x`` [B, T, E]: the final norm's output; ``head`` [E, V], or with
+    ``tied`` the embedding [V, E]; ``tokens`` [B, T].  Both operands in
+    the compute dtype, which is the dtype the logits are held in.
+    """
+    b, t, _ = x.shape
+    vocab = head.shape[0 if tied else 1]
+    announce_head_loss(b * t // batch_shard.shards(), vocab,
+                       jnp.result_type(x, head).name)
+    return _head_loss(x, head, tokens, tied)

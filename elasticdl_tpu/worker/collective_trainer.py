@@ -477,10 +477,11 @@ class CollectiveTrainer(Trainer):
                 p = jax.tree_util.tree_map(to_bf16, p)
                 x = jax.tree_util.tree_map(to_bf16, x)
             # Pallas kernels inside the model run per shard of the data
-            # axis instead of being replicated by the partitioner.
+            # axis instead of being replicated by the partitioner; the
+            # model's loss states its sizes per shard too.
             with batch_axis(self._mesh, self._data_axis):
                 out = apply_fn(p, x, True)
-            per_example = loss_fn(out, labels).astype(jnp.float32)
+                per_example = loss_fn(out, labels).astype(jnp.float32)
             # The spec's step statistics ride out as value_and_grad's
             # aux: an empty tuple (no such spec) is no output at all.
             return _masked_mean(per_example, weights), (

@@ -187,3 +187,23 @@ def test_chip_check_tiny_mode_has_a_grouped_matmul_leg(monkeypatch, capsys,
     assert [r["case"].rsplit(".", 1)[1] for r in rows] == ["zipf", "empty"]
     for row in rows:
         assert set(row["errs"]) == {"fwd", "dlhs", "drhs"} and row["ok"]
+
+
+def test_chip_check_tiny_mode_has_a_head_loss_leg(monkeypatch, capsys,
+                                                  tmp_path):
+    """Tied and untied, the loss and both gradients against float32
+    logits, at the bf16 tolerances the chip is held to."""
+    import json
+
+    monkeypatch.syspath_prepend(REPO)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("ELASTICDL_FUSED_GN", "interpret")
+    import chip_check
+
+    assert chip_check.main(["--tiny", "head_loss"]) == 0
+    rows = [json.loads(line)
+            for line in capsys.readouterr().out.splitlines()
+            if line.startswith('{"case"')]
+    assert [r["case"].rsplit(".", 1)[1] for r in rows] == ["untied", "tied"]
+    for row in rows:
+        assert set(row["errs"]) == {"loss", "dx", "dhead"} and row["ok"]

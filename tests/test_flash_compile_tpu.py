@@ -1,5 +1,6 @@
-"""The flash-attention and grouped-matmul kernels through the TPU's own
-compiler, for a v5e that is described and not attached.
+"""The flash-attention and grouped-matmul kernels, and the head-and-loss
+op, through the TPU's own compiler, for a v5e that is described and not
+attached.
 
 Interpret mode cannot see what Mosaic refuses (a slice off the tiling, a
 transpose it has no lowering for, more scoped VMEM than a kernel may
@@ -15,8 +16,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from elasticdl_tpu.models import transformer as tfm
 from elasticdl_tpu.ops import flash_attention as fa
 from elasticdl_tpu.ops import grouped_matmul as gm
+from elasticdl_tpu.ops import head_loss as hl
 
 
 @pytest.fixture(scope="module")
@@ -99,3 +102,41 @@ def test_grouped_matmul_fwd_bwd_compile_for_v5e(one_chip, k, n, dtype):
              if 'custom_call_target="tpu_custom_call"' in l]
     for name in ("gmm_nn", "gmm_nt", "gmm_tn"):
         assert len([c for c in calls if name in c]) == 1, (name, calls)
+
+
+@pytest.mark.parametrize("b,t,tied", [
+    (4, 4096, False),    # olmoe1b7b.seq4096: an untied head
+    (8, 2048, True),     # olmo1b.seq2048: the tied embedding
+])
+def test_head_loss_holds_no_float32_logits_on_v5e(one_chip, b, t, tied):
+    """Forward + backward at the benchmark's head shapes (E 2048,
+    V 50,304): no float32 buffer of tokens x vocabulary elements, and
+    under 4 GB of temporaries where ``next_token_loss`` over ``_head``'s
+    float32 logits, compiled beside it, takes more than 4.5."""
+    dim, vocab = 2048, 50304
+    x = jax.ShapeDtypeStruct((b, t, dim), jnp.bfloat16, sharding=one_chip)
+    head = jax.ShapeDtypeStruct((vocab, dim) if tied else (dim, vocab),
+                                jnp.bfloat16, sharding=one_chip)
+    tokens = jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=one_chip)
+    weights = jax.ShapeDtypeStruct((b,), jnp.float32, sharding=one_chip)
+
+    def separate(x, head, tokens, tied):
+        logits = (x @ (head.T if tied else head)).astype(jnp.float32)
+        return tfm.next_token_loss(logits, tokens)
+
+    def compiled(loss):
+        def f(x, head, tokens, weights):
+            return (loss(x, head, tokens, tied) * weights).sum()
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1))).lower(
+            x, head, tokens, weights).compile()
+
+    from tools.head_loss_on_chip import entry_results
+
+    op, parent = compiled(hl.head_loss), compiled(separate)
+    logits = b * (t - 1) * vocab       # elements; a bf16 one is 2 B each
+    big = lambda c: {dtype for _, _, _, results in entry_results(c.as_text())
+                     for dtype, size in results if size >= 2 * logits}
+    assert big(op) == {"bf16"}
+    assert "f32" in big(parent)        # the check can see one
+    assert op.memory_analysis().temp_size_in_bytes < 4e9
+    assert parent.memory_analysis().temp_size_in_bytes > 4.5e9
