@@ -56,7 +56,6 @@ def run_bench(blocks=5, steps_per_block=40, fused_steps=8,
     import numpy as np
     from jax.sharding import Mesh
 
-    import bench as _bench  # provenance helpers
     from elasticdl_tpu.models import mnist
 
     platform = jax.devices()[0].platform
@@ -282,8 +281,14 @@ def run_bench(blocks=5, steps_per_block=40, fused_steps=8,
                 if platform == "cpu" else
                 "TPU capture: sharded update over ICI"
             ),
-            "device": _bench._device_fingerprint(jax),
-            "env": _bench._env_snapshot(),
+            "device": {
+                "platform": platform,
+                "device_kind": jax.devices()[0].device_kind,
+                "num_devices": len(jax.devices()),
+                "jax_version": jax.__version__,
+            },
+            "env": {k: v for k, v in sorted(os.environ.items())
+                    if k.startswith(("ELASTICDL_", "JAX_", "XLA_"))},
         },
     }
 

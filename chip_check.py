@@ -11,10 +11,10 @@ forward and gradient, against its reference (``_attention_ref``,
 ``_partial_ref``, ``_group_norm_ref``, one matmul per group for the
 grouped matmul) evaluated in float32 at highest
 matmul precision on the same bf16 values; then the full ResNet-50
-(batch 128, bf16) takes two ``CollectiveTrainer`` steps with the default
-``ELASTICDL_FUSED_GN=auto``, and two more with the model's GroupNorm
-swapped for the reference, and the losses must agree.  ``--tiny`` is the
-same code at toy shapes through the Pallas interpreter
+(batch 128, bf16) takes two ``CollectiveTrainer`` steps in the default
+mode (``ops/mode.py``: the kernel on a TPU), and two more with the
+model's GroupNorm swapped for the reference, and the losses must agree.
+``--tiny`` is the same code at toy shapes through the Pallas interpreter
 (tests/test_chip_bringup.py drives it, so the checker itself is
 exercised without chip time).  One JSON line per case, a summary line
 last; exit 1 if any case failed.  The process owns the chip for its
@@ -35,6 +35,7 @@ import numpy as np
 from elasticdl_tpu.ops import flash_attention as fa
 from elasticdl_tpu.ops import group_norm as gn
 from elasticdl_tpu.ops import grouped_matmul as gm
+from elasticdl_tpu.ops.mode import SWITCH, kernel_mode
 from elasticdl_tpu.utils.device import device_report, place_compile_cache
 
 # Errors are taken relative to the reference's largest value, so a
@@ -70,6 +71,15 @@ TOLERANCES["loss0_minus_ln_classes"] = 1e-2
 # 6.90775 -> 6.41835 fused, -> 6.42008 reference — my chip run, PR 21;
 # 5.7e-4 in --tiny on the CPU).
 TOLERANCES["step_vs_reference_gn"] = 2e-2
+# ZeRO-1 against the replicated update, 12 steps of a bf16 LM: the same
+# arithmetic on the same values.  On one chip (one shard) the losses are
+# the same bits (``zero1_steps_differing`` 0); over the four chips of a
+# v5e host 11 of 12 differ, by at most 3.1e-5 (my chip run, PR 28), and
+# on 4 virtual CPU devices 9 of 12, 4.3e-5 (--tiny): a last-ulp
+# difference in a float32 parameter now and then flips a bf16 rounding
+# downstream (tests/test_zero1.py::LAST_ULPS).  A wrong slice or a
+# missing sum moves the loss by far more than this.
+TOLERANCES["zero1_rel_diff"] = 1e-3
 
 
 def _rel_err(got, want):
@@ -349,12 +359,12 @@ def _resnet_losses(variant, image_size, batch, num_classes):
 
 def check_resnet_step(variant, image_size, batch, num_classes):
     """The whole model through the trainer with whatever
-    ``fused_gn_mode()`` resolves to by default, then again with the
+    ``ops/mode.py`` resolves to by default, then again with the
     reference GroupNorm in the model."""
     args = (variant, image_size, batch, num_classes)
     fused = _resnet_losses(*args)
     print(json.dumps({"resnet_step": "fused", "losses": fused,
-                      "fused_gn": gn.fused_gn_mode()}), flush=True)
+                      "fused_gn": kernel_mode()}), flush=True)
     with _model_group_norm(_reference_group_norm):
         ref = _resnet_losses(*args)
     moved, ref_moved = fused[1] - fused[0], ref[1] - ref[0]
@@ -364,6 +374,40 @@ def check_resnet_step(variant, image_size, batch, num_classes):
         "loss1_reference_gn": ref[1],
         "step_vs_reference_gn": abs(moved - ref_moved) / abs(ref_moved),
     }
+
+
+def check_zero1(steps=12):
+    """ZeRO-1 (``--zero1``: optimizer state and update sharded over the
+    data axis) against the replicated update, a small LM under AdamW over
+    every device this process has, same seed, same batches.  ``worker/
+    collective_trainer._zero1_apply`` pins its numerics so that the two
+    trajectories can be the same bits; whether they are is the backend's
+    to say, so this reports ``zero1_steps_differing`` and holds the
+    losses to the last ulps."""
+    from jax.sharding import Mesh
+
+    from elasticdl_tpu.models import transformer as tfm
+    from elasticdl_tpu.worker.collective_trainer import CollectiveTrainer
+
+    devices = jax.devices()
+    spec = tfm.model_spec(vocab_size=1024, dim=256, num_heads=2,
+                          num_layers=2, seq_len=256)
+    batch = 2 * len(devices)
+    tokens = np.random.RandomState(5).randint(
+        0, 1024, size=(batch, 256)).astype(np.int32)
+    mesh = Mesh(np.array(devices), axis_names=("data",))
+    losses = []
+    for zero1 in (False, True):
+        trainer = CollectiveTrainer(spec, batch_size=batch, mesh=mesh,
+                                    rng_seed=3, zero1=zero1)
+        losses.append([float(trainer.train_minibatch(tokens, tokens)[0])
+                       for _ in range(steps)])
+    base, sharded = (np.asarray(l, np.float32) for l in losses)
+    print(json.dumps({"zero1": "losses", "devices": len(devices),
+                      "replicated": losses[0], "zero1_on": losses[1]}),
+          flush=True)
+    return {"zero1_rel_diff": float(np.max(np.abs(sharded - base) / base)),
+            "zero1_steps_differing": int((sharded != base).sum())}
 
 
 def _cases(tiny):
@@ -409,6 +453,7 @@ def _cases(tiny):
                check_group_norm(batch, hw, c, groups, relu, interpret))
     yield ("resnet_step/%s.b%d" % (variant, batch),
            lambda: check_resnet_step(variant, image_size, batch, classes))
+    yield ("zero1/lm.adamw.%ddevices" % jax.device_count(), check_zero1)
 
 
 def main(argv=None):
@@ -417,7 +462,7 @@ def main(argv=None):
     wanted = [a for a in argv if not a.startswith("--")]
     place_compile_cache()
     if tiny:
-        os.environ["ELASTICDL_FUSED_GN"] = "interpret"
+        os.environ[SWITCH] = "interpret"
     report = device_report()
     print(json.dumps({"device": report}), flush=True)
     if not tiny and report["platform"] != "tpu":

@@ -18,9 +18,9 @@ A leg fails if the worker's stated platform is not ``tpu``, the master's
 exit code is non-zero, a worker exited non-zero or was relaunched, the
 log holds a swallowed ``minibatch failed`` / ``training task ... failed``
 or (LM legs) an ``attention fallback:`` line, a loss is not finite, or
-fewer steps ran than were asked for.  No kernel escape hatch
-(``ELASTICDL_FLASH_BWD``, ``ELASTICDL_FUSED_GN``, ``ELASTICDL_FLASH``) is
-set or inherited.
+fewer steps ran than were asked for.  The kernels' one switch
+(``ELASTICDL_FLASH``, ``elasticdl_tpu/ops/mode.py``) is neither set nor
+inherited.
 
 Exit 0 and, as the last line of stdout, one JSON object
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``
@@ -50,8 +50,7 @@ LM_BATCH_PER_CHIP = 8
 LM_STEPS = 20          # >= 16 after the step that compiles
 LM_MINIBATCHES_PER_TASK = 4
 
-KERNEL_SWITCHES = ("ELASTICDL_FLASH", "ELASTICDL_FLASH_BWD",
-                   "ELASTICDL_FUSED_GN")
+KERNEL_SWITCH = "ELASTICDL_FLASH"
 
 _STAMP = re.compile(r"^\[(\d{4}-\d\d-\d\d \d\d:\d\d:\d\d),(\d{3})\]")
 _DEVICE = re.compile(r"\[worker-(\d+)\].*worker device: (.*)$")
@@ -89,8 +88,7 @@ def _clean_ignored_artifacts():
 
 
 def _child_env():
-    env = {k: v for k, v in os.environ.items()
-           if k not in KERNEL_SWITCHES}
+    env = {k: v for k, v in os.environ.items() if k != KERNEL_SWITCH}
     env["PYTHONPATH"] = HERE + os.pathsep + env.get("PYTHONPATH", "")
     return env
 

@@ -19,7 +19,6 @@ RoPE positions, pre-norm RMSNorm, SwiGLU MLP.
 """
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +27,9 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from elasticdl_tpu.models.spec import ModelSpec
-from elasticdl_tpu.parallel.ring_attention import ring_attention
+from elasticdl_tpu.ops.flash_attention import flash_attention
+from elasticdl_tpu.ops.mode import kernels_off
+from elasticdl_tpu.ops.moe_dispatch import moe_experts
 from elasticdl_tpu.utils import metrics
 
 
@@ -246,81 +247,14 @@ def moe_route(h, w_router, cfg):
     return probs, gates, experts
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _take_rows(k, x, order, inverse):
-    """``x[order // k]``: row i of the result is the row of x that
-    ``order[i]`` of the n * k (row, choice) assignments claims, and
-    ``inverse`` undoes ``order``, so the pullback is a gather and a sum
-    over a row's k claims, not a scatter-add.  With k = 1 it permutes
-    rows, by gathers both ways."""
-    return x[order // k]
-
-
-def _take_rows_bwd(k, inverse, g):
-    claims = g[inverse].reshape(-1, k, g.shape[-1])
-    return claims.astype(jnp.float32).sum(axis=1).astype(g.dtype), None, None
-
-
-_take_rows.defvjp(
-    lambda k, x, order, inverse: (x[order // k], inverse), _take_rows_bwd)
-
-
-def _moe_experts(h, gates, experts, w_gate, w_up, w_down, kernel):
-    """The routed FFN of the rows this device holds: sort the n * K
-    (token, choice) assignments by expert, gather their rows, three
-    grouped matmuls, un-sort and sum each token's K results weighted by
-    its gates.  O(n * K * width) memory, no capacity, nothing dropped.
-    Returns (out [b, T, E], load [1, X + 1]: rows per expert, then the
-    rows the grouped matmul computes beyond the real ones)."""
-    from elasticdl_tpu.ops import grouped_matmul as gm
-
-    b, t, e = h.shape
-    x, k = w_gate.shape[0], experts.shape[-1]
-    n, rows = b * t, b * t * k
-    if kernel != "interpret":
-        announce_dispatch(n, x, k, kernel)
-    flat = experts.reshape(rows)
-    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-    inverse = jnp.argsort(order).astype(jnp.int32)
-    sizes = (flat[:, None] == jnp.arange(x, dtype=flat.dtype)).sum(
-        axis=0, dtype=jnp.int32)
-    if kernel:
-        matmul = functools.partial(gm.grouped_matmul,
-                                   interpret=kernel == "interpret")
-        padded = gm.padded_rows(sizes, rows)
-    else:
-        matmul, padded = gm.grouped_matmul_ref, jnp.int32(0)
-    xs = _take_rows(k, h.reshape(n, e), order, inverse)
-    act = jax.nn.silu(matmul(xs, w_gate, sizes)) * matmul(xs, w_up, sizes)
-    ys = _take_rows(1, matmul(act, w_down, sizes), inverse, order)
-    out = jnp.einsum("nke,nk->ne", ys.reshape(n, k, e).astype(jnp.float32),
-                     gates.reshape(n, k))
-    load = jnp.concatenate([sizes, padded.reshape(1)])[None]
-    return out.astype(h.dtype).reshape(b, t, e), load
-
-
-@functools.lru_cache(maxsize=None)
-def announce_dispatch(tokens, experts, top_k, kernel):
-    """Once per compiled shape, by the logger ``announce_tiles`` uses:
-    what the dispatch hands the grouped matmul (of one shard of the
-    trainer's data axis, where there is one)."""
-    from elasticdl_tpu.ops import flash_attention, grouped_matmul as gm
-
-    rows = tokens * top_k
-    tile = gm.row_tile(rows)
-    flash_attention.logger.info(
-        "moe dispatch: tokens=%d experts=%d top_k=%d rows=%d row_tile=%d "
-        "groups_tiles<=%d kernel=%s", tokens, experts, top_k, rows, tile,
-        -(-rows // tile) + experts - 1, kernel or "off")
-
-
-def _moe_ffn(h, w, cfg, mesh, mode=None):
+def _moe_ffn(h, w, cfg, mesh):
     """Dropless top-k MoE FFN (expert weights sharded over ``ep``).
 
-    One dispatch (:func:`_moe_experts`) with two ways to multiply,
-    chosen by where the code runs: the Pallas grouped matmul, per shard
-    of the trainer's data axis, where ``flash_mode()`` allows a kernel;
-    ``lax.ragged_dot`` under a model-parallel mesh and everywhere else.
+    One dispatch (``ops/moe_dispatch.moe_experts``) with two ways to
+    multiply, chosen by where the code runs: the Pallas grouped matmul,
+    per shard of the trainer's data axis, where no mesh is given and
+    ``ops/mode.py`` allows a kernel; ``lax.ragged_dot`` under a
+    model-parallel mesh and everywhere else.
 
     Returns (out, aux, stats, load).  ``aux`` is the load-balance loss
     over all K choices, X * sum_x assigned(x) * mean_prob(x) with
@@ -331,22 +265,13 @@ def _moe_ffn(h, w, cfg, mesh, mode=None):
     the end for the exact full-batch aux.  ``load`` [X + 1] float32:
     assignments per expert, and the grouped matmul's padded rows.
     """
-    from elasticdl_tpu.ops.batch_shard import per_batch_shard
-    from elasticdl_tpu.ops.flash_attention import flash_mode
-
     B, T = h.shape[:2]
     X = cfg.moe_experts
-    if mode is None:
-        mode = flash_mode()
-    kernel = mode if mesh is None and mode in ("tpu", "interpret") else ""
     probs, gates, experts = moe_route(h, w["w_router"], cfg)
     weights = tuple(w[name].astype(h.dtype)
                     for name in ("w_gate", "w_up", "w_down"))
-    fn = functools.partial(_moe_experts, kernel=kernel)
-    if kernel:
-        out, load = per_batch_shard(fn, (h, gates, experts), weights)
-    else:
-        out, load = fn(h, gates, experts, *weights)
+    with kernels_off(mesh is not None):
+        out, load = moe_experts(h, gates, experts, *weights)
     load = load.sum(axis=0).astype(jnp.float32)
     stats = jnp.stack([load[:X] / (B * T), probs.mean(axis=(0, 1))])
     aux = X * jnp.sum(stats[0] * stats[1])
@@ -379,14 +304,14 @@ def _project_qkv(h, w, cfg, positions):
     return _rope(q, positions), _rope(k, positions), v
 
 
-def _ffn(x, w, cfg, mesh, mode=None):
+def _ffn(x, w, cfg, mesh):
     """x + FFN(norm(x)) -> (x, aux, stats, load); the last three are
     the MoE's (:func:`_moe_ffn`), zeros and None for a dense FFN."""
     compute_dtype = jnp.dtype(cfg.dtype)
     act_spec = P("dp", "sp", None)
     h = _rmsnorm(x, w["ln2"].astype(compute_dtype), cfg.norm_eps)
     if cfg.moe_experts:
-        out, aux, stats, load = _moe_ffn(h, w, cfg, mesh, mode)
+        out, aux, stats, load = _moe_ffn(h, w, cfg, mesh)
         return x + _constrain(out, mesh, act_spec), aux, stats, load
     gate = jax.nn.silu(h @ w["w_gate"].astype(compute_dtype))
     up = h @ w["w_up"].astype(compute_dtype)
@@ -396,9 +321,11 @@ def _ffn(x, w, cfg, mesh, mode=None):
     return x, jnp.float32(0.0), None, None
 
 
-def _attention(x, w, cfg, mesh, positions, attention_mode=None):
+def _attention(x, w, cfg, mesh, positions):
     """x + Attention(norm(x)) -> (x, (k, v)): k, v post-RoPE and
-    pre-GQA-expand, [B, T, G, D]."""
+    pre-GQA-expand, [B, T, G, D].  Without a mesh the attention is the
+    op itself (``ops/flash_attention.py``, which picks kernel or
+    reference); ``parallel/`` serves a mesh."""
     compute_dtype = jnp.dtype(cfg.dtype)
     act_spec = P("dp", "sp", None)
     B, T = x.shape[0], x.shape[1]
@@ -415,24 +342,26 @@ def _attention(x, w, cfg, mesh, positions, attention_mode=None):
         # score matmuls.
         k = jnp.repeat(k, H // G, axis=2)
         v = jnp.repeat(v, H // G, axis=2)
-    if mesh is None and attention_mode is not None:
-        from elasticdl_tpu.parallel.ring_attention import attention_local
-
-        attn = attention_local(q, k, v, causal=True, mode=attention_mode,
-                               window=cfg.window)
+    if cfg.attention_impl not in ("ring", "ulysses"):
+        raise ValueError(
+            "unknown attention_impl %r (want 'ring' or 'ulysses')"
+            % (cfg.attention_impl,)
+        )
+    if mesh is None:
+        attn = flash_attention(
+            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3), causal=True, window=cfg.window,
+        ).transpose(0, 2, 1, 3)
     elif cfg.attention_impl == "ulysses":
         from elasticdl_tpu.parallel.ulysses import ulysses_attention
 
         attn = ulysses_attention(q, k, v, mesh, causal=True,
                                  window=cfg.window)
-    elif cfg.attention_impl == "ring":
+    else:
+        from elasticdl_tpu.parallel.ring_attention import ring_attention
+
         attn = ring_attention(q, k, v, mesh, causal=True,
                               window=cfg.window)
-    else:
-        raise ValueError(
-            "unknown attention_impl %r (want 'ring' or 'ulysses')"
-            % (cfg.attention_impl,)
-        )
     attn = attn.reshape(B, T, H * D)
     # Named so remat="attn" can save exactly this tensor: the layer
     # recompute in the backward then skips re-running flash attention
@@ -446,18 +375,19 @@ def _attention(x, w, cfg, mesh, positions, attention_mode=None):
     ), kv_out
 
 
-def _layer_body(x, w, cfg, mesh, positions, attention_mode=None,
-                moe_stats=False, return_kv=False, moe_load=False):
+def _layer_body(x, w, cfg, mesh, positions, moe_stats=False,
+                return_kv=False, moe_load=False):
     """One transformer block; shared by the scanned stack (forward) and
     the per-stage slice scan (forward_pipelined).  ``moe_stats`` swaps
     the scalar aux for the linear [2, X] router statistics (pipeline
     accumulation); ``moe_load`` returns (aux, load [X + 1]) for an MoE
     (the step statistics).  ``return_kv`` additionally returns this
     layer's (k, v) — the decode prefill captures them into the KV
-    cache.  ``attention_mode`` also chooses how the MoE multiplies
-    (``"off"``: no Pallas call)."""
-    x, kv_out = _attention(x, w, cfg, mesh, positions, attention_mode)
-    x, aux, stats, load = _ffn(x, w, cfg, mesh, attention_mode)
+    cache.  Which kernels run is not its business: the ops ask
+    ``ops/mode.py``, and a caller that traces it where none can run
+    says so with ``kernels_off()``."""
+    x, kv_out = _attention(x, w, cfg, mesh, positions)
+    x, aux, stats, load = _ffn(x, w, cfg, mesh)
     if moe_stats and cfg.moe_experts:
         aux = stats
     elif moe_load and cfg.moe_experts:
@@ -585,13 +515,12 @@ def forward_pipelined(params, tokens, cfg, mesh, num_microbatches,
 
     def stage_fn(w, x_mb):
         def body(x, w1):
-            # attention_mode="off": inside the pp-manual shard_map the
-            # dp/tp axes are auto, and a pallas_call under auto axes
-            # would be all-gathered by GSPMD; the jnp path partitions.
-            return _layer_body(
-                x, w1, cfg, None, positions, attention_mode="off",
-                moe_stats=collect_aux,
-            )
+            # Inside the pp-manual shard_map the dp/tp axes are auto,
+            # and a pallas_call under auto axes would be all-gathered by
+            # GSPMD; the jnp paths partition.
+            with kernels_off():
+                return _layer_body(x, w1, cfg, None, positions,
+                                   moe_stats=collect_aux)
 
         x_mb, aux_per_layer = jax.lax.scan(body, x_mb, w)
         if collect_aux:

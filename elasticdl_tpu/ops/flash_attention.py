@@ -26,12 +26,11 @@ streamed; scores built transposed, so nothing is transposed for its
 matmuls), each rebuilding its probability tiles IN VMEM as
 ``exp(s - lse)`` from the one saved row constant ``lse = m + log l``:
 no divide per score, the scale applied once to the f32 accumulator, and
-``delta = sum(dO * O)`` made once outside both.  Unlike the older XLA
-``lax.scan`` block-recompute (kept behind ``ELASTICDL_FLASH_BWD=xla``),
-the [T, block] p/ds tiles never make an HBM round-trip between einsums.
-Peak memory stays O(T·block), never the full T x T.  Operands go into
-the MXU in their storage dtype (bf16), scores, stats and accumulators
-are f32, exp and the final division exact.
+``delta = sum(dO * O)`` made once outside both.  There is no XLA
+backward: the [T, block] p/ds tiles never make an HBM round-trip between
+einsums.  Peak memory stays O(T·block), never the full T x T.  Operands
+go into the MXU in their storage dtype (bf16), scores, stats and
+accumulators are f32, exp and the final division exact.
 
 ``flash_attention_partial`` exposes the same kernel without the final
 normalization, returning (acc, l, m) for one KV block — the building
@@ -42,15 +41,16 @@ closed-form pullback ``_partial_stats_bwd`` (scans K blocks,
 recomputing each [T, block_k] score tile), so each ring step's bwd is
 O(T/sp x block_k) live, never the dense per-shard square.
 
-Layout: [batch, heads, seq, head_dim].  The caller-facing block sizes
-are a friendliness contract (seq divisible by them, 128-lane block_k);
-the kernel chooses its own internal tiling.  `flash_attention` falls
-back to the reference implementation for unfriendly shapes, and in the
-compiled mode says so once per shape (``announce_fallback``): on the
-chip a silent reference path is a slow path nobody asked for.
-Mode selection: ``ELASTICDL_FLASH=auto`` (default: compiled kernel on
-TPU; jnp elsewhere), ``interpret`` (Pallas interpret mode, for tests),
-``off``.  Forward and Pallas backward compile and match the reference at
+Layout: [batch, heads, seq, head_dim].  The kernels choose their own
+tiling (``_major_tile``, ``_TilePlan``); a caller gives none.  A shape
+they cannot take (seq not a multiple of the 128 lanes, an odd head_dim)
+falls back to ``_attention_ref``, the one reference attention of the
+tree, and in the compiled mode says so once per shape
+(``announce_fallback``): on the chip a silent reference path is a slow
+path nobody asked for.  Which of kernel, interpreter and reference runs
+is ``ops/mode.py``'s answer, asked here and by no caller (an explicit
+``interpret=`` is for tests and ``chip_check.py``, which force the
+kernel).  Forward and Pallas backward compile and match the reference at
 B8·H16·T2048, D64 and D128, full causal and windowed, on a v5e
 (``chip_check.py`` at the repo root; my chip run, PR 25), where one
 call at D128 takes 1.21 (forward), 1.41 (dq) and 1.69 ms (dk-dv):
@@ -60,7 +60,6 @@ BENCHMARKS.md are superseded by these and by PERF_LEDGER.jsonl).
 """
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -69,6 +68,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from elasticdl_tpu.ops.batch_shard import per_batch_shard
+from elasticdl_tpu.ops.mode import kernel_mode, resolve
 from elasticdl_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -80,19 +80,17 @@ FALLBACK_PREFIX = "attention fallback:"
 
 
 @functools.lru_cache(maxsize=None)
-def announce_fallback(what, shape, why):
-    """Once per (call site, shape, reason): the compiled kernel was asked
-    for and a jnp path ran instead."""
+def _announce_once(what, shape, why):
     logger.warning("%s %s for shape %s took the jnp reference path: %s",
                    FALLBACK_PREFIX, what, shape, why)
 
 
-def flash_mode():
-    """"tpu" (compiled), "interpret", or "off" for the current config."""
-    mode = os.environ.get("ELASTICDL_FLASH", "auto")
-    if mode == "auto":
-        return "tpu" if jax.default_backend() == "tpu" else "off"
-    return mode
+def announce_fallback(what, shape, why, mode=None):
+    """Once per (call site, shape, reason), where the compiled kernel was
+    asked for and a jnp path ran instead.  ``mode``: the op's resolved
+    one; a caller outside ``ops/`` leaves it to the tracing context."""
+    if (mode or kernel_mode()) == "tpu":
+        _announce_once(what, shape, why)
 
 
 def _attention_ref(q, k, v, causal, scale, window=0):
@@ -435,8 +433,8 @@ def _tile_specs(tile, d, order_axis):
     )
 
 
-def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
-                   normalize=True, window=0):
+def _flash_forward(q, k, v, causal, scale, interpret, normalize=True,
+                   window=0):
     """Returns (out, l, m); out is normalized iff ``normalize``."""
     b, h, t, d = q.shape
     bh = b * h
@@ -445,11 +443,9 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
     vr = v.reshape(bh, t, d)
     # Work per grid step must amortize the per-step pipeline overhead:
     # a wide q block and a major K/V tile of the same edge, both capped
-    # by what divides t.  The caller's block_q/block_k are a
-    # friendliness contract (t divisible, 128 lanes) — the kernel owns
-    # its tiling, and its grid holds the live tiles only (their indices
-    # are scalar-prefetched), so a dead tile costs neither a step nor a
-    # DMA.
+    # by what divides t.  The grid holds the live tiles only (their
+    # indices are scalar-prefetched), so a dead tile costs neither a
+    # step nor a DMA.
     tile = _major_tile(t, d * q.dtype.itemsize)
     plan = _tile_plan(t, tile, causal, window)
     if not interpret:
@@ -505,8 +501,8 @@ def _masked_block_scores(qf, kf, ki, block_k, causal, scale, k_offset,
                          q_pos, window=0):
     """One [B,H,T,block_k] f32 score tile, causally masked against k
     rows offset by ``k_offset + ki*block_k``.  Returns (scores, mask)
-    with mask None when not causal — the single source of truth both
-    blockwise backwards recompute from."""
+    with mask None when not causal — what the ring's banded partial
+    and the partial's stats backward both recompute from."""
     s = jnp.einsum(
         "bhqd,bhkd->bhqk", qf, kf,
         preferred_element_type=jnp.float32,
@@ -520,44 +516,6 @@ def _masked_block_scores(qf, kf, ki, block_k, causal, scale, k_offset,
         mask = mask[None, None]
         return jnp.where(mask, s, NEG_INF), mask
     return s, None
-
-
-def _blockwise_bwd(q, k, v, out, lse, g, causal, scale, block_k,
-                   window=0):
-    """Block-recompute backward: scan over K blocks rebuilding each
-    [T, block_k] probability tile from the saved log-sum-exp.  Peak
-    live memory O(B·H·T·block_k), never the T x T matrix."""
-    _, _, tk, _ = k.shape
-    qf = q.astype(jnp.float32)
-    gf = g.astype(jnp.float32)
-    outf = out.astype(jnp.float32)
-    # delta_i = sum_d dO_i O_i  (the usual flash-bwd row constant)
-    delta = (gf * outf).sum(axis=-1)                    # [B,H,T]
-    q_pos = jnp.arange(q.shape[2])
-
-    num_k, k_blocks, v_blocks = _kv_blocks(k, v, block_k)
-
-    def body(carry, inputs):
-        dq = carry
-        ki, kf, vf = inputs
-        s, _ = _masked_block_scores(
-            qf, kf, ki, block_k, causal, scale, 0, q_pos, window=window
-        )                                               # [B,H,T,bk]
-        p = jnp.exp(s - lse[..., None])
-        dv = jnp.einsum("bhqk,bhqd->bhkd", p, gf)
-        dp = jnp.einsum("bhqd,bhkd->bhqk", gf, vf)
-        ds = p * (dp - delta[..., None]) * scale
-        dq = dq + jnp.einsum("bhqk,bhkd->bhqd", ds, kf)
-        dk = jnp.einsum("bhqk,bhqd->bhkd", ds, qf)
-        return dq, (dk, dv)
-
-    dq0 = jnp.zeros(q.shape, jnp.float32)
-    dq, (dk, dv) = jax.lax.scan(
-        body, dq0, (jnp.arange(num_k), k_blocks, v_blocks)
-    )
-    dk = jnp.moveaxis(dk, 0, 2).reshape(k.shape)
-    dv = jnp.moveaxis(dv, 0, 2).reshape(v.shape)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 def _bwd_dq_kernel(qi_tab, ki_tab, q_ref, do_ref, k_ref, v_ref, lse_ref,
@@ -663,11 +621,9 @@ def _bwd_dkv_kernel(qi_tab, ki_tab, k_ref, v_ref, q_ref, do_ref, lse_ref,
 def _pallas_bwd(q, k, v, out, lse, g, causal, scale, interpret,
                 window=0):
     """Pallas backward: dq in one pass (K streamed), dk/dv in another
-    (Q streamed).  Same FLOPs as the XLA block-recompute path but the
-    probability/ds tiles live only in VMEM — no [B,H,T,block] HBM
-    round-trips between the einsums of a scan step.  What is constant
-    along a row is made once, out here: lse came with the residuals,
-    delta_i = sum_d dO_i O_i is one pass over dO and O."""
+    (Q streamed).  The probability/ds tiles live only in VMEM.  What is
+    constant along a row is made once, out here: lse came with the
+    residuals, delta_i = sum_d dO_i O_i is one pass over dO and O."""
     b, h, t, d = q.shape
     bh = b * h
     tile = _major_tile(t, d * q.dtype.itemsize)
@@ -731,30 +687,23 @@ def _pallas_bwd(q, k, v, out, lse, g, causal, scale, interpret,
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret,
-           window=0):
-    out, _, _ = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                               interpret, window=window)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, scale, interpret, window=0):
+    out, _, _ = _flash_forward(q, k, v, causal, scale, interpret,
+                               window=window)
     return out
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-               window=0):
-    out, l, m = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                               interpret, window=window)
+def _flash_fwd(q, k, v, causal, scale, interpret, window=0):
+    out, l, m = _flash_forward(q, k, v, causal, scale, interpret,
+                               window=window)
     # One row constant for the backward: p = exp(s - lse), no divide.
     lse = m + jnp.log(jnp.maximum(l, 1e-30))
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res,
-               g):
+def _flash_bwd(causal, scale, interpret, window, res, g):
     q, k, v, out, lse = res
-    if os.environ.get("ELASTICDL_FLASH_BWD", "pallas") == "xla":
-        # Escape hatch: the XLA block-recompute backward.
-        return _blockwise_bwd(q, k, v, out, lse, g, causal, scale,
-                              block_k, window=window)
     return _pallas_bwd(q, k, v, out, lse, g, causal, scale, interpret,
                        window=window)
 
@@ -769,53 +718,45 @@ def _check_window(window, causal):
         raise ValueError("window must be >= 0, got %d" % window)
 
 
-def _unfriendly(t, d, block_q, block_k):
+def _unfriendly(t, d):
     """Why the kernel cannot take this shape, or "" when it can."""
-    # block_k must equal STATS_LANES so the kernel's [bq, bk] score tile
-    # is lane-aligned with the [bq, STATS_LANES] running stats.
-    if block_k != STATS_LANES:
-        return "block_k %d is not the %d-lane stats width" % (
-            block_k, STATS_LANES)
-    if t % block_q or t % block_k:
-        return "seq %d is not a multiple of the %dx%d blocks" % (
-            t, block_q, block_k)
+    if t % STATS_LANES:
+        return "seq %d is not a multiple of the %d lanes" % (
+            t, STATS_LANES)
     if d % 128 and d != 64:
         return "head_dim %d is neither 64 nor a multiple of 128" % d
     return ""
 
 
-def flash_attention(q, k, v, causal=True, scale=None, block_q=128,
-                    block_k=128, interpret=False, window=0):
+def flash_attention(q, k, v, causal=True, scale=None, interpret=None,
+                    window=0):
     """q, k, v: [batch, heads, seq, head_dim].  ``window`` > 0 limits
     causal attention to the last ``window`` positions (O(T·W) compute:
-    blocks outside the band skip both matmuls and DMA)."""
+    blocks outside the band skip both matmuls and DMA).  The kernel
+    where ``ops/mode.py`` allows one and the shape is friendly, per
+    shard of the declared batch axis; else ``_attention_ref``."""
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     _check_window(window, causal)
-    t = q.shape[2]
-    d = q.shape[3]
-    block_q = min(block_q, t)
-    block_k = min(block_k, t)
-    why = _unfriendly(t, d, block_q, block_k)
-    if why:
-        if not interpret:
-            announce_fallback("flash_attention", q.shape, why)
-        return _attention_ref(q, k, v, causal, scale, window=window)
-    return per_batch_shard(
-        lambda q, k, v: _flash(q, k, v, causal, scale, block_q, block_k,
-                               interpret, window),
-        (q, k, v),
-    )
+    mode = resolve(interpret)
+    if mode != "off":
+        why = _unfriendly(q.shape[2], q.shape[3])
+        if not why:
+            return per_batch_shard(
+                lambda q, k, v: _flash(q, k, v, causal, scale,
+                                       mode == "interpret", window),
+                (q, k, v),
+            )
+        announce_fallback("flash_attention", q.shape, why, mode)
+    return _attention_ref(q, k, v, causal, scale, window=window)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _flash_partial(q, k, v, causal, scale, block_q, block_k, interpret,
-                   k_offset, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_partial(q, k, v, causal, scale, interpret, k_offset, window):
     # causal here means the diagonal (k_offset == 0) block, where the
     # kernel's absolute-position mask equals the local mask.
     out, l, m = _flash_forward(
-        q, k, v, causal=causal, scale=scale, block_q=block_q,
-        block_k=block_k, interpret=interpret, normalize=False,
-        window=window,
+        q, k, v, causal=causal, scale=scale, interpret=interpret,
+        normalize=False, window=window,
     )
     return out, l, m
 
@@ -848,7 +789,7 @@ def _partial_ref(q, k, v, causal, scale, k_offset, window=0):
     return acc, l, m
 
 
-def _partial_banded(q, k, v, scale, k_offset, window, block_k=128):
+def _partial_banded(q, k, v, scale, k_offset, window, block_k=SUB):
     """Causal banded partial for a TRACED ``k_offset`` (the ring's
     window-straddling block, where the offset depends on the device
     rank).  Scans K blocks with the online-softmax fold and
@@ -993,23 +934,23 @@ def _partial_stats_bwd(q, k, v, acc, l, ga, gl, gm, causal, scale,
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-def _flash_partial_fwd(q, k, v, causal, scale, block_q, block_k,
-                       interpret, k_offset, window):
-    out = _flash_partial(q, k, v, causal, scale, block_q, block_k,
-                         interpret, k_offset, window)
+def _flash_partial_fwd(q, k, v, causal, scale, interpret, k_offset,
+                       window):
+    out = _flash_partial(q, k, v, causal, scale, interpret, k_offset,
+                         window)
     acc, l, _ = out
     return out, (q, k, v, acc, l)
 
 
-def _flash_partial_bwd(causal, scale, block_q, block_k, interpret,
-                       k_offset, window, res, g):
+def _flash_partial_bwd(causal, scale, interpret, k_offset, window, res,
+                       g):
     q, k, v, acc, l = res
     ga, gl, gm = g
     tk = k.shape[2]
-    if tk % block_k == 0 and tk // block_k > 1:
+    if tk // SUB > 1:       # tk is a multiple of SUB: the kernel ran
         return _partial_stats_bwd(
-            q, k, v, acc, l, ga, gl, gm, causal, scale, k_offset,
-            block_k, window=window,
+            q, k, v, acc, l, ga, gl, gm, causal, scale, k_offset, SUB,
+            window=window,
         )
     _, vjp = jax.vjp(
         lambda q, k, v: _partial_ref(q, k, v, causal, scale, k_offset,
@@ -1023,8 +964,7 @@ _flash_partial.defvjp(_flash_partial_fwd, _flash_partial_bwd)
 
 
 def flash_attention_partial(q, k, v, causal=True, scale=None, k_offset=0,
-                            block_q=128, block_k=128, interpret=False,
-                            window=0):
+                            interpret=None, window=0):
     """Unnormalized online-softmax block attention: returns
     (acc [B,H,T,D] f32, l [B,H,T] f32, m [B,H,T] f32) for this KV block,
     ready to fold into a running (o, l, m) state — the per-shard step of
@@ -1035,19 +975,16 @@ def flash_attention_partial(q, k, v, causal=True, scale=None, k_offset=0,
     where absolute and local positions coincide) and every non-causal
     block; a non-zero offset (not needed by the ring's dispatch, which
     routes lower blocks as non-causal and skips upper ones) uses the jnp
-    reference."""
+    reference ``_partial_ref``, as does the mode ``off``."""
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     _check_window(window, causal)
-    t, d = q.shape[2], q.shape[3]
-    block_q = min(block_q, t)
-    block_k = min(block_k, t)
-    why = _unfriendly(t, d, block_q, block_k)
-    if causal and k_offset != 0:
-        why = "causal block with k_offset %d" % k_offset
-    if why:
-        if not interpret:
-            announce_fallback("flash_attention_partial", q.shape, why)
-        return _partial_ref(q, k, v, causal, scale, k_offset,
-                            window=window)
-    return _flash_partial(q, k, v, causal, scale, block_q, block_k,
-                          interpret, k_offset, window)
+    mode = resolve(interpret)
+    if mode != "off":
+        why = _unfriendly(q.shape[2], q.shape[3])
+        if causal and k_offset != 0:
+            why = "causal block with k_offset %d" % k_offset
+        if not why:
+            return _flash_partial(q, k, v, causal, scale,
+                                  mode == "interpret", k_offset, window)
+        announce_fallback("flash_attention_partial", q.shape, why, mode)
+    return _partial_ref(q, k, v, causal, scale, k_offset, window=window)

@@ -20,13 +20,11 @@ temporaries fit scoped VMEM even for the 112x112 stem map.
 Layouts: channels-last [..., C] (the conv layout everywhere in this
 framework); stats are over (spatial..., C/G) per group, matching
 flax.linen.GroupNorm semantics (models/resnet.py used nn.GroupNorm
-before this kernel).  Mode selection mirrors ops/flash_attention.py:
-``ELASTICDL_FUSED_GN=auto`` (compiled on TPU, jnp elsewhere),
-``interpret`` (for tests), ``off``.
+before this kernel).  Kernel, interpreter or the jnp reference:
+``ops/mode.py``'s answer, the one every Pallas op here asks.
 """
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -34,13 +32,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from elasticdl_tpu.ops.batch_shard import per_batch_shard
-
-
-def fused_gn_mode():
-    mode = os.environ.get("ELASTICDL_FUSED_GN", "auto")
-    if mode == "auto":
-        return "tpu" if jax.default_backend() == "tpu" else "off"
-    return mode
+from elasticdl_tpu.ops.mode import kernel_mode
 
 
 def _group_norm_ref(x, scale, bias, num_groups, eps, relu):
@@ -350,16 +342,16 @@ _fused.defvjp(
 def fused_group_norm(x, scale, bias, num_groups, eps=1e-6, relu=False):
     """GroupNorm + affine (+ ReLU) over the trailing channel axis.
 
-    x: [B, spatial..., C]; scale/bias: [C].  Dispatches to the Pallas
-    kernel per ELASTICDL_FUSED_GN, else the jnp reference.
+    x: [B, spatial..., C]; scale/bias: [C].  The Pallas kernel where
+    ``ops/mode.py`` allows one, else the jnp reference.
     """
     C = x.shape[-1]
     if C % num_groups:
         raise ValueError(
             "channels %d not divisible by %d groups" % (C, num_groups)
         )
-    mode = fused_gn_mode()
-    if mode in ("tpu", "interpret"):
+    mode = kernel_mode()
+    if mode != "off":
         return per_batch_shard(
             lambda x, scale, bias: _fused(x, scale, bias, num_groups, eps,
                                           relu, mode == "interpret"),

@@ -27,11 +27,13 @@ dout[rows of g]``, accumulated over a group's row tiles in VMEM and
 written as ``[X * K, N]`` (every result of these calls is 2-D: the
 benchmark tells the flash kernels apart by their 3-D results).
 
-Reference: ``grouped_matmul_ref`` (``lax.ragged_dot``), which is also the
-path under a model-parallel mesh and wherever ``flash_mode()`` is
-``off``.  ``interpret`` runs the kernels in Pallas interpret mode (CPU
-tests).  On a v5e, alone, at M = 131,072, 64 groups, 2048 x 1024: see
-PERF.md section 5 (``tools/grouped_matmul_on_chip.py``).
+Reference: ``grouped_matmul_ref`` (``lax.ragged_dot``), which is also
+what ``grouped_matmul`` itself returns wherever ``ops/mode.py`` answers
+``off`` (no TPU, the switch, a model-parallel mesh or a pipeline stage:
+``kernels_off()``).  An explicit ``interpret=`` forces the kernels, in
+Pallas interpret mode or compiled (tests, ``chip_check.py``).  On a
+v5e, alone, at M = 131,072, 64 groups, 2048 x 1024: see PERF.md
+section 5 (``tools/grouped_matmul_on_chip.py``).
 """
 
 import functools
@@ -43,6 +45,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from elasticdl_tpu.ops.flash_attention import announce_fallback
+from elasticdl_tpu.ops.mode import resolve
 
 ROW_TILE = 512
 # Rows of the blocks a tile shared by two groups is computed in: the
@@ -350,21 +353,24 @@ def _unfriendly(k, n, tm, itemsize):
 
 
 def grouped_matmul(lhs, rhs, group_sizes, transpose_rhs=False,
-                   interpret=False, row_tile_rows=None):
+                   interpret=None, row_tile_rows=None):
     """lhs [M, K] (rows sorted by group), rhs [X, K, N] (or [X, N, K]
     with ``transpose_rhs``), group_sizes [X] int32 adding up to M ->
     [M, N] in lhs's dtype, float32 accumulation.  Differentiable in lhs
-    and rhs.  ``row_tile_rows`` overrides the row tile (tests)."""
+    and rhs.  The kernels where ``ops/mode.py`` allows them and the
+    widths fit, else ``grouped_matmul_ref``.  ``row_tile_rows``
+    overrides the row tile (tests)."""
+    mode = resolve(interpret)
+    if mode == "off":
+        return grouped_matmul_ref(lhs, rhs, group_sizes, transpose_rhs)
     m, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     tm = row_tile_rows or row_tile(m)
-    compiled = not interpret
     why = _unfriendly(k, n, tm, lhs.dtype.itemsize)
-    if not why and compiled and (k % 128 or n % 128):
+    if not why and mode == "tpu" and (k % 128 or n % 128):
         why = "widths %dx%d are not multiples of the 128 lanes" % (k, n)
     if why:
-        if compiled:
-            announce_fallback("grouped_matmul", (m, k, n), why)
+        announce_fallback("grouped_matmul", (m, k, n), why, mode)
         return grouped_matmul_ref(lhs, rhs, group_sizes, transpose_rhs)
     return _gmm(lhs, rhs.astype(lhs.dtype), group_sizes.astype(jnp.int32),
-                transpose_rhs, interpret, tm)
+                transpose_rhs, mode == "interpret", tm)

@@ -1,0 +1,91 @@
+"""The routed FFN of a dropless mixture of experts: sort, gather, three
+grouped matmuls, un-sort (``models/transformer._moe_ffn`` routes and
+weighs the statistics; this is the multiply).
+
+``moe_experts`` is the op: it asks ``ops/mode.py`` which product runs
+(the Pallas ``grouped_matmul`` kernels, per shard of the trainer's data
+axis, or ``lax.ragged_dot``) and says so once per compiled shape (``moe
+dispatch:``, which the benchmark and ``chip_smoke.py`` read).
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from elasticdl_tpu.ops import flash_attention, grouped_matmul as gm
+from elasticdl_tpu.ops.batch_shard import per_batch_shard
+from elasticdl_tpu.ops.mode import kernel_mode
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _take_rows(k, x, order, inverse):
+    """``x[order // k]``: row i of the result is the row of x that
+    ``order[i]`` of the n * k (row, choice) assignments claims, and
+    ``inverse`` undoes ``order``, so the pullback is a gather and a sum
+    over a row's k claims, not a scatter-add.  With k = 1 it permutes
+    rows, by gathers both ways."""
+    return x[order // k]
+
+
+def _take_rows_bwd(k, inverse, g):
+    claims = g[inverse].reshape(-1, k, g.shape[-1])
+    return claims.astype(jnp.float32).sum(axis=1).astype(g.dtype), None, None
+
+
+_take_rows.defvjp(
+    lambda k, x, order, inverse: (x[order // k], inverse), _take_rows_bwd)
+
+
+def _moe_experts(h, gates, experts, w_gate, w_up, w_down):
+    """The routed FFN of the rows this device holds: sort the n * K
+    (token, choice) assignments by expert, gather their rows, three
+    grouped matmuls, un-sort and sum each token's K results weighted by
+    its gates.  O(n * K * width) memory, no capacity, nothing dropped.
+    Returns (out [b, T, E], load [1, X + 1]: rows per expert, then the
+    rows the grouped matmul computes beyond the real ones)."""
+    b, t, e = h.shape
+    x, k = w_gate.shape[0], experts.shape[-1]
+    n, rows = b * t, b * t * k
+    mode = kernel_mode()
+    if mode != "interpret":
+        announce_dispatch(n, x, k, mode)
+    flat = experts.reshape(rows)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    inverse = jnp.argsort(order).astype(jnp.int32)
+    sizes = (flat[:, None] == jnp.arange(x, dtype=flat.dtype)).sum(
+        axis=0, dtype=jnp.int32)
+    padded = (jnp.int32(0) if mode == "off"
+              else gm.padded_rows(sizes, rows))
+    matmul = gm.grouped_matmul      # asks the mode itself: as here
+    xs = _take_rows(k, h.reshape(n, e), order, inverse)
+    act = jax.nn.silu(matmul(xs, w_gate, sizes)) * matmul(xs, w_up, sizes)
+    ys = _take_rows(1, matmul(act, w_down, sizes), inverse, order)
+    out = jnp.einsum("nke,nk->ne", ys.reshape(n, k, e).astype(jnp.float32),
+                     gates.reshape(n, k))
+    load = jnp.concatenate([sizes, padded.reshape(1)])[None]
+    return out.astype(h.dtype).reshape(b, t, e), load
+
+
+@functools.lru_cache(maxsize=None)
+def announce_dispatch(tokens, experts, top_k, kernel):
+    """Once per compiled shape, by the logger ``announce_tiles`` uses:
+    what the dispatch hands the grouped matmul (of one shard of the
+    trainer's data axis, where there is one)."""
+    rows = tokens * top_k
+    tile = gm.row_tile(rows)
+    flash_attention.logger.info(
+        "moe dispatch: tokens=%d experts=%d top_k=%d rows=%d row_tile=%d "
+        "groups_tiles<=%d kernel=%s", tokens, experts, top_k, rows, tile,
+        -(-rows // tile) + experts - 1, kernel)
+
+
+def moe_experts(h, gates, experts, w_gate, w_up, w_down):
+    """h [B, T, E], gates and experts [B, T, K], the three expert
+    weights [X, ...] in h's dtype -> (out [B, T, E], load [shards,
+    X + 1]).  Where a kernel runs, once per shard of the declared batch
+    axis (weights whole on each); the reference partitions by itself."""
+    if kernel_mode() == "off":
+        return _moe_experts(h, gates, experts, w_gate, w_up, w_down)
+    return per_batch_shard(_moe_experts, (h, gates, experts),
+                           (w_gate, w_up, w_down))

@@ -8,13 +8,13 @@ accumulation flash attention uses, distributed over devices).  Peak memory
 per device is O(T/sp · T/sp) instead of O(T²), and the KV transfers ride
 ICI concurrently with compute.
 
-The per-shard block attention inside the fold is the Pallas flash
-kernel (ops/flash_attention.py ``flash_attention_partial``) when the
-platform supports it, so even the per-device T/sp x T/sp score matrix
-never materializes in the forward.  Causal folds dispatch per ring step:
-the diagonal block runs the causal kernel, blocks from lower ranks run
-the (cheaper) non-causal kernel, and blocks from higher ranks are
-skipped outright — about half the ring FLOPs for causal LMs.
+The per-shard block attention inside the fold is
+``ops/flash_attention.flash_attention_partial``: the Pallas flash kernel
+where ``ops/mode.py`` allows one, so even the per-device T/sp x T/sp
+score matrix never materializes in the forward.  Causal folds dispatch
+per ring step: the diagonal block runs the causal kernel, blocks from
+lower ranks run the (cheaper) non-causal kernel, and blocks from higher
+ranks are skipped outright — about half the ring FLOPs for causal LMs.
 
 Layout convention: [batch, seq, heads, head_dim]; heads shard over ``tp``,
 sequence over ``sp``, batch over ``dp``.
@@ -28,37 +28,33 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from elasticdl_tpu.ops.flash_attention import (
+    _check_window,
+    _partial_banded,
     announce_fallback,
+    flash_attention,
     flash_attention_partial,
-    flash_mode,
 )
+from elasticdl_tpu.ops.mode import kernels_off
 
 _NEG_INF = -1e30
 
 
-def _ring_attention_local(q, k, v, axis_name, causal, scale, mode="off",
-                          window=0):
+def _ring_attention_local(q, k, v, axis_name, causal, scale, window=0):
     """Per-device fold, [B, T/sp, H, D] shards in; the block math runs in
     [B, H, T, D] (the flash kernel's layout) and transposes back once."""
     axis_size = jax.lax.psum(1, axis_name)
     rank = jax.lax.axis_index(axis_name)
     b, tq, h, d = q.shape
-    interpret = mode == "interpret"
 
     qT = q.transpose(0, 2, 1, 3)                         # [B,H,Tq,D]
     kT = k.transpose(0, 2, 1, 3)
     vT = v.transpose(0, 2, 1, 3)
 
     def partial(qT, kT, vT, block_causal, block_window=0):
-        if mode in ("tpu", "interpret"):
-            return flash_attention_partial(
-                qT, kT, vT, causal=block_causal, scale=scale,
-                interpret=interpret, window=block_window,
-            )
-        from elasticdl_tpu.ops.flash_attention import _partial_ref
-
-        return _partial_ref(qT, kT, vT, block_causal, scale, 0,
-                            window=block_window)
+        return flash_attention_partial(
+            qT, kT, vT, causal=block_causal, scale=scale,
+            window=block_window,
+        )
 
     def skip_partial(qT):
         return (
@@ -92,8 +88,6 @@ def _ring_attention_local(q, k, v, axis_name, causal, scale, mode="off",
             # steps, since the straddle interval spans up to 2C-2 diffs)
             # run the blockwise banded partial with a rank-dependent
             # k offset — O(C·block_k) live, never the dense square.
-            from elasticdl_tpu.ops.flash_attention import _partial_banded
-
             delta = rank - src_rank
 
             def banded(ops):
@@ -148,43 +142,16 @@ def _ring_attention_local(q, k, v, axis_name, causal, scale, mode="off",
     return o.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
-def attention_local(q, k, v, causal=True, scale=None, mode=None,
-                    window=0):
-    """Single-device attention in ring layout [B, T, H, D].
-
-    Routes to the Pallas flash kernel (with its Pallas bwd) when the
-    platform allows — this is the sp=1 hot path the flagship
-    transformer hits; the jnp reference covers everything else.
-    ``window`` > 0 = sliding-window causal attention."""
-    from elasticdl_tpu.ops.flash_attention import _check_window
-
-    _check_window(window, causal)
-    scale = scale if scale is not None else q.shape[-1] ** -0.5
-    mode = flash_mode() if mode is None else mode
-    if mode in ("tpu", "interpret"):
-        from elasticdl_tpu.ops.flash_attention import flash_attention
-
-        o = flash_attention(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), causal=causal, scale=scale,
-            interpret=(mode == "interpret"), window=window,
-        )
-        return o.transpose(0, 2, 1, 3).astype(q.dtype)
-    s = jnp.einsum(
-        "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
-    ) * scale
-    if causal:
-        tq, tk = q.shape[1], k.shape[1]
-        diff = jnp.arange(tq)[:, None] - jnp.arange(tk)[None, :]
-        mask = diff >= 0
-        if window:
-            mask &= diff < window
-        s = jnp.where(mask[None, None], s, _NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum(
-        "bhqk,bkhd->bqhd", p, v, preferred_element_type=jnp.float32
+def attention_local(q, k, v, causal=True, scale=None, window=0):
+    """``ops.flash_attention.flash_attention`` in ring layout
+    [B, T, H, D]: what a ``shard_map`` body here and in ulysses.py runs
+    on its shard.  ``window`` > 0 = sliding-window causal attention."""
+    o = flash_attention(
+        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+        v.transpose(0, 2, 1, 3), causal=causal, scale=scale,
+        window=window,
     )
-    return o.astype(q.dtype)
+    return o.transpose(0, 2, 1, 3)
 
 
 def ring_attention(q, k, v, mesh, causal=True, scale=None,
@@ -196,23 +163,16 @@ def ring_attention(q, k, v, mesh, causal=True, scale=None,
     ``window`` > 0 = sliding-window causal attention; ring steps whose
     shard lies entirely outside the band skip compute AND the fold.
     """
-    from elasticdl_tpu.ops.flash_attention import _check_window
-
     _check_window(window, causal)
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     if mesh is None:
         return attention_local(q, k, v, causal=causal, scale=scale,
                                window=window)
-    mode = flash_mode()
     if mesh.shape.get(sp_axis, 1) == 1:
         dp = mesh.shape.get(dp_axis, 1)
         tp = mesh.shape.get(tp_axis, 1)
-        if (
-            mode in ("tpu", "interpret")
-            and q.shape[0] % dp == 0
-            and q.shape[2] % tp == 0
-        ):
-            # The Pallas kernel must run INSIDE a manual shard_map over
+        if q.shape[0] % dp == 0 and q.shape[2] % tp == 0:
+            # A Pallas kernel must run INSIDE a manual shard_map over
             # dp/tp: pallas_call is opaque to the partitioner, and under
             # a multi-device GSPMD jit JAX 0.9.0 refuses to lower a
             # Mosaic kernel at all ("cannot be automatically
@@ -223,7 +183,7 @@ def ring_attention(q, k, v, mesh, causal=True, scale=None,
             fn = shard_map(
                 functools.partial(
                     attention_local, causal=causal, scale=scale,
-                    mode=mode, window=window,
+                    window=window,
                 ),
                 mesh=mesh,
                 in_specs=(spec, spec, spec),
@@ -231,17 +191,15 @@ def ring_attention(q, k, v, mesh, causal=True, scale=None,
                 check_vma=False,
             )
             return fn(q, k, v)
-        if mode == "tpu":
-            announce_fallback(
-                "ring_attention", q.shape,
-                "batch %d / heads %d do not divide dp=%d / tp=%d, and "
-                "outside a shard_map the kernel cannot be partitioned"
-                % (q.shape[0], q.shape[2], dp, tp),
-            )
-        return attention_local(
-            q, k, v, causal=causal, scale=scale, mode="off",
-            window=window,
+        announce_fallback(
+            "ring_attention", q.shape,
+            "batch %d / heads %d do not divide dp=%d / tp=%d, and "
+            "outside a shard_map the kernel cannot be partitioned"
+            % (q.shape[0], q.shape[2], dp, tp),
         )
+        with kernels_off():
+            return attention_local(q, k, v, causal=causal, scale=scale,
+                                   window=window)
     sp = mesh.shape[sp_axis]
     tp = mesh.shape.get(tp_axis, 1)
     dp = mesh.shape.get(dp_axis, 1)
@@ -258,8 +216,7 @@ def ring_attention(q, k, v, mesh, causal=True, scale=None,
     fn = shard_map(
         functools.partial(
             _ring_attention_local,
-            axis_name=sp_axis, causal=causal, scale=scale, mode=mode,
-            window=window,
+            axis_name=sp_axis, causal=causal, scale=scale, window=window,
         ),
         mesh=mesh,
         in_specs=(spec, spec, spec),

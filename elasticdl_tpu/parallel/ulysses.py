@@ -23,8 +23,8 @@ wins when sp exceeds the head count (Ulysses requires
 matters more.  Both compose with dp/tp the same way.
 
 Autodiff passes straight through (the transpose of an all-to-all is
-the reverse all-to-all), so the backward inherits the flash kernel's
-block-recompute VJP unchanged.
+the reverse all-to-all), so the backward is the flash kernel's own
+(its Pallas pair) unchanged.
 
 Layout convention matches ring attention: [batch, seq, heads,
 head_dim]; batch shards over ``dp``, sequence over ``sp``, heads over
@@ -37,10 +37,11 @@ import jax
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
+from elasticdl_tpu.ops.flash_attention import _check_window
 from elasticdl_tpu.parallel.ring_attention import attention_local
 
 
-def _ulysses_local(q, k, v, sp_axis, causal, scale, mode, window):
+def _ulysses_local(q, k, v, sp_axis, causal, scale, window):
     """Per-device body: shards are [B, T/sp, H_local, D]."""
 
     def a2a_to_heads(x):
@@ -56,30 +57,23 @@ def _ulysses_local(q, k, v, sp_axis, causal, scale, mode, window):
 
     q, k, v = a2a_to_heads(q), a2a_to_heads(k), a2a_to_heads(v)
     out = attention_local(q, k, v, causal=causal, scale=scale,
-                          mode=mode, window=window)
+                          window=window)
     return a2a_to_seq(out)
 
 
 def ulysses_attention(q, k, v, mesh, causal=True, scale=None,
-                      dp_axis="dp", sp_axis="sp", tp_axis="tp",
-                      mode=None, window=0):
+                      dp_axis="dp", sp_axis="sp", tp_axis="tp", window=0):
     """All-to-all sequence-parallel attention over mesh axis ``sp``.
 
     q, k, v: [batch, seq, heads, head_dim] global (or sharded) arrays.
     Requires the per-tp-shard head count to be divisible by the sp
     extent.  Falls back to local attention when there is no sp extent.
     """
-    from elasticdl_tpu.ops.flash_attention import _check_window
-
     _check_window(window, causal)
     scale = scale if scale is not None else q.shape[-1] ** -0.5
-    if mode is None:
-        from elasticdl_tpu.ops.flash_attention import flash_mode
-
-        mode = flash_mode()
     if mesh is None or mesh.shape.get(sp_axis, 1) == 1:
         return attention_local(q, k, v, causal=causal, scale=scale,
-                               mode=mode, window=window)
+                               window=window)
     sp = mesh.shape[sp_axis]
     tp = mesh.shape.get(tp_axis, 1)
     heads_local = q.shape[2] // tp
@@ -92,7 +86,7 @@ def ulysses_attention(q, k, v, mesh, causal=True, scale=None,
     fn = shard_map(
         functools.partial(
             _ulysses_local, sp_axis=sp_axis, causal=causal, scale=scale,
-            mode=mode, window=window,
+            window=window,
         ),
         mesh=mesh,
         in_specs=(spec, spec, spec),

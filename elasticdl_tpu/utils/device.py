@@ -37,10 +37,8 @@ def place_compile_cache():
 
 
 def device_report():
-    """Initializes the backend and returns what it is, as plain values."""
-    from elasticdl_tpu.ops.flash_attention import flash_mode
-    from elasticdl_tpu.ops.group_norm import fused_gn_mode
-
+    """Initializes the backend and returns what it is, as plain values.
+    ``worker/main.py`` adds what its line says of the kernels."""
     devices = jax.devices()
     stats = [d.memory_stats() or {} for d in jax.local_devices()]
     return {
@@ -54,8 +52,6 @@ def device_report():
         # host numbers its own devices from 0, so this is what tells two
         # workers' chips apart.
         "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS", "all"),
-        "flash": flash_mode(),
-        "fused_gn": fused_gn_mode(),
         # Per local device; 0 where the backend keeps no statistics.
         # On the TPU a program's temporaries (activations, logits) are
         # not in ``peak_bytes_in_use`` but in ``peak_bytes_reserved``

@@ -496,10 +496,21 @@ class CollectiveTrainer(Trainer):
         constraints on the flat padded views (traceable; used inside
         the jitted step).
 
-        Two numerics pins make the trajectory BIT-IDENTICAL to the
-        replicated path (measured over 100 steps, bench_zero.py), which
-        is what lets the elastic churn drills verify zero1 worlds
-        exactly:
+        Two numerics pins make this program do the replicated path's
+        arithmetic on the same values in the same order, which is what
+        lets the elastic churn drills hold zero1 worlds to the
+        replicated trajectory.  Whether the two are then the same BITS
+        is the backend's to say, and on this toolchain neither backend
+        says yes beyond one shard.  XLA:CPU's fusion emitters round the
+        flat shard's update unlike the original shapes' (mu and nu
+        differ in the last bit from the second update on, a loss within
+        six steps; bit-identical again with
+        ``--xla_cpu_use_fusion_emitters=false``): tests/test_zero1.py
+        holds the CPU to 1e-6.  On the four chips of a v5e host a bf16
+        LM under AdamW differs from the first update on (11 of 12
+        losses, at most 3.1e-5 relative: ``chip_check.py zero1``, my
+        chip run, PR 28; on one chip, one shard, all 12 are the same
+        bits).  The pins keep the difference at that last-ulp level:
 
         1. grads are first constrained replicated — the cross-replica
            sum lands at the same program point as the replicated path's
@@ -598,35 +609,6 @@ class CollectiveTrainer(Trainer):
             donate_argnums=(0, 1),
         )
 
-    def build_fused_steps(self, num_steps):
-        """Compile num_steps optimizer steps into ONE XLA program over a
-        fixed device-resident batch — the steps-per-loop pattern that
-        amortizes host dispatch latency on TPU.  Returns
-        fn(params, opt_state, features, labels, weights) ->
-        (params, opt_state, last_loss)."""
-        raw = self._raw_step
-
-        def multi(params, opt_state, features, labels, weights):
-            def body(_i, carry):
-                params, opt_state, _ = carry
-                return raw(params, opt_state, features, labels, weights)
-
-            return jax.lax.fori_loop(
-                0, num_steps, body, (params, opt_state, jnp.float32(0))
-            )
-
-        if self._mesh is None:
-            return jax.jit(multi, donate_argnums=(0, 1))
-        rep = self._replicated
-        opt_sharding = self._opt_out_shardings()
-        return jax.jit(
-            multi,
-            in_shardings=(rep, opt_sharding, self._batch_sharding,
-                          self._batch_sharding, self._batch_sharding),
-            out_shardings=(rep, opt_sharding, rep),
-            donate_argnums=(0, 1),
-        )
-
     def _window_batch_sharding(self):
         """Sharding for window-stacked batch leaves: [K, batch, ...]
         shards dim 1 (the data axis); with accumulation the stack is
@@ -642,8 +624,7 @@ class CollectiveTrainer(Trainer):
     def build_fused_window(self, num_steps):
         """Compile num_steps optimizer steps over num_steps DISTINCT
         minibatches (stacked on the leading axis) into ONE XLA program —
-        the production fused-step path (``build_fused_steps`` reuses a
-        single device-resident batch and exists for the bench).
+        the fused-step path (``worker/fused_driver.py``).
 
         Returns fn(params, opt_state, features, labels, weights) ->
         (params, opt_state, losses[num_steps]); losses stay on device

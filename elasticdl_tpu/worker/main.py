@@ -9,6 +9,7 @@ import os
 
 from elasticdl_tpu.data.factory import create_data_reader
 from elasticdl_tpu.models.spec import load_model_spec
+from elasticdl_tpu.ops.mode import kernel_mode
 from elasticdl_tpu.utils import grpc_utils, tracing
 from elasticdl_tpu.utils.args import parse_worker_args
 from elasticdl_tpu.utils.checkpoint import CheckpointSaver
@@ -316,6 +317,19 @@ def xla_compiles_logged(steps_done):
         jax.monitoring.unregister_event_duration_listener(on_duration)
 
 
+def device_line():
+    """``device_report`` as the one ``key=value`` line a JAX-free parent
+    parses (``chip_smoke.py``, the benchmark), with what the kernels'
+    one mode is where the line has always said it, under both names it
+    has had: ``flash=`` and ``fused_gn=``, before the peaks."""
+    report = device_report()
+    peaks = {key: report.pop(key)
+             for key in ("peak_bytes_in_use", "peak_bytes_reserved")}
+    mode = kernel_mode()
+    return format_device_report(
+        {**report, "flash": mode, "fused_gn": mode, **peaks})
+
+
 def main(argv=None):
     import signal
 
@@ -331,8 +345,8 @@ def main(argv=None):
     cache_dir = place_compile_cache()
     # Which backend this process actually got, stated for a JAX-free
     # parent to check (the master only sees the log and the exit code).
-    logger.info("worker device: %s compile_cache=%s",
-                format_device_report(device_report()), cache_dir)
+    logger.info("worker device: %s compile_cache=%s", device_line(),
+                cache_dir)
     worker = None
     with xla_compiles_logged(
             lambda: worker.steps_done if worker is not None else 0):
@@ -360,7 +374,7 @@ def main(argv=None):
         else:
             worker.run()
         logger.info("worker end-of-run: steps=%d %s", worker.steps_done,
-                    format_device_report(device_report()))
+                    device_line())
         if worker.preempted:
             logger.info("worker preempted (checkpointed)")
             return PREEMPTED_EXIT_CODE

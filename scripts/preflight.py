@@ -21,8 +21,8 @@ Runs, in order, each in a fresh subprocess with the CPU platform pinned:
      losses bit-identical frame-vs-pb)
 
 These are CPU gates.  The chip is checked separately, by sending
-``python chip_smoke.py`` through the chip tool; ``bench.py`` measures the
-chip and is not a stage here (it exits non-zero without a TPU).  What the
+``python chip_smoke.py`` through the chip tool, and measured by
+``python3 benchmark/run.py`` there (neither is a stage here).  What the
 observability plane costs is measured on the chip too, by the benchmark
 (``BENCHMARK.json``; PERF.md section 6 has the traced rate beside the
 untraced one), not by a steps/s ratio on this CPU.

@@ -89,7 +89,7 @@ def test_reference_fallback_in_compiled_mode_is_announced():
 
     q = jnp.asarray(np.random.RandomState(0).randn(1, 2, 100, 48),
                     jnp.float32)
-    fa.announce_fallback.cache_clear()
+    fa._announce_once.cache_clear()
     with mock.patch.object(fa.logger, "warning") as warning:
         fa.flash_attention(q, q, q, interpret=False)   # the "tpu" mode
         fa.flash_attention(q, q, q, interpret=False)   # once per shape
@@ -97,7 +97,7 @@ def test_reference_fallback_in_compiled_mode_is_announced():
     assert warning.call_count == 1
     message = warning.call_args[0][0] % warning.call_args[0][1:]
     assert message.startswith(fa.FALLBACK_PREFIX)
-    assert "(1, 2, 100, 48)" in message and "block_k 100" in message
+    assert "(1, 2, 100, 48)" in message and "seq 100" in message
 
 
 def test_kernels_run_per_shard_of_the_declared_batch_axis(monkeypatch):
@@ -112,7 +112,7 @@ def test_kernels_run_per_shard_of_the_declared_batch_axis(monkeypatch):
     from elasticdl_tpu.ops import group_norm as gn
     from elasticdl_tpu.ops.batch_shard import batch_axis
 
-    monkeypatch.setenv("ELASTICDL_FUSED_GN", "interpret")
+    monkeypatch.setenv("ELASTICDL_FLASH", "interpret")
     mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
     rng = np.random.RandomState(0)
     x = jnp.asarray(rng.randn(8, 16, 64), jnp.float32)
@@ -149,7 +149,7 @@ def test_chip_check_tiny_mode_runs_the_checker(monkeypatch, capsys,
     # main() places the compile cache and, for --tiny, the GN mode:
     # with both already in the environment it changes nothing here.
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
-    monkeypatch.setenv("ELASTICDL_FUSED_GN", "interpret")
+    monkeypatch.setenv("ELASTICDL_FLASH", "interpret")
     import chip_check
 
     assert chip_check.main([]) == 1           # full size: needs the chip
@@ -177,7 +177,7 @@ def test_chip_check_tiny_mode_has_a_grouped_matmul_leg(monkeypatch, capsys,
 
     monkeypatch.syspath_prepend(REPO)
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
-    monkeypatch.setenv("ELASTICDL_FUSED_GN", "interpret")
+    monkeypatch.setenv("ELASTICDL_FLASH", "interpret")
     import chip_check
 
     assert chip_check.main(["--tiny", "grouped_matmul"]) == 0
@@ -197,7 +197,7 @@ def test_chip_check_tiny_mode_has_a_head_loss_leg(monkeypatch, capsys,
 
     monkeypatch.syspath_prepend(REPO)
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
-    monkeypatch.setenv("ELASTICDL_FUSED_GN", "interpret")
+    monkeypatch.setenv("ELASTICDL_FLASH", "interpret")
     import chip_check
 
     assert chip_check.main(["--tiny", "head_loss"]) == 0

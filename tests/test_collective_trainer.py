@@ -100,15 +100,19 @@ def test_elastic_mesh_rebuild(spec):
 
 
 def test_fused_steps_match_sequential(spec):
-    """K fused steps in one XLA program == K sequential step calls."""
+    """K fused steps in one XLA program (the fused window over the same
+    batch stacked K times) == K sequential step calls."""
     xs, ys = mnist.synthetic_data(n=16, seed=9)
     w = np.ones(16, np.float32)
     seq = CollectiveTrainer(spec, batch_size=16, rng_seed=2)
     fused_tr = CollectiveTrainer(spec, batch_size=16, rng_seed=2)
     for _ in range(3):
         seq.train_minibatch(xs, ys)
-    fused = fused_tr.build_fused_steps(3)
-    p, o, loss = fused(fused_tr._params, fused_tr._opt_state, xs, ys, w)
+    fused = fused_tr.build_fused_window(3)
+    p, o, losses = fused(fused_tr._params, fused_tr._opt_state,
+                         np.stack([xs] * 3), np.stack([ys] * 3),
+                         np.stack([w] * 3))
+    assert losses.shape == (3,)
     p_seq = seq.export_parameters()
     import jax
 

@@ -25,8 +25,7 @@ def make_qkv(b=2, h=2, t=256, d=64, seed=0):
 def test_flash_matches_reference(causal):
     q, k, v = make_qkv()
     ref = _attention_ref(q, k, v, causal, q.shape[-1] ** -0.5)
-    out = flash_attention(q, k, v, causal=causal, block_q=128,
-                          block_k=128, interpret=True)
+    out = flash_attention(q, k, v, causal=causal, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -49,20 +48,25 @@ def test_flash_multi_k_block_grid(causal, t):
                                rtol=2e-5, atol=2e-5)
 
 
-def test_flash_small_blocks_fall_back():
-    # block_k != 128 cannot lane-align with the kernel's stats tiles;
-    # the wrapper must take the dense reference path (and still be
-    # numerically right).
-    q, k, v = make_qkv(t=128, d=64)
+def test_flash_unaligned_seq_falls_back_and_says_so(monkeypatch):
+    # T = 192 is no multiple of the 128 lanes of the kernel's stats
+    # tiles: the op must take the reference path (and still be
+    # numerically right), and in the compiled mode say so.
+    import elasticdl_tpu.ops.flash_attention as fa
+
+    monkeypatch.setenv("ELASTICDL_FLASH", "tpu")
+    q, k, v = make_qkv(t=192, d=64)
     ref = _attention_ref(q, k, v, True, q.shape[-1] ** -0.5)
+    fa._announce_once.cache_clear()
     with mock.patch(
         "elasticdl_tpu.ops.flash_attention._flash",
-        side_effect=AssertionError("kernel must not run for block_k=64"),
-    ):
-        out = flash_attention(q, k, v, block_q=64, block_k=64,
-                              interpret=True)
+        side_effect=AssertionError("kernel must not run for T=192"),
+    ), mock.patch.object(fa.logger, "warning") as warning:
+        out = flash_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+    message = warning.call_args[0][0] % warning.call_args[0][1:]
+    assert message.startswith(fa.FALLBACK_PREFIX) and "seq 192" in message
 
 
 def test_flash_grad_matches_reference():
@@ -157,38 +161,6 @@ def test_pallas_bwd_matches_reference(causal, t, d, monkeypatch):
     g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     assert called.get("yes"), "pallas bwd was not invoked"
-    for a, b in zip(g1, g2):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-3, atol=1e-3)
-
-
-def test_xla_bwd_escape_hatch_matches(monkeypatch):
-    """ELASTICDL_FLASH_BWD=xla routes through the block-recompute scan
-    (the A/B partner of the Pallas backward)."""
-    import elasticdl_tpu.ops.flash_attention as fa
-
-    monkeypatch.setenv("ELASTICDL_FLASH_BWD", "xla")
-    called = {}
-    orig = fa._blockwise_bwd
-
-    def spy(*args, **kwargs):
-        called["yes"] = True
-        return orig(*args, **kwargs)
-
-    monkeypatch.setattr(fa, "_blockwise_bwd", spy)
-    q, k, v = make_qkv(t=256)
-
-    def loss_flash(q, k, v):
-        return (fa.flash_attention(q, k, v, interpret=True) ** 2).sum()
-
-    def loss_ref(q, k, v):
-        return (
-            fa._attention_ref(q, k, v, True, q.shape[-1] ** -0.5) ** 2
-        ).sum()
-
-    g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    assert called.get("yes"), "xla block-recompute bwd was not invoked"
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-3, atol=1e-3)

@@ -49,7 +49,7 @@ def one_chip():
 ])
 def test_flash_fwd_bwd_compile_for_v5e(one_chip, t, d, dtype, window):
     x = jax.ShapeDtypeStruct((1, 16, t, d), dtype, sharding=one_chip)
-    static = (True, d ** -0.5, 128, 128, False, window)
+    static = (True, d ** -0.5, False, window)
 
     def fwd_bwd(q, k, v, g):
         out, res = fa._flash_fwd(q, k, v, *static)
@@ -67,7 +67,7 @@ def test_flash_partial_compiles_for_v5e(one_chip, causal):
                              sharding=one_chip)
     text = jax.jit(
         lambda q, k, v: fa._flash_forward(
-            q, k, v, causal, 0.125, 128, 128, False, normalize=False)
+            q, k, v, causal, 0.125, False, normalize=False)
     ).lower(x, x, x).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
 
@@ -92,7 +92,9 @@ def test_grouped_matmul_fwd_bwd_compile_for_v5e(one_chip, k, n, dtype):
 
     def fwd_bwd(lhs, rhs, sizes, cot):
         out, vjp = jax.vjp(
-            lambda lhs, rhs: gm.grouped_matmul(lhs, rhs, sizes), lhs, rhs)
+            lambda lhs, rhs: gm.grouped_matmul(lhs, rhs, sizes,
+                                               interpret=False),
+            lhs, rhs)
         return out, vjp(cot)
 
     text = jax.jit(fwd_bwd).lower(lhs, rhs, sizes, cot).compile().as_text()

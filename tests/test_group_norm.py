@@ -11,7 +11,7 @@ from elasticdl_tpu.ops import group_norm as gn
 
 @pytest.fixture(autouse=True)
 def interpret_mode(monkeypatch):
-    monkeypatch.setenv("ELASTICDL_FUSED_GN", "interpret")
+    monkeypatch.setenv("ELASTICDL_FLASH", "interpret")
 
 
 def _flax_gn(x, scale, bias, num_groups, relu):
@@ -96,7 +96,7 @@ def test_large_mean_variance_stability():
 
 
 def test_off_mode_matches(monkeypatch):
-    monkeypatch.setenv("ELASTICDL_FUSED_GN", "off")
+    monkeypatch.setenv("ELASTICDL_FLASH", "off")
     rng = np.random.RandomState(3)
     x = jnp.asarray(rng.randn(2, 4, 4, 32), jnp.float32)
     scale = jnp.ones((32,), jnp.float32)

@@ -272,21 +272,25 @@ def test_full_pipeline_converges_close_to_serialized():
                 async_push_window=1 if pipelined else 0,
             )
             data = batches(spec, n=320)
-            first = last = None
+            losses = []
             for step in range(50):
                 if pipelined:
                     trainer.prefetch_embeddings(
                         data[(step + 1) % len(data)][0]
                     )
-                last, _ = trainer.train_minibatch(
+                loss, _ = trainer.train_minibatch(
                     *data[step % len(data)]
                 )
-                if first is None:
-                    first = last
+                losses.append(float(loss))
             trainer.drain_pushes()
             client = make_client(addrs)
             _, _, dense = client.pull_dense_parameters(-1)
-            results.append((first, last, dense))
+            # Like with like: the same batches, ten passes apart (one
+            # batch's loss is no yardstick for another's: at this
+            # learning rate they differ by more than 50 steps move them).
+            epoch = len(data)
+            results.append((np.mean(losses[:epoch]),
+                            np.mean(losses[-epoch:]), dense))
             if pipelined:
                 hits = trainer.timing.counters().get("prefetch_hit", 0)
                 assert hits > 0  # the prefetcher actually served pulls

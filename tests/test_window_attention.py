@@ -22,6 +22,7 @@ from elasticdl_tpu.ops.flash_attention import (
     flash_attention,
     flash_attention_partial,
 )
+from elasticdl_tpu.ops.mode import kernels_off
 from elasticdl_tpu.parallel.mesh import build_mesh
 from elasticdl_tpu.parallel.ring_attention import (
     attention_local,
@@ -132,8 +133,8 @@ def test_ring_window_blockwise_banded():
     q, k, v = make_bthd(b=1, t=1024, h=1, d=32, seed=11)
     mesh = build_mesh(sp=4, devices=jax.devices()[:4])
     for window in (300, 700):
-        ref = attention_local(q, k, v, causal=True, window=window,
-                              mode="off")
+        with kernels_off():
+            ref = attention_local(q, k, v, causal=True, window=window)
         out = ring_attention(q, k, v, mesh, causal=True, window=window)
         np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
                                    rtol=2e-5, atol=2e-5)
@@ -171,33 +172,6 @@ def test_flash_window_pallas_bwd(t, window, monkeypatch):
     g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     assert called.get("yes"), "pallas bwd was not invoked"
-    for a, b in zip(g1, g2):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-3, atol=1e-3)
-
-
-@pytest.mark.parametrize("window", [64, 200])
-def test_flash_window_xla_bwd(window, monkeypatch):
-    """The block-recompute escape hatch must honor the window too."""
-    import elasticdl_tpu.ops.flash_attention as fa
-
-    monkeypatch.setenv("ELASTICDL_FLASH_BWD", "xla")
-    q, k, v = make_bhtd(seed=3)
-
-    def loss_flash(q, k, v):
-        return (
-            fa.flash_attention(q, k, v, causal=True, interpret=True,
-                               window=window) ** 2
-        ).sum()
-
-    def loss_ref(q, k, v):
-        return (
-            fa._attention_ref(q, k, v, True, q.shape[-1] ** -0.5,
-                              window=window) ** 2
-        ).sum()
-
-    g1 = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-3, atol=1e-3)
@@ -295,8 +269,8 @@ def test_ring_window_grad_banded_scan():
         return (out * out).sum()
 
     def loss_ref(q, k, v):
-        out = attention_local(q, k, v, causal=True, window=300,
-                              mode="off")
+        with kernels_off():
+            out = attention_local(q, k, v, causal=True, window=300)
         return (out * out).sum()
 
     g = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
@@ -313,8 +287,8 @@ def test_ring_window_flash_fold(monkeypatch):
     q, k, v = make_bthd(b=1, t=512, h=1, d=64, seed=4)
     mesh = build_mesh(sp=4, devices=jax.devices()[:4])
     for window in (100, 300):
-        ref = attention_local(q, k, v, causal=True, window=window,
-                              mode="off")
+        with kernels_off():
+            ref = attention_local(q, k, v, causal=True, window=window)
         out = ring_attention(q, k, v, mesh, causal=True, window=window)
         np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
                                    rtol=2e-5, atol=2e-5)

@@ -5,8 +5,10 @@ The contract under test, end to end:
  - full coverage: EVERY non-scalar optimizer leaf shards (flat padded
    dim 0 over the data axis), including the odd shapes the old stub
    silently replicated;
- - trajectory: zero1 on vs off is BIT-identical, per-step and through
-   fused windows, with and without gradient accumulation;
+ - trajectory: zero1 on vs off is the same trajectory, per-step and
+   through fused windows, with and without gradient accumulation: to
+   the last ulp or two on this backend (``LAST_ULPS`` below says why
+   not bit for bit; ``chip_check.py zero1`` says what the chip gives);
  - elastic: a world re-form re-partitions live shards device-to-device
    with Adam moments preserved bit-exactly, and a same-size re-form
    continues the trajectory bitwise;
@@ -50,26 +52,39 @@ def assert_trees_bitwise(a, b):
 
 # -- trajectory equivalence ------------------------------------------------
 
+# The two programs do the same arithmetic on the same values, but this
+# toolchain's XLA:CPU compiles an elementwise AdamW update to different
+# roundings for the flat 1/N shard and for the original shapes: the
+# second update already differs in the last bit of mu and nu, and a
+# loss follows within six steps (1.0593639612 against 1.0593638420).
+# With --xla_cpu_use_fusion_emitters=false (or optimization level 0) the
+# same 12 steps are bit-identical (PR 28), so the difference is the
+# backend's code generation, not the sharded update.  A wrong slice, a
+# missing sum or a leaked padding row is orders of magnitude over this.
+LAST_ULPS = dict(rtol=1e-6, atol=0)
+
 
 def test_zero1_per_step_bitwise_equivalence(spec):
-    """Same seed, same batches: zero1 losses == replicated losses,
-    float-exact, over enough steps for 1-ulp drift to show if the
-    update were not numerically pinned."""
+    """Same seed, same batches: zero1 losses == replicated losses to
+    the last ulps (``LAST_ULPS``), the first step's bit for bit (its
+    update starts from zero moments and rounds once)."""
     xs, ys = mnist.synthetic_data(n=64, seed=21)
     mesh = make_mesh(8)
     base = CollectiveTrainer(spec, batch_size=64, mesh=mesh, rng_seed=7)
     z1 = CollectiveTrainer(spec, batch_size=64, mesh=mesh, rng_seed=7,
                            zero1=True)
-    for _ in range(12):
+    for step in range(12):
         loss_b, _ = base.train_minibatch(xs, ys)
         loss_z, _ = z1.train_minibatch(xs, ys)
-        assert float(loss_b) == float(loss_z)
+        if step < 2:    # the loss before, and after, the first update
+            assert float(loss_b) == float(loss_z)
+        np.testing.assert_allclose(loss_z, loss_b, **LAST_ULPS)
 
 
 @pytest.mark.parametrize("window", [1, 4])
 def test_zero1_fused_window_bitwise_equivalence(spec, window):
     """K fused steps per dispatch: the zero1 window (opt-state carry =
-    1/N flat shards) reproduces the replicated window bit-for-bit."""
+    1/N flat shards) reproduces the replicated window (``LAST_ULPS``)."""
     xs, ys = mnist.synthetic_data(n=64, seed=23)
     mesh = make_mesh(8)
     base = CollectiveTrainer(spec, batch_size=64, mesh=mesh, rng_seed=9)
@@ -80,7 +95,8 @@ def test_zero1_fused_window_bitwise_equivalence(spec, window):
         pz = [z1.prepare_batch(xs, ys) for _ in range(window)]
         lb, _ = base.train_window(base.stage_window(pb))
         lz, _ = z1.train_window(z1.stage_window(pz))
-        np.testing.assert_array_equal(np.asarray(lb), np.asarray(lz))
+        np.testing.assert_allclose(np.asarray(lz), np.asarray(lb),
+                                   **LAST_ULPS)
 
 
 def test_zero1_accum_bitwise_equivalence(spec):

@@ -15,6 +15,7 @@ Exits 3 without a TPU: a CPU timing is no device number.
 
 import argparse
 import collections
+import inspect
 import json
 import os
 import sys
@@ -56,7 +57,10 @@ def main():
     shape = (1, args.bh, args.t, args.d)
     q, k, v, g = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
                   for _ in range(4))
-    static = (True, args.d ** -0.5, 128, 128, False, args.window)
+    static = (True, args.d ** -0.5, False, args.window)
+    if "block_q" in inspect.signature(fa._flash_fwd).parameters:
+        # --root at a checkout from before PR 28: block_q, block_k
+        static = static[:2] + (128, 128) + static[2:]
     fwd = jax.jit(lambda q, k, v: fa._flash_fwd(q, k, v, *static))
     bwd = jax.jit(lambda res, g: fa._flash_bwd(*static, res, g))
     dq_only = jax.jit(lambda res, g: fa._flash_bwd(*static, res, g)[0])

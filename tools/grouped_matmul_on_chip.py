@@ -61,8 +61,9 @@ def main():
     import numpy as np
 
     from benchmark.lib import peaks, xplane
-    from elasticdl_tpu.models import transformer as tfm
     from elasticdl_tpu.ops import grouped_matmul as gm
+    from elasticdl_tpu.ops.mode import kernels_off
+    from elasticdl_tpu.ops.moe_dispatch import moe_experts
 
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -89,9 +90,8 @@ def main():
             gm.ROW_TILE = tile or gm.ROW_TILE
             for kernel in ["tpu"] + ["ref"] * args.reference:
                 def loss(h, wg, wu, wd):
-                    out, _ = tfm._moe_experts(
-                        h, gates, experts, wg, wu, wd,
-                        kernel=kernel if kernel == "tpu" else "")
+                    with kernels_off(kernel != "tpu"):
+                        out, _ = moe_experts(h, gates, experts, wg, wu, wd)
                     return (out.astype(jnp.float32)
                             * cot.astype(jnp.float32)).sum()
 
