@@ -80,6 +80,16 @@ TOLERANCES["step_vs_reference_gn"] = 2e-2
 # downstream (tests/test_zero1.py::LAST_ULPS).  A wrong slice or a
 # missing sum moves the loss by far more than this.
 TOLERANCES["zero1_rel_diff"] = 1e-3
+# remat=true with the names the room holds kept against nothing kept:
+# the kept values are the ones the second forward would have produced,
+# by the same kernels on the same values.  What differs is where XLA
+# rounds to bf16 inside the fusions it forms around them: in --tiny on
+# the CPU every gradient leaf moves by ~1.3e-2 of its norm and the loss
+# by 2e-5, while the float32 stacks of tests/test_remat_keep.py agree
+# to 1e-7.  ``keep_grad`` is the worst leaf's max error over its max,
+# ``keep_grad_l2`` the whole tree's difference over its norm.
+TOLERANCES["keep_loss"] = 1e-4
+TOLERANCES["keep_grad"] = TOLERANCES["keep_grad_l2"] = _BF16_GRAD
 
 
 def _rel_err(got, want):
@@ -410,6 +420,65 @@ def check_zero1(steps=12):
             "zero1_steps_differing": int((sharded != base).sum())}
 
 
+def check_remat_keep(tiny):
+    """One loss-and-gradients evaluation of an LM with ``remat=true``
+    through the trainer (``chip_smoke.py``'s ``lm_local`` model, over
+    every device this process has), with what ``models/remat_keep.py``
+    keeps in the room the trainer states here, against the same
+    evaluation with no room stated (nothing kept).  ``--tiny`` states a
+    room itself: the CPU has no limit."""
+    from jax.sharding import Mesh
+
+    from elasticdl_tpu.models import remat_keep, transformer as tfm
+    from elasticdl_tpu.ops.batch_shard import DeviceRoom
+    from elasticdl_tpu.worker.collective_trainer import CollectiveTrainer
+
+    devices = jax.devices()
+    sizes = (dict(vocab_size=256, dim=128, num_heads=2, num_layers=2,
+                  seq_len=256) if tiny else
+             dict(vocab_size=32768, dim=1024, num_heads=16, num_layers=24,
+                  seq_len=2048))
+    per_device = 1 if tiny else 4
+    spec = tfm.model_spec(remat=True, **sizes)
+    batch = per_device * len(devices)
+    tokens = np.random.RandomState(9).randint(
+        0, sizes["vocab_size"], size=(batch, sizes["seq_len"])
+    ).astype(np.int32)
+    mesh = Mesh(np.array(devices), axis_names=("data",))
+    trainer = CollectiveTrainer(spec, batch_size=batch, mesh=mesh,
+                                rng_seed=3)
+    prepared = trainer.prepare_batch(tokens, tokens)
+    room = DeviceRoom(10 ** 12, 10 ** 12 - 1) if tiny else trainer._room
+    if room is None:
+        raise AssertionError("the backend states no bytes_limit")
+    names, kept, budget, peak = remat_keep.choose(
+        spec.config, trainer._params, per_device * sizes["seq_len"], room)
+    print(json.dumps({"remat_keep": "chosen", "devices": len(devices),
+                      "names": names, "bytes": kept, "budget": budget,
+                      "predicted_peak": peak, "room": list(room)}),
+          flush=True)
+    if not names:
+        raise AssertionError("nothing fits the room %s" % (room,))
+
+    def evaluate(room):
+        trainer._room = room        # read where the step is traced
+        loss, grads, _ = jax.jit(trainer._loss_and_grads)(
+            trainer._params, prepared.features, prepared.labels,
+            prepared.weights)
+        return float(loss), grads
+
+    loss, grads = evaluate(room)
+    base_loss, base_grads = evaluate(None)
+    pairs = list(zip(jax.tree_util.tree_leaves(grads),
+                     jax.tree_util.tree_leaves(base_grads)))
+    norm = lambda trees: float(jnp.sqrt(sum(
+        jnp.sum(jnp.square(t.astype(jnp.float32))) for t in trees)))
+    return {"keep_loss": abs(loss - base_loss) / abs(base_loss),
+            "keep_grad": max(_rel_err(g, b) for g, b in pairs),
+            "keep_grad_l2": norm([g - b for g, b in pairs])
+            / norm([b for _, b in pairs])}
+
+
 def _cases(tiny):
     interpret = tiny
     if tiny:
@@ -454,6 +523,8 @@ def _cases(tiny):
     yield ("resnet_step/%s.b%d" % (variant, batch),
            lambda: check_resnet_step(variant, image_size, batch, classes))
     yield ("zero1/lm.adamw.%ddevices" % jax.device_count(), check_zero1)
+    yield ("remat_keep/lm.%ddevices" % jax.device_count(),
+           lambda: check_remat_keep(tiny))
 
 
 def main(argv=None):

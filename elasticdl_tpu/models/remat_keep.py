@@ -1,0 +1,156 @@
+"""What ``remat=True`` keeps: the residuals of the scanned layer stack
+that fit the memory a device has left, chosen from shapes.
+
+``jax.checkpoint`` with no policy saves nothing of a layer, and the
+backward runs every layer's forward a second time.  The ops name the
+values their backward reads where they make them (``checkpoint_name``);
+``choose`` walks ``table``, an order of what a byte buys, and takes each
+entry whose bytes (one shard's rows, all layers: the scan stacks what a
+layer keeps) still fit the budget; ``models/transformer.forward_hidden``
+turns the names into ``save_only_these_names``.  The kept values are the
+ones the second forward would have produced, so the gradients are the
+same bits' worth of arithmetic, done once.
+
+The budget is what the caller's ``batch_shard.DeviceRoom`` leaves once
+``step_bytes`` (what this model's step needs with nothing kept) and a
+reserve of ``RESERVE`` of the limit are taken off.  No room stated (the
+CPU, a model-parallel mesh, the pipelined forward): nothing kept, the
+program ``jax.checkpoint(layer)`` always gave.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from elasticdl_tpu.ops import batch_shard, flash_attention, moe_dispatch
+
+# Named in models/transformer.py: q, k, v as the attention takes them
+# (after RoPE and the QK norm), the stream after the attention's
+# residual add, the router's results, a dense FFN's two products.
+KEEP_Q, KEEP_K, KEEP_V = "attn_q", "attn_k", "attn_v"
+KEEP_STREAM = "attn_stream"
+KEEP_ROUTE = "moe_route"
+KEEP_GATE, KEEP_UP = "ffn_gate", "ffn_up"
+
+# The share of the device's limit nothing is planned into: the
+# allocator's fragmentation, the batches in flight, whatever
+# ``step_bytes`` does not see.
+RESERVE = 0.05
+
+# remat="attn": the first entry whatever the room.
+ATTN_NAMES = (flash_attention.KEEP_OUT, flash_attention.KEEP_LSE)
+
+
+def table(cfg, rows):
+    """[(label, names, bytes a layer)] for ``rows`` tokens a device, in
+    the order of what a kept byte saves of the second forward (ms a GB,
+    from the traces of PERF.md section 5: the flash forward ~18; the
+    un-sorted down product, a grouped matmul and a gather, ~14; q, k, v
+    and the stream ~13; the FFN's products ~12; the sorted rows, one
+    gather, ~8).  Elementwise work (norms, RoPE's rotation, the
+    activation, the weighted combine) is not here: it is cheap and its
+    inputs are what is kept."""
+    size = jnp.dtype(cfg.dtype).itemsize
+    e, h, g, d, f = (cfg.dim, cfg.num_heads, cfg.kv_heads, cfg.head_dim,
+                     cfg.mlp_dim)
+    entries = [
+        ("flash", ATTN_NAMES, rows * h * (d * size + 4)),
+    ]
+    if cfg.moe_experts:
+        x = cfg.moe_experts
+        k = min(cfg.moe_top_k, x)
+        # probs f32 [rows, X]; gates f32, experts, order, inverse int32
+        # [rows, k]; sizes [X]
+        entries.append(("route", (KEEP_ROUTE, moe_dispatch.KEEP_SORT),
+                        4 * (rows * (x + 4 * k) + x)))
+    entries += [
+        ("qkv", (KEEP_Q, KEEP_K, KEEP_V), rows * (h + 2 * g) * d * size),
+        ("stream", (KEEP_STREAM,), rows * e * size),
+    ]
+    if cfg.moe_experts:
+        entries += [
+            ("moe_out", (moe_dispatch.KEEP_OUT,), rows * k * e * size),
+            ("moe_gate", (moe_dispatch.KEEP_GATE,), rows * k * f * size),
+            ("moe_up", (moe_dispatch.KEEP_UP,), rows * k * f * size),
+            ("moe_rows", (moe_dispatch.KEEP_ROWS,), rows * k * e * size),
+        ]
+    else:
+        entries += [
+            ("ffn_gate", (KEEP_GATE,), rows * f * size),
+            ("ffn_up", (KEEP_UP,), rows * f * size),
+        ]
+    return entries
+
+
+def step_bytes(cfg, params, rows):
+    """Bytes one device needs for a training step of this model on
+    ``rows`` tokens with nothing kept, beside the state its caller
+    holds (parameters, optimizer state, gradients):
+
+     - the compute-dtype copies of the parameters (XLA hoists the
+       stack's ``astype`` out of the scan: all layers' at once);
+     - the carries the scan saves, one stream a layer;
+     - the larger of the two places the peak can be: the head
+       (``ops/head_loss.py``: the logits, and their cotangent where the
+       head is tied), while the stack's gradients, which the caller
+       counted, do not exist yet; or one layer's backward with its
+       second forward.
+
+    Held to the compiler's own count for the three cells of the
+    benchmark (tests/test_remat_keep.py: +0.2 GB on the dense cells, whose
+    peak is at the head; +0.8 GB on the one-layer MoE, where the
+    compiler never holds all the gradients the caller counted)."""
+    dtype = jnp.dtype(cfg.dtype)
+    size = dtype.itemsize
+    leaves = jax.tree_util.tree_leaves
+    copies = sum(a.size * size for a in leaves(params) if a.dtype != dtype)
+    stack_grads = sum(a.size * jnp.dtype(a.dtype).itemsize
+                      for a in leaves(params["layers"]))
+    stream = rows * cfg.dim * size
+    carries = (cfg.num_layers + 1) * stream
+    head = rows * cfg.vocab_size * size * (2 if cfg.tied_embeddings else 1)
+    if cfg.moe_experts:
+        k = min(cfg.moe_top_k, cfg.moe_experts)
+        layer = rows * k * (cfg.dim + 2 * cfg.mlp_dim) * size
+    else:
+        layer = rows * 4 * cfg.mlp_dim * size
+    return copies + carries + max(head - stack_grads, layer)
+
+
+def choose(cfg, params, rows, room):
+    """(names kept, their bytes a device, the budget, the peak predicted
+    with them) for ``rows`` tokens a device under ``room``."""
+    need = step_bytes(cfg, params, rows)
+    budget = int(room.free - need - RESERVE * room.limit)
+    names, kept = [], 0
+    for _label, entry, per_layer in table(cfg, rows):
+        nbytes = per_layer * cfg.num_layers
+        if kept + nbytes <= budget:
+            names += entry
+            kept += nbytes
+    return tuple(names), kept, budget, room.limit - room.free + need + kept
+
+
+@functools.lru_cache(maxsize=None)
+def announce_keep(names, kept, budget, peak, layers, rows, fallback):
+    """Once per compiled shape, by the logger ``announce_tiles`` uses:
+    what the layer stack keeps for its backward, of one shard of the
+    trainer's data axis."""
+    flash_attention.logger.info(
+        "remat keep: names=%s bytes=%d budget=%d predicted_peak=%d "
+        "layers=%d rows=%d fallback=%d", ",".join(names) or "-", kept,
+        budget, peak, layers, rows, fallback)
+
+
+def names_for(cfg, params, tokens_shape):
+    """The names ``remat=True`` saves for a [B, T] batch traced here:
+    () where no ``DeviceRoom`` is declared."""
+    room = batch_shard.device_room()
+    if room is None:
+        return ()
+    rows = tokens_shape[0] * tokens_shape[1] // batch_shard.shards()
+    names, kept, budget, peak = choose(cfg, params, rows, room)
+    announce_keep(names, kept, budget, peak, cfg.num_layers, rows,
+                  int(not room.free))
+    return names

@@ -24,8 +24,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from elasticdl_tpu.models import remat_keep
 from elasticdl_tpu.models.spec import ModelSpec
 from elasticdl_tpu.ops.flash_attention import flash_attention
 from elasticdl_tpu.ops.mode import kernels_off
@@ -61,13 +63,18 @@ class TransformerConfig:
     moe_norm_topk: bool = True
     moe_aux_weight: float = 0.01
     # Rematerialize each scanned layer in the backward pass instead of
-    # saving its activations — O(1)-layers activation memory for ~1/3
-    # more FLOPs.  Required to fit training-scale configs (24 layers x
-    # T=2048 saves ~20 GB of activations un-remat'ed on one chip).
-    # True = save nothing; "dots" = save matmul outputs and recompute
-    # only the cheap elementwise work (more memory, fewer re-FLOPs);
-    # "attn" = save only the attention outputs (B*T*dim per layer), so
-    # the recompute skips flash attention but everything else remats.
+    # saving all its activations (24 layers x T=2048 save ~20 GB of
+    # them un-remat'ed on one chip).  True = recompute what does not
+    # fit: of the values the backward reads, the ops name the dear ones,
+    # and the stack keeps the names whose bytes fit what the trainer
+    # says a device has left (models/remat_keep.py; the worker's
+    # ``remat keep:`` line says which).  Where no memory is stated (the
+    # CPU, a model-parallel mesh, the pipelined forward) nothing is
+    # kept and the backward runs every layer's forward again, ~1/3 more
+    # FLOPs.  "attn" = keep the flash kernel's output and row statistics
+    # whatever the room, so the recompute skips flash attention;
+    # "dots" = keep every matmul's output and recompute the elementwise
+    # work alone (more memory than a chip has at training sizes).
     remat: bool | str = False
     # Sequence-parallel strategy over the ``sp`` mesh axis: "ring"
     # (ppermute K/V streaming, parallel/ring_attention.py) or "ulysses"
@@ -235,9 +242,11 @@ def moe_route(h, w_router, cfg):
         "bte,ex->btx", h.astype(jnp.float32),
         w_router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, experts = jax.lax.top_k(
-        probs, min(cfg.moe_top_k, cfg.moe_experts))
+    probs = checkpoint_name(jax.nn.softmax(logits, axis=-1),
+                            remat_keep.KEEP_ROUTE)
+    gates, experts = (
+        checkpoint_name(a, remat_keep.KEEP_ROUTE)
+        for a in jax.lax.top_k(probs, min(cfg.moe_top_k, cfg.moe_experts)))
     if cfg.moe_norm_topk and gates.shape[-1] > 1:
         # GShard-style renormalization over the chosen experts.  Top-1
         # keeps the raw p_top1 gate (Switch): renormalizing would make
@@ -301,7 +310,10 @@ def _project_qkv(h, w, cfg, positions):
         k = _rmsnorm(k, w["k_norm"].astype(compute_dtype), cfg.norm_eps)
     k = k.reshape(B, T, G, D)
     v = (h @ w["wv"].astype(compute_dtype)).reshape(B, T, G, D)
-    return _rope(q, positions), _rope(k, positions), v
+    # as the attention takes them: what its backward reads
+    return (checkpoint_name(_rope(q, positions), remat_keep.KEEP_Q),
+            checkpoint_name(_rope(k, positions), remat_keep.KEEP_K),
+            checkpoint_name(v, remat_keep.KEEP_V))
 
 
 def _ffn(x, w, cfg, mesh):
@@ -313,10 +325,13 @@ def _ffn(x, w, cfg, mesh):
     if cfg.moe_experts:
         out, aux, stats, load = _moe_ffn(h, w, cfg, mesh)
         return x + _constrain(out, mesh, act_spec), aux, stats, load
-    gate = jax.nn.silu(h @ w["w_gate"].astype(compute_dtype))
-    up = h @ w["w_up"].astype(compute_dtype)
+    gate = checkpoint_name(h @ w["w_gate"].astype(compute_dtype),
+                           remat_keep.KEEP_GATE)
+    up = checkpoint_name(h @ w["w_up"].astype(compute_dtype),
+                         remat_keep.KEEP_UP)
     x = x + _constrain(
-        (gate * up) @ w["w_down"].astype(compute_dtype), mesh, act_spec,
+        (jax.nn.silu(gate) * up) @ w["w_down"].astype(compute_dtype),
+        mesh, act_spec,
     )
     return x, jnp.float32(0.0), None, None
 
@@ -363,16 +378,10 @@ def _attention(x, w, cfg, mesh, positions):
         attn = ring_attention(q, k, v, mesh, causal=True,
                               window=cfg.window)
     attn = attn.reshape(B, T, H * D)
-    # Named so remat="attn" can save exactly this tensor: the layer
-    # recompute in the backward then skips re-running flash attention
-    # (the score-matmul ~40% of layer FLOPs at T=2048) while saving
-    # only B*T*dim per layer instead of every intermediate.
-    from jax.ad_checkpoint import checkpoint_name
-
-    attn = checkpoint_name(attn, "attn_out")
-    return x + _constrain(
+    x = x + _constrain(
         attn @ w["wo"].astype(compute_dtype), mesh, act_spec
-    ), kv_out
+    )
+    return checkpoint_name(x, remat_keep.KEEP_STREAM), kv_out
 
 
 def _layer_body(x, w, cfg, mesh, positions, moe_stats=False,
@@ -447,15 +456,17 @@ def forward_hidden(params, tokens, cfg, mesh=None, return_load=False):
             layer,
             policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
         )
-    elif cfg.remat == "attn":
+    elif cfg.remat:
+        # True: the names that fit the room declared around this trace,
+        # none without one or under a model-parallel mesh (whose
+        # activations are not whole on a device).
+        names = (remat_keep.ATTN_NAMES if cfg.remat == "attn"
+                 else () if mesh is not None
+                 else remat_keep.names_for(cfg, params, tokens.shape))
         layer = jax.checkpoint(
             layer,
-            policy=jax.checkpoint_policies.save_only_these_names(
-                "attn_out"
-            ),
-        )
-    elif cfg.remat:
-        layer = jax.checkpoint(layer)
+            policy=(jax.checkpoint_policies.save_only_these_names(*names)
+                    if names else None))
     x, aux_per_layer = jax.lax.scan(layer, x, params["layers"])
     if with_load:
         aux_per_layer, load = aux_per_layer

@@ -15,6 +15,7 @@ model-parallel path, whose ``shard_map`` in
 over ``dp`` and so cannot be expressed as a batch axis alone.
 """
 
+import collections
 import contextlib
 import contextvars
 
@@ -22,17 +23,35 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 _BATCH_AXIS = contextvars.ContextVar("elasticdl_batch_axis", default=None)
+_ROOM = contextvars.ContextVar("elasticdl_device_room", default=None)
+
+# One device's memory as the caller of ``batch_axis`` sees it: the
+# backend's ``limit`` in bytes, and the bytes ``free`` once the caller's
+# own state is on the device (for a trainer: parameters, optimizer state
+# as sharded, gradients).  ``free`` == 0 says that an earlier statement
+# proved too large (the step's compile ran out of memory): keep nothing.
+DeviceRoom = collections.namedtuple("DeviceRoom", "limit free")
 
 
 @contextlib.contextmanager
-def batch_axis(mesh, axis):
+def batch_axis(mesh, axis, room=None):
     """Declare that, while tracing inside this block, the leading axis of
-    every activation is sharded over ``axis`` of ``mesh``."""
+    every activation is sharded over ``axis`` of ``mesh``, and, where
+    the backend states its memory, the ``DeviceRoom`` a device has for
+    what is traced here (None: not stated, and code that would trade
+    memory for time does what it did without)."""
     token = _BATCH_AXIS.set(None if mesh is None else (mesh, axis))
+    room_token = _ROOM.set(room)
     try:
         yield
     finally:
+        _ROOM.reset(room_token)
         _BATCH_AXIS.reset(token)
+
+
+def device_room():
+    """The ``DeviceRoom`` declared around the code traced here, or None."""
+    return _ROOM.get()
 
 
 def shards():

@@ -64,6 +64,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -74,6 +75,11 @@ from elasticdl_tpu.utils.logging import get_logger
 logger = get_logger(__name__)
 
 NEG_INF = -1e30
+
+# ``checkpoint_name``s of the forward's two results that the backward
+# reads (``_flash_fwd``): what a remat policy saves to skip the second
+# forward (models/remat_keep.py).
+KEEP_OUT, KEEP_LSE = "flash_out", "flash_lse"
 
 # chip_smoke.py fails an LM leg on this prefix.
 FALLBACK_PREFIX = "attention fallback:"
@@ -699,6 +705,13 @@ def _flash_fwd(q, k, v, causal, scale, interpret, window=0):
                                window=window)
     # One row constant for the backward: p = exp(s - lse), no divide.
     lse = m + jnp.log(jnp.maximum(l, 1e-30))
+    # Named where they are made: a ``jax.checkpoint`` around the caller
+    # whose policy saves these two keeps the backward's residuals and
+    # does not run this forward a second time (q, k, v are the op's
+    # inputs, the caller's to name).  A name outside the op saves a
+    # copy of ``out`` and the kernel still runs again.
+    out = checkpoint_name(out, KEEP_OUT)
+    lse = checkpoint_name(lse, KEEP_LSE)
     return out, (q, k, v, out, lse)
 
 

@@ -12,10 +12,19 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from elasticdl_tpu.ops import flash_attention, grouped_matmul as gm
 from elasticdl_tpu.ops.batch_shard import per_batch_shard
 from elasticdl_tpu.ops.mode import kernel_mode
+
+
+# ``checkpoint_name``s of what the dispatch's backward reads, where it
+# is made (models/remat_keep.py picks among them): the sort's three
+# int32 results, the sorted rows, the two products before the
+# activation, the un-sorted down product.
+KEEP_SORT, KEEP_ROWS = "moe_sort", "moe_rows"
+KEEP_GATE, KEEP_UP, KEEP_OUT = "moe_gate", "moe_up", "moe_out"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -55,12 +64,18 @@ def _moe_experts(h, gates, experts, w_gate, w_up, w_down):
     inverse = jnp.argsort(order).astype(jnp.int32)
     sizes = (flat[:, None] == jnp.arange(x, dtype=flat.dtype)).sum(
         axis=0, dtype=jnp.int32)
+    order, inverse, sizes = (
+        checkpoint_name(a, KEEP_SORT) for a in (order, inverse, sizes))
     padded = (jnp.int32(0) if mode == "off"
               else gm.padded_rows(sizes, rows))
     matmul = gm.grouped_matmul      # asks the mode itself: as here
-    xs = _take_rows(k, h.reshape(n, e), order, inverse)
-    act = jax.nn.silu(matmul(xs, w_gate, sizes)) * matmul(xs, w_up, sizes)
-    ys = _take_rows(1, matmul(act, w_down, sizes), inverse, order)
+    xs = checkpoint_name(
+        _take_rows(k, h.reshape(n, e), order, inverse), KEEP_ROWS)
+    gate = checkpoint_name(matmul(xs, w_gate, sizes), KEEP_GATE)
+    up = checkpoint_name(matmul(xs, w_up, sizes), KEEP_UP)
+    act = jax.nn.silu(gate) * up
+    ys = checkpoint_name(
+        _take_rows(1, matmul(act, w_down, sizes), inverse, order), KEEP_OUT)
     out = jnp.einsum("nke,nk->ne", ys.reshape(n, k, e).astype(jnp.float32),
                      gates.reshape(n, k))
     load = jnp.concatenate([sizes, padded.reshape(1)])[None]

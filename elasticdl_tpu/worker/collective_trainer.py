@@ -25,7 +25,7 @@ import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from elasticdl_tpu.ops.batch_shard import batch_axis
+from elasticdl_tpu.ops.batch_shard import DeviceRoom, batch_axis
 from elasticdl_tpu.utils import tracing
 from elasticdl_tpu.utils.logging import get_logger
 from elasticdl_tpu.utils.pytree import flatten_with_names, to_numpy
@@ -80,6 +80,35 @@ class _PadPlan:
         if accum > 1:
             weights = weights.reshape(accum, micro)
         self.weights = weights
+
+
+def _device_bytes(tree):
+    """Bytes of ``tree`` on one device: a leaf's shard where it is
+    sharded, all of it where it is replicated or still on the host."""
+    total = 0
+    for leaf in jax.tree_util.tree_leaves(tree):
+        shape = np.shape(leaf)
+        sharding = getattr(leaf, "sharding", None)
+        if sharding is not None:
+            shape = sharding.shard_shape(shape)
+        total += int(np.prod(shape)) * np.dtype(leaf.dtype).itemsize
+    return total
+
+
+def _bytes_limit(devices):
+    """The least ``bytes_limit`` the backend states for ``devices``, or
+    None where it states none (the CPU keeps no memory statistics)."""
+    limits = [(d.memory_stats() or {}).get("bytes_limit") for d in devices]
+    return min(limits) if all(limits) else None
+
+
+def _out_of_memory(error, args):
+    """Did a step's compile end in the backend's out-of-memory error?
+    (Its arguments are then still alive: a step that failed while
+    running has consumed the donated ones, and cannot be tried again.)"""
+    return "RESOURCE_EXHAUSTED" in str(error) and not any(
+        leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(args)
+        if isinstance(leaf, jax.Array))
 
 
 def _masked_mean(per_example, weights):
@@ -172,6 +201,9 @@ class CollectiveTrainer(Trainer):
         # ``step_stats_fn``), lazy like the loss it left the step with:
         # fetched after that loss it costs no sync.  () without one.
         self.last_step_stats = ()
+        # Set when a step's compile ran out of memory with a
+        # ``DeviceRoom`` stated: every later build states none left.
+        self._room_refused = False
 
         params = spec.init_fn(jax.random.PRNGKey(rng_seed))
         self._opt_state = spec.optimizer.init(params)
@@ -478,8 +510,9 @@ class CollectiveTrainer(Trainer):
                 x = jax.tree_util.tree_map(to_bf16, x)
             # Pallas kernels inside the model run per shard of the data
             # axis instead of being replicated by the partitioner; the
-            # model's loss states its sizes per shard too.
-            with batch_axis(self._mesh, self._data_axis):
+            # model's loss states its sizes per shard too, and a model
+            # that can trade memory for time is told what a device has.
+            with batch_axis(self._mesh, self._data_axis, self._room):
                 out = apply_fn(p, x, True)
                 per_example = loss_fn(out, labels).astype(jnp.float32)
             # The spec's step statistics ride out as value_and_grad's
@@ -546,10 +579,31 @@ class CollectiveTrainer(Trainer):
         flat_new = jax.lax.with_sharding_constraint(flat_new, rep_t)
         return z.unflatten_params(flat_new), opt_state
 
+    def _device_room(self):
+        """What one device has left for the model's step once this
+        trainer's state is on it: the backend's limit less the
+        parameters, the optimizer state as sharded, the gradients (and
+        their accumulator under accumulation) and, with
+        ``use_bf16_compute``, the bfloat16 copy of the parameters.  None
+        where the backend states no limit (the CPU)."""
+        limit = _bytes_limit(jax.local_devices()[:1] if self._mesh is None
+                             else self._mesh.local_devices)
+        if limit is None:
+            return None
+        if self._room_refused:
+            return DeviceRoom(limit, 0)
+        params = _device_bytes(self._params)
+        held = (params * (3 if self._accum_steps > 1 else 2)
+                + _device_bytes(self._opt_state))
+        if self._use_bf16_compute:
+            held += params // 2
+        return DeviceRoom(limit, limit - held)
+
     def _build_train_step(self):
         tx = self._spec.optimizer
         accum = self._accum_steps
         zero = self._zero
+        self._room = self._device_room()
 
         def step(params, opt_state, features, labels, weights):
             if accum == 1:
@@ -757,18 +811,51 @@ class CollectiveTrainer(Trainer):
             features, labels, weights, n if count is None else count
         )
 
+    def _program(self, window):
+        """The per-step program (``window`` 1) or a cached fused window."""
+        if window == 1:
+            return self._train_step
+        fn = self._fused_window_cache.get(window)
+        if fn is None:
+            fn = self._fused_window_cache[window] = self.build_fused_window(
+                window)
+        return fn
+
+    def _run_step(self, window, *batch):
+        """``_program(window)`` on (params, opt_state, *batch).  The
+        model sized what it keeps for the backward from an estimate
+        (``_device_room``); when the compile behind a program's first
+        call says the estimate was short, the room is restated as none
+        left and the program is built once more: a job that fits with
+        nothing kept trains."""
+        args = (self._params, self._opt_state) + batch
+        try:
+            with self.timing.timeit("step_dispatch"):
+                return self._program(window)(*args)
+        except jax.errors.JaxRuntimeError as e:
+            if (self._room_refused or not self._room
+                    or not _out_of_memory(e, args)):
+                raise
+            logger.warning(
+                "remat keep: fallback=1 the step's compile ran out of "
+                "device memory with %d bytes stated free; rebuilding it "
+                "with nothing kept (%s)",
+                self._room.free, str(e).splitlines()[0][:200])
+        self._room_refused = True
+        self._fused_window_cache = {}
+        self._train_step = self._build_train_step()
+        with self.timing.timeit("step_dispatch"):
+            return self._program(window)(*args)
+
     def train_minibatch(self, features, labels):
         """One step; returns (loss, version) where ``loss`` is a LAZY
         device scalar — no host sync here.  Callers that need a float
         (cadence logging, benches) pull it explicitly via
         ``float(loss)``; that fetch is the fence."""
         prepared = self.prepare_batch(features, labels)
-        with self.timing.timeit("step_dispatch"):
-            (self._params, self._opt_state, loss,
-             self.last_step_stats) = self._train_step(
-                self._params, self._opt_state,
-                prepared.features, prepared.labels, prepared.weights,
-            )
+        (self._params, self._opt_state, loss,
+         self.last_step_stats) = self._run_step(
+            1, prepared.features, prepared.labels, prepared.weights)
         self._count_zero1_traffic(1)
         self._version += 1
         self._maybe_report_and_checkpoint()
@@ -858,23 +945,13 @@ class CollectiveTrainer(Trainer):
         caller is responsible for clamping K to ``steps_to_boundary``
         (fused_driver does) — report/checkpoint cadence checks run once
         at the window boundary."""
+        out = self._run_step(
+            staged.size, staged.features, staged.labels, staged.weights)
         if staged.size == 1:
-            with self.timing.timeit("step_dispatch"):
-                (self._params, self._opt_state, losses,
-                 self.last_step_stats) = self._train_step(
-                    self._params, self._opt_state,
-                    staged.features, staged.labels, staged.weights,
-                )
+            (self._params, self._opt_state, losses,
+             self.last_step_stats) = out
         else:
-            fn = self._fused_window_cache.get(staged.size)
-            if fn is None:
-                fn = self.build_fused_window(staged.size)
-                self._fused_window_cache[staged.size] = fn
-            with self.timing.timeit("step_dispatch"):
-                self._params, self._opt_state, losses = fn(
-                    self._params, self._opt_state,
-                    staged.features, staged.labels, staged.weights,
-                )
+            self._params, self._opt_state, losses = out
         self._count_zero1_traffic(staged.size)
         self._version += staged.size
         self._maybe_report_and_checkpoint()

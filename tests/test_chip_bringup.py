@@ -207,3 +207,27 @@ def test_chip_check_tiny_mode_has_a_head_loss_leg(monkeypatch, capsys,
     assert [r["case"].rsplit(".", 1)[1] for r in rows] == ["untied", "tied"]
     for row in rows:
         assert set(row["errs"]) == {"loss", "dx", "dhead"} and row["ok"]
+
+
+def test_chip_check_tiny_mode_has_a_remat_keep_leg(monkeypatch, capsys,
+                                                   tmp_path):
+    """An LM's loss and gradients through the trainer with every name
+    kept against nothing kept, at the bf16 tolerances the chip is held
+    to; the case says which names the room chose."""
+    import json
+
+    monkeypatch.syspath_prepend(REPO)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("ELASTICDL_FLASH", "interpret")
+    import chip_check
+
+    assert chip_check.main(["--tiny", "remat_keep"]) == 0
+    rows = [json.loads(line)
+            for line in capsys.readouterr().out.splitlines()
+            if line.startswith('{"')]
+    chosen = [r for r in rows if r.get("remat_keep") == "chosen"]
+    assert chosen[0]["names"][:2] == ["flash_out", "flash_lse"]
+    assert "ffn_up" in chosen[0]["names"]
+    case = [r for r in rows if "case" in r]
+    assert len(case) == 1 and case[0]["ok"]
+    assert set(case[0]["errs"]) == {"keep_loss", "keep_grad", "keep_grad_l2"}

@@ -1,0 +1,401 @@
+"""``remat=True`` keeps, by name, the residuals that fit
+(models/remat_keep.py): the kept values change no gradient, the flash
+forward leaves the backward when its two results are kept, the choice
+follows the stated room per shard, the estimate is held to the three
+cells' measured peaks, and a refused compile falls back to nothing
+kept."""
+
+import dataclasses
+import json
+import logging
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh
+
+from elasticdl_tpu.models import remat_keep as rk, transformer as tfm
+from elasticdl_tpu.ops import flash_attention as fa, moe_dispatch as md
+from elasticdl_tpu.ops.batch_shard import DeviceRoom, batch_axis
+from elasticdl_tpu.worker import collective_trainer as ct
+
+GB = 10 ** 9
+# bytes_limit of a v5e chip (15.75 GiB), as its backend states it
+V5E_LIMIT = 16911433728
+ROWS = 2 * 128
+
+
+def _cfg(moe, **kw):
+    return tfm.TransformerConfig(
+        vocab_size=96, dim=128, num_heads=2, num_layers=2, max_seq_len=128,
+        dtype="float32", ffn_dim=128, remat=True, moe_experts=4 * moe,
+        moe_top_k=2, qk_norm=bool(moe), tied_embeddings=not moe, **kw)
+
+
+def _problem(moe, **kw):
+    cfg = _cfg(moe, **kw)
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 128), 0, 96)
+
+    def loss(params, room):
+        with batch_axis(None, "data", room):
+            hidden, aux = tfm.forward_hidden(params, tokens, cfg)
+            return (tfm.head_loss(params, hidden, tokens, cfg).mean()
+                    + 0.01 * aux)
+
+    return cfg, params, loss
+
+
+def _room_for(cfg, params, entries):
+    """A room whose budget is exactly the first ``entries`` of the table."""
+    kept = sum(b for _, _, b in rk.table(cfg, ROWS)[:entries])
+    need = rk.step_bytes(cfg, params, ROWS) + kept * cfg.num_layers
+    return DeviceRoom(GB, int(need + rk.RESERVE * GB))
+
+
+def _pallas_results(jaxpr, found=None):
+    """How many results each ``pallas_call`` of a jaxpr has, sub-jaxprs
+    (scan, remat, custom_vjp, shard_map) included."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(len(eqn.outvars))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _pallas_results(sub, found)
+    return found
+
+
+DENSE = ["flash", "qkv", "stream", "ffn_gate", "ffn_up"]
+MOE = ["flash", "route", "qkv", "stream", "moe_out", "moe_gate", "moe_up",
+       "moe_rows"]
+
+
+def test_the_table_is_ordered_and_sized_from_shapes():
+    cfg = _cfg(0)
+    assert [label for label, _, _ in rk.table(cfg, ROWS)] == DENSE
+    assert [label for label, _, _ in rk.table(_cfg(1), ROWS)] == MOE
+    sizes = dict((label, b) for label, _, b in rk.table(cfg, ROWS))
+    assert sizes["flash"] == ROWS * 2 * (64 * 4 + 4)   # out, and lse f32
+    assert sizes["qkv"] == 3 * ROWS * 128 * 4
+    assert sizes["ffn_gate"] == ROWS * 128 * 4
+    # grouped-query attention keeps G heads of k and v, not H
+    gqa = dataclasses.replace(cfg, num_kv_heads=1)
+    assert dict((l, b) for l, _, b in rk.table(gqa, ROWS))["qkv"] == (
+        ROWS * (2 + 1 + 1) * 64 * 4)
+    # at OLMo-1B's widths, 16,384 rows: the issue's table
+    wide = tfm.TransformerConfig(vocab_size=50304, dim=2048, num_heads=16,
+                                 num_layers=7)
+    sizes = dict((l, b) for l, _, b in rk.table(wide, 16384))
+    assert sizes["stream"] == 67108864 and sizes["qkv"] == 3 * 67108864
+    assert sizes["ffn_gate"] + sizes["ffn_up"] == 536870912
+
+
+@pytest.mark.parametrize("mode", ["interpret", "off"])
+@pytest.mark.parametrize("moe,entries", [(0, n + 1) for n in range(5)]
+                         + [(1, n + 1) for n in range(8)])
+def test_gradients_equal_the_nothing_kept_ones(monkeypatch, mode, moe,
+                                               entries):
+    """Every prefix of the list: the kept values are the ones the second
+    forward would have produced, so only float32 round-off differs."""
+    monkeypatch.setenv("ELASTICDL_FLASH", mode)
+    cfg, params, loss = _problem(moe)
+    room = _room_for(cfg, params, entries)
+    names = rk.choose(cfg, params, ROWS, room)[0]
+    want = sum((n for _, n, _ in rk.table(cfg, ROWS)[:entries]), ())
+    assert names == want
+    base = jax.jit(jax.grad(lambda p: loss(p, None)))(params)
+    kept = jax.jit(jax.grad(lambda p: loss(p, room)))(params)
+    for a, b in zip(jax.tree_util.tree_leaves(kept),
+                    jax.tree_util.tree_leaves(base)):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("how", ["room", "attn"])
+def test_keeping_out_and_lse_takes_the_flash_forward_out_of_the_backward(
+        monkeypatch, how):
+    """The kernel's forward has three results, dq one, dk-dv two
+    (benchmark/kernels/flash_attention.py tells them the same way): with
+    nothing kept the forward runs in the forward scan and again in the
+    backward's; with the two names kept, and under remat="attn" whatever
+    the room, once."""
+    monkeypatch.setenv("ELASTICDL_FLASH", "interpret")
+    cfg, params, loss = _problem(0)
+    base = jax.make_jaxpr(jax.grad(lambda p: loss(p, None)))(params)
+    assert sorted(_pallas_results(base.jaxpr)) == [1, 2, 3, 3]
+    if how == "attn":
+        cfg = dataclasses.replace(cfg, remat="attn")
+        tokens = jnp.zeros((2, 128), jnp.int32)
+        kept = jax.make_jaxpr(jax.grad(
+            lambda p: tfm.forward_hidden(p, tokens, cfg)[0].sum()))(params)
+    else:
+        room = _room_for(cfg, params, 1)
+        kept = jax.make_jaxpr(jax.grad(lambda p: loss(p, room)))(params)
+    assert sorted(_pallas_results(kept.jaxpr)) == [1, 2, 3]
+
+
+def test_every_moe_name_kept_leaves_the_backward_its_own_calls(monkeypatch):
+    """16 kernel calls with nothing kept (flash 1 + 3 grouped matmuls,
+    twice, and flash's 2 + the matmuls' 6 backward calls); 12 with
+    every name kept."""
+    monkeypatch.setenv("ELASTICDL_FLASH", "interpret")
+    cfg, params, loss = _problem(1)
+    base = jax.make_jaxpr(jax.grad(lambda p: loss(p, None)))(params)
+    room = _room_for(cfg, params, len(MOE))
+    kept = jax.make_jaxpr(jax.grad(lambda p: loss(p, room)))(params)
+    assert len(_pallas_results(base.jaxpr)) == 16
+    assert len(_pallas_results(kept.jaxpr)) == 12
+
+
+def test_no_room_stated_is_the_program_without_the_names(monkeypatch):
+    """With no ``DeviceRoom`` the layer is checkpointed with no policy,
+    and a ``checkpoint_name`` lowers to nothing: the step is, letter for
+    letter, the program of a tree in which no value is named (but for
+    the running numbers JAX gives the functions it emits)."""
+    cfg, params, loss = _problem(1)
+
+    def lowered():
+        text = jax.jit(jax.grad(lambda p: loss(p, None))).lower(
+            params).as_text()
+        return re.sub(r"@(\w+?)_\d+\b", r"@\1", text)
+
+    jaxpr = str(jax.make_jaxpr(jax.grad(lambda p: loss(p, None)))(params))
+    assert "policy=None" in jaxpr and "save_only" not in jaxpr
+    named = lowered()
+    for module in (tfm, fa, md):
+        monkeypatch.setattr(module, "checkpoint_name", lambda x, name: x)
+    assert lowered() == named
+
+
+def test_budget_zero_keeps_nothing_and_huge_keeps_everything():
+    for moe in (0, 1):
+        cfg, params, _ = _problem(moe)
+        need = rk.step_bytes(cfg, params, ROWS)
+        spent = int(need + rk.RESERVE * GB)
+        names, kept, budget, peak = rk.choose(
+            cfg, params, ROWS, DeviceRoom(GB, spent))
+        assert (names, kept, budget) == ((), 0, 0)
+        assert peak == GB - spent + need
+        # a refused room (free == 0) and one already overdrawn
+        assert rk.choose(cfg, params, ROWS, DeviceRoom(GB, 0))[0] == ()
+        assert rk.choose(cfg, params, ROWS, DeviceRoom(GB, -5))[0] == ()
+        names, kept, _, _ = rk.choose(
+            cfg, params, ROWS, DeviceRoom(100 * GB, 99 * GB))
+        table = rk.table(cfg, ROWS)
+        assert names == sum((n for _, n, _ in table), ())
+        assert kept == cfg.num_layers * sum(b for _, _, b in table)
+
+
+def test_an_entry_that_does_not_fit_is_passed_over_not_the_rest():
+    cfg, params, _ = _problem(0)
+    sizes = [b * cfg.num_layers for _, _, b in rk.table(cfg, ROWS)]
+    need = rk.step_bytes(cfg, params, ROWS)
+    # room for flash and the stream, not for q, k, v between them
+    budget = sizes[0] + sizes[2] + 8
+    assert budget < sizes[0] + sizes[1]
+    names = rk.choose(cfg, params, ROWS, DeviceRoom(
+        GB, int(need + rk.RESERVE * GB + budget)))[0]
+    assert names == rk.ATTN_NAMES + (rk.KEEP_STREAM,)
+
+
+# cell -> (configuration, rows a step, chips, the ledger's nothing-kept
+# ``trainer.peak_hbm_gb`` (PR 28), the names PR 29 reports it keeps)
+ATTENTION = rk.ATTN_NAMES + (rk.KEEP_Q, rk.KEEP_K, rk.KEEP_V,
+                             rk.KEEP_STREAM)
+CELLS = {
+    "olmo1b.seq2048": ("olmo1b", 8, 1, 12.035, ATTENTION),
+    "olmo1b.seq2048-dp4": ("olmo1b", 32, 4, 11.992, ATTENTION),
+    "olmoe1b7b.seq4096": (
+        "olmoe1b7b", 4, 1, 11.628,
+        rk.ATTN_NAMES + (rk.KEEP_ROUTE, md.KEEP_SORT) + ATTENTION[2:]
+        + (md.KEEP_OUT, md.KEEP_GATE, md.KEEP_UP, md.KEEP_ROWS)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_the_estimate_is_held_to_the_cells_measured_peaks(cell):
+    """The three cells at their real shapes, no arrays: what the trainer
+    would state (16 B a parameter) and what the model adds lands within
+    -0.1 / +0.9 GB of the peak the chip measured with nothing kept (over,
+    never under: +0.23, +0.27 and, at depth 1, +0.84), picks the names
+    the PR reports, and predicts a peak under the limit less the
+    reserve."""
+    config, batch, chips, measured, names = CELLS[cell]
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           config + ".json")) as fh:
+        model_params = json.load(fh)["cli"]["model_params"]
+    spec = tfm.model_spec(**model_params)
+    cfg = spec.config
+    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+    state = jax.eval_shape(spec.optimizer.init, params)
+    held = 2 * ct._device_bytes(params) + ct._device_bytes(state)
+    rows = batch * model_params["seq_len"] // chips
+    assert rows == 16384
+    estimate = held + rk.step_bytes(cfg, params, rows)
+    assert -0.1 < estimate / GB - measured < 0.9
+    room = DeviceRoom(V5E_LIMIT, V5E_LIMIT - held)
+    got, kept, budget, peak = rk.choose(cfg, params, rows, room)
+    assert got == names
+    assert kept <= budget and peak == estimate + kept
+    assert peak <= (1 - rk.RESERVE) * V5E_LIMIT
+
+
+def _lines(fn, prefix="remat keep:"):
+    """The ``remat keep:`` lines logged while ``fn`` runs (the repo's
+    loggers do not propagate to the root one)."""
+    lines = []
+    handler = logging.Handler()
+    handler.emit = lambda record: lines.append(record.getMessage())
+    loggers = (fa.logger, ct.logger)
+    for logger in loggers:
+        logger.addHandler(handler)
+    try:
+        fn()
+    finally:
+        for logger in loggers:
+            logger.removeHandler(handler)
+    return [line for line in lines if line.startswith(prefix)]
+
+
+def _spec(**kw):
+    return tfm.model_spec(vocab_size=96, dim=128, num_heads=2, num_layers=2,
+                          seq_len=128, ffn_dim=128, dtype="float32",
+                          remat=True, **kw)
+
+
+def _fields(line):
+    return dict(part.split("=") for part in line.split()[2:])
+
+
+def test_the_trainer_states_the_room_and_the_model_logs_once(monkeypatch):
+    """limit - (parameters + gradients + optimizer state), stated around
+    the model's trace; one line per compiled shape however many steps."""
+    rk.announce_keep.cache_clear()
+    monkeypatch.setattr(ct, "_bytes_limit", lambda devices: GB)
+    trainer = ct.CollectiveTrainer(_spec(), batch_size=2)
+    params = ct._device_bytes(trainer._params)
+    assert trainer._room == DeviceRoom(
+        GB, GB - 2 * params - ct._device_bytes(trainer._opt_state))
+    tokens = np.zeros((2, 128), np.int32)
+
+    def train():
+        for _ in range(3):
+            trainer.train_minibatch(tokens, tokens)
+
+    lines = _lines(train)
+    assert len(lines) == 1
+    fields = _fields(lines[0])
+    assert fields["names"] == ",".join(sum(
+        (n for _, n, _ in rk.table(trainer._spec.config, ROWS)), ()))
+    assert fields["rows"] == str(ROWS) and fields["fallback"] == "0"
+    assert int(fields["bytes"]) <= int(fields["budget"])
+    assert int(fields["predicted_peak"]) < GB
+    # gradient accumulation holds a second gradient tree
+    trainer.set_accum_steps(2)
+    assert trainer._room.free == GB - 3 * params - ct._device_bytes(
+        trainer._opt_state)
+
+
+def test_no_limit_stated_no_room_and_no_line():
+    """The CPU states no ``bytes_limit``: tier-1 trains as it did."""
+    rk.announce_keep.cache_clear()
+    trainer = ct.CollectiveTrainer(_spec(), batch_size=2)
+    assert trainer._room is None
+    tokens = np.zeros((2, 128), np.int32)
+    assert _lines(lambda: trainer.train_minibatch(tokens, tokens)) == []
+
+
+def test_on_a_mesh_the_bytes_are_one_shards(monkeypatch):
+    """Four devices, 32 rows a step: 8 a device; ZeRO-1 states a
+    quarter of the optimizer state."""
+    rk.announce_keep.cache_clear()
+    monkeypatch.setattr(ct, "_bytes_limit", lambda devices: GB)
+    mesh = Mesh(np.array(jax.devices()[:4]), axis_names=("data",))
+    trainer = ct.CollectiveTrainer(_spec(), batch_size=32, mesh=mesh)
+    tokens = np.zeros((32, 128), np.int32)
+    lines = _lines(lambda: trainer.train_minibatch(tokens, tokens))
+    assert len(lines) == 1
+    fields = _fields(lines[0])
+    assert fields["rows"] == str(8 * 128)
+    table = rk.table(trainer._spec.config, 8 * 128)
+    assert int(fields["bytes"]) == 2 * sum(b for _, _, b in table)
+    sharded = ct.CollectiveTrainer(_spec(), batch_size=32, mesh=mesh,
+                                   zero1=True)
+    state = ct._device_bytes(trainer._opt_state)
+    assert ct._device_bytes(sharded._opt_state) < 0.3 * state
+    assert sharded._room.free > trainer._room.free + 0.7 * state
+
+
+def test_a_model_parallel_mesh_keeps_nothing():
+    cfg = _cfg(0)
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jnp.zeros((2, 128), jnp.int32)
+    mesh = Mesh(np.array(jax.devices()[:2]).reshape(2, 1, 1, 1),
+                ("dp", "tp", "sp", "pp"))
+    with batch_axis(None, "data", DeviceRoom(100 * GB, 99 * GB)):
+        jaxpr = str(jax.make_jaxpr(jax.grad(
+            lambda p: tfm.forward_hidden(p, tokens, cfg, mesh=mesh)[0]
+            .sum()))(params))
+    assert "policy=None" in jaxpr and "save_only" not in jaxpr
+
+
+class _Refuse:
+    """A step whose compile ends as the TPU's does when the program
+    does not fit (the message is the backend's, PR 23)."""
+
+    def __init__(self, message):
+        self.calls, self.message = 0, message
+
+    def __call__(self, *args):
+        self.calls += 1
+        raise jax.errors.JaxRuntimeError(self.message)
+
+
+OOM = ("RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+       "memory in memory space hbm. Used 16.23G of 15.75G hbm.")
+
+
+def test_a_refused_compile_rebuilds_once_with_nothing_kept(monkeypatch):
+    rk.announce_keep.cache_clear()
+    monkeypatch.setattr(ct, "_bytes_limit", lambda devices: GB)
+    trainer = ct.CollectiveTrainer(_spec(), batch_size=2)
+    refuse = trainer._train_step = _Refuse(OOM)
+    tokens = np.zeros((2, 128), np.int32)
+    lines = _lines(lambda: trainer.train_minibatch(tokens, tokens))
+    assert refuse.calls == 1 and trainer._room == DeviceRoom(GB, 0)
+    assert len(lines) == 2
+    assert lines[0].startswith("remat keep: fallback=1")
+    fields = _fields(lines[1])
+    assert fields["names"] == "-" and fields["fallback"] == "1"
+    loss, version = trainer.train_minibatch(tokens, tokens)
+    assert version == 2 and np.isfinite(float(loss))
+    # every later build states none left; a second refusal is the job's
+    trainer.set_accum_steps(2)
+    assert trainer._room == DeviceRoom(GB, 0)
+    trainer._train_step = _Refuse(OOM)
+    tokens = np.zeros((4, 128), np.int32)
+    with pytest.raises(jax.errors.JaxRuntimeError):
+        trainer.train_minibatch(tokens, tokens)
+
+
+@pytest.mark.parametrize("case", ["another error", "no room stated",
+                                  "arguments consumed"])
+def test_what_is_not_a_refused_estimate_is_raised(monkeypatch, case):
+    if case != "no room stated":
+        monkeypatch.setattr(ct, "_bytes_limit", lambda devices: GB)
+    trainer = ct.CollectiveTrainer(_spec(), batch_size=2)
+    message = "INTERNAL: something else" if case == "another error" else OOM
+    refuse = trainer._train_step = _Refuse(message)
+    if case == "arguments consumed":
+        # the step ran and failed: its donated arguments are gone
+        jax.tree_util.tree_leaves(trainer._params)[0].delete()
+    tokens = np.zeros((2, 128), np.int32)
+    with pytest.raises(jax.errors.JaxRuntimeError):
+        trainer.train_minibatch(tokens, tokens)
+    assert refuse.calls == 1 and not trainer._room_refused
