@@ -5,8 +5,8 @@ that fit the memory a device has left, chosen from shapes.
 backward runs every layer's forward a second time.  The ops name the
 values their backward reads where they make them (``checkpoint_name``);
 ``choose`` walks ``table``, an order of what a byte buys, and takes each
-entry whose bytes (one shard's rows, all layers: the scan stacks what a
-layer keeps) still fit the budget; ``models/transformer.forward_hidden``
+entry whose bytes (one shard's rows, all the layers of the kind that
+makes it: the scan stacks what a layer keeps) still fit the budget; ``models/transformer.forward_hidden``
 turns the names into ``save_only_these_names``.  The kept values are the
 ones the second forward would have produced, so the gradients are the
 same bits' worth of arithmetic, done once.
@@ -23,11 +23,14 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from elasticdl_tpu.ops import batch_shard, flash_attention, moe_dispatch
+from elasticdl_tpu.ops import (batch_shard, flash_attention, moe_dispatch,
+                               short_conv)
 
 # Named in models/transformer.py: q, k, v as the attention takes them
-# (after RoPE and the QK norm), the stream after the attention's
-# residual add, the router's results, a dense FFN's two products.
+# (after RoPE and the QK norm), the stream after the operator's
+# residual add (attention or short convolution), the router's results,
+# a dense FFN's two products.  A short convolution's are the op's:
+# its input (the projection's output) and its result.
 KEEP_Q, KEEP_K, KEEP_V = "attn_q", "attn_k", "attn_v"
 KEEP_STREAM = "attn_stream"
 KEEP_ROUTE = "moe_route"
@@ -42,45 +45,68 @@ RESERVE = 0.05
 ATTN_NAMES = (flash_attention.KEEP_OUT, flash_attention.KEEP_LSE)
 
 
+def _entries(cfg, rows):
+    """[(label, names, bytes a layer that makes the entry, how many
+    layers do)]: ``table`` in full."""
+    size = jnp.dtype(cfg.dtype).itemsize
+    e, h, g, d = cfg.dim, cfg.num_heads, cfg.kv_heads, cfg.head_dim
+    kinds = cfg.kinds
+    attention = sum(kind.op == "a" for kind in kinds)
+    conv = len(kinds) - attention
+    dense = sum(kind.dense for kind in kinds)
+    experts = len(kinds) - dense
+    entries = [
+        ("flash", ATTN_NAMES, rows * h * (d * size + 4), attention),
+    ]
+    x = cfg.moe_experts
+    k = min(cfg.moe_top_k, x)
+    # probs f32 [rows, X]; gates f32, experts, order, inverse int32
+    # [rows, k]; sizes [X]
+    entries.append(("route", (KEEP_ROUTE, moe_dispatch.KEEP_SORT),
+                    4 * (rows * (x + 4 * k) + x), experts))
+    entries += [
+        ("qkv", (KEEP_Q, KEEP_K, KEEP_V), rows * (h + 2 * g) * d * size,
+         attention),
+        ("stream", (KEEP_STREAM,), rows * e * size, len(kinds)),
+    ]
+    f, dense_f = cfg.mlp_dim, cfg.dense_ffn_dim if x else cfg.mlp_dim
+    routed = [
+        ("moe_out", (moe_dispatch.KEEP_OUT,), rows * k * e * size, experts),
+        ("moe_gate", (moe_dispatch.KEEP_GATE,), rows * k * f * size,
+         experts),
+        ("moe_up", (moe_dispatch.KEEP_UP,), rows * k * f * size, experts),
+        ("moe_rows", (moe_dispatch.KEEP_ROWS,), rows * k * e * size,
+         experts),
+    ]
+    rest = [
+        ("ffn_gate", (KEEP_GATE,), rows * dense_f * size, dense),
+        ("ffn_up", (KEEP_UP,), rows * dense_f * size, dense),
+        ("conv_in", (short_conv.KEEP_IN,), rows * 3 * e * size, conv),
+        ("conv_out", (short_conv.KEEP_OUT,), rows * e * size, conv),
+    ]
+    # With a share of the experts the buffers stay whole and the second
+    # forward multiplies the held experts' rows alone: a byte of them
+    # buys that share of what it buys with all held, so they go last.
+    entries += rest + routed if cfg.moe_experts_held else routed + rest
+    return [entry for entry in entries if entry[3]]
+
+
 def table(cfg, rows):
-    """[(label, names, bytes a layer)] for ``rows`` tokens a device, in
+    """[(label, names, bytes a layer that makes the entry)] for ``rows``
+    tokens a device, in
     the order of what a kept byte saves of the second forward (ms a GB,
     from the traces of PERF.md section 5: the flash forward ~18; the
     un-sorted down product, a grouped matmul and a gather, ~14; q, k, v
-    and the stream ~13; the FFN's products ~12; the sorted rows, one
-    gather, ~8).  Elementwise work (norms, RoPE's rotation, the
+    and the stream ~13; the FFN's products ~12, and by their shapes a
+    short convolution's input ~11; the sorted rows, one gather, ~8; the
+    convolution's result, a pass bound by memory, ~5).  Elementwise
+    work (norms, RoPE's rotation, the
     activation, the weighted combine) is not here: it is cheap and its
-    inputs are what is kept."""
-    size = jnp.dtype(cfg.dtype).itemsize
-    e, h, g, d, f = (cfg.dim, cfg.num_heads, cfg.kv_heads, cfg.head_dim,
-                     cfg.mlp_dim)
-    entries = [
-        ("flash", ATTN_NAMES, rows * h * (d * size + 4)),
-    ]
-    if cfg.moe_experts:
-        x = cfg.moe_experts
-        k = min(cfg.moe_top_k, x)
-        # probs f32 [rows, X]; gates f32, experts, order, inverse int32
-        # [rows, k]; sizes [X]
-        entries.append(("route", (KEEP_ROUTE, moe_dispatch.KEEP_SORT),
-                        4 * (rows * (x + 4 * k) + x)))
-    entries += [
-        ("qkv", (KEEP_Q, KEEP_K, KEEP_V), rows * (h + 2 * g) * d * size),
-        ("stream", (KEEP_STREAM,), rows * e * size),
-    ]
-    if cfg.moe_experts:
-        entries += [
-            ("moe_out", (moe_dispatch.KEEP_OUT,), rows * k * e * size),
-            ("moe_gate", (moe_dispatch.KEEP_GATE,), rows * k * f * size),
-            ("moe_up", (moe_dispatch.KEEP_UP,), rows * k * f * size),
-            ("moe_rows", (moe_dispatch.KEEP_ROWS,), rows * k * e * size),
-        ]
-    else:
-        entries += [
-            ("ffn_gate", (KEEP_GATE,), rows * f * size),
-            ("ffn_up", (KEEP_UP,), rows * f * size),
-        ]
-    return entries
+    inputs are what is kept.  An entry is there if a layer of the model
+    makes it: attention layers the flash kernel's and q, k, v, layers
+    with experts the router's and the dispatch's, dense layers the
+    FFN's, short-convolution layers the op's."""
+    return [entry[:3] for entry in _entries(cfg, rows)]
 
 
 def step_bytes(cfg, params, rows):
@@ -95,7 +121,8 @@ def step_bytes(cfg, params, rows):
        (``ops/head_loss.py``: the logits, and their cotangent where the
        head is tied), while the stack's gradients, which the caller
        counted, do not exist yet; or one layer's backward with its
-       second forward.
+       second forward, of the layer kind that needs most (a leading
+       dense layer's beside the expert layers').
 
     Held to the compiler's own count for the three cells of the
     benchmark (tests/test_remat_keep.py: +0.2 GB on the dense cells, whose
@@ -110,11 +137,13 @@ def step_bytes(cfg, params, rows):
     stream = rows * cfg.dim * size
     carries = (cfg.num_layers + 1) * stream
     head = rows * cfg.vocab_size * size * (2 if cfg.tied_embeddings else 1)
+    layer = 0
     if cfg.moe_experts:
         k = min(cfg.moe_top_k, cfg.moe_experts)
         layer = rows * k * (cfg.dim + 2 * cfg.mlp_dim) * size
-    else:
-        layer = rows * 4 * cfg.mlp_dim * size
+    if any(kind.dense for kind in cfg.kinds):
+        f = cfg.dense_ffn_dim if cfg.moe_experts else cfg.mlp_dim
+        layer = max(layer, rows * 4 * f * size)
     return copies + carries + max(head - stack_grads, layer)
 
 
@@ -124,8 +153,8 @@ def choose(cfg, params, rows, room):
     need = step_bytes(cfg, params, rows)
     budget = int(room.free - need - RESERVE * room.limit)
     names, kept = [], 0
-    for _label, entry, per_layer in table(cfg, rows):
-        nbytes = per_layer * cfg.num_layers
+    for _label, entry, per_layer, layers in _entries(cfg, rows):
+        nbytes = per_layer * layers
         if kept + nbytes <= budget:
             names += entry
             kept += nbytes

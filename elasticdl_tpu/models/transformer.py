@@ -18,7 +18,9 @@ The reference has no model parallelism at all beyond PS-sharded embeddings
 RoPE positions, pre-norm RMSNorm, SwiGLU MLP.
 """
 
+import collections
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +31,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from elasticdl_tpu.models import remat_keep
 from elasticdl_tpu.models.spec import ModelSpec
-from elasticdl_tpu.ops.flash_attention import flash_attention
+from elasticdl_tpu.ops import short_conv
+from elasticdl_tpu.ops.flash_attention import flash_attention, logger
 from elasticdl_tpu.ops.mode import kernels_off
 from elasticdl_tpu.ops.moe_dispatch import moe_experts
 from elasticdl_tpu.utils import metrics
@@ -47,10 +50,30 @@ class TransformerConfig:
     tied_embeddings: bool = True
     # Width of the MLP (of one expert, in an MoE): 0 = dim * mlp_ratio.
     ffn_dim: int = 0
-    # RMSNorm's epsilon, and a learned RMSNorm over the whole q and k
-    # projections before the split into heads (OLMoE).
+    # RMSNorm's epsilon.
     norm_eps: float = 1e-6
-    qk_norm: bool = False
+    # A learned RMSNorm of q and k before RoPE, in one of two forms:
+    # True = over the whole projection, before the split into heads,
+    # with a scale of its width (OLMoE); "head" = over each head's
+    # ``head_dim`` values, with one scale of ``head_dim`` that the heads
+    # share (LFM2).
+    qk_norm: bool | str = False
+    # RoPE's base.
+    rope_theta: float = 10000.0
+    # A stack whose layers differ.  ``layer_pattern``: one letter a
+    # layer, "a" attention, "c" gated short convolution of
+    # ``conv_kernel`` taps (ops/short_conv.py); "" = attention in every
+    # layer.  ``dense_layers``: how many leading layers have a dense MLP
+    # of ``dense_ffn_dim`` in an MoE model.  With either, the stack is
+    # the leading layers, then whole periods of the rest's pattern under
+    # one scan (a period's layers unrolled in its body, weights stacked
+    # over periods), then a remainder (:func:`stack_plan`), and
+    # ``params["layers"]`` is {"lead", "period", "tail"}.  Without,
+    # one layer scanned ``num_layers`` times, as ever.
+    layer_pattern: str = ""
+    dense_layers: int = 0
+    dense_ffn_dim: int = 0
+    conv_kernel: int = 3
     # Mixture-of-experts: 0 = dense FFN; >0 = dropless top-k routing
     # (every chosen expert computes every token that chose it, whatever
     # the routing) with experts sharded over the ``ep`` mesh axis and an
@@ -62,6 +85,23 @@ class TransformerConfig:
     moe_top_k: int = 2
     moe_norm_topk: bool = True
     moe_aux_weight: float = 0.01
+    # The router's scores (float32 at the highest precision either way):
+    # "softmax" over the experts, the K largest chosen; "sigmoid_bias" =
+    # a sigmoid of each expert's logit, the K largest of score +
+    # ``expert_bias`` chosen (a float32 vector a layer that no gradient
+    # reaches and AdamW's decay is masked from: ``model_spec``), their
+    # weights the unbiased scores, with ``moe_norm_topk`` divided by
+    # their sum + 1e-6, times ``moe_route_scale``.
+    moe_router: str = "softmax"
+    moe_route_scale: float = 1.0
+    # One chip's share of the experts: ``moe_experts`` stays the
+    # router's width, every token is routed over all of them, and this
+    # model holds (and multiplies) experts ``moe_share_index *
+    # moe_experts_held .. + moe_experts_held`` alone, so an expert
+    # layer's result is the held experts' part, weighted as if all were
+    # here; what the absent ones would add is left out.  0 = all held.
+    moe_experts_held: int = 0
+    moe_share_index: int = 0
     # Rematerialize each scanned layer in the backward pass instead of
     # saving all its activations (24 layers x T=2048 save ~20 GB of
     # them un-remat'ed on one chip).  True = recompute what does not
@@ -111,62 +151,166 @@ class TransformerConfig:
     def mlp_dim(self):
         return self.ffn_dim or self.dim * self.mlp_ratio
 
+    @property
+    def kinds(self):
+        """The Kind of every layer, in order."""
+        plan = stack_plan(self)
+        if plan is None:
+            return (Kind("a", not self.moe_experts),) * self.num_layers
+        return plan.lead + plan.period * plan.periods + plan.tail
+
+    @property
+    def experts_held(self):
+        """(first held expert, how many): all of them without a share."""
+        held = self.moe_experts_held or self.moe_experts
+        if held and (self.moe_experts % held
+                     or not 0 <= self.moe_share_index
+                     < self.moe_experts // held):
+            raise ValueError(
+                "moe_experts_held (%d) must divide moe_experts (%d) and "
+                "moe_share_index (%d) name one of the shares"
+                % (held, self.moe_experts, self.moe_share_index))
+        return self.moe_share_index * held, held
+
+
+# One layer's kind: its operator ("a" | "c") and whether its FFN is
+# dense (in an MoE model, a leading layer's).
+Kind = collections.namedtuple("Kind", "op dense")
+# ``lead`` and ``tail``: the kinds of the layers before and after the
+# scan; ``period``: the kinds of one period; ``periods``: how many the
+# scan runs.
+StackPlan = collections.namedtuple("StackPlan", "lead period periods tail")
+
+
+def stack_plan(cfg):
+    """How a stack whose layers differ is run, or None for a model of
+    one layer kind (no pattern, no leading dense layers).  The period is
+    the shortest that the layers after the leading ones repeat."""
+    if not cfg.layer_pattern and not cfg.dense_layers:
+        return None
+    pattern = cfg.layer_pattern or "a" * cfg.num_layers
+    if len(pattern) != cfg.num_layers or set(pattern) - set("ac"):
+        raise ValueError(
+            "layer_pattern %r: want %d letters, each a (attention) or c "
+            "(short convolution)" % (pattern, cfg.num_layers))
+    if cfg.dense_layers and not (cfg.moe_experts and cfg.dense_ffn_dim):
+        raise ValueError(
+            "dense_layers=%d needs moe_experts and dense_ffn_dim: "
+            "without experts every layer's FFN is dense"
+            % cfg.dense_layers)
+    dense = not cfg.moe_experts
+    lead = tuple(Kind(op, True) for op in pattern[:cfg.dense_layers])
+    rest = pattern[cfg.dense_layers:]
+    size = next((p for p in range(1, len(rest) + 1)
+                 if all(rest[i] == rest[i % p] for i in range(len(rest)))),
+                1)
+    periods = len(rest) // size
+    return StackPlan(
+        lead, tuple(Kind(op, dense) for op in rest[:size]), periods,
+        tuple(Kind(op, dense) for op in rest[periods * size:]))
+
+
+def _uniform_only(cfg, what):
+    if stack_plan(cfg) is not None:
+        raise NotImplementedError(
+            "%s does not run a stack whose layers differ (layer_pattern="
+            "%r, dense_layers=%d): a short-convolution layer needs a "
+            "state cache of its own and the stages a split by kind "
+            "(ROADMAP)" % (what, cfg.layer_pattern, cfg.dense_layers))
+
 
 # -- parameters --------------------------------------------------------------
 
 
-def init_params(rng, cfg):
-    """Layer weights are stacked on a leading [num_layers] axis (scanned)."""
-    k_embed, k_attn, k_mlp, k_out = jax.random.split(rng, 4)
-    L, E, H, D, F = (cfg.num_layers, cfg.dim, cfg.num_heads,
-                     cfg.head_dim, cfg.mlp_dim)
+def _norm_init(*shape):
+    return jnp.ones(shape, jnp.float32)
 
-    def norm_init(*shape):
-        return jnp.ones(shape, jnp.float32)
 
-    def dense_init(key, *shape, scale=None):
-        fan_in = shape[-2] if len(shape) >= 2 else shape[0]
-        scale = scale or (1.0 / np.sqrt(fan_in))
-        return jax.random.normal(key, shape, jnp.float32) * scale
+def _dense_init(key, *shape, scale=None):
+    fan_in = shape[-2] if len(shape) >= 2 else shape[0]
+    scale = scale or (1.0 / np.sqrt(fan_in))
+    return jax.random.normal(key, shape, jnp.float32) * scale
 
+
+def _init_layers(k_attn, k_mlp, cfg, kind, stack):
+    """The weights of layers of one Kind, every leaf led by ``stack``
+    (the scanned axis, or () for one layer)."""
+    E, H, D, G = cfg.dim, cfg.num_heads, cfg.head_dim, cfg.kv_heads
     keys = jax.random.split(k_attn, 6)
-    G = cfg.kv_heads
-    layers = {
-        "ln1": norm_init(L, E),
-        "wq": dense_init(keys[0], L, E, H * D),
-        "wk": dense_init(keys[1], L, E, G * D),
-        "wv": dense_init(keys[2], L, E, G * D),
-        "wo": dense_init(keys[3], L, H * D, E),
-        "ln2": norm_init(L, E),
-    }
-    if cfg.qk_norm:
-        layers["q_norm"] = norm_init(L, H * D)
-        layers["k_norm"] = norm_init(L, G * D)
-    if cfg.moe_experts:
-        X = cfg.moe_experts
-        layers["w_router"] = dense_init(keys[4], L, E, X, scale=0.02)
-        layers["w_gate"] = dense_init(keys[5], L, X, E, F)
-        layers["w_up"] = dense_init(jax.random.fold_in(k_mlp, 0),
-                                    L, X, E, F)
-        layers["w_down"] = dense_init(jax.random.fold_in(k_mlp, 1),
-                                      L, X, F, E)
+    layers = {"ln1": _norm_init(*stack, E), "ln2": _norm_init(*stack, E)}
+    if kind.op == "a":
+        layers.update(
+            wq=_dense_init(keys[0], *stack, E, H * D),
+            wk=_dense_init(keys[1], *stack, E, G * D),
+            wv=_dense_init(keys[2], *stack, E, G * D),
+            wo=_dense_init(keys[3], *stack, H * D, E))
+        if cfg.qk_norm:
+            whole = cfg.qk_norm != "head"
+            layers["q_norm"] = _norm_init(*stack, H * D if whole else D)
+            layers["k_norm"] = _norm_init(*stack, G * D if whole else D)
     else:
-        layers["w_gate"] = dense_init(keys[4], L, E, F)
-        layers["w_up"] = dense_init(keys[5], L, E, F)
-        layers["w_down"] = dense_init(jax.random.fold_in(k_mlp, 1),
-                                      L, F, E)
+        layers.update(
+            w_in=_dense_init(keys[0], *stack, E, 3 * E),
+            conv_w=_dense_init(keys[1], *stack, E, cfg.conv_kernel,
+                               scale=cfg.conv_kernel ** -0.5),
+            w_out=_dense_init(keys[3], *stack, E, E))
+    if kind.dense:
+        F = cfg.dense_ffn_dim if cfg.moe_experts else cfg.mlp_dim
+        layers["w_gate"] = _dense_init(keys[4], *stack, E, F)
+        layers["w_up"] = _dense_init(keys[5], *stack, E, F)
+        layers["w_down"] = _dense_init(jax.random.fold_in(k_mlp, 1),
+                                       *stack, F, E)
+    else:
+        X, F, held = cfg.moe_experts, cfg.mlp_dim, cfg.experts_held[1]
+        layers["w_router"] = _dense_init(keys[4], *stack, E, X, scale=0.02)
+        if cfg.moe_router == "sigmoid_bias":
+            layers["expert_bias"] = jnp.zeros((*stack, X), jnp.float32)
+        layers["w_gate"] = _dense_init(keys[5], *stack, held, E, F)
+        layers["w_up"] = _dense_init(jax.random.fold_in(k_mlp, 0),
+                                     *stack, held, E, F)
+        layers["w_down"] = _dense_init(jax.random.fold_in(k_mlp, 1),
+                                       *stack, held, F, E)
+    return layers
+
+
+def init_params(rng, cfg):
+    """Layer weights are stacked on a leading [num_layers] axis
+    (scanned); for a stack whose layers differ (:func:`stack_plan`),
+    ``layers`` is {"lead": {"0": layer, ..}, "period": {"0": layers
+    stacked over the periods, ..}, "tail": {..}}."""
+    k_embed, k_attn, k_mlp, k_out = jax.random.split(rng, 4)
+    E = cfg.dim
+    plan = stack_plan(cfg)
+    if plan is None:
+        layers = _init_layers(k_attn, k_mlp, cfg, cfg.kinds[0],
+                              (cfg.num_layers,))
+    else:
+        def group(name, kinds, stack):
+            return {str(i): _init_layers(
+                jax.random.fold_in(k_attn, 1000 * name + i),
+                jax.random.fold_in(k_mlp, 2 + 1000 * name + i),
+                cfg, kind, stack) for i, kind in enumerate(kinds)}
+
+        layers = {"lead": group(0, plan.lead, ()),
+                  "period": group(1, plan.period, (plan.periods,)),
+                  "tail": group(2, plan.tail, ())}
     params = {
-        "embed": dense_init(k_embed, cfg.vocab_size, E, scale=0.02),
+        "embed": _dense_init(k_embed, cfg.vocab_size, E, scale=0.02),
         "layers": layers,
-        "ln_f": norm_init(E),
+        "ln_f": _norm_init(E),
     }
     if not cfg.tied_embeddings:
-        params["lm_head"] = dense_init(k_out, E, cfg.vocab_size, scale=0.02)
+        params["lm_head"] = _dense_init(k_out, E, cfg.vocab_size, scale=0.02)
     return params
 
 
 def param_specs(cfg):
     """PartitionSpec tree matching init_params' structure."""
+    _uniform_only(cfg, "a model-parallel mesh")
+    if cfg.moe_experts_held:
+        raise NotImplementedError(
+            "a model-parallel mesh shards all the experts over ep; "
+            "moe_experts_held is one chip's share (ROADMAP B6)")
     layers = {
         "ln1": P("pp", None),
         "wq": P("pp", None, "tp"),
@@ -215,12 +359,14 @@ def _rmsnorm(x, scale, eps=1e-6):
     return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 
-def _rope(x, positions):
-    """Rotary embeddings; x: [B, T, H, D]."""
+def _rope(x, positions, theta=10000.0):
+    """Rotary embeddings (rotate-half) at base ``theta``; x: [B, T, H,
+    D]."""
     d = x.shape[-1]
     half = d // 2
     freqs = jnp.exp(
-        -np.log(10000.0) * jnp.arange(0, half, dtype=jnp.float32) / half
+        -np.log(float(theta)) * jnp.arange(0, half, dtype=jnp.float32)
+        / half
     )
     angles = positions[:, None].astype(jnp.float32) * freqs[None, :]
     cos = jnp.cos(angles)[None, :, None, :]
@@ -232,21 +378,39 @@ def _rope(x, positions):
     return rotated.astype(x.dtype)
 
 
-def moe_route(h, w_router, cfg):
+def moe_route(h, w_router, cfg, expert_bias=None):
     """(probs [B, T, X] float32, gates [B, T, K] float32, experts
-    [B, T, K] int32).  The router's matmul and softmax run in float32
+    [B, T, K] int32).  The router's matmul and scores run in float32
     at the highest precision whatever the compute dtype (X*E
     multiply-adds a token), so that near-ties alone can change which
-    experts a token gets."""
+    experts a token gets.  ``cfg.moe_router`` "sigmoid_bias":
+    ``probs`` are the sigmoid scores, ``expert_bias`` [X] moves the
+    choice and not the weights, and no gradient reaches it."""
     logits = jnp.einsum(
         "bte,ex->btx", h.astype(jnp.float32),
         w_router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST)
+    top_k = min(cfg.moe_top_k, cfg.moe_experts)
+    if cfg.moe_router == "sigmoid_bias":
+        probs = checkpoint_name(jax.nn.sigmoid(logits),
+                                remat_keep.KEEP_ROUTE)
+        experts = checkpoint_name(jax.lax.top_k(
+            probs + jax.lax.stop_gradient(expert_bias), top_k)[1],
+            remat_keep.KEEP_ROUTE)
+        gates = checkpoint_name(
+            jnp.take_along_axis(probs, experts, axis=-1),
+            remat_keep.KEEP_ROUTE)
+        if cfg.moe_norm_topk:
+            gates = gates / (gates.sum(axis=-1, keepdims=True) + 1e-6)
+        return probs, gates * cfg.moe_route_scale, experts
+    if cfg.moe_router != "softmax":
+        raise ValueError("unknown moe_router %r (want 'softmax' or "
+                         "'sigmoid_bias')" % (cfg.moe_router,))
     probs = checkpoint_name(jax.nn.softmax(logits, axis=-1),
                             remat_keep.KEEP_ROUTE)
     gates, experts = (
         checkpoint_name(a, remat_keep.KEEP_ROUTE)
-        for a in jax.lax.top_k(probs, min(cfg.moe_top_k, cfg.moe_experts)))
+        for a in jax.lax.top_k(probs, top_k))
     if cfg.moe_norm_topk and gates.shape[-1] > 1:
         # GShard-style renormalization over the chosen experts.  Top-1
         # keeps the raw p_top1 gate (Switch): renormalizing would make
@@ -272,18 +436,25 @@ def _moe_ffn(h, w, cfg, mesh):
     its LINEAR sufficient statistics (assigned, mean_prob): callers
     that accumulate across microbatches (the pipeline) combine them at
     the end for the exact full-batch aux.  ``load`` [X + 1] float32:
-    assignments per expert, and the grouped matmul's padded rows.
+    assignments per expert, and the grouped matmul's padded rows; with
+    a share of the experts (``cfg.moe_experts_held``) [held + 1]: the
+    held experts' assignments alone.
     """
     B, T = h.shape[:2]
     X = cfg.moe_experts
-    probs, gates, experts = moe_route(h, w["w_router"], cfg)
+    first, held = cfg.experts_held
+    probs, gates, experts = moe_route(h, w["w_router"], cfg,
+                                      w.get("expert_bias"))
     weights = tuple(w[name].astype(h.dtype)
                     for name in ("w_gate", "w_up", "w_down"))
     with kernels_off(mesh is not None):
-        out, load = moe_experts(h, gates, experts, *weights)
+        out, load = moe_experts(h, gates, experts, *weights,
+                                total=X, first=first)
     load = load.sum(axis=0).astype(jnp.float32)
     stats = jnp.stack([load[:X] / (B * T), probs.mean(axis=(0, 1))])
     aux = X * jnp.sum(stats[0] * stats[1])
+    if held != X:
+        load = jnp.concatenate([load[first:first + held], load[X:]])
     return out, aux, stats, load
 
 
@@ -301,28 +472,34 @@ def _project_qkv(h, w, cfg, positions):
     compute_dtype = jnp.dtype(cfg.dtype)
     B, T = h.shape[0], h.shape[1]
     H, D, G = cfg.num_heads, cfg.head_dim, cfg.kv_heads
-    q = h @ w["wq"].astype(compute_dtype)
-    if cfg.qk_norm:
-        q = _rmsnorm(q, w["q_norm"].astype(compute_dtype), cfg.norm_eps)
-    q = q.reshape(B, T, H, D)
-    k = h @ w["wk"].astype(compute_dtype)
-    if cfg.qk_norm:
-        k = _rmsnorm(k, w["k_norm"].astype(compute_dtype), cfg.norm_eps)
-    k = k.reshape(B, T, G, D)
-    v = (h @ w["wv"].astype(compute_dtype)).reshape(B, T, G, D)
+    def project(name, norm, heads):
+        x = h @ w[name].astype(compute_dtype)
+        if norm and cfg.qk_norm != "head":   # over the whole projection
+            x = _rmsnorm(x, w[norm].astype(compute_dtype), cfg.norm_eps)
+        x = x.reshape(B, T, heads, D)
+        if norm and cfg.qk_norm == "head":   # over each head's D values
+            x = _rmsnorm(x, w[norm].astype(compute_dtype), cfg.norm_eps)
+        return x
+
+    q = project("wq", cfg.qk_norm and "q_norm", H)
+    k = project("wk", cfg.qk_norm and "k_norm", G)
+    v = project("wv", None, G)
     # as the attention takes them: what its backward reads
-    return (checkpoint_name(_rope(q, positions), remat_keep.KEEP_Q),
-            checkpoint_name(_rope(k, positions), remat_keep.KEEP_K),
+    return (checkpoint_name(_rope(q, positions, cfg.rope_theta),
+                            remat_keep.KEEP_Q),
+            checkpoint_name(_rope(k, positions, cfg.rope_theta),
+                            remat_keep.KEEP_K),
             checkpoint_name(v, remat_keep.KEEP_V))
 
 
-def _ffn(x, w, cfg, mesh):
+def _ffn(x, w, cfg, mesh, dense=False):
     """x + FFN(norm(x)) -> (x, aux, stats, load); the last three are
-    the MoE's (:func:`_moe_ffn`), zeros and None for a dense FFN."""
+    the MoE's (:func:`_moe_ffn`), zeros and None for a dense FFN (a
+    model without experts, or a ``dense`` layer of one with)."""
     compute_dtype = jnp.dtype(cfg.dtype)
     act_spec = P("dp", "sp", None)
     h = _rmsnorm(x, w["ln2"].astype(compute_dtype), cfg.norm_eps)
-    if cfg.moe_experts:
+    if cfg.moe_experts and not dense:
         out, aux, stats, load = _moe_ffn(h, w, cfg, mesh)
         return x + _constrain(out, mesh, act_spec), aux, stats, load
     gate = checkpoint_name(h @ w["w_gate"].astype(compute_dtype),
@@ -384,9 +561,23 @@ def _attention(x, w, cfg, mesh, positions):
     return checkpoint_name(x, remat_keep.KEEP_STREAM), kv_out
 
 
+def _short_conv(x, w, cfg):
+    """x + W_out(short_conv(W_in norm(x))): the operator of a "c"
+    layer (``ops/short_conv.py``, which picks kernel or reference)."""
+    compute_dtype = jnp.dtype(cfg.dtype)
+    h = _rmsnorm(x, w["ln1"].astype(compute_dtype), cfg.norm_eps)
+    bcu = checkpoint_name(h @ w["w_in"].astype(compute_dtype),
+                          short_conv.KEEP_IN)
+    mixed = short_conv.short_conv(bcu, w["conv_w"])
+    x = x + mixed @ w["w_out"].astype(compute_dtype)
+    return checkpoint_name(x, remat_keep.KEEP_STREAM)
+
+
 def _layer_body(x, w, cfg, mesh, positions, moe_stats=False,
-                return_kv=False, moe_load=False):
-    """One transformer block; shared by the scanned stack (forward) and
+                return_kv=False, moe_load=False, kind=None):
+    """One block, ``x + Op(norm(x))`` then ``x + FFN(norm(x))``, of
+    ``kind`` (None: attention, and the model's one FFN); shared by the
+    scanned stack (forward) and
     the per-stage slice scan (forward_pipelined).  ``moe_stats`` swaps
     the scalar aux for the linear [2, X] router statistics (pipeline
     accumulation); ``moe_load`` returns (aux, load [X + 1]) for an MoE
@@ -395,11 +586,15 @@ def _layer_body(x, w, cfg, mesh, positions, moe_stats=False,
     cache.  Which kernels run is not its business: the ops ask
     ``ops/mode.py``, and a caller that traces it where none can run
     says so with ``kernels_off()``."""
-    x, kv_out = _attention(x, w, cfg, mesh, positions)
-    x, aux, stats, load = _ffn(x, w, cfg, mesh)
-    if moe_stats and cfg.moe_experts:
+    kind = kind or Kind("a", not cfg.moe_experts)
+    if kind.op == "c":
+        x, kv_out = _short_conv(x, w, cfg), None
+    else:
+        x, kv_out = _attention(x, w, cfg, mesh, positions)
+    x, aux, stats, load = _ffn(x, w, cfg, mesh, dense=kind.dense)
+    if moe_stats and not kind.dense:
         aux = stats
-    elif moe_load and cfg.moe_experts:
+    elif moe_load and not kind.dense:
         aux = (aux, load)
     if return_kv:
         return x, (aux, kv_out)
@@ -446,14 +641,12 @@ def forward_hidden(params, tokens, cfg, mesh=None, return_load=False):
     x = _constrain(x, mesh, act_spec)
     positions = jnp.arange(tokens.shape[1])
 
-    with_load = bool(return_load and cfg.moe_experts)
-
-    def layer(x, w):
-        return _layer_body(x, w, cfg, mesh, positions, moe_load=with_load)
-
+    with_load = bool(return_load
+                     and not all(kind.dense for kind in cfg.kinds))
+    remat = lambda fn: fn
     if cfg.remat == "dots":
-        layer = jax.checkpoint(
-            layer,
+        remat = functools.partial(
+            jax.checkpoint,
             policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
         )
     elif cfg.remat:
@@ -463,15 +656,74 @@ def forward_hidden(params, tokens, cfg, mesh=None, return_load=False):
         names = (remat_keep.ATTN_NAMES if cfg.remat == "attn"
                  else () if mesh is not None
                  else remat_keep.names_for(cfg, params, tokens.shape))
-        layer = jax.checkpoint(
-            layer,
+        remat = functools.partial(
+            jax.checkpoint,
             policy=(jax.checkpoint_policies.save_only_these_names(*names)
                     if names else None))
-    x, aux_per_layer = jax.lax.scan(layer, x, params["layers"])
+
+    def block(kind=None):
+        def layer(x, w):
+            return _layer_body(x, w, cfg, mesh, positions,
+                               moe_load=with_load, kind=kind)
+
+        return remat(layer)
+
+    plan = stack_plan(cfg)
+    if plan is None:
+        x, aux_per_layer = jax.lax.scan(block(), x, params["layers"])
+    else:
+        x, aux_per_layer = _mixed_stack(x, params["layers"], cfg, plan,
+                                        block)
     if with_load:
         aux_per_layer, load = aux_per_layer
         return x, aux_per_layer.mean(), load
     return x, aux_per_layer.mean()
+
+
+@functools.lru_cache(maxsize=None)
+def announce_stack(pattern, plan, experts):
+    """Once per model, by the logger ``announce_tiles`` uses: how a
+    stack whose layers differ is run."""
+    letters = lambda kinds: "".join(k.op for k in kinds) or "-"
+    logger.info(
+        "layer stack: pattern=%s lead=%s period=%s periods=%d tail=%s "
+        "dense_layers=%d experts_held=%d/%d", pattern, letters(plan.lead),
+        letters(plan.period), plan.periods, letters(plan.tail),
+        len(plan.lead), *experts)
+
+
+def _mixed_stack(x, layers, cfg, plan, block):
+    """The leading layers, the whole periods under one scan (a period's
+    layers unrolled in its body), the remainder -> (x, what the layers
+    with experts returned beside it, stacked in layer order: aux [L_moe]
+    or (aux [L_moe], load [L_moe, ..]); a zero where none has experts)."""
+    announce_stack(cfg.layer_pattern or "a" * cfg.num_layers, plan,
+                   (cfg.experts_held[1], cfg.moe_experts))
+
+    tree_map = jax.tree_util.tree_map
+
+    def run(x, kinds, weights):
+        """(x, what the layers with experts returned, stacked; None
+        where none has)."""
+        seen = []
+        for i, kind in enumerate(kinds):
+            x, out = block(kind)(x, weights[str(i)])
+            if not kind.dense:
+                seen.append(out)
+        return x, tree_map(lambda *a: jnp.stack(a), *seen) if seen else None
+
+    x, lead = run(x, plan.lead, layers["lead"])
+    period = None
+    if plan.periods:
+        x, period = jax.lax.scan(
+            lambda x, w: run(x, plan.period, w), x, layers["period"])
+        # [periods, positions, ..] -> [periods * positions, ..]
+        period = tree_map(lambda a: a.reshape((-1,) + a.shape[2:]), period)
+    x, tail = run(x, plan.tail, layers["tail"])
+    parts = [part for part in (lead, period, tail) if part is not None]
+    if not parts:
+        return x, jnp.zeros((1,), jnp.float32)
+    return x, tree_map(lambda *a: jnp.concatenate(a), *parts)
 
 
 def forward(params, tokens, cfg, mesh=None, return_aux=False):
@@ -513,6 +765,7 @@ def forward_pipelined(params, tokens, cfg, mesh, num_microbatches,
         split_microbatches,
     )
 
+    _uniform_only(cfg, "forward_pipelined")
     if mesh.shape.get("sp", 1) != 1:
         raise ValueError(
             "forward_pipelined requires sp=1 (stage-local attention); "
@@ -631,6 +884,7 @@ def prefill(params, cfg, prompt, max_len):
     ``max_len``.  Returns (last-position logits [B, V], caches).  This
     is the time-to-first-token path — Tp sequential decode steps would
     be MXU-starved serialized work."""
+    _uniform_only(cfg, "prefill")
     compute_dtype = jnp.dtype(cfg.dtype)
     b, tp = prompt.shape
     x = params["embed"].astype(compute_dtype)[prompt]
@@ -656,6 +910,7 @@ def decode_step(params, cfg, caches, pos, tokens_1):
     """One decode step: tokens_1 [B] int32 at position ``pos`` ->
     (logits [B, V], updated caches).  ``caches`` from
     :func:`init_kv_cache`."""
+    _uniform_only(cfg, "decode_step")
     compute_dtype = jnp.dtype(cfg.dtype)
     x = params["embed"].astype(compute_dtype)[tokens_1][:, None, :]
 
@@ -679,6 +934,7 @@ def generate(params, cfg, prompt, max_new_tokens, temperature=0.0,
     temperature.  Positions use RoPE, so sequences may run past
     cfg.max_seq_len (quality, not correctness, degrades).
     """
+    _uniform_only(cfg, "generate")
     prompt = jnp.asarray(prompt, jnp.int32)
     b, tp = prompt.shape
     if tp == 0:
@@ -793,21 +1049,36 @@ def _flag(name, value):
     return bool(value)
 
 
+def _decayed(params):
+    """AdamW's weight-decay mask: everything but the routers'
+    ``expert_bias``."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: getattr(path[-1], "key", None) != "expert_bias",
+        params)
+
+
 def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
                seq_len=512, learning_rate=3e-4, mesh=None, dtype="bfloat16",
                pipeline_microbatches=0, moe_experts=0, moe_top_k=2,
                moe_aux_weight=0.01, remat=False, attention_impl="ring",
                window=0, xent_chunk=0, num_kv_heads=0, ffn_dim=0,
                norm_eps=1e-6, qk_norm=False, moe_norm_topk=True,
-               tied_embeddings=True):
+               tied_embeddings=True, rope_theta=10000.0, layer_pattern="",
+               dense_layers=0, dense_ffn_dim=0, conv_kernel=3,
+               moe_router="softmax", moe_route_scale=1.0,
+               moe_experts_held=0, moe_share_index=0, warmup_steps=0):
     """Zoo entry for the flagship LM.
 
     ``remat`` (False | True | "dots" | "attn"), ``attention_impl``
     ("ring" | "ulysses"), ``window`` (sliding-window causal, 0 = full),
     ``num_kv_heads`` (grouped-query attention: 0 = MHA, G > 0
     shares each K/V head across num_heads/G query heads), ``ffn_dim``
-    (MLP or expert width, 0 = 4 * dim), ``norm_eps``, ``qk_norm``,
-    ``moe_norm_topk`` and ``tied_embeddings`` pass through
+    (MLP or expert width, 0 = 4 * dim), ``norm_eps``, ``qk_norm``
+    (false | true | head), ``moe_norm_topk``, ``tied_embeddings``,
+    ``rope_theta``, the stack's ``layer_pattern`` / ``dense_layers`` /
+    ``dense_ffn_dim`` / ``conv_kernel``, the router's ``moe_router`` /
+    ``moe_route_scale`` and the share ``moe_experts_held`` /
+    ``moe_share_index`` pass through
     to :class:`TransformerConfig`.  ``xent_chunk`` > 0 computes the
     loss via :func:`next_token_loss_chunked` — no [B, T, V] logits
     tensor, the memory-lean path for large vocab x seq (numerically
@@ -815,7 +1086,22 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
 
     Training an MoE through the scanned stack, the spec also hands the
     trainer its step statistics (``step_stats_fn``): each layer's
-    assignments per expert and padded rows, ``moe_load`` [L, X + 1].
+    assignments per expert and padded rows, ``moe_load`` [L, X + 1];
+    with a share of the experts, the held experts' alone, and
+    ``moe_moved`` [L], the rows each layer's dispatch moved.
+
+    ``warmup_steps`` > 0 raises AdamW's rate from 0 to ``learning_rate``
+    linearly over that many steps (0: constant, as ever).  Adam's steps
+    do not scale with the gradient, so at the full rate a 2048-wide
+    router's logits move by up to ~0.6 a step from the first step on;
+    under a share of the experts, whose router gradient holds the held
+    experts' terms alone, that drove every held expert out of the top K
+    within 16-24 steps on the chip (PERF.md section 6, PR 31).
+
+    A ``sigmoid_bias`` router's ``expert_bias`` is state and no weight:
+    it lives in the parameter tree (so checkpoints and the reference
+    carry it), no gradient reaches it, and AdamW's weight decay is
+    masked from it here, so the optimizer leaves it as it is.
     """
     cfg = TransformerConfig(
         vocab_size=vocab_size, dim=dim, num_heads=num_heads,
@@ -824,11 +1110,22 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
         moe_aux_weight=moe_aux_weight, remat=remat,
         attention_impl=attention_impl, window=window,
         num_kv_heads=num_kv_heads, ffn_dim=ffn_dim,
-        norm_eps=float(norm_eps), qk_norm=_flag("qk_norm", qk_norm),
+        norm_eps=float(norm_eps),
+        qk_norm=("head" if str(qk_norm).strip().lower() == "head"
+                 else _flag("qk_norm", qk_norm)),
         moe_norm_topk=_flag("moe_norm_topk", moe_norm_topk),
         tied_embeddings=_flag("tied_embeddings", tied_embeddings),
+        rope_theta=float(rope_theta), layer_pattern=str(layer_pattern),
+        dense_layers=int(dense_layers), dense_ffn_dim=int(dense_ffn_dim),
+        conv_kernel=int(conv_kernel), moe_router=str(moe_router),
+        moe_route_scale=float(moe_route_scale),
+        moe_experts_held=int(moe_experts_held),
+        moe_share_index=int(moe_share_index),
     )
-    cfg.kv_heads  # validate num_heads % num_kv_heads at spec build
+    # validate at spec build: heads, the share, the pattern
+    cfg.kv_heads, cfg.experts_held, cfg.kinds
+    if mesh is not None:
+        param_specs(cfg)    # raises, naming what a mesh cannot run yet
     pipelined = (
         pipeline_microbatches > 0
         and mesh is not None
@@ -868,7 +1165,7 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
             params = shard_params(params, mesh, cfg)
         return params
 
-    moe = bool(cfg.moe_experts)
+    moe = not all(kind.dense for kind in cfg.kinds)   # a layer has experts
 
     def apply_fn(params, tokens, train):
         """Logits; training, a dict for ``loss_fn``, in which the head
@@ -908,6 +1205,16 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
             loss = loss + cfg.moe_aux_weight * outputs["aux"]
         return loss
 
+    def step_stats(outputs):
+        stats = {"moe_load": outputs["moe_load"]}
+        if cfg.moe_experts_held:
+            # every (token, choice) row, whoever holds its expert
+            batch, seq_len = outputs["hidden"].shape[:2]
+            stats["moe_moved"] = jnp.full(
+                stats["moe_load"].shape[:1], float(
+                    batch * seq_len * min(cfg.moe_top_k, cfg.moe_experts)))
+        return stats
+
     def feed(records):
         toks = np.stack(
             [np.asarray(r[0], dtype=np.int32) for r in records]
@@ -920,14 +1227,15 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
         init_fn=init_fn,
         apply_fn=apply_fn,
         loss_fn=loss_fn,
-        optimizer=optax.adamw(learning_rate, weight_decay=0.01),
+        optimizer=optax.adamw(
+            (optax.linear_schedule(0.0, learning_rate, int(warmup_steps))
+             if warmup_steps else learning_rate), weight_decay=0.01,
+            mask=(_decayed if cfg.moe_router == "sigmoid_bias" else None)),
         feed=feed,
         eval_metrics_fn=lambda: {
             "nll": metrics.Mean(lambda outputs, labels: outputs)
         },
-        step_stats_fn=(
-            (lambda outputs: {"moe_load": outputs["moe_load"]})
-            if moe and not pipelined else None),
+        step_stats_fn=None if pipelined or not moe else step_stats,
     )
     spec.config = cfg
     return spec

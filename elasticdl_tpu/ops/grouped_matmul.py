@@ -4,7 +4,11 @@ weights — the multiply of a dropless mixture-of-experts FFN.
 ``grouped_matmul(lhs [M, K], rhs [X, K, N], group_sizes [X]) -> [M, N]``:
 rows ``offset[g] .. offset[g + 1]`` of ``lhs`` (``offset`` the running
 sum of ``group_sizes``, which must add up to M) are multiplied by
-``rhs[g]``.  The algorithm is the public one of
+``rhs[g]``.  With ``zero_tail`` the sizes may add up to less (a chip that
+holds a share of the experts sorts their rows first): the kernels visit
+no tile past the groups and write nothing there, so the op itself zeroes
+those rows, of the product and of the input's gradient alike, and no
+undefined value leaves it.  The algorithm is the public one of
 ``jax.experimental.pallas.ops.tpu.megablox``: the grid walks (column
 tile, work item), a work item being one (row tile, group) pair that
 share rows; which tile and which group each item is comes from the group
@@ -62,13 +66,31 @@ _NT = (((1,), (1,)), ((), ()))   # a @ b^T
 _TN = (((0,), (0,)), ((), ()))   # a^T @ b
 
 
-def grouped_matmul_ref(lhs, rhs, group_sizes, transpose_rhs=False):
-    """The same product by ``lax.ragged_dot`` (float32 accumulation)."""
+def grouped_matmul_ref(lhs, rhs, group_sizes, transpose_rhs=False,
+                       zero_tail=False):
+    """The same product by ``lax.ragged_dot`` (float32 accumulation).
+    With ``zero_tail`` a last group of zero weights takes the rows past
+    the others: what ``ragged_dot`` and its transposes leave in rows no
+    group covers is the backend's business (zeros on the CPU, and from
+    one call alone on a TPU; inside a whole step there they were not:
+    PERF.md section 6, PR 31)."""
     if transpose_rhs:
         rhs = rhs.swapaxes(1, 2)
+    sizes = group_sizes.astype(jnp.int32)
+    if zero_tail:
+        sizes = jnp.concatenate([sizes, lhs.shape[0] - sizes.sum(
+            keepdims=True)])
+        rhs = jnp.concatenate([rhs, jnp.zeros_like(rhs[:1])])
     return lax.ragged_dot(
-        lhs, rhs, group_sizes.astype(jnp.int32),
+        lhs, rhs, sizes,
         preferred_element_type=jnp.float32).astype(lhs.dtype)
+
+
+def _zero_tail(out, group_sizes):
+    """``out`` with the rows past the groups' total zeroed."""
+    live = lax.broadcasted_iota(jnp.int32, (out.shape[0], 1), 0) \
+        < group_sizes.sum()
+    return jnp.where(live, out, jnp.zeros((), out.dtype))
 
 
 def row_tile(m):
@@ -308,26 +330,29 @@ def _pad_rows(a, m_pad):
         a, ((0, m_pad - a.shape[0]), (0, 0)))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _gmm(lhs, rhs, group_sizes, transpose_rhs, interpret, tm):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _gmm(lhs, rhs, group_sizes, transpose_rhs, interpret, tm, zero_tail):
     m = lhs.shape[0]
     m_pad = -(-m // tm) * tm
     k = lhs.shape[1]
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     tn = _column_tile(tm, k, n, lhs.dtype.itemsize)
-    return _gmm_call(_pad_rows(lhs, m_pad), rhs, group_sizes,
-                     transpose_rhs, interpret, tm, tn)[:m]
+    out = _gmm_call(_pad_rows(lhs, m_pad), rhs, group_sizes,
+                    transpose_rhs, interpret, tm, tn)[:m]
+    return _zero_tail(out, group_sizes) if zero_tail else out
 
 
-def _gmm_fwd(lhs, rhs, group_sizes, transpose_rhs, interpret, tm):
-    return (_gmm(lhs, rhs, group_sizes, transpose_rhs, interpret, tm),
-            (lhs, rhs, group_sizes))
+def _gmm_fwd(lhs, rhs, group_sizes, transpose_rhs, interpret, tm,
+             zero_tail):
+    return (_gmm(lhs, rhs, group_sizes, transpose_rhs, interpret, tm,
+                 zero_tail), (lhs, rhs, group_sizes))
 
 
-def _gmm_bwd(transpose_rhs, interpret, tm, res, dout):
+def _gmm_bwd(transpose_rhs, interpret, tm, zero_tail, res, dout):
     lhs, rhs, group_sizes = res
     m_pad = -(-lhs.shape[0] // tm) * tm
-    dlhs = _gmm(dout, rhs, group_sizes, not transpose_rhs, interpret, tm)
+    dlhs = _gmm(dout, rhs, group_sizes, not transpose_rhs, interpret, tm,
+                zero_tail)
     # rhs is [X, a, b] and its gradient a^T-side @ b-side of the rows.
     a, b = (dout, lhs) if transpose_rhs else (lhs, dout)
     tk, tn = _tgmm_tiles(tm, a.shape[1], b.shape[1], lhs.dtype.itemsize)
@@ -353,16 +378,18 @@ def _unfriendly(k, n, tm, itemsize):
 
 
 def grouped_matmul(lhs, rhs, group_sizes, transpose_rhs=False,
-                   interpret=None, row_tile_rows=None):
+                   interpret=None, row_tile_rows=None, zero_tail=False):
     """lhs [M, K] (rows sorted by group), rhs [X, K, N] (or [X, N, K]
-    with ``transpose_rhs``), group_sizes [X] int32 adding up to M ->
+    with ``transpose_rhs``), group_sizes [X] int32 adding up to M (to
+    at most M with ``zero_tail``: the rows past them come back zero) ->
     [M, N] in lhs's dtype, float32 accumulation.  Differentiable in lhs
     and rhs.  The kernels where ``ops/mode.py`` allows them and the
     widths fit, else ``grouped_matmul_ref``.  ``row_tile_rows``
     overrides the row tile (tests)."""
     mode = resolve(interpret)
     if mode == "off":
-        return grouped_matmul_ref(lhs, rhs, group_sizes, transpose_rhs)
+        return grouped_matmul_ref(lhs, rhs, group_sizes, transpose_rhs,
+                                  zero_tail)
     m, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     tm = row_tile_rows or row_tile(m)
@@ -371,6 +398,7 @@ def grouped_matmul(lhs, rhs, group_sizes, transpose_rhs=False,
         why = "widths %dx%d are not multiples of the 128 lanes" % (k, n)
     if why:
         announce_fallback("grouped_matmul", (m, k, n), why, mode)
-        return grouped_matmul_ref(lhs, rhs, group_sizes, transpose_rhs)
+        return grouped_matmul_ref(lhs, rhs, group_sizes, transpose_rhs,
+                                  zero_tail)
     return _gmm(lhs, rhs.astype(lhs.dtype), group_sizes.astype(jnp.int32),
-                transpose_rhs, mode == "interpret", tm)
+                transpose_rhs, mode == "interpret", tm, zero_tail)

@@ -46,29 +46,45 @@ _take_rows.defvjp(
     lambda k, x, order, inverse: (x[order // k], inverse), _take_rows_bwd)
 
 
-def _moe_experts(h, gates, experts, w_gate, w_up, w_down):
+def _moe_experts(h, gates, experts, w_gate, w_up, w_down, total=None,
+                 first=0):
     """The routed FFN of the rows this device holds: sort the n * K
     (token, choice) assignments by expert, gather their rows, three
     grouped matmuls, un-sort and sum each token's K results weighted by
     its gates.  O(n * K * width) memory, no capacity, nothing dropped.
     Returns (out [b, T, E], load [1, X + 1]: rows per expert, then the
-    rows the grouped matmul computes beyond the real ones)."""
+    rows the grouped matmul computes beyond the real ones).
+
+    The weights may be a share of the ``total`` experts the tokens are
+    routed over, ``first .. first + held``: the assignments are sorted
+    with the held experts' first, all n * K rows are moved as ever, the
+    held experts' alone are multiplied, and the others' rows are zeros
+    (``grouped_matmul(zero_tail=True)``) that the weighted sum adds as
+    nothing.  ``load`` counts all ``total`` experts either way."""
     b, t, e = h.shape
-    x, k = w_gate.shape[0], experts.shape[-1]
+    held, k = w_gate.shape[0], experts.shape[-1]
+    x = total or held
+    share = held != x
     n, rows = b * t, b * t * k
     mode = kernel_mode()
     if mode != "interpret":
-        announce_dispatch(n, x, k, mode)
+        announce_dispatch(n, x, k, mode, held if share else None)
     flat = experts.reshape(rows)
+    if share:    # expert ``first`` sorts as 0, the absent ones last
+        flat = (flat - first) % x
     order = jnp.argsort(flat, stable=True).astype(jnp.int32)
     inverse = jnp.argsort(order).astype(jnp.int32)
     sizes = (flat[:, None] == jnp.arange(x, dtype=flat.dtype)).sum(
         axis=0, dtype=jnp.int32)
     order, inverse, sizes = (
         checkpoint_name(a, KEEP_SORT) for a in (order, inverse, sizes))
+    counted = sizes          # every expert's rows, in the experts' order
+    if share:
+        counted, sizes = jnp.roll(sizes, first), sizes[:held]
     padded = (jnp.int32(0) if mode == "off"
               else gm.padded_rows(sizes, rows))
-    matmul = gm.grouped_matmul      # asks the mode itself: as here
+    # asks the mode itself: as here
+    matmul = functools.partial(gm.grouped_matmul, zero_tail=share)
     xs = checkpoint_name(
         _take_rows(k, h.reshape(n, e), order, inverse), KEEP_ROWS)
     gate = checkpoint_name(matmul(xs, w_gate, sizes), KEEP_GATE)
@@ -78,29 +94,35 @@ def _moe_experts(h, gates, experts, w_gate, w_up, w_down):
         _take_rows(1, matmul(act, w_down, sizes), inverse, order), KEEP_OUT)
     out = jnp.einsum("nke,nk->ne", ys.reshape(n, k, e).astype(jnp.float32),
                      gates.reshape(n, k))
-    load = jnp.concatenate([sizes, padded.reshape(1)])[None]
+    load = jnp.concatenate([counted, padded.reshape(1)])[None]
     return out.astype(h.dtype).reshape(b, t, e), load
 
 
 @functools.lru_cache(maxsize=None)
-def announce_dispatch(tokens, experts, top_k, kernel):
+def announce_dispatch(tokens, experts, top_k, kernel, held=None):
     """Once per compiled shape, by the logger ``announce_tiles`` uses:
     what the dispatch hands the grouped matmul (of one shard of the
-    trainer's data axis, where there is one)."""
+    trainer's data axis, where there is one); ``held=`` where the
+    weights are a share of the experts."""
     rows = tokens * top_k
     tile = gm.row_tile(rows)
     flash_attention.logger.info(
         "moe dispatch: tokens=%d experts=%d top_k=%d rows=%d row_tile=%d "
-        "groups_tiles<=%d kernel=%s", tokens, experts, top_k, rows, tile,
-        -(-rows // tile) + experts - 1, kernel)
+        "groups_tiles<=%d kernel=%s%s", tokens, experts, top_k, rows, tile,
+        -(-rows // tile) + (held or experts) - 1, kernel,
+        "" if held is None else " held=%d" % held)
 
 
-def moe_experts(h, gates, experts, w_gate, w_up, w_down):
+def moe_experts(h, gates, experts, w_gate, w_up, w_down, total=None,
+                first=0):
     """h [B, T, E], gates and experts [B, T, K], the three expert
-    weights [X, ...] in h's dtype -> (out [B, T, E], load [shards,
-    X + 1]).  Where a kernel runs, once per shard of the declared batch
-    axis (weights whole on each); the reference partitions by itself."""
+    weights [X, ...] in h's dtype (or the share ``first .. first + X``
+    of ``total`` experts: ``_moe_experts``) -> (out [B, T, E], load
+    [shards, total + 1]).  Where a kernel runs, once per shard of the
+    declared batch axis (weights whole on each); the reference
+    partitions by itself."""
+    fn = functools.partial(_moe_experts, total=total, first=first)
     if kernel_mode() == "off":
-        return _moe_experts(h, gates, experts, w_gate, w_up, w_down)
-    return per_batch_shard(_moe_experts, (h, gates, experts),
+        return fn(h, gates, experts, w_gate, w_up, w_down)
+    return per_batch_shard(fn, (h, gates, experts),
                            (w_gate, w_up, w_down))

@@ -1,0 +1,296 @@
+"""Gated short convolution (Pallas TPU): the operator of a layer that
+mixes a sequence with a depthwise causal convolution a few taps long.
+
+``short_conv(bcu [B, T, 3E], w [E, K]) -> [B, T, E]``: ``bcu`` is the
+input projection's output, whose three column blocks are B, C and u;
+``g = B * u``; ``c_t = sum_k w[:, k] * g_(t - (K - 1 - k))`` per channel
+(causal, zero before the sequence's start); the result is ``C * c``.
+One pass: a call reads B, C and u as column blocks of the one array (no
+split copies), keeps ``g`` and ``c`` in VMEM, and writes the result; the
+taps before a row tile's first row come from the ``HALO`` rows above it,
+and a tile that starts a sequence takes zeros (a row tile never spans
+two sequences: it divides T).
+
+The ``custom_vjp`` is one more call of the same shape: it rebuilds ``g``
+and ``c`` from the saved ``bcu``, runs the taps the other way over
+``dc = dout * C`` (the rows below the tile are its halo, zero past the
+sequence's end) and writes dB, dC and du as the three column blocks of
+one ``[rows, 3E]`` array, a block a grid step, beside each row tile's
+part of ``dw`` (summed outside: ``[tiles * 8, E]`` float32).  Every
+result of these calls is 2-D (the benchmark tells the flash kernels
+apart by their 3-D results) and the calls carry their names into the
+trace, ``sconv_fwd`` and ``sconv_bwd`` (``benchmark/kernels/``).
+
+The op is bound by memory: 4 passes of ``[rows, E]`` forward, 7 backward.
+
+Reference: ``short_conv_ref``, plain ``jax.numpy`` differentiated by JAX,
+which is also what ``short_conv`` returns wherever ``ops/mode.py``
+answers ``off``, or for shapes the kernel does not tile (it says so:
+``announce_fallback``).  An explicit ``interpret=`` forces the kernels.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from elasticdl_tpu.ops import flash_attention
+from elasticdl_tpu.ops.batch_shard import per_batch_shard, shards
+from elasticdl_tpu.ops.mode import resolve
+
+# ``checkpoint_name``s of what the backward reads, for
+# models/remat_keep.py: the projection's output (named by the model,
+# where it is made) and the op's result (the output projection's
+# operand).
+KEEP_IN, KEEP_OUT = "conv_in", "conv_out"
+
+# Rows of the block a tile's neighbouring rows are read in: bfloat16's
+# least tile height.  The kernel has K - 1 <= HALO.
+HALO = 16
+ROW_TILES = (512, 256, 128, 64, 32, 16)
+CHANNEL_TILES = (512, 256, 128)
+VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def short_conv_ref(bcu, w):
+    """The same in plain ``jax.numpy`` (float32 inside, bcu's dtype
+    out); bcu [..., T, 3E], w [E, K]."""
+    b, c, u = jnp.split(bcu.astype(jnp.float32), 3, axis=-1)
+    g = b * u
+    t, taps = g.shape[-2], w.shape[1]
+    pad = [(0, 0)] * (g.ndim - 2) + [(taps - 1, 0), (0, 0)]
+    padded = jnp.pad(g, pad)
+    conv = sum(w[:, k].astype(jnp.float32)
+               * lax.slice_in_dim(padded, k, k + t, axis=-2)
+               for k in range(taps))
+    return (c * conv).astype(bcu.dtype)
+
+
+def tiles(seq_len, channels):
+    """(row tile, channel tile) of the kernels for sequences of
+    ``seq_len`` rows, or None where they do not tile."""
+    tm = next((t for t in ROW_TILES if seq_len % t == 0), None)
+    tc = next((t for t in CHANNEL_TILES if channels % t == 0), None)
+    return None if tm is None or tc is None else (tm, tc)
+
+
+def _shifted(g, edge, back):
+    """g's rows moved ``back`` rows down (up for ``back`` < 0), the rows
+    that enter from outside the tile taken from ``edge`` [HALO, tc]: the
+    rows above the tile (their last ones enter) or below it (their
+    first)."""
+    tm = g.shape[0]
+    row = lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+    out = pltpu.roll(g, back % tm, 0)
+    for i in range(abs(back)):
+        if back > 0:      # row i takes the halo's row HALO - back + i
+            at, src = i, HALO - back + i
+        else:             # row tm - |back| + i takes the halo's row i
+            at, src = tm + back + i, i
+        out = jnp.where(row == at, edge[src:src + 1, :], out)
+    return out
+
+
+def _gate(b_ref, u_ref):
+    return b_ref[...].astype(jnp.float32) * u_ref[...].astype(jnp.float32)
+
+
+def _conv(g, above, w_ref, taps):
+    """c [tm, tc] float32 of the tile's g and the HALO rows above."""
+    conv = w_ref[taps - 1:taps, :] * g
+    for k in range(taps - 1):
+        conv += w_ref[k:k + 1, :] * _shifted(g, above, taps - 1 - k)
+    return conv
+
+
+def _fwd_kernel(b_ref, c_ref, u_ref, b_up_ref, u_up_ref, w_ref, out_ref,
+                *, taps, tiles_a_sequence):
+    # the rows above a sequence's first tile are another sequence's
+    starts = pl.program_id(0) % tiles_a_sequence == 0
+    above = jnp.where(starts, 0.0, _gate(b_up_ref, u_up_ref))
+    conv = _conv(_gate(b_ref, u_ref), above, w_ref, taps)
+    out_ref[...] = (c_ref[...].astype(jnp.float32) * conv).astype(
+        out_ref.dtype)
+
+
+def _bwd_kernel(b_ref, c_ref, u_ref, b_up_ref, u_up_ref, w_ref, dout_ref,
+                dout_dn_ref, c_dn_ref, dbcu_ref, dw_ref, dc_keep, du_keep,
+                *, taps, tiles_a_sequence):
+    part = pl.program_id(2)
+    starts = pl.program_id(0) % tiles_a_sequence == 0
+    ends = (pl.program_id(0) + 1) % tiles_a_sequence == 0
+
+    @pl.when(part == 0)
+    def _():
+        g = _gate(b_ref, u_ref)
+        above = jnp.where(starts, 0.0, _gate(b_up_ref, u_up_ref))
+        dout = dout_ref[...].astype(jnp.float32)
+        dc_keep[...] = (dout * _conv(g, above, w_ref, taps)).astype(
+            dc_keep.dtype)
+        dconv = dout * c_ref[...].astype(jnp.float32)
+        below = jnp.where(ends, 0.0, dout_dn_ref[...].astype(jnp.float32)
+                          * c_dn_ref[...].astype(jnp.float32))
+        dg = w_ref[taps - 1:taps, :] * dconv
+        dw = [None] * taps
+        dw[taps - 1] = jnp.sum(dconv * g, axis=0, keepdims=True)
+        for k in range(taps - 1):
+            back = taps - 1 - k
+            dg += w_ref[k:k + 1, :] * _shifted(dconv, below, -back)
+            dw[k] = jnp.sum(dconv * _shifted(g, above, back), axis=0,
+                            keepdims=True)
+        row = lax.broadcasted_iota(jnp.int32, dw_ref.shape, 0)
+        dw_ref[...] = sum(jnp.where(row == k, dw[k], 0.0)
+                          for k in range(taps))
+        dbcu_ref[...] = (dg * u_ref[...].astype(jnp.float32)).astype(
+            dbcu_ref.dtype)
+        du_keep[...] = (dg * b_ref[...].astype(jnp.float32)).astype(
+            du_keep.dtype)
+
+    @pl.when(part == 1)
+    def _():
+        dbcu_ref[...] = dc_keep[...]
+
+    @pl.when(part == 2)
+    def _():
+        dbcu_ref[...] = du_keep[...]
+
+
+def _specs(tm, tc, e, rows):
+    """BlockSpecs of B, C, u (column blocks of one [rows, 3E] array),
+    of B's and u's HALO rows above the tile, and of the taps, for a grid
+    that leads with (row tile, channel tile)."""
+    blocks = e // tc
+    per_halo = tm // HALO
+
+    def column(which):
+        return pl.BlockSpec((tm, tc),
+                            lambda i, j, *_: (i, which * blocks + j))
+
+    def above(which):
+        return pl.BlockSpec(
+            (HALO, tc), lambda i, j, *_: (
+                jnp.maximum(i * per_halo - 1, 0), which * blocks + j))
+
+    def below(which):
+        return pl.BlockSpec(
+            (HALO, tc), lambda i, j, *_: (
+                jnp.minimum((i + 1) * per_halo, rows // HALO - 1),
+                which * blocks + j))
+
+    taps = pl.BlockSpec((8, tc), lambda i, j, *_: (0, j))
+    return column, above, below, taps
+
+
+def _taps(w):
+    """[8, E] float32: row k is tap k of every channel."""
+    e, taps = w.shape
+    return jnp.zeros((8, e), jnp.float32).at[:taps].set(
+        w.astype(jnp.float32).T)
+
+
+def _fwd_call(bcu, w, seq_len, interpret, tm, tc):
+    rows, e = bcu.shape[0], w.shape[0]
+    column, above, _below, taps = _specs(tm, tc, e, rows)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, taps=w.shape[1],
+                          tiles_a_sequence=seq_len // tm),
+        out_shape=jax.ShapeDtypeStruct((rows, e), bcu.dtype),
+        grid=(rows // tm, e // tc),
+        in_specs=[column(0), column(1), column(2), above(0), above(2),
+                  taps],
+        out_specs=pl.BlockSpec((tm, tc), lambda i, j: (i, j)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name="sconv_fwd",
+    )(bcu, bcu, bcu, bcu, bcu, _taps(w))
+
+
+def _bwd_call(bcu, w, dout, seq_len, interpret, tm, tc):
+    """(dbcu [rows, 3E], dw's parts [tiles * 8, E] float32)."""
+    rows, e = bcu.shape[0], w.shape[0]
+    column, above, below, taps = _specs(tm, tc, e, rows)
+    blocks = e // tc
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, taps=w.shape[1],
+                          tiles_a_sequence=seq_len // tm),
+        out_shape=(jax.ShapeDtypeStruct((rows, 3 * e), bcu.dtype),
+                   jax.ShapeDtypeStruct((rows // tm * 8, e), jnp.float32)),
+        # the third axis writes dB, dC, du in turn: the inputs' blocks
+        # stay where they are, so nothing is read again
+        grid=(rows // tm, blocks, 3),
+        in_specs=[column(0), column(1), column(2), above(0), above(2),
+                  taps, column(0), below(0), below(1)],
+        out_specs=(
+            pl.BlockSpec((tm, tc), lambda i, j, p: (i, p * blocks + j)),
+            pl.BlockSpec((8, tc), lambda i, j, p: (i, j))),
+        scratch_shapes=[pltpu.VMEM((tm, tc), bcu.dtype),
+                        pltpu.VMEM((tm, tc), bcu.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name="sconv_bwd",
+    )(bcu, bcu, bcu, bcu, bcu, _taps(w), dout, dout, bcu)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
+def _sconv(bcu, w, seq_len, interpret, tm, tc):
+    return _fwd_call(bcu, w, seq_len, interpret, tm, tc)
+
+
+def _sconv_fwd(bcu, w, seq_len, interpret, tm, tc):
+    return _fwd_call(bcu, w, seq_len, interpret, tm, tc), (bcu, w)
+
+
+def _sconv_bwd(seq_len, interpret, tm, tc, res, dout):
+    bcu, w = res
+    dbcu, parts = _bwd_call(bcu, w, dout, seq_len, interpret, tm, tc)
+    dw = parts.reshape(-1, 8, w.shape[0]).sum(axis=0)[:w.shape[1]]
+    return dbcu, dw.T.astype(w.dtype)
+
+
+_sconv.defvjp(_sconv_fwd, _sconv_bwd)
+
+
+@functools.lru_cache(maxsize=None)
+def announce_conv(rows, channels, tile, kernel):
+    """Once per compiled shape, by the logger ``announce_tiles`` uses:
+    what the op runs (of one shard of the trainer's data axis)."""
+    flash_attention.logger.info(
+        "short conv: rows=%d channels=%d tile=%s kernel=%s", rows,
+        channels, "%dx%d" % tile if tile else "-", kernel)
+
+
+def short_conv(bcu, w, interpret=None):
+    """bcu [B, T, 3E] (B, C, u side by side), w [E, K] -> [B, T, E] in
+    bcu's dtype, causal within each of the B sequences.  Differentiable
+    in both.  The kernels where ``ops/mode.py`` allows them and the
+    shapes tile, else ``short_conv_ref``."""
+    mode = resolve(interpret)
+    batch, seq_len, e3 = bcu.shape
+    e, taps = w.shape
+    tile = None if mode == "off" else tiles(seq_len, e)
+    if mode != "off" and (tile is None or taps > 8):
+        flash_attention.announce_fallback(
+            "short_conv", (batch, seq_len, e),
+            "T %% %d, E %% %d or %d taps" % (ROW_TILES[-1],
+                                             CHANNEL_TILES[-1], taps), mode)
+        tile, mode = None, "off"
+    if mode != "interpret":
+        announce_conv(batch * seq_len // shards(), e, tile, mode)
+    if mode == "off":
+        return checkpoint_name(short_conv_ref(bcu, w), KEEP_OUT)
+
+    def op(bcu, w):
+        rows = bcu.shape[0] * seq_len
+        out = _sconv(bcu.reshape(rows, e3), w, seq_len,
+                     mode == "interpret", *tile)
+        return out.reshape(-1, seq_len, e)
+
+    return checkpoint_name(per_batch_shard(op, (bcu,), (w,)), KEEP_OUT)

@@ -32,18 +32,25 @@ def _log_step_stats(step, stats):
     """One line per logged loss for what the step program handed back
     beside it (``ModelSpec.step_stats_fn``).  Called after the loss was
     fetched: the same program made both, so this fetch waits for
-    nothing.  ``moe_load`` [layers, experts + 1]: assignments per
-    expert, then the rows the grouped matmul computed beyond them."""
+    nothing.  ``moe_load`` [layers, experts held + 1]: assignments per
+    held expert, then the rows the grouped matmul computed beyond them;
+    ``rows``, ``max`` and ``mean`` are over the experts this worker
+    holds.  ``moe_moved`` [layers], where the model holds a share of its
+    experts: the rows each layer's dispatch sorted and gathered,
+    whoever holds their expert; without it every row moved is a held
+    expert's and ``moved`` = ``rows``."""
     if not stats or "moe_load" not in stats:
         return
     import numpy as np
 
     load = np.asarray(stats["moe_load"])
     counts = load[:, :-1]
+    moved = stats.get("moe_moved", counts)
     logger.info(
         "moe load: step=%d layers=%d rows=%d max=%d mean=%.1f "
-        "padded_rows=%d", step, counts.shape[0], counts.sum(),
-        counts.max(), counts.mean(), load[:, -1].sum())
+        "padded_rows=%d moved=%d", step, counts.shape[0], counts.sum(),
+        counts.max(), counts.mean(), load[:, -1].sum(),
+        np.asarray(moved).sum())
 
 
 class PreemptedExit(Exception):

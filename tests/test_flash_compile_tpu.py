@@ -142,3 +142,137 @@ def test_head_loss_holds_no_float32_logits_on_v5e(one_chip, b, t, tied):
     assert "f32" in big(parent)        # the check can see one
     assert op.memory_analysis().temp_size_in_bytes < 4e9
     assert parent.memory_analysis().temp_size_in_bytes > 4.5e9
+
+
+# -- the lfm2-24b-a2b cell's shapes (benchmark/configs/lfm2-24b-a2b.json) --
+
+
+def test_short_conv_fwd_bwd_compile_for_v5e(one_chip):
+    """4 sequences of 8,192 x 2,048 channels, three taps: two Mosaic
+    calls, told apart downstream by the names they carry
+    (benchmark/kernels/short_conv.py), every result 2-D."""
+    from elasticdl_tpu.ops import short_conv as sc
+
+    bcu = jax.ShapeDtypeStruct((4, 8192, 3 * 2048), jnp.bfloat16,
+                               sharding=one_chip)
+    cot = jax.ShapeDtypeStruct((4, 8192, 2048), jnp.bfloat16,
+                               sharding=one_chip)
+    w = jax.ShapeDtypeStruct((2048, 3), jnp.float32, sharding=one_chip)
+
+    def fwd_bwd(bcu, w, cot):
+        out, pull = jax.vjp(
+            lambda bcu, w: sc.short_conv(bcu, w, interpret=False), bcu, w)
+        return out, pull(cot)
+
+    text = jax.jit(fwd_bwd).lower(bcu, w, cot).compile().as_text()
+    calls = [l.strip() for l in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l]
+    assert len(calls) == 2, calls
+    fwd = next(c for c in calls if "sconv_fwd" in c.split(" = ")[0])
+    bwd = next(c for c in calls if "sconv_bwd" in c.split(" = ")[0])
+    assert " bf16[32768,2048]" in " " + fwd.split(" custom-call(")[0]
+    assert "bf16[32768,6144]" in bwd and "f32[512,2048]" in bwd
+
+
+def test_flash_compiles_at_head_size_64_and_8192_positions(one_chip):
+    x = jax.ShapeDtypeStruct((4, 32, 8192, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    static = (True, 64 ** -0.5, False, 0)
+
+    def fwd_bwd(q, k, v, g):
+        out, res = fa._flash_fwd(q, k, v, *static)
+        return out, fa._flash_bwd(*static, res, g)
+
+    text = jax.jit(fwd_bwd).lower(x, x, x, x).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def test_grouped_matmul_over_a_share_compiles_for_v5e(one_chip):
+    """8 held experts' groups at the head of 131,072 sorted rows, the
+    rows past them zeroed (``zero_tail``): the three kernels."""
+    rows, k, n, held = 131072, 2048, 1536, 8
+    lhs = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip)
+    cot = jax.ShapeDtypeStruct((rows, n), jnp.bfloat16, sharding=one_chip)
+    rhs = jax.ShapeDtypeStruct((held, k, n), jnp.bfloat16,
+                               sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((held,), jnp.int32, sharding=one_chip)
+
+    def fwd_bwd(lhs, rhs, sizes, cot):
+        out, pull = jax.vjp(lambda lhs, rhs: gm.grouped_matmul(
+            lhs, rhs, sizes, interpret=False, zero_tail=True), lhs, rhs)
+        return out, pull(cot)
+
+    text = jax.jit(fwd_bwd).lower(lhs, rhs, sizes, cot).compile().as_text()
+    calls = [l.split(" = ")[0] for l in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l]
+    for name in ("gmm_nn", "gmm_nt", "gmm_tn"):
+        assert len([c for c in calls if name in c]) == 1, (name, calls)
+
+
+def test_the_mixed_stacks_step_fits_a_v5e_as_remat_keep_predicts(
+        one_chip, monkeypatch):
+    """The ``lfm2-24b-a2b.seq8192`` cell's whole training step (4
+    sequences of 8,192 through a dense conv layer and a period of
+    attention + 3 conv layers over 8 of 64 experts, AdamW) through the
+    TPU's compiler: ``remat_keep``'s estimate with nothing kept, and its
+    predicted peak with what it chose, are held to the compiler's own
+    byte count (arguments + temporaries; the updated state aliases the
+    donated one): over, never under.  Nothing kept: 12.34 GB against
+    the compiler's 10.93 (+1.41: it never holds all the gradients the
+    trainer counted, a layer's AdamW update runs behind its backward;
+    +0.84 at depth 1 of olmoe1b7b).  With the names chosen, 3.13 GB of
+    them: 15.47 against 13.51 (+1.96: the kept values cost 2.58)."""
+    import json
+    import os
+
+    import optax
+
+    from elasticdl_tpu.models import remat_keep as rk
+    from elasticdl_tpu.ops import batch_shard
+    from elasticdl_tpu.ops.mode import SWITCH
+
+    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "lfm2-24b-a2b.json")) as fh:
+        model_params = json.load(fh)["cli"]["model_params"]
+    spec = tfm.model_spec(**model_params)
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+    state = jax.eval_shape(spec.optimizer.init, params)
+    tokens = jax.ShapeDtypeStruct((4, 8192), jnp.int32, sharding=one_chip)
+    nbytes = lambda tree: sum(
+        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
+    limit = 16911433728           # a v5e's bytes_limit (chip run, PR 29)
+    held = 2 * nbytes(params) + nbytes(state)
+
+    def compiled(room):
+        def step(params, state, tokens):
+            def loss(p):
+                with batch_shard.batch_axis(None, None, room):
+                    out = spec.apply_fn(p, tokens, True)
+                    return spec.loss_fn(out, tokens).mean()
+
+            value, grads = jax.value_and_grad(loss)(params)
+            updates, state2 = spec.optimizer.update(grads, state, params)
+            return optax.apply_updates(params, updates), state2, value
+
+        stats = jax.jit(step, donate_argnums=(0, 1)).lower(
+            on_chip(params), on_chip(state), tokens).compile(
+        ).memory_analysis()
+        return stats.argument_size_in_bytes + stats.temp_size_in_bytes
+
+    estimate = held + rk.step_bytes(spec.config, params, 32768)
+    nothing_kept = compiled(None)
+    assert -0.1e9 < estimate - nothing_kept < 1.6e9, (
+        estimate, nothing_kept)
+    room = batch_shard.DeviceRoom(limit, limit - held)
+    names, kept, budget, peak = rk.choose(spec.config, params, 32768, room)
+    assert names and kept <= budget and peak == estimate + kept
+    with_names = compiled(room)
+    assert with_names < peak <= (1 - rk.RESERVE) * limit
+    assert peak - with_names < 2.2e9, (peak, with_names, names)
+    assert set(names) >= set(rk.ATTN_NAMES) | {
+        rk.KEEP_STREAM, rk.KEEP_GATE, rk.KEEP_UP}, names

@@ -267,4 +267,4 @@ def test_the_worker_logs_one_moe_load_line(caplog):
         worker_mod.logger.removeHandler(caplog.handler)
     lines = [r.getMessage() for r in caplog.records]
     assert lines == ["moe load: step=40 layers=2 rows=128 max=30 "
-                     "mean=16.0 padded_rows=48"]
+                     "mean=16.0 padded_rows=48 moved=128"]
