@@ -255,19 +255,22 @@ def check_short_conv(b, t, e, taps, interpret):
             "dw": _rel_err(grads[1], want_grads[1])}
 
 
-def check_mixed_stack(tiny):
+def check_mixed_stack(tiny, spill=False):
     """One loss-and-gradients evaluation of a stack whose layers differ
     (a leading short-convolution layer with a dense MLP, then attention
     and short convolutions with a share of the experts behind a sigmoid
     router: the ``lfm2-24b-a2b`` cell's model at one short sequence), bf16
     through the kernels, against the same weights in float32 through
     the references at the highest matmul precision (the loss), and the
-    same bf16 program through the references (the gradients)."""
+    same bf16 program through the references (the gradients).
+    ``spill``: ``expert_bias`` raised on the held experts, so every row
+    of every dispatch is theirs, four times ``moe_dispatch.row_bound``:
+    each layer runs all four of its blocks."""
     from elasticdl_tpu.models import transformer as tfm
     from elasticdl_tpu.ops.mode import kernels_off
 
     sizes = (dict(vocab_size=256, dim=128, num_heads=4, num_kv_heads=2,
-                  seq_len=64, dense_ffn_dim=192, ffn_dim=128, moe_experts=8,
+                  seq_len=128, dense_ffn_dim=192, ffn_dim=128, moe_experts=8,
                   moe_top_k=2, moe_experts_held=2) if tiny else
              # the cell's widths at a sequence of 2,048: the attention
              # reference holds all [32, T, T] float32 scores at once
@@ -282,8 +285,18 @@ def check_mixed_stack(tiny):
     exact = tfm.model_spec(dtype="float32", **common)
     params = jax.jit(spec.init_fn)(jax.random.PRNGKey(5))
     params["embed"] = params["embed"] * 25.0    # logits that matter
+    if spill:
+        held = sizes["moe_experts_held"]
+        params = jax.tree_util.tree_map_with_path(
+            lambda path, a: (a.at[..., :held].add(5.0) if getattr(
+                path[-1], "key", None) == "expert_bias" else a), params)
     tokens = jnp.asarray(np.random.RandomState(7).randint(
         0, sizes["vocab_size"], size=(1, sizes["seq_len"])), jnp.int32)
+    stats = spec.step_stats_fn(jax.jit(
+        lambda p: spec.apply_fn(p, tokens, True))(params))
+    if bool((stats["moe_spilled"] > 0).all()) != spill:
+        raise AssertionError("blocks run a layer: %s" % (
+            stats["moe_moved"],))
 
     def evaluate(spec, grads=True):
         loss = lambda p: spec.loss_fn(
@@ -630,6 +643,8 @@ def _cases(tiny):
     yield ("short_conv/B%d.T%d.E%d.K3" % (cb, ct, ce),
            lambda: check_short_conv(cb, ct, ce, 3, interpret))
     yield ("mixed_stack/caccc.share", lambda: check_mixed_stack(tiny))
+    yield ("mixed_stack/caccc.share.spill",
+           lambda: check_mixed_stack(tiny, spill=True))
     # The benchmark's two heads: OLMoE's untied, OLMo's tied embedding.
     hdim, vocab, heads = (64, 256, ((2, 24, False), (2, 24, True))) if tiny \
         else (2048, 50304, ((4, 4096, False), (8, 2048, True)))

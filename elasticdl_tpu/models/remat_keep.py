@@ -60,35 +60,55 @@ def _entries(cfg, rows):
     ]
     x = cfg.moe_experts
     k = min(cfg.moe_top_k, x)
-    # probs f32 [rows, X]; gates f32, experts, order, inverse int32
-    # [rows, k]; sizes [X]
+    # probs f32 [rows, X]; gates f32, experts, order and (where all
+    # experts are held) inverse int32 [rows, k]; sizes [X]
+    sorts = 3 if cfg.moe_experts_held else 4
     entries.append(("route", (KEEP_ROUTE, moe_dispatch.KEEP_SORT),
-                    4 * (rows * (x + 4 * k) + x), experts))
+                    4 * (rows * (x + sorts * k) + x), experts))
     entries += [
         ("qkv", (KEEP_Q, KEEP_K, KEEP_V), rows * (h + 2 * g) * d * size,
          attention),
         ("stream", (KEEP_STREAM,), rows * e * size, len(kinds)),
     ]
     f, dense_f = cfg.mlp_dim, cfg.dense_ffn_dim if x else cfg.mlp_dim
+    # What a kept GB is worth, ms (``table``).  The dispatch's buffers
+    # have ``row_bound`` rows, of which a balanced router fills
+    # ``live``: a byte of them buys that share of what a byte of full
+    # rows buys.
+    bound, live = _routed_rows(cfg, rows)
     routed = [
-        ("moe_out", (moe_dispatch.KEEP_OUT,), rows * k * e * size, experts),
-        ("moe_gate", (moe_dispatch.KEEP_GATE,), rows * k * f * size,
+        (14 * live, "moe_out", (moe_dispatch.KEEP_OUT,), bound * e * size,
          experts),
-        ("moe_up", (moe_dispatch.KEEP_UP,), rows * k * f * size, experts),
-        ("moe_rows", (moe_dispatch.KEEP_ROWS,), rows * k * e * size,
+        (12 * live, "moe_gate", (moe_dispatch.KEEP_GATE,),
+         bound * f * size, experts),
+        (12 * live, "moe_up", (moe_dispatch.KEEP_UP,), bound * f * size,
+         experts),
+        (8 * live, "moe_rows", (moe_dispatch.KEEP_ROWS,), bound * e * size,
          experts),
     ]
     rest = [
-        ("ffn_gate", (KEEP_GATE,), rows * dense_f * size, dense),
-        ("ffn_up", (KEEP_UP,), rows * dense_f * size, dense),
-        ("conv_in", (short_conv.KEEP_IN,), rows * 3 * e * size, conv),
-        ("conv_out", (short_conv.KEEP_OUT,), rows * e * size, conv),
+        (12, "ffn_gate", (KEEP_GATE,), rows * dense_f * size, dense),
+        (12, "ffn_up", (KEEP_UP,), rows * dense_f * size, dense),
+        (11, "conv_in", (short_conv.KEEP_IN,), rows * 3 * e * size, conv),
+        (5, "conv_out", (short_conv.KEEP_OUT,), rows * e * size, conv),
     ]
-    # With a share of the experts the buffers stay whole and the second
-    # forward multiplies the held experts' rows alone: a byte of them
-    # buys that share of what it buys with all held, so they go last.
-    entries += rest + routed if cfg.moe_experts_held else routed + rest
+    # stable: at equal worth the dispatch's come first
+    entries += [entry[1:] for entry in sorted(
+        routed + rest, key=lambda entry: -entry[0])]
     return [entry for entry in entries if entry[3]]
+
+
+def _routed_rows(cfg, rows):
+    """(rows of the dispatch's buffers for ``rows`` tokens,
+    ``ops/moe_dispatch.row_bound``; the share of them that a balanced
+    router makes a held expert's)."""
+    x = cfg.moe_experts
+    if not x:
+        return 0, 1.0
+    assigned = rows * min(cfg.moe_top_k, x)
+    held = cfg.experts_held[1]
+    bound = moe_dispatch.row_bound(assigned, held, x)
+    return bound, assigned * held / x / bound
 
 
 def table(cfg, rows):
@@ -96,10 +116,13 @@ def table(cfg, rows):
     tokens a device, in
     the order of what a kept byte saves of the second forward (ms a GB,
     from the traces of PERF.md section 5: the flash forward ~18; the
-    un-sorted down product, a grouped matmul and a gather, ~14; q, k, v
+    down product, a grouped matmul and a gather, ~14; q, k, v
     and the stream ~13; the FFN's products ~12, and by their shapes a
     short convolution's input ~11; the sorted rows, one gather, ~8; the
-    convolution's result, a pass bound by memory, ~5).  Elementwise
+    convolution's result, a pass bound by memory, ~5; the flash
+    forward's, the router's and attention's first as they stand, the
+    others by that worth, a share's dispatch buffers at the part of
+    their rows a balanced router fills, a half).  Elementwise
     work (norms, RoPE's rotation, the
     activation, the weighted combine) is not here: it is cheap and its
     inputs are what is kept.  An entry is there if a layer of the model
@@ -139,8 +162,8 @@ def step_bytes(cfg, params, rows):
     head = rows * cfg.vocab_size * size * (2 if cfg.tied_embeddings else 1)
     layer = 0
     if cfg.moe_experts:
-        k = min(cfg.moe_top_k, cfg.moe_experts)
-        layer = rows * k * (cfg.dim + 2 * cfg.mlp_dim) * size
+        layer = _routed_rows(cfg, rows)[0] * (
+            cfg.dim + 2 * cfg.mlp_dim) * size
     if any(kind.dense for kind in cfg.kinds):
         f = cfg.dense_ffn_dim if cfg.moe_experts else cfg.mlp_dim
         layer = max(layer, rows * 4 * f * size)

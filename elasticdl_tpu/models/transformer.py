@@ -437,8 +437,11 @@ def _moe_ffn(h, w, cfg, mesh):
     that accumulate across microbatches (the pipeline) combine them at
     the end for the exact full-batch aux.  ``load`` [X + 1] float32:
     assignments per expert, and the grouped matmul's padded rows; with
-    a share of the experts (``cfg.moe_experts_held``) [held + 1]: the
-    held experts' assignments alone.
+    a share of the experts (``cfg.moe_experts_held``) [held + 3]: the
+    held experts' assignments alone, the padded rows, then what the
+    dispatch measured of itself (``ops/moe_dispatch._moe_experts``):
+    the rows its blocks moved, and how many of its shards ran more
+    than one block.
     """
     B, T = h.shape[:2]
     X = cfg.moe_experts
@@ -1087,8 +1090,10 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
     Training an MoE through the scanned stack, the spec also hands the
     trainer its step statistics (``step_stats_fn``): each layer's
     assignments per expert and padded rows, ``moe_load`` [L, X + 1];
-    with a share of the experts, the held experts' alone, and
-    ``moe_moved`` [L], the rows each layer's dispatch moved.
+    with a share of the experts, the held experts' alone,
+    ``moe_moved`` [L], the rows each layer's dispatch moved (its bound
+    times the blocks that ran), and ``moe_spilled`` [L], the shards on
+    which it ran more than one.
 
     ``warmup_steps`` > 0 raises AdamW's rate from 0 to ``learning_rate``
     linearly over that many steps (0: constant, as ever).  Adam's steps
@@ -1206,14 +1211,12 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
         return loss
 
     def step_stats(outputs):
-        stats = {"moe_load": outputs["moe_load"]}
-        if cfg.moe_experts_held:
-            # every (token, choice) row, whoever holds its expert
-            batch, seq_len = outputs["hidden"].shape[:2]
-            stats["moe_moved"] = jnp.full(
-                stats["moe_load"].shape[:1], float(
-                    batch * seq_len * min(cfg.moe_top_k, cfg.moe_experts)))
-        return stats
+        load = outputs["moe_load"]
+        if not cfg.moe_experts_held:
+            return {"moe_load": load}
+        # a share's dispatch counts the rows it moved and its spills
+        return {"moe_load": load[:, :-2], "moe_moved": load[:, -2],
+                "moe_spilled": load[:, -1]}
 
     def feed(records):
         toks = np.stack(

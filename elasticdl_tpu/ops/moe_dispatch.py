@@ -6,12 +6,20 @@ weighs the statistics; this is the multiply).
 (the Pallas ``grouped_matmul`` kernels, per shard of the trainer's data
 axis, or ``lax.ragged_dot``) and says so once per compiled shape (``moe
 dispatch:``, which the benchmark and ``chip_smoke.py`` read).
+
+With all the experts held every buffer has n * K rows.  With a share of
+them (``held != total``) every buffer between the sort and the
+token-shaped result has ``row_bound`` rows: the held experts' rows are a
+prefix of the sort, the first ``row_bound`` of them are one block, and
+the blocks after it run while held rows are left (``_further_blocks``),
+so nothing is dropped and the work follows the data.
 """
 
 import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from elasticdl_tpu.ops import flash_attention, grouped_matmul as gm
@@ -20,11 +28,34 @@ from elasticdl_tpu.ops.mode import kernel_mode
 
 
 # ``checkpoint_name``s of what the dispatch's backward reads, where it
-# is made (models/remat_keep.py picks among them): the sort's three
-# int32 results, the sorted rows, the two products before the
-# activation, the un-sorted down product.
+# is made (models/remat_keep.py picks among them): the sort's int32
+# results, the sorted rows, the two products before the activation, the
+# down product (un-sorted where all experts are held, else the first
+# block's ``row_bound`` rows as the grouped matmul gives them).
 KEEP_SORT, KEEP_ROWS = "moe_sort", "moe_rows"
 KEEP_GATE, KEEP_UP, KEEP_OUT = "moe_gate", "moe_up", "moe_out"
+
+# Rows of a share's buffers over the rows a balanced router gives the
+# held experts.  2 is the usual receive capacity of an expert-parallel
+# exchange, and well above what a layer of the benchmark's cell holds
+# at random weights (13,233-19,456 of a bound of 32,768 over forty layer
+# dispatches of ten seeds: PERF.md section 6, PR 32).  Rows past it are
+# multiplied in further blocks, so the factor sets a cost, never a
+# result.
+BOUND_FACTOR = 2
+
+
+def row_bound(rows, held, total):
+    """Rows of every buffer of a dispatch that holds ``held`` of the
+    ``total`` experts its ``rows`` assignments are routed over:
+    ``BOUND_FACTOR`` times the balanced share, a whole number of the
+    grouped matmul's row tiles, and never more than ``rows`` (all of
+    them where all experts are held)."""
+    if held == total:
+        return rows
+    need = -(-BOUND_FACTOR * rows * held // total)
+    tile = gm.row_tile(need)
+    return min(rows, -(-need // tile) * tile)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -46,6 +77,131 @@ _take_rows.defvjp(
     lambda k, x, order, inverse: (x[order // k], inverse), _take_rows_bwd)
 
 
+# -- a share's rows: C of them to and from n tokens ------------------------
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def tokens_to_rows(n, x, tok):
+    """x [n, w], tok [C] int32 in 0 .. n -> [C, w]: row i is token
+    ``tok[i]``'s.  ``tok[i] == n`` marks a row that is no token's (past
+    the held rows): what it gets is not read.  The transpose of
+    ``rows_to_tokens`` with no scale."""
+    return jnp.take(x, tok, axis=0, mode="clip")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def rows_to_tokens(n, y, tok, scale):
+    """y [C, w], tok [C], scale [C] float32 or None -> float32 [n, w]:
+    token t's row is the sum of ``scale[i] * y[i]`` over its rows i
+    (``tok[i] == t``), zeros where it has none; a row that is no
+    token's (``tok[i] == n``) is dropped whatever it holds.
+
+    A scatter-add of C rows: in the job's trace on a v5e it takes 3.5 ms
+    at 32,768 x 2,048, where the scatter-free form (rows gathered into
+    token order, K - 1 shifted adds, the tokens gathering their first
+    rows) took ~6 (PERF.md section 6, PR 32)."""
+    z = y.astype(jnp.float32)
+    if scale is not None:
+        z = z * scale[:, None]
+    return jnp.zeros((n, y.shape[1]), jnp.float32).at[tok].add(
+        z, mode="drop")
+
+
+def _tokens_to_rows_fwd(n, x, tok):
+    return tokens_to_rows(n, x, tok), tok
+
+
+def _tokens_to_rows_bwd(n, tok, g):
+    return rows_to_tokens(n, g, tok, None).astype(g.dtype), None
+
+
+def _rows_to_tokens_fwd(n, y, tok, scale):
+    return rows_to_tokens(n, y, tok, scale), (y, tok, scale)
+
+
+def _rows_to_tokens_bwd(n, res, g):
+    y, tok, scale = res
+    rows = tokens_to_rows(n, g, tok)
+    live = (tok < n)[:, None]
+    dy = rows if scale is None else rows * scale[:, None]
+    dscale = None if scale is None else jnp.where(
+        live, y.astype(jnp.float32) * rows, 0.0).sum(axis=1)
+    return jnp.where(live, dy, 0.0).astype(y.dtype), None, dscale
+
+
+tokens_to_rows.defvjp(_tokens_to_rows_fwd, _tokens_to_rows_bwd)
+rows_to_tokens.defvjp(_rows_to_tokens_fwd, _rows_to_tokens_bwd)
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def _block(i, c, x, gates, weights, order, sizes):
+    """Float32 [n, e]: what the sorted rows ``i * c .. (i + 1) * c`` add
+    to the tokens' results.  x [n, e], gates [n, k], order [blocks * c]
+    (the sort, padded with n * k: no assignment), sizes [held] the held
+    experts' rows (a prefix of the sort): the block multiplies those of
+    them that fall inside it.
+
+    The names are the first block's to keep: a further block's values
+    never leave its loop.  Jitted, so that the layers of a stack and the
+    blocks of a layer, which trace it at the same shapes, lower its
+    kernels once (a worker lowers its step at every start, compile cache
+    or not: PERF.md section 6, PR 32)."""
+    n, k = gates.shape
+    ends = jnp.cumsum(sizes)
+    inside = lambda at: jnp.clip(at - i * c, 0, c)
+    sizes = inside(ends) - inside(ends - sizes)
+    claims = lax.dynamic_slice_in_dim(order, i * c, c)
+    tok = jnp.where(jnp.arange(c) < sizes.sum(), claims // k, n)
+    matmul = functools.partial(gm.grouped_matmul, zero_tail=True)
+    w_gate, w_up, w_down = weights
+    xs = checkpoint_name(tokens_to_rows(n, x, tok), KEEP_ROWS)
+    gate = checkpoint_name(matmul(xs, w_gate, sizes), KEEP_GATE)
+    up = checkpoint_name(matmul(xs, w_up, sizes), KEEP_UP)
+    ys = checkpoint_name(
+        matmul(jax.nn.silu(gate) * up, w_down, sizes), KEEP_OUT)
+    scale = gates.reshape(n * k).at[claims].get(mode="fill", fill_value=0)
+    return rows_to_tokens(n, ys, tok, scale)
+
+
+def _blocks(c, sizes):
+    """The blocks of c rows that hold a held expert's row."""
+    return -(-sizes.sum() // c)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _further_blocks(c, out, x, gates, weights, order, sizes):
+    """``out`` + every block after the first that holds a held expert's
+    row: a loop whose trip count is the data's (none at the balance the
+    bound was taken from, all of them under a router collapsed onto the
+    held experts).  Its backward is the same loop over each block's
+    pullback, the block's forward run again: a block keeps nothing."""
+    return lax.fori_loop(
+        jnp.int32(1), _blocks(c, sizes),
+        lambda i, out: out + _block(i, c, x, gates, weights, order, sizes),
+        out)
+
+
+def _further_blocks_fwd(c, out, x, gates, weights, order, sizes):
+    return (_further_blocks(c, out, x, gates, weights, order, sizes),
+            (x, gates, weights, order, sizes))
+
+
+def _further_blocks_bwd(c, res, g):
+    *operands, order, sizes = res
+
+    def add_block(i, grads):
+        pull = jax.vjp(lambda *operands: _block(
+            i, c, *operands, order, sizes), *operands)[1]
+        return jax.tree_util.tree_map(jnp.add, grads, pull(g))
+
+    grads = lax.fori_loop(
+        jnp.int32(1), _blocks(c, sizes), add_block,
+        jax.tree_util.tree_map(jnp.zeros_like, tuple(operands)))
+    return (g, *grads, None, None)
+
+
+_further_blocks.defvjp(_further_blocks_fwd, _further_blocks_bwd)
+
+
 def _moe_experts(h, gates, experts, w_gate, w_up, w_down, total=None,
                  first=0):
     """The routed FFN of the rows this device holds: sort the n * K
@@ -57,41 +213,55 @@ def _moe_experts(h, gates, experts, w_gate, w_up, w_down, total=None,
 
     The weights may be a share of the ``total`` experts the tokens are
     routed over, ``first .. first + held``: the assignments are sorted
-    with the held experts' first, all n * K rows are moved as ever, the
-    held experts' alone are multiplied, and the others' rows are zeros
-    (``grouped_matmul(zero_tail=True)``) that the weighted sum adds as
-    nothing.  ``load`` counts all ``total`` experts either way."""
+    with the held experts' first, and those rows alone are gathered,
+    multiplied and summed into their tokens, ``row_bound`` rows a block
+    (the module's docstring); the others' are never moved.  ``load``
+    counts all ``total`` experts either way, and with a share ends in
+    two more numbers: the rows the blocks that ran moved, and 1 if more
+    than one ran."""
     b, t, e = h.shape
     held, k = w_gate.shape[0], experts.shape[-1]
     x = total or held
     share = held != x
     n, rows = b * t, b * t * k
+    bound = row_bound(rows, held, x)
     mode = kernel_mode()
     if mode != "interpret":
-        announce_dispatch(n, x, k, mode, held if share else None)
+        announce_dispatch(n, x, k, mode, (held, bound) if share else None)
     flat = experts.reshape(rows)
     if share:    # expert ``first`` sorts as 0, the absent ones last
         flat = (flat - first) % x
-    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-    inverse = jnp.argsort(order).astype(jnp.int32)
-    sizes = (flat[:, None] == jnp.arange(x, dtype=flat.dtype)).sum(
-        axis=0, dtype=jnp.int32)
-    order, inverse, sizes = (
-        checkpoint_name(a, KEEP_SORT) for a in (order, inverse, sizes))
+    order = checkpoint_name(
+        jnp.argsort(flat, stable=True).astype(jnp.int32), KEEP_SORT)
+    if not share:    # a share's rows find their tokens themselves
+        inverse = checkpoint_name(
+            jnp.argsort(order).astype(jnp.int32), KEEP_SORT)
+    sizes = checkpoint_name(
+        (flat[:, None] == jnp.arange(x, dtype=flat.dtype)).sum(
+            axis=0, dtype=jnp.int32), KEEP_SORT)
     counted = sizes          # every expert's rows, in the experts' order
     if share:
         counted, sizes = jnp.roll(sizes, first), sizes[:held]
     padded = (jnp.int32(0) if mode == "off"
               else gm.padded_rows(sizes, rows))
-    # asks the mode itself: as here
-    matmul = functools.partial(gm.grouped_matmul, zero_tail=share)
+    if share:
+        # whole blocks: the sort padded with assignments that are none
+        order = jnp.pad(order, (0, -rows % bound), constant_values=rows)
+        operands = (h.reshape(n, e), gates.reshape(n, k),
+                    (w_gate, w_up, w_down), order, sizes)
+        out = _further_blocks(
+            bound, _block(jnp.int32(0), bound, *operands), *operands)
+        blocks = jnp.maximum(_blocks(bound, sizes), 1)
+        load = jnp.concatenate([counted, jnp.stack(
+            [padded, blocks * bound, (blocks > 1).astype(jnp.int32)])])
+        return out.astype(h.dtype).reshape(b, t, e), load[None]
     xs = checkpoint_name(
         _take_rows(k, h.reshape(n, e), order, inverse), KEEP_ROWS)
-    gate = checkpoint_name(matmul(xs, w_gate, sizes), KEEP_GATE)
-    up = checkpoint_name(matmul(xs, w_up, sizes), KEEP_UP)
+    gate = checkpoint_name(gm.grouped_matmul(xs, w_gate, sizes), KEEP_GATE)
+    up = checkpoint_name(gm.grouped_matmul(xs, w_up, sizes), KEEP_UP)
     act = jax.nn.silu(gate) * up
-    ys = checkpoint_name(
-        _take_rows(1, matmul(act, w_down, sizes), inverse, order), KEEP_OUT)
+    ys = checkpoint_name(_take_rows(
+        1, gm.grouped_matmul(act, w_down, sizes), inverse, order), KEEP_OUT)
     out = jnp.einsum("nke,nk->ne", ys.reshape(n, k, e).astype(jnp.float32),
                      gates.reshape(n, k))
     load = jnp.concatenate([counted, padded.reshape(1)])[None]
@@ -99,18 +269,20 @@ def _moe_experts(h, gates, experts, w_gate, w_up, w_down, total=None,
 
 
 @functools.lru_cache(maxsize=None)
-def announce_dispatch(tokens, experts, top_k, kernel, held=None):
+def announce_dispatch(tokens, experts, top_k, kernel, share=None):
     """Once per compiled shape, by the logger ``announce_tiles`` uses:
     what the dispatch hands the grouped matmul (of one shard of the
-    trainer's data axis, where there is one); ``held=`` where the
-    weights are a share of the experts."""
+    trainer's data axis, where there is one); ``held=`` and ``bound=``
+    (``row_bound``: the rows of one block) where the weights are a
+    ``share`` (held, bound) of the experts."""
     rows = tokens * top_k
-    tile = gm.row_tile(rows)
+    held, bound = share or (experts, rows)
+    tile = gm.row_tile(bound)
     flash_attention.logger.info(
         "moe dispatch: tokens=%d experts=%d top_k=%d rows=%d row_tile=%d "
         "groups_tiles<=%d kernel=%s%s", tokens, experts, top_k, rows, tile,
-        -(-rows // tile) + (held or experts) - 1, kernel,
-        "" if held is None else " held=%d" % held)
+        -(-bound // tile) + held - 1, kernel,
+        "" if share is None else " held=%d bound=%d" % share)
 
 
 def moe_experts(h, gates, experts, w_gate, w_up, w_down, total=None,
@@ -118,9 +290,9 @@ def moe_experts(h, gates, experts, w_gate, w_up, w_down, total=None,
     """h [B, T, E], gates and experts [B, T, K], the three expert
     weights [X, ...] in h's dtype (or the share ``first .. first + X``
     of ``total`` experts: ``_moe_experts``) -> (out [B, T, E], load
-    [shards, total + 1]).  Where a kernel runs, once per shard of the
-    declared batch axis (weights whole on each); the reference
-    partitions by itself."""
+    [shards, total + 1], with a share [shards, total + 3]).  Where a
+    kernel runs, once per shard of the declared batch axis (weights
+    whole on each); the reference partitions by itself."""
     fn = functools.partial(_moe_experts, total=total, first=first)
     if kernel_mode() == "off":
         return fn(h, gates, experts, w_gate, w_up, w_down)

@@ -36,9 +36,11 @@ def _log_step_stats(step, stats):
     held expert, then the rows the grouped matmul computed beyond them;
     ``rows``, ``max`` and ``mean`` are over the experts this worker
     holds.  ``moe_moved`` [layers], where the model holds a share of its
-    experts: the rows each layer's dispatch sorted and gathered,
-    whoever holds their expert; without it every row moved is a held
-    expert's and ``moved`` = ``rows``."""
+    experts: the rows each layer's dispatch gathered and multiplied
+    (``ops/moe_dispatch.row_bound`` times the blocks that ran), and
+    ``moe_spilled`` [layers], the dispatches that ran more than one
+    block: held rows past the bound.  Without them every row moved is
+    a held expert's, ``moved`` = ``rows`` and nothing spills."""
     if not stats or "moe_load" not in stats:
         return
     import numpy as np
@@ -48,9 +50,10 @@ def _log_step_stats(step, stats):
     moved = stats.get("moe_moved", counts)
     logger.info(
         "moe load: step=%d layers=%d rows=%d max=%d mean=%.1f "
-        "padded_rows=%d moved=%d", step, counts.shape[0], counts.sum(),
-        counts.max(), counts.mean(), load[:, -1].sum(),
-        np.asarray(moved).sum())
+        "padded_rows=%d moved=%d spilled=%d", step, counts.shape[0],
+        counts.sum(), counts.max(), counts.mean(), load[:, -1].sum(),
+        np.asarray(moved).sum(),
+        np.asarray(stats.get("moe_spilled", 0)).sum())
 
 
 class PreemptedExit(Exception):

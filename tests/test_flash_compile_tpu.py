@@ -218,10 +218,12 @@ def test_the_mixed_stacks_step_fits_a_v5e_as_remat_keep_predicts(
     predicted peak with what it chose, are held to the compiler's own
     byte count (arguments + temporaries; the updated state aliases the
     donated one): over, never under.  Nothing kept: 12.34 GB against
-    the compiler's 10.93 (+1.41: it never holds all the gradients the
+    the compiler's 10.30 (+2.04: it never holds all the gradients the
     trainer counted, a layer's AdamW update runs behind its backward;
-    +0.84 at depth 1 of olmoe1b7b).  With the names chosen, 3.13 GB of
-    them: 15.47 against 13.51 (+1.96: the kept values cost 2.58)."""
+    +0.84 at depth 1 of olmoe1b7b; it counted 10.93 while the dispatch
+    moved all 131,072 rows a layer, and the estimate's larger term, the
+    dense layer's backward, did not move with them).  With the names
+    chosen, 3.53 GB of them: 15.87 against 13.51 (+2.36)."""
     import json
     import os
 
@@ -266,13 +268,13 @@ def test_the_mixed_stacks_step_fits_a_v5e_as_remat_keep_predicts(
 
     estimate = held + rk.step_bytes(spec.config, params, 32768)
     nothing_kept = compiled(None)
-    assert -0.1e9 < estimate - nothing_kept < 1.6e9, (
+    assert -0.1e9 < estimate - nothing_kept < 2.2e9, (
         estimate, nothing_kept)
     room = batch_shard.DeviceRoom(limit, limit - held)
     names, kept, budget, peak = rk.choose(spec.config, params, 32768, room)
     assert names and kept <= budget and peak == estimate + kept
     with_names = compiled(room)
     assert with_names < peak <= (1 - rk.RESERVE) * limit
-    assert peak - with_names < 2.2e9, (peak, with_names, names)
+    assert peak - with_names < 2.6e9, (peak, with_names, names)
     assert set(names) >= set(rk.ATTN_NAMES) | {
         rk.KEEP_STREAM, rk.KEEP_GATE, rk.KEEP_UP}, names

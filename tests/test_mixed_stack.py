@@ -145,18 +145,42 @@ def test_one_block_of_each_kind_matches_the_reference(op, dense):
         assert float(jnp.abs(g - w).max()) <= 2e-5 * top + 1e-7, name
 
 
+def _onto_share(params, cfg, by=5.0):
+    """``expert_bias`` raised on the held experts: every token chooses
+    them, so every row of every dispatch is a held expert's."""
+    first, held = cfg.experts_held
+    return jax.tree_util.tree_map_with_path(
+        lambda path, a: (a.at[..., first:first + held].add(by)
+                         if path[-1].key == "expert_bias" else a), params)
+
+
 @pytest.mark.parametrize("mode", ["off", "interpret"])
-def test_the_whole_stack_matches_the_reference(monkeypatch, mode):
+@pytest.mark.parametrize("onto_share", [False, True])
+def test_the_whole_stack_matches_the_reference(monkeypatch, mode,
+                                               onto_share):
     """cc | accc x 2 | ac, a share of 4 of 16 experts, non-zero
     ``expert_bias``: the loss, the gradients' tree and each layer's
     choice of experts, with the jnp paths and with the kernels in
-    interpret mode."""
+    interpret mode.  ``onto_share``: a router biased onto the held
+    experts, so each dispatch's 256 rows are theirs and run as two
+    blocks of the bound's 128."""
     monkeypatch.setenv("ELASTICDL_FLASH", mode)
     monkeypatch.setattr(sc, "ROW_TILES", (16,))
     spec = tfm.model_spec(remat=True, **STACK)
     params = _with_bias(jax.jit(spec.init_fn)(jax.random.PRNGKey(4)))
     params["embed"] = params["embed"] * 25.0
+    if onto_share:
+        params = _onto_share(params, spec.config)
     tokens = _tokens(spec)
+    load = spec.step_stats_fn(spec.apply_fn(params, tokens, True))
+    assert md.row_bound(2 * 32 * 4, 4, 16) == 128
+    rows = np.asarray(load["moe_load"][:, :-1].sum(axis=1))
+    if onto_share:
+        np.testing.assert_array_equal(rows, [256] * 10)
+    # moved: the bound times the blocks that held a held expert's row
+    np.testing.assert_array_equal(
+        load["moe_moved"], 128 * np.maximum(np.ceil(rows / 128), 1))
+    np.testing.assert_array_equal(load["moe_spilled"], rows > 128)
     got, grads = jax.jit(jax.value_and_grad(_loss(spec, tokens)))(params)
     shape = dict(SHAPE, first=4)
     want, want_grads = jax.value_and_grad(lambda p: REF.loss(
@@ -205,11 +229,13 @@ def test_the_bias_moves_the_choice_and_not_the_weights():
                                2.5 * picked, rtol=1e-6)
 
 
-@pytest.mark.parametrize("shares", [2, 4])
-def test_the_shares_add_up_to_the_uncut_layer(shares):
+@pytest.mark.parametrize("shares,onto", [(2, None), (4, None), (4, 1)])
+def test_the_shares_add_up_to_the_uncut_layer(shares, onto):
     """16 experts in 2 and in 4 shares: the shares' expert-layer results
     add up to what the reference gives for the whole layer with all 16
-    held (this model has nothing every share computes alike)."""
+    held (this model has nothing every share computes alike).  ``onto``:
+    the bias sends most rows to that share, past its bound of 128 of
+    the 192, and its further block adds what the first left."""
     whole = tfm.TransformerConfig(
         dim=64, ffn_dim=48, moe_experts=16, moe_top_k=4,
         moe_router="sigmoid_bias", dtype="float32")
@@ -220,9 +246,12 @@ def test_the_shares_add_up_to_the_uncut_layer(shares):
          "w_up": draw(16, 64, 48), "w_down": draw(16, 48, 64),
          "expert_bias": jnp.asarray(0.2 * rng.standard_normal(16),
                                     jnp.float32)}
+    held = 16 // shares
+    if onto is not None:     # three of a token's four choices, or so
+        w["expert_bias"] = w["expert_bias"].at[
+            onto * held:(onto + 1) * held - 1].add(5.0)
     h = jnp.asarray(rng.standard_normal((2, 24, 64)), jnp.float32)
     want, _ = REF.experts(h, w, 4, True, 1.0, 0)
-    held = 16 // shares
     total, rows = 0.0, 0.0
     for index in range(shares):
         cfg = dataclasses.replace(whole, moe_experts_held=held,
@@ -232,7 +261,12 @@ def test_the_shares_add_up_to_the_uncut_layer(shares):
         out, _, _, load = tfm._moe_ffn(h, part, cfg, None)
         ref_part, _ = REF.experts(h, part, 4, True, 1.0, index * held)
         np.testing.assert_allclose(out, ref_part, rtol=1e-4, atol=1e-5)
-        assert load.shape == (held + 1,)
+        # the held experts' rows, the padded ones, rows moved, spills
+        assert load.shape == (held + 3,)
+        bound = md.row_bound(192, held, 16)
+        spilled = float(load[:held].sum()) > bound
+        assert spilled == (index == onto)
+        assert list(load[held + 1:]) == [bound * (1 + spilled), spilled]
         total, rows = total + out, rows + float(load[:held].sum())
     np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)
     assert rows == 2 * 24 * 4          # every assignment held by one share
@@ -241,11 +275,14 @@ def test_the_shares_add_up_to_the_uncut_layer(shares):
 # -- rows no expert here takes (the dispatch under a share) -----------------
 
 
-def _share_operands(seed=0, n=40, e=32, f=48, total=8, held=2, k=2):
+def _share_operands(seed=0, n=40, e=32, f=48, total=8, held=2, k=2,
+                    onto=None):
+    """``onto``: every token's choices are the experts ``onto ..``."""
     rng = np.random.default_rng(seed)
     h = jnp.asarray(rng.standard_normal((1, n, e)), jnp.float32)
     experts = jnp.asarray(
-        np.stack([rng.permutation(total)[:k] for _ in range(n)])[None],
+        np.stack([rng.permutation(total)[:k] if onto is None
+                  else onto + rng.permutation(k) for _ in range(n)])[None],
         jnp.int32)
     gates = jnp.asarray(rng.random((1, n, k)), jnp.float32)
     weights = tuple(jnp.asarray(rng.standard_normal(s) * 0.2, jnp.float32)
@@ -253,12 +290,19 @@ def _share_operands(seed=0, n=40, e=32, f=48, total=8, held=2, k=2):
     return h, gates, experts, weights
 
 
-def test_rows_of_absent_experts_never_reach_the_output(monkeypatch):
+@pytest.mark.parametrize("operands", [
+    dict(), dict(n=200), dict(n=200, onto=4)],
+    ids=["whole", "one-block-of-two", "two-blocks"])
+def test_rows_of_absent_experts_never_reach_the_output(monkeypatch,
+                                                       operands):
     """The kernels write nothing in the rows sorted past the held
     groups.  NaNs planted there (what undefined memory may hold), in the
     product and in the input's gradient, leave the layer's result and
-    every gradient finite and equal to the reference path's."""
-    h, gates, experts, weights = _share_operands()
+    every gradient finite and equal to the reference path's: with 80
+    rows, which are one block; with 400 and a bound of 256, where the
+    held experts' ~100 leave the block's tail dead; and with all 400
+    the held experts', whose second block ends in 112 dead rows."""
+    h, gates, experts, weights = _share_operands(**operands)
     call = gm._gmm_call
 
     def planted(lhs, rhs, group_sizes, *rest):
@@ -325,6 +369,158 @@ def test_zero_tail_zeroes_what_the_groups_do_not_cover(sizes):
         np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
 
 
+# -- a share's bound, and the rows past it -----------------------------------
+
+
+def test_the_bound_is_twice_the_balanced_share_in_whole_tiles():
+    assert md.row_bound(131072, 8, 64) == 32768       # the benchmark's cell
+    assert md.row_bound(131072, 64, 64) == 131072     # all held: every row
+    assert md.row_bound(512, 4, 32) == 128            # 2 x 64, one 128 tile
+    assert md.row_bound(1040, 8, 16) == 1040          # never past the rows
+    assert md.row_bound(6000, 2, 16) == 1536          # 1500 -> 3 tiles of 512
+
+
+def _held_first(n, k, total, first, held_rows, seed):
+    """[1, n, k] choices of ``total`` experts with exactly ``held_rows``
+    of the n * k on the k experts ``first ..``: the first tokens have all
+    k choices there, one the remainder, the rest none."""
+    rng = np.random.default_rng(seed)
+    absent = np.setdiff1d(np.arange(total), first + np.arange(k))
+    experts = np.empty((n, k), np.int64)
+    for t in range(n):
+        mine = min(k, max(held_rows - t * k, 0))
+        experts[t] = rng.permutation(np.concatenate(
+            [first + rng.permutation(k)[:mine],
+             rng.permutation(absent)[:k - mine]]))
+    assert (np.isin(experts, first + np.arange(k))).sum() == held_rows
+    return jnp.asarray(experts[None], jnp.int32)
+
+
+def _plain_share(h, gates, experts, w_gate, w_up, w_down, first):
+    """The held experts' part of the layer, every expert over every
+    token and the gates of the tokens that chose it."""
+    out = 0.0
+    for x in range(w_gate.shape[0]):
+        y = (jax.nn.silu(h @ w_gate[x]) * (h @ w_up[x])) @ w_down[x]
+        mine = (gates * (experts == first + x)).sum(-1)
+        out = out + mine[..., None] * y
+    return out
+
+
+@pytest.mark.parametrize("mode", ["off", "interpret"])
+@pytest.mark.parametrize("held_rows", [100, 128, 129, 256, 512])
+def test_a_share_multiplies_every_held_row_whatever_the_bound(
+        monkeypatch, mode, held_rows):
+    """128 tokens, 4 choices of 32 experts, experts 8 .. 12 held: the
+    bound is 128 rows of the 512.  Held rows under it, exactly at it, one
+    over it, two blocks full and every row: result, load and every
+    gradient are the plain layer's and the whole-buffer dispatch's (all
+    32 experts held, the absent ones' weights zeros), with the reference
+    product and with the kernels."""
+    monkeypatch.setenv("ELASTICDL_FLASH", mode)
+    n, k, total, first = 128, 4, 32, 8
+    assert md.row_bound(n * k, k, total) == 128
+    rng = np.random.default_rng(held_rows)
+    h = jnp.asarray(rng.standard_normal((1, n, 32)), jnp.float32)
+    gates = jnp.asarray(rng.random((1, n, k)), jnp.float32)
+    experts = _held_first(n, k, total, first, held_rows, held_rows)
+    weights = tuple(jnp.asarray(rng.standard_normal(s) * 0.2, jnp.float32)
+                    for s in ((k, 32, 48), (k, 32, 48), (k, 48, 32)))
+    cot = jnp.asarray(rng.standard_normal((1, n, 32)), jnp.float32)
+
+    def share(h, gates, *weights):
+        out, load = md.moe_experts(h, gates, experts, *weights,
+                                   total=total, first=first)
+        return (out * cot).sum(), (out, load)
+
+    def whole(h, gates, *weights):
+        full = tuple(jnp.zeros((total,) + w.shape[1:]).at[
+            first:first + k].set(w) for w in weights)
+        out, _ = md.moe_experts(h, gates, experts, *full)
+        return (out * cot).sum(), out
+
+    def plain(h, gates, *weights):
+        out = _plain_share(h, gates, experts, *weights, first)
+        return (out * cot).sum(), out
+
+    args = (h, gates) + weights
+    grad = lambda fn: jax.jit(jax.value_and_grad(
+        fn, argnums=tuple(range(5)), has_aux=True))(*args)
+    (_, (out, load)), grads = grad(share)
+    blocks = max(-(-held_rows // 128), 1)
+    np.testing.assert_array_equal(
+        load[0, total + 1:], [128 * blocks, blocks > 1])
+    assert int(load[0, first:first + k].sum()) == held_rows
+    for other in (whole, plain):
+        (_, want), want_grads = grad(other)
+        np.testing.assert_allclose(out, want, rtol=1e-4, atol=1e-5)
+        for g, w in zip(grads, want_grads):
+            np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("claims", ["k-on-one-token", "none", "mixed"])
+def test_rows_to_tokens_and_tokens_to_rows_are_transposes(claims):
+    """<rows_to_tokens(y), x> = <y, tokens_to_rows(x)> over the rows
+    that are a token's, with all 3 rows one token's, with no row any
+    token's (``tok == n``), and mixed; a scale's gradients check out
+    numerically."""
+    from jax.test_util import check_grads
+
+    n, c, w = 6, 8, 5
+    tok = {"k-on-one-token": [4, 4, 4] + [n] * 5, "none": [n] * c,
+           "mixed": [2, 0, 2, 5, 0, 2, n, n]}[claims]
+    tok = jnp.asarray(tok, jnp.int32)
+    rng = np.random.default_rng(len(claims))
+    x = jnp.asarray(rng.standard_normal((n, w)), jnp.float32)
+    y = jnp.asarray(rng.standard_normal((c, w)), jnp.float32)
+    scale = jnp.asarray(rng.random(c), jnp.float32)
+    live = np.asarray(tok) < n
+    by_hand = np.zeros((n, w), np.float32)
+    for i in np.flatnonzero(live):
+        by_hand[tok[i]] += np.asarray(y)[i]
+    summed = md.rows_to_tokens(n, y, tok, None)
+    np.testing.assert_allclose(summed, by_hand, rtol=1e-6, atol=1e-6)
+    taken = md.tokens_to_rows(n, x, tok)
+    np.testing.assert_array_equal(np.asarray(taken)[live],
+                                  np.asarray(x)[np.asarray(tok)[live]])
+    assert float((summed * x).sum()) == pytest.approx(
+        float((y * taken)[live].sum()), rel=1e-5, abs=1e-6)
+    # each is the other's pullback
+    pulled = jax.vjp(lambda x: md.tokens_to_rows(n, x, tok), x)[1](y)[0]
+    np.testing.assert_allclose(pulled, by_hand, rtol=1e-6, atol=1e-6)
+    pulled = jax.vjp(lambda y: md.rows_to_tokens(n, y, tok, None),
+                     y)[1](x)[0]
+    np.testing.assert_array_equal(pulled, jnp.where(live[:, None], taken, 0))
+    check_grads(lambda y, scale: md.rows_to_tokens(n, y, tok, scale),
+                (y, scale), order=1, modes=["rev"])
+
+
+@pytest.mark.parametrize("held", [4, 0])
+def test_a_shares_step_has_no_buffer_of_all_the_rows(held):
+    """The training step of one expert layer, 64 tokens x 4 choices: with
+    4 of 16 experts held (a bound of 128 rows) no result of any
+    equation, forward or backward, is [n * K, width] or [n, K, width];
+    with all held both are there (so the walk would see them)."""
+    spec = tfm.model_spec(**dict(
+        TINY, num_layers=1, layer_pattern="c", moe_experts_held=held))
+    cfg = spec.config
+    shapes = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, t: _loss(spec, t)(p)))(
+        shapes, tokens)
+    seen = {tuple(v.aval.shape) for eqn in _eqns(jaxpr.jaxpr)
+            for v in eqn.outvars}
+    n, k = 2 * 32, cfg.moe_top_k
+    whole = {(n * k, cfg.dim), (n * k, cfg.mlp_dim), (n, k, cfg.dim),
+             (n, k, cfg.mlp_dim)}
+    if held:
+        assert not seen & whole
+        assert {(128, cfg.dim), (128, cfg.mlp_dim)} <= seen
+    else:
+        assert {(n * k, cfg.dim), (n * k, cfg.mlp_dim),
+                (n, k, cfg.dim)} <= seen
+
+
 # -- expert_bias is state ----------------------------------------------------
 
 
@@ -389,15 +585,20 @@ def test_warmup_steps_raises_the_rate_linearly_and_zero_is_constant():
 # -- what must not have moved ------------------------------------------------
 
 
-def _primitives(jaxpr, out):
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
     for eqn in jaxpr.eqns:
-        out[eqn.primitive.name] += 1
+        yield eqn
         for value in eqn.params.values():
             for inner in (value if isinstance(value, (list, tuple))
                           else [value]):
                 inner = getattr(inner, "jaxpr", inner)
                 if hasattr(inner, "eqns"):
-                    _primitives(inner, out)
+                    yield from _eqns(inner)
+
+
+def _primitives(jaxpr, out):
+    out.update(eqn.primitive.name for eqn in _eqns(jaxpr))
     return out
 
 
@@ -484,13 +685,22 @@ def test_remat_keeps_table_counts_each_kinds_layers():
     assert entries["conv_in"][:2] == ((sc.KEEP_IN,), rows * 3 * 128 * 2)
     assert entries["conv_out"][:2] == ((sc.KEEP_OUT,), rows * 128 * 2)
     assert entries["ffn_gate"][1] == rows * 80 * 2       # dense_ffn_dim
-    assert entries["moe_gate"][1] == rows * 4 * 48 * 2   # K x ffn_dim
-    # a share's expert entries go last; with all held they keep their place
+    # a share's dispatch buffers have the bound's rows, not rows x K
+    bound = md.row_bound(rows * 4, 4, 16)
+    assert bound == 128 and entries["moe_gate"][1] == bound * 48 * 2
+    assert entries["moe_rows"][1] == entries["moe_out"][1] == bound * 128 * 2
+    # by what a GB of them is worth: a balanced router fills half the
+    # bound, so a share's go at half their worth; all held, at all of it
     order = [label for label, _, _ in rk.table(cfg, rows)]
-    assert order.index("conv_out") < order.index("moe_out")
-    held_all = dataclasses.replace(cfg, moe_experts_held=0)
-    order = [label for label, _, _ in rk.table(held_all, rows)]
-    assert order.index("moe_rows") < order.index("ffn_gate")
+    assert order[4:] == ["ffn_gate", "ffn_up", "conv_in", "moe_out",
+                         "moe_gate", "moe_up", "conv_out", "moe_rows"]
+    held_all = dataclasses.replace(cfg, moe_experts_held=0,
+                                   moe_share_index=0)
+    entries_all = {e[0]: e for e in rk._entries(held_all, rows)}
+    assert entries_all["moe_gate"][2] == rows * 4 * 48 * 2   # K x ffn_dim
+    assert [e[0] for e in rk._entries(held_all, rows)][4:] == [
+        "moe_out", "moe_gate", "moe_up", "ffn_gate", "ffn_up", "conv_in",
+        "moe_rows", "conv_out"]
     # choose() sums an entry over the layers that make it
     params = jax.eval_shape(
         lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
@@ -504,9 +714,14 @@ def test_remat_keeps_table_counts_each_kinds_layers():
     assert need >= rows * 4 * 80 * 2 + 13 * rows * 128 * 2
 
 
-def test_kept_names_change_no_gradient_of_a_mixed_stack():
+@pytest.mark.parametrize("onto_share", [False, True])
+def test_kept_names_change_no_gradient_of_a_mixed_stack(onto_share):
+    """``onto_share``: every dispatch runs a second block, which keeps
+    nothing whatever the first block's names say."""
     spec = tfm.model_spec(remat=True, **STACK)
     params = jax.jit(spec.init_fn)(jax.random.PRNGKey(0))
+    if onto_share:
+        params = _onto_share(params, spec.config)
     tokens = _tokens(spec)
 
     def grads(room):
@@ -559,26 +774,46 @@ def test_the_published_pattern_takes_a_step_through_the_trainer():
     lines = _log_lines(step, fa.logger, worker_mod.logger)
     stats = trainer.last_step_stats
     assert np.asarray(stats["moe_load"]).shape == (38, 4 + 1)
-    np.testing.assert_array_equal(stats["moe_moved"], [2 * 32 * 4] * 38)
+    # one block of the bound's 128 of the 2 * 32 * 4 rows, every layer
+    np.testing.assert_array_equal(stats["moe_moved"], [128] * 38)
+    np.testing.assert_array_equal(stats["moe_spilled"], [0] * 38)
     assert [l for l in lines if l.startswith("layer stack:")] == [
         "layer stack: pattern=%s lead=cc period=accc periods=9 tail=ac "
         "dense_layers=2 experts_held=4/16" % PUBLISHED]
     load = [l for l in lines if l.startswith("moe load:")]
-    assert len(load) == 1 and load[0].endswith("moved=%d" % (38 * 256))
+    assert len(load) == 1 and load[0].endswith(
+        "moved=%d spilled=0" % (38 * 128))
     fields = dict(item.split("=") for item in load[0].split()[2:])
     assert fields["layers"] == "38"
     assert 0 < int(fields["rows"]) < int(fields["moved"])
 
 
 def test_the_worker_logs_moved_rows_beside_held_rows(caplog):
+    """``moved=`` is what the step handed back, ``spilled=`` ends the
+    line, and the benchmark's reader takes ``1 - rows / moved`` from
+    it."""
+    import types
+
+    from benchmark.lib import job
+
     load = np.array([[10, 0, 30], [16, 16, 32]], np.float32)
     worker_mod.logger.addHandler(caplog.handler)
     try:
         with caplog.at_level(logging.INFO, logger=worker_mod.logger.name):
-            worker_mod._log_step_stats(
-                8, {"moe_load": load, "moe_moved": np.array([256., 256.])})
+            worker_mod._log_step_stats(8, {
+                "moe_load": load, "moe_moved": np.array([64., 128.]),
+                "moe_spilled": np.array([0., 1.])})
     finally:
         worker_mod.logger.removeHandler(caplog.handler)
-    assert [r.getMessage() for r in caplog.records] == [
-        "moe load: step=8 layers=2 rows=42 max=16 mean=10.5 "
-        "padded_rows=62 moved=512"]
+    line, = [r.getMessage() for r in caplog.records]
+    assert line == ("moe load: step=8 layers=2 rows=42 max=16 mean=10.5 "
+                    "padded_rows=62 moved=192 spilled=1")
+    fields = job.fields(line.split("moe load:", 1)[1])
+    assert list(fields)[-2:] == ["moved", "spilled"]
+    stamped = "[2026-09-28 02:00:20,000] [INFO] [worker-0] " + line
+    run = types.SimpleNamespace(
+        job=types.SimpleNamespace(text=stamped + "\n"),
+        times={"open": job.stamp_seconds(stamped) - 1,
+               "close": job.stamp_seconds(stamped) + 1})
+    reader = manifest.load_named("layers", "moe.dead_row_share")
+    assert reader.read(run) == pytest.approx(100 * (1 - 42 / 192))

@@ -151,23 +151,35 @@ def test_model_loss_and_gradients_match_the_plain_reference(
                                    err_msg=str(path))
 
 
-def test_a_collapsed_router_drops_no_row(monkeypatch):
+@pytest.mark.parametrize("held", [0, 2])
+def test_a_collapsed_router_drops_no_row(monkeypatch, held):
     """Every token's first two choices are experts 0 and 1: 64 rows each
     where a capacity of T * K * 2 / X + 1 = 33 would have dropped half.
-    The result must still equal the reference's."""
+    The result must still equal the reference's.  ``held`` = 2: a model
+    that holds experts 0 and 1 alone, whose bound is a quarter of the
+    4 x 64 x 2 rows and every row theirs: four blocks, the reference the
+    whole layer with the six absent experts' weights zeros."""
     monkeypatch.setenv("ELASTICDL_FLASH", "interpret")
-    spec = tfm.model_spec(**_cfg(2, layers=1))
+    spec = tfm.model_spec(moe_experts_held=held, **_cfg(2, layers=1))
     params = spec.init_fn(jax.random.PRNGKey(1))
     params["embed"] = jnp.abs(params["embed"]) * 25.0 + 0.1
     params["layers"]["ln2"] = jnp.abs(params["layers"]["ln2"])
     router = np.zeros((1, 64, 8), np.float32)
     router[..., 0], router[..., 1] = 10.0, 9.0
     params["layers"]["w_router"] = jnp.asarray(router)
-    tokens = _tokens(b=1)
+    tokens = _tokens(b=4 if held else 1)
     got, load = _product_loss(spec, params, tokens)
-    want = _reference_loss(params, tokens, 2)[0].mean()
+    whole = dict(params, layers=dict(params["layers"], **{
+        name: jnp.pad(w, ((0, 0), (0, 8 - w.shape[1]), (0, 0), (0, 0)))
+        for name, w in params["layers"].items()
+        if name in ("w_gate", "w_up", "w_down")}))
+    want = _reference_loss(whole, tokens, 2)[0].mean()
     load = np.asarray(load)[0]
-    assert load[0] == load[1] == 64 and load[2:-1].sum() == 0
+    if held:
+        # experts 0 and 1, padded rows, moved, shards that spilled
+        assert list(load[[0, 1, 3, 4]]) == [256, 256, 4 * 128, 1]
+    else:
+        assert load[0] == load[1] == 64 and load[2:-1].sum() == 0
     np.testing.assert_allclose(got, want, rtol=1e-5)
 
 
@@ -267,4 +279,4 @@ def test_the_worker_logs_one_moe_load_line(caplog):
         worker_mod.logger.removeHandler(caplog.handler)
     lines = [r.getMessage() for r in caplog.records]
     assert lines == ["moe load: step=40 layers=2 rows=128 max=30 "
-                     "mean=16.0 padded_rows=48 moved=128"]
+                     "mean=16.0 padded_rows=48 moved=128 spilled=0"]

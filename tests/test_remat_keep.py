@@ -246,6 +246,51 @@ def test_the_estimate_is_held_to_the_cells_measured_peaks(cell):
     assert peak <= (1 - rk.RESERVE) * V5E_LIMIT
 
 
+@pytest.mark.parametrize("held", [8, 64])
+def test_the_routed_entries_have_the_bounds_rows(held):
+    """LFM2-24B-A2B's cell at its real shapes, no arrays: 32,768 tokens x
+    4 choices over 64 experts.  With 8 held the dispatch's four entries
+    have ``row_bound``'s 32,768 rows and go by half their worth (a
+    balanced router fills half the bound), so the list the chip's room
+    takes ends in the down product and the gate product where the
+    whole-buffer entries, four times the bytes, fitted none; with all
+    64 held they have every row and their whole worth."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "lfm2-24b-a2b.json")) as fh:
+        model_params = json.load(fh)["cli"]["model_params"]
+    assert model_params["moe_experts_held"] == 8
+    spec = tfm.model_spec(**dict(model_params, moe_experts_held=held))
+    cfg = spec.config
+    rows, k, e, f = 4 * 8192, 4, 2048, 1536
+    bound = md.row_bound(rows * k, held, 64)
+    assert bound == (32768 if held == 8 else rows * k)
+    table = {label: (names, nbytes)
+             for label, names, nbytes in rk.table(cfg, rows)}
+    assert table["moe_rows"] == ((md.KEEP_ROWS,), bound * e * 2)
+    assert table["moe_out"] == ((md.KEEP_OUT,), bound * e * 2)
+    assert table["moe_gate"] == ((md.KEEP_GATE,), bound * f * 2)
+    assert table["moe_up"] == ((md.KEEP_UP,), bound * f * 2)
+    order = list(table)
+    assert order[:4] == ["flash", "route", "qkv", "stream"]
+    assert order[4:] == (
+        ["ffn_gate", "ffn_up", "conv_in", "moe_out", "moe_gate", "moe_up",
+         "conv_out", "moe_rows"] if held == 8 else
+        ["moe_out", "moe_gate", "moe_up", "ffn_gate", "ffn_up", "conv_in",
+         "moe_rows", "conv_out"])
+    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+    state = jax.eval_shape(spec.optimizer.init, params)
+    used = 2 * ct._device_bytes(params) + ct._device_bytes(state)
+    if held == 64:
+        assert used > V5E_LIMIT       # no chip holds all 64 of a layer
+        return
+    names, kept, budget, peak = rk.choose(
+        cfg, params, rows, DeviceRoom(V5E_LIMIT, V5E_LIMIT - used))
+    assert names == ATTENTION[:2] + (rk.KEEP_ROUTE, md.KEEP_SORT) + \
+        ATTENTION[2:] + (rk.KEEP_GATE, rk.KEEP_UP, md.KEEP_OUT, md.KEEP_GATE)
+    assert kept <= budget and peak <= (1 - rk.RESERVE) * V5E_LIMIT
+
+
 def _lines(fn, prefix="remat keep:"):
     """The ``remat keep:`` lines logged while ``fn`` runs (the repo's
     loggers do not propagate to the root one)."""
