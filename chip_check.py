@@ -255,11 +255,49 @@ def check_short_conv(b, t, e, taps, interpret):
             "dw": _rel_err(grads[1], want_grads[1])}
 
 
-def check_mixed_stack(tiny, spill=False):
+# The stacks ``check_mixed_stack`` runs: (--tiny sizes, the cell's
+# widths, what both share).
+MIXED_STACKS = {
+    # benchmark/configs/lfm2-24b-a2b.json at a sequence of 2,048: the
+    # attention reference holds all [32, T, T] float32 scores at once
+    "caccc": (
+        dict(vocab_size=256, dim=128, num_heads=4, num_kv_heads=2,
+             seq_len=128, dense_ffn_dim=192, ffn_dim=128, moe_experts=8,
+             moe_top_k=2, moe_experts_held=2),
+        dict(vocab_size=8192, dim=2048, num_heads=32, num_kv_heads=8,
+             seq_len=2048, dense_ffn_dim=11776, ffn_dim=1536,
+             moe_experts=64, moe_top_k=4, moe_experts_held=8),
+        dict(num_layers=5, layer_pattern="caccc", dense_layers=1,
+             moe_router="sigmoid_bias", moe_aux_weight=0, qk_norm="head",
+             rope_theta=1e6, norm_eps=1e-5)),
+    # benchmark/configs/smallthinker-21b-a3b.json at a sequence of 2,048
+    # and an eighth of the slice of the vocabulary, the window cut from
+    # 4,096 to 512 so that it bites inside what the reference can hold
+    # (the band at 4,096 in 16,384 is the cell's own comparison)
+    "awww": (
+        dict(vocab_size=256, dim=128, num_heads=4, num_kv_heads=2,
+             head_dim=64, seq_len=256, window=128, ffn_dim=128,
+             moe_experts=8, moe_top_k=2, moe_experts_held=2),
+        dict(vocab_size=4748, dim=2560, num_heads=28, num_kv_heads=4,
+             head_dim=128, seq_len=2048, window=512, ffn_dim=768,
+             moe_experts=64, moe_top_k=6, moe_experts_held=16),
+        dict(num_layers=4, layer_pattern="awww", rope_kinds="w",
+             rope_theta=1.5e6, ffn_activation="relu",
+             moe_route_before_op=True, moe_aux_weight=0,
+             tied_embeddings=False, embed_scale=1.0)),
+}
+
+
+def check_mixed_stack(tiny, spill=False, stack="caccc"):
     """One loss-and-gradients evaluation of a stack whose layers differ
-    (a leading short-convolution layer with a dense MLP, then attention
-    and short convolutions with a share of the experts behind a sigmoid
-    router: the ``lfm2-24b-a2b`` cell's model at one short sequence), bf16
+    (``MIXED_STACKS``: a leading short-convolution layer with a dense
+    MLP, then attention and short convolutions with a share of the
+    experts behind a sigmoid router, the ``lfm2-24b-a2b`` cell's model;
+    or full attention without positional encoding then windowed
+    attention with RoPE, heads wider than the hidden size divides into,
+    the router read before attention, ReGLU over a share of the
+    experts, the ``smallthinker-21b-a3b`` cell's; each at one short
+    sequence), bf16
     through the kernels, against the same weights in float32 through
     the references at the highest matmul precision (the loss), and the
     same bf16 program through the references (the gradients).
@@ -269,22 +307,18 @@ def check_mixed_stack(tiny, spill=False):
     from elasticdl_tpu.models import transformer as tfm
     from elasticdl_tpu.ops.mode import kernels_off
 
-    sizes = (dict(vocab_size=256, dim=128, num_heads=4, num_kv_heads=2,
-                  seq_len=128, dense_ffn_dim=192, ffn_dim=128, moe_experts=8,
-                  moe_top_k=2, moe_experts_held=2) if tiny else
-             # the cell's widths at a sequence of 2,048: the attention
-             # reference holds all [32, T, T] float32 scores at once
-             dict(vocab_size=8192, dim=2048, num_heads=32, num_kv_heads=8,
-                  seq_len=2048, dense_ffn_dim=11776, ffn_dim=1536,
-                  moe_experts=64, moe_top_k=4, moe_experts_held=8))
-    common = dict(num_layers=5, layer_pattern="caccc", dense_layers=1,
-                  moe_router="sigmoid_bias", moe_aux_weight=0,
-                  qk_norm="head", rope_theta=1e6, norm_eps=1e-5, remat=True,
-                  **sizes)
+    small, full, shared = MIXED_STACKS[stack]
+    sizes = small if tiny else full
+    common = dict(shared, remat=True, **sizes)
     spec = tfm.model_spec(**common)
     exact = tfm.model_spec(dtype="float32", **common)
     params = jax.jit(spec.init_fn)(jax.random.PRNGKey(5))
-    params["embed"] = params["embed"] * 25.0    # logits that matter
+    # logits that matter: an untied head's by its own weights, a tied
+    # one's through its embedding
+    if "lm_head" in params:
+        params["lm_head"] = params["lm_head"] * 5.0
+    else:
+        params["embed"] = params["embed"] * 25.0
     if spill:
         held = sizes["moe_experts_held"]
         params = jax.tree_util.tree_map_with_path(
@@ -645,6 +679,8 @@ def _cases(tiny):
     yield ("mixed_stack/caccc.share", lambda: check_mixed_stack(tiny))
     yield ("mixed_stack/caccc.share.spill",
            lambda: check_mixed_stack(tiny, spill=True))
+    yield ("mixed_stack/awww.share",
+           lambda: check_mixed_stack(tiny, stack="awww"))
     # The benchmark's two heads: OLMoE's untied, OLMo's tied embedding.
     hdim, vocab, heads = (64, 256, ((2, 24, False), (2, 24, True))) if tiny \
         else (2048, 50304, ((4, 4096, False), (8, 2048, True)))

@@ -34,7 +34,7 @@ from elasticdl_tpu.models.spec import ModelSpec
 from elasticdl_tpu.ops import short_conv
 from elasticdl_tpu.ops.flash_attention import flash_attention, logger
 from elasticdl_tpu.ops.mode import kernels_off
-from elasticdl_tpu.ops.moe_dispatch import moe_experts
+from elasticdl_tpu.ops.moe_dispatch import ACTIVATIONS, moe_experts
 from elasticdl_tpu.utils import metrics
 
 
@@ -48,6 +48,14 @@ class TransformerConfig:
     max_seq_len: int = 2048
     dtype: str = "bfloat16"
     tied_embeddings: bool = True
+    # The standard deviation the embedding is drawn at.  0.02 leaves a
+    # token's own row a tenth of what the first block adds to the
+    # stream (at random weights attention's output is mostly the mean
+    # of its values, one vector every token shares), so every later
+    # norm, and every router behind one, sees nearly the same input
+    # for every token; 1.0 keeps the stream a token's own, as trained
+    # weights do.
+    embed_scale: float = 0.02
     # Width of the MLP (of one expert, in an MoE): 0 = dim * mlp_ratio.
     ffn_dim: int = 0
     # RMSNorm's epsilon.
@@ -58,12 +66,22 @@ class TransformerConfig:
     # ``head_dim`` values, with one scale of ``head_dim`` that the heads
     # share (LFM2).
     qk_norm: bool | str = False
-    # RoPE's base.
+    # RoPE's base, and the attention kinds of ``layer_pattern`` whose q
+    # and k it turns: a kind that is not named has no positional
+    # encoding at all ("w": windowed layers alone, full ones NoPE).
     rope_theta: float = 10000.0
+    rope_kinds: str = "aw"
+    # Values a head: 0 = dim // num_heads; a size of its own makes the
+    # q and output projections num_heads * head_dim wide, whatever
+    # ``dim`` (resolved at construction: ``dataclasses.replace`` of
+    # ``dim`` or ``num_heads`` keeps it).
+    head_dim: int = 0
     # A stack whose layers differ.  ``layer_pattern``: one letter a
-    # layer, "a" attention, "c" gated short convolution of
-    # ``conv_kernel`` taps (ops/short_conv.py); "" = attention in every
-    # layer.  ``dense_layers``: how many leading layers have a dense MLP
+    # layer, "a" causal attention over the whole sequence, "w" causal
+    # attention over the last ``window`` positions, "c" gated short
+    # convolution of ``conv_kernel`` taps (ops/short_conv.py); "" = one
+    # attention kind in every layer ("w" if ``window``, else "a").
+    # ``dense_layers``: how many leading layers have a dense MLP
     # of ``dense_ffn_dim`` in an MoE model.  With either, the stack is
     # the leading layers, then whole periods of the rest's pattern under
     # one scan (a period's layers unrolled in its body, weights stacked
@@ -94,6 +112,15 @@ class TransformerConfig:
     # their sum + 1e-6, times ``moe_route_scale``.
     moe_router: str = "softmax"
     moe_route_scale: float = 1.0
+    # What the router reads: False = the FFN's input, RMSNorm_2 of the
+    # stream after the operator; True = the operator's input,
+    # RMSNorm_1 of the block's input, so the route is taken before the
+    # operator runs and carried past it to the experts.
+    moe_route_before_op: bool = False
+    # The gate's activation, of the experts and of a dense FFN alike
+    # (``ops/moe_dispatch.ACTIVATIONS``): "silu" (SwiGLU) | "relu"
+    # (ReGLU).
+    ffn_activation: str = "silu"
     # One chip's share of the experts: ``moe_experts`` stays the
     # router's width, every token is routed over all of them, and this
     # model holds (and multiplies) experts ``moe_share_index *
@@ -121,10 +148,12 @@ class TransformerConfig:
     # (all-to-all head/sequence re-sharding, parallel/ulysses.py;
     # requires (heads/tp) % sp == 0).
     attention_impl: str = "ring"
-    # Sliding-window causal attention: 0 = full causal; W > 0 keeps only
+    # The window of the windowed attention kind: its layers keep only
     # the last W positions (O(T·W) attention compute — out-of-band
     # blocks skip matmuls and DMA in the flash kernel, and whole ring
-    # steps skip when the shard lies past the band).
+    # steps skip when the shard lies past the band).  Which layers are
+    # of that kind is ``layer_pattern``'s to say ("w"); without a
+    # pattern W > 0 makes every layer one.  0 = no such kind.
     window: int = 0
     # Grouped-query attention: 0 = MHA (kv heads == num_heads); G > 0
     # projects K/V to G heads and each group of num_heads/G query heads
@@ -133,9 +162,10 @@ class TransformerConfig:
     # grouped consecutively (head i attends kv head i // (H/G)).
     num_kv_heads: int = 0
 
-    @property
-    def head_dim(self):
-        return self.dim // self.num_heads
+    def __post_init__(self):
+        if not self.head_dim:
+            object.__setattr__(self, "head_dim",
+                               self.dim // self.num_heads)
 
     @property
     def kv_heads(self):
@@ -156,7 +186,8 @@ class TransformerConfig:
         """The Kind of every layer, in order."""
         plan = stack_plan(self)
         if plan is None:
-            return (Kind("a", not self.moe_experts),) * self.num_layers
+            return (_kind(self, "w" if self.window else "a",
+                          not self.moe_experts),) * self.num_layers
         return plan.lead + plan.period * plan.periods + plan.tail
 
     @property
@@ -173,41 +204,73 @@ class TransformerConfig:
         return self.moe_share_index * held, held
 
 
-# One layer's kind: its operator ("a" | "c") and whether its FFN is
-# dense (in an MoE model, a leading layer's).
-Kind = collections.namedtuple("Kind", "op dense")
+# One layer's kind: its operator ("a" attention | "c" short
+# convolution), whether its FFN is dense (in an MoE model, a leading
+# layer's) and, of an attention layer, the window it attends over (0:
+# the whole sequence) and whether RoPE turns its q and k.
+Kind = collections.namedtuple("Kind", "op dense window rope",
+                              defaults=(0, True))
 # ``lead`` and ``tail``: the kinds of the layers before and after the
 # scan; ``period``: the kinds of one period; ``periods``: how many the
 # scan runs.
 StackPlan = collections.namedtuple("StackPlan", "lead period periods tail")
 
 
+def _kind(cfg, letter, dense):
+    """The Kind a letter of ``layer_pattern`` names."""
+    if letter == "c":
+        return Kind("c", dense)
+    return Kind("a", dense, cfg.window if letter == "w" else 0,
+                letter in cfg.rope_kinds)
+
+
+def _letter(kind):
+    return "w" if kind.window else kind.op
+
+
 def stack_plan(cfg):
     """How a stack whose layers differ is run, or None for a model of
     one layer kind (no pattern, no leading dense layers).  The period is
     the shortest that the layers after the leading ones repeat."""
+    if set(cfg.rope_kinds) - set("aw"):
+        raise ValueError(
+            "rope_kinds %r: want letters of a (full attention) and w "
+            "(windowed attention)" % (cfg.rope_kinds,))
     if not cfg.layer_pattern and not cfg.dense_layers:
         return None
-    pattern = cfg.layer_pattern or "a" * cfg.num_layers
-    if len(pattern) != cfg.num_layers or set(pattern) - set("ac"):
+    pattern = cfg.layer_pattern or ("w" if cfg.window else "a") * (
+        cfg.num_layers)
+    if len(pattern) != cfg.num_layers or set(pattern) - set("awc"):
         raise ValueError(
-            "layer_pattern %r: want %d letters, each a (attention) or c "
-            "(short convolution)" % (pattern, cfg.num_layers))
+            "layer_pattern %r: want %d letters, each a (attention), w "
+            "(attention over the last `window` positions) or c (short "
+            "convolution)" % (pattern, cfg.num_layers))
+    if ("w" in pattern) != bool(cfg.window):
+        raise ValueError(
+            "layer_pattern %r and window=%d: the window is the w "
+            "layers' and theirs alone, so each needs the other"
+            % (pattern, cfg.window))
     if cfg.dense_layers and not (cfg.moe_experts and cfg.dense_ffn_dim):
         raise ValueError(
             "dense_layers=%d needs moe_experts and dense_ffn_dim: "
             "without experts every layer's FFN is dense"
             % cfg.dense_layers)
     dense = not cfg.moe_experts
-    lead = tuple(Kind(op, True) for op in pattern[:cfg.dense_layers])
+    lead = tuple(_kind(cfg, op, True) for op in pattern[:cfg.dense_layers])
     rest = pattern[cfg.dense_layers:]
     size = next((p for p in range(1, len(rest) + 1)
                  if all(rest[i] == rest[i % p] for i in range(len(rest)))),
                 1)
     periods = len(rest) // size
     return StackPlan(
-        lead, tuple(Kind(op, dense) for op in rest[:size]), periods,
-        tuple(Kind(op, dense) for op in rest[periods * size:]))
+        lead, tuple(_kind(cfg, op, dense) for op in rest[:size]), periods,
+        tuple(_kind(cfg, op, dense) for op in rest[periods * size:]))
+
+
+def _one_kind(cfg):
+    """The kind of a model's layers where no caller names one: the
+    first attention kind of its stack."""
+    return next(kind for kind in cfg.kinds if kind.op == "a")
 
 
 def _uniform_only(cfg, what):
@@ -215,8 +278,10 @@ def _uniform_only(cfg, what):
         raise NotImplementedError(
             "%s does not run a stack whose layers differ (layer_pattern="
             "%r, dense_layers=%d): a short-convolution layer needs a "
-            "state cache of its own and the stages a split by kind "
-            "(ROADMAP)" % (what, cfg.layer_pattern, cfg.dense_layers))
+            "state cache of its own, a windowed layer (w) beside full "
+            "ones a K/V cache that keeps its last `window` positions, "
+            "and the stages a split by kind (ROADMAP)"
+            % (what, cfg.layer_pattern, cfg.dense_layers))
 
 
 # -- parameters --------------------------------------------------------------
@@ -295,7 +360,8 @@ def init_params(rng, cfg):
                   "period": group(1, plan.period, (plan.periods,)),
                   "tail": group(2, plan.tail, ())}
     params = {
-        "embed": _dense_init(k_embed, cfg.vocab_size, E, scale=0.02),
+        "embed": _dense_init(k_embed, cfg.vocab_size, E,
+                             scale=cfg.embed_scale),
         "layers": layers,
         "ln_f": _norm_init(E),
     }
@@ -420,8 +486,11 @@ def moe_route(h, w_router, cfg, expert_bias=None):
     return probs, gates, experts
 
 
-def _moe_ffn(h, w, cfg, mesh):
+def _moe_ffn(h, w, cfg, mesh, route=None):
     """Dropless top-k MoE FFN (expert weights sharded over ``ep``).
+    ``route``: :func:`moe_route`'s three results where the block took
+    them before its operator (``cfg.moe_route_before_op``); else the
+    router reads ``h``.
 
     One dispatch (``ops/moe_dispatch.moe_experts``) with two ways to
     multiply, chosen by where the code runs: the Pallas grouped matmul,
@@ -446,13 +515,14 @@ def _moe_ffn(h, w, cfg, mesh):
     B, T = h.shape[:2]
     X = cfg.moe_experts
     first, held = cfg.experts_held
-    probs, gates, experts = moe_route(h, w["w_router"], cfg,
-                                      w.get("expert_bias"))
+    probs, gates, experts = route or moe_route(
+        h, w["w_router"], cfg, w.get("expert_bias"))
     weights = tuple(w[name].astype(h.dtype)
                     for name in ("w_gate", "w_up", "w_down"))
     with kernels_off(mesh is not None):
         out, load = moe_experts(h, gates, experts, *weights,
-                                total=X, first=first)
+                                total=X, first=first,
+                                activation=cfg.ffn_activation)
     load = load.sum(axis=0).astype(jnp.float32)
     stats = jnp.stack([load[:X] / (B * T), probs.mean(axis=(0, 1))])
     aux = X * jnp.sum(stats[0] * stats[1])
@@ -469,9 +539,10 @@ def _constrain(x, mesh, spec):
     return x
 
 
-def _project_qkv(h, w, cfg, positions):
+def _project_qkv(h, w, cfg, positions, rope=True):
     """q [B, T, H, D], k and v [B, T, G, D] of the normed input, RoPE
-    applied to q and k (after the QK norm where the model has one)."""
+    applied to q and k (after the QK norm where the model has one)
+    unless the layer's kind has none (``rope`` False)."""
     compute_dtype = jnp.dtype(cfg.dtype)
     B, T = h.shape[0], h.shape[1]
     H, D, G = cfg.num_heads, cfg.head_dim, cfg.kv_heads
@@ -487,47 +558,53 @@ def _project_qkv(h, w, cfg, positions):
     q = project("wq", cfg.qk_norm and "q_norm", H)
     k = project("wk", cfg.qk_norm and "k_norm", G)
     v = project("wv", None, G)
+    if rope:
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
     # as the attention takes them: what its backward reads
-    return (checkpoint_name(_rope(q, positions, cfg.rope_theta),
-                            remat_keep.KEEP_Q),
-            checkpoint_name(_rope(k, positions, cfg.rope_theta),
-                            remat_keep.KEEP_K),
+    return (checkpoint_name(q, remat_keep.KEEP_Q),
+            checkpoint_name(k, remat_keep.KEEP_K),
             checkpoint_name(v, remat_keep.KEEP_V))
 
 
-def _ffn(x, w, cfg, mesh, dense=False):
+def _ffn(x, w, cfg, mesh, dense=False, route=None):
     """x + FFN(norm(x)) -> (x, aux, stats, load); the last three are
-    the MoE's (:func:`_moe_ffn`), zeros and None for a dense FFN (a
-    model without experts, or a ``dense`` layer of one with)."""
+    the MoE's (:func:`_moe_ffn`, which ``route`` is for), zeros and None
+    for a dense FFN (a model without experts, or a ``dense`` layer of
+    one with)."""
     compute_dtype = jnp.dtype(cfg.dtype)
     act_spec = P("dp", "sp", None)
     h = _rmsnorm(x, w["ln2"].astype(compute_dtype), cfg.norm_eps)
     if cfg.moe_experts and not dense:
-        out, aux, stats, load = _moe_ffn(h, w, cfg, mesh)
+        out, aux, stats, load = _moe_ffn(h, w, cfg, mesh, route)
         return x + _constrain(out, mesh, act_spec), aux, stats, load
     gate = checkpoint_name(h @ w["w_gate"].astype(compute_dtype),
                            remat_keep.KEEP_GATE)
     up = checkpoint_name(h @ w["w_up"].astype(compute_dtype),
                          remat_keep.KEEP_UP)
     x = x + _constrain(
-        (jax.nn.silu(gate) * up) @ w["w_down"].astype(compute_dtype),
+        (ACTIVATIONS[cfg.ffn_activation](gate) * up)
+        @ w["w_down"].astype(compute_dtype),
         mesh, act_spec,
     )
     return x, jnp.float32(0.0), None, None
 
 
-def _attention(x, w, cfg, mesh, positions):
+def _attention(x, w, cfg, mesh, positions, kind=None):
     """x + Attention(norm(x)) -> (x, (k, v)): k, v post-RoPE and
-    pre-GQA-expand, [B, T, G, D].  Without a mesh the attention is the
-    op itself (``ops/flash_attention.py``, which picks kernel or
-    reference); ``parallel/`` serves a mesh."""
+    pre-GQA-expand, [B, T, G, D].  ``kind`` says the layer's window and
+    whether it has RoPE (None: the model's one attention kind).
+    Without a mesh the attention is the op itself
+    (``ops/flash_attention.py``, which picks kernel or reference);
+    ``parallel/`` serves a mesh."""
     compute_dtype = jnp.dtype(cfg.dtype)
     act_spec = P("dp", "sp", None)
+    kind = kind or _one_kind(cfg)
     B, T = x.shape[0], x.shape[1]
     H, D = cfg.num_heads, cfg.head_dim
     G = cfg.kv_heads
     h = _rmsnorm(x, w["ln1"].astype(compute_dtype), cfg.norm_eps)
-    q, k, v = _project_qkv(h, w, cfg, positions)
+    q, k, v = _project_qkv(h, w, cfg, positions, kind.rope)
     kv_out = (k, v)
     if G != H:
         # GQA: expand K/V to the full head count for the (unchanged)
@@ -545,18 +622,18 @@ def _attention(x, w, cfg, mesh, positions):
     if mesh is None:
         attn = flash_attention(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), causal=True, window=cfg.window,
+            v.transpose(0, 2, 1, 3), causal=True, window=kind.window,
         ).transpose(0, 2, 1, 3)
     elif cfg.attention_impl == "ulysses":
         from elasticdl_tpu.parallel.ulysses import ulysses_attention
 
         attn = ulysses_attention(q, k, v, mesh, causal=True,
-                                 window=cfg.window)
+                                 window=kind.window)
     else:
         from elasticdl_tpu.parallel.ring_attention import ring_attention
 
         attn = ring_attention(q, k, v, mesh, causal=True,
-                              window=cfg.window)
+                              window=kind.window)
     attn = attn.reshape(B, T, H * D)
     x = x + _constrain(
         attn @ w["wo"].astype(compute_dtype), mesh, act_spec
@@ -589,12 +666,21 @@ def _layer_body(x, w, cfg, mesh, positions, moe_stats=False,
     cache.  Which kernels run is not its business: the ops ask
     ``ops/mode.py``, and a caller that traces it where none can run
     says so with ``kernels_off()``."""
-    kind = kind or Kind("a", not cfg.moe_experts)
+    kind = kind or _one_kind(cfg)
+    route = None
+    if cfg.moe_route_before_op and not kind.dense:
+        # the router reads what the operator reads (the operator takes
+        # the same norm again: one value, the compiler's to share)
+        route = moe_route(
+            _rmsnorm(x, w["ln1"].astype(jnp.dtype(cfg.dtype)),
+                     cfg.norm_eps),
+            w["w_router"], cfg, w.get("expert_bias"))
     if kind.op == "c":
         x, kv_out = _short_conv(x, w, cfg), None
     else:
-        x, kv_out = _attention(x, w, cfg, mesh, positions)
-    x, aux, stats, load = _ffn(x, w, cfg, mesh, dense=kind.dense)
+        x, kv_out = _attention(x, w, cfg, mesh, positions, kind)
+    x, aux, stats, load = _ffn(x, w, cfg, mesh, dense=kind.dense,
+                               route=route)
     if moe_stats and not kind.dense:
         aux = stats
     elif moe_load and not kind.dense:
@@ -687,12 +773,16 @@ def forward_hidden(params, tokens, cfg, mesh=None, return_load=False):
 def announce_stack(pattern, plan, experts):
     """Once per model, by the logger ``announce_tiles`` uses: how a
     stack whose layers differ is run."""
-    letters = lambda kinds: "".join(k.op for k in kinds) or "-"
+    letters = lambda kinds: "".join(map(_letter, kinds)) or "-"
+    kinds = sorted(set(k for k in plan.lead + plan.period + plan.tail
+                       if k.op == "a"), key=_letter)
     logger.info(
         "layer stack: pattern=%s lead=%s period=%s periods=%d tail=%s "
-        "dense_layers=%d experts_held=%d/%d", pattern, letters(plan.lead),
-        letters(plan.period), plan.periods, letters(plan.tail),
-        len(plan.lead), *experts)
+        "dense_layers=%d experts_held=%d/%d%s", pattern,
+        letters(plan.lead), letters(plan.period), plan.periods,
+        letters(plan.tail), len(plan.lead), *experts,
+        "".join(" %s:window=%d,rope=%d" % (_letter(k), k.window, k.rope)
+                for k in kinds))
 
 
 def _mixed_stack(x, layers, cfg, plan, block):
@@ -700,7 +790,7 @@ def _mixed_stack(x, layers, cfg, plan, block):
     layers unrolled in its body), the remainder -> (x, what the layers
     with experts returned beside it, stacked in layer order: aux [L_moe]
     or (aux [L_moe], load [L_moe, ..]); a zero where none has experts)."""
-    announce_stack(cfg.layer_pattern or "a" * cfg.num_layers, plan,
+    announce_stack("".join(map(_letter, cfg.kinds)), plan,
                    (cfg.experts_held[1], cfg.moe_experts))
 
     tree_map = jax.tree_util.tree_map
@@ -854,9 +944,14 @@ def _decode_layer(x, w, cfg, ck, cv, pos):
     B = x.shape[0]
     H, D, G = cfg.num_heads, cfg.head_dim, cfg.kv_heads
     R = H // G
+    kind = _one_kind(cfg)     # a uniform stack's (``_uniform_only``)
     positions = jnp.reshape(pos, (1,))
     h = _rmsnorm(x, w["ln1"].astype(compute_dtype), cfg.norm_eps)
-    q, k, v = _project_qkv(h, w, cfg, positions)
+    route = None
+    if cfg.moe_route_before_op and not kind.dense:
+        # as ``_layer_body``: the router reads what attention reads
+        route = moe_route(h, w["w_router"], cfg, w.get("expert_bias"))
+    q, k, v = _project_qkv(h, w, cfg, positions, kind.rope)
     ck = jax.lax.dynamic_update_slice(
         ck, k.astype(ck.dtype), (0, pos, 0, 0))
     cv = jax.lax.dynamic_update_slice(
@@ -868,8 +963,8 @@ def _decode_layer(x, w, cfg, ck, cv, pos):
     ) * (D ** -0.5)                                   # [B, G, R, max]
     idx = jnp.arange(ck.shape[1])
     valid = idx <= pos
-    if cfg.window:
-        valid &= (pos - idx) < cfg.window
+    if kind.window:
+        valid &= (pos - idx) < kind.window
     s = jnp.where(valid[None, None, None, :], s, NEG_INF_DECODE)
     p = jax.nn.softmax(s, axis=-1)
     attn = jnp.einsum(
@@ -877,7 +972,7 @@ def _decode_layer(x, w, cfg, ck, cv, pos):
     ).reshape(B, 1, H * D).astype(compute_dtype)
     x = x + attn @ w["wo"].astype(compute_dtype)
 
-    x = _ffn(x, w, cfg, None)[0]
+    x = _ffn(x, w, cfg, None, route=route)[0]
     return x, ck, cv
 
 
@@ -1069,19 +1164,28 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
                tied_embeddings=True, rope_theta=10000.0, layer_pattern="",
                dense_layers=0, dense_ffn_dim=0, conv_kernel=3,
                moe_router="softmax", moe_route_scale=1.0,
-               moe_experts_held=0, moe_share_index=0, warmup_steps=0):
+               moe_experts_held=0, moe_share_index=0, warmup_steps=0,
+               head_dim=0, rope_kinds="aw", moe_route_before_op=False,
+               ffn_activation="silu", embed_scale=0.02):
     """Zoo entry for the flagship LM.
 
     ``remat`` (False | True | "dots" | "attn"), ``attention_impl``
-    ("ring" | "ulysses"), ``window`` (sliding-window causal, 0 = full),
-    ``num_kv_heads`` (grouped-query attention: 0 = MHA, G > 0
-    shares each K/V head across num_heads/G query heads), ``ffn_dim``
-    (MLP or expert width, 0 = 4 * dim), ``norm_eps``, ``qk_norm``
-    (false | true | head), ``moe_norm_topk``, ``tied_embeddings``,
-    ``rope_theta``, the stack's ``layer_pattern`` / ``dense_layers`` /
-    ``dense_ffn_dim`` / ``conv_kernel``, the router's ``moe_router`` /
-    ``moe_route_scale`` and the share ``moe_experts_held`` /
-    ``moe_share_index`` pass through
+    ("ring" | "ulysses"), ``num_kv_heads`` (grouped-query attention:
+    0 = MHA, G > 0 shares each K/V head across num_heads/G query
+    heads), ``head_dim`` (0 = dim / num_heads), ``ffn_dim`` (MLP or
+    expert width, 0 = 4 * dim), ``ffn_activation`` (silu | relu),
+    ``norm_eps``, ``qk_norm`` (false | true | head), ``moe_norm_topk``,
+    ``tied_embeddings``, ``embed_scale`` (the embedding's init, 0.02),
+    ``rope_theta``, the stack's ``layer_pattern``
+    (a letter a layer: a full attention, w attention over the last
+    ``window`` positions, c short convolution) / ``dense_layers`` /
+    ``dense_ffn_dim`` / ``conv_kernel``, what is per attention kind:
+    ``window`` (the w layers' window; without a pattern every layer's,
+    0 = full) and ``rope_kinds`` (the kinds RoPE turns, "aw"; a kind
+    left out has no positional encoding), the router's ``moe_router`` /
+    ``moe_route_scale`` / ``moe_route_before_op`` (the router reads the
+    operator's normed input and not the FFN's) and the share
+    ``moe_experts_held`` / ``moe_share_index`` pass through
     to :class:`TransformerConfig`.  ``xent_chunk`` > 0 computes the
     loss via :func:`next_token_loss_chunked` — no [B, T, V] logits
     tensor, the memory-lean path for large vocab x seq (numerically
@@ -1125,10 +1229,18 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
         conv_kernel=int(conv_kernel), moe_router=str(moe_router),
         moe_route_scale=float(moe_route_scale),
         moe_experts_held=int(moe_experts_held),
-        moe_share_index=int(moe_share_index),
+        moe_share_index=int(moe_share_index), head_dim=int(head_dim),
+        rope_kinds=str(rope_kinds),
+        moe_route_before_op=_flag("moe_route_before_op",
+                                  moe_route_before_op),
+        ffn_activation=str(ffn_activation),
+        embed_scale=float(embed_scale),
     )
-    # validate at spec build: heads, the share, the pattern
+    # validate at spec build: heads, the share, the pattern, the gate
     cfg.kv_heads, cfg.experts_held, cfg.kinds
+    if cfg.ffn_activation not in ACTIVATIONS:
+        raise ValueError("unknown ffn_activation %r (want one of %s)" % (
+            cfg.ffn_activation, ", ".join(sorted(ACTIVATIONS))))
     if mesh is not None:
         param_specs(cfg)    # raises, naming what a mesh cannot run yet
     pipelined = (

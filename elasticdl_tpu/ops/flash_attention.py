@@ -439,6 +439,14 @@ def _tile_specs(tile, d, order_axis):
     )
 
 
+def _call_name(kernel, window):
+    """The name a kernel's call carries into the compiled program and a
+    device trace (``%flash_fwd_w4096.3 = ... custom-call(...)``): the
+    kernel, and its window where it has one, so that a trace tells a
+    windowed layer's calls from a full layer's."""
+    return kernel + ("_w%d" % window if window else "")
+
+
 def _flash_forward(q, k, v, causal, scale, interpret, normalize=True,
                    window=0):
     """Returns (out, l, m); out is normalized iff ``normalize``."""
@@ -481,6 +489,7 @@ def _flash_forward(q, k, v, causal, scale, interpret, normalize=True,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name=_call_name("flash_fwd", window),
     )(*plan.tables(plan.q_major), qr, kr, vr)
     return (
         out.reshape(b, h, t, d),
@@ -663,6 +672,7 @@ def _pallas_bwd(q, k, v, out, lse, g, causal, scale, interpret,
         ),
         compiler_params=params,
         interpret=interpret,
+        name=_call_name("flash_dq", window),
     )(*plan.tables(plan.q_major), qr, gr, kr, vr, lse, delta)
 
     kv_spec, q_spec, _, qstat_spec = _tile_specs(tile, d, 1)
@@ -685,6 +695,7 @@ def _pallas_bwd(q, k, v, out, lse, g, causal, scale, interpret,
         ),
         compiler_params=params,
         interpret=interpret,
+        name=_call_name("flash_dkv", window),
     )(*plan.tables(plan.k_major), kr, vr, qr, gr, lse, delta)
     return (
         dq.reshape(b, h, t, d),

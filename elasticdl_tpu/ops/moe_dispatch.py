@@ -44,6 +44,11 @@ KEEP_GATE, KEEP_UP, KEEP_OUT = "moe_gate", "moe_up", "moe_out"
 # result.
 BOUND_FACTOR = 2
 
+# The gate's activation by the model's name for it
+# (``TransformerConfig.ffn_activation``): act(gate) * up is SwiGLU with
+# "silu", ReGLU with "relu".
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
 
 def row_bound(rows, held, total):
     """Rows of every buffer of a dispatch that holds ``held`` of the
@@ -132,8 +137,8 @@ tokens_to_rows.defvjp(_tokens_to_rows_fwd, _tokens_to_rows_bwd)
 rows_to_tokens.defvjp(_rows_to_tokens_fwd, _rows_to_tokens_bwd)
 
 
-@functools.partial(jax.jit, static_argnums=(1,))
-def _block(i, c, x, gates, weights, order, sizes):
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _block(i, c, activation, x, gates, weights, order, sizes):
     """Float32 [n, e]: what the sorted rows ``i * c .. (i + 1) * c`` add
     to the tokens' results.  x [n, e], gates [n, k], order [blocks * c]
     (the sort, padded with n * k: no assignment), sizes [held] the held
@@ -157,7 +162,8 @@ def _block(i, c, x, gates, weights, order, sizes):
     gate = checkpoint_name(matmul(xs, w_gate, sizes), KEEP_GATE)
     up = checkpoint_name(matmul(xs, w_up, sizes), KEEP_UP)
     ys = checkpoint_name(
-        matmul(jax.nn.silu(gate) * up, w_down, sizes), KEEP_OUT)
+        matmul(ACTIVATIONS[activation](gate) * up, w_down, sizes),
+        KEEP_OUT)
     scale = gates.reshape(n * k).at[claims].get(mode="fill", fill_value=0)
     return rows_to_tokens(n, ys, tok, scale)
 
@@ -167,8 +173,8 @@ def _blocks(c, sizes):
     return -(-sizes.sum() // c)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _further_blocks(c, out, x, gates, weights, order, sizes):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _further_blocks(c, activation, out, x, gates, weights, order, sizes):
     """``out`` + every block after the first that holds a held expert's
     row: a loop whose trip count is the data's (none at the balance the
     bound was taken from, all of them under a router collapsed onto the
@@ -176,21 +182,24 @@ def _further_blocks(c, out, x, gates, weights, order, sizes):
     pullback, the block's forward run again: a block keeps nothing."""
     return lax.fori_loop(
         jnp.int32(1), _blocks(c, sizes),
-        lambda i, out: out + _block(i, c, x, gates, weights, order, sizes),
+        lambda i, out: out + _block(i, c, activation, x, gates, weights,
+                                    order, sizes),
         out)
 
 
-def _further_blocks_fwd(c, out, x, gates, weights, order, sizes):
-    return (_further_blocks(c, out, x, gates, weights, order, sizes),
+def _further_blocks_fwd(c, activation, out, x, gates, weights, order,
+                        sizes):
+    return (_further_blocks(c, activation, out, x, gates, weights, order,
+                            sizes),
             (x, gates, weights, order, sizes))
 
 
-def _further_blocks_bwd(c, res, g):
+def _further_blocks_bwd(c, activation, res, g):
     *operands, order, sizes = res
 
     def add_block(i, grads):
         pull = jax.vjp(lambda *operands: _block(
-            i, c, *operands, order, sizes), *operands)[1]
+            i, c, activation, *operands, order, sizes), *operands)[1]
         return jax.tree_util.tree_map(jnp.add, grads, pull(g))
 
     grads = lax.fori_loop(
@@ -203,7 +212,7 @@ _further_blocks.defvjp(_further_blocks_fwd, _further_blocks_bwd)
 
 
 def _moe_experts(h, gates, experts, w_gate, w_up, w_down, total=None,
-                 first=0):
+                 first=0, activation="silu"):
     """The routed FFN of the rows this device holds: sort the n * K
     (token, choice) assignments by expert, gather their rows, three
     grouped matmuls, un-sort and sum each token's K results weighted by
@@ -250,7 +259,8 @@ def _moe_experts(h, gates, experts, w_gate, w_up, w_down, total=None,
         operands = (h.reshape(n, e), gates.reshape(n, k),
                     (w_gate, w_up, w_down), order, sizes)
         out = _further_blocks(
-            bound, _block(jnp.int32(0), bound, *operands), *operands)
+            bound, activation,
+            _block(jnp.int32(0), bound, activation, *operands), *operands)
         blocks = jnp.maximum(_blocks(bound, sizes), 1)
         load = jnp.concatenate([counted, jnp.stack(
             [padded, blocks * bound, (blocks > 1).astype(jnp.int32)])])
@@ -259,7 +269,7 @@ def _moe_experts(h, gates, experts, w_gate, w_up, w_down, total=None,
         _take_rows(k, h.reshape(n, e), order, inverse), KEEP_ROWS)
     gate = checkpoint_name(gm.grouped_matmul(xs, w_gate, sizes), KEEP_GATE)
     up = checkpoint_name(gm.grouped_matmul(xs, w_up, sizes), KEEP_UP)
-    act = jax.nn.silu(gate) * up
+    act = ACTIVATIONS[activation](gate) * up
     ys = checkpoint_name(_take_rows(
         1, gm.grouped_matmul(act, w_down, sizes), inverse, order), KEEP_OUT)
     out = jnp.einsum("nke,nk->ne", ys.reshape(n, k, e).astype(jnp.float32),
@@ -286,14 +296,16 @@ def announce_dispatch(tokens, experts, top_k, kernel, share=None):
 
 
 def moe_experts(h, gates, experts, w_gate, w_up, w_down, total=None,
-                first=0):
+                first=0, activation="silu"):
     """h [B, T, E], gates and experts [B, T, K], the three expert
     weights [X, ...] in h's dtype (or the share ``first .. first + X``
-    of ``total`` experts: ``_moe_experts``) -> (out [B, T, E], load
+    of ``total`` experts: ``_moe_experts``), the gate's ``activation``
+    (a name of ``ACTIVATIONS``) -> (out [B, T, E], load
     [shards, total + 1], with a share [shards, total + 3]).  Where a
     kernel runs, once per shard of the declared batch axis (weights
     whole on each); the reference partitions by itself."""
-    fn = functools.partial(_moe_experts, total=total, first=first)
+    fn = functools.partial(_moe_experts, total=total, first=first,
+                           activation=activation)
     if kernel_mode() == "off":
         return fn(h, gates, experts, w_gate, w_up, w_down)
     return per_batch_shard(fn, (h, gates, experts),

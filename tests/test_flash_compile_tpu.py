@@ -11,6 +11,8 @@ described inside a fixture, never at import: only the worker that is
 given this file loads the TPU library.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -207,6 +209,131 @@ def test_grouped_matmul_over_a_share_compiles_for_v5e(one_chip):
              if 'custom_call_target="tpu_custom_call"' in l]
     for name in ("gmm_nn", "gmm_nt", "gmm_tn"):
         assert len([c for c in calls if name in c]) == 1, (name, calls)
+
+
+# -- the smallthinker-21b-a3b cell's shapes
+# (benchmark/configs/smallthinker-21b-a3b.json) --
+
+
+@pytest.mark.parametrize("window", [0, 4096])
+def test_flash_compiles_at_16384_positions_full_and_windowed(one_chip,
+                                                             window):
+    """One sequence of 16,384, 28 heads of 128: forward, dq and dk-dv
+    under both tile plans, each call carrying its kernel's name and its
+    window's into the compiled program (benchmark/kernels/
+    banded_attention.py tells them by it)."""
+    x = jax.ShapeDtypeStruct((1, 28, 16384, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    static = (True, 128 ** -0.5, False, window)
+
+    def fwd_bwd(q, k, v, g):
+        out, res = fa._flash_fwd(q, k, v, *static)
+        return out, fa._flash_bwd(*static, res, g)
+
+    text = jax.jit(fwd_bwd).lower(x, x, x, x).compile().as_text()
+    calls = [l.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
+             for l in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l]
+    tail = "_w4096" if window else ""
+    assert sorted(calls) == sorted(
+        name + tail for name in ("flash_fwd", "flash_dq", "flash_dkv"))
+
+
+def test_head_loss_compiles_for_a_vocabulary_off_the_lanes(one_chip):
+    """The head-and-loss op at [16,384, 37,984] (a quarter of 151,936:
+    no multiple of 128), untied, hidden 2,560: forward + backward, the
+    one tokens x vocabulary buffer in bfloat16."""
+    dim, vocab, t = 2560, 37984, 16384
+    assert vocab % 128
+    x = jax.ShapeDtypeStruct((1, t, dim), jnp.bfloat16, sharding=one_chip)
+    head = jax.ShapeDtypeStruct((dim, vocab), jnp.bfloat16,
+                                sharding=one_chip)
+    tokens = jax.ShapeDtypeStruct((1, t), jnp.int32, sharding=one_chip)
+
+    def f(x, head, tokens):
+        return hl.head_loss(x, head, tokens, False).sum()
+
+    compiled = jax.jit(jax.value_and_grad(f, argnums=(0, 1))).lower(
+        x, head, tokens).compile()
+
+    from tools.head_loss_on_chip import entry_results
+
+    logits = (t - 1) * vocab
+    big = {dtype for _, _, _, results in entry_results(compiled.as_text())
+           for dtype, size in results if size >= 2 * logits}
+    assert big == {"bf16"}
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.2e9
+
+
+def test_the_banded_stacks_step_fits_a_v5e_as_remat_keep_predicts(
+        one_chip, monkeypatch):
+    """The ``smallthinker-21b-a3b.seq16384`` cell's whole training step
+    (one sequence of 16,384 through a full-NoPE and three windowed-RoPE
+    attention layers, heads x head size 3,584 over a hidden 2,560, 16 of
+    64 ReGLU experts at 6 a token, an untied head over 37,984 ids,
+    AdamW) through the TPU's compiler with what ``remat_keep`` chose
+    kept: its predicted peak is over the compiler's own byte count,
+    never under, and under the device's limit less the reserve (15.69
+    GB against the compiler's 14.62; with nothing kept the estimate,
+    12.64, is the compiler's 12.67 to 0.03).  Both kinds of flash call
+    are in the one program, and no forward runs twice."""
+    import json
+    import os
+
+    import optax
+
+    from elasticdl_tpu.models import remat_keep as rk
+    from elasticdl_tpu.ops import batch_shard
+    from elasticdl_tpu.ops.mode import SWITCH
+
+    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "smallthinker-21b-a3b.json")) as fh:
+        model_params = json.load(fh)["cli"]["model_params"]
+    spec = tfm.model_spec(**model_params)
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+    state = jax.eval_shape(spec.optimizer.init, params)
+    rows = 16384
+    tokens = jax.ShapeDtypeStruct((1, rows), jnp.int32, sharding=one_chip)
+    nbytes = lambda tree: sum(
+        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
+    assert nbytes(params) == 4 * 656529920          # 656.5 M parameters
+    limit = 16911433728           # a v5e's bytes_limit (chip run, PR 29)
+    held = 2 * nbytes(params) + nbytes(state)
+    room = batch_shard.DeviceRoom(limit, limit - held)
+    names, kept, budget, peak = rk.choose(spec.config, params, rows, room)
+    assert set(names) >= set(rk.ATTN_NAMES) | {
+        rk.KEEP_Q, rk.KEEP_K, rk.KEEP_V, rk.KEEP_STREAM}, names
+    assert kept <= budget and peak <= (1 - rk.RESERVE) * limit
+
+    def step(params, state, tokens):
+        def loss(p):
+            with batch_shard.batch_axis(None, None, room):
+                out = spec.apply_fn(p, tokens, True)
+                return spec.loss_fn(out, tokens).mean()
+
+        value, grads = jax.value_and_grad(loss)(params)
+        updates, state2 = spec.optimizer.update(grads, state, params)
+        return optax.apply_updates(params, updates), state2, value
+
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(state), tokens).compile()
+    stats = compiled.memory_analysis()
+    counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
+    assert counted < peak and peak - counted < 1.5e9, (peak, counted)
+    calls = [l.split(" = ")[0].strip().lstrip("%")
+             for l in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l]
+    count = lambda name: len([c for c in calls if re.search(
+        name + r"(__)?\.\d+$|" + name + "$", c)])
+    assert (count("flash_fwd"), count("flash_dq"),
+            count("flash_dkv")) == (1, 1, 1), calls
+    assert (count("flash_fwd_w4096"), count("flash_dq_w4096"),
+            count("flash_dkv_w4096")) == (3, 3, 3), calls
 
 
 def test_the_mixed_stacks_step_fits_a_v5e_as_remat_keep_predicts(

@@ -779,7 +779,7 @@ def test_the_published_pattern_takes_a_step_through_the_trainer():
     np.testing.assert_array_equal(stats["moe_spilled"], [0] * 38)
     assert [l for l in lines if l.startswith("layer stack:")] == [
         "layer stack: pattern=%s lead=cc period=accc periods=9 tail=ac "
-        "dense_layers=2 experts_held=4/16" % PUBLISHED]
+        "dense_layers=2 experts_held=4/16 a:window=0,rope=1" % PUBLISHED]
     load = [l for l in lines if l.startswith("moe load:")]
     assert len(load) == 1 and load[0].endswith(
         "moved=%d spilled=0" % (38 * 128))
