@@ -22,6 +22,7 @@ from elasticdl_tpu.utils.args import (
     parse_master_args,
 )
 from elasticdl_tpu.utils.logging import get_logger
+from elasticdl_tpu.utils.timing import MASTER_SETUP, SETUP
 
 logger = get_logger(__name__)
 
@@ -534,6 +535,7 @@ def _arm_master_slo(servicers):
 def _run_multitenant(args):
     master = build_multitenant_master(args)
     master.prepare()
+    SETUP.close()   # a master that launches no worker says so here
     _arm_master_slo(
         lambda: [job.servicer for job in master.registry.jobs()])
     status_server = None
@@ -560,6 +562,8 @@ def _run_multitenant(args):
 
 
 def main(argv=None):
+    # The interpreter's start and this module's import chain end here.
+    SETUP.begin("master", MASTER_SETUP, logger)
     args = parse_master_args(argv)
     tracing.configure_identity("master")
     tracing.arm_crash_dump()
@@ -568,6 +572,7 @@ def main(argv=None):
         return _run_multitenant(args)
     master = build_master(args)
     master.prepare()
+    SETUP.close()   # a master that launches no worker says so here
     _arm_master_slo(lambda: [master.servicer])
     status_server = None
     if args.status_port >= 0:

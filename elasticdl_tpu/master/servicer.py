@@ -100,6 +100,10 @@ class MasterServicer:
         # not journaled — a restarted master re-learns it from the next
         # cadence of reports.
         self.ps_shard_state = {}
+        # A launched worker's warm start as the task plane sees it:
+        # worker_id -> when its first get_task arrived, None once its
+        # ``worker ready:`` line is logged.  Under self._lock.
+        self._registered_at = {}
 
     def restore_from_journal(self, state):
         """Master restart: resume the version high-water mark and the
@@ -120,6 +124,8 @@ class MasterServicer:
     @_timed_rpc
     def get_task(self, request, _context=None):
         res = pb.GetTaskResponse()
+        with self._lock:
+            self._registered_at.setdefault(request.worker_id, time.time())
         task = self._task_manager.get(request.worker_id)
         if task is not None:
             task.to_pb(out=res.task)
@@ -155,6 +161,9 @@ class MasterServicer:
         # worker and master rings.
         if success:
             tracing.event("task.completed", task=request.task_id)
+            if result.ok and result.worker_id is not None:
+                self._log_worker_ready(result.worker_id,
+                                       result.dispatched_at)
         elif request.requeue:
             tracing.event("task.requeued", task=request.task_id)
         else:
@@ -172,6 +181,28 @@ class MasterServicer:
                 model_version=result.task.model_version
             )
         return pb.Empty()
+
+    def _log_worker_ready(self, worker_id, dispatched_at):
+        """Once for each worker incarnation this master launched, at its
+        first completed task: its warm start on the master's own clock,
+        for an operator who has no access to the worker's log.  From the
+        launch to its first ``get_task`` (interpreter, imports, backend,
+        model and parameters), from there to the first task in its hands
+        (any wait for one to exist), and that task's time to its
+        completion (the step's trace and compile, its first run)."""
+        with self._lock:
+            registered = self._registered_at.get(worker_id)
+            self._registered_at[worker_id] = None
+        if registered is None or self._worker_manager is None:
+            return
+        launched = self._worker_manager.launched_at(worker_id)
+        if launched is None:
+            return
+        logger.info(
+            "worker ready: id=%d launch_to_register_s=%.3f "
+            "register_to_first_task_s=%.3f first_task_s=%.3f",
+            worker_id, registered - launched, dispatched_at - registered,
+            time.time() - dispatched_at)
 
     @rpc_error_guard
     @_timed_rpc

@@ -26,7 +26,13 @@ TASK_TIMEOUT_THRESHOLD_SECS = 300
 
 # Result of TaskManager.report: task is None for unknown ids;
 # permanent_failure marks a task that exhausted its retries.
-ReportResult = namedtuple("ReportResult", ["ok", "task", "permanent_failure"])
+# ``worker_id`` and ``dispatched_at`` (when that worker was handed the
+# task) come with a completion reported from ``doing``: the servicer's
+# ``worker ready:`` line reads them.
+ReportResult = namedtuple(
+    "ReportResult",
+    ["ok", "task", "permanent_failure", "worker_id", "dispatched_at"],
+    defaults=(None, None))
 
 
 class Shard:
@@ -331,7 +337,8 @@ class TaskManager:
             self._max_task_completed_time = max(
                 self._max_task_completed_time, elapsed
             )
-            return self._complete_locked(task, events)
+            return self._complete_locked(task, events)._replace(
+                worker_id=worker_id, dispatched_at=start_time)
         return self._fail_locked(task, err_message, events)
 
     def _report_undispatched_locked(self, task_id, success, err_message,

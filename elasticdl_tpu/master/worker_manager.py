@@ -17,9 +17,11 @@ import signal
 import subprocess
 import sys
 import threading
+import time
 
 from elasticdl_tpu.master import worker_state as ws
 from elasticdl_tpu.utils.logging import get_logger
+from elasticdl_tpu.utils.timing import SETUP
 
 logger = get_logger(__name__)
 
@@ -33,6 +35,7 @@ class WorkerHandle:
         # priority classes follow the slot, not the ever-increasing id.
         self.slot = worker_id if slot is None else slot
         self.status = ws.INIT
+        self.launched_at = time.time()
         self.relaunch_count = 0
         self.relaunch_pending = False
 
@@ -186,6 +189,7 @@ class WorkerManager:
     # -- lifecycle ----------------------------------------------------------
 
     def start(self):
+        SETUP.mark("launch")
         for _ in range(self._num_workers):
             self._launch_worker()
 
@@ -205,6 +209,8 @@ class WorkerManager:
             handle.status = ws.PENDING
             self._workers[worker_id] = handle
         logger.info("launched worker %d", worker_id)
+        # The master's set-up ends with its first worker on its way.
+        SETUP.close()
         watcher = threading.Thread(
             target=self._watch_worker, args=(handle,),
             name="worker-watch-%d" % worker_id, daemon=True,
@@ -295,6 +301,13 @@ class WorkerManager:
             handle.relaunch_pending = True
         self._backend.kill(handle.backend_ref, force=True)
         return True
+
+    def launched_at(self, worker_id):
+        """When this manager launched ``worker_id``; None for a worker it
+        did not launch."""
+        with self._lock:
+            handle = self._workers.get(worker_id)
+        return None if handle is None else handle.launched_at
 
     def live_worker_ids(self):
         with self._lock:

@@ -15,6 +15,12 @@ there is no switch.  This module never imports JAX; it annotates only in
 a process that already has (the worker), so the master and the PS shards
 start no slower.
 
+Set-up is timed by the same phases: ``SETUP``, the process's one
+``SetupTimeline``, turns an entry point's marks into contiguous
+``setup_<phase>`` phases from the OS's start of the process to its first
+piece of work, and says them in one ``<role> setup:`` log line
+(docs/observability.md, "Set-up timeline").
+
 Thread model: phases and counters are written by training/executor
 threads while /statz, /metrics, and Timing.report() readers snapshot
 concurrently.  Every mutation AND every snapshot runs under one plain
@@ -29,12 +35,17 @@ against every snapshot path.
 """
 
 import contextlib
+import os
 import sys
 import threading
 import time
 from collections import defaultdict
 
 from elasticdl_tpu.utils import hist as hist_mod
+
+# Where /proc cannot say when the OS started the process, set-up counts
+# from here: this module's import, the first of the package's.
+_IMPORTED_AT = time.time()
 
 
 def _annotate(name, ids):
@@ -253,24 +264,179 @@ class Timing:
             self._logger.info("counter[%s]: %d", name, n)
 
 
-@contextlib.contextmanager
-def device_trace(log_dir):
-    """Capture an XLA/JAX profiler trace around a block (xplane format):
-    the device's operations and, on the same clock, the ``edl.*`` spans.
-
-    The options are the ones that keep a trace of a training job usable
+def trace_options():
+    """The profiler's options that keep a trace of a training job usable
     (chip runs, PR 23): with the Python tracer on and the HLO protos in,
     6 s of ResNet-50 made a 234 MB trace that took minutes to write; host
     level 2 adds millions of futex waits of the gRPC threads.  Level 1
-    holds the runtime's events and the program's annotations."""
+    holds the runtime's events and the program's annotations.  Every
+    trace the program starts takes these (``device_trace``,
+    ``tracing.profilez_capture``)."""
     import jax
 
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     options.host_tracer_level = 1
     options.enable_hlo_proto = False
-    jax.profiler.start_trace(log_dir, profiler_options=options)
+    return options
+
+
+@contextlib.contextmanager
+def device_trace(log_dir):
+    """Capture an XLA/JAX profiler trace around a block (xplane format):
+    the device's operations and, on the same clock, the ``edl.*`` spans."""
+    import jax
+
+    jax.profiler.start_trace(log_dir, profiler_options=trace_options())
     try:
         yield
     finally:
         jax.profiler.stop_trace()
+
+
+# -- the set-up timeline ------------------------------------------------------
+
+# A process's set-up, in the order its marks come (docs/observability.md,
+# "Set-up timeline").  ``import`` runs from the OS's start of the process
+# to ``main()``'s first line; every other phase from its mark to the next.
+WORKER_SETUP = ("import", "backend_init", "build", "param_init",
+                "first_task_fetch", "first_batch", "first_dispatch",
+                "first_run", "first_report")
+MASTER_SETUP = ("import", "build", "launch")
+
+# What the compile listener (worker/main.xla_compiles_logged) adds up
+# while the timeline is open, as the line names it.
+_OF_FIRST_DISPATCH = ("trace_s", "lower_s", "compile_or_load_s")
+_OF_EVERY_PHASE = ("programs", "cache_hits", "cache_misses")
+
+
+def process_age():
+    """Seconds since the OS started this process: the start time of
+    ``/proc/self/stat`` (clock ticks since boot) against the boot clock.
+    ``/proc/stat``'s ``btime`` would give the same instant in whole
+    seconds only.  Without ``/proc``, since this module's import."""
+    try:
+        with open("/proc/self/stat") as fh:
+            stat = fh.read()
+        ticks = int(stat[stat.rindex(")") + 2:].split()[19])
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - (
+            ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError, AttributeError):
+        age = -1.0
+    return age if age >= 0 else time.time() - _IMPORTED_AT
+
+
+class SetupTimeline:
+    """From the process's start to the end of its first piece of work, as
+    contiguous phases: a mark ends the open phase and opens the next, so
+    the phases partition the time by construction.  Each phase is a
+    ``Timing`` phase ``setup_<name>`` (an ``edl.setup_<name>`` annotation
+    in a whole-run ``--profile_dir`` trace); when the timeline closes it
+    logs one ``<role> setup:`` line of ``key=value`` fields and records
+    one ``<role>.setup`` flight-recorder event.
+
+    A process has one (``SETUP``), begun by its entry point alone: in a
+    process whose ``main()`` never ran (a library user, a test) every
+    mark finds ``open`` false and returns, as it does after the close."""
+
+    def __init__(self):
+        self.open = False       # the one attribute a mark tests
+        self._begun = False
+        self._lock = threading.Lock()
+
+    def begin(self, role, phases, logger):
+        """``main()``'s first line: ``phases[0]`` (the interpreter's
+        start and the import chain) ends here, ``phases[1]`` opens.  A
+        second ``main()`` in one process begins nothing."""
+        with self._lock:
+            if self._begun:
+                return
+            self._begun = True
+            age, now = process_age(), time.perf_counter()
+            self.role, self._logger = role, logger
+            self._phases = tuple(phases)
+            self.t0 = time.time() - age
+            self.timing = Timing()
+            self.timing.observe("setup_" + phases[0], age)
+            self._seconds = {phases[0]: age}
+            self._counts = defaultdict(lambda: defaultdict(float))
+            self._index, self._at = 1, now
+            self.timing.start("setup_" + phases[1])
+            self.open = True
+
+    def mark(self, phase):
+        """``phase`` opens and the open one ends, at one instant.  A mark
+        of the open phase or of an earlier one (the second step's mark
+        of ``first_dispatch``) does nothing: phases only advance; one
+        that is never marked stays at 0."""
+        # elint: disable=EL001 -- the hot path's one test: a flag published by single assignment, tested again under the lock
+        if not self.open:
+            return
+        with self._lock:
+            # Another role's phase (a trainer built in a master's
+            # process) is not this timeline's.
+            index = (self._phases.index(phase)
+                     if phase in self._phases else -1)
+            if self.open and index > self._index:
+                self._advance_locked(index)
+
+    def _advance_locked(self, index):
+        now = time.perf_counter()
+        ended = self._phases[self._index]
+        self._seconds[ended] = now - self._at
+        self.timing.end("setup_" + ended)
+        self._index, self._at = index, now
+        if index < len(self._phases):
+            self.timing.start("setup_" + self._phases[index])
+
+    def add(self, **counts):
+        """Counts of the open phase (the compile listener's)."""
+        # elint: disable=EL001 -- as in mark(): tested again under the lock
+        if not self.open:
+            return
+        with self._lock:
+            if self.open:
+                bucket = self._counts[self._phases[self._index]]
+                for key, value in counts.items():
+                    bucket[key] += value
+
+    def close(self, into=None):
+        """End the open phase and say the whole: the line, the event and,
+        into ``into`` (the process's own ``Timing``), each phase's
+        seconds for its end-of-run report.  Returns the fields, or None
+        where the timeline is not open: a second close says nothing."""
+        with self._lock:
+            if not self.open:
+                return None
+            self._advance_locked(len(self._phases))
+            self.open = False
+            # Rounded first, so that the line's phases sum to its total.
+            seconds = {phase: round(self._seconds.get(phase, 0.0), 6)
+                       for phase in self._phases}
+            counts = {phase: dict(c) for phase, c in self._counts.items()}
+            role, logger, t0 = self.role, self._logger, self.t0
+        fields = {"t0": int(round(t0 * 1000))}
+        fields.update((phase + "_s", s) for phase, s in seconds.items())
+        fields["total_s"] = round(sum(seconds.values()), 6)
+        if counts:
+            first = counts.get("first_dispatch", {})
+            for key in _OF_FIRST_DISPATCH:
+                fields[key] = round(first.get(key, 0.0), 6)
+            for key in _OF_EVERY_PHASE:
+                fields[key] = int(sum(
+                    c.get(key, 0) for c in counts.values()))
+            fields["init_compile_or_load_s"] = round(counts.get(
+                "param_init", {}).get("compile_or_load_s", 0.0), 6)
+        logger.info("%s setup: %s", role, " ".join(
+            "%s=%s" % (key, "%.6f" % value if isinstance(value, float)
+                       else value) for key, value in fields.items()))
+        from elasticdl_tpu.utils import tracing
+
+        tracing.event(role + ".setup", **fields)
+        if into is not None:
+            for phase, s in seconds.items():
+                into.observe("setup_" + phase, s)
+        return fields
+
+
+SETUP = SetupTimeline()

@@ -644,9 +644,11 @@ def profilez_capture(secs, trace_dir=None, profiler=None,
     flight-recorder event, so a Perfetto profile links back to the
     /tracez trace that requested it (docs/observability.md).
 
-    ``profiler`` defaults to ``jax.profiler`` (injected by tests); a
-    missing/failing backend returns an error dict, never raises — this
-    runs on status-server request threads."""
+    ``profiler`` defaults to ``jax.profiler`` with the options of
+    ``timing.trace_options`` (an injected one, as tests do, is started
+    with the directory alone); a missing/failing backend returns an
+    error dict, never raises — this runs on status-server request
+    threads."""
     tracer = tracer or _TRACER
     secs = max(0.0, min(float(secs), _PROFILE_MAX_SECS))
     with _profile_lock:
@@ -657,10 +659,17 @@ def profilez_capture(secs, trace_dir=None, profiler=None,
         _profile_state["captures"] += 1
         n = _profile_state["captures"]
     try:
+        options = {}
         if profiler is None:
             import jax
 
+            from elasticdl_tpu.utils.timing import trace_options
+
+            # The options of every trace the program starts: JAX's
+            # defaults (Python tracer on, HLO protos in) make a trace of
+            # a training job too large to write out.
             profiler = jax.profiler
+            options = {"profiler_options": trace_options()}
         base = trace_dir or os.environ.get(ENV_TRACE_DIR) or "/tmp"
         role = tracer.process_attrs.get("role", "proc")
         out_dir = os.path.join(
@@ -668,7 +677,7 @@ def profilez_capture(secs, trace_dir=None, profiler=None,
         os.makedirs(out_dir, exist_ok=True)
         trace_id, span_id = tracer.current()
         tracer.event("profile.capture", dir=out_dir, secs=secs)
-        profiler.start_trace(out_dir)
+        profiler.start_trace(out_dir, **options)
         try:
             time.sleep(secs)
         finally:

@@ -29,7 +29,7 @@ from elasticdl_tpu.ops.batch_shard import DeviceRoom, batch_axis
 from elasticdl_tpu.utils import tracing
 from elasticdl_tpu.utils.logging import get_logger
 from elasticdl_tpu.utils.pytree import flatten_with_names, to_numpy
-from elasticdl_tpu.utils.timing import Timing
+from elasticdl_tpu.utils.timing import SETUP, Timing
 from elasticdl_tpu.worker.fused_driver import PreparedBatch, StagedWindow
 from elasticdl_tpu.worker.trainer import Trainer
 from elasticdl_tpu.worker.zero import ZeroPartitioner
@@ -205,11 +205,16 @@ class CollectiveTrainer(Trainer):
         # ``DeviceRoom`` stated: every later build states none left.
         self._room_refused = False
 
+        SETUP.mark("param_init")
         params = spec.init_fn(jax.random.PRNGKey(rng_seed))
         self._opt_state = spec.optimizer.init(params)
         self._params = params
         self._mesh = None
         self.rebuild(mesh)
+        if SETUP.open:
+            # The process's first step is set-up's: this one call sees
+            # its marks, every later one is the class's ``_run_step``.
+            self._run_step = self._first_run_step
 
     # -- mesh / jit management ---------------------------------------------
 
@@ -846,6 +851,19 @@ class CollectiveTrainer(Trainer):
         self._train_step = self._build_train_step()
         with self.timing.timeit("step_dispatch"):
             return self._program(window)(*args)
+
+    def _first_run_step(self, window, *batch):
+        """The first ``_run_step`` of a process still setting up: the
+        Python trace, the lowering and the compile or cache load of the
+        step (the ``remat keep:`` fallback's rebuild included) are the
+        timeline's ``first_dispatch``; what follows until the task's
+        fence is ``first_run``."""
+        del self._run_step
+        SETUP.mark("first_dispatch")
+        try:
+            return self._run_step(window, *batch)
+        finally:
+            SETUP.mark("first_run")
 
     def train_minibatch(self, features, labels):
         """One step; returns (loss, version) where ``loss`` is a LAZY

@@ -59,6 +59,7 @@ import numpy as np
 
 from elasticdl_tpu.utils import tracing
 from elasticdl_tpu.utils.logging import get_logger
+from elasticdl_tpu.utils.timing import SETUP
 
 logger = get_logger(__name__)
 
@@ -316,6 +317,7 @@ class FusedStepDriver:
                     # protocol can auto-complete the task (same strictness
                     # the per-step loop had via its per-step sync).
                     fetched = self._fence()
+                    SETUP.mark("first_report")
                 # Coalesced progress accounting: one report_batch_done RPC
                 # per fused window (counts buffered per batch, flushed at
                 # the window boundary — and, structurally, at task

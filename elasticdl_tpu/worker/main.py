@@ -6,6 +6,8 @@ flag overrides; the model comes from the zoo contract by module name.
 
 import contextlib
 import os
+import threading
+import time
 
 from elasticdl_tpu.data.factory import create_data_reader
 from elasticdl_tpu.models.spec import load_model_spec
@@ -19,6 +21,7 @@ from elasticdl_tpu.utils.device import (
     place_compile_cache,
 )
 from elasticdl_tpu.utils.logging import get_logger
+from elasticdl_tpu.utils.timing import SETUP, WORKER_SETUP
 from elasticdl_tpu.worker.collective_trainer import CollectiveTrainer
 from elasticdl_tpu.worker.master_client import MasterClient
 from elasticdl_tpu.worker.worker import Worker
@@ -289,7 +292,11 @@ def build_worker(args):
     return worker
 
 
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
 
 
 @contextlib.contextmanager
@@ -298,23 +305,63 @@ def xla_compiles_logged(steps_done):
     for as long as the block lasts: JAX reports one
     ``backend_compile_duration`` per fresh jit (a program loaded from the
     persistent cache included: the duration is then the load), and each
-    becomes one stamped log line, ``xla compile: secs=<s> step=<n>``.  A
-    compile after the first steps is the per-shape recompile that stalls
-    a step; counting new files in the cache directory misses every one
-    shorter than the persistent cache's minimum compile time.
-    ``steps_done()`` is the number of steps trained so far."""
+    becomes one stamped log line, ``xla compile: secs=<s> step=<n>
+    fun=<name> trace_s=<s> lower_s=<s> cache=hit|miss|off``.  A compile
+    after the first steps is the per-shape recompile that stalls a step;
+    counting new files in the cache directory misses every one shorter
+    than the persistent cache's minimum compile time.  ``steps_done()``
+    is the number of steps trained so far.
+
+    ``secs`` is the backend's part alone.  ``trace_s`` and ``lower_s``
+    are what JAX reported on the same thread since that thread's
+    previous program: the Python trace to a jaxpr (a jit traced inside
+    another's trace reports first and lies within the outer one's
+    seconds, so it is not counted again) and the lowering to MLIR.
+    ``cache`` is the persistent cache's word: ``hit`` (``secs`` is the
+    load), ``miss`` (compiled, and written for the next process) or
+    ``off`` (the cache keeps no entry of it: no directory, or a compile
+    under its minimum time).  While the set-up timeline is open the same
+    numbers are added up by its phase (utils/timing.SETUP)."""
     import jax
 
-    def on_duration(event, secs, fun_name="", **_):
-        if event == _COMPILE_EVENT:
-            logger.info("xla compile: secs=%.3f step=%d fun=%s", secs,
-                        steps_done(), fun_name)
+    class Building(threading.local):
+        """What a thread has heard of the program it is building."""
 
+        def __init__(self):
+            self.traces, self.lower_s, self.cache = [], 0.0, "off"
+
+    mine = Building()
+
+    def on_event(event, **_):
+        if event in _CACHE_EVENTS:
+            mine.cache = _CACHE_EVENTS[event]
+
+    def on_duration(event, secs, fun_name="", **_):
+        if event == _TRACE_EVENT:
+            began = time.time() - secs
+            mine.traces = [t for t in mine.traces if t[0] < began]
+            mine.traces.append((began, secs))
+        elif event == _LOWER_EVENT:
+            mine.lower_s += secs
+        elif event == _COMPILE_EVENT:
+            trace_s = sum(s for _, s in mine.traces)
+            logger.info(
+                "xla compile: secs=%.3f step=%d fun=%s trace_s=%.3f "
+                "lower_s=%.3f cache=%s", secs, steps_done(), fun_name,
+                trace_s, mine.lower_s, mine.cache)
+            SETUP.add(programs=1, trace_s=trace_s, lower_s=mine.lower_s,
+                      compile_or_load_s=secs,
+                      cache_hits=mine.cache == "hit",
+                      cache_misses=mine.cache == "miss")
+            mine.__init__()
+
+    jax.monitoring.register_event_listener(on_event)
     jax.monitoring.register_event_duration_secs_listener(on_duration)
     try:
         yield
     finally:
         jax.monitoring.unregister_event_duration_listener(on_duration)
+        jax.monitoring.unregister_event_listener(on_event)
 
 
 def device_line():
@@ -331,6 +378,8 @@ def device_line():
 
 
 def main(argv=None):
+    # The interpreter's start and this module's import chain end here.
+    SETUP.begin("worker", WORKER_SETUP, logger)
     import signal
 
     from elasticdl_tpu.worker.worker import PREEMPTED_EXIT_CODE
@@ -347,6 +396,7 @@ def main(argv=None):
     # parent to check (the master only sees the log and the exit code).
     logger.info("worker device: %s compile_cache=%s", device_line(),
                 cache_dir)
+    SETUP.mark("build")
     worker = None
     with xla_compiles_logged(
             lambda: worker.steps_done if worker is not None else 0):

@@ -15,7 +15,7 @@ from elasticdl_tpu.utils import hist as hist_mod
 from elasticdl_tpu.utils import tracing
 from elasticdl_tpu.utils.logging import get_logger
 from elasticdl_tpu.utils.retry import RetryPolicy
-from elasticdl_tpu.utils.timing import Timing
+from elasticdl_tpu.utils.timing import SETUP, Timing
 from elasticdl_tpu.worker.data_shard_service import DataShardService
 from elasticdl_tpu.worker.task_data_service import TaskDataService
 
@@ -544,6 +544,7 @@ class Worker:
                             # them all.
                             with timing.timeit("loss_sync"):
                                 float(loss)
+                            SETUP.mark("first_report")
                         with timing.timeit("progress_rpc"):
                             self._shard_service.report_batch_done(count)
                     if self._preempt_requested:
@@ -665,6 +666,7 @@ class Worker:
             self._run_traced()
 
     def _run_traced(self):
+        SETUP.mark("first_task_fetch")
         if self._join_rendezvous:
             self._mc.report_train_loop_status(pb.LOOP_START)
         try:
@@ -688,7 +690,11 @@ class Worker:
                         # because the job finished — checkpoint first.
                         raise PreemptedExit()
                     break
+                SETUP.mark("first_batch")
                 self._run_one_task(task)
+                if SETUP.open:
+                    # The first task is reported done: set-up is over.
+                    SETUP.close(into=self.timing)
         except PreemptedExit:
             self.preempted = True
             logger.warning(
@@ -711,4 +717,7 @@ class Worker:
                     logger.warning("trainer close failed: %s", e)
             if self._join_rendezvous:
                 self._mc.report_train_loop_status(pb.LOOP_END)
+            # A worker that ends before its first task is done says how
+            # far its set-up came.
+            SETUP.close(into=self.timing)
             self.timing.report()
