@@ -11,11 +11,14 @@ turns the names into ``save_only_these_names``.  The kept values are the
 ones the second forward would have produced, so the gradients are the
 same bits' worth of arithmetic, done once.
 
-The budget is what the caller's ``batch_shard.DeviceRoom`` leaves once
-``step_bytes`` (what this model's step needs with nothing kept) and a
-reserve of ``RESERVE`` of the limit are taken off.  No room stated (the
-CPU, a model-parallel mesh, the pipelined forward): nothing kept, the
-program ``jax.checkpoint(layer)`` always gave.
+An entry is taken when the peak predicted with it kept stays under the
+caller's ``batch_shard.DeviceRoom`` less a reserve of ``RESERVE`` of the
+limit: ``step_bytes`` is what this model's step needs beside the
+caller's state *with the entries chosen so far kept*, since a layer's
+backward does not make again the products it reads from the kept
+stack.  No room stated (the CPU, a model-parallel mesh, the pipelined
+forward): nothing kept, the program ``jax.checkpoint(layer)`` always
+gave.
 """
 
 import functools
@@ -43,6 +46,12 @@ RESERVE = 0.05
 
 # remat="attn": the first entry whatever the room.
 ATTN_NAMES = (flash_attention.KEEP_OUT, flash_attention.KEEP_LSE)
+
+# The entries of ``table`` that are products of a layer's own second
+# forward and stand in ``step_bytes``'s one-layer term: a dense FFN's
+# gate and up, an expert layer's gate, up and down products.
+DENSE_PRODUCTS = ("ffn_gate", "ffn_up")
+EXPERT_PRODUCTS = ("moe_out", "moe_gate", "moe_up")
 
 
 def _entries(cfg, rows):
@@ -132,67 +141,115 @@ def table(cfg, rows):
     return [entry[:3] for entry in _entries(cfg, rows)]
 
 
-def step_bytes(cfg, params, rows):
-    """Bytes one device needs for a training step of this model on
-    ``rows`` tokens with nothing kept, beside the state its caller
-    holds (parameters, optimizer state, gradients):
+def _weight_copies(layers, copy):
+    """Bytes of the stack's compute-dtype weight copies that stand at
+    once (``copy(tree)``: those of a tree's leaves).  A layer casts its
+    weights inside its checkpoint, so no copy is a saved residual; XLA
+    hoists the cast of a scan's stacked weights out of its loop, all
+    turns' at once, but a loop of one turn it unrolls, and a layer
+    outside a loop is cast where it is read: there the copies are the
+    running layer's and the next one's, which XLA fetches ahead (the
+    TPU compiler's buffer assignment of ``lfm2-24b-a2b``'s step: 0.3-0.5
+    GB of the five layers' 0.9 at the peak)."""
+    if "period" in layers:       # ``transformer.init_params``: a plan
+        period = list(layers["period"].values())
+        singles = list(layers["lead"].values()) + list(
+            layers["tail"].values())
+    else:
+        period, singles = [layers], []
+    turns = jax.tree_util.tree_leaves(period)[0].shape[0]
+    if turns > 1:
+        return copy(period) + sum(sorted(map(copy, singles))[-2:])
+    return sum(sorted(map(copy, singles + period))[-2:])
 
-     - the compute-dtype copies of the parameters (XLA hoists the
-       stack's ``astype`` out of the scan: all layers' at once);
+
+def step_bytes(cfg, params, rows, kept=()):
+    """Bytes one device needs for a training step of this model on
+    ``rows`` tokens with the entries labelled ``kept`` kept, beside
+    those entries and the state its caller holds (parameters, optimizer
+    state, gradients):
+
+     - the compute-dtype copies of the parameters (``_weight_copies``:
+       all of a scan's at once, two layers' where XLA unrolls);
      - the carries the scan saves, one stream a layer;
      - the larger of the two places the peak can be: the head
        (``ops/head_loss.py``: the logits, and their cotangent where the
        head is tied), while the stack's gradients, which the caller
        counted, do not exist yet; or one layer's backward with its
        second forward, of the layer kind that needs most (a leading
-       dense layer's beside the expert layers').
+       dense layer's beside the expert layers').  A kept product of
+       that layer's own is read from the scan's stack and not made
+       again, so it leaves the term: a dense layer's gate and up leave
+       their product and a cotangent; an expert layer's term, which
+       counts no cotangent, keeps half;
+     - less, at either place, what an untied embedding was counted
+       for: its copy is read by the forward's first gather alone and
+       its gradient is the last thing the backward makes.
 
-    Held to the compiler's own count for the three cells of the
-    benchmark (tests/test_remat_keep.py: +0.2 GB on the dense cells, whose
-    peak is at the head; +0.8 GB on the one-layer MoE, where the
-    compiler never holds all the gradients the caller counted)."""
+    Held to the chips' measured peaks for the five cells of the
+    benchmark (tests/test_remat_keep.py: -0.1 / +0.9 GB) and to the
+    TPU compiler's own count of the two share cells' whole steps
+    (tests/test_flash_compile_tpu.py: over, by under 0.5 GB)."""
     dtype = jnp.dtype(cfg.dtype)
     size = dtype.itemsize
     leaves = jax.tree_util.tree_leaves
-    copies = sum(a.size * size for a in leaves(params) if a.dtype != dtype)
-    stack_grads = sum(a.size * jnp.dtype(a.dtype).itemsize
-                      for a in leaves(params["layers"]))
+    nbytes = lambda a: a.size * jnp.dtype(a.dtype).itemsize
+    copy = lambda tree: sum(a.size * size * (a.dtype != dtype)
+                            for a in leaves(tree))
+    copies = (copy(params) - copy(params["layers"])
+              + _weight_copies(params["layers"], copy))
+    stack_grads = sum(nbytes(a) for a in leaves(params["layers"]))
     stream = rows * cfg.dim * size
     carries = (cfg.num_layers + 1) * stream
     head = rows * cfg.vocab_size * size * (2 if cfg.tied_embeddings else 1)
+    sizes = {label: per_layer
+             for label, _, per_layer, _ in _entries(cfg, rows)}
+    own = lambda labels: sum(sizes[label] for label in labels
+                             if label in kept)
     layer = 0
     if cfg.moe_experts:
-        layer = _routed_rows(cfg, rows)[0] * (
+        term = _routed_rows(cfg, rows)[0] * (
             cfg.dim + 2 * cfg.mlp_dim) * size
+        layer = max(term - own(EXPERT_PRODUCTS), term // 2)
     if any(kind.dense for kind in cfg.kinds):
         f = cfg.dense_ffn_dim if cfg.moe_experts else cfg.mlp_dim
-        layer = max(layer, rows * 4 * f * size)
-    return copies + carries + max(head - stack_grads, layer)
+        layer = max(layer, rows * 4 * f * size - own(DENSE_PRODUCTS))
+    embed = params["embed"]
+    unread = 0 if cfg.tied_embeddings else copy(embed) + nbytes(embed)
+    return copies + carries + max(head - stack_grads, layer) - unread
 
 
 def choose(cfg, params, rows, room):
-    """(names kept, their bytes a device, the budget, the peak predicted
-    with them) for ``rows`` tokens a device under ``room``."""
+    """(names kept, their bytes a device, the budget: what the room
+    leaves for them once the step's need with them kept and the reserve
+    are taken off, the peak predicted with them) for ``rows`` tokens a
+    device under ``room``."""
+    budget = lambda need: int(room.free - need - RESERVE * room.limit)
     need = step_bytes(cfg, params, rows)
-    budget = int(room.free - need - RESERVE * room.limit)
-    names, kept = [], 0
-    for _label, entry, per_layer, layers in _entries(cfg, rows):
+    labels, names, kept = [], [], 0
+    for label, entry, per_layer, layers in _entries(cfg, rows):
         nbytes = per_layer * layers
-        if kept + nbytes <= budget:
+        with_it = step_bytes(cfg, params, rows, labels + [label])
+        if kept + nbytes <= budget(with_it):
+            labels.append(label)
             names += entry
             kept += nbytes
-    return tuple(names), kept, budget, room.limit - room.free + need + kept
+            need = with_it
+    return (tuple(names), kept, budget(need),
+            room.limit - room.free + need + kept)
 
 
 @functools.lru_cache(maxsize=None)
-def announce_keep(names, kept, budget, peak, layers, rows, fallback):
+def announce_keep(names, kept, budget, need, peak, layers, rows,
+                  fallback):
     """Once per compiled shape, by the logger ``announce_tiles`` uses:
     what the layer stack keeps for its backward, of one shard of the
     trainer's data axis."""
     flash_attention.logger.info(
-        "remat keep: names=%s bytes=%d budget=%d predicted_peak=%d "
-        "layers=%d rows=%d fallback=%d", ",".join(names) or "-", kept,
-        budget, peak, layers, rows, fallback)
+        "remat keep: names=%s bytes=%d budget=%d need=%d "
+        "predicted_peak=%d layers=%d rows=%d fallback=%d",
+        ",".join(names) or "-", kept, budget, need, peak, layers, rows,
+        fallback)
 
 
 def names_for(cfg, params, tokens_shape):
@@ -203,6 +260,7 @@ def names_for(cfg, params, tokens_shape):
         return ()
     rows = tokens_shape[0] * tokens_shape[1] // batch_shard.shards()
     names, kept, budget, peak = choose(cfg, params, rows, room)
-    announce_keep(names, kept, budget, peak, cfg.num_layers, rows,
+    need = peak - kept - (room.limit - room.free)
+    announce_keep(names, kept, budget, need, peak, cfg.num_layers, rows,
                   int(not room.free))
     return names

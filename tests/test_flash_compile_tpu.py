@@ -318,18 +318,21 @@ def test_the_banded_stacks_step_fits_a_v5e_as_remat_keep_predicts(
     attention layers, heads x head size 3,584 over a hidden 2,560, 16 of
     64 ReGLU experts at 6 a token, an untied head over 37,984 ids,
     AdamW) through the TPU's compiler with what ``remat_keep`` chose
-    kept: its predicted peak is over the compiler's own byte count,
-    never under, and under the device's limit less the reserve (15.69
-    GB against the compiler's 14.62; with nothing kept the estimate,
-    12.64, is the compiler's 12.67 to 0.03).  Both kinds of flash call
-    are in the one program, and no forward runs twice."""
+    kept (every entry of its table since the step's need counts a
+    layer's kept products once and an unrolled stack's weight copies
+    two layers at a time: the sorted rows too, 4.06 GB in all): its
+    predicted peak is over the compiler's own byte count, never
+    under, and under the device's limit less the reserve (15.45 GB
+    against the compiler's 15.28; PR 35's eleven names read 15.69
+    against 14.39).  Both kinds of flash call are in the one program,
+    and no forward runs twice."""
     import json
     import os
 
     import optax
 
     from elasticdl_tpu.models import remat_keep as rk
-    from elasticdl_tpu.ops import batch_shard
+    from elasticdl_tpu.ops import batch_shard, moe_dispatch
     from elasticdl_tpu.ops.mode import SWITCH
 
     monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
@@ -353,7 +356,8 @@ def test_the_banded_stacks_step_fits_a_v5e_as_remat_keep_predicts(
     room = batch_shard.DeviceRoom(limit, limit - held)
     names, kept, budget, peak = rk.choose(spec.config, params, rows, room)
     assert set(names) >= set(rk.ATTN_NAMES) | {
-        rk.KEEP_Q, rk.KEEP_K, rk.KEEP_V, rk.KEEP_STREAM}, names
+        rk.KEEP_Q, rk.KEEP_K, rk.KEEP_V, rk.KEEP_STREAM,
+        moe_dispatch.KEEP_ROWS}, names
     assert kept <= budget and peak <= (1 - rk.RESERVE) * limit
 
     def step(params, state, tokens):
@@ -370,7 +374,7 @@ def test_the_banded_stacks_step_fits_a_v5e_as_remat_keep_predicts(
         on_chip(params), on_chip(state), tokens).compile()
     stats = compiled.memory_analysis()
     counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
-    assert counted < peak and peak - counted < 1.5e9, (peak, counted)
+    assert counted < peak and peak - counted < 0.5e9, (peak, counted)
     calls = [l.split(" = ")[0].strip().lstrip("%")
              for l in compiled.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in l]
@@ -390,20 +394,26 @@ def test_the_mixed_stacks_step_fits_a_v5e_as_remat_keep_predicts(
     TPU's compiler: ``remat_keep``'s estimate with nothing kept, and its
     predicted peak with what it chose, are held to the compiler's own
     byte count (arguments + temporaries; the updated state aliases the
-    donated one): over, never under.  Nothing kept: 12.34 GB against
-    the compiler's 10.30 (+2.04: it never holds all the gradients the
-    trainer counted, a layer's AdamW update runs behind its backward;
-    +0.84 at depth 1 of olmoe1b7b; it counted 10.93 while the dispatch
-    moved all 131,072 rows a layer, and the estimate's larger term, the
-    dense layer's backward, did not move with them).  With the names
-    chosen, 3.53 GB of them: 15.87 against 13.51 (+2.36)."""
+    donated one): over, never under.  Nothing kept: 11.80 GB against
+    the compiler's 10.34 (+1.47: it never holds all the gradients the
+    trainer counted, a layer's AdamW update runs behind its backward).
+    With the names chosen, the convolutions' input and the experts' up
+    product among them, 5.55 GB: 15.81 against 15.41 (+0.40, inside
+    -0.1 / +0.5; the parent read 15.87 against 13.43 with 3.53 GB kept:
+    the dense layer's kept gate and up stood in the need as well, and
+    five layers' weight copies where two stand at once).  What is left
+    over is not a term of the estimate's but their sum: by the buffer
+    assignment the peak is in the first expert layer back-propagated,
+    where no gradient of the stack exists yet (1.8 GB counted) and the
+    dispatch's temporaries and the tied head's cotangent (2.8 GB) stand
+    where the estimate has the dense layer's 1.54: PERF.md section 7."""
     import json
     import os
 
     import optax
 
     from elasticdl_tpu.models import remat_keep as rk
-    from elasticdl_tpu.ops import batch_shard
+    from elasticdl_tpu.ops import batch_shard, moe_dispatch, short_conv
     from elasticdl_tpu.ops.mode import SWITCH
 
     monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
@@ -441,13 +451,14 @@ def test_the_mixed_stacks_step_fits_a_v5e_as_remat_keep_predicts(
 
     estimate = held + rk.step_bytes(spec.config, params, 32768)
     nothing_kept = compiled(None)
-    assert -0.1e9 < estimate - nothing_kept < 2.2e9, (
+    assert -0.1e9 < estimate - nothing_kept < 1.6e9, (
         estimate, nothing_kept)
     room = batch_shard.DeviceRoom(limit, limit - held)
     names, kept, budget, peak = rk.choose(spec.config, params, 32768, room)
-    assert names and kept <= budget and peak == estimate + kept
-    with_names = compiled(room)
-    assert with_names < peak <= (1 - rk.RESERVE) * limit
-    assert peak - with_names < 2.6e9, (peak, with_names, names)
+    assert kept <= budget
     assert set(names) >= set(rk.ATTN_NAMES) | {
-        rk.KEEP_STREAM, rk.KEEP_GATE, rk.KEEP_UP}, names
+        rk.KEEP_STREAM, rk.KEEP_GATE, rk.KEEP_UP, short_conv.KEEP_IN,
+        moe_dispatch.KEEP_UP}, names
+    with_names = compiled(room)
+    assert peak <= (1 - rk.RESERVE) * limit
+    assert -0.1e9 < peak - with_names < 0.5e9, (peak, with_names, names)

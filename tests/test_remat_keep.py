@@ -1,9 +1,9 @@
 """``remat=True`` keeps, by name, the residuals that fit
 (models/remat_keep.py): the kept values change no gradient, the flash
 forward leaves the backward when its two results are kept, the choice
-follows the stated room per shard, the estimate is held to the three
-cells' measured peaks, and a refused compile falls back to nothing
-kept."""
+follows the stated room per shard, what the step needs shrinks by the
+kept products of a layer's own, the estimate is held to the five cells'
+measured peaks, and a refused compile falls back to nothing kept."""
 
 import dataclasses
 import json
@@ -18,7 +18,8 @@ import pytest
 from jax.sharding import Mesh
 
 from elasticdl_tpu.models import remat_keep as rk, transformer as tfm
-from elasticdl_tpu.ops import flash_attention as fa, moe_dispatch as md
+from elasticdl_tpu.ops import (flash_attention as fa, moe_dispatch as md,
+                               short_conv as sc)
 from elasticdl_tpu.ops.batch_shard import DeviceRoom, batch_axis
 from elasticdl_tpu.worker import collective_trainer as ct
 
@@ -50,10 +51,13 @@ def _problem(moe, **kw):
 
 
 def _room_for(cfg, params, entries):
-    """A room whose budget is exactly the first ``entries`` of the table."""
-    kept = sum(b for _, _, b in rk.table(cfg, ROWS)[:entries])
-    need = rk.step_bytes(cfg, params, ROWS) + kept * cfg.num_layers
-    return DeviceRoom(GB, int(need + rk.RESERVE * GB))
+    """A room whose budget is exactly the first ``entries`` of the table
+    (the step's need is the need with those kept)."""
+    first = rk.table(cfg, ROWS)[:entries]
+    kept = sum(b for _, _, b in first)
+    need = rk.step_bytes(cfg, params, ROWS, [label for label, _, _ in first])
+    return DeviceRoom(GB, int(need + kept * cfg.num_layers
+                              + rk.RESERVE * GB))
 
 
 def _pallas_results(jaxpr, found=None):
@@ -203,47 +207,226 @@ def test_an_entry_that_does_not_fit_is_passed_over_not_the_rest():
     assert names == rk.ATTN_NAMES + (rk.KEEP_STREAM,)
 
 
-# cell -> (configuration, rows a step, chips, the ledger's nothing-kept
-# ``trainer.peak_hbm_gb`` (PR 28), the names PR 29 reports it keeps)
 ATTENTION = rk.ATTN_NAMES + (rk.KEEP_Q, rk.KEEP_K, rk.KEEP_V,
                              rk.KEEP_STREAM)
+ROUTED = rk.ATTN_NAMES + (rk.KEEP_ROUTE, md.KEEP_SORT) + ATTENTION[2:]
+SHARED = ["flash", "route", "qkv", "stream"]
+# cell -> (configuration, rows a step, chips, a ``trainer.peak_hbm_gb``
+# of the ledger, the entries kept when it was measured: none in PR 28's
+# three, PR 35's lists in the two share cells; the names this tree
+# keeps)
 CELLS = {
-    "olmo1b.seq2048": ("olmo1b", 8, 1, 12.035, ATTENTION),
-    "olmo1b.seq2048-dp4": ("olmo1b", 32, 4, 11.992, ATTENTION),
+    "olmo1b.seq2048": ("olmo1b", 8, 1, 12.035, [], ATTENTION),
+    "olmo1b.seq2048-dp4": ("olmo1b", 32, 4, 11.992, [], ATTENTION),
     "olmoe1b7b.seq4096": (
-        "olmoe1b7b", 4, 1, 11.628,
-        rk.ATTN_NAMES + (rk.KEEP_ROUTE, md.KEEP_SORT) + ATTENTION[2:]
-        + (md.KEEP_OUT, md.KEEP_GATE, md.KEEP_UP, md.KEEP_ROWS)),
+        "olmoe1b7b", 4, 1, 11.628, [],
+        ROUTED + (md.KEEP_OUT, md.KEEP_GATE, md.KEEP_UP, md.KEEP_ROWS)),
+    "lfm2-24b-a2b.seq8192": (
+        "lfm2-24b-a2b", 4, 1, 13.520,
+        SHARED + ["ffn_gate", "ffn_up", "moe_out", "moe_gate"],
+        ROUTED + (rk.KEEP_GATE, rk.KEEP_UP, sc.KEEP_IN, md.KEEP_OUT,
+                  md.KEEP_GATE, md.KEEP_UP)),
+    "smallthinker-21b-a3b.seq16384": (
+        "smallthinker-21b-a3b", 1, 1, 14.514,
+        SHARED + ["moe_out", "moe_gate", "moe_up"],
+        ROUTED + (md.KEEP_OUT, md.KEEP_GATE, md.KEEP_UP, md.KEEP_ROWS)),
 }
 
 
-@pytest.mark.parametrize("cell", sorted(CELLS))
-def test_the_estimate_is_held_to_the_cells_measured_peaks(cell):
-    """The three cells at their real shapes, no arrays: what the trainer
-    would state (16 B a parameter) and what the model adds lands within
-    -0.1 / +0.9 GB of the peak the chip measured with nothing kept (over,
-    never under: +0.23, +0.27 and, at depth 1, +0.84), picks the names
-    the PR reports, and predicts a peak under the limit less the
-    reserve."""
-    config, batch, chips, measured, names = CELLS[cell]
+# tokens a chip a step in each configuration's cells
+ROWS_OF = {"olmo1b": 16384, "olmoe1b7b": 16384, "lfm2-24b-a2b": 32768,
+           "smallthinker-21b-a3b": 16384}
+
+
+def _cell(config, **override):
+    """(cfg, shapes of the parameters, what the trainer would state it
+    holds: 16 B a parameter, the sequence length) of a configuration of
+    the benchmark, no arrays."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "configs",
                            config + ".json")) as fh:
         model_params = json.load(fh)["cli"]["model_params"]
-    spec = tfm.model_spec(**model_params)
-    cfg = spec.config
+    spec = tfm.model_spec(**dict(model_params, **override))
     params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
     state = jax.eval_shape(spec.optimizer.init, params)
     held = 2 * ct._device_bytes(params) + ct._device_bytes(state)
-    rows = batch * model_params["seq_len"] // chips
-    assert rows == 16384
-    estimate = held + rk.step_bytes(cfg, params, rows)
+    return spec.config, params, held, model_params["seq_len"]
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_the_estimate_is_held_to_the_cells_measured_peaks(cell):
+    """The five cells at their real shapes, no arrays: what the trainer
+    would state and what the model adds, with the entries kept that
+    were kept when the chip measured, lands within -0.1 / +0.9 GB of
+    that peak (over, never under: +0.23, +0.27 and, at depth 1, +0.22
+    with nothing kept; +0.81 and +0.39 with PR 35's lists in the two
+    share cells, whose estimates read +2.35 and +1.17 while a layer's
+    kept products were counted twice), picks the names the PR reports,
+    and predicts a peak under the limit less the reserve."""
+    config, batch, chips, measured, labels, names = CELLS[cell]
+    cfg, params, held, seq_len = _cell(config)
+    rows = batch * seq_len // chips
+    assert rows == ROWS_OF[config]
+    then = sum(b * n for label, _, b, n in rk._entries(cfg, rows)
+               if label in labels)
+    estimate = held + rk.step_bytes(cfg, params, rows, labels) + then
     assert -0.1 < estimate / GB - measured < 0.9
     room = DeviceRoom(V5E_LIMIT, V5E_LIMIT - held)
     got, kept, budget, peak = rk.choose(cfg, params, rows, room)
     assert got == names
-    assert kept <= budget and peak == estimate + kept
+    chosen = [label for label, entry, _ in rk.table(cfg, rows)
+              if set(entry) <= set(got)]
+    assert kept <= budget
+    assert peak == held + rk.step_bytes(cfg, params, rows, chosen) + kept
     assert peak <= (1 - rk.RESERVE) * V5E_LIMIT
+
+
+def test_a_dense_layers_kept_products_leave_what_the_step_needs():
+    """``lfm2-24b-a2b`` at its cell's rows: the leading dense layer's
+    backward (gate, up, their product and a cotangent, 4 x 0.772 GB)
+    is the larger place.  Kept, gate and up are read from the stack
+    and not made again: each lowers the need by its bytes, and no
+    entry that is not a product of that layer does."""
+    cfg, params, _, _ = _cell("lfm2-24b-a2b")
+    rows = 32768
+    one = rows * cfg.dense_ffn_dim * 2
+    need = lambda *kept: rk.step_bytes(cfg, params, rows, kept)
+    assert need() - need("ffn_gate") == one == 771751936
+    assert need() - need("ffn_gate", "ffn_up") == 2 * one
+    for label, _, _ in rk.table(cfg, rows):
+        if label not in rk.DENSE_PRODUCTS:
+            assert need(label) == need(), label
+    # the dense layer's term still stands over the expert layers'
+    assert need("ffn_gate", "ffn_up", "moe_out") == need("ffn_gate",
+                                                         "ffn_up")
+
+
+def test_the_need_does_not_fall_below_the_next_kinds_term():
+    """A dense layer narrow enough that, its products kept, an expert
+    layer's backward is the larger place: the need stops at that term;
+    and an expert layer's own kept products leave its term no lower
+    than half (the term counts no cotangent)."""
+    cfg, params, _, _ = _cell("lfm2-24b-a2b")
+    cfg = dataclasses.replace(cfg, dense_ffn_dim=2048)
+    rows = 32768
+    need = lambda *kept: rk.step_bytes(cfg, params, rows, kept)
+    dense = rows * 4 * 2048 * 2
+    experts = md.row_bound(rows * 4, 8, 64) * (2048 + 2 * 1536) * 2
+    assert dense // 2 < experts < dense
+    assert need() - need("ffn_gate", "ffn_up") == dense - experts
+    # the experts' products kept too: the dense layer's product and
+    # cotangent stand over half the experts' term
+    assert need() - need("ffn_gate", "ffn_up", *rk.EXPERT_PRODUCTS) == (
+        dense - max(dense // 2, experts // 2))
+    # where every layer has experts the term is theirs alone
+    cfg, params, _, _ = _cell("olmoe1b7b")
+    term = 16384 * 8 * (2048 + 2 * 1024) * 2
+    need = lambda *kept: rk.step_bytes(cfg, params, 16384, kept)
+    assert need() - need("moe_gate") == 16384 * 8 * 1024 * 2
+    assert need() - need(*rk.EXPERT_PRODUCTS) == term // 2
+
+
+def test_an_untied_embedding_is_counted_at_neither_place():
+    """Its compute-dtype copy is read by the forward's first gather
+    alone and its gradient is the last thing the backward makes: 6 B a
+    weight that ``copies`` and the trainer counted; a tied one is the
+    head's weight and stays."""
+    for config, tied in (("olmoe1b7b", False), ("olmo1b", True)):
+        cfg, params, _, _ = _cell(config)
+        assert cfg.tied_embeddings == tied
+        wider = jax.tree_util.tree_map(lambda a: a, params)
+        wider["embed"] = jax.ShapeDtypeStruct(
+            (2 * cfg.vocab_size, cfg.dim), params["embed"].dtype)
+        more = (rk.step_bytes(cfg, wider, 16384)
+                - rk.step_bytes(cfg, params, 16384))
+        assert more == (2 * cfg.vocab_size * cfg.dim if tied else
+                        -4 * cfg.vocab_size * cfg.dim)
+
+
+@pytest.mark.parametrize("config,layers,standing", [
+    ("olmo1b", 7, 7), ("olmoe1b7b", 1, 1), ("lfm2-24b-a2b", 5, 2),
+    ("smallthinker-21b-a3b", 4, 2)])
+def test_the_weight_copies_that_stand_at_once(config, layers, standing):
+    """A scan of several turns has all its layers' compute-dtype copies
+    at once (XLA hoists the cast out of the loop: ``olmo1b``'s seven);
+    a stack XLA unrolls (one layer, or leading layers and one period)
+    has the two largest layers': the one running and the one fetched
+    ahead."""
+    cfg, params, _, _ = _cell(config)
+    assert cfg.num_layers == layers
+    size = jnp.dtype(cfg.dtype).itemsize
+    leaves = jax.tree_util.tree_leaves
+    copy = lambda tree: sum(a.size * size for a in leaves(tree))
+    stack = params["layers"]
+    if "period" in stack:
+        each = [copy(layer) for group in ("lead", "period", "tail")
+                for layer in stack[group].values()]
+    else:
+        each = [copy(stack) // layers] * layers
+    assert len(each) == layers
+    want = sum(sorted(each)[-standing:])
+    assert rk._weight_copies(stack, copy) == want
+    # parameters already in the compute dtype have no copies
+    none = lambda tree: 0
+    assert rk._weight_copies(stack, none) == 0
+
+
+# (bytes kept, budget, predicted peak) of the parent's ``remat keep:``
+# line at the cell's shapes
+TODAY = {
+    "olmo1b": (2356150272, 3800754581, 14621257732),
+    "olmoe1b7b": (1953497344, 3596798357, 14422561028),
+}
+
+
+@pytest.mark.parametrize("config", sorted(TODAY))
+def test_the_older_cells_keep_what_they_kept(config):
+    """``olmo1b`` keeps no product of its layers' own and its peak is at
+    the head: bytes, budget and predicted peak are the parent's to the
+    byte (``ffn_gate``, 1.879 GB, must not fit: the chip would stand at
+    16.35 GB).  ``olmoe1b7b`` kept every entry and keeps them: the same
+    names and bytes; its predicted peak falls by half its layer's term
+    and its untied embedding's 6 B a weight, and stays over the 12.083
+    GB the chip measured (ledger, PR 35)."""
+    cfg, params, held, _ = _cell(config)
+    room = DeviceRoom(V5E_LIMIT, V5E_LIMIT - held)
+    names, kept, budget, peak = rk.choose(cfg, params, 16384, room)
+    assert kept == TODAY[config][0]
+    if config == "olmo1b":
+        assert (kept, budget, peak) == TODAY[config]
+        assert rk.KEEP_GATE not in names
+        return
+    assert names == sum((n for _, n, _ in rk.table(cfg, 16384)), ())
+    term = 16384 * 8 * (2048 + 2 * 1024) * 2
+    unread = 6 * cfg.vocab_size * cfg.dim
+    assert TODAY[config][2] - peak == term // 2 + unread
+    assert peak > 12.083 * GB
+
+
+@pytest.mark.parametrize("config", ["olmo1b", "olmoe1b7b", "lfm2-24b-a2b",
+                                    "smallthinker-21b-a3b"])
+@pytest.mark.parametrize("share", [1.0, 0.9, 0.8])
+def test_no_predicted_peak_passes_the_limit_less_the_reserve(config, share):
+    """Every configuration of the benchmark, at the chip's limit and at
+    smaller ones (another process's share, a smaller chip): what is
+    chosen never predicts a peak over ``(1 - RESERVE) * limit`` while
+    the state and the step with nothing kept fit under it, and a list
+    is never longer under less room."""
+    cfg, params, held, _ = _cell(config)
+    rows = ROWS_OF[config]
+    limit = int(share * V5E_LIMIT)
+    names, kept, budget, peak = rk.choose(
+        cfg, params, rows, DeviceRoom(limit, limit - held))
+    fits = held + rk.step_bytes(cfg, params, rows) <= (
+        1 - rk.RESERVE) * limit
+    assert fits == (budget >= 0)
+    if fits:
+        assert peak <= (1 - rk.RESERVE) * limit and kept <= budget
+    else:
+        assert names == ()
+    whole = rk.choose(cfg, params, rows,
+                      DeviceRoom(V5E_LIMIT, V5E_LIMIT - held))[0]
+    assert set(names) <= set(whole)
 
 
 @pytest.mark.parametrize("held", [8, 64])
@@ -252,16 +435,12 @@ def test_the_routed_entries_have_the_bounds_rows(held):
     4 choices over 64 experts.  With 8 held the dispatch's four entries
     have ``row_bound``'s 32,768 rows and go by half their worth (a
     balanced router fills half the bound), so the list the chip's room
-    takes ends in the down product and the gate product where the
-    whole-buffer entries, four times the bytes, fitted none; with all
-    64 held they have every row and their whole worth."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "lfm2-24b-a2b.json")) as fh:
-        model_params = json.load(fh)["cli"]["model_params"]
-    assert model_params["moe_experts_held"] == 8
-    spec = tfm.model_spec(**dict(model_params, moe_experts_held=held))
-    cfg = spec.config
+    takes holds the convolution's input and ends in the down, gate and
+    up products where the whole-buffer entries, four times the bytes,
+    fitted none; with all 64 held they have every row and their
+    whole worth."""
+    cfg, params, used, _ = _cell("lfm2-24b-a2b", moe_experts_held=held)
+    assert _cell("lfm2-24b-a2b")[0].experts_held[1] == 8
     rows, k, e, f = 4 * 8192, 4, 2048, 1536
     bound = md.row_bound(rows * k, held, 64)
     assert bound == (32768 if held == 8 else rows * k)
@@ -278,16 +457,13 @@ def test_the_routed_entries_have_the_bounds_rows(held):
          "conv_out", "moe_rows"] if held == 8 else
         ["moe_out", "moe_gate", "moe_up", "ffn_gate", "ffn_up", "conv_in",
          "moe_rows", "conv_out"])
-    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
-    state = jax.eval_shape(spec.optimizer.init, params)
-    used = 2 * ct._device_bytes(params) + ct._device_bytes(state)
     if held == 64:
         assert used > V5E_LIMIT       # no chip holds all 64 of a layer
         return
     names, kept, budget, peak = rk.choose(
         cfg, params, rows, DeviceRoom(V5E_LIMIT, V5E_LIMIT - used))
-    assert names == ATTENTION[:2] + (rk.KEEP_ROUTE, md.KEEP_SORT) + \
-        ATTENTION[2:] + (rk.KEEP_GATE, rk.KEEP_UP, md.KEEP_OUT, md.KEEP_GATE)
+    assert names == ROUTED + (rk.KEEP_GATE, rk.KEEP_UP, sc.KEEP_IN,
+                              md.KEEP_OUT, md.KEEP_GATE, md.KEEP_UP)
     assert kept <= budget and peak <= (1 - rk.RESERVE) * V5E_LIMIT
 
 
