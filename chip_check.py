@@ -54,6 +54,11 @@ TOLERANCES = {
     "fwd": _BF16_FWD, "o": _BF16_FWD,
     "dq": _BF16_GRAD, "dk": _BF16_GRAD, "dv": _BF16_GRAD,
     "dx": _BF16_GRAD,
+    # latent attention's RoPE parts; the one key's gradient against the
+    # sum of the parts the same kernel writes a head (bf16 roundings of
+    # float32 partials on both sides)
+    "dq_rope": _BF16_GRAD, "dk_rope": _BF16_GRAD,
+    "dk_rope_sum": _BF16_FWD,
     # grouped matmul: bf16 results of float32 sums over the same bf16
     # values, so one rounding of the result is all that may differ.
     "dlhs": _BF16_FWD, "drhs": _BF16_FWD,
@@ -184,6 +189,85 @@ def check_flash(b, h, t, d, window, interpret, ref_slice=(1, 2)):
                                           window=window),
         loss, _qkv(b, h, t, d, seed=t + d + window), ref_slice)
     return {"fwd": _rel_err(out[:sb, :sh], want), **errs}
+
+
+def check_latent(b, h, t, interpret, widths=(128, 64, 128), ref_slice=2):
+    """``latent_attention`` (scores over D_nope + D_rope, values of
+    D_v, ONE RoPE key a sequence) at the full shape against the jnp
+    math, a head's key put together the long way, on the first
+    ``ref_slice`` heads of the same values; the RoPE key's gradient is
+    the sum over every head, so it is held to the sum of the float32
+    parts the dk-dv kernel writes a head (``_pallas_bwd``'s last
+    result), and the first heads' parts to the reference's with the key
+    spread to the heads.  Where there are
+    more chips than one and ``b`` is a multiple of them, the kernel
+    runs a sequence a chip, its operands sharded over the chips under
+    the trainer's ``batch_axis`` (``ops/batch_shard.per_batch_shard``)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from elasticdl_tpu.ops.batch_shard import batch_axis, per_batch_shard
+
+    dn, dr, dv = widths
+    rng = np.random.RandomState(t + dn + dr)
+    draw = lambda *shape: jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+    args = (draw(b, h, t, dn), draw(b, h, t, dr), draw(b, h, t, dn),
+            draw(b, t, dr), draw(b, h, t, dv))
+    w = draw(b, h, t, dv)
+    scale = (dn + dr) ** -0.5
+    every = tuple(range(5))
+
+    def loss(fn):
+        return lambda *a: (fn(*a[:5]).astype(jnp.float32)
+                           * a[5].astype(jnp.float32)).sum()
+
+    chips = jax.device_count()
+    mesh = Mesh(np.array(jax.devices()), ("data",)) if (
+        chips > 1 and b % chips == 0) else None
+
+    def kernel(*a):
+        with batch_axis(mesh, "data"):
+            return fa.latent_attention(*a, interpret=interpret)
+
+    def head_parts(q_nope, q_rope, k_nope, k_rope, v, g):
+        def one(q_nope, q_rope, k_nope, k_rope, v, g):
+            static = (True, scale, bool(interpret))
+            _, res = fa._latent_fwd(q_nope, q_rope, k_nope, k_rope, v,
+                                    *static)
+            return fa._pallas_bwd(q_nope, k_nope, v, *res[5:], g, *static,
+                                  rope=(q_rope, k_rope))[4]
+
+        with batch_axis(mesh, "data"):
+            return per_batch_shard(
+                one, (q_nope, q_rope, k_nope, k_rope, v, g))
+
+    def jit(fn):     # a sequence a chip where a mesh is
+        return jax.jit(fn) if mesh is None else jax.jit(
+            fn, in_shardings=NamedSharding(mesh, P("data")))
+
+    out = jit(kernel)(*args)
+    grads = jit(jax.grad(loss(kernel), every))(*args, w)
+    parts = jit(head_parts)(*args, w)
+    sh = ref_slice
+    corner = tuple(a if a.ndim == 3 else a[:, :sh] for a in _f32(*args, w))
+    corner = corner[:3] + (jnp.broadcast_to(
+        corner[3][:, None], corner[1].shape),) + corner[4:]
+    # the key spread to the corner's heads, so that the reference's
+    # gradient of it is a head's own part
+    ref = lambda q_nope, q_rope, k_nope, k_spread, v: fa._attention_ref(
+        jnp.concatenate([q_nope, q_rope], axis=-1),
+        jnp.concatenate([k_nope, k_spread], axis=-1), v, True, scale)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(ref)(*corner[:5])
+        want_grads = jax.jit(jax.grad(loss(ref), every))(*corner)
+    errs = {"fwd": _rel_err(out[:, :sh], want)}
+    for name, g, wg in zip(("dq", "dq_rope", "dk", "dk_rope", "dv"),
+                           grads, want_grads):
+        if name == "dk_rope":     # a head's part, then the heads' sum
+            errs["dk_rope"] = _rel_err(parts[:, :sh], wg)
+            errs["dk_rope_sum"] = _rel_err(g, parts.sum(axis=1))
+        else:
+            errs[name] = _rel_err(g[:, :sh], wg)
+    return errs
 
 
 def check_grouped_matmul(rows, k, n, groups, skew, interpret):
@@ -700,6 +784,15 @@ def _cases(tiny):
             yield ("flash/B%d.H%d.T%d.D%d.window%d" % (b, h, t, d, window),
                    lambda d=d, window=window: check_flash(
                        b, h, t, d, window, interpret))
+    # kanana-2-30b-a3b's attention (benchmark/configs/kanana-2-30b-a3b
+    # .json): 32 heads, scores over 128 + 64, values of 128; at its
+    # cell's 16,384 positions and at 4,096, two sequences on one chip
+    # and a sequence a chip where there are more
+    for lb, lh, lt in ((1, 2, 256),) if tiny else (
+            (1, 32, 16384), (max(2, jax.device_count()), 32, 4096)):
+        yield ("latent/B%d.H%d.T%d.QK192.V128" % (lb, lh, lt),
+               lambda lb=lb, lh=lh, lt=lt: check_latent(
+                   lb, lh, lt, interpret, ref_slice=1 + (lt < 16384)))
     for causal in (True, False):
         yield ("flash_partial/B%d.H%d.T%d.D64.causal%d" % (b, h, t, causal),
                lambda causal=causal: check_flash_partial(

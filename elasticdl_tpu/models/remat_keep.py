@@ -35,9 +35,14 @@ from elasticdl_tpu.ops import (batch_shard, flash_attention, moe_dispatch,
 # a dense FFN's two products.  A short convolution's are the op's:
 # its input (the projection's output) and its result.
 KEEP_Q, KEEP_K, KEEP_V = "attn_q", "attn_k", "attn_v"
+# Latent attention's: the down-projection's result (the latent before
+# its norm, and the one RoPE key before RoPE), and the k_nope and v a
+# matmul makes from it for every head.
+KEEP_LATENT, KEEP_KV = "attn_latent", "attn_kv"
 KEEP_STREAM = "attn_stream"
 KEEP_ROUTE = "moe_route"
 KEEP_GATE, KEEP_UP = "ffn_gate", "ffn_up"
+KEEP_SHARED_GATE, KEEP_SHARED_UP = "shared_gate", "shared_up"
 
 # The share of the device's limit nothing is planned into: the
 # allocator's fragmentation, the batches in flight, whatever
@@ -51,6 +56,7 @@ ATTN_NAMES = (flash_attention.KEEP_OUT, flash_attention.KEEP_LSE)
 # forward and stand in ``step_bytes``'s one-layer term: a dense FFN's
 # gate and up, an expert layer's gate, up and down products.
 DENSE_PRODUCTS = ("ffn_gate", "ffn_up")
+SHARED_PRODUCTS = (KEEP_SHARED_GATE, KEEP_SHARED_UP)
 EXPERT_PRODUCTS = ("moe_out", "moe_gate", "moe_up")
 
 
@@ -64,6 +70,9 @@ def _entries(cfg, rows):
     conv = len(kinds) - attention
     dense = sum(kind.dense for kind in kinds)
     experts = len(kinds) - dense
+    latent = cfg.latent
+    if latent:
+        rank, d_nope, d_rope, d = latent    # d: a value head's, out's
     entries = [
         ("flash", ATTN_NAMES, rows * h * (d * size + 4), attention),
     ]
@@ -74,11 +83,21 @@ def _entries(cfg, rows):
     sorts = 3 if cfg.moe_experts_held else 4
     entries.append(("route", (KEEP_ROUTE, moe_dispatch.KEEP_SORT),
                     4 * (rows * (x + sorts * k) + x), experts))
-    entries += [
-        ("qkv", (KEEP_Q, KEEP_K, KEEP_V), rows * (h + 2 * g) * d * size,
-         attention),
-        ("stream", (KEEP_STREAM,), rows * e * size, len(kinds)),
-    ]
+    if latent:
+        # the latent first: [rows, rank + d_rope], a fourteenth of the
+        # k_nope and v that one matmul makes from it again
+        entries += [
+            ("latent", (KEEP_LATENT,), rows * (rank + d_rope) * size,
+             attention),
+            # as the kernels take it: the RoPE part a plane of its own,
+            # its minor dimension tiled to the 128 lanes in HBM
+            ("q", (KEEP_Q,), rows * h * (d_nope + _lanes(d_rope)) * size,
+             attention),
+        ]
+    else:
+        entries.append(("qkv", (KEEP_Q, KEEP_K, KEEP_V),
+                        rows * (h + 2 * g) * d * size, attention))
+    entries.append(("stream", (KEEP_STREAM,), rows * e * size, len(kinds)))
     f, dense_f = cfg.mlp_dim, cfg.dense_ffn_dim if x else cfg.mlp_dim
     # What a kept GB is worth, ms (``table``).  The dispatch's buffers
     # have ``row_bound`` rows, of which a balanced router fills
@@ -101,10 +120,50 @@ def _entries(cfg, rows):
         (11, "conv_in", (short_conv.KEEP_IN,), rows * 3 * e * size, conv),
         (5, "conv_out", (short_conv.KEEP_OUT,), rows * e * size, conv),
     ]
+    if cfg.shared_dim:
+        rest += [(12, name, (name,), rows * cfg.shared_dim * size, experts)
+                 for name in SHARED_PRODUCTS]
+    if latent:
+        # a contraction over the rank, not the hidden size: rank / dim
+        # of what a byte of q buys
+        rest.append((13 * rank / e, "kv", (KEEP_KV,),
+                     rows * h * (d_nope + d) * size, attention))
     # stable: at equal worth the dispatch's come first
     entries += [entry[1:] for entry in sorted(
         routed + rest, key=lambda entry: -entry[0])]
     return [entry for entry in entries if entry[3]]
+
+
+def _lanes(width):
+    """A minor dimension as HBM tiles it: whole 128-lane tiles."""
+    return -(-width // 128) * 128
+
+
+def _latent_layer(cfg, rows, kept):
+    """(bytes of a latent-attention layer's second forward that stay
+    until its attention's backward has read them, with the entries
+    ``kept`` read from the stack instead; bytes that backward makes):
+    what ``ops/flash_attention.latent_attention`` takes and gives a
+    head, [rows, heads, width] each (a RoPE part 128 lanes wide in
+    HBM), and the two projections' results and cotangents before the
+    head-major transposes.  32 heads x 16,384 rows make each of them
+    0.13 GB: 2.8 GB a layer with nothing kept, where attention with wk
+    and wv of the benchmark's other cells stays under their FFN's
+    term."""
+    size = jnp.dtype(cfg.dtype).itemsize
+    _, d_nope, d_rope, d_v = cfg.latent
+    unit = rows * cfg.num_heads * size
+    q, kv = d_nope + _lanes(d_rope), d_nope + d_v
+    flat_q, flat_kv = d_nope + d_rope, d_nope + d_v
+    made = {"flash": d_v, "q": q + flat_q, "kv": kv + flat_kv}
+    # the output again as ``wo`` takes it, token-major: never kept
+    residuals = unit * (d_v + sum(width for label, width in made.items()
+                                  if label not in kept))
+    # dO in both layouts, dq, dk_nope and dv, each head's float32 part
+    # of the RoPE key's gradient, the projections' cotangents
+    backward = unit * (2 * d_v + q + kv + flat_q + flat_kv) + (
+        rows * cfg.num_heads * _lanes(d_rope) * 4)
+    return residuals, backward
 
 
 def _routed_rows(cfg, rows):
@@ -129,8 +188,12 @@ def table(cfg, rows):
     and the stream ~13; the FFN's products ~12, and by their shapes a
     short convolution's input ~11; the sorted rows, one gather, ~8; the
     convolution's result, a pass bound by memory, ~5; the flash
-    forward's, the router's and attention's first as they stand, the
-    others by that worth, a share's dispatch buffers at the part of
+    forward's, the router's and attention's first as they stand (latent
+    attention's: the latent with the RoPE key, [rows, rank + D_rope],
+    then q; the k_nope and v that one matmul over the rank makes from
+    the latent again are worth rank / dim of q's, ~3, and stand among
+    the others), the others by that worth, a share's dispatch buffers
+    at the part of
     their rows a balanced router fills, a half).  Elementwise
     work (norms, RoPE's rotation, the
     activation, the weighted combine) is not here: it is cheap and its
@@ -182,6 +245,10 @@ def step_bytes(cfg, params, rows, kept=()):
        again, so it leaves the term: a dense layer's gate and up leave
        their product and a cotangent; an expert layer's term, which
        counts no cotangent, keeps half;
+     - for latent attention, whose operands are [rows, heads, width]
+       each at 32 heads (``_latent_layer``): the attention residuals
+       of the second forward that are not kept beside that backward,
+       or attention's own backward where it is the larger;
      - less, at either place, what an untied embedding was counted
        for: its copy is read by the forward's first gather alone and
        its gradient is the last thing the backward makes.
@@ -211,9 +278,16 @@ def step_bytes(cfg, params, rows, kept=()):
         term = _routed_rows(cfg, rows)[0] * (
             cfg.dim + 2 * cfg.mlp_dim) * size
         layer = max(term - own(EXPERT_PRODUCTS), term // 2)
+        # the shared expert's gate, up, product and a cotangent
+        layer += rows * 4 * cfg.shared_dim * size - own(SHARED_PRODUCTS)
     if any(kind.dense for kind in cfg.kinds):
         f = cfg.dense_ffn_dim if cfg.moe_experts else cfg.mlp_dim
         layer = max(layer, rows * 4 * f * size - own(DENSE_PRODUCTS))
+    if cfg.latent and any(kind.op == "a" for kind in cfg.kinds):
+        # attention's residuals stand through the FFN's backward, and
+        # its own backward follows where the FFN's was
+        residuals, backward = _latent_layer(cfg, rows, kept)
+        layer = max(layer, backward) + residuals
     embed = params["embed"]
     unread = 0 if cfg.tied_embeddings else copy(embed) + nbytes(embed)
     return copies + carries + max(head - stack_grads, layer) - unread

@@ -32,7 +32,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from elasticdl_tpu.models import remat_keep
 from elasticdl_tpu.models.spec import ModelSpec
 from elasticdl_tpu.ops import short_conv
-from elasticdl_tpu.ops.flash_attention import flash_attention, logger
+from elasticdl_tpu.ops.flash_attention import (flash_attention,
+                                               latent_attention,
+                                               latent_mode, logger)
 from elasticdl_tpu.ops.mode import kernels_off
 from elasticdl_tpu.ops.moe_dispatch import ACTIVATIONS, moe_experts
 from elasticdl_tpu.utils import metrics
@@ -76,6 +78,26 @@ class TransformerConfig:
     # ``dim`` (resolved at construction: ``dataclasses.replace`` of
     # ``dim`` or ``num_heads`` keeps it).
     head_dim: int = 0
+    # Multi-head latent attention: ``kv_latent_rank`` > 0 replaces wk
+    # and wv by ``w_kv_a`` [dim, rank + qk_rope_dim], whose first
+    # ``rank`` outputs are the latent (RMSNorm ``kv_norm`` of its own)
+    # and whose last ``qk_rope_dim`` are ONE RoPE key for all the
+    # heads, and ``w_kv_b`` [rank, heads * (qk_nope_dim + v_head_dim)],
+    # which makes each head's no-position key and its value from the
+    # latent.  A q/k head is ``qk_nope_dim`` values RoPE leaves alone
+    # then ``qk_rope_dim`` that it turns (``wq`` [dim, heads * their
+    # sum], no query latent); scores run over both at ``(qk_nope_dim +
+    # qk_rope_dim) ** -0.5``, values and ``wo``'s inputs are
+    # ``v_head_dim`` a head (``ops/flash_attention.latent_attention``);
+    # ``head_dim``, ``num_kv_heads`` and ``qk_norm`` are not read.  RoPE
+    # turns the halves of the RoPE part (``_rope``): a checkpoint whose
+    # rotation pairs neighbours (2i, 2i + 1) loads the RoPE columns of
+    # ``wq`` and ``w_kv_a`` evens first, then odds, and every score is
+    # the same.  0 = attention as above, all four.
+    kv_latent_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
     # A stack whose layers differ.  ``layer_pattern``: one letter a
     # layer, "a" causal attention over the whole sequence, "w" causal
     # attention over the last ``window`` positions, "c" gated short
@@ -129,6 +151,12 @@ class TransformerConfig:
     # here; what the absent ones would add is left out.  0 = all held.
     moe_experts_held: int = 0
     moe_share_index: int = 0
+    # Always-on shared experts: N > 0 adds to every expert layer's
+    # routed result one SwiGLU of N x the expert width on the same
+    # normed input, which every token passes through; a dense layer has
+    # none.  Under a share it is whole here: every chip of the group
+    # computes it alike for its own tokens.
+    moe_shared_experts: int = 0
     # Rematerialize each scanned layer in the backward pass instead of
     # saving all its activations (24 layers x T=2048 save ~20 GB of
     # them un-remat'ed on one chip).  True = recompute what does not
@@ -180,6 +208,27 @@ class TransformerConfig:
     @property
     def mlp_dim(self):
         return self.ffn_dim or self.dim * self.mlp_ratio
+
+    @property
+    def shared_dim(self):
+        """Width of an expert layer's shared expert (0: none)."""
+        return self.moe_shared_experts * self.mlp_dim if (
+            self.moe_experts) else 0
+
+    @property
+    def latent(self):
+        """(rank, qk_nope_dim, qk_rope_dim, v_head_dim) of latent
+        attention, or None for attention with wk and wv."""
+        sizes = (self.kv_latent_rank, self.qk_nope_dim, self.qk_rope_dim,
+                 self.v_head_dim)
+        if not any(sizes):
+            return None
+        if not all(size > 0 for size in sizes) or self.qk_rope_dim % 2:
+            raise ValueError(
+                "latent attention needs kv_latent_rank, qk_nope_dim, an "
+                "even qk_rope_dim and v_head_dim, all > 0; got %s"
+                % (sizes,))
+        return sizes
 
     @property
     def kinds(self):
@@ -273,7 +322,20 @@ def _one_kind(cfg):
     return next(kind for kind in cfg.kinds if kind.op == "a")
 
 
+def _no_latent(cfg, what):
+    if cfg.latent:
+        raise NotImplementedError(
+            "%s does not run latent attention (kv_latent_rank=%d): "
+            "decoding needs a cache of the latent and the one RoPE key "
+            "(rank + qk_rope_dim values a position, not heads x head "
+            "size) and the up-projection absorbed into q and the "
+            "output; ring_attention, ulysses_attention and the "
+            "pipeline's stages take q, k, v of one width (ROADMAP B8)"
+            % (what, cfg.kv_latent_rank))
+
+
 def _uniform_only(cfg, what):
+    _no_latent(cfg, what)
     if stack_plan(cfg) is not None:
         raise NotImplementedError(
             "%s does not run a stack whose layers differ (layer_pattern="
@@ -303,7 +365,15 @@ def _init_layers(k_attn, k_mlp, cfg, kind, stack):
     E, H, D, G = cfg.dim, cfg.num_heads, cfg.head_dim, cfg.kv_heads
     keys = jax.random.split(k_attn, 6)
     layers = {"ln1": _norm_init(*stack, E), "ln2": _norm_init(*stack, E)}
-    if kind.op == "a":
+    if kind.op == "a" and cfg.latent:
+        rank, dn, dr, dv = cfg.latent
+        layers.update(
+            wq=_dense_init(keys[0], *stack, E, H * (dn + dr)),
+            w_kv_a=_dense_init(keys[1], *stack, E, rank + dr),
+            kv_norm=_norm_init(*stack, rank),
+            w_kv_b=_dense_init(keys[2], *stack, rank, H * (dn + dv)),
+            wo=_dense_init(keys[3], *stack, H * dv, E))
+    elif kind.op == "a":
         layers.update(
             wq=_dense_init(keys[0], *stack, E, H * D),
             wk=_dense_init(keys[1], *stack, E, G * D),
@@ -335,6 +405,12 @@ def _init_layers(k_attn, k_mlp, cfg, kind, stack):
                                      *stack, held, E, F)
         layers["w_down"] = _dense_init(jax.random.fold_in(k_mlp, 1),
                                        *stack, held, F, E)
+        S = cfg.shared_dim
+        if S:
+            shared = jax.random.split(jax.random.fold_in(k_mlp, 2), 3)
+            layers["ws_gate"] = _dense_init(shared[0], *stack, E, S)
+            layers["ws_up"] = _dense_init(shared[1], *stack, E, S)
+            layers["ws_down"] = _dense_init(shared[2], *stack, S, E)
     return layers
 
 
@@ -393,6 +469,10 @@ def param_specs(cfg):
         layers["w_gate"] = P("pp", "ep", None, "tp")
         layers["w_up"] = P("pp", "ep", None, "tp")
         layers["w_down"] = P("pp", "ep", "tp", None)
+        if cfg.shared_dim:      # as a dense FFN's
+            layers["ws_gate"] = P("pp", None, "tp")
+            layers["ws_up"] = P("pp", None, "tp")
+            layers["ws_down"] = P("pp", "tp", None)
     else:
         layers["w_gate"] = P("pp", None, "tp")
         layers["w_up"] = P("pp", None, "tp")
@@ -567,6 +647,88 @@ def _project_qkv(h, w, cfg, positions, rope=True):
             checkpoint_name(v, remat_keep.KEEP_V))
 
 
+def _project_latent(h, w, cfg, positions, rope=True):
+    """Latent attention's five operands of the normed input, as
+    ``ops/flash_attention.latent_attention`` takes them: q_nope
+    [B, H, T, Dn], q_rope [B, H, T, Dr], k_nope [B, H, T, Dn], k_rope
+    [B, T, Dr] (one key for all the heads), v [B, H, T, Dv].  RoPE turns
+    q_rope and k_rope alone; the latent has an RMSNorm of its own."""
+    compute_dtype = jnp.dtype(cfg.dtype)
+    B, T = h.shape[0], h.shape[1]
+    H = cfg.num_heads
+    rank, dn, dr, dv = cfg.latent
+    q = (h @ w["wq"].astype(compute_dtype)).reshape(B, T, H, dn + dr)
+    # the latent and the RoPE key as the projection gives them: [T,
+    # rank + Dr] a layer, what one matmul makes k_nope and v from
+    c = checkpoint_name(h @ w["w_kv_a"].astype(compute_dtype),
+                        remat_keep.KEEP_LATENT)
+    kv = (_rmsnorm(c[..., :rank], w["kv_norm"].astype(compute_dtype),
+                   cfg.norm_eps)
+          @ w["w_kv_b"].astype(compute_dtype)).reshape(B, T, H, dn + dv)
+    q_rope, k_rope = q[..., dn:], c[..., None, rank:]
+    if rope:
+        q_rope = _rope(q_rope, positions, cfg.rope_theta)
+        k_rope = _rope(k_rope, positions, cfg.rope_theta)
+    heads_first = lambda a: a.transpose(0, 2, 1, 3)
+    name = checkpoint_name
+    return (name(heads_first(q[..., :dn]), remat_keep.KEEP_Q),
+            name(heads_first(q_rope), remat_keep.KEEP_Q),
+            name(heads_first(kv[..., :dn]), remat_keep.KEEP_KV),
+            k_rope[:, :, 0],
+            name(heads_first(kv[..., dn:]), remat_keep.KEEP_KV))
+
+
+@functools.lru_cache(maxsize=None)
+def announce_latent(heads, seq, latent, mode, tile, why):
+    """Once per compiled shape and mode, by the logger ``announce_tiles``
+    uses: what latent attention runs as (``latent_mode``'s answer)."""
+    logger.info(
+        "latent attention: heads=%d t=%d rank=%d qk_nope=%d qk_rope=%d "
+        "v=%d rope_key=shared tile=%d %s%s", heads, seq, *latent, tile,
+        {"tpu": "kernel", "interpret": "interpreter",
+         "off": "reference"}[mode], " (%s)" % why if why else "")
+
+
+def _latent_mix(h, w, cfg, positions, kind):
+    """LatentAttention(h) of the normed input, [B, T, dim]: the five
+    operands, the op, ``W_o``."""
+    compute_dtype = jnp.dtype(cfg.dtype)
+    B, T = h.shape[0], h.shape[1]
+    announce_latent(cfg.num_heads, T, cfg.latent, *latent_mode(
+        T, *cfg.latent[1:], compute_dtype.itemsize))
+    attn = latent_attention(
+        *_project_latent(h, w, cfg, positions, kind.rope), causal=True,
+        window=kind.window)
+    attn = attn.transpose(0, 2, 1, 3).reshape(B, T, -1)
+    return attn @ w["wo"].astype(compute_dtype)
+
+
+def _latent_attention(x, w, cfg, positions, kind):
+    """x + LatentAttention(norm(x)) -> (x, None): nothing is cached
+    (``_no_latent``)."""
+    h = _rmsnorm(x, w["ln1"].astype(jnp.dtype(cfg.dtype)), cfg.norm_eps)
+    x = x + _latent_mix(h, w, cfg, positions, kind)
+    return checkpoint_name(x, remat_keep.KEEP_STREAM), None
+
+
+def _gated_mlp(h, w, cfg, weights, keep):
+    """``(act(h W_gate) * (h W_up)) W_down`` with the three ``weights``
+    named, the gate and up products named ``keep`` for a remat policy."""
+    compute_dtype = jnp.dtype(cfg.dtype)
+    gate, up, down = (w[name].astype(compute_dtype) for name in weights)
+    gate = checkpoint_name(h @ gate, keep[0])
+    up = checkpoint_name(h @ up, keep[1])
+    return (ACTIVATIONS[cfg.ffn_activation](gate) * up) @ down
+
+
+def _shared_expert(h, w, cfg):
+    """The always-on shared expert of an expert layer: one gated MLP of
+    ``cfg.shared_dim`` on the FFN's normed input."""
+    return _gated_mlp(h, w, cfg, ("ws_gate", "ws_up", "ws_down"),
+                      (remat_keep.KEEP_SHARED_GATE,
+                       remat_keep.KEEP_SHARED_UP))
+
+
 def _ffn(x, w, cfg, mesh, dense=False, route=None):
     """x + FFN(norm(x)) -> (x, aux, stats, load); the last three are
     the MoE's (:func:`_moe_ffn`, which ``route`` is for), zeros and None
@@ -577,14 +739,12 @@ def _ffn(x, w, cfg, mesh, dense=False, route=None):
     h = _rmsnorm(x, w["ln2"].astype(compute_dtype), cfg.norm_eps)
     if cfg.moe_experts and not dense:
         out, aux, stats, load = _moe_ffn(h, w, cfg, mesh, route)
+        if cfg.shared_dim:
+            out = out + _shared_expert(h, w, cfg)
         return x + _constrain(out, mesh, act_spec), aux, stats, load
-    gate = checkpoint_name(h @ w["w_gate"].astype(compute_dtype),
-                           remat_keep.KEEP_GATE)
-    up = checkpoint_name(h @ w["w_up"].astype(compute_dtype),
-                         remat_keep.KEEP_UP)
     x = x + _constrain(
-        (ACTIVATIONS[cfg.ffn_activation](gate) * up)
-        @ w["w_down"].astype(compute_dtype),
+        _gated_mlp(h, w, cfg, ("w_gate", "w_up", "w_down"),
+                   (remat_keep.KEEP_GATE, remat_keep.KEEP_UP)),
         mesh, act_spec,
     )
     return x, jnp.float32(0.0), None, None
@@ -600,6 +760,10 @@ def _attention(x, w, cfg, mesh, positions, kind=None):
     compute_dtype = jnp.dtype(cfg.dtype)
     act_spec = P("dp", "sp", None)
     kind = kind or _one_kind(cfg)
+    if cfg.latent:
+        if mesh is not None:
+            _no_latent(cfg, "a model-parallel mesh")
+        return _latent_attention(x, w, cfg, positions, kind)
     B, T = x.shape[0], x.shape[1]
     H, D = cfg.num_heads, cfg.head_dim
     G = cfg.kv_heads
@@ -770,17 +934,19 @@ def forward_hidden(params, tokens, cfg, mesh=None, return_load=False):
 
 
 @functools.lru_cache(maxsize=None)
-def announce_stack(pattern, plan, experts):
+def announce_stack(pattern, plan, experts, shared=0):
     """Once per model, by the logger ``announce_tiles`` uses: how a
-    stack whose layers differ is run."""
+    stack whose layers differ is run (``shared``: the width of an
+    expert layer's always-on shared expert, said where there is one)."""
     letters = lambda kinds: "".join(map(_letter, kinds)) or "-"
     kinds = sorted(set(k for k in plan.lead + plan.period + plan.tail
                        if k.op == "a"), key=_letter)
     logger.info(
         "layer stack: pattern=%s lead=%s period=%s periods=%d tail=%s "
-        "dense_layers=%d experts_held=%d/%d%s", pattern,
+        "dense_layers=%d experts_held=%d/%d%s%s", pattern,
         letters(plan.lead), letters(plan.period), plan.periods,
         letters(plan.tail), len(plan.lead), *experts,
+        " shared_expert=%d" % shared if shared else "",
         "".join(" %s:window=%d,rope=%d" % (_letter(k), k.window, k.rope)
                 for k in kinds))
 
@@ -791,7 +957,7 @@ def _mixed_stack(x, layers, cfg, plan, block):
     with experts returned beside it, stacked in layer order: aux [L_moe]
     or (aux [L_moe], load [L_moe, ..]); a zero where none has experts)."""
     announce_stack("".join(map(_letter, cfg.kinds)), plan,
-                   (cfg.experts_held[1], cfg.moe_experts))
+                   (cfg.experts_held[1], cfg.moe_experts), cfg.shared_dim)
 
     tree_map = jax.tree_util.tree_map
 
@@ -1166,7 +1332,9 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
                moe_router="softmax", moe_route_scale=1.0,
                moe_experts_held=0, moe_share_index=0, warmup_steps=0,
                head_dim=0, rope_kinds="aw", moe_route_before_op=False,
-               ffn_activation="silu", embed_scale=0.02):
+               ffn_activation="silu", embed_scale=0.02, kv_latent_rank=0,
+               qk_nope_dim=0, qk_rope_dim=0, v_head_dim=0,
+               moe_shared_experts=0):
     """Zoo entry for the flagship LM.
 
     ``remat`` (False | True | "dots" | "attn"), ``attention_impl``
@@ -1184,8 +1352,12 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
     0 = full) and ``rope_kinds`` (the kinds RoPE turns, "aw"; a kind
     left out has no positional encoding), the router's ``moe_router`` /
     ``moe_route_scale`` / ``moe_route_before_op`` (the router reads the
-    operator's normed input and not the FFN's) and the share
-    ``moe_experts_held`` / ``moe_share_index`` pass through
+    operator's normed input and not the FFN's), the share
+    ``moe_experts_held`` / ``moe_share_index``, ``moe_shared_experts``
+    (always-on experts beside the routed ones, as one SwiGLU of that
+    many expert widths) and latent attention's four sizes
+    ``kv_latent_rank`` / ``qk_nope_dim`` / ``qk_rope_dim`` /
+    ``v_head_dim`` (all 0: attention with wk and wv) pass through
     to :class:`TransformerConfig`.  ``xent_chunk`` > 0 computes the
     loss via :func:`next_token_loss_chunked` — no [B, T, V] logits
     tensor, the memory-lean path for large vocab x seq (numerically
@@ -1235,9 +1407,13 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
                                   moe_route_before_op),
         ffn_activation=str(ffn_activation),
         embed_scale=float(embed_scale),
+        kv_latent_rank=int(kv_latent_rank), qk_nope_dim=int(qk_nope_dim),
+        qk_rope_dim=int(qk_rope_dim), v_head_dim=int(v_head_dim),
+        moe_shared_experts=int(moe_shared_experts),
     )
-    # validate at spec build: heads, the share, the pattern, the gate
-    cfg.kv_heads, cfg.experts_held, cfg.kinds
+    # validate at spec build: heads, the share, the pattern, the gate,
+    # the latent's sizes
+    cfg.kv_heads, cfg.experts_held, cfg.kinds, cfg.latent
     if cfg.ffn_activation not in ACTIVATIONS:
         raise ValueError("unknown ffn_activation %r (want one of %s)" % (
             cfg.ffn_activation, ", ".join(sorted(ACTIVATIONS))))
@@ -1380,6 +1556,7 @@ def export_generate(export_dir, params, cfg, max_new_tokens,
     """
     from elasticdl_tpu.serving.export import export_servable
 
+    _no_latent(cfg, "export_generate")
     if prompt_len + max_new_tokens > cfg.max_seq_len:
         raise ValueError(
             "prompt_len %d + max_new_tokens %d exceeds max_seq_len %d"

@@ -41,6 +41,23 @@ closed-form pullback ``_partial_stats_bwd`` (scans K blocks,
 recomputing each [T, block_k] score tile), so each ring step's bwd is
 O(T/sp x block_k) live, never the dense per-shard square.
 
+``latent_attention`` is the same three kernels for a head whose scores
+run over two parts, ``D_nope + D_rope``, and whose values are ``D_v``
+wide (multi-head latent attention): a second score matmul over the RoPE
+parts is accumulated into the one S (``_scores``), the values, ``dO``
+and the accumulators keep their own width, and the RoPE key, ONE
+[seq, D_rope] plane for all the heads of a sequence, is read through a
+BlockSpec whose index leaves the head out (``_tile_specs(shared=)``), so
+it is never repeated to the heads in HBM; each head's part of its
+gradient leaves the dk-dv kernel in float32 and XLA sums them.  Its
+calls carry the widths behind the name (``flash_fwd_qk192_v128``).  At
+equal widths nothing of this is traced: ``flash_attention``'s kernels
+are what they were (tests/flash_equal_width_program.json).  One
+sequence of 16,384 at 32 heads, 128 | 64 | 128, takes 21.3 ms forward
+and 93.2 ms with its backward on a v5e; with the key repeated to the
+heads, a form measured once and not kept, 21.6 and 93.6 (my chip run,
+PR 37).
+
 Layout: [batch, heads, seq, head_dim].  The kernels choose their own
 tiling (``_major_tile``, ``_TilePlan``); a caller gives none.  A shape
 they cannot take (seq not a multiple of the 128 lanes, an odd head_dim)
@@ -322,9 +339,24 @@ _FWD_STATS_CHUNKS = 4
 _FWD_ROW_RUN = 4
 
 
-def _flash_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, o_ref, l_ref,
-                  m_ref, acc_scr, l_scr, m_scr, *, plan, scale,
-                  normalize):
+def _scores(a_ref, b_ref, rows, cols, rope, scale):
+    """[rows, cols] scaled scores of two resident blocks, f32.  ``rope``:
+    None, or the two blocks' RoPE parts, whose product over their own
+    width is added before the scale (a latent-attention head: scores
+    over ``D_nope + D_rope``, two matmuls into one S)."""
+    s = lax.dot_general(
+        a_ref[0, rows, :], b_ref[0, cols, :], _NT,
+        preferred_element_type=jnp.float32,
+    )
+    if rope is not None:
+        s = lax.add(s, lax.dot_general(
+            rope[0][0, rows, :], rope[1][0, cols, :], _NT,
+            preferred_element_type=jnp.float32))
+    return s * scale
+
+
+def _flash_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, *rest, plan, scale,
+                  normalize, rope=False):
     # grid: (bh, live tiles), the tiles of one query block in a row, K
     # ascending.  Each grid step sees ONE [1, tile, D] K/V tile —
     # Pallas's automatic pipelining streams tiles HBM->VMEM overlapped
@@ -332,8 +364,11 @@ def _flash_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, o_ref, l_ref,
     # (acc, l, m) lives in VMEM scratch, persistent across a query
     # block's steps.  Stats stay 2D [tile, STATS_LANES] (every lane
     # equal) so all vector ops live on full (8, 128) tiles, and leave
-    # as one [1, tile] row each.
-    head_dim = q_ref.shape[2]
+    # as one [1, tile] row each.  With ``rope`` two more inputs follow
+    # v: the queries' and the keys' RoPE parts (``_scores``).
+    qk_rope = tuple(rest[:2]) if rope else None
+    o_ref, l_ref, m_ref, acc_scr, l_scr, m_scr = rest[2 * rope:]
+    head_dim = v_ref.shape[2]
     step = pl.program_id(1)
     qi, ki = qi_tab[step], ki_tab[step]
 
@@ -359,11 +394,9 @@ def _flash_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, o_ref, l_ref,
                 span = _live_span(cmap, b)
                 if span is None:
                     continue
-                sb = lax.dot_general(
-                    q_ref[0, span[0] * SUB:span[1] * SUB, :],
-                    k_ref[0, b * SUB:(b + 1) * SUB, :], _NT,
-                    preferred_element_type=jnp.float32,
-                ) * scale
+                sb = _scores(
+                    q_ref, k_ref, slice(span[0] * SUB, span[1] * SUB),
+                    slice(b * SUB, (b + 1) * SUB), qk_rope, scale)
                 s[b] = span[0], _mask_edges(sb, cmap, b, plan.window)
             # Query sub-blocks with the same live chunks fold together.
             runs = []           # [a0, a1, live chunks]
@@ -414,16 +447,20 @@ def _flash_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, o_ref, l_ref,
         m_ref[0] = m_scr[...].T[0:1, :]
 
 
-def _tile_specs(tile, d, order_axis):
+def _tile_specs(tile, d, order_axis, shared=0):
     """(resident, streamed, resident-row stats, streamed-row stats)
     BlockSpecs over a (bh, live tiles) grid whose step s works on tile
     (qi_tab[s], ki_tab[s]); ``order_axis`` 0 keeps the query block
-    resident (forward, dq), 1 the key block (dk-dv)."""
+    resident (forward, dq), 1 the key block (dk-dv).  ``shared`` = H > 0:
+    the array is [b, T, d], one plane for the H heads of a batch row,
+    and the block's index leaves the head out."""
+    lead = (lambda i: i // shared) if shared else (lambda i: i)
+
     def resident(i, s, qi_tab, ki_tab):
-        return (i, (qi_tab, ki_tab)[order_axis][s], 0)
+        return (lead(i), (qi_tab, ki_tab)[order_axis][s], 0)
 
     def streamed(i, s, qi_tab, ki_tab):
-        return (i, (qi_tab, ki_tab)[1 - order_axis][s], 0)
+        return (lead(i), (qi_tab, ki_tab)[1 - order_axis][s], 0)
 
     def row_of(index):
         def row(*args):
@@ -439,48 +476,84 @@ def _tile_specs(tile, d, order_axis):
     )
 
 
-def _call_name(kernel, window):
+def _call_name(kernel, window, widths=None):
     """The name a kernel's call carries into the compiled program and a
     device trace (``%flash_fwd_w4096.3 = ... custom-call(...)``): the
-    kernel, and its window where it has one, so that a trace tells a
-    windowed layer's calls from a full layer's."""
-    return kernel + ("_w%d" % window if window else "")
+    kernel, its window where it has one, so that a trace tells a
+    windowed layer's calls from a full layer's, and ``widths`` (scores',
+    values') where they differ: ``flash_dkv_qk192_v128``."""
+    return (kernel + ("_w%d" % window if window else "")
+            + ("_qk%d_v%d" % widths if widths else ""))
+
+
+def _tile_for(t, q, rope):
+    """``_major_tile`` by the bytes of a score's row: q's, and its RoPE
+    part's where it has one."""
+    width = q.shape[3] + (rope[0].shape[3] if rope is not None else 0)
+    return _major_tile(t, width * q.dtype.itemsize)
+
+
+def _rope_parts(rope, order_axis, tile):
+    """What a latent-attention call adds to an equal-width one: (the
+    RoPE parts as the kernels take them: the queries' [bh, T, D_rope],
+    the keys' [b, T, D_rope], one plane a batch row; their BlockSpecs,
+    resident side first)."""
+    q_rope, k_rope = rope
+    b, h, t, dr = q_rope.shape
+    q_specs = _tile_specs(tile, dr, order_axis)
+    k_specs = _tile_specs(tile, dr, order_axis, shared=h)
+    # order_axis 0: queries resident, keys streamed; 1: the reverse
+    specs = ((q_specs[0], k_specs[1]) if order_axis == 0
+             else (q_specs[1], k_specs[0]))
+    return q_rope.reshape(b * h, t, dr), k_rope, specs
 
 
 def _flash_forward(q, k, v, causal, scale, interpret, normalize=True,
-                   window=0):
-    """Returns (out, l, m); out is normalized iff ``normalize``."""
+                   window=0, rope=None):
+    """Returns (out, l, m); out is normalized iff ``normalize``.
+    ``rope``: None, or (q_rope [b, h, t, dr], k_rope [b, t, dr]), the
+    RoPE parts of a latent-attention head, whose scores run over q's
+    width + dr and whose values are v's width."""
     b, h, t, d = q.shape
+    dv = v.shape[3]
     bh = b * h
     qr = q.reshape(bh, t, d)
     kr = k.reshape(bh, t, d)
-    vr = v.reshape(bh, t, d)
+    vr = v.reshape(bh, t, dv)
     # Work per grid step must amortize the per-step pipeline overhead:
     # a wide q block and a major K/V tile of the same edge, both capped
     # by what divides t.  The grid holds the live tiles only (their
     # indices are scalar-prefetched), so a dead tile costs neither a
     # step nor a DMA.
-    tile = _major_tile(t, d * q.dtype.itemsize)
+    tile = _tile_for(t, q, rope)
     plan = _tile_plan(t, tile, causal, window)
     if not interpret:
         announce_tiles(bh, t, d, tile, causal, window)
     q_spec, kv_spec, stat_spec, _ = _tile_specs(tile, d, 0)
+    o_spec, v_spec = (q_spec, kv_spec) if dv == d else _tile_specs(
+        tile, dv, 0)[:2]
+    operands, in_specs, widths = [qr, kr, vr], [q_spec, kv_spec, v_spec], None
+    if rope is not None:
+        q_rope, k_rope, specs = _rope_parts(rope, 0, tile)
+        operands += [q_rope, k_rope]
+        in_specs += specs
+        widths = (d + q_rope.shape[2], dv)
     out_dtype = q.dtype if normalize else jnp.float32
     out, l, m = pl.pallas_call(
         functools.partial(_flash_kernel, plan=plan, scale=scale,
-                          normalize=normalize),
+                          normalize=normalize, rope=rope is not None),
         out_shape=(
-            jax.ShapeDtypeStruct((bh, t, d), out_dtype),
+            jax.ShapeDtypeStruct((bh, t, dv), out_dtype),
             jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
             jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh, len(plan.q_major)),
-            in_specs=[q_spec, kv_spec, kv_spec],
-            out_specs=(q_spec, stat_spec, stat_spec),
+            in_specs=in_specs,
+            out_specs=(o_spec, stat_spec, stat_spec),
             scratch_shapes=[
-                pltpu.VMEM((tile, d), jnp.float32),
+                pltpu.VMEM((tile, dv), jnp.float32),
                 pltpu.VMEM((tile, STATS_LANES), jnp.float32),
                 pltpu.VMEM((tile, STATS_LANES), jnp.float32),
             ],
@@ -489,10 +562,10 @@ def _flash_forward(q, k, v, causal, scale, interpret, normalize=True,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name=_call_name("flash_fwd", window),
-    )(*plan.tables(plan.q_major), qr, kr, vr)
+        name=_call_name("flash_fwd", window, widths),
+    )(*plan.tables(plan.q_major), *operands)
     return (
-        out.reshape(b, h, t, d),
+        out.reshape(b, h, t, dv),
         l.reshape(b, h, t),
         m.reshape(b, h, t),
     )
@@ -534,14 +607,22 @@ def _masked_block_scores(qf, kf, ki, block_k, causal, scale, k_offset,
 
 
 def _bwd_dq_kernel(qi_tab, ki_tab, q_ref, do_ref, k_ref, v_ref, lse_ref,
-                   delta_ref, dq_ref, dq_scr, lse_scr, delta_scr, *,
-                   plan, scale):
+                   delta_ref, *rest, plan, scale, rope=False):
     """dq = scale * sum_j ds_ij k_j, ds = p (dp - delta), p = exp(s -
     lse).  Grid (bh, live tiles), a query block's tiles in a row: the
     q/dO tiles and the row constants stay resident while K/V tiles
     stream through VMEM; the probability/ds tiles never exist outside
     VMEM.  lse and delta arrive as [1, tile] rows and are spread over
-    the lanes once a query block."""
+    the lanes once a query block.  With ``rope`` (``_scores``) the
+    RoPE parts follow the inputs, and their dq the outputs and the
+    scratch: dq_rope = scale * sum_j ds_ij k_rope_j."""
+    if rope:
+        (qr_ref, kr_ref, dq_ref, dqr_ref, dq_scr, lse_scr, delta_scr,
+         dqr_scr) = rest
+        qk_rope = qr_ref, kr_ref
+    else:
+        dq_ref, dq_scr, lse_scr, delta_scr = rest
+        qk_rope = None
     step = pl.program_id(1)
     qi, ki = qi_tab[step], ki_tab[step]
     tile = plan.tile
@@ -549,6 +630,8 @@ def _bwd_dq_kernel(qi_tab, ki_tab, q_ref, do_ref, k_ref, v_ref, lse_ref,
     @pl.when(ki == jnp.maximum(0, qi - plan.dt_max))
     def _init():
         dq_scr[...] = jnp.zeros(dq_scr.shape, dq_scr.dtype)
+        if rope:
+            dqr_scr[...] = jnp.zeros(dqr_scr.shape, dqr_scr.dtype)
         lse_scr[...] = jnp.broadcast_to(
             lse_ref[0], (STATS_LANES, tile)).T
         delta_scr[...] = jnp.broadcast_to(
@@ -562,10 +645,8 @@ def _bwd_dq_kernel(qi_tab, ki_tab, q_ref, do_ref, k_ref, v_ref, lse_ref,
                 continue
             rows = slice(span[0] * SUB, span[1] * SUB)
             keys = slice(b * SUB, (b + 1) * SUB)
-            s = lax.dot_general(
-                q_ref[0, rows, :], k_ref[0, keys, :], _NT,
-                preferred_element_type=jnp.float32,
-            ) * scale                                  # [rows, SUB]
+            s = _scores(q_ref, k_ref, rows, keys, qk_rope,
+                        scale)                         # [rows, SUB]
             s = _mask_edges(s, cmap, b, plan.window)
             p = lax.exp(lax.sub(s, lse_scr[rows, :]))
             dp = lax.dot_general(
@@ -575,24 +656,38 @@ def _bwd_dq_kernel(qi_tab, ki_tab, q_ref, do_ref, k_ref, v_ref, lse_ref,
             ds[b] = lax.convert_element_type(
                 lax.mul(p, lax.sub(dp, delta_scr[rows, :])), k_ref.dtype)
         _accumulate(dq_scr, cmap, ds, k_ref)
+        if rope:
+            _accumulate(dqr_scr, cmap, ds, kr_ref)
 
     plan.for_tile(qi, ki, tile_body)
 
     @pl.when(ki == jnp.minimum(plan.num - 1, qi - plan.dt_min))
     def _finish():
         dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
+        if rope:
+            dqr_ref[0] = (dqr_scr[...] * scale).astype(dqr_ref.dtype)
 
 
 def _bwd_dkv_kernel(qi_tab, ki_tab, k_ref, v_ref, q_ref, do_ref, lse_ref,
-                    delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, plan,
-                    scale):
+                    delta_ref, *rest, plan, scale, rope=False):
     """dk_j = scale * sum_i ds_ij q_i, dv_j = sum_i p_ij dO_i.  Grid
     (bh, live tiles), a key block's tiles in a row: the K/V tiles and
     accumulators stay resident while q/dO tiles and their row constants
     stream through.  The score tiles are built transposed, [keys,
     queries], so that every matmul contracts a minor dimension with a
     major one (no transpose of p or ds) and lse, delta are [1, SUB] rows
-    spread over sublanes."""
+    spread over sublanes.  With ``rope`` (``_scores``) the RoPE parts
+    follow the inputs, and this head's part of the RoPE key's gradient,
+    scale * sum_i ds_ij q_rope_i in float32, the outputs and the
+    scratch: the key is one plane for the heads, its gradient their sum,
+    which the caller takes."""
+    if rope:
+        (kr_ref, qr_ref, dk_ref, dv_ref, dkr_ref, dk_scr, dv_scr,
+         dkr_scr) = rest
+        kq_rope = kr_ref, qr_ref
+    else:
+        dk_ref, dv_ref, dk_scr, dv_scr = rest
+        kq_rope = None
     step = pl.program_id(1)
     qi, ki = qi_tab[step], ki_tab[step]
 
@@ -600,6 +695,8 @@ def _bwd_dkv_kernel(qi_tab, ki_tab, k_ref, v_ref, q_ref, do_ref, lse_ref,
     def _init():
         dk_scr[...] = jnp.zeros(dk_scr.shape, dk_scr.dtype)
         dv_scr[...] = jnp.zeros(dv_scr.shape, dv_scr.dtype)
+        if rope:
+            dkr_scr[...] = jnp.zeros(dkr_scr.shape, dkr_scr.dtype)
 
     def tile_body(cmap):
         p_t, ds_t = {}, {}
@@ -609,10 +706,8 @@ def _bwd_dkv_kernel(qi_tab, ki_tab, k_ref, v_ref, q_ref, do_ref, lse_ref,
                 continue
             rows = slice(span[0] * SUB, span[1] * SUB)     # keys
             qs = slice(a * SUB, (a + 1) * SUB)
-            s = lax.dot_general(
-                k_ref[0, rows, :], q_ref[0, qs, :], _NT,
-                preferred_element_type=jnp.float32,
-            ) * scale                                  # [keys, SUB]
+            s = _scores(k_ref, q_ref, rows, qs, kq_rope,
+                        scale)                         # [keys, SUB]
             s = _mask_edges(s, cmap, a, plan.window, keys_resident=True)
             p = lax.exp(s - lse_ref[0, :, qs])
             dp = lax.dot_general(
@@ -624,6 +719,8 @@ def _bwd_dkv_kernel(qi_tab, ki_tab, k_ref, v_ref, q_ref, do_ref, lse_ref,
                 lax.mul(p, dp - delta_ref[0, :, qs]), q_ref.dtype)
         _accumulate(dv_scr, cmap, p_t, do_ref)
         _accumulate(dk_scr, cmap, ds_t, q_ref)
+        if rope:
+            _accumulate(dkr_scr, cmap, ds_t, qr_ref)
 
     plan.for_tile(qi, ki, tile_body, keys_resident=True)
 
@@ -631,76 +728,113 @@ def _bwd_dkv_kernel(qi_tab, ki_tab, k_ref, v_ref, q_ref, do_ref, lse_ref,
     def _finish():
         dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+        if rope:
+            dkr_ref[0] = (dkr_scr[...] * scale).astype(dkr_ref.dtype)
 
 
 def _pallas_bwd(q, k, v, out, lse, g, causal, scale, interpret,
-                window=0):
+                window=0, rope=None):
     """Pallas backward: dq in one pass (K streamed), dk/dv in another
     (Q streamed).  The probability/ds tiles live only in VMEM.  What is
     constant along a row is made once, out here: lse came with the
-    residuals, delta_i = sum_d dO_i O_i is one pass over dO and O."""
+    residuals, delta_i = sum_d dO_i O_i is one pass over dO and O.
+    With ``rope`` (``_flash_forward``) also (dq_rope [b, h, t, dr],
+    dk_rope [b, h, t, dr] float32: each head's part of the RoPE key's
+    gradient, the caller's to sum: the key is one plane)."""
     b, h, t, d = q.shape
+    dv = v.shape[3]
     bh = b * h
-    tile = _major_tile(t, d * q.dtype.itemsize)
+    tile = _tile_for(t, q, rope)
     plan = _tile_plan(t, tile, causal, window)
     qr = q.reshape(bh, t, d)
     kr = k.reshape(bh, t, d)
-    vr = v.reshape(bh, t, d)
-    gr = g.astype(q.dtype).reshape(bh, t, d)
+    vr = v.reshape(bh, t, dv)
+    gr = g.astype(q.dtype).reshape(bh, t, dv)
     lse = lse.astype(jnp.float32).reshape(bh, 1, t)
     delta = (
-        gr.astype(jnp.float32) * out.reshape(bh, t, d).astype(jnp.float32)
+        gr.astype(jnp.float32) * out.reshape(bh, t, dv).astype(jnp.float32)
     ).sum(axis=-1).reshape(bh, 1, t)
     params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary"))
+    latent = rope is not None
+    dr = rope[0].shape[3] if latent else 0
+    widths = (d + dr, dv) if latent else None
+    scratch = lambda width: pltpu.VMEM((tile, width), jnp.float32)
 
     q_spec, kv_spec, qstat_spec, _ = _tile_specs(tile, d, 0)
+    do_spec, v_spec = (q_spec, kv_spec) if dv == d else _tile_specs(
+        tile, dv, 0)[:2]
+    operands = [qr, gr, kr, vr, lse, delta]
+    in_specs = [q_spec, do_spec, kv_spec, v_spec, qstat_spec, qstat_spec]
+    out_shape = jax.ShapeDtypeStruct((bh, t, d), q.dtype)
+    out_specs = q_spec
+    scratch_shapes = [scratch(d), scratch(STATS_LANES),
+                      scratch(STATS_LANES)]
+    if latent:
+        q_rope, k_rope, specs = _rope_parts(rope, 0, tile)
+        operands += [q_rope, k_rope]
+        in_specs += specs
+        out_shape = (out_shape,
+                     jax.ShapeDtypeStruct((bh, t, dr), q_rope.dtype))
+        out_specs = (q_spec, specs[0])
+        scratch_shapes.append(scratch(dr))
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, plan=plan, scale=scale),
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+        functools.partial(_bwd_dq_kernel, plan=plan, scale=scale,
+                          rope=latent),
+        out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh, len(plan.q_major)),
-            in_specs=[q_spec, q_spec, kv_spec, kv_spec, qstat_spec,
-                      qstat_spec],
-            out_specs=q_spec,
-            scratch_shapes=[
-                pltpu.VMEM((tile, d), jnp.float32),
-                pltpu.VMEM((tile, STATS_LANES), jnp.float32),
-                pltpu.VMEM((tile, STATS_LANES), jnp.float32),
-            ],
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch_shapes,
         ),
         compiler_params=params,
         interpret=interpret,
-        name=_call_name("flash_dq", window),
-    )(*plan.tables(plan.q_major), qr, gr, kr, vr, lse, delta)
+        name=_call_name("flash_dq", window, widths),
+    )(*plan.tables(plan.q_major), *operands)
 
     kv_spec, q_spec, _, qstat_spec = _tile_specs(tile, d, 1)
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, plan=plan, scale=scale),
-        out_shape=(
-            jax.ShapeDtypeStruct((bh, t, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, t, d), v.dtype),
-        ),
+    v_spec, do_spec = (kv_spec, q_spec) if dv == d else _tile_specs(
+        tile, dv, 1)[:2]
+    operands = [kr, vr, qr, gr, lse, delta]
+    in_specs = [kv_spec, v_spec, q_spec, do_spec, qstat_spec, qstat_spec]
+    out_shape = (jax.ShapeDtypeStruct((bh, t, d), k.dtype),
+                 jax.ShapeDtypeStruct((bh, t, dv), v.dtype))
+    out_specs = (kv_spec, v_spec)
+    scratch_shapes = [scratch(d), scratch(dv)]
+    if latent:
+        q_rope, k_rope, specs = _rope_parts(rope, 1, tile)
+        operands += [k_rope, q_rope]
+        in_specs += specs[::-1]
+        out_shape += (jax.ShapeDtypeStruct((bh, t, dr), jnp.float32),)
+        # a head's own part of the one plane's gradient
+        out_specs += (_tile_specs(tile, dr, 1)[0],)
+        scratch_shapes.append(scratch(dr))
+    dkv = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, plan=plan, scale=scale,
+                          rope=latent),
+        out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh, len(plan.k_major)),
-            in_specs=[kv_spec, kv_spec, q_spec, q_spec, qstat_spec,
-                      qstat_spec],
-            out_specs=(kv_spec, kv_spec),
-            scratch_shapes=[
-                pltpu.VMEM((tile, d), jnp.float32),
-                pltpu.VMEM((tile, d), jnp.float32),
-            ],
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch_shapes,
         ),
         compiler_params=params,
         interpret=interpret,
-        name=_call_name("flash_dkv", window),
-    )(*plan.tables(plan.k_major), kr, vr, qr, gr, lse, delta)
+        name=_call_name("flash_dkv", window, widths),
+    )(*plan.tables(plan.k_major), *operands)
+    if latent:
+        dq, dq_rope = dq
+        return (dq.reshape(b, h, t, d), dkv[0].reshape(b, h, t, d),
+                dkv[1].reshape(b, h, t, dv), dq_rope.reshape(b, h, t, dr),
+                dkv[2].reshape(b, h, t, dr))
     return (
         dq.reshape(b, h, t, d),
-        dk.reshape(b, h, t, d),
-        dv.reshape(b, h, t, d),
+        dkv[0].reshape(b, h, t, d),
+        dkv[1].reshape(b, h, t, dv),
     )
 
 
@@ -772,6 +906,95 @@ def flash_attention(q, k, v, causal=True, scale=None, interpret=None,
             )
         announce_fallback("flash_attention", q.shape, why, mode)
     return _attention_ref(q, k, v, causal, scale, window=window)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _latent(q_nope, q_rope, k_nope, k_rope, v, causal, scale, interpret,
+            window=0):
+    out, _, _ = _flash_forward(q_nope, k_nope, v, causal, scale, interpret,
+                               window=window, rope=(q_rope, k_rope))
+    return out
+
+
+def _latent_fwd(q_nope, q_rope, k_nope, k_rope, v, causal, scale,
+                interpret, window=0):
+    out, l, m = _flash_forward(q_nope, k_nope, v, causal, scale, interpret,
+                               window=window, rope=(q_rope, k_rope))
+    lse = m + jnp.log(jnp.maximum(l, 1e-30))
+    out = checkpoint_name(out, KEEP_OUT)          # as ``_flash_fwd``
+    lse = checkpoint_name(lse, KEEP_LSE)
+    return out, (q_nope, q_rope, k_nope, k_rope, v, out, lse)
+
+
+def _latent_bwd(causal, scale, interpret, window, res, g):
+    q_nope, q_rope, k_nope, k_rope, v, out, lse = res
+    dq, dk, dv, dq_rope, dk_rope = _pallas_bwd(
+        q_nope, k_nope, v, out, lse, g, causal, scale, interpret,
+        window=window, rope=(q_rope, k_rope))
+    # one plane for the heads: its gradient is the sum of their parts
+    return (dq, dq_rope, dk, dk_rope.sum(axis=1).astype(k_rope.dtype), dv)
+
+
+_latent.defvjp(_latent_fwd, _latent_bwd)
+
+
+def _latent_ref(q_nope, q_rope, k_nope, k_rope, v, causal, scale,
+                window=0):
+    """The latent op by ``_attention_ref``: a head's q and k put together
+    the long way, the one RoPE key spread to every head."""
+    k_rope = jnp.broadcast_to(k_rope[:, None], q_rope.shape)
+    return _attention_ref(jnp.concatenate([q_nope, q_rope], axis=-1),
+                          jnp.concatenate([k_nope, k_rope], axis=-1), v,
+                          causal, scale, window=window)
+
+
+def latent_mode(t, d_nope, d_rope, d_v, itemsize=2, interpret=None):
+    """(mode: "tpu" | "interpret" | "off" as ``latent_attention`` runs
+    shapes of these sizes here, the kernels' major tile or 0, why not
+    the kernel or "")."""
+    mode = resolve(interpret)
+    if mode == "off":
+        return mode, 0, ""
+    why = next((w for w in (_unfriendly(t, d) for d in (d_nope, d_rope,
+                                                        d_v)) if w), "")
+    if why:
+        return "off", 0, why
+    return mode, _major_tile(t, (d_nope + d_rope) * itemsize), ""
+
+
+def latent_attention(q_nope, q_rope, k_nope, k_rope, v, causal=True,
+                     scale=None, interpret=None, window=0):
+    """Attention whose scores run over ``D_nope + D_rope`` and whose
+    values are ``D_v`` wide (multi-head latent attention): q_nope and
+    k_nope [batch, heads, seq, D_nope], q_rope [batch, heads, seq,
+    D_rope], v [batch, heads, seq, D_v] -> [batch, heads, seq, D_v].
+    ``k_rope`` is [batch, seq, D_rope], ONE RoPE key for all the heads
+    of a sequence, which the kernels read as one plane (its block's
+    index leaves the head out; its gradient is the heads' sum).  A
+    head's score is ``(q_nope . k_nope + q_rope . k_rope) * scale``, two
+    matmuls into one S under the tile plan of ``flash_attention``;
+    ``scale`` defaults to ``(D_nope + D_rope) ** -0.5``.  Kernel,
+    interpreter or ``_latent_ref`` as ``flash_attention`` chooses."""
+    d_nope, d_rope, d_v = q_nope.shape[3], q_rope.shape[3], v.shape[3]
+    if k_rope.ndim != 3:
+        raise ValueError(
+            "latent_attention takes the RoPE key as one [batch, seq, "
+            "D_rope] plane for all the heads; got %s" % (k_rope.shape,))
+    scale = scale if scale is not None else (d_nope + d_rope) ** -0.5
+    _check_window(window, causal)
+    mode, _, why = latent_mode(q_nope.shape[2], d_nope, d_rope, d_v,
+                               q_nope.dtype.itemsize, interpret)
+    if mode != "off":
+        return per_batch_shard(
+            lambda *a: _latent(*a, causal, scale, mode == "interpret",
+                               window),
+            (q_nope, q_rope, k_nope, k_rope, v),
+        )
+    if why:
+        announce_fallback("latent_attention", q_nope.shape + (d_rope, d_v),
+                          why, resolve(interpret))
+    return _latent_ref(q_nope, q_rope, k_nope, k_rope, v, causal, scale,
+                       window=window)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))

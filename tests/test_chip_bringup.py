@@ -189,6 +189,27 @@ def test_chip_check_tiny_mode_has_a_grouped_matmul_leg(monkeypatch, capsys,
         assert set(row["errs"]) == {"fwd", "dlhs", "drhs"} and row["ok"]
 
 
+def test_chip_check_tiny_mode_has_a_latent_attention_leg(monkeypatch,
+                                                         capsys, tmp_path):
+    """Forward, dq and dk in their two parts and dv against the jnp
+    math; the one RoPE key's gradient as a head's part and as the
+    heads' sum."""
+    import json
+
+    monkeypatch.syspath_prepend(REPO)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("ELASTICDL_FLASH", "interpret")
+    import chip_check
+
+    assert chip_check.main(["--tiny", "latent"]) == 0
+    row, = [json.loads(line)
+            for line in capsys.readouterr().out.splitlines()
+            if line.startswith('{"case"')]
+    assert row["case"] == "latent/B1.H2.T256.QK192.V128" and row["ok"]
+    assert set(row["errs"]) == {"fwd", "dq", "dq_rope", "dk", "dk_rope",
+                                "dk_rope_sum", "dv"}
+
+
 def test_chip_check_tiny_mode_has_a_head_loss_leg(monkeypatch, capsys,
                                                   tmp_path):
     """Tied and untied, the loss and both gradients against float32

@@ -285,6 +285,40 @@ def test_flash_compiles_at_16384_positions_full_and_windowed(one_chip,
         name + tail for name in ("flash_fwd", "flash_dq", "flash_dkv"))
 
 
+def test_latent_attention_compiles_at_the_cells_shapes(one_chip):
+    """``kanana-2-30b-a3b.seq16384``'s attention: one sequence of
+    16,384, 32 heads, scores over 128 + 64 and values of 128, the RoPE
+    key one [T, 64] plane: forward, dq and
+    dk-dv through Mosaic at the 1,024 tile, each call carrying its
+    kernel's name and the widths (benchmark/kernels/latent_attention.py
+    tells them by it), dq's second result the RoPE part's, dk-dv's third
+    each head's part of the RoPE key's gradient in float32."""
+    shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.bfloat16,
+                                               sharding=one_chip)
+    heads, t = 32, 16384
+    wide, rope = shape(1, heads, t, 128), shape(1, heads, t, 64)
+    k_rope = shape(1, t, 64)
+    static = (True, 192 ** -0.5, False, 0)
+    assert fa.latent_mode(t, 128, 64, 128, interpret=False) == (
+        "tpu", 1024, "")
+
+    def fwd_bwd(q_nope, q_rope, k_nope, k_rope, v, g):
+        out, res = fa._latent_fwd(q_nope, q_rope, k_nope, k_rope, v,
+                                  *static)
+        return out, fa._latent_bwd(*static, res, g)
+
+    text = jax.jit(fwd_bwd).lower(
+        wide, rope, wide, k_rope, wide, wide).compile().as_text()
+    calls = {l.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]:
+             l.split(" custom-call(")[0]
+             for l in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l}
+    assert sorted(calls) == ["flash_dkv_qk192_v128", "flash_dq_qk192_v128",
+                             "flash_fwd_qk192_v128"]
+    assert "bf16[32,16384,64]" in calls["flash_dq_qk192_v128"]
+    assert "f32[32,16384,64]" in calls["flash_dkv_qk192_v128"]
+
+
 def test_head_loss_compiles_for_a_vocabulary_off_the_lanes(one_chip):
     """The head-and-loss op at [16,384, 37,984] (a quarter of 151,936:
     no multiple of 128), untied, hidden 2,560: forward + backward, the
