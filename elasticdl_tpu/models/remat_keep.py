@@ -145,22 +145,33 @@ def _latent_layer(cfg, rows, kept):
     ``kept`` read from the stack instead; bytes that backward makes):
     what ``ops/flash_attention.latent_attention`` takes and gives a
     head, [rows, heads, width] each (a RoPE part 128 lanes wide in
-    HBM), and the two projections' results and cotangents before the
-    head-major transposes.  32 heads x 16,384 rows make each of them
-    0.13 GB: 2.8 GB a layer with nothing kept, where attention with wk
-    and wv of the benchmark's other cells stays under their FFN's
-    term."""
+    HBM), and as much again for the two projections' results and
+    cotangents.  32 heads x 16,384 rows make each of them 0.13 GB: 2.8
+    GB a layer with nothing kept, where attention with wk and wv of the
+    benchmark's other cells stays under their FFN's term.
+
+    The ``flat_*`` widths were written for token-major copies of the
+    projections' results and cotangents.  The program makes none any
+    more (``models/transformer._project_latent`` writes the kernels'
+    planes itself), and the chip's peak stands where it stood with
+    them counted: 16.003 GB against 16.005 with the same eleven names,
+    and over six kept lists of 2.9-4.4 GB this count reads +0.03 ..
+    +0.39 GB over the chip where the count without them reads 0.6-1.0
+    GB under (PERF.md section 6, PR 38; tests/test_remat_keep.py holds
+    the six).  So they stay, as what the estimate needs to describe the
+    chip and not as arrays anyone can name: where in the step that peak
+    stands is an open question (PERF.md section 7)."""
     size = jnp.dtype(cfg.dtype).itemsize
     _, d_nope, d_rope, d_v = cfg.latent
     unit = rows * cfg.num_heads * size
     q, kv = d_nope + _lanes(d_rope), d_nope + d_v
     flat_q, flat_kv = d_nope + d_rope, d_nope + d_v
     made = {"flash": d_v, "q": q + flat_q, "kv": kv + flat_kv}
-    # the output again as ``wo`` takes it, token-major: never kept
+    # the output a second time: never kept
     residuals = unit * (d_v + sum(width for label, width in made.items()
                                   if label not in kept))
-    # dO in both layouts, dq, dk_nope and dv, each head's float32 part
-    # of the RoPE key's gradient, the projections' cotangents
+    # dO twice, dq, dk_nope and dv, each head's float32 part of the
+    # RoPE key's gradient, the ``flat_*`` widths
     backward = unit * (2 * d_v + q + kv + flat_q + flat_kv) + (
         rows * cfg.num_heads * _lanes(d_rope) * 4)
     return residuals, backward

@@ -505,22 +505,48 @@ def _rmsnorm(x, scale, eps=1e-6):
     return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 
-def _rope(x, positions, theta=10000.0):
-    """Rotary embeddings (rotate-half) at base ``theta``; x: [B, T, H,
-    D]."""
-    d = x.shape[-1]
+def _rope_tables(d, positions, theta):
+    """(cos, sin) [T, d / 2] float32 of RoPE's angles at base ``theta``
+    for heads of ``d``."""
     half = d // 2
     freqs = jnp.exp(
         -np.log(float(theta)) * jnp.arange(0, half, dtype=jnp.float32)
         / half
     )
     angles = positions[:, None].astype(jnp.float32) * freqs[None, :]
-    cos = jnp.cos(angles)[None, :, None, :]
-    sin = jnp.sin(angles)[None, :, None, :]
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def _rope(x, positions, theta=10000.0):
+    """Rotary embeddings (rotate-half) at base ``theta``; x: [B, T, H,
+    D]."""
+    half = x.shape[-1] // 2
+    cos, sin = (table[None, :, None, :]
+                for table in _rope_tables(x.shape[-1], positions, theta))
     x1, x2 = x[..., :half], x[..., half:]
     rotated = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
     )
+    return rotated.astype(x.dtype)
+
+
+def _rope_heads_first(x, positions, theta):
+    """:func:`_rope` of x [B, H, T, D], positions along T, in the
+    layout it has: the same arithmetic written ``x * [cos, cos] + (x P)
+    * [-sin, sin]``, P the D x D permutation that swaps the halves (a
+    product of 0 / 1 entries summed in float32: exact).  A slice or
+    concatenate at D / 2 = 32 lanes XLA's TPU backend writes out as
+    arrays of their own, each padded to 128 lanes in HBM (five ops and
+    3.7 ms a layer at 32 heads x 16,384, forward and backward); the
+    product it fuses with the elementwise work into one pass
+    (docs/designs/attention.md)."""
+    d = x.shape[-1]
+    cos, sin = _rope_tables(d, positions, theta)
+    swap = jnp.asarray(np.roll(np.eye(d), d // 2, axis=1), x.dtype)
+    swapped = jnp.einsum("bhtd,de->bhte", x, swap,
+                         preferred_element_type=jnp.float32)
+    rotated = (x * jnp.concatenate([cos, cos], axis=-1)
+               + swapped * jnp.concatenate([-sin, sin], axis=-1))
     return rotated.astype(x.dtype)
 
 
@@ -652,30 +678,40 @@ def _project_latent(h, w, cfg, positions, rope=True):
     ``ops/flash_attention.latent_attention`` takes them: q_nope
     [B, H, T, Dn], q_rope [B, H, T, Dr], k_nope [B, H, T, Dn], k_rope
     [B, T, Dr] (one key for all the heads), v [B, H, T, Dv].  RoPE turns
-    q_rope and k_rope alone; the latent has an RMSNorm of its own."""
+    q_rope and k_rope alone; the latent has an RMSNorm of its own.
+
+    Each head-major operand is its own product of the input with a
+    per-head view of the one weight (``wq`` as [dim, H, Dn + Dr] cut at
+    Dn, ``w_kv_b`` as [rank, H, Dn + Dv] cut at Dn: weight-sized
+    slices), written [B, H, T, .] by the matmul itself, so no [T, H,
+    width] product is sliced, padded or transposed on its way to the
+    kernels, nor a cotangent on its way back; the two views' gradients
+    are joined weight-sized.  Splitting a product by output columns
+    changes no sum."""
     compute_dtype = jnp.dtype(cfg.dtype)
-    B, T = h.shape[0], h.shape[1]
     H = cfg.num_heads
     rank, dn, dr, dv = cfg.latent
-    q = (h @ w["wq"].astype(compute_dtype)).reshape(B, T, H, dn + dr)
+    heads_first = lambda x, weight: jnp.einsum("btd,dhk->bhtk", x, weight)
+    wq = w["wq"].astype(compute_dtype).reshape(-1, H, dn + dr)
+    q_nope = heads_first(h, wq[..., :dn])
+    q_rope = heads_first(h, wq[..., dn:])
     # the latent and the RoPE key as the projection gives them: [T,
-    # rank + Dr] a layer, what one matmul makes k_nope and v from
+    # rank + Dr] a layer, what two matmuls make k_nope and v from
     c = checkpoint_name(h @ w["w_kv_a"].astype(compute_dtype),
                         remat_keep.KEEP_LATENT)
-    kv = (_rmsnorm(c[..., :rank], w["kv_norm"].astype(compute_dtype),
-                   cfg.norm_eps)
-          @ w["w_kv_b"].astype(compute_dtype)).reshape(B, T, H, dn + dv)
-    q_rope, k_rope = q[..., dn:], c[..., None, rank:]
+    latent = _rmsnorm(c[..., :rank], w["kv_norm"].astype(compute_dtype),
+                      cfg.norm_eps)
+    w_kv_b = w["w_kv_b"].astype(compute_dtype).reshape(rank, H, dn + dv)
+    k_rope = c[..., None, rank:]
     if rope:
-        q_rope = _rope(q_rope, positions, cfg.rope_theta)
+        q_rope = _rope_heads_first(q_rope, positions, cfg.rope_theta)
         k_rope = _rope(k_rope, positions, cfg.rope_theta)
-    heads_first = lambda a: a.transpose(0, 2, 1, 3)
     name = checkpoint_name
-    return (name(heads_first(q[..., :dn]), remat_keep.KEEP_Q),
-            name(heads_first(q_rope), remat_keep.KEEP_Q),
-            name(heads_first(kv[..., :dn]), remat_keep.KEEP_KV),
+    return (name(q_nope, remat_keep.KEEP_Q),
+            name(q_rope, remat_keep.KEEP_Q),
+            name(heads_first(latent, w_kv_b[..., :dn]), remat_keep.KEEP_KV),
             k_rope[:, :, 0],
-            name(heads_first(kv[..., dn:]), remat_keep.KEEP_KV))
+            name(heads_first(latent, w_kv_b[..., dn:]), remat_keep.KEEP_KV))
 
 
 @functools.lru_cache(maxsize=None)
@@ -693,14 +729,19 @@ def _latent_mix(h, w, cfg, positions, kind):
     """LatentAttention(h) of the normed input, [B, T, dim]: the five
     operands, the op, ``W_o``."""
     compute_dtype = jnp.dtype(cfg.dtype)
-    B, T = h.shape[0], h.shape[1]
+    T = h.shape[1]
     announce_latent(cfg.num_heads, T, cfg.latent, *latent_mode(
         T, *cfg.latent[1:], compute_dtype.itemsize))
     attn = latent_attention(
         *_project_latent(h, w, cfg, positions, kind.rope), causal=True,
         window=kind.window)
-    attn = attn.transpose(0, 2, 1, 3).reshape(B, T, -1)
-    return attn @ w["wo"].astype(compute_dtype)
+    # W_o contracts the kernels' [B, H, T, Dv] output over (head, width)
+    # as it stands: no token-major copy of it is made first.  Reshaped
+    # before the cast, so that the gradient stays [H, Dv, dim] through
+    # its convert and XLA does not merge (head, width) in the product,
+    # which costs a [H, Dv, T] copy of the output
+    wo = w["wo"].reshape(cfg.num_heads, -1, cfg.dim).astype(compute_dtype)
+    return jnp.einsum("bhtk,hkd->btd", attn, wo)
 
 
 def _latent_attention(x, w, cfg, positions, kind):

@@ -11,6 +11,9 @@ described inside a fixture, never at import: only the worker that is
 given this file loads the TPU library.
 """
 
+import json
+import math
+import os
 import re
 
 import jax
@@ -43,6 +46,15 @@ def one_chip():
     yield SingleDeviceSharding(topo.devices[0])
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+def _model_params(config):
+    """A benchmark configuration's ``model_params``, as the cell's job
+    gives them to ``model_spec``."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           config + ".json")) as fh:
+        return json.load(fh)["cli"]["model_params"]
 
 
 @pytest.mark.parametrize("t,d,dtype,window", [
@@ -319,6 +331,114 @@ def test_latent_attention_compiles_at_the_cells_shapes(one_chip):
     assert "f32[32,16384,64]" in calls["flash_dkv_qk192_v128"]
 
 
+def _head_sized_ops(text, rows, heads):
+    """(standalone, matmul fusions, Mosaic calls): the instructions of
+    a compiled program, outside every fusion's body, with a result of
+    rows x heads x width elements (a dimension ``rows``, one ``heads``,
+    width 32 and up), by what makes them.  Standalone is what is
+    neither a Mosaic call nor a fusion around a convolution or dot:
+    the transposes, pads, slices, copies and elementwise fusions that
+    move an activation and compute nothing a matmul could not write
+    itself."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            bodies[name].append(line)
+    called = lambda line: re.search(r"calls=%?([\w.\-]+)", line).group(1)
+    fused = {called(line) for lines in bodies.values() for line in lines
+             if " fusion(" in line}
+    matmul = {name for name in fused if any(re.search(
+        r" (convolution|dot)\(", line) for line in bodies[name])}
+    found = {"standalone": [], "matmul": [], "mosaic": []}
+    for name, lines in bodies.items():
+        if name in fused:
+            continue
+        for line in lines:
+            op = re.match(
+                r"\s*(?:ROOT )?%?(\S+) = \(?(.*?)\)? ([\w-]+)\(", line)
+            if not op or op.group(3) in (
+                    "parameter", "get-tuple-element", "tuple", "bitcast",
+                    "while", "conditional", "call", "constant"):
+                continue
+            sized = []
+            for dims in re.findall(r"\w+\[([\d,]+)\]", op.group(2)):
+                dims = [int(d) for d in dims.split(",")]
+                size = math.prod(dims)
+                if (rows in dims and heads in dims
+                        and size >= 32 * rows * heads
+                        and not size % (rows * heads)):
+                    sized.append(dims)
+            if not sized:
+                continue
+            if "tpu_custom_call" in line:
+                kind = "mosaic"
+            elif op.group(3) == "custom-call":
+                continue              # a buffer's allocation, no move
+            elif op.group(3) == "fusion" and called(line) in matmul:
+                kind = "matmul"
+            else:
+                kind = "standalone"
+            found[kind].append((op.group(1), sized))
+    return found["standalone"], found["matmul"], found["mosaic"]
+
+
+def test_a_latent_layer_moves_no_activation_between_matmuls_and_kernels(
+        one_chip, monkeypatch):
+    """One latent-attention layer of ``kanana-2-30b-a3b.seq16384``
+    (``_latent_mix`` on one sequence of 16,384: the five projections,
+    RoPE, the three kernels, ``W_o``), forward + backward through the
+    TPU's compiler: between ``W_q`` / ``W_kv_b`` / ``W_o`` and the
+    flash calls **no** instruction stands alone whose result has rows x
+    heads x width elements: every such array is written by a matmul
+    fusion or a Mosaic call in the layout its reader takes.  Seven
+    matmul fusions write one: q_nope, k_nope, v and the RoPE part of
+    ``W_q``'s product, RoPE's 64 x 64 permutation product forward and
+    its transpose backward, ``W_o``'s backward (dO with the kernels'
+    row sums); three Mosaic calls.
+
+    The parent (d5e277b, one product a weight on [T, H, 192] / [T, H,
+    256], sliced, turned and transposed) compiled to **12** standalone
+    ops here: two slice-and-transpose fusions and RoPE's two forward,
+    the output's token-major copy, RoPE's three backward, two pads and
+    two copies of the cotangents; 36 in the whole step where this tree
+    has 5 (three loads and a store of kept values on the scan's stack,
+    a copy in the leading layer).  On the chip those 12 were 51 ms of a
+    785 ms step (PERF.md section 6, PR 38).  No time is read here: a
+    count of what the compiler emits is what can be held without a
+    chip."""
+    from elasticdl_tpu.ops.mode import SWITCH
+
+    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
+    spec = tfm.model_spec(**_model_params("kanana-2-30b-a3b"))
+    cfg = spec.config
+    rows, heads = 16384, cfg.num_heads
+    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+    layer = params["layers"]["lead"]["0"]
+    on_chip = lambda a, dtype=None: jax.ShapeDtypeStruct(
+        a.shape, dtype or a.dtype, sharding=one_chip)
+    w = {name: on_chip(layer[name])
+         for name in ("wq", "w_kv_a", "kv_norm", "w_kv_b", "wo")}
+    h = on_chip(jax.ShapeDtypeStruct((1, rows, cfg.dim), jnp.bfloat16))
+
+    def fwd_bwd(h, w, g):
+        out, vjp = jax.vjp(
+            lambda h, w: tfm._latent_mix(h, w, cfg, jnp.arange(rows),
+                                         cfg.kinds[0]), h, w)
+        return out, vjp(g)
+
+    text = jax.jit(fwd_bwd).lower(h, w, h).compile().as_text()
+    standalone, matmul, mosaic = _head_sized_ops(text, rows, heads)
+    assert standalone == [], standalone
+    assert len(mosaic) == 3, mosaic
+    assert len(matmul) <= 7, matmul
+
+
 def test_head_loss_compiles_for_a_vocabulary_off_the_lanes(one_chip):
     """The head-and-loss op at [16,384, 37,984] (a quarter of 151,936:
     no multiple of 128), untied, hidden 2,560: forward + backward, the
@@ -360,9 +480,6 @@ def test_the_banded_stacks_step_fits_a_v5e_as_remat_keep_predicts(
     against the compiler's 15.28; PR 35's eleven names read 15.69
     against 14.39).  Both kinds of flash call are in the one program,
     and no forward runs twice."""
-    import json
-    import os
-
     import optax
 
     from elasticdl_tpu.models import remat_keep as rk
@@ -370,11 +487,7 @@ def test_the_banded_stacks_step_fits_a_v5e_as_remat_keep_predicts(
     from elasticdl_tpu.ops.mode import SWITCH
 
     monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "smallthinker-21b-a3b.json")) as fh:
-        model_params = json.load(fh)["cli"]["model_params"]
-    spec = tfm.model_spec(**model_params)
+    spec = tfm.model_spec(**_model_params("smallthinker-21b-a3b"))
     on_chip = lambda tree: jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         tree)
@@ -441,9 +554,6 @@ def test_the_mixed_stacks_step_fits_a_v5e_as_remat_keep_predicts(
     where no gradient of the stack exists yet (1.8 GB counted) and the
     dispatch's temporaries and the tied head's cotangent (2.8 GB) stand
     where the estimate has the dense layer's 1.54: PERF.md section 7."""
-    import json
-    import os
-
     import optax
 
     from elasticdl_tpu.models import remat_keep as rk
@@ -451,11 +561,7 @@ def test_the_mixed_stacks_step_fits_a_v5e_as_remat_keep_predicts(
     from elasticdl_tpu.ops.mode import SWITCH
 
     monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "lfm2-24b-a2b.json")) as fh:
-        model_params = json.load(fh)["cli"]["model_params"]
-    spec = tfm.model_spec(**model_params)
+    spec = tfm.model_spec(**_model_params("lfm2-24b-a2b"))
     on_chip = lambda tree: jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         tree)

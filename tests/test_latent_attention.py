@@ -338,6 +338,107 @@ def test_equal_widths_trace_the_kernels_they_traced():
         assert len(str(jaxpr)) == was[name]["len"], name
 
 
+# -- the projections: head-major from the matmuls themselves ----------------------
+
+
+def _token_major_projections(h, w, cfg, positions, rope):
+    """``_project_latent`` as it was before the projections wrote the
+    kernels' planes themselves: one product a weight on [B, T, H, .],
+    sliced at the no-position width in the minor dimension, RoPE on
+    [B, T, H, 64], each part transposed to [B, H, T, .]."""
+    dtype = jnp.dtype(cfg.dtype)
+    B, T = h.shape[:2]
+    H = cfg.num_heads
+    rank, dn, dr, dv = cfg.latent
+    q = (h @ w["wq"].astype(dtype)).reshape(B, T, H, dn + dr)
+    c = h @ w["w_kv_a"].astype(dtype)
+    kv = (tfm._rmsnorm(c[..., :rank], w["kv_norm"].astype(dtype),
+                       cfg.norm_eps)
+          @ w["w_kv_b"].astype(dtype)).reshape(B, T, H, dn + dv)
+    q_rope, k_rope = q[..., dn:], c[..., None, rank:]
+    if rope:
+        q_rope = tfm._rope(q_rope, positions, cfg.rope_theta)
+        k_rope = tfm._rope(k_rope, positions, cfg.rope_theta)
+    heads_first = lambda a: a.transpose(0, 2, 1, 3)
+    return (heads_first(q[..., :dn]), heads_first(q_rope),
+            heads_first(kv[..., :dn]), k_rope[:, :, 0],
+            heads_first(kv[..., dn:]))
+
+
+def _token_major_mix(h, w, cfg, positions, kind):
+    """``_latent_mix`` over those: the op's [B, H, T, Dv] output copied
+    to [B, T, H * Dv] before ``W_o``."""
+    B, T = h.shape[:2]
+    attn = fa.latent_attention(
+        *_token_major_projections(h, w, cfg, positions, kind.rope),
+        causal=True, window=kind.window)
+    attn = attn.transpose(0, 2, 1, 3).reshape(B, T, -1)
+    return attn @ w["wo"].astype(jnp.dtype(cfg.dtype))
+
+
+LATENT_WEIGHTS = ("wq", "w_kv_a", "kv_norm", "w_kv_b", "wo")
+
+
+@pytest.mark.parametrize("batch", [1, 2])
+@pytest.mark.parametrize("rope", [True, False])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_projections_equal_the_token_major_ones(monkeypatch, dtype,
+                                                    rope, batch):
+    """The five operands, ``_latent_mix``'s result and the gradients of
+    the five weights and of the input, from products that write [B, H,
+    T, .] themselves (two views of ``wq`` and of ``w_kv_b``, RoPE's
+    halves swapped by a permutation product, ``W_o`` over (head,
+    width)) against the form they replace.  A product split by output
+    columns sums what it summed: float32 lands within its rounding
+    (2e-7 read), and in bfloat16 every operand, the result and the
+    gradients of ``wq``, ``w_kv_b`` and ``wo`` are the same bits.  An
+    input gradient is now the sum of one bfloat16-rounded product a
+    view where it was one product a weight, so ``dh`` and, through the
+    latent, ``w_kv_a``'s and ``kv_norm``'s gradients move by 3-8e-3 of
+    their norm in bfloat16: a rounding, far inside what the op is held
+    to on the chip (``chip_check.py latent``: 2-4e-2)."""
+    monkeypatch.setenv("ELASTICDL_FLASH", "off")
+    spec = tfm.model_spec(**dict(TINY, dtype=dtype))
+    cfg = spec.config
+    params = jax.jit(spec.init_fn)(jax.random.PRNGKey(5))
+    layer = jax.tree_util.tree_map(lambda a: a[0],
+                                   params["layers"]["period"]["0"])
+    w = {name: layer[name] for name in LATENT_WEIGHTS}
+    T = cfg.max_seq_len
+    draw = lambda seed: jax.random.normal(
+        jax.random.PRNGKey(seed), (batch, T, cfg.dim), jnp.float32
+    ).astype(cfg.dtype)
+    h, g = draw(6), draw(7)
+    positions = jnp.arange(T)
+    kind = tfm.Kind("a", False, 0, rope)
+    operands, mix = (1e-6, 1e-6) if dtype == "float32" else (3e-3, 2e-2)
+
+    got = tfm._project_latent(h, w, cfg, positions, rope)
+    want = _token_major_projections(h, w, cfg, positions, rope)
+    for name, a, b in zip(("q_nope", "q_rope", "k_nope", "k_rope", "v"),
+                          got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert _apart(a.astype(jnp.float32),
+                      b.astype(jnp.float32)) <= operands, name
+
+    def run(fn):
+        out, vjp = jax.vjp(
+            lambda h, w: fn(h, w, cfg, positions, kind), h, w)
+        return out, vjp(g)
+
+    f32 = lambda tree: jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.float32), tree)
+    (out, (dh, dw)), (ref, (ref_dh, ref_dw)) = (
+        f32(jax.jit(lambda: run(tfm._latent_mix))()),
+        f32(jax.jit(lambda: run(_token_major_mix))()))
+    assert out.shape == (batch, T, cfg.dim)
+    assert _apart(out, ref) <= mix
+    assert _apart(dh, ref_dh) <= mix
+    for name in LATENT_WEIGHTS:
+        assert dw[name].shape == w[name].shape, name
+        assert _apart(dw[name], ref_dw[name]) <= mix, name
+
+
 # -- the shared expert and the shares -------------------------------------------
 
 
