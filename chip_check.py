@@ -107,7 +107,9 @@ TOLERANCES["dw"] = _F32
 # its norm: 0.9e-2 in --tiny on the CPU, 4.2e-2 on the chip at the
 # published widths, where the two paths' bf16 roundings send a token in
 # fifty to other experts; a reference path that left the rows of absent
-# experts to the backend read 1.0 there: my chip runs, PR 31) and
+# experts to the backend read 1.0 there: my chip runs, PR 31; the gated,
+# output-normed ``wwaww`` stack 3.7e-2, and 2.2e-4 in its loss: my chip
+# run, PR 40) and
 # (b) the loss of the same weights in float32 through the references
 # (``stack_loss``, 7e-4 in --tiny).  Gradients are not held to the
 # float32 path: bf16's roundings through five layers, and a token's
@@ -419,6 +421,29 @@ MIXED_STACKS = {
              rope_theta=1.5e6, ffn_activation="relu",
              moe_route_before_op=True, moe_aux_weight=0,
              tied_embeddings=False, embed_scale=1.0)),
+    # benchmark/configs/trinity-mini.json at a sequence of 2,048 and an
+    # eighth of the slice of the vocabulary, the window cut from 2,048
+    # to 512 so that it bites inside what the reference can hold.  In
+    # --tiny every token takes all 8 experts: with a choice of 2, 256
+    # tokens through five layers of attention kernels are few enough
+    # that the two paths' bf16 roundings flip choices worth 8-13% of the
+    # tree's norm (2.3e-2 without a choice; on the chip, 8 of 128 chosen
+    # at the published widths, 3.7e-2: my chip run, PR 40)
+    "wwaww": (
+        dict(vocab_size=256, dim=128, num_heads=4, num_kv_heads=2,
+             head_dim=64, seq_len=256, window=128, dense_ffn_dim=192,
+             ffn_dim=128, moe_experts=8, moe_top_k=8, moe_experts_held=2,
+             embed_multiplier=128 ** 0.5),
+        dict(vocab_size=3128, dim=2048, num_heads=32, num_kv_heads=4,
+             head_dim=128, seq_len=2048, window=512, dense_ffn_dim=6144,
+             ffn_dim=1024, moe_experts=128, moe_top_k=8,
+             moe_experts_held=16, embed_multiplier=2048 ** 0.5),
+        dict(num_layers=5, layer_pattern="wwaww", rope_kinds="w",
+             rope_theta=10000, qk_norm="head", attn_gate=True,
+             post_norms=True, dense_layers=1, moe_shared_experts=1,
+             moe_router="sigmoid_bias", moe_norm_topk=True,
+             moe_route_scale=2.826, moe_aux_weight=0, norm_eps=1e-5,
+             tied_embeddings=False)),
 }
 
 
@@ -430,8 +455,11 @@ def check_mixed_stack(tiny, spill=False, stack="caccc"):
     or full attention without positional encoding then windowed
     attention with RoPE, heads wider than the hidden size divides into,
     the router read before attention, ReGLU over a share of the
-    experts, the ``smallthinker-21b-a3b`` cell's; each at one short
-    sequence), bf16
+    experts, the ``smallthinker-21b-a3b`` cell's; or the gated,
+    output-normed block over windowed-RoPE and full-NoPE layers at 32
+    query heads on 4, a per-head QK norm, a muP-scaled embedding and a
+    shared expert beside the share, the ``trinity-mini`` cell's; each at
+    one short sequence), bf16
     through the kernels, against the same weights in float32 through
     the references at the highest matmul precision (the loss), and the
     same bf16 program through the references (the gradients).
@@ -830,6 +858,8 @@ def _cases(tiny):
            lambda: check_mixed_stack(tiny, spill=True))
     yield ("mixed_stack/awww.share",
            lambda: check_mixed_stack(tiny, stack="awww"))
+    yield ("mixed_stack/wwaww.gated.share",
+           lambda: check_mixed_stack(tiny, stack="wwaww"))
     # The benchmark's two heads: OLMoE's untied, OLMo's tied embedding.
     hdim, vocab, heads = (64, 256, ((2, 24, False), (2, 24, True))) if tiny \
         else (2048, 50304, ((4, 4096, False), (8, 2048, True)))

@@ -35,6 +35,9 @@ from elasticdl_tpu.ops import (batch_shard, flash_attention, moe_dispatch,
 # a dense FFN's two products.  A short convolution's are the op's:
 # its input (the projection's output) and its result.
 KEEP_Q, KEEP_K, KEEP_V = "attn_q", "attn_k", "attn_v"
+# The projection behind a gate on attention's output (``cfg.attn_gate``),
+# [rows, heads * head_dim] before its sigmoid.
+KEEP_ATTN_GATE = "attn_gate"
 # Latent attention's: the down-projection's result (the latent before
 # its norm, and the one RoPE key before RoPE), and the k_nope and v a
 # matmul makes from it for every head.
@@ -97,6 +100,11 @@ def _entries(cfg, rows):
     else:
         entries.append(("qkv", (KEEP_Q, KEEP_K, KEEP_V),
                         rows * (h + 2 * g) * d * size, attention))
+        if cfg.attn_gate:
+            # a product of the hidden size as wide as q: a byte's worth
+            # the same, ~13
+            entries.append(("gate", (KEEP_ATTN_GATE,), rows * h * d * size,
+                            attention))
     entries.append(("stream", (KEEP_STREAM,), rows * e * size, len(kinds)))
     f, dense_f = cfg.mlp_dim, cfg.dense_ffn_dim if x else cfg.mlp_dim
     # What a kept GB is worth, ms (``table``).  The dispatch's buffers
@@ -203,9 +211,10 @@ def table(cfg, rows):
     attention's: the latent with the RoPE key, [rows, rank + D_rope],
     then q; the k_nope and v that one matmul over the rank makes from
     the latent again are worth rank / dim of q's, ~3, and stand among
-    the others), the others by that worth, a share's dispatch buffers
-    at the part of
-    their rows a balanced router fills, a half).  Elementwise
+    the others; behind q, k, v the projection of a gate on attention's
+    output, as wide as q and made as q is), the others by that worth, a
+    share's dispatch buffers at the part of their rows a balanced
+    router fills, a half).  Elementwise
     work (norms, RoPE's rotation, the
     activation, the weighted combine) is not here: it is cheap and its
     inputs are what is kept.  An entry is there if a layer of the model
@@ -264,10 +273,13 @@ def step_bytes(cfg, params, rows, kept=()):
        for: its copy is read by the forward's first gather alone and
        its gradient is the last thing the backward makes.
 
-    Held to the chips' measured peaks for the five cells of the
-    benchmark (tests/test_remat_keep.py: -0.1 / +0.9 GB) and to the
-    TPU compiler's own count of the two share cells' whole steps
-    (tests/test_flash_compile_tpu.py: over, by under 0.5 GB)."""
+    Held to the chips' measured peaks for the cells of the benchmark
+    (tests/test_remat_keep.py: -0.1 / +0.9 GB; +0.95 in the one cell
+    whose stack has both a leading dense layer and 2.4 GB of
+    gradients: ``OVER`` there says where the sum is off, and it is
+    the sum, no term of it) and to the TPU compiler's own count of the
+    two share cells' whole steps (tests/test_flash_compile_tpu.py:
+    over, by under 0.5 GB)."""
     dtype = jnp.dtype(cfg.dtype)
     size = dtype.itemsize
     leaves = jax.tree_util.tree_leaves

@@ -31,7 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from elasticdl_tpu.models import remat_keep
 from elasticdl_tpu.models.spec import ModelSpec
-from elasticdl_tpu.ops import short_conv
+from elasticdl_tpu.ops import batch_shard, short_conv
 from elasticdl_tpu.ops.flash_attention import (flash_attention,
                                                latent_attention,
                                                latent_mode, logger)
@@ -68,6 +68,24 @@ class TransformerConfig:
     # ``head_dim`` values, with one scale of ``head_dim`` that the heads
     # share (LFM2).
     qk_norm: bool | str = False
+    # A block whose sublayers' OUTPUTS are normed too: ``x + n2(Op(n1(x)))``
+    # then ``x + n4(FFN(n3(x)))``, the two extra RMSNorms (``ln1_post``,
+    # ``ln2_post``: learned scales drawn at 1 that AdamW does not decay)
+    # on what the operator and the FFN return, before the residual add,
+    # whatever the operator and whatever the FFN (``_residual``).
+    post_norms: bool = False
+    # A gate on attention's output: ``w_attn_gate`` [dim, heads *
+    # head_dim] reads the normed input that q, k and v read, and every
+    # value of the kernel's output is multiplied by the sigmoid of its
+    # own gate, in the compute dtype, before ``wo``.  Attention with wk
+    # and wv alone: latent attention and a short-convolution layer
+    # refuse it at construction.
+    attn_gate: bool = False
+    # What a token's row is multiplied by where the table is read as the
+    # model's input (muP: sqrt(dim)), in the compute dtype; never where
+    # a tied head reads the table.  Not ``embed_scale``, which is the
+    # standard deviation the table is drawn at.  1.0 = no multiply.
+    embed_multiplier: float = 1.0
     # RoPE's base, and the attention kinds of ``layer_pattern`` whose q
     # and k it turns: a kind that is not named has no positional
     # encoding at all ("w": windowed layers alone, full ones NoPE).
@@ -194,6 +212,13 @@ class TransformerConfig:
         if not self.head_dim:
             object.__setattr__(self, "head_dim",
                                self.dim // self.num_heads)
+        if self.attn_gate and (self.kv_latent_rank
+                               or "c" in self.layer_pattern):
+            raise ValueError(
+                "attn_gate is a gate on the output of attention with wk "
+                "and wv: latent attention (kv_latent_rank=%d) and a short "
+                "convolution (layer_pattern=%r) have no w_attn_gate"
+                % (self.kv_latent_rank, self.layer_pattern))
 
     @property
     def kv_heads(self):
@@ -334,8 +359,20 @@ def _no_latent(cfg, what):
             % (what, cfg.kv_latent_rank))
 
 
+def _plain_block(cfg, what):
+    if cfg.post_norms or cfg.attn_gate:
+        raise NotImplementedError(
+            "%s does not run a block with norms on its sublayers' outputs "
+            "(post_norms=%s: ln1_post, ln2_post) or a gate on attention's "
+            "output (attn_gate=%s: w_attn_gate): decoding and the "
+            "pipeline's stages restate the plain block, and a mesh has "
+            "no spec for the three weights (ROADMAP B7)"
+            % (what, cfg.post_norms, cfg.attn_gate))
+
+
 def _uniform_only(cfg, what):
     _no_latent(cfg, what)
+    _plain_block(cfg, what)
     if stack_plan(cfg) is not None:
         raise NotImplementedError(
             "%s does not run a stack whose layers differ (layer_pattern="
@@ -365,6 +402,12 @@ def _init_layers(k_attn, k_mlp, cfg, kind, stack):
     E, H, D, G = cfg.dim, cfg.num_heads, cfg.head_dim, cfg.kv_heads
     keys = jax.random.split(k_attn, 6)
     layers = {"ln1": _norm_init(*stack, E), "ln2": _norm_init(*stack, E)}
+    if cfg.post_norms:
+        layers.update(ln1_post=_norm_init(*stack, E),
+                      ln2_post=_norm_init(*stack, E))
+    if kind.op == "a" and cfg.attn_gate:
+        layers["w_attn_gate"] = _dense_init(
+            jax.random.fold_in(k_attn, 6), *stack, E, H * D)
     if kind.op == "a" and cfg.latent:
         rank, dn, dr, dv = cfg.latent
         layers.update(
@@ -744,14 +787,6 @@ def _latent_mix(h, w, cfg, positions, kind):
     return jnp.einsum("bhtk,hkd->btd", attn, wo)
 
 
-def _latent_attention(x, w, cfg, positions, kind):
-    """x + LatentAttention(norm(x)) -> (x, None): nothing is cached
-    (``_no_latent``)."""
-    h = _rmsnorm(x, w["ln1"].astype(jnp.dtype(cfg.dtype)), cfg.norm_eps)
-    x = x + _latent_mix(h, w, cfg, positions, kind)
-    return checkpoint_name(x, remat_keep.KEEP_STREAM), None
-
-
 def _gated_mlp(h, w, cfg, weights, keep):
     """``(act(h W_gate) * (h W_up)) W_down`` with the three ``weights``
     named, the gate and up products named ``keep`` for a remat policy."""
@@ -770,45 +805,67 @@ def _shared_expert(h, w, cfg):
                        remat_keep.KEEP_SHARED_UP))
 
 
+def _residual(x, out, w, cfg, mesh, post):
+    """``x + post(out)``: where a sublayer's result ``out`` joins the
+    stream, the operator's and the FFN's alike; ``post`` names the
+    RMSNorm it passes first in a block with ``cfg.post_norms``."""
+    if cfg.post_norms:
+        out = _rmsnorm(out, w[post].astype(jnp.dtype(cfg.dtype)),
+                       cfg.norm_eps)
+    return x + _constrain(out, mesh, P("dp", "sp", None))
+
+
 def _ffn(x, w, cfg, mesh, dense=False, route=None):
-    """x + FFN(norm(x)) -> (x, aux, stats, load); the last three are
-    the MoE's (:func:`_moe_ffn`, which ``route`` is for), zeros and None
-    for a dense FFN (a model without experts, or a ``dense`` layer of
-    one with)."""
-    compute_dtype = jnp.dtype(cfg.dtype)
-    act_spec = P("dp", "sp", None)
-    h = _rmsnorm(x, w["ln2"].astype(compute_dtype), cfg.norm_eps)
+    """x + post(FFN(norm(x))) -> (x, aux, stats, load); the last three
+    are the MoE's (:func:`_moe_ffn`, which ``route`` is for), zeros and
+    None for a dense FFN (a model without experts, or a ``dense`` layer
+    of one with)."""
+    h = _rmsnorm(x, w["ln2"].astype(jnp.dtype(cfg.dtype)), cfg.norm_eps)
     if cfg.moe_experts and not dense:
         out, aux, stats, load = _moe_ffn(h, w, cfg, mesh, route)
         if cfg.shared_dim:
             out = out + _shared_expert(h, w, cfg)
-        return x + _constrain(out, mesh, act_spec), aux, stats, load
-    x = x + _constrain(
-        _gated_mlp(h, w, cfg, ("w_gate", "w_up", "w_down"),
-                   (remat_keep.KEEP_GATE, remat_keep.KEEP_UP)),
-        mesh, act_spec,
-    )
-    return x, jnp.float32(0.0), None, None
+    else:
+        out = _gated_mlp(h, w, cfg, ("w_gate", "w_up", "w_down"),
+                         (remat_keep.KEEP_GATE, remat_keep.KEEP_UP))
+        aux, stats, load = jnp.float32(0.0), None, None
+    return _residual(x, out, w, cfg, mesh, "ln2_post"), aux, stats, load
 
 
-def _attention(x, w, cfg, mesh, positions, kind=None):
-    """x + Attention(norm(x)) -> (x, (k, v)): k, v post-RoPE and
-    pre-GQA-expand, [B, T, G, D].  ``kind`` says the layer's window and
-    whether it has RoPE (None: the model's one attention kind).
-    Without a mesh the attention is the op itself
-    (``ops/flash_attention.py``, which picks kernel or reference);
-    ``parallel/`` serves a mesh."""
+@functools.lru_cache(maxsize=None)
+def announce_attention(cfg, rows):
+    """Once per compiled shape, by the logger ``announce_tiles`` uses:
+    the attention block one shard of the data axis runs on ``rows``
+    tokens, and the bytes a layer's step moves beyond what its K/V
+    heads hold because K and V are repeated to the query heads before
+    the kernels (``kv_repeat_bytes``: K, V and their two gradients,
+    heads - kv_heads more of each; ``kv_repeat_again_bytes``: K and V
+    once more, in the backward of a rematerialized layer, whose kept K
+    and V are the ones before the repeat); 0 without GQA."""
+    more = cfg.num_heads - cfg.kv_heads
+    repeat = 2 * more * rows * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize
+    logger.info(
+        "attention block: rows=%d heads=%d kv_heads=%d head_dim=%d "
+        "qk_norm=%s gate=%d out_norms=%d embed_multiplier=%g layers=%d "
+        "kv_repeat_bytes=%d kv_repeat_again_bytes=%d", rows,
+        cfg.num_heads, cfg.kv_heads, cfg.head_dim, cfg.qk_norm,
+        cfg.attn_gate, cfg.post_norms, cfg.embed_multiplier,
+        sum(kind.op == "a" for kind in cfg.kinds), 2 * repeat,
+        repeat if cfg.remat else 0)
+
+
+def _attention_mix(h, w, cfg, mesh, positions, kind):
+    """Attention(h) of the normed input -> ([B, T, dim], (k, v)): k, v
+    post-RoPE and pre-GQA-expand, [B, T, G, D].  Without a mesh the
+    attention is the op itself (``ops/flash_attention.py``, which picks
+    kernel or reference); ``parallel/`` serves a mesh.  With
+    ``cfg.attn_gate`` each value of the kernel's output is multiplied
+    by the sigmoid of its own gate, a projection of ``h``, before
+    ``wo``."""
     compute_dtype = jnp.dtype(cfg.dtype)
-    act_spec = P("dp", "sp", None)
-    kind = kind or _one_kind(cfg)
-    if cfg.latent:
-        if mesh is not None:
-            _no_latent(cfg, "a model-parallel mesh")
-        return _latent_attention(x, w, cfg, positions, kind)
-    B, T = x.shape[0], x.shape[1]
+    B, T = h.shape[0], h.shape[1]
     H, D = cfg.num_heads, cfg.head_dim
     G = cfg.kv_heads
-    h = _rmsnorm(x, w["ln1"].astype(compute_dtype), cfg.norm_eps)
     q, k, v = _project_qkv(h, w, cfg, positions, kind.rope)
     kv_out = (k, v)
     if G != H:
@@ -825,6 +882,7 @@ def _attention(x, w, cfg, mesh, positions, kind=None):
             % (cfg.attention_impl,)
         )
     if mesh is None:
+        announce_attention(cfg, B * T // batch_shard.shards())
         attn = flash_attention(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
             v.transpose(0, 2, 1, 3), causal=True, window=kind.window,
@@ -840,30 +898,61 @@ def _attention(x, w, cfg, mesh, positions, kind=None):
         attn = ring_attention(q, k, v, mesh, causal=True,
                               window=kind.window)
     attn = attn.reshape(B, T, H * D)
-    x = x + _constrain(
-        attn @ w["wo"].astype(compute_dtype), mesh, act_spec
-    )
-    return checkpoint_name(x, remat_keep.KEEP_STREAM), kv_out
+    if cfg.attn_gate:
+        gate = checkpoint_name(h @ w["w_attn_gate"].astype(compute_dtype),
+                               remat_keep.KEEP_ATTN_GATE)
+        attn = attn * jax.nn.sigmoid(gate)
+    return attn @ w["wo"].astype(compute_dtype), kv_out
 
 
-def _short_conv(x, w, cfg):
-    """x + W_out(short_conv(W_in norm(x))): the operator of a "c"
-    layer (``ops/short_conv.py``, which picks kernel or reference)."""
+def _conv_mix(h, w, cfg):
+    """W_out(short_conv(W_in h)) of the normed input: the operator of a
+    "c" layer (``ops/short_conv.py``, which picks kernel or
+    reference)."""
     compute_dtype = jnp.dtype(cfg.dtype)
-    h = _rmsnorm(x, w["ln1"].astype(compute_dtype), cfg.norm_eps)
     bcu = checkpoint_name(h @ w["w_in"].astype(compute_dtype),
                           short_conv.KEEP_IN)
     mixed = short_conv.short_conv(bcu, w["conv_w"])
-    x = x + mixed @ w["w_out"].astype(compute_dtype)
-    return checkpoint_name(x, remat_keep.KEEP_STREAM)
+    return mixed @ w["w_out"].astype(compute_dtype)
+
+
+def _operator(x, w, cfg, mesh, positions, kind):
+    """x + post(Op(norm(x))) -> (x, (k, v) or None), ``Op`` the
+    operator of ``kind``: attention, latent attention (nothing cached:
+    ``_no_latent``) or the short convolution."""
+    h = _rmsnorm(x, w["ln1"].astype(jnp.dtype(cfg.dtype)), cfg.norm_eps)
+    kv_out = None
+    if kind.op == "c":
+        out = _conv_mix(h, w, cfg)
+    elif cfg.latent:
+        if mesh is not None:
+            _no_latent(cfg, "a model-parallel mesh")
+        out = _latent_mix(h, w, cfg, positions, kind)
+    else:
+        out, kv_out = _attention_mix(h, w, cfg, mesh, positions, kind)
+    x = _residual(x, out, w, cfg, mesh, "ln1_post")
+    return checkpoint_name(x, remat_keep.KEEP_STREAM), kv_out
+
+
+def _attention(x, w, cfg, mesh, positions, kind=None):
+    """:func:`_operator` of an attention layer (``kind`` None: the
+    model's one attention kind)."""
+    return _operator(x, w, cfg, mesh, positions, kind or _one_kind(cfg))
+
+
+def _short_conv(x, w, cfg):
+    """:func:`_operator` of a "c" layer, the stream alone."""
+    return _operator(x, w, cfg, None, None, Kind("c", False))[0]
 
 
 def _layer_body(x, w, cfg, mesh, positions, moe_stats=False,
                 return_kv=False, moe_load=False, kind=None):
-    """One block, ``x + Op(norm(x))`` then ``x + FFN(norm(x))``, of
-    ``kind`` (None: attention, and the model's one FFN); shared by the
-    scanned stack (forward) and
-    the per-stage slice scan (forward_pipelined).  ``moe_stats`` swaps
+    """One block, ``x + post(Op(norm(x)))`` then ``x + post(FFN(norm(
+    x)))``, of ``kind`` (None: attention, and the model's one FFN);
+    ``post`` is the identity unless ``cfg.post_norms`` (``_residual``,
+    the one place either is written).  Shared by the scanned stack
+    (forward) and the per-stage slice scan (forward_pipelined).
+    ``moe_stats`` swaps
     the scalar aux for the linear [2, X] router statistics (pipeline
     accumulation); ``moe_load`` returns (aux, load [X + 1]) for an MoE
     (the step statistics).  ``return_kv`` additionally returns this
@@ -880,10 +969,7 @@ def _layer_body(x, w, cfg, mesh, positions, moe_stats=False,
             _rmsnorm(x, w["ln1"].astype(jnp.dtype(cfg.dtype)),
                      cfg.norm_eps),
             w["w_router"], cfg, w.get("expert_bias"))
-    if kind.op == "c":
-        x, kv_out = _short_conv(x, w, cfg), None
-    else:
-        x, kv_out = _attention(x, w, cfg, mesh, positions, kind)
+    x, kv_out = _operator(x, w, cfg, mesh, positions, kind)
     x, aux, stats, load = _ffn(x, w, cfg, mesh, dense=kind.dense,
                                route=route)
     if moe_stats and not kind.dense:
@@ -893,6 +979,17 @@ def _layer_body(x, w, cfg, mesh, positions, moe_stats=False,
     if return_kv:
         return x, (aux, kv_out)
     return x, aux
+
+
+def _embed(params, tokens, cfg):
+    """The stream's first value: the tokens' rows of the table in the
+    compute dtype, times ``cfg.embed_multiplier`` where the model has
+    one."""
+    compute_dtype = jnp.dtype(cfg.dtype)
+    x = params["embed"].astype(compute_dtype)[tokens]
+    if cfg.embed_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embed_multiplier, compute_dtype)
+    return x
 
 
 def _head(params, x, cfg):
@@ -928,11 +1025,7 @@ def forward_hidden(params, tokens, cfg, mesh=None, return_load=False):
     that tensor is ~2 GB in f32 — a pure HBM-bandwidth tax the chunked
     loss removes).
     """
-    compute_dtype = jnp.dtype(cfg.dtype)
-    act_spec = P("dp", "sp", None)
-
-    x = params["embed"].astype(compute_dtype)[tokens]
-    x = _constrain(x, mesh, act_spec)
+    x = _constrain(_embed(params, tokens, cfg), mesh, P("dp", "sp", None))
     positions = jnp.arange(tokens.shape[1])
 
     with_load = bool(return_load
@@ -1071,8 +1164,7 @@ def forward_pipelined(params, tokens, cfg, mesh, num_microbatches,
             "forward_pipelined requires sp=1 (stage-local attention); "
             "use ring attention (plain forward) for sequence parallelism"
         )
-    compute_dtype = jnp.dtype(cfg.dtype)
-    x = params["embed"].astype(compute_dtype)[tokens]
+    x = _embed(params, tokens, cfg)
     positions = jnp.arange(tokens.shape[1])
 
     collect_aux = bool(return_aux and cfg.moe_experts)
@@ -1190,9 +1282,8 @@ def prefill(params, cfg, prompt, max_len):
     is the time-to-first-token path — Tp sequential decode steps would
     be MXU-starved serialized work."""
     _uniform_only(cfg, "prefill")
-    compute_dtype = jnp.dtype(cfg.dtype)
     b, tp = prompt.shape
-    x = params["embed"].astype(compute_dtype)[prompt]
+    x = _embed(params, prompt, cfg)
     positions = jnp.arange(tp)
 
     def layer(x, w):
@@ -1216,8 +1307,7 @@ def decode_step(params, cfg, caches, pos, tokens_1):
     (logits [B, V], updated caches).  ``caches`` from
     :func:`init_kv_cache`."""
     _uniform_only(cfg, "decode_step")
-    compute_dtype = jnp.dtype(cfg.dtype)
-    x = params["embed"].astype(compute_dtype)[tokens_1][:, None, :]
+    x = _embed(params, tokens_1, cfg)[:, None, :]
 
     def body(x, inputs):
         w, ck, cv = inputs
@@ -1356,10 +1446,11 @@ def _flag(name, value):
 
 def _decayed(params):
     """AdamW's weight-decay mask: everything but the routers'
-    ``expert_bias``."""
+    ``expert_bias`` and the scales of the norms on a sublayer's
+    output."""
     return jax.tree_util.tree_map_with_path(
-        lambda path, _: getattr(path[-1], "key", None) != "expert_bias",
-        params)
+        lambda path, _: getattr(path[-1], "key", None) not in (
+            "expert_bias", "ln1_post", "ln2_post"), params)
 
 
 def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
@@ -1375,7 +1466,8 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
                head_dim=0, rope_kinds="aw", moe_route_before_op=False,
                ffn_activation="silu", embed_scale=0.02, kv_latent_rank=0,
                qk_nope_dim=0, qk_rope_dim=0, v_head_dim=0,
-               moe_shared_experts=0):
+               moe_shared_experts=0, post_norms=False, attn_gate=False,
+               embed_multiplier=1.0):
     """Zoo entry for the flagship LM.
 
     ``remat`` (False | True | "dots" | "attn"), ``attention_impl``
@@ -1398,7 +1490,11 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
     (always-on experts beside the routed ones, as one SwiGLU of that
     many expert widths) and latent attention's four sizes
     ``kv_latent_rank`` / ``qk_nope_dim`` / ``qk_rope_dim`` /
-    ``v_head_dim`` (all 0: attention with wk and wv) pass through
+    ``v_head_dim`` (all 0: attention with wk and wv), the block's
+    ``post_norms`` (an RMSNorm on each sublayer's output, before the
+    residual add) and ``attn_gate`` (a sigmoid gate a value on
+    attention's output, before ``wo``) and ``embed_multiplier`` (what a
+    token's row is multiplied by on its way in; 1.0) pass through
     to :class:`TransformerConfig`.  ``xent_chunk`` > 0 computes the
     loss via :func:`next_token_loss_chunked` — no [B, T, V] logits
     tensor, the memory-lean path for large vocab x seq (numerically
@@ -1423,7 +1519,9 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
     A ``sigmoid_bias`` router's ``expert_bias`` is state and no weight:
     it lives in the parameter tree (so checkpoints and the reference
     carry it), no gradient reaches it, and AdamW's weight decay is
-    masked from it here, so the optimizer leaves it as it is.
+    masked from it here, so the optimizer leaves it as it is.  The
+    decay is masked from ``post_norms``' two scales as well: they set
+    how much of a sublayer's result joins the stream.
     """
     cfg = TransformerConfig(
         vocab_size=vocab_size, dim=dim, num_heads=num_heads,
@@ -1451,6 +1549,9 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
         kv_latent_rank=int(kv_latent_rank), qk_nope_dim=int(qk_nope_dim),
         qk_rope_dim=int(qk_rope_dim), v_head_dim=int(v_head_dim),
         moe_shared_experts=int(moe_shared_experts),
+        post_norms=_flag("post_norms", post_norms),
+        attn_gate=_flag("attn_gate", attn_gate),
+        embed_multiplier=float(embed_multiplier),
     )
     # validate at spec build: heads, the share, the pattern, the gate,
     # the latent's sizes
@@ -1562,7 +1663,8 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
         optimizer=optax.adamw(
             (optax.linear_schedule(0.0, learning_rate, int(warmup_steps))
              if warmup_steps else learning_rate), weight_decay=0.01,
-            mask=(_decayed if cfg.moe_router == "sigmoid_bias" else None)),
+            mask=(_decayed if cfg.moe_router == "sigmoid_bias"
+                  or cfg.post_norms else None)),
         feed=feed,
         eval_metrics_fn=lambda: {
             "nll": metrics.Mean(lambda outputs, labels: outputs)
@@ -1598,6 +1700,7 @@ def export_generate(export_dir, params, cfg, max_new_tokens,
     from elasticdl_tpu.serving.export import export_servable
 
     _no_latent(cfg, "export_generate")
+    _plain_block(cfg, "export_generate")
     if prompt_len + max_new_tokens > cfg.max_seq_len:
         raise ValueError(
             "prompt_len %d + max_new_tokens %d exceeds max_seq_len %d"

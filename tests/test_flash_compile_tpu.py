@@ -270,17 +270,20 @@ def test_the_row_kernel_compiles_for_v5e(one_chip, n, w, k, bound):
 
 
 # -- the smallthinker-21b-a3b cell's shapes
-# (benchmark/configs/smallthinker-21b-a3b.json) --
+# (benchmark/configs/smallthinker-21b-a3b.json), and the trinity-mini
+# cell's (benchmark/configs/trinity-mini.json) --
 
 
-@pytest.mark.parametrize("window", [0, 4096])
+@pytest.mark.parametrize("heads,window", [(28, 0), (28, 4096), (32, 0),
+                                          (32, 2048)])
 def test_flash_compiles_at_16384_positions_full_and_windowed(one_chip,
-                                                             window):
-    """One sequence of 16,384, 28 heads of 128: forward, dq and dk-dv
-    under both tile plans, each call carrying its kernel's name and its
-    window's into the compiled program (benchmark/kernels/
-    banded_attention.py tells them by it)."""
-    x = jax.ShapeDtypeStruct((1, 28, 16384, 128), jnp.bfloat16,
+                                                             heads, window):
+    """One sequence of 16,384, 28 heads of 128 (a window of 4,096, a
+    band 32 sub-tiles wide) and 32 heads of 128 (a window of 2,048, 16
+    wide): forward, dq and dk-dv under both tile plans, each call
+    carrying its kernel's name and its window's into the compiled
+    program (benchmark/kernels/banded_attention.py tells them by it)."""
+    x = jax.ShapeDtypeStruct((1, heads, 16384, 128), jnp.bfloat16,
                              sharding=one_chip)
     static = (True, 128 ** -0.5, False, window)
 
@@ -292,7 +295,7 @@ def test_flash_compiles_at_16384_positions_full_and_windowed(one_chip,
     calls = [l.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
              for l in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in l]
-    tail = "_w4096" if window else ""
+    tail = "_w%d" % window if window else ""
     assert sorted(calls) == sorted(
         name + tail for name in ("flash_fwd", "flash_dq", "flash_dkv"))
 
