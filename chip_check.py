@@ -199,7 +199,7 @@ def check_latent(b, h, t, interpret, widths=(128, 64, 128), ref_slice=2):
     math, a head's key put together the long way, on the first
     ``ref_slice`` heads of the same values; the RoPE key's gradient is
     the sum over every head, so it is held to the sum of the float32
-    parts the dk-dv kernel writes a head (``_pallas_bwd``'s last
+    parts the backward kernel writes a head (``_pallas_bwd``'s last
     result), and the first heads' parts to the reference's with the key
     spread to the heads.  Where there are
     more chips than one and ``b`` is a multiple of them, the kernel

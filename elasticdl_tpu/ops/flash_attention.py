@@ -20,17 +20,33 @@ fetched.  ``tile_census`` says how often each class engages for a
 shape, and the compiled mode logs it once per shape.
 
 Forward emits the per-row softmax stats (l, m) alongside the output as
-[1, T] rows, and the backward is a Pallas kernel pair: a dq pass (q/dO
-tiles resident, K/V streamed) and a dk/dv pass (K/V resident, q/dO
-streamed; scores built transposed, so nothing is transposed for its
-matmuls), each rebuilding its probability tiles IN VMEM as
-``exp(s - lse)`` from the one saved row constant ``lse = m + log l``:
-no divide per score, the scale applied once to the f32 accumulator, and
-``delta = sum(dO * O)`` made once outside both.  There is no XLA
-backward: the [T, block] p/ds tiles never make an HBM round-trip between
-einsums.  Peak memory stays O(T·block), never the full T x T.  Operands
-go into the MXU in their storage dtype (bf16), scores, stats and
-accumulators are f32, exp and the final division exact.
+[1, T] rows, and the backward is ONE Pallas call (``_bwd_kernel``,
+``flash_bwd`` in a trace): grid (batch*head, live tiles) with a key
+block's tiles in a row, K/V and dk's and dv's accumulators resident,
+q/dO and their row constants streamed, each score tile rebuilt once IN
+VMEM, transposed, as ``exp(s - lse)`` from the one saved row constant
+``lse = m + log l`` (no divide per score, the scale applied once to the
+f32 accumulators, ``delta = sum(dO * O)`` made once outside), and dk, dv
+AND dq made from it: five matmuls and one exp pass a live sub-tile,
+where a dq pass beside a dk-dv pass made seven and two.  dq's sums
+cross key blocks, so a head's whole dq lives in VMEM in float32 ([T, D],
+8 MB at T 16,384 and D 128; transposed for a latent head), is zeroed at
+the head's first grid step and written out at its last; the call sets
+its own VMEM limit (``VMEM_LIMIT``; by shape a head's dq and its output
+block's buffers take 2 MB at T 2,048, 8 at 8,192 x 64, 16 at 16,384, 28
+for the latent widths, 64 at 65,536), and a sequence whose dq does not
+fit keeps the two passes (``_backward_plan``, from shapes alone; the
+once-per-shape line says ``backward=fused dq_acc_mb=..`` or
+``backward=pair why=..``).  There is no XLA backward: the [T, block]
+p/ds tiles never make an HBM round-trip between einsums.  Peak memory
+stays O(T·block), never the full T x T.  Operands go into the MXU in
+their storage dtype (bf16), scores, stats and accumulators are f32, exp
+and the final division exact.  A call on a v5e, the pair's two /
+the fused one, ms (``tools/flash_kernels_on_chip.py``; my chip run, PR
+41): 3.09 / 2.18 at bh 128, T 2,048, D 128; 50.85 / 37.34 at bh 128,
+T 8,192, D 64; 42.84 / 30.34 at bh 32, T 16,384, D 128, 11.23 / 7.93
+under a window of 2,048; 17.38 / 12.19 at bh 28 under 4,096; 70.85 /
+51.09 at the latent widths.
 
 ``flash_attention_partial`` exposes the same kernel without the final
 normalization, returning (acc, l, m) for one KV block — the building
@@ -41,7 +57,7 @@ closed-form pullback ``_partial_stats_bwd`` (scans K blocks,
 recomputing each [T, block_k] score tile), so each ring step's bwd is
 O(T/sp x block_k) live, never the dense per-shard square.
 
-``latent_attention`` is the same three kernels for a head whose scores
+``latent_attention`` is the same kernels for a head whose scores
 run over two parts, ``D_nope + D_rope``, and whose values are ``D_v``
 wide (multi-head latent attention): a second score matmul over the RoPE
 parts is accumulated into the one S (``_scores``), the values, ``dO``
@@ -49,14 +65,13 @@ and the accumulators keep their own width, and the RoPE key, ONE
 [seq, D_rope] plane for all the heads of a sequence, is read through a
 BlockSpec whose index leaves the head out (``_tile_specs(shared=)``), so
 it is never repeated to the heads in HBM; each head's part of its
-gradient leaves the dk-dv kernel in float32 and XLA sums them.  Its
+gradient leaves the backward kernel in float32 and XLA sums them.  Its
 calls carry the widths behind the name (``flash_fwd_qk192_v128``).  At
-equal widths nothing of this is traced: ``flash_attention``'s kernels
-are what they were (tests/flash_equal_width_program.json).  One
-sequence of 16,384 at 32 heads, 128 | 64 | 128, takes 21.3 ms forward
-and 93.2 ms with its backward on a v5e; with the key repeated to the
-heads, a form measured once and not kept, 21.6 and 93.6 (my chip run,
-PR 37).
+equal widths nothing of this is traced
+(tests/flash_equal_width_program.json).  One sequence of 16,384 at 32
+heads, 128 | 64 | 128, takes 20.5 ms forward and 51.1 backward on a v5e
+(my chip run, PR 41; 21.3 and 71.9 with the two backward passes, PR
+37).
 
 Layout: [batch, heads, seq, head_dim].  The kernels choose their own
 tiling (``_major_tile``, ``_TilePlan``); a caller gives none.  A shape
@@ -69,10 +84,10 @@ is ``ops/mode.py``'s answer, asked here and by no caller (an explicit
 ``interpret=`` is for tests and ``chip_check.py``, which force the
 kernel).  Forward and Pallas backward compile and match the reference at
 B8·H16·T2048, D64 and D128, full causal and windowed, on a v5e
-(``chip_check.py`` at the repo root; my chip run, PR 25), where one
-call at D128 takes 1.21 (forward), 1.41 (dq) and 1.69 ms (dk-dv):
-58 / 75 / 83% of the MXU's time for the causal half
-(``tools/flash_kernels_on_chip.py``; the figures of 2026-07-29 in
+(``chip_check.py`` at the repo root; my chip run, PR 41), where one
+call at D128 takes 1.21 (forward) and 2.18 ms (backward; 1.41 + 1.69
+as dq and dk-dv, PR 25): 58 and 80% of the MXU's time for the causal
+half (``tools/flash_kernels_on_chip.py``; the figures of 2026-07-29 in
 BENCHMARKS.md are superseded by these and by PERF_LEDGER.jsonl).
 """
 
@@ -193,8 +208,8 @@ class _TilePlan:
     depends only on the tile's offset ``qi - ki``; a run of offsets with
     one map (the interior of the band) shares one compiled branch.
     ``q_major`` / ``k_major``: the live tiles (qi, ki) in the order the
-    forward and dq (resp. dk-dv) grids visit them; a tile with no live
-    sub-tile is no grid step.
+    forward's (resp. the backward's) grid visits them; a tile with no
+    live sub-tile is no grid step.
     """
 
     def __init__(self, t, tile, causal, window):
@@ -260,9 +275,12 @@ def tile_census(bh, t, d, tile, causal, window):
 
 
 @functools.lru_cache(maxsize=None)
-def announce_tiles(*shape):
-    """Once per compiled shape, beside ``announce_fallback``."""
-    logger.info(tile_census(*shape))
+def announce_tiles(*shape, backward=None):
+    """Once per compiled shape, beside ``announce_fallback``: the tile
+    census and, where the caller says, which backward the shape gets
+    (``_backward_plan``'s two words)."""
+    logger.info(tile_census(*shape)
+                + (" backward=%s %s" % backward if backward else ""))
 
 
 def _live_span(cmap, c):
@@ -451,7 +469,7 @@ def _tile_specs(tile, d, order_axis, shared=0):
     """(resident, streamed, resident-row stats, streamed-row stats)
     BlockSpecs over a (bh, live tiles) grid whose step s works on tile
     (qi_tab[s], ki_tab[s]); ``order_axis`` 0 keeps the query block
-    resident (forward, dq), 1 the key block (dk-dv).  ``shared`` = H > 0:
+    resident (forward), 1 the key block (backward).  ``shared`` = H > 0:
     the array is [b, T, d], one plane for the H heads of a batch row,
     and the block's index leaves the head out."""
     lead = (lambda i: i // shared) if shared else (lambda i: i)
@@ -481,7 +499,7 @@ def _call_name(kernel, window, widths=None):
     device trace (``%flash_fwd_w4096.3 = ... custom-call(...)``): the
     kernel, its window where it has one, so that a trace tells a
     windowed layer's calls from a full layer's, and ``widths`` (scores',
-    values') where they differ: ``flash_dkv_qk192_v128``."""
+    values') where they differ: ``flash_bwd_qk192_v128``."""
     return (kernel + ("_w%d" % window if window else "")
             + ("_qk%d_v%d" % widths if widths else ""))
 
@@ -528,7 +546,12 @@ def _flash_forward(q, k, v, causal, scale, interpret, normalize=True,
     tile = _tile_for(t, q, rope)
     plan = _tile_plan(t, tile, causal, window)
     if not interpret:
-        announce_tiles(bh, t, d, tile, causal, window)
+        announce_tiles(
+            bh, t, d, tile, causal, window,
+            backward=_backward_plan(
+                t, d, rope[0].shape[3] if rope is not None else 0,
+                q.dtype.itemsize) if normalize
+            else ("scan", "why=ring_partial"))
     q_spec, kv_spec, stat_spec, _ = _tile_specs(tile, d, 0)
     o_spec, v_spec = (q_spec, kv_spec) if dv == d else _tile_specs(
         tile, dv, 0)[:2]
@@ -608,14 +631,14 @@ def _masked_block_scores(qf, kf, ki, block_k, causal, scale, k_offset,
 
 def _bwd_dq_kernel(qi_tab, ki_tab, q_ref, do_ref, k_ref, v_ref, lse_ref,
                    delta_ref, *rest, plan, scale, rope=False):
-    """dq = scale * sum_j ds_ij k_j, ds = p (dp - delta), p = exp(s -
-    lse).  Grid (bh, live tiles), a query block's tiles in a row: the
-    q/dO tiles and the row constants stay resident while K/V tiles
-    stream through VMEM; the probability/ds tiles never exist outside
-    VMEM.  lse and delta arrive as [1, tile] rows and are spread over
-    the lanes once a query block.  With ``rope`` (``_scores``) the
-    RoPE parts follow the inputs, and their dq the outputs and the
-    scratch: dq_rope = scale * sum_j ds_ij k_rope_j."""
+    """The pair's dq pass, for a sequence whose dq does not fit VMEM
+    (``_backward_plan``): dq = scale * sum_j ds_ij k_j, ds = p (dp -
+    delta), p = exp(s - lse).  Grid (bh, live tiles), a query block's
+    tiles in a row: the q/dO tiles and the row constants stay resident
+    while K/V tiles stream through VMEM.  lse and delta arrive as [1,
+    tile] rows and are spread over the lanes once a query block.  With
+    ``rope`` (``_scores``) the RoPE parts follow the inputs, and their
+    dq the outputs and the scratch."""
     if rope:
         (qr_ref, kr_ref, dq_ref, dqr_ref, dq_scr, lse_scr, delta_scr,
          dqr_scr) = rest
@@ -668,35 +691,89 @@ def _bwd_dq_kernel(qi_tab, ki_tab, q_ref, do_ref, k_ref, v_ref, lse_ref,
             dqr_ref[0] = (dqr_scr[...] * scale).astype(dqr_ref.dtype)
 
 
-def _bwd_dkv_kernel(qi_tab, ki_tab, k_ref, v_ref, q_ref, do_ref, lse_ref,
-                    delta_ref, *rest, plan, scale, rope=False):
-    """dk_j = scale * sum_i ds_ij q_i, dv_j = sum_i p_ij dO_i.  Grid
+def _dq_rows(dq_scr, first_row, ds_t, cmap, k_ref):
+    """dq[the streamed tile's queries] += ds k, a key chunk at a time:
+    the [SUB, SUB] blocks of ``ds_t`` ([keys, queries], by query
+    sub-block) that are live against chunk c are transposed on the XLU
+    and stacked, so that one matmul streams every live query row past
+    the chunk's [SUB, D] keys, as the dq pass of the pair did.  (The
+    same product with the transposed operand left to the matmul, a
+    query sub-block at a time, streams SUB rows a weight tile and takes
+    three times as long: 42.7 for 30.3 ms a call, bh=32, t=16384,
+    d=128 — my chip run, PR 41.)"""
+    for c in range(len(cmap)):
+        live = [a for a in ds_t if cmap[c][a][0] != _DEAD]
+        if not live:
+            continue
+        blocks = []
+        for a in live:
+            r = c - _live_span(cmap, a)[0]
+            blocks.append(_sub_blocks(ds_t[a], r, r + 1).T)
+        rows = pl.ds(pl.multiple_of(first_row + live[0] * SUB, SUB),
+                     len(live) * SUB)
+        dq_scr[rows, :] = lax.add(dq_scr[rows, :], lax.dot_general(
+            _stack(blocks), k_ref[0, c * SUB:(c + 1) * SUB, :], _NN,
+            preferred_element_type=jnp.float32))
+
+
+def _bwd_kernel(qi_tab, ki_tab, k_ref, v_ref, q_ref, do_ref, lse_ref,
+                delta_ref, *rest, plan, scale, rope=False):
+    """The whole backward from one rebuild of each score tile: dk_j =
+    scale * sum_i ds_ij q_i, dv_j = sum_i p_ij dO_i and dq_i = scale *
+    sum_j ds_ij k_j, with p = exp(s - lse), ds = p (dp - delta).  Grid
     (bh, live tiles), a key block's tiles in a row: the K/V tiles and
-    accumulators stay resident while q/dO tiles and their row constants
-    stream through.  The score tiles are built transposed, [keys,
-    queries], so that every matmul contracts a minor dimension with a
-    major one (no transpose of p or ds) and lse, delta are [1, SUB] rows
-    spread over sublanes.  With ``rope`` (``_scores``) the RoPE parts
-    follow the inputs, and this head's part of the RoPE key's gradient,
-    scale * sum_i ds_ij q_rope_i in float32, the outputs and the
-    scratch: the key is one plane for the heads, its gradient their sum,
-    which the caller takes."""
+    their accumulators stay resident while q/dO tiles and their row
+    constants stream through.  The score tiles are built transposed,
+    [keys, queries], so that dk's and dv's matmuls contract a minor
+    dimension with a major one (no transpose of p or ds) and lse, delta
+    are [1, SUB] rows spread over sublanes.
+
+    dq's sums cross key blocks, which are not consecutive grid steps,
+    so a head's whole dq is accumulated in VMEM in float32, zeroed at
+    the head's first step and scaled, cast and written out at its last
+    (the output block is the head's: it stays in VMEM until the head
+    changes).  At equal widths it is [T, D] and takes ``_dq_rows``.
+    With ``rope`` (``_scores``: the RoPE parts follow the inputs, their
+    gradients the outputs) it is kept transposed, [T / tile, D + D_rope,
+    tile]: dq^T = [k | k_rope]^T ds_t needs no transpose of ds, the
+    keys' is made once a key block, and 192 rows stream past each
+    weight tile (at 128 the weights' load is not hidden: 42.1 for 30.3
+    ms at equal widths; the latent call takes 51.1 this way, 54.2 by
+    ``_dq_rows`` — my chip run, PR 41).  This head's part of the RoPE
+    key's gradient, scale * sum_i ds_ij q_rope_i, leaves in float32:
+    the key is one plane for the heads, its gradient their sum, which
+    the caller takes.
+
+    Results, and scratch in the same order: dk, dv (, dk_rope), then dq
+    (, dq_rope) and dq's accumulator (, the keys transposed).  A call without the
+    latter (``_backward_plan``: a sequence whose dq does not fit) is
+    the pair's dk-dv pass."""
+    kq_rope = None
     if rope:
-        (kr_ref, qr_ref, dk_ref, dv_ref, dkr_ref, dk_scr, dv_scr,
-         dkr_scr) = rest
+        kr_ref, qr_ref, *rest = rest
         kq_rope = kr_ref, qr_ref
-    else:
-        dk_ref, dv_ref, dk_scr, dv_scr = rest
-        kq_rope = None
+    n = 2 + rope
+    outs, scrs = rest[:len(rest) // 2], rest[len(rest) // 2:]
+    (dk_ref, dv_ref), (dk_scr, dv_scr) = outs[:2], scrs[:2]
+    dq_refs = outs[n:]          # none: the pair's dk-dv pass
+    dq_scr = scrs[n] if dq_refs else None
     step = pl.program_id(1)
     qi, ki = qi_tab[step], ki_tab[step]
+    tile, d = plan.tile, k_ref.shape[2]
+
+    if dq_refs:
+        @pl.when(step == 0)
+        def _init_head():
+            dq_scr[...] = jnp.zeros(dq_scr.shape, dq_scr.dtype)
 
     @pl.when(qi == jnp.maximum(0, ki + plan.dt_min))
     def _init():
-        dk_scr[...] = jnp.zeros(dk_scr.shape, dk_scr.dtype)
-        dv_scr[...] = jnp.zeros(dv_scr.shape, dv_scr.dtype)
-        if rope:
-            dkr_scr[...] = jnp.zeros(dkr_scr.shape, dkr_scr.dtype)
+        for scr in scrs[:n]:
+            scr[...] = jnp.zeros(scr.shape, scr.dtype)
+        if dq_refs and rope:
+            kt_scr = scrs[n + 1]
+            kt_scr[:d, :] = k_ref[0].T
+            kt_scr[d:, :] = kr_ref[0].T
 
     def tile_body(cmap):
         p_t, ds_t = {}, {}
@@ -717,10 +794,17 @@ def _bwd_dkv_kernel(qi_tab, ki_tab, k_ref, v_ref, q_ref, do_ref, lse_ref,
             p_t[a] = lax.convert_element_type(p, do_ref.dtype)
             ds_t[a] = lax.convert_element_type(
                 lax.mul(p, dp - delta_ref[0, :, qs]), q_ref.dtype)
+            if dq_refs and rope:
+                dq_scr[qi, :, qs] = lax.add(
+                    dq_scr[qi, :, qs], lax.dot_general(
+                        scrs[n + 1][:, rows], ds_t[a], _NN,
+                        preferred_element_type=jnp.float32))
         _accumulate(dv_scr, cmap, p_t, do_ref)
         _accumulate(dk_scr, cmap, ds_t, q_ref)
         if rope:
-            _accumulate(dkr_scr, cmap, ds_t, qr_ref)
+            _accumulate(scrs[2], cmap, ds_t, qr_ref)
+        elif dq_refs:
+            _dq_rows(dq_scr, qi * tile, ds_t, cmap, k_ref)
 
     plan.for_tile(qi, ki, tile_body, keys_resident=True)
 
@@ -729,16 +813,58 @@ def _bwd_dkv_kernel(qi_tab, ki_tab, k_ref, v_ref, q_ref, do_ref, lse_ref,
         dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
         if rope:
-            dkr_ref[0] = (dkr_scr[...] * scale).astype(dkr_ref.dtype)
+            outs[2][0] = (scrs[2][...] * scale).astype(outs[2].dtype)
+
+    if dq_refs:
+        @pl.when(step == pl.num_programs(1) - 1)
+        def _finish_head():
+            if not rope:
+                dq_refs[0][0] = (dq_scr[...] * scale).astype(
+                    dq_refs[0].dtype)
+                return
+            dq_ref, dqr_ref = dq_refs
+            for j in range(plan.num):
+                at = slice(j * tile, (j + 1) * tile)
+                acc = dq_scr[j] * scale                  # [d + dr, tile]
+                dq_ref[0, at, :] = acc[:d].T.astype(dq_ref.dtype)
+                dqr_ref[0, at, :] = acc[d:].T.astype(dqr_ref.dtype)
+
+
+# What the fused backward call may hold of VMEM, and what of that a
+# head's dq may take: its float32 accumulator and the two buffers of
+# its output block.  A v5e core has 128 MiB; the rest of the call (the
+# tiles, dk's and dv's accumulators) fits the 16 MiB a call has by
+# default, as the dk-dv pass did.
+VMEM_LIMIT = 96 * 2 ** 20
+_DQ_VMEM = VMEM_LIMIT - 16 * 2 ** 20
+
+
+def _backward_plan(t, d, d_rope, itemsize):
+    """("fused", the MB of VMEM a head's dq takes there) or ("pair",
+    why): which backward ``_pallas_bwd`` runs, from the shapes alone.
+    The fused call wants a head's whole dq in VMEM; a sequence too long
+    for that (over 65,536 at d=128 in bfloat16) keeps the two passes,
+    each of which holds a tile's."""
+    lanes = lambda width: -(-width // STATS_LANES) * STATS_LANES
+    out = 2 * itemsize * (lanes(d) + lanes(d_rope))   # two buffers each
+    # float32: [d + d_rope, T] for a latent head, else [T, d]
+    nbytes = t * (out + 4 * (d + d_rope if d_rope else lanes(d)))
+    mb = -(-nbytes // 2 ** 20)
+    if nbytes > _DQ_VMEM:
+        return "pair", "why=dq_acc_mb_%d_over_%d" % (mb, _DQ_VMEM // 2 ** 20)
+    return "fused", "dq_acc_mb=%d" % mb
 
 
 def _pallas_bwd(q, k, v, out, lse, g, causal, scale, interpret,
                 window=0, rope=None):
-    """Pallas backward: dq in one pass (K streamed), dk/dv in another
-    (Q streamed).  The probability/ds tiles live only in VMEM.  What is
-    constant along a row is made once, out here: lse came with the
-    residuals, delta_i = sum_d dO_i O_i is one pass over dO and O.
-    With ``rope`` (``_flash_forward``) also (dq_rope [b, h, t, dr],
+    """Pallas backward: one call, keys resident, that makes dk, dv and
+    dq from one rebuild of each score tile (``_bwd_kernel``); for a
+    sequence whose dq does not fit VMEM (``_backward_plan``), dk/dv in
+    one pass (Q streamed) and dq in another (K streamed).  The
+    probability/ds tiles live only in VMEM.  What is constant along a
+    row is made once, out here: lse came with the residuals, delta_i =
+    sum_d dO_i O_i is one pass over dO and O.  Returns (dq, dk, dv) and,
+    with ``rope`` (``_flash_forward``), also (dq_rope [b, h, t, dr],
     dk_rope [b, h, t, dr] float32: each head's part of the RoPE key's
     gradient, the caller's to sum: the key is one plane)."""
     b, h, t, d = q.shape
@@ -754,88 +880,89 @@ def _pallas_bwd(q, k, v, out, lse, g, causal, scale, interpret,
     delta = (
         gr.astype(jnp.float32) * out.reshape(bh, t, dv).astype(jnp.float32)
     ).sum(axis=-1).reshape(bh, 1, t)
-    params = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"))
     latent = rope is not None
     dr = rope[0].shape[3] if latent else 0
-    widths = (d + dr, dv) if latent else None
+    fused = _backward_plan(t, d, dr, q.dtype.itemsize)[0] == "fused"
     scratch = lambda width: pltpu.VMEM((tile, width), jnp.float32)
+    shaped = lambda width, dtype: jax.ShapeDtypeStruct((bh, t, width), dtype)
 
-    q_spec, kv_spec, qstat_spec, _ = _tile_specs(tile, d, 0)
-    do_spec, v_spec = (q_spec, kv_spec) if dv == d else _tile_specs(
-        tile, dv, 0)[:2]
-    operands = [qr, gr, kr, vr, lse, delta]
-    in_specs = [q_spec, do_spec, kv_spec, v_spec, qstat_spec, qstat_spec]
-    out_shape = jax.ShapeDtypeStruct((bh, t, d), q.dtype)
-    out_specs = q_spec
-    scratch_shapes = [scratch(d), scratch(STATS_LANES),
-                      scratch(STATS_LANES)]
-    if latent:
-        q_rope, k_rope, specs = _rope_parts(rope, 0, tile)
-        operands += [q_rope, k_rope]
-        in_specs += specs
-        out_shape = (out_shape,
-                     jax.ShapeDtypeStruct((bh, t, dr), q_rope.dtype))
-        out_specs = (q_spec, specs[0])
-        scratch_shapes.append(scratch(dr))
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, plan=plan, scale=scale,
-                          rope=latent),
-        out_shape=out_shape,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(bh, len(plan.q_major)),
-            in_specs=in_specs,
-            out_specs=out_specs,
-            scratch_shapes=scratch_shapes,
-        ),
-        compiler_params=params,
-        interpret=interpret,
-        name=_call_name("flash_dq", window, widths),
-    )(*plan.tables(plan.q_major), *operands)
+    def call(kernel, name, order, operands, in_specs, out_shape, out_specs,
+             scratch_shapes, **params):
+        return pl.pallas_call(
+            functools.partial(kernel, plan=plan, scale=scale, rope=latent),
+            out_shape=tuple(out_shape),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(bh, len(order)),
+                in_specs=in_specs,
+                out_specs=tuple(out_specs),
+                scratch_shapes=scratch_shapes,
+            ),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"), **params),
+            interpret=interpret,
+            name=_call_name(name, window, (d + dr, dv) if latent else None),
+        )(*plan.tables(order), *operands)
 
+    # Keys resident: dk, dv (, dk_rope) ...
     kv_spec, q_spec, _, qstat_spec = _tile_specs(tile, d, 1)
     v_spec, do_spec = (kv_spec, q_spec) if dv == d else _tile_specs(
         tile, dv, 1)[:2]
     operands = [kr, vr, qr, gr, lse, delta]
     in_specs = [kv_spec, v_spec, q_spec, do_spec, qstat_spec, qstat_spec]
-    out_shape = (jax.ShapeDtypeStruct((bh, t, d), k.dtype),
-                 jax.ShapeDtypeStruct((bh, t, dv), v.dtype))
-    out_specs = (kv_spec, v_spec)
+    out_shape = [shaped(d, k.dtype), shaped(dv, v.dtype)]
+    out_specs = [kv_spec, v_spec]
     scratch_shapes = [scratch(d), scratch(dv)]
     if latent:
         q_rope, k_rope, specs = _rope_parts(rope, 1, tile)
         operands += [k_rope, q_rope]
         in_specs += specs[::-1]
-        out_shape += (jax.ShapeDtypeStruct((bh, t, dr), jnp.float32),)
         # a head's own part of the one plane's gradient
-        out_specs += (_tile_specs(tile, dr, 1)[0],)
+        out_shape.append(shaped(dr, jnp.float32))
+        out_specs.append(_tile_specs(tile, dr, 1)[0])
         scratch_shapes.append(scratch(dr))
-    dkv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, plan=plan, scale=scale,
-                          rope=latent),
-        out_shape=out_shape,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(bh, len(plan.k_major)),
-            in_specs=in_specs,
-            out_specs=out_specs,
-            scratch_shapes=scratch_shapes,
-        ),
-        compiler_params=params,
-        interpret=interpret,
-        name=_call_name("flash_dkv", window, widths),
-    )(*plan.tables(plan.k_major), *operands)
-    if latent:
-        dq, dq_rope = dq
-        return (dq.reshape(b, h, t, d), dkv[0].reshape(b, h, t, d),
-                dkv[1].reshape(b, h, t, dv), dq_rope.reshape(b, h, t, dr),
-                dkv[2].reshape(b, h, t, dr))
-    return (
-        dq.reshape(b, h, t, d),
-        dkv[0].reshape(b, h, t, d),
-        dkv[1].reshape(b, h, t, dv),
-    )
+    if fused:
+        # ... and dq (, dq_rope), a head's whole block
+        head = lambda width: pl.BlockSpec(
+            (1, t, width), lambda i, s, qi_tab, ki_tab: (i, 0, 0))
+        out_shape.append(shaped(d, q.dtype))
+        out_specs.append(head(d))
+        if latent:
+            out_shape.append(shaped(dr, q_rope.dtype))
+            out_specs.append(head(dr))
+            scratch_shapes += [
+                pltpu.VMEM((plan.num, d + dr, tile), jnp.float32),
+                pltpu.VMEM((d + dr, tile), k.dtype)]
+        else:
+            scratch_shapes.append(pltpu.VMEM((t, d), jnp.float32))
+        dkv = call(_bwd_kernel, "flash_bwd", plan.k_major, operands,
+                   in_specs, out_shape, out_specs, scratch_shapes,
+                   vmem_limit_bytes=VMEM_LIMIT)
+        dkv, dq = dkv[:2 + latent], dkv[2 + latent:]
+    else:
+        dkv = call(_bwd_kernel, "flash_dkv", plan.k_major, operands,
+                   in_specs, out_shape, out_specs, scratch_shapes)
+        # Queries resident: dq (, dq_rope).
+        q_spec, kv_spec, qstat_spec, _ = _tile_specs(tile, d, 0)
+        do_spec, v_spec = (q_spec, kv_spec) if dv == d else _tile_specs(
+            tile, dv, 0)[:2]
+        operands = [qr, gr, kr, vr, lse, delta]
+        in_specs = [q_spec, do_spec, kv_spec, v_spec, qstat_spec,
+                    qstat_spec]
+        out_shape, out_specs = [shaped(d, q.dtype)], [q_spec]
+        scratch_shapes = [scratch(d), scratch(STATS_LANES),
+                          scratch(STATS_LANES)]
+        if latent:
+            q_rope, k_rope, specs = _rope_parts(rope, 0, tile)
+            operands += [q_rope, k_rope]
+            in_specs += specs
+            out_shape.append(shaped(dr, q_rope.dtype))
+            out_specs.append(specs[0])
+            scratch_shapes.append(scratch(dr))
+        dq = call(_bwd_dq_kernel, "flash_dq", plan.q_major, operands,
+                  in_specs, out_shape, out_specs, scratch_shapes)
+    grads = (dq[0], dkv[0], dkv[1]) + ((dq[1], dkv[2]) if latent else ())
+    return tuple(x.reshape(b, h, t, x.shape[2]) for x in grads)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))

@@ -439,7 +439,7 @@ def test_the_stack_line_says_each_kinds_window_and_rope(caplog):
 
 
 @pytest.mark.parametrize("kernel,window,want", [
-    ("flash_fwd", 0, "flash_fwd"), ("flash_dq", 4096, "flash_dq_w4096"),
+    ("flash_fwd", 0, "flash_fwd"), ("flash_bwd", 4096, "flash_bwd_w4096"),
     ("flash_dkv", 512, "flash_dkv_w512")])
 def test_a_flash_call_is_named_by_its_kernel_and_window(kernel, window,
                                                         want):

@@ -126,14 +126,14 @@ def test_partial_matches_reference_stats(t):
 @pytest.mark.parametrize("t,d", [(256, 64), (384, 64), (1536, 64),
                                  (256, 128)])
 def test_pallas_bwd_matches_reference(causal, t, d, monkeypatch):
-    """The default backward is the Pallas kernel pair (dq; dk/dv) —
-    it must be the path taken and match reference gradients.  t=384
-    forces tile=128 -> a 3x3 block grid, exercising the cross-step
-    scratch accumulation and the live-tile tables (t=256 is a
-    single-block grid where init/finish coincide); t=1536 is a 3x3 grid
-    of 4x4 sub-tiles: the diagonal tiles' branch skips the sub-tiles
-    above the diagonal and masks the four on it, the others' branch
-    masks nothing, in dq and (transposed) in dk-dv."""
+    """The default backward is the one Pallas call (dk, dv and dq from
+    one rebuild of each score tile) — it must be the path taken and
+    match reference gradients.  t=384 forces tile=128 -> a 3x3 block
+    grid, exercising the cross-step scratch accumulation and the
+    live-tile tables (t=256 is a single-block grid where init/finish
+    coincide); t=1536 is a 3x3 grid of 4x4 sub-tiles: the diagonal
+    tiles' branch skips the sub-tiles above the diagonal and masks the
+    four on it, the others' branch masks nothing."""
     import elasticdl_tpu.ops.flash_attention as fa
 
     called = {}
@@ -164,6 +164,99 @@ def test_pallas_bwd_matches_reference(causal, t, d, monkeypatch):
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-3, atol=1e-3)
+
+
+def _backward_calls(fn, *args):
+    """Names of the Pallas calls in the traced gradient of ``fn``."""
+    from tests.test_mixed_stack import _eqns
+
+    jaxpr = jax.make_jaxpr(jax.grad(fn, tuple(range(len(args)))))(*args)
+    return sorted(str(e.params["name"]) for e in _eqns(jaxpr.jaxpr)
+                  if e.primitive.name == "pallas_call")
+
+
+@pytest.mark.parametrize("case,b,h,t,d,causal,dtype,tol", [
+    # three key blocks a head (tile 128): query tile 2's dq is added to
+    # at grid steps 2, 4 and 5 of (0,0) (1,0) (2,0) (1,1) (2,1) (2,2)
+    ("three-key-blocks", 2, 3, 384, 128, True, "float32", 1e-4),
+    # every query tile ends in the last key block: the head's dq is
+    # written whole at the head's last step
+    ("not-causal", 1, 2, 384, 64, False, "float32", 1e-4),
+    # rows over 512 bytes take the 512 tile: two key blocks of 4 x 4
+    ("d256-float32", 1, 2, 1024, 256, True, "float32", 1e-4),
+    # sub-tiles stacked a key chunk at a time across a 1024 tile's
+    # diagonal, in the storage dtype the cells run
+    ("bfloat16-two-tiles", 1, 2, 2048, 64, True, "bfloat16", 2e-2),
+])
+def test_fused_backward_matches_reference(case, b, h, t, d, causal, dtype,
+                                          tol):
+    """dq, dk and dv of the fused backward against ``_attention_ref``,
+    each head against its own reference: a head's dq accumulator is
+    revisited across grid steps that are not adjacent, and zeroed at
+    the head's first step (batch x heads > 1, every head's values its
+    own)."""
+    import elasticdl_tpu.ops.flash_attention as fa
+
+    q, k, v = (x.astype(dtype) for x in make_qkv(b=b, h=h, t=t, d=d,
+                                                 seed=t + d))
+    g = make_qkv(b=b, h=h, t=t, d=d, seed=7)[0].astype(dtype)
+
+    def loss(op):
+        return lambda q, k, v: (op(q, k, v).astype(jnp.float32)
+                                * g.astype(jnp.float32)).sum()
+
+    flash = loss(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=causal, interpret=True))
+    ref = loss(lambda q, k, v: fa._attention_ref(q, k, v, causal,
+                                                 d ** -0.5))
+    assert _backward_calls(flash, q, k, v) == ["flash_bwd", "flash_fwd"]
+    got = jax.grad(flash, (0, 1, 2))(q, k, v)
+    want = jax.grad(ref, (0, 1, 2))(q, k, v)
+    for name, a, w in zip("qkv", got, want):
+        a, w = (np.asarray(x, np.float32) for x in (a, w))
+        for head in range(b * h):       # a head's error over ITS largest
+            ah, wh = (x.reshape(b * h, t, d)[head] for x in (a, w))
+            assert np.abs(ah - wh).max() <= tol * np.abs(wh).max(), (
+                case, name, head)
+
+
+@pytest.mark.parametrize("t,d,d_rope,itemsize,want", [
+    (2048, 128, 0, 2, ("fused", "dq_acc_mb=2")),
+    (8192, 64, 0, 2, ("fused", "dq_acc_mb=8")),
+    (16384, 128, 0, 2, ("fused", "dq_acc_mb=16")),
+    (16384, 128, 64, 2, ("fused", "dq_acc_mb=28")),
+    (65536, 128, 0, 2, ("fused", "dq_acc_mb=64")),
+    (131072, 128, 0, 2, ("pair", "why=dq_acc_mb_128_over_80")),
+    (65536, 128, 0, 4, ("pair", "why=dq_acc_mb_96_over_80")),
+])
+def test_the_backward_is_chosen_from_the_shapes_alone(t, d, d_rope,
+                                                      itemsize, want):
+    """Fused wherever a head's float32 dq and its output block's two
+    buffers fit the VMEM the call may hold; every cell's shape does."""
+    import elasticdl_tpu.ops.flash_attention as fa
+
+    assert fa._backward_plan(t, d, d_rope, itemsize) == want
+
+
+def test_a_dq_too_long_for_vmem_keeps_the_two_passes(monkeypatch):
+    """Past the budget (here set to 64 KiB: t=384 at d=64 wants 576)
+    the backward is the dk-dv pass, the fused body without dq's part,
+    and the dq pass, and the gradients are the fused call's."""
+    import elasticdl_tpu.ops.flash_attention as fa
+
+    q, k, v = make_qkv(b=1, h=2, t=384, d=64, seed=3)
+    loss = lambda q, k, v: (fa.flash_attention(
+        q, k, v, window=200, interpret=True) ** 2).sum()
+    fused = jax.grad(loss, (0, 1, 2))(q, k, v)
+    monkeypatch.setattr(fa, "_DQ_VMEM", 64 * 1024)
+    assert fa._backward_plan(384, 64, 0, 4)[0] == "pair"
+    assert _backward_calls(loss, q, k, v) == ["flash_dkv_w200",
+                                              "flash_dq_w200",
+                                              "flash_fwd_w200"]
+    pair = jax.grad(loss, (0, 1, 2))(q, k, v)
+    for a, b in zip(fused, pair):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -317,3 +410,28 @@ def test_tile_census_is_announced_once_per_shape():
     assert [c.args[0] for c in info.call_args_list] == [
         fa.tile_census(4, 1024, 64, 1024, True, 0),
         fa.tile_census(4, 1024, 64, 1024, True, 256)]
+
+
+def test_the_line_says_which_backward_the_shape_got(monkeypatch):
+    """``backward=fused dq_acc_mb=<n>`` behind the census, or
+    ``backward=pair why=<reason>``: once per compiled shape, from the
+    forward that traces it (the interpreter logs nothing)."""
+    import elasticdl_tpu.ops.flash_attention as fa
+
+    fa.announce_tiles.cache_clear()
+    monkeypatch.setattr(fa.pl, "pallas_call", lambda *a, **kw: (
+        lambda *operands: tuple(jnp.zeros(s.shape, s.dtype)
+                                for s in kw["out_shape"])))
+    x = jnp.zeros((1, 2, 2048, 128), jnp.bfloat16)
+    with mock.patch.object(fa.logger, "info") as info:
+        for _ in range(2):
+            fa._flash_forward(x, x, x, True, 1.0, False)
+        monkeypatch.setattr(fa, "_DQ_VMEM", 2 ** 20)
+        fa._flash_forward(x, x, x, True, 1.0, False, window=512)
+        fa._flash_forward(x, x, x, True, 1.0, False, normalize=False)
+    census = fa.tile_census(2, 2048, 128, 1024, True, 0)
+    assert [c.args[0] for c in info.call_args_list] == [
+        census + " backward=fused dq_acc_mb=2",
+        fa.tile_census(2, 2048, 128, 1024, True, 512)
+        + " backward=pair why=dq_acc_mb_2_over_1",
+        census + " backward=scan why=ring_partial"]

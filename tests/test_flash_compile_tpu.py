@@ -71,9 +71,8 @@ def test_flash_fwd_bwd_compile_for_v5e(one_chip, t, d, dtype, window):
         return out, fa._flash_bwd(*static, res, g)
 
     text = jax.jit(fwd_bwd).lower(x, x, x, x).compile().as_text()
-    # forward, dq, dk-dv: three Mosaic calls, told apart downstream by
-    # their result counts (3, 1, 2: benchmark/kernels/flash_attention.py)
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    # the forward and the one backward: two Mosaic calls
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -199,7 +198,7 @@ def test_flash_compiles_at_head_size_64_and_8192_positions(one_chip):
         return out, fa._flash_bwd(*static, res, g)
 
     text = jax.jit(fwd_bwd).lower(x, x, x, x).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
 def test_grouped_matmul_over_a_share_compiles_for_v5e(one_chip):
@@ -274,40 +273,60 @@ def test_the_row_kernel_compiles_for_v5e(one_chip, n, w, k, bound):
 # cell's (benchmark/configs/trinity-mini.json) --
 
 
-@pytest.mark.parametrize("heads,window", [(28, 0), (28, 4096), (32, 0),
-                                          (32, 2048)])
-def test_flash_compiles_at_16384_positions_full_and_windowed(one_chip,
-                                                             heads, window):
-    """One sequence of 16,384, 28 heads of 128 (a window of 4,096, a
-    band 32 sub-tiles wide) and 32 heads of 128 (a window of 2,048, 16
-    wide): forward, dq and dk-dv under both tile plans, each call
-    carrying its kernel's name and its window's into the compiled
-    program (benchmark/kernels/banded_attention.py tells them by it)."""
-    x = jax.ShapeDtypeStruct((1, heads, 16384, 128), jnp.bfloat16,
-                             sharding=one_chip)
-    static = (True, 128 ** -0.5, False, window)
+def _flash_calls(q_shape, window, one_chip):
+    """Names of the Mosaic calls of a forward and backward at these
+    shapes, compiled for the described chip."""
+    x = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16, sharding=one_chip)
+    static = (True, q_shape[-1] ** -0.5, False, window)
 
     def fwd_bwd(q, k, v, g):
         out, res = fa._flash_fwd(q, k, v, *static)
         return out, fa._flash_bwd(*static, res, g)
 
     text = jax.jit(fwd_bwd).lower(x, x, x, x).compile().as_text()
-    calls = [l.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
-             for l in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in l]
+    return sorted(l.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
+                  for l in text.splitlines()
+                  if 'custom_call_target="tpu_custom_call"' in l)
+
+
+@pytest.mark.parametrize("heads,window", [(28, 0), (28, 4096), (32, 0),
+                                          (32, 2048)])
+def test_flash_compiles_at_16384_positions_full_and_windowed(one_chip,
+                                                             heads, window):
+    """One sequence of 16,384, 28 heads of 128 (a window of 4,096, a
+    band 32 sub-tiles wide) and 32 heads of 128 (a window of 2,048, 16
+    wide): the forward and the one backward under both tile plans, a
+    head's 8 MB float32 dq and its output block's two buffers inside
+    the VMEM limit the call sets, each call carrying its kernel's name
+    and its window's into the compiled program
+    (benchmark/kernels/banded_attention.py tells the forward by it)."""
+    assert fa._backward_plan(16384, 128, 0, 2) == ("fused", "dq_acc_mb=16")
     tail = "_w%d" % window if window else ""
-    assert sorted(calls) == sorted(
-        name + tail for name in ("flash_fwd", "flash_dq", "flash_dkv"))
+    assert _flash_calls((1, heads, 16384, 128), window, one_chip) == [
+        "flash_bwd" + tail, "flash_fwd" + tail]
+
+
+@pytest.mark.parametrize("t,calls", [
+    (65536, ["flash_bwd", "flash_fwd"]),
+    (131072, ["flash_dkv", "flash_dq", "flash_fwd"])])
+def test_the_longest_dq_that_fits_vmem_compiles_and_the_next_is_a_pair(
+        one_chip, t, calls):
+    """65,536 positions at head size 128: a head's dq takes 64 MB of the
+    96 the fused call may hold, and Mosaic takes it; at 131,072 the
+    backward is the dk-dv pass and the dq pass, a tile's each."""
+    assert _flash_calls((1, 2, t, 128), 0, one_chip) == calls
 
 
 def test_latent_attention_compiles_at_the_cells_shapes(one_chip):
     """``kanana-2-30b-a3b.seq16384``'s attention: one sequence of
     16,384, 32 heads, scores over 128 + 64 and values of 128, the RoPE
-    key one [T, 64] plane: forward, dq and
-    dk-dv through Mosaic at the 1,024 tile, each call carrying its
-    kernel's name and the widths (benchmark/kernels/latent_attention.py
-    tells them by it), dq's second result the RoPE part's, dk-dv's third
-    each head's part of the RoPE key's gradient in float32."""
+    key one [T, 64] plane: the forward and the one backward through
+    Mosaic at the 1,024 tile (a head's dq kept transposed, 12 MB of
+    float32 beside its two outputs' buffers, under the VMEM limit the
+    call sets), each call carrying its kernel's name and the widths
+    (benchmark/kernels/latent_attention.py tells the forward by it),
+    among the backward's results dq's RoPE part and each head's part of
+    the RoPE key's gradient in float32."""
     shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.bfloat16,
                                                sharding=one_chip)
     heads, t = 32, 16384
@@ -328,10 +347,10 @@ def test_latent_attention_compiles_at_the_cells_shapes(one_chip):
              l.split(" custom-call(")[0]
              for l in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in l}
-    assert sorted(calls) == ["flash_dkv_qk192_v128", "flash_dq_qk192_v128",
-                             "flash_fwd_qk192_v128"]
-    assert "bf16[32,16384,64]" in calls["flash_dq_qk192_v128"]
-    assert "f32[32,16384,64]" in calls["flash_dkv_qk192_v128"]
+    assert sorted(calls) == ["flash_bwd_qk192_v128", "flash_fwd_qk192_v128"]
+    assert "bf16[32,16384,64]" in calls["flash_bwd_qk192_v128"]
+    assert "f32[32,16384,64]" in calls["flash_bwd_qk192_v128"]
+    assert fa._backward_plan(t, 128, 64, 2) == ("fused", "dq_acc_mb=28")
 
 
 def _head_sized_ops(text, rows, heads):
@@ -395,7 +414,7 @@ def test_a_latent_layer_moves_no_activation_between_matmuls_and_kernels(
         one_chip, monkeypatch):
     """One latent-attention layer of ``kanana-2-30b-a3b.seq16384``
     (``_latent_mix`` on one sequence of 16,384: the five projections,
-    RoPE, the three kernels, ``W_o``), forward + backward through the
+    RoPE, the two kernels, ``W_o``), forward + backward through the
     TPU's compiler: between ``W_q`` / ``W_kv_b`` / ``W_o`` and the
     flash calls **no** instruction stands alone whose result has rows x
     heads x width elements: every such array is written by a matmul
@@ -403,7 +422,7 @@ def test_a_latent_layer_moves_no_activation_between_matmuls_and_kernels(
     matmul fusions write one: q_nope, k_nope, v and the RoPE part of
     ``W_q``'s product, RoPE's 64 x 64 permutation product forward and
     its transpose backward, ``W_o``'s backward (dO with the kernels'
-    row sums); three Mosaic calls.
+    row sums); two Mosaic calls, the forward and the one backward.
 
     The parent (d5e277b, one product a weight on [T, H, 192] / [T, H,
     256], sliced, turned and transposed) compiled to **12** standalone
@@ -438,7 +457,7 @@ def test_a_latent_layer_moves_no_activation_between_matmuls_and_kernels(
     text = jax.jit(fwd_bwd).lower(h, w, h).compile().as_text()
     standalone, matmul, mosaic = _head_sized_ops(text, rows, heads)
     assert standalone == [], standalone
-    assert len(mosaic) == 3, mosaic
+    assert len(mosaic) == 2, mosaic
     assert len(matmul) <= 7, matmul
 
 
@@ -530,10 +549,10 @@ def test_the_banded_stacks_step_fits_a_v5e_as_remat_keep_predicts(
              if 'custom_call_target="tpu_custom_call"' in l]
     count = lambda name: len([c for c in calls if re.search(
         name + r"(__)?\.\d+$|" + name + "$", c)])
-    assert (count("flash_fwd"), count("flash_dq"),
-            count("flash_dkv")) == (1, 1, 1), calls
-    assert (count("flash_fwd_w4096"), count("flash_dq_w4096"),
-            count("flash_dkv_w4096")) == (3, 3, 3), calls
+    assert (count("flash_fwd"), count("flash_bwd")) == (1, 1), calls
+    assert (count("flash_fwd_w4096"), count("flash_bwd_w4096")) == (3, 3), \
+        calls
+    assert not [c for c in calls if "flash_dq" in c or "flash_dkv" in c]
 
 
 def test_the_mixed_stacks_step_fits_a_v5e_as_remat_keep_predicts(

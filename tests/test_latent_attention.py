@@ -243,7 +243,7 @@ def test_the_op_takes_the_rope_key_as_one_plane_alone():
 
 
 def test_the_rope_keys_gradient_is_the_sum_of_the_heads_parts():
-    """The dk-dv kernel writes each head's own float32 part
+    """The backward kernel writes each head's own float32 part
     (``_pallas_bwd``'s last result); the plane's gradient is their sum,
     and a head's part is what the long way gives that head's copy of
     the key."""
@@ -269,9 +269,36 @@ def test_the_rope_keys_gradient_is_the_sum_of_the_heads_parts():
     assert float(jnp.abs(heads[:, 0] - heads[:, 1]).max()) > 1e-2
 
 
+@pytest.mark.parametrize("window,blocks_of_last_tile", [
+    (0, [0, 1, 2]), (100, [1, 2])])
+def test_the_fused_backward_sums_dq_over_three_key_blocks_a_head(
+        window, blocks_of_last_tile):
+    """Scores over 128 + 64 in float32 take the 128 tile at T = 384:
+    three key blocks a head, six heads.  A head's dq and dq_rope are
+    kept transposed ([T / tile, 192, tile]: ``_bwd_kernel``), a query
+    tile's added to at grid steps with other tiles' between them, and
+    turned back at the head's last step; under a window the last query
+    tile's first and last key block differ, and tile 0 is finished long
+    before the head is."""
+    plan = fa._tile_plan(384, 128, True, window)
+    assert [ki for qi, ki in plan.k_major if qi == 2] == blocks_of_last_tile
+    args, g = _op_inputs(b=2, h=3, t=384, seed=window)
+    scale = 192 ** -0.5
+    every = tuple(range(5))
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(lambda *a: (fa.latent_attention(
+            *a, window=window, interpret=True) * g).sum(), every)(*args)
+        want = jax.grad(lambda *a: (fa._latent_ref(
+            *a, True, scale, window=window) * g).sum(), every)(*args)
+    for name, a, b in zip(("q_nope", "q_rope", "k_nope", "k_rope", "v"),
+                          got, want):
+        assert a.shape == b.shape, name
+        assert _apart(a, b) <= 1e-5, name
+
+
 @pytest.mark.parametrize("kernel,window,widths,want", [
     ("flash_fwd", 0, (192, 128), "flash_fwd_qk192_v128"),
-    ("flash_dkv", 4096, (192, 128), "flash_dkv_w4096_qk192_v128"),
+    ("flash_bwd", 4096, (192, 128), "flash_bwd_w4096_qk192_v128"),
     ("flash_dq", 0, None, "flash_dq"),
 ])
 def test_a_latent_call_is_named_by_its_kernel_and_widths(kernel, window,
@@ -279,9 +306,10 @@ def test_a_latent_call_is_named_by_its_kernel_and_widths(kernel, window,
     assert fa._call_name(kernel, window, widths) == want
 
 
-def test_the_three_calls_carry_their_names_and_read_one_key_plane():
-    """The traced program: three Pallas calls named by their widths; the
-    RoPE key goes in as [b, T, 64], not spread to the heads."""
+def test_the_two_calls_carry_their_names_and_read_one_key_plane():
+    """The traced program: two Pallas calls, the forward and the one
+    backward, named by their widths; the RoPE key goes in as [b, T, 64],
+    not spread to the heads."""
     args, g = _op_inputs(b=1, h=2)
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda *a: (fa.latent_attention(*a, interpret=True) * g).sum(),
@@ -291,8 +319,7 @@ def test_the_three_calls_carry_their_names_and_read_one_key_plane():
     calls = [e for e in _eqns(jaxpr.jaxpr)
              if e.primitive.name == "pallas_call"]
     names = sorted(str(e.params["name"]) for e in calls)
-    assert names == ["flash_dkv_qk192_v128", "flash_dq_qk192_v128",
-                     "flash_fwd_qk192_v128"]
+    assert names == ["flash_bwd_qk192_v128", "flash_fwd_qk192_v128"]
     for e in calls:
         shapes = [tuple(v.aval.shape) for v in e.invars]
         assert (1, 256, 64) in shapes and (2, 256, 64) in shapes, shapes
@@ -309,12 +336,14 @@ def test_widths_the_kernels_refuse_take_the_reference_and_say_so():
 
 
 def test_equal_widths_trace_the_kernels_they_traced():
-    """``flash_attention``'s forward, dq and dk-dv at equal widths (the
+    """``flash_attention``'s forward and backward at equal widths (the
     four older cells' and the banded calls'): the primitives of the
     traced kernels, counted through every nested jaxpr, and the length
-    of the jaxpr's text are those recorded from the parent of the PR
-    that taught the kernels two widths
-    (tests/flash_equal_width_program.json)."""
+    of the jaxpr's text are those recorded from PR 41's commit, the
+    child of 7ba575a that made the backward one call
+    (tests/flash_equal_width_program.json; until then the record was
+    the pair's, from the parent of the PR that taught the kernels two
+    widths): nothing of a latent head's parts is traced here."""
     from tests.test_mixed_stack import _eqns
 
     with open(os.path.join(HERE, "flash_equal_width_program.json")) as fh:

@@ -60,18 +60,18 @@ def _room_for(cfg, params, entries):
                               + rk.RESERVE * GB))
 
 
-def _pallas_results(jaxpr, found=None):
-    """How many results each ``pallas_call`` of a jaxpr has, sub-jaxprs
+def _pallas_calls(jaxpr, found=None):
+    """The name each ``pallas_call`` of a jaxpr carries, sub-jaxprs
     (scan, remat, custom_vjp, shard_map) included."""
     found = [] if found is None else found
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            found.append(len(eqn.outvars))
+            found.append(str(eqn.params["name"]))
         for value in eqn.params.values():
             for sub in value if isinstance(value, (tuple, list)) else [value]:
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    _pallas_results(sub, found)
+                    _pallas_calls(sub, found)
     return found
 
 
@@ -123,15 +123,15 @@ def test_gradients_equal_the_nothing_kept_ones(monkeypatch, mode, moe,
 @pytest.mark.parametrize("how", ["room", "attn"])
 def test_keeping_out_and_lse_takes_the_flash_forward_out_of_the_backward(
         monkeypatch, how):
-    """The kernel's forward has three results, dq one, dk-dv two
-    (benchmark/kernels/flash_attention.py tells them the same way): with
-    nothing kept the forward runs in the forward scan and again in the
-    backward's; with the two names kept, and under remat="attn" whatever
-    the room, once."""
+    """The calls by the names they carry: with nothing kept the forward
+    runs in the forward scan and again in the backward's, before the
+    one backward call; with the two names kept, and under remat="attn"
+    whatever the room, once."""
     monkeypatch.setenv("ELASTICDL_FLASH", "interpret")
     cfg, params, loss = _problem(0)
     base = jax.make_jaxpr(jax.grad(lambda p: loss(p, None)))(params)
-    assert sorted(_pallas_results(base.jaxpr)) == [1, 2, 3, 3]
+    assert sorted(_pallas_calls(base.jaxpr)) == [
+        "flash_bwd", "flash_fwd", "flash_fwd"]
     if how == "attn":
         cfg = dataclasses.replace(cfg, remat="attn")
         tokens = jnp.zeros((2, 128), jnp.int32)
@@ -140,20 +140,20 @@ def test_keeping_out_and_lse_takes_the_flash_forward_out_of_the_backward(
     else:
         room = _room_for(cfg, params, 1)
         kept = jax.make_jaxpr(jax.grad(lambda p: loss(p, room)))(params)
-    assert sorted(_pallas_results(kept.jaxpr)) == [1, 2, 3]
+    assert sorted(_pallas_calls(kept.jaxpr)) == ["flash_bwd", "flash_fwd"]
 
 
 def test_every_moe_name_kept_leaves_the_backward_its_own_calls(monkeypatch):
-    """16 kernel calls with nothing kept (flash 1 + 3 grouped matmuls,
-    twice, and flash's 2 + the matmuls' 6 backward calls); 12 with
+    """15 kernel calls with nothing kept (flash 1 + 3 grouped matmuls,
+    twice, and flash's 1 + the matmuls' 6 backward calls); 11 with
     every name kept."""
     monkeypatch.setenv("ELASTICDL_FLASH", "interpret")
     cfg, params, loss = _problem(1)
     base = jax.make_jaxpr(jax.grad(lambda p: loss(p, None)))(params)
     room = _room_for(cfg, params, len(MOE))
     kept = jax.make_jaxpr(jax.grad(lambda p: loss(p, room)))(params)
-    assert len(_pallas_results(base.jaxpr)) == 16
-    assert len(_pallas_results(kept.jaxpr)) == 12
+    assert len(_pallas_calls(base.jaxpr)) == 15
+    assert len(_pallas_calls(kept.jaxpr)) == 11
 
 
 def test_no_room_stated_is_the_program_without_the_names(monkeypatch):

@@ -143,8 +143,8 @@ def test_ring_window_blockwise_banded():
 @pytest.mark.parametrize(
     "t,window", [(384, w) for w in WINDOWS] + TILE_EDGE_WINDOWS)
 def test_flash_window_pallas_bwd(t, window, monkeypatch):
-    """Both Pallas backward kernels under a window — the live-tile
-    tables and the in-kernel band mask on the edge sub-tiles."""
+    """The Pallas backward under a window — the live-tile tables and
+    the in-kernel band mask on the edge sub-tiles."""
     import elasticdl_tpu.ops.flash_attention as fa
 
     called = {}
@@ -175,6 +175,44 @@ def test_flash_window_pallas_bwd(t, window, monkeypatch):
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-3, atol=1e-3)
+
+
+@pytest.mark.parametrize("t,window,tile,blocks_of_last_tile", [
+    # tiles of 128: the last query tile's first rows reach 199 keys
+    # back, into key block 1 of four
+    (512, 200, 128, [1, 2, 3]),
+    # tiles of 512 (4 x 4 sub-tiles, some skipped): the last of three
+    # reaches into key block 0
+    (1536, 600, 512, [0, 1, 2]),
+])
+def test_fused_backward_dq_across_a_windows_key_blocks(t, window, tile,
+                                                       blocks_of_last_tile):
+    """A query tile whose first and last key block differ, under a
+    window: its dq is the sum of parts made at grid steps with other
+    tiles' steps between them (keys resident: a key block's query tiles
+    in a row), each head's in its own accumulator."""
+    import elasticdl_tpu.ops.flash_attention as fa
+
+    plan = fa._tile_plan(t, tile, True, window)
+    last = plan.num - 1
+    assert [ki for qi, ki in plan.k_major if qi == last] == \
+        blocks_of_last_tile
+    steps = [s for s, (qi, _) in enumerate(plan.k_major) if qi == last]
+    assert max(b - a for a, b in zip(steps, steps[1:])) > 1
+    q, k, v = make_bhtd(b=2, h=2, t=t, seed=window)
+    g = make_bhtd(b=2, h=2, t=t, seed=1)[0]
+
+    def grads(op):
+        return jax.grad(lambda q, k, v: (op(q, k, v) * g).sum(),
+                        (0, 1, 2))(q, k, v)
+
+    got = grads(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, interpret=True, window=window))
+    want = grads(lambda q, k, v: _attention_ref(
+        q, k, v, True, q.shape[-1] ** -0.5, window=window))
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("window", [64, 200])

@@ -1,13 +1,16 @@
 #!/usr/bin/env python3
-"""Time the three flash-attention kernels alone, on the chip.
+"""Time the flash-attention kernels alone, on the chip.
 
     python3 tools/flash_kernels_on_chip.py [--bh 128] [--t 2048] [--d 128]
-        [--window 0] [--root DIR] [--set NAME=INT]
+        [--window 0] [--latent] [--root DIR] [--set NAME=INT]
 
-Prints one JSON line: ms a call of the forward, dq and dk-dv kernels
-(device time of each ``custom-call`` event in a profiler trace, which is
-what ``kernel.flash_attention_roofline`` reads) and of the whole forward
-and backward on the host's clock (XLA glue around the kernels included).
+Prints one JSON line: ms a call of the forward and the backward kernels
+(device time of each ``custom-call`` event in a profiler trace, by the
+name the call carries: ``fwd`` and ``bwd``, or ``fwd``, ``dq`` and
+``dkv`` for a ``--root`` from before the backward was one call) and of
+the whole forward and backward on the host's clock (XLA glue around the
+kernels included).  ``--latent`` times ``latent_attention``'s calls:
+scores over ``--d`` + 64, one RoPE key a sequence, values of ``--d``.
 ``--root DIR`` imports ``elasticdl_tpu`` from another checkout (the
 parent commit unpacked beside this one), so one call measures both.
 Exits 3 without a TPU: a CPU timing is no device number.
@@ -18,6 +21,7 @@ import collections
 import inspect
 import json
 import os
+import re
 import sys
 import tempfile
 import time
@@ -29,6 +33,7 @@ def main():
     ap.add_argument("--t", type=int, default=2048)
     ap.add_argument("--d", type=int, default=128)
     ap.add_argument("--window", type=int, default=0)
+    ap.add_argument("--latent", action="store_true")
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--set", action="append", default=[],
                     metavar="NAME=INT", help="set a module constant of "
@@ -54,17 +59,24 @@ def main():
               % dev.platform, file=sys.stderr)
         return 3
     rng = np.random.RandomState(0)
-    shape = (1, args.bh, args.t, args.d)
-    q, k, v, g = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
-                  for _ in range(4))
-    static = (True, args.d ** -0.5, False, args.window)
-    if "block_q" in inspect.signature(fa._flash_fwd).parameters:
-        # --root at a checkout from before PR 28: block_q, block_k
-        static = static[:2] + (128, 128) + static[2:]
-    fwd = jax.jit(lambda q, k, v: fa._flash_fwd(q, k, v, *static))
-    bwd = jax.jit(lambda res, g: fa._flash_bwd(*static, res, g))
-    dq_only = jax.jit(lambda res, g: fa._flash_bwd(*static, res, g)[0])
-    dkv_only = jax.jit(lambda res, g: fa._flash_bwd(*static, res, g)[1:])
+    tensor = lambda *shape: jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+    wide = (1, args.bh, args.t, args.d)
+    g = tensor(*wide)
+    if args.latent:
+        rope = 64
+        operands = (tensor(*wide), tensor(1, args.bh, args.t, rope),
+                    tensor(*wide), tensor(1, args.t, rope), tensor(*wide))
+        static = (True, (args.d + rope) ** -0.5, False, args.window)
+        fwd_rule, bwd_rule = fa._latent_fwd, fa._latent_bwd
+    else:
+        operands = tuple(tensor(*wide) for _ in range(3))
+        static = (True, args.d ** -0.5, False, args.window)
+        if "block_q" in inspect.signature(fa._flash_fwd).parameters:
+            # --root at a checkout from before PR 28: block_q, block_k
+            static = static[:2] + (128, 128) + static[2:]
+        fwd_rule, bwd_rule = fa._flash_fwd, fa._flash_bwd
+    fwd = jax.jit(lambda *a: fwd_rule(*a, *static))
+    bwd = jax.jit(lambda res, g: bwd_rule(*static, res, g))
 
     def host_ms(fn, *a):
         jax.block_until_ready(fn(*a))
@@ -74,32 +86,29 @@ def main():
         jax.block_until_ready(out)
         return 1e3 * (time.perf_counter() - t0) / args.iters
 
-    _, res = fwd(q, k, v)
+    _, res = fwd(*operands)
     row = {"root": args.root, "set": args.set, "device": dev.device_kind,
            "shape": [args.bh, args.t, args.d], "window": args.window,
-           "host_ms": {
-               "fwd": host_ms(fwd, q, k, v), "bwd": host_ms(bwd, res, g),
-               "dq": host_ms(dq_only, res, g),
-               "dkv": host_ms(dkv_only, res, g)}}
+           "latent": args.latent,
+           "host_ms": {"fwd": host_ms(fwd, *operands),
+                       "bwd": host_ms(bwd, res, g)}}
 
-    # Device time of each custom call, told apart by its result arity
-    # the way benchmark/kernels/flash_attention.py does.
-    from benchmark.lib import kernels, xplane
+    # Device time of each custom call, told apart by the name it carries.
+    from benchmark.lib import xplane
     with tempfile.TemporaryDirectory(prefix="flash_trace_") as trace_dir:
         with jax.profiler.trace(trace_dir):
             for _ in range(5):
-                _, res = fwd(q, k, v)
+                _, res = fwd(*operands)
                 out = bwd(res, g)
             jax.block_until_ready(out)
         reduced = xplane.load(trace_dir)
     by_kind = collections.defaultdict(list)
     other = collections.defaultdict(float)
     for name, _, dur in next(iter(reduced["devices"].values())):
-        parsed = (kernels.parse_call(name) if "tpu_custom_call" in name
-                  else None)
-        if parsed:
-            kind = {3: "fwd", 1: "dq", 2: "dkv"}.get(len(parsed[0]), "?")
-            by_kind[kind].append(dur / 1e6)
+        call = (re.search(r"flash_(fwd|bwd|dq|dkv)", name.split(" = ")[0])
+                if "tpu_custom_call" in name else None)
+        if call:
+            by_kind[call.group(1)].append(dur / 1e6)
         else:
             other[name.split(" = ")[0][:40]] += dur / 1e6 / 5
     row["kernel_ms"] = {kind: round(float(np.median(ms)), 4)
