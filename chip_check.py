@@ -147,10 +147,13 @@ def _f32(*arrays):
     return tuple(a.astype(jnp.float32) for a in arrays)
 
 
-def _qkv(b, h, t, d, seed):
+def _qkv(b, h, t, d, seed, kv_heads=None):
+    """q, k, v and a cotangent; k and v at ``kv_heads`` (None: ``h``)."""
     rng = np.random.RandomState(seed)
+    g = kv_heads or h
     return tuple(
-        jnp.asarray(rng.randn(b, h, t, d), jnp.bfloat16) for _ in range(4)
+        jnp.asarray(rng.randn(b, heads, t, d), jnp.bfloat16)
+        for heads in (h, g, g, h)
     )
 
 
@@ -162,19 +165,28 @@ def _kernel_vs_corner(kernel, ref, loss, operands, ref_slice):
     sb, sh = ref_slice
     out = jax.jit(kernel)(*operands[:3])
     grads = jax.jit(jax.grad(loss(kernel), argnums=(0, 1, 2)))(*operands)
-    corner = tuple(a[:sb, :sh] for a in _f32(*operands))
+    # K and V at their own head count: the corner's query heads are
+    # whole groups, and its K/V heads the ones they read
+    group = operands[0].shape[1] // operands[1].shape[1]
+    heads = lambda a: sh if a.shape[1] == operands[0].shape[1] \
+        else sh // group
+    corner = tuple(a[:sb, :heads(a)] for a in _f32(*operands))
     with jax.default_matmul_precision("highest"):
         want = jax.jit(ref)(*corner[:3])
         want_grads = jax.jit(
             jax.grad(loss(ref), argnums=(0, 1, 2)))(*corner)
     errs = {
-        name: _rel_err(g[:sb, :sh], wg)
+        name: _rel_err(g[:sb, :heads(g)], wg)
         for name, g, wg in zip(("dq", "dk", "dv"), grads, want_grads)
     }
     return out, want, errs
 
 
-def check_flash(b, h, t, d, window, interpret, ref_slice=(1, 2)):
+def check_flash(b, h, t, d, window, interpret, ref_slice=(1, 2),
+                kv_heads=None):
+    """``kv_heads`` < ``h``: K and V at their own head count, read by
+    the kernels as ``head // group`` and repeated by the reference;
+    ``ref_slice`` then names whole groups of query heads."""
     scale = d ** -0.5
     sb, sh = ref_slice
 
@@ -189,7 +201,8 @@ def check_flash(b, h, t, d, window, interpret, ref_slice=(1, 2)):
             window=window),
         lambda q, k, v: fa._attention_ref(q, k, v, True, scale,
                                           window=window),
-        loss, _qkv(b, h, t, d, seed=t + d + window), ref_slice)
+        loss, _qkv(b, h, t, d, seed=t + d + window, kv_heads=kv_heads),
+        ref_slice)
     return {"fwd": _rel_err(out[:sb, :sh], want), **errs}
 
 
@@ -844,6 +857,18 @@ def _cases(tiny):
         yield ("flash/B1.H32.T8192.D64.window0",
                lambda: check_flash(1, 32, 8192, 64, 0, interpret,
                                    ref_slice=(1, 1)))
+    # Grouped-query attention as the three GQA cells run it: K and V at
+    # their own head count (32 on 4 under a window, 28 on 4, 32 on 8 at
+    # heads of 64), dk and dv summed over the group in the backward
+    # call; the reference on the first group of the first batch row.
+    for gb, gh, gg, gt, gd, gw in ((2, 4, 2, 256, 64, 128),) if tiny else (
+            (2, 32, 4, 4096, 128, 1024), (1, 28, 4, 4096, 128, 0),
+            (1, 32, 8, 8192, 64, 0)):
+        yield ("flash/B%d.H%d.G%d.T%d.D%d.window%d"
+               % (gb, gh, gg, gt, gd, gw),
+               lambda gb=gb, gh=gh, gg=gg, gt=gt, gd=gd, gw=gw: check_flash(
+                   gb, gh, gt, gd, gw, interpret,
+                   ref_slice=(1, gh // gg), kv_heads=gg))
     # The two share cells' row moves (tokens x width, choices, bound).
     for n, w, k, bound in ((96, 256, 4, 128),) if tiny else (
             (32768, 2048, 4, 32768), (16384, 2560, 6, 49152)):

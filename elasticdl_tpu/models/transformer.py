@@ -32,7 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from elasticdl_tpu.models import remat_keep
 from elasticdl_tpu.models.spec import ModelSpec
 from elasticdl_tpu.ops import batch_shard, short_conv
-from elasticdl_tpu.ops.flash_attention import (flash_attention,
+from elasticdl_tpu.ops.flash_attention import (flash_attention, flash_mode,
                                                latent_attention,
                                                latent_mode, logger)
 from elasticdl_tpu.ops.mode import kernels_off
@@ -542,8 +542,10 @@ def shard_params(params, mesh, cfg):
 # -- forward ------------------------------------------------------------------
 
 
-def _rmsnorm(x, scale, eps=1e-6):
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
+def _rmsnorm(x, scale, eps=1e-6, axis=-1):
+    """RMSNorm over ``axis`` (the last; the head and width axes of a
+    head-major projection normed whole), ``scale`` broadcast to x."""
+    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=axis,
                    keepdims=True)
     return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
@@ -688,32 +690,47 @@ def _constrain(x, mesh, spec):
     return x
 
 
+def _heads_first(x, weight):
+    """x [B, T, dim] times a per-head view [dim, heads, width] of a
+    weight, written [B, heads, T, width] by the matmul itself: the
+    layout the flash kernels take, so no [T, heads, width] product is
+    transposed on its way to them, nor a cotangent on its way back."""
+    return jnp.einsum("btd,dhk->bhtk", x, weight)
+
+
 def _project_qkv(h, w, cfg, positions, rope=True):
-    """q [B, T, H, D], k and v [B, T, G, D] of the normed input, RoPE
+    """q [B, H, T, D], k and v [B, G, T, D] of the normed input, as
+    ``ops/flash_attention.flash_attention`` takes them (head-major
+    planes, K and V at their own head count: ``_heads_first``), RoPE
     applied to q and k (after the QK norm where the model has one)
-    unless the layer's kind has none (``rope`` False)."""
+    unless the layer's kind has none (``rope`` False).  A caller that
+    wants them token-major (ring / Ulysses attention, the decode cache)
+    transposes at its own edge."""
     compute_dtype = jnp.dtype(cfg.dtype)
-    B, T = h.shape[0], h.shape[1]
     H, D, G = cfg.num_heads, cfg.head_dim, cfg.kv_heads
-    def project(name, norm, heads):
-        x = h @ w[name].astype(compute_dtype)
-        if norm and cfg.qk_norm != "head":   # over the whole projection
-            x = _rmsnorm(x, w[norm].astype(compute_dtype), cfg.norm_eps)
-        x = x.reshape(B, T, heads, D)
-        if norm and cfg.qk_norm == "head":   # over each head's D values
-            x = _rmsnorm(x, w[norm].astype(compute_dtype), cfg.norm_eps)
+
+    def project(name, heads, norm=None, turn=False):
+        x = _heads_first(
+            h, w[name].astype(compute_dtype).reshape(-1, heads, D))
+        if norm:
+            scale = w[norm].astype(compute_dtype)
+            if cfg.qk_norm == "head":        # over each head's D values
+                x = _rmsnorm(x, scale, cfg.norm_eps)
+            else:                            # over the whole projection
+                x = _rmsnorm(x, scale.reshape(heads, 1, D), cfg.norm_eps,
+                             axis=(1, 3))
+        if turn:
+            x = _rope_heads_first(x, positions, cfg.rope_theta)
         return x
 
-    q = project("wq", cfg.qk_norm and "q_norm", H)
-    k = project("wk", cfg.qk_norm and "k_norm", G)
-    v = project("wv", None, G)
-    if rope:
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
     # as the attention takes them: what its backward reads
-    return (checkpoint_name(q, remat_keep.KEEP_Q),
-            checkpoint_name(k, remat_keep.KEEP_K),
-            checkpoint_name(v, remat_keep.KEEP_V))
+    return (checkpoint_name(
+                project("wq", H, cfg.qk_norm and "q_norm", rope),
+                remat_keep.KEEP_Q),
+            checkpoint_name(
+                project("wk", G, cfg.qk_norm and "k_norm", rope),
+                remat_keep.KEEP_K),
+            checkpoint_name(project("wv", G), remat_keep.KEEP_V))
 
 
 def _project_latent(h, w, cfg, positions, rope=True):
@@ -734,10 +751,9 @@ def _project_latent(h, w, cfg, positions, rope=True):
     compute_dtype = jnp.dtype(cfg.dtype)
     H = cfg.num_heads
     rank, dn, dr, dv = cfg.latent
-    heads_first = lambda x, weight: jnp.einsum("btd,dhk->bhtk", x, weight)
     wq = w["wq"].astype(compute_dtype).reshape(-1, H, dn + dr)
-    q_nope = heads_first(h, wq[..., :dn])
-    q_rope = heads_first(h, wq[..., dn:])
+    q_nope = _heads_first(h, wq[..., :dn])
+    q_rope = _heads_first(h, wq[..., dn:])
     # the latent and the RoPE key as the projection gives them: [T,
     # rank + Dr] a layer, what two matmuls make k_nope and v from
     c = checkpoint_name(h @ w["w_kv_a"].astype(compute_dtype),
@@ -752,9 +768,9 @@ def _project_latent(h, w, cfg, positions, rope=True):
     name = checkpoint_name
     return (name(q_nope, remat_keep.KEEP_Q),
             name(q_rope, remat_keep.KEEP_Q),
-            name(heads_first(latent, w_kv_b[..., :dn]), remat_keep.KEEP_KV),
+            name(_heads_first(latent, w_kv_b[..., :dn]), remat_keep.KEEP_KV),
             k_rope[:, :, 0],
-            name(heads_first(latent, w_kv_b[..., dn:]), remat_keep.KEEP_KV))
+            name(_heads_first(latent, w_kv_b[..., dn:]), remat_keep.KEEP_KV))
 
 
 @functools.lru_cache(maxsize=None)
@@ -833,16 +849,18 @@ def _ffn(x, w, cfg, mesh, dense=False, route=None):
 
 
 @functools.lru_cache(maxsize=None)
-def announce_attention(cfg, rows):
+def announce_attention(cfg, rows, repeated):
     """Once per compiled shape, by the logger ``announce_tiles`` uses:
     the attention block one shard of the data axis runs on ``rows``
     tokens, and the bytes a layer's step moves beyond what its K/V
-    heads hold because K and V are repeated to the query heads before
-    the kernels (``kv_repeat_bytes``: K, V and their two gradients,
-    heads - kv_heads more of each; ``kv_repeat_again_bytes``: K and V
-    once more, in the backward of a rematerialized layer, whose kept K
-    and V are the ones before the repeat); 0 without GQA."""
-    more = cfg.num_heads - cfg.kv_heads
+    heads hold where K and V are ``repeated`` to the query heads
+    (``kv_repeat_bytes``: K, V and their two gradients, heads -
+    kv_heads more of each; ``kv_repeat_again_bytes``: K and V once
+    more, in the backward of a rematerialized layer, whose kept K and V
+    are the ones before the repeat).  The jnp reference repeats; the
+    kernels read K/V head ``head // group`` and the line says 0 and 0,
+    as it does without GQA."""
+    more = (cfg.num_heads - cfg.kv_heads) * repeated
     repeat = 2 * more * rows * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize
     logger.info(
         "attention block: rows=%d heads=%d kv_heads=%d head_dim=%d "
@@ -856,53 +874,55 @@ def announce_attention(cfg, rows):
 
 def _attention_mix(h, w, cfg, mesh, positions, kind):
     """Attention(h) of the normed input -> ([B, T, dim], (k, v)): k, v
-    post-RoPE and pre-GQA-expand, [B, T, G, D].  Without a mesh the
-    attention is the op itself (``ops/flash_attention.py``, which picks
-    kernel or reference); ``parallel/`` serves a mesh.  With
-    ``cfg.attn_gate`` each value of the kernel's output is multiplied
-    by the sigmoid of its own gate, a projection of ``h``, before
-    ``wo``."""
+    post-RoPE at their own head count, [B, G, T, D].  Nothing
+    activation-sized is made between the projections and the op:
+    ``_project_qkv`` writes the op's operands (q at H heads, k and v at
+    G, head-major), the gate is projected in the output's layout, and
+    ``wo`` contracts the op's [B, H, T, D] output where it stands.
+    Without a mesh the attention is the op itself
+    (``ops/flash_attention.py``, which picks kernel or reference, and
+    reads K/V head ``head // (H // G)``); ``parallel/`` serves a mesh,
+    token-major and at the query heads, so its edge transposes and
+    repeats.  With ``cfg.attn_gate`` each value of the kernel's output
+    is multiplied by the sigmoid of its own gate, a projection of
+    ``h``, before ``wo``."""
     compute_dtype = jnp.dtype(cfg.dtype)
     B, T = h.shape[0], h.shape[1]
     H, D = cfg.num_heads, cfg.head_dim
-    G = cfg.kv_heads
     q, k, v = _project_qkv(h, w, cfg, positions, kind.rope)
     kv_out = (k, v)
-    if G != H:
-        # GQA: expand K/V to the full head count for the (unchanged)
-        # attention kernels.  jnp.repeat keeps group order consecutive,
-        # matching the q-head grouping convention (head i -> kv head
-        # i // (H/G)); XLA lowers this to a broadcast feeding the
-        # score matmuls.
-        k = jnp.repeat(k, H // G, axis=2)
-        v = jnp.repeat(v, H // G, axis=2)
     if cfg.attention_impl not in ("ring", "ulysses"):
         raise ValueError(
             "unknown attention_impl %r (want 'ring' or 'ulysses')"
             % (cfg.attention_impl,)
         )
     if mesh is None:
-        announce_attention(cfg, B * T // batch_shard.shards())
-        attn = flash_attention(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), causal=True, window=kind.window,
-        ).transpose(0, 2, 1, 3)
-    elif cfg.attention_impl == "ulysses":
-        from elasticdl_tpu.parallel.ulysses import ulysses_attention
-
-        attn = ulysses_attention(q, k, v, mesh, causal=True,
-                                 window=kind.window)
+        announce_attention(cfg, B * T // batch_shard.shards(),
+                           flash_mode(T, D)[0] == "off")
+        attn = flash_attention(q, k, v, causal=True, window=kind.window)
     else:
-        from elasticdl_tpu.parallel.ring_attention import ring_attention
-
-        attn = ring_attention(q, k, v, mesh, causal=True,
-                              window=kind.window)
-    attn = attn.reshape(B, T, H * D)
+        if cfg.attention_impl == "ulysses":
+            from elasticdl_tpu.parallel.ulysses import (
+                ulysses_attention as sharded)
+        else:
+            from elasticdl_tpu.parallel.ring_attention import (
+                ring_attention as sharded)
+        # [B, T, H, D] each: K/V (G heads wide) repeated, group order
+        # consecutive (head i -> K/V head i // (H / G))
+        q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+        if cfg.kv_heads != H:
+            k, v = (jnp.repeat(x, H // cfg.kv_heads, axis=2)
+                    for x in (k, v))
+        attn = sharded(q, k, v, mesh, causal=True,
+                       window=kind.window).transpose(0, 2, 1, 3)
     if cfg.attn_gate:
-        gate = checkpoint_name(h @ w["w_attn_gate"].astype(compute_dtype),
-                               remat_keep.KEEP_ATTN_GATE)
+        gate = checkpoint_name(
+            _heads_first(h, w["w_attn_gate"].astype(compute_dtype).reshape(
+                -1, H, D)), remat_keep.KEEP_ATTN_GATE)
         attn = attn * jax.nn.sigmoid(gate)
-    return attn @ w["wo"].astype(compute_dtype), kv_out
+    # as ``_latent_mix``: reshaped before the cast
+    wo = w["wo"].reshape(H, D, cfg.dim).astype(compute_dtype)
+    return jnp.einsum("bhtk,hkd->btd", attn, wo), kv_out
 
 
 def _conv_mix(h, w, cfg):
@@ -1251,10 +1271,11 @@ def _decode_layer(x, w, cfg, ck, cv, pos):
         # as ``_layer_body``: the router reads what attention reads
         route = moe_route(h, w["w_router"], cfg, w.get("expert_bias"))
     q, k, v = _project_qkv(h, w, cfg, positions, kind.rope)
+    # head-major [B, ., 1, D]; the cache is token-major
     ck = jax.lax.dynamic_update_slice(
-        ck, k.astype(ck.dtype), (0, pos, 0, 0))
+        ck, k.transpose(0, 2, 1, 3).astype(ck.dtype), (0, pos, 0, 0))
     cv = jax.lax.dynamic_update_slice(
-        cv, v.astype(cv.dtype), (0, pos, 0, 0))
+        cv, v.transpose(0, 2, 1, 3).astype(cv.dtype), (0, pos, 0, 0))
 
     qg = q.reshape(B, G, R, D).astype(jnp.float32)
     s = jnp.einsum(
@@ -1294,10 +1315,11 @@ def prefill(params, cfg, prompt, max_len):
 
     x, (ks, vs) = jax.lax.scan(layer, x, params["layers"])
     ck, cv = init_kv_cache(cfg, b, max_len)  # [L, B, max, G, D]
+    # the layers' K/V are head-major [L, B, G, Tp, D]; the cache is not
     ck = jax.lax.dynamic_update_slice(
-        ck, ks.astype(ck.dtype), (0, 0, 0, 0, 0))
+        ck, ks.transpose(0, 1, 3, 2, 4).astype(ck.dtype), (0, 0, 0, 0, 0))
     cv = jax.lax.dynamic_update_slice(
-        cv, vs.astype(cv.dtype), (0, 0, 0, 0, 0))
+        cv, vs.transpose(0, 1, 3, 2, 4).astype(cv.dtype), (0, 0, 0, 0, 0))
     logits = _head(params, x, cfg)[:, -1]
     return logits, (ck, cv)
 

@@ -73,7 +73,19 @@ heads, 128 | 64 | 128, takes 20.5 ms forward and 51.1 backward on a v5e
 (my chip run, PR 41; 21.3 and 71.9 with the two backward passes, PR
 37).
 
-Layout: [batch, heads, seq, head_dim].  The kernels choose their own
+Layout: [batch, heads, seq, head_dim]; k and v may hold fewer heads
+than q, [batch, kv_heads, seq, head_dim] with ``kv_heads`` dividing
+``heads`` (grouped-query attention).  Nothing is repeated for the
+kernels: program ``i`` of the grid's first axis reads K/V plane ``i //
+group`` (``_tile_specs(shared=group)``, the index latent attention's
+one RoPE key has), and the fused backward sums dk and dv over a group's
+consecutive heads in two float32 [T, D] planes in VMEM beside dq's, so
+they leave at ``kv_heads`` from one rounding (``_group_sum``;
+``_backward_plan`` counts the planes, 32 MB at T 16,384 and D 128, and
+the once-per-shape line says ``kv_heads=.. group=..`` and
+``dkv_acc_mb=..``).  ``group`` comes from the shapes alone; equal head
+counts are group 1, whose index is the identity and whose program is
+the one it was.  The kernels choose their own
 tiling (``_major_tile``, ``_TilePlan``); a caller gives none.  A shape
 they cannot take (seq not a multiple of the 128 lanes, an odd head_dim)
 falls back to ``_attention_ref``, the one reference attention of the
@@ -132,8 +144,14 @@ def announce_fallback(what, shape, why, mode=None):
 
 
 def _attention_ref(q, k, v, causal, scale, window=0):
-    """jnp reference in the same [B, H, T, D] layout.  ``window`` > 0
-    limits causal attention to the last ``window`` positions."""
+    """jnp reference in the same layout: q [B, H, T, D], k and v
+    [B, G, T, D] with G dividing H (query head i reads K/V head
+    ``i // (H // G)``: the reference repeats them to the query heads,
+    the kernels index them).  ``window`` > 0 limits causal attention to
+    the last ``window`` positions."""
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
     s = jnp.einsum(
         "bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32
     ) * scale
@@ -275,11 +293,15 @@ def tile_census(bh, t, d, tile, causal, window):
 
 
 @functools.lru_cache(maxsize=None)
-def announce_tiles(*shape, backward=None):
+def announce_tiles(*shape, kv_heads=None, backward=None):
     """Once per compiled shape, beside ``announce_fallback``: the tile
-    census and, where the caller says, which backward the shape gets
-    (``_backward_plan``'s two words)."""
+    census, the K/V planes the call reads (``kv_heads``, batch x heads
+    as ``bh``, and the query heads to each: ``group``) and, where the
+    caller says, which backward the shape gets (``_backward_plan``'s
+    two words)."""
+    kv_heads = kv_heads or shape[0]
     logger.info(tile_census(*shape)
+                + " kv_heads=%d group=%d" % (kv_heads, shape[0] // kv_heads)
                 + (" backward=%s %s" % backward if backward else ""))
 
 
@@ -465,14 +487,24 @@ def _flash_kernel(qi_tab, ki_tab, q_ref, k_ref, v_ref, *rest, plan, scale,
         m_ref[0] = m_scr[...].T[0:1, :]
 
 
+def _plane_of(n):
+    """Program i of the grid's first axis -> the plane it reads of an
+    array that holds one for every ``n`` consecutive programs (n <= 1:
+    its own, and no division is traced)."""
+    return (lambda i: i // n) if n > 1 else (lambda i: i)
+
+
 def _tile_specs(tile, d, order_axis, shared=0):
     """(resident, streamed, resident-row stats, streamed-row stats)
     BlockSpecs over a (bh, live tiles) grid whose step s works on tile
     (qi_tab[s], ki_tab[s]); ``order_axis`` 0 keeps the query block
-    resident (forward), 1 the key block (backward).  ``shared`` = H > 0:
-    the array is [b, T, d], one plane for the H heads of a batch row,
-    and the block's index leaves the head out."""
-    lead = (lambda i: i // shared) if shared else (lambda i: i)
+    resident (forward), 1 the key block (backward).  ``shared`` = n > 1:
+    the array holds one plane for every n consecutive values of the
+    grid's first axis, [bh / n, T, d] (latent attention's one RoPE key
+    for the H heads of a batch row; K and V at their own head count,
+    one for a group of query heads), and the block's index is the
+    plane's: (b * H + h) // n."""
+    lead = _plane_of(shared)
 
     def resident(i, s, qi_tab, ki_tab):
         return (lead(i), (qi_tab, ki_tab)[order_axis][s], 0)
@@ -492,6 +524,21 @@ def _tile_specs(tile, d, order_axis, shared=0):
         pl.BlockSpec((1, 1, tile), row_of(resident)),
         pl.BlockSpec((1, 1, tile), row_of(streamed)),
     )
+
+
+def _operand_specs(tile, d, dv, order_axis, group):
+    """BlockSpecs (q, k, v, out or dO, the queries' row stats, dk, dv)
+    of a call over q [bh, T, d] and K, V [bh / group, T, d | dv], one
+    plane for ``group`` consecutive query heads (``_tile_specs``:
+    ``order_axis`` 0 keeps the query block resident, 1 the key block);
+    dk's and dv's are a query head's own [bh, T, .] blocks."""
+    specs = lambda width, shared=0: _tile_specs(tile, width, order_axis,
+                                                shared)
+    qs, ks = specs(d), specs(d, group)
+    os, vs = (qs, ks) if dv == d else (specs(dv), specs(dv, group))
+    query, key = order_axis, 1 - order_axis
+    return (qs[query], ks[key], vs[key], os[query], qs[2 + query],
+            qs[key], os[key])
 
 
 def _call_name(kernel, window, widths=None):
@@ -529,15 +576,19 @@ def _rope_parts(rope, order_axis, tile):
 def _flash_forward(q, k, v, causal, scale, interpret, normalize=True,
                    window=0, rope=None):
     """Returns (out, l, m); out is normalized iff ``normalize``.
-    ``rope``: None, or (q_rope [b, h, t, dr], k_rope [b, t, dr]), the
-    RoPE parts of a latent-attention head, whose scores run over q's
-    width + dr and whose values are v's width."""
+    k and v may hold fewer heads than q, [b, g, t, .] with g dividing
+    h: the program of query head i streams the tiles of K/V head
+    ``i // (h // g)``.  ``rope``: None, or (q_rope [b, h, t, dr],
+    k_rope [b, t, dr]), the RoPE parts of a latent-attention head,
+    whose scores run over q's width + dr and whose values are v's
+    width."""
     b, h, t, d = q.shape
     dv = v.shape[3]
-    bh = b * h
+    bh, kv_heads = b * h, b * k.shape[1]
+    group = bh // kv_heads
     qr = q.reshape(bh, t, d)
-    kr = k.reshape(bh, t, d)
-    vr = v.reshape(bh, t, dv)
+    kr = k.reshape(kv_heads, t, d)
+    vr = v.reshape(kv_heads, t, dv)
     # Work per grid step must amortize the per-step pipeline overhead:
     # a wide q block and a major K/V tile of the same edge, both capped
     # by what divides t.  The grid holds the live tiles only (their
@@ -547,14 +598,13 @@ def _flash_forward(q, k, v, causal, scale, interpret, normalize=True,
     plan = _tile_plan(t, tile, causal, window)
     if not interpret:
         announce_tiles(
-            bh, t, d, tile, causal, window,
+            bh, t, d, tile, causal, window, kv_heads=kv_heads,
             backward=_backward_plan(
                 t, d, rope[0].shape[3] if rope is not None else 0,
-                q.dtype.itemsize) if normalize
+                q.dtype.itemsize, group) if normalize
             else ("scan", "why=ring_partial"))
-    q_spec, kv_spec, stat_spec, _ = _tile_specs(tile, d, 0)
-    o_spec, v_spec = (q_spec, kv_spec) if dv == d else _tile_specs(
-        tile, dv, 0)[:2]
+    q_spec, kv_spec, v_spec, o_spec, stat_spec, _, _ = _operand_specs(
+        tile, d, dv, 0, group)
     operands, in_specs, widths = [qr, kr, vr], [q_spec, kv_spec, v_spec], None
     if rope is not None:
         q_rope, k_rope, specs = _rope_parts(rope, 0, tile)
@@ -716,8 +766,30 @@ def _dq_rows(dq_scr, first_row, ds_t, cmap, k_ref):
             preferred_element_type=jnp.float32))
 
 
+def _group_sum(out_ref, plane, acc_scr, rows, member, group, scale):
+    """One query head's [tile, D] ``acc_scr`` (a key block's dk or dv)
+    into ``rows`` of its K/V head's float32 ``plane``: head ``member``
+    of the ``group`` that reads the K/V head starts the rows, adds to
+    them, or adds, scales, casts and writes them to the K/V head's
+    output block."""
+    @pl.when(member == 0)
+    def _first():
+        plane[rows, :] = acc_scr[...]
+
+    if group > 2:
+        @pl.when((member > 0) & (member < group - 1))
+        def _add():
+            plane[rows, :] = lax.add(plane[rows, :], acc_scr[...])
+
+    @pl.when(member == group - 1)
+    def _last():
+        out_ref[0, rows, :] = (
+            lax.add(plane[rows, :], acc_scr[...]) * scale
+        ).astype(out_ref.dtype)
+
+
 def _bwd_kernel(qi_tab, ki_tab, k_ref, v_ref, q_ref, do_ref, lse_ref,
-                delta_ref, *rest, plan, scale, rope=False):
+                delta_ref, *rest, plan, scale, rope=False, group=1):
     """The whole backward from one rebuild of each score tile: dk_j =
     scale * sum_i ds_ij q_i, dv_j = sum_i p_ij dO_i and dq_i = scale *
     sum_j ds_ij k_j, with p = exp(s - lse), ds = p (dp - delta).  Grid
@@ -744,10 +816,22 @@ def _bwd_kernel(qi_tab, ki_tab, k_ref, v_ref, q_ref, do_ref, lse_ref,
     the key is one plane for the heads, its gradient their sum, which
     the caller takes.
 
+    ``group`` > 1: K and V hold one head for ``group`` consecutive
+    values of the grid's first axis (their blocks' index is ``head //
+    group``), and dk, dv leave at that head count, summed over the
+    group: a K/V head's whole [T, D] dk and dv are float32 planes in
+    VMEM beside dq's, and their output blocks the K/V head's.  A key
+    block's accumulators, when its last query tile is done, start the
+    plane's rows (the group's first head), are added to them, or (the
+    last head) are added, scaled, cast and written out: one rounding of
+    the float32 sum, where XLA summed ``group`` rounded planes.
+
     Results, and scratch in the same order: dk, dv (, dk_rope), then dq
-    (, dq_rope) and dq's accumulator (, the keys transposed).  A call without the
-    latter (``_backward_plan``: a sequence whose dq does not fit) is
-    the pair's dk-dv pass."""
+    (, dq_rope) and dq's accumulator (, the keys transposed), then the
+    group's two planes.  A call without dq (``_backward_plan``: a
+    sequence whose accumulators do not fit) is the pair's dk-dv pass."""
+    planes = rest[len(rest) - 2:] if group > 1 else ()
+    rest = rest[:len(rest) - len(planes)]
     kq_rope = None
     if rope:
         kr_ref, qr_ref, *rest = rest
@@ -760,6 +844,8 @@ def _bwd_kernel(qi_tab, ki_tab, k_ref, v_ref, q_ref, do_ref, lse_ref,
     step = pl.program_id(1)
     qi, ki = qi_tab[step], ki_tab[step]
     tile, d = plan.tile, k_ref.shape[2]
+    if planes:                  # which of its K/V head's query heads
+        member = pl.program_id(0) % group
 
     if dq_refs:
         @pl.when(step == 0)
@@ -810,6 +896,11 @@ def _bwd_kernel(qi_tab, ki_tab, k_ref, v_ref, q_ref, do_ref, lse_ref,
 
     @pl.when(qi == jnp.minimum(plan.num - 1, ki + plan.dt_max))
     def _finish():
+        if planes:
+            rows = pl.ds(pl.multiple_of(ki * tile, tile), tile)
+            _group_sum(dk_ref, planes[0], dk_scr, rows, member, group, scale)
+            _group_sum(dv_ref, planes[1], dv_scr, rows, member, group, 1.0)
+            return
         dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
         if rope:
@@ -831,28 +922,40 @@ def _bwd_kernel(qi_tab, ki_tab, k_ref, v_ref, q_ref, do_ref, lse_ref,
 
 
 # What the fused backward call may hold of VMEM, and what of that a
-# head's dq may take: its float32 accumulator and the two buffers of
-# its output block.  A v5e core has 128 MiB; the rest of the call (the
-# tiles, dk's and dv's accumulators) fits the 16 MiB a call has by
-# default, as the dk-dv pass did.
+# head's dq may take (its float32 accumulator and the two buffers of
+# its output block) with, where a group of query heads reads one K/V
+# head, that head's dk and dv planes and their blocks' buffers.  A v5e
+# core has 128 MiB; the rest of the call (the tiles, a key block's dk
+# and dv accumulators) fits the 16 MiB a call has by default, as the
+# dk-dv pass did.
 VMEM_LIMIT = 96 * 2 ** 20
 _DQ_VMEM = VMEM_LIMIT - 16 * 2 ** 20
 
 
-def _backward_plan(t, d, d_rope, itemsize):
+def _backward_plan(t, d, d_rope, itemsize, group=1):
     """("fused", the MB of VMEM a head's dq takes there) or ("pair",
     why): which backward ``_pallas_bwd`` runs, from the shapes alone.
-    The fused call wants a head's whole dq in VMEM; a sequence too long
-    for that (over 65,536 at d=128 in bfloat16) keeps the two passes,
-    each of which holds a tile's."""
+    The fused call wants a head's whole dq in VMEM, and with ``group``
+    > 1 query heads to a K/V head that head's whole dk and dv beside it
+    (``dkv_acc_mb``: a float32 plane and an output block's two buffers
+    each, 32 MB at T 16,384 and d=128 in bfloat16); a sequence too long
+    for that (over 65,536 at d=128 in bfloat16, 32,768 already with a
+    group) keeps the two passes, each of which holds a tile's, and a
+    group's dk and dv are then summed outside."""
     lanes = lambda width: -(-width // STATS_LANES) * STATS_LANES
     out = 2 * itemsize * (lanes(d) + lanes(d_rope))   # two buffers each
     # float32: [d + d_rope, T] for a latent head, else [T, d]
     nbytes = t * (out + 4 * (d + d_rope if d_rope else lanes(d)))
     mb = -(-nbytes // 2 ** 20)
+    said = "dq_acc_mb=%d" % mb
+    if group > 1:
+        planes = 2 * t * lanes(d) * (4 + 2 * itemsize)
+        nbytes += planes
+        said += " dkv_acc_mb=%d" % -(-planes // 2 ** 20)
     if nbytes > _DQ_VMEM:
-        return "pair", "why=dq_acc_mb_%d_over_%d" % (mb, _DQ_VMEM // 2 ** 20)
-    return "fused", "dq_acc_mb=%d" % mb
+        return "pair", "why=%s_over_%d" % (
+            said.replace("=", "_").replace(" ", "_"), _DQ_VMEM // 2 ** 20)
+    return "fused", said
 
 
 def _pallas_bwd(q, k, v, out, lse, g, causal, scale, interpret,
@@ -863,18 +966,22 @@ def _pallas_bwd(q, k, v, out, lse, g, causal, scale, interpret,
     one pass (Q streamed) and dq in another (K streamed).  The
     probability/ds tiles live only in VMEM.  What is constant along a
     row is made once, out here: lse came with the residuals, delta_i =
-    sum_d dO_i O_i is one pass over dO and O.  Returns (dq, dk, dv) and,
+    sum_d dO_i O_i is one pass over dO and O.  Returns (dq, dk, dv), dk
+    and dv at k's and v's own head count (``_flash_forward``: the sum
+    over the query heads of a group, made in the fused call's VMEM, or
+    outside from the pair's per-head results) and,
     with ``rope`` (``_flash_forward``), also (dq_rope [b, h, t, dr],
     dk_rope [b, h, t, dr] float32: each head's part of the RoPE key's
     gradient, the caller's to sum: the key is one plane)."""
     b, h, t, d = q.shape
     dv = v.shape[3]
-    bh = b * h
+    bh, kv_heads = b * h, b * k.shape[1]
+    group = bh // kv_heads
     tile = _tile_for(t, q, rope)
     plan = _tile_plan(t, tile, causal, window)
     qr = q.reshape(bh, t, d)
-    kr = k.reshape(bh, t, d)
-    vr = v.reshape(bh, t, dv)
+    kr = k.reshape(kv_heads, t, d)
+    vr = v.reshape(kv_heads, t, dv)
     gr = g.astype(q.dtype).reshape(bh, t, dv)
     lse = lse.astype(jnp.float32).reshape(bh, 1, t)
     delta = (
@@ -882,14 +989,21 @@ def _pallas_bwd(q, k, v, out, lse, g, causal, scale, interpret,
     ).sum(axis=-1).reshape(bh, 1, t)
     latent = rope is not None
     dr = rope[0].shape[3] if latent else 0
-    fused = _backward_plan(t, d, dr, q.dtype.itemsize)[0] == "fused"
+    fused = _backward_plan(t, d, dr, q.dtype.itemsize, group)[0] == "fused"
     scratch = lambda width: pltpu.VMEM((tile, width), jnp.float32)
     shaped = lambda width, dtype: jax.ShapeDtypeStruct((bh, t, width), dtype)
+    # a plane's whole [t, width] block, for every ``heads`` grid rows
+    whole = lambda width, heads=1: pl.BlockSpec(
+        (1, t, width),
+        lambda i, s, qi_tab, ki_tab: (_plane_of(heads)(i), 0, 0))
 
     def call(kernel, name, order, operands, in_specs, out_shape, out_specs,
-             scratch_shapes, **params):
+             scratch_shapes, heads_apart=True, **params):
+        # ``heads_apart``: no value outlives a step of the grid's first
+        # axis; the fused call of a group sums dk, dv across them
         return pl.pallas_call(
-            functools.partial(kernel, plan=plan, scale=scale, rope=latent),
+            functools.partial(kernel, plan=plan, scale=scale, rope=latent,
+                              **({} if heads_apart else {"group": group})),
             out_shape=tuple(out_shape),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
@@ -899,19 +1013,21 @@ def _pallas_bwd(q, k, v, out, lse, g, causal, scale, interpret,
                 scratch_shapes=scratch_shapes,
             ),
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary"), **params),
+                dimension_semantics=(
+                    "parallel" if heads_apart else "arbitrary",
+                    "arbitrary"), **params),
             interpret=interpret,
             name=_call_name(name, window, (d + dr, dv) if latent else None),
         )(*plan.tables(order), *operands)
 
     # Keys resident: dk, dv (, dk_rope) ...
-    kv_spec, q_spec, _, qstat_spec = _tile_specs(tile, d, 1)
-    v_spec, do_spec = (kv_spec, q_spec) if dv == d else _tile_specs(
-        tile, dv, 1)[:2]
+    (q_spec, kv_spec, v_spec, do_spec, qstat_spec, dkv_spec,
+     ddv_spec) = _operand_specs(tile, d, dv, 1, group)
     operands = [kr, vr, qr, gr, lse, delta]
     in_specs = [kv_spec, v_spec, q_spec, do_spec, qstat_spec, qstat_spec]
+    # a query head's dk, dv, which a group's fused call does not write
     out_shape = [shaped(d, k.dtype), shaped(dv, v.dtype)]
-    out_specs = [kv_spec, v_spec]
+    out_specs = [dkv_spec, ddv_spec]
     scratch_shapes = [scratch(d), scratch(dv)]
     if latent:
         q_rope, k_rope, specs = _rope_parts(rope, 1, tile)
@@ -923,29 +1039,38 @@ def _pallas_bwd(q, k, v, out, lse, g, causal, scale, interpret,
         scratch_shapes.append(scratch(dr))
     if fused:
         # ... and dq (, dq_rope), a head's whole block
-        head = lambda width: pl.BlockSpec(
-            (1, t, width), lambda i, s, qi_tab, ki_tab: (i, 0, 0))
         out_shape.append(shaped(d, q.dtype))
-        out_specs.append(head(d))
+        out_specs.append(whole(d))
         if latent:
             out_shape.append(shaped(dr, q_rope.dtype))
-            out_specs.append(head(dr))
+            out_specs.append(whole(dr))
             scratch_shapes += [
                 pltpu.VMEM((plan.num, d + dr, tile), jnp.float32),
                 pltpu.VMEM((d + dr, tile), k.dtype)]
         else:
             scratch_shapes.append(pltpu.VMEM((t, d), jnp.float32))
+        if group > 1:
+            # dk, dv at the K/V heads: a head's whole block, written
+            # from the group's two float32 planes
+            out_shape[:2] = [
+                jax.ShapeDtypeStruct((kv_heads, t, d), k.dtype),
+                jax.ShapeDtypeStruct((kv_heads, t, dv), v.dtype)]
+            out_specs[:2] = [whole(d, group), whole(dv, group)]
+            scratch_shapes += [pltpu.VMEM((t, d), jnp.float32),
+                               pltpu.VMEM((t, dv), jnp.float32)]
         dkv = call(_bwd_kernel, "flash_bwd", plan.k_major, operands,
                    in_specs, out_shape, out_specs, scratch_shapes,
-                   vmem_limit_bytes=VMEM_LIMIT)
+                   heads_apart=group == 1, vmem_limit_bytes=VMEM_LIMIT)
         dkv, dq = dkv[:2 + latent], dkv[2 + latent:]
     else:
         dkv = call(_bwd_kernel, "flash_dkv", plan.k_major, operands,
                    in_specs, out_shape, out_specs, scratch_shapes)
+        if group > 1:
+            dkv = [x.reshape(kv_heads, group, t, x.shape[2]).sum(
+                axis=1, dtype=jnp.float32).astype(x.dtype) for x in dkv]
         # Queries resident: dq (, dq_rope).
-        q_spec, kv_spec, qstat_spec, _ = _tile_specs(tile, d, 0)
-        do_spec, v_spec = (q_spec, kv_spec) if dv == d else _tile_specs(
-            tile, dv, 0)[:2]
+        q_spec, kv_spec, v_spec, do_spec, qstat_spec, _, _ = _operand_specs(
+            tile, d, dv, 0, group)
         operands = [qr, gr, kr, vr, lse, delta]
         in_specs = [q_spec, do_spec, kv_spec, v_spec, qstat_spec,
                     qstat_spec]
@@ -962,7 +1087,7 @@ def _pallas_bwd(q, k, v, out, lse, g, causal, scale, interpret,
         dq = call(_bwd_dq_kernel, "flash_dq", plan.q_major, operands,
                   in_specs, out_shape, out_specs, scratch_shapes)
     grads = (dq[0], dkv[0], dkv[1]) + ((dq[1], dkv[2]) if latent else ())
-    return tuple(x.reshape(b, h, t, x.shape[2]) for x in grads)
+    return tuple(x.reshape(b, -1, t, x.shape[2]) for x in grads)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -1013,25 +1138,44 @@ def _unfriendly(t, d):
     return ""
 
 
+def flash_mode(t, d, interpret=None):
+    """(mode: "tpu" | "interpret" | "off" as ``flash_attention`` runs a
+    sequence of ``t`` at heads of ``d`` here, why not the kernel or
+    "")."""
+    mode = resolve(interpret)
+    why = _unfriendly(t, d) if mode != "off" else ""
+    return ("off" if why else mode), why
+
+
 def flash_attention(q, k, v, causal=True, scale=None, interpret=None,
                     window=0):
-    """q, k, v: [batch, heads, seq, head_dim].  ``window`` > 0 limits
+    """q: [batch, heads, seq, head_dim]; k, v: [batch, kv_heads, seq,
+    head_dim], ``kv_heads`` dividing ``heads`` (grouped-query
+    attention: query head i reads K/V head ``i // (heads // kv_heads)``
+    and dk, dv come back at ``kv_heads``; nothing is repeated for the
+    kernels, which index the K/V planes, and equal counts are the
+    multi-head case).  ``window`` > 0 limits
     causal attention to the last ``window`` positions (O(T·W) compute:
     blocks outside the band skip both matmuls and DMA).  The kernel
     where ``ops/mode.py`` allows one and the shape is friendly, per
     shard of the declared batch axis; else ``_attention_ref``."""
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     _check_window(window, causal)
-    mode = resolve(interpret)
+    if q.shape[1] % k.shape[1] or k.shape[1] != v.shape[1]:
+        raise ValueError(
+            "flash_attention takes K and V at one head count that divides "
+            "the queries'; got q %s, k %s, v %s"
+            % (q.shape, k.shape, v.shape))
+    mode, why = flash_mode(q.shape[2], q.shape[3], interpret)
     if mode != "off":
-        why = _unfriendly(q.shape[2], q.shape[3])
-        if not why:
-            return per_batch_shard(
-                lambda q, k, v: _flash(q, k, v, causal, scale,
-                                       mode == "interpret", window),
-                (q, k, v),
-            )
-        announce_fallback("flash_attention", q.shape, why, mode)
+        return per_batch_shard(
+            lambda q, k, v: _flash(q, k, v, causal, scale,
+                                   mode == "interpret", window),
+            (q, k, v),
+        )
+    if why:
+        announce_fallback("flash_attention", q.shape, why,
+                          resolve(interpret))
     return _attention_ref(q, k, v, causal, scale, window=window)
 
 

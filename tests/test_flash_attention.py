@@ -13,12 +13,46 @@ from elasticdl_tpu.ops.flash_attention import (
 )
 
 
-def make_qkv(b=2, h=2, t=256, d=64, seed=0):
+def make_qkv(b=2, h=2, t=256, d=64, seed=0, g=None):
+    """q at ``h`` heads, k and v at ``g`` (None: ``h``)."""
     rng = np.random.RandomState(seed)
-    shape = (b, h, t, d)
     return tuple(
-        jnp.asarray(rng.randn(*shape).astype(np.float32)) for _ in range(3)
+        jnp.asarray(rng.randn(b, heads, t, d).astype(np.float32))
+        for heads in (h, g or h, g or h)
     )
+
+
+def _on_repeated(k, v, h):
+    """K and V spread to ``h`` query heads the long way, group order
+    consecutive: what the kernels' ``head // group`` index stands for.
+    A gradient taken through it is the group's sum."""
+    return tuple(jnp.repeat(x, h // x.shape[1], axis=1) for x in (k, v))
+
+
+@pytest.mark.parametrize("window", [0, 200])
+@pytest.mark.parametrize("h,g,d", [(4, 2, 64), (7, 1, 128), (8, 1, 64),
+                                   (8, 4, 128)])
+def test_grouped_forward_reads_kv_head_of_its_group(h, g, d, window):
+    """K and V at their own head count (groups of 2, 7 and 8 query
+    heads; two batch rows, so that ``(b * H + h) // group`` crosses a
+    row): the kernel's output is the reference's on K/V repeated the
+    long way, and the reference given G heads repeats inside itself."""
+    q, k, v = make_qkv(b=2, h=h, t=384, d=d, seed=h + d, g=g)
+    scale = d ** -0.5
+    want = _attention_ref(q, *_on_repeated(k, v, h), True, scale,
+                          window=window)
+    np.testing.assert_array_equal(
+        np.asarray(_attention_ref(q, k, v, True, scale, window=window)),
+        np.asarray(want))
+    out = flash_attention(q, k, v, window=window, interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_kv_heads_that_do_not_divide_the_queries_are_refused():
+    q, k, v = make_qkv(h=4, t=128, g=3)
+    with pytest.raises(ValueError, match="divides"):
+        flash_attention(q, k, v, interpret=True)
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -175,86 +209,127 @@ def _backward_calls(fn, *args):
                   if e.primitive.name == "pallas_call")
 
 
-@pytest.mark.parametrize("case,b,h,t,d,causal,dtype,tol", [
+@pytest.mark.parametrize("case,b,h,g,t,d,causal,window,dtype,tol", [
     # three key blocks a head (tile 128): query tile 2's dq is added to
     # at grid steps 2, 4 and 5 of (0,0) (1,0) (2,0) (1,1) (2,1) (2,2)
-    ("three-key-blocks", 2, 3, 384, 128, True, "float32", 1e-4),
+    ("three-key-blocks", 2, 3, 3, 384, 128, True, 0, "float32", 1e-4),
     # every query tile ends in the last key block: the head's dq is
     # written whole at the head's last step
-    ("not-causal", 1, 2, 384, 64, False, "float32", 1e-4),
+    ("not-causal", 1, 2, 2, 384, 64, False, 0, "float32", 1e-4),
     # rows over 512 bytes take the 512 tile: two key blocks of 4 x 4
-    ("d256-float32", 1, 2, 1024, 256, True, "float32", 1e-4),
+    ("d256-float32", 1, 2, 2, 1024, 256, True, 0, "float32", 1e-4),
     # sub-tiles stacked a key chunk at a time across a 1024 tile's
     # diagonal, in the storage dtype the cells run
-    ("bfloat16-two-tiles", 1, 2, 2048, 64, True, "bfloat16", 2e-2),
+    ("bfloat16-two-tiles", 1, 2, 2, 2048, 64, True, 0, "bfloat16", 2e-2),
+    # K and V at their own head count: a K/V head's dk and dv planes are
+    # started by its group's first query head, added to by the middle
+    # ones and written by the last, a key block's rows at a time (three
+    # key blocks a head), across two batch rows
+    ("group-2", 2, 4, 2, 384, 64, True, 0, "float32", 1e-4),
+    ("group-7-window", 1, 7, 1, 384, 128, True, 200, "float32", 1e-4),
+    ("group-8", 2, 8, 1, 384, 128, True, 0, "float32", 1e-4),
+    ("group-8-window-d64", 1, 8, 1, 384, 64, True, 200, "float32", 1e-4),
+    ("group-4-not-causal", 1, 8, 2, 256, 64, False, 0, "float32", 1e-4),
+    # the one rounding of the float32 sum, over two key blocks of 1024
+    ("group-2-bfloat16-two-tiles", 1, 2, 1, 2048, 128, True, 0, "bfloat16",
+     2e-2),
 ])
-def test_fused_backward_matches_reference(case, b, h, t, d, causal, dtype,
-                                          tol):
-    """dq, dk and dv of the fused backward against ``_attention_ref``,
-    each head against its own reference: a head's dq accumulator is
-    revisited across grid steps that are not adjacent, and zeroed at
-    the head's first step (batch x heads > 1, every head's values its
-    own)."""
+def test_fused_backward_matches_reference(case, b, h, g, t, d, causal,
+                                          window, dtype, tol):
+    """dq, dk and dv of the fused backward against ``_attention_ref``
+    on K and V repeated to the query heads the long way (dk and dv, at
+    K's and V's own head count, against the sum over the group that the
+    repeat's transpose takes), each head against its own reference: a
+    head's dq accumulator is revisited across grid steps that are not
+    adjacent, and zeroed at the head's first step (batch x heads > 1,
+    every head's values its own)."""
     import elasticdl_tpu.ops.flash_attention as fa
 
     q, k, v = (x.astype(dtype) for x in make_qkv(b=b, h=h, t=t, d=d,
-                                                 seed=t + d))
-    g = make_qkv(b=b, h=h, t=t, d=d, seed=7)[0].astype(dtype)
+                                                 seed=t + d, g=g))
+    cot = make_qkv(b=b, h=h, t=t, d=d, seed=7)[0].astype(dtype)
 
     def loss(op):
         return lambda q, k, v: (op(q, k, v).astype(jnp.float32)
-                                * g.astype(jnp.float32)).sum()
+                                * cot.astype(jnp.float32)).sum()
 
     flash = loss(lambda q, k, v: fa.flash_attention(
-        q, k, v, causal=causal, interpret=True))
-    ref = loss(lambda q, k, v: fa._attention_ref(q, k, v, causal,
-                                                 d ** -0.5))
-    assert _backward_calls(flash, q, k, v) == ["flash_bwd", "flash_fwd"]
+        q, k, v, causal=causal, window=window, interpret=True))
+    ref = loss(lambda q, k, v: fa._attention_ref(
+        q, *_on_repeated(k, v, h), causal, d ** -0.5, window=window))
+    suffix = "_w%d" % window if window else ""
+    assert _backward_calls(flash, q, k, v) == ["flash_bwd" + suffix,
+                                               "flash_fwd" + suffix]
     got = jax.grad(flash, (0, 1, 2))(q, k, v)
     want = jax.grad(ref, (0, 1, 2))(q, k, v)
     for name, a, w in zip("qkv", got, want):
-        a, w = (np.asarray(x, np.float32) for x in (a, w))
-        for head in range(b * h):       # a head's error over ITS largest
-            ah, wh = (x.reshape(b * h, t, d)[head] for x in (a, w))
+        assert a.shape == w.shape == (b, g if name in "kv" else h, t, d)
+        a, w = (np.asarray(x, np.float32).reshape(-1, t, d)
+                for x in (a, w))
+        for head, (ah, wh) in enumerate(zip(a, w)):
+            # a head's error over ITS largest
             assert np.abs(ah - wh).max() <= tol * np.abs(wh).max(), (
                 case, name, head)
 
 
-@pytest.mark.parametrize("t,d,d_rope,itemsize,want", [
-    (2048, 128, 0, 2, ("fused", "dq_acc_mb=2")),
-    (8192, 64, 0, 2, ("fused", "dq_acc_mb=8")),
-    (16384, 128, 0, 2, ("fused", "dq_acc_mb=16")),
-    (16384, 128, 64, 2, ("fused", "dq_acc_mb=28")),
-    (65536, 128, 0, 2, ("fused", "dq_acc_mb=64")),
-    (131072, 128, 0, 2, ("pair", "why=dq_acc_mb_128_over_80")),
-    (65536, 128, 0, 4, ("pair", "why=dq_acc_mb_96_over_80")),
+@pytest.mark.parametrize("t,d,d_rope,itemsize,group,want", [
+    (2048, 128, 0, 2, 1, ("fused", "dq_acc_mb=2")),
+    (8192, 64, 0, 2, 1, ("fused", "dq_acc_mb=8")),
+    (16384, 128, 0, 2, 1, ("fused", "dq_acc_mb=16")),
+    (16384, 128, 64, 2, 1, ("fused", "dq_acc_mb=28")),
+    (65536, 128, 0, 2, 1, ("fused", "dq_acc_mb=64")),
+    (131072, 128, 0, 2, 1, ("pair", "why=dq_acc_mb_128_over_80")),
+    (65536, 128, 0, 4, 1, ("pair", "why=dq_acc_mb_96_over_80")),
+    # the three grouped cells': 32 on 4 and 28 on 4 at 16,384 x 128, 32
+    # on 8 at 8,192 x 64 (a plane's 64 lanes padded to 128)
+    (16384, 128, 0, 2, 8, ("fused", "dq_acc_mb=16 dkv_acc_mb=32")),
+    (16384, 128, 0, 2, 7, ("fused", "dq_acc_mb=16 dkv_acc_mb=32")),
+    (8192, 64, 0, 2, 4, ("fused", "dq_acc_mb=8 dkv_acc_mb=16")),
+    # dq alone would fit: the group's planes beside it do not
+    (32768, 128, 0, 2, 8,
+     ("pair", "why=dq_acc_mb_32_dkv_acc_mb_64_over_80")),
 ])
 def test_the_backward_is_chosen_from_the_shapes_alone(t, d, d_rope,
-                                                      itemsize, want):
+                                                      itemsize, group, want):
     """Fused wherever a head's float32 dq and its output block's two
-    buffers fit the VMEM the call may hold; every cell's shape does."""
+    buffers (and, with a group of query heads to a K/V head, that
+    head's float32 dk and dv planes and their blocks' buffers) fit the
+    VMEM the call may hold; every cell's shape does."""
     import elasticdl_tpu.ops.flash_attention as fa
 
-    assert fa._backward_plan(t, d, d_rope, itemsize) == want
+    assert fa._backward_plan(t, d, d_rope, itemsize, group) == want
 
 
-def test_a_dq_too_long_for_vmem_keeps_the_two_passes(monkeypatch):
-    """Past the budget (here set to 64 KiB: t=384 at d=64 wants 576)
-    the backward is the dk-dv pass, the fused body without dq's part,
-    and the dq pass, and the gradients are the fused call's."""
+@pytest.mark.parametrize("h,g,budget_kb,why", [
+    # t=384 at d=64 in float32: a head's dq wants 576 KiB
+    (2, 2, 64, "why=dq_acc_mb_1_over_0"),
+    # ... which fits 1 MiB, but not with a K/V head's dk and dv planes
+    # (1,152 KiB) beside it
+    (4, 2, 1024, "why=dq_acc_mb_1_dkv_acc_mb_2_over_1"),
+    (7, 1, 1024, "why=dq_acc_mb_1_dkv_acc_mb_2_over_1"),
+])
+def test_a_dq_too_long_for_vmem_keeps_the_two_passes(monkeypatch, h, g,
+                                                     budget_kb, why):
+    """Past the budget the backward is the dk-dv pass, the fused body
+    without dq's part, and the dq pass, and the gradients are the fused
+    call's; ``_backward_plan`` says which accumulators did not fit.
+    With a group both passes still read K/V head ``head // group``, and
+    the dk-dv pass's per-query-head results are summed outside."""
     import elasticdl_tpu.ops.flash_attention as fa
 
-    q, k, v = make_qkv(b=1, h=2, t=384, d=64, seed=3)
+    q, k, v = make_qkv(b=1, h=h, t=384, d=64, seed=3, g=g)
     loss = lambda q, k, v: (fa.flash_attention(
         q, k, v, window=200, interpret=True) ** 2).sum()
+    assert fa._backward_plan(384, 64, 0, 4, h // g)[0] == "fused"
     fused = jax.grad(loss, (0, 1, 2))(q, k, v)
-    monkeypatch.setattr(fa, "_DQ_VMEM", 64 * 1024)
-    assert fa._backward_plan(384, 64, 0, 4)[0] == "pair"
+    monkeypatch.setattr(fa, "_DQ_VMEM", budget_kb * 1024)
+    assert fa._backward_plan(384, 64, 0, 4, h // g) == ("pair", why)
     assert _backward_calls(loss, q, k, v) == ["flash_dkv_w200",
                                               "flash_dq_w200",
                                               "flash_fwd_w200"]
     pair = jax.grad(loss, (0, 1, 2))(q, k, v)
     for a, b in zip(fused, pair):
+        assert a.shape == b.shape
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5)
 
@@ -408,14 +483,17 @@ def test_tile_census_is_announced_once_per_shape():
             fa.announce_tiles(4, 1024, 64, 1024, True, 0)
         fa.announce_tiles(4, 1024, 64, 1024, True, 256)
     assert [c.args[0] for c in info.call_args_list] == [
-        fa.tile_census(4, 1024, 64, 1024, True, 0),
-        fa.tile_census(4, 1024, 64, 1024, True, 256)]
+        fa.tile_census(4, 1024, 64, 1024, True, 0) + " kv_heads=4 group=1",
+        fa.tile_census(4, 1024, 64, 1024, True, 256)
+        + " kv_heads=4 group=1"]
 
 
 def test_the_line_says_which_backward_the_shape_got(monkeypatch):
-    """``backward=fused dq_acc_mb=<n>`` behind the census, or
-    ``backward=pair why=<reason>``: once per compiled shape, from the
-    forward that traces it (the interpreter logs nothing)."""
+    """``kv_heads=<batch x K/V heads> group=<query heads to each>``
+    behind the census, then ``backward=fused dq_acc_mb=<n>`` (and
+    ``dkv_acc_mb=<n>`` where a group's dk and dv planes are resident)
+    or ``backward=pair why=<reason>``: once per compiled shape, from
+    the forward that traces it (the interpreter logs nothing)."""
     import elasticdl_tpu.ops.flash_attention as fa
 
     fa.announce_tiles.cache_clear()
@@ -423,15 +501,19 @@ def test_the_line_says_which_backward_the_shape_got(monkeypatch):
         lambda *operands: tuple(jnp.zeros(s.shape, s.dtype)
                                 for s in kw["out_shape"])))
     x = jnp.zeros((1, 2, 2048, 128), jnp.bfloat16)
+    one = x[:, :1]
     with mock.patch.object(fa.logger, "info") as info:
         for _ in range(2):
             fa._flash_forward(x, x, x, True, 1.0, False)
+        fa._flash_forward(x, one, one, True, 1.0, False)
         monkeypatch.setattr(fa, "_DQ_VMEM", 2 ** 20)
         fa._flash_forward(x, x, x, True, 1.0, False, window=512)
         fa._flash_forward(x, x, x, True, 1.0, False, normalize=False)
     census = fa.tile_census(2, 2048, 128, 1024, True, 0)
     assert [c.args[0] for c in info.call_args_list] == [
-        census + " backward=fused dq_acc_mb=2",
+        census + " kv_heads=2 group=1 backward=fused dq_acc_mb=2",
+        census + " kv_heads=1 group=2 backward=fused dq_acc_mb=2 "
+        "dkv_acc_mb=4",
         fa.tile_census(2, 2048, 128, 1024, True, 512)
-        + " backward=pair why=dq_acc_mb_2_over_1",
-        census + " backward=scan why=ring_partial"]
+        + " kv_heads=2 group=1 backward=pair why=dq_acc_mb_2_over_1",
+        census + " kv_heads=2 group=1 backward=scan why=ring_partial"]

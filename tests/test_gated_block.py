@@ -314,11 +314,13 @@ def test_the_head_norm_stands_on_a_layer_with_and_without_rope(rope):
     """``qk_norm="head"`` on both attention kinds of the stack: q and k
     are normed over each head's values before RoPE where the layer has
     it, and where it has none they are the normed projections
-    themselves."""
+    themselves; head-major as the kernels take them, [B, H, T, D] and
+    [B, G, T, D]."""
     cfg = tfm.model_spec(**TINY).config
     x, w = _layer(cfg)
     positions = jnp.arange(cfg.max_seq_len)
-    q, k, v = tfm._project_qkv(x, w, cfg, positions, rope)
+    q, k, v = (a.transpose(0, 2, 1, 3)
+               for a in tfm._project_qkv(x, w, cfg, positions, rope))
     heads = lambda a, n: a.reshape(2, cfg.max_seq_len, n, cfg.head_dim)
     want_q = REF.rmsnorm(heads(x @ w["wq"], 4), w["q_norm"], cfg.norm_eps)
     want_k = REF.rmsnorm(heads(x @ w["wk"], 2), w["k_norm"], cfg.norm_eps)
@@ -498,7 +500,12 @@ def test_with_the_three_fields_off_tree_and_program_are_the_parents(name):
     every nested jaxpr, and the length of the jaxpr's text are those
     recorded from the parent of the PR that gave the block its one
     ``x + post(Op(pre(x)))`` (tests/plain_block_program.json; the two
-    texts were the same character for character)."""
+    texts were the same character for character).  The four
+    equal-width models' primitives and text lengths were recorded again
+    at PR 42 (q, k, v written head-major by the projections, RoPE by
+    its permutation product, ``wo`` over (head, width): ``dense`` 44,604
+    -> 42,783 characters); the latent stack's, which ran none of the
+    changed code, are the record's as they were, and the trees are."""
     from tests.test_mixed_stack import _eqns
 
     was = PLAIN[name]
@@ -529,12 +536,9 @@ def test_a_block_with_norms_and_gate_off_is_the_plain_block_bit_for_bit():
     got = tfm._layer_body(x, w, cfg, None, positions, kind=kind)[0]
     h = tfm._rmsnorm(x, w["ln1"], cfg.norm_eps)
     q, k, v = tfm._project_qkv(h, w, cfg, positions, True)
-    k, v = (jnp.repeat(a, 2, axis=2) for a in (k, v))
-    attn = fa.flash_attention(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), causal=True, window=cfg.window,
-    ).transpose(0, 2, 1, 3).reshape(2, cfg.max_seq_len, 128)
-    mid = x + attn @ w["wo"]
+    attn = fa.flash_attention(q, k, v, causal=True, window=cfg.window)
+    mid = x + jnp.einsum("bhtk,hkd->btd", attn,
+                         w["wo"].reshape(4, 32, cfg.dim))
     u = tfm._rmsnorm(mid, w["ln2"], cfg.norm_eps)
     want = mid + (tfm._moe_ffn(u, w, cfg, None)[0]
                   + tfm._shared_expert(u, w, cfg))
@@ -542,6 +546,173 @@ def test_a_block_with_norms_and_gate_off_is_the_plain_block_bit_for_bit():
     extra = {"w_attn_gate", "ln1_post", "ln2_post"}
     assert not extra & set(tfm.init_params(
         jax.random.PRNGKey(0), cfg)["layers"]["tail"]["0"])
+
+
+# -- the projections: the kernels' planes from the matmuls themselves -------------
+
+
+def _token_major_projections(h, w, cfg, positions, rope):
+    """``_project_qkv`` as it was before the projections wrote the
+    kernels' planes themselves: one product a weight on [B, T, heads *
+    D], the whole-projection norm there, the per-head norm and RoPE on
+    [B, T, heads, D]."""
+    dtype = jnp.dtype(cfg.dtype)
+    B, T = h.shape[:2]
+
+    def project(name, norm, heads):
+        x = h @ w[name].astype(dtype)
+        if norm and cfg.qk_norm != "head":
+            x = tfm._rmsnorm(x, w[norm].astype(dtype), cfg.norm_eps)
+        x = x.reshape(B, T, heads, cfg.head_dim)
+        if norm and cfg.qk_norm == "head":
+            x = tfm._rmsnorm(x, w[norm].astype(dtype), cfg.norm_eps)
+        return x
+
+    q = project("wq", cfg.qk_norm and "q_norm", cfg.num_heads)
+    k = project("wk", cfg.qk_norm and "k_norm", cfg.kv_heads)
+    v = project("wv", None, cfg.kv_heads)
+    if rope:
+        q = tfm._rope(q, positions, cfg.rope_theta)
+        k = tfm._rope(k, positions, cfg.rope_theta)
+    return q, k, v
+
+
+def _token_major_mix(h, w, cfg, positions, kind):
+    """``_attention_mix`` over those: K and V repeated to the query
+    heads, the three transposed to the op's [B, H, T, D], its output
+    transposed back and flattened before the gate and ``wo``."""
+    dtype = jnp.dtype(cfg.dtype)
+    B, T = h.shape[:2]
+    q, k, v = _token_major_projections(h, w, cfg, positions, kind.rope)
+    group = cfg.num_heads // cfg.kv_heads
+    k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
+    attn = fa.flash_attention(
+        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+        v.transpose(0, 2, 1, 3), causal=True, window=kind.window,
+    ).transpose(0, 2, 1, 3).reshape(B, T, -1)
+    if cfg.attn_gate:
+        attn = attn * jax.nn.sigmoid(h @ w["w_attn_gate"].astype(dtype))
+    return attn @ w["wo"].astype(dtype)
+
+
+@pytest.mark.parametrize("gate", [False, True])
+@pytest.mark.parametrize("rope", [False, True])
+@pytest.mark.parametrize("qk_norm", [False, True, "head"])
+def test_the_projections_equal_the_token_major_ones(monkeypatch, qk_norm,
+                                                    rope, gate):
+    """q, k, v (head-major, K and V at their own 2 heads for 4 query
+    heads), ``_attention_mix``'s result and the gradients of every
+    weight and of the input, from products that write [B, heads, T, D]
+    themselves (per-head views of ``wq`` / ``wk`` / ``wv`` / the gate's
+    weight, the whole-projection norm over (head, width), RoPE's halves
+    swapped by a permutation product, ``wo`` over (head, width)),
+    against the token-major form they replace with its repeat and its
+    four transposes: float32 lands within its rounding, for each kind
+    of QK norm, with and without RoPE and the gate."""
+    monkeypatch.setenv("ELASTICDL_FLASH", "off")
+    cfg = tfm.TransformerConfig(
+        vocab_size=64, dim=64, num_heads=4, num_kv_heads=2, head_dim=32,
+        num_layers=1, max_seq_len=32, qk_norm=qk_norm, attn_gate=gate,
+        rope_theta=10000, norm_eps=1e-5, dtype="float32")
+    H, G, D, E = 4, 2, 32, cfg.dim
+    shapes = {"wq": (E, H * D), "wk": (E, G * D), "wv": (E, G * D),
+              "wo": (H * D, E)}
+    if gate:
+        shapes["w_attn_gate"] = (E, H * D)
+    if qk_norm:
+        whole = qk_norm != "head"
+        shapes.update(q_norm=(H * D if whole else D,),
+                      k_norm=(G * D if whole else D,))
+    rng = np.random.default_rng(11)
+    w = {name: jnp.asarray(
+        (1.0 + 0.25 * rng.uniform(-1, 1, shape)) if "norm" in name
+        else rng.standard_normal(shape) * shape[0] ** -0.5, jnp.float32)
+        for name, shape in shapes.items()}
+    draw = lambda: jnp.asarray(rng.standard_normal((2, 32, E)), jnp.float32)
+    h, g = draw(), draw()
+    positions = jnp.arange(32)
+    kind = tfm.Kind("a", False, 0, rope)
+
+    got = tfm._project_qkv(h, w, cfg, positions, rope)
+    want = _token_major_projections(h, w, cfg, positions, rope)
+    for name, a, b, heads in zip("qkv", got, want, (H, G, G)):
+        assert a.shape == (2, heads, 32, D), name
+        assert _apart(a, b.transpose(0, 2, 1, 3)) <= 2e-6, name
+
+    def run(fn):
+        out, vjp = jax.vjp(
+            lambda h, w: fn(h, w, cfg, positions, kind), h, w)
+        return out, vjp(g)
+
+    (out, (dh, dw)), (ref, (ref_dh, ref_dw)) = (
+        jax.jit(lambda: run(lambda *a: tfm._attention_mix(
+            a[0], a[1], a[2], None, *a[3:])[0]))(),
+        jax.jit(lambda: run(_token_major_mix))())
+    assert out.shape == (2, 32, E)
+    assert _apart(out, ref) <= 2e-6
+    assert _apart(dh, ref_dh) <= 2e-6
+    for name in w:
+        assert dw[name].shape == w[name].shape, name
+        assert _apart(dw[name], ref_dw[name]) <= 2e-6, name
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_the_traced_step_moves_no_activation_round_the_flash_calls(
+        monkeypatch, remat):
+    """The training step of a grouped-query stack (2 query heads on 1,
+    windowed and full layers, per-head QK norm, RoPE, the gate) traced
+    with the kernels: every ``pallas_call`` takes q at the query heads
+    and K, V at their own count, the backward's dk and dv leave at that
+    count, and no ``broadcast_in_dim`` anywhere in the step (forward,
+    backward, a rematerialized layer's second forward) has the
+    repeat's result, K / V spread to [B, G, group, T, D]: nothing
+    spreads a K/V head over its group, and so nothing sums a gradient
+    back over it (a table or a norm's scale broadcast to [B, H, T, D]
+    is elementwise work's, fused).  Under ``off`` the same model
+    repeats inside the reference (a ``broadcast_in_dim`` to [B, G,
+    group, T, D]), which is what the count would catch.  (A jaxpr's ``transpose`` says nothing here: an
+    einsum's is the order its matmul writes, folded by the compiler;
+    that no copy stands between the projections and the calls is held
+    on the TPU compiler's own program,
+    tests/test_flash_compile_tpu.py::test_a_gqa_layer_moves_...)"""
+    from tests.test_mixed_stack import _eqns
+
+    B, T = 2, 256
+
+    def step_eqns(mode):
+        monkeypatch.setenv("ELASTICDL_FLASH", mode)
+        spec = tfm.model_spec(**dict(KERNEL, remat=remat))
+        params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+        tokens = jnp.zeros((B, T), jnp.int32)
+        return spec.config, list(_eqns(jax.make_jaxpr(jax.grad(
+            lambda p: _loss(spec, tokens)(p)))(params).jaxpr))
+
+    def moved(cfg, eqns):
+        wide = B * T * cfg.num_heads * cfg.head_dim
+        return [(e.primitive.name, v.aval.shape) for e in eqns
+                if e.primitive.name == "broadcast_in_dim"
+                for v in e.outvars
+                if len(v.aval.shape) == 5 and v.aval.size == wide]
+
+    cfg, eqns = step_eqns("interpret")
+    H, G, D = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+    assert (H, G) == (2, 1)
+    assert not moved(cfg, eqns), moved(cfg, eqns)
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    name = lambda e: e.params["name"]
+    fwd = [e for e in calls if name(e).startswith("flash_fwd")]
+    bwd = [e for e in calls if name(e).startswith("flash_bwd")]
+    assert len(bwd) == 3 and len(fwd) == (6 if remat else 3), (
+        [name(e) for e in calls])
+    planes = lambda vs: [v.aval.shape for v in vs
+                         if v.aval.shape[1:] == (T, D)]
+    for e in fwd:       # q, k, v in; the output (and the row stats) out
+        assert planes(e.invars) == [(B * H, T, D)] + 2 * [(B * G, T, D)]
+    for e in bwd:       # k, v, q, dO in; dk, dv, dq out
+        assert planes(e.invars) == 2 * [(B * G, T, D)] + 2 * [(B * H, T, D)]
+        assert planes(e.outvars) == 2 * [(B * G, T, D)] + [(B * H, T, D)]
+    assert ("broadcast_in_dim", (B, G, H // G, T, D)) in moved(
+        *step_eqns("off"))
 
 
 # -- what does not run it says so by name ------------------------------------
@@ -612,11 +783,26 @@ def test_latent_attention_and_the_convolution_refuse_the_gate_by_name(
 # -- the lines ---------------------------------------------------------------
 
 
-def test_the_attention_block_line_says_what_runs_and_what_the_repeat_moves():
+def test_the_attention_block_line_says_what_runs_and_what_the_repeat_moves(
+        monkeypatch):
     """Once per compiled shape: heads, the block's pieces, and the bytes
-    of K, V and their gradients beyond what the K/V heads hold (4 x
+    of K, V and their gradients beyond what the K/V heads hold where
+    they are repeated to the query heads: the jnp reference does (4 x
     (heads - kv_heads) x rows x head_dim x size a layer, and K and V
-    once more in a rematerialized layer's backward); 0 without GQA."""
+    once more in a rematerialized layer's backward), the kernels read
+    K/V head ``head // group`` and the line, still printed, says 0 and
+    0; 0 without GQA."""
+    tfm.announce_attention.cache_clear()
+    monkeypatch.setenv("ELASTICDL_FLASH", "interpret")
+    kernel = tfm.model_spec(**dict(KERNEL, remat=True))
+    shapes = jax.eval_shape(kernel.init_fn, jax.random.PRNGKey(0))
+    line, = _lines(lambda: jax.eval_shape(_loss(
+        kernel, jnp.zeros((1, 256), jnp.int32)), shapes), "attention block:")
+    assert line == (
+        "attention block: rows=256 heads=2 kv_heads=1 head_dim=64 "
+        "qk_norm=head gate=1 out_norms=1 embed_multiplier=11.3137 layers=3 "
+        "kv_repeat_bytes=0 kv_repeat_again_bytes=0")
+    monkeypatch.setenv("ELASTICDL_FLASH", "off")
     tfm.announce_attention.cache_clear()
     spec = tfm.model_spec(**dict(TINY, remat=True))
     params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))

@@ -170,7 +170,10 @@ def test_one_chip_attention_is_the_op_itself(monkeypatch, mode):
     x, w, positions = _layer_call(cfg)
     got, _ = tfm._attention(x, w, cfg, None, positions)
     h = tfm._rmsnorm(x, w["ln1"], cfg.norm_eps)
-    q, k, v = tfm._project_qkv(h, w, cfg, positions)
+    # head-major from the projections; the sequence-parallel module
+    # takes token-major operands
+    q, k, v = (a.transpose(0, 2, 1, 3)
+               for a in tfm._project_qkv(h, w, cfg, positions))
     attn = ring_attention(q, k, v, None, causal=True)
     want = x + attn.reshape(2, 128, cfg.dim) @ w["wo"]
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)

@@ -343,7 +343,10 @@ def test_equal_widths_trace_the_kernels_they_traced():
     child of 7ba575a that made the backward one call
     (tests/flash_equal_width_program.json; until then the record was
     the pair's, from the parent of the PR that taught the kernels two
-    widths): nothing of a latent head's parts is traced here."""
+    widths): nothing of a latent head's parts is traced here, and, K
+    and V at the queries' head count being group 1, nothing of PR 42's
+    ``head // group`` index or its dk / dv planes either: the record
+    stands as PR 41 left it."""
     from tests.test_mixed_stack import _eqns
 
     with open(os.path.join(HERE, "flash_equal_width_program.json")) as fh:

@@ -464,7 +464,13 @@ def test_a_patternless_models_tree_and_program_are_what_they_were(name):
     configurations in small): the parameter tree, the seeded values and
     the training program's primitives, counted through every nested
     jaxpr, are those recorded from the tree before the stack learned
-    kinds (tests/patternless_program.json, PR 29's commit)."""
+    kinds (tests/patternless_program.json, PR 29's commit); the
+    primitive counts were recorded again at PR 42, whose projections
+    write q, k, v head-major and whose ``wo`` contracts (head, width):
+    6 ``dot_general`` more (RoPE's permutation product on q and k,
+    forward and backward; ``dense``: 38 -> 44), RoPE's slices, pads,
+    split and one concatenate a turn gone, two transposes fewer; the
+    tree and the seeded values are PR 29's."""
     with open(os.path.join(HERE, "patternless_program.json")) as fh:
         was = json.load(fh)[name]
     spec = tfm.model_spec(vocab_size=128, dim=64, num_heads=4,

@@ -2,14 +2,18 @@
 """Time the flash-attention kernels alone, on the chip.
 
     python3 tools/flash_kernels_on_chip.py [--bh 128] [--t 2048] [--d 128]
-        [--window 0] [--latent] [--root DIR] [--set NAME=INT]
+        [--kv_heads N] [--window 0] [--latent] [--root DIR] [--set NAME=INT]
 
 Prints one JSON line: ms a call of the forward and the backward kernels
 (device time of each ``custom-call`` event in a profiler trace, by the
 name the call carries: ``fwd`` and ``bwd``, or ``fwd``, ``dq`` and
 ``dkv`` for a ``--root`` from before the backward was one call) and of
 the whole forward and backward on the host's clock (XLA glue around the
-kernels included).  ``--latent`` times ``latent_attention``'s calls:
+kernels included).  ``--kv_heads N`` gives K and V ``N`` heads for the
+``--bh`` query heads (grouped-query attention: the kernels read K/V head
+``head // (bh / N)`` and the backward sums dk, dv over the group; a
+``--root`` from before the kernels took K/V at their own head count is
+given them repeated, the call its model made).  ``--latent`` times ``latent_attention``'s calls:
 scores over ``--d`` + 64, one RoPE key a sequence, values of ``--d``.
 ``--root DIR`` imports ``elasticdl_tpu`` from another checkout (the
 parent commit unpacked beside this one), so one call measures both.
@@ -32,6 +36,8 @@ def main():
     ap.add_argument("--bh", type=int, default=128)
     ap.add_argument("--t", type=int, default=2048)
     ap.add_argument("--d", type=int, default=128)
+    ap.add_argument("--kv_heads", type=int, default=0,
+                    help="K/V heads for the --bh query heads (0: --bh)")
     ap.add_argument("--window", type=int, default=0)
     ap.add_argument("--latent", action="store_true")
     ap.add_argument("--iters", type=int, default=20)
@@ -69,7 +75,10 @@ def main():
         static = (True, (args.d + rope) ** -0.5, False, args.window)
         fwd_rule, bwd_rule = fa._latent_fwd, fa._latent_bwd
     else:
-        operands = tuple(tensor(*wide) for _ in range(3))
+        grouped = "flash_mode" in vars(fa)    # K/V at their own count
+        kv = (1, args.kv_heads or args.bh if grouped else args.bh,
+              args.t, args.d)
+        operands = (tensor(*wide), tensor(*kv), tensor(*kv))
         static = (True, args.d ** -0.5, False, args.window)
         if "block_q" in inspect.signature(fa._flash_fwd).parameters:
             # --root at a checkout from before PR 28: block_q, block_k
@@ -88,7 +97,8 @@ def main():
 
     _, res = fwd(*operands)
     row = {"root": args.root, "set": args.set, "device": dev.device_kind,
-           "shape": [args.bh, args.t, args.d], "window": args.window,
+           "shape": [args.bh, args.t, args.d],
+           "kv_heads": operands[2].shape[1], "window": args.window,
            "latent": args.latent,
            "host_ms": {"fwd": host_ms(fwd, *operands),
                        "bwd": host_ms(bwd, res, g)}}
