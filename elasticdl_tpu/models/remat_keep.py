@@ -52,7 +52,7 @@ KEEP_SHARED_GATE, KEEP_SHARED_UP = "shared_gate", "shared_up"
 # ``step_bytes`` does not see.
 RESERVE = 0.05
 
-# remat="attn": the first entry whatever the room.
+# The ``flash`` entry's names: the kernel's output and row statistics.
 ATTN_NAMES = (flash_attention.KEEP_OUT, flash_attention.KEEP_LSE)
 
 # The entries of ``table`` that are products of a layer's own second

@@ -184,11 +184,8 @@ class TransformerConfig:
     # ``remat keep:`` line says which).  Where no memory is stated (the
     # CPU, a model-parallel mesh, the pipelined forward) nothing is
     # kept and the backward runs every layer's forward again, ~1/3 more
-    # FLOPs.  "attn" = keep the flash kernel's output and row statistics
-    # whatever the room, so the recompute skips flash attention;
-    # "dots" = keep every matmul's output and recompute the elementwise
-    # work alone (more memory than a chip has at training sizes).
-    remat: bool | str = False
+    # FLOPs.
+    remat: bool = False
     # Sequence-parallel strategy over the ``sp`` mesh axis: "ring"
     # (ppermute K/V streaming, parallel/ring_attention.py) or "ulysses"
     # (all-to-all head/sequence re-sharding, parallel/ulysses.py;
@@ -212,6 +209,20 @@ class TransformerConfig:
         if not self.head_dim:
             object.__setattr__(self, "head_dim",
                                self.dim // self.num_heads)
+        self._validate()
+
+    def _validate(self):
+        """Everything that makes a configuration invalid, refused where
+        it is built; what reads the fields afterwards only computes."""
+        for field in dataclasses.fields(self):
+            words = _WORDS.get(field.name,
+                               (False, True) if field.type is bool else ())
+            value = getattr(self, field.name)
+            if words and value not in words:
+                raise ValueError("unknown %s %r (want one of %s)%s" % (
+                    field.name, value,
+                    ", ".join(str(word).lower() for word in words),
+                    _WORDS_GONE.get(field.name, "")))
         if self.attn_gate and (self.kv_latent_rank
                                or "c" in self.layer_pattern):
             raise ValueError(
@@ -219,16 +230,52 @@ class TransformerConfig:
                 "and wv: latent attention (kv_latent_rank=%d) and a short "
                 "convolution (layer_pattern=%r) have no w_attn_gate"
                 % (self.kv_latent_rank, self.layer_pattern))
+        if self.kv_heads <= 0 or self.num_heads % self.kv_heads:
+            raise ValueError(
+                "num_heads (%d) must be a positive multiple of "
+                "num_kv_heads (%d)" % (self.num_heads, self.kv_heads))
+        held = self.experts_held[1]
+        if held and (self.moe_experts % held
+                     or not 0 <= self.moe_share_index
+                     < self.moe_experts // held):
+            raise ValueError(
+                "moe_experts_held (%d) must divide moe_experts (%d) and "
+                "moe_share_index (%d) name one of the shares"
+                % (held, self.moe_experts, self.moe_share_index))
+        sizes = self.latent
+        if sizes and (min(sizes) <= 0 or self.qk_rope_dim % 2):
+            raise ValueError(
+                "latent attention needs kv_latent_rank, qk_nope_dim, an "
+                "even qk_rope_dim and v_head_dim, all > 0; got %s"
+                % (sizes,))
+        if set(self.rope_kinds) - set("aw"):
+            raise ValueError(
+                "rope_kinds %r: want letters of a (full attention) and w "
+                "(windowed attention)" % (self.rope_kinds,))
+        pattern = _pattern(self)
+        if pattern is None:
+            return
+        if len(pattern) != self.num_layers or set(pattern) - set("awc"):
+            raise ValueError(
+                "layer_pattern %r: want %d letters, each a (attention), w "
+                "(attention over the last `window` positions) or c (short "
+                "convolution)" % (pattern, self.num_layers))
+        if ("w" in pattern) != bool(self.window):
+            raise ValueError(
+                "layer_pattern %r and window=%d: the window is the w "
+                "layers' and theirs alone, so each needs the other"
+                % (pattern, self.window))
+        if self.dense_layers and not (self.moe_experts
+                                      and self.dense_ffn_dim):
+            raise ValueError(
+                "dense_layers=%d needs moe_experts and dense_ffn_dim: "
+                "without experts every layer's FFN is dense"
+                % self.dense_layers)
 
     @property
     def kv_heads(self):
         """Effective K/V head count (num_kv_heads=0 -> MHA)."""
-        kv = self.num_kv_heads or self.num_heads
-        if kv <= 0 or self.num_heads % kv:
-            raise ValueError(
-                "num_heads (%d) must be a positive multiple of "
-                "num_kv_heads (%d)" % (self.num_heads, kv))
-        return kv
+        return self.num_kv_heads or self.num_heads
 
     @property
     def mlp_dim(self):
@@ -246,14 +293,7 @@ class TransformerConfig:
         attention, or None for attention with wk and wv."""
         sizes = (self.kv_latent_rank, self.qk_nope_dim, self.qk_rope_dim,
                  self.v_head_dim)
-        if not any(sizes):
-            return None
-        if not all(size > 0 for size in sizes) or self.qk_rope_dim % 2:
-            raise ValueError(
-                "latent attention needs kv_latent_rank, qk_nope_dim, an "
-                "even qk_rope_dim and v_head_dim, all > 0; got %s"
-                % (sizes,))
-        return sizes
+        return sizes if any(sizes) else None
 
     @property
     def kinds(self):
@@ -268,14 +308,22 @@ class TransformerConfig:
     def experts_held(self):
         """(first held expert, how many): all of them without a share."""
         held = self.moe_experts_held or self.moe_experts
-        if held and (self.moe_experts % held
-                     or not 0 <= self.moe_share_index
-                     < self.moe_experts // held):
-            raise ValueError(
-                "moe_experts_held (%d) must divide moe_experts (%d) and "
-                "moe_share_index (%d) name one of the shares"
-                % (held, self.moe_experts, self.moe_share_index))
         return self.moe_share_index * held, held
+
+
+# The fields that take one of a few words (one declared ``bool`` takes
+# true | false and has no row), and what the refusal says besides of a
+# field that took more words once.
+_WORDS = {
+    "qk_norm": (False, True, "head"),
+    "ffn_activation": tuple(sorted(ACTIVATIONS)),
+    "attention_impl": ("ring", "ulysses"),
+    "moe_router": ("softmax", "sigmoid_bias"),
+}
+_WORDS_GONE = {
+    "remat": ': "attn" and "dots" are gone; remat=true keeps the flash '
+             "kernel's output and row statistics, and more, when they fit",
+}
 
 
 # One layer's kind: its operator ("a" attention | "c" short
@@ -302,33 +350,22 @@ def _letter(kind):
     return "w" if kind.window else kind.op
 
 
-def stack_plan(cfg):
-    """How a stack whose layers differ is run, or None for a model of
-    one layer kind (no pattern, no leading dense layers).  The period is
-    the shortest that the layers after the leading ones repeat."""
-    if set(cfg.rope_kinds) - set("aw"):
-        raise ValueError(
-            "rope_kinds %r: want letters of a (full attention) and w "
-            "(windowed attention)" % (cfg.rope_kinds,))
+def _pattern(cfg):
+    """A letter a layer of a stack whose layers differ, or None for a
+    model of one layer kind (no pattern, no leading dense layers)."""
     if not cfg.layer_pattern and not cfg.dense_layers:
         return None
-    pattern = cfg.layer_pattern or ("w" if cfg.window else "a") * (
+    return cfg.layer_pattern or ("w" if cfg.window else "a") * (
         cfg.num_layers)
-    if len(pattern) != cfg.num_layers or set(pattern) - set("awc"):
-        raise ValueError(
-            "layer_pattern %r: want %d letters, each a (attention), w "
-            "(attention over the last `window` positions) or c (short "
-            "convolution)" % (pattern, cfg.num_layers))
-    if ("w" in pattern) != bool(cfg.window):
-        raise ValueError(
-            "layer_pattern %r and window=%d: the window is the w "
-            "layers' and theirs alone, so each needs the other"
-            % (pattern, cfg.window))
-    if cfg.dense_layers and not (cfg.moe_experts and cfg.dense_ffn_dim):
-        raise ValueError(
-            "dense_layers=%d needs moe_experts and dense_ffn_dim: "
-            "without experts every layer's FFN is dense"
-            % cfg.dense_layers)
+
+
+def stack_plan(cfg):
+    """How a stack whose layers differ is run, or None for a model of
+    one layer kind.  The period is the shortest that the layers after
+    the leading ones repeat."""
+    pattern = _pattern(cfg)
+    if pattern is None:
+        return None
     dense = not cfg.moe_experts
     lead = tuple(_kind(cfg, op, True) for op in pattern[:cfg.dense_layers])
     rest = pattern[cfg.dense_layers:]
@@ -347,40 +384,52 @@ def _one_kind(cfg):
     return next(kind for kind in cfg.kinds if kind.op == "a")
 
 
-def _no_latent(cfg, what):
-    if cfg.latent:
-        raise NotImplementedError(
-            "%s does not run latent attention (kv_latent_rank=%d): "
-            "decoding needs a cache of the latent and the one RoPE key "
-            "(rank + qk_rope_dim values a position, not heads x head "
-            "size) and the up-projection absorbed into q and the "
-            "output; ring_attention, ulysses_attention and the "
-            "pipeline's stages take q, k, v of one width (ROADMAP B8)"
-            % (what, cfg.kv_latent_rank))
+# What some callers cannot run yet (docs/training_pipeline.md, "What
+# runs where"): feature -> (whether a model has it, how the refusal
+# names it, its fields and its weights, why).
+_CANNOT = {
+    "latent": (
+        lambda cfg: cfg.latent,
+        "latent attention (kv_latent_rank={cfg.kv_latent_rank})",
+        "decoding needs a cache of the latent and the one RoPE key (rank "
+        "+ qk_rope_dim values a position, not heads x head size) and the "
+        "up-projection absorbed into q and the output; a mesh has no "
+        "spec for w_kv_a, kv_norm and w_kv_b, its ring_attention and "
+        "ulysses_attention take q, k, v of one width, and the pipeline's "
+        "weights lie on a mesh"),
+    "block": (
+        lambda cfg: cfg.post_norms or cfg.attn_gate,
+        "a block with norms on its sublayers' outputs (post_norms="
+        "{cfg.post_norms}: ln1_post, ln2_post) or a gate on attention's "
+        "output (attn_gate={cfg.attn_gate}: w_attn_gate)",
+        "decoding restates the block for one position (_decode_layer) "
+        "without them; the pipeline's stages run the block itself, but "
+        "their weights lie on a mesh, which has no spec for the three"),
+    "stack": (
+        lambda cfg: _pattern(cfg) is not None,
+        "a stack whose layers differ (layer_pattern={cfg.layer_pattern!r}"
+        ", dense_layers={cfg.dense_layers})",
+        "a short-convolution layer needs a state cache of its own, a "
+        "windowed layer (w) beside full ones a K/V cache that keeps its "
+        "last `window` positions, a mesh specs for the weights of lead, "
+        "period and tail, and the pipeline a split of them into stages"),
+    "share": (
+        lambda cfg: cfg.moe_experts_held,
+        "one chip's share of the experts (moe_experts_held="
+        "{cfg.moe_experts_held})",
+        "a model-parallel mesh shards all the experts over ep"),
+}
+# what decoding and the pipelined forward cannot run
+_TRAINS_ONLY = ("latent", "block", "stack")
 
 
-def _plain_block(cfg, what):
-    if cfg.post_norms or cfg.attn_gate:
-        raise NotImplementedError(
-            "%s does not run a block with norms on its sublayers' outputs "
-            "(post_norms=%s: ln1_post, ln2_post) or a gate on attention's "
-            "output (attn_gate=%s: w_attn_gate): decoding and the "
-            "pipeline's stages restate the plain block, and a mesh has "
-            "no spec for the three weights (ROADMAP B7)"
-            % (what, cfg.post_norms, cfg.attn_gate))
-
-
-def _uniform_only(cfg, what):
-    _no_latent(cfg, what)
-    _plain_block(cfg, what)
-    if stack_plan(cfg) is not None:
-        raise NotImplementedError(
-            "%s does not run a stack whose layers differ (layer_pattern="
-            "%r, dense_layers=%d): a short-convolution layer needs a "
-            "state cache of its own, a windowed layer (w) beside full "
-            "ones a K/V cache that keeps its last `window` positions, "
-            "and the stages a split by kind (ROADMAP)"
-            % (what, cfg.layer_pattern, cfg.dense_layers))
+def _refuse(cfg, what, *features):
+    """Raise, naming each of ``features`` that ``cfg`` has and the
+    caller ``what`` cannot run."""
+    found = ["%s does not run %s: %s" % (what, named.format(cfg=cfg), why)
+             for has, named, why in map(_CANNOT.get, features) if has(cfg)]
+    if found:
+        raise NotImplementedError("\n".join(found))
 
 
 # -- parameters --------------------------------------------------------------
@@ -491,11 +540,7 @@ def init_params(rng, cfg):
 
 def param_specs(cfg):
     """PartitionSpec tree matching init_params' structure."""
-    _uniform_only(cfg, "a model-parallel mesh")
-    if cfg.moe_experts_held:
-        raise NotImplementedError(
-            "a model-parallel mesh shards all the experts over ep; "
-            "moe_experts_held is one chip's share (ROADMAP B6)")
+    _refuse(cfg, "a model-parallel mesh", *_TRAINS_ONLY, "share")
     layers = {
         "ln1": P("pp", None),
         "wq": P("pp", None, "tp"),
@@ -620,9 +665,6 @@ def moe_route(h, w_router, cfg, expert_bias=None):
         if cfg.moe_norm_topk:
             gates = gates / (gates.sum(axis=-1, keepdims=True) + 1e-6)
         return probs, gates * cfg.moe_route_scale, experts
-    if cfg.moe_router != "softmax":
-        raise ValueError("unknown moe_router %r (want 'softmax' or "
-                         "'sigmoid_bias')" % (cfg.moe_router,))
     probs = checkpoint_name(jax.nn.softmax(logits, axis=-1),
                             remat_keep.KEEP_ROUTE)
     gates, experts = (
@@ -891,11 +933,6 @@ def _attention_mix(h, w, cfg, mesh, positions, kind):
     H, D = cfg.num_heads, cfg.head_dim
     q, k, v = _project_qkv(h, w, cfg, positions, kind.rope)
     kv_out = (k, v)
-    if cfg.attention_impl not in ("ring", "ulysses"):
-        raise ValueError(
-            "unknown attention_impl %r (want 'ring' or 'ulysses')"
-            % (cfg.attention_impl,)
-        )
     if mesh is None:
         announce_attention(cfg, B * T // batch_shard.shards(),
                            flash_mode(T, D)[0] == "off")
@@ -939,14 +976,14 @@ def _conv_mix(h, w, cfg):
 def _operator(x, w, cfg, mesh, positions, kind):
     """x + post(Op(norm(x))) -> (x, (k, v) or None), ``Op`` the
     operator of ``kind``: attention, latent attention (nothing cached:
-    ``_no_latent``) or the short convolution."""
+    decoding refuses it) or the short convolution."""
     h = _rmsnorm(x, w["ln1"].astype(jnp.dtype(cfg.dtype)), cfg.norm_eps)
     kv_out = None
     if kind.op == "c":
         out = _conv_mix(h, w, cfg)
     elif cfg.latent:
         if mesh is not None:
-            _no_latent(cfg, "a model-parallel mesh")
+            _refuse(cfg, "a model-parallel mesh", "latent")
         out = _latent_mix(h, w, cfg, positions, kind)
     else:
         out, kv_out = _attention_mix(h, w, cfg, mesh, positions, kind)
@@ -1051,18 +1088,12 @@ def forward_hidden(params, tokens, cfg, mesh=None, return_load=False):
     with_load = bool(return_load
                      and not all(kind.dense for kind in cfg.kinds))
     remat = lambda fn: fn
-    if cfg.remat == "dots":
-        remat = functools.partial(
-            jax.checkpoint,
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-        )
-    elif cfg.remat:
-        # True: the names that fit the room declared around this trace,
-        # none without one or under a model-parallel mesh (whose
-        # activations are not whole on a device).
-        names = (remat_keep.ATTN_NAMES if cfg.remat == "attn"
-                 else () if mesh is not None
-                 else remat_keep.names_for(cfg, params, tokens.shape))
+    if cfg.remat:
+        # the names that fit the room declared around this trace, none
+        # without one or under a model-parallel mesh (whose activations
+        # are not whole on a device)
+        names = () if mesh is not None else remat_keep.names_for(
+            cfg, params, tokens.shape)
         remat = functools.partial(
             jax.checkpoint,
             policy=(jax.checkpoint_policies.save_only_these_names(*names)
@@ -1178,7 +1209,7 @@ def forward_pipelined(params, tokens, cfg, mesh, num_microbatches,
         split_microbatches,
     )
 
-    _uniform_only(cfg, "forward_pipelined")
+    _refuse(cfg, "forward_pipelined", *_TRAINS_ONLY)
     if mesh.shape.get("sp", 1) != 1:
         raise ValueError(
             "forward_pipelined requires sp=1 (stage-local attention); "
@@ -1263,7 +1294,7 @@ def _decode_layer(x, w, cfg, ck, cv, pos):
     B = x.shape[0]
     H, D, G = cfg.num_heads, cfg.head_dim, cfg.kv_heads
     R = H // G
-    kind = _one_kind(cfg)     # a uniform stack's (``_uniform_only``)
+    kind = _one_kind(cfg)     # a uniform stack's (``_refuse``)
     positions = jnp.reshape(pos, (1,))
     h = _rmsnorm(x, w["ln1"].astype(compute_dtype), cfg.norm_eps)
     route = None
@@ -1302,7 +1333,7 @@ def prefill(params, cfg, prompt, max_len):
     ``max_len``.  Returns (last-position logits [B, V], caches).  This
     is the time-to-first-token path — Tp sequential decode steps would
     be MXU-starved serialized work."""
-    _uniform_only(cfg, "prefill")
+    _refuse(cfg, "prefill", *_TRAINS_ONLY)
     b, tp = prompt.shape
     x = _embed(params, prompt, cfg)
     positions = jnp.arange(tp)
@@ -1328,7 +1359,7 @@ def decode_step(params, cfg, caches, pos, tokens_1):
     """One decode step: tokens_1 [B] int32 at position ``pos`` ->
     (logits [B, V], updated caches).  ``caches`` from
     :func:`init_kv_cache`."""
-    _uniform_only(cfg, "decode_step")
+    _refuse(cfg, "decode_step", *_TRAINS_ONLY)
     x = _embed(params, tokens_1, cfg)[:, None, :]
 
     def body(x, inputs):
@@ -1351,7 +1382,7 @@ def generate(params, cfg, prompt, max_new_tokens, temperature=0.0,
     temperature.  Positions use RoPE, so sequences may run past
     cfg.max_seq_len (quality, not correctness, degrades).
     """
-    _uniform_only(cfg, "generate")
+    _refuse(cfg, "generate", *_TRAINS_ONLY)
     prompt = jnp.asarray(prompt, jnp.int32)
     b, tp = prompt.shape
     if tp == 0:
@@ -1455,14 +1486,13 @@ def next_token_loss_chunked(params, hidden, tokens, cfg, chunk=512):
 # -- zoo contract -------------------------------------------------------------
 
 
-def _flag(name, value):
-    """CLI model_params arrive as strings: "false" is not falsy."""
+def _flag(value):
+    """CLI model_params arrive as strings: "false" is not falsy.  A word
+    that is neither true nor false goes on as it is, for the
+    configuration's check to take (``qk_norm=head``) or refuse."""
     if isinstance(value, str):
-        parsed = {"true": True, "false": False}.get(value.strip().lower())
-        if parsed is None:
-            raise ValueError("%s must be true or false; got %r"
-                             % (name, value))
-        return parsed
+        word = value.strip().lower()
+        return {"true": True, "false": False}.get(word, word)
     return bool(value)
 
 
@@ -1475,52 +1505,21 @@ def _decayed(params):
             "expert_bias", "ln1_post", "ln2_post"), params)
 
 
-def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
-               seq_len=512, learning_rate=3e-4, mesh=None, dtype="bfloat16",
-               pipeline_microbatches=0, moe_experts=0, moe_top_k=2,
-               moe_aux_weight=0.01, remat=False, attention_impl="ring",
-               window=0, xent_chunk=0, num_kv_heads=0, ffn_dim=0,
-               norm_eps=1e-6, qk_norm=False, moe_norm_topk=True,
-               tied_embeddings=True, rope_theta=10000.0, layer_pattern="",
-               dense_layers=0, dense_ffn_dim=0, conv_kernel=3,
-               moe_router="softmax", moe_route_scale=1.0,
-               moe_experts_held=0, moe_share_index=0, warmup_steps=0,
-               head_dim=0, rope_kinds="aw", moe_route_before_op=False,
-               ffn_activation="silu", embed_scale=0.02, kv_latent_rank=0,
-               qk_nope_dim=0, qk_rope_dim=0, v_head_dim=0,
-               moe_shared_experts=0, post_norms=False, attn_gate=False,
-               embed_multiplier=1.0):
+def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
+               pipeline_microbatches=0, xent_chunk=0, **model_params):
     """Zoo entry for the flagship LM.
 
-    ``remat`` (False | True | "dots" | "attn"), ``attention_impl``
-    ("ring" | "ulysses"), ``num_kv_heads`` (grouped-query attention:
-    0 = MHA, G > 0 shares each K/V head across num_heads/G query
-    heads), ``head_dim`` (0 = dim / num_heads), ``ffn_dim`` (MLP or
-    expert width, 0 = 4 * dim), ``ffn_activation`` (silu | relu),
-    ``norm_eps``, ``qk_norm`` (false | true | head), ``moe_norm_topk``,
-    ``tied_embeddings``, ``embed_scale`` (the embedding's init, 0.02),
-    ``rope_theta``, the stack's ``layer_pattern``
-    (a letter a layer: a full attention, w attention over the last
-    ``window`` positions, c short convolution) / ``dense_layers`` /
-    ``dense_ffn_dim`` / ``conv_kernel``, what is per attention kind:
-    ``window`` (the w layers' window; without a pattern every layer's,
-    0 = full) and ``rope_kinds`` (the kinds RoPE turns, "aw"; a kind
-    left out has no positional encoding), the router's ``moe_router`` /
-    ``moe_route_scale`` / ``moe_route_before_op`` (the router reads the
-    operator's normed input and not the FFN's), the share
-    ``moe_experts_held`` / ``moe_share_index``, ``moe_shared_experts``
-    (always-on experts beside the routed ones, as one SwiGLU of that
-    many expert widths) and latent attention's four sizes
-    ``kv_latent_rank`` / ``qk_nope_dim`` / ``qk_rope_dim`` /
-    ``v_head_dim`` (all 0: attention with wk and wv), the block's
-    ``post_norms`` (an RMSNorm on each sublayer's output, before the
-    residual add) and ``attn_gate`` (a sigmoid gate a value on
-    attention's output, before ``wo``) and ``embed_multiplier`` (what a
-    token's row is multiplied by on its way in; 1.0) pass through
-    to :class:`TransformerConfig`.  ``xent_chunk`` > 0 computes the
-    loss via :func:`next_token_loss_chunked` — no [B, T, V] logits
-    tensor, the memory-lean path for large vocab x seq (numerically
-    identical, tested).
+    ``model_params`` are :class:`TransformerConfig`'s fields, under
+    their names and with their defaults: the class and its comments are
+    the list of the model's options.  Each is coerced by its field's
+    declared type (they arrive as strings from the command line) and the
+    configuration is checked where it is built; a keyword that is no
+    field is a ``TypeError``.  ``seq_len`` is ``max_seq_len``.
+
+    ``xent_chunk`` > 0 computes the loss via
+    :func:`next_token_loss_chunked` — no [B, T, V] logits tensor, the
+    memory-lean path for large vocab x seq (numerically identical,
+    tested).
 
     Training an MoE through the scanned stack, the spec also hands the
     trainer its step statistics (``step_stats_fn``): each layer's
@@ -1545,42 +1544,17 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
     decay is masked from ``post_norms``' two scales as well: they set
     how much of a sublayer's result joins the stream.
     """
-    cfg = TransformerConfig(
-        vocab_size=vocab_size, dim=dim, num_heads=num_heads,
-        num_layers=num_layers, max_seq_len=seq_len, dtype=dtype,
-        moe_experts=moe_experts, moe_top_k=moe_top_k,
-        moe_aux_weight=moe_aux_weight, remat=remat,
-        attention_impl=attention_impl, window=window,
-        num_kv_heads=num_kv_heads, ffn_dim=ffn_dim,
-        norm_eps=float(norm_eps),
-        qk_norm=("head" if str(qk_norm).strip().lower() == "head"
-                 else _flag("qk_norm", qk_norm)),
-        moe_norm_topk=_flag("moe_norm_topk", moe_norm_topk),
-        tied_embeddings=_flag("tied_embeddings", tied_embeddings),
-        rope_theta=float(rope_theta), layer_pattern=str(layer_pattern),
-        dense_layers=int(dense_layers), dense_ffn_dim=int(dense_ffn_dim),
-        conv_kernel=int(conv_kernel), moe_router=str(moe_router),
-        moe_route_scale=float(moe_route_scale),
-        moe_experts_held=int(moe_experts_held),
-        moe_share_index=int(moe_share_index), head_dim=int(head_dim),
-        rope_kinds=str(rope_kinds),
-        moe_route_before_op=_flag("moe_route_before_op",
-                                  moe_route_before_op),
-        ffn_activation=str(ffn_activation),
-        embed_scale=float(embed_scale),
-        kv_latent_rank=int(kv_latent_rank), qk_nope_dim=int(qk_nope_dim),
-        qk_rope_dim=int(qk_rope_dim), v_head_dim=int(v_head_dim),
-        moe_shared_experts=int(moe_shared_experts),
-        post_norms=_flag("post_norms", post_norms),
-        attn_gate=_flag("attn_gate", attn_gate),
-        embed_multiplier=float(embed_multiplier),
-    )
-    # validate at spec build: heads, the share, the pattern, the gate,
-    # the latent's sizes
-    cfg.kv_heads, cfg.experts_held, cfg.kinds, cfg.latent
-    if cfg.ffn_activation not in ACTIVATIONS:
-        raise ValueError("unknown ffn_activation %r (want one of %s)" % (
-            cfg.ffn_activation, ", ".join(sorted(ACTIVATIONS))))
+    types = {field.name: field.type
+             for field in dataclasses.fields(TransformerConfig)}
+
+    def coerced(key, value):
+        # ``bool | str`` (a flag or a word) as ``bool``; a keyword that
+        # is no field is left to the constructor's TypeError
+        kind = types.get(key)
+        return kind(value) if kind in (int, float, str) else _flag(value)
+
+    cfg = TransformerConfig(max_seq_len=int(seq_len), **{
+        key: coerced(key, value) for key, value in model_params.items()})
     if mesh is not None:
         param_specs(cfg)    # raises, naming what a mesh cannot run yet
     pipelined = (
@@ -1589,22 +1563,6 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
         and mesh.shape.get("pp", 1) > 1
         and mesh.shape.get("sp", 1) == 1
     )
-    if not (
-        remat in (False, True, "dots", "attn")
-    ):
-        # CLI model_params arrive as strings; normalize the booleans and
-        # reject typos instead of silently enabling full remat (any
-        # truthy non-keyword string would take the jax.checkpoint
-        # branch).
-        normalized = {"false": False, "true": True,
-                      "dots": "dots", "attn": "attn"}.get(
-            str(remat).strip().lower())
-        if normalized is None:
-            raise ValueError(
-                "remat must be one of False, True, 'dots', 'attn'; "
-                "got %r" % (remat,))
-        remat = normalized
-        cfg = dataclasses.replace(cfg, remat=remat)
     if pipeline_microbatches > 0 and not pipelined:
         # No mesh, pp=1, or sp>1 (ring attention needs the sequence
         # axis): say so instead of silently ignoring the knob.
@@ -1632,13 +1590,13 @@ def model_spec(vocab_size=32000, dim=512, num_heads=8, num_layers=4,
             if pipelined:
                 return forward_pipelined(
                     params, tokens, cfg, mesh, pipeline_microbatches,
-                    remat=bool(cfg.remat))
+                    remat=cfg.remat)
             return forward(params, tokens, cfg, mesh=mesh)
         out = {"params": params}
         if pipelined:
             out["hidden"], out["aux"] = forward_pipelined(
                 params, tokens, cfg, mesh, pipeline_microbatches,
-                remat=bool(cfg.remat), return_aux=True,
+                remat=cfg.remat, return_aux=True,
                 return_hidden=True)
         elif moe:
             out["hidden"], out["aux"], out["moe_load"] = forward_hidden(
@@ -1721,8 +1679,7 @@ def export_generate(export_dir, params, cfg, max_new_tokens,
     """
     from elasticdl_tpu.serving.export import export_servable
 
-    _no_latent(cfg, "export_generate")
-    _plain_block(cfg, "export_generate")
+    _refuse(cfg, "export_generate", *_TRAINS_ONLY)
     if prompt_len + max_new_tokens > cfg.max_seq_len:
         raise ValueError(
             "prompt_len %d + max_new_tokens %d exceeds max_seq_len %d"

@@ -120,26 +120,18 @@ def test_gradients_equal_the_nothing_kept_ones(monkeypatch, mode, moe,
         np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-6)
 
 
-@pytest.mark.parametrize("how", ["room", "attn"])
 def test_keeping_out_and_lse_takes_the_flash_forward_out_of_the_backward(
-        monkeypatch, how):
+        monkeypatch):
     """The calls by the names they carry: with nothing kept the forward
     runs in the forward scan and again in the backward's, before the
-    one backward call; with the two names kept, and under remat="attn"
-    whatever the room, once."""
+    one backward call; with the two names kept, once."""
     monkeypatch.setenv("ELASTICDL_FLASH", "interpret")
     cfg, params, loss = _problem(0)
     base = jax.make_jaxpr(jax.grad(lambda p: loss(p, None)))(params)
     assert sorted(_pallas_calls(base.jaxpr)) == [
         "flash_bwd", "flash_fwd", "flash_fwd"]
-    if how == "attn":
-        cfg = dataclasses.replace(cfg, remat="attn")
-        tokens = jnp.zeros((2, 128), jnp.int32)
-        kept = jax.make_jaxpr(jax.grad(
-            lambda p: tfm.forward_hidden(p, tokens, cfg)[0].sum()))(params)
-    else:
-        room = _room_for(cfg, params, 1)
-        kept = jax.make_jaxpr(jax.grad(lambda p: loss(p, room)))(params)
+    room = _room_for(cfg, params, 1)
+    kept = jax.make_jaxpr(jax.grad(lambda p: loss(p, room)))(params)
     assert sorted(_pallas_calls(kept.jaxpr)) == ["flash_bwd", "flash_fwd"]
 
 

@@ -255,8 +255,8 @@ def test_model_spec_remat_validation():
                           num_layers=2, seq_len=16, remat="False")
     assert spec.config.remat is False
     spec = tfm.model_spec(vocab_size=64, dim=32, num_heads=2,
-                          num_layers=2, seq_len=16, remat="attn")
-    assert spec.config.remat == "attn"
+                          num_layers=2, seq_len=16, remat="true")
+    assert spec.config.remat is True
     with pytest.raises(ValueError, match="remat"):
         tfm.model_spec(vocab_size=64, dim=32, num_heads=2,
                        num_layers=2, seq_len=16, remat="atn")
@@ -280,10 +280,9 @@ def test_model_spec_xent_chunk_pipelined_matches_dense():
                                rtol=1e-5)
 
 
-@pytest.mark.parametrize("remat", [True, "attn", "dots"])
-def test_remat_policies_preserve_gradients(params, remat):
+def test_remat_preserves_gradients(params):
     tokens = make_tokens(b=2, t=16)
-    cfg_r = dataclasses.replace(CFG, remat=remat)
+    cfg_r = dataclasses.replace(CFG, remat=True)
 
     def loss(cfg):
         def f(p):
