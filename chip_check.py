@@ -457,6 +457,27 @@ MIXED_STACKS = {
              moe_router="sigmoid_bias", moe_norm_topk=True,
              moe_route_scale=2.826, moe_aux_weight=0, norm_eps=1e-5,
              tied_embeddings=False)),
+    # benchmark/configs/olmo-hybrid-7b.json at a sequence of 2,048 (32
+    # chunks of the scan) and an eighth of the slice of the vocabulary:
+    # three gated-delta layers and a full NoPE layer, 15 of 30 heads
+    # held, a dense SwiGLU, norms on the sublayers' outputs alone
+    "ddda": (
+        # --tiny runs one layer of each kind: the two bf16 paths'
+        # roundings grow ~3.5 times through every delta layer's backward
+        # at these widths (1.8e-2 through ``da``, 5.8e-2 ``dda``, 0.24
+        # ``ddda``, and the twin's own path reads 0.10-0.16 from float32
+        # there), while on the same operands the kernels' output and five
+        # gradients stand 3-6e-3 from the float32 recurrence
+        dict(vocab_size=256, dim=128, num_heads=2, num_kv_heads=2,
+             head_dim=64, seq_len=256, ffn_dim=192, delta_key_dim=32,
+             delta_value_dim=64, num_layers=2, layer_pattern="da"),
+        dict(vocab_size=1568, dim=3840, num_heads=15, num_kv_heads=15,
+             head_dim=128, seq_len=2048, ffn_dim=11008, delta_key_dim=96,
+             delta_value_dim=192),
+        dict(num_layers=4, layer_pattern="ddda", rope_kinds="w",
+             qk_norm=True, post_norms=True, pre_norms=False,
+             delta_neg_eigval=True, conv_kernel=4, head_shares=2,
+             tied_embeddings=False, embed_scale=1.0)),
 }
 
 
@@ -471,7 +492,9 @@ def check_mixed_stack(tiny, spill=False, stack="caccc"):
     experts, the ``smallthinker-21b-a3b`` cell's; or the gated,
     output-normed block over windowed-RoPE and full-NoPE layers at 32
     query heads on 4, a per-head QK norm, a muP-scaled embedding and a
-    shared expert beside the share, the ``trinity-mini`` cell's; each at
+    shared expert beside the share, the ``trinity-mini`` cell's; or
+    three gated-delta layers to one full NoPE layer over a chip's share
+    of the heads, no experts, the ``olmo-hybrid-7b`` cell's; each at
     one short sequence), bf16
     through the kernels, against the same weights in float32 through
     the references at the highest matmul precision (the loss), and the
@@ -501,11 +524,12 @@ def check_mixed_stack(tiny, spill=False, stack="caccc"):
                 path[-1], "key", None) == "expert_bias" else a), params)
     tokens = jnp.asarray(np.random.RandomState(7).randint(
         0, sizes["vocab_size"], size=(1, sizes["seq_len"])), jnp.int32)
-    stats = spec.step_stats_fn(jax.jit(
-        lambda p: spec.apply_fn(p, tokens, True))(params))
-    if bool((stats["moe_spilled"] > 0).all()) != spill:
-        raise AssertionError("blocks run a layer: %s" % (
-            stats["moe_moved"],))
+    if spec.step_stats_fn is not None:      # a stack with experts
+        stats = spec.step_stats_fn(jax.jit(
+            lambda p: spec.apply_fn(p, tokens, True))(params))
+        if bool((stats["moe_spilled"] > 0).all()) != spill:
+            raise AssertionError("blocks run a layer: %s" % (
+                stats["moe_moved"],))
 
     def evaluate(spec, grads=True):
         loss = lambda p: spec.loss_fn(
@@ -885,6 +909,8 @@ def _cases(tiny):
            lambda: check_mixed_stack(tiny, stack="awww"))
     yield ("mixed_stack/wwaww.gated.share",
            lambda: check_mixed_stack(tiny, stack="wwaww"))
+    yield ("mixed_stack/ddda.share",
+           lambda: check_mixed_stack(tiny, stack="ddda"))
     # The benchmark's two heads: OLMoE's untied, OLMo's tied embedding.
     hdim, vocab, heads = (64, 256, ((2, 24, False), (2, 24, True))) if tiny \
         else (2048, 50304, ((4, 4096, False), (8, 2048, True)))

@@ -21,13 +21,15 @@ forward): nothing kept, the program ``jax.checkpoint(layer)`` always
 gave.
 """
 
+import contextlib
+import contextvars
 import functools
 
 import jax
 import jax.numpy as jnp
 
-from elasticdl_tpu.ops import (batch_shard, flash_attention, moe_dispatch,
-                               short_conv)
+from elasticdl_tpu.ops import (batch_shard, flash_attention, gated_delta,
+                               moe_dispatch, short_conv)
 
 # Named in models/transformer.py: q, k, v as the attention takes them
 # (after RoPE and the QK norm), the stream after the operator's
@@ -46,6 +48,14 @@ KEEP_STREAM = "attn_stream"
 KEEP_ROUTE = "moe_route"
 KEEP_GATE, KEEP_UP = "ffn_gate", "ffn_up"
 KEEP_SHARED_GATE, KEEP_SHARED_UP = "shared_gate", "shared_up"
+# A gated-delta layer's (``transformer._delta_mix``): the one projection
+# of q, k and v before and after its convolution and SiLU (the first is
+# what the convolution's backward reads, the second what the scan's
+# does, behind an L2 norm), the log decays and write strengths, [rows,
+# heads] float32 each, and the output gate's projection.  The scan's
+# own two are ``gated_delta.KEEP_OUT`` and ``KEEP_STATES``.
+KEEP_DELTA_IN, KEEP_DELTA_QKV = "delta_in", "delta_qkv"
+KEEP_DELTA_DECAY, KEEP_DELTA_GATE = "delta_decay", "delta_gate"
 
 # The share of the device's limit nothing is planned into: the
 # allocator's fragmentation, the batches in flight, whatever
@@ -70,7 +80,9 @@ def _entries(cfg, rows):
     e, h, g, d = cfg.dim, cfg.num_heads, cfg.kv_heads, cfg.head_dim
     kinds = cfg.kinds
     attention = sum(kind.op == "a" for kind in kinds)
-    conv = len(kinds) - attention
+    conv = sum(kind.op == "c" for kind in kinds)
+    delta = sum(kind.op == "d" for kind in kinds)
+    d_k, d_v = cfg.delta_key_dim, cfg.delta_value_dim
     dense = sum(kind.dense for kind in kinds)
     experts = len(kinds) - dense
     latent = cfg.latent
@@ -128,6 +140,26 @@ def _entries(cfg, rows):
         (11, "conv_in", (short_conv.KEEP_IN,), rows * 3 * e * size, conv),
         (5, "conv_out", (short_conv.KEEP_OUT,), rows * e * size, conv),
     ]
+    if delta:
+        # the decays are [rows, heads] and save two products that read
+        # the whole stream; the scan's output with its chunk-start
+        # states (float32, a state's rows 128-lane tiles in HBM) saves
+        # ``gdn_fwd``, 6.7 ms of a layer's second forward for 0.47 GB
+        # (PERF.md section 5, PR 44); the gate's projection is made as
+        # q is; the projection of q, k, v as a convolution's input is;
+        # the convolved projection a pass bound by memory
+        states = rows // gated_delta.CHUNK * h * d_k * _lanes(d_v) * 4
+        rest += [
+            (100, "delta_decay", (KEEP_DELTA_DECAY,), rows * h * 8, delta),
+            (14, "delta", (gated_delta.KEEP_OUT, gated_delta.KEEP_STATES),
+             rows * h * d_v * size + states, delta),
+            (13, "delta_gate", (KEEP_DELTA_GATE,), rows * h * d_v * size,
+             delta),
+            (11, "delta_in", (KEEP_DELTA_IN,),
+             rows * h * (2 * d_k + d_v) * size, delta),
+            (5, "delta_qkv", (KEEP_DELTA_QKV,),
+             rows * h * (2 * d_k + d_v) * size, delta),
+        ]
     if cfg.shared_dim:
         rest += [(12, name, (name,), rows * cfg.shared_dim * size, experts)
                  for name in SHARED_PRODUCTS]
@@ -347,6 +379,28 @@ def announce_keep(names, kept, budget, need, peak, layers, rows,
         "predicted_peak=%d layers=%d rows=%d fallback=%d",
         ",".join(names) or "-", kept, budget, need, peak, layers, rows,
         fallback)
+
+
+_KEPT = contextvars.ContextVar("elasticdl_remat_kept", default=())
+
+
+@contextlib.contextmanager
+def keeping(names):
+    """Declare that the layers traced inside this block are
+    rematerialized with ``names`` saved (``forward_hidden``), for an op
+    that says in its once-per-shape line what its backward finds
+    kept."""
+    token = _KEPT.set(tuple(names))
+    try:
+        yield
+    finally:
+        _KEPT.reset(token)
+
+
+def keeps(name):
+    """Whether ``name`` is among the names declared kept around the
+    code traced here."""
+    return name in _KEPT.get()
 
 
 def names_for(cfg, params, tokens_shape):

@@ -31,7 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from elasticdl_tpu.models import remat_keep
 from elasticdl_tpu.models.spec import ModelSpec
-from elasticdl_tpu.ops import batch_shard, short_conv
+from elasticdl_tpu.ops import batch_shard, gated_delta, short_conv
 from elasticdl_tpu.ops.flash_attention import (flash_attention, flash_mode,
                                                latent_attention,
                                                latent_mode, logger)
@@ -74,6 +74,11 @@ class TransformerConfig:
     # on what the operator and the FFN return, before the residual add,
     # whatever the operator and whatever the FFN (``_residual``).
     post_norms: bool = False
+    # False: a block with no norm on a sublayer's INPUT (no ``ln1``, no
+    # ``ln2``): the operator and the FFN read the stream itself, ``x +
+    # n(Op(x))`` then ``x + n(FFN(x))`` with ``post_norms``, which it
+    # needs (OLMo 2's reordered norm).
+    pre_norms: bool = True
     # A gate on attention's output: ``w_attn_gate`` [dim, heads *
     # head_dim] reads the normed input that q, k and v read, and every
     # value of the kernel's output is multiplied by the sigmoid of its
@@ -119,8 +124,9 @@ class TransformerConfig:
     # A stack whose layers differ.  ``layer_pattern``: one letter a
     # layer, "a" causal attention over the whole sequence, "w" causal
     # attention over the last ``window`` positions, "c" gated short
-    # convolution of ``conv_kernel`` taps (ops/short_conv.py); "" = one
-    # attention kind in every layer ("w" if ``window``, else "a").
+    # convolution of ``conv_kernel`` taps (ops/short_conv.py), "d" the
+    # gated delta rule (below); "" = one attention kind in every layer
+    # ("w" if ``window``, else "a").
     # ``dense_layers``: how many leading layers have a dense MLP
     # of ``dense_ffn_dim`` in an MoE model.  With either, the stack is
     # the leading layers, then whole periods of the rest's pattern under
@@ -132,6 +138,24 @@ class TransformerConfig:
     dense_layers: int = 0
     dense_ffn_dim: int = 0
     conv_kernel: int = 3
+    # A "d" layer of ``layer_pattern``: a Gated DeltaNet mixer
+    # (``_delta_mix``; ops/gated_delta.py) of ``num_heads`` heads, each
+    # with keys and queries of ``delta_key_dim`` and values of
+    # ``delta_value_dim`` and a float32 state of their product carried
+    # through the sequence; q, k and v pass a causal convolution of
+    # ``conv_kernel`` taps and a SiLU.  ``delta_neg_eigval`` doubles the
+    # write strength, sigmoid -> (0, 2), so that a token can flip the
+    # state along its key and not only erase it.
+    delta_key_dim: int = 0
+    delta_value_dim: int = 0
+    delta_neg_eigval: bool = False
+    # How many chips share a layer's heads in the deployment this model
+    # is one chip of (tensor parallel over heads): ``num_heads`` and
+    # ``num_kv_heads`` are the heads held HERE, every mixer's result is
+    # their part of the ``wo`` product, and nothing stands in for the
+    # exchange.  Changes no arithmetic: the ``layer stack:`` line states
+    # it (``heads_held=15/30``).
+    head_shares: int = 1
     # Mixture-of-experts: 0 = dense FFN; >0 = dropless top-k routing
     # (every chosen expert computes every token that chose it, whatever
     # the routing) with experts sharded over the ``ep`` mesh axis and an
@@ -230,6 +254,12 @@ class TransformerConfig:
                 "and wv: latent attention (kv_latent_rank=%d) and a short "
                 "convolution (layer_pattern=%r) have no w_attn_gate"
                 % (self.kv_latent_rank, self.layer_pattern))
+        if not self.pre_norms and (not self.post_norms
+                                   or self.moe_route_before_op):
+            raise ValueError(
+                "pre_norms=false needs post_norms (a block with no norm at "
+                "all is not trained here) and a router that reads the "
+                "FFN's input (moe_route_before_op reads ln1's result)")
         if self.kv_heads <= 0 or self.num_heads % self.kv_heads:
             raise ValueError(
                 "num_heads (%d) must be a positive multiple of "
@@ -255,11 +285,20 @@ class TransformerConfig:
         pattern = _pattern(self)
         if pattern is None:
             return
-        if len(pattern) != self.num_layers or set(pattern) - set("awc"):
+        if len(pattern) != self.num_layers or set(pattern) - set("awcd"):
             raise ValueError(
                 "layer_pattern %r: want %d letters, each a (attention), w "
-                "(attention over the last `window` positions) or c (short "
-                "convolution)" % (pattern, self.num_layers))
+                "(attention over the last `window` positions), c (short "
+                "convolution) or d (gated delta rule)"
+                % (pattern, self.num_layers))
+        if "d" in pattern and (
+                min(self.delta_key_dim, self.delta_value_dim) <= 0
+                or not 1 <= self.conv_kernel <= short_conv.HALO + 1):
+            raise ValueError(
+                "a d layer needs delta_key_dim and delta_value_dim > 0 "
+                "and 1 <= conv_kernel <= %d; got %d, %d and %d"
+                % (short_conv.HALO + 1, self.delta_key_dim,
+                   self.delta_value_dim, self.conv_kernel))
         if ("w" in pattern) != bool(self.window):
             raise ValueError(
                 "layer_pattern %r and window=%d: the window is the w "
@@ -327,7 +366,8 @@ _WORDS_GONE = {
 
 
 # One layer's kind: its operator ("a" attention | "c" short
-# convolution), whether its FFN is dense (in an MoE model, a leading
+# convolution | "d" gated delta rule), whether its FFN is dense (in an
+# MoE model, a leading
 # layer's) and, of an attention layer, the window it attends over (0:
 # the whole sequence) and whether RoPE turns its q and k.
 Kind = collections.namedtuple("Kind", "op dense window rope",
@@ -340,8 +380,8 @@ StackPlan = collections.namedtuple("StackPlan", "lead period periods tail")
 
 def _kind(cfg, letter, dense):
     """The Kind a letter of ``layer_pattern`` names."""
-    if letter == "c":
-        return Kind("c", dense)
+    if letter in "cd":
+        return Kind(letter, dense)
     return Kind("a", dense, cfg.window if letter == "w" else 0,
                 letter in cfg.rope_kinds)
 
@@ -398,9 +438,10 @@ _CANNOT = {
         "ulysses_attention take q, k, v of one width, and the pipeline's "
         "weights lie on a mesh"),
     "block": (
-        lambda cfg: cfg.post_norms or cfg.attn_gate,
+        lambda cfg: cfg.post_norms or cfg.attn_gate or not cfg.pre_norms,
         "a block with norms on its sublayers' outputs (post_norms="
-        "{cfg.post_norms}: ln1_post, ln2_post) or a gate on attention's "
+        "{cfg.post_norms}: ln1_post, ln2_post; pre_norms={cfg.pre_norms}"
+        ": ln1, ln2) or a gate on attention's "
         "output (attn_gate={cfg.attn_gate}: w_attn_gate)",
         "decoding restates the block for one position (_decode_layer) "
         "without them; the pipeline's stages run the block itself, but "
@@ -410,6 +451,9 @@ _CANNOT = {
         "a stack whose layers differ (layer_pattern={cfg.layer_pattern!r}"
         ", dense_layers={cfg.dense_layers})",
         "a short-convolution layer needs a state cache of its own, a "
+        "gated-delta layer (d) a recurrent state [heads, value_dim, "
+        "key_dim] and the last conv_kernel - 1 rows of its convolution's "
+        "input and no K/V cache, a "
         "windowed layer (w) beside full ones a K/V cache that keeps its "
         "last `window` positions, a mesh specs for the weights of lead, "
         "period and tail, and the pipeline a split of them into stages"),
@@ -450,7 +494,9 @@ def _init_layers(k_attn, k_mlp, cfg, kind, stack):
     (the scanned axis, or () for one layer)."""
     E, H, D, G = cfg.dim, cfg.num_heads, cfg.head_dim, cfg.kv_heads
     keys = jax.random.split(k_attn, 6)
-    layers = {"ln1": _norm_init(*stack, E), "ln2": _norm_init(*stack, E)}
+    layers = {}
+    if cfg.pre_norms:
+        layers.update(ln1=_norm_init(*stack, E), ln2=_norm_init(*stack, E))
     if cfg.post_norms:
         layers.update(ln1_post=_norm_init(*stack, E),
                       ln2_post=_norm_init(*stack, E))
@@ -475,6 +521,8 @@ def _init_layers(k_attn, k_mlp, cfg, kind, stack):
             whole = cfg.qk_norm != "head"
             layers["q_norm"] = _norm_init(*stack, H * D if whole else D)
             layers["k_norm"] = _norm_init(*stack, G * D if whole else D)
+    elif kind.op == "d":
+        layers.update(_init_delta(k_attn, cfg, stack))
     else:
         layers.update(
             w_in=_dense_init(keys[0], *stack, E, 3 * E),
@@ -504,6 +552,34 @@ def _init_layers(k_attn, k_mlp, cfg, kind, stack):
             layers["ws_up"] = _dense_init(shared[1], *stack, E, S)
             layers["ws_down"] = _dense_init(shared[2], *stack, S, E)
     return layers
+
+
+def _init_delta(key, cfg, stack):
+    """A "d" layer's mixer (``_delta_mix``).  ``A_log`` and ``dt_bias``
+    as the Gated DeltaNet layer of the flash-linear-attention library
+    draws them: a decay rate A uniform in (0, 16) a head, a step dt
+    log-uniform in (0.001, 0.1) behind an inverse softplus, so that at
+    a zero projection a head's decay ``exp(-A dt)`` lies in (0.2, 1)."""
+    E, H = cfg.dim, cfg.num_heads
+    dk, dv = cfg.delta_key_dim, cfg.delta_value_dim
+    keys = jax.random.split(jax.random.fold_in(key, 7), 8)
+    width = H * (2 * dk + dv)
+    dt = jnp.exp(jax.random.uniform(keys[5], (*stack, H), jnp.float32,
+                                    np.log(1e-3), np.log(1e-1)))
+    return dict(
+        # q, k and v of every head side by side: one product, one
+        # convolution
+        w_qkv=_dense_init(keys[0], *stack, E, width),
+        delta_conv=_dense_init(keys[1], *stack, width, cfg.conv_kernel,
+                               scale=cfg.conv_kernel ** -0.5),
+        w_a=_dense_init(keys[2], *stack, E, H),
+        w_b=_dense_init(keys[3], *stack, E, H),
+        A_log=jnp.log(jax.random.uniform(keys[4], (*stack, H),
+                                         jnp.float32, 1e-3, 16.0)),
+        dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
+        w_out_gate=_dense_init(keys[6], *stack, E, H * dv),
+        o_norm=_norm_init(*stack, dv),
+        wo=_dense_init(keys[7], *stack, H * dv, E))
 
 
 def init_params(rng, cfg):
@@ -873,12 +949,21 @@ def _residual(x, out, w, cfg, mesh, post):
     return x + _constrain(out, mesh, P("dp", "sp", None))
 
 
+def _pre(x, w, cfg, name):
+    """What a sublayer reads: the RMSNorm ``name`` of the stream, or the
+    stream itself in a block without norms on its sublayers' inputs
+    (``cfg.pre_norms`` False)."""
+    if not cfg.pre_norms:
+        return x
+    return _rmsnorm(x, w[name].astype(jnp.dtype(cfg.dtype)), cfg.norm_eps)
+
+
 def _ffn(x, w, cfg, mesh, dense=False, route=None):
     """x + post(FFN(norm(x))) -> (x, aux, stats, load); the last three
     are the MoE's (:func:`_moe_ffn`, which ``route`` is for), zeros and
     None for a dense FFN (a model without experts, or a ``dense`` layer
     of one with)."""
-    h = _rmsnorm(x, w["ln2"].astype(jnp.dtype(cfg.dtype)), cfg.norm_eps)
+    h = _pre(x, w, cfg, "ln2")
     if cfg.moe_experts and not dense:
         out, aux, stats, load = _moe_ffn(h, w, cfg, mesh, route)
         if cfg.shared_dim:
@@ -973,14 +1058,90 @@ def _conv_mix(h, w, cfg):
     return mixed @ w["w_out"].astype(compute_dtype)
 
 
+@functools.lru_cache(maxsize=None)
+def announce_delta(cfg, rows, chunk, kept, mode, why):
+    """Once per compiled shape, by the logger ``announce_tiles`` uses:
+    the gated delta rule one shard of the data axis scans over ``rows``
+    tokens, and whether the chunk-start states its backward reads are
+    ``kept`` from the forward or made again by the second forward of a
+    rematerialized layer."""
+    logger.info(
+        "delta scan: rows=%d heads=%d key_dim=%d value_dim=%d chunk=%d "
+        "conv_taps=%d neg_eigval=%d states=%s %s%s", rows, cfg.num_heads,
+        cfg.delta_key_dim, cfg.delta_value_dim, chunk, cfg.conv_kernel,
+        cfg.delta_neg_eigval, "kept" if kept else "recomputed",
+        {"tpu": "kernel", "interpret": "interpreter",
+         "off": "reference"}[mode], " (%s)" % why if why else "")
+
+
+def _l2norm(x, eps=1e-6):
+    """x over the L2 norm of its last axis, in float32."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+def _delta_mix(h, w, cfg):
+    """GatedDeltaNet(h) of the block's input, [B, T, dim]: the operator
+    of a "d" layer.  q, k and v of every head are one projection, pass
+    one causal convolution and a SiLU (``ops/short_conv.conv_silu``),
+    then q and k an L2 norm a head (q also ``key_dim ** -0.5``); the
+    write strength ``beta`` is a sigmoid a head (times 2 with
+    ``cfg.delta_neg_eigval``) and the log decay ``g = -exp(A_log) *
+    softplus(h W_a + dt_bias)``, both float32; the scan is
+    ``ops/gated_delta.py``'s, which picks kernel or reference; its
+    output takes an RMSNorm over each head's values (one scale the
+    heads share) times the SiLU of a gate projected from ``h``, and
+    ``wo`` contracts (head, width) where the output stands."""
+    compute_dtype = jnp.dtype(cfg.dtype)
+    B, T, _ = h.shape
+    H, dk, dv = cfg.num_heads, cfg.delta_key_dim, cfg.delta_value_dim
+    mode, why = gated_delta.delta_mode(T, dk, dv)
+    announce_delta(cfg, B * T // batch_shard.shards(), gated_delta.CHUNK,
+                   not cfg.remat or remat_keep.keeps(
+                       gated_delta.KEEP_STATES), mode, why)
+    qkv = checkpoint_name(h @ w["w_qkv"].astype(compute_dtype),
+                          remat_keep.KEEP_DELTA_IN)
+    qkv = checkpoint_name(short_conv.conv_silu(qkv, w["delta_conv"]),
+                          remat_keep.KEEP_DELTA_QKV)
+
+    def heads(x, width, norm=None):
+        x = x.reshape(B, T, H, width).transpose(0, 2, 1, 3)
+        return x if norm is None else (_l2norm(x) * norm).astype(x.dtype)
+
+    q = heads(qkv[..., :H * dk], dk, dk ** -0.5)
+    k = heads(qkv[..., H * dk:2 * H * dk], dk, 1.0)
+    v = heads(qkv[..., 2 * H * dk:], dv)
+    # [B, H, T] float32 each, from the compute dtype's products
+    a, b = (jnp.einsum("btd,dh->bht", h, w[name].astype(
+        compute_dtype)).astype(jnp.float32) for name in ("w_a", "w_b"))
+    per_head = lambda x: x.astype(jnp.float32)[None, :, None]
+    beta = jax.nn.sigmoid(b) * (2.0 if cfg.delta_neg_eigval else 1.0)
+    g = -jnp.exp(per_head(w["A_log"])) * jax.nn.softplus(
+        a + per_head(w["dt_bias"]))
+    g, beta = (checkpoint_name(x, remat_keep.KEEP_DELTA_DECAY)
+               for x in (g, beta))
+    o = gated_delta.gated_delta(q, k, v, g, beta)
+    gate = checkpoint_name(
+        _heads_first(h, w["w_out_gate"].astype(compute_dtype).reshape(
+            -1, H, dv)), remat_keep.KEEP_DELTA_GATE)
+    o = _rmsnorm(o, w["o_norm"].astype(compute_dtype),
+                 cfg.norm_eps) * jax.nn.silu(gate)
+    # as ``_latent_mix``: reshaped before the cast
+    wo = w["wo"].reshape(H, dv, cfg.dim).astype(compute_dtype)
+    return jnp.einsum("bhtk,hkd->btd", o, wo)
+
+
 def _operator(x, w, cfg, mesh, positions, kind):
     """x + post(Op(norm(x))) -> (x, (k, v) or None), ``Op`` the
     operator of ``kind``: attention, latent attention (nothing cached:
-    decoding refuses it) or the short convolution."""
-    h = _rmsnorm(x, w["ln1"].astype(jnp.dtype(cfg.dtype)), cfg.norm_eps)
+    decoding refuses it), the short convolution or the gated delta
+    rule."""
+    h = _pre(x, w, cfg, "ln1")
     kv_out = None
     if kind.op == "c":
         out = _conv_mix(h, w, cfg)
+    elif kind.op == "d":
+        out = _delta_mix(h, w, cfg)
     elif cfg.latent:
         if mesh is not None:
             _refuse(cfg, "a model-parallel mesh", "latent")
@@ -1110,8 +1271,9 @@ def forward_hidden(params, tokens, cfg, mesh=None, return_load=False):
     if plan is None:
         x, aux_per_layer = jax.lax.scan(block(), x, params["layers"])
     else:
-        x, aux_per_layer = _mixed_stack(x, params["layers"], cfg, plan,
-                                        block)
+        with remat_keep.keeping(names if cfg.remat else ()):
+            x, aux_per_layer = _mixed_stack(x, params["layers"], cfg, plan,
+                                            block)
     if with_load:
         aux_per_layer, load = aux_per_layer
         return x, aux_per_layer.mean(), load
@@ -1119,19 +1281,22 @@ def forward_hidden(params, tokens, cfg, mesh=None, return_load=False):
 
 
 @functools.lru_cache(maxsize=None)
-def announce_stack(pattern, plan, experts, shared=0):
+def announce_stack(pattern, plan, experts, shared=0, heads=None):
     """Once per model, by the logger ``announce_tiles`` uses: how a
     stack whose layers differ is run (``shared``: the width of an
-    expert layer's always-on shared expert, said where there is one)."""
+    expert layer's always-on shared expert, said where there is one;
+    ``heads``: (held here, of how many) where chips share a layer's
+    heads)."""
     letters = lambda kinds: "".join(map(_letter, kinds)) or "-"
     kinds = sorted(set(k for k in plan.lead + plan.period + plan.tail
                        if k.op == "a"), key=_letter)
     logger.info(
         "layer stack: pattern=%s lead=%s period=%s periods=%d tail=%s "
-        "dense_layers=%d experts_held=%d/%d%s%s", pattern,
+        "dense_layers=%d experts_held=%d/%d%s%s%s", pattern,
         letters(plan.lead), letters(plan.period), plan.periods,
         letters(plan.tail), len(plan.lead), *experts,
         " shared_expert=%d" % shared if shared else "",
+        " heads_held=%d/%d" % heads if heads else "",
         "".join(" %s:window=%d,rope=%d" % (_letter(k), k.window, k.rope)
                 for k in kinds))
 
@@ -1142,7 +1307,9 @@ def _mixed_stack(x, layers, cfg, plan, block):
     with experts returned beside it, stacked in layer order: aux [L_moe]
     or (aux [L_moe], load [L_moe, ..]); a zero where none has experts)."""
     announce_stack("".join(map(_letter, cfg.kinds)), plan,
-                   (cfg.experts_held[1], cfg.moe_experts), cfg.shared_dim)
+                   (cfg.experts_held[1], cfg.moe_experts), cfg.shared_dim,
+                   (cfg.num_heads, cfg.num_heads * cfg.head_shares)
+                   if cfg.head_shares > 1 else None)
 
     tree_map = jax.tree_util.tree_map
 
@@ -1498,11 +1665,13 @@ def _flag(value):
 
 def _decayed(params):
     """AdamW's weight-decay mask: everything but the routers'
-    ``expert_bias`` and the scales of the norms on a sublayer's
-    output."""
+    ``expert_bias``, the scales of the norms on a sublayer's output
+    and, of a gated-delta layer, its decay rates, its step bias, its
+    output norm's scale and its convolution's taps."""
     return jax.tree_util.tree_map_with_path(
         lambda path, _: getattr(path[-1], "key", None) not in (
-            "expert_bias", "ln1_post", "ln2_post"), params)
+            "expert_bias", "ln1_post", "ln2_post", "A_log", "dt_bias",
+            "o_norm", "delta_conv"), params)
 
 
 def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
@@ -1542,7 +1711,8 @@ def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
     carry it), no gradient reaches it, and AdamW's weight decay is
     masked from it here, so the optimizer leaves it as it is.  The
     decay is masked from ``post_norms``' two scales as well: they set
-    how much of a sublayer's result joins the stream.
+    how much of a sublayer's result joins the stream; and from a
+    gated-delta layer's ``A_log``, ``dt_bias``, ``o_norm`` and taps.
     """
     types = {field.name: field.type
              for field in dataclasses.fields(TransformerConfig)}
@@ -1644,7 +1814,8 @@ def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
             (optax.linear_schedule(0.0, learning_rate, int(warmup_steps))
              if warmup_steps else learning_rate), weight_decay=0.01,
             mask=(_decayed if cfg.moe_router == "sigmoid_bias"
-                  or cfg.post_norms else None)),
+                  or cfg.post_norms or "d" in cfg.layer_pattern
+                  else None)),
         feed=feed,
         eval_metrics_fn=lambda: {
             "nll": metrics.Mean(lambda outputs, labels: outputs)

@@ -1,0 +1,432 @@
+"""Gated delta rule (Pallas TPU): the sequence mixer of a Gated DeltaNet
+layer, a recurrence over T run a chunk of tokens at a time.
+
+``gated_delta(q, k [B, H, T, d_k], v [B, H, T, d_v], g, beta [B, H, T])
+-> o [B, H, T, d_v]``.  A head carries a float32 state ``S`` [d_v, d_k],
+zero before a sequence's first token, and at every token
+
+    S_t = alpha_t S_(t-1) (I - beta_t k_t k_t^T) + beta_t v_t k_t^T
+    o_t = S_t q_t
+
+with ``alpha_t = exp(g_t)`` in (0, 1] (``g`` the log decay, <= 0) and a
+write strength ``beta_t`` that may pass 1 (in (0, 2) the transition
+keeps an eigenvalue in (-1, 1)).  q and k come normalised and scaled by
+the caller; nothing here knows how.
+
+The chunk form (Yang, Kautz, Hatamizadeh, "Gated Delta Networks",
+arXiv:2412.06464, section 3.3).  Inside a chunk of C tokens with start
+state ``h`` (= S^T, [d_k, d_v]), ``b`` the cumulative sum of ``g`` from
+the chunk's first token and ``gamma = exp(b)``:
+
+    A   = strictly_lower(diag(beta) (K K^T * exp(b_r - b_i)))
+    U   = (I + A)^-1 diag(beta) (V - diag(gamma) K h)       [C, d_v]
+    O   = diag(gamma) Q h + lower(Q K^T * exp(b_r - b_i)) U
+    h'  = gamma_C h + (diag(exp(b_C - b)) K)^T U
+
+``(I + A)^-1`` is taken by matmuls alone, no row-by-row substitution
+(``_inverse``: pairs of tokens, then blocks joined in pairs).  Every exponent
+is a difference ``b_r - b_i`` with i <= r, never positive: nothing
+overflows however fast a head forgets.
+
+Two kernels, one grid step a (block of heads, chunk), the chunk axis
+sequential:
+
+ - ``gdn_fwd``: the state of each head of the block resident in VMEM in
+   float32 across the chunk axis; q, k, v stream through a chunk at a
+   time; writes ``o`` and each chunk's START state (float32, [B * H, T /
+   C, d_k, d_v]: what the backward reads, 283 MB a layer at 15 heads x
+   16,384 x 96 | 192);
+ - ``gdn_bwd``: the same grid walked from the last chunk to the first,
+   the state's cotangent resident; rebuilds the chunk's A, inverse, U
+   and scores from q, k, v and the saved start state, and writes dq, dk,
+   dv and the cotangents of ``b`` and ``beta``.
+
+Float32 whatever the compute dtype: the cumulative sums of ``g``, the
+decays, ``A`` and its inverse (matmuls at the highest precision:
+Mosaic's default contracts float32 operands in bfloat16), the state and
+its cotangent.  The other matmuls take their operands in the compute
+dtype (q's) and accumulate in float32; what leaves a kernel [T, .]-sized
+is in the compute dtype.
+
+The forward's two results carry names for a remat policy
+(models/remat_keep.py): with both kept the backward of a rematerialized
+layer does not run ``gdn_fwd`` a second time.
+
+Reference: ``gated_delta_ref``, the same chunk form in plain
+``jax.numpy`` (float32 inside), differentiated by JAX: what
+``gated_delta`` returns wherever ``ops/mode.py`` answers ``off`` or the
+kernel does not tile the shape (it says so: ``announce_fallback``).  The
+token-by-token recurrence both are held to is the tests' and the
+benchmark reference's, not this file's.
+"""
+
+import functools
+import types
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from elasticdl_tpu.ops import flash_attention
+from elasticdl_tpu.ops.batch_shard import per_batch_shard
+from elasticdl_tpu.ops.mode import resolve
+
+# ``checkpoint_name``s of the forward's results that the backward reads
+# or the layer goes on with: the output and the chunk-start states.
+KEEP_OUT, KEEP_STATES = "gdn_out", "gdn_states"
+
+CHUNK = 64
+# Heads a grid step runs, the largest that divides the heads: their
+# chains of dependent matmuls are independent of each other, so the
+# scheduler has several to interleave.
+HEAD_BLOCKS = (5, 4, 3, 2, 1)
+VMEM_LIMIT = 64 * 1024 * 1024
+
+_F32 = jnp.float32
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+_NT = (((1,), (1,)), ((), ()))   # a @ b^T
+_TN = (((0,), (0,)), ((), ()))   # a^T @ b
+
+
+def _dot(a, b, dims=_NN):
+    return lax.dot_general(a, b, dims, preferred_element_type=_F32)
+
+
+def _inverse(a):
+    """(I + a)^-1 of strictly lower triangular ``a`` [.., C, C] float32,
+    by matmuls alone, at the highest precision: block forward
+    substitution with masks in place of slices.  A pair of tokens is
+    exact, ``[[1, 0], [-a, 1]]``; then blocks are joined in pairs until
+    one is left, ``[[T1, 0], [-T2 a21 T1, T2]]``, two [C, C] matmuls a
+    join (ten at C = 64).  The finite Neumann series of the nilpotent
+    ``-a`` over the whole chunk, (I + n)(I + n^2)(I + n^4).., is as many
+    matmuls and is not stable: with keys that share a direction (a
+    SiLU's outputs do) its terms grow as binomial(C, n) |a|^n before
+    they cancel, past float32 at C = 64 (a NaN in the test's draw; 1e-2
+    over blocks of 16, 1e-5 over 8)."""
+    chunk = a.shape[-1]
+    mm = functools.partial(jnp.matmul, precision=lax.Precision.HIGHEST,
+                           preferred_element_type=_F32)
+    row = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # whether an entry lies in a diagonal block of edge 2 ** bits
+    within = lambda bits: (row >> bits) == (col >> bits)
+    inv = (row == col).astype(_F32) - jnp.where(within(1), a, 0.0)
+    bits = 1
+    while 2 ** bits < chunk:
+        below = jnp.where(within(bits + 1) & ~within(bits), a, 0.0)
+        inv = inv - mm(mm(inv, below), inv)
+        bits += 1
+    return inv
+
+
+# -- the plain twin ----------------------------------------------------------
+
+
+def gated_delta_ref(q, k, v, g, beta, chunk=CHUNK):
+    """The chunk form in plain ``jax.numpy``, float32 inside, v's dtype
+    out; any T (the last chunk padded with tokens that neither decay nor
+    write)."""
+    batch, heads, seq, _ = q.shape
+    dtype = v.dtype
+    pad = -seq % chunk
+    if pad:
+        widths = lambda x: [(0, 0), (0, 0), (0, pad)] + [(0, 0)] * (
+            x.ndim - 3)
+        q, k, v, g, beta = (jnp.pad(x, widths(x))
+                            for x in (q, k, v, g, beta))
+    n = (seq + pad) // chunk
+    split = lambda x: x.astype(_F32).reshape(
+        batch, heads, n, chunk, *x.shape[3:])
+    q, k, v, g, beta = map(split, (q, k, v, g, beta))
+    b = jnp.cumsum(g, axis=-1)
+    _, lower, strict = _masks(chunk)
+    diff = b[..., :, None] - b[..., None, :]
+    decay = jnp.where(lower, jnp.exp(jnp.where(lower, diff, 0.0)), 0.0)
+    mm = functools.partial(jnp.matmul, precision=lax.Precision.HIGHEST)
+    a = jnp.where(strict, mm(k, jnp.swapaxes(k, -1, -2)) * decay,
+                  0.0) * beta[..., None]
+    inv = _inverse(a)
+    gamma = jnp.exp(b)
+    w = mm(inv, k * (beta * gamma)[..., None])
+    u0 = mm(inv, v * beta[..., None])
+    scores = jnp.where(lower, mm(q, jnp.swapaxes(k, -1, -2)) * decay, 0.0)
+    last = b[..., -1:]
+    kd = k * jnp.exp(last - b)[..., None]
+
+    def step(h, xs):
+        w, u0, scores, qg, kd, carry = xs
+        u = u0 - mm(w, h)
+        o = mm(qg, h) + mm(scores, u)
+        return carry[..., None] * h + mm(jnp.swapaxes(kd, -1, -2), u), o
+
+    chunks_first = lambda x: jnp.moveaxis(x, 2, 0)
+    h0 = jnp.zeros((batch, heads, q.shape[-1], v.shape[-1]), _F32)
+    _, o = lax.scan(step, h0, tuple(map(chunks_first, (
+        w, u0, scores, q * gamma[..., None], kd, jnp.exp(last)))))
+    o = jnp.moveaxis(o, 0, 2).reshape(batch, heads, seq + pad, -1)
+    return o[:, :, :seq].astype(dtype)
+
+
+# -- the kernels -------------------------------------------------------------
+
+
+def _masks(chunk):
+    """(diagonal, lower with the diagonal, strictly lower) [C, C]."""
+    row = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    return row == col, row >= col, row > col
+
+
+def _col(row, eye):
+    """A [1, C] row as a [C, 1] column (a masked lane sum: no
+    transpose of a one-row array)."""
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
+def _row(col, eye):
+    return jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
+
+
+def _chunk(q, k, v, b, beta, h):
+    """What both kernels build of a chunk: q, k [C, d_k] and v [C, d_v]
+    in the compute dtype, b and beta [1, C] float32, h [d_k, d_v]
+    float32 (the start state)."""
+    dtype = q.dtype
+    eye, lower, strict = _masks(q.shape[0])
+    b_col, beta_col = _col(b, eye), _col(beta, eye)
+    decay = jnp.where(
+        lower, jnp.exp(jnp.where(lower, b_col - b, 0.0)), 0.0)
+    kk = _dot(k, k, _NT)
+    inv = _inverse(jnp.where(strict, kk * decay, 0.0) * beta_col)
+    gamma = jnp.exp(b_col)
+    # b at the chunk's last token, [1, 1]: a masked lane sum (a slice of
+    # the last lane is a layout Mosaic does not broadcast from)
+    lane = lax.broadcasted_iota(jnp.int32, b.shape, 1)
+    last = jnp.sum(jnp.where(lane == b.shape[1] - 1, b, 0.0), axis=1,
+                   keepdims=True)
+    kf, hc = k.astype(_F32), h.astype(dtype)
+    kb = (kf * (beta_col * gamma)).astype(dtype)
+    r = v.astype(_F32) * beta_col - _dot(kb, hc)
+    u = _dot(inv.astype(dtype), r.astype(dtype)).astype(dtype)
+    qk = _dot(q, k, _NT)
+    scores = jnp.where(lower, qk * decay, 0.0).astype(dtype)
+    to_end = jnp.exp(last - b_col)
+    return types.SimpleNamespace(
+        eye=eye, lower=lower, strict=strict, beta_col=beta_col,
+        decay=decay, kk=kk, inv=inv, gamma=gamma, carry=jnp.exp(last),
+        kf=kf, hc=hc, kb=kb, u=u, qk=qk, scores=scores, to_end=to_end,
+        kd=(kf * to_end).astype(dtype),
+        qg=(q.astype(_F32) * gamma).astype(dtype))
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, gates_ref, o_ref, states_ref, h_scr,
+                *, heads):
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        h_scr[...] = jnp.zeros_like(h_scr)
+
+    for i in range(heads):
+        h = h_scr[i]
+        states_ref[i] = h
+        c = _chunk(q_ref[i], k_ref[i], v_ref[i], gates_ref[i, 0:1, :],
+                   gates_ref[i, 1:2, :], h)
+        o = _dot(c.qg, c.hc) + _dot(c.scores, c.u)
+        o_ref[i] = o.astype(o_ref.dtype)
+        h_scr[i] = c.carry * h + _dot(c.kd, c.u, _TN)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, gates_ref, states_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dgates_ref, dh_scr, *, heads):
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        dh_scr[...] = jnp.zeros_like(dh_scr)
+
+    for i in range(heads):
+        q, k, v, do = q_ref[i], k_ref[i], v_ref[i], do_ref[i]
+        dtype = q.dtype
+        h, dh = states_ref[i], dh_scr[i]
+        c = _chunk(q, k, v, gates_ref[i, 0:1, :], gates_ref[i, 1:2, :], h)
+        eye, lower, strict = c.eye, c.lower, c.strict
+        decay, beta_col, gamma = c.decay, c.beta_col, c.gamma
+        u, hc, kf = c.u, c.hc, c.kf
+        cast = lambda x: x.astype(dtype)
+        rowsum = lambda x: jnp.sum(x, axis=1, keepdims=True)
+        dhc, dof = cast(dh), do.astype(_F32)
+
+        # O = gamma Q h + scores U;  h' = gamma_C h + kd^T U
+        du = _dot(c.scores, do, _TN) + _dot(c.kd, dhc)
+        dscores = jnp.where(lower, _dot(do, u, _NT), 0.0)
+        dqk = cast(dscores * decay)
+        dq = gamma * _dot(do, hc, _NT) + _dot(dqk, k)
+        dk = _dot(dqk, q, _TN)
+        dkd = _dot(u, dhc, _NT)
+        dgamma = rowsum(_dot(q, hc) * dof)
+        # U = inv R, R = beta V - kb h;  d(inv) = -inv^T . inv^T
+        dr = _dot(cast(c.inv), cast(du), _TN)
+        drc = cast(dr)
+        da = jnp.where(strict, -_dot(drc, u, _NT), 0.0)
+        dkb = -_dot(drc, hc, _NT)
+        dh_scr[i] = (c.carry * dh + _dot(c.qg, do, _TN)
+                     - _dot(c.kb, drc, _TN))
+        dv_ref[i] = (beta_col * dr).astype(dv_ref.dtype)
+        dbeta = rowsum(dr * v.astype(_F32))
+        both = rowsum(dkb * kf)               # d(beta gamma)
+        dk += beta_col * gamma * dkb
+        dbeta += gamma * both
+        dgamma += beta_col * both
+        # A = beta (K K^T * decay), strictly lower
+        dbeta += rowsum(da * c.kk * decay)
+        dkk = cast(beta_col * da * decay)
+        dk += _dot(dkk, k) + _dot(dkk, k, _TN)
+        # kd = exp(b_C - b) K
+        dk += c.to_end * dkd
+        to_end = rowsum(dkd * kf) * c.to_end     # d(b_C - b_i)
+        dq_ref[i] = dq.astype(dq_ref.dtype)
+        dk_ref[i] = dk.astype(dk_ref.dtype)
+        # decay = exp(b_r - b_i): a row's cotangents add, a column's
+        # subtract (the diagonal's cancel: its exponent is 0)
+        ddiff = (dscores * c.qk + beta_col * da * c.kk) * decay
+        db = (_row(rowsum(ddiff) + dgamma * gamma - to_end, eye)
+              - jnp.sum(ddiff, axis=0, keepdims=True))
+        at_last = lax.broadcasted_iota(jnp.int32, db.shape, 1) == (
+            db.shape[1] - 1)
+        db += jnp.where(
+            at_last, jnp.sum(to_end) + jnp.sum(dh * h) * c.carry,
+            0.0)
+        dgates_ref[i, 0:1, :] = db
+        dgates_ref[i, 1:2, :] = _row(dbeta, eye)
+
+
+def _specs(heads, chunk, d_k, d_v, chunks, reverse):
+    """BlockSpecs of a [B * H, T, d_k] plane, a [B * H, T, d_v] plane,
+    the gates [B * H, T / C, 2, C] and the states [B * H, T / C, d_k,
+    d_v] for a grid (head block, chunk), the chunks walked backwards
+    with ``reverse``."""
+    at = (lambda j: chunks - 1 - j) if reverse else (lambda j: j)
+    return (pl.BlockSpec((heads, chunk, d_k), lambda i, j: (i, at(j), 0)),
+            pl.BlockSpec((heads, chunk, d_v), lambda i, j: (i, at(j), 0)),
+            pl.BlockSpec((heads, None, 2, chunk),
+                         lambda i, j: (i, at(j), 0, 0)),
+            pl.BlockSpec((heads, None, d_k, d_v),
+                         lambda i, j: (i, at(j), 0, 0)))
+
+
+def _params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=VMEM_LIMIT)
+
+
+def _fwd_call(q, k, v, gates, chunk, heads, interpret):
+    """(o [B * H, T, d_v], chunk-start states [B * H, T / C, d_k, d_v]
+    float32)."""
+    bh, seq, d_k = q.shape
+    d_v = v.shape[-1]
+    chunks = seq // chunk
+    qk, vo, gate, state = _specs(heads, chunk, d_k, d_v, chunks, False)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, heads=heads),
+        out_shape=(jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct((bh, chunks, d_k, d_v), _F32)),
+        grid=(bh // heads, chunks),
+        in_specs=[qk, qk, vo, gate],
+        out_specs=(vo, state),
+        scratch_shapes=[pltpu.VMEM((heads, d_k, d_v), _F32)],
+        compiler_params=_params(),
+        interpret=interpret,
+        name="gdn_fwd",
+    )(q, k, v, gates)
+
+
+def _bwd_call(q, k, v, gates, states, do, chunk, heads, interpret):
+    """(dq, dk, dv, dgates)."""
+    bh, seq, d_k = q.shape
+    d_v = v.shape[-1]
+    chunks = seq // chunk
+    qk, vo, gate, state = _specs(heads, chunk, d_k, d_v, chunks, True)
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, heads=heads),
+        out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct(gates.shape, _F32)),
+        grid=(bh // heads, chunks),
+        in_specs=[qk, qk, vo, gate, state, vo],
+        out_specs=(qk, qk, vo, gate),
+        scratch_shapes=[pltpu.VMEM((heads, d_k, d_v), _F32)],
+        compiler_params=_params(),
+        interpret=interpret,
+        name="gdn_bwd",
+    )(q, k, v, gates, states, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _gdn(q, k, v, gates, chunk, heads, interpret):
+    return _fwd_call(q, k, v, gates, chunk, heads, interpret)[0]
+
+
+def _gdn_fwd(q, k, v, gates, chunk, heads, interpret):
+    o, states = _fwd_call(q, k, v, gates, chunk, heads, interpret)
+    # named where they are made, as the flash forward's two: a policy
+    # that saves both does not run this forward a second time
+    o = checkpoint_name(o, KEEP_OUT)
+    states = checkpoint_name(states, KEEP_STATES)
+    return o, (q, k, v, gates, states)
+
+
+def _gdn_bwd(chunk, heads, interpret, res, do):
+    return _bwd_call(*res, do, chunk, heads, interpret)
+
+
+_gdn.defvjp(_gdn_fwd, _gdn_bwd)
+
+
+def _unfriendly(seq, d_k, d_v, chunk):
+    """Why the kernels cannot take this shape, or "" when they can."""
+    if seq % chunk:
+        return "seq %d is not a multiple of the chunk %d" % (seq, chunk)
+    if d_k % 8 or d_v % 8:
+        return "head sizes %d | %d are not multiples of 8" % (d_k, d_v)
+    return ""
+
+
+def delta_mode(seq, d_k, d_v, chunk=CHUNK, interpret=None):
+    """(mode: "tpu" | "interpret" | "off" as ``gated_delta`` runs a
+    sequence of ``seq`` here, why not the kernel or "")."""
+    mode = resolve(interpret)
+    why = _unfriendly(seq, d_k, d_v, chunk) if mode != "off" else ""
+    return ("off" if why else mode), why
+
+
+def gated_delta(q, k, v, g, beta, chunk=CHUNK, interpret=None):
+    """q, k [B, H, T, d_k], v [B, H, T, d_v] in the compute dtype, g
+    (the log decay, <= 0) and beta [B, H, T] -> o [B, H, T, d_v] in v's
+    dtype; every sequence and head starts from a zero state.
+    Differentiable in all five.  The kernels where ``ops/mode.py``
+    allows them and the shape tiles, per shard of the declared batch
+    axis; else ``gated_delta_ref``."""
+    batch, heads, seq, d_k = q.shape
+    d_v = v.shape[-1]
+    mode, why = delta_mode(seq, d_k, d_v, chunk, interpret)
+    if mode == "off":
+        if why:
+            flash_attention.announce_fallback(
+                "gated_delta", q.shape, why, resolve(interpret))
+        return checkpoint_name(
+            gated_delta_ref(q, k, v, g, beta, chunk), KEEP_OUT)
+    block = next(n for n in HEAD_BLOCKS if heads % n == 0)
+
+    def op(q, k, v, g, beta):
+        planes = lambda x: x.reshape(-1, *x.shape[2:])
+        rows = lambda x: x.astype(_F32).reshape(-1, seq // chunk, 1, chunk)
+        gates = jnp.concatenate(
+            [jnp.cumsum(rows(g), axis=-1), rows(beta)], axis=2)
+        o = _gdn(planes(q), planes(k), planes(v), gates, chunk, block,
+                 mode == "interpret")
+        return o.reshape(-1, heads, seq, d_v)
+
+    return per_batch_shard(op, (q, k, v, g, beta))

@@ -23,6 +23,15 @@ trace, ``sconv_fwd`` and ``sconv_bwd`` (``benchmark/kernels/``).
 
 The op is bound by memory: 4 passes of ``[rows, E]`` forward, 7 backward.
 
+A second entry point, ``conv_silu(x [B, T, E], w [E, K]) -> [B, T,
+E]``, runs the same depthwise causal taps over ``x`` itself and puts a
+SiLU behind them, ``silu(conv(x))``: what a Gated DeltaNet layer puts
+between its q, k, v projection and its scan (models/transformer.py).
+Its calls are ``sconv_silu_fwd`` (2 passes) and ``sconv_silu_bwd``, which
+rebuilds ``conv`` of the tile and of the HALO rows below it for the
+SiLU's slope (5 passes); the tiles, the halo and the taps' layout are
+the gated op's.
+
 Reference: ``short_conv_ref``, plain ``jax.numpy`` differentiated by JAX,
 which is also what ``short_conv`` returns wherever ``ops/mode.py``
 answers ``off``, or for shapes the kernel does not tile (it says so:
@@ -60,14 +69,23 @@ def short_conv_ref(bcu, w):
     """The same in plain ``jax.numpy`` (float32 inside, bcu's dtype
     out); bcu [..., T, 3E], w [E, K]."""
     b, c, u = jnp.split(bcu.astype(jnp.float32), 3, axis=-1)
-    g = b * u
+    return (c * _taps_ref(b * u, w)).astype(bcu.dtype)
+
+
+def _taps_ref(g, w):
+    """``conv`` [..., T, E] float32 of g and the taps w [E, K]."""
     t, taps = g.shape[-2], w.shape[1]
     pad = [(0, 0)] * (g.ndim - 2) + [(taps - 1, 0), (0, 0)]
     padded = jnp.pad(g, pad)
-    conv = sum(w[:, k].astype(jnp.float32)
+    return sum(w[:, k].astype(jnp.float32)
                * lax.slice_in_dim(padded, k, k + t, axis=-2)
                for k in range(taps))
-    return (c * conv).astype(bcu.dtype)
+
+
+def conv_silu_ref(x, w):
+    """``silu(conv(x))`` in plain ``jax.numpy`` (float32 inside, x's
+    dtype out); x [..., T, E], w [E, K]."""
+    return jax.nn.silu(_taps_ref(x.astype(jnp.float32), w)).astype(x.dtype)
 
 
 def tiles(seq_len, channels):
@@ -248,23 +266,138 @@ def _sconv_fwd(bcu, w, seq_len, interpret, tm, tc):
     return _fwd_call(bcu, w, seq_len, interpret, tm, tc), (bcu, w)
 
 
+def _taps_gradient(parts, w):
+    """dw [E, K] of the row tiles' parts [tiles * 8, E] float32."""
+    dw = parts.reshape(-1, 8, w.shape[0]).sum(axis=0)[:w.shape[1]]
+    return dw.T.astype(w.dtype)
+
+
 def _sconv_bwd(seq_len, interpret, tm, tc, res, dout):
     bcu, w = res
     dbcu, parts = _bwd_call(bcu, w, dout, seq_len, interpret, tm, tc)
-    dw = parts.reshape(-1, 8, w.shape[0]).sum(axis=0)[:w.shape[1]]
-    return dbcu, dw.T.astype(w.dtype)
+    return dbcu, _taps_gradient(parts, w)
 
 
 _sconv.defvjp(_sconv_fwd, _sconv_bwd)
 
 
+# -- taps and a SiLU ---------------------------------------------------------
+
+
+def _silu_slope(conv):
+    """(silu(conv), d silu / d conv)."""
+    s = jax.nn.sigmoid(conv)
+    return conv * s, s * (1.0 + conv * (1.0 - s))
+
+
+def _silu_fwd_kernel(x_ref, x_up_ref, w_ref, out_ref, *, taps,
+                     tiles_a_sequence):
+    starts = pl.program_id(0) % tiles_a_sequence == 0
+    above = jnp.where(starts, 0.0, x_up_ref[...].astype(jnp.float32))
+    conv = _conv(x_ref[...].astype(jnp.float32), above, w_ref, taps)
+    out_ref[...] = _silu_slope(conv)[0].astype(out_ref.dtype)
+
+
+def _silu_bwd_kernel(x_ref, x_up_ref, x_dn_ref, w_ref, dout_ref,
+                     dout_dn_ref, dx_ref, dw_ref, *, taps,
+                     tiles_a_sequence):
+    starts = pl.program_id(0) % tiles_a_sequence == 0
+    ends = (pl.program_id(0) + 1) % tiles_a_sequence == 0
+    x = x_ref[...].astype(jnp.float32)
+    above = jnp.where(starts, 0.0, x_up_ref[...].astype(jnp.float32))
+    dconv = dout_ref[...].astype(jnp.float32) * _silu_slope(
+        _conv(x, above, w_ref, taps))[1]
+    # the HALO rows below: their conv reads this tile's last rows
+    conv_dn = _conv(x_dn_ref[...].astype(jnp.float32),
+                    x[x.shape[0] - HALO:], w_ref, taps)
+    below = jnp.where(ends, 0.0, dout_dn_ref[...].astype(jnp.float32)
+                      * _silu_slope(conv_dn)[1])
+    dx = w_ref[taps - 1:taps, :] * dconv
+    dw = [None] * taps
+    dw[taps - 1] = jnp.sum(dconv * x, axis=0, keepdims=True)
+    for k in range(taps - 1):
+        back = taps - 1 - k
+        dx += w_ref[k:k + 1, :] * _shifted(dconv, below, -back)
+        dw[k] = jnp.sum(dconv * _shifted(x, above, back), axis=0,
+                        keepdims=True)
+    row = lax.broadcasted_iota(jnp.int32, dw_ref.shape, 0)
+    dw_ref[...] = sum(jnp.where(row == k, dw[k], 0.0) for k in range(taps))
+    dx_ref[...] = dx.astype(dx_ref.dtype)
+
+
+def _silu_call(x, w, seq_len, interpret, tm, tc, dout=None):
+    """``silu(conv(x))`` [rows, E], or with ``dout`` its backward: (dx
+    [rows, E], dw's parts [tiles * 8, E] float32)."""
+    rows, e = x.shape
+    column, above, below, taps = _specs(tm, tc, e, rows)
+    here = pl.BlockSpec((tm, tc), lambda i, j: (i, j))
+    options = dict(
+        grid=(rows // tm, e // tc),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret)
+    static = dict(taps=w.shape[1], tiles_a_sequence=seq_len // tm)
+    if dout is None:
+        return pl.pallas_call(
+            functools.partial(_silu_fwd_kernel, **static),
+            out_shape=jax.ShapeDtypeStruct((rows, e), x.dtype),
+            in_specs=[column(0), above(0), taps], out_specs=here,
+            name="sconv_silu_fwd", **options)(x, x, _taps(w))
+    return pl.pallas_call(
+        functools.partial(_silu_bwd_kernel, **static),
+        out_shape=(jax.ShapeDtypeStruct((rows, e), x.dtype),
+                   jax.ShapeDtypeStruct((rows // tm * 8, e), jnp.float32)),
+        in_specs=[column(0), above(0), below(0), taps, column(0),
+                  below(0)],
+        out_specs=(here, pl.BlockSpec((8, tc), lambda i, j: (i, j))),
+        name="sconv_silu_bwd", **options)(x, x, x, _taps(w), dout, dout)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
+def _sconv_silu(x, w, seq_len, interpret, tm, tc):
+    return _silu_call(x, w, seq_len, interpret, tm, tc)
+
+
+def _sconv_silu_fwd(x, w, seq_len, interpret, tm, tc):
+    return _silu_call(x, w, seq_len, interpret, tm, tc), (x, w)
+
+
+def _sconv_silu_bwd(seq_len, interpret, tm, tc, res, dout):
+    x, w = res
+    dx, parts = _silu_call(x, w, seq_len, interpret, tm, tc, dout)
+    return dx, _taps_gradient(parts, w)
+
+
+_sconv_silu.defvjp(_sconv_silu_fwd, _sconv_silu_bwd)
+
+
 @functools.lru_cache(maxsize=None)
-def announce_conv(rows, channels, tile, kernel):
+def announce_conv(rows, channels, tile, kernel, silu=False):
     """Once per compiled shape, by the logger ``announce_tiles`` uses:
-    what the op runs (of one shard of the trainer's data axis)."""
+    what the op runs (of one shard of the trainer's data axis); with
+    ``silu`` it is ``conv_silu``, and the line says so at its end."""
     flash_attention.logger.info(
-        "short conv: rows=%d channels=%d tile=%s kernel=%s", rows,
-        channels, "%dx%d" % tile if tile else "-", kernel)
+        "short conv: rows=%d channels=%d tile=%s kernel=%s%s", rows,
+        channels, "%dx%d" % tile if tile else "-", kernel,
+        " epilogue=silu" if silu else "")
+
+
+def _plan(what, batch, seq_len, e, taps, interpret, silu=False):
+    """(mode, tile) an op of this file runs [batch, seq_len, e] in:
+    the kernels where ``ops/mode.py`` allows them and the shape tiles,
+    else ("off", None), said once."""
+    mode = resolve(interpret)
+    tile = None if mode == "off" else tiles(seq_len, e)
+    if mode != "off" and (tile is None or taps > 8):
+        flash_attention.announce_fallback(
+            what, (batch, seq_len, e),
+            "T %% %d, E %% %d or %d taps" % (ROW_TILES[-1],
+                                             CHANNEL_TILES[-1], taps), mode)
+        tile, mode = None, "off"
+    if mode != "interpret":
+        announce_conv(batch * seq_len // shards(), e, tile, mode, silu)
+    return mode, tile
 
 
 def short_conv(bcu, w, interpret=None):
@@ -272,18 +405,9 @@ def short_conv(bcu, w, interpret=None):
     bcu's dtype, causal within each of the B sequences.  Differentiable
     in both.  The kernels where ``ops/mode.py`` allows them and the
     shapes tile, else ``short_conv_ref``."""
-    mode = resolve(interpret)
     batch, seq_len, e3 = bcu.shape
     e, taps = w.shape
-    tile = None if mode == "off" else tiles(seq_len, e)
-    if mode != "off" and (tile is None or taps > 8):
-        flash_attention.announce_fallback(
-            "short_conv", (batch, seq_len, e),
-            "T %% %d, E %% %d or %d taps" % (ROW_TILES[-1],
-                                             CHANNEL_TILES[-1], taps), mode)
-        tile, mode = None, "off"
-    if mode != "interpret":
-        announce_conv(batch * seq_len // shards(), e, tile, mode)
+    mode, tile = _plan("short_conv", batch, seq_len, e, taps, interpret)
     if mode == "off":
         return checkpoint_name(short_conv_ref(bcu, w), KEEP_OUT)
 
@@ -294,3 +418,22 @@ def short_conv(bcu, w, interpret=None):
         return out.reshape(-1, seq_len, e)
 
     return checkpoint_name(per_batch_shard(op, (bcu,), (w,)), KEEP_OUT)
+
+
+def conv_silu(x, w, interpret=None):
+    """x [B, T, E], w [E, K] -> ``silu(conv(x))`` [B, T, E] in x's
+    dtype, the taps causal within each of the B sequences.
+    Differentiable in both.  The kernels where ``ops/mode.py`` allows
+    them and the shapes tile, else ``conv_silu_ref``."""
+    batch, seq_len, e = x.shape
+    mode, tile = _plan("conv_silu", batch, seq_len, e, w.shape[1],
+                       interpret, silu=True)
+    if mode == "off":
+        return conv_silu_ref(x, w)
+
+    def op(x, w):
+        out = _sconv_silu(x.reshape(-1, e), w, seq_len,
+                          mode == "interpret", *tile)
+        return out.reshape(-1, seq_len, e)
+
+    return per_batch_shard(op, (x,), (w,))

@@ -1,0 +1,398 @@
+"""A stack of gated-delta layers and a full-attention layer
+(``layer_pattern=ddda``: models/transformer._delta_mix over
+ops/gated_delta.py and ops/short_conv.conv_silu, a block with norms on
+its sublayers' outputs alone) against the plain reference of the
+benchmark (benchmark/reference/olmo-hybrid-7b.py: the delta rule token
+by token), at the configuration's rehearsal size, float32 on the CPU."""
+
+import functools
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.lib import manifest
+from benchmark.lib.runner import merge, params_string
+from elasticdl_tpu.models import remat_keep as rk
+from elasticdl_tpu.models import transformer as tfm
+from elasticdl_tpu.models.spec import load_model_spec
+from elasticdl_tpu.ops import batch_shard, gated_delta as gd
+from elasticdl_tpu.ops import flash_attention as fa
+from elasticdl_tpu.ops.mode import SWITCH
+
+REF = manifest.load_named("reference", "olmo-hybrid-7b")
+with open(os.path.join(manifest.BENCH_DIR, "configs",
+                       "olmo-hybrid-7b.json")) as fh:
+    PUBLISHED = json.load(fh)
+CONFIG = merge(PUBLISHED, PUBLISHED["rehearsal"])
+SHAPE = REF.shape_of(CONFIG)
+# float32 on both sides: the chunk form against the recurrence reads
+# 1e-6 in the loss and 2e-5 .. 4e-4 in a gradient leaf (the decay's
+# projection, whose gradient is small beside the others')
+LOSS_TOLERANCE, GRAD_TOLERANCE = 2e-5, 2e-3
+
+
+def _spec(**override):
+    return load_model_spec("transformer", model_params=params_string(
+        dict(CONFIG["cli"]["model_params"], **override)))
+
+
+@functools.lru_cache(maxsize=None)
+def _case(seed=3):
+    """(spec, params with the norms' scales drawn, tokens) as the chip's
+    comparison draws them."""
+    spec = _spec()
+    params, tokens = REF.inputs(
+        CONFIG, spec.init_fn(jax.random.PRNGKey(seed)),
+        np.random.default_rng(seed))
+    return spec, params, jnp.concatenate([tokens, tokens[:, ::-1]])
+
+
+def _product(spec, tokens):
+    return lambda p: spec.loss_fn(spec.apply_fn(p, tokens, True),
+                                  tokens).mean()
+
+
+def _reference(tokens, **how):
+    return lambda p: REF.loss(p, tokens, **how, **SHAPE)[0].mean()
+
+
+@functools.lru_cache(maxsize=None)
+def _wanted():
+    spec, params, tokens = _case()
+    return jax.value_and_grad(_reference(tokens))(params)
+
+
+@pytest.mark.parametrize("mode", ["off", "interpret"])
+def test_the_loss_and_every_gradient_leaf_match_the_recurrence(
+        monkeypatch, mode):
+    """``off`` + remat: the jnp twins under ``jax.checkpoint``;
+    ``interpret``: the scan's and the convolution's kernels and the
+    flash kernels in the interpreter."""
+    monkeypatch.setenv(SWITCH, mode)
+    spec, params, tokens = _case()
+    assert spec.config.remat and [k.op for k in spec.config.kinds] == list(
+        "ddda")
+    got, grads = jax.value_and_grad(_product(spec, tokens))(params)
+    want, wanted = _wanted()
+    assert abs(float(got) - float(want)) <= LOSS_TOLERANCE * float(want)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    far = {}
+    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(wanted)):
+        far[jax.tree_util.keystr(path)] = float(
+            jnp.linalg.norm(g - w) / (jnp.linalg.norm(w) + 1e-12))
+        assert float(jnp.linalg.norm(w)) > 0, path
+    assert len(far) == 3 * 14 + 11 + 3
+    assert max(far.values()) < GRAD_TOLERANCE, sorted(
+        far.items(), key=lambda item: -item[1])[:4]
+
+
+@pytest.mark.parametrize("piece", ["conv", "silu", "l2norm", "beta2",
+                                   "decay", "out_norm", "gate"])
+def test_the_reference_without_one_piece_fails_the_tolerance(piece):
+    """Each of steps 1-6 moves the loss by more than the tolerance the
+    product is held to: leaving one out is not support."""
+    spec, params, tokens = _case()
+    want = float(_wanted()[0])
+    less = float(jax.jit(_reference(tokens, without=(piece,)))(params))
+    # (without the L2 norm the state diverges: a NaN is not within it)
+    assert not abs(less - want) <= 20 * LOSS_TOLERANCE * want, (
+        piece, less, want)
+
+
+@pytest.mark.parametrize("how", [dict(rounded=jnp.bfloat16),
+                                 dict(state=jnp.bfloat16)],
+                         ids=["operands", "state"])
+def test_the_reference_in_bfloat16_fails_the_tolerance(how):
+    spec, params, tokens = _case()
+    want = float(_wanted()[0])
+    lower = float(jax.jit(_reference(tokens, **how))(params))
+    assert abs(lower - want) > 5 * LOSS_TOLERANCE * want, (lower, want)
+
+
+# -- the shares add up ------------------------------------------------------
+
+
+def _head_columns(width, heads, share, shares):
+    """Columns of a [.., heads * width] projection that the heads of
+    ``share`` hold."""
+    held = heads // shares
+    return np.arange(share * held * width, (share + 1) * held * width)
+
+
+def _delta_share(w, heads, d_k, d_v, share, shares):
+    """A delta layer's mixer weights cut to one share's heads."""
+    held = heads // shares
+    heads_of = np.arange(share * held, (share + 1) * held)
+    q, k, v = (_head_columns(d_k, heads, share, shares),
+               heads * d_k + _head_columns(d_k, heads, share, shares),
+               2 * heads * d_k + _head_columns(d_v, heads, share, shares))
+    columns = np.concatenate([q, k, v])
+    out = _head_columns(d_v, heads, share, shares)
+    return dict(w, w_qkv=w["w_qkv"][:, columns],
+                delta_conv=w["delta_conv"][columns],
+                w_a=w["w_a"][:, heads_of], w_b=w["w_b"][:, heads_of],
+                A_log=w["A_log"][heads_of], dt_bias=w["dt_bias"][heads_of],
+                w_out_gate=w["w_out_gate"][:, out], wo=w["wo"][out])
+
+
+def _attention_share(w, heads, head_dim, share, shares):
+    columns = _head_columns(head_dim, heads, share, shares)
+    cut = {name: w[name][:, columns] for name in ("wq", "wk", "wv")}
+    return dict(w, wo=w["wo"][columns], q_norm=w["q_norm"][columns],
+                k_norm=w["k_norm"][columns], **cut)
+
+
+def _uncut(kind):
+    """(weights of one uncut layer of 4 heads, its input, the shape)."""
+    heads = 4
+    spec = _spec(num_heads=heads, num_kv_heads=heads, head_shares=1,
+                 num_layers=1, layer_pattern=kind)
+    params = REF.inputs(dict(CONFIG, seq_len=64), spec.init_fn(
+        jax.random.PRNGKey(5)), np.random.default_rng(5))[0]
+    w = REF.layers_of(params)[0]
+    x = jnp.asarray(np.random.default_rng(6).standard_normal((2, 64, 64)),
+                    jnp.float32)
+    return w, x, heads
+
+
+def test_the_shares_of_a_delta_layer_add_up_to_the_uncut_layer():
+    """2 x 2 of 4 heads: the held heads' parts of the ``W_o`` product
+    sum to the uncut mixer's, and with the norm on the sublayer's output
+    taken of the SUM and the MLP counted once that is the uncut
+    reference's layer.  The program's mixer of a share is the same
+    part."""
+    w, x, heads = _uncut("d")
+    d_k, d_v, eps = SHAPE["d_k"], SHAPE["d_v"], SHAPE["eps"]
+    whole = REF.delta_mixer(x, w, heads, d_k, d_v, eps, True)
+    parts = [REF.delta_mixer(x, _delta_share(w, heads, d_k, d_v, i, 2),
+                             heads // 2, d_k, d_v, eps, True)
+             for i in range(2)]
+    np.testing.assert_allclose(parts[0] + parts[1], whole, atol=2e-5)
+    assert float(jnp.abs(parts[0] - whole).max()) > 1e-2
+    # the layer: norm of the sum, then the MLP once
+    x1 = x + REF.rmsnorm(sum(parts), w["ln1_post"], eps)
+    layer = x1 + REF.rmsnorm(REF.swiglu(x1, w), w["ln2_post"], eps)
+    shape = dict(SHAPE, delta_heads=heads, kinds=("linear_attention",))
+    head = jnp.zeros((64, 256), jnp.float32)
+    tokens = jnp.zeros((2, 64), jnp.int32)
+    seen = []
+    real = REF.swiglu
+    try:
+        REF.swiglu = lambda u, w, r=None: seen.append(u) or real(u, w)
+        params = {"embed": jnp.zeros((256, 64)), "ln_f": jnp.ones((64,)),
+                  "lm_head": head, "layers": {
+                      "lead": {"0": w}, "period": {}, "tail": {}}}
+        # the uncut reference's own walk of the layer, from the stream x
+        REF.loss(dict(params, embed=x.reshape(-1, 64)),
+                 jnp.arange(128).reshape(2, 64), **shape)
+    finally:
+        REF.swiglu = real
+    np.testing.assert_allclose(seen[0], x1, atol=2e-5)
+    # the program's held-heads mixer is the reference's part
+    cfg = _spec(num_layers=1, layer_pattern="d").config
+    got = tfm._delta_mix(x, _delta_share(w, heads, d_k, d_v, 1, 2), cfg)
+    np.testing.assert_allclose(got, parts[1], atol=3e-5)
+    assert layer.shape == x.shape
+
+
+def test_the_shares_of_the_full_layer_add_up_given_the_pairs_exchange():
+    """The QK norm runs over the whole projection, so the held heads'
+    parts add up to the uncut mixer once each share norms with the mean
+    square of all 4 heads' values (what the pair would exchange); with
+    its own 2 heads' mean square, as the cell runs it, a share is off
+    by that statistic alone."""
+    w, x, heads = _uncut("a")
+    head_dim, eps = SHAPE["head_dim"], SHAPE["eps"]
+    whole = REF.attention(x, w, heads, head_dim, eps)
+    stat = tuple(jnp.mean(jnp.square(x @ w[name]), axis=-1, keepdims=True)
+                 for name in ("wq", "wk"))
+    share = lambda i, **kw: REF.attention(
+        x, _attention_share(w, heads, head_dim, i, 2), heads // 2,
+        head_dim, eps, **kw)
+    exchanged = share(0, qk_stat=stat) + share(1, qk_stat=stat)
+    np.testing.assert_allclose(exchanged, whole, atol=2e-5)
+    alone = share(0) + share(1)
+    assert float(jnp.abs(alone - whole).max()) > 1e-3
+    # the program's held-heads mixer is the reference's part, its own
+    # mean square
+    cfg = _spec(num_layers=1, layer_pattern="a").config
+    got = tfm._attention_mix(
+        x, _attention_share(w, heads, head_dim, 1, 2), cfg, None,
+        jnp.arange(64), cfg.kinds[0])[0]
+    np.testing.assert_allclose(got, share(1), atol=3e-5)
+
+
+# -- the plan, the tree, the refusals ---------------------------------------
+
+
+def test_stack_plan_of_ddda_is_one_period_and_its_letters_round_trip():
+    cfg = _spec().config
+    plan = tfm.stack_plan(cfg)
+    assert (plan.lead, plan.periods, plan.tail) == ((), 1, ())
+    assert "".join(map(tfm._letter, plan.period)) == "ddda"
+    assert plan.period[0] == tfm.Kind("d", True)
+    assert plan.period[3] == tfm.Kind("a", True, 0, False)    # NoPE
+    eight = tfm.stack_plan(_spec(num_layers=8,
+                                 layer_pattern="dddaddda").config)
+    assert eight.periods == 2 and len(eight.period) == 4
+
+
+def test_the_parameter_tree_and_the_decay_mask():
+    spec = _spec()
+    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+    period = params["layers"]["period"]
+    assert sorted(period["0"]) == sorted([
+        "A_log", "delta_conv", "dt_bias", "ln1_post", "ln2_post", "o_norm",
+        "w_a", "w_b", "w_down", "w_gate", "w_out_gate", "w_qkv", "w_up",
+        "wo"])
+    assert sorted(period["3"]) == sorted([
+        "k_norm", "ln1_post", "ln2_post", "q_norm", "w_down", "w_gate",
+        "w_up", "wk", "wo", "wq", "wv"])       # no ln1, no ln2
+    heads, d_k, d_v = 2, 16, 32
+    assert period["0"]["w_qkv"].shape == (1, 64, heads * (2 * d_k + d_v))
+    assert period["0"]["delta_conv"].shape == (1, heads * (2 * d_k + d_v), 4)
+    assert period["0"]["o_norm"].shape == (1, d_v)
+    mask = tfm._decayed(params)
+    spared = {jax.tree_util.keystr(path[-1:]) for path, keep in
+              jax.tree_util.tree_flatten_with_path(mask)[0] if not keep}
+    assert spared == {"['A_log']", "['dt_bias']", "['o_norm']",
+                      "['delta_conv']", "['ln1_post']", "['ln2_post']"}
+
+
+def test_the_published_count_of_parameters():
+    spec = load_model_spec("transformer", model_params=params_string(
+        PUBLISHED["cli"]["model_params"]))
+    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+    assert sum(a.size for a in jax.tree_util.tree_leaves(params)) == (
+        766241946)
+
+
+@pytest.mark.parametrize("what,call", [
+    ("decode_step", lambda spec, p: tfm.decode_step(
+        p, spec.config, None, 0, jnp.zeros((1,), jnp.int32))),
+    ("a model-parallel mesh", lambda spec, p: tfm.param_specs(spec.config)),
+    ("forward_pipelined", lambda spec, p: tfm.forward_pipelined(
+        p, jnp.zeros((2, 64), jnp.int32), spec.config, None, 2)),
+])
+def test_what_cannot_run_the_stack_refuses_it_by_name(what, call):
+    spec = _spec()
+    with pytest.raises(NotImplementedError) as refusal:
+        call(spec, None)
+    text = str(refusal.value)
+    assert text.startswith(what + " does not run")
+    assert "layer_pattern='ddda'" in text and "recurrent state" in text
+    assert "pre_norms=False" in text
+
+
+@pytest.mark.parametrize("bad,match", [
+    (dict(layer_pattern="dd", num_layers=2), "delta_key_dim"),
+    (dict(layer_pattern="dd", num_layers=2, delta_key_dim=8,
+          delta_value_dim=8, conv_kernel=18), "conv_kernel"),
+    (dict(pre_norms=False), "pre_norms=false needs post_norms"),
+    (dict(layer_pattern="dx", num_layers=2), "gated delta rule"),
+])
+def test_a_delta_layer_without_its_sizes_is_refused_by_name(bad, match):
+    with pytest.raises(ValueError, match=match):
+        tfm.TransformerConfig(**bad)
+
+
+# -- what is kept ------------------------------------------------------------
+
+DELTA_ROWS = ["delta_decay", "delta", "delta_gate", "delta_in", "delta_qkv"]
+
+
+def test_remat_keeps_table_has_the_delta_layers_rows():
+    cfg = _spec().config
+    rows = 2 * 64
+    table = {label: (names, nbytes) for label, names, nbytes in rk.table(
+        cfg, rows)}
+    assert [label for label in table if label.startswith("delta")] == (
+        DELTA_ROWS)
+    heads, d_k, d_v, size = 2, 16, 32, 4
+    assert table["delta_in"] == ((rk.KEEP_DELTA_IN,),
+                                 rows * heads * (2 * d_k + d_v) * size)
+    assert table["delta_qkv"][1] == table["delta_in"][1]
+    assert table["delta_decay"] == ((rk.KEEP_DELTA_DECAY,), rows * heads * 8)
+    assert table["delta_gate"] == ((rk.KEEP_DELTA_GATE,),
+                                   rows * heads * d_v * size)
+    # the output, and a float32 state a chunk a head, 128 lanes a row
+    assert table["delta"] == (
+        (gd.KEEP_OUT, gd.KEEP_STATES), rows * heads * d_v * size
+        + rows // gd.CHUNK * heads * d_k * 128 * 4)
+    layers = {label: n for label, _, _, n in rk._entries(cfg, rows)}
+    assert layers["delta"] == 3 and layers["flash"] == 1
+    assert layers["stream"] == layers["ffn_gate"] == 4
+    assert "conv_in" not in layers
+
+
+@pytest.mark.parametrize("mode", ["interpret", "off"])
+def test_kept_names_change_no_gradient(monkeypatch, mode):
+    monkeypatch.setenv(SWITCH, mode)
+    spec, params, tokens = _case()
+    held = 16 * sum(a.size for a in jax.tree_util.tree_leaves(params))
+
+    def grads(room):
+        with batch_shard.batch_axis(None, None, room):
+            return jax.grad(_product(spec, tokens))(params)
+
+    everything = batch_shard.DeviceRoom(2 ** 40, 2 ** 40 - held)
+    names = rk.choose(spec.config, params, tokens.size, everything)[0]
+    assert {gd.KEEP_OUT, gd.KEEP_STATES, rk.KEEP_DELTA_IN,
+            rk.KEEP_DELTA_QKV, rk.KEEP_DELTA_DECAY,
+            rk.KEEP_DELTA_GATE} <= set(names)
+    for a, b in zip(jax.tree_util.tree_leaves(grads(everything)),
+                    jax.tree_util.tree_leaves(grads(None))):
+        np.testing.assert_allclose(a, b, rtol=0, atol=1e-6)
+
+
+# -- the lines ---------------------------------------------------------------
+
+
+def _lines(fn, *prefixes):
+    import logging
+
+    seen = []
+
+    class Handler(logging.Handler):
+        def emit(self, record):
+            seen.append(record.getMessage())
+
+    handler = Handler(level=logging.INFO)
+    fa.logger.addHandler(handler)
+    for announce in (tfm.announce_delta, tfm.announce_stack):
+        announce.cache_clear()
+    try:
+        fn()
+    finally:
+        fa.logger.removeHandler(handler)
+    return [line for line in seen if line.startswith(prefixes)]
+
+
+@pytest.mark.parametrize("mode,ran", [("off", "reference"),
+                                      ("interpret", "interpreter")])
+def test_the_delta_scan_and_layer_stack_lines(monkeypatch, mode, ran):
+    monkeypatch.setenv(SWITCH, mode)
+    spec, params, tokens = _case()
+    held = 16 * sum(a.size for a in jax.tree_util.tree_leaves(params))
+    room = batch_shard.DeviceRoom(2 ** 40, 2 ** 40 - held)
+
+    def trace(room):
+        def run():
+            with batch_shard.batch_axis(None, None, room):
+                jax.eval_shape(_product(spec, tokens), params)
+        return _lines(run, "delta scan:", "layer stack:")
+
+    stack, scan = trace(None)
+    assert stack == (
+        "layer stack: pattern=ddda lead=- period=ddda periods=1 tail=- "
+        "dense_layers=0 experts_held=0/0 heads_held=2/4 "
+        "a:window=0,rope=0")
+    assert scan == (
+        "delta scan: rows=128 heads=2 key_dim=16 value_dim=32 chunk=64 "
+        "conv_taps=4 neg_eigval=1 states=recomputed " + ran)
+    # with room for everything the states are among the kept names
+    assert trace(room)[1] == scan.replace("recomputed", "kept")

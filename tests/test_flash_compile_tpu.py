@@ -744,3 +744,139 @@ def test_the_mixed_stacks_step_fits_a_v5e_as_remat_keep_predicts(
     with_names = compiled(room)
     assert peak <= (1 - rk.RESERVE) * limit
     assert -0.1e9 < peak - with_names < 0.5e9, (peak, with_names, names)
+
+
+# -- the olmo-hybrid-7b cell's shapes (benchmark/configs/olmo-hybrid-7b.json)
+
+
+def _mosaic_calls(text):
+    return [l.strip() for l in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in l]
+
+
+@pytest.mark.parametrize("chunk", [64, 128])
+def test_the_delta_scan_compiles_at_the_cells_shape(one_chip, chunk):
+    """15 heads x 16,384 x 96 | 192, one sequence: ``gdn_fwd`` (a block
+    of 5 heads' float32 states resident, the chunk's inverse by 10 or 12
+    float32 matmuls at the highest precision) and ``gdn_bwd`` (the
+    states' cotangents resident, the chunk walked backwards) for a
+    described v5e, under their names and the VMEM limit the calls set;
+    the forward writes the output and a float32 state a chunk a head,
+    the backward dq, dk, dv and the two gates' cotangents."""
+    from elasticdl_tpu.ops import gated_delta as gd
+
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip)
+    q, v = shape(1, 15, 16384, 96), shape(1, 15, 16384, 192)
+    g = shape(1, 15, 16384, dtype=jnp.float32)
+
+    def fwd_bwd(q, k, v, g, beta, cot):
+        out, pull = jax.vjp(lambda *a: gd.gated_delta(
+            *a, chunk=chunk, interpret=False), q, k, v, g, beta)
+        return out, pull(cot)
+
+    assert gd.delta_mode(16384, 96, 192, chunk, interpret=False) == (
+        "tpu", "")
+    assert gd.VMEM_LIMIT <= 64 * 2 ** 20
+    calls = _mosaic_calls(jax.jit(fwd_bwd).lower(
+        q, q, v, g, g, v).compile().as_text())
+    assert len(calls) == 2, calls
+    fwd = next(c for c in calls if "gdn_fwd" in c.split(" = ")[0])
+    bwd = next(c for c in calls if "gdn_bwd" in c.split(" = ")[0])
+    chunks = 16384 // chunk
+    results = lambda call: call.split(" custom-call(")[0]
+    assert "bf16[15,16384,192]" in results(fwd)
+    assert "f32[15,%d,96,192]" % chunks in results(fwd)
+    assert results(bwd).count("bf16[15,16384,96]") == 2       # dq, dk
+    assert "bf16[15,16384,192]" in results(bwd)               # dv
+    assert "f32[15,%d,2,%d]" % (chunks, chunk) in results(bwd)
+
+
+def test_the_convolution_with_a_silu_compiles_at_the_cells_shape(one_chip):
+    """One sequence of 16,384 x 5,760 channels (15 heads' q, k and v
+    side by side), four taps: two Mosaic calls under names the gated
+    convolution's reader does not match."""
+    from elasticdl_tpu.ops import short_conv as sc
+
+    x = jax.ShapeDtypeStruct((1, 16384, 5760), jnp.bfloat16,
+                             sharding=one_chip)
+    w = jax.ShapeDtypeStruct((5760, 4), jnp.float32, sharding=one_chip)
+
+    def fwd_bwd(x, w, cot):
+        out, pull = jax.vjp(
+            lambda x, w: sc.conv_silu(x, w, interpret=False), x, w)
+        return out, pull(cot)
+
+    assert sc.tiles(16384, 5760) == (512, 128)
+    calls = _mosaic_calls(jax.jit(fwd_bwd).lower(x, w, x).compile().as_text())
+    # (under ``jax.vjp`` XLA wraps the names: jvp_.._fwd_, transpose_..)
+    assert len(calls) == 2 and any(
+        "sconv_silu_fwd" in c.split(" = ")[0] for c in calls), calls
+    bwd = next(c for c in calls if "sconv_silu_bwd" in c.split(" = ")[0])
+    assert "bf16[16384,5760]" in bwd and "f32[256,5760]" in bwd
+
+
+def test_flash_compiles_at_15_heads_of_128_on_as_many_kv_heads(one_chip):
+    """The cell's full layer: 15 query heads on 15 K/V heads at 16,384,
+    the first equal count at that length (a group of 1: no group sum in
+    the fused backward)."""
+    assert fa._backward_plan(16384, 128, 0, 2, 1) == ("fused",
+                                                     "dq_acc_mb=16")
+    assert _flash_calls((1, 15, 16384, 128), 0, one_chip, 15) == [
+        "flash_bwd", "flash_fwd"]
+
+
+def test_the_delta_stacks_step_fits_a_v5e_with_nothing_kept(
+        one_chip, monkeypatch):
+    """The ``olmo-hybrid-7b.seq16384`` cell's whole training step (one
+    sequence of 16,384 through three gated-delta layers and a full NoPE
+    layer at 15 of 30 heads, a SwiGLU of 11,008 in each, an untied head
+    over 12,544 ids, AdamW; 766.2 M parameters) through the TPU's
+    compiler with nothing kept: the configuration's condition for its
+    two-way head share (12.88 GB of the 16.91), so the three-way
+    fallback was not taken; ``remat_keep``'s estimate is over the
+    compiler's count, as in the other cells whose gradients the trainer
+    counts whole (+2.04 GB; ``lfm2-24b-a2b`` +2.01).  The scan runs once
+    forward and once again in each delta layer's backward, and the
+    convolution with it."""
+    import optax
+
+    from elasticdl_tpu.models import remat_keep as rk
+    from elasticdl_tpu.ops.mode import SWITCH
+
+    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
+    spec = tfm.model_spec(**_model_params("olmo-hybrid-7b"))
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+    state = jax.eval_shape(spec.optimizer.init, params)
+    tokens = jax.ShapeDtypeStruct((1, 16384), jnp.int32, sharding=one_chip)
+    nbytes = lambda tree: sum(
+        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
+    assert nbytes(params) == 4 * 766241946
+    held = 2 * nbytes(params) + nbytes(state)
+
+    def step(params, state, tokens):
+        def loss(p):
+            out = spec.apply_fn(p, tokens, True)
+            return spec.loss_fn(out, tokens).mean()
+
+        value, grads = jax.value_and_grad(loss)(params)
+        updates, state2 = spec.optimizer.update(grads, state, params)
+        return optax.apply_updates(params, updates), state2, value
+
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(state), tokens).compile()
+    stats = compiled.memory_analysis()
+    counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
+    assert counted < 0.95 * 16911433728
+    estimate = held + rk.step_bytes(spec.config, params, 16384)
+    assert -0.1e9 < estimate - counted < 2.3e9, (estimate, counted)
+    names = [c.split(" = ")[0].lstrip("%") for c in _mosaic_calls(
+        compiled.as_text())]
+    count = lambda name: len([c for c in names if re.search(
+        r"(^|_)" + name + r"(__)?\.\d+$", c)])
+    assert (count("gdn_fwd"), count("gdn_bwd")) == (6, 3), names
+    assert (count("sconv_silu_fwd"), count("sconv_silu_bwd")) == (6, 3)
+    assert (count("flash_fwd"), count("flash_bwd")) == (2, 1), names

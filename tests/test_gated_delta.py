@@ -1,0 +1,219 @@
+"""``ops/gated_delta.py`` and ``ops/short_conv.conv_silu`` against the
+plain definitions: the delta rule token by token (``lax.scan`` over T,
+no chunks), the convolution tap by tap.  Float32 unless said."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+from elasticdl_tpu.ops import gated_delta as gd
+from elasticdl_tpu.ops import short_conv as sc
+
+B, H, T, DK, DV = 2, 3, 96, 16, 24
+
+
+def recurrence(q, k, v, g, beta):
+    """S' = alpha S; u = beta (v - S' k); S = S' + u k^T; o = S q."""
+    q, k, v, g, beta = (x.astype(jnp.float32) for x in (q, k, v, g, beta))
+
+    def token(S, x):
+        q, k, v, g, beta = x
+        S = S * jnp.exp(g)[..., None, None]
+        u = beta[..., None] * (v - jnp.einsum("bhvk,bhk->bhv", S, k))
+        S = S + u[..., :, None] * k[..., None, :]
+        return S, jnp.einsum("bhvk,bhk->bhv", S, q)
+
+    xs = tuple(jnp.moveaxis(x, 2, 0) for x in (q, k, v, g, beta))
+    start = jnp.zeros(q.shape[:2] + (v.shape[-1], q.shape[-1]), jnp.float32)
+    return jnp.moveaxis(lax.scan(token, start, xs)[1], 0, 2)
+
+
+def draw(seed, big_beta, dtype=jnp.float32, batch=B, seq=T, d_k=DK):
+    """Operands as a layer hands them: unit keys, queries at d_k^-1/2;
+    head 0 decays by 0.4-0.99 a token, head 1 forgets nearly all of its
+    state at every token (alpha ~ 1e-9) and head 2 nearly nothing
+    (alpha > 0.999); ``big_beta`` puts half the write strengths in (1,
+    2), else all lie in (0, 1)."""
+    r = np.random.default_rng(seed)
+    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(r.standard_normal((batch, H, seq, d_k))) / np.sqrt(d_k)
+    k = unit(r.standard_normal((batch, H, seq, d_k)))
+    v = r.standard_normal((batch, H, seq, DV))
+    beta = r.uniform(0.05, 2.0 if big_beta else 1.0, (batch, H, seq))
+    g = -np.stack([r.uniform(0.01, 1.0, (batch, seq)),
+                   r.uniform(10.0, 30.0, (batch, seq)),
+                   r.uniform(0.0, 1e-3, (batch, seq))], axis=1)
+    return (jnp.asarray(q, dtype), jnp.asarray(k, dtype),
+            jnp.asarray(v, dtype), jnp.asarray(g, jnp.float32),
+            jnp.asarray(beta, jnp.float32))
+
+
+def weights(shape):
+    return jnp.asarray(np.random.default_rng(7).standard_normal(shape),
+                       jnp.float32)
+
+
+def out_and_grads(fn, operands):
+    """(o, its five gradients under a fixed random cotangent)."""
+    loss = lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * weights(
+        operands[2].shape))
+    return (fn(*operands),) + jax.grad(loss, argnums=(0, 1, 2, 3, 4))(
+        *operands)
+
+
+@functools.lru_cache(maxsize=None)
+def wanted(big_beta):
+    return out_and_grads(recurrence, draw(1, big_beta))
+
+
+def far(got, want):
+    got, want = (a.astype(jnp.float32) for a in (got, want))
+    return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+
+
+IMPLEMENTATIONS = {
+    "twin": lambda chunk: functools.partial(gd.gated_delta_ref, chunk=chunk),
+    "kernel": lambda chunk: functools.partial(gd.gated_delta, chunk=chunk,
+                                              interpret=True),
+}
+
+
+@pytest.mark.parametrize("big_beta", [True, False],
+                         ids=["beta_to_2", "beta_to_1"])
+@pytest.mark.parametrize("chunk", [16, 32])
+@pytest.mark.parametrize("which", sorted(IMPLEMENTATIONS))
+def test_the_chunk_form_is_the_recurrence_in_the_output_and_every_gradient(
+        which, chunk, big_beta):
+    """Six and three chunks a sequence; a head that forgets everything
+    and one that forgets nothing beside an ordinary one; dq, dk, dv, dg
+    and dbeta each within 2e-5 of the recurrence's (float32 rounding
+    over 96 tokens reads 2e-7 .. 1e-6; dg of the head that forgets
+    everything is ~1e-9 of the others' and is held with them)."""
+    got = out_and_grads(IMPLEMENTATIONS[which](chunk), draw(1, big_beta))
+    names = ("o", "dq", "dk", "dv", "dg", "dbeta")
+    errors = {name: far(a, b) for name, a, b in zip(
+        names, got, wanted(big_beta))}
+    assert max(errors.values()) < 2e-5, errors
+    # the head that forgets nothing, alone: its gradients are not lost
+    # in the norm of the others'
+    for name, a, b in zip(names, got, wanted(big_beta)):
+        assert far(a[:, 2], b[:, 2]) < 2e-5, name
+
+
+def test_bfloat16_operands_stay_within_their_own_tolerance():
+    """bfloat16 q, k, v (the decays, the inverse and the state float32):
+    2^-9 a rounding, a few dozen roundings a chunk: under 1e-2 of the
+    float32 recurrence on the same bfloat16 values, in the output and
+    every gradient (3-4e-3 read)."""
+    operands = draw(2, True, jnp.bfloat16)
+    got = out_and_grads(IMPLEMENTATIONS["kernel"](16), operands)
+    want = out_and_grads(recurrence, operands)
+    assert got[0].dtype == jnp.bfloat16 and got[1].dtype == jnp.bfloat16
+    assert got[4].dtype == jnp.float32
+    errors = [far(a, b) for a, b in zip(got, want)]
+    assert max(errors) < 1e-2, errors
+    assert max(errors) > 1e-4      # and it is bfloat16 that ran
+
+
+def test_two_sequences_in_a_batch_leak_no_state_into_each_other():
+    operands = draw(3, True)
+    both = gd.gated_delta(*operands, chunk=32, interpret=True)
+    for i in range(B):
+        alone = gd.gated_delta(*(x[i:i + 1] for x in operands), chunk=32,
+                               interpret=True)
+        np.testing.assert_allclose(both[i:i + 1], alone, rtol=0, atol=1e-6)
+    # and the second is not the first's continuation
+    joined = recurrence(*(jnp.concatenate([x[0:1], x[1:2]], axis=2)
+                          for x in operands))
+    assert far(both[1:2], joined[:, :, T:]) > 1e-2
+
+
+def test_keys_that_share_a_direction_do_not_break_the_inverse():
+    """Keys behind a SiLU share a direction; here k_i . k_j ~ 0.9 for
+    every pair, no decay and beta near 2: the Neumann series of the
+    chunk's nilpotent matrix over a whole chunk of 64 reads NaN in
+    float32 and over diagonal blocks of 16 1e-2; block forward
+    substitution from pairs of tokens reads 2-3e-6."""
+    r = np.random.default_rng(5)
+    q, k, v, g, beta = draw(5, True, seq=256)
+    k = k + 0.7 * jnp.asarray(r.standard_normal((1, 1, 1, DK)), jnp.float32)
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    g = jnp.zeros_like(g) - 1e-4
+    operands = (q, k, v, g, jnp.clip(beta + 0.9, 0.0, 1.98))
+    want = recurrence(*operands)
+    for chunk in (64, 128):
+        assert far(gd.gated_delta_ref(*operands, chunk=chunk), want) < 1e-5
+    assert far(gd.gated_delta(*operands, chunk=128, interpret=True),
+               want) < 1e-5
+
+
+def test_a_length_the_kernel_does_not_tile_takes_the_twin_and_says_so(
+        monkeypatch):
+    said = []
+    monkeypatch.setattr(gd.flash_attention, "announce_fallback",
+                        lambda *a: said.append(a))
+    assert gd.delta_mode(100, DK, DV, 32, interpret=False) == (
+        "off", "seq 100 is not a multiple of the chunk 32")
+    assert gd.delta_mode(96, DK, DV, 32, interpret=True) == ("interpret", "")
+    operands = draw(4, True, seq=100)
+    got = gd.gated_delta(*operands, chunk=32, interpret=False)
+    assert said and said[0][0] == "gated_delta" and said[0][3] == "tpu"
+    assert far(got, recurrence(*operands)) < 2e-5
+
+
+# -- the convolution with a SiLU behind it -----------------------------------
+
+
+def explicit_conv_silu(x, w):
+    seq, taps = x.shape[1], w.shape[1]
+    conv = jnp.zeros_like(x)
+    for k in range(taps):
+        back = taps - 1 - k
+        moved = x if not back else jnp.concatenate(
+            [jnp.zeros_like(x[:, :back]), x[:, :seq - back]], axis=1)
+        conv = conv + w[:, k] * moved
+    return conv * jax.nn.sigmoid(conv)
+
+
+@pytest.mark.parametrize("which", ["reference", "kernel"])
+def test_conv_silu_is_the_explicit_taps_first_rows_included(which):
+    """4 taps over 3 row tiles of 32 a sequence, 2 sequences, 2 channel
+    tiles: the rows at a tile's edges read the tile above (forward) and
+    below (backward), a sequence's first rows read zeros."""
+    r = np.random.default_rng(0)
+    x = jnp.asarray(r.standard_normal((2, 96, 256)), jnp.float32)
+    w = jnp.asarray(0.5 * r.standard_normal((256, 4)), jnp.float32)
+    fn = sc.conv_silu_ref if which == "reference" else functools.partial(
+        sc.conv_silu, interpret=True)
+    assert sc.tiles(96, 256) == (32, 256)
+    want = explicit_conv_silu(x, w)
+    got = fn(x, w)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    np.testing.assert_allclose(got[:, :4], want[:, :4], atol=2e-6)
+    cot = weights(x.shape)
+    grads = lambda f: jax.grad(lambda x, w: jnp.sum(f(x, w) * cot),
+                               argnums=(0, 1))(x, w)
+    for a, b in zip(grads(fn), grads(explicit_conv_silu)):
+        np.testing.assert_allclose(a, b, atol=3e-5)
+
+
+def test_conv_silu_keeps_the_gated_ops_line_apart(caplog):
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    sc.announce_conv.cache_clear()
+    fa.logger.addHandler(caplog.handler)
+    try:
+        with caplog.at_level("INFO"):
+            sc.conv_silu(jnp.zeros((1, 32, 128)), jnp.zeros((128, 4)))
+            sc.short_conv(jnp.zeros((1, 32, 384)), jnp.zeros((128, 3)))
+    finally:
+        fa.logger.removeHandler(caplog.handler)
+    lines = [r.getMessage() for r in caplog.records
+             if r.getMessage().startswith("short conv:")]
+    assert lines == [
+        "short conv: rows=32 channels=128 tile=- kernel=off epilogue=silu",
+        "short conv: rows=32 channels=128 tile=- kernel=off"]

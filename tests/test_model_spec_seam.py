@@ -67,6 +67,11 @@ AS_TYPED = {
     "attention_impl": ("ulysses", "ulysses", ""),
     "window": ("8", 8, ""),
     "num_kv_heads": ("2", 2, ""),
+    "pre_norms": ("false", False, "post_norms=true"),
+    "delta_key_dim": ("16", 16, ""),
+    "delta_value_dim": ("32", 32, ""),
+    "delta_neg_eigval": ("true", True, ""),
+    "head_shares": ("2", 2, ""),
 }
 FIELDS = [field for field in dataclasses.fields(tfm.TransformerConfig)
           if field.name != "max_seq_len"]
