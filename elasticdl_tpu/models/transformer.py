@@ -1233,6 +1233,65 @@ def head_loss(params, hidden, tokens, cfg):
                         tied=cfg.tied_embeddings)
 
 
+@jax.custom_vjp
+def _update_apart(w):
+    """``w``, its gradient handed on through an ``optimization_barrier``
+    of its own: the optimizer's update of ``w`` cannot become the
+    epilogue of the matmul that makes the gradient."""
+    return w
+
+
+_update_apart.defvjp(
+    lambda w: (w, None),
+    lambda _, g: (jax.lax.optimization_barrier(g),))
+
+
+@functools.lru_cache(maxsize=None)
+def announce_updates_apart(leaves, nbytes):
+    """Once per model, by the logger ``announce_tiles`` uses: the
+    matrices of the layer stack whose gradients stand behind a barrier
+    each, and their float32 bytes."""
+    logger.info("update apart: leaves=%d bytes=%d", leaves, nbytes)
+
+
+def _updates_apart(layers, plan):
+    """The stack's weights, each MATRIX (a leaf of rank 2 once the
+    scanned axis of ``layers`` or of ``layers["period"]`` is taken off:
+    projections, FFN weights, routers, a convolution's taps; not a
+    norm's vector, not the experts' ``[X, ., .]``, whose gradients
+    Mosaic calls make) through :func:`_update_apart`, a leaf at a time
+    and before the stack reads it.  Left alone, XLA unrolls a scan of
+    one turn and runs a layer's AdamW update (seven float32 streams) as
+    the epilogue of its weight-gradient matmul, which then reads 34-54%
+    of the MXU's peak where the same product alone reads 80-90 (PERF.md
+    section 6, PR 46; ``ops/head_loss.py`` holds the head's apart the
+    same way, which is why ``embed`` and ``lm_head`` are not in this
+    rule: held apart here as well, ``lm_head`` costs ``trinity-mini``
+    1.1 GB).  A scan of several turns is left as it is: its update
+    already runs after the loop on the stacked gradient, and a barrier
+    there buys nothing and moves the program (``olmo1b`` on four chips:
+    the compiler's bytes +0.18 GB, the loop's all-reduces combined
+    otherwise)."""
+    held = []
+
+    def apart(scanned):
+        def leaf(w):
+            if w.ndim - scanned != 2 or (scanned and w.shape[0] > 1):
+                return w
+            held.append(w.size)
+            return _update_apart(w)
+
+        return functools.partial(jax.tree_util.tree_map, leaf)
+
+    if plan is None:
+        layers = apart(1)(layers)
+    else:
+        layers = {name: apart(int(name == "period"))(group)
+                  for name, group in layers.items()}
+    announce_updates_apart(len(held), 4 * sum(held))
+    return layers
+
+
 def forward_hidden(params, tokens, cfg, mesh=None, return_load=False):
     """tokens: [B, T] int32 -> (final hidden [B, T, dim] BEFORE the
     ln_f/head, mean per-layer MoE aux); with ``return_load`` (an MoE)
@@ -1268,12 +1327,12 @@ def forward_hidden(params, tokens, cfg, mesh=None, return_load=False):
         return remat(layer)
 
     plan = stack_plan(cfg)
+    layers = _updates_apart(params["layers"], plan)
     if plan is None:
-        x, aux_per_layer = jax.lax.scan(block(), x, params["layers"])
+        x, aux_per_layer = jax.lax.scan(block(), x, layers)
     else:
         with remat_keep.keeping(names if cfg.remat else ()):
-            x, aux_per_layer = _mixed_stack(x, params["layers"], cfg, plan,
-                                            block)
+            x, aux_per_layer = _mixed_stack(x, layers, cfg, plan, block)
     if with_load:
         aux_per_layer, load = aux_per_layer
         return x, aux_per_layer.mean(), load

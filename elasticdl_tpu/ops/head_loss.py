@@ -21,6 +21,10 @@ computes the final norm again in the operands of both matmuls that read
 epilogue, and the matmuls lose more than the passes saved (PERF.md
 section 6, PR 27: -1.4% ``records_per_s`` in ``olmo1b.seq2048`` without
 the barriers, +0.5% with them; +1.3% and +3.5% in ``olmoe1b7b.seq4096``).
+What the last barrier does for ``dhead`` the layer stack does for each
+of its matrices (``models/transformer._updates_apart``, PR 46); the head
+and the embedding stay this op's: held apart there as well they cost
+``trinity-mini``'s step 1.1 GB (docs/training_pipeline.md).
 """
 
 import functools

@@ -603,6 +603,59 @@ def test_head_loss_compiles_for_a_vocabulary_off_the_lanes(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 3.2e9
 
 
+def _fused_computations(text):
+    """name -> the instructions of every fused computation of a
+    compiled program's text, each cut before its metadata."""
+    bodies, body = {}, None
+    for line in text.splitlines():
+        head = re.match(r"%?(fused_computation[\w.\-]*) .*\{$", line)
+        if head:
+            body = bodies.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            body = None
+        elif body is not None:
+            body.append(line.split(", metadata=")[0].strip())
+    return bodies
+
+
+def _updates_in_matmuls(text):
+    """The fused computations that hold both a matmul and the square
+    root of AdamW's update: a weight gradient with its update as the
+    epilogue."""
+    return [name for name, body in _fused_computations(text).items()
+            if any(" convolution(" in l for l in body)
+            and any(" sqrt(" in l for l in body)]
+
+
+def _step(spec, one_chip, batch, rows, room=None):
+    """A cell's whole training step (loss, gradients, AdamW) compiled
+    for the described chip from shapes."""
+    import optax
+
+    from elasticdl_tpu.ops import batch_shard
+
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+    state = jax.eval_shape(spec.optimizer.init, params)
+    tokens = jax.ShapeDtypeStruct((batch, rows), jnp.int32,
+                                  sharding=one_chip)
+
+    def step(params, state, tokens):
+        def loss(p):
+            with batch_shard.batch_axis(None, None, room):
+                out = spec.apply_fn(p, tokens, True)
+                return spec.loss_fn(out, tokens).mean()
+
+        value, grads = jax.value_and_grad(loss)(params)
+        updates, state2 = spec.optimizer.update(grads, state, params)
+        return optax.apply_updates(params, updates), state2, value
+
+    return jax.jit(step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(state), tokens)
+
+
 def test_the_banded_stacks_step_fits_a_v5e_as_remat_keep_predicts(
         one_chip, monkeypatch):
     """The ``smallthinker-21b-a3b.seq16384`` cell's whole training step
@@ -618,21 +671,15 @@ def test_the_banded_stacks_step_fits_a_v5e_as_remat_keep_predicts(
     against the compiler's 15.28; PR 35's eleven names read 15.69
     against 14.39).  Both kinds of flash call are in the one program,
     and no forward runs twice."""
-    import optax
-
     from elasticdl_tpu.models import remat_keep as rk
     from elasticdl_tpu.ops import batch_shard, moe_dispatch
     from elasticdl_tpu.ops.mode import SWITCH
 
     monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
     spec = tfm.model_spec(**_model_params("smallthinker-21b-a3b"))
-    on_chip = lambda tree: jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        tree)
     params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
     state = jax.eval_shape(spec.optimizer.init, params)
     rows = 16384
-    tokens = jax.ShapeDtypeStruct((1, rows), jnp.int32, sharding=one_chip)
     nbytes = lambda tree: sum(
         a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
     assert nbytes(params) == 4 * 656529920          # 656.5 M parameters
@@ -645,18 +692,7 @@ def test_the_banded_stacks_step_fits_a_v5e_as_remat_keep_predicts(
         moe_dispatch.KEEP_ROWS}, names
     assert kept <= budget and peak <= (1 - rk.RESERVE) * limit
 
-    def step(params, state, tokens):
-        def loss(p):
-            with batch_shard.batch_axis(None, None, room):
-                out = spec.apply_fn(p, tokens, True)
-                return spec.loss_fn(out, tokens).mean()
-
-        value, grads = jax.value_and_grad(loss)(params)
-        updates, state2 = spec.optimizer.update(grads, state, params)
-        return optax.apply_updates(params, updates), state2, value
-
-    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
-        on_chip(params), on_chip(state), tokens).compile()
+    compiled = _step(spec, one_chip, 1, rows, room).compile()
     stats = compiled.memory_analysis()
     counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
     assert counted < peak and peak - counted < 0.5e9, (peak, counted)
@@ -696,38 +732,21 @@ def test_the_mixed_stacks_step_fits_a_v5e_as_remat_keep_predicts(
     where no gradient of the stack exists yet (1.8 GB counted) and the
     dispatch's temporaries and the tied head's cotangent (2.8 GB) stand
     where the estimate has the dense layer's 1.54: PERF.md section 7."""
-    import optax
-
     from elasticdl_tpu.models import remat_keep as rk
     from elasticdl_tpu.ops import batch_shard, moe_dispatch, short_conv
     from elasticdl_tpu.ops.mode import SWITCH
 
     monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
     spec = tfm.model_spec(**_model_params("lfm2-24b-a2b"))
-    on_chip = lambda tree: jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        tree)
     params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
     state = jax.eval_shape(spec.optimizer.init, params)
-    tokens = jax.ShapeDtypeStruct((4, 8192), jnp.int32, sharding=one_chip)
     nbytes = lambda tree: sum(
         a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
     limit = 16911433728           # a v5e's bytes_limit (chip run, PR 29)
     held = 2 * nbytes(params) + nbytes(state)
 
     def compiled(room):
-        def step(params, state, tokens):
-            def loss(p):
-                with batch_shard.batch_axis(None, None, room):
-                    out = spec.apply_fn(p, tokens, True)
-                    return spec.loss_fn(out, tokens).mean()
-
-            value, grads = jax.value_and_grad(loss)(params)
-            updates, state2 = spec.optimizer.update(grads, state, params)
-            return optax.apply_updates(params, updates), state2, value
-
-        stats = jax.jit(step, donate_argnums=(0, 1)).lower(
-            on_chip(params), on_chip(state), tokens).compile(
+        stats = _step(spec, one_chip, 4, 8192, room).compile(
         ).memory_analysis()
         return stats.argument_size_in_bytes + stats.temp_size_in_bytes
 
@@ -833,50 +852,98 @@ def test_the_delta_stacks_step_fits_a_v5e_with_nothing_kept(
     layer at 15 of 30 heads, a SwiGLU of 11,008 in each, an untied head
     over 12,544 ids, AdamW; 766.2 M parameters) through the TPU's
     compiler with nothing kept: the configuration's condition for its
-    two-way head share (12.88 GB of the 16.91), so the three-way
-    fallback was not taken; ``remat_keep``'s estimate is over the
-    compiler's count, as in the other cells whose gradients the trainer
-    counts whole (+2.04 GB; ``lfm2-24b-a2b`` +2.01).  The scan runs once
-    forward and once again in each delta layer's backward, and the
-    convolution with it."""
-    import optax
+    two-way head share (12.77 GB of the 16.91; 13.00 until PR 46), so
+    the three-way fallback was not taken; ``remat_keep``'s estimate is
+    over the compiler's count, as in the other cells whose gradients
+    the trainer counts whole (+2.15 GB; ``lfm2-24b-a2b`` +2.01).  The
+    scan runs once forward and once again in each delta layer's
+    backward, and the convolution with it.
 
+    Since PR 46 (``models/transformer._updates_apart``) no matmul
+    carries an AdamW update as its epilogue (27 did): each of the twelve
+    MLP weight gradients is a fusion that writes its float32 matrix
+    from the convolution through a convert alone, and the update is a
+    pass of its own behind it."""
     from elasticdl_tpu.models import remat_keep as rk
     from elasticdl_tpu.ops.mode import SWITCH
 
     monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
     spec = tfm.model_spec(**_model_params("olmo-hybrid-7b"))
-    on_chip = lambda tree: jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        tree)
     params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
     state = jax.eval_shape(spec.optimizer.init, params)
-    tokens = jax.ShapeDtypeStruct((1, 16384), jnp.int32, sharding=one_chip)
     nbytes = lambda tree: sum(
         a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
     assert nbytes(params) == 4 * 766241946
     held = 2 * nbytes(params) + nbytes(state)
 
-    def step(params, state, tokens):
-        def loss(p):
-            out = spec.apply_fn(p, tokens, True)
-            return spec.loss_fn(out, tokens).mean()
-
-        value, grads = jax.value_and_grad(loss)(params)
-        updates, state2 = spec.optimizer.update(grads, state, params)
-        return optax.apply_updates(params, updates), state2, value
-
-    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
-        on_chip(params), on_chip(state), tokens).compile()
+    compiled = _step(spec, one_chip, 1, 16384).compile()
     stats = compiled.memory_analysis()
     counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
     assert counted < 0.95 * 16911433728
+    # a barrier a leaf keeps no gradient waiting: the parent's 13.00 GB
+    assert counted < 12.998e9 + 0.1e9, counted
     estimate = held + rk.step_bytes(spec.config, params, 16384)
     assert -0.1e9 < estimate - counted < 2.3e9, (estimate, counted)
-    names = [c.split(" = ")[0].lstrip("%") for c in _mosaic_calls(
-        compiled.as_text())]
+    text = compiled.as_text()
+    names = [c.split(" = ")[0].lstrip("%") for c in _mosaic_calls(text)]
     count = lambda name: len([c for c in names if re.search(
         r"(^|_)" + name + r"(__)?\.\d+$", c)])
     assert (count("gdn_fwd"), count("gdn_bwd")) == (6, 3), names
     assert (count("sconv_silu_fwd"), count("sconv_silu_bwd")) == (6, 3)
     assert (count("flash_fwd"), count("flash_bwd")) == (2, 1), names
+    assert not _updates_in_matmuls(text)
+    mlp_grads = [body for body in _fused_computations(text).values()
+                 if re.search(r"ROOT \S+ = f32\[1,(3840,11008|11008,3840)\]",
+                              body[-1])
+                 and any(" convolution(" in l for l in body)]
+    assert len(mlp_grads) == 12
+    for body in mlp_grads:
+        product = next(i for i, l in enumerate(body) if " convolution(" in l)
+        assert [re.search(r" (\w+)\(", l).group(1)
+                for l in body[product + 1:]] == ["convert", "bitcast"], body
+
+
+def test_the_gated_blocks_step_holds_no_update_in_a_matmul_nor_more_bytes(
+        one_chip, monkeypatch):
+    """The ``trinity-mini.seq16384`` cell's whole training step with the
+    names ``remat_keep`` chose, for a described v5e: no weight-gradient
+    matmul carries an AdamW update (39 did until PR 46) and the
+    compiler's bytes are the parent's 14.69 GB within 0.1 (14.72): the
+    guard against holding ``embed`` and ``lm_head`` apart as well, which
+    reads 15.82."""
+    from elasticdl_tpu.ops import batch_shard
+    from elasticdl_tpu.ops.mode import SWITCH
+
+    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
+    spec = tfm.model_spec(**_model_params("trinity-mini"))
+    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+    nbytes = lambda tree: sum(
+        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
+    limit = 16911433728           # a v5e's bytes_limit (chip run, PR 29)
+    held = 2 * nbytes(params) + nbytes(
+        jax.eval_shape(spec.optimizer.init, params))
+    compiled = _step(spec, one_chip, 1, 16384,
+                     batch_shard.DeviceRoom(limit, limit - held)).compile()
+    stats = compiled.memory_analysis()
+    counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
+    assert abs(counted - 14.693e9) < 0.1e9, counted
+    assert not _updates_in_matmuls(compiled.as_text())
+
+
+def test_a_scan_of_several_turns_is_handed_to_the_compiler_as_it_was(
+        one_chip, monkeypatch):
+    """``olmo1b.seq2048``'s step as it is handed to the compiler: the
+    stack is a scan of seven turns, whose update already runs after the
+    loop on the stacked gradient, so ``_updates_apart`` holds none of
+    its leaves and the program's ``opt-barrier`` are what they were,
+    the head's three and ``jax.checkpoint``'s own in the backward
+    loop's body (with the seven stacked gradients held as well the
+    loops' bodies were the parent's too, but for four chips the
+    compiler's bytes read 17.29 GB for 17.11 and the loop's all-reduces
+    combined otherwise: PERF.md section 6, PR 46)."""
+    from elasticdl_tpu.ops.mode import SWITCH
+
+    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
+    spec = tfm.model_spec(**_model_params("olmo1b"))
+    text = _step(spec, one_chip, 8, 2048).as_text(dialect="hlo")
+    assert text.count(" while(") == 2 and text.count(" opt-barrier(") == 3 + 1

@@ -505,7 +505,17 @@ def test_with_the_three_fields_off_tree_and_program_are_the_parents(name):
     at PR 42 (q, k, v written head-major by the projections, RoPE by
     its permutation product, ``wo`` over (head, width): ``dense`` 44,604
     -> 42,783 characters); the latent stack's, which ran none of the
-    changed code, are the record's as they were, and the trees are."""
+    changed code, were the record's as they were.  The three stacks
+    whose layers differ were recorded again at PR 46, whose
+    ``_updates_apart`` hands each matrix of an unrolled stack its
+    gradient through a barrier of its own: their
+    ``optimization_barrier`` count alone moved (by 23, 20 and 7: the
+    conv and the banded stack are one period each, the latent stack's
+    period is scanned twice and left alone, so its leading layer's
+    alone) and the text grew by those equations (the conv
+    stack 289,982 -> 291,070); with the identity taken out the program
+    was the record's character for character.  ``dense`` and ``moe``,
+    scans of several turns, and the trees are the record's."""
     from tests.test_mixed_stack import _eqns
 
     was = PLAIN[name]

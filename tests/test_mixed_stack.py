@@ -470,7 +470,9 @@ def test_a_patternless_models_tree_and_program_are_what_they_were(name):
     6 ``dot_general`` more (RoPE's permutation product on q and k,
     forward and backward; ``dense``: 38 -> 44), RoPE's slices, pads,
     split and one concatenate a turn gone, two transposes fewer; the
-    tree and the seeded values are PR 29's."""
+    tree and the seeded values are PR 29's.  (PR 46's
+    ``_updates_apart`` leaves a scan of several turns, which these
+    are, as it was: the record stands.)"""
     with open(os.path.join(HERE, "patternless_program.json")) as fh:
         was = json.load(fh)[name]
     spec = tfm.model_spec(vocab_size=128, dim=64, num_heads=4,
@@ -488,6 +490,81 @@ def test_a_patternless_models_tree_and_program_are_what_they_were(name):
     total = float(sum(jnp.abs(a).sum()
                       for a in jax.tree_util.tree_leaves(params)))
     assert total == pytest.approx(was["sum"], rel=1e-6)
+
+
+def _delta_stack():
+    from tests import test_delta_stack
+
+    return test_delta_stack._spec()
+
+
+def _scanned(layers):
+    return lambda: tfm.model_spec(
+        vocab_size=128, dim=64, num_heads=4, num_layers=layers, seq_len=32,
+        dtype="float32", **PATTERNLESS["moe"])
+
+
+# name -> (the model, how many of its stack's leaves are held apart)
+APART = {
+    # a scan of one turn: wq, wk, wv, wo and a router; all 8 experts
+    # held, [1, 8, ., .]; an untied head
+    "scanned_once": (_scanned(1), 5),
+    # the same stack scanned three times: left as it was
+    "scanned": (_scanned(3), 0),
+    # ddda, a period of four scanned once: 3 x (w_qkv, delta_conv, w_a,
+    # w_b, w_out_gate, wo) + wq, wk, wv, wo + 4 x 3 of a SwiGLU
+    "one_period": (_delta_stack, 34),
+    # cc | accc accc | ac: 2 dense conv layers of 3 + 3; the period,
+    # scanned twice, left as it was; an attention layer's wq, wk, wv,
+    # wo and a conv layer's three with a router each
+    "lead_period_tail": (lambda: tfm.model_spec(**STACK), 21),
+}
+
+
+@pytest.mark.parametrize("name", sorted(APART))
+def test_every_matrix_of_an_unrolled_stack_hands_its_gradient_through_a_barrier(
+        monkeypatch, name):
+    """``_updates_apart``: the gradient is bit for bit the gradient of
+    the same model with the identity taken out, and the gradient's
+    program holds one ``optimization_barrier`` more a matrix of the
+    stack (a leaf of rank 2 once a scanned axis of one turn is taken
+    off), on that leaf alone, at the program's top level and in no
+    scan's body: none for
+    ``embed``, ``lm_head``, a norm, a vector, an expert stack or a
+    leaf scanned over several turns."""
+    make, matrices = APART[name]
+    spec = make()
+    params = jax.jit(spec.init_fn)(jax.random.PRNGKey(0))
+    tokens = _tokens(spec)
+
+    def run():
+        """(the gradient, its program's top-level barriers by their
+        operands' shapes, the barriers of every nested jaxpr too)."""
+        grads = jax.grad(_loss(spec, tokens))(params)
+        jaxpr = jax.make_jaxpr(jax.grad(_loss(spec, tokens)))(params).jaxpr
+        barrier = lambda e: e.primitive.name == "optimization_barrier"
+        return grads, collections.Counter(
+            tuple(v.aval.shape for v in e.invars)
+            for e in jaxpr.eqns if barrier(e)), sum(
+                map(barrier, _eqns(jaxpr)))
+
+    grads, barriers, everywhere = run()
+    monkeypatch.setattr(tfm, "_update_apart", lambda w: w)
+    plain_grads, plain_barriers, plain_everywhere = run()
+    jax.tree_util.tree_map(np.testing.assert_array_equal, grads,
+                           plain_grads)
+    assert not plain_barriers - barriers
+    plan = tfm.stack_plan(spec.config)
+    layers = params["layers"]
+    scanned = {"period": layers["period"]} if plan else layers
+    whole = {k: layers[k] for k in ("lead", "tail")} if plan else {}
+    shapes = lambda tree, rank: [
+        a.shape for a in jax.tree_util.tree_leaves(tree) if a.ndim == rank]
+    wanted = collections.Counter(
+        (shape,) for shape in [s for s in shapes(scanned, 3) if s[0] == 1]
+        + shapes(whole, 2))
+    assert barriers - plain_barriers == wanted
+    assert everywhere - plain_everywhere == sum(wanted.values()) == matrices
 
 
 @pytest.mark.parametrize("what", ["prefill", "decode_step", "generate",
