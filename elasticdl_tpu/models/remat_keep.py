@@ -53,7 +53,8 @@ KEEP_SHARED_GATE, KEEP_SHARED_UP = "shared_gate", "shared_up"
 # what the convolution's backward reads, the second what the scan's
 # does, behind an L2 norm), the log decays and write strengths, [rows,
 # heads] float32 each, and the output gate's projection.  The scan's
-# own two are ``gated_delta.KEEP_OUT`` and ``KEEP_STATES``.
+# own three are ``gated_delta.KEEP_OUT``, ``KEEP_STATES`` and
+# ``KEEP_INVERSE``.
 KEEP_DELTA_IN, KEEP_DELTA_QKV = "delta_in", "delta_qkv"
 KEEP_DELTA_DECAY, KEEP_DELTA_GATE = "delta_decay", "delta_gate"
 
@@ -142,17 +143,21 @@ def _entries(cfg, rows):
     ]
     if delta:
         # the decays are [rows, heads] and save two products that read
-        # the whole stream; the scan's output with its chunk-start
-        # states (float32, a state's rows 128-lane tiles in HBM) saves
-        # ``gdn_fwd``, 6.7 ms of a layer's second forward for 0.47 GB
-        # (PERF.md section 5, PR 44); the gate's projection is made as
-        # q is; the projection of q, k, v as a convolution's input is;
-        # the convolved projection a pass bound by memory
+        # the whole stream; the gate's projection is made as q is; the
+        # scan's output with its chunk-start states (float32, a state's
+        # rows 128-lane tiles in HBM) and its chunks' inverses (the
+        # compute dtype, two side by side) saves ``gdn_fwd``, 5.4 ms of
+        # a layer's second forward for 0.50 GB (PERF.md section 5, PR
+        # 48; 6.7 for 0.47 until then: 14); the projection of q, k, v
+        # as a convolution's input is; the convolved projection a pass
+        # bound by memory
         states = rows // gated_delta.CHUNK * h * d_k * _lanes(d_v) * 4
         rest += [
             (100, "delta_decay", (KEEP_DELTA_DECAY,), rows * h * 8, delta),
-            (14, "delta", (gated_delta.KEEP_OUT, gated_delta.KEEP_STATES),
-             rows * h * d_v * size + states, delta),
+            (11, "delta", (gated_delta.KEEP_OUT, gated_delta.KEEP_STATES,
+                           gated_delta.KEEP_INVERSE),
+             rows * h * d_v * size + states
+             + gated_delta.inverse_bytes(rows, h, size), delta),
             (13, "delta_gate", (KEEP_DELTA_GATE,), rows * h * d_v * size,
              delta),
             (11, "delta_in", (KEEP_DELTA_IN,),

@@ -1059,17 +1059,22 @@ def _conv_mix(h, w, cfg):
 
 
 @functools.lru_cache(maxsize=None)
-def announce_delta(cfg, rows, chunk, kept, mode, why):
+def announce_delta(cfg, rows, chunk, kept, inverse, mode, why):
     """Once per compiled shape, by the logger ``announce_tiles`` uses:
     the gated delta rule one shard of the data axis scans over ``rows``
-    tokens, and whether the chunk-start states its backward reads are
+    tokens, whether the chunk-start states its backward reads are
     ``kept`` from the forward or made again by the second forward of a
-    rematerialized layer."""
+    rematerialized layer, and how the backward gets a chunk's inverse:
+    from the forward kernel, ``inverse`` bytes a layer, or by
+    differentiating the jnp twin."""
     logger.info(
         "delta scan: rows=%d heads=%d key_dim=%d value_dim=%d chunk=%d "
-        "conv_taps=%d neg_eigval=%d states=%s %s%s", rows, cfg.num_heads,
-        cfg.delta_key_dim, cfg.delta_value_dim, chunk, cfg.conv_kernel,
-        cfg.delta_neg_eigval, "kept" if kept else "recomputed",
+        "conv_taps=%d neg_eigval=%d states=%s inverse=%s %s%s", rows,
+        cfg.num_heads, cfg.delta_key_dim, cfg.delta_value_dim, chunk,
+        cfg.conv_kernel, cfg.delta_neg_eigval,
+        "kept" if kept else "recomputed",
+        "twin" if mode == "off" else "forward inverse_mb=%.1f" % (
+            inverse / 1e6),
         {"tpu": "kernel", "interpret": "interpreter",
          "off": "reference"}[mode], " (%s)" % why if why else "")
 
@@ -1096,9 +1101,13 @@ def _delta_mix(h, w, cfg):
     B, T, _ = h.shape
     H, dk, dv = cfg.num_heads, cfg.delta_key_dim, cfg.delta_value_dim
     mode, why = gated_delta.delta_mode(T, dk, dv)
-    announce_delta(cfg, B * T // batch_shard.shards(), gated_delta.CHUNK,
+    rows = B * T // batch_shard.shards()
+    announce_delta(cfg, rows, gated_delta.CHUNK,
                    not cfg.remat or remat_keep.keeps(
-                       gated_delta.KEEP_STATES), mode, why)
+                       gated_delta.KEEP_STATES),
+                   gated_delta.inverse_bytes(
+                       rows, H, compute_dtype.itemsize,
+                       gated_delta.pack_of(T)), mode, why)
     qkv = checkpoint_name(h @ w["w_qkv"].astype(compute_dtype),
                           remat_keep.KEEP_DELTA_IN)
     qkv = checkpoint_name(short_conv.conv_silu(qkv, w["delta_conv"]),

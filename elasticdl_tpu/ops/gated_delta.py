@@ -28,29 +28,40 @@ the chunk's first token and ``gamma = exp(b)``:
 is a difference ``b_r - b_i`` with i <= r, never positive: nothing
 overflows however fast a head forgets.
 
-Two kernels, one grid step a (block of heads, chunk), the chunk axis
-sequential:
+``A`` reads k, ``b`` and beta and never the state: a chunk's inverse is
+made ONCE a forward call, beside the chain of matmuls that carries the
+state and not in it, and for P chunks a run of the joins (``PACKS``: two
+of 64 on the diagonal blocks of one [128, 128] operand, the MXU's width;
+``_inverses``).  What reads the state is ``_chunk``, what both kernels
+need of a chunk before it ``_decays``.
+
+Two kernels, one grid step a (block of heads, pack of P chunks), the
+pack axis sequential:
 
  - ``gdn_fwd``: the state of each head of the block resident in VMEM in
-   float32 across the chunk axis; q, k, v stream through a chunk at a
-   time; writes ``o`` and each chunk's START state (float32, [B * H, T /
-   C, d_k, d_v]: what the backward reads, 283 MB a layer at 15 heads x
-   16,384 x 96 | 192);
+   float32 across the pack axis; q, k, v stream through a pack at a
+   time; a step builds its heads' inverses, then walks its chunks.
+   Writes ``o``, each chunk's START state (float32, [B * H, T / C, d_k,
+   d_v]: 283 MB a layer at 15 heads x 16,384 x 96 | 192) and, third,
+   the inverses in the compute dtype, the one both kernels multiply by
+   ([B * H, T / (P C), C, P C], a pack's side by side: 31.5 MB a layer);
  - ``gdn_bwd``: the same grid walked from the last chunk to the first,
-   the state's cotangent resident; rebuilds the chunk's A, inverse, U
-   and scores from q, k, v and the saved start state, and writes dq, dk,
-   dv and the cotangents of ``b`` and ``beta``.
+   the state's cotangent resident; takes the start states and the
+   inverses as operands, rebuilds the chunk's U and scores from them and
+   q, k, v, and writes dq, dk, dv and the cotangents of ``b`` and
+   ``beta``.  No product of it runs at the highest precision.
 
 Float32 whatever the compute dtype: the cumulative sums of ``g``, the
-decays, ``A`` and its inverse (matmuls at the highest precision:
-Mosaic's default contracts float32 operands in bfloat16), the state and
-its cotangent.  The other matmuls take their operands in the compute
-dtype (q's) and accumulate in float32; what leaves a kernel [T, .]-sized
-is in the compute dtype.
+decays, ``A`` and the joins of its inverse (matmuls at the highest
+precision: Mosaic's default contracts float32 operands in bfloat16), the
+state and its cotangent.  The other matmuls take their operands in the
+compute dtype (q's) and accumulate in float32, the product with the
+inverse among them; what leaves a kernel [T, .]-sized is in the compute
+dtype.
 
-The forward's two results carry names for a remat policy
-(models/remat_keep.py): with both kept the backward of a rematerialized
-layer does not run ``gdn_fwd`` a second time.
+The forward's three results carry names for a remat policy
+(models/remat_keep.py): with all three kept the backward of a
+rematerialized layer does not run ``gdn_fwd`` a second time.
 
 Reference: ``gated_delta_ref``, the same chunk form in plain
 ``jax.numpy`` (float32 inside), differentiated by JAX: what
@@ -61,6 +72,7 @@ benchmark reference's, not this file's.
 """
 
 import functools
+import itertools
 import types
 
 import jax
@@ -75,14 +87,20 @@ from elasticdl_tpu.ops.batch_shard import per_batch_shard
 from elasticdl_tpu.ops.mode import resolve
 
 # ``checkpoint_name``s of the forward's results that the backward reads
-# or the layer goes on with: the output and the chunk-start states.
-KEEP_OUT, KEEP_STATES = "gdn_out", "gdn_states"
+# or the layer goes on with: the output, the chunk-start states and the
+# chunks' inverses.
+KEEP_OUT, KEEP_STATES, KEEP_INVERSE = "gdn_out", "gdn_states", "gdn_inverse"
 
 CHUNK = 64
 # Heads a grid step runs, the largest that divides the heads: their
 # chains of dependent matmuls are independent of each other, so the
 # scheduler has several to interleave.
 HEAD_BLOCKS = (5, 4, 3, 2, 1)
+# Chunks a grid step walks behind one build of their inverses, the
+# largest that divides the chunks and stays inside the MXU's width: two
+# of 64 fill its 128.
+PACKS = (2, 1)
+MXU = 128
 VMEM_LIMIT = 64 * 1024 * 1024
 
 _F32 = jnp.float32
@@ -95,7 +113,21 @@ def _dot(a, b, dims=_NN):
     return lax.dot_general(a, b, dims, preferred_element_type=_F32)
 
 
-def _inverse(a):
+def pack_of(seq, chunk=CHUNK):
+    """Chunks of a sequence of ``seq`` a grid step of the kernels walks."""
+    return next(n for n in PACKS if n == 1 or (
+        seq // chunk % n == 0 and n * chunk <= MXU))
+
+
+def inverse_bytes(rows, heads, size, pack=PACKS[0], chunk=CHUNK):
+    """HBM bytes of the inverses ``gdn_fwd`` writes for ``rows`` tokens
+    of ``heads`` heads in a dtype of ``size`` bytes, ``pack`` chunks'
+    side by side (a row of them whole 128-lane tiles)."""
+    edge = pack * chunk
+    return rows // edge * heads * chunk * -(-edge // 128) * 128 * size
+
+
+def _inverse(a, chunk=None):
     """(I + a)^-1 of strictly lower triangular ``a`` [.., C, C] float32,
     by matmuls alone, at the highest precision: block forward
     substitution with masks in place of slices.  A pair of tokens is
@@ -106,12 +138,19 @@ def _inverse(a):
     matmuls and is not stable: with keys that share a direction (a
     SiLU's outputs do) its terms grow as binomial(C, n) |a|^n before
     they cancel, past float32 at C = 64 (a NaN in the test's draw; 1e-2
-    over blocks of 16, 1e-5 over 8)."""
-    chunk = a.shape[-1]
+    over blocks of 16, 1e-5 over 8).
+
+    With ``chunk`` below ``a``'s edge, ``a`` holds several chunks'
+    matrices on its diagonal blocks of that edge and zeros elsewhere:
+    the joins stop at the chunk's edge, each of them the same products
+    with zeros added, and the result holds the chunks' inverses where
+    ``a`` held their matrices."""
+    edge = a.shape[-1]
+    chunk = chunk or edge
     mm = functools.partial(jnp.matmul, precision=lax.Precision.HIGHEST,
                            preferred_element_type=_F32)
-    row = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    col = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    row = lax.broadcasted_iota(jnp.int32, (edge, edge), 0)
+    col = lax.broadcasted_iota(jnp.int32, (edge, edge), 1)
     # whether an entry lies in a diagonal block of edge 2 ** bits
     within = lambda bits: (row >> bits) == (col >> bits)
     inv = (row == col).astype(_F32) - jnp.where(within(1), a, 0.0)
@@ -191,127 +230,179 @@ def _row(col, eye):
     return jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
 
 
-def _chunk(q, k, v, b, beta, h):
-    """What both kernels build of a chunk: q, k [C, d_k] and v [C, d_v]
-    in the compute dtype, b and beta [1, C] float32, h [d_k, d_v]
-    float32 (the start state)."""
-    dtype = q.dtype
-    eye, lower, strict = _masks(q.shape[0])
+def _decays(k, b, beta):
+    """What a chunk is before its start state enters, in both kernels:
+    k [C, d_k] in the compute dtype, b and beta [1, C] float32."""
+    dtype = k.dtype
+    eye, lower, strict = _masks(k.shape[0])
     b_col, beta_col = _col(b, eye), _col(beta, eye)
     decay = jnp.where(
         lower, jnp.exp(jnp.where(lower, b_col - b, 0.0)), 0.0)
-    kk = _dot(k, k, _NT)
-    inv = _inverse(jnp.where(strict, kk * decay, 0.0) * beta_col)
     gamma = jnp.exp(b_col)
     # b at the chunk's last token, [1, 1]: a masked lane sum (a slice of
     # the last lane is a layout Mosaic does not broadcast from)
     lane = lax.broadcasted_iota(jnp.int32, b.shape, 1)
     last = jnp.sum(jnp.where(lane == b.shape[1] - 1, b, 0.0), axis=1,
                    keepdims=True)
-    kf, hc = k.astype(_F32), h.astype(dtype)
-    kb = (kf * (beta_col * gamma)).astype(dtype)
-    r = v.astype(_F32) * beta_col - _dot(kb, hc)
-    u = _dot(inv.astype(dtype), r.astype(dtype)).astype(dtype)
-    qk = _dot(q, k, _NT)
-    scores = jnp.where(lower, qk * decay, 0.0).astype(dtype)
+    kf = k.astype(_F32)
     to_end = jnp.exp(last - b_col)
     return types.SimpleNamespace(
-        eye=eye, lower=lower, strict=strict, beta_col=beta_col,
-        decay=decay, kk=kk, inv=inv, gamma=gamma, carry=jnp.exp(last),
-        kf=kf, hc=hc, kb=kb, u=u, qk=qk, scores=scores, to_end=to_end,
-        kd=(kf * to_end).astype(dtype),
-        qg=(q.astype(_F32) * gamma).astype(dtype))
+        eye=eye, lower=lower, strict=strict, b_col=b_col,
+        beta_col=beta_col, decay=decay, gamma=gamma, carry=jnp.exp(last),
+        kf=kf, kb=(kf * (beta_col * gamma)).astype(dtype), to_end=to_end,
+        kd=(kf * to_end).astype(dtype))
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, gates_ref, o_ref, states_ref, h_scr,
-                *, heads):
+def _inverses(k, decays, chunk):
+    """``(I + A)^-1`` of the P chunks of k [P * C, d_k] (``decays``:
+    each one's ``_decays``) by ONE run of ``_inverse``'s joins, over a
+    [P * C, P * C] operand with the chunks' ``A`` on its diagonal
+    blocks: at P * C = 128 a product fills the MXU where a chunk's own
+    fills a quarter.  Returns them side by side, [C, P * C] in the
+    compute dtype (chunk p's in columns p C ..): the diagonal blocks'
+    rows added, every other block an exact zero.  Nothing here reads the
+    state: the joins, the one long chain of a grid step, run beside the
+    chain that carries it and not in it."""
+    edge = k.shape[0]
+    stack = lambda name: jnp.concatenate(
+        [getattr(d, name) for d in decays], axis=0)
+    b_col, beta_col = stack("b_col"), stack("beta_col")
+    row = lax.broadcasted_iota(jnp.int32, (edge, edge), 0)
+    col = lax.broadcasted_iota(jnp.int32, (edge, edge), 1)
+    # strictly lower, inside a chunk's own block
+    inside = (row > col) & (row // chunk == col // chunk)
+    decay = jnp.where(inside, jnp.exp(jnp.where(
+        inside, b_col - _row(b_col, row == col), 0.0)), 0.0)
+    inv = _inverse(_dot(k, k, _NT) * decay * beta_col, chunk)
+    blocks = [inv[p * chunk:(p + 1) * chunk] for p in range(edge // chunk)]
+    return sum(blocks[1:], blocks[0]).astype(k.dtype)
+
+
+def _chunk(q, k, v, d, inv, at, h):
+    """What both kernels build of a chunk once its start state h [d_k,
+    d_v] float32 is there: q, k [C, d_k] and v [C, d_v] in the compute
+    dtype, ``d`` the chunk's ``_decays``, ``inv`` its pack's inverses
+    [C, P * C] with this chunk's the ``at``-th."""
+    dtype = q.dtype
+    hc = h.astype(dtype)
+    r = (v.astype(_F32) * d.beta_col - _dot(d.kb, hc)).astype(dtype)
+    # inv_at r: rows of zeros meet the other chunks' inverses
+    u = _dot(inv, jnp.concatenate(
+        [r if p == at else jnp.zeros_like(r)
+         for p in range(inv.shape[1] // inv.shape[0])], axis=0)).astype(dtype)
+    qk = _dot(q, k, _NT)
+    return types.SimpleNamespace(
+        hc=hc, u=u, qk=qk,
+        scores=jnp.where(d.lower, qk * d.decay, 0.0).astype(dtype),
+        qg=(q.astype(_F32) * d.gamma).astype(dtype))
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, gates_ref, o_ref, states_ref, inv_ref,
+                h_scr, *, heads, chunk):
     @pl.when(pl.program_id(1) == 0)
     def _():
         h_scr[...] = jnp.zeros_like(h_scr)
 
+    pack = q_ref.shape[1] // chunk
+    rows = [slice(p * chunk, (p + 1) * chunk) for p in range(pack)]
     for i in range(heads):
+        decays = [_decays(k_ref[i, rows[p]], gates_ref[i, p, 0:1, :],
+                          gates_ref[i, p, 1:2, :]) for p in range(pack)]
+        inv = _inverses(k_ref[i], decays, chunk)
+        inv_ref[i] = inv
         h = h_scr[i]
-        states_ref[i] = h
-        c = _chunk(q_ref[i], k_ref[i], v_ref[i], gates_ref[i, 0:1, :],
-                   gates_ref[i, 1:2, :], h)
-        o = _dot(c.qg, c.hc) + _dot(c.scores, c.u)
-        o_ref[i] = o.astype(o_ref.dtype)
-        h_scr[i] = c.carry * h + _dot(c.kd, c.u, _TN)
+        for p, d in enumerate(decays):
+            states_ref[i, p] = h
+            c = _chunk(q_ref[i, rows[p]], k_ref[i, rows[p]],
+                       v_ref[i, rows[p]], d, inv, p, h)
+            o = _dot(c.qg, c.hc) + _dot(c.scores, c.u)
+            o_ref[i, rows[p]] = o.astype(o_ref.dtype)
+            h = d.carry * h + _dot(d.kd, c.u, _TN)
+        h_scr[i] = h
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, gates_ref, states_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, dgates_ref, dh_scr, *, heads):
+def _bwd_kernel(q_ref, k_ref, v_ref, gates_ref, states_ref, inv_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dgates_ref, dh_scr, *, heads, chunk):
     @pl.when(pl.program_id(1) == 0)
     def _():
         dh_scr[...] = jnp.zeros_like(dh_scr)
 
-    for i in range(heads):
-        q, k, v, do = q_ref[i], k_ref[i], v_ref[i], do_ref[i]
+    pack = q_ref.shape[1] // chunk
+    for i, p in itertools.product(range(heads), reversed(range(pack))):
+        rows = slice(p * chunk, (p + 1) * chunk)
+        q, k, v, do = (ref[i, rows] for ref in (q_ref, k_ref, v_ref, do_ref))
         dtype = q.dtype
-        h, dh = states_ref[i], dh_scr[i]
-        c = _chunk(q, k, v, gates_ref[i, 0:1, :], gates_ref[i, 1:2, :], h)
-        eye, lower, strict = c.eye, c.lower, c.strict
-        decay, beta_col, gamma = c.decay, c.beta_col, c.gamma
-        u, hc, kf = c.u, c.hc, c.kf
+        h, dh, inv = states_ref[i, p], dh_scr[i], inv_ref[i]
+        d = _decays(k, gates_ref[i, p, 0:1, :], gates_ref[i, p, 1:2, :])
+        c = _chunk(q, k, v, d, inv, p, h)
+        eye, lower, strict = d.eye, d.lower, d.strict
+        decay, beta_col, gamma = d.decay, d.beta_col, d.gamma
+        u, hc, kf = c.u, c.hc, d.kf
         cast = lambda x: x.astype(dtype)
         rowsum = lambda x: jnp.sum(x, axis=1, keepdims=True)
         dhc, dof = cast(dh), do.astype(_F32)
 
         # O = gamma Q h + scores U;  h' = gamma_C h + kd^T U
-        du = _dot(c.scores, do, _TN) + _dot(c.kd, dhc)
+        du = _dot(c.scores, do, _TN) + _dot(d.kd, dhc)
         dscores = jnp.where(lower, _dot(do, u, _NT), 0.0)
         dqk = cast(dscores * decay)
         dq = gamma * _dot(do, hc, _NT) + _dot(dqk, k)
         dk = _dot(dqk, q, _TN)
         dkd = _dot(u, dhc, _NT)
         dgamma = rowsum(_dot(q, hc) * dof)
-        # U = inv R, R = beta V - kb h;  d(inv) = -inv^T . inv^T
-        dr = _dot(cast(c.inv), cast(du), _TN)
+        # U = inv R, R = beta V - kb h;  d(inv) = -inv^T . inv^T (the
+        # pack's inverses side by side: this chunk's rows of the product)
+        dr = _dot(inv, cast(du), _TN)[rows]
         drc = cast(dr)
         da = jnp.where(strict, -_dot(drc, u, _NT), 0.0)
         dkb = -_dot(drc, hc, _NT)
-        dh_scr[i] = (c.carry * dh + _dot(c.qg, do, _TN)
-                     - _dot(c.kb, drc, _TN))
-        dv_ref[i] = (beta_col * dr).astype(dv_ref.dtype)
+        dh_scr[i] = (d.carry * dh + _dot(c.qg, do, _TN)
+                     - _dot(d.kb, drc, _TN))
+        dv_ref[i, rows] = (beta_col * dr).astype(dv_ref.dtype)
         dbeta = rowsum(dr * v.astype(_F32))
         both = rowsum(dkb * kf)               # d(beta gamma)
         dk += beta_col * gamma * dkb
         dbeta += gamma * both
         dgamma += beta_col * both
         # A = beta (K K^T * decay), strictly lower
-        dbeta += rowsum(da * c.kk * decay)
+        kk = _dot(k, k, _NT)
+        dbeta += rowsum(da * kk * decay)
         dkk = cast(beta_col * da * decay)
         dk += _dot(dkk, k) + _dot(dkk, k, _TN)
         # kd = exp(b_C - b) K
-        dk += c.to_end * dkd
-        to_end = rowsum(dkd * kf) * c.to_end     # d(b_C - b_i)
-        dq_ref[i] = dq.astype(dq_ref.dtype)
-        dk_ref[i] = dk.astype(dk_ref.dtype)
+        dk += d.to_end * dkd
+        to_end = rowsum(dkd * kf) * d.to_end     # d(b_C - b_i)
+        dq_ref[i, rows] = dq.astype(dq_ref.dtype)
+        dk_ref[i, rows] = dk.astype(dk_ref.dtype)
         # decay = exp(b_r - b_i): a row's cotangents add, a column's
         # subtract (the diagonal's cancel: its exponent is 0)
-        ddiff = (dscores * c.qk + beta_col * da * c.kk) * decay
+        ddiff = (dscores * c.qk + beta_col * da * kk) * decay
         db = (_row(rowsum(ddiff) + dgamma * gamma - to_end, eye)
               - jnp.sum(ddiff, axis=0, keepdims=True))
         at_last = lax.broadcasted_iota(jnp.int32, db.shape, 1) == (
             db.shape[1] - 1)
         db += jnp.where(
-            at_last, jnp.sum(to_end) + jnp.sum(dh * h) * c.carry,
+            at_last, jnp.sum(to_end) + jnp.sum(dh * h) * d.carry,
             0.0)
-        dgates_ref[i, 0:1, :] = db
-        dgates_ref[i, 1:2, :] = _row(dbeta, eye)
+        dgates_ref[i, p, 0:1, :] = db
+        dgates_ref[i, p, 1:2, :] = _row(dbeta, eye)
 
 
-def _specs(heads, chunk, d_k, d_v, chunks, reverse):
+def _specs(heads, chunk, pack, d_k, d_v, steps, reverse):
     """BlockSpecs of a [B * H, T, d_k] plane, a [B * H, T, d_v] plane,
-    the gates [B * H, T / C, 2, C] and the states [B * H, T / C, d_k,
-    d_v] for a grid (head block, chunk), the chunks walked backwards
-    with ``reverse``."""
-    at = (lambda j: chunks - 1 - j) if reverse else (lambda j: j)
-    return (pl.BlockSpec((heads, chunk, d_k), lambda i, j: (i, at(j), 0)),
-            pl.BlockSpec((heads, chunk, d_v), lambda i, j: (i, at(j), 0)),
-            pl.BlockSpec((heads, None, 2, chunk),
+    the gates [B * H, T / C, 2, C], the states [B * H, T / C, d_k, d_v]
+    and the inverses [B * H, T / (P C), C, P C] for a grid (head block,
+    pack of P chunks), the packs walked backwards with ``reverse``."""
+    at = (lambda j: steps - 1 - j) if reverse else (lambda j: j)
+    return (pl.BlockSpec((heads, pack * chunk, d_k),
+                         lambda i, j: (i, at(j), 0)),
+            pl.BlockSpec((heads, pack * chunk, d_v),
+                         lambda i, j: (i, at(j), 0)),
+            pl.BlockSpec((heads, pack, 2, chunk),
                          lambda i, j: (i, at(j), 0, 0)),
-            pl.BlockSpec((heads, None, d_k, d_v),
+            pl.BlockSpec((heads, pack, d_k, d_v),
+                         lambda i, j: (i, at(j), 0, 0)),
+            pl.BlockSpec((heads, None, chunk, pack * chunk),
                          lambda i, j: (i, at(j), 0, 0)))
 
 
@@ -321,20 +412,28 @@ def _params():
         vmem_limit_bytes=VMEM_LIMIT)
 
 
-def _fwd_call(q, k, v, gates, chunk, heads, interpret):
+# Both calls are jitted, so that the layers of a stack and a layer's
+# second forward under remat, which trace them at the same shapes, trace
+# and lower a kernel once (a worker lowers its step at every start).
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+def _fwd_call(q, k, v, gates, chunk, heads, pack, interpret):
     """(o [B * H, T, d_v], chunk-start states [B * H, T / C, d_k, d_v]
-    float32)."""
+    float32, the chunks' inverses [B * H, T / (P C), C, P C] in q's
+    dtype, P chunks' side by side)."""
     bh, seq, d_k = q.shape
     d_v = v.shape[-1]
     chunks = seq // chunk
-    qk, vo, gate, state = _specs(heads, chunk, d_k, d_v, chunks, False)
+    qk, vo, gate, state, inverse = _specs(
+        heads, chunk, pack, d_k, d_v, chunks // pack, False)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, heads=heads),
+        functools.partial(_fwd_kernel, heads=heads, chunk=chunk),
         out_shape=(jax.ShapeDtypeStruct(v.shape, v.dtype),
-                   jax.ShapeDtypeStruct((bh, chunks, d_k, d_v), _F32)),
-        grid=(bh // heads, chunks),
+                   jax.ShapeDtypeStruct((bh, chunks, d_k, d_v), _F32),
+                   jax.ShapeDtypeStruct(
+                       (bh, chunks // pack, chunk, pack * chunk), q.dtype)),
+        grid=(bh // heads, chunks // pack),
         in_specs=[qk, qk, vo, gate],
-        out_specs=(vo, state),
+        out_specs=(vo, state, inverse),
         scratch_shapes=[pltpu.VMEM((heads, d_k, d_v), _F32)],
         compiler_params=_params(),
         interpret=interpret,
@@ -342,47 +441,62 @@ def _fwd_call(q, k, v, gates, chunk, heads, interpret):
     )(q, k, v, gates)
 
 
-def _bwd_call(q, k, v, gates, states, do, chunk, heads, interpret):
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10))
+def _bwd_call(q, k, v, gates, states, inv, do, chunk, heads, pack,
+              interpret):
     """(dq, dk, dv, dgates)."""
     bh, seq, d_k = q.shape
     d_v = v.shape[-1]
-    chunks = seq // chunk
-    qk, vo, gate, state = _specs(heads, chunk, d_k, d_v, chunks, True)
+    steps = inv.shape[1]
+    qk, vo, gate, state, inverse = _specs(
+        heads, chunk, pack, d_k, d_v, steps, True)
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, heads=heads),
+        functools.partial(_bwd_kernel, heads=heads, chunk=chunk),
         out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct(gates.shape, _F32)),
-        grid=(bh // heads, chunks),
-        in_specs=[qk, qk, vo, gate, state, vo],
+        grid=(bh // heads, steps),
+        in_specs=[qk, qk, vo, gate, state, inverse, vo],
         out_specs=(qk, qk, vo, gate),
         scratch_shapes=[pltpu.VMEM((heads, d_k, d_v), _F32)],
         compiler_params=_params(),
         interpret=interpret,
         name="gdn_bwd",
-    )(q, k, v, gates, states, do)
+    )(q, k, v, gates, states, inv, do)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _gdn(q, k, v, gates, chunk, heads, interpret):
-    return _fwd_call(q, k, v, gates, chunk, heads, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _gdn(q, k, v, gates, chunk, heads, pack, interpret):
+    return _fwd_call(q, k, v, gates, chunk, heads, pack, interpret)[0]
 
 
-def _gdn_fwd(q, k, v, gates, chunk, heads, interpret):
-    o, states = _fwd_call(q, k, v, gates, chunk, heads, interpret)
+def _gdn_fwd(q, k, v, gates, chunk, heads, pack, interpret):
+    o, states, inv = _fwd_call(q, k, v, gates, chunk, heads, pack,
+                               interpret)
     # named where they are made, as the flash forward's two: a policy
-    # that saves both does not run this forward a second time
+    # that saves all three does not run this forward a second time
     o = checkpoint_name(o, KEEP_OUT)
     states = checkpoint_name(states, KEEP_STATES)
-    return o, (q, k, v, gates, states)
+    inv = checkpoint_name(inv, KEEP_INVERSE)
+    return o, (q, k, v, gates, states, inv)
 
 
-def _gdn_bwd(chunk, heads, interpret, res, do):
-    return _bwd_call(*res, do, chunk, heads, interpret)
+def _gdn_bwd(chunk, heads, pack, interpret, res, do):
+    return _bwd_call(*res, do, chunk, heads, pack, interpret)
 
 
 _gdn.defvjp(_gdn_fwd, _gdn_bwd)
+
+
+def _gates(g, beta, chunk):
+    """g, beta [B, H, T] as the kernels take them, [B * H, T / C, 2, C]
+    float32: ``b``, the cumulative sum of g from a chunk's first token,
+    above beta."""
+    rows = lambda x: x.astype(_F32).reshape(
+        -1, x.shape[-1] // chunk, 1, chunk)
+    return jnp.concatenate(
+        [jnp.cumsum(rows(g), axis=-1), rows(beta)], axis=2)
 
 
 def _unfriendly(seq, d_k, d_v, chunk):
@@ -422,11 +536,8 @@ def gated_delta(q, k, v, g, beta, chunk=CHUNK, interpret=None):
 
     def op(q, k, v, g, beta):
         planes = lambda x: x.reshape(-1, *x.shape[2:])
-        rows = lambda x: x.astype(_F32).reshape(-1, seq // chunk, 1, chunk)
-        gates = jnp.concatenate(
-            [jnp.cumsum(rows(g), axis=-1), rows(beta)], axis=2)
-        o = _gdn(planes(q), planes(k), planes(v), gates, chunk, block,
-                 mode == "interpret")
+        o = _gdn(planes(q), planes(k), planes(v), _gates(g, beta, chunk),
+                 chunk, block, pack_of(seq, chunk), mode == "interpret")
         return o.reshape(-1, heads, seq, d_v)
 
     return per_batch_shard(op, (q, k, v, g, beta))

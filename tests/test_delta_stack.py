@@ -5,6 +5,7 @@ its sublayers' outputs alone) against the plain reference of the
 benchmark (benchmark/reference/olmo-hybrid-7b.py: the delta rule token
 by token), at the configuration's rehearsal size, float32 on the CPU."""
 
+import collections
 import functools
 import json
 import os
@@ -302,7 +303,7 @@ def test_a_delta_layer_without_its_sizes_is_refused_by_name(bad, match):
 
 # -- what is kept ------------------------------------------------------------
 
-DELTA_ROWS = ["delta_decay", "delta", "delta_gate", "delta_in", "delta_qkv"]
+DELTA_ROWS = ["delta_decay", "delta_gate", "delta", "delta_in", "delta_qkv"]
 
 
 def test_remat_keeps_table_has_the_delta_layers_rows():
@@ -319,10 +320,13 @@ def test_remat_keeps_table_has_the_delta_layers_rows():
     assert table["delta_decay"] == ((rk.KEEP_DELTA_DECAY,), rows * heads * 8)
     assert table["delta_gate"] == ((rk.KEEP_DELTA_GATE,),
                                    rows * heads * d_v * size)
-    # the output, and a float32 state a chunk a head, 128 lanes a row
+    # the output, a float32 state a chunk a head, 128 lanes a row, and
+    # two chunks' inverses side by side in the compute dtype
     assert table["delta"] == (
-        (gd.KEEP_OUT, gd.KEEP_STATES), rows * heads * d_v * size
-        + rows // gd.CHUNK * heads * d_k * 128 * 4)
+        (gd.KEEP_OUT, gd.KEEP_STATES, gd.KEEP_INVERSE),
+        rows * heads * d_v * size
+        + rows // gd.CHUNK * heads * d_k * 128 * 4
+        + rows // 128 * heads * gd.CHUNK * 128 * size)
     layers = {label: n for label, _, _, n in rk._entries(cfg, rows)}
     assert layers["delta"] == 3 and layers["flash"] == 1
     assert layers["stream"] == layers["ffn_gate"] == 4
@@ -341,12 +345,46 @@ def test_kept_names_change_no_gradient(monkeypatch, mode):
 
     everything = batch_shard.DeviceRoom(2 ** 40, 2 ** 40 - held)
     names = rk.choose(spec.config, params, tokens.size, everything)[0]
-    assert {gd.KEEP_OUT, gd.KEEP_STATES, rk.KEEP_DELTA_IN,
+    assert {gd.KEEP_OUT, gd.KEEP_STATES, gd.KEEP_INVERSE, rk.KEEP_DELTA_IN,
             rk.KEEP_DELTA_QKV, rk.KEEP_DELTA_DECAY,
             rk.KEEP_DELTA_GATE} <= set(names)
     for a, b in zip(jax.tree_util.tree_leaves(grads(everything)),
                     jax.tree_util.tree_leaves(grads(None))):
         np.testing.assert_allclose(a, b, rtol=0, atol=1e-6)
+
+
+@pytest.mark.parametrize("kept,forwards", [
+    ("everything", 3), ("nothing", 6), ("all_but_the_inverse", 6)])
+def test_a_policy_that_keeps_the_delta_row_runs_the_forward_once_a_layer(
+        monkeypatch, kept, forwards):
+    """The gradient's jaxpr of three delta layers under ``remat=true``:
+    with the ``delta`` row's three names kept ``gdn_fwd`` stands once a
+    layer, with nothing kept twice (the backward's second forward), and
+    twice as well were the row to leave out the inverse the backward
+    reads: ``gdn_bwd`` once a layer always."""
+    monkeypatch.setenv(SWITCH, "interpret")
+    spec, params, tokens = _case()
+    held = 16 * sum(a.size for a in jax.tree_util.tree_leaves(params))
+    room = None if kept == "nothing" else batch_shard.DeviceRoom(
+        2 ** 40, 2 ** 40 - held)
+    if kept == "all_but_the_inverse":
+        entries = rk._entries
+        monkeypatch.setattr(rk, "_entries", lambda cfg, rows: [
+            (label, tuple(n for n in names if n != gd.KEEP_INVERSE), *rest)
+            for label, names, *rest in entries(cfg, rows)])
+    with batch_shard.batch_axis(None, None, room):
+        whole = jax.make_jaxpr(jax.grad(_product(spec, tokens)))(params)
+    calls = collections.Counter()
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                calls[eqn.params["name"]] += 1
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(whole.jaxpr)
+    assert (calls["gdn_fwd"], calls["gdn_bwd"]) == (forwards, 3)
 
 
 # -- the lines ---------------------------------------------------------------
@@ -393,6 +431,8 @@ def test_the_delta_scan_and_layer_stack_lines(monkeypatch, mode, ran):
         "a:window=0,rope=0")
     assert scan == (
         "delta scan: rows=128 heads=2 key_dim=16 value_dim=32 chunk=64 "
-        "conv_taps=4 neg_eigval=1 states=recomputed " + ran)
+        "conv_taps=4 neg_eigval=1 states=recomputed " + {
+            "reference": "inverse=twin ",
+            "interpreter": "inverse=forward inverse_mb=0.1 "}[ran] + ran)
     # with room for everything the states are among the kept names
     assert trace(room)[1] == scan.replace("recomputed", "kept")

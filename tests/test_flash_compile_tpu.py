@@ -776,12 +776,15 @@ def _mosaic_calls(text):
 @pytest.mark.parametrize("chunk", [64, 128])
 def test_the_delta_scan_compiles_at_the_cells_shape(one_chip, chunk):
     """15 heads x 16,384 x 96 | 192, one sequence: ``gdn_fwd`` (a block
-    of 5 heads' float32 states resident, the chunk's inverse by 10 or 12
-    float32 matmuls at the highest precision) and ``gdn_bwd`` (the
-    states' cotangents resident, the chunk walked backwards) for a
-    described v5e, under their names and the VMEM limit the calls set;
-    the forward writes the output and a float32 state a chunk a head,
-    the backward dq, dk, dv and the two gates' cotangents."""
+    of 5 heads' float32 states resident, the inverses of two chunks of
+    64 by 10 float32 matmuls of [128, 128] at the highest precision, of
+    one of 128 by 12) and ``gdn_bwd`` (the states' cotangents resident,
+    the chunks walked backwards) for a described v5e, under their names
+    and the VMEM limit the calls set; the forward writes the output, a
+    float32 state a chunk a head and then the inverses in the compute
+    dtype, a row of them 128 wide, which the backward takes as an
+    operand; the backward writes dq, dk, dv and the two gates'
+    cotangents."""
     from elasticdl_tpu.ops import gated_delta as gd
 
     shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
@@ -797,15 +800,24 @@ def test_the_delta_scan_compiles_at_the_cells_shape(one_chip, chunk):
     assert gd.delta_mode(16384, 96, 192, chunk, interpret=False) == (
         "tpu", "")
     assert gd.VMEM_LIMIT <= 64 * 2 ** 20
-    calls = _mosaic_calls(jax.jit(fwd_bwd).lower(
-        q, q, v, g, g, v).compile().as_text())
+    text = jax.jit(fwd_bwd).lower(q, q, v, g, g, v).compile().as_text()
+    calls = _mosaic_calls(text)
     assert len(calls) == 2, calls
     fwd = next(c for c in calls if "gdn_fwd" in c.split(" = ")[0])
     bwd = next(c for c in calls if "gdn_bwd" in c.split(" = ")[0])
     chunks = 16384 // chunk
     results = lambda call: call.split(" custom-call(")[0]
-    assert "bf16[15,16384,192]" in results(fwd)
-    assert "f32[15,%d,96,192]" % chunks in results(fwd)
+    inverse = "bf16[15,128,%d,128]" % chunk
+    assert re.findall(r"\w+\[[\d,]+\]", results(fwd)) == [
+        "bf16[15,16384,192]", "f32[15,%d,96,192]" % chunks, inverse]
+    # the backward's seven operands: the forward's third result is one
+    operands = re.findall(r"%[\w.]+", bwd.split(" custom-call(")[1].split(
+        "), custom_call_target")[0])
+    made = [l for l in text.splitlines() for name in operands
+            if l.strip().startswith(name + " = ")]
+    assert len(operands) == 7 and any(
+        inverse in l and "get-tuple-element(" in l and "index=2" in l
+        for l in made), made
     assert results(bwd).count("bf16[15,16384,96]") == 2       # dq, dk
     assert "bf16[15,16384,192]" in results(bwd)               # dv
     assert "f32[15,%d,2,%d]" % (chunks, chunk) in results(bwd)

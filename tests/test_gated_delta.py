@@ -132,23 +132,159 @@ def test_two_sequences_in_a_batch_leak_no_state_into_each_other():
     assert far(both[1:2], joined[:, :, T:]) > 1e-2
 
 
-def test_keys_that_share_a_direction_do_not_break_the_inverse():
+def shared_direction():
     """Keys behind a SiLU share a direction; here k_i . k_j ~ 0.9 for
-    every pair, no decay and beta near 2: the Neumann series of the
-    chunk's nilpotent matrix over a whole chunk of 64 reads NaN in
-    float32 and over diagonal blocks of 16 1e-2; block forward
-    substitution from pairs of tokens reads 2-3e-6."""
+    every pair, no decay and beta near 2, 256 tokens."""
     r = np.random.default_rng(5)
     q, k, v, g, beta = draw(5, True, seq=256)
     k = k + 0.7 * jnp.asarray(r.standard_normal((1, 1, 1, DK)), jnp.float32)
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
     g = jnp.zeros_like(g) - 1e-4
-    operands = (q, k, v, g, jnp.clip(beta + 0.9, 0.0, 1.98))
-    want = recurrence(*operands)
-    for chunk in (64, 128):
-        assert far(gd.gated_delta_ref(*operands, chunk=chunk), want) < 1e-5
-    assert far(gd.gated_delta(*operands, chunk=128, interpret=True),
-               want) < 1e-5
+    return q, k, v, g, jnp.clip(beta + 0.9, 0.0, 1.98)
+
+
+@functools.lru_cache(maxsize=None)
+def wanted_of_shared_direction():
+    return recurrence(*shared_direction())
+
+
+@pytest.mark.parametrize("chunk", [64, 128])
+@pytest.mark.parametrize("which", sorted(IMPLEMENTATIONS))
+def test_keys_that_share_a_direction_do_not_break_the_inverse(which, chunk):
+    """The Neumann series of the chunk's nilpotent matrix over a whole
+    chunk of 64 reads NaN in float32 on these keys and over diagonal
+    blocks of 16 1e-2; block forward substitution from pairs of tokens
+    reads 2-3e-6, in the twin and in the kernel, whose chunks of 64 go
+    through the joins two at a time on one [128, 128] operand and whose
+    chunks of 128 one at a time."""
+    assert gd.pack_of(256, chunk) == {64: 2, 128: 1}[chunk]
+    got = IMPLEMENTATIONS[which](chunk)(*shared_direction())
+    assert far(got, wanted_of_shared_direction()) < 1e-5
+
+
+# -- what the forward hands the backward -------------------------------------
+
+
+def forward_results(operands, chunk):
+    """``gdn_fwd``'s three results on [B, H, T, .] operands."""
+    q, k, v, g, beta = operands
+    planes = lambda x: x.reshape(-1, *x.shape[2:])
+    return gd._fwd_call(planes(q), planes(k), planes(v),
+                        gd._gates(g, beta, chunk), chunk, H,
+                        gd.pack_of(q.shape[2], chunk), True)
+
+
+def twins_inverses(operands, chunk):
+    """``_inverse`` of the twin's ``A``, [B * H, T / C, C, C] float32: a
+    chunk's alone, a [C, C] operand."""
+    _, k, _, g, beta = operands
+    split = lambda x: x.astype(jnp.float32).reshape(
+        B * H, -1, chunk, *x.shape[3:])
+    k, g, beta = split(k), split(g), split(beta)
+    b = jnp.cumsum(g, axis=-1)
+    _, lower, strict = gd._masks(chunk)
+    diff = b[..., :, None] - b[..., None, :]
+    decay = jnp.where(lower, jnp.exp(jnp.where(lower, diff, 0.0)), 0.0)
+    kk = jnp.matmul(k, jnp.swapaxes(k, -1, -2),
+                    precision=lax.Precision.HIGHEST)
+    return gd._inverse(jnp.where(strict, kk * decay, 0.0) * beta[..., None])
+
+
+INVERSE_DRAWS = {
+    "keys_share_a_direction": (shared_direction, jnp.float32),
+    "two_sequences": (lambda: draw(3, True, seq=256), jnp.float32),
+    "two_sequences_bfloat16": (lambda: draw(3, True, seq=256),
+                               jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("chunk,pack", [(64, 2), (32, 2), (128, 1)])
+@pytest.mark.parametrize("case", sorted(INVERSE_DRAWS))
+def test_the_forwards_third_result_is_the_inverse_of_the_twins_matrix(
+        case, chunk, pack):
+    """Chunk by chunk and head by head, in the compute dtype, P chunks'
+    side by side in a row of P C: the joins over a [P C, P C] operand
+    with P chunks' ``A`` on its diagonal are the joins over each [C, C]
+    alone (zeros added: 1e-6 apart in float32 here, where the
+    interpreter's products sum in another order; the same bfloat16
+    values but for a rounding's tie); the states come second, float32,
+    and both sequences of the batch have their own."""
+    make, dtype = INVERSE_DRAWS[case]
+    q, k, v, g, beta = make()
+    operands = (q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta)
+    o, states, inv = forward_results(operands, chunk)
+    chunks = 256 // chunk
+    assert gd.pack_of(256, chunk) == pack
+    assert o.shape == (B * H, 256, DV) and o.dtype == dtype
+    assert states.shape == (B * H, chunks, DK, DV)
+    assert states.dtype == jnp.float32
+    assert inv.shape == (B * H, chunks // pack, chunk, pack * chunk)
+    assert inv.dtype == dtype
+    apart = jnp.moveaxis(inv.reshape(B * H, chunks // pack, chunk, pack,
+                                     chunk), 3, 2)
+    got = apart.reshape(B * H, chunks, chunk, chunk).astype(jnp.float32)
+    want = twins_inverses(operands, chunk)
+    # no identity matrices, but for the head that forgets everything:
+    # every chunk's has entries under its diagonal
+    under = jnp.abs(want - jnp.eye(chunk)).max(axis=(2, 3))
+    assert float(under.reshape(B, H, -1)[:, (0, 2)].min()) > 0.1
+    tolerance = 2e-5 if dtype == jnp.float32 else 2 ** -8
+    for head in range(B * H):
+        for c in range(chunks):
+            np.testing.assert_allclose(
+                got[head, c], want[head, c].astype(dtype).astype(jnp.float32),
+                rtol=tolerance, atol=tolerance, err_msg="%d %d" % (head, c))
+    assert gd.inverse_bytes(B * 256, H, jnp.dtype(dtype).itemsize, pack,
+                            chunk) == B * H * chunks // pack * chunk * max(
+        128, pack * chunk) * jnp.dtype(dtype).itemsize
+
+
+def kernels_jaxprs(chunk, seq=256):
+    """{call name: its kernel's jaxpr} of the op's forward and backward."""
+    operands = draw(3, True, seq=seq)
+    fn = functools.partial(gd.gated_delta, chunk=chunk, interpret=True)
+    whole = jax.make_jaxpr(
+        lambda *a: jax.vjp(fn, *a)[1](a[2]))(*operands)
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found[eqn.params["name"]] = eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(whole.jaxpr)
+    return found
+
+
+def highest(eqn):
+    return sum("HIGHEST" in str(e.params["precision"])
+               for e in eqn.params["jaxpr"].eqns
+               if e.primitive.name == "dot_general")
+
+
+@pytest.mark.parametrize("chunk,joins", [(64, 10), (32, 8), (128, 12)])
+def test_the_inverse_is_built_once_a_pack_in_the_forward_and_never_behind_it(
+        chunk, joins):
+    """A grid step of ``gdn_fwd`` runs the joins once a head for the P
+    chunks it walks (ten at 64, whether it walks one or two), and
+    ``gdn_bwd``, which reads the result, holds no product at the highest
+    precision at all."""
+    calls = kernels_jaxprs(chunk)
+    assert sorted(calls) == ["gdn_bwd", "gdn_fwd"]
+    fwd, bwd = calls["gdn_fwd"], calls["gdn_bwd"]
+    assert highest(fwd) == joins * H          # H heads a grid step
+    assert highest(bwd) == 0
+    pack = gd.pack_of(256, chunk)
+    block = lambda eqn, at: eqn.params["grid_mapping"].block_mappings[
+        at].block_shape
+    # q's block: H heads x P chunks' rows
+    assert tuple(int(getattr(n, "block_size", n) or 1)
+                 for n in block(fwd, 0))[:2] == (H, pack * chunk)
+    # the backward's sixth operand is the forward's third result
+    assert tuple(bwd.invars[5].aval.shape) == tuple(
+        fwd.params["out_avals"][2].shape)
 
 
 def test_a_length_the_kernel_does_not_tile_takes_the_twin_and_says_so(

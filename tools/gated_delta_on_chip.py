@@ -3,9 +3,10 @@
 
     python3 tools/gated_delta_on_chip.py [--heads 15] [--t 16384]
         [--key_dim 96] [--value_dim 192] [--chunk 64 ...]
-        [--head_block 5 ...] [--iters 3] [--no_reference]
+        [--head_block 5 ...] [--pack 2 ...] [--iters 3] [--no_reference]
 
-Prints one JSON line a (chunk, head block): the device time of the
+Prints one JSON line a (chunk, head block, pack: the chunks a grid step
+walks behind one build of their inverses): the device time of the
 forward kernel's and the backward kernel's calls (``gdn_fwd``,
 ``gdn_bwd``, told by name in a profiler trace) in ms a call, the least
 time the chip could take for the work the recurrence needs
@@ -22,6 +23,7 @@ the interpreter, none is timed).
 """
 
 import argparse
+import itertools
 import json
 import os
 import sys
@@ -39,6 +41,8 @@ def main():
     ap.add_argument("--chunk", type=int, nargs="+", default=[64])
     ap.add_argument("--head_block", type=int, nargs="+", default=[0],
                     help="heads a grid step runs; 0: the op's own choice")
+    ap.add_argument("--pack", type=int, nargs="+", default=[0],
+                    help="chunks a grid step walks; 0: the op's own choice")
     ap.add_argument("--iters", type=int, default=3)
     ap.add_argument("--no_reference", action="store_true")
     args = ap.parse_args()
@@ -86,46 +90,49 @@ def main():
         twin = dict(out=forward(*operands), grads=backward(*operands))
         twin["fwd_ms"] = device_ms(forward, operands, 1)[1]
         twin["fwd_bwd_ms"] = device_ms(backward, operands, 1)[1]
-    for chunk in args.chunk:
-        for block in args.head_block:
-            if block:
-                gd.HEAD_BLOCKS = (block,)
-            forward, backward = both(
-                lambda *a: gd.gated_delta(*a, chunk=chunk,
-                                          interpret=not on_chip))
-            ops_f, all_f = device_ms(forward, operands, args.iters)
-            ops_b, all_b = device_ms(backward, operands, args.iters)
-            row = {"device": dev.device_kind, "heads": H, "t": T,
-                   "key_dim": dk, "value_dim": dv, "chunk": chunk,
-                   "head_block": block or next(
-                       n for n in gd.HEAD_BLOCKS if H % n == 0)}
-            for kind, ops in (("fwd", ops_f), ("bwd", ops_b)):
-                # under ``jax.vjp`` XLA wraps the name: transpose_jvp_..
-                ms = sum(v for op, v in ops.items() if "gdn_" + kind in op)
-                if not ms:      # the rehearsal: the calls ran, untimed
-                    if on_chip:
-                        print("no gdn_%s among %s" % (kind, sorted(ops)),
-                              file=sys.stderr)
-                    continue
-                flops, nbytes = work(1, H, T, dk, dv, kind)
-                floor, bound = peaks.roofline_seconds(
-                    flops, nbytes, dev.device_kind)
-                row[kind] = {"ms": round(ms, 4),
-                             "least_ms": round(1e3 * floor, 4),
-                             "bound": bound,
-                             "roofline_pct": round(1e5 * floor / ms, 3)}
-            if on_chip:
-                row["around_fwd_ms"] = round(all_f - row["fwd"]["ms"], 4)
-                # the backward's program runs the forward kernel too
-                row["bwd_program_ms"] = round(all_b, 4)
-            if twin:
-                row["twin_fwd_ms"] = round(twin["fwd_ms"], 3)
-                row["twin_fwd_bwd_ms"] = round(twin["fwd_bwd_ms"], 3)
-                row["out_from_twin"] = far(forward(*operands), twin["out"])
-                row["grads_from_twin"] = [
-                    far(a, b) for a, b in zip(backward(*operands),
-                                              twin["grads"])]
-            print(json.dumps(row), flush=True)
+    packs = gd.PACKS
+    for chunk, block, pack in itertools.product(
+            args.chunk, args.head_block, args.pack):
+        if block:
+            gd.HEAD_BLOCKS = (block,)
+        gd.PACKS = (pack, 1) if pack else packs
+        forward, backward = both(
+            lambda *a: gd.gated_delta(*a, chunk=chunk,
+                                      interpret=not on_chip))
+        ops_f, all_f = device_ms(forward, operands, args.iters)
+        ops_b, all_b = device_ms(backward, operands, args.iters)
+        row = {"device": dev.device_kind, "heads": H, "t": T,
+               "key_dim": dk, "value_dim": dv, "chunk": chunk,
+               "head_block": block or next(
+                   n for n in gd.HEAD_BLOCKS if H % n == 0),
+               "pack": gd.pack_of(T, chunk)}
+        for kind, ops in (("fwd", ops_f), ("bwd", ops_b)):
+            # under ``jax.vjp`` XLA wraps the name: transpose_jvp_..
+            ms = sum(v for op, v in ops.items() if "gdn_" + kind in op)
+            if not ms:      # the rehearsal: the calls ran, untimed
+                if on_chip:
+                    print("no gdn_%s among %s" % (kind, sorted(ops)),
+                          file=sys.stderr)
+                continue
+            flops, nbytes = work(1, H, T, dk, dv, kind)
+            floor, bound = peaks.roofline_seconds(
+                flops, nbytes, dev.device_kind)
+            row[kind] = {"ms": round(ms, 4),
+                         "least_ms": round(1e3 * floor, 4),
+                         "bound": bound,
+                         "roofline_pct": round(1e5 * floor / ms, 3)}
+        if on_chip:
+            row["around_fwd_ms"] = round(all_f - row["fwd"]["ms"], 4)
+            # the backward's program runs the forward kernel too
+            row["bwd_program_ms"] = round(all_b, 4)
+        if twin:
+            row["twin_fwd_ms"] = round(twin["fwd_ms"], 3)
+            row["twin_fwd_bwd_ms"] = round(twin["fwd_bwd_ms"], 3)
+            row["out_from_twin"] = far(forward(*operands), twin["out"])
+            row["grads_from_twin"] = [
+                far(a, b) for a, b in zip(backward(*operands),
+                                          twin["grads"])]
+        print(json.dumps(row), flush=True)
     return 0
 
 
