@@ -478,6 +478,28 @@ MIXED_STACKS = {
              qk_norm=True, post_norms=True, pre_norms=False,
              delta_neg_eigval=True, conv_kernel=4, head_shares=2,
              tied_embeddings=False, embed_scale=1.0)),
+    # benchmark/configs/solar-open2-250b.json at a sequence of 2,048 and
+    # an eighth of the slice of the vocabulary: a gated NoPE layer at 8
+    # query heads on 1 K/V head then three Kimi Delta Attention layers
+    # (a decay a channel, the low-rank pairs) at 8 of 64 heads, every
+    # FFN 8 of 320 experts behind a sigmoid router beside a shared one
+    "addd": (
+        # --tiny runs one layer of each kind, as ``ddda`` does and for
+        # its reason, every token taking all 8 experts, as ``wwaww``
+        dict(vocab_size=256, dim=128, num_heads=2, num_kv_heads=1,
+             head_dim=64, seq_len=256, ffn_dim=128, delta_key_dim=32,
+             delta_value_dim=32, delta_rank=16, moe_experts=8, moe_top_k=8,
+             moe_experts_held=2, num_layers=2, layer_pattern="ad"),
+        dict(vocab_size=3072, dim=4096, num_heads=8, num_kv_heads=1,
+             head_dim=128, seq_len=2048, ffn_dim=1280, delta_key_dim=128,
+             delta_value_dim=128, delta_rank=128, moe_experts=320,
+             moe_top_k=8, moe_experts_held=8),
+        dict(num_layers=4, layer_pattern="addd", rope_kinds="w",
+             attn_gate=True, delta_kind="kda", delta_neg_eigval=True,
+             conv_kernel=4, head_shares=8, moe_shared_experts=1,
+             moe_router="sigmoid_bias", moe_norm_topk=True,
+             moe_aux_weight=0, norm_eps=1e-5, tied_embeddings=False,
+             embed_scale=1.0)),
 }
 
 
@@ -494,8 +516,10 @@ def check_mixed_stack(tiny, spill=False, stack="caccc"):
     query heads on 4, a per-head QK norm, a muP-scaled embedding and a
     shared expert beside the share, the ``trinity-mini`` cell's; or
     three gated-delta layers to one full NoPE layer over a chip's share
-    of the heads, no experts, the ``olmo-hybrid-7b`` cell's; each at
-    one short sequence), bf16
+    of the heads, no experts, the ``olmo-hybrid-7b`` cell's; or a gated
+    NoPE layer then three Kimi Delta Attention layers, a decay a
+    channel, over a chip's share of the heads AND of the experts, the
+    ``solar-open2-250b`` cell's; each at one short sequence), bf16
     through the kernels, against the same weights in float32 through
     the references at the highest matmul precision (the loss), and the
     same bf16 program through the references (the gradients).
@@ -911,6 +935,8 @@ def _cases(tiny):
            lambda: check_mixed_stack(tiny, stack="wwaww"))
     yield ("mixed_stack/ddda.share",
            lambda: check_mixed_stack(tiny, stack="ddda"))
+    yield ("mixed_stack/addd.kda.share",
+           lambda: check_mixed_stack(tiny, stack="addd"))
     # The benchmark's two heads: OLMoE's untied, OLMo's tied embedding.
     hdim, vocab, heads = (64, 256, ((2, 24, False), (2, 24, True))) if tiny \
         else (2048, 50304, ((4, 4096, False), (8, 2048, True)))

@@ -57,11 +57,21 @@ KEEP_SHARED_GATE, KEEP_SHARED_UP = "shared_gate", "shared_up"
 # ``KEEP_INVERSE``.
 KEEP_DELTA_IN, KEEP_DELTA_QKV = "delta_in", "delta_qkv"
 KEEP_DELTA_DECAY, KEEP_DELTA_GATE = "delta_decay", "delta_gate"
+# A kda layer's (``delta_kind``): the two [rows, delta_rank] products of
+# the stream that its decay's and its gate's low-rank pairs start from;
+# its ``delta_decay`` is [rows, heads * key_dim] float32, a channel each.
+KEEP_DELTA_RANK = "delta_rank"
 
 # The share of the device's limit nothing is planned into: the
 # allocator's fragmentation, the batches in flight, whatever
 # ``step_bytes`` does not see.
 RESERVE = 0.05
+
+# What a kept GB of a kda layer's scan results (output, chunk-start
+# states, inverses) saves of its second forward, ms: ``kda_fwd``'s 3.8
+# ms a call over their 0.185 GB at the ``solar-open2-250b`` cell's
+# shape (my chip run, PR 49; PERF.md section 5).
+KDA_SCAN = 21
 
 # The ``flash`` entry's names: the kernel's output and row statistics.
 ATTN_NAMES = (flash_attention.KEEP_OUT, flash_attention.KEEP_LSE)
@@ -152,19 +162,34 @@ def _entries(cfg, rows):
         # as a convolution's input is; the convolved projection a pass
         # bound by memory
         states = rows // gated_delta.CHUNK * h * d_k * _lanes(d_v) * 4
+        # a kda layer (``cfg.delta_kind``): the decays a channel, [rows,
+        # heads * key_dim] float32, and the gate's projection are each
+        # made from a [rows, rank] product by a contraction over the
+        # rank: what a byte of them buys is what a byte of q buys times
+        # their share of q's operations; the two [rows, rank] products
+        # themselves read the whole stream for 2 * rank values a row
+        # (the dearest byte of the layer)
+        kda, rank = cfg.delta_kind == "kda", cfg.delta_rank
+        cheap = rank * (1 / e + 1 / (h * d_v)) if kda else 1.0
         rest += [
-            (100, "delta_decay", (KEEP_DELTA_DECAY,), rows * h * 8, delta),
-            (11, "delta", (gated_delta.KEEP_OUT, gated_delta.KEEP_STATES,
-                           gated_delta.KEEP_INVERSE),
+            (13 * cheap * size / 4 if kda else 100, "delta_decay",
+             (KEEP_DELTA_DECAY,), rows * h * 4 * (d_k + 1 if kda else 2),
+             delta),
+            (KDA_SCAN if kda else 11, "delta",
+             (gated_delta.KEEP_OUT, gated_delta.KEEP_STATES,
+              gated_delta.KEEP_INVERSE),
              rows * h * d_v * size + states
              + gated_delta.inverse_bytes(rows, h, size), delta),
-            (13, "delta_gate", (KEEP_DELTA_GATE,), rows * h * d_v * size,
-             delta),
+            (13 * cheap, "delta_gate", (KEEP_DELTA_GATE,),
+             rows * h * d_v * size, delta),
             (11, "delta_in", (KEEP_DELTA_IN,),
              rows * h * (2 * d_k + d_v) * size, delta),
             (5, "delta_qkv", (KEEP_DELTA_QKV,),
              rows * h * (2 * d_k + d_v) * size, delta),
         ]
+        if kda:
+            rest.append((13 * e / rank / 2, "delta_rank", (KEEP_DELTA_RANK,),
+                         rows * 2 * rank * size, delta))
     if cfg.shared_dim:
         rest += [(12, name, (name,), rows * cfg.shared_dim * size, experts)
                  for name in SHARED_PRODUCTS]
@@ -306,6 +331,8 @@ def step_bytes(cfg, params, rows, kept=()):
        each at 32 heads (``_latent_layer``): the attention residuals
        of the second forward that are not kept beside that backward,
        or attention's own backward where it is the larger;
+     - for a kda layer, its decays a channel (four float32 planes of
+       [rows, heads * key_dim]);
      - less, at either place, what an untied embedding was counted
        for: its copy is read by the forward's first gather alone and
        its gradient is the last thing the backward makes.
@@ -348,6 +375,15 @@ def step_bytes(cfg, params, rows, kept=()):
         # its own backward follows where the FFN's was
         residuals, backward = _latent_layer(cfg, rows, kept)
         layer = max(layer, backward) + residuals
+    if cfg.delta_kind == "kda" and any(kind.op == "d" for kind in cfg.kinds):
+        # a decay a channel: the log decays, their cumulative sums and
+        # both's cotangents, [rows, heads * key_dim] float32 each (a
+        # scalar decay's are [rows, heads]: nothing), beside the FFN's
+        # term, which the other cells' slack has covered for the scan's
+        # own operands (the compiler's count of the cell's step: tests/
+        # test_flash_compile_tpu.py)
+        channels = rows * cfg.num_heads * cfg.delta_key_dim * 4
+        layer += 4 * channels - min(own(("delta_decay",)), channels)
     embed = params["embed"]
     unread = 0 if cfg.tied_embeddings else copy(embed) + nbytes(embed)
     return copies + carries + max(head - stack_grads, layer) - unread

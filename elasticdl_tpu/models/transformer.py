@@ -149,6 +149,16 @@ class TransformerConfig:
     delta_key_dim: int = 0
     delta_value_dim: int = 0
     delta_neg_eigval: bool = False
+    # Which delta layer a "d" is: "gdn" (Gated DeltaNet: one decay a
+    # head, ``w_a`` [dim, heads]; the output gate a SiLU of ``w_out_gate``
+    # [dim, heads * value_dim]) | "kda" (Kimi Delta Attention,
+    # arXiv:2510.26692: a decay a CHANNEL of the key, projected through
+    # the low-rank pair ``w_a_down`` [dim, delta_rank] / ``w_a_up``
+    # [delta_rank, heads * key_dim] with a ``dt_bias`` a channel; the
+    # output gate a sigmoid of the pair ``w_g_down`` / ``w_g_up`` + ``b_g``
+    # of the same rank).  ``delta_rank`` is the pairs' and kda's alone.
+    delta_kind: str = "gdn"
+    delta_rank: int = 0
     # How many chips share a layer's heads in the deployment this model
     # is one chip of (tensor parallel over heads): ``num_heads`` and
     # ``num_kv_heads`` are the heads held HERE, every mixer's result is
@@ -299,6 +309,13 @@ class TransformerConfig:
                 "and 1 <= conv_kernel <= %d; got %d, %d and %d"
                 % (short_conv.HALO + 1, self.delta_key_dim,
                    self.delta_value_dim, self.conv_kernel))
+        if "d" in pattern and (self.delta_kind == "kda") != bool(
+                self.delta_rank > 0):
+            raise ValueError(
+                "delta_kind=%s and delta_rank=%d: the rank is the kda "
+                "layer's low-rank pairs' (decay, output gate) and theirs "
+                "alone, so each needs the other"
+                % (self.delta_kind, self.delta_rank))
         if ("w" in pattern) != bool(self.window):
             raise ValueError(
                 "layer_pattern %r and window=%d: the window is the w "
@@ -358,6 +375,7 @@ _WORDS = {
     "ffn_activation": tuple(sorted(ACTIVATIONS)),
     "attention_impl": ("ring", "ulysses"),
     "moe_router": ("softmax", "sigmoid_bias"),
+    "delta_kind": ("gdn", "kda"),
 }
 _WORDS_GONE = {
     "remat": ': "attn" and "dots" are gone; remat=true keeps the flash '
@@ -449,11 +467,11 @@ _CANNOT = {
     "stack": (
         lambda cfg: _pattern(cfg) is not None,
         "a stack whose layers differ (layer_pattern={cfg.layer_pattern!r}"
-        ", dense_layers={cfg.dense_layers})",
+        ", dense_layers={cfg.dense_layers}, delta_kind={cfg.delta_kind})",
         "a short-convolution layer needs a state cache of its own, a "
-        "gated-delta layer (d) a recurrent state [heads, value_dim, "
-        "key_dim] and the last conv_kernel - 1 rows of its convolution's "
-        "input and no K/V cache, a "
+        "gated-delta layer (d; gdn or kda) a recurrent state [heads, "
+        "value_dim, key_dim] and the last conv_kernel - 1 rows of its "
+        "convolution's input and no K/V cache, a "
         "windowed layer (w) beside full ones a K/V cache that keeps its "
         "last `window` positions, a mesh specs for the weights of lead, "
         "period and tail, and the pipeline a split of them into stages"),
@@ -559,27 +577,43 @@ def _init_delta(key, cfg, stack):
     as the Gated DeltaNet layer of the flash-linear-attention library
     draws them: a decay rate A uniform in (0, 16) a head, a step dt
     log-uniform in (0.001, 0.1) behind an inverse softplus, so that at
-    a zero projection a head's decay ``exp(-A dt)`` lies in (0.2, 1)."""
+    a zero projection a head's decay ``exp(-A dt)`` lies in (0.2, 1).
+    A kda layer draws A in (1, 16) and a step a CHANNEL of the key, and
+    holds the two low-rank pairs in place of ``w_a`` and ``w_out_gate``
+    (the second of a pair drawn at ``rank ** -0.5``, so that the pair's
+    result is of the order the one projection's is)."""
     E, H = cfg.dim, cfg.num_heads
     dk, dv = cfg.delta_key_dim, cfg.delta_value_dim
+    kda, rank = cfg.delta_kind == "kda", cfg.delta_rank
     keys = jax.random.split(jax.random.fold_in(key, 7), 8)
     width = H * (2 * dk + dv)
-    dt = jnp.exp(jax.random.uniform(keys[5], (*stack, H), jnp.float32,
-                                    np.log(1e-3), np.log(1e-1)))
-    return dict(
+    dt = jnp.exp(jax.random.uniform(
+        keys[5], (*stack, H * dk if kda else H), jnp.float32,
+        np.log(1e-3), np.log(1e-1)))
+    layers = dict(
         # q, k and v of every head side by side: one product, one
         # convolution
         w_qkv=_dense_init(keys[0], *stack, E, width),
         delta_conv=_dense_init(keys[1], *stack, width, cfg.conv_kernel,
                                scale=cfg.conv_kernel ** -0.5),
-        w_a=_dense_init(keys[2], *stack, E, H),
         w_b=_dense_init(keys[3], *stack, E, H),
-        A_log=jnp.log(jax.random.uniform(keys[4], (*stack, H),
-                                         jnp.float32, 1e-3, 16.0)),
+        A_log=jnp.log(jax.random.uniform(
+            keys[4], (*stack, H), jnp.float32, 1.0 if kda else 1e-3, 16.0)),
         dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
-        w_out_gate=_dense_init(keys[6], *stack, E, H * dv),
         o_norm=_norm_init(*stack, dv),
         wo=_dense_init(keys[7], *stack, H * dv, E))
+    if not kda:
+        layers.update(w_a=_dense_init(keys[2], *stack, E, H),
+                      w_out_gate=_dense_init(keys[6], *stack, E, H * dv))
+        return layers
+    down, up = jax.random.split(keys[2]), jax.random.split(keys[6])
+    layers.update(
+        w_a_down=_dense_init(down[0], *stack, E, rank),
+        w_a_up=_dense_init(down[1], *stack, rank, H * dk),
+        w_g_down=_dense_init(up[0], *stack, E, rank),
+        w_g_up=_dense_init(up[1], *stack, rank, H * dv),
+        b_g=jnp.zeros((*stack, H * dv), jnp.float32))
+    return layers
 
 
 def init_params(rng, cfg):
@@ -1069,9 +1103,11 @@ def announce_delta(cfg, rows, chunk, kept, inverse, mode, why):
     differentiating the jnp twin."""
     logger.info(
         "delta scan: rows=%d heads=%d key_dim=%d value_dim=%d chunk=%d "
-        "conv_taps=%d neg_eigval=%d states=%s inverse=%s %s%s", rows,
-        cfg.num_heads, cfg.delta_key_dim, cfg.delta_value_dim, chunk,
+        "conv_taps=%d neg_eigval=%d decay=%s states=%s inverse=%s %s%s",
+        rows, cfg.num_heads, cfg.delta_key_dim, cfg.delta_value_dim, chunk,
         cfg.conv_kernel, cfg.delta_neg_eigval,
+        "channel rank=%d" % cfg.delta_rank if cfg.delta_kind == "kda"
+        else "head",
         "kept" if kept else "recomputed",
         "twin" if mode == "off" else "forward inverse_mb=%.1f" % (
             inverse / 1e6),
@@ -1096,11 +1132,16 @@ def _delta_mix(h, w, cfg):
     ``ops/gated_delta.py``'s, which picks kernel or reference; its
     output takes an RMSNorm over each head's values (one scale the
     heads share) times the SiLU of a gate projected from ``h``, and
-    ``wo`` contracts (head, width) where the output stands."""
+    ``wo`` contracts (head, width) where the output stands.  A kda
+    layer (``cfg.delta_kind``): the log decay a CHANNEL of the key, ``g =
+    -exp(A_log[head]) * softplus((h W_a_down) W_a_up + dt_bias)`` [B, H,
+    T, key_dim], and the gate a sigmoid of ``(h W_g_down) W_g_up +
+    b_g``."""
     compute_dtype = jnp.dtype(cfg.dtype)
     B, T, _ = h.shape
     H, dk, dv = cfg.num_heads, cfg.delta_key_dim, cfg.delta_value_dim
-    mode, why = gated_delta.delta_mode(T, dk, dv)
+    kda = cfg.delta_kind == "kda"
+    mode, why = gated_delta.delta_mode(T, dk, dv, vector=kda)
     rows = B * T // batch_shard.shards()
     announce_delta(cfg, rows, gated_delta.CHUNK,
                    not cfg.remat or remat_keep.keeps(
@@ -1120,21 +1161,36 @@ def _delta_mix(h, w, cfg):
     q = heads(qkv[..., :H * dk], dk, dk ** -0.5)
     k = heads(qkv[..., H * dk:2 * H * dk], dk, 1.0)
     v = heads(qkv[..., 2 * H * dk:], dv)
+    low_rank = lambda name, width: _heads_first(
+        checkpoint_name(h @ w[name + "_down"].astype(compute_dtype),
+                        remat_keep.KEEP_DELTA_RANK),
+        w[name + "_up"].astype(compute_dtype).reshape(-1, H, width))
     # [B, H, T] float32 each, from the compute dtype's products
     a, b = (jnp.einsum("btd,dh->bht", h, w[name].astype(
-        compute_dtype)).astype(jnp.float32) for name in ("w_a", "w_b"))
+        compute_dtype)).astype(jnp.float32) if name in w else None
+        for name in ("w_a", "w_b"))
     per_head = lambda x: x.astype(jnp.float32)[None, :, None]
     beta = jax.nn.sigmoid(b) * (2.0 if cfg.delta_neg_eigval else 1.0)
-    g = -jnp.exp(per_head(w["A_log"])) * jax.nn.softplus(
-        a + per_head(w["dt_bias"]))
+    if kda:     # [B, H, T, key_dim]: a channel its own step and bias
+        g = -jnp.exp(per_head(w["A_log"]))[..., None] * jax.nn.softplus(
+            low_rank("w_a", dk).astype(jnp.float32)
+            + w["dt_bias"].astype(jnp.float32).reshape(H, 1, dk))
+    else:
+        g = -jnp.exp(per_head(w["A_log"])) * jax.nn.softplus(
+            a + per_head(w["dt_bias"]))
     g, beta = (checkpoint_name(x, remat_keep.KEEP_DELTA_DECAY)
                for x in (g, beta))
     o = gated_delta.gated_delta(q, k, v, g, beta)
-    gate = checkpoint_name(
-        _heads_first(h, w["w_out_gate"].astype(compute_dtype).reshape(
-            -1, H, dv)), remat_keep.KEEP_DELTA_GATE)
-    o = _rmsnorm(o, w["o_norm"].astype(compute_dtype),
-                 cfg.norm_eps) * jax.nn.silu(gate)
+    if kda:
+        gate = checkpoint_name(low_rank("w_g", dv),
+                               remat_keep.KEEP_DELTA_GATE)
+        gate = gate + w["b_g"].astype(compute_dtype).reshape(H, 1, dv)
+    else:
+        gate = checkpoint_name(
+            _heads_first(h, w["w_out_gate"].astype(compute_dtype).reshape(
+                -1, H, dv)), remat_keep.KEEP_DELTA_GATE)
+    o = _rmsnorm(o, w["o_norm"].astype(compute_dtype), cfg.norm_eps) * (
+        jax.nn.sigmoid(gate) if kda else jax.nn.silu(gate))
     # as ``_latent_mix``: reshaped before the cast
     wo = w["wo"].reshape(H, dv, cfg.dim).astype(compute_dtype)
     return jnp.einsum("bhtk,hkd->btd", o, wo)
@@ -1735,11 +1791,12 @@ def _decayed(params):
     """AdamW's weight-decay mask: everything but the routers'
     ``expert_bias``, the scales of the norms on a sublayer's output
     and, of a gated-delta layer, its decay rates, its step bias, its
-    output norm's scale and its convolution's taps."""
+    output norm's scale, its convolution's taps and (kda) its output
+    gate's bias."""
     return jax.tree_util.tree_map_with_path(
         lambda path, _: getattr(path[-1], "key", None) not in (
             "expert_bias", "ln1_post", "ln2_post", "A_log", "dt_bias",
-            "o_norm", "delta_conv"), params)
+            "o_norm", "delta_conv", "b_g"), params)
 
 
 def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
@@ -1780,7 +1837,8 @@ def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
     masked from it here, so the optimizer leaves it as it is.  The
     decay is masked from ``post_norms``' two scales as well: they set
     how much of a sublayer's result joins the stream; and from a
-    gated-delta layer's ``A_log``, ``dt_bias``, ``o_norm`` and taps.
+    gated-delta layer's ``A_log``, ``dt_bias``, ``o_norm``, taps and
+    ``b_g``.
     """
     types = {field.name: field.type
              for field in dataclasses.fields(TransformerConfig)}

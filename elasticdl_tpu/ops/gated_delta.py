@@ -63,6 +63,29 @@ The forward's three results carry names for a remat policy
 (models/remat_keep.py): with all three kept the backward of a
 rematerialized layer does not run ``gdn_fwd`` a second time.
 
+A decay that is a VECTOR a head (Kimi Delta Attention, arXiv:2510.26692:
+``g`` [B, H, T, d_k], a log decay a channel of the key) turns the
+recurrence into ``S_t = S_(t-1) Diag(alpha_t) (I - beta_t k_t k_t^T) +
+beta_t v_t k_t^T`` and the chunk's two score matrices into
+
+    M_xk[r, i] = sum_c x_r[c] k_i[c] exp(b_r[c] - b_i[c])      (i <= r)
+
+(x = k for ``A``, x = q for the output's scores; ``b`` [C, d_k] now),
+which no longer factor as ``(X K^T) * decay``: the product ``(X *
+exp(b)) (K * exp(-b))^T`` overflows where a channel forgets fast.
+``_pair_terms`` splits a chunk into sub-blocks of ``SUB`` tokens and
+writes every exponent against a REFERENCE ROW between the two tokens,
+``exp(b_r - b_m) exp(b_m - b_i)`` with i <= m <= r, both factors <= 1:
+a sub-block's rows against the earlier sub-blocks of their chunk with m
+the sub-block's first row (one matmul a sub-block), and inside a
+sub-block against its j-th row, m = i (one matmul a j, every sub-block's
+column j at once: the direct ``[c, c, d_k]`` form a column at a time,
+reduced by the MXU).  ``gamma``, the carry and ``exp(b_C - b)`` become
+[C, d_k], [d_k, 1] and [C, d_k]; the rest of the chunk, ``_inverse``,
+the grid, the packs, the resident state and the kept names are the
+scalar decay's.  Kernels ``kda_fwd`` / ``kda_bwd``: ``b`` rides as a
+float32 plane beside k, the backward writes its cotangent a channel.
+
 Reference: ``gated_delta_ref``, the same chunk form in plain
 ``jax.numpy`` (float32 inside), differentiated by JAX: what
 ``gated_delta`` returns wherever ``ops/mode.py`` answers ``off`` or the
@@ -101,6 +124,8 @@ HEAD_BLOCKS = (5, 4, 3, 2, 1)
 # of 64 fill its 128.
 PACKS = (2, 1)
 MXU = 128
+# Tokens a sub-block of a vector decay's chunk (``_pair_terms``).
+SUB = 16
 VMEM_LIMIT = 64 * 1024 * 1024
 
 _F32 = jnp.float32
@@ -168,8 +193,10 @@ def _inverse(a, chunk=None):
 def gated_delta_ref(q, k, v, g, beta, chunk=CHUNK):
     """The chunk form in plain ``jax.numpy``, float32 inside, v's dtype
     out; any T (the last chunk padded with tokens that neither decay nor
-    write)."""
+    write).  ``g`` [B, H, T] a scalar a head, or [B, H, T, d_k] a vector
+    (the decayed scores then by ``_pair_scores``, a chunk at a time)."""
     batch, heads, seq, _ = q.shape
+    vector = g.ndim == q.ndim
     dtype = v.dtype
     pad = -seq % chunk
     if pad:
@@ -181,31 +208,45 @@ def gated_delta_ref(q, k, v, g, beta, chunk=CHUNK):
     split = lambda x: x.astype(_F32).reshape(
         batch, heads, n, chunk, *x.shape[3:])
     q, k, v, g, beta = map(split, (q, k, v, g, beta))
-    b = jnp.cumsum(g, axis=-1)
     _, lower, strict = _masks(chunk)
-    diff = b[..., :, None] - b[..., None, :]
-    decay = jnp.where(lower, jnp.exp(jnp.where(lower, diff, 0.0)), 0.0)
     mm = functools.partial(jnp.matmul, precision=lax.Precision.HIGHEST)
-    a = jnp.where(strict, mm(k, jnp.swapaxes(k, -1, -2)) * decay,
-                  0.0) * beta[..., None]
+    if vector:
+        b = jnp.cumsum(g, axis=-2)                    # [.., C, d_k]
+        with jax.default_matmul_precision("highest"):
+            pairs = jnp.vectorize(
+                functools.partial(_pair_scores, chunk=chunk),
+                signature="(m,d),(c,d),(c,d)->(m,c)")(
+                    jnp.concatenate([k, q], axis=-2), k, b)
+        kk, qk = pairs[..., :chunk, :], pairs[..., chunk:, :]
+        gamma, last = jnp.exp(b), b[..., -1:, :]
+        to_end = jnp.exp(last - b)
+        carry = jnp.swapaxes(jnp.exp(last), -1, -2)   # [.., d_k, 1]
+    else:
+        b = jnp.cumsum(g, axis=-1)
+        diff = b[..., :, None] - b[..., None, :]
+        decay = jnp.where(lower, jnp.exp(jnp.where(lower, diff, 0.0)), 0.0)
+        kk = mm(k, jnp.swapaxes(k, -1, -2)) * decay
+        qk = mm(q, jnp.swapaxes(k, -1, -2)) * decay
+        gamma, last = jnp.exp(b)[..., None], b[..., -1:]
+        to_end = jnp.exp(last - b)[..., None]
+        carry = jnp.exp(last)[..., None]
+    a = jnp.where(strict, kk, 0.0) * beta[..., None]
     inv = _inverse(a)
-    gamma = jnp.exp(b)
-    w = mm(inv, k * (beta * gamma)[..., None])
+    w = mm(inv, k * gamma * beta[..., None])
     u0 = mm(inv, v * beta[..., None])
-    scores = jnp.where(lower, mm(q, jnp.swapaxes(k, -1, -2)) * decay, 0.0)
-    last = b[..., -1:]
-    kd = k * jnp.exp(last - b)[..., None]
+    scores = jnp.where(lower, qk, 0.0)
+    kd = k * to_end
 
     def step(h, xs):
         w, u0, scores, qg, kd, carry = xs
         u = u0 - mm(w, h)
         o = mm(qg, h) + mm(scores, u)
-        return carry[..., None] * h + mm(jnp.swapaxes(kd, -1, -2), u), o
+        return carry * h + mm(jnp.swapaxes(kd, -1, -2), u), o
 
     chunks_first = lambda x: jnp.moveaxis(x, 2, 0)
     h0 = jnp.zeros((batch, heads, q.shape[-1], v.shape[-1]), _F32)
     _, o = lax.scan(step, h0, tuple(map(chunks_first, (
-        w, u0, scores, q * gamma[..., None], kd, jnp.exp(last)))))
+        w, u0, scores, q * gamma, kd, carry))))
     o = jnp.moveaxis(o, 0, 2).reshape(batch, heads, seq + pad, -1)
     return o[:, :, :seq].astype(dtype)
 
@@ -388,17 +429,242 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gates_ref, states_ref, inv_ref, do_ref,
         dgates_ref[i, p, 1:2, :] = _row(dbeta, eye)
 
 
-def _specs(heads, chunk, pack, d_k, d_v, steps, reverse):
+# -- a decay that is a vector a head ------------------------------------------
+
+
+def _held(x, at, size):
+    """x [rows, width] with every row replaced by row ``at`` of its own
+    block of ``size`` rows (a masked sum down the block: no gather)."""
+    rows, width = x.shape
+    blocks = x.reshape(rows // size, size, width)
+    here = lax.broadcasted_iota(jnp.int32, blocks.shape, 1) == at
+    row = jnp.sum(jnp.where(here, blocks, 0.0), axis=1, keepdims=True)
+    return jnp.broadcast_to(row, blocks.shape).reshape(rows, width)
+
+
+def _pair_terms(xs, k, b, chunk, sub):
+    """The products whose masked sum is ``M[r, i] = sum_c x_r[c] k_i[c]
+    exp(b_r[c] - b_i[c])`` for i <= r inside a chunk, and 0 elsewhere:
+    k [E, d_k] (E a whole number of chunks) in the compute dtype, b [E,
+    d_k] float32 (each chunk's own cumulative sum of a log decay <= 0),
+    xs [n E, d_k] n planes of rows at the same positions (k itself, q).
+    Yields (where the term counts [n E, E], the left operand [n E, d_k]
+    in the compute dtype, its scale [n E, d_k], the right operand [E,
+    d_k], its scale): ``x * left_scale`` times ``(k * right_scale)^T``.
+    Every scale is the exponential of a difference against a row m
+    between the pair, i <= m <= r, so none is over 1:
+
+     - inside a sub-block of ``sub`` tokens, m = i: one term a j, the
+       j-th row of every sub-block at once as the right operand;
+     - a sub-block's rows against the earlier sub-blocks of its chunk,
+       m the sub-block's first row: one term a sub-block."""
+    dtype = k.dtype
+    edge = k.shape[0]
+    n = xs.shape[0] // edge
+    xf, kf = xs.astype(_F32), k.astype(_F32)
+    planes = lambda a: jnp.concatenate([a] * n, axis=0) if n > 1 else a
+    pos = lax.broadcasted_iota(jnp.int32, (edge, 1), 0)
+    r = lax.broadcasted_iota(jnp.int32, (n * edge, edge), 0) % edge
+    i = lax.broadcasted_iota(jnp.int32, (n * edge, edge), 1)
+    inside = r // sub == i // sub
+    before = (r // chunk == i // chunk) & ~inside
+    scale = lambda rows, exponent: jnp.where(
+        rows, jnp.exp(jnp.where(rows, exponent, 0.0)), 0.0)
+
+    def term(counts, left, right):
+        left = planes(left)
+        return (counts, (xf * left).astype(dtype), left,
+                (kf * right).astype(dtype), right)
+
+    for j in range(sub):
+        yield term(inside, scale(pos % sub >= j, b - _held(b, j, sub)),
+                   jnp.where(pos % sub == j, 1.0, 0.0))
+    for first in range(sub, chunk, sub):
+        ref = _held(b, first, chunk)
+        yield term(before,
+                   scale(pos % chunk // sub == first // sub, b - ref),
+                   scale(pos % chunk < first, ref - b))
+
+
+def _pair_scores(xs, k, b, chunk, sub=SUB):
+    """``M`` of ``_pair_terms`` [n E, E] float32."""
+    return sum(jnp.where(counts, _dot(left, right, _NT), 0.0)
+               for counts, left, _, right, _ in _pair_terms(
+                   xs, k, b, chunk, sub))
+
+
+def _pair_scores_bwd(dm, xs, k, b, chunk, sub=SUB):
+    """(the cotangent of xs [n E, d_k], of k [E, d_k]) through
+    ``_pair_scores`` for ``dm`` [n E, E], float32 each; b's is the
+    caller's: x * dx summed over the planes, less k * dk (the reference
+    rows cancel)."""
+    dxs = dk = 0.0
+    masked = {}     # dm where a term counts: two masks for all the terms
+    for counts, left, left_scale, right, right_scale in _pair_terms(
+            xs, k, b, chunk, sub):
+        if id(counts) not in masked:
+            masked[id(counts)] = jnp.where(counts, dm, 0.0).astype(k.dtype)
+        d = masked[id(counts)]
+        dxs += _dot(d, right) * left_scale
+        dk += _dot(d, left, _TN) * right_scale
+    return dxs, dk
+
+
+def _kda_decays(k, b, beta_col):
+    """``_decays`` of a chunk under a vector decay: k [C, d_k], b [C,
+    d_k] float32, beta [C, 1]."""
+    dtype = k.dtype
+    eye = _masks(k.shape[1])[0]
+    ends = lax.broadcasted_iota(jnp.int32, (k.shape[0], 1), 0) == (
+        k.shape[0] - 1)
+    last = jnp.sum(jnp.where(ends, b, 0.0), axis=0, keepdims=True)
+    gamma, to_end, carry_row = jnp.exp(b), jnp.exp(last - b), jnp.exp(last)
+    kf = k.astype(_F32)
+    return types.SimpleNamespace(
+        eye=eye, ends=ends, beta_col=beta_col, gamma=gamma, to_end=to_end,
+        carry_row=carry_row, carry=_col(carry_row, eye), kf=kf,
+        kb=(kf * gamma * beta_col).astype(dtype),
+        kd=(kf * to_end).astype(dtype))
+
+
+def _kda_pack(q, k, b, betas):
+    """(kk, qk [E, E] float32: ``_pair_scores`` of a pack's k and q, a
+    chunk's scores in its own columns; beta [E, 1]) of a pack of P
+    chunks; ``betas`` the chunks' [1, C] rows."""
+    chunk = betas[0].shape[1]
+    eye = _masks(chunk)[0]
+    m = _pair_scores(jnp.concatenate([k, q], axis=0), k, b, chunk)
+    edge = k.shape[0]
+    return m[:edge], m[edge:], jnp.concatenate(
+        [_col(row, eye) for row in betas], axis=0)
+
+
+def _kda_chunk(q, v, d, scores, inv, at, h):
+    """``_chunk`` under a vector decay: ``scores`` [C, P C] the chunk's
+    rows of the pack's qk, which meet ``u`` between rows of zeros as the
+    inverses do."""
+    dtype = q.dtype
+    pack = inv.shape[1] // inv.shape[0]
+    among = lambda x: jnp.concatenate(
+        [x if p == at else jnp.zeros_like(x) for p in range(pack)], axis=0)
+    hc = h.astype(dtype)
+    r = (v.astype(_F32) * d.beta_col - _dot(d.kb, hc)).astype(dtype)
+    u = _dot(inv, among(r)).astype(dtype)
+    return types.SimpleNamespace(
+        hc=hc, u=u, among=among(u), scores=scores.astype(dtype),
+        qg=(q.astype(_F32) * d.gamma).astype(dtype))
+
+
+def _kda_fwd_kernel(q_ref, k_ref, v_ref, b_ref, gates_ref, o_ref,
+                    states_ref, inv_ref, h_scr, *, heads, chunk):
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        h_scr[...] = jnp.zeros_like(h_scr)
+
+    edge = q_ref.shape[1]
+    pack = edge // chunk
+    row = lax.broadcasted_iota(jnp.int32, (edge, edge), 0)
+    col = lax.broadcasted_iota(jnp.int32, (edge, edge), 1)
+    for i in range(heads):
+        kk, qk, beta_col = _kda_pack(
+            q_ref[i], k_ref[i], b_ref[i],
+            [gates_ref[i, p, 0:1, :] for p in range(pack)])
+        # kk is zero outside a chunk's own block: ``_inverses``' operand
+        full = _inverse(jnp.where(row > col, kk, 0.0) * beta_col, chunk)
+        blocks = [full[p * chunk:(p + 1) * chunk] for p in range(pack)]
+        inv = sum(blocks[1:], blocks[0]).astype(q_ref.dtype)
+        inv_ref[i] = inv
+        h = h_scr[i]
+        for p in range(pack):
+            rows = slice(p * chunk, (p + 1) * chunk)
+            states_ref[i, p] = h
+            d = _kda_decays(k_ref[i, rows], b_ref[i, rows], beta_col[rows])
+            c = _kda_chunk(q_ref[i, rows], v_ref[i, rows], d, qk[rows],
+                           inv, p, h)
+            o = _dot(c.qg, c.hc) + _dot(c.scores, c.among)
+            o_ref[i, rows] = o.astype(o_ref.dtype)
+            h = d.carry * h + _dot(d.kd, c.u, _TN)
+        h_scr[i] = h
+
+
+def _kda_bwd_kernel(q_ref, k_ref, v_ref, b_ref, gates_ref, states_ref,
+                    inv_ref, do_ref, dq_ref, dk_ref, dv_ref, db_ref,
+                    dgates_ref, dh_scr, *, heads, chunk):
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        dh_scr[...] = jnp.zeros_like(dh_scr)
+
+    edge = q_ref.shape[1]
+    pack = edge // chunk
+    eye = _masks(chunk)[0]
+    row = lax.broadcasted_iota(jnp.int32, (chunk, edge), 0)
+    col = lax.broadcasted_iota(jnp.int32, (chunk, edge), 1)
+    rowsum = lambda x: jnp.sum(x, axis=1, keepdims=True)
+    for i in range(heads):
+        q, k, b, inv = q_ref[i], k_ref[i], b_ref[i], inv_ref[i]
+        dtype = q.dtype
+        cast = lambda x: x.astype(dtype)
+        kk, qk, beta_all = _kda_pack(
+            q, k, b, [gates_ref[i, p, 0:1, :] for p in range(pack)])
+        dqs, dks, dbs, dkks, dqks = ([None] * pack for _ in range(5))
+        for p in reversed(range(pack)):
+            rows = slice(p * chunk, (p + 1) * chunk)
+            v, do = v_ref[i, rows], do_ref[i, rows]
+            h, dh = states_ref[i, p], dh_scr[i]
+            beta_col = beta_all[rows]
+            d = _kda_decays(k[rows], b[rows], beta_col)
+            c = _kda_chunk(q[rows], v, d, qk[rows], inv, p, h)
+            gamma, kf, qf = d.gamma, d.kf, q[rows].astype(_F32)
+            dhc = cast(dh)
+            # O = (Q gamma) h + scores U;  h' = carry h + kd^T U
+            du = _dot(c.scores, do, _TN)[rows] + _dot(d.kd, dhc)
+            dqg = _dot(do, c.hc, _NT)
+            dkd = _dot(c.u, dhc, _NT)
+            # U = inv R, R = beta V - kb h;  d(inv) = -inv^T . inv^T
+            dr = _dot(inv, cast(du), _TN)[rows]
+            drc = cast(dr)
+            da = jnp.where(row + p * chunk > col,
+                           -_dot(drc, c.among, _NT), 0.0)
+            dkb = -_dot(drc, c.hc, _NT)
+            dh_scr[i] = (d.carry * dh + _dot(c.qg, do, _TN)
+                         - _dot(d.kb, drc, _TN))
+            dv_ref[i, rows] = (beta_col * dr).astype(dv_ref.dtype)
+            both = dkb * kf * gamma                    # d(beta gamma)
+            dbeta = (rowsum(dr * v.astype(_F32)) + rowsum(both)
+                     + rowsum(da * kk[rows]))
+            dgates_ref[i, p, 0:1, :] = _row(dbeta, eye)
+            # kd = exp(b_C - b) K;  carry = exp(b_C)
+            to_end = dkd * kf * d.to_end               # d(b_C - b)
+            last = (jnp.sum(to_end, axis=0, keepdims=True)
+                    + _row(rowsum(dh * h), d.eye) * d.carry_row)
+            dqs[p] = dqg * gamma
+            dks[p] = beta_col * gamma * dkb + d.to_end * dkd
+            dbs[p] = (dqg * qf * gamma + beta_col * both - to_end
+                      + jnp.where(d.ends, last, 0.0))
+            dkks[p] = beta_col * da
+            dqks[p] = _dot(do, c.among, _NT)
+        stack = lambda parts: jnp.concatenate(parts, axis=0)
+        xs = stack([k, q])
+        dxs, dk2 = _pair_scores_bwd(stack(dkks + dqks), xs, k, b, chunk)
+        dxk, dxq = dxs[:edge], dxs[edge:]
+        kf, qf = k.astype(_F32), q.astype(_F32)
+        dq_ref[i] = (stack(dqs) + dxq).astype(dq_ref.dtype)
+        dk_ref[i] = (stack(dks) + dxk + dk2).astype(dk_ref.dtype)
+        db_ref[i] = stack(dbs) + kf * (dxk - dk2) + qf * dxq
+
+
+def _specs(heads, chunk, pack, d_k, d_v, steps, reverse, gates=2):
     """BlockSpecs of a [B * H, T, d_k] plane, a [B * H, T, d_v] plane,
-    the gates [B * H, T / C, 2, C], the states [B * H, T / C, d_k, d_v]
-    and the inverses [B * H, T / (P C), C, P C] for a grid (head block,
-    pack of P chunks), the packs walked backwards with ``reverse``."""
+    the gates [B * H, T / C, ``gates``, C], the states [B * H, T / C,
+    d_k, d_v] and the inverses [B * H, T / (P C), C, P C] for a grid
+    (head block, pack of P chunks), the packs walked backwards with
+    ``reverse``."""
     at = (lambda j: steps - 1 - j) if reverse else (lambda j: j)
     return (pl.BlockSpec((heads, pack * chunk, d_k),
                          lambda i, j: (i, at(j), 0)),
             pl.BlockSpec((heads, pack * chunk, d_v),
                          lambda i, j: (i, at(j), 0)),
-            pl.BlockSpec((heads, pack, 2, chunk),
+            pl.BlockSpec((heads, pack, gates, chunk),
                          lambda i, j: (i, at(j), 0, 0)),
             pl.BlockSpec((heads, pack, d_k, d_v),
                          lambda i, j: (i, at(j), 0, 0)),
@@ -489,6 +755,80 @@ def _gdn_bwd(chunk, heads, pack, interpret, res, do):
 _gdn.defvjp(_gdn_fwd, _gdn_bwd)
 
 
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
+def _kda_fwd_call(q, k, v, b, gates, chunk, heads, pack, interpret):
+    """``_fwd_call`` under a vector decay: ``b`` [B * H, T, d_k] float32
+    each chunk's cumulative log decay, ``gates`` [B * H, T / C, 1, C]
+    the write strengths."""
+    bh, seq, d_k = q.shape
+    d_v = v.shape[-1]
+    chunks = seq // chunk
+    qk, vo, gate, state, inverse = _specs(
+        heads, chunk, pack, d_k, d_v, chunks // pack, False, 1)
+    return pl.pallas_call(
+        functools.partial(_kda_fwd_kernel, heads=heads, chunk=chunk),
+        out_shape=(jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct((bh, chunks, d_k, d_v), _F32),
+                   jax.ShapeDtypeStruct(
+                       (bh, chunks // pack, chunk, pack * chunk), q.dtype)),
+        grid=(bh // heads, chunks // pack),
+        in_specs=[qk, qk, vo, qk, gate],
+        out_specs=(vo, state, inverse),
+        scratch_shapes=[pltpu.VMEM((heads, d_k, d_v), _F32)],
+        compiler_params=_params(),
+        interpret=interpret,
+        name="kda_fwd",
+    )(q, k, v, b, gates)
+
+
+@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11))
+def _kda_bwd_call(q, k, v, b, gates, states, inv, do, chunk, heads, pack,
+                  interpret):
+    """(dq, dk, dv, db, dgates)."""
+    bh, seq, d_k = q.shape
+    d_v = v.shape[-1]
+    steps = inv.shape[1]
+    qk, vo, gate, state, inverse = _specs(
+        heads, chunk, pack, d_k, d_v, steps, True, 1)
+    return pl.pallas_call(
+        functools.partial(_kda_bwd_kernel, heads=heads, chunk=chunk),
+        out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct(b.shape, _F32),
+                   jax.ShapeDtypeStruct(gates.shape, _F32)),
+        grid=(bh // heads, steps),
+        in_specs=[qk, qk, vo, qk, gate, state, inverse, vo],
+        out_specs=(qk, qk, vo, qk, gate),
+        scratch_shapes=[pltpu.VMEM((heads, d_k, d_v), _F32)],
+        compiler_params=_params(),
+        interpret=interpret,
+        name="kda_bwd",
+    )(q, k, v, b, gates, states, inv, do)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _kda(q, k, v, b, gates, chunk, heads, pack, interpret):
+    return _kda_fwd_call(q, k, v, b, gates, chunk, heads, pack,
+                         interpret)[0]
+
+
+def _kda_fwd(q, k, v, b, gates, chunk, heads, pack, interpret):
+    o, states, inv = _kda_fwd_call(q, k, v, b, gates, chunk, heads, pack,
+                                   interpret)
+    o = checkpoint_name(o, KEEP_OUT)
+    states = checkpoint_name(states, KEEP_STATES)
+    inv = checkpoint_name(inv, KEEP_INVERSE)
+    return o, (q, k, v, b, gates, states, inv)
+
+
+def _kda_bwd(chunk, heads, pack, interpret, res, do):
+    return _kda_bwd_call(*res, do, chunk, heads, pack, interpret)
+
+
+_kda.defvjp(_kda_fwd, _kda_bwd)
+
+
 def _gates(g, beta, chunk):
     """g, beta [B, H, T] as the kernels take them, [B * H, T / C, 2, C]
     float32: ``b``, the cumulative sum of g from a chunk's first token,
@@ -499,33 +839,39 @@ def _gates(g, beta, chunk):
         [jnp.cumsum(rows(g), axis=-1), rows(beta)], axis=2)
 
 
-def _unfriendly(seq, d_k, d_v, chunk):
+def _unfriendly(seq, d_k, d_v, chunk, vector=False):
     """Why the kernels cannot take this shape, or "" when they can."""
     if seq % chunk:
         return "seq %d is not a multiple of the chunk %d" % (seq, chunk)
     if d_k % 8 or d_v % 8:
         return "head sizes %d | %d are not multiples of 8" % (d_k, d_v)
+    if vector and chunk % SUB:
+        return "the chunk %d is not a multiple of the sub-block %d" % (
+            chunk, SUB)
     return ""
 
 
-def delta_mode(seq, d_k, d_v, chunk=CHUNK, interpret=None):
+def delta_mode(seq, d_k, d_v, chunk=CHUNK, interpret=None, vector=False):
     """(mode: "tpu" | "interpret" | "off" as ``gated_delta`` runs a
-    sequence of ``seq`` here, why not the kernel or "")."""
+    sequence of ``seq`` here, under a ``vector`` decay or a scalar one;
+    why not the kernel or "")."""
     mode = resolve(interpret)
-    why = _unfriendly(seq, d_k, d_v, chunk) if mode != "off" else ""
+    why = _unfriendly(seq, d_k, d_v, chunk, vector) if mode != "off" else ""
     return ("off" if why else mode), why
 
 
 def gated_delta(q, k, v, g, beta, chunk=CHUNK, interpret=None):
     """q, k [B, H, T, d_k], v [B, H, T, d_v] in the compute dtype, g
-    (the log decay, <= 0) and beta [B, H, T] -> o [B, H, T, d_v] in v's
-    dtype; every sequence and head starts from a zero state.
-    Differentiable in all five.  The kernels where ``ops/mode.py``
-    allows them and the shape tiles, per shard of the declared batch
-    axis; else ``gated_delta_ref``."""
+    (the log decay, <= 0: [B, H, T] a scalar a head, or [B, H, T, d_k]
+    a vector, a channel of the key each) and beta [B, H, T] -> o [B, H,
+    T, d_v] in v's dtype; every sequence and head starts from a zero
+    state.  Differentiable in all five.  The kernels where
+    ``ops/mode.py`` allows them and the shape tiles, per shard of the
+    declared batch axis; else ``gated_delta_ref``."""
     batch, heads, seq, d_k = q.shape
     d_v = v.shape[-1]
-    mode, why = delta_mode(seq, d_k, d_v, chunk, interpret)
+    vector = g.ndim == q.ndim
+    mode, why = delta_mode(seq, d_k, d_v, chunk, interpret, vector)
     if mode == "off":
         if why:
             flash_attention.announce_fallback(
@@ -536,8 +882,16 @@ def gated_delta(q, k, v, g, beta, chunk=CHUNK, interpret=None):
 
     def op(q, k, v, g, beta):
         planes = lambda x: x.reshape(-1, *x.shape[2:])
-        o = _gdn(planes(q), planes(k), planes(v), _gates(g, beta, chunk),
-                 chunk, block, pack_of(seq, chunk), mode == "interpret")
+        scan = (chunk, block, pack_of(seq, chunk), mode == "interpret")
+        if vector:
+            chunks = g.astype(_F32).reshape(-1, seq // chunk, chunk, d_k)
+            o = _kda(planes(q), planes(k), planes(v),
+                     jnp.cumsum(chunks, axis=2).reshape(-1, seq, d_k),
+                     beta.astype(_F32).reshape(-1, seq // chunk, 1, chunk),
+                     *scan)
+        else:
+            o = _gdn(planes(q), planes(k), planes(v),
+                     _gates(g, beta, chunk), *scan)
         return o.reshape(-1, heads, seq, d_v)
 
     return per_batch_shard(op, (q, k, v, g, beta))

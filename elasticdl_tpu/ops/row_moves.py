@@ -278,6 +278,15 @@ def _call(words, halves, rows, idx, weight, other, out_dtype, interpret,
     if other is not None:
         out_shape.append(jax.ShapeDtypeStruct((tiles * tm, 1), jnp.float32))
         out_specs.append(tile(1))
+    # What Mosaic is asked for: the pipelined [tm, width] blocks
+    # (``other`` in, the result out, two buffers each), the slots, and
+    # the slots' budget once more for the sums and the narrow blocks.
+    # Over VMEM_LIMIT only at rows wider than the cells of hidden size
+    # 2,560 and less have (4,096: 33.5 MB of blocks; the call wanted
+    # 48.04 MB of a 48 MiB scope).
+    blocks = 2 * tm * width * (jnp.dtype(out_dtype).itemsize + (
+        other.dtype.itemsize if other is not None else 0))
+    scoped = blocks + 4 * k * tm * words.shape[-1] + _VMEM_SLOTS
     out = pl.pallas_call(
         functools.partial(
             _kernel, k=k, tm=tm, halves=halves, rows=rows, bits=bits,
@@ -294,7 +303,7 @@ def _call(words, halves, rows, idx, weight, other, out_dtype, interpret,
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=VMEM_LIMIT,
+            vmem_limit_bytes=max(VMEM_LIMIT, scoped),
         ),
         interpret=interpret,
         # The HLO instruction's name, so the trace's: the benchmark

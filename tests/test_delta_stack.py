@@ -431,8 +431,245 @@ def test_the_delta_scan_and_layer_stack_lines(monkeypatch, mode, ran):
         "a:window=0,rope=0")
     assert scan == (
         "delta scan: rows=128 heads=2 key_dim=16 value_dim=32 chunk=64 "
-        "conv_taps=4 neg_eigval=1 states=recomputed " + {
+        "conv_taps=4 neg_eigval=1 decay=head states=recomputed " + {
             "reference": "inverse=twin ",
             "interpreter": "inverse=forward inverse_mb=0.1 "}[ran] + ran)
     # with room for everything the states are among the kept names
     assert trace(room)[1] == scan.replace("recomputed", "kept")
+
+
+# -- a softmax layer then three KDA layers, every FFN an expert layer -------
+# (``layer_pattern=addd``, ``delta_kind=kda``: benchmark/reference/
+# solar-open2-250b.py, the recurrence under a decay a channel)
+
+KREF = manifest.load_named("reference", "solar-open2-250b")
+with open(os.path.join(manifest.BENCH_DIR, "configs",
+                       "solar-open2-250b.json")) as fh:
+    KPUBLISHED = json.load(fh)
+KCONFIG = merge(KPUBLISHED, KPUBLISHED["rehearsal"])
+KSHAPE = KREF.shape_of(KCONFIG)
+
+
+def _kspec(**override):
+    return load_model_spec("transformer", model_params=params_string(
+        dict(KCONFIG["cli"]["model_params"], **override)))
+
+
+@functools.lru_cache(maxsize=None)
+def _kcase(seed=3):
+    spec = _kspec()
+    params, tokens = KREF.inputs(
+        KCONFIG, spec.init_fn(jax.random.PRNGKey(seed)),
+        np.random.default_rng(seed))
+    return spec, params, jnp.concatenate([tokens, tokens[:, ::-1]])
+
+
+def _kreference(tokens, **how):
+    return lambda p: KREF.loss(p, tokens, **how, **KSHAPE)[0].mean()
+
+
+@functools.lru_cache(maxsize=None)
+def _kwanted():
+    spec, params, tokens = _kcase()
+    return jax.value_and_grad(_kreference(tokens))(params)
+
+
+@pytest.mark.parametrize("mode", ["off", "interpret"])
+def test_the_addd_expert_stack_matches_the_recurrence_a_channel(
+        monkeypatch, mode):
+    """Loss and every gradient leaf of the cut ``solar-open2-250b``
+    model at its rehearsal size (2 of 16 heads of both kinds, the
+    softmax layer's on 1 K/V head, 2 of 8 experts beside a shared one
+    under a sigmoid router with a bias) against the plain reference:
+    ``off`` the jnp twins under ``jax.checkpoint``, ``interpret`` the
+    vector-decay scan's, the convolution's, the flash and the dispatch's
+    kernels in the interpreter.  No gradient reaches ``expert_bias``."""
+    monkeypatch.setenv(SWITCH, mode)
+    spec, params, tokens = _kcase()
+    cfg = spec.config
+    assert cfg.remat and [k.op for k in cfg.kinds] == list("addd")
+    assert not any(k.dense for k in cfg.kinds) and cfg.delta_kind == "kda"
+    assert (cfg.num_heads, cfg.kv_heads, cfg.head_shares) == (2, 1, 8)
+    got, grads = jax.value_and_grad(_product(spec, tokens))(params)
+    want, wanted = _kwanted()
+    assert abs(float(got) - float(want)) <= LOSS_TOLERANCE * float(want)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    far = {}
+    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(wanted)):
+        name = jax.tree_util.keystr(path)
+        if "expert_bias" in name or not float(jnp.linalg.norm(w)):
+            # no gradient reaches the bias; a layer whose router sends
+            # the held experts no token leaves theirs, and the router's
+            # (which a share reaches through its experts alone), zero on
+            # both sides
+            assert not float(jnp.abs(g).max()), name
+            assert "expert_bias" in name or name.split("'")[-2] in (
+                "w_gate", "w_up", "w_down", "w_router"), name
+            continue
+        far[name] = float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w))
+    # a KDA layer's 12 mixer leaves and the softmax layer's 5, 2 norms
+    # and 7 FFN leaves (the bias apart) each, and embed, ln_f, lm_head;
+    # at most one layer's held experts idle
+    assert len(far) >= 3 * (12 + 9) + (5 + 9) + 3 - 4
+    assert max(far.values()) < GRAD_TOLERANCE, sorted(
+        far.items(), key=lambda item: -item[1])[:4]
+
+
+@pytest.mark.parametrize("piece", KREF.PIECES)
+def test_the_kda_reference_without_one_piece_fails_the_tolerance(piece):
+    """Each piece of the KDA layer and the softmax layer's gate moves
+    the loss by more than the tolerance the product is held to;
+    ``channels`` puts a head's mean log decay on every channel: a scalar
+    decay in the vector's place is not support."""
+    spec, params, tokens = _kcase()
+    want = float(_kwanted()[0])
+    less = float(jax.jit(_kreference(tokens, without=(piece,)))(params))
+    assert not abs(less - want) <= 20 * LOSS_TOLERANCE * want, (
+        piece, less, want)
+
+
+def _kda_share(w, heads, d, share, shares):
+    """A KDA layer's mixer weights cut to one share's heads: their
+    columns of every projection (the low-rank pairs' first halves feed
+    every head and stay whole), their rows of W_o."""
+    held = heads // shares
+    heads_of = np.arange(share * held, (share + 1) * held)
+    one = _head_columns(d, heads, share, shares)
+    columns = np.concatenate([one, heads * d + one, 2 * heads * d + one])
+    return dict(w, w_qkv=w["w_qkv"][:, columns],
+                delta_conv=w["delta_conv"][columns],
+                w_b=w["w_b"][:, heads_of], A_log=w["A_log"][heads_of],
+                w_a_up=w["w_a_up"][:, one], dt_bias=w["dt_bias"][one],
+                w_g_up=w["w_g_up"][:, one], b_g=w["b_g"][one],
+                wo=w["wo"][one])
+
+
+def _gqa_share(w, heads, kv_heads, head_dim, share, shares):
+    q = _head_columns(head_dim, heads, share, shares)
+    kv = _head_columns(head_dim, kv_heads, share, shares)
+    return dict(w, wq=w["wq"][:, q], w_attn_gate=w["w_attn_gate"][:, q],
+                wk=w["wk"][:, kv], wv=w["wv"][:, kv], wo=w["wo"][q])
+
+
+def _kuncut(kind, heads, kv_heads=0, **more):
+    """(the weights of one uncut layer, its normed input)."""
+    spec = _kspec(num_heads=heads, num_kv_heads=kv_heads or heads,
+                  head_shares=1, num_layers=1, layer_pattern=kind, **more)
+    params = KREF.inputs(dict(KCONFIG, seq_len=64), spec.init_fn(
+        jax.random.PRNGKey(5)), np.random.default_rng(5))[0]
+    x = jnp.asarray(np.random.default_rng(6).standard_normal((2, 64, 64)),
+                    jnp.float32)
+    return spec.config, KREF.layers_of(params)[0], x
+
+
+def test_the_eight_head_shares_of_a_kda_layer_add_up_to_the_uncut_mixer():
+    """8 x 1 of 8 heads: the held head's parts of the ``W_o`` product
+    sum to the uncut mixer's (a head has its own q, k, v, decay a
+    channel, write strength, state, output norm and gate; the low-rank
+    pairs' down projections feed every head and are whole on a chip),
+    and the program's mixer of a share is the reference's part."""
+    heads, d, eps = 8, KSHAPE["d_k"], KSHAPE["eps"]
+    _, w, x = _kuncut("d", heads)
+    whole = KREF.kda_mixer(x, w, heads, d, d, eps, True)
+    parts = [KREF.kda_mixer(x, _kda_share(w, heads, d, i, 8), 1, d, d, eps,
+                            True) for i in range(8)]
+    np.testing.assert_allclose(sum(parts), whole, atol=3e-5)
+    assert float(jnp.abs(parts[0] - whole).max()) > 1e-2
+    cfg = _kspec(num_heads=1, num_layers=1, layer_pattern="d").config
+    got = tfm._delta_mix(x, _kda_share(w, heads, d, 3, 8), cfg)
+    np.testing.assert_allclose(got, parts[3], atol=3e-5)
+
+
+def test_the_eight_head_shares_of_the_softmax_layer_add_up():
+    """16 query heads on 8 K/V heads, 8 shares of one K/V group each (2
+    query heads on 1 K/V head, as the cell's 8 on 1): no statistic
+    crosses heads (no QK norm), so the parts of the ``W_o`` product add
+    up to the uncut mixer, gate and all; the program's ``head // group``
+    read of a share's one K/V head is the reference's repeat."""
+    heads, kv, head_dim = 16, 8, KSHAPE["head_dim"]
+    _, w, x = _kuncut("a", heads, kv)
+    whole = KREF.attention(x, w, heads, kv, head_dim)
+    parts = [KREF.attention(x, _gqa_share(w, heads, kv, head_dim, i, 8),
+                            2, 1, head_dim) for i in range(8)]
+    np.testing.assert_allclose(sum(parts), whole, atol=3e-5)
+    cfg = _kspec(num_layers=1, layer_pattern="a").config
+    assert (cfg.num_heads, cfg.kv_heads) == (2, 1)
+    got = tfm._attention_mix(
+        x, _gqa_share(w, heads, kv, head_dim, 5, 8), cfg, None,
+        jnp.arange(64), cfg.kinds[0])[0]
+    np.testing.assert_allclose(got, parts[5], atol=3e-5)
+
+
+def test_the_expert_shares_and_the_shared_expert_once_are_the_uncut_layer():
+    """4 shares of 2 of 8 experts: every share routes over all 8 and
+    multiplies its own 2, and their routed parts plus the shared expert
+    counted ONCE are the uncut reference's FFN (all 8 held); the
+    program's expert layer of a share (``_ffn`` of a ``d`` layer's kind)
+    is that share's routed part plus the shared expert."""
+    cfg, w, u = _kuncut("d", 2, moe_experts_held=0)
+    assert w["w_gate"].shape[0] == 8
+    route = dict(top_k=KSHAPE["top_k"], norm_topk=KSHAPE["norm_topk"],
+                 scale=KSHAPE["scale"])
+    whole, chosen = KREF.experts(u, w, first=0, **route)
+    cut = lambda i: dict(w, **{name: w[name][2 * i:2 * i + 2]
+                               for name in ("w_gate", "w_up", "w_down")})
+    parts = [KREF.experts(u, cut(i), first=2 * i, **route)[0]
+             for i in range(4)]
+    np.testing.assert_allclose(sum(parts), whole, atol=3e-5)
+    assert int(chosen.sum(-1).min()) == int(chosen.sum(-1).max()) == 2
+    shared = KREF.shared_expert(u, w)
+    assert float(jnp.abs(shared).max()) > 1e-2
+    share = _kspec(num_layers=1, layer_pattern="d", moe_share_index=3).config
+    got = tfm._ffn(u, dict(cut(3), ln2=jnp.ones_like(w["ln2"])), share,
+                   None, dense=False)[0]
+    # ``_ffn`` returns the stream: u + FFN(norm(u)); hand it a normed u
+    normed = KREF.rmsnorm(u, jnp.ones_like(w["ln2"]), KSHAPE["eps"])
+    want = u + KREF.experts(normed, cut(3), first=6, **route)[0] + (
+        KREF.shared_expert(normed, w))
+    np.testing.assert_allclose(got, want, atol=3e-5)
+
+
+def test_the_kda_stacks_tree_count_and_decay_mask():
+    """The cut model's 840,875,672 parameters (the configuration's
+    ``reduced_why``), a KDA layer's leaves, and AdamW's mask: the decay
+    rates, the step biases a channel, the gate's bias, the output norm,
+    the taps and the router's bias are not decayed."""
+    spec = load_model_spec("transformer", model_params=params_string(
+        KPUBLISHED["cli"]["model_params"]))
+    shapes = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+    count = lambda tree: sum(
+        int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(tree))
+    assert count(shapes) == 840875672
+    layers = shapes["layers"]["period"]
+    ffn = ("w_router", "expert_bias", "w_gate", "w_up", "w_down", "ws_gate",
+           "ws_up", "ws_down", "ln1", "ln2")
+    mixer = lambda w: count({k: v for k, v in w.items() if k not in ffn})
+    assert mixer(layers["0"]) == 13631488
+    assert mixer(layers["1"]) == 18135176
+    assert layers["1"]["dt_bias"].shape == (1, 8 * 128)
+    assert layers["1"]["w_a_up"].shape == (1, 128, 8 * 128)
+    assert "w_a" not in layers["1"] and "w_out_gate" not in layers["1"]
+    mask = tfm._decayed(shapes)["layers"]["period"]["1"]
+    assert {k for k, v in mask.items() if not v} == {
+        "A_log", "dt_bias", "b_g", "o_norm", "delta_conv", "expert_bias"}
+
+
+@pytest.mark.parametrize("mode,ran", [("off", "reference"),
+                                      ("interpret", "interpreter")])
+def test_the_kda_stacks_lines(monkeypatch, mode, ran):
+    monkeypatch.setenv(SWITCH, mode)
+    spec, params, tokens = _kcase()
+
+    def run():
+        jax.eval_shape(_product(spec, tokens), params)
+
+    stack, scan = _lines(run, "delta scan:", "layer stack:")
+    assert stack == (
+        "layer stack: pattern=addd lead=- period=addd periods=1 tail=- "
+        "dense_layers=0 experts_held=2/8 shared_expert=48 heads_held=2/16 "
+        "a:window=0,rope=0")
+    assert scan == (
+        "delta scan: rows=128 heads=2 key_dim=32 value_dim=32 chunk=64 "
+        "conv_taps=4 neg_eigval=1 decay=channel rank=16 states=recomputed "
+        + {"reference": "inverse=twin ",
+           "interpreter": "inverse=forward inverse_mb=0.1 "}[ran] + ran)

@@ -959,3 +959,90 @@ def test_a_scan_of_several_turns_is_handed_to_the_compiler_as_it_was(
     spec = tfm.model_spec(**_model_params("olmo1b"))
     text = _step(spec, one_chip, 8, 2048).as_text(dialect="hlo")
     assert text.count(" while(") == 2 and text.count(" opt-barrier(") == 3 + 1
+
+
+# -- the solar-open2-250b cell's shapes (benchmark/configs/solar-open2-250b.json)
+
+
+def test_the_vector_decay_scan_compiles_at_the_cells_shape(one_chip):
+    """8 heads x 16,384 x 128 | 128, one sequence, a log decay a channel
+    of the key: ``kda_fwd`` (a block of 4 heads' float32 states
+    resident; a pack's two score matrices by 19 products of [256, 128]
+    x [128, 128], every exponent against a reference row; the inverses
+    by the scalar decay's ten joins) and ``kda_bwd`` for a described
+    v5e, under names the scalar decay's reader (``gdn_(fwd|bwd)``) does
+    not match and the VMEM limit the calls set.  The forward writes what
+    ``gdn_fwd`` writes; the backward dq, dk, dv, the decay's cotangent a
+    channel in float32 and the write strength's."""
+    from elasticdl_tpu.ops import gated_delta as gd
+
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip)
+    q = shape(1, 8, 16384, 128)
+    g = shape(1, 8, 16384, 128, dtype=jnp.float32)
+    beta = shape(1, 8, 16384, dtype=jnp.float32)
+
+    def fwd_bwd(q, k, v, g, beta, cot):
+        out, pull = jax.vjp(lambda *a: gd.gated_delta(
+            *a, interpret=False), q, k, v, g, beta)
+        return out, pull(cot)
+
+    assert gd.delta_mode(16384, 128, 128, interpret=False, vector=True) == (
+        "tpu", "")
+    assert gd.delta_mode(16384, 128, 128, 40, interpret=False,
+                         vector=True)[0] == "off"
+    text = jax.jit(fwd_bwd).lower(q, q, q, g, beta, q).compile().as_text()
+    calls = _mosaic_calls(text)
+    assert len(calls) == 2, calls
+    fwd = next(c for c in calls if "kda_fwd" in c.split(" = ")[0])
+    bwd = next(c for c in calls if "kda_bwd" in c.split(" = ")[0])
+    assert not any(re.search(r"gdn_(fwd|bwd)", c) for c in calls)
+    results = lambda call: call.split(" custom-call(")[0]
+    assert re.findall(r"\w+\[[\d,]+\]", results(fwd)) == [
+        "bf16[8,16384,128]", "f32[8,256,128,128]", "bf16[8,128,64,128]"]
+    assert results(bwd).count("bf16[8,16384,128]") == 3       # dq, dk, dv
+    assert "f32[8,16384,128]" in results(bwd)                 # dg a channel
+    assert "f32[8,256,1,64]" in results(bwd)                  # dbeta
+
+
+def test_the_kda_expert_stacks_step_fits_a_v5e_with_nothing_kept(
+        one_chip, monkeypatch):
+    """The ``solar-open2-250b.seq16384`` cell's whole training step (one
+    sequence of 16,384 through a gated NoPE GQA layer at 8 query heads
+    on 1 K/V head and three KDA layers at 8 of 64 heads, a 320-wide
+    router over 8 held experts of 1,280 and a shared expert in each, an
+    untied head over 24,576 ids, AdamW; 840,875,672 parameters) through
+    the TPU's compiler with nothing kept: the configuration's condition
+    for its 8-way head share, so the 16-way fallback is not taken.  The
+    scan runs once forward and once again in each KDA layer's backward."""
+    from elasticdl_tpu.models import remat_keep as rk
+    from elasticdl_tpu.ops.mode import SWITCH
+
+    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
+    spec = tfm.model_spec(**_model_params("solar-open2-250b"))
+    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+    state = jax.eval_shape(spec.optimizer.init, params)
+    nbytes = lambda tree: sum(
+        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
+    assert nbytes(params) == 4 * 840875672
+    held = 2 * nbytes(params) + nbytes(state)
+
+    compiled = _step(spec, one_chip, 1, 16384).compile()
+    stats = compiled.memory_analysis()
+    counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
+    # 14.98 GB of the 16.91 (the chip's peak with 1.0 GB kept: 15.08)
+    assert counted < 0.95 * 16911433728, counted
+    assert 14.9e9 < counted < 15.1e9, counted
+    # ``remat_keep``'s estimate stands over it, by the kda layer's
+    # decays a channel (+0.11 GB; -0.16 without that term)
+    estimate = held + rk.step_bytes(spec.config, params, 16384)
+    assert 0 < estimate - counted < 0.5e9, (estimate, counted)
+    text = compiled.as_text()
+    names = [c.split(" = ")[0].lstrip("%") for c in _mosaic_calls(text)]
+    count = lambda name: len([c for c in names if re.search(
+        r"(^|_)" + name + r"(__)?\.\d+$", c)])
+    assert (count("kda_fwd"), count("kda_bwd")) == (6, 3), names
+    assert (count("gdn_fwd"), count("gdn_bwd")) == (0, 0), names
+    assert (count("sconv_silu_fwd"), count("sconv_silu_bwd")) == (6, 3)
+    assert (count("flash_fwd"), count("flash_bwd")) == (2, 1), names
+    assert not _updates_in_matmuls(text)

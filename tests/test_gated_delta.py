@@ -301,6 +301,143 @@ def test_a_length_the_kernel_does_not_tile_takes_the_twin_and_says_so(
     assert far(got, recurrence(*operands)) < 2e-5
 
 
+# -- a decay that is a vector a head (Kimi Delta Attention) --------------------
+
+
+def channel_recurrence(q, k, v, g, beta):
+    """S' = S Diag(alpha); u = beta (v - S' k); S = S' + u k^T; o = S q,
+    ``g`` [B, H, T, d_k] a log decay a channel of the key."""
+    q, k, v, g, beta = (x.astype(jnp.float32) for x in (q, k, v, g, beta))
+
+    def token(S, x):
+        q, k, v, g, beta = x
+        S = S * jnp.exp(g)[..., None, :]
+        u = beta[..., None] * (v - jnp.einsum("bhvk,bhk->bhv", S, k))
+        S = S + u[..., :, None] * k[..., None, :]
+        return S, jnp.einsum("bhvk,bhk->bhv", S, q)
+
+    xs = tuple(jnp.moveaxis(x, 2, 0) for x in (q, k, v, g, beta))
+    start = jnp.zeros(q.shape[:2] + (v.shape[-1], q.shape[-1]), jnp.float32)
+    return jnp.moveaxis(lax.scan(token, start, xs)[1], 0, 2)
+
+
+def draw_channels(seed, dtype=jnp.float32, seq=T):
+    """``draw``'s operands with a decay a channel: head 0's channels
+    decay by 0.4-0.99 a token each; head 1's EVEN channels forget
+    everything at every token (alpha ~ 1e-9) beside ODD ones that forget
+    nothing (alpha > 0.999): the product (K * exp(b)) (K * exp(-b))^T
+    would overflow in the first and the kernel must not lose the second;
+    head 2 nearly nothing anywhere."""
+    q, k, v, _, beta = draw(seed, True, dtype, seq=seq)
+    r = np.random.default_rng(seed + 100)
+    g = -r.uniform(0.01, 1.0, (B, H, seq, DK))
+    g[:, 1, :, 0::2] = -r.uniform(10.0, 30.0, (B, seq, DK // 2))
+    g[:, 1, :, 1::2] = -r.uniform(0.0, 1e-3, (B, seq, DK // 2))
+    g[:, 2] = -r.uniform(0.0, 1e-3, (B, seq, DK))
+    return q, k, v, jnp.asarray(g, jnp.float32), beta
+
+
+@functools.lru_cache(maxsize=None)
+def wanted_channels():
+    return out_and_grads(channel_recurrence, draw_channels(1))
+
+
+@pytest.mark.parametrize("chunk", [16, 32, 96])
+@pytest.mark.parametrize("which", sorted(IMPLEMENTATIONS))
+def test_the_vector_decays_chunk_form_is_the_recurrence_and_every_gradient(
+        which, chunk):
+    """Sub-blocks of 16 in chunks of 16 (the direct columns alone), 32
+    and 96 (reference rows between sub-blocks too; six of them a chunk),
+    packs of two chunks and of one: o, dq, dk, dv, dg A CHANNEL and
+    dbeta each within 2e-5 of the token-by-token recurrence's, and the
+    head whose channels forget everything beside channels that forget
+    nothing held alone (every exponent is a difference against a row
+    between the pair, so nothing overflows and nothing is lost)."""
+    operands = draw_channels(1)
+    got = out_and_grads(IMPLEMENTATIONS[which](chunk), operands)
+    names = ("o", "dq", "dk", "dv", "dg", "dbeta")
+    assert got[4].shape == operands[3].shape
+    errors = {name: far(a, b) for name, a, b in zip(
+        names, got, wanted_channels())}
+    assert max(errors.values()) < 2e-5, errors
+    assert all(bool(jnp.isfinite(a).all()) for a in got)
+    for head in (1, 2):
+        for name, a, b in zip(names, got, wanted_channels()):
+            assert far(a[:, head], b[:, head]) < 2e-5, (head, name)
+
+
+@pytest.mark.parametrize("which", sorted(IMPLEMENTATIONS))
+def test_a_vector_decay_constant_across_channels_is_the_scalar_kernels(
+        which):
+    """The scalar decay broadcast to every channel of the key is the
+    scalar recurrence: the vector path's output and q, k, v, beta
+    gradients are the scalar path's, and its dg summed over the channels
+    the scalar's dg."""
+    q, k, v, g, beta = draw(1, True)
+    wide = jnp.broadcast_to(g[..., None], g.shape + (DK,))
+    fn = IMPLEMENTATIONS[which](32)
+    scalar = out_and_grads(fn, (q, k, v, g, beta))
+    vector = out_and_grads(fn, (q, k, v, wide, beta))
+    for at, (a, b) in enumerate(zip(vector, scalar)):
+        a = a.sum(-1) if at == 4 else a
+        assert far(a, b) < 2e-5, at
+
+
+def test_the_vector_decay_in_bfloat16_stays_within_its_own_tolerance():
+    """bfloat16 q, k, v; the decays, their cumulative sums, ``A``, the
+    inverse's joins and the state float32: under 1e-2 of the float32
+    recurrence on the same bfloat16 values in the output and every
+    gradient, dg a channel in float32."""
+    operands = draw_channels(2, jnp.bfloat16)
+    got = out_and_grads(IMPLEMENTATIONS["kernel"](32), operands)
+    want = out_and_grads(channel_recurrence, operands)
+    assert got[0].dtype == jnp.bfloat16 and got[4].dtype == jnp.float32
+    errors = [far(a, b) for a, b in zip(got, want)]
+    assert 1e-4 < max(errors) < 1e-2, errors
+
+
+def test_a_vector_decays_calls_have_names_of_their_own_and_share_the_joins():
+    """``kda_fwd`` / ``kda_bwd``: names the scalar decay's trace reader
+    (``gdn_(fwd|bwd)``) does not match; the forward runs the scalar
+    kernel's ten joins once a head for a pack of two chunks of 64, the
+    backward none, and takes the forward's inverses as an operand."""
+    q, k, v, g, beta = draw_channels(3, seq=256)
+    fn = functools.partial(gd.gated_delta, chunk=64, interpret=True)
+    whole = jax.make_jaxpr(lambda *a: jax.vjp(fn, *a)[1](a[2]))(
+        q, k, v, g, beta)
+    found = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found[eqn.params["name"]] = eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(whole.jaxpr)
+    assert sorted(found) == ["kda_bwd", "kda_fwd"]
+    assert highest(found["kda_fwd"]) == 10 * H
+    assert highest(found["kda_bwd"]) == 0
+    assert tuple(found["kda_bwd"].invars[6].aval.shape) == tuple(
+        found["kda_fwd"].params["out_avals"][2].shape)
+
+
+def test_a_vector_decay_at_a_length_off_the_chunk_takes_the_twin(monkeypatch):
+    """T = 100 is no multiple of the chunk 32: the twin pads the last
+    chunk with tokens that neither decay nor write, and says so; a chunk
+    that is no multiple of the sub-block is refused by name."""
+    said = []
+    monkeypatch.setattr(gd.flash_attention, "announce_fallback",
+                        lambda *a: said.append(a))
+    assert gd.delta_mode(80, DK, DV, 40, interpret=True, vector=True) == (
+        "off", "the chunk 40 is not a multiple of the sub-block 16")
+    assert gd.delta_mode(80, DK, DV, 40, interpret=True) == ("interpret", "")
+    operands = draw_channels(4, seq=100)
+    got = gd.gated_delta(*operands, chunk=32, interpret=False)
+    assert said and said[0][0] == "gated_delta" and said[0][3] == "tpu"
+    assert far(got, channel_recurrence(*operands)) < 2e-5
+
+
 # -- the convolution with a SiLU behind it -----------------------------------
 
 

@@ -71,6 +71,8 @@ AS_TYPED = {
     "delta_key_dim": ("16", 16, ""),
     "delta_value_dim": ("32", 32, ""),
     "delta_neg_eigval": ("true", True, ""),
+    "delta_kind": ("kda", "kda", ""),
+    "delta_rank": ("16", 16, ""),
     "head_shares": ("2", 2, ""),
 }
 FIELDS = [field for field in dataclasses.fields(tfm.TransformerConfig)
@@ -155,6 +157,11 @@ def test_remats_two_strings_are_refused_by_name(word):
     (dict(moe_router="softmin"), "moe_router"),
     (dict(qk_norm="yes"), "qk_norm"),
     (dict(post_norms="false"), "post_norms"),
+    (dict(delta_kind="ssm"), "delta_kind"),
+    (dict(num_layers=2, layer_pattern="ad", delta_key_dim=16,
+          delta_value_dim=16, delta_kind="kda"), "delta_rank"),
+    (dict(num_layers=2, layer_pattern="ad", delta_key_dim=16,
+          delta_value_dim=16, delta_rank=8), "delta_kind=gdn"),
 ])
 def test_a_configuration_built_directly_is_refused_where_it_is_built(
         bad, match):
@@ -166,3 +173,31 @@ def test_no_property_of_the_configuration_refuses_anything():
     for name, member in vars(tfm.TransformerConfig).items():
         if isinstance(member, property):
             assert "raise" not in inspect.getsource(member.fget), name
+
+
+KDA = dict(num_layers=4, layer_pattern="addd", rope_kinds="w", num_heads=2,
+           num_kv_heads=1, head_dim=32, dim=64, delta_key_dim=32,
+           delta_value_dim=32, delta_kind="kda", delta_rank=16, conv_kernel=4,
+           moe_experts=8, moe_experts_held=2, vocab_size=64, max_seq_len=64)
+
+
+@pytest.mark.parametrize("what,call", [
+    ("decode_step", lambda cfg: tfm.decode_step(None, cfg, None, 0, None)),
+    ("prefill", lambda cfg: tfm.prefill(None, cfg, None, 8)),
+    ("forward_pipelined", lambda cfg: tfm.forward_pipelined(
+        None, None, cfg, None, 2)),
+    ("a model-parallel mesh", tfm.param_specs),
+])
+def test_a_kda_stack_is_refused_in_the_one_place_by_its_fields(what, call):
+    """Decoding, the pipelined forward and a mesh refuse the stack
+    through ``_refuse``'s rows, naming the delta layer's kind beside
+    the pattern (and, a mesh, the expert share)."""
+    cfg = tfm.TransformerConfig(**KDA)
+    with pytest.raises(NotImplementedError) as refusal:
+        call(cfg)
+    said = str(refusal.value)
+    assert said.startswith(what + " does not run a stack whose layers "
+                           "differ (layer_pattern='addd', dense_layers=0, "
+                           "delta_kind=kda)")
+    assert "gdn or kda" in said
+    assert ("moe_experts_held=2" in said) == (what == "a model-parallel mesh")

@@ -4,6 +4,7 @@
     python3 tools/gated_delta_on_chip.py [--heads 15] [--t 16384]
         [--key_dim 96] [--value_dim 192] [--chunk 64 ...]
         [--head_block 5 ...] [--pack 2 ...] [--iters 3] [--no_reference]
+        [--decay scalar|vector]
 
 Prints one JSON line a (chunk, head block, pack: the chunks a grid step
 walks behind one build of their inverses): the device time of the
@@ -17,7 +18,11 @@ beside it, once, the device time of the jnp twin
 (``ops/gated_delta.gated_delta_ref``) forward and forward + backward,
 and the largest relative distance of the kernels' output and gradients
 from the twin's.  The default shape is the ``olmo-hybrid-7b.seq16384``
-cell's, one layer.  Exits 3 without a TPU: a CPU timing is no device
+cell's, one layer; ``--decay vector --heads 8 --key_dim 128 --value_dim
+128`` is the ``solar-open2-250b.seq16384`` cell's (a log decay a channel
+of the key, calls ``kda_fwd`` / ``kda_bwd``, counted by
+``benchmark/kernels/kda.py``; channel 0 of every head forgets at once
+and channel 1 never, beside the others' 0.3 to 0.9999 a token).  Exits 3 without a TPU: a CPU timing is no device
 number (``--t 256 --heads 2`` there is a rehearsal: every call runs in
 the interpreter, none is timed).
 """
@@ -45,6 +50,8 @@ def main():
                     help="chunks a grid step walks; 0: the op's own choice")
     ap.add_argument("--iters", type=int, default=3)
     ap.add_argument("--no_reference", action="store_true")
+    ap.add_argument("--decay", choices=("scalar", "vector"),
+                    default="scalar")
     args = ap.parse_args()
 
     import jax
@@ -61,7 +68,10 @@ def main():
         print("gated_delta_on_chip: platform is %r, not tpu" % dev.platform,
               file=sys.stderr)
         return 3
-    work = manifest.load_named("kernels", "gated_delta").call
+    vector = args.decay == "vector"
+    name = "kda_" if vector else "gdn_"
+    work = manifest.load_named(
+        "kernels", "kda" if vector else "gated_delta").call
     H, T, dk, dv = args.heads, args.t, args.key_dim, args.value_dim
     rng = np.random.default_rng(0)
     unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)
@@ -70,8 +80,11 @@ def main():
     k = bf16(unit(rng.standard_normal((1, H, T, dk))))
     v = bf16(rng.standard_normal((1, H, T, dv)))
     # decays from 0.3 to 0.9999 a token, write strengths in (0, 2)
-    g = jnp.asarray(-np.exp(rng.uniform(np.log(1e-4), np.log(1.2),
-                                        (1, H, T))), jnp.float32)
+    g = -np.exp(rng.uniform(np.log(1e-4), np.log(1.2),
+                            (1, H, T) + (dk,) * vector))
+    if vector:
+        g[..., 0], g[..., 1] = -30.0, 0.0
+    g = jnp.asarray(g, jnp.float32)
     beta = jnp.asarray(rng.uniform(0.05, 1.95, (1, H, T)), jnp.float32)
     do = bf16(rng.standard_normal((1, H, T, dv)))
     operands = (q, k, v, g, beta)
@@ -102,16 +115,17 @@ def main():
         ops_f, all_f = device_ms(forward, operands, args.iters)
         ops_b, all_b = device_ms(backward, operands, args.iters)
         row = {"device": dev.device_kind, "heads": H, "t": T,
-               "key_dim": dk, "value_dim": dv, "chunk": chunk,
+               "key_dim": dk, "value_dim": dv, "decay": args.decay,
+               "chunk": chunk,
                "head_block": block or next(
                    n for n in gd.HEAD_BLOCKS if H % n == 0),
                "pack": gd.pack_of(T, chunk)}
         for kind, ops in (("fwd", ops_f), ("bwd", ops_b)):
             # under ``jax.vjp`` XLA wraps the name: transpose_jvp_..
-            ms = sum(v for op, v in ops.items() if "gdn_" + kind in op)
+            ms = sum(v for op, v in ops.items() if name + kind in op)
             if not ms:      # the rehearsal: the calls ran, untimed
                 if on_chip:
-                    print("no gdn_%s among %s" % (kind, sorted(ops)),
+                    print("no %s%s among %s" % (name, kind, sorted(ops)),
                           file=sys.stderr)
                 continue
             flops, nbytes = work(1, H, T, dk, dv, kind)
