@@ -286,6 +286,28 @@ def table(cfg, rows):
     return [entry[:3] for entry in _entries(cfg, rows)]
 
 
+def _nbytes(tree):
+    """Bytes of a tree's leaves as they are stored."""
+    return sum(a.size * jnp.dtype(a.dtype).itemsize
+               for a in jax.tree_util.tree_leaves(tree))
+
+
+def _unrolled(layers):
+    """(the groups of the stack that a scan runs over several turns,
+    stacked; the layers XLA unrolls, one tree each): a loop of one turn
+    it unrolls, and a leading or trailing layer is outside any loop
+    (``transformer.init_params``: a plan's ``lead`` / ``period`` /
+    ``tail``, or one stacked tree)."""
+    if "period" in layers:
+        period = list(layers["period"].values())
+        singles = list(layers["lead"].values()) + list(
+            layers["tail"].values())
+    else:
+        period, singles = [layers], []
+    turns = jax.tree_util.tree_leaves(period)[0].shape[0]
+    return (period, singles) if turns > 1 else ([], singles + period)
+
+
 def _weight_copies(layers, copy):
     """Bytes of the stack's compute-dtype weight copies that stand at
     once (``copy(tree)``: those of a tree's leaves).  A layer casts its
@@ -296,16 +318,48 @@ def _weight_copies(layers, copy):
     running layer's and the next one's, which XLA fetches ahead (the
     TPU compiler's buffer assignment of ``lfm2-24b-a2b``'s step: 0.3-0.5
     GB of the five layers' 0.9 at the peak)."""
-    if "period" in layers:       # ``transformer.init_params``: a plan
-        period = list(layers["period"].values())
-        singles = list(layers["lead"].values()) + list(
-            layers["tail"].values())
-    else:
-        period, singles = [layers], []
-    turns = jax.tree_util.tree_leaves(period)[0].shape[0]
-    if turns > 1:
-        return copy(period) + sum(sorted(map(copy, singles))[-2:])
-    return sum(sorted(map(copy, singles + period))[-2:])
+    scanned, unrolled = _unrolled(layers)
+    return copy(scanned) + sum(sorted(map(copy, unrolled))[-2:])
+
+
+def grads_standing(cfg, params, rows, kept=()):
+    """Bytes of the layer stack's gradients, which the caller counted
+    whole, that stand where a layer's backward is the step's peak, with
+    the entries labelled ``kept`` kept.
+
+     - A group the stack scans over several turns: its gradient is one
+       stacked array that the loop fills and the update reads after it.
+       Whole.
+     - The layers XLA unrolls (``_unrolled``; the test
+       ``transformer._updates_apart`` makes): each matrix goes to AdamW
+       behind its own layer's backward through a barrier of its own, so
+       the stack's never stand at once.  How much does is read from
+       the TPU compiler's count and schedule of ``olmo-hybrid-7b``'s
+       step for a described v5e over six kept lists (PERF.md section
+       6, PR 50): in the first layer back-propagated, where every kept
+       value still stands, no gradient of the stack exists that the
+       layer's own term does not hold; in a later one the layer
+       before's, whose update is in flight, stands where that layer's
+       kept values stood (with nothing kept the count is 0.56 GB over
+       an estimate that has none and 0.12 under one that has a
+       layer's).  So: ONE layer's worth, the largest, less what a
+       layer done gives back (the kept entries that every layer
+       makes).
+     - But only in a stack without expert layers, where the one-layer
+       term of ``step_bytes`` describes what a backward holds.  In a
+       stack with them the whole-gradient count is all that stands in
+       for the dispatch's 1.4-2.3 GB of temporaries, which that term
+       does not have (``OVER`` in tests/test_remat_keep.py): whole, as
+       before PR 50, until the dispatch's backward has an inventory
+       from shapes (ROADMAP A3 (t), C18)."""
+    scanned, unrolled = _unrolled(params["layers"])
+    each = sorted(map(_nbytes, unrolled))
+    if not each or not all(kind.dense for kind in cfg.kinds):
+        return _nbytes(scanned) + sum(each)
+    given_back = sum(per_layer
+                     for label, _, per_layer, layers in _entries(cfg, rows)
+                     if label in kept and layers == cfg.num_layers)
+    return _nbytes(scanned) + max(0, each[-1] - given_back)
 
 
 def step_bytes(cfg, params, rows, kept=()):
@@ -322,7 +376,8 @@ def step_bytes(cfg, params, rows, kept=()):
        head is tied), while the stack's gradients, which the caller
        counted, do not exist yet; or one layer's backward with its
        second forward, of the layer kind that needs most (a leading
-       dense layer's beside the expert layers').  A kept product of
+       dense layer's beside the expert layers'), beside what of those
+       gradients stands there (``grads_standing``).  A kept product of
        that layer's own is read from the scan's stack and not made
        again, so it leaves the term: a dense layer's gate and up leave
        their product and a cotangent; an expert layer's term, which
@@ -338,21 +393,20 @@ def step_bytes(cfg, params, rows, kept=()):
        its gradient is the last thing the backward makes.
 
     Held to the chips' measured peaks for the cells of the benchmark
-    (tests/test_remat_keep.py: -0.1 / +0.9 GB; +0.95 in the one cell
-    whose stack has both a leading dense layer and 2.4 GB of
+    (tests/test_remat_keep.py: -0.1 / +0.9 GB; +0.95 in the two cells
+    whose unrolled stack has expert layers and 2.4-2.6 GB of
     gradients: ``OVER`` there says where the sum is off, and it is
     the sum, no term of it) and to the TPU compiler's own count of the
-    two share cells' whole steps (tests/test_flash_compile_tpu.py:
+    two share cells' whole steps and of the dense hybrid cell's
+    (tests/test_flash_compile_tpu.py, tests/test_remat_compile_tpu.py:
     over, by under 0.5 GB)."""
     dtype = jnp.dtype(cfg.dtype)
     size = dtype.itemsize
-    leaves = jax.tree_util.tree_leaves
-    nbytes = lambda a: a.size * jnp.dtype(a.dtype).itemsize
     copy = lambda tree: sum(a.size * size * (a.dtype != dtype)
-                            for a in leaves(tree))
+                            for a in jax.tree_util.tree_leaves(tree))
     copies = (copy(params) - copy(params["layers"])
               + _weight_copies(params["layers"], copy))
-    stack_grads = sum(nbytes(a) for a in leaves(params["layers"]))
+    stack_grads = _nbytes(params["layers"])
     stream = rows * cfg.dim * size
     carries = (cfg.num_layers + 1) * stream
     head = rows * cfg.vocab_size * size * (2 if cfg.tied_embeddings else 1)
@@ -385,8 +439,10 @@ def step_bytes(cfg, params, rows, kept=()):
         channels = rows * cfg.num_heads * cfg.delta_key_dim * 4
         layer += 4 * channels - min(own(("delta_decay",)), channels)
     embed = params["embed"]
-    unread = 0 if cfg.tied_embeddings else copy(embed) + nbytes(embed)
-    return copies + carries + max(head - stack_grads, layer) - unread
+    unread = 0 if cfg.tied_embeddings else copy(embed) + _nbytes(embed)
+    absent = stack_grads - grads_standing(cfg, params, rows, kept)
+    return copies + carries + max(head - stack_grads,
+                                  layer - absent) - unread
 
 
 def choose(cfg, params, rows, room):
@@ -410,16 +466,17 @@ def choose(cfg, params, rows, room):
 
 
 @functools.lru_cache(maxsize=None)
-def announce_keep(names, kept, budget, need, peak, layers, rows,
+def announce_keep(names, kept, budget, need, peak, standing, layers, rows,
                   fallback):
     """Once per compiled shape, by the logger ``announce_tiles`` uses:
     what the layer stack keeps for its backward, of one shard of the
-    trainer's data axis."""
+    trainer's data axis, and what of the stack's gradients the estimate
+    took to stand at the peak (``grads_standing``)."""
     flash_attention.logger.info(
         "remat keep: names=%s bytes=%d budget=%d need=%d "
-        "predicted_peak=%d layers=%d rows=%d fallback=%d",
-        ",".join(names) or "-", kept, budget, need, peak, layers, rows,
-        fallback)
+        "predicted_peak=%d grads_standing=%d layers=%d rows=%d "
+        "fallback=%d", ",".join(names) or "-", kept, budget, need, peak,
+        standing, layers, rows, fallback)
 
 
 _KEPT = contextvars.ContextVar("elasticdl_remat_kept", default=())
@@ -453,6 +510,9 @@ def names_for(cfg, params, tokens_shape):
     rows = tokens_shape[0] * tokens_shape[1] // batch_shard.shards()
     names, kept, budget, peak = choose(cfg, params, rows, room)
     need = peak - kept - (room.limit - room.free)
-    announce_keep(names, kept, budget, need, peak, cfg.num_layers, rows,
-                  int(not room.free))
+    labels = [label for label, entry, _ in table(cfg, rows)
+              if set(entry) <= set(names)]
+    announce_keep(names, kept, budget, need, peak,
+                  grads_standing(cfg, params, rows, labels), cfg.num_layers,
+                  rows, int(not room.free))
     return names

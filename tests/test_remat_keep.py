@@ -245,17 +245,21 @@ CELLS = {
         ROUTED[:7] + (rk.KEEP_ATTN_GATE, rk.KEEP_STREAM, rk.KEEP_GATE,
                       rk.KEEP_UP, rk.KEEP_SHARED_GATE, rk.KEEP_SHARED_UP,
                       md.KEEP_GATE)),
-    # three gated-delta layers and a full one, no experts: the flash
-    # residuals, q, k, v, the stream, the decays and the output gate's
-    # projection (1.04 GB); the scan's output and states, the MLPs'
-    # products and the q, k, v projection do not fit by this estimate
-    # (my chip runs, PR 44: 12.897 GB traced, 12.898 on six untraced
-    # seeds; ``OVER`` below)
+    # three gated-delta layers and a full one, no experts, one unrolled
+    # period: the flash residuals, q, k, v, the stream, the decays, the
+    # output gate's projection, the four MLPs' gate and up products and
+    # the delta layers' projection of q, k, v (4.50 GB); the scan's
+    # output, states and inverses (1.51 GB) and the convolved projection
+    # do not fit (my chip runs, PR 50: 15.706 GB traced and untraced;
+    # 12.838 with the first five entries, 1.04 GB, which is all that
+    # fitted while the stack's 2.68 GB of gradients were counted whole)
     "olmo-hybrid-7b.seq16384": (
-        "olmo-hybrid-7b", 1, 1, 12.898,
-        ["flash", "qkv", "stream", "delta_decay", "delta_gate"],
+        "olmo-hybrid-7b", 1, 1, 15.706,
+        ["flash", "qkv", "stream", "delta_decay", "delta_gate", "ffn_gate",
+         "ffn_up", "delta_in"],
         rk.ATTN_NAMES + (rk.KEEP_Q, rk.KEEP_K, rk.KEEP_V, rk.KEEP_STREAM,
-                         rk.KEEP_DELTA_DECAY, rk.KEEP_DELTA_GATE)),
+                         rk.KEEP_DELTA_DECAY, rk.KEEP_DELTA_GATE,
+                         rk.KEEP_GATE, rk.KEEP_UP, rk.KEEP_DELTA_IN)),
     # a gated softmax layer and three KDA layers, every FFN an expert
     # layer under a 1/40 share: the flash residuals, the route, q, k, v,
     # the gate's projection, the stream, the KDA layers' two [rows, 128]
@@ -273,7 +277,7 @@ CELLS = {
 
 
 # How far over the chip's peak a cell's estimate may read, GB, where it
-# is not the 0.9 the six older cells are held to.  The cell with a gate
+# is not the 0.9 the seven other cells are held to.  The cell with a gate
 # on attention's output reads +0.95, and not because of the gate: the
 # TPU compiler's buffer assignment of its whole step (a described v5e;
 # 15.082 GB in all, the chip's 15.086) has its peak in the first layer
@@ -288,19 +292,16 @@ CELLS = {
 # With every entry of the table kept the chip reads 15.520 GB and this
 # estimate 17.377 (my chip run, PR 40, ``c5``): what it keeps off the
 # list is PERF.md section 6's, and a ``perf_opt`` issue's to repair.
-# The dense hybrid cell reads +3.07: its stack is one period, so XLA
-# unrolls it and a layer's AdamW update runs behind its backward; of
-# the 3.06 GB of gradients the trainer states, the stack's 2.68 never
-# stand at once (the TPU compiler's count of the whole step, a
-# described v5e: 12.81 GB with these names kept, the chip's 12.90),
-# while ``step_bytes`` counts them whole beside the kept names.  What
-# that costs is PERF.md section 6's (PR 44): ~3 GB of room unused,
-# which the four MLPs' gate and up products (2.9 GB) would fill.
 # The KDA cell reads +0.95, as the other gated cell of one unrolled
 # period does and for its reason: the stack's 2.56 GB of gradients are
 # counted whole beside the kept names where they never stand at once.
-OVER = {"trinity-mini.seq16384": 1.0, "olmo-hybrid-7b.seq16384": 3.2,
-        "solar-open2-250b.seq16384": 1.0}
+# Both wait for the expert half of ROADMAP A3 (t): in a stack without
+# expert layers ``rk.grads_standing`` counts one layer's worth since PR
+# 50 (the dense hybrid cell read +3.07 until then and reads +0.31); in
+# these two the whole-gradient count is what stands in for the
+# dispatch's temporaries, so it stays until their backward has an
+# inventory from shapes.
+OVER = {"trinity-mini.seq16384": 1.0, "solar-open2-250b.seq16384": 1.0}
 
 # tokens a chip a step in each configuration's cells
 ROWS_OF = {"olmo1b": 16384, "olmoe1b7b": 16384, "lfm2-24b-a2b": 32768,
@@ -341,8 +342,9 @@ def test_the_estimate_is_held_to_the_cells_measured_peaks(cell):
     that peak (over, never under: +0.23, +0.27 and, at depth 1, +0.22
     with nothing kept; +0.81 and +0.39 with PR 35's lists in the two
     share cells, whose estimates read +2.35 and +1.17 while a layer's
-    kept products were counted twice; the cell ``OVER`` names is held
-    to its own reading and no other cell to more than it was), picks
+    kept products were counted twice; +0.31 in the dense hybrid cell,
+    +3.07 until PR 50; the cells ``OVER`` names are held to their own
+    readings and no other cell to more than it was), picks
     the names the PR reports, and predicts a peak under the limit less
     the reserve."""
     config, batch, chips, measured, labels, names = CELLS[cell]
@@ -524,6 +526,134 @@ def test_the_latent_cell_keeps_what_it_kept_to_the_byte():
     assert names == CELLS["kanana-2-30b-a3b.seq16384"][5]
     assert (kept, peak) == (3246917632, 16034369544)
     assert kept <= budget < kept + 201326592      # shared_up does not fit
+
+
+# configuration -> (bytes kept, budget, predicted peak) of ``choose``
+# under a v5e's room at the cell's rows, on PR 49's tree (commit
+# d5697ee): the seven configurations whose estimate PR 50 does not touch
+PARENT = {
+    "olmo1b": (2356150272, 3800754581, 14621257732),
+    "olmoe1b7b": (1953497344, 4751804821, 13267554564),
+    "lfm2-24b-a2b": (5546968064, 5805265041, 15807565064),
+    "smallthinker-21b-a3b": (4055368704, 4673050001, 15448180744),
+    "kanana-2-30b-a3b": (3246917632, 3278410129, 16034369544),
+    "trinity-mini": (3506440192, 3537507217, 16034795016),
+    "solar-open2-250b": (997725184, 1032202481, 16031384744),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(
+    cell for cell in CELLS if CELLS[cell][0] in PARENT))
+def test_the_other_cells_choose_what_the_parent_chose_to_the_byte(cell):
+    """What ``grads_standing`` leaves alone: a scan of several turns
+    (``olmo1b``, both cells; ``kanana-2-30b-a3b``'s period behind its
+    dense lead) and every stack with an expert layer.  Names, bytes,
+    budget and predicted peak are the parent's, so the step those cells
+    trace is the parent's program."""
+    config, batch, chips, _, _, names = CELLS[cell]
+    cfg, params, held, seq_len = _cell(config)
+    rows = batch * seq_len // chips
+    room = DeviceRoom(V5E_LIMIT, V5E_LIMIT - held)
+    got = rk.choose(cfg, params, rows, room)
+    assert got == (names,) + PARENT[config]
+    stack = ct._device_bytes(params["layers"])
+    for kept in ((), [label for label, _, _ in rk.table(cfg, rows)]):
+        assert rk.grads_standing(cfg, params, rows, kept) == stack
+
+
+def _stack(pattern, **kw):
+    """(cfg, shapes of the parameters, the bytes of each layer's
+    gradients in the stack's order) of a small float32 stack."""
+    spec = tfm.model_spec(vocab_size=96, dim=128, num_heads=2, seq_len=128,
+                          ffn_dim=256, dtype="float32", remat=True,
+                          num_layers=len(pattern), layer_pattern=pattern,
+                          **kw)
+    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+    nbytes = ct._device_bytes
+    layers = params["layers"]
+    turns = tfm.stack_plan(spec.config).periods
+    each = ([nbytes(layer) for layer in layers["lead"].values()]
+            + [nbytes(layer) // turns
+               for layer in layers["period"].values()] * turns
+            + [nbytes(layer) for layer in layers["tail"].values()])
+    assert sum(each) == nbytes(layers) and len(each) == len(pattern)
+    return spec.config, params, each
+
+
+def test_the_gradients_that_stand_at_the_layer_place_from_shapes():
+    """``grads_standing``, no arrays.  A scan of several turns: whole,
+    whatever is kept.  An unrolled group with an expert layer: whole
+    (its one-layer term has no inventory of the dispatch: ROADMAP A3
+    (t)).  An unrolled dense group: one layer's worth, the largest (the
+    layer whose update is in flight), less what a layer done gives back
+    of the kept entries that every layer makes; nothing of what one
+    kind alone makes.  A scanned period with a tail: the period's whole
+    and the tail's one layer.  ``step_bytes`` falls by what is
+    absent."""
+    rows = 2 * 128
+    # cacaca: a period of two layers, three turns
+    cfg, params, each = _stack("cacaca")
+    labels = [label for label, _, _ in rk.table(cfg, rows)]
+    for kept in ((), labels):
+        assert rk.grads_standing(cfg, params, rows, kept) == sum(each)
+    # caw: one turn, unrolled, every layer dense
+    cfg, params, each = _stack("caw", window=64)
+    assert len(set(each)) == 2 and tfm.stack_plan(cfg).periods == 1
+    sizes = {label: (b, n) for label, _, b, n in rk._entries(cfg, rows)}
+    assert sizes["stream"][1] == sizes["ffn_gate"][1] == 3
+    assert sizes["qkv"][1] == 2 and sizes["conv_in"][1] == 1
+    assert rk.grads_standing(cfg, params, rows) == max(each)
+    assert rk.grads_standing(cfg, params, rows, ["qkv", "conv_in"]) == (
+        max(each))
+    back = sizes["stream"][0] + sizes["ffn_gate"][0]
+    assert 0 < back < max(each)
+    assert rk.grads_standing(cfg, params, rows, ["stream", "ffn_gate"]) == (
+        max(each) - back)
+    big = 64 * rows
+    assert sizes["stream"][0] * 64 > max(each)
+    assert rk.grads_standing(cfg, params, big, ["stream"]) == 0
+    # the need falls by the gradients that do not stand, where a
+    # layer's backward is the larger place
+    absent = sum(each) - max(each)
+    head = big * cfg.vocab_size * 4 * 2
+    layer = big * 4 * cfg.mlp_dim * 4
+    assert layer - absent > head - sum(each)
+    assert (rk.step_bytes(cfg, params, big)
+            - rk.step_bytes(cfg, params, big, ["stream"])) == max(each)
+    # the same stack over experts: whole
+    cfg, params, each = _stack("caw", window=64, moe_experts=4, moe_top_k=2)
+    labels = [label for label, _, _ in rk.table(cfg, rows)]
+    for kept in ((), labels):
+        assert rk.grads_standing(cfg, params, rows, kept) == sum(each)
+    # acaca: the period scanned twice, and a tail of one layer
+    cfg, params, each = _stack("acaca")
+    plan = tfm.stack_plan(cfg)
+    assert (plan.periods, len(plan.tail)) == (2, 1)
+    assert rk.grads_standing(cfg, params, rows) == sum(each)
+    assert rk.grads_standing(cfg, params, big, ["stream"]) == sum(each[:4])
+
+
+@pytest.mark.parametrize("pattern,standing", [
+    ("aa", "whole"), ("aw", "a layer's"), ("aw", "none")])
+def test_the_line_says_what_of_the_gradients_stands(pattern, standing):
+    """``grads_standing=`` on the ``remat keep:`` line: the stack's whole
+    gradients for a scan of two turns; for an unrolled dense stack one
+    layer's while nothing is kept, and 0 once the kept entries a layer
+    gives back are more than that."""
+    rk.announce_keep.cache_clear()
+    cfg, params, each = _stack(pattern, window=64 * ("w" in pattern))
+    rows = 4 * 128 if standing == "none" else 128
+    need = rk.step_bytes(cfg, params, rows)
+    free = 99 * GB if standing == "none" else int(need + rk.RESERVE * GB)
+    with batch_axis(None, "data", DeviceRoom(100 * GB, free)):
+        lines = _lines(lambda: rk.names_for(
+            cfg, params, (rows // 128, 128)))
+    fields = _fields(lines[0])
+    assert (fields["names"] == "-") == (standing != "none")
+    assert int(fields["grads_standing"]) == {
+        "whole": sum(each), "a layer's": max(each), "none": 0}[standing]
+    assert int(fields["predicted_peak"]) == (
+        100 * GB - free + int(fields["need"]) + int(fields["bytes"]))
 
 
 @pytest.mark.parametrize("config", ["olmo1b", "olmoe1b7b", "lfm2-24b-a2b",
