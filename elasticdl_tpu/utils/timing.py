@@ -21,6 +21,11 @@ Set-up is timed by the same phases: ``SETUP``, the process's one
 piece of work, and says them in one ``<role> setup:`` log line
 (docs/observability.md, "Set-up timeline").
 
+The steady state is measured at the fence: ``FenceWatch`` observes
+``step_time`` from one device fence's return to the next one's, keeps
+what the host did in between, and says a stalled interval in one
+``worker stall:`` line (docs/observability.md, "Stalls").
+
 Thread model: phases and counters are written by training/executor
 threads while /statz, /metrics, and Timing.report() readers snapshot
 concurrently.  Every mutation AND every snapshot runs under one plain
@@ -35,11 +40,14 @@ against every snapshot path.
 """
 
 import contextlib
+import gc
 import os
+import resource
+import statistics
 import sys
 import threading
 import time
-from collections import defaultdict
+from collections import defaultdict, deque
 
 from elasticdl_tpu.utils import hist as hist_mod
 
@@ -95,6 +103,14 @@ class Timing:
     def counters(self):
         with self._lock:
             return dict(self._events)
+
+    def totals(self, phases, counter=None):
+        """The seconds so far of each of ``phases`` and the count of
+        ``counter``, at one instant: what ``FenceWatch`` differences
+        from fence to fence."""
+        with self._lock:
+            return ([self._totals.get(name, 0.0) for name in phases],
+                    self._events.get(counter, 0))
 
     def observe(self, name, seconds, n=1):
         """Record ``n`` already-measured durations of ``seconds`` each
@@ -440,3 +456,193 @@ class SetupTimeline:
 
 
 SETUP = SetupTimeline()
+
+
+# -- the fence ----------------------------------------------------------------
+
+# A fence is a stall where its interval a step lies over the quiet fences'
+# median by more than STALL_RATIO and the whole interval over ``steps``
+# medians by more than STALL_EXCESS_S.  Quiet runs' tasks repeat to
+# 0.01-0.7%, and the slightest stall the builders saw was a task at
+# 1.21 x (PERF.md section 6, PR 52): constants, not options.
+STALL_RATIO = 1.05
+STALL_EXCESS_S = 0.050
+QUIET_FENCES = 32       # the ring whose median a fence is judged against
+QUIET_FENCES_MIN = 3    # fences in the ring before one is judged
+JUDGED_KEPT = 4096      # newest judged fences behind ``fence_p50_ms``
+# The counter the compile listener bumps (worker/main.xla_compiles_logged).
+XLA_PROGRAMS = "xla_programs"
+PRESSURE_DIR = "/proc/pressure"
+# The training thread's phases an interval is told apart by, under the
+# stall line's names; the rest of the interval is ``host_other``.
+_FENCE_PHASES = {"fence_wait": "loss_sync", "data_wait": "data_wait",
+                 "rpc": "progress_rpc", "task_fetch": "task_fetch"}
+_STALL_MS = ("fence_wait", "data_wait", "rpc", "task_fetch", "host_other",
+             "host_excess")
+_STALL_COUNTS = ("nivcsw", "majflt")
+
+
+def _ms(seconds):
+    return round(1e3 * seconds, 3)
+
+
+def _pressure_us(kind):
+    """``some total=`` of ``/proc/pressure/<kind>``: the microseconds so
+    far in which some task of the machine (of the container, where it has
+    a cgroup of its own) waited for the resource.  None where the kernel
+    keeps no such file."""
+    try:
+        with open(os.path.join(PRESSURE_DIR, kind)) as fh:
+            return int(fh.readline().rsplit("total=", 1)[1])
+    except (OSError, IndexError, ValueError):
+        return None
+
+
+class FenceWatch:
+    """The training loop measured at its fences: the one place where the
+    host knows the device has caught up (docs/observability.md, "Worker
+    step-time anatomy" and "Stalls").
+
+    Both loops call ``fence(step)`` right after a ``timeit("loss_sync")``
+    fence returns, with the number of the last optimizer step the fence
+    proves done.  Each call takes one ``perf_counter()`` and
+
+    - observes ``step_time``: the interval from the previous fence's
+      return to this one's over the steps run in it, once a step
+      (``n=steps``).  It is the only ``step_time`` observation, so the
+      master's percentiles and its straggler sweep read the device-paced
+      step and not the dispatch burst between fences.  A fence with no
+      step since the previous one (the task-final fence right behind a
+      log-cadence fence) is no interval: its time goes into the next.
+      Steps dispatched after a run's last fence (a task preempted in
+      the per-step loop) are never observed;
+    - keeps what the host did in the interval, as differences of totals:
+      the owner's ``Timing`` phases, the collector's pauses
+      (``gc.callbacks``, process-wide), the process's CPU time, its
+      involuntary context switches and major faults (``getrusage``), the
+      machine's pressure-stall microseconds and the compile listener's
+      programs;
+    - once the set-up timeline has closed and QUIET_FENCES_MIN quiet
+      fences exist, judges the interval against their median a step
+      (STALL_RATIO, STALL_EXCESS_S).  A stall is one ``worker stall:``
+      line and one ``worker.stall`` flight-recorder event, and stays out
+      of the ring.
+
+    ``start()`` and ``report()`` bracket the run (``Worker.run``): the
+    collector's callback is installed between them, and ``report()`` logs
+    the run's one ``worker fences:`` line.  Written and read by the
+    training thread alone; the collector's callback runs on whichever
+    thread allocates, one collection at a time."""
+
+    def __init__(self, timing, logger=None):
+        self._timing = timing
+        self._logger = logger
+        self.task = 0           # the task in hand, for the stall line
+        self._step = 0          # the last step a fence has proven done
+        self._gc = [0, 0.0, 0.0]    # pauses, their seconds, one's start
+        self._pressure = [kind for kind in ("cpu", "io", "memory")
+                          if _pressure_us(kind) is not None]
+        # (interval, interval less the fence's wait) a step, in seconds
+        self._quiet = deque(maxlen=QUIET_FENCES)
+        self._judged = deque(maxlen=JUDGED_KEPT)    # interval a step
+        self._fences = self._steps = self._stalls = 0
+        self._max = self._excess = self._host_excess = 0.0
+        self._was = {}          # what ``_read`` falls back on
+        self._at, self._was = time.perf_counter(), self._read()
+
+    def _on_gc(self, phase, _info):
+        if phase == "start":
+            self._gc[2] = time.perf_counter()
+        else:
+            self._gc[0] += 1
+            self._gc[1] += time.perf_counter() - self._gc[2]
+
+    def _read(self):
+        """Every total an interval is a difference of: seconds, counts,
+        and the pressure files' microseconds."""
+        seconds, programs = self._timing.totals(
+            _FENCE_PHASES.values(), XLA_PROGRAMS)
+        usage = resource.getrusage(resource.RUSAGE_SELF)
+        out = dict(zip(_FENCE_PHASES, seconds))
+        out.update(gc_n=self._gc[0], gc=self._gc[1],
+                   cpu=usage.ru_utime + usage.ru_stime,
+                   nivcsw=usage.ru_nivcsw, majflt=usage.ru_majflt,
+                   compiles=programs)
+        for kind in self._pressure:
+            # A file that went away reads as no pressure since.
+            out["psi_" + kind] = (_pressure_us(kind)
+                                  or self._was.get("psi_" + kind, 0))
+        return out
+
+    def start(self):
+        """The run begins: the first interval counts from here."""
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
+        self._at, self._was = time.perf_counter(), self._read()
+
+    def fence(self, step, now=None):
+        """A fence has returned and proves every step up to ``step``
+        done.  ``now`` is its ``perf_counter()`` (a test's clock)."""
+        now = time.perf_counter() if now is None else now
+        steps = step - self._step
+        if steps <= 0:
+            return
+        reading = self._read()
+        interval = now - self._at
+        spent = {key: reading[key] - self._was[key] for key in reading}
+        self._at, self._step, self._was = now, step, reading
+        a_step = interval / steps
+        self._timing.observe("step_time", a_step, n=steps)
+        if SETUP.open:
+            return      # the compile never counts
+        away = interval - spent["fence_wait"]
+        self._fences += 1
+        self._steps += steps
+        self._judged.append(a_step)
+        self._max = max(self._max, a_step)
+        median = excess = 0.0
+        if len(self._quiet) >= QUIET_FENCES_MIN:
+            median = statistics.median(q[0] for q in self._quiet)
+            excess = interval - steps * median
+        if not (a_step > STALL_RATIO * median and excess > STALL_EXCESS_S):
+            self._quiet.append((a_step, away / steps))
+            return
+        spent["host_other"] = away - sum(
+            spent[key] for key in _FENCE_PHASES if key != "fence_wait")
+        spent["host_excess"] = max(0.0, away - steps * statistics.median(
+            q[1] for q in self._quiet))
+        self._stalls += 1
+        self._excess += excess
+        self._host_excess += spent["host_excess"]
+        fields = {"step": step, "task": self.task, "steps": steps,
+                  "interval_ms": _ms(interval), "median_ms": _ms(median),
+                  "excess_ms": _ms(excess)}
+        fields.update((key + "_ms", _ms(spent[key])) for key in _STALL_MS)
+        fields.update(gc_n=spent["gc_n"], gc_ms=_ms(spent["gc"]),
+                      cpu_ms=_ms(spent["cpu"]))
+        fields.update((key, spent[key]) for key in _STALL_COUNTS)
+        fields.update(("psi_%s_ms" % kind[:3], round(
+            spent["psi_" + kind] / 1e3, 3)) for kind in self._pressure)
+        fields["compiles"] = spent["compiles"]
+        if self._logger is not None:
+            self._logger.warning("worker stall: %s", " ".join(
+                "%s=%s" % item for item in fields.items()))
+        from elasticdl_tpu.utils import tracing
+
+        tracing.event("worker.stall", **fields)
+
+    def report(self):
+        """The run is over: one ``worker fences:`` line over the fences
+        judged (the steady state by the program's own word; the median
+        over the newest JUDGED_KEPT of them)."""
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+        if self._logger is None:
+            return
+        self._logger.info(
+            "worker fences: fences=%d steps=%d fence_p50_ms=%.3f "
+            "fence_max_ms=%.3f stalls=%d stall_excess_ms=%.3f "
+            "stall_host_ms=%.3f", self._fences, self._steps,
+            1e3 * statistics.median(self._judged or [0.0]),
+            1e3 * self._max, self._stalls, 1e3 * self._excess,
+            1e3 * self._host_excess)

@@ -59,7 +59,7 @@ import numpy as np
 
 from elasticdl_tpu.utils import tracing
 from elasticdl_tpu.utils.logging import get_logger
-from elasticdl_tpu.utils.timing import SETUP
+from elasticdl_tpu.utils.timing import SETUP, FenceWatch
 
 logger = get_logger(__name__)
 
@@ -121,6 +121,7 @@ class FusedStepDriver:
         trainer,
         shard_service,
         timing,
+        fences=None,
         fused_steps=1,
         device_prefetch=2,
         log_loss_steps=100,
@@ -135,10 +136,12 @@ class FusedStepDriver:
         elastic path uses it so a world re-form (which can change batch
         geometry via an accum resize) never sees batches prepared under
         the old world.  None means the stream already yields
-        PreparedBatch (the prefetch producer prepared them)."""
+        PreparedBatch (the prefetch producer prepared them).
+        ``fences``: the worker's ``FenceWatch`` over ``timing``."""
         self._trainer = trainer
         self._shard = shard_service
         self._timing = timing
+        self._fences = fences if fences is not None else FenceWatch(timing)
         self._prepare = prepare
         self._fused_steps = max(1, int(fused_steps))
         # > 0: stage (stack + device_put) the next window while the
@@ -217,9 +220,14 @@ class FusedStepDriver:
 
     def _fence(self):
         """One blocking loss fetch — the sync half of the
-        dispatch-vs-sync timing split (see Timing.sync_fraction)."""
+        dispatch-vs-sync timing split (see Timing.sync_fraction) —
+        and the loop's unit of measurement: the fence watch observes
+        ``step_time`` over the steps it proves done."""
         with self._timing.timeit("loss_sync"):
-            return self.loss_ring.fetch_last()
+            fetched = self.loss_ring.fetch_last()
+        if fetched is not None:
+            self._fences.fence(fetched[0])
+        return fetched
 
     def _stage(self, batches):
         """Stage ahead (the device double-buffer) when enabled; None
@@ -272,11 +280,9 @@ class FusedStepDriver:
         # below is decomposed into data_wait (producer starvation) /
         # host_prep (stack + device_put) / window_dispatch (XLA
         # enqueue) / loss_sync (device fence) / progress_rpc (master
-        # report), each feeding a per-phase histogram via Timing; the
-        # whole pass's wall time over its step count is the honest
-        # per-step step time (windowed dispatch means individual steps
-        # inside one program are not separately observable).
-        t_prev = time.perf_counter()
+        # report), each feeding a per-phase histogram via Timing.  The
+        # per-step step time is the fence's to observe (``_fence``):
+        # between fences a pass's wall time is its dispatch.
         while cur:
             # One ``step`` phase per window pass (``steps`` of them in
             # one program), holding the phases above: the per-step
@@ -327,13 +333,6 @@ class FusedStepDriver:
                         self._shard.report_batch_done(batch.count,
                                                       defer=True)
                     self._shard.flush_batch_done()
-                # One bulk observation per window: this pass's wall time
-                # spread over its steps — the step-time distribution the
-                # master aggregates per job (and judges stragglers on).
-                t_now = time.perf_counter()
-                timing.observe("step_time",
-                               (t_now - t_prev) / len(cur), n=len(cur))
-                t_prev = t_now
                 if (
                     self._log_loss_steps
                     and steps_done % self._log_loss_steps == 0

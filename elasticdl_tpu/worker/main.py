@@ -21,7 +21,7 @@ from elasticdl_tpu.utils.device import (
     place_compile_cache,
 )
 from elasticdl_tpu.utils.logging import get_logger
-from elasticdl_tpu.utils.timing import SETUP, WORKER_SETUP
+from elasticdl_tpu.utils.timing import SETUP, WORKER_SETUP, XLA_PROGRAMS
 from elasticdl_tpu.worker.collective_trainer import CollectiveTrainer
 from elasticdl_tpu.worker.master_client import MasterClient
 from elasticdl_tpu.worker.worker import Worker
@@ -300,7 +300,7 @@ _CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
 
 
 @contextlib.contextmanager
-def xla_compiles_logged(steps_done):
+def xla_compiles_logged(steps_done, timing=lambda: None):
     """Make every XLA program this process builds visible from inside,
     for as long as the block lasts: JAX reports one
     ``backend_compile_duration`` per fresh jit (a program loaded from the
@@ -310,7 +310,9 @@ def xla_compiles_logged(steps_done):
     after the first steps is the per-shape recompile that stalls a step;
     counting new files in the cache directory misses every one shorter
     than the persistent cache's minimum compile time.  ``steps_done()``
-    is the number of steps trained so far.
+    is the number of steps trained so far, ``timing()`` the ``Timing``
+    whose ``xla_programs`` counter counts the lines (None: nobody's yet):
+    a stalled fence's ``compiles=`` (utils/timing.FenceWatch).
 
     ``secs`` is the backend's part alone.  ``trace_s`` and ``lower_s``
     are what JAX reported on the same thread since that thread's
@@ -349,6 +351,9 @@ def xla_compiles_logged(steps_done):
                 "xla compile: secs=%.3f step=%d fun=%s trace_s=%.3f "
                 "lower_s=%.3f cache=%s", secs, steps_done(), fun_name,
                 trace_s, mine.lower_s, mine.cache)
+            counted = timing()
+            if counted is not None:
+                counted.bump(XLA_PROGRAMS)
             SETUP.add(programs=1, trace_s=trace_s, lower_s=mine.lower_s,
                       compile_or_load_s=secs,
                       cache_hits=mine.cache == "hit",
@@ -399,7 +404,8 @@ def main(argv=None):
     SETUP.mark("build")
     worker = None
     with xla_compiles_logged(
-            lambda: worker.steps_done if worker is not None else 0):
+            lambda: worker.steps_done if worker is not None else 0,
+            lambda: worker.timing if worker is not None else None):
         worker = build_worker(args)
 
         def _graceful_preempt(_sig, _frame):

@@ -15,7 +15,7 @@ from elasticdl_tpu.utils import hist as hist_mod
 from elasticdl_tpu.utils import tracing
 from elasticdl_tpu.utils.logging import get_logger
 from elasticdl_tpu.utils.retry import RetryPolicy
-from elasticdl_tpu.utils.timing import SETUP, Timing
+from elasticdl_tpu.utils.timing import SETUP, FenceWatch, Timing
 from elasticdl_tpu.worker.data_shard_service import DataShardService
 from elasticdl_tpu.worker.task_data_service import TaskDataService
 
@@ -153,6 +153,9 @@ class Worker:
         )
         self._data_service = TaskDataService(data_reader, spec.feed)
         self.timing = Timing(logger=logger)
+        # The loop measured at its fences: the one ``step_time``
+        # observation, and a stalled fence's ``worker stall:`` line.
+        self.fences = FenceWatch(self.timing, logger=logger)
         # One retry policy family (utils/retry.py): the minibatch loop
         # below keeps its structure (the elastic branch re-rendezvouses
         # instead of sleeping) but the backoff/budget bookkeeping and
@@ -376,6 +379,7 @@ class Worker:
                     # float() is the only per-cadence host sync.
                     with self.timing.timeit("loss_sync"):
                         loss_value = float(loss)
+                    self.fences.fence(self._steps)
                     logger.info(
                         "step %d loss %.6f (version %d)",
                         self._steps, loss_value, version,
@@ -433,6 +437,7 @@ class Worker:
 
         driver = FusedStepDriver(
             self._trainer, self._shard_service, self.timing,
+            fences=self.fences,
             fused_steps=self._fused_steps,
             device_prefetch=self._device_prefetch,
             log_loss_steps=self._log_loss_steps,
@@ -492,6 +497,7 @@ class Worker:
             self._trainer, "prefetch_embeddings", None
         )
         timing = self.timing
+        self.fences.task = task.id
         with timing.timeit("task_process", task=task.id):
             try:
                 if driver is not None:
@@ -516,7 +522,6 @@ class Worker:
                 # batches: the last pull sees the stream's end.
                 with timing.timeit("data_wait"):
                     pending = next(batches, None)
-                t_prev = time.perf_counter()
                 while pending is not None:
                     with timing.timeit("step", step=self._steps + 1,
                                        task=task.id):
@@ -526,13 +531,6 @@ class Worker:
                         if pending is not None and prefetch_embeddings:
                             prefetch_embeddings(pending[0])
                         loss = self._process_minibatch(features, labels)
-                        # Per-step wall time into the step-time
-                        # histogram (the fused path observes per
-                        # window); feeds the master's per-job p50/p99
-                        # via the telemetry piggyback's hist delta.
-                        t_now = time.perf_counter()
-                        timing.observe("step_time", t_now - t_prev)
-                        t_prev = t_now
                         if pending is None:
                             # Task-final fence: the last report below
                             # can auto-complete the task at the master,
@@ -544,6 +542,7 @@ class Worker:
                             # them all.
                             with timing.timeit("loss_sync"):
                                 float(loss)
+                            self.fences.fence(self._steps)
                             SETUP.mark("first_report")
                         with timing.timeit("progress_rpc"):
                             self._shard_service.report_batch_done(count)
@@ -667,6 +666,7 @@ class Worker:
 
     def _run_traced(self):
         SETUP.mark("first_task_fetch")
+        self.fences.start()
         if self._join_rendezvous:
             self._mc.report_train_loop_status(pb.LOOP_START)
         try:
@@ -721,3 +721,4 @@ class Worker:
             # far its set-up came.
             SETUP.close(into=self.timing)
             self.timing.report()
+            self.fences.report()

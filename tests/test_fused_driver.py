@@ -10,6 +10,7 @@ import pytest
 from elasticdl_tpu.data.reader import ArrayDataReader
 from elasticdl_tpu.models import mnist
 from elasticdl_tpu.proto import elastic_pb2 as pb
+from elasticdl_tpu.utils import hist
 from elasticdl_tpu.utils.args import parse_master_args, parse_worker_args
 from elasticdl_tpu.utils.timing import Timing
 from elasticdl_tpu.worker.collective_trainer import CollectiveTrainer
@@ -46,6 +47,7 @@ class FakeMasterClient:
             for i, size in enumerate(sizes)
         ]
         self.batch_done_calls = []   # record_count per RPC
+        self.telemetry = []          # the piggyback of each RPC that had one
         self.task_results = []       # (task_id, err_message, requeue)
         self.versions = []           # report_version stream
 
@@ -58,6 +60,8 @@ class FakeMasterClient:
 
     def report_batch_done(self, count, telemetry=None):
         self.batch_done_calls.append(count)
+        if telemetry:
+            self.telemetry.append(telemetry)
 
     def report_task_result(self, task_id, err_message="",
                            exec_counters=None, requeue=False):
@@ -407,9 +411,12 @@ def test_step_anatomy_phases_and_step_time_hist(dataset, spec):
         assert snap is not None and snap["count"] > 0, phase
     # host_prep only when staging ahead ran (device_prefetch > 0)
     assert timing.hist_snapshot("host_prep") is not None
-    # and the telemetry snapshot carries the encoded delta
-    worker2_out = worker._telemetry_snapshot()
-    assert "hist_delta" in worker2_out
+    # and the progress report behind each fence carried the encoded
+    # delta: every sample has reached the master by the run's end
+    shipped = [hist.decode_deltas(t["hist_delta"])["step_time"]["count"]
+               for t in mc.telemetry if "hist_delta" in t]
+    assert sum(shipped) == worker._steps
+    assert "hist_delta" not in worker._telemetry_snapshot()
 
 
 def test_fused_flags_roundtrip_master_to_worker():
