@@ -125,6 +125,10 @@ TOLERANCES["rows_gather"] = 0.0
 TOLERANCES["rows_sum"] = TOLERANCES["rows_sum_back"] = _F32
 TOLERANCES["rows_dots"] = _F32
 TOLERANCES["rows_gather_back"] = _BF16_FWD
+# The embedding table's gradient by the kernel (ops/embed_rows.py)
+# against one float32 scatter-add of the same bf16 rows: float32 sums
+# of the same values, whose order alone differs.
+TOLERANCES["embed_grad"] = _F32
 TOLERANCES["stack_loss"] = 2e-3
 TOLERANCES["stack_grad_l2"] = 2 * _BF16_GRAD
 
@@ -365,6 +369,23 @@ def check_row_moves(n, w, k, bound, live, interpret):
         if other is not None:
             errs["rows_dots"] = _rel_err(got[1], want[1])
     return errs
+
+
+def check_embed_rows(n, vocab, dim, interpret):
+    """The embedding table's gradient by the kernel against
+    ``rows_added_ref``: ``n`` Zipf ids as the benchmark draws them (one
+    id a seventh of them) and their bfloat16 rows."""
+    from elasticdl_tpu.ops import embed_rows
+    from tools.head_loss_on_chip import draw_ids
+
+    tokens = jnp.asarray(draw_ids("zipf", vocab, n).reshape(1, n), jnp.int32)
+    g = jnp.asarray(np.random.default_rng(n + vocab + dim).standard_normal(
+        (1, n, dim), np.float32), jnp.bfloat16)
+    got = jax.jit(lambda t, g: embed_rows.rows_added(
+        t, g, vocab, interpret=interpret))(tokens, g)
+    want = jax.jit(lambda t, g: embed_rows.rows_added_ref(
+        t, g, vocab))(tokens, g)
+    return {"embed_grad": _rel_err(got, want)}
 
 
 def check_short_conv(b, t, e, taps, interpret):
@@ -923,6 +944,12 @@ def _cases(tiny):
         yield ("row_moves/N%d.W%d.K%d.C%d" % (n, w, k, bound),
                lambda n=n, w=w, k=k, bound=bound: check_row_moves(
                    n, w, k, bound, bound * 9 // 16, interpret))
+    # The embedding's gradient at two cells' tables (tokens, ids, width).
+    for n, vocab, dim in ((300, 96, 256),) if tiny else (
+            (16384, 37984, 2560), (16384, 24576, 4096)):
+        yield ("embed_rows/N%d.V%d.E%d" % (n, vocab, dim),
+               lambda n=n, vocab=vocab, dim=dim: check_embed_rows(
+                   n, vocab, dim, interpret))
     cb, ct, ce = (3, 64, 128) if tiny else (4, 8192, 2048)
     yield ("short_conv/B%d.T%d.E%d.K3" % (cb, ct, ce),
            lambda: check_short_conv(cb, ct, ce, 3, interpret))

@@ -32,6 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from elasticdl_tpu.models import remat_keep
 from elasticdl_tpu.models.spec import ModelSpec
 from elasticdl_tpu.ops import batch_shard, gated_delta, short_conv
+from elasticdl_tpu.ops.embed_rows import embed_rows
 from elasticdl_tpu.ops.flash_attention import (flash_attention, flash_mode,
                                                latent_attention,
                                                latent_mode, logger)
@@ -1264,12 +1265,13 @@ def _layer_body(x, w, cfg, mesh, positions, moe_stats=False,
     return x, aux
 
 
-def _embed(params, tokens, cfg):
+def _embed(params, tokens, cfg, mesh=None):
     """The stream's first value: the tokens' rows of the table in the
     compute dtype, times ``cfg.embed_multiplier`` where the model has
-    one."""
+    one (``ops/embed_rows.py``: the lookup, the table's gradient its
+    own).  ``mesh``: a model-parallel mesh."""
     compute_dtype = jnp.dtype(cfg.dtype)
-    x = params["embed"].astype(compute_dtype)[tokens]
+    x = embed_rows(params["embed"], tokens, compute_dtype, mesh)
     if cfg.embed_multiplier != 1.0:
         x = x * jnp.asarray(cfg.embed_multiplier, compute_dtype)
     return x
@@ -1367,7 +1369,8 @@ def forward_hidden(params, tokens, cfg, mesh=None, return_load=False):
     that tensor is ~2 GB in f32 — a pure HBM-bandwidth tax the chunked
     loss removes).
     """
-    x = _constrain(_embed(params, tokens, cfg), mesh, P("dp", "sp", None))
+    x = _constrain(_embed(params, tokens, cfg, mesh), mesh,
+                   P("dp", "sp", None))
     positions = jnp.arange(tokens.shape[1])
 
     with_load = bool(return_load
@@ -1506,7 +1509,7 @@ def forward_pipelined(params, tokens, cfg, mesh, num_microbatches,
             "forward_pipelined requires sp=1 (stage-local attention); "
             "use ring attention (plain forward) for sequence parallelism"
         )
-    x = _embed(params, tokens, cfg)
+    x = _embed(params, tokens, cfg, mesh)
     positions = jnp.arange(tokens.shape[1])
 
     collect_aux = bool(return_aux and cfg.moe_experts)

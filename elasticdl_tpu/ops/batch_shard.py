@@ -54,10 +54,22 @@ def device_room():
     return _ROOM.get()
 
 
+def declared():
+    """The (mesh, axis) declared around the code traced here, or None:
+    for an op whose backward, traced after the block has closed, must
+    run per shard as its forward did."""
+    return _BATCH_AXIS.get()
+
+
+def shards_of(axis):
+    """How many shards ``axis`` has, a (mesh, axis) that
+    :func:`declared` gave; 1 for None."""
+    return 1 if axis is None else axis[0].shape[axis[1]]
+
+
 def shards():
     """How many shards the declared batch axis has (1 without one)."""
-    declared = _BATCH_AXIS.get()
-    return 1 if declared is None else declared[0].shape[declared[1]]
+    return shards_of(_BATCH_AXIS.get())
 
 
 def per_batch_shard(fn, batched, replicated=()):

@@ -664,8 +664,35 @@ def _step(spec, one_chip, batch, rows, room=None):
         on_chip(params), on_chip(state), tokens)
 
 
+@pytest.fixture(scope="module")
+def banded_step(one_chip):
+    """The ``smallthinker-21b-a3b.seq16384`` cell's whole training step
+    compiled once for the tests that read it (a minute): what
+    ``remat_keep`` chose, and the compiled program."""
+    from elasticdl_tpu.models import remat_keep as rk
+    from elasticdl_tpu.ops import batch_shard
+    from elasticdl_tpu.ops.mode import SWITCH
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setenv(SWITCH, "tpu")       # the ops' own choice on a chip
+        spec = tfm.model_spec(**_model_params("smallthinker-21b-a3b"))
+        params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+        state = jax.eval_shape(spec.optimizer.init, params)
+        rows = 16384
+        nbytes = lambda tree: sum(
+            a.size * a.dtype.itemsize
+            for a in jax.tree_util.tree_leaves(tree))
+        assert nbytes(params) == 4 * 656529920      # 656.5 M parameters
+        limit = 16911433728       # a v5e's bytes_limit (chip run, PR 29)
+        held = 2 * nbytes(params) + nbytes(state)
+        room = batch_shard.DeviceRoom(limit, limit - held)
+        chosen = rk.choose(spec.config, params, rows, room)
+        compiled = _step(spec, one_chip, 1, rows, room).compile()
+    return limit, chosen, compiled
+
+
 def test_the_banded_stacks_step_fits_a_v5e_as_remat_keep_predicts(
-        one_chip, monkeypatch):
+        banded_step):
     """The ``smallthinker-21b-a3b.seq16384`` cell's whole training step
     (one sequence of 16,384 through a full-NoPE and three windowed-RoPE
     attention layers, heads x head size 3,584 over a hidden 2,560, 16 of
@@ -680,27 +707,14 @@ def test_the_banded_stacks_step_fits_a_v5e_as_remat_keep_predicts(
     against 14.39).  Both kinds of flash call are in the one program,
     and no forward runs twice."""
     from elasticdl_tpu.models import remat_keep as rk
-    from elasticdl_tpu.ops import batch_shard, moe_dispatch
-    from elasticdl_tpu.ops.mode import SWITCH
+    from elasticdl_tpu.ops import moe_dispatch
 
-    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
-    spec = tfm.model_spec(**_model_params("smallthinker-21b-a3b"))
-    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
-    state = jax.eval_shape(spec.optimizer.init, params)
-    rows = 16384
-    nbytes = lambda tree: sum(
-        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
-    assert nbytes(params) == 4 * 656529920          # 656.5 M parameters
-    limit = 16911433728           # a v5e's bytes_limit (chip run, PR 29)
-    held = 2 * nbytes(params) + nbytes(state)
-    room = batch_shard.DeviceRoom(limit, limit - held)
-    names, kept, budget, peak = rk.choose(spec.config, params, rows, room)
+    limit, (names, kept, budget, peak), compiled = banded_step
     assert set(names) >= set(rk.ATTN_NAMES) | {
         rk.KEEP_Q, rk.KEEP_K, rk.KEEP_V, rk.KEEP_STREAM,
         moe_dispatch.KEEP_ROWS}, names
     assert kept <= budget and peak <= (1 - rk.RESERVE) * limit
 
-    compiled = _step(spec, one_chip, 1, rows, room).compile()
     stats = compiled.memory_analysis()
     counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
     assert counted < peak and peak - counted < 0.5e9, (peak, counted)
@@ -713,6 +727,29 @@ def test_the_banded_stacks_step_fits_a_v5e_as_remat_keep_predicts(
     assert (count("flash_fwd_w4096"), count("flash_bwd_w4096")) == (3, 3), \
         calls
     assert not [c for c in calls if "flash_dq" in c or "flash_dkv" in c]
+
+
+def test_the_banded_stacks_step_scatters_no_row_into_the_table(
+        banded_step):
+    """The same compiled step: the embedding table's gradient is the one
+    float32 ``[37984, 2560]`` result of the ``embed_grad`` call
+    (``ops/embed_rows.py``: the lookup's own derivative), where JAX's
+    derivative of the lookup left XLA a scatter of bfloat16 rows into
+    ``bf16[37984,2560]`` and a convert pass, 15-17 ms of the cell's
+    step on the chip (PERF.md section 6, PR 53).  The compiler's
+    arguments + temporaries are the parent's 15,224,888,320 within what
+    buffer assignment moved them by (15,225,532,416, +0.6 MB: the
+    table's gradient stands where the step's peak is not)."""
+    _, _, compiled = banded_step
+    text = compiled.as_text()
+    assert not re.findall(r" = \w+\[37984,2560\]\S* scatter\(", text)
+    calls = [l for l in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l
+             and "embed_grad" in l.split(" = ")[0]]
+    assert len(calls) == 1 and " = f32[37984,2560]{" in calls[0], calls
+    stats = compiled.memory_analysis()
+    counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
+    assert counted <= 15224888320 + 2 ** 20, counted
 
 
 def test_the_mixed_stacks_step_fits_a_v5e_as_remat_keep_predicts(
@@ -966,13 +1003,17 @@ def test_a_scan_of_several_turns_is_handed_to_the_compiler_as_it_was(
     loop's body (with the seven stacked gradients held as well the
     loops' bodies were the parent's too, but for four chips the
     compiler's bytes read 17.29 GB for 17.11 and the loop's all-reduces
-    combined otherwise: PERF.md section 6, PR 46)."""
+    combined otherwise: PERF.md section 6, PR 46).  Two more loops since
+    PR 53, neither the stack's: the two binary searches with which
+    ``ops/embed_rows._schedule`` lists the (block of ids, chunk of sorted
+    rows) pairs the embedding's gradient walks."""
     from elasticdl_tpu.ops.mode import SWITCH
 
     monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
     spec = tfm.model_spec(**_model_params("olmo1b"))
     text = _step(spec, one_chip, 8, 2048).as_text(dialect="hlo")
-    assert text.count(" while(") == 2 and text.count(" opt-barrier(") == 3 + 1
+    assert text.count(" while(") == 2 + 2
+    assert text.count(" opt-barrier(") == 3 + 1
 
 
 # -- the solar-open2-250b cell's shapes (benchmark/configs/solar-open2-250b.json)

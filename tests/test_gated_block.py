@@ -514,8 +514,15 @@ def test_with_the_three_fields_off_tree_and_program_are_the_parents(name):
     period is scanned twice and left alone, so its leading layer's
     alone) and the text grew by those equations (the conv
     stack 289,982 -> 291,070); with the identity taken out the program
-    was the record's character for character.  ``dense`` and ``moe``,
-    scans of several turns, and the trees are the record's."""
+    was the record's character for character.  All five were recorded
+    again at PR 53, whose embedding lookup has a derivative of its own
+    (``ops/embed_rows.py``): one ``custom_vjp_call`` more, and the
+    ``lt`` / ``add`` / ``select_n`` / ``broadcast_in_dim`` with which
+    the backward's one float32 ``scatter-add`` wraps a negative id as
+    the forward's gather did (577 characters or so a model: ``dense``
+    42,783 -> 43,342); ``gather`` and ``scatter-add`` count what they
+    counted (the scatter is the op's own now, of float32 rows).  The
+    trees are the record's."""
     from tests.test_mixed_stack import _eqns
 
     was = PLAIN[name]

@@ -472,7 +472,12 @@ def test_a_patternless_models_tree_and_program_are_what_they_were(name):
     split and one concatenate a turn gone, two transposes fewer; the
     tree and the seeded values are PR 29's.  (PR 46's
     ``_updates_apart`` leaves a scan of several turns, which these
-    are, as it was: the record stands.)"""
+    are, as it was.)  Recorded once more at PR 53, whose embedding
+    lookup has a derivative of its own (``ops/embed_rows.py``): one
+    ``custom_vjp_call``, and one ``lt`` / ``add`` / ``select_n`` /
+    ``broadcast_in_dim`` each with which the backward's float32
+    ``scatter-add`` wraps a negative id as the gather did; ``gather``
+    and ``scatter-add`` count what they counted."""
     with open(os.path.join(HERE, "patternless_program.json")) as fh:
         was = json.load(fh)[name]
     spec = tfm.model_spec(vocab_size=128, dim=64, num_heads=4,

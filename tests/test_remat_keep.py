@@ -125,28 +125,32 @@ def test_keeping_out_and_lse_takes_the_flash_forward_out_of_the_backward(
         monkeypatch):
     """The calls by the names they carry: with nothing kept the forward
     runs in the forward scan and again in the backward's, before the
-    one backward call; with the two names kept, once."""
+    one backward call; with the two names kept, once.  (The embedding
+    table's gradient is a call of its own since PR 53, outside the
+    stack: ``ops/embed_rows.py``.)"""
     monkeypatch.setenv("ELASTICDL_FLASH", "interpret")
     cfg, params, loss = _problem(0)
     base = jax.make_jaxpr(jax.grad(lambda p: loss(p, None)))(params)
     assert sorted(_pallas_calls(base.jaxpr)) == [
-        "flash_bwd", "flash_fwd", "flash_fwd"]
+        "embed_grad", "flash_bwd", "flash_fwd", "flash_fwd"]
     room = _room_for(cfg, params, 1)
     kept = jax.make_jaxpr(jax.grad(lambda p: loss(p, room)))(params)
-    assert sorted(_pallas_calls(kept.jaxpr)) == ["flash_bwd", "flash_fwd"]
+    assert sorted(_pallas_calls(kept.jaxpr)) == [
+        "embed_grad", "flash_bwd", "flash_fwd"]
 
 
 def test_every_moe_name_kept_leaves_the_backward_its_own_calls(monkeypatch):
-    """15 kernel calls with nothing kept (flash 1 + 3 grouped matmuls,
-    twice, and flash's 1 + the matmuls' 6 backward calls); 11 with
-    every name kept."""
+    """15 kernel calls of the stack's with nothing kept (flash 1 + 3
+    grouped matmuls, twice, and flash's 1 + the matmuls' 6 backward
+    calls); 11 with every name kept; and the embedding table's gradient,
+    one call either way (PR 53)."""
     monkeypatch.setenv("ELASTICDL_FLASH", "interpret")
     cfg, params, loss = _problem(1)
     base = jax.make_jaxpr(jax.grad(lambda p: loss(p, None)))(params)
     room = _room_for(cfg, params, len(MOE))
     kept = jax.make_jaxpr(jax.grad(lambda p: loss(p, room)))(params)
-    assert len(_pallas_calls(base.jaxpr)) == 15
-    assert len(_pallas_calls(kept.jaxpr)) == 11
+    assert len(_pallas_calls(base.jaxpr)) == 15 + 1
+    assert len(_pallas_calls(kept.jaxpr)) == 11 + 1
 
 
 def test_no_room_stated_is_the_program_without_the_names(monkeypatch):
