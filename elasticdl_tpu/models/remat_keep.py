@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from elasticdl_tpu.ops import (batch_shard, flash_attention, gated_delta,
-                               moe_dispatch, short_conv)
+                               hyper_mix, moe_dispatch, short_conv)
 
 # Named in models/transformer.py: q, k, v as the attention takes them
 # (after RoPE and the QK norm), the stream after the operator's
@@ -44,6 +44,9 @@ KEEP_ATTN_GATE = "attn_gate"
 # its norm, and the one RoPE key before RoPE), and the k_nope and v a
 # matmul makes from it for every head.
 KEEP_LATENT, KEEP_KV = "attn_latent", "attn_kv"
+# The query latent before its norm, [rows, q_latent_rank]: kept with the
+# latent, as cheap and as dear.
+KEEP_Q_LATENT = "attn_q_latent"
 KEEP_STREAM = "attn_stream"
 KEEP_ROUTE = "moe_route"
 KEEP_GATE, KEEP_UP = "ffn_gate", "ffn_up"
@@ -89,7 +92,8 @@ def _entries(cfg, rows):
     layers do)]: ``table`` in full."""
     size = jnp.dtype(cfg.dtype).itemsize
     e, h, g, d = cfg.dim, cfg.num_heads, cfg.kv_heads, cfg.head_dim
-    kinds = cfg.kinds
+    # a multi-token-prediction module's block is one layer more
+    kinds = cfg.kinds + (cfg.mtp_kind,) * cfg.mtp_modules
     attention = sum(kind.op == "a" for kind in kinds)
     conv = sum(kind.op == "c" for kind in kinds)
     delta = sum(kind.op == "d" for kind in kinds)
@@ -113,8 +117,9 @@ def _entries(cfg, rows):
         # the latent first: [rows, rank + d_rope], a fourteenth of the
         # k_nope and v that one matmul makes from it again
         entries += [
-            ("latent", (KEEP_LATENT,), rows * (rank + d_rope) * size,
-             attention),
+            ("latent",
+             (KEEP_LATENT,) + (KEEP_Q_LATENT,) * bool(cfg.q_latent_rank),
+             rows * (rank + d_rope + cfg.q_latent_rank) * size, attention),
             # as the kernels take it: the RoPE part a plane of its own,
             # its minor dimension tiled to the 128 lanes in HBM
             ("q", (KEEP_Q,), rows * h * (d_nope + _lanes(d_rope)) * size,
@@ -128,7 +133,8 @@ def _entries(cfg, rows):
             # the same, ~13
             entries.append(("gate", (KEEP_ATTN_GATE,), rows * h * d * size,
                             attention))
-    entries.append(("stream", (KEEP_STREAM,), rows * e * size, len(kinds)))
+    entries.append(("stream", (KEEP_STREAM,),
+                    rows * cfg.stream_width * size, len(kinds)))
     f, dense_f = cfg.mlp_dim, cfg.dense_ffn_dim if x else cfg.mlp_dim
     # What a kept GB is worth, ms (``table``).  The dispatch's buffers
     # have ``row_bound`` rows, of which a balanced router fills
@@ -190,6 +196,14 @@ def _entries(cfg, rows):
         if kda:
             rest.append((13 * e / rank / 2, "delta_rank", (KEEP_DELTA_RANK,),
                          rows * 2 * rank * size, delta))
+    if cfg.hyper_streams:
+        # a sublayer's mixed input and its logits (a 128-lane float32
+        # tile a row), two sublayers a layer: kept, the second forward
+        # makes no ``hc_pre_fwd`` call, a read of the n-wide stream for
+        # a one-wide result: n passes' worth of a pass bound by memory
+        rest.append((5, "hc_read", (hyper_mix.KEEP_U, hyper_mix.KEEP_Z),
+                     2 * rows * (e * size + hyper_mix.LANES * 4),
+                     len(kinds)))
     if cfg.shared_dim:
         rest += [(12, name, (name,), rows * cfg.shared_dim * size, experts)
                  for name in SHARED_PRODUCTS]
@@ -292,6 +306,18 @@ def _nbytes(tree):
                for a in jax.tree_util.tree_leaves(tree))
 
 
+def _stack(params):
+    """The layer stack's weights, ``params["layers"]``, with each
+    multi-token-prediction module among the layers outside any loop."""
+    layers, mtp = params["layers"], params.get("mtp")
+    if not mtp:
+        return layers
+    if "period" not in layers:
+        layers = {"lead": {}, "period": {"0": layers}, "tail": {}}
+    return dict(layers, tail={
+        **layers["tail"], **{"mtp" + k: w for k, w in mtp.items()}})
+
+
 def _unrolled(layers):
     """(the groups of the stack that a scan runs over several turns,
     stacked; the layers XLA unrolls, one tree each): a loop of one turn
@@ -352,7 +378,7 @@ def grads_standing(cfg, params, rows, kept=()):
        does not have (``OVER`` in tests/test_remat_keep.py): whole, as
        before PR 50, until the dispatch's backward has an inventory
        from shapes (ROADMAP A3 (t), C18)."""
-    scanned, unrolled = _unrolled(params["layers"])
+    scanned, unrolled = _unrolled(_stack(params))
     each = sorted(map(_nbytes, unrolled))
     if not each or not all(kind.dense for kind in cfg.kinds):
         return _nbytes(scanned) + sum(each)
@@ -370,7 +396,9 @@ def step_bytes(cfg, params, rows, kept=()):
 
      - the compute-dtype copies of the parameters (``_weight_copies``:
        all of a scan's at once, two layers' where XLA unrolls);
-     - the carries the scan saves, one stream a layer;
+     - the carries the scan saves, one stream a layer
+       (``cfg.stream_width`` wide: ``hyper_streams`` times the hidden
+       size), a multi-token-prediction module's block a layer more;
      - the larger of the two places the peak can be: the head
        (``ops/head_loss.py``: the logits, and their cotangent where the
        head is tied), while the stack's gradients, which the caller
@@ -388,6 +416,7 @@ def step_bytes(cfg, params, rows, kept=()):
        or attention's own backward where it is the larger;
      - for a kda layer, its decays a channel (four float32 planes of
        [rows, heads * key_dim]);
+     - for a wide stream, four streams more in a layer's backward;
      - less, at either place, what an untied embedding was counted
        for: its copy is read by the forward's first gather alone and
        its gradient is the last thing the backward makes.
@@ -404,12 +433,14 @@ def step_bytes(cfg, params, rows, kept=()):
     size = dtype.itemsize
     copy = lambda tree: sum(a.size * size * (a.dtype != dtype)
                             for a in jax.tree_util.tree_leaves(tree))
-    copies = (copy(params) - copy(params["layers"])
-              + _weight_copies(params["layers"], copy))
-    stack_grads = _nbytes(params["layers"])
-    stream = rows * cfg.dim * size
-    carries = (cfg.num_layers + 1) * stream
-    head = rows * cfg.vocab_size * size * (2 if cfg.tied_embeddings else 1)
+    stack = _stack(params)
+    copies = copy(params) - copy(stack) + _weight_copies(stack, copy)
+    stack_grads = _nbytes(stack)
+    stream = rows * cfg.stream_width * size
+    carries = (cfg.num_layers + cfg.mtp_modules + 1) * stream
+    # a multi-token-prediction module's logits stand beside the model's
+    head = rows * cfg.vocab_size * size * (
+        2 if cfg.tied_embeddings else 1) * (1 + cfg.mtp_modules)
     sizes = {label: per_layer
              for label, _, per_layer, _ in _entries(cfg, rows)}
     own = lambda labels: sum(sizes[label] for label in labels
@@ -438,6 +469,12 @@ def step_bytes(cfg, params, rows, kept=()):
         # test_flash_compile_tpu.py)
         channels = rows * cfg.num_heads * cfg.delta_key_dim * 4
         layer += 4 * channels - min(own(("delta_decay",)), channels)
+    if cfg.hyper_streams:
+        # a wide stream in a layer's backward: the stream the second
+        # forward makes between the sublayers and the one it ends on,
+        # a cotangent in and a cotangent out (``ops/hyper_mix.py``: the
+        # kernels make no float32 copy of any)
+        layer += 4 * stream
     embed = params["embed"]
     unread = 0 if cfg.tied_embeddings else copy(embed) + _nbytes(embed)
     absent = stack_grads - grads_standing(cfg, params, rows, kept)

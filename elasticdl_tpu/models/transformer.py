@@ -31,7 +31,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from elasticdl_tpu.models import remat_keep
 from elasticdl_tpu.models.spec import ModelSpec
-from elasticdl_tpu.ops import batch_shard, gated_delta, short_conv
+from elasticdl_tpu.ops import (batch_shard, gated_delta, hyper_mix,
+                               short_conv)
 from elasticdl_tpu.ops.embed_rows import embed_rows
 from elasticdl_tpu.ops.flash_attention import (flash_attention, flash_mode,
                                                latent_attention,
@@ -122,6 +123,37 @@ class TransformerConfig:
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
     v_head_dim: int = 0
+    # Latent attention's query latent: R > 0 replaces ``wq`` by ``w_q_a``
+    # [dim, R], an RMSNorm ``q_norm`` [R] of its own and ``w_q_b`` [R,
+    # heads * (qk_nope_dim + qk_rope_dim)]: ``q = RMSNorm(h W_qa) W_qb``.
+    q_latent_rank: int = 0
+    # YaRN (arXiv:2309.00071) on RoPE's frequencies: "factor,original,
+    # beta_fast,beta_slow", e.g. "64,4096,32,1".  Each frequency is a
+    # blend of itself and itself / factor by a linear ramp between the
+    # two correction dimensions (where ``original`` positions make
+    # beta_fast and beta_slow turns), cos and sin are unscaled, and
+    # latent attention's softmax scale is multiplied by ``(0.1 ln factor
+    # + 1) ** 2`` (``mscale = mscale_all_dim = 1``).  "" = none.
+    rope_scaling: str = ""
+    # Manifold-constrained hyper-connections (arXiv:2512.24880): n > 0
+    # makes the residual stream n wide, [B, T, n * dim].  Each sublayer
+    # reads the streams mixed by a learned map and writes back through
+    # two more, one of them (streams onto streams) made doubly
+    # stochastic by ``hyper_sinkhorn_iters`` Sinkhorn rounds
+    # (``ops/hyper_mix.py``: the equations; ``_read``, ``_residual``),
+    # and the model reads the last layer's streams through one map
+    # more before its final norm.  0 = ``x + F(norm(x))``, as ever.
+    hyper_streams: int = 0
+    hyper_sinkhorn_iters: int = 20
+    # Multi-token prediction (DeepSeek-V3's form): N modules, module k
+    # one block of the model's last kind on ``[RMSNorm(h) ; RMSNorm(E[
+    # token_(t+k)])] W_proj`` (h: the hidden state before the final norm,
+    # of the model for k = 1, of module k - 1 after), the model's own
+    # final norm and head, and a loss on ``token_(t+k+1)``; training's
+    # loss is ``main + mtp_weight * mean of the modules'``.  Training
+    # alone: no caller reads a module's logits.
+    mtp_modules: int = 0
+    mtp_weight: float = 0.1
     # A stack whose layers differ.  ``layer_pattern``: one letter a
     # layer, "a" causal attention over the whole sequence, "w" causal
     # attention over the last ``window`` positions, "c" gated short
@@ -139,6 +171,16 @@ class TransformerConfig:
     dense_layers: int = 0
     dense_ffn_dim: int = 0
     conv_kernel: int = 3
+    # False: the layers behind the leading ones are ONE period, however
+    # short the pattern they repeat: no loop over stacked weights, each
+    # layer's weights a tree of its own (``params["layers"]["period"]``
+    # has a key a layer, each leaf led by 1).  A loop of several turns
+    # fills one stacked gradient that stands whole until the loop has
+    # ended and AdamW reads it; unrolled, each matrix goes to AdamW
+    # behind its own layer's backward (``_updates_apart``) and the
+    # stack's gradients never stand at once: 4 B a parameter of the
+    # stack that a model whose state fills the chip does not have.
+    scan_periods: bool = True
     # A "d" layer of ``layer_pattern``: a Gated DeltaNet mixer
     # (``_delta_mix``; ops/gated_delta.py) of ``num_heads`` heads, each
     # with keys and queries of ``delta_key_dim`` and values of
@@ -289,6 +331,28 @@ class TransformerConfig:
                 "latent attention needs kv_latent_rank, qk_nope_dim, an "
                 "even qk_rope_dim and v_head_dim, all > 0; got %s"
                 % (sizes,))
+        if self.q_latent_rank and not sizes:
+            raise ValueError(
+                "q_latent_rank=%d is latent attention's query latent: it "
+                "needs kv_latent_rank" % self.q_latent_rank)
+        if self.rope_scaling and not (sizes and yarn_of(self.rope_scaling)):
+            raise ValueError(
+                "rope_scaling=%r is latent attention's (its softmax scale "
+                "takes YaRN's mscale): it needs kv_latent_rank"
+                % self.rope_scaling)
+        if self.hyper_streams and (
+                self.hyper_streams < 2 or self.hyper_sinkhorn_iters < 1
+                or self.moe_route_before_op or self.post_norms):
+            raise ValueError(
+                "hyper_streams=%d: want at least 2 streams and 1 Sinkhorn "
+                "round, a router that reads the FFN's input and a block "
+                "without norms on its sublayers' outputs"
+                % self.hyper_streams)
+        if self.mtp_modules and (self.mtp_modules < 0
+                                 or self.moe_route_before_op):
+            raise ValueError(
+                "mtp_modules=%d: want a count >= 0 and a router that reads "
+                "the FFN's input" % self.mtp_modules)
         if set(self.rope_kinds) - set("aw"):
             raise ValueError(
                 "rope_kinds %r: want letters of a (full attention) and w "
@@ -362,6 +426,17 @@ class TransformerConfig:
         return plan.lead + plan.period * plan.periods + plan.tail
 
     @property
+    def mtp_kind(self):
+        """The Kind of a multi-token-prediction module's block: the
+        model's last layer's."""
+        return self.kinds[-1]
+
+    @property
+    def stream_width(self):
+        """Values a token of the residual stream holds."""
+        return self.dim * max(1, self.hyper_streams)
+
+    @property
     def experts_held(self):
         """(first held expert, how many): all of them without a share."""
         held = self.moe_experts_held or self.moe_experts
@@ -430,7 +505,7 @@ def stack_plan(cfg):
     rest = pattern[cfg.dense_layers:]
     size = next((p for p in range(1, len(rest) + 1)
                  if all(rest[i] == rest[i % p] for i in range(len(rest)))),
-                1)
+                1) if cfg.scan_periods else max(len(rest), 1)
     periods = len(rest) // size
     return StackPlan(
         lead, tuple(_kind(cfg, op, dense) for op in rest[:size]), periods,
@@ -441,6 +516,18 @@ def _one_kind(cfg):
     """The kind of a model's layers where no caller names one: the
     first attention kind of its stack."""
     return next(kind for kind in cfg.kinds if kind.op == "a")
+
+
+def yarn_of(text):
+    """(factor, original positions, beta_fast, beta_slow) of a
+    ``rope_scaling`` string."""
+    try:
+        factor, original, fast, slow = (float(x) for x in text.split(","))
+    except ValueError:
+        raise ValueError(
+            "rope_scaling %r: want \"factor,original,beta_fast,beta_slow\" "
+            "(YaRN), e.g. \"64,4096,32,1\"" % (text,)) from None
+    return factor, original, fast, slow
 
 
 # What some callers cannot run yet (docs/training_pipeline.md, "What
@@ -476,6 +563,21 @@ _CANNOT = {
         "windowed layer (w) beside full ones a K/V cache that keeps its "
         "last `window` positions, a mesh specs for the weights of lead, "
         "period and tail, and the pipeline a split of them into stages"),
+    "hyper": (
+        lambda cfg: cfg.hyper_streams,
+        "a residual stream {cfg.hyper_streams} wide (hyper_streams: hc1_*, "
+        "hc2_*, hc_out_*)",
+        "decoding restates the block for one position (_decode_layer) on "
+        "a stream one wide; a mesh has no spec for the maps' weights and "
+        "its activations' specs are [batch, seq, dim]; the pipeline's "
+        "stages pass a stream one wide"),
+    "mtp": (
+        lambda cfg: cfg.mtp_modules,
+        "multi-token prediction (mtp_modules={cfg.mtp_modules}: "
+        "params[\"mtp\"])",
+        "the modules are training's second loss: decoding has no draft "
+        "path that reads them, a mesh no spec for their weights, and the "
+        "pipeline's stages end at the model's own hidden state"),
     "share": (
         lambda cfg: cfg.moe_experts_held,
         "one chip's share of the experts (moe_experts_held="
@@ -483,7 +585,7 @@ _CANNOT = {
         "a model-parallel mesh shards all the experts over ep"),
 }
 # what decoding and the pipelined forward cannot run
-_TRAINS_ONLY = ("latent", "block", "stack")
+_TRAINS_ONLY = ("latent", "block", "stack", "hyper", "mtp")
 
 
 def _refuse(cfg, what, *features):
@@ -524,8 +626,16 @@ def _init_layers(k_attn, k_mlp, cfg, kind, stack):
             jax.random.fold_in(k_attn, 6), *stack, E, H * D)
     if kind.op == "a" and cfg.latent:
         rank, dn, dr, dv = cfg.latent
+        if cfg.q_latent_rank:
+            q_keys = jax.random.split(keys[0])
+            layers.update(
+                w_q_a=_dense_init(q_keys[0], *stack, E, cfg.q_latent_rank),
+                q_norm=_norm_init(*stack, cfg.q_latent_rank),
+                w_q_b=_dense_init(q_keys[1], *stack, cfg.q_latent_rank,
+                                  H * (dn + dr)))
+        else:
+            layers["wq"] = _dense_init(keys[0], *stack, E, H * (dn + dr))
         layers.update(
-            wq=_dense_init(keys[0], *stack, E, H * (dn + dr)),
             w_kv_a=_dense_init(keys[1], *stack, E, rank + dr),
             kv_norm=_norm_init(*stack, rank),
             w_kv_b=_dense_init(keys[2], *stack, rank, H * (dn + dv)),
@@ -570,7 +680,44 @@ def _init_layers(k_attn, k_mlp, cfg, kind, stack):
             layers["ws_gate"] = _dense_init(shared[0], *stack, E, S)
             layers["ws_up"] = _dense_init(shared[1], *stack, E, S)
             layers["ws_down"] = _dense_init(shared[2], *stack, S, E)
+    if cfg.hyper_streams:
+        for which in (1, 2):
+            layers.update(_init_hyper(
+                jax.random.fold_in(k_attn, 10 + which), cfg, stack,
+                "hc%d" % which))
     return layers
+
+
+# ``hc_eps``: what a Sinkhorn round adds to a sum it divides by.
+HYPER_SINKHORN_EPS = 1e-6
+# What a hyper-connection's ``alpha`` starts at: the dynamic part of a
+# map is a hundredth of its bias's.
+HYPER_ALPHA = 0.01
+# The diagonal of ``b_res``: ``exp(8)`` to 1 off it, so that the
+# Sinkhorn rounds start from the identity to 1e-3.
+HYPER_RES_DIAGONAL = 8.0
+
+
+def _init_hyper(key, cfg, stack, name, read_only=False):
+    """A sublayer's three maps (``ops/hyper_mix.py``), or with
+    ``read_only`` the first alone: ``<name>_phi`` [n dim, 2n + n^2]
+    drawn as a projection, ``<name>_alpha`` [3] at HYPER_ALPHA and
+    ``<name>_bias`` such that a new model is ``x + F(norm(x))`` on
+    streams that stay what they were: H_pre = 1 / n a stream (the mean:
+    the streams start as n copies of the embedding), H_post = 1, H_res
+    the identity to 1e-3."""
+    n = cfg.hyper_streams
+    bias = [jnp.full((n,), -np.log(n - 1.0), jnp.float32)]
+    if not read_only:
+        bias += [jnp.zeros((n,), jnp.float32),
+                 HYPER_RES_DIAGONAL * jnp.eye(n, dtype=jnp.float32).ravel()]
+    bias = jnp.concatenate(bias)
+    return {
+        name + "_phi": _dense_init(key, *stack, n * cfg.dim, bias.shape[0]),
+        name + "_alpha": jnp.full((*stack, 1 if read_only else 3),
+                                  HYPER_ALPHA, jnp.float32),
+        name + "_bias": jnp.broadcast_to(bias, (*stack, bias.shape[0])),
+    }
 
 
 def _init_delta(key, cfg, stack):
@@ -646,7 +793,30 @@ def init_params(rng, cfg):
     }
     if not cfg.tied_embeddings:
         params["lm_head"] = _dense_init(k_out, E, cfg.vocab_size, scale=0.02)
+    if cfg.hyper_streams:
+        params.update(_init_hyper(jax.random.fold_in(k_out, 1), cfg, (),
+                                  "hc_out", read_only=True))
+    if cfg.mtp_modules:
+        params["mtp"] = {str(k): _init_mtp(jax.random.fold_in(k_out, 2 + k),
+                                           cfg)
+                         for k in range(cfg.mtp_modules)}
     return params
+
+
+def _init_mtp(key, cfg):
+    """One multi-token-prediction module: the norms of its two inputs,
+    the projection of their concatenation, one block of ``cfg.mtp_kind``
+    and, on a wide stream, the map its result is read through."""
+    E = cfg.dim
+    k_proj, k_attn, k_mlp, k_out = jax.random.split(key, 4)
+    module = {
+        "norm_h": _norm_init(E), "norm_e": _norm_init(E),
+        "proj": _dense_init(k_proj, 2 * E, E),
+        "layer": _init_layers(k_attn, k_mlp, cfg, cfg.mtp_kind, ()),
+    }
+    if cfg.hyper_streams:
+        module.update(_init_hyper(k_out, cfg, (), "hc_out", read_only=True))
+    return module
 
 
 def param_specs(cfg):
@@ -706,24 +876,52 @@ def _rmsnorm(x, scale, eps=1e-6, axis=-1):
     return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 
-def _rope_tables(d, positions, theta):
+def yarn_ramp(d, theta, scaling):
+    """[d / 2] float64: how far each of RoPE's frequencies for heads of
+    ``d`` goes from itself (0) to itself / factor (1) under YaRN: a
+    linear ramp between the two correction dimensions, those whose
+    wavelength fits ``original`` positions beta_fast and beta_slow
+    times."""
+    _, original, fast, slow = yarn_of(scaling)
+    turns = lambda n: d * np.log(original / (n * 2 * np.pi)) / (
+        2 * np.log(float(theta)))
+    low = max(np.floor(turns(fast)), 0)
+    high = min(np.ceil(turns(slow)), d - 1)
+    return np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0, 1)
+
+
+def yarn_scale(scaling):
+    """What YaRN multiplies latent attention's softmax scale by: the
+    square of ``0.1 ln factor + 1`` (1 without scaling)."""
+    factor = yarn_of(scaling)[0] if scaling else 1.0
+    return (0.1 * np.log(factor) + 1.0) ** 2 if factor > 1 else 1.0
+
+
+def _rope_tables(d, positions, theta, scaling=""):
     """(cos, sin) [T, d / 2] float32 of RoPE's angles at base ``theta``
-    for heads of ``d``."""
+    for heads of ``d``, the frequencies blended by YaRN where
+    ``scaling`` says so (``TransformerConfig.rope_scaling``)."""
     half = d // 2
     freqs = jnp.exp(
         -np.log(float(theta)) * jnp.arange(0, half, dtype=jnp.float32)
         / half
     )
+    if scaling:
+        # f (1 - ramp) + (f / factor) ramp, written so that factor 1
+        # is f itself
+        freqs = freqs * jnp.asarray(
+            1.0 - yarn_ramp(d, theta, scaling) * (
+                1.0 - 1.0 / yarn_of(scaling)[0]), jnp.float32)
     angles = positions[:, None].astype(jnp.float32) * freqs[None, :]
     return jnp.cos(angles), jnp.sin(angles)
 
 
-def _rope(x, positions, theta=10000.0):
+def _rope(x, positions, theta=10000.0, scaling=""):
     """Rotary embeddings (rotate-half) at base ``theta``; x: [B, T, H,
     D]."""
     half = x.shape[-1] // 2
-    cos, sin = (table[None, :, None, :]
-                for table in _rope_tables(x.shape[-1], positions, theta))
+    cos, sin = (table[None, :, None, :] for table in _rope_tables(
+        x.shape[-1], positions, theta, scaling))
     x1, x2 = x[..., :half], x[..., half:]
     rotated = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
@@ -731,7 +929,7 @@ def _rope(x, positions, theta=10000.0):
     return rotated.astype(x.dtype)
 
 
-def _rope_heads_first(x, positions, theta):
+def _rope_heads_first(x, positions, theta, scaling=""):
     """:func:`_rope` of x [B, H, T, D], positions along T, in the
     layout it has: the same arithmetic written ``x * [cos, cos] + (x P)
     * [-sin, sin]``, P the D x D permutation that swaps the halves (a
@@ -742,7 +940,7 @@ def _rope_heads_first(x, positions, theta):
     product it fuses with the elementwise work into one pass
     (docs/designs/attention.md)."""
     d = x.shape[-1]
-    cos, sin = _rope_tables(d, positions, theta)
+    cos, sin = _rope_tables(d, positions, theta, scaling)
     swap = jnp.asarray(np.roll(np.eye(d), d // 2, axis=1), x.dtype)
     swapped = jnp.einsum("bhtd,de->bhte", x, swap,
                          preferred_element_type=jnp.float32)
@@ -904,9 +1102,17 @@ def _project_latent(h, w, cfg, positions, rope=True):
     compute_dtype = jnp.dtype(cfg.dtype)
     H = cfg.num_heads
     rank, dn, dr, dv = cfg.latent
-    wq = w["wq"].astype(compute_dtype).reshape(-1, H, dn + dr)
-    q_nope = _heads_first(h, wq[..., :dn])
-    q_rope = _heads_first(h, wq[..., dn:])
+    q_in = h
+    if cfg.q_latent_rank:
+        # the query latent, normed: what the two per-head products read
+        q_in = _rmsnorm(
+            checkpoint_name(h @ w["w_q_a"].astype(compute_dtype),
+                            remat_keep.KEEP_Q_LATENT),
+            w["q_norm"].astype(compute_dtype), cfg.norm_eps)
+    wq = w["w_q_b" if cfg.q_latent_rank else "wq"].astype(
+        compute_dtype).reshape(-1, H, dn + dr)
+    q_nope = _heads_first(q_in, wq[..., :dn])
+    q_rope = _heads_first(q_in, wq[..., dn:])
     # the latent and the RoPE key as the projection gives them: [T,
     # rank + Dr] a layer, what two matmuls make k_nope and v from
     c = checkpoint_name(h @ w["w_kv_a"].astype(compute_dtype),
@@ -916,8 +1122,9 @@ def _project_latent(h, w, cfg, positions, rope=True):
     w_kv_b = w["w_kv_b"].astype(compute_dtype).reshape(rank, H, dn + dv)
     k_rope = c[..., None, rank:]
     if rope:
-        q_rope = _rope_heads_first(q_rope, positions, cfg.rope_theta)
-        k_rope = _rope(k_rope, positions, cfg.rope_theta)
+        q_rope = _rope_heads_first(q_rope, positions, cfg.rope_theta,
+                                   cfg.rope_scaling)
+        k_rope = _rope(k_rope, positions, cfg.rope_theta, cfg.rope_scaling)
     name = checkpoint_name
     return (name(q_nope, remat_keep.KEEP_Q),
             name(q_rope, remat_keep.KEEP_Q),
@@ -944,9 +1151,13 @@ def _latent_mix(h, w, cfg, positions, kind):
     T = h.shape[1]
     announce_latent(cfg.num_heads, T, cfg.latent, *latent_mode(
         T, *cfg.latent[1:], compute_dtype.itemsize))
+    scale = None
+    if cfg.rope_scaling:
+        scale = float((cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+                      * yarn_scale(cfg.rope_scaling))
     attn = latent_attention(
         *_project_latent(h, w, cfg, positions, kind.rope), causal=True,
-        window=kind.window)
+        scale=scale, window=kind.window)
     # W_o contracts the kernels' [B, H, T, Dv] output over (head, width)
     # as it stands: no token-major copy of it is made first.  Reshaped
     # before the cast, so that the gradient stays [H, Dv, dim] through
@@ -974,10 +1185,31 @@ def _shared_expert(h, w, cfg):
                        remat_keep.KEEP_SHARED_UP))
 
 
-def _residual(x, out, w, cfg, mesh, post):
+def _read(x, w, cfg, name):
+    """(what a sublayer computes on, the stream as ``_residual`` takes
+    it back, how ``_residual`` writes to it): the stream itself, and
+    None; of a stream ``cfg.hyper_streams`` wide, [B, T, n dim], its
+    streams mixed by the sublayer's first map, [B, T, dim], and the two
+    maps that write (``ops/hyper_mix.pre``, whose Sinkhorn error joins
+    the running maximum the stream carries beside it)."""
+    if not cfg.hyper_streams:
+        return x, x, None
+    x, err = x
+    u, x, maps, off = hyper_mix.pre(
+        x, *(w["%s_%s" % (name, part)] for part in ("phi", "alpha", "bias")),
+        cfg.hyper_streams, cfg.hyper_sinkhorn_iters, cfg.norm_eps,
+        HYPER_SINKHORN_EPS)
+    return u, (x, jnp.maximum(err, off)), maps
+
+
+def _residual(x, out, w, cfg, mesh, post, maps=None):
     """``x + post(out)``: where a sublayer's result ``out`` joins the
     stream, the operator's and the FFN's alike; ``post`` names the
-    RMSNorm it passes first in a block with ``cfg.post_norms``."""
+    RMSNorm it passes first in a block with ``cfg.post_norms``.  On a
+    wide stream (``maps``: ``_read``'s) ``H_res x + H_post out``."""
+    if maps is not None:
+        x, err = x
+        return hyper_mix.post(x, out, maps, cfg.hyper_streams), err
     if cfg.post_norms:
         out = _rmsnorm(out, w[post].astype(jnp.dtype(cfg.dtype)),
                        cfg.norm_eps)
@@ -998,7 +1230,8 @@ def _ffn(x, w, cfg, mesh, dense=False, route=None):
     are the MoE's (:func:`_moe_ffn`, which ``route`` is for), zeros and
     None for a dense FFN (a model without experts, or a ``dense`` layer
     of one with)."""
-    h = _pre(x, w, cfg, "ln2")
+    u, x, maps = _read(x, w, cfg, "hc2")
+    h = _pre(u, w, cfg, "ln2")
     if cfg.moe_experts and not dense:
         out, aux, stats, load = _moe_ffn(h, w, cfg, mesh, route)
         if cfg.shared_dim:
@@ -1007,7 +1240,8 @@ def _ffn(x, w, cfg, mesh, dense=False, route=None):
         out = _gated_mlp(h, w, cfg, ("w_gate", "w_up", "w_down"),
                          (remat_keep.KEEP_GATE, remat_keep.KEEP_UP))
         aux, stats, load = jnp.float32(0.0), None, None
-    return _residual(x, out, w, cfg, mesh, "ln2_post"), aux, stats, load
+    return (_residual(x, out, w, cfg, mesh, "ln2_post", maps), aux, stats,
+            load)
 
 
 @functools.lru_cache(maxsize=None)
@@ -1202,7 +1436,8 @@ def _operator(x, w, cfg, mesh, positions, kind):
     operator of ``kind``: attention, latent attention (nothing cached:
     decoding refuses it), the short convolution or the gated delta
     rule."""
-    h = _pre(x, w, cfg, "ln1")
+    u, x, maps = _read(x, w, cfg, "hc1")
+    h = _pre(u, w, cfg, "ln1")
     kv_out = None
     if kind.op == "c":
         out = _conv_mix(h, w, cfg)
@@ -1214,7 +1449,7 @@ def _operator(x, w, cfg, mesh, positions, kind):
         out = _latent_mix(h, w, cfg, positions, kind)
     else:
         out, kv_out = _attention_mix(h, w, cfg, mesh, positions, kind)
-    x = _residual(x, out, w, cfg, mesh, "ln1_post")
+    x = _residual(x, out, w, cfg, mesh, "ln1_post", maps)
     return checkpoint_name(x, remat_keep.KEEP_STREAM), kv_out
 
 
@@ -1286,18 +1521,20 @@ def _head(params, x, cfg):
     return (x @ head).astype(jnp.float32)
 
 
-def head_loss(params, hidden, tokens, cfg):
+def head_loss(params, hidden, tokens, cfg, shift=1):
     """``next_token_loss(_head(params, hidden, cfg), tokens)`` for the
     training path: the head and the loss as one op
     (:mod:`elasticdl_tpu.ops.head_loss`), whose one [B, T, V] tensor is
-    the logits in the compute dtype."""
+    the logits in the compute dtype.  ``shift``: the target is the
+    token that many on (a multi-token-prediction module's is 2 and
+    more)."""
     from elasticdl_tpu.ops import head_loss as op
 
     compute_dtype = jnp.dtype(cfg.dtype)
     x = _rmsnorm(hidden, params["ln_f"].astype(compute_dtype), cfg.norm_eps)
     head = params["embed" if cfg.tied_embeddings else "lm_head"]
     return op.head_loss(x, head.astype(compute_dtype), tokens,
-                        tied=cfg.tied_embeddings)
+                        tied=cfg.tied_embeddings, shift=shift)
 
 
 @jax.custom_vjp
@@ -1321,7 +1558,7 @@ def announce_updates_apart(leaves, nbytes):
     logger.info("update apart: leaves=%d bytes=%d", leaves, nbytes)
 
 
-def _updates_apart(layers, plan):
+def _updates_apart(layers, plan, mtp=None):
     """The stack's weights, each MATRIX (a leaf of rank 2 once the
     scanned axis of ``layers`` or of ``layers["period"]`` is taken off:
     projections, FFN weights, routers, a convolution's taps; not a
@@ -1338,7 +1575,8 @@ def _updates_apart(layers, plan):
     already runs after the loop on the stacked gradient, and a barrier
     there buys nothing and moves the program (``olmo1b`` on four chips:
     the compiler's bytes +0.18 GB, the loop's all-reduces combined
-    otherwise)."""
+    otherwise).  With ``mtp`` (the multi-token-prediction modules, each
+    a layer outside any loop) -> (layers, mtp)."""
     held = []
 
     def apart(scanned):
@@ -1355,26 +1593,67 @@ def _updates_apart(layers, plan):
     else:
         layers = {name: apart(int(name == "period"))(group)
                   for name, group in layers.items()}
+    if mtp is not None:     # the modules' blocks and projections too
+        mtp = apart(0)(mtp)
     announce_updates_apart(len(held), 4 * sum(held))
-    return layers
+    return layers if mtp is None else (layers, mtp)
 
 
-def forward_hidden(params, tokens, cfg, mesh=None, return_load=False):
-    """tokens: [B, T] int32 -> (final hidden [B, T, dim] BEFORE the
-    ln_f/head, mean per-layer MoE aux); with ``return_load`` (an MoE)
-    also each layer's load [L, X + 1] (:func:`_moe_ffn`).
+def _widen(x, cfg):
+    """The stream's first value of a model with ``cfg.hyper_streams``:
+    x [B, T, dim] n times side by side, beside the running maximum of
+    the Sinkhorn error that the blocks' reads raise (``_read``)."""
+    if not cfg.hyper_streams:
+        return x
+    return jnp.tile(x, (1, 1, cfg.hyper_streams)), jnp.float32(0.0)
 
-    Pair with :func:`next_token_loss_chunked` to train without ever
-    materializing the [B, T, V] logits tensor (at the flagship config
-    that tensor is ~2 GB in f32 — a pure HBM-bandwidth tax the chunked
-    loss removes).
-    """
-    x = _constrain(_embed(params, tokens, cfg, mesh), mesh,
-                   P("dp", "sp", None))
+
+def _narrow(x, w, cfg):
+    """(hidden state [B, T, dim], Sinkhorn error or None) of the stream
+    behind the last block: on a wide stream the streams read through
+    the map ``hc_out_*`` of ``w``."""
+    if not cfg.hyper_streams:
+        return x, None
+    x, err = x
+    return hyper_mix.narrow(
+        x, w["hc_out_phi"], w["hc_out_alpha"], w["hc_out_bias"],
+        cfg.hyper_streams, cfg.norm_eps), err
+
+
+def _mtp_module(w, hidden, embedded, k, cfg, block):
+    """Multi-token-prediction module ``k`` (0-based) -> (its hidden
+    state [B, T, dim] before the final norm, what its block returned
+    beside the stream, its Sinkhorn error or None).  ``hidden``: the
+    hidden state it continues; ``embedded``: the tokens' embeddings, of
+    which position t takes token t + k + 1's (the last k + 1 positions
+    take the sequence's first: no loss reads them, and causal attention
+    lets no other position see them)."""
+    dtype = jnp.dtype(cfg.dtype)
+    norm = lambda x, name: _rmsnorm(x, w[name].astype(dtype), cfg.norm_eps)
+    proj = w["proj"].astype(dtype)
+    # [RMSNorm(h) ; RMSNorm(E)] W_proj, the product split by W_proj's
+    # rows: no [B, T, 2 dim] copy
+    x = (norm(hidden, "norm_h") @ proj[:cfg.dim]
+         + norm(jnp.roll(embedded, -(k + 1), axis=1), "norm_e")
+         @ proj[cfg.dim:])
+    x, out = block(cfg.mtp_kind)(_widen(x, cfg), w["layer"])
+    return (*_narrow(x, w, cfg), out)
+
+
+def _forward_stack(params, tokens, cfg, mesh=None, with_load=False,
+                   with_mtp=False):
+    """tokens [B, T] -> {"hidden": [B, T, dim] before ln_f and the head;
+    "aux": each layer's MoE aux [L_moe] (a zero where none has experts);
+    "load": each such layer's load (``_moe_ffn``) with ``with_load``;
+    "hc_err": on a wide stream the largest Sinkhorn error of the step;
+    "mtp_hidden": with ``with_mtp`` each multi-token-prediction
+    module's hidden state, its block's aux and load joined to the
+    stack's}."""
+    embedded = _constrain(_embed(params, tokens, cfg, mesh), mesh,
+                          P("dp", "sp", None))
+    x = _widen(embedded, cfg)
     positions = jnp.arange(tokens.shape[1])
 
-    with_load = bool(return_load
-                     and not all(kind.dense for kind in cfg.kinds))
     remat = lambda fn: fn
     if cfg.remat:
         # the names that fit the room declared around this trace, none
@@ -1395,35 +1674,70 @@ def forward_hidden(params, tokens, cfg, mesh=None, return_load=False):
         return remat(layer)
 
     plan = stack_plan(cfg)
-    layers = _updates_apart(params["layers"], plan)
-    if plan is None:
-        x, aux_per_layer = jax.lax.scan(block(), x, layers)
-    else:
-        with remat_keep.keeping(names if cfg.remat else ()):
-            x, aux_per_layer = _mixed_stack(x, layers, cfg, plan, block)
+    modules = params.get("mtp") if with_mtp else None
+    layers = _updates_apart(params["layers"], plan, modules)
+    if modules:
+        layers, modules = layers
+    out = {"mtp_hidden": []}
+    with remat_keep.keeping(names if cfg.remat and plan is not None
+                            else ()):
+        if plan is None:
+            x, seen = jax.lax.scan(block(), x, layers)
+        else:
+            x, seen = _mixed_stack(x, layers, cfg, plan, block)
+        hidden, err = _narrow(x, params, cfg)
+        out["hidden"] = hidden
+        for k in range(len(modules or ())):
+            hidden, off, more = _mtp_module(
+                modules[str(k)], hidden, embedded, k, cfg, block)
+            out["mtp_hidden"].append(hidden)
+            if off is not None:
+                err = jnp.maximum(err, off)
+            if not cfg.mtp_kind.dense:
+                seen = jax.tree_util.tree_map(
+                    lambda a, b: jnp.concatenate([a, b[None]]), seen, more)
+    out["aux"], out["load"] = seen if with_load else (seen, None)
+    out["hc_err"] = err
+    return out
+
+
+def forward_hidden(params, tokens, cfg, mesh=None, return_load=False):
+    """tokens: [B, T] int32 -> (final hidden [B, T, dim] BEFORE the
+    ln_f/head, mean per-layer MoE aux); with ``return_load`` (an MoE)
+    also each layer's load [L, X + 1] (:func:`_moe_ffn`).
+
+    Pair with :func:`next_token_loss_chunked` to train without ever
+    materializing the [B, T, V] logits tensor (at the flagship config
+    that tensor is ~2 GB in f32 — a pure HBM-bandwidth tax the chunked
+    loss removes).
+    """
+    with_load = bool(return_load
+                     and not all(kind.dense for kind in cfg.kinds))
+    out = _forward_stack(params, tokens, cfg, mesh, with_load)
     if with_load:
-        aux_per_layer, load = aux_per_layer
-        return x, aux_per_layer.mean(), load
-    return x, aux_per_layer.mean()
+        return out["hidden"], out["aux"].mean(), out["load"]
+    return out["hidden"], out["aux"].mean()
 
 
 @functools.lru_cache(maxsize=None)
-def announce_stack(pattern, plan, experts, shared=0, heads=None):
+def announce_stack(pattern, plan, experts, shared=0, heads=None,
+                   more=""):
     """Once per model, by the logger ``announce_tiles`` uses: how a
     stack whose layers differ is run (``shared``: the width of an
     expert layer's always-on shared expert, said where there is one;
     ``heads``: (held here, of how many) where chips share a layer's
-    heads)."""
+    heads; ``more``: the fields of a wide stream, multi-token
+    prediction and a query latent, where the model has them)."""
     letters = lambda kinds: "".join(map(_letter, kinds)) or "-"
     kinds = sorted(set(k for k in plan.lead + plan.period + plan.tail
                        if k.op == "a"), key=_letter)
     logger.info(
         "layer stack: pattern=%s lead=%s period=%s periods=%d tail=%s "
-        "dense_layers=%d experts_held=%d/%d%s%s%s", pattern,
+        "dense_layers=%d experts_held=%d/%d%s%s%s%s", pattern,
         letters(plan.lead), letters(plan.period), plan.periods,
         letters(plan.tail), len(plan.lead), *experts,
         " shared_expert=%d" % shared if shared else "",
-        " heads_held=%d/%d" % heads if heads else "",
+        " heads_held=%d/%d" % heads if heads else "", more,
         "".join(" %s:window=%d,rope=%d" % (_letter(k), k.window, k.rope)
                 for k in kinds))
 
@@ -1436,7 +1750,13 @@ def _mixed_stack(x, layers, cfg, plan, block):
     announce_stack("".join(map(_letter, cfg.kinds)), plan,
                    (cfg.experts_held[1], cfg.moe_experts), cfg.shared_dim,
                    (cfg.num_heads, cfg.num_heads * cfg.head_shares)
-                   if cfg.head_shares > 1 else None)
+                   if cfg.head_shares > 1 else None,
+                   "".join(" %s=%d" % (name, value) for name, value in (
+                       ("hyper", cfg.hyper_streams),
+                       ("sinkhorn", cfg.hyper_streams
+                        and cfg.hyper_sinkhorn_iters),
+                       ("mtp", cfg.mtp_modules),
+                       ("q_latent", cfg.q_latent_rank)) if value))
 
     tree_map = jax.tree_util.tree_map
 
@@ -1795,11 +2115,14 @@ def _decayed(params):
     ``expert_bias``, the scales of the norms on a sublayer's output
     and, of a gated-delta layer, its decay rates, its step bias, its
     output norm's scale, its convolution's taps and (kda) its output
-    gate's bias."""
+    gate's bias, and a hyper-connection's ``alpha`` and bias (decayed,
+    its maps would leave the identity they start from)."""
     return jax.tree_util.tree_map_with_path(
         lambda path, _: getattr(path[-1], "key", None) not in (
             "expert_bias", "ln1_post", "ln2_post", "A_log", "dt_bias",
-            "o_norm", "delta_conv", "b_g"), params)
+            "o_norm", "delta_conv", "b_g")
+        and not str(getattr(path[-1], "key", "")).endswith(
+            ("_alpha", "_bias")), params)
 
 
 def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
@@ -1880,6 +2203,12 @@ def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
         return params
 
     moe = not all(kind.dense for kind in cfg.kinds)   # a layer has experts
+    wide = bool(cfg.hyper_streams or cfg.mtp_modules)
+    if cfg.mtp_modules and (xent_chunk or pipelined):
+        raise ValueError(
+            "mtp_modules=%d: the modules' loss is ops/head_loss.py's at a "
+            "shift of its own; xent_chunk and a pipelined forward have "
+            "none" % cfg.mtp_modules)
 
     def apply_fn(params, tokens, train):
         """Logits; training, a dict for ``loss_fn``, in which the head
@@ -1892,7 +2221,14 @@ def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
                     remat=cfg.remat)
             return forward(params, tokens, cfg, mesh=mesh)
         out = {"params": params}
-        if pipelined:
+        if wide:
+            # a wide stream's Sinkhorn error and the modules' hidden
+            # states leave the stack beside the model's
+            out.update(_forward_stack(params, tokens, cfg, mesh, moe,
+                                      with_mtp=True))
+            out["aux"] = out["aux"].mean()
+            out["moe_load"] = out.pop("load")
+        elif pipelined:
             out["hidden"], out["aux"] = forward_pipelined(
                 params, tokens, cfg, mesh, pipeline_microbatches,
                 remat=cfg.remat, return_aux=True,
@@ -1915,17 +2251,31 @@ def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
         else:
             loss = head_loss(
                 outputs["params"], outputs["hidden"], tokens, cfg)
+        if outputs.get("mtp_hidden"):
+            # left in ``outputs`` for ``step_stats``, before its weight
+            outputs["mtp_loss"] = sum(
+                head_loss(outputs["params"], hidden, tokens, cfg, shift=k + 2)
+                for k, hidden in enumerate(outputs["mtp_hidden"])
+            ) / cfg.mtp_modules
+            loss = loss + cfg.mtp_weight * outputs["mtp_loss"]
         if moe:
             loss = loss + cfg.moe_aux_weight * outputs["aux"]
         return loss
 
     def step_stats(outputs):
+        stats = {}
+        if outputs.get("hc_err") is not None:
+            stats["hc_err"] = outputs["hc_err"]
+        if "mtp_loss" in outputs:
+            stats["mtp_loss"] = outputs["mtp_loss"].mean()
+        if not moe:
+            return stats
         load = outputs["moe_load"]
         if not cfg.moe_experts_held:
-            return {"moe_load": load}
+            return dict(stats, moe_load=load)
         # a share's dispatch counts the rows it moved and its spills
-        return {"moe_load": load[:, :-2], "moe_moved": load[:, -2],
-                "moe_spilled": load[:, -1]}
+        return dict(stats, moe_load=load[:, :-2], moe_moved=load[:, -2],
+                    moe_spilled=load[:, -1])
 
     def feed(records):
         toks = np.stack(
@@ -1944,12 +2294,13 @@ def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
              if warmup_steps else learning_rate), weight_decay=0.01,
             mask=(_decayed if cfg.moe_router == "sigmoid_bias"
                   or cfg.post_norms or "d" in cfg.layer_pattern
-                  else None)),
+                  or cfg.hyper_streams else None)),
         feed=feed,
         eval_metrics_fn=lambda: {
             "nll": metrics.Mean(lambda outputs, labels: outputs)
         },
-        step_stats_fn=None if pipelined or not moe else step_stats,
+        step_stats_fn=(None if pipelined or not (moe or wide)
+                       else step_stats),
     )
     spec.config = cfg
     return spec

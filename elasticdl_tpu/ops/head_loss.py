@@ -12,7 +12,11 @@ saved logits and their log-sum-exp, rounds it to the logits' dtype and
 feeds the two matmuls JAX would emit.
 
 All T positions are computed and the last one weighs zero: the matmuls
-then run on whole tiles (4 x 4096 rows, not 4 x 4095).
+then run on whole tiles (4 x 4096 rows, not 4 x 4095).  ``shift`` = k
+makes the target the k-th token on, the last k positions weigh zero and
+the mean is over T - k (a multi-token-prediction module's loss,
+``models/transformer.py``: a second call on another hidden state shares
+the head, whose gradient is then the sum of both calls').
 
 The op owns how its three matmuls are emitted, and holds them apart
 from their neighbours with ``optimization_barrier``: left alone, XLA
@@ -45,24 +49,25 @@ def announce_head_loss(rows, vocab, dtype):
         dtype, rows * vocab * jnp.dtype(dtype).itemsize)
 
 
-def _targets(tokens):
-    """Next tokens, and which positions have one: all but the last."""
+def _targets(tokens, shift):
+    """The tokens ``shift`` on, and which positions have one: all but
+    the last ``shift``."""
     t = tokens.shape[1]
-    has_target = (jnp.arange(t) < t - 1).astype(jnp.float32)
-    return jnp.roll(tokens, -1, axis=1), has_target
+    has_target = (jnp.arange(t) < t - shift).astype(jnp.float32)
+    return jnp.roll(tokens, -shift, axis=1), has_target
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _head_loss(x, head, tokens, tied):
-    return _head_loss_fwd(x, head, tokens, tied)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _head_loss(x, head, tokens, tied, shift):
+    return _head_loss_fwd(x, head, tokens, tied, shift)[0]
 
 
-def _head_loss_fwd(x, head, tokens, tied):
+def _head_loss_fwd(x, head, tokens, tied, shift):
     x = jax.lax.optimization_barrier(x)     # read, not recomputed
     # no preferred_element_type: the result takes the operands' dtype,
     # as ``x @ head`` does
     logits = jnp.einsum("bte,ve->btv" if tied else "bte,ev->btv", x, head)
-    targets, has_target = _targets(tokens)
+    targets, has_target = _targets(tokens, shift)
     # optax.softmax_cross_entropy_with_integer_labels' arithmetic, on
     # values upcast where they are read
     top = logits.max(axis=-1).astype(jnp.float32)
@@ -70,15 +75,16 @@ def _head_loss_fwd(x, head, tokens, tied):
         logits, targets[..., None], axis=-1)[..., 0].astype(jnp.float32) - top
     log_z = jnp.log(jnp.exp(
         logits.astype(jnp.float32) - top[..., None]).sum(axis=-1))
-    loss = ((log_z - label) * has_target).sum(axis=-1) / (x.shape[1] - 1)
+    loss = ((log_z - label) * has_target).sum(axis=-1) / (
+        x.shape[1] - shift)
     return loss, (logits, log_z + top, x, head, tokens)
 
 
-def _head_loss_bwd(tied, residuals, g):
+def _head_loss_bwd(tied, shift, residuals, g):
     logits, lse, x, head, tokens = residuals
-    targets, has_target = _targets(tokens)
+    targets, has_target = _targets(tokens, shift)
     scale = (g.astype(jnp.float32)[:, None] * has_target
-             / (x.shape[1] - 1))                              # [B, T]
+             / (x.shape[1] - shift))                          # [B, T]
     vocab = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2)
     softmax = jnp.exp(logits.astype(jnp.float32) - lse[..., None])
     cot = ((softmax - (vocab == targets[..., None])) * scale[..., None]
@@ -101,8 +107,9 @@ def _head_loss_bwd(tied, residuals, g):
 _head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
 
 
-def head_loss(x, head, tokens, tied=False):
-    """Per-example mean next-token cross entropy ``[B]`` (float32).
+def head_loss(x, head, tokens, tied=False, shift=1):
+    """Per-example mean cross entropy ``[B]`` (float32) of the token
+    ``shift`` on (1: the next).
 
     ``x`` [B, T, E]: the final norm's output; ``head`` [E, V], or with
     ``tied`` the embedding [V, E]; ``tokens`` [B, T].  Both operands in
@@ -112,4 +119,4 @@ def head_loss(x, head, tokens, tied=False):
     vocab = head.shape[0 if tied else 1]
     announce_head_loss(b * t // batch_shard.shards(), vocab,
                        jnp.result_type(x, head).name)
-    return _head_loss(x, head, tokens, tied)
+    return _head_loss(x, head, tokens, tied, shift)

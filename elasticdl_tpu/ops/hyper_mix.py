@@ -1,0 +1,447 @@
+"""Manifold-constrained hyper-connections (Pallas TPU): a residual
+stream ``n`` wide, read and written through learned maps.
+
+A token's stream is ``X`` in R^{n x C}, held as ``[B, T, n * C]`` (stream
+``i`` is columns ``i C .. (i + 1) C``: a ``[.., n, C]`` array's second
+minor dimension of 4 would be tiled to 16 sublanes in HBM, four times
+the bytes).  A sublayer ``F`` owns ``phi`` [n C, n + n + n^2], three
+scalars ``alpha`` and a bias ``[n + n + n^2]`` (arXiv:2512.24880):
+
+    x~ = vec(X) / rms(vec(X));  [p, q, r] = x~ phi
+    H_pre  = sigmoid(alpha_pre p + b_pre)                      [n]
+    H_post = 2 sigmoid(alpha_post q + b_post)                  [n]
+    H_res  = SK(exp(clamp(alpha_res mat(r) + b_res)))          [n, n]
+    u = sum_i H_pre[i] X[i];  y = F(norm(u))
+    X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y
+
+``SK``: ``iters`` rounds of rows over their sums, then columns over
+their sums (``+ sk_eps``), float32.  ``pre(X, ..) -> (u, X, maps, err)``
+and ``post(X, y, maps) -> X'`` are the two halves a block writes round
+its sublayer; ``narrow`` is ``pre`` with the first map alone (what a
+model reads the stream through after its last layer).
+
+Bound by memory: the stream is 4 C values a token where every other
+operand of a block is C.  The kernels make the fewest passes of it
+there are: ``hc_pre_fwd`` reads X once (the logits' matmul on the MXU
+from the same block in VMEM, ``alpha`` folded into ``phi`` outside, the
+normalization a float32 scale of the product's rows), ``hc_post_fwd``
+reads X and writes X'; three passes a sublayer.  Backward:
+``hc_post_bwd`` reads X and dX', writes dX; ``hc_pre_bwd`` reads X and
+that dX and writes their sum with its own terms (``pre`` hands X
+through, so that the stream's two readers' cotangents meet in the
+kernel and not in a pass of XLA's), and ``phi``'s gradient is one
+matmul of XLA's that reads X once more: seven.  The maps between (24
+values a token: the sigmoids, the Sinkhorn rounds on ``[n, n, rows]``
+with the tokens minor, so that a round is four small fusions whatever
+the rows, the rounds one ``lax.scan``) are ``jax.numpy``, differentiated
+by JAX.
+
+The calls carry their names into the compiled program and a device
+trace: ``hc_pre_fwd``, ``hc_post_fwd``, ``hc_pre_bwd``, ``hc_post_bwd``
+(``benchmark/kernels/hyper_mix.py``).  Reference: ``pre_ref`` and
+``post_ref``, plain ``jax.numpy`` differentiated by JAX, which is also
+what runs wherever ``ops/mode.py`` answers ``off`` or the shape does not
+tile (it says so: ``announce_fallback``).
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from elasticdl_tpu.ops import flash_attention
+from elasticdl_tpu.ops.batch_shard import per_batch_shard, shards
+from elasticdl_tpu.ops.mode import resolve
+
+# ``checkpoint_name``s of what a second forward need not make again
+# (models/remat_keep.py): a sublayer's mixed input and its logits.
+KEEP_U, KEEP_Z = "hc_u", "hc_z"
+
+# The maps' columns as the kernels take them: one 128-lane tile.
+LANES = 128
+ROW_TILES = (128, 64, 32, 16)
+VMEM_LIMIT = 64 * 1024 * 1024
+CLAMP = (-30.0, 30.0)
+
+
+def columns(n):
+    """How many logits a token has: H_pre, H_post, H_res."""
+    return 2 * n + n * n
+
+
+def sinkhorn(m, iters, eps):
+    """m [n, n, rows], positive -> the same after ``iters`` rounds of
+    each row (axis 1) over its sum, then each column (axis 0) over its
+    sum (``+ eps``).  The token axis is the minor one: a round is two
+    sums over four planes and two divisions, whatever n.  A loop of
+    the program's and not of Python's: a model's step holds the rounds
+    of every sublayer three times (forward, second forward, backward),
+    and unrolled the benchmark's cell compiled 5,000 small fusions more
+    into an executable past the compile cache's 192 MiB an entry."""
+    def one_round(m, _):
+        m = m / (m.sum(axis=1, keepdims=True) + eps)
+        return m / (m.sum(axis=0, keepdims=True) + eps), None
+
+    return lax.scan(one_round, m, None, length=iters)[0]
+
+
+def maps_of(z, bias, n, iters, eps, sinkhorn_dtype=jnp.float32):
+    """(maps [.., LANES] float32: H_post in columns n .. 2n, H_res row
+    major in 2n .. 2n + n^2, zeros elsewhere; err: the largest ``|row or
+    column sum - 1|`` of any token's H_res, no gradient) of the logits
+    ``z`` [.., >= 2n + n^2] float32 (``alpha`` already in them) and
+    ``bias``.  ``sinkhorn_dtype``: what the rounds run in (float32; a
+    precision tool shows what a lower one costs)."""
+    z = z[..., :columns(n)] + bias
+    lead = z.shape[:-1]
+    h_post = 2.0 * jax.nn.sigmoid(z[..., n:2 * n])
+    logits = jnp.clip(z[..., 2 * n:], *CLAMP)
+    h_res = sinkhorn(
+        jnp.exp(jnp.moveaxis(logits, -1, 0).reshape(n, n, -1)).astype(
+            sinkhorn_dtype), iters, eps).astype(jnp.float32)
+    err = lax.stop_gradient(jnp.maximum(
+        jnp.max(jnp.abs(h_res.sum(axis=1) - 1.0)),
+        jnp.max(jnp.abs(h_res.sum(axis=0) - 1.0))))
+    flat = jnp.moveaxis(h_res.reshape(n * n, *lead), 0, -1)
+    maps = jnp.concatenate([jnp.zeros_like(h_post), h_post, flat], axis=-1)
+    pad = [(0, 0)] * (maps.ndim - 1) + [(0, LANES - maps.shape[-1])]
+    return jnp.pad(maps, pad), err
+
+
+def _streams(x, n):
+    """The n streams of x [.., n C], float32."""
+    c = x.shape[-1] // n
+    return [x[..., i * c:(i + 1) * c].astype(jnp.float32) for i in range(n)]
+
+
+def logits_ref(x, phi, eps):
+    """``(x / rms(x)) phi`` [.., M] float32 as the kernel rounds it: the
+    product of x and phi in x's dtype, summed in float32, its rows
+    scaled by the float32 ``1 / rms``."""
+    xf = x.astype(jnp.float32)
+    inv = lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return jnp.einsum("...k,km->...m", x, phi.astype(x.dtype),
+                      preferred_element_type=jnp.float32) * inv
+
+
+def pre_ref(x, phi, bias, n, eps):
+    """(u [.., C] in x's dtype, z [.., M] float32) of x [.., n C], phi
+    [n C, M] (``alpha`` folded in) and bias [M] in plain ``jax.numpy``:
+    the logits and the streams mixed by ``H_pre``."""
+    z = logits_ref(x, phi, eps)
+    h_pre = jax.nn.sigmoid(z[..., :n] + bias[:n])
+    u = sum(h_pre[..., i:i + 1] * xi for i, xi in enumerate(_streams(x, n)))
+    return u.astype(x.dtype), z
+
+
+def post_ref(x, y, maps, n):
+    """X' [.., n C] in x's dtype of x, y [.., C] and maps [.., LANES]."""
+    xs, yf = _streams(x, n), y.astype(jnp.float32)
+    out = []
+    for i in range(n):
+        acc = maps[..., n + i, None] * yf
+        for j in range(n):
+            acc = acc + maps[..., 2 * n + i * n + j, None] * xs[j]
+        out.append(acc.astype(x.dtype))
+    return jnp.concatenate(out, axis=-1)
+
+
+# -- kernels -----------------------------------------------------------------
+
+
+def _column(a, i):
+    """Column i of a [rows, LANES] value, [rows, 1]."""
+    return a[:, i:i + 1]
+
+
+def _inv_rms(x_ref, n, c, eps):
+    ss = None
+    for i in range(n):
+        xi = x_ref[:, i * c:(i + 1) * c].astype(jnp.float32)
+        part = jnp.sum(xi * xi, axis=1, keepdims=True)
+        ss = part if ss is None else ss + part
+    return lax.rsqrt(ss / (n * c) + eps)
+
+
+def _pre_fwd_kernel(x_ref, phi_ref, bias_ref, u_ref, z_ref, *, n, c, eps):
+    z = jnp.dot(x_ref[...], phi_ref[...],
+                preferred_element_type=jnp.float32,
+                precision=(lax.Precision.HIGHEST
+                           if x_ref.dtype == jnp.float32 else None))
+    z = z * _inv_rms(x_ref, n, c, eps)
+    z_ref[...] = z
+    h = jax.nn.sigmoid(z + bias_ref[...])
+    u = None
+    for i in range(n):
+        part = _column(h, i) * x_ref[:, i * c:(i + 1) * c].astype(
+            jnp.float32)
+        u = part if u is None else u + part
+    u_ref[...] = u.astype(u_ref.dtype)
+
+
+def _pre_bwd_kernel(x_ref, phi_ref, bias_ref, z_ref, du_ref, dz_ref,
+                    dx_in_ref, dx_ref, ds_ref, dzs_ref, *, n, c, eps):
+    """dx = dx_in + h_i du + (ds phi^T) + x coef; ds = dz inv (phi's
+    gradient is x^T ds), dzs = dz with the pre columns' part (the
+    bias's gradient is its column sums)."""
+    inv = _inv_rms(x_ref, n, c, eps)
+    z = z_ref[...]
+    h = jax.nn.sigmoid(z + bias_ref[...])
+    du = du_ref[...].astype(jnp.float32)
+    lane = lax.broadcasted_iota(jnp.int32, z.shape, 1)
+    dh = jnp.zeros_like(z)
+    for i in range(n):
+        xi = x_ref[:, i * c:(i + 1) * c].astype(jnp.float32)
+        dh = dh + jnp.where(lane == i,
+                            jnp.sum(du * xi, axis=1, keepdims=True), 0.0)
+    dz = dz_ref[...] + jnp.where(lane < n, dh * h * (1.0 - h), 0.0)
+    dzs_ref[...] = dz
+    ds = dz * inv
+    ds_ref[...] = ds
+    # z = s inv, inv = (mean(x^2) + eps)^-1/2: the norm's part of dx
+    coef = -inv * inv * jnp.sum(dz * z, axis=1, keepdims=True) / (n * c)
+    low = ds.astype(x_ref.dtype)
+    for i in range(n):
+        xi = x_ref[:, i * c:(i + 1) * c].astype(jnp.float32)
+        through = lax.dot_general(
+            low, phi_ref[i * c:(i + 1) * c, :], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=(lax.Precision.HIGHEST
+                       if x_ref.dtype == jnp.float32 else None))
+        dx = (dx_in_ref[:, i * c:(i + 1) * c].astype(jnp.float32)
+              + _column(h, i) * du + through + coef * xi)
+        dx_ref[:, i * c:(i + 1) * c] = dx.astype(dx_ref.dtype)
+
+
+def _post_fwd_kernel(x_ref, y_ref, maps_ref, out_ref, *, n, c):
+    maps = maps_ref[...]
+    y = y_ref[...].astype(jnp.float32)
+    xs = [x_ref[:, j * c:(j + 1) * c].astype(jnp.float32) for j in range(n)]
+    for i in range(n):
+        acc = _column(maps, n + i) * y
+        for j in range(n):
+            acc = acc + _column(maps, 2 * n + i * n + j) * xs[j]
+        out_ref[:, i * c:(i + 1) * c] = acc.astype(out_ref.dtype)
+
+
+def _post_bwd_kernel(x_ref, y_ref, maps_ref, dout_ref, dx_ref, dy_ref,
+                     dmaps_ref, *, n, c):
+    maps = maps_ref[...]
+    y = y_ref[...].astype(jnp.float32)
+    lane = lax.broadcasted_iota(jnp.int32, maps.shape, 1)
+    dmaps = jnp.zeros_like(maps)
+    dy = None
+    dxs = [None] * n
+    for i in range(n):
+        dout = dout_ref[:, i * c:(i + 1) * c].astype(jnp.float32)
+        part = _column(maps, n + i) * dout
+        dy = part if dy is None else dy + part
+        dmaps = dmaps + jnp.where(
+            lane == n + i, jnp.sum(dout * y, axis=1, keepdims=True), 0.0)
+        for j in range(n):
+            xj = x_ref[:, j * c:(j + 1) * c].astype(jnp.float32)
+            k = 2 * n + i * n + j
+            dmaps = dmaps + jnp.where(
+                lane == k, jnp.sum(dout * xj, axis=1, keepdims=True), 0.0)
+            part = _column(maps, k) * dout
+            dxs[j] = part if dxs[j] is None else dxs[j] + part
+    for j in range(n):
+        dx_ref[:, j * c:(j + 1) * c] = dxs[j].astype(dx_ref.dtype)
+    dy_ref[...] = dy.astype(dy_ref.dtype)
+    dmaps_ref[...] = dmaps
+
+
+def _call(kernel, name, tm, interpret, ins, outs, whole=()):
+    """One call over row blocks of ``tm``: ``ins`` and ``outs`` are
+    [rows, width] (arrays, ShapeDtypeStructs), blocked by rows; the
+    positions ``whole`` of ``ins`` are given to every block entire."""
+    rows = outs[0].shape[0]
+    block = lambda a: pl.BlockSpec((tm, a.shape[1]), lambda r: (r, 0))
+    entire = lambda a: pl.BlockSpec(a.shape, lambda r: (0, 0))
+    return pl.pallas_call(
+        kernel, out_shape=outs, grid=(rows // tm,),
+        in_specs=[entire(a) if i in whole else block(a)
+                  for i, a in enumerate(ins)],
+        out_specs=[block(a) for a in outs],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        # The HLO instruction's name, so the trace's.
+        name=name,
+    )(*ins)
+
+
+def _shape(rows, width, dtype):
+    return jax.ShapeDtypeStruct((rows, width), dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _pre(x, phi, bias, n, eps, tm, interpret):
+    return _pre_fwd(x, phi, bias, n, eps, tm, interpret)[0]
+
+
+def _pre_fwd(x, phi, bias, n, eps, tm, interpret):
+    rows, width = x.shape
+    c = width // n
+    u, z = _call(
+        functools.partial(_pre_fwd_kernel, n=n, c=c, eps=eps),
+        "hc_pre_fwd", tm, interpret, (x, phi, bias),
+        (_shape(rows, c, x.dtype), _shape(rows, LANES, jnp.float32)),
+        whole=(1, 2))
+    return (u, z, x), (x, phi, bias, z)
+
+
+def _pre_bwd(n, eps, tm, interpret, residuals, cotangents):
+    x, phi, bias, z = residuals
+    du, dz, dx_in = cotangents
+    rows, width = x.shape
+    dx, ds, dzs = _call(
+        functools.partial(_pre_bwd_kernel, n=n, c=width // n, eps=eps),
+        "hc_pre_bwd", tm, interpret, (x, phi, bias, z, du, dz, dx_in),
+        (_shape(rows, width, x.dtype), _shape(rows, LANES, jnp.float32),
+         _shape(rows, LANES, jnp.float32)), whole=(1, 2))
+    dphi = jnp.einsum("rk,rm->km", x, ds.astype(x.dtype),
+                      preferred_element_type=jnp.float32)
+    lane = lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    dbias = jnp.where(lane < n, dzs.sum(axis=0, keepdims=True), 0.0)
+    return dx, dphi.astype(phi.dtype), dbias
+
+
+_pre.defvjp(_pre_fwd, _pre_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _post(x, y, maps, n, tm, interpret):
+    return _post_fwd(x, y, maps, n, tm, interpret)[0]
+
+
+def _post_fwd(x, y, maps, n, tm, interpret):
+    out = _call(
+        functools.partial(_post_fwd_kernel, n=n, c=y.shape[1]),
+        "hc_post_fwd", tm, interpret, (x, y, maps),
+        (_shape(*x.shape, x.dtype),))[0]
+    return out, (x, y, maps)
+
+
+def _post_bwd(n, tm, interpret, residuals, dout):
+    x, y, maps = residuals
+    return tuple(_call(
+        functools.partial(_post_bwd_kernel, n=n, c=y.shape[1]),
+        "hc_post_bwd", tm, interpret, (x, y, maps, dout),
+        (_shape(*x.shape, x.dtype), _shape(*y.shape, y.dtype),
+         _shape(*maps.shape, jnp.float32))))
+
+
+_post.defvjp(_post_fwd, _post_bwd)
+
+
+# -- the op ------------------------------------------------------------------
+
+
+@functools.lru_cache(maxsize=None)
+def announce_hyper(rows, n, c, nbytes, tile, mode, why):
+    """Once per compiled shape, by the logger ``announce_tiles`` uses:
+    the stream one shard of the data axis mixes, and by what."""
+    flash_attention.logger.info(
+        "hyper residual: tokens=%d streams=%d width=%d stream_bytes=%d "
+        "tile=%s %s%s", rows, n, c, nbytes, tile or "-",
+        {"tpu": "kernel", "interpret": "interpreter",
+         "off": "reference"}[mode], " (%s)" % why if why else "")
+
+
+def hyper_mode(rows, n, c, interpret=None):
+    """(mode, row tile, why not the kernel) for a stream of ``rows``
+    tokens a shard, n x c wide."""
+    mode = resolve(interpret)
+    if mode == "off":
+        return mode, None, ""
+    tile = next((tm for tm in ROW_TILES if rows % tm == 0), None)
+    if tile is None or c % LANES or columns(n) > LANES:
+        return "off", None, "rows %% %d, width %% %d or %d > %d logits" % (
+            ROW_TILES[-1], LANES, columns(n), LANES)
+    return mode, tile, ""
+
+
+def _plan(x, n, interpret):
+    batch, seq_len, width = x.shape
+    rows = batch * seq_len // shards()
+    mode, tile, why = hyper_mode(rows, n, width // n, interpret)
+    if why:
+        flash_attention.announce_fallback("hyper_mix", x.shape, why,
+                                          resolve(interpret))
+    if mode != "interpret":
+        announce_hyper(rows, n, width // n, rows * width * x.dtype.itemsize,
+                       tile, mode, why)
+    return mode, tile
+
+
+def _folded(phi, alpha, n):
+    """phi [n C, M] with each map's ``alpha`` in its columns."""
+    sizes = (n, n, n * n)[:alpha.shape[0]]
+    return phi * jnp.concatenate(
+        [jnp.broadcast_to(a, (size,)) for a, size in zip(alpha, sizes)])
+
+
+def _logits(x, phi, alpha, bias, n, eps, interpret):
+    """(u [B, T, C], z [B, T, >= M] float32, x handed through) of the
+    stream x [B, T, n C]: ``pre_ref``, by the kernel where it runs."""
+    mode, tile = _plan(x, n, interpret)
+    phi = _folded(phi.astype(jnp.float32), alpha.astype(jnp.float32), n)
+    bias = bias.astype(jnp.float32)
+    if mode == "off":
+        u, z = pre_ref(x, phi, bias, n, eps)
+        return u, z, x
+    pad = LANES - phi.shape[1]
+    phi = jnp.pad(phi, ((0, 0), (0, pad))).astype(x.dtype)
+    bias = jnp.pad(bias, (0, pad))[None]
+
+    def op(x, phi, bias):
+        b, t, width = x.shape
+        u, z, through = _pre(x.reshape(b * t, width), phi, bias, n, eps,
+                             tile, mode == "interpret")
+        return (u.reshape(b, t, -1), z.reshape(b, t, LANES),
+                through.reshape(b, t, width))
+
+    return per_batch_shard(op, (x,), (phi, bias))
+
+
+def pre(x, phi, alpha, bias, streams, iters, eps, sk_eps, interpret=None,
+        sinkhorn_dtype=jnp.float32):
+    """A sublayer's read of the stream x [B, T, n C] -> (u [B, T, C]:
+    the streams mixed by H_pre; x handed through: what ``post`` takes;
+    maps [B, T, LANES] float32 (``maps_of``); err).  phi [n C, 2n +
+    n^2], alpha [3], bias [2n + n^2], float32."""
+    u, z, x = _logits(x, phi, alpha, bias, streams, eps, interpret)
+    u, z = checkpoint_name(u, KEEP_U), checkpoint_name(z, KEEP_Z)
+    maps, err = maps_of(z, bias.astype(jnp.float32), streams, iters, sk_eps,
+                        sinkhorn_dtype)
+    return u, x, maps, err
+
+
+def narrow(x, phi, alpha, bias, streams, eps, interpret=None):
+    """The stream x [B, T, n C] read through one H_pre-like map (phi
+    [n C, n], alpha [1], bias [n]) -> [B, T, C]."""
+    return _logits(x, phi, alpha, bias, streams, eps, interpret)[0]
+
+
+def post(x, y, maps, streams, interpret=None):
+    """A sublayer's write: x [B, T, n C] (``pre``'s second result), the
+    sublayer's result y [B, T, C], ``pre``'s maps -> X' [B, T, n C]."""
+    mode, tile, _ = hyper_mode(x.shape[0] * x.shape[1] // shards(), streams,
+                               y.shape[-1], interpret)
+    if mode == "off":
+        return post_ref(x, y, maps, streams)
+
+    def op(x, y, maps):
+        b, t, width = x.shape
+        out = _post(x.reshape(b * t, width), y.reshape(b * t, -1),
+                    maps.reshape(b * t, LANES), streams, tile,
+                    mode == "interpret")
+        return out.reshape(b, t, width)
+
+    return per_batch_shard(op, (x, y, maps))

@@ -56,6 +56,21 @@ def _log_step_stats(step, stats):
         np.asarray(stats.get("moe_spilled", 0)).sum())
 
 
+def _loss_fields(stats):
+    """What the loss line says beside the loss of what left the step
+    with it: `` mtp=`` the multi-token-prediction modules' mean loss
+    before its weight, `` hc_err=`` the largest ``|row or column sum -
+    1|`` of any Sinkhorn map of the step (``models/transformer.py``:
+    ``mtp_modules``, ``hyper_streams``); nothing for a model with
+    neither.  Fetched after the loss: the same program made them."""
+    stats = stats or {}
+    return "".join(
+        " %s=%s" % (name, form % float(stats[key]))
+        for name, key, form in (("mtp", "mtp_loss", "%.6f"),
+                                ("hc_err", "hc_err", "%.3e"))
+        if key in stats)
+
+
 class PreemptedExit(Exception):
     """Raised inside the task loop when a graceful-preemption stop was
     requested (SIGTERM): unwind cleanly after the current minibatch."""
@@ -380,12 +395,13 @@ class Worker:
                     with self.timing.timeit("loss_sync"):
                         loss_value = float(loss)
                     self.fences.fence(self._steps)
+                    stats = getattr(self._trainer, "last_step_stats", None)
                     logger.info(
-                        "step %d loss %.6f (version %d)",
+                        "step %d loss %.6f (version %d)%s",
                         self._steps, loss_value, version,
+                        _loss_fields(stats),
                     )
-                    _log_step_stats(self._steps, getattr(
-                        self._trainer, "last_step_stats", None))
+                    _log_step_stats(self._steps, stats)
                 if self._step_throttle:
                     # Drill knob (step_throttle_secs): a DELIBERATE
                     # per-step slowdown so churn drills can stage a

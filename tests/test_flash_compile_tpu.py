@@ -11,6 +11,7 @@ described inside a fixture, never at import: only the worker that is
 given this file loads the TPU library.
 """
 
+import collections
 import json
 import math
 import os
@@ -25,6 +26,7 @@ from elasticdl_tpu.models import transformer as tfm
 from elasticdl_tpu.ops import flash_attention as fa
 from elasticdl_tpu.ops import grouped_matmul as gm
 from elasticdl_tpu.ops import head_loss as hl
+from elasticdl_tpu.ops import hyper_mix as hm
 from elasticdl_tpu.ops import row_moves
 
 
@@ -1101,3 +1103,97 @@ def test_the_kda_expert_stacks_step_fits_a_v5e_with_nothing_kept(
     assert (count("sconv_silu_fwd"), count("sconv_silu_bwd")) == (6, 3)
     assert (count("flash_fwd"), count("flash_bwd")) == (2, 1), names
     assert not _updates_in_matmuls(text)
+
+
+# -- the hyper-connection kernels and the wide stream's step (PR 54) ---------
+# (in this file, not one of their own: a second file that describes the
+# topology can go to another worker of the suite, which cannot load the
+# TPU's library a second time)
+
+HC_ROWS, HC_N, HC_C = 8192, 4, 3584
+
+
+def _names(text):
+    """How many Mosaic calls of a compiled program carry each name."""
+    names = [c.split(" = ")[0].lstrip("%") for c in _mosaic_calls(text)]
+    return collections.Counter(
+        re.sub(r"^(checkpoint_|jvp_|transpose_)+|(__)?[._]*\d+$", "", n)
+        for n in names)
+
+
+def test_the_four_calls_compile_at_the_cells_shape(one_chip):
+    """Two sequences of 4,096 tokens of a stream 4 x 3,584 wide,
+    bfloat16: one call each of ``hc_pre_fwd`` and ``hc_post_fwd``
+    forward, ``hc_post_bwd`` and ``hc_pre_bwd`` backward, in 128-row
+    tiles under the 64 MB of VMEM the calls ask for."""
+    on_chip = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    x = on_chip((2, 4096, HC_N * HC_C), jnp.bfloat16)
+    y = on_chip((2, 4096, HC_C), jnp.bfloat16)
+    phi = on_chip((HC_N * HC_C, hm.columns(HC_N)), jnp.float32)
+    alpha = on_chip((3,), jnp.float32)
+    bias = on_chip((hm.columns(HC_N),), jnp.float32)
+    assert hm.hyper_mode(HC_ROWS, HC_N, HC_C, interpret=False) == (
+        "tpu", 128, "")
+
+    def loss(x, phi, alpha, bias, y):
+        u, through, maps, err = hm.pre(x, phi, alpha, bias, HC_N, 20, 1e-6,
+                                       1e-6, interpret=False)
+        out = hm.post(through, u * y, maps, HC_N, interpret=False)
+        return jnp.square(out.astype(jnp.float32)).sum() + err
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        x, phi, alpha, bias, y).compile()
+    assert _names(compiled.as_text()) == {
+        "hc_pre_fwd": 1, "hc_post_fwd": 1, "hc_post_bwd": 1,
+        "hc_pre_bwd": 1}
+    # the stream in, its gradient out, and under two streams between
+    stats = compiled.memory_analysis()
+    assert stats.temp_size_in_bytes < 2.2 * HC_ROWS * HC_N * HC_C * 2
+
+
+@pytest.mark.slow
+def test_the_wide_streams_step_fits_a_v5e_with_nothing_kept(one_chip,
+                                                            monkeypatch):
+    """The cell's whole training step (two sequences of 4,096 through a
+    dense layer, four expert layers and the module's block on a stream
+    four wide, 8 of 32 heads and 8 of 64 experts held, two passes of an
+    untied head over 16,384 ids, AdamW; 807,416,462 parameters) through
+    the TPU's compiler with nothing kept: 15.54 GB of a v5e's 16.91
+    (the chip's own peak reads 15.50, my chip runs, PR 54),
+    the configuration's condition for 8 heads and two sequences, so
+    neither fallback is taken.  Scanned (``scan_periods`` at its
+    default) the same step counts 18.25 GB: the four expert layers'
+    stacked gradient stands whole.  Marked slow: the one program takes
+    two minutes to compile here (my run, PR 54)."""
+    from elasticdl_tpu.models import remat_keep as rk
+    from elasticdl_tpu.ops.mode import SWITCH
+
+    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
+    spec = tfm.model_spec(**_model_params("xing4.0-29b-a4b"))
+    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+    state = jax.eval_shape(spec.optimizer.init, params)
+    nbytes = lambda tree: sum(
+        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
+    assert nbytes(params) == 4 * 807416462
+    held = 2 * nbytes(params) + nbytes(state)
+
+    compiled = _step(spec, one_chip, 2, 4096).compile()
+    stats = compiled.memory_analysis()
+    counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
+    assert counted < 0.95 * 16911433728, counted
+    assert 15.4e9 < counted < 15.7e9, counted
+    # ``remat_keep``'s estimate stands over it by the stack's gradients,
+    # counted whole where expert layers are unrolled (ROADMAP A3 (t))
+    estimate = held + rk.step_bytes(spec.config, params, HC_ROWS)
+    assert 0.9e9 < estimate - counted < 1.5e9, (estimate, counted)
+    names = _names(compiled.as_text())
+    # twelve sublayers: read twice (the second forward), written twice
+    # but for each block's last (its result is the next block's kept
+    # input), back-propagated once; the two narrowing maps
+    assert names["hc_pre_fwd"] == 2 * 12 + 2
+    assert names["hc_post_fwd"] == 2 * 12 - 6
+    assert (names["hc_post_bwd"], names["hc_pre_bwd"]) == (12, 12 + 2)
+    assert names["flash_fwd_qk192_v128"] == 12
+    assert names["flash_bwd_qk192_v128"] == 6
+    assert names["embed_grad"] == 1

@@ -1,0 +1,466 @@
+"""A residual stream four wide (``hyper_streams``: ops/hyper_mix.py,
+models/transformer.py) with the query latent and YaRN that came with
+it, against the plain reference of the benchmark
+(benchmark/reference/xing4.0-29b-a4b.py).  Float32 on the CPU at tiny
+widths; the kernels in interpret mode."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh
+
+from benchmark.lib import manifest
+from elasticdl_tpu.models import remat_keep as rk
+from elasticdl_tpu.models import transformer as tfm
+from elasticdl_tpu.ops import hyper_mix as hm
+
+REF = manifest.load_named("reference", "xing4.0-29b-a4b")
+
+# a leading dense layer and two expert layers over 4 of 16 experts
+# beside a shared expert, latent attention with a query latent under
+# YaRN (16 original positions of 32), a stream 4 x 128, one module
+TINY = dict(vocab_size=96, dim=128, num_heads=2, num_layers=3, seq_len=32,
+            kv_latent_rank=32, q_latent_rank=24, qk_nope_dim=16,
+            qk_rope_dim=8, v_head_dim=24, rope_scaling="64,16,32,1",
+            rope_theta=1e4, dense_layers=1, dense_ffn_dim=96, ffn_dim=48,
+            moe_experts=16, moe_top_k=3, moe_experts_held=4,
+            moe_share_index=1, moe_shared_experts=1,
+            moe_router="sigmoid_bias", moe_norm_topk=True,
+            moe_route_scale=2.0, moe_aux_weight=0, norm_eps=1e-6,
+            tied_embeddings=False, embed_scale=1.0, hyper_streams=4,
+            mtp_modules=1, dtype="float32")
+N = 4
+
+
+def shape_of(cfg, **over):
+    """``REF.loss``'s keywords for a model of ``cfg``."""
+    rank, d_nope, d_rope, d_v = cfg.latent
+    return dict(dict(
+        heads=cfg.num_heads, rank=rank, q_rank=cfg.q_latent_rank,
+        d_nope=d_nope, d_rope=d_rope, d_v=d_v, top_k=cfg.moe_top_k,
+        eps=cfg.norm_eps, theta=cfg.rope_theta,
+        yarn=tfm.yarn_of(cfg.rope_scaling), norm_topk=cfg.moe_norm_topk,
+        scale=cfg.moe_route_scale, first=cfg.experts_held[0],
+        streams=cfg.hyper_streams, iters=cfg.hyper_sinkhorn_iters,
+        sk_eps=tfm.HYPER_SINKHORN_EPS, mtp_weight=cfg.mtp_weight), **over)
+
+
+def product_loss(spec, tokens):
+    return lambda p: spec.loss_fn(spec.apply_fn(p, tokens, True),
+                                  tokens).mean()
+
+
+def reference_loss(cfg, tokens, **over):
+    shape = shape_of(cfg, **over)
+
+    def total(p):
+        main, mtp = REF.loss(p, tokens, **shape)[:2]
+        return (main + shape["mtp_weight"] * mtp).mean()
+
+    return total
+
+
+def case(spec, batch=2, seed=3):
+    """(params, tokens) as the comparison draws them: a wider head, a
+    bias on the routers, maps off their initial values."""
+    cfg = spec.config
+    params = jax.jit(spec.init_fn)(jax.random.PRNGKey(seed))
+    params, _ = REF.inputs(
+        dict(vocab_size=cfg.vocab_size, seq_len=4, hc_mult=N), params,
+        np.random.default_rng(seed))
+    tokens = jnp.asarray(np.random.default_rng(seed + 1).integers(
+        0, cfg.vocab_size, (batch, cfg.max_seq_len)), jnp.int32)
+    return params, tokens
+
+
+def apart(got, want):
+    """The distance of two trees over the second's norm."""
+    leaves = jax.tree_util.tree_leaves
+    norm = lambda trees: float(jnp.sqrt(sum(
+        jnp.sum(jnp.square(t)) for t in trees)))
+    return norm([g - w for g, w in zip(leaves(got), leaves(want))]) / norm(
+        leaves(want))
+
+
+def sublayer(seed=0, rows=(2, 32), c=128):
+    """(x [B, T, 4 c], y [B, T, c], the maps' weights) drawn generic."""
+    rng = np.random.default_rng(seed)
+    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape),
+                                      jnp.float32)
+    w = {"hc1_phi": draw(N * c, hm.columns(N)) * (N * c) ** -0.5,
+         "hc1_alpha": jnp.asarray([1.0, 0.7, 1.3], jnp.float32),
+         "hc1_bias": 0.5 * draw(hm.columns(N))}
+    return draw(*rows, N * c), draw(*rows, c), w
+
+
+def mixed(x, y, w, interpret, iters=20):
+    u, through, maps, err = hm.pre(
+        x, w["hc1_phi"], w["hc1_alpha"], w["hc1_bias"], N, iters, 1e-6,
+        1e-6, interpret=interpret)
+    return u, hm.post(through, y, maps, N, interpret=interpret), maps, err
+
+
+# -- the mixing ----------------------------------------------------------------
+
+
+@pytest.mark.parametrize("interpret", [None, True],
+                         ids=["reference", "kernels"])
+def test_one_sublayer_matches_the_plain_reference(interpret):
+    x, y, w = sublayer()
+    u, out, _, _ = mixed(x, y, w, interpret)
+    X = x.reshape(2, 32, N, -1)
+    h_pre, h_post, h_res = REF.hyper_maps(X, w, "hc1", 1e-6, 20, 1e-6)
+    np.testing.assert_allclose(u, REF.read(X, h_pre), rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(
+        out.reshape(X.shape), REF.write(X, y, h_post, h_res), rtol=2e-5,
+        atol=2e-5)
+
+
+def test_the_sinkhorn_maps_rows_and_columns_sum_to_one():
+    """From the values a new model starts at (``_init_hyper``) 20
+    float32 rounds leave every column sum within 2e-6 of 1 (the last
+    half round divides by it, ``+ hc_eps``) and every row sum within
+    1e-4, and the op says how far; not 1e-5: next to the identity the
+    rounds close a row's gap by a part in a few hundred each, and
+    float64 leaves the same 4.5e-5.  From generic logits they leave
+    1e-3, and it says that."""
+    spec = tfm.model_spec(**TINY)
+    w = jax.jit(spec.init_fn)(jax.random.PRNGKey(0))["layers"]["lead"]["0"]
+    x, y, _ = sublayer()
+    _, _, maps, err = mixed(x, y, w, None)
+    h_res = np.asarray(maps[..., 2 * N:2 * N + N * N]).reshape(2, 32, N, N)
+    worst = max(np.abs(h_res.sum(-1) - 1).max(),
+                np.abs(h_res.sum(-2) - 1).max())
+    assert np.abs(h_res.sum(-2) - 1).max() <= 2e-6
+    assert worst <= 1e-4 and float(err) == pytest.approx(worst, abs=1e-7)
+    # H_pre = 1 / 4 a stream, H_post = 1, H_res the identity to 1e-3
+    np.testing.assert_allclose(maps[..., N:2 * N], 1.0, atol=0.02)
+    np.testing.assert_allclose(h_res, np.broadcast_to(np.eye(N), h_res.shape),
+                               atol=2e-3)
+    _, _, _, generic = mixed(x, y, sublayer()[2], None)
+    assert 1e-6 < float(generic) < 5e-3
+    # one round is not twenty
+    assert float(mixed(x, y, sublayer()[2], None, iters=1)[3]) > float(
+        generic)
+
+
+def test_the_kernels_derivatives_are_jax_grads_of_the_jnp_form():
+    x, y, w = sublayer(seed=1)
+    weigh = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
+
+    def scalar(interpret):
+        def f(x, y, phi, alpha, bias):
+            u, out, _, _ = mixed(x, y, dict(
+                hc1_phi=phi, hc1_alpha=alpha, hc1_bias=bias), interpret,
+                iters=3)
+            return (out * weigh).sum() + (u * u).sum()
+
+        return jax.grad(f, argnums=(0, 1, 2, 3, 4))(
+            x, y, w["hc1_phi"], w["hc1_alpha"], w["hc1_bias"])
+
+    for got, want in zip(scalar(True), scalar(None)):
+        assert apart(got, want) <= 1e-5
+
+
+def test_narrow_reads_the_streams_through_one_map():
+    x, _, w = sublayer(seed=2)
+    w = {"hc_out_phi": w["hc1_phi"][:, :N], "hc_out_alpha": jnp.ones((1,)),
+         "hc_out_bias": w["hc1_bias"][:N]}
+    X = x.reshape(2, 32, N, -1)
+    want = REF.read(X, REF.hyper_maps(X, w, "hc_out", 1e-6, 20, 1e-6)[0])
+    for interpret in (None, True):
+        got = hm.narrow(x, w["hc_out_phi"], w["hc_out_alpha"],
+                        w["hc_out_bias"], N, 1e-6, interpret=interpret)
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+def test_shapes_the_kernels_refuse_take_the_reference(monkeypatch):
+    monkeypatch.setenv("ELASTICDL_FLASH", "interpret")
+    assert hm.hyper_mode(64, 4, 128)[:2] == ("interpret", 64)
+    assert hm.hyper_mode(8192, 4, 3584)[1] == 128
+    assert hm.hyper_mode(24, 4, 128)[0] == "off"       # rows % 16
+    assert hm.hyper_mode(64, 4, 96)[0] == "off"        # no whole lanes
+    assert hm.hyper_mode(64, 12, 128)[0] == "off"      # 168 logits
+    monkeypatch.setenv("ELASTICDL_FLASH", "off")
+    assert hm.hyper_mode(64, 4, 128) == ("off", None, "")
+
+
+# -- the whole model -----------------------------------------------------------
+
+
+@pytest.mark.parametrize("mode", ["off", "interpret-remat"])
+def test_the_model_matches_the_reference(monkeypatch, mode):
+    """Loss (main + 0.1 module) and every gradient leaf of a dense layer,
+    an expert layer and the module's block on a stream four wide: 1e-5
+    of the loss, 1e-4 of each leaf's norm.  In interpret mode the
+    mixing's kernels run (attention's widths take its reference).
+    Three Sinkhorn rounds, not twenty: each is unrolled into the
+    program six times over, and the CPU compiles them one by one."""
+    kernels, _, remat = mode.partition("-")
+    monkeypatch.setenv("ELASTICDL_FLASH", kernels)
+    spec = tfm.model_spec(**dict(TINY, num_layers=2, hyper_sinkhorn_iters=3,
+                                 remat=bool(remat)))
+    params, tokens = case(spec)
+    got, grads = jax.jit(jax.value_and_grad(product_loss(spec, tokens)))(
+        params)
+    want, want_grads = jax.jit(jax.value_and_grad(
+        reference_loss(spec.config, tokens)))(params)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    for (path, leaf), ref in zip(flat, jax.tree_util.tree_leaves(
+            want_grads)):
+        if float(jnp.abs(ref).max()):          # expert_bias: no gradient
+            assert apart(leaf, ref) <= 1e-4, jax.tree_util.keystr(path)
+        else:
+            assert not float(jnp.abs(leaf).max())
+    # and the reference tells the mechanisms apart: one Sinkhorn round
+    # for twenty; no YaRN; the query latent's norm left out
+    for other in (dict(iters=1), dict(yarn=(1.0, 16.0, 32.0, 1.0))):
+        moved = jax.jit(reference_loss(spec.config, tokens, **other))(
+            params)
+        assert abs(float(moved) - float(want)) > 1e-4 * abs(float(want))
+
+
+def test_a_new_model_starts_as_the_plain_residual_on_equal_streams():
+    """At the initial maps the four streams stay (nearly) equal and the
+    model is ``x + F(norm(x))``: its loss is a one-wide model's with the
+    same weights, to the 1e-3 the maps start off the identity by."""
+    wide = tfm.model_spec(**dict(TINY, mtp_modules=0))
+    plain = tfm.model_spec(**dict(TINY, mtp_modules=0, hyper_streams=0))
+    params = jax.jit(wide.init_fn)(jax.random.PRNGKey(5))
+    tokens = case(wide)[1]
+    strip = lambda tree: {k: strip(v) if isinstance(v, dict) else v
+                          for k, v in tree.items() if not k.startswith("hc")}
+    got = product_loss(wide, tokens)(params)
+    want = product_loss(plain, tokens)(strip(params))
+    assert float(got) == pytest.approx(float(want), rel=5e-3)
+    assert float(got) != float(want)
+
+
+def test_the_step_statistics_carry_the_sinkhorn_error_and_the_modules_loss():
+    spec = tfm.model_spec(**TINY)
+    params, tokens = case(spec)
+    out = spec.apply_fn(params, tokens, True)
+    loss = spec.loss_fn(out, tokens)
+    stats = spec.step_stats_fn(out)
+    assert set(stats) == {"hc_err", "mtp_loss", "moe_load", "moe_moved",
+                          "moe_spilled"}
+    assert 0 < float(stats["hc_err"]) < 1e-2
+    main = tfm.head_loss(params, out["hidden"], tokens, spec.config)
+    np.testing.assert_allclose(
+        loss, main + 0.1 * out["mtp_loss"], rtol=1e-6)
+    # two expert layers and the module's block
+    assert stats["moe_load"].shape[0] == 3
+
+
+def test_the_loss_line_says_both_fields_and_nothing_without_them():
+    from elasticdl_tpu.worker import worker
+
+    assert worker._loss_fields({"mtp_loss": jnp.float32(5.5),
+                                "hc_err": jnp.float32(3e-6)}) == (
+        " mtp=5.500000 hc_err=3.000e-06")
+    assert worker._loss_fields({"moe_load": 1}) == ""
+    assert worker._loss_fields(()) == worker._loss_fields(None) == ""
+
+
+# -- YaRN and the query latent ------------------------------------------------
+
+
+def test_yarn_at_factor_one_is_rope_to_the_bit_and_at_64_the_references():
+    positions = jnp.arange(48)
+    plain = tfm._rope_tables(64, positions, 1e4)
+    one = tfm._rope_tables(64, positions, 1e4, "1,4096,32,1")
+    assert all(np.array_equal(a, b) for a, b in zip(plain, one))
+    cos, sin = tfm._rope_tables(64, positions, 1e4, "64,4096,32,1")
+    freqs = REF.yarn_frequencies(64, 1e4, (64.0, 4096.0, 32.0, 1.0))
+    angles = np.arange(48, dtype=np.float32)[:, None] * np.asarray(freqs)
+    np.testing.assert_allclose(cos, np.cos(angles), atol=2e-6)
+    np.testing.assert_allclose(sin, np.sin(angles), atol=2e-6)
+    # the fastest frequencies are RoPE's own, the slowest RoPE's / 64
+    base = 1e4 ** (-np.arange(32) / 32)
+    np.testing.assert_allclose(freqs[:8], base[:8], rtol=1e-6)
+    np.testing.assert_allclose(freqs[-4:], base[-4:] / 64, rtol=1e-6)
+    assert tfm.yarn_scale("64,4096,32,1") == pytest.approx(
+        1.41589 ** 2, rel=1e-5)
+    assert 192 ** -0.5 * tfm.yarn_scale("64,4096,32,1") == pytest.approx(
+        0.144680, rel=1e-5)
+
+
+@pytest.mark.parametrize("bad,match", [
+    (dict(rope_scaling="64,4096"), "rope_scaling"),
+    (dict(rope_scaling="64,4096,32,1", kv_latent_rank=0, qk_nope_dim=0,
+          qk_rope_dim=0, v_head_dim=0, q_latent_rank=0), "rope_scaling"),
+    (dict(q_latent_rank=8, kv_latent_rank=0, qk_nope_dim=0, qk_rope_dim=0,
+          v_head_dim=0, rope_scaling=""), "q_latent_rank"),
+    (dict(hyper_streams=1), "hyper_streams"),
+    (dict(hyper_sinkhorn_iters=0), "hyper_streams"),
+    (dict(moe_route_before_op=True), "hyper_streams"),
+])
+def test_a_new_option_without_what_it_needs_is_refused_where_it_is_built(
+        bad, match):
+    with pytest.raises(ValueError, match=match):
+        tfm.model_spec(**dict(TINY, **bad))
+
+
+def test_latent_attention_with_a_query_latent_matches_the_reference():
+    """``q = RMSNorm(h W_qa) W_qb`` under YaRN and its softmax scale,
+    the product's halves against the reference's neighbours."""
+    spec = tfm.model_spec(**TINY)
+    cfg = spec.config
+    params, _ = case(spec)
+    w = params["layers"]["lead"]["0"]
+    h = jnp.asarray(np.random.default_rng(4).standard_normal((2, 32, 128)),
+                    jnp.float32)
+    got = tfm._latent_mix(h, w, cfg, jnp.arange(32), cfg.kinds[0])
+    s = shape_of(cfg)
+    want = REF.attention(h, w, s["heads"], s["rank"], s["q_rank"],
+                         s["d_nope"], s["d_rope"], s["d_v"], s["eps"],
+                         s["theta"], s["yarn"], lambda a: a)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+    assert {"w_q_a", "q_norm", "w_q_b"} <= set(w) and "wq" not in w
+    assert w["w_q_a"].shape == (128, 24) and w["w_q_b"].shape == (24, 48)
+    # the scale is YaRN's: without it the result is another
+    bare = dataclasses.replace(cfg, rope_scaling="")
+    assert apart(tfm._latent_mix(h, w, bare, jnp.arange(32), cfg.kinds[0]),
+                 want) > 1e-2
+
+
+# -- the shares ------------------------------------------------------------------
+
+
+def test_four_head_shares_and_eight_expert_shares_add_up_to_the_uncut_layer():
+    """The deployment's cut, on one layer: the four head shares' parts of
+    the ``W_o`` product (each with its heads' columns of W_qb and W_kvb
+    and rows of W_o; both latents' down-projections and norms whole on
+    every share, so counted once) add up to the reference's attention
+    with all 8 heads; the eight expert shares' routed parts and the
+    shared expert, counted once, to its uncut expert layer."""
+    whole = tfm.TransformerConfig(
+        dim=64, num_heads=8, kv_latent_rank=32, q_latent_rank=24,
+        qk_nope_dim=16, qk_rope_dim=8, v_head_dim=24,
+        rope_scaling="64,16,32,1", ffn_dim=48, moe_experts=64, moe_top_k=4,
+        moe_router="sigmoid_bias", moe_route_scale=2.0,
+        moe_shared_experts=1, dtype="float32")
+    rng = np.random.default_rng(8)
+    draw = lambda *shape: jnp.asarray(
+        rng.standard_normal(shape) * shape[-2] ** -0.5, jnp.float32)
+    w = {"w_q_a": draw(64, 24), "q_norm": 1 + 0.1 * draw(1, 24)[0],
+         "w_q_b": draw(24, 8 * 24), "w_kv_a": draw(64, 40),
+         "kv_norm": 1 + 0.1 * draw(1, 32)[0], "w_kv_b": draw(32, 8 * 40),
+         "wo": draw(8 * 24, 64),
+         "w_router": draw(64, 64), "w_gate": draw(64, 64, 48),
+         "w_up": draw(64, 64, 48), "w_down": draw(64, 48, 64),
+         "ws_gate": draw(64, 48), "ws_up": draw(64, 48),
+         "ws_down": draw(48, 64), "ln2": jnp.ones((64,), jnp.float32),
+         "expert_bias": jnp.asarray(0.2 * rng.standard_normal(64),
+                                    jnp.float32)}
+    h = jnp.asarray(rng.standard_normal((2, 24, 64)), jnp.float32)
+    identity = lambda a: a
+    yarn = tfm.yarn_of(whole.rope_scaling)
+    want = REF.attention(h, w, 8, 32, 24, 16, 8, 24, 1e-6, 1e4, yarn,
+                         identity)
+    held = dataclasses.replace(whole, num_heads=2, head_shares=4)
+    parts = 0.0
+    for share in range(4):
+        heads = lambda a, width, axis: jnp.take(
+            a.reshape(a.shape[:axis] + (8, width) + a.shape[axis + 1:]),
+            jnp.arange(2 * share, 2 * share + 2), axis=axis)
+        part = dict(
+            w, w_q_b=heads(w["w_q_b"], 24, 1).reshape(24, -1),
+            w_kv_b=heads(w["w_kv_b"], 40, 1).reshape(32, -1),
+            wo=heads(w["wo"], 24, 0).reshape(-1, 64))
+        parts = parts + tfm._latent_mix(h, part, held, jnp.arange(24),
+                                        held.kinds[0])
+    np.testing.assert_allclose(parts, want, rtol=2e-4, atol=2e-5)
+    # the experts: as kanana-2-30b-a3b's, 8 shares of 8 of 64
+    u = REF.rmsnorm(h, w["ln2"], whole.norm_eps)
+    shared = REF.swiglu(u, w["ws_gate"], w["ws_up"], w["ws_down"], identity)
+    want = REF.experts(u, w, 4, True, 2.0, 0)[0] + shared
+    routed, rows = 0.0, 0.0
+    for index in range(8):
+        cfg = dataclasses.replace(whole, moe_experts_held=8,
+                                  moe_share_index=index)
+        part = dict(w, **{name: w[name][index * 8:(index + 1) * 8]
+                          for name in ("w_gate", "w_up", "w_down")})
+        out, _, _, load = tfm._ffn(h, part, cfg, None)
+        routed = routed + (out - h - shared)
+        rows += float(load[:8].sum())
+    np.testing.assert_allclose(routed + shared, want, rtol=1e-4, atol=2e-5)
+    assert rows == 2 * 24 * 4
+
+
+# -- what does not run it says so by name ---------------------------------------
+
+
+@pytest.mark.parametrize("feature,said", [
+    ("hyper", "a residual stream 4 wide"),
+    ("mtp", "multi-token prediction (mtp_modules=1")])
+@pytest.mark.parametrize("what", ["prefill", "decode_step", "generate",
+                                  "export_generate", "forward_pipelined",
+                                  "mesh"])
+def test_what_cannot_run_a_wide_stream_or_a_module_refuses_it_by_name(
+        what, feature, said, tmp_path):
+    plain = dict(vocab_size=64, dim=128, num_heads=2, num_layers=2,
+                 seq_len=16, dtype="float32")
+    plain.update(dict(hyper_streams=4) if feature == "hyper"
+                 else dict(mtp_modules=1))
+    spec = tfm.model_spec(**plain)
+    cfg = spec.config
+    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+    prompt = jnp.zeros((1, 4), jnp.int32)
+    mesh = Mesh(np.array(jax.devices()[:2]).reshape(1, 2, 1, 1, 1),
+                ("dp", "pp", "tp", "sp", "ep"))
+    calls = {
+        "prefill": lambda: tfm.prefill(params, cfg, prompt, 8),
+        "decode_step": lambda: tfm.decode_step(
+            params, cfg, None, 0, prompt[:, 0]),
+        "generate": lambda: tfm.generate(params, cfg, prompt, 2),
+        "export_generate": lambda: tfm.export_generate(
+            str(tmp_path), params, cfg, 2, 4),
+        "forward_pipelined": lambda: tfm.forward_pipelined(
+            params, prompt, cfg, mesh, 2),
+        "mesh": lambda: tfm.param_specs(cfg),
+    }
+    with pytest.raises(NotImplementedError) as refusal:
+        calls[what]()
+    assert said in str(refusal.value)
+    assert what.split("_")[0] in str(refusal.value) or what == "mesh"
+
+
+# -- remat -------------------------------------------------------------------
+
+
+def test_remat_counts_a_stream_four_wide_and_the_modules_block():
+    spec = tfm.model_spec(**dict(TINY, remat=True))
+    cfg = spec.config
+    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+    plain = dataclasses.replace(cfg, hyper_streams=0, mtp_modules=0)
+    rows = 64
+    entries = {label: (names, per_layer, layers) for label, names,
+               per_layer, layers in rk._entries(cfg, rows)}
+    was = {label: (names, per_layer, layers) for label, names, per_layer,
+           layers in rk._entries(plain, rows)}
+    # the stream's carry four wide, a block more of everything
+    assert entries["stream"][1:] == (4 * was["stream"][1], 4)
+    assert entries["flash"][2] == was["flash"][2] + 1 == 4
+    assert entries["route"][2] == was["route"][2] + 1 == 3
+    # the latent's entry holds the query latent too
+    assert entries["latent"][0] == (rk.KEEP_LATENT, rk.KEEP_Q_LATENT)
+    assert entries["latent"][1] == rows * (32 + 8 + 24) * 4
+    # what the mixing may keep: a sublayer's read, two a layer
+    assert entries["hc_read"] == (
+        (hm.KEEP_U, hm.KEEP_Z), 2 * rows * (128 * 4 + 128 * 4), 4)
+    assert "hc_read" not in was
+    # the step's need: five carries four wide, two logits buffers, four
+    # streams in a layer's backward
+    stripped = {k: v for k, v in params.items() if k != "mtp"}
+    need = rk.step_bytes(cfg, params, rows)
+    less = rk.step_bytes(dataclasses.replace(cfg, mtp_modules=0), stripped,
+                         rows)
+    stream = rows * 4 * 128 * 4
+    assert need - less >= stream
+    assert rk.step_bytes(dataclasses.replace(cfg, mtp_modules=0), stripped,
+                         2 * rows) > less + 8 * stream

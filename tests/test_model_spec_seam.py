@@ -74,6 +74,13 @@ AS_TYPED = {
     "delta_kind": ("kda", "kda", ""),
     "delta_rank": ("16", 16, ""),
     "head_shares": ("2", 2, ""),
+    "q_latent_rank": ("24", 24, "kv_latent_rank=32;qk_nope_dim=16;qk_rope_dim=8;v_head_dim=8"),
+    "rope_scaling": ("64,4096,32,1", "64,4096,32,1", "kv_latent_rank=32;qk_nope_dim=16;qk_rope_dim=8;v_head_dim=8"),
+    "hyper_streams": ("4", 4, ""),
+    "hyper_sinkhorn_iters": ("5", 5, ""),
+    "mtp_modules": ("1", 1, ""),
+    "mtp_weight": ("0.3", 0.3, ""),
+    "scan_periods": ("false", False, ""),
 }
 FIELDS = [field for field in dataclasses.fields(tfm.TransformerConfig)
           if field.name != "max_seq_len"]
