@@ -129,6 +129,12 @@ TOLERANCES["rows_gather_back"] = _BF16_FWD
 # against one float32 scatter-add of the same bf16 rows: float32 sums
 # of the same values, whose order alone differs.
 TOLERANCES["embed_grad"] = _F32
+# A sublayer's maps by the kernels (ops/hyper_mix.hyper_maps) against
+# ``maps_of`` differentiated by JAX: float32 both, the rounds' sums in
+# another order and a reciprocal and a product for a quotient.
+for _name in ("hyper_maps", "hyper_maps_err", "hyper_maps_dz",
+              "hyper_maps_dbias"):
+    TOLERANCES[_name] = 1e-5
 TOLERANCES["stack_loss"] = 2e-3
 TOLERANCES["stack_grad_l2"] = 2 * _BF16_GRAD
 
@@ -386,6 +392,40 @@ def check_embed_rows(n, vocab, dim, interpret):
     want = jax.jit(lambda t, g: embed_rows.rows_added_ref(
         t, g, vocab))(tokens, g)
     return {"embed_grad": _rel_err(got, want)}
+
+
+def check_hyper_maps(b, t, n, iters, interpret):
+    """``hyper_maps`` (value, the error it states, both gradients)
+    against ``maps_of``: logits of a few units, some beyond the clamps,
+    a cotangent on every column."""
+    from elasticdl_tpu.ops import hyper_mix
+
+    rng = np.random.default_rng(b * t + iters)
+    width = hyper_mix.columns(n)
+    z = np.zeros((b, t, hyper_mix.LANES), np.float32)
+    z[..., :width] = 3.0 * rng.standard_normal((b, t, width))
+    z[0, :8, 2 * n:width] = 40.0 * np.sign(z[0, :8, 2 * n:width])
+    bias = jnp.asarray(0.5 * rng.standard_normal(width), jnp.float32)
+    weigh = jnp.asarray(rng.standard_normal(z.shape), jnp.float32)
+
+    def both(maps):
+        def loss(z, bias):
+            out, err = maps(z, bias)
+            return (out * weigh).sum(), (out, err)
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
+                                          has_aux=True))
+
+    z = jnp.asarray(z)
+    (_, (got, got_err)), got_grads = both(
+        lambda z, bias: hyper_mix.hyper_maps(
+            z, bias, n, iters, 1e-6, interpret=interpret))(z, bias)
+    (_, (want, want_err)), want_grads = both(
+        lambda z, bias: hyper_mix.maps_of(z, bias, n, iters, 1e-6))(z, bias)
+    return {"hyper_maps": _rel_err(got, want),
+            "hyper_maps_err": abs(float(got_err) - float(want_err)),
+            "hyper_maps_dz": _rel_err(got_grads[0], want_grads[0]),
+            "hyper_maps_dbias": _rel_err(got_grads[1], want_grads[1])}
 
 
 def check_short_conv(b, t, e, taps, interpret):
@@ -950,6 +990,11 @@ def _cases(tiny):
         yield ("embed_rows/N%d.V%d.E%d" % (n, vocab, dim),
                lambda n=n, vocab=vocab, dim=dim: check_embed_rows(
                    n, vocab, dim, interpret))
+    # xing4.0-29b-a4b.seq4096's maps: 2 x 4,096 tokens, 4 streams, 20
+    # Sinkhorn rounds.
+    mb, mt = (2, 128) if tiny else (2, 4096)
+    yield ("hyper_maps/B%d.T%d.N4.R20" % (mb, mt),
+           lambda: check_hyper_maps(mb, mt, 4, 20, interpret))
     cb, ct, ce = (3, 64, 128) if tiny else (4, 8192, 2048)
     yield ("short_conv/B%d.T%d.E%d.K3" % (cb, ct, ce),
            lambda: check_short_conv(cb, ct, ce, 3, interpret))

@@ -30,18 +30,30 @@ reads X and writes X'; three passes a sublayer.  Backward:
 that dX and writes their sum with its own terms (``pre`` hands X
 through, so that the stream's two readers' cotangents meet in the
 kernel and not in a pass of XLA's), and ``phi``'s gradient is one
-matmul of XLA's that reads X once more: seven.  The maps between (24
-values a token: the sigmoids, the Sinkhorn rounds on ``[n, n, rows]``
-with the tokens minor, so that a round is four small fusions whatever
-the rows, the rounds one ``lax.scan``) are ``jax.numpy``, differentiated
-by JAX.
+matmul of XLA's that reads X once more: seven.
+
+The maps between (24 values a token: the sigmoids, the Sinkhorn rounds)
+are one call each way, ``hyper_maps``: ``hc_maps_fwd`` reads a tile of
+the logits, turns it so that the tokens ride the lanes and each of the
+24 live columns is a dense ``[tile / 128, 128]`` plane, runs the rounds
+on the 16 planes of ``H_res`` in registers (a sum over a row or a
+column of ``H_res`` is three adds of whole planes, no lane or sublane
+is crossed) and turns the 128-lane tile ``post`` takes back out;
+``hc_maps_bwd`` reads the logits and the maps' cotangent, runs the
+rounds again keeping each half round's planes in VMEM (40 x 20 planes,
+3.3 MB at a tile of 1,024), walks them back and writes the logits'
+cotangent.  Nothing of the rounds reaches HBM, and the compiled step
+holds no loop for them.  ``maps_of``, the same in ``jax.numpy`` on
+``[n, n, rows]`` with the rounds one ``lax.scan`` that JAX
+differentiates, is the reference, and what runs wherever the op cannot.
 
 The calls carry their names into the compiled program and a device
 trace: ``hc_pre_fwd``, ``hc_post_fwd``, ``hc_pre_bwd``, ``hc_post_bwd``
-(``benchmark/kernels/hyper_mix.py``).  Reference: ``pre_ref`` and
-``post_ref``, plain ``jax.numpy`` differentiated by JAX, which is also
-what runs wherever ``ops/mode.py`` answers ``off`` or the shape does not
-tile (it says so: ``announce_fallback``).
+(``benchmark/kernels/hyper_mix.py``), and the maps' ``hc_maps_fwd``,
+``hc_maps_bwd``.  Reference: ``pre_ref``, ``maps_of`` and ``post_ref``,
+plain ``jax.numpy`` differentiated by JAX, which is also what runs
+wherever ``ops/mode.py`` answers ``off`` or the shape does not tile (it
+says so: ``announce_fallback``).
 """
 
 import functools
@@ -64,6 +76,9 @@ KEEP_U, KEEP_Z = "hc_u", "hc_z"
 # The maps' columns as the kernels take them: one 128-lane tile.
 LANES = 128
 ROW_TILES = (128, 64, 32, 16)
+# The maps' tokens a block: whole 128-lane rows of a plane, 8 of them
+# a full vreg.
+MAPS_TILES = (1024, 512, 256, 128)
 VMEM_LIMIT = 64 * 1024 * 1024
 CLAMP = (-30.0, 30.0)
 
@@ -77,11 +92,11 @@ def sinkhorn(m, iters, eps):
     """m [n, n, rows], positive -> the same after ``iters`` rounds of
     each row (axis 1) over its sum, then each column (axis 0) over its
     sum (``+ eps``).  The token axis is the minor one: a round is two
-    sums over four planes and two divisions, whatever n.  A loop of
-    the program's and not of Python's: a model's step holds the rounds
-    of every sublayer three times (forward, second forward, backward),
-    and unrolled the benchmark's cell compiled 5,000 small fusions more
-    into an executable past the compile cache's 192 MiB an entry."""
+    sums over four planes and two divisions, whatever n.  The
+    reference's form (``hyper_maps`` runs the rounds in one kernel): a
+    loop of the program's and not of Python's, because unrolled the
+    benchmark's cell compiled 5,000 small fusions more into an
+    executable past the compile cache's 192 MiB an entry."""
     def one_round(m, _):
         m = m / (m.sum(axis=1, keepdims=True) + eps)
         return m / (m.sum(axis=0, keepdims=True) + eps), None
@@ -255,18 +270,22 @@ def _post_bwd_kernel(x_ref, y_ref, maps_ref, dout_ref, dx_ref, dy_ref,
     dmaps_ref[...] = dmaps
 
 
-def _call(kernel, name, tm, interpret, ins, outs, whole=()):
+def _call(kernel, name, tm, interpret, ins, outs, whole=(), scratch=()):
     """One call over row blocks of ``tm``: ``ins`` and ``outs`` are
-    [rows, width] (arrays, ShapeDtypeStructs), blocked by rows; the
-    positions ``whole`` of ``ins`` are given to every block entire."""
-    rows = outs[0].shape[0]
-    block = lambda a: pl.BlockSpec((tm, a.shape[1]), lambda r: (r, 0))
+    [rows, width] (arrays, ShapeDtypeStructs), blocked by rows (an
+    output of fewer rows, a block's own few, evenly); the positions
+    ``whole`` of ``ins`` are given to every block entire; ``scratch``:
+    VMEM shapes the kernel takes last."""
+    grid = outs[0].shape[0] // tm
+    block = lambda a: pl.BlockSpec((a.shape[0] // grid, a.shape[1]),
+                                   lambda r: (r, 0))
     entire = lambda a: pl.BlockSpec(a.shape, lambda r: (0, 0))
     return pl.pallas_call(
-        kernel, out_shape=outs, grid=(rows // tm,),
+        kernel, out_shape=outs, grid=(grid,),
         in_specs=[entire(a) if i in whole else block(a)
                   for i, a in enumerate(ins)],
         out_specs=[block(a) for a in outs],
+        scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in scratch],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=VMEM_LIMIT),
@@ -280,11 +299,16 @@ def _shape(rows, width, dtype):
     return jax.ShapeDtypeStruct((rows, width), dtype)
 
 
+# Every call's forward and backward is jitted, so that a stack's
+# sublayers and their second forward under remat, which trace them at the
+# same shapes, trace and lower a kernel once (a worker lowers its step at
+# every start, and the maps' kernels unroll 20 rounds).
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _pre(x, phi, bias, n, eps, tm, interpret):
     return _pre_fwd(x, phi, bias, n, eps, tm, interpret)[0]
 
 
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
 def _pre_fwd(x, phi, bias, n, eps, tm, interpret):
     rows, width = x.shape
     c = width // n
@@ -296,6 +320,7 @@ def _pre_fwd(x, phi, bias, n, eps, tm, interpret):
     return (u, z, x), (x, phi, bias, z)
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
 def _pre_bwd(n, eps, tm, interpret, residuals, cotangents):
     x, phi, bias, z = residuals
     du, dz, dx_in = cotangents
@@ -320,6 +345,7 @@ def _post(x, y, maps, n, tm, interpret):
     return _post_fwd(x, y, maps, n, tm, interpret)[0]
 
 
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
 def _post_fwd(x, y, maps, n, tm, interpret):
     out = _call(
         functools.partial(_post_fwd_kernel, n=n, c=y.shape[1]),
@@ -328,6 +354,7 @@ def _post_fwd(x, y, maps, n, tm, interpret):
     return out, (x, y, maps)
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
 def _post_bwd(n, tm, interpret, residuals, dout):
     x, y, maps = residuals
     return tuple(_call(
@@ -338,6 +365,156 @@ def _post_bwd(n, tm, interpret, residuals, dout):
 
 
 _post.defvjp(_post_fwd, _post_bwd)
+
+
+# -- the maps' kernels --------------------------------------------------------
+#
+# A block of ``tile`` tokens is turned 128 by 128 into a VMEM scratch
+# ``[tile, LANES]`` whose row ``c * LANES + k`` holds column k of the
+# block's c-th 128 tokens; column k's plane, ``[tile / 128, 128]`` with
+# every lane and (at a tile of 1,024) every sublane a token, is that
+# scratch read at a stride of 128 rows.  The way back is the same.
+
+
+def _planes_in(ref, bias_ref, turn, first, count):
+    """Columns ``first .. first + count`` of the block ``ref`` (``+
+    bias``), a dense plane each."""
+    s = turn.shape[0] // LANES
+    for c in range(s):
+        rows = slice(c * LANES, (c + 1) * LANES)
+        block = ref[rows, :]
+        turn[rows, :] = (block if bias_ref is None
+                         else block + bias_ref[...]).T
+    return [turn[pl.ds(first + k, s, stride=LANES), :] for k in range(count)]
+
+
+def _planes_out(planes, first, turn, ref):
+    """The block ``ref``: ``planes`` in columns ``first ..``, zeros in
+    the others."""
+    s = turn.shape[0] // LANES
+    for k, plane in enumerate(planes):
+        turn[pl.ds(first + k, s, stride=LANES), :] = plane
+    lane = lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
+    live = (lane >= first) & (lane < first + len(planes))
+    for c in range(s):
+        rows = slice(c * LANES, (c + 1) * LANES)
+        ref[rows, :] = jnp.where(live, turn[rows, :].T, 0.0)
+
+
+def _halves(n, iters):
+    """The rounds' half rounds in order, each the groups of H_res's
+    planes (row major) it sums over: the rows, then the columns."""
+    by_row = [[i * n + j for j in range(n)] for i in range(n)]
+    by_column = [[i * n + j for i in range(n)] for j in range(n)]
+    return [by_row, by_column] * iters
+
+
+def _total(planes):
+    return functools.reduce(lambda a, b: a + b, planes)
+
+
+def _normalized(m, groups, eps):
+    """Half a round: each group's planes over their sum ``+ eps``, as
+    one reciprocal a group and a product a plane -> (planes, the
+    groups' reciprocals)."""
+    out, recips = list(m), []
+    for group in groups:
+        recip = 1.0 / (_total([m[k] for k in group]) + eps)
+        recips.append(recip)
+        for k in group:
+            out[k] = m[k] * recip
+    return out, recips
+
+
+def _maps_fwd_kernel(z_ref, bias_ref, maps_ref, err_ref, turn, *, n, iters,
+                     eps):
+    z = _planes_in(z_ref, bias_ref, turn, n, n + n * n)
+    h_post = [2.0 * jax.nn.sigmoid(p) for p in z[:n]]
+    m = [jnp.exp(jnp.clip(p, *CLAMP)) for p in z[n:]]
+    halves = _halves(n, iters)
+    for groups in halves:
+        m = _normalized(m, groups, eps)[0]
+    off = functools.reduce(jnp.maximum, [
+        jnp.abs(_total([m[k] for k in group]) - 1.0)
+        for group in halves[0] + halves[1]])
+    err_ref[...] = jnp.broadcast_to(jnp.max(off, axis=0, keepdims=True),
+                                    err_ref.shape)
+    _planes_out(h_post + m, n, turn, maps_ref)
+
+
+def _maps_bwd_kernel(z_ref, bias_ref, dmaps_ref, dz_ref, dsum_ref, turn,
+                     kept, kept_recips, *, n, iters, eps):
+    """With y = m r, r = 1 / (sum of the group's m + eps) a half round:
+    dm = r (dy - sum of the group's dy y), so the walk back reads each
+    half round's y and r, which the rounds run again here leave in
+    ``kept`` / ``kept_recips``.  A logit on a clamp passes nothing on
+    (JAX halves it there)."""
+    z = _planes_in(z_ref, bias_ref, turn, n, n + n * n)
+    halves = _halves(n, iters)
+    first = m = [jnp.exp(jnp.clip(p, *CLAMP)) for p in z[n:]]
+    for t, groups in enumerate(halves):
+        m, recips = _normalized(m, groups, eps)
+        for k, plane in enumerate(m):
+            kept[t * n * n + k] = plane
+        for g, recip in enumerate(recips):
+            kept_recips[t * n + g] = recip
+    d = _planes_in(dmaps_ref, None, turn, n, n + n * n)
+    d_post = []
+    for p, dp in zip(z[:n], d[:n]):
+        sig = jax.nn.sigmoid(p)
+        d_post.append(dp * (2.0 * sig * (1.0 - sig)))
+    dy = d[n:]
+    for t in reversed(range(len(halves))):
+        dm = list(dy)
+        for g, group in enumerate(halves[t]):
+            inner = _total([dy[k] * kept[t * n * n + k] for k in group])
+            for k in group:
+                dm[k] = kept_recips[t * n + g] * (dy[k] - inner)
+        dy = dm
+    d_res = [jnp.where((p > CLAMP[0]) & (p < CLAMP[1]), g * e, 0.0)
+             for p, g, e in zip(z[n:], dy, first)]
+    _planes_out(d_post + d_res, n, turn, dz_ref)
+    # the bias's gradient is dz's column sums: this block's, 8 rows of
+    # partial sums
+    dsum_ref[...] = _total([dz_ref[r:r + 8, :]
+                            for r in range(0, dz_ref.shape[0], 8)])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
+def _maps(z, bias, n, iters, eps, tile, interpret):
+    return _maps_fwd(z, bias, n, iters, eps, tile, interpret)[0]
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6))
+def _maps_fwd(z, bias, n, iters, eps, tile, interpret):
+    """(maps [rows, LANES], the blocks' largest errors [8 a block,
+    LANES]) of z [rows, LANES] and bias [1, LANES]."""
+    rows = z.shape[0]
+    out = _call(
+        functools.partial(_maps_fwd_kernel, n=n, iters=iters, eps=eps),
+        "hc_maps_fwd", tile, interpret, (z, bias),
+        (_shape(rows, LANES, jnp.float32),
+         _shape(rows // tile * 8, LANES, jnp.float32)),
+        whole=(1,), scratch=((tile, LANES),))
+    return tuple(out), (z, bias)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
+def _maps_bwd(n, iters, eps, tile, interpret, residuals, cotangents):
+    z, bias = residuals
+    rows, halves = z.shape[0], 2 * iters
+    dz, dsum = _call(
+        functools.partial(_maps_bwd_kernel, n=n, iters=iters, eps=eps),
+        "hc_maps_bwd", tile, interpret, (z, bias, cotangents[0]),
+        (_shape(rows, LANES, jnp.float32),
+         _shape(rows // tile * 8, LANES, jnp.float32)),
+        whole=(1,), scratch=((tile, LANES),
+                             (halves * n * n, tile // LANES, LANES),
+                             (halves * n, tile // LANES, LANES)))
+    return dz, dsum.sum(axis=0, keepdims=True)
+
+
+_maps.defvjp(_maps_fwd, _maps_bwd)
 
 
 # -- the op ------------------------------------------------------------------
@@ -410,17 +587,68 @@ def _logits(x, phi, alpha, bias, n, eps, interpret):
     return per_batch_shard(op, (x,), (phi, bias))
 
 
+@functools.lru_cache(maxsize=None)
+def announce_maps(rows, n, iters, tile, mode):
+    """Once per compiled shape, beside ``announce_hyper``: the maps one
+    shard of the data axis makes, and by what."""
+    flash_attention.logger.info(
+        "hyper maps: rows=%d n=%d iters=%d tile=%s form=%s", rows, n, iters,
+        tile or "-", {"tpu": "kernel", "interpret": "interpreter",
+                      "off": "jnp"}[mode])
+
+
+def maps_mode(rows, n, width, interpret=None):
+    """(mode, tokens a block, why not the kernel) for the maps of
+    ``rows`` tokens a shard whose logits are ``width`` wide."""
+    mode = resolve(interpret)
+    if mode == "off":
+        return mode, None, ""
+    tile = next((t for t in MAPS_TILES if rows % t == 0), None)
+    if tile is None or width != LANES or columns(n) > LANES:
+        return "off", None, "maps: rows %% %d, logits %d wide or %d > %d" % (
+            MAPS_TILES[-1], width, columns(n), LANES)
+    return mode, tile, ""
+
+
+def hyper_maps(z, bias, n, iters, eps, interpret=None):
+    """``maps_of(z, bias, n, iters, eps)`` of the logits z [B, T, >= 2n
+    + n^2] float32, one call forward and one backward where a kernel
+    may run; ``maps_of`` itself where none may, or the logits are not
+    ``pre``'s 128-lane tile."""
+    batch, seq_len, width = z.shape
+    rows = batch * seq_len // shards()
+    mode, tile, why = maps_mode(rows, n, width, interpret)
+    if why:
+        flash_attention.announce_fallback("hyper_mix", z.shape, why,
+                                          resolve(interpret))
+    announce_maps(rows, n, iters, tile, mode)
+    if mode == "off":
+        return maps_of(z, bias, n, iters, eps)
+
+    def op(z, bias):
+        b, t, _ = z.shape
+        maps, off = _maps(z.reshape(b * t, LANES), bias, n, iters, eps, tile,
+                          mode == "interpret")
+        return maps.reshape(b, t, LANES), off
+
+    maps, off = per_batch_shard(
+        op, (z,), (jnp.pad(bias, (0, LANES - bias.shape[0]))[None],))
+    return maps, lax.stop_gradient(off.max())
+
+
 def pre(x, phi, alpha, bias, streams, iters, eps, sk_eps, interpret=None,
         sinkhorn_dtype=jnp.float32):
     """A sublayer's read of the stream x [B, T, n C] -> (u [B, T, C]:
     the streams mixed by H_pre; x handed through: what ``post`` takes;
-    maps [B, T, LANES] float32 (``maps_of``); err).  phi [n C, 2n +
-    n^2], alpha [3], bias [2n + n^2], float32."""
+    maps [B, T, LANES] float32 and err: ``hyper_maps``, or ``maps_of``
+    in a ``sinkhorn_dtype`` that is not float32).  phi [n C, 2n + n^2],
+    alpha [3], bias [2n + n^2], float32."""
     u, z, x = _logits(x, phi, alpha, bias, streams, eps, interpret)
     u, z = checkpoint_name(u, KEEP_U), checkpoint_name(z, KEEP_Z)
-    maps, err = maps_of(z, bias.astype(jnp.float32), streams, iters, sk_eps,
-                        sinkhorn_dtype)
-    return u, x, maps, err
+    bias = bias.astype(jnp.float32)
+    if sinkhorn_dtype != jnp.float32:
+        return u, x, *maps_of(z, bias, streams, iters, sk_eps, sinkhorn_dtype)
+    return u, x, *hyper_maps(z, bias, streams, iters, sk_eps, interpret)
 
 
 def narrow(x, phi, alpha, bias, streams, eps, interpret=None):

@@ -1125,7 +1125,9 @@ def test_the_four_calls_compile_at_the_cells_shape(one_chip):
     """Two sequences of 4,096 tokens of a stream 4 x 3,584 wide,
     bfloat16: one call each of ``hc_pre_fwd`` and ``hc_post_fwd``
     forward, ``hc_post_bwd`` and ``hc_pre_bwd`` backward, in 128-row
-    tiles under the 64 MB of VMEM the calls ask for."""
+    tiles under the 64 MB of VMEM the calls ask for; the maps between
+    one call each way (PR 55), 1,024 tokens a block, and no loop of the
+    program's for their 20 rounds."""
     on_chip = lambda shape, dtype: jax.ShapeDtypeStruct(
         shape, dtype, sharding=one_chip)
     x = on_chip((2, 4096, HC_N * HC_C), jnp.bfloat16)
@@ -1135,6 +1137,8 @@ def test_the_four_calls_compile_at_the_cells_shape(one_chip):
     bias = on_chip((hm.columns(HC_N),), jnp.float32)
     assert hm.hyper_mode(HC_ROWS, HC_N, HC_C, interpret=False) == (
         "tpu", 128, "")
+    assert hm.maps_mode(HC_ROWS, HC_N, hm.LANES, interpret=False) == (
+        "tpu", 1024, "")
 
     def loss(x, phi, alpha, bias, y):
         u, through, maps, err = hm.pre(x, phi, alpha, bias, HC_N, 20, 1e-6,
@@ -1144,9 +1148,11 @@ def test_the_four_calls_compile_at_the_cells_shape(one_chip):
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         x, phi, alpha, bias, y).compile()
-    assert _names(compiled.as_text()) == {
+    text = compiled.as_text()
+    assert _names(text) == {
         "hc_pre_fwd": 1, "hc_post_fwd": 1, "hc_post_bwd": 1,
-        "hc_pre_bwd": 1}
+        "hc_pre_bwd": 1, "hc_maps_fwd": 1, "hc_maps_bwd": 1}
+    assert " while(" not in text
     # the stream in, its gradient out, and under two streams between
     stats = compiled.memory_analysis()
     assert stats.temp_size_in_bytes < 2.2 * HC_ROWS * HC_N * HC_C * 2
@@ -1187,11 +1193,16 @@ def test_the_wide_streams_step_fits_a_v5e_with_nothing_kept(one_chip,
     # counted whole where expert layers are unrolled (ROADMAP A3 (t))
     estimate = held + rk.step_bytes(spec.config, params, HC_ROWS)
     assert 0.9e9 < estimate - counted < 1.5e9, (estimate, counted)
-    names = _names(compiled.as_text())
+    text = compiled.as_text()
+    names = _names(text)
     # twelve sublayers: read twice (the second forward), written twice
     # but for each block's last (its result is the next block's kept
     # input), back-propagated once; the two narrowing maps
     assert names["hc_pre_fwd"] == 2 * 12 + 2
+    # their maps: made twice, back-propagated once, one call each; no
+    # loop of the program's turns over the rounds' [4, 4, 8192] planes
+    assert (names["hc_maps_fwd"], names["hc_maps_bwd"]) == (2 * 12, 12)
+    assert not re.search(r"f32\[4,4,8192\]", text)
     assert names["hc_post_fwd"] == 2 * 12 - 6
     assert (names["hc_post_bwd"], names["hc_pre_bwd"]) == (12, 12 + 2)
     assert names["flash_fwd_qk192_v128"] == 12

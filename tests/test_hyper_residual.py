@@ -4,7 +4,13 @@ it, against the plain reference of the benchmark
 (benchmark/reference/xing4.0-29b-a4b.py).  Float32 on the CPU at tiny
 widths; the kernels in interpret mode."""
 
+import collections
+import contextlib
 import dataclasses
+import json
+import logging
+import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -147,8 +153,16 @@ def test_the_sinkhorn_maps_rows_and_columns_sum_to_one():
         generic)
 
 
-def test_the_kernels_derivatives_are_jax_grads_of_the_jnp_form():
-    x, y, w = sublayer(seed=1)
+@pytest.mark.parametrize("rows", [(2, 32), (2, 64)],
+                         ids=["maps_jnp", "maps_kernel"])
+def test_the_kernels_derivatives_are_jax_grads_of_the_jnp_form(rows):
+    """A whole block, ``pre`` -> sublayer -> ``post``, differentiated:
+    the kernels' against ``pre_ref`` / ``maps_of`` / ``post_ref``'s.  64
+    tokens tile for the four stream calls alone (the maps fall back to
+    ``maps_of``), 128 for the maps' two calls too."""
+    assert (hm.maps_mode(rows[0] * rows[1], N, hm.LANES, True)[0]
+            == ("interpret" if rows[1] == 64 else "off"))
+    x, y, w = sublayer(seed=1, rows=rows)
     weigh = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
 
     def scalar(interpret):
@@ -186,6 +200,173 @@ def test_shapes_the_kernels_refuse_take_the_reference(monkeypatch):
     assert hm.hyper_mode(64, 12, 128)[0] == "off"      # 168 logits
     monkeypatch.setenv("ELASTICDL_FLASH", "off")
     assert hm.hyper_mode(64, 4, 128) == ("off", None, "")
+
+
+# -- the maps: one call each way ----------------------------------------------
+
+
+@contextlib.contextmanager
+def lines_of(logger):
+    """The messages ``logger`` takes inside the block (it hands nothing
+    up to the root, so ``caplog`` sees none)."""
+    seen = []
+    handler = logging.Handler()
+    handler.emit = lambda record: seen.append(record.getMessage())
+    logger.addHandler(handler)
+    try:
+        yield seen
+    finally:
+        logger.removeHandler(handler)
+
+
+def logits(rows=(2, 64), seed=7, width=hm.LANES):
+    """(z [B, T, width] float32 with the 24 logits of a few units, the
+    first eight tokens' H_res logits beyond both clamps; bias [24]; a
+    cotangent for the maps)."""
+    rng = np.random.default_rng(seed)
+    live = hm.columns(N)
+    z = np.zeros((*rows, width), np.float32)
+    z[..., :live] = 3.0 * rng.standard_normal((*rows, live))
+    z[0, :8, 2 * N:live] = 40.0 * np.sign(z[0, :8, 2 * N:live])
+    bias = 0.5 * rng.standard_normal(live)
+    weigh = rng.standard_normal((*rows, hm.LANES))
+    return tuple(jnp.asarray(a, jnp.float32) for a in (z, bias, weigh))
+
+
+def maps_and_gradients(maps, z, bias, weigh):
+    def loss(z, bias):
+        out, err = maps(z, bias)
+        return (out * weigh).sum() + err, (out, err)
+
+    (_, (out, err)), (dz, dbias) = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1), has_aux=True))(z, bias)
+    return out, err, dz, dbias
+
+
+@pytest.mark.parametrize("iters", [20, 1])
+@pytest.mark.parametrize("form", ["interpreter", "jnp"])
+def test_the_maps_op_is_maps_of_in_value_and_gradient(form, iters,
+                                                      monkeypatch):
+    """``hyper_maps`` against ``maps_of`` differentiated by JAX: the
+    maps, the error they state, the logits' and the bias's gradients,
+    each to 1e-6 of its largest value; logits beyond both clamps pass
+    no gradient on, and ``err`` none at all."""
+    monkeypatch.setenv("ELASTICDL_FLASH",
+                       "interpret" if form == "interpreter" else "off")
+    z, bias, weigh = logits()
+    assert float(jnp.abs(z[..., 2 * N:] + 0).max()) > 30 > float(
+        jnp.abs(z[0, 8:, 2 * N:hm.columns(N)]).min())
+    got = maps_and_gradients(
+        lambda z, b: hm.hyper_maps(z, b, N, iters, 1e-6), z, bias, weigh)
+    want = maps_and_gradients(
+        lambda z, b: hm.maps_of(z, b, N, iters, 1e-6), z, bias, weigh)
+    for name, g, w in zip(("maps", "err", "dz", "dbias"), got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        assert float(jnp.abs(g - w).max()) <= 1e-6 * float(
+            jnp.abs(w).max()), name
+    maps, _, dz, _ = got
+    # H_pre's columns and the tile's padding hold nothing, either way
+    assert not float(jnp.abs(maps[..., :N]).max())
+    assert not float(jnp.abs(maps[..., hm.columns(N):]).max())
+    assert not float(jnp.abs(dz[..., :N]).max())
+    assert not float(jnp.abs(dz[0, :8, 2 * N:hm.columns(N)]).max())
+    assert float(jnp.abs(dz[0, 8:, N:hm.columns(N)]).min()) > 0
+
+
+@pytest.mark.parametrize("rows,width", [((2, 50), hm.LANES), ((2, 64), 24)],
+                         ids=["rows", "width"])
+def test_maps_that_do_not_tile_fall_back_and_say_so(rows, width):
+    """100 tokens are no whole 128-lane plane and 24 logits no tile of
+    ``pre``'s: asked for the compiled kernel, the op runs ``maps_of``
+    and an ``attention fallback:`` line says why."""
+    from elasticdl_tpu.ops import flash_attention
+
+    flash_attention._announce_once.cache_clear()
+    z, bias, _ = logits(rows, width=width)
+    assert hm.maps_mode(rows[0] * rows[1], N, width, False)[:2] == (
+        "off", None)
+    with lines_of(flash_attention.logger) as seen:
+        got = hm.hyper_maps(z, bias, N, 20, 1e-6, interpret=False)
+    want = hm.maps_of(z, bias, N, 20, 1e-6)
+    np.testing.assert_array_equal(got[0], want[0])
+    assert float(got[1]) == float(want[1])
+    said, = [m for m in seen if m.startswith("attention fallback: hyper_mix")]
+    assert "maps: rows % 128" in said and str(z.shape) in said
+
+
+def test_the_maps_tiles_are_whole_planes():
+    assert hm.maps_mode(8192, 4, 128, True) == ("interpret", 1024, "")
+    assert hm.maps_mode(384, 4, 128, True)[1] == 128
+    assert hm.maps_mode(8192, 12, 128, True)[0] == "off"   # 168 logits
+    assert hm.maps_mode(8192, 4, 128, None) == ("off", None, "")
+
+
+def test_the_hyper_maps_line_is_said_once_a_shape(monkeypatch):
+    from elasticdl_tpu.ops import flash_attention
+
+    monkeypatch.setenv("ELASTICDL_FLASH", "interpret")
+    hm.announce_maps.cache_clear()
+    with lines_of(flash_attention.logger) as seen:
+        for rows in ((2, 64), (2, 64), (2, 32), (2, 64)):
+            z, bias, _ = logits(rows)
+            jax.eval_shape(lambda z, b: hm.hyper_maps(z, b, N, 20, 1e-6),
+                           z, bias)
+    assert [m for m in seen if m.startswith("hyper maps:")] == [
+        "hyper maps: rows=128 n=4 iters=20 tile=128 form=interpreter",
+        "hyper maps: rows=64 n=4 iters=20 tile=- form=jnp"]
+
+
+def test_the_cells_rehearsal_step_names_the_two_calls_and_scans_no_round(
+        monkeypatch):
+    """The benchmark configuration's step at its rehearsal widths (two
+    sequences of 64 tokens, three layers and the module's block: eight
+    sublayers), lowered for the TPU as a chip would lower it: every
+    sublayer's maps are ``hc_maps_fwd`` twice (the second forward) and
+    ``hc_maps_bwd`` once, and no loop of the program's carries the
+    rounds' ``[4, 4, rows]`` planes; with the kernels off the scan is
+    there."""
+    from benchmark.lib.runner import merge, params_string
+    from elasticdl_tpu.models.spec import load_model_spec
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "xing4.0-29b-a4b.json")) as fh:
+        config = json.load(fh)
+    config = merge(config, config["rehearsal"])
+    tokens = jax.ShapeDtypeStruct((2, config["seq_len"]), jnp.int32)
+
+    def lowered(switch):
+        monkeypatch.setenv("ELASTICDL_FLASH", switch)
+        spec = load_model_spec(
+            config["cli"]["model_zoo"],
+            model_params=params_string(config["cli"]["model_params"]))
+        assert spec.config.hyper_sinkhorn_iters == 20
+
+        def step(params, tokens):
+            def loss(p):
+                out = spec.apply_fn(p, tokens, True)
+                return (spec.loss_fn(out, tokens).mean(),
+                        spec.step_stats_fn(out))
+
+            return jax.value_and_grad(loss, has_aux=True)(params)
+
+        params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+        return jax.jit(step).trace(params, tokens).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    planes = "tensor<4x4x%dxf32>" % (2 * config["seq_len"])
+    text = lowered("tpu")
+    # the jitted calls lower once a tracing context and are called from
+    # each sublayer's place
+    calls = collections.Counter(
+        re.findall(r"call @_(\w+?_(?:fwd|bwd))(?:_\d+)?\(", text))
+    assert (calls["maps_fwd"], calls["maps_bwd"]) == (16, 8)
+    assert (calls["post_bwd"], calls["pre_bwd"]) == (8, 8 + 2)
+    assert {"hc_maps_fwd", "hc_maps_bwd", "hc_pre_fwd", "hc_post_bwd"} <= set(
+        re.findall(r'kernel_name = "(\w+)"', text))
+    assert planes not in text
+    off = lowered("off")
+    assert planes in off and "kernel_name" not in off
 
 
 # -- the whole model -----------------------------------------------------------
