@@ -38,7 +38,8 @@ from elasticdl_tpu.ops import (batch_shard, flash_attention, gated_delta,
 # its input (the projection's output) and its result.
 KEEP_Q, KEEP_K, KEEP_V = "attn_q", "attn_k", "attn_v"
 # The projection behind a gate on attention's output (``cfg.attn_gate``),
-# [rows, heads * head_dim] before its sigmoid.
+# [rows, heads * head_dim] before its sigmoid; a gate a head's, [rows,
+# heads].
 KEEP_ATTN_GATE = "attn_gate"
 # Latent attention's: the down-projection's result (the latent before
 # its norm, and the one RoPE key before RoPE), and the k_nope and v a
@@ -61,8 +62,9 @@ KEEP_SHARED_GATE, KEEP_SHARED_UP = "shared_gate", "shared_up"
 KEEP_DELTA_IN, KEEP_DELTA_QKV = "delta_in", "delta_qkv"
 KEEP_DELTA_DECAY, KEEP_DELTA_GATE = "delta_decay", "delta_gate"
 # A kda layer's (``delta_kind``): the two [rows, delta_rank] products of
-# the stream that its decay's and its gate's low-rank pairs start from;
-# its ``delta_decay`` is [rows, heads * key_dim] float32, a channel each.
+# the stream that its decay's and its gate's low-rank pairs start from
+# (none under full projections, ``delta_rank`` 0); its ``delta_decay``
+# is [rows, heads * key_dim] float32, a channel each.
 KEEP_DELTA_RANK = "delta_rank"
 
 # The share of the device's limit nothing is planned into: the
@@ -128,11 +130,12 @@ def _entries(cfg, rows):
     else:
         entries.append(("qkv", (KEEP_Q, KEEP_K, KEEP_V),
                         rows * (h + 2 * g) * d * size, attention))
-        if cfg.attn_gate:
-            # a product of the hidden size as wide as q: a byte's worth
-            # the same, ~13
-            entries.append(("gate", (KEEP_ATTN_GATE,), rows * h * d * size,
-                            attention))
+    if cfg.attn_gate:
+        # a product of the hidden size as wide as q: a byte's worth the
+        # same, ~13; a gate a head (latent attention's one form): the
+        # whole stream read for a 128-lane tile of ``heads`` values a row
+        entries.append(("gate", (KEEP_ATTN_GATE,), rows * size * (
+            _lanes(h) if cfg.attn_gate == "head" else h * d), attention))
     entries.append(("stream", (KEEP_STREAM,),
                     rows * cfg.stream_width * size, len(kinds)))
     f, dense_f = cfg.mlp_dim, cfg.dense_ffn_dim if x else cfg.mlp_dim
@@ -174,9 +177,10 @@ def _entries(cfg, rows):
         # rank: what a byte of them buys is what a byte of q buys times
         # their share of q's operations; the two [rows, rank] products
         # themselves read the whole stream for 2 * rank values a row
-        # (the dearest byte of the layer)
+        # (the dearest byte of the layer).  Under full projections
+        # (rank 0) both are products of the hidden size, as q is
         kda, rank = cfg.delta_kind == "kda", cfg.delta_rank
-        cheap = rank * (1 / e + 1 / (h * d_v)) if kda else 1.0
+        cheap = rank * (1 / e + 1 / (h * d_v)) if kda and rank else 1.0
         rest += [
             (13 * cheap * size / 4 if kda else 100, "delta_decay",
              (KEEP_DELTA_DECAY,), rows * h * 4 * (d_k + 1 if kda else 2),
@@ -193,7 +197,7 @@ def _entries(cfg, rows):
             (5, "delta_qkv", (KEEP_DELTA_QKV,),
              rows * h * (2 * d_k + d_v) * size, delta),
         ]
-        if kda:
+        if kda and rank:
             rest.append((13 * e / rank / 2, "delta_rank", (KEEP_DELTA_RANK,),
                          rows * 2 * rank * size, delta))
     if cfg.hyper_streams:

@@ -38,7 +38,8 @@ from elasticdl_tpu.ops.flash_attention import (flash_attention, flash_mode,
                                                latent_attention,
                                                latent_mode, logger)
 from elasticdl_tpu.ops.mode import kernels_off
-from elasticdl_tpu.ops.moe_dispatch import ACTIVATIONS, moe_experts
+from elasticdl_tpu.ops.moe_dispatch import (ACTIVATIONS, gated,
+                                            moe_experts)
 from elasticdl_tpu.utils import metrics
 
 
@@ -81,13 +82,16 @@ class TransformerConfig:
     # n(Op(x))`` then ``x + n(FFN(x))`` with ``post_norms``, which it
     # needs (OLMo 2's reordered norm).
     pre_norms: bool = True
-    # A gate on attention's output: ``w_attn_gate`` [dim, heads *
-    # head_dim] reads the normed input that q, k and v read, and every
-    # value of the kernel's output is multiplied by the sigmoid of its
-    # own gate, in the compute dtype, before ``wo``.  Attention with wk
-    # and wv alone: latent attention and a short-convolution layer
-    # refuse it at construction.
-    attn_gate: bool = False
+    # A gate on attention's output, read from the normed input that q, k
+    # and v read and applied to the kernel's output in the compute
+    # dtype, before ``wo``.  True = a gate a VALUE: ``w_attn_gate``
+    # [dim, heads * head_dim], every value multiplied by the sigmoid of
+    # its own gate (attention with wk and wv alone: latent attention
+    # refuses it at construction); "head" = a gate a HEAD:
+    # ``w_attn_gate`` [dim, heads], a head's values by the sigmoid of
+    # its one gate (attention with wk and wv, and latent attention).  A
+    # stack with a short-convolution layer refuses either.
+    attn_gate: bool | str = False
     # What a token's row is multiplied by where the table is read as the
     # model's input (muP: sqrt(dim)), in the compute dtype; never where
     # a tied head reads the table.  Not ``embed_scale``, which is the
@@ -199,9 +203,17 @@ class TransformerConfig:
     # the low-rank pair ``w_a_down`` [dim, delta_rank] / ``w_a_up``
     # [delta_rank, heads * key_dim] with a ``dt_bias`` a channel; the
     # output gate a sigmoid of the pair ``w_g_down`` / ``w_g_up`` + ``b_g``
-    # of the same rank).  ``delta_rank`` is the pairs' and kda's alone.
+    # of the same rank).  ``delta_rank`` is the pairs' and kda's alone;
+    # a kda layer with ``delta_rank`` 0 has FULL projections in the
+    # pairs' place, ``w_a`` [dim, heads * key_dim] and ``w_out_gate``
+    # [dim, heads * value_dim], and no ``b_g``.
     delta_kind: str = "gdn"
     delta_rank: int = 0
+    # A kda layer's decay gate: 0 = ``g = -exp(A_log) softplus(a +
+    # dt_bias)``, unbounded below; F < 0 = the bounded gate ``g = F
+    # sigmoid(exp(A_log) (a + dt_bias))``, a log decay a channel in (F,
+    # 0) (``a`` the decay's projection a channel, ``A_log`` a head's).
+    delta_gate_floor: float = 0.0
     # How many chips share a layer's heads in the deployment this model
     # is one chip of (tensor parallel over heads): ``num_heads`` and
     # ``num_kv_heads`` are the heads held HERE, every mixer's result is
@@ -229,6 +241,13 @@ class TransformerConfig:
     # their sum + 1e-6, times ``moe_route_scale``.
     moe_router: str = "softmax"
     moe_route_scale: float = 1.0
+    # A choice limited to groups (DeepSeek-V3's ``noaux_tc``; the
+    # "sigmoid_bias" router's alone): the experts lie in ``moe_groups``
+    # equal groups of neighbours, a group's score is the sum of its two
+    # largest score + bias, and the K experts are chosen inside the
+    # ``moe_top_groups`` best groups alone.  0 and 0 = no limit.
+    moe_groups: int = 0
+    moe_top_groups: int = 0
     # What the router reads: False = the FFN's input, RMSNorm_2 of the
     # stream after the operator; True = the operator's input,
     # RMSNorm_1 of the block's input, so the route is taken before the
@@ -238,6 +257,15 @@ class TransformerConfig:
     # (``ops/moe_dispatch.ACTIVATIONS``): "silu" (SwiGLU) | "relu"
     # (ReGLU).
     ffn_activation: str = "silu"
+    # A clamp on a gated MLP's two products that is a LAYER's: "L0,L1,
+    # .." one limit a layer of ``num_layers``; where a layer's L > 0 its
+    # routed experts (``ffn_limits``) or its shared expert
+    # (``shared_limits``) compute ``(act(min(a, L)) * clip(b, -L, L))
+    # W_down`` of the gate product a and the up product b; 0 = no clamp
+    # in that layer, "" = none in any.  A dense layer's MLP and a
+    # multi-token-prediction module have none.
+    ffn_limits: str = ""
+    shared_limits: str = ""
     # One chip's share of the experts: ``moe_experts`` stays the
     # router's width, every token is routed over all of them, and this
     # model holds (and multiplies) experts ``moe_share_index *
@@ -300,13 +328,15 @@ class TransformerConfig:
                     field.name, value,
                     ", ".join(str(word).lower() for word in words),
                     _WORDS_GONE.get(field.name, "")))
-        if self.attn_gate and (self.kv_latent_rank
-                               or "c" in self.layer_pattern):
+        if self.attn_gate and ("c" in self.layer_pattern or (
+                self.kv_latent_rank and self.attn_gate != "head")):
             raise ValueError(
-                "attn_gate is a gate on the output of attention with wk "
-                "and wv: latent attention (kv_latent_rank=%d) and a short "
-                "convolution (layer_pattern=%r) have no w_attn_gate"
-                % (self.kv_latent_rank, self.layer_pattern))
+                "attn_gate=%s: a gate a value (true) is on the output of "
+                "attention with wk and wv, a gate a head (head) on theirs "
+                "or latent attention's: latent attention (kv_latent_rank="
+                "%d) takes head alone and a short convolution "
+                "(layer_pattern=%r) has no w_attn_gate"
+                % (self.attn_gate, self.kv_latent_rank, self.layer_pattern))
         if not self.pre_norms and (not self.post_norms
                                    or self.moe_route_before_op):
             raise ValueError(
@@ -353,11 +383,47 @@ class TransformerConfig:
             raise ValueError(
                 "mtp_modules=%d: want a count >= 0 and a router that reads "
                 "the FFN's input" % self.mtp_modules)
+        groups, top_groups = self.moe_groups, self.moe_top_groups
+        if (groups or top_groups) and (
+                self.moe_router != "sigmoid_bias"
+                or not 0 < top_groups <= groups
+                or self.moe_experts % groups
+                or self.moe_top_k > top_groups * (self.moe_experts // groups)
+                or self.moe_experts // groups < 2):
+            raise ValueError(
+                "moe_groups=%d, moe_top_groups=%d: the sigmoid_bias router's "
+                "(moe_router=%s), 0 < moe_top_groups <= moe_groups, groups of "
+                "moe_experts / moe_groups >= 2 experts (moe_experts=%d) and "
+                "moe_top_k (%d) experts inside the chosen groups"
+                % (groups, top_groups, self.moe_router, self.moe_experts,
+                   self.moe_top_k))
+        for name in ("ffn_limits", "shared_limits"):
+            limits = limits_of(getattr(self, name))
+            if limits and (len(limits) != self.num_layers or min(limits) < 0
+                           or not self.moe_experts):
+                raise ValueError(
+                    "%s=%r: want one limit >= 0 a layer of num_layers=%d, of "
+                    "a model with experts (a dense MLP has no clamp)"
+                    % (name, getattr(self, name), self.num_layers))
         if set(self.rope_kinds) - set("aw"):
             raise ValueError(
                 "rope_kinds %r: want letters of a (full attention) and w "
                 "(windowed attention)" % (self.rope_kinds,))
         pattern = _pattern(self)
+        if pattern is None or len(pattern) == self.num_layers:
+            kinds, plan = self.kinds, stack_plan(self)
+            first, size, turns = (0, 1, self.num_layers) if plan is None \
+                else (len(plan.lead), len(plan.period), plan.periods)
+            if any(kind.dense and (kind.limit or kind.shared_limit)
+                   for kind in kinds) or any(
+                       kinds[first + i] != kinds[first + i % size]
+                       for i in range(size * turns)):
+                raise ValueError(
+                    "ffn_limits=%r, shared_limits=%r: a clamp is an expert "
+                    "layer's (a dense layer's limit is 0), and the turns of "
+                    "a scan (%d of a period of %d) cannot differ in a "
+                    "constant; scan_periods=false unrolls them"
+                    % (self.ffn_limits, self.shared_limits, turns, size))
         if pattern is None:
             return
         if len(pattern) != self.num_layers or set(pattern) - set("awcd"):
@@ -374,13 +440,19 @@ class TransformerConfig:
                 "and 1 <= conv_kernel <= %d; got %d, %d and %d"
                 % (short_conv.HALO + 1, self.delta_key_dim,
                    self.delta_value_dim, self.conv_kernel))
-        if "d" in pattern and (self.delta_kind == "kda") != bool(
-                self.delta_rank > 0):
+        if "d" in pattern and self.delta_kind != "kda" and (
+                self.delta_rank or self.delta_gate_floor):
             raise ValueError(
-                "delta_kind=%s and delta_rank=%d: the rank is the kda "
-                "layer's low-rank pairs' (decay, output gate) and theirs "
-                "alone, so each needs the other"
-                % (self.delta_kind, self.delta_rank))
+                "delta_kind=%s, delta_rank=%d, delta_gate_floor=%g: the "
+                "rank is the kda layer's low-rank pairs' (decay, output "
+                "gate; 0 = full projections) and the floor its decay "
+                "gate's: both need delta_kind=kda"
+                % (self.delta_kind, self.delta_rank, self.delta_gate_floor))
+        if self.delta_rank < 0 or self.delta_gate_floor > 0:
+            raise ValueError(
+                "delta_rank=%d, delta_gate_floor=%g: want a rank >= 0 and a "
+                "floor <= 0 (a log decay's)"
+                % (self.delta_rank, self.delta_gate_floor))
         if ("w" in pattern) != bool(self.window):
             raise ValueError(
                 "layer_pattern %r and window=%d: the window is the w "
@@ -419,17 +491,20 @@ class TransformerConfig:
     @property
     def kinds(self):
         """The Kind of every layer, in order."""
-        plan = stack_plan(self)
-        if plan is None:
-            return (_kind(self, "w" if self.window else "a",
-                          not self.moe_experts),) * self.num_layers
-        return plan.lead + plan.period * plan.periods + plan.tail
+        pattern = _pattern(self) or ("w" if self.window else "a") * (
+            self.num_layers)
+        routed, shared = (limits_of(text) or (0.0,) * len(pattern)
+                          for text in (self.ffn_limits, self.shared_limits))
+        return tuple(
+            _kind(self, letter, i < self.dense_layers or not self.moe_experts,
+                  routed[i], shared[i])
+            for i, letter in enumerate(pattern))
 
     @property
     def mtp_kind(self):
         """The Kind of a multi-token-prediction module's block: the
-        model's last layer's."""
-        return self.kinds[-1]
+        model's last layer's, without a clamp."""
+        return self.kinds[-1]._replace(limit=0.0, shared_limit=0.0)
 
     @property
     def stream_width(self):
@@ -452,6 +527,7 @@ _WORDS = {
     "attention_impl": ("ring", "ulysses"),
     "moe_router": ("softmax", "sigmoid_bias"),
     "delta_kind": ("gdn", "kda"),
+    "attn_gate": (False, True, "head"),
 }
 _WORDS_GONE = {
     "remat": ': "attn" and "dots" are gone; remat=true keeps the flash '
@@ -463,21 +539,35 @@ _WORDS_GONE = {
 # convolution | "d" gated delta rule), whether its FFN is dense (in an
 # MoE model, a leading
 # layer's) and, of an attention layer, the window it attends over (0:
-# the whole sequence) and whether RoPE turns its q and k.
-Kind = collections.namedtuple("Kind", "op dense window rope",
-                              defaults=(0, True))
+# the whole sequence) and whether RoPE turns its q and k; of a layer
+# with experts, the clamps of its routed and of its shared experts'
+# products (``ffn_limits``, ``shared_limits``; 0: none).
+Kind = collections.namedtuple(
+    "Kind", "op dense window rope limit shared_limit",
+    defaults=(0, True, 0.0, 0.0))
 # ``lead`` and ``tail``: the kinds of the layers before and after the
 # scan; ``period``: the kinds of one period; ``periods``: how many the
 # scan runs.
 StackPlan = collections.namedtuple("StackPlan", "lead period periods tail")
 
 
-def _kind(cfg, letter, dense):
+def _kind(cfg, letter, dense, limit=0.0, shared_limit=0.0):
     """The Kind a letter of ``layer_pattern`` names."""
     if letter in "cd":
-        return Kind(letter, dense)
+        return Kind(letter, dense, limit=limit, shared_limit=shared_limit)
     return Kind("a", dense, cfg.window if letter == "w" else 0,
-                letter in cfg.rope_kinds)
+                letter in cfg.rope_kinds, limit, shared_limit)
+
+
+def limits_of(text):
+    """The floats of an ``ffn_limits`` / ``shared_limits`` string, one a
+    layer; () for ""."""
+    try:
+        return tuple(float(x) for x in text.split(",")) if text else ()
+    except ValueError:
+        raise ValueError(
+            "%r: want limits as \"L0,L1,..\", one number a layer, e.g. "
+            "\"0,4,4\"" % (text,)) from None
 
 
 def _letter(kind):
@@ -500,16 +590,15 @@ def stack_plan(cfg):
     pattern = _pattern(cfg)
     if pattern is None:
         return None
-    dense = not cfg.moe_experts
-    lead = tuple(_kind(cfg, op, True) for op in pattern[:cfg.dense_layers])
-    rest = pattern[cfg.dense_layers:]
+    kinds = cfg.kinds
+    lead = cfg.dense_layers
+    rest = pattern[lead:]
     size = next((p for p in range(1, len(rest) + 1)
                  if all(rest[i] == rest[i % p] for i in range(len(rest)))),
                 1) if cfg.scan_periods else max(len(rest), 1)
     periods = len(rest) // size
-    return StackPlan(
-        lead, tuple(_kind(cfg, op, dense) for op in rest[:size]), periods,
-        tuple(_kind(cfg, op, dense) for op in rest[periods * size:]))
+    return StackPlan(kinds[:lead], kinds[lead:lead + size], periods,
+                     kinds[lead + periods * size:])
 
 
 def _one_kind(cfg):
@@ -548,7 +637,8 @@ _CANNOT = {
         "a block with norms on its sublayers' outputs (post_norms="
         "{cfg.post_norms}: ln1_post, ln2_post; pre_norms={cfg.pre_norms}"
         ": ln1, ln2) or a gate on attention's "
-        "output (attn_gate={cfg.attn_gate}: w_attn_gate)",
+        "output (attn_gate={cfg.attn_gate}: w_attn_gate, a value's or a "
+        "head's)",
         "decoding restates the block for one position (_decode_layer) "
         "without them; the pipeline's stages run the block itself, but "
         "their weights lie on a mesh, which has no spec for the three"),
@@ -563,6 +653,16 @@ _CANNOT = {
         "windowed layer (w) beside full ones a K/V cache that keeps its "
         "last `window` positions, a mesh specs for the weights of lead, "
         "period and tail, and the pipeline a split of them into stages"),
+    "route": (
+        lambda cfg: cfg.moe_groups or cfg.ffn_limits or cfg.shared_limits,
+        "a router limited to groups (moe_groups={cfg.moe_groups}, "
+        "moe_top_groups={cfg.moe_top_groups}) or a clamp a layer on the "
+        "experts' products (ffn_limits={cfg.ffn_limits!r}, shared_limits="
+        "{cfg.shared_limits!r})",
+        "decoding restates the block for one position (_decode_layer) "
+        "with one FFN for every layer; a mesh's ragged_dot path and the "
+        "pipeline's stages scan one layer body, which has no limit a "
+        "layer, and neither was held to the group choice"),
     "hyper": (
         lambda cfg: cfg.hyper_streams,
         "a residual stream {cfg.hyper_streams} wide (hyper_streams: hc1_*, "
@@ -585,7 +685,7 @@ _CANNOT = {
         "a model-parallel mesh shards all the experts over ep"),
 }
 # what decoding and the pipelined forward cannot run
-_TRAINS_ONLY = ("latent", "block", "stack", "hyper", "mtp")
+_TRAINS_ONLY = ("latent", "block", "stack", "route", "hyper", "mtp")
 
 
 def _refuse(cfg, what, *features):
@@ -621,9 +721,10 @@ def _init_layers(k_attn, k_mlp, cfg, kind, stack):
     if cfg.post_norms:
         layers.update(ln1_post=_norm_init(*stack, E),
                       ln2_post=_norm_init(*stack, E))
-    if kind.op == "a" and cfg.attn_gate:
+    if kind.op == "a" and cfg.attn_gate:   # a gate a value | a head
         layers["w_attn_gate"] = _dense_init(
-            jax.random.fold_in(k_attn, 6), *stack, E, H * D)
+            jax.random.fold_in(k_attn, 6), *stack, E,
+            H if cfg.attn_gate == "head" else H * D)
     if kind.op == "a" and cfg.latent:
         rank, dn, dr, dv = cfg.latent
         if cfg.q_latent_rank:
@@ -721,15 +822,21 @@ def _init_hyper(key, cfg, stack, name, read_only=False):
 
 
 def _init_delta(key, cfg, stack):
-    """A "d" layer's mixer (``_delta_mix``).  ``A_log`` and ``dt_bias``
-    as the Gated DeltaNet layer of the flash-linear-attention library
-    draws them: a decay rate A uniform in (0, 16) a head, a step dt
-    log-uniform in (0.001, 0.1) behind an inverse softplus, so that at
-    a zero projection a head's decay ``exp(-A dt)`` lies in (0.2, 1).
-    A kda layer draws A in (1, 16) and a step a CHANNEL of the key, and
-    holds the two low-rank pairs in place of ``w_a`` and ``w_out_gate``
-    (the second of a pair drawn at ``rank ** -0.5``, so that the pair's
-    result is of the order the one projection's is)."""
+    """A "d" layer's mixer (``_delta_mix``, which states the forms).
+    ``A_log`` and ``dt_bias`` as the Gated DeltaNet layer of the
+    flash-linear-attention library draws them: a decay rate A uniform
+    in (0, 16) a head, a step dt log-uniform in (0.001, 0.1) behind an
+    inverse softplus, so that at a zero projection a head's decay
+    ``exp(-A dt)`` lies in (0.2, 1).  A kda layer draws A in (1, 16) and
+    a step a CHANNEL of the key; its decay and its output gate are
+    projected by the low-rank pairs ``w_a_down`` / ``w_a_up`` and
+    ``w_g_down`` / ``w_g_up`` + ``b_g`` (the second of a pair drawn at
+    ``rank ** -0.5``, so that the pair's result is of the order the one
+    projection's is) or, with ``cfg.delta_rank`` 0, by the full ``w_a``
+    [dim, heads * key_dim] and ``w_out_gate`` alone.  Under the floored
+    gate (``cfg.delta_gate_floor`` F) ``dt_bias`` is the b at which a
+    zero projection decays as the softplus gate does at the same draw,
+    ``F sigmoid(A b) = -A dt``."""
     E, H = cfg.dim, cfg.num_heads
     dk, dv = cfg.delta_key_dim, cfg.delta_value_dim
     kda, rank = cfg.delta_kind == "kda", cfg.delta_rank
@@ -738,6 +845,13 @@ def _init_delta(key, cfg, stack):
     dt = jnp.exp(jax.random.uniform(
         keys[5], (*stack, H * dk if kda else H), jnp.float32,
         np.log(1e-3), np.log(1e-1)))
+    A = jax.random.uniform(keys[4], (*stack, H), jnp.float32,
+                           1.0 if kda else 1e-3, 16.0)
+    dt_bias = dt + jnp.log(-jnp.expm1(-dt))
+    if cfg.delta_gate_floor:
+        rate = jnp.repeat(A, dk, axis=-1)      # a channel its head's
+        share = rate * dt / -cfg.delta_gate_floor
+        dt_bias = (jnp.log(share) - jnp.log1p(-share)) / rate
     layers = dict(
         # q, k and v of every head side by side: one product, one
         # convolution
@@ -745,14 +859,14 @@ def _init_delta(key, cfg, stack):
         delta_conv=_dense_init(keys[1], *stack, width, cfg.conv_kernel,
                                scale=cfg.conv_kernel ** -0.5),
         w_b=_dense_init(keys[3], *stack, E, H),
-        A_log=jnp.log(jax.random.uniform(
-            keys[4], (*stack, H), jnp.float32, 1.0 if kda else 1e-3, 16.0)),
-        dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
+        A_log=jnp.log(A),
+        dt_bias=dt_bias,
         o_norm=_norm_init(*stack, dv),
         wo=_dense_init(keys[7], *stack, H * dv, E))
-    if not kda:
-        layers.update(w_a=_dense_init(keys[2], *stack, E, H),
-                      w_out_gate=_dense_init(keys[6], *stack, E, H * dv))
+    if not (kda and rank):
+        layers.update(
+            w_a=_dense_init(keys[2], *stack, E, H * dk if kda else H),
+            w_out_gate=_dense_init(keys[6], *stack, E, H * dv))
         return layers
     down, up = jax.random.split(keys[2]), jax.random.split(keys[6])
     layers.update(
@@ -949,6 +1063,17 @@ def _rope_heads_first(x, positions, theta, scaling=""):
     return rotated.astype(x.dtype)
 
 
+def chosen_groups(biased, cfg):
+    """[B, T, groups] bool of the biased scores [B, T, X]: the
+    ``cfg.moe_top_groups`` groups a token may choose its experts in, a
+    group's score the sum of its two largest members'."""
+    groups = biased.reshape(*biased.shape[:-1], cfg.moe_groups, -1)
+    score = jax.lax.top_k(groups, 2)[0].sum(axis=-1)
+    best = jax.lax.top_k(score, cfg.moe_top_groups)[1]
+    return jax.nn.one_hot(best, cfg.moe_groups, dtype=jnp.bool_).any(
+        axis=-2)
+
+
 def moe_route(h, w_router, cfg, expert_bias=None):
     """(probs [B, T, X] float32, gates [B, T, K] float32, experts
     [B, T, K] int32).  The router's matmul and scores run in float32
@@ -956,7 +1081,9 @@ def moe_route(h, w_router, cfg, expert_bias=None):
     multiply-adds a token), so that near-ties alone can change which
     experts a token gets.  ``cfg.moe_router`` "sigmoid_bias":
     ``probs`` are the sigmoid scores, ``expert_bias`` [X] moves the
-    choice and not the weights, and no gradient reaches it."""
+    choice and not the weights, and no gradient reaches it; with
+    ``cfg.moe_groups`` the K are the largest inside the token's
+    ``chosen_groups`` (the others' scores count as -inf)."""
     logits = jnp.einsum(
         "bte,ex->btx", h.astype(jnp.float32),
         w_router.astype(jnp.float32),
@@ -965,9 +1092,13 @@ def moe_route(h, w_router, cfg, expert_bias=None):
     if cfg.moe_router == "sigmoid_bias":
         probs = checkpoint_name(jax.nn.sigmoid(logits),
                                 remat_keep.KEEP_ROUTE)
-        experts = checkpoint_name(jax.lax.top_k(
-            probs + jax.lax.stop_gradient(expert_bias), top_k)[1],
-            remat_keep.KEEP_ROUTE)
+        biased = probs + jax.lax.stop_gradient(expert_bias)
+        if cfg.moe_groups:
+            allowed = jnp.repeat(chosen_groups(biased, cfg),
+                                 cfg.moe_experts // cfg.moe_groups, axis=-1)
+            biased = jnp.where(allowed, biased, -jnp.inf)
+        experts = checkpoint_name(jax.lax.top_k(biased, top_k)[1],
+                                  remat_keep.KEEP_ROUTE)
         gates = checkpoint_name(
             jnp.take_along_axis(probs, experts, axis=-1),
             remat_keep.KEEP_ROUTE)
@@ -988,11 +1119,12 @@ def moe_route(h, w_router, cfg, expert_bias=None):
     return probs, gates, experts
 
 
-def _moe_ffn(h, w, cfg, mesh, route=None):
+def _moe_ffn(h, w, cfg, mesh, route=None, limit=0.0):
     """Dropless top-k MoE FFN (expert weights sharded over ``ep``).
     ``route``: :func:`moe_route`'s three results where the block took
     them before its operator (``cfg.moe_route_before_op``); else the
-    router reads ``h``.
+    router reads ``h``.  ``limit``: the layer's clamp on the experts'
+    gate and up products (``Kind.limit``; 0: none).
 
     One dispatch (``ops/moe_dispatch.moe_experts``) with two ways to
     multiply, chosen by where the code runs: the Pallas grouped matmul,
@@ -1012,7 +1144,9 @@ def _moe_ffn(h, w, cfg, mesh, route=None):
     held experts' assignments alone, the padded rows, then what the
     dispatch measured of itself (``ops/moe_dispatch._moe_experts``):
     the rows its blocks moved, and how many of its shards ran more
-    than one block.
+    than one block; under a router limited to groups
+    (``cfg.moe_groups``) [held + 4], last the share of the tokens among
+    whose chosen groups is one with an expert held here.
     """
     B, T = h.shape[:2]
     X = cfg.moe_experts
@@ -1024,12 +1158,20 @@ def _moe_ffn(h, w, cfg, mesh, route=None):
     with kernels_off(mesh is not None):
         out, load = moe_experts(h, gates, experts, *weights,
                                 total=X, first=first,
-                                activation=cfg.ffn_activation)
+                                activation=cfg.ffn_activation, limit=limit)
     load = load.sum(axis=0).astype(jnp.float32)
     stats = jnp.stack([load[:X] / (B * T), probs.mean(axis=(0, 1))])
     aux = X * jnp.sum(stats[0] * stats[1])
     if held != X:
         load = jnp.concatenate([load[first:first + held], load[X:]])
+        if cfg.moe_groups:
+            # the group choice once more, the compiler's to share with
+            # the router's: which tokens could choose an expert held here
+            size = X // cfg.moe_groups
+            ours = slice(first // size, (first + held - 1) // size + 1)
+            hit = chosen_groups(probs + w["expert_bias"], cfg)[..., ours]
+            load = jnp.concatenate(
+                [load, hit.any(axis=-1).mean(dtype=jnp.float32)[None]])
     return out, aux, stats, load
 
 
@@ -1134,23 +1276,27 @@ def _project_latent(h, w, cfg, positions, rope=True):
 
 
 @functools.lru_cache(maxsize=None)
-def announce_latent(heads, seq, latent, mode, tile, why):
+def announce_latent(heads, seq, latent, mode, tile, why, gate=False):
     """Once per compiled shape and mode, by the logger ``announce_tiles``
-    uses: what latent attention runs as (``latent_mode``'s answer)."""
+    uses: what latent attention runs as (``latent_mode``'s answer), and
+    the gate on its output where it has one (``gate=head``)."""
     logger.info(
         "latent attention: heads=%d t=%d rank=%d qk_nope=%d qk_rope=%d "
-        "v=%d rope_key=shared tile=%d %s%s", heads, seq, *latent, tile,
+        "v=%d rope_key=shared%s tile=%d %s%s", heads, seq, *latent,
+        " gate=%s" % gate if gate else "", tile,
         {"tpu": "kernel", "interpret": "interpreter",
          "off": "reference"}[mode], " (%s)" % why if why else "")
 
 
 def _latent_mix(h, w, cfg, positions, kind):
     """LatentAttention(h) of the normed input, [B, T, dim]: the five
-    operands, the op, ``W_o``."""
+    operands, the op, with ``cfg.attn_gate`` (a gate a head: the one
+    form it takes) each head's output times the sigmoid of its gate, a
+    projection of ``h``, then ``W_o``."""
     compute_dtype = jnp.dtype(cfg.dtype)
     T = h.shape[1]
     announce_latent(cfg.num_heads, T, cfg.latent, *latent_mode(
-        T, *cfg.latent[1:], compute_dtype.itemsize))
+        T, *cfg.latent[1:], compute_dtype.itemsize), gate=cfg.attn_gate)
     scale = None
     if cfg.rope_scaling:
         scale = float((cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
@@ -1158,6 +1304,8 @@ def _latent_mix(h, w, cfg, positions, kind):
     attn = latent_attention(
         *_project_latent(h, w, cfg, positions, kind.rope), causal=True,
         scale=scale, window=kind.window)
+    if cfg.attn_gate:
+        attn = attn * jax.nn.sigmoid(_head_gate(h, w, cfg))
     # W_o contracts the kernels' [B, H, T, Dv] output over (head, width)
     # as it stands: no token-major copy of it is made first.  Reshaped
     # before the cast, so that the gradient stays [H, Dv, dim] through
@@ -1167,22 +1315,34 @@ def _latent_mix(h, w, cfg, positions, kind):
     return jnp.einsum("bhtk,hkd->btd", attn, wo)
 
 
-def _gated_mlp(h, w, cfg, weights, keep):
+def _head_gate(h, w, cfg):
+    """A gate a head on attention's output (``cfg.attn_gate`` "head"),
+    before its sigmoid: [B, H, T, 1], a projection of the normed input
+    in the layout of the kernels' output."""
+    gate = jnp.einsum("btd,dh->bht", h,
+                      w["w_attn_gate"].astype(jnp.dtype(cfg.dtype)))
+    return checkpoint_name(gate, remat_keep.KEEP_ATTN_GATE)[..., None]
+
+
+def _gated_mlp(h, w, cfg, weights, keep, limit=0.0):
     """``(act(h W_gate) * (h W_up)) W_down`` with the three ``weights``
-    named, the gate and up products named ``keep`` for a remat policy."""
+    named, the gate and up products named ``keep`` for a remat policy
+    and clamped where the layer has a ``limit``
+    (``ops/moe_dispatch.gated``)."""
     compute_dtype = jnp.dtype(cfg.dtype)
     gate, up, down = (w[name].astype(compute_dtype) for name in weights)
     gate = checkpoint_name(h @ gate, keep[0])
     up = checkpoint_name(h @ up, keep[1])
-    return (ACTIVATIONS[cfg.ffn_activation](gate) * up) @ down
+    return gated(cfg.ffn_activation, gate, up, limit) @ down
 
 
-def _shared_expert(h, w, cfg):
+def _shared_expert(h, w, cfg, limit=0.0):
     """The always-on shared expert of an expert layer: one gated MLP of
-    ``cfg.shared_dim`` on the FFN's normed input."""
+    ``cfg.shared_dim`` on the FFN's normed input (``limit``:
+    ``Kind.shared_limit``)."""
     return _gated_mlp(h, w, cfg, ("ws_gate", "ws_up", "ws_down"),
                       (remat_keep.KEEP_SHARED_GATE,
-                       remat_keep.KEEP_SHARED_UP))
+                       remat_keep.KEEP_SHARED_UP), limit)
 
 
 def _read(x, w, cfg, name):
@@ -1225,17 +1385,18 @@ def _pre(x, w, cfg, name):
     return _rmsnorm(x, w[name].astype(jnp.dtype(cfg.dtype)), cfg.norm_eps)
 
 
-def _ffn(x, w, cfg, mesh, dense=False, route=None):
+def _ffn(x, w, cfg, mesh, dense=False, route=None, limits=(0.0, 0.0)):
     """x + post(FFN(norm(x))) -> (x, aux, stats, load); the last three
     are the MoE's (:func:`_moe_ffn`, which ``route`` is for), zeros and
     None for a dense FFN (a model without experts, or a ``dense`` layer
-    of one with)."""
+    of one with).  ``limits``: the layer's clamps, of its routed and of
+    its shared experts."""
     u, x, maps = _read(x, w, cfg, "hc2")
     h = _pre(u, w, cfg, "ln2")
     if cfg.moe_experts and not dense:
-        out, aux, stats, load = _moe_ffn(h, w, cfg, mesh, route)
+        out, aux, stats, load = _moe_ffn(h, w, cfg, mesh, route, limits[0])
         if cfg.shared_dim:
-            out = out + _shared_expert(h, w, cfg)
+            out = out + _shared_expert(h, w, cfg, limits[1])
     else:
         out = _gated_mlp(h, w, cfg, ("w_gate", "w_up", "w_down"),
                          (remat_keep.KEEP_GATE, remat_keep.KEEP_UP))
@@ -1260,10 +1421,11 @@ def announce_attention(cfg, rows, repeated):
     repeat = 2 * more * rows * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize
     logger.info(
         "attention block: rows=%d heads=%d kv_heads=%d head_dim=%d "
-        "qk_norm=%s gate=%d out_norms=%d embed_multiplier=%g layers=%d "
+        "qk_norm=%s gate=%s out_norms=%d embed_multiplier=%g layers=%d "
         "kv_repeat_bytes=%d kv_repeat_again_bytes=%d", rows,
         cfg.num_heads, cfg.kv_heads, cfg.head_dim, cfg.qk_norm,
-        cfg.attn_gate, cfg.post_norms, cfg.embed_multiplier,
+        cfg.attn_gate if cfg.attn_gate == "head" else int(cfg.attn_gate),
+        cfg.post_norms, cfg.embed_multiplier,
         sum(kind.op == "a" for kind in cfg.kinds), 2 * repeat,
         repeat if cfg.remat else 0)
 
@@ -1281,7 +1443,8 @@ def _attention_mix(h, w, cfg, mesh, positions, kind):
     token-major and at the query heads, so its edge transposes and
     repeats.  With ``cfg.attn_gate`` each value of the kernel's output
     is multiplied by the sigmoid of its own gate, a projection of
-    ``h``, before ``wo``."""
+    ``h``, before ``wo`` (``"head"``: a head's values by its one
+    gate's)."""
     compute_dtype = jnp.dtype(cfg.dtype)
     B, T = h.shape[0], h.shape[1]
     H, D = cfg.num_heads, cfg.head_dim
@@ -1306,7 +1469,9 @@ def _attention_mix(h, w, cfg, mesh, positions, kind):
                     for x in (k, v))
         attn = sharded(q, k, v, mesh, causal=True,
                        window=kind.window).transpose(0, 2, 1, 3)
-    if cfg.attn_gate:
+    if cfg.attn_gate == "head":
+        attn = attn * jax.nn.sigmoid(_head_gate(h, w, cfg))
+    elif cfg.attn_gate:
         gate = checkpoint_name(
             _heads_first(h, w["w_attn_gate"].astype(compute_dtype).reshape(
                 -1, H, D)), remat_keep.KEEP_ATTN_GATE)
@@ -1335,14 +1500,18 @@ def announce_delta(cfg, rows, chunk, kept, inverse, mode, why):
     ``kept`` from the forward or made again by the second forward of a
     rematerialized layer, and how the backward gets a chunk's inverse:
     from the forward kernel, ``inverse`` bytes a layer, or by
-    differentiating the jnp twin."""
+    differentiating the jnp twin.  ``decay=`` names a kda layer's
+    projections (``rank=0``: full) and its gate (``gate=softplus`` |
+    ``gate=floor<F>``)."""
     logger.info(
         "delta scan: rows=%d heads=%d key_dim=%d value_dim=%d chunk=%d "
         "conv_taps=%d neg_eigval=%d decay=%s states=%s inverse=%s %s%s",
         rows, cfg.num_heads, cfg.delta_key_dim, cfg.delta_value_dim, chunk,
         cfg.conv_kernel, cfg.delta_neg_eigval,
-        "channel rank=%d" % cfg.delta_rank if cfg.delta_kind == "kda"
-        else "head",
+        "channel rank=%d gate=%s" % (
+            cfg.delta_rank, "floor%g" % cfg.delta_gate_floor
+            if cfg.delta_gate_floor else "softplus")
+        if cfg.delta_kind == "kda" else "head",
         "kept" if kept else "recomputed",
         "twin" if mode == "off" else "forward inverse_mb=%.1f" % (
             inverse / 1e6),
@@ -1356,22 +1525,28 @@ def _l2norm(x, eps=1e-6):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
 
-def _delta_mix(h, w, cfg):
-    """GatedDeltaNet(h) of the block's input, [B, T, dim]: the operator
-    of a "d" layer.  q, k and v of every head are one projection, pass
-    one causal convolution and a SiLU (``ops/short_conv.conv_silu``),
-    then q and k an L2 norm a head (q also ``key_dim ** -0.5``); the
-    write strength ``beta`` is a sigmoid a head (times 2 with
-    ``cfg.delta_neg_eigval``) and the log decay ``g = -exp(A_log) *
-    softplus(h W_a + dt_bias)``, both float32; the scan is
-    ``ops/gated_delta.py``'s, which picks kernel or reference; its
-    output takes an RMSNorm over each head's values (one scale the
-    heads share) times the SiLU of a gate projected from ``h``, and
-    ``wo`` contracts (head, width) where the output stands.  A kda
-    layer (``cfg.delta_kind``): the log decay a CHANNEL of the key, ``g =
-    -exp(A_log[head]) * softplus((h W_a_down) W_a_up + dt_bias)`` [B, H,
-    T, key_dim], and the gate a sigmoid of ``(h W_g_down) W_g_up +
-    b_g``."""
+def _delta_mix(h, w, cfg, with_excess=False):
+    """GatedDeltaNet(h) of the block's input, [B, T, dim] (with
+    ``with_excess`` also how far under ``cfg.delta_gate_floor`` the
+    lowest log decay lies): the operator of a "d" layer.  q, k and v of
+    every head are one projection, pass one causal convolution and a
+    SiLU (``ops/short_conv.conv_silu``), then q and k an L2 norm a head
+    (q also ``key_dim ** -0.5``); the write strength ``beta`` is a
+    sigmoid a head (times 2 with ``cfg.delta_neg_eigval``) and the log
+    decay ``g = -exp(A_log) * softplus(h W_a + dt_bias)``, both float32;
+    the scan is ``ops/gated_delta.py``'s, which picks kernel or
+    reference; its output takes an RMSNorm over each head's values (one
+    scale the heads share) times the SiLU of a gate projected from
+    ``h``, and ``wo`` contracts (head, width) where the output stands.
+
+    A kda layer (``cfg.delta_kind``): the log decay a CHANNEL of the
+    key, [B, H, T, key_dim], and the gate a sigmoid.  Its two
+    projections are low-rank pairs, ``a = (h W_a_down) W_a_up`` and
+    ``(h W_g_down) W_g_up + b_g`` (``cfg.delta_rank`` > 0) | full, ``a
+    = h W_a`` and ``h W_out_gate`` with no bias (rank 0); its decay
+    gate ``g = -exp(A_log[head]) * softplus(a + dt_bias)`` | floored,
+    ``g = F * sigmoid(exp(A_log[head]) * (a + dt_bias))`` in (F, 0)
+    (``cfg.delta_gate_floor`` F < 0)."""
     compute_dtype = jnp.dtype(cfg.dtype)
     B, T, _ = h.shape
     H, dk, dv = cfg.num_heads, cfg.delta_key_dim, cfg.delta_value_dim
@@ -1400,49 +1575,66 @@ def _delta_mix(h, w, cfg):
         checkpoint_name(h @ w[name + "_down"].astype(compute_dtype),
                         remat_keep.KEEP_DELTA_RANK),
         w[name + "_up"].astype(compute_dtype).reshape(-1, H, width))
+    full = lambda name, width: _heads_first(
+        h, w[name].astype(compute_dtype).reshape(-1, H, width))
+    pairs = kda and cfg.delta_rank
     # [B, H, T] float32 each, from the compute dtype's products
     a, b = (jnp.einsum("btd,dh->bht", h, w[name].astype(
-        compute_dtype)).astype(jnp.float32) if name in w else None
+        compute_dtype)).astype(jnp.float32)
+        if name in w and not (kda and name == "w_a") else None
         for name in ("w_a", "w_b"))
     per_head = lambda x: x.astype(jnp.float32)[None, :, None]
     beta = jax.nn.sigmoid(b) * (2.0 if cfg.delta_neg_eigval else 1.0)
     if kda:     # [B, H, T, key_dim]: a channel its own step and bias
-        g = -jnp.exp(per_head(w["A_log"]))[..., None] * jax.nn.softplus(
-            low_rank("w_a", dk).astype(jnp.float32)
-            + w["dt_bias"].astype(jnp.float32).reshape(H, 1, dk))
+        # (called where they stand: the softplus gate traces its
+        # operations in the order it always did, and lowers to the text
+        # it did)
+        rate = lambda: jnp.exp(per_head(w["A_log"]))[..., None]
+        step = lambda: (low_rank if pairs else full)("w_a", dk).astype(
+            jnp.float32) + w["dt_bias"].astype(jnp.float32).reshape(H, 1, dk)
+        floor = cfg.delta_gate_floor
+        if floor:
+            g = floor * jax.nn.sigmoid(rate() * step())
+        else:
+            g = -rate() * jax.nn.softplus(step())
     else:
         g = -jnp.exp(per_head(w["A_log"])) * jax.nn.softplus(
             a + per_head(w["dt_bias"]))
+    if with_excess:
+        excess = jnp.maximum(0.0, cfg.delta_gate_floor - g.min())
     g, beta = (checkpoint_name(x, remat_keep.KEEP_DELTA_DECAY)
                for x in (g, beta))
     o = gated_delta.gated_delta(q, k, v, g, beta)
-    if kda:
+    if pairs:
         gate = checkpoint_name(low_rank("w_g", dv),
                                remat_keep.KEEP_DELTA_GATE)
         gate = gate + w["b_g"].astype(compute_dtype).reshape(H, 1, dv)
     else:
-        gate = checkpoint_name(
-            _heads_first(h, w["w_out_gate"].astype(compute_dtype).reshape(
-                -1, H, dv)), remat_keep.KEEP_DELTA_GATE)
+        gate = checkpoint_name(full("w_out_gate", dv),
+                               remat_keep.KEEP_DELTA_GATE)
     o = _rmsnorm(o, w["o_norm"].astype(compute_dtype), cfg.norm_eps) * (
         jax.nn.sigmoid(gate) if kda else jax.nn.silu(gate))
     # as ``_latent_mix``: reshaped before the cast
     wo = w["wo"].reshape(H, dv, cfg.dim).astype(compute_dtype)
-    return jnp.einsum("bhtk,hkd->btd", o, wo)
+    out = jnp.einsum("bhtk,hkd->btd", o, wo)
+    return (out, excess) if with_excess else out
 
 
 def _operator(x, w, cfg, mesh, positions, kind):
-    """x + post(Op(norm(x))) -> (x, (k, v) or None), ``Op`` the
-    operator of ``kind``: attention, latent attention (nothing cached:
-    decoding refuses it), the short convolution or the gated delta
-    rule."""
+    """x + post(Op(norm(x))) -> (x, what the operator hands on beside
+    it: attention's (k, v); a floored kda layer's gate excess
+    (``_delta_mix``); else None), ``Op`` the operator of ``kind``:
+    attention, latent attention (nothing cached: decoding refuses it),
+    the short convolution or the gated delta rule."""
     u, x, maps = _read(x, w, cfg, "hc1")
     h = _pre(u, w, cfg, "ln1")
     kv_out = None
     if kind.op == "c":
         out = _conv_mix(h, w, cfg)
     elif kind.op == "d":
-        out = _delta_mix(h, w, cfg)
+        out = _delta_mix(h, w, cfg, bool(cfg.delta_gate_floor))
+        if cfg.delta_gate_floor:
+            out, kv_out = out
     elif cfg.latent:
         if mesh is not None:
             _refuse(cfg, "a model-parallel mesh", "latent")
@@ -1476,7 +1668,9 @@ def _layer_body(x, w, cfg, mesh, positions, moe_stats=False,
     accumulation); ``moe_load`` returns (aux, load [X + 1]) for an MoE
     (the step statistics).  ``return_kv`` additionally returns this
     layer's (k, v) — the decode prefill captures them into the KV
-    cache.  Which kernels run is not its business: the ops ask
+    cache.  In a model with ``cfg.delta_gate_floor`` what it returns
+    beside x is (that, the layer's gate excess).  Which kernels run is
+    not its business: the ops ask
     ``ops/mode.py``, and a caller that traces it where none can run
     says so with ``kernels_off()``."""
     kind = kind or _one_kind(cfg)
@@ -1490,13 +1684,18 @@ def _layer_body(x, w, cfg, mesh, positions, moe_stats=False,
             w["w_router"], cfg, w.get("expert_bias"))
     x, kv_out = _operator(x, w, cfg, mesh, positions, kind)
     x, aux, stats, load = _ffn(x, w, cfg, mesh, dense=kind.dense,
-                               route=route)
+                               route=route,
+                               limits=(kind.limit, kind.shared_limit))
     if moe_stats and not kind.dense:
         aux = stats
     elif moe_load and not kind.dense:
         aux = (aux, load)
     if return_kv:
         return x, (aux, kv_out)
+    if cfg.delta_gate_floor:
+        # every layer of a model with a floored gate hands on how far
+        # under the floor its decays went: 0 for a layer that has none
+        aux = (aux, kv_out if kind.op == "d" else jnp.float32(0.0))
     return x, aux
 
 
@@ -1646,6 +1845,8 @@ def _forward_stack(params, tokens, cfg, mesh=None, with_load=False,
     "aux": each layer's MoE aux [L_moe] (a zero where none has experts);
     "load": each such layer's load (``_moe_ffn``) with ``with_load``;
     "hc_err": on a wide stream the largest Sinkhorn error of the step;
+    "gate_excess": under ``cfg.delta_gate_floor`` how far under it the
+    step's lowest log decay lies (``_delta_mix``; 0 by construction);
     "mtp_hidden": with ``with_mtp`` each multi-token-prediction
     module's hidden state, its block's aux and load joined to the
     stack's}."""
@@ -1681,10 +1882,11 @@ def _forward_stack(params, tokens, cfg, mesh=None, with_load=False,
     out = {"mtp_hidden": []}
     with remat_keep.keeping(names if cfg.remat and plan is not None
                             else ()):
+        excess = None
         if plan is None:
             x, seen = jax.lax.scan(block(), x, layers)
         else:
-            x, seen = _mixed_stack(x, layers, cfg, plan, block)
+            x, seen, excess = _mixed_stack(x, layers, cfg, plan, block)
         hidden, err = _narrow(x, params, cfg)
         out["hidden"] = hidden
         for k in range(len(modules or ())):
@@ -1693,11 +1895,14 @@ def _forward_stack(params, tokens, cfg, mesh=None, with_load=False,
             out["mtp_hidden"].append(hidden)
             if off is not None:
                 err = jnp.maximum(err, off)
+            if excess is not None:
+                more, under = more
+                excess = jnp.maximum(excess, under)
             if not cfg.mtp_kind.dense:
                 seen = jax.tree_util.tree_map(
                     lambda a, b: jnp.concatenate([a, b[None]]), seen, more)
     out["aux"], out["load"] = seen if with_load else (seen, None)
-    out["hc_err"] = err
+    out["hc_err"], out["gate_excess"] = err, excess
     return out
 
 
@@ -1727,9 +1932,13 @@ def announce_stack(pattern, plan, experts, shared=0, heads=None,
     expert layer's always-on shared expert, said where there is one;
     ``heads``: (held here, of how many) where chips share a layer's
     heads; ``more``: the fields of a wide stream, multi-token
-    prediction and a query latent, where the model has them)."""
+    prediction, a query latent, a gate a head, a router's groups
+    (chosen/all), a kda layer's full projections, its gate's floor and
+    the clamps a layer, where the model has them)."""
     letters = lambda kinds: "".join(map(_letter, kinds)) or "-"
-    kinds = sorted(set(k for k in plan.lead + plan.period + plan.tail
+    # an attention kind once, whatever its layer's FFN and clamps
+    kinds = sorted(set(k._replace(limit=0.0, shared_limit=0.0)
+                       for k in plan.lead + plan.period + plan.tail
                        if k.op == "a"), key=_letter)
     logger.info(
         "layer stack: pattern=%s lead=%s period=%s periods=%d tail=%s "
@@ -1746,42 +1955,61 @@ def _mixed_stack(x, layers, cfg, plan, block):
     """The leading layers, the whole periods under one scan (a period's
     layers unrolled in its body), the remainder -> (x, what the layers
     with experts returned beside it, stacked in layer order: aux [L_moe]
-    or (aux [L_moe], load [L_moe, ..]); a zero where none has experts)."""
+    or (aux [L_moe], load [L_moe, ..]); a zero where none has experts,
+    the largest gate excess of a layer: None without
+    ``cfg.delta_gate_floor``)."""
     announce_stack("".join(map(_letter, cfg.kinds)), plan,
                    (cfg.experts_held[1], cfg.moe_experts), cfg.shared_dim,
                    (cfg.num_heads, cfg.num_heads * cfg.head_shares)
                    if cfg.head_shares > 1 else None,
-                   "".join(" %s=%d" % (name, value) for name, value in (
+                   "".join(" %s=%s" % (name, value) for name, value in (
                        ("hyper", cfg.hyper_streams),
                        ("sinkhorn", cfg.hyper_streams
                         and cfg.hyper_sinkhorn_iters),
                        ("mtp", cfg.mtp_modules),
-                       ("q_latent", cfg.q_latent_rank)) if value))
+                       ("q_latent", cfg.q_latent_rank),
+                       ("attn_gate", cfg.attn_gate == "head" and "head"),
+                       ("route_groups", cfg.moe_groups and "%d/%d" % (
+                           cfg.moe_top_groups, cfg.moe_groups)),
+                       ("kda_rank", "d" in cfg.layer_pattern
+                        and cfg.delta_kind == "kda" and not cfg.delta_rank
+                        and "full"),
+                       ("gate_floor", cfg.delta_gate_floor),
+                       ("ffn_limits", cfg.ffn_limits),
+                       ("shared_limits", cfg.shared_limits)) if value))
 
     tree_map = jax.tree_util.tree_map
+    floored = bool(cfg.delta_gate_floor)
 
     def run(x, kinds, weights):
-        """(x, what the layers with experts returned, stacked; None
-        where none has)."""
-        seen = []
+        """(x, (what the layers with experts returned, stacked; None
+        where none has, the layers' largest gate excess or None))."""
+        seen, off = [], jnp.float32(0.0) if floored else None
         for i, kind in enumerate(kinds):
             x, out = block(kind)(x, weights[str(i)])
+            if floored:
+                out, excess = out
+                off = jnp.maximum(off, excess)
             if not kind.dense:
                 seen.append(out)
-        return x, tree_map(lambda *a: jnp.stack(a), *seen) if seen else None
+        return x, (tree_map(lambda *a: jnp.stack(a), *seen) if seen
+                   else None, off)
 
-    x, lead = run(x, plan.lead, layers["lead"])
+    x, (lead, off) = run(x, plan.lead, layers["lead"])
     period = None
     if plan.periods:
-        x, period = jax.lax.scan(
+        x, (period, offs) = jax.lax.scan(
             lambda x, w: run(x, plan.period, w), x, layers["period"])
         # [periods, positions, ..] -> [periods * positions, ..]
         period = tree_map(lambda a: a.reshape((-1,) + a.shape[2:]), period)
-    x, tail = run(x, plan.tail, layers["tail"])
+    x, (tail, last) = run(x, plan.tail, layers["tail"])
+    if floored:
+        off = jnp.maximum(jnp.maximum(off, last),
+                          offs.max() if plan.periods else 0.0)
     parts = [part for part in (lead, period, tail) if part is not None]
     if not parts:
-        return x, jnp.zeros((1,), jnp.float32)
-    return x, tree_map(lambda *a: jnp.concatenate(a), *parts)
+        return x, jnp.zeros((1,), jnp.float32), off
+    return x, tree_map(lambda *a: jnp.concatenate(a), *parts), off
 
 
 def forward(params, tokens, cfg, mesh=None, return_aux=False):
@@ -2147,7 +2375,11 @@ def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
     with a share of the experts, the held experts' alone,
     ``moe_moved`` [L], the rows each layer's dispatch moved (its bound
     times the blocks that ran), and ``moe_spilled`` [L], the shards on
-    which it ran more than one.
+    which it ran more than one; under a router limited to groups
+    ``moe_group_hit`` [L], the share of the tokens whose chosen groups
+    reach an expert held here; with a floored kda gate
+    ``kda_gate_excess``, how far under the floor the step's lowest log
+    decay lies (0 by construction).
 
     ``warmup_steps`` > 0 raises AdamW's rate from 0 to ``learning_rate``
     linearly over that many steps (0: constant, as ever).  Adam's steps
@@ -2203,7 +2435,8 @@ def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
         return params
 
     moe = not all(kind.dense for kind in cfg.kinds)   # a layer has experts
-    wide = bool(cfg.hyper_streams or cfg.mtp_modules)
+    wide = bool(cfg.hyper_streams or cfg.mtp_modules
+                or cfg.delta_gate_floor)
     if cfg.mtp_modules and (xent_chunk or pipelined):
         raise ValueError(
             "mtp_modules=%d: the modules' loss is ops/head_loss.py's at a "
@@ -2222,8 +2455,9 @@ def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
             return forward(params, tokens, cfg, mesh=mesh)
         out = {"params": params}
         if wide:
-            # a wide stream's Sinkhorn error and the modules' hidden
-            # states leave the stack beside the model's
+            # a wide stream's Sinkhorn error, the modules' hidden states
+            # and a floored gate's excess leave the stack beside the
+            # model's
             out.update(_forward_stack(params, tokens, cfg, mesh, moe,
                                       with_mtp=True))
             out["aux"] = out["aux"].mean()
@@ -2268,12 +2502,17 @@ def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
             stats["hc_err"] = outputs["hc_err"]
         if "mtp_loss" in outputs:
             stats["mtp_loss"] = outputs["mtp_loss"].mean()
+        if outputs.get("gate_excess") is not None:
+            stats["kda_gate_excess"] = outputs["gate_excess"]
         if not moe:
             return stats
         load = outputs["moe_load"]
         if not cfg.moe_experts_held:
             return dict(stats, moe_load=load)
-        # a share's dispatch counts the rows it moved and its spills
+        # a share's dispatch counts the rows it moved and its spills,
+        # and under a group limit its layer the tokens that could reach it
+        if cfg.moe_groups:
+            stats["moe_group_hit"], load = load[:, -1], load[:, :-1]
         return dict(stats, moe_load=load[:, :-2], moe_moved=load[:, -2],
                     moe_spilled=load[:, -1])
 

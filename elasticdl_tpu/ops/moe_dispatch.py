@@ -56,6 +56,15 @@ BOUND_FACTOR = 2
 ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
+def gated(activation, gate, up, limit=0.0):
+    """``act(gate) * up`` of a gated MLP's two products; with a
+    ``limit`` L > 0 the clamped form, ``act(min(gate, L)) * clip(up,
+    -L, L)``: no gradient reaches a value past its bound."""
+    if limit:
+        gate, up = jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
+    return ACTIVATIONS[activation](gate) * up
+
+
 def row_bound(rows, held, total):
     """Rows of every buffer of a dispatch that holds ``held`` of the
     ``total`` experts its ``rows`` assignments are routed over:
@@ -225,8 +234,9 @@ def held_positions(flat, sizes):
     return jnp.where(flat < held, at, -1).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2))
-def _block(i, c, activation, x, gates, weights, order, sizes, pos=None):
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _block(i, c, activation, limit, x, gates, weights, order, sizes,
+           pos=None):
     """Float32 [n, e]: what the sorted rows ``i * c .. (i + 1) * c`` add
     to the tokens' results.  x [n, e], gates [n, k], order [blocks * c]
     (the sort, padded with n * k: no assignment), sizes [held] the held
@@ -261,7 +271,7 @@ def _block(i, c, activation, x, gates, weights, order, sizes, pos=None):
     gate = checkpoint_name(matmul(xs, w_gate, sizes), KEEP_GATE)
     up = checkpoint_name(matmul(xs, w_up, sizes), KEEP_UP)
     ys = checkpoint_name(
-        matmul(ACTIVATIONS[activation](gate) * up, w_down, sizes),
+        matmul(gated(activation, gate, up, limit), w_down, sizes),
         KEEP_OUT)
     if pos is not None:
         return _sum_rows(n, ys, tok, pos, live, claims, gates, tokens)
@@ -274,9 +284,9 @@ def _blocks(c, sizes):
     return -(-sizes.sum() // c)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _further_blocks(c, activation, out, x, gates, weights, order, sizes,
-                    pos):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _further_blocks(c, activation, limit, out, x, gates, weights, order,
+                    sizes, pos):
     """``out`` + every block after the first that holds a held expert's
     row: a loop whose trip count is the data's (none at the balance the
     bound was taken from, all of them under a router collapsed onto the
@@ -284,24 +294,25 @@ def _further_blocks(c, activation, out, x, gates, weights, order, sizes,
     pullback, the block's forward run again: a block keeps nothing."""
     return lax.fori_loop(
         jnp.int32(1), _blocks(c, sizes),
-        lambda i, out: out + _block(i, c, activation, x, gates, weights,
-                                    order, sizes, pos),
+        lambda i, out: out + _block(i, c, activation, limit, x, gates,
+                                    weights, order, sizes, pos),
         out)
 
 
-def _further_blocks_fwd(c, activation, out, x, gates, weights, order,
-                        sizes, pos):
-    return (_further_blocks(c, activation, out, x, gates, weights, order,
-                            sizes, pos),
+def _further_blocks_fwd(c, activation, limit, out, x, gates, weights,
+                        order, sizes, pos):
+    return (_further_blocks(c, activation, limit, out, x, gates, weights,
+                            order, sizes, pos),
             (x, gates, weights, order, sizes, pos))
 
 
-def _further_blocks_bwd(c, activation, res, g):
+def _further_blocks_bwd(c, activation, limit, res, g):
     *operands, order, sizes, pos = res
 
     def add_block(i, grads):
         pull = jax.vjp(lambda *operands: _block(
-            i, c, activation, *operands, order, sizes, pos), *operands)[1]
+            i, c, activation, limit, *operands, order, sizes, pos),
+            *operands)[1]
         return jax.tree_util.tree_map(jnp.add, grads, pull(g))
 
     grads = lax.fori_loop(
@@ -314,7 +325,7 @@ _further_blocks.defvjp(_further_blocks_fwd, _further_blocks_bwd)
 
 
 def _moe_experts(h, gates, experts, w_gate, w_up, w_down, total=None,
-                 first=0, activation="silu"):
+                 first=0, activation="silu", limit=0.0):
     """The routed FFN of the rows this device holds: sort the n * K
     (token, choice) assignments by expert, gather their rows, three
     grouped matmuls, un-sort and sum each token's K results weighted by
@@ -366,8 +377,9 @@ def _moe_experts(h, gates, experts, w_gate, w_up, w_down, total=None,
         operands = (h.reshape(n, e), gates.reshape(n, k),
                     (w_gate, w_up, w_down), order, sizes, pos)
         out = _further_blocks(
-            bound, activation,
-            _block(jnp.int32(0), bound, activation, *operands), *operands)
+            bound, activation, limit,
+            _block(jnp.int32(0), bound, activation, limit, *operands),
+            *operands)
         blocks = jnp.maximum(_blocks(bound, sizes), 1)
         load = jnp.concatenate([counted, jnp.stack(
             [padded, blocks * bound, (blocks > 1).astype(jnp.int32)])])
@@ -376,7 +388,7 @@ def _moe_experts(h, gates, experts, w_gate, w_up, w_down, total=None,
         _take_rows(k, h.reshape(n, e), order, inverse), KEEP_ROWS)
     gate = checkpoint_name(gm.grouped_matmul(xs, w_gate, sizes), KEEP_GATE)
     up = checkpoint_name(gm.grouped_matmul(xs, w_up, sizes), KEEP_UP)
-    act = ACTIVATIONS[activation](gate) * up
+    act = gated(activation, gate, up, limit)
     ys = checkpoint_name(_take_rows(
         1, gm.grouped_matmul(act, w_down, sizes), inverse, order), KEEP_OUT)
     out = jnp.einsum("nke,nk->ne", ys.reshape(n, k, e).astype(jnp.float32),
@@ -407,16 +419,17 @@ def announce_dispatch(tokens, experts, top_k, kernel, share=None,
 
 
 def moe_experts(h, gates, experts, w_gate, w_up, w_down, total=None,
-                first=0, activation="silu"):
+                first=0, activation="silu", limit=0.0):
     """h [B, T, E], gates and experts [B, T, K], the three expert
     weights [X, ...] in h's dtype (or the share ``first .. first + X``
     of ``total`` experts: ``_moe_experts``), the gate's ``activation``
-    (a name of ``ACTIVATIONS``) -> (out [B, T, E], load
+    (a name of ``ACTIVATIONS``) and the layer's clamp on the two
+    products (``gated``'s ``limit``) -> (out [B, T, E], load
     [shards, total + 1], with a share [shards, total + 3]).  Where a
     kernel runs, once per shard of the declared batch axis (weights
     whole on each); the reference partitions by itself."""
     fn = functools.partial(_moe_experts, total=total, first=first,
-                           activation=activation)
+                           activation=activation, limit=limit)
     if kernel_mode() == "off":
         return fn(h, gates, experts, w_gate, w_up, w_down)
     return per_batch_shard(fn, (h, gates, experts),

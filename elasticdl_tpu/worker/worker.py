@@ -40,7 +40,10 @@ def _log_step_stats(step, stats):
     (``ops/moe_dispatch.row_bound`` times the blocks that ran), and
     ``moe_spilled`` [layers], the dispatches that ran more than one
     block: held rows past the bound.  Without them every row moved is
-    a held expert's, ``moved`` = ``rows`` and nothing spills."""
+    a held expert's, ``moved`` = ``rows`` and nothing spills.
+    ``moe_group_hit`` [layers], under a router limited to groups: the
+    share of a layer's tokens whose chosen groups reach an expert held
+    here, `` group_hit=`` their mean."""
     if not stats or "moe_load" not in stats:
         return
     import numpy as np
@@ -50,24 +53,29 @@ def _log_step_stats(step, stats):
     moved = stats.get("moe_moved", counts)
     logger.info(
         "moe load: step=%d layers=%d rows=%d max=%d mean=%.1f "
-        "padded_rows=%d moved=%d spilled=%d", step, counts.shape[0],
+        "padded_rows=%d moved=%d spilled=%d%s", step, counts.shape[0],
         counts.sum(), counts.max(), counts.mean(), load[:, -1].sum(),
         np.asarray(moved).sum(),
-        np.asarray(stats.get("moe_spilled", 0)).sum())
+        np.asarray(stats.get("moe_spilled", 0)).sum(),
+        " group_hit=%.4f" % np.asarray(stats["moe_group_hit"]).mean()
+        if "moe_group_hit" in stats else "")
 
 
 def _loss_fields(stats):
     """What the loss line says beside the loss of what left the step
     with it: `` mtp=`` the multi-token-prediction modules' mean loss
     before its weight, `` hc_err=`` the largest ``|row or column sum -
-    1|`` of any Sinkhorn map of the step (``models/transformer.py``:
-    ``mtp_modules``, ``hyper_streams``); nothing for a model with
-    neither.  Fetched after the loss: the same program made them."""
+    1|`` of any Sinkhorn map of the step, `` g_excess=`` how far under
+    its floor a kda layer's lowest log decay of the step lies
+    (``models/transformer.py``: ``mtp_modules``, ``hyper_streams``,
+    ``delta_gate_floor``); nothing for a model with none of them.
+    Fetched after the loss: the same program made them."""
     stats = stats or {}
     return "".join(
         " %s=%s" % (name, form % float(stats[key]))
         for name, key, form in (("mtp", "mtp_loss", "%.6f"),
-                                ("hc_err", "hc_err", "%.3e"))
+                                ("hc_err", "hc_err", "%.3e"),
+                                ("g_excess", "kda_gate_excess", "%.3e"))
         if key in stats)
 
 

@@ -1208,3 +1208,72 @@ def test_the_wide_streams_step_fits_a_v5e_with_nothing_kept(one_chip,
     assert names["flash_fwd_qk192_v128"] == 12
     assert names["flash_bwd_qk192_v128"] == 6
     assert names["embed_grad"] == 1
+
+
+# -- the linear / latent hybrid's step (PR 56) --------------------------------
+
+LING_PARAMETERS = 654478128
+
+
+def test_the_hybrids_parameters_are_the_configurations_count():
+    """``ling-3.0-flash`` as ``init_params`` builds it: a leading dense
+    KDA layer (62,953,608), five KDA expert layers (70,163,080 each),
+    the latent expert layer (63,498,240), the module (76,610,560), the
+    untied 19,648-id vocabulary (100,597,760) and the last norm: the
+    count the configuration's ``reduced_why`` states, shapes alone."""
+    spec = tfm.model_spec(**_model_params("ling-3.0-flash"))
+    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+    count = lambda tree: sum(a.size for a in jax.tree_util.tree_leaves(tree))
+    layers = params["layers"]
+    assert count(layers["lead"]["0"]) == 62953608
+    assert [count(layers["period"][str(i)]) for i in range(6)] == [
+        70163080] * 5 + [63498240]
+    assert count(params["mtp"]) == 76610560
+    assert count(params) == LING_PARAMETERS
+    mixer = lambda w, names: sum(w[name].size for name in names)
+    assert mixer(layers["period"]["0"], (
+        "w_qkv", "delta_conv", "w_a", "w_out_gate", "w_b", "A_log",
+        "dt_bias", "o_norm", "wo")) == 15762568
+    assert mixer(layers["period"]["5"], (
+        "wq", "w_kv_a", "kv_norm", "w_kv_b", "w_attn_gate", "wo")) == 9097728
+
+
+@pytest.mark.slow
+def test_the_linear_latent_hybrids_step_fits_a_v5e_with_nothing_kept(
+        one_chip, monkeypatch):
+    """The ``ling-3.0-flash.seq16384`` cell's whole training step (one
+    sequence of 16,384 through six KDA layers with full projections
+    under the bounded gate and a head-gated latent layer at 8 of 32
+    heads, a 512-wide group-limited router over 8 held experts of 768
+    and a clamped shared expert in six of them, the module's latent
+    block, two passes of an untied head over 19,648 ids, AdamW) through
+    the TPU's compiler with nothing kept: 12.13 GB of a v5e's 16.91,
+    which leaves ``remat_keep`` 4 GB to keep.  Marked slow: the one
+    program takes four minutes to compile here (my run, PR 56)."""
+    from elasticdl_tpu.models import remat_keep as rk
+    from elasticdl_tpu.ops.mode import SWITCH
+
+    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
+    spec = tfm.model_spec(**_model_params("ling-3.0-flash"))
+    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+    state = jax.eval_shape(spec.optimizer.init, params)
+    nbytes = lambda tree: sum(
+        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
+    held = 2 * nbytes(params) + nbytes(state)
+    compiled = _step(spec, one_chip, 1, 16384).compile()
+    stats = compiled.memory_analysis()
+    counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
+    assert 12.0e9 < counted < 12.3e9, counted
+    # ``remat_keep``'s estimate stands over it (+0.69 GB: the stack's
+    # gradients counted whole where expert layers are unrolled, as in
+    # the other share cells)
+    estimate = held + rk.step_bytes(spec.config, params, 16384)
+    assert 0.4e9 < estimate - counted < 1.0e9, (estimate, counted)
+    names = _names(compiled.as_text())
+    # six scans forward, again in each layer's backward, once back
+    assert (names["kda_fwd"], names["kda_bwd"]) == (12, 6), names
+    assert (names["sconv_silu_fwd"], names["sconv_silu_bwd"]) == (12, 6)
+    # the latent layer and the module's block
+    assert names["flash_fwd_qk192_v128"] == 4, names
+    assert names["flash_bwd_qk192_v128"] == 2, names
+    assert names["embed_grad"] == 1

@@ -81,6 +81,15 @@ AS_TYPED = {
     "mtp_modules": ("1", 1, ""),
     "mtp_weight": ("0.3", 0.3, ""),
     "scan_periods": ("false", False, ""),
+    "delta_gate_floor": ("-5", -5.0, "delta_kind=kda"),
+    "moe_groups": ("4", 4, "moe_experts=8;moe_top_groups=2;"
+                   "moe_router=sigmoid_bias"),
+    "moe_top_groups": ("2", 2, "moe_experts=8;moe_groups=4;"
+                       "moe_router=sigmoid_bias"),
+    "ffn_limits": ("4,0", "4,0", "moe_experts=4;num_layers=2;"
+                   "scan_periods=false;dense_layers=0;layer_pattern=aa"),
+    "shared_limits": ("0,7", "0,7", "moe_experts=4;num_layers=2;"
+                      "scan_periods=false;layer_pattern=aa"),
 }
 FIELDS = [field for field in dataclasses.fields(tfm.TransformerConfig)
           if field.name != "max_seq_len"]
@@ -166,7 +175,30 @@ def test_remats_two_strings_are_refused_by_name(word):
     (dict(post_norms="false"), "post_norms"),
     (dict(delta_kind="ssm"), "delta_kind"),
     (dict(num_layers=2, layer_pattern="ad", delta_key_dim=16,
-          delta_value_dim=16, delta_kind="kda"), "delta_rank"),
+          delta_value_dim=16, delta_gate_floor=-5.0), "delta_gate_floor"),
+    (dict(num_layers=2, layer_pattern="ad", delta_key_dim=16,
+          delta_value_dim=16, delta_kind="kda", delta_rank=-1),
+     "delta_rank"),
+    (dict(num_layers=2, layer_pattern="ad", delta_key_dim=16,
+          delta_value_dim=16, delta_kind="kda", delta_gate_floor=5.0),
+     "floor <= 0"),
+    (dict(moe_experts=8, moe_groups=4, moe_top_groups=2), "sigmoid_bias"),
+    (dict(moe_experts=8, moe_groups=3, moe_top_groups=2,
+          moe_router="sigmoid_bias"), "moe_groups=3"),
+    (dict(moe_experts=8, moe_groups=4, moe_top_groups=5,
+          moe_router="sigmoid_bias"), "moe_top_groups=5"),
+    (dict(moe_experts=8, moe_groups=4, moe_top_groups=1, moe_top_k=3,
+          moe_router="sigmoid_bias"), "moe_top_k"),
+    (dict(moe_experts=4, num_layers=2, ffn_limits="4"), "ffn_limits='4'"),
+    (dict(num_layers=2, ffn_limits="4,4"), "ffn_limits"),
+    (dict(moe_experts=4, num_layers=2, shared_limits="0,x"), "L0,L1"),
+    (dict(moe_experts=4, num_layers=2, ffn_limits="0,4"),
+     "scan_periods=false"),
+    (dict(moe_experts=4, num_layers=2, dense_layers=1, dense_ffn_dim=8,
+          ffn_limits="4,4"), "dense layer's limit"),
+    (dict(attn_gate="value"), "attn_gate"),
+    (dict(attn_gate=True, kv_latent_rank=32, qk_nope_dim=16, qk_rope_dim=8,
+          v_head_dim=8), "takes head alone"),
     (dict(num_layers=2, layer_pattern="ad", delta_key_dim=16,
           delta_value_dim=16, delta_rank=8), "delta_kind=gdn"),
 ])

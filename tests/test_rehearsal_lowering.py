@@ -1,7 +1,10 @@
 """The benchmark configurations' rehearsal steps lower to the text they
 lowered to when ``tests/rehearsal_step_hashes.json`` was written: a PR
 that adds options to ``TransformerConfig`` (PR 54: ``hyper_streams``,
-``q_latent_rank``, ``rope_scaling``, ``mtp_modules``) holds the models
+``q_latent_rank``, ``rope_scaling``, ``mtp_modules``; PR 56:
+``moe_groups``, ``delta_gate_floor``, ``ffn_limits``, ``attn_gate=head``,
+with every hash written from the parent's tree, ``xing4.0-29b-a4b``'s
+among them) holds the models
 that leave them at their defaults to the very program they had, loss,
 gradients and step statistics, before any chip says so.  A PR that
 means to change a listed model's step writes the file anew,

@@ -284,6 +284,26 @@ CELLS = {
     # calls ``c3``, ``c4``: 15.498 GB on ten seeds, ``remat keep:
     # names=- .. fallback=0``; ``OVER`` and ``NO_ROOM`` below)
     "xing4.0-29b-a4b.seq4096": ("xing4.0-29b-a4b", 2, 1, 15.498, [], ()),
+    # six KDA layers with full projections under the bounded gate, a
+    # head-gated latent layer and the module's latent block, all
+    # unrolled, a 1/64 share: a state of 10.47 GB leaves room for every
+    # entry but the KDA layers' two [rows, 3072] projections and the
+    # sorted rows (4.00 GB kept): the first cell that keeps the scans'
+    # outputs, states and inverses (``delta scan: .. states=kept``; my
+    # chip runs, PR 56, call ``c1``: 15.205 GB traced and untraced,
+    # predicted 16.061; ``OVER`` below)
+    "ling-3.0-flash.seq16384": (
+        "ling-3.0-flash", 1, 1, 15.205,
+        ["flash", "route", "latent", "q", "gate", "stream", "delta",
+         "delta_gate", "ffn_gate", "ffn_up", "shared_gate", "shared_up",
+         "moe_out", "delta_decay", "moe_gate", "moe_up", "kv"],
+        rk.ATTN_NAMES + (rk.KEEP_ROUTE, md.KEEP_SORT, rk.KEEP_LATENT,
+                         rk.KEEP_Q, rk.KEEP_ATTN_GATE, rk.KEEP_STREAM,
+                         gd.KEEP_OUT, gd.KEEP_STATES, gd.KEEP_INVERSE,
+                         rk.KEEP_DELTA_GATE, rk.KEEP_GATE, rk.KEEP_UP,
+                         rk.KEEP_SHARED_GATE, rk.KEEP_SHARED_UP, md.KEEP_OUT,
+                         rk.KEEP_DELTA_DECAY, md.KEEP_GATE, md.KEEP_UP,
+                         rk.KEEP_KV)),
 }
 
 
@@ -314,8 +334,10 @@ CELLS = {
 # inventory from shapes.
 # The wide stream's cell reads +1.14, for the first two's reason: its
 # unrolled expert layers' gradients (1.77 GB) are counted whole.
+# The linear / latent hybrid's reads +0.86, for the same reason (its
+# unrolled expert layers' 2.2 GB of gradients counted whole).
 OVER = {"trinity-mini.seq16384": 1.0, "solar-open2-250b.seq16384": 1.0,
-        "xing4.0-29b-a4b.seq4096": 1.2}
+        "xing4.0-29b-a4b.seq4096": 1.2, "ling-3.0-flash.seq16384": 1.0}
 # Cells in which the estimate lies past the limit less the reserve with
 # NOTHING kept: ``choose`` keeps nothing and states a negative budget
 # (-0.58 GB), and the chip runs the step 0.57 GB under that line, 1.41
@@ -326,7 +348,8 @@ NO_ROOM = {"xing4.0-29b-a4b.seq4096"}
 ROWS_OF = {"olmo1b": 16384, "olmoe1b7b": 16384, "lfm2-24b-a2b": 32768,
            "smallthinker-21b-a3b": 16384, "kanana-2-30b-a3b": 16384,
            "trinity-mini": 16384, "olmo-hybrid-7b": 16384,
-           "solar-open2-250b": 16384, "xing4.0-29b-a4b": 8192}
+           "solar-open2-250b": 16384, "xing4.0-29b-a4b": 8192,
+           "ling-3.0-flash": 16384}
 
 
 def _cell(config, **override):
