@@ -1501,16 +1501,18 @@ def announce_delta(cfg, rows, chunk, kept, inverse, mode, why):
     rematerialized layer, and how the backward gets a chunk's inverse:
     from the forward kernel, ``inverse`` bytes a layer, or by
     differentiating the jnp twin.  ``decay=`` names a kda layer's
-    projections (``rank=0``: full) and its gate (``gate=softplus`` |
-    ``gate=floor<F>``)."""
+    projections (``rank=0``: full), its gate (``gate=softplus`` |
+    ``gate=floor<F>``) and what the scan makes of that floor
+    (``pairs=block`` | ``pairs=columns``: ``gated_delta.pairs_of``)."""
     logger.info(
         "delta scan: rows=%d heads=%d key_dim=%d value_dim=%d chunk=%d "
         "conv_taps=%d neg_eigval=%d decay=%s states=%s inverse=%s %s%s",
         rows, cfg.num_heads, cfg.delta_key_dim, cfg.delta_value_dim, chunk,
         cfg.conv_kernel, cfg.delta_neg_eigval,
-        "channel rank=%d gate=%s" % (
+        "channel rank=%d gate=%s pairs=%s" % (
             cfg.delta_rank, "floor%g" % cfg.delta_gate_floor
-            if cfg.delta_gate_floor else "softplus")
+            if cfg.delta_gate_floor else "softplus",
+            gated_delta.pairs_of(cfg.delta_gate_floor))
         if cfg.delta_kind == "kda" else "head",
         "kept" if kept else "recomputed",
         "twin" if mode == "off" else "forward inverse_mb=%.1f" % (
@@ -1604,7 +1606,8 @@ def _delta_mix(h, w, cfg, with_excess=False):
         excess = jnp.maximum(0.0, cfg.delta_gate_floor - g.min())
     g, beta = (checkpoint_name(x, remat_keep.KEEP_DELTA_DECAY)
                for x in (g, beta))
-    o = gated_delta.gated_delta(q, k, v, g, beta)
+    o = gated_delta.gated_delta(q, k, v, g, beta,
+                                floor=cfg.delta_gate_floor)
     if pairs:
         gate = checkpoint_name(low_rank("w_g", dv),
                                remat_keep.KEEP_DELTA_GATE)

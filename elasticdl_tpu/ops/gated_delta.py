@@ -86,6 +86,19 @@ the grid, the packs, the resident state and the kept names are the
 scalar decay's.  Kernels ``kda_fwd`` / ``kda_bwd``: ``b`` rides as a
 float32 plane beside k, the backward writes its cotangent a channel.
 
+A floor the caller promises (``gated_delta(.., floor=F)``: a bounded
+gate's published floor, no entry of ``g`` under it; 0.0: none) bounds
+what a sub-block can forget: ``(SUB - 1) * -F``, 75 at a floor of -5,
+and exp(75) is inside float32 and bfloat16 alike.  Where ``SUB * -F <=
+FACTORS_TO`` (``pairs_of``: "block") a sub-block's own scores stand
+against its FIRST row too, exp(b_r - b_m) <= 1 on its rows times exp(b_m
+- b_i) <= exp(75) on every row of the chunk up to its last (the
+exponent masked before ``exp``: past the sub-block it is inf), and one
+product of the sub-block's rows covers its diagonal block and every
+earlier sub-block of its chunk (``_block_terms``): 4 score products a
+pack of two chunks where 19 stood, 8 cotangent products where 38.
+Everywhere else the nineteen terms stand, unchanged to the operation.
+
 Reference: ``gated_delta_ref``, the same chunk form in plain
 ``jax.numpy`` (float32 inside), differentiated by JAX: what
 ``gated_delta`` returns wherever ``ops/mode.py`` answers ``off`` or the
@@ -126,6 +139,10 @@ PACKS = (2, 1)
 MXU = 128
 # Tokens a sub-block of a vector decay's chunk (``_pair_terms``).
 SUB = 16
+# The largest ``sub * -floor`` under which a sub-block's scores factor
+# against its first row (``pairs_of``): float32 ends at exp(88.7), and a
+# sum over d_k channels of such terms needs the room between.
+FACTORS_TO = 80.0
 VMEM_LIMIT = 64 * 1024 * 1024
 
 _F32 = jnp.float32
@@ -190,11 +207,12 @@ def _inverse(a, chunk=None):
 # -- the plain twin ----------------------------------------------------------
 
 
-def gated_delta_ref(q, k, v, g, beta, chunk=CHUNK):
+def gated_delta_ref(q, k, v, g, beta, chunk=CHUNK, floor=0.0):
     """The chunk form in plain ``jax.numpy``, float32 inside, v's dtype
     out; any T (the last chunk padded with tokens that neither decay nor
     write).  ``g`` [B, H, T] a scalar a head, or [B, H, T, d_k] a vector
-    (the decayed scores then by ``_pair_scores``, a chunk at a time)."""
+    (the decayed scores then by ``_pair_scores``, a chunk at a time, its
+    terms chosen from ``floor`` as the kernels': ``pairs_of``)."""
     batch, heads, seq, _ = q.shape
     vector = g.ndim == q.ndim
     dtype = v.dtype
@@ -214,7 +232,7 @@ def gated_delta_ref(q, k, v, g, beta, chunk=CHUNK):
         b = jnp.cumsum(g, axis=-2)                    # [.., C, d_k]
         with jax.default_matmul_precision("highest"):
             pairs = jnp.vectorize(
-                functools.partial(_pair_scores, chunk=chunk),
+                functools.partial(_pair_scores, chunk=chunk, floor=floor),
                 signature="(m,d),(c,d),(c,d)->(m,c)")(
                     jnp.concatenate([k, q], axis=-2), k, b)
         kk, qk = pairs[..., :chunk, :], pairs[..., chunk:, :]
@@ -442,6 +460,16 @@ def _held(x, at, size):
     return jnp.broadcast_to(row, blocks.shape).reshape(rows, width)
 
 
+def pairs_of(floor, sub=SUB):
+    """How a sub-block of ``sub`` tokens' own scores are taken under a
+    log decay whose caller promises ``floor`` <= g (0.0: no promise):
+    "block", one product against the sub-block's first row
+    (``_block_terms``), where the cumulative decay across it cannot pass
+    ``FACTORS_TO``; else "columns", one product a row of the sub-block
+    (``_pair_terms``)."""
+    return "block" if 0 < sub * -floor <= FACTORS_TO else "columns"
+
+
 def _pair_terms(xs, k, b, chunk, sub):
     """The products whose masked sum is ``M[r, i] = sum_c x_r[c] k_i[c]
     exp(b_r[c] - b_i[c])`` for i <= r inside a chunk, and 0 elsewhere:
@@ -486,18 +514,85 @@ def _pair_terms(xs, k, b, chunk, sub):
                    scale(pos % chunk < first, ref - b))
 
 
-def _pair_scores(xs, k, b, chunk, sub=SUB):
-    """``M`` of ``_pair_terms`` [n E, E] float32."""
+def _sub_rows(a, first, chunk, sub):
+    """The ``sub`` rows from row ``first`` of every block of ``chunk``
+    rows of ``a``, block after block."""
+    return jnp.concatenate([a[at + first:at + first + sub]
+                            for at in range(0, a.shape[0], chunk)], axis=0)
+
+
+def _from_sub_rows(parts, chunk, sub):
+    """``_sub_rows`` undone: ``parts[s]`` the rows from ``s * sub`` of
+    every block."""
+    return jnp.concatenate(
+        [part[at:at + sub] for at in range(0, parts[0].shape[0], sub)
+         for part in parts], axis=0)
+
+
+def _block_terms(xs, k, b, chunk, sub):
+    """``_pair_terms`` where a floor under the log decay bounds what a
+    sub-block can forget (``pairs_of``: "block"): the reference row m is
+    the sub-block's first row for its own columns too, so ONE term a
+    sub-block covers every column of its chunk up to the sub-block's
+    last row.  Yields, a sub-block of the chunks in turn, (its first
+    row in a chunk; the left operand: ITS rows of every chunk of every
+    plane alone, ``_sub_rows``, scaled by exp(b_r - b_m) <= 1; that
+    scale; the right operand [E, d_k]; its scale, exp(b_m - b): at most
+    1 before m, at most exp((sub - 1) * -floor) behind it, inside
+    float32 and bfloat16 alike, and 0 past the sub-block, the exponent
+    masked BEFORE ``exp`` is taken: a row further down may lie hundreds
+    under m, and inf x 0 is a NaN).  What a product puts at i > r the
+    caller's mask takes off."""
+    dtype = k.dtype
+    edge = k.shape[0]
+    kf = k.astype(_F32)
+    pos = lax.broadcasted_iota(jnp.int32, (edge, 1), 0) % chunk
+    own = jnp.concatenate(
+        [jnp.exp(b - _held(b, 0, sub))] * (xs.shape[0] // edge), axis=0)
+    left = (xs.astype(_F32) * own).astype(dtype)
+    for first in range(0, chunk, sub):
+        rows = pos < first + sub
+        right = jnp.where(rows, jnp.exp(jnp.where(
+            rows, _held(b, first, chunk) - b, 0.0)), 0.0)
+        yield (first, _sub_rows(left, first, chunk, sub),
+               _sub_rows(own, first, chunk, sub),
+               (kf * right).astype(dtype), right)
+
+
+def _lower(shape, chunk):
+    """Where i <= r inside a chunk, ``shape`` [n E, E]: n planes of
+    rows."""
+    r = lax.broadcasted_iota(jnp.int32, shape, 0) % shape[1]
+    i = lax.broadcasted_iota(jnp.int32, shape, 1)
+    return (r // chunk == i // chunk) & (i <= r)
+
+
+def _pair_scores(xs, k, b, chunk, sub=SUB, floor=0.0):
+    """``M`` of ``_pair_terms`` [n E, E] float32, by the terms that
+    ``floor`` allows (``pairs_of``)."""
+    if pairs_of(floor, sub) == "block":
+        m = _from_sub_rows([_dot(left, right, _NT) for _, left, _, right, _
+                            in _block_terms(xs, k, b, chunk, sub)], chunk, sub)
+        return jnp.where(_lower(m.shape, chunk), m, 0.0)
     return sum(jnp.where(counts, _dot(left, right, _NT), 0.0)
                for counts, left, _, right, _ in _pair_terms(
                    xs, k, b, chunk, sub))
 
 
-def _pair_scores_bwd(dm, xs, k, b, chunk, sub=SUB):
+def _pair_scores_bwd(dm, xs, k, b, chunk, sub=SUB, floor=0.0):
     """(the cotangent of xs [n E, d_k], of k [E, d_k]) through
     ``_pair_scores`` for ``dm`` [n E, E], float32 each; b's is the
     caller's: x * dx summed over the planes, less k * dk (the reference
-    rows cancel)."""
+    rows cancel, whichever they are)."""
+    if pairs_of(floor, sub) == "block":
+        d = jnp.where(_lower(dm.shape, chunk), dm, 0.0).astype(k.dtype)
+        dxs, dk = [], 0.0
+        for first, left, left_scale, right, right_scale in _block_terms(
+                xs, k, b, chunk, sub):
+            rows = _sub_rows(d, first, chunk, sub)
+            dxs.append(_dot(rows, right) * left_scale)
+            dk += _dot(rows, left, _TN) * right_scale
+        return _from_sub_rows(dxs, chunk, sub), dk
     dxs = dk = 0.0
     masked = {}     # dm where a term counts: two masks for all the terms
     for counts, left, left_scale, right, right_scale in _pair_terms(
@@ -527,13 +622,14 @@ def _kda_decays(k, b, beta_col):
         kd=(kf * to_end).astype(dtype))
 
 
-def _kda_pack(q, k, b, betas):
+def _kda_pack(q, k, b, betas, floor):
     """(kk, qk [E, E] float32: ``_pair_scores`` of a pack's k and q, a
     chunk's scores in its own columns; beta [E, 1]) of a pack of P
     chunks; ``betas`` the chunks' [1, C] rows."""
     chunk = betas[0].shape[1]
     eye = _masks(chunk)[0]
-    m = _pair_scores(jnp.concatenate([k, q], axis=0), k, b, chunk)
+    m = _pair_scores(jnp.concatenate([k, q], axis=0), k, b, chunk,
+                     floor=floor)
     edge = k.shape[0]
     return m[:edge], m[edge:], jnp.concatenate(
         [_col(row, eye) for row in betas], axis=0)
@@ -556,7 +652,7 @@ def _kda_chunk(q, v, d, scores, inv, at, h):
 
 
 def _kda_fwd_kernel(q_ref, k_ref, v_ref, b_ref, gates_ref, o_ref,
-                    states_ref, inv_ref, h_scr, *, heads, chunk):
+                    states_ref, inv_ref, h_scr, *, heads, chunk, floor):
     @pl.when(pl.program_id(1) == 0)
     def _():
         h_scr[...] = jnp.zeros_like(h_scr)
@@ -568,7 +664,7 @@ def _kda_fwd_kernel(q_ref, k_ref, v_ref, b_ref, gates_ref, o_ref,
     for i in range(heads):
         kk, qk, beta_col = _kda_pack(
             q_ref[i], k_ref[i], b_ref[i],
-            [gates_ref[i, p, 0:1, :] for p in range(pack)])
+            [gates_ref[i, p, 0:1, :] for p in range(pack)], floor)
         # kk is zero outside a chunk's own block: ``_inverses``' operand
         full = _inverse(jnp.where(row > col, kk, 0.0) * beta_col, chunk)
         blocks = [full[p * chunk:(p + 1) * chunk] for p in range(pack)]
@@ -589,7 +685,7 @@ def _kda_fwd_kernel(q_ref, k_ref, v_ref, b_ref, gates_ref, o_ref,
 
 def _kda_bwd_kernel(q_ref, k_ref, v_ref, b_ref, gates_ref, states_ref,
                     inv_ref, do_ref, dq_ref, dk_ref, dv_ref, db_ref,
-                    dgates_ref, dh_scr, *, heads, chunk):
+                    dgates_ref, dh_scr, *, heads, chunk, floor):
     @pl.when(pl.program_id(1) == 0)
     def _():
         dh_scr[...] = jnp.zeros_like(dh_scr)
@@ -605,7 +701,7 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, b_ref, gates_ref, states_ref,
         dtype = q.dtype
         cast = lambda x: x.astype(dtype)
         kk, qk, beta_all = _kda_pack(
-            q, k, b, [gates_ref[i, p, 0:1, :] for p in range(pack)])
+            q, k, b, [gates_ref[i, p, 0:1, :] for p in range(pack)], floor)
         dqs, dks, dbs, dkks, dqks = ([None] * pack for _ in range(5))
         for p in reversed(range(pack)):
             rows = slice(p * chunk, (p + 1) * chunk)
@@ -645,7 +741,8 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, b_ref, gates_ref, states_ref,
             dqks[p] = _dot(do, c.among, _NT)
         stack = lambda parts: jnp.concatenate(parts, axis=0)
         xs = stack([k, q])
-        dxs, dk2 = _pair_scores_bwd(stack(dkks + dqks), xs, k, b, chunk)
+        dxs, dk2 = _pair_scores_bwd(stack(dkks + dqks), xs, k, b, chunk,
+                                    floor=floor)
         dxk, dxq = dxs[:edge], dxs[edge:]
         kf, qf = k.astype(_F32), q.astype(_F32)
         dq_ref[i] = (stack(dqs) + dxq).astype(dq_ref.dtype)
@@ -755,18 +852,20 @@ def _gdn_bwd(chunk, heads, pack, interpret, res, do):
 _gdn.defvjp(_gdn_fwd, _gdn_bwd)
 
 
-@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
-def _kda_fwd_call(q, k, v, b, gates, chunk, heads, pack, interpret):
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8, 9))
+def _kda_fwd_call(q, k, v, b, gates, chunk, heads, pack, interpret, floor):
     """``_fwd_call`` under a vector decay: ``b`` [B * H, T, d_k] float32
     each chunk's cumulative log decay, ``gates`` [B * H, T / C, 1, C]
-    the write strengths."""
+    the write strengths, ``floor`` what the caller promises the log
+    decay stays over (``pairs_of``)."""
     bh, seq, d_k = q.shape
     d_v = v.shape[-1]
     chunks = seq // chunk
     qk, vo, gate, state, inverse = _specs(
         heads, chunk, pack, d_k, d_v, chunks // pack, False, 1)
     return pl.pallas_call(
-        functools.partial(_kda_fwd_kernel, heads=heads, chunk=chunk),
+        functools.partial(_kda_fwd_kernel, heads=heads, chunk=chunk,
+                          floor=floor),
         out_shape=(jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct((bh, chunks, d_k, d_v), _F32),
                    jax.ShapeDtypeStruct(
@@ -781,9 +880,9 @@ def _kda_fwd_call(q, k, v, b, gates, chunk, heads, pack, interpret):
     )(q, k, v, b, gates)
 
 
-@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11))
+@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11, 12))
 def _kda_bwd_call(q, k, v, b, gates, states, inv, do, chunk, heads, pack,
-                  interpret):
+                  interpret, floor):
     """(dq, dk, dv, db, dgates)."""
     bh, seq, d_k = q.shape
     d_v = v.shape[-1]
@@ -791,7 +890,8 @@ def _kda_bwd_call(q, k, v, b, gates, states, inv, do, chunk, heads, pack,
     qk, vo, gate, state, inverse = _specs(
         heads, chunk, pack, d_k, d_v, steps, True, 1)
     return pl.pallas_call(
-        functools.partial(_kda_bwd_kernel, heads=heads, chunk=chunk),
+        functools.partial(_kda_bwd_kernel, heads=heads, chunk=chunk,
+                          floor=floor),
         out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype),
@@ -807,23 +907,23 @@ def _kda_bwd_call(q, k, v, b, gates, states, inv, do, chunk, heads, pack,
     )(q, k, v, b, gates, states, inv, do)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _kda(q, k, v, b, gates, chunk, heads, pack, interpret):
-    return _kda_fwd_call(q, k, v, b, gates, chunk, heads, pack,
-                         interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _kda(q, k, v, b, gates, chunk, heads, pack, interpret, floor):
+    return _kda_fwd_call(q, k, v, b, gates, chunk, heads, pack, interpret,
+                         floor)[0]
 
 
-def _kda_fwd(q, k, v, b, gates, chunk, heads, pack, interpret):
+def _kda_fwd(q, k, v, b, gates, chunk, heads, pack, interpret, floor):
     o, states, inv = _kda_fwd_call(q, k, v, b, gates, chunk, heads, pack,
-                                   interpret)
+                                   interpret, floor)
     o = checkpoint_name(o, KEEP_OUT)
     states = checkpoint_name(states, KEEP_STATES)
     inv = checkpoint_name(inv, KEEP_INVERSE)
     return o, (q, k, v, b, gates, states, inv)
 
 
-def _kda_bwd(chunk, heads, pack, interpret, res, do):
-    return _kda_bwd_call(*res, do, chunk, heads, pack, interpret)
+def _kda_bwd(chunk, heads, pack, interpret, floor, res, do):
+    return _kda_bwd_call(*res, do, chunk, heads, pack, interpret, floor)
 
 
 _kda.defvjp(_kda_fwd, _kda_bwd)
@@ -860,12 +960,16 @@ def delta_mode(seq, d_k, d_v, chunk=CHUNK, interpret=None, vector=False):
     return ("off" if why else mode), why
 
 
-def gated_delta(q, k, v, g, beta, chunk=CHUNK, interpret=None):
+def gated_delta(q, k, v, g, beta, chunk=CHUNK, interpret=None, floor=0.0):
     """q, k [B, H, T, d_k], v [B, H, T, d_v] in the compute dtype, g
     (the log decay, <= 0: [B, H, T] a scalar a head, or [B, H, T, d_k]
     a vector, a channel of the key each) and beta [B, H, T] -> o [B, H,
     T, d_v] in v's dtype; every sequence and head starts from a zero
-    state.  Differentiable in all five.  The kernels where
+    state.  ``floor`` < 0 is the caller's PROMISE that no entry of a
+    vector ``g`` lies under it (0.0: none made; a bounded gate's
+    published floor), from which the vector decay's score products are
+    chosen (``pairs_of``); nothing checks it here, and a ``g`` under it
+    may overflow them.  Differentiable in all five.  The kernels where
     ``ops/mode.py`` allows them and the shape tiles, per shard of the
     declared batch axis; else ``gated_delta_ref``."""
     batch, heads, seq, d_k = q.shape
@@ -877,7 +981,7 @@ def gated_delta(q, k, v, g, beta, chunk=CHUNK, interpret=None):
             flash_attention.announce_fallback(
                 "gated_delta", q.shape, why, resolve(interpret))
         return checkpoint_name(
-            gated_delta_ref(q, k, v, g, beta, chunk), KEEP_OUT)
+            gated_delta_ref(q, k, v, g, beta, chunk, floor), KEEP_OUT)
     block = next(n for n in HEAD_BLOCKS if heads % n == 0)
 
     def op(q, k, v, g, beta):
@@ -888,7 +992,7 @@ def gated_delta(q, k, v, g, beta, chunk=CHUNK, interpret=None):
             o = _kda(planes(q), planes(k), planes(v),
                      jnp.cumsum(chunks, axis=2).reshape(-1, seq, d_k),
                      beta.astype(_F32).reshape(-1, seq // chunk, 1, chunk),
-                     *scan)
+                     *scan, floor)
         else:
             o = _gdn(planes(q), planes(k), planes(v),
                      _gates(g, beta, chunk), *scan)

@@ -671,6 +671,6 @@ def test_the_kda_stacks_lines(monkeypatch, mode, ran):
     assert scan == (
         "delta scan: rows=128 heads=2 key_dim=32 value_dim=32 chunk=64 "
         "conv_taps=4 neg_eigval=1 decay=channel rank=16 gate=softplus "
-        "states=recomputed "
+        "pairs=columns states=recomputed "
         + {"reference": "inverse=twin ",
            "interpreter": "inverse=forward inverse_mb=0.1 "}[ran] + ran)

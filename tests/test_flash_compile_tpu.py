@@ -1021,13 +1021,15 @@ def test_a_scan_of_several_turns_is_handed_to_the_compiler_as_it_was(
 # -- the solar-open2-250b cell's shapes (benchmark/configs/solar-open2-250b.json)
 
 
-def test_the_vector_decay_scan_compiles_at_the_cells_shape(one_chip):
+@pytest.mark.parametrize("floor", [0.0, -5.0], ids=["no_floor", "floor-5"])
+def test_the_vector_decay_scan_compiles_at_the_cells_shape(one_chip, floor):
     """8 heads x 16,384 x 128 | 128, one sequence, a log decay a channel
     of the key: ``kda_fwd`` (a block of 4 heads' float32 states
     resident; a pack's two score matrices by 19 products of [256, 128]
-    x [128, 128], every exponent against a reference row; the inverses
-    by the scalar decay's ten joins) and ``kda_bwd`` for a described
-    v5e, under names the scalar decay's reader (``gdn_(fwd|bwd)``) does
+    x [128, 128], every exponent against a reference row, or by the 4
+    of [64, 128] x [128, 128] a promised floor of -5 allows, the
+    ``ling-3.0-flash`` cell's; the inverses by the scalar decay's ten
+    joins) and ``kda_bwd`` for a described v5e, under names the scalar decay's reader (``gdn_(fwd|bwd)``) does
     not match and the VMEM limit the calls set.  The forward writes what
     ``gdn_fwd`` writes; the backward dq, dk, dv, the decay's cotangent a
     channel in float32 and the write strength's."""
@@ -1041,9 +1043,10 @@ def test_the_vector_decay_scan_compiles_at_the_cells_shape(one_chip):
 
     def fwd_bwd(q, k, v, g, beta, cot):
         out, pull = jax.vjp(lambda *a: gd.gated_delta(
-            *a, interpret=False), q, k, v, g, beta)
+            *a, interpret=False, floor=floor), q, k, v, g, beta)
         return out, pull(cot)
 
+    assert gd.pairs_of(floor) == ("block" if floor else "columns")
     assert gd.delta_mode(16384, 128, 128, interpret=False, vector=True) == (
         "tpu", "")
     assert gd.delta_mode(16384, 128, 128, 40, interpret=False,

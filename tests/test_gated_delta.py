@@ -337,32 +337,65 @@ def draw_channels(seed, dtype=jnp.float32, seq=T):
     return q, k, v, jnp.asarray(g, jnp.float32), beta
 
 
+FLOOR = -5.0
+
+
+def draw_floored(seed, dtype=jnp.float32, seq=T):
+    """``draw_channels`` under a gate with a floor, ``g`` in [FLOOR, 0]:
+    head 0's channels anywhere in the range; head 1's EVEN channels AT
+    the floor every token (a sub-block's last row lies 15 x 5 = 75 under
+    its first: the right scale reaches exp(75) = 3.7e32) beside ODD ones
+    that forget nothing; head 2 nearly nothing anywhere."""
+    q, k, v, _, beta = draw(seed, True, dtype, seq=seq)
+    r = np.random.default_rng(seed + 200)
+    g = r.uniform(FLOOR, 0.0, (B, H, seq, DK))
+    g[:, 1, :, 0::2] = FLOOR
+    g[:, 1, :, 1::2] = -r.uniform(0.0, 1e-3, (B, seq, DK // 2))
+    g[:, 2] = -r.uniform(0.0, 1e-3, (B, seq, DK))
+    return q, k, v, jnp.asarray(g, jnp.float32), beta
+
+
+CHANNEL_DRAWS = {"any_decay": draw_channels, "floored": draw_floored}
+
+
 @functools.lru_cache(maxsize=None)
-def wanted_channels():
-    return out_and_grads(channel_recurrence, draw_channels(1))
+def wanted_channels(draw="any_decay", seq=T):
+    return out_and_grads(channel_recurrence, CHANNEL_DRAWS[draw](1, seq=seq))
 
 
-@pytest.mark.parametrize("chunk", [16, 32, 96])
+@pytest.mark.parametrize("chunk,seq,draw,floor", [
+    (16, T, "any_decay", 0.0), (32, T, "any_decay", 0.0),
+    (96, T, "any_decay", 0.0),
+    (32, T, "floored", FLOOR), (64, 128, "floored", FLOOR)],
+    ids=["16", "32", "96", "32-floor-5", "64-floor-5"])
 @pytest.mark.parametrize("which", sorted(IMPLEMENTATIONS))
 def test_the_vector_decays_chunk_form_is_the_recurrence_and_every_gradient(
-        which, chunk):
+        which, chunk, seq, draw, floor):
     """Sub-blocks of 16 in chunks of 16 (the direct columns alone), 32
     and 96 (reference rows between sub-blocks too; six of them a chunk),
     packs of two chunks and of one: o, dq, dk, dv, dg A CHANNEL and
     dbeta each within 2e-5 of the token-by-token recurrence's, and the
     head whose channels forget everything beside channels that forget
     nothing held alone (every exponent is a difference against a row
-    between the pair, so nothing overflows and nothing is lost)."""
-    operands = draw_channels(1)
-    got = out_and_grads(IMPLEMENTATIONS[which](chunk), operands)
+    between the pair, so nothing overflows and nothing is lost).
+
+    Under a promised floor of -5 (``pairs_of``: "block") a sub-block's
+    own scores stand against its FIRST row, one term for it and every
+    earlier sub-block of its chunk, two and four a chunk here: the same
+    2e-5, every entry finite, the head whose even channels sit at the
+    floor beside odd ones that forget nothing held alone."""
+    assert gd.pairs_of(floor) == ("block" if floor else "columns")
+    operands = CHANNEL_DRAWS[draw](1, seq=seq)
+    wanted = wanted_channels(draw, seq)
+    got = out_and_grads(functools.partial(
+        IMPLEMENTATIONS[which](chunk), floor=floor), operands)
     names = ("o", "dq", "dk", "dv", "dg", "dbeta")
     assert got[4].shape == operands[3].shape
-    errors = {name: far(a, b) for name, a, b in zip(
-        names, got, wanted_channels())}
+    errors = {name: far(a, b) for name, a, b in zip(names, got, wanted)}
     assert max(errors.values()) < 2e-5, errors
     assert all(bool(jnp.isfinite(a).all()) for a in got)
     for head in (1, 2):
-        for name, a, b in zip(names, got, wanted_channels()):
+        for name, a, b in zip(names, got, wanted):
             assert far(a[:, head], b[:, head]) < 2e-5, (head, name)
 
 
@@ -383,26 +416,55 @@ def test_a_vector_decay_constant_across_channels_is_the_scalar_kernels(
         assert far(a, b) < 2e-5, at
 
 
-def test_the_vector_decay_in_bfloat16_stays_within_its_own_tolerance():
+@pytest.mark.parametrize("draw,floor", [("any_decay", 0.0),
+                                        ("floored", FLOOR)])
+def test_the_vector_decay_in_bfloat16_stays_within_its_own_tolerance(
+        draw, floor):
     """bfloat16 q, k, v; the decays, their cumulative sums, ``A``, the
     inverse's joins and the state float32: under 1e-2 of the float32
     recurrence on the same bfloat16 values in the output and every
-    gradient, dg a channel in float32."""
-    operands = draw_channels(2, jnp.bfloat16)
-    got = out_and_grads(IMPLEMENTATIONS["kernel"](32), operands)
+    gradient, dg a channel in float32.  Under a promised floor the right
+    operand of a sub-block's term is scaled by up to exp(75) before it
+    is cast: bfloat16 ends where float32 does, and the tolerance is the
+    same."""
+    operands = CHANNEL_DRAWS[draw](2, jnp.bfloat16)
+    got = out_and_grads(functools.partial(
+        IMPLEMENTATIONS["kernel"](32), floor=floor), operands)
     want = out_and_grads(channel_recurrence, operands)
     assert got[0].dtype == jnp.bfloat16 and got[4].dtype == jnp.float32
     errors = [far(a, b) for a, b in zip(got, want)]
     assert 1e-4 < max(errors) < 1e-2, errors
 
 
-def test_a_vector_decays_calls_have_names_of_their_own_and_share_the_joins():
+def products(eqn):
+    return sum(e.primitive.name == "dot_general"
+               for e in eqn.params["jaxpr"].eqns)
+
+
+# ``dot_general``s of a grid step of (``kda_fwd``, ``kda_bwd``), a head,
+# at a pack of two chunks of 64: the score products of ``_pair_terms``
+# | ``_block_terms`` (19 | 4, the four over a sub-block's own 64 of the
+# 256 rows; the backward rebuilds them and adds two cotangent products a
+# term) beside what does not change: the forward's ten joins, the chunk
+# walk's five products a chunk forward and twelve backward.
+PRODUCTS = {"columns": (19 + 20, 19 + 38 + 24), "block": (4 + 20, 4 + 8 + 24)}
+
+
+@pytest.mark.parametrize("floor,pairs", [
+    (0.0, "columns"), (FLOOR, "block"), (-6.0, "columns")])
+def test_a_vector_decays_calls_have_names_of_their_own_and_share_the_joins(
+        floor, pairs):
     """``kda_fwd`` / ``kda_bwd``: names the scalar decay's trace reader
     (``gdn_(fwd|bwd)``) does not match; the forward runs the scalar
     kernel's ten joins once a head for a pack of two chunks of 64, the
-    backward none, and takes the forward's inverses as an operand."""
+    backward none, and takes the forward's inverses as an operand.  A
+    promised floor of -5 takes 15 products a head a pack out of the
+    forward and 45 out of the backward and leaves the joins as they are;
+    one of -6, too low for a sub-block of 16, takes none."""
     q, k, v, g, beta = draw_channels(3, seq=256)
-    fn = functools.partial(gd.gated_delta, chunk=64, interpret=True)
+    assert gd.pairs_of(floor) == pairs
+    fn = functools.partial(gd.gated_delta, chunk=64, interpret=True,
+                           floor=floor)
     whole = jax.make_jaxpr(lambda *a: jax.vjp(fn, *a)[1](a[2]))(
         q, k, v, g, beta)
     found = {}
@@ -418,6 +480,8 @@ def test_a_vector_decays_calls_have_names_of_their_own_and_share_the_joins():
     assert sorted(found) == ["kda_bwd", "kda_fwd"]
     assert highest(found["kda_fwd"]) == 10 * H
     assert highest(found["kda_bwd"]) == 0
+    assert (products(found["kda_fwd"]), products(found["kda_bwd"])) == tuple(
+        H * n for n in PRODUCTS[pairs])
     assert tuple(found["kda_bwd"].invars[6].aval.shape) == tuple(
         found["kda_fwd"].params["out_avals"][2].shape)
 

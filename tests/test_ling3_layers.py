@@ -531,7 +531,8 @@ def test_the_lines_state_the_new_fields():
         "heads_held=2/8 mtp=1 attn_gate=head route_groups=2/4 "
         "kda_rank=full gate_floor=-5.0 ffn_limits=0,4,4 "
         "shared_limits=0,7,7 a:window=0,rope=1")
-    assert "decay=channel rank=0 gate=floor-5 " in line("delta scan:")
+    assert ("decay=channel rank=0 gate=floor-5 pairs=block states="
+            in line("delta scan:"))
     assert "rope_key=shared gate=head tile=" in line("latent attention:")
     # (``mtp_loss`` joins them in ``loss_fn``)
     assert set(stats) == {"kda_gate_excess", "moe_group_hit", "moe_load",

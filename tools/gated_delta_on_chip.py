@@ -4,7 +4,7 @@
     python3 tools/gated_delta_on_chip.py [--heads 15] [--t 16384]
         [--key_dim 96] [--value_dim 192] [--chunk 64 ...]
         [--head_block 5 ...] [--pack 2 ...] [--iters 3] [--no_reference]
-        [--decay scalar|vector]
+        [--decay scalar|vector] [--floor 0 -5 ...]
 
 Prints one JSON line a (chunk, head block, pack: the chunks a grid step
 walks behind one build of their inverses): the device time of the
@@ -22,7 +22,13 @@ cell's, one layer; ``--decay vector --heads 8 --key_dim 128 --value_dim
 128`` is the ``solar-open2-250b.seq16384`` cell's (a log decay a channel
 of the key, calls ``kda_fwd`` / ``kda_bwd``, counted by
 ``benchmark/kernels/kda.py``; channel 0 of every head forgets at once
-and channel 1 never, beside the others' 0.3 to 0.9999 a token).  Exits 3 without a TPU: a CPU timing is no device
+and channel 1 never, beside the others' 0.3 to 0.9999 a token).
+``--floor F ..`` (vector decay) promises the op that no log decay lies
+under F, one line a floor on the same draw, whose channel 0 then sits AT
+the lowest floor given: ``--floor 0 -5`` is the ``ling-3.0-flash.seq16384``
+cell's call with today's nineteen score products a pack and with the four
+its floor allows (``pairs`` in the line: ``ops/gated_delta.pairs_of``).
+Exits 3 without a TPU: a CPU timing is no device
 number (``--t 256 --heads 2`` there is a rehearsal: every call runs in
 the interpreter, none is timed).
 """
@@ -52,6 +58,8 @@ def main():
     ap.add_argument("--no_reference", action="store_true")
     ap.add_argument("--decay", choices=("scalar", "vector"),
                     default="scalar")
+    ap.add_argument("--floor", type=float, nargs="+", default=[0.0],
+                    help="the log decay's promised floor; 0: none")
     args = ap.parse_args()
 
     import jax
@@ -83,7 +91,9 @@ def main():
     g = -np.exp(rng.uniform(np.log(1e-4), np.log(1.2),
                             (1, H, T) + (dk,) * vector))
     if vector:
-        g[..., 0], g[..., 1] = -30.0, 0.0
+        lowest = min(args.floor)
+        g[..., 0], g[..., 1] = lowest or -30.0, 0.0
+        g = np.maximum(g, lowest or -np.inf)
     g = jnp.asarray(g, jnp.float32)
     beta = jnp.asarray(rng.uniform(0.05, 1.95, (1, H, T)), jnp.float32)
     do = bf16(rng.standard_normal((1, H, T, dv)))
@@ -104,13 +114,13 @@ def main():
         twin["fwd_ms"] = device_ms(forward, operands, 1)[1]
         twin["fwd_bwd_ms"] = device_ms(backward, operands, 1)[1]
     packs = gd.PACKS
-    for chunk, block, pack in itertools.product(
-            args.chunk, args.head_block, args.pack):
+    for chunk, block, pack, floor in itertools.product(
+            args.chunk, args.head_block, args.pack, args.floor):
         if block:
             gd.HEAD_BLOCKS = (block,)
         gd.PACKS = (pack, 1) if pack else packs
         forward, backward = both(
-            lambda *a: gd.gated_delta(*a, chunk=chunk,
+            lambda *a: gd.gated_delta(*a, chunk=chunk, floor=floor,
                                       interpret=not on_chip))
         ops_f, all_f = device_ms(forward, operands, args.iters)
         ops_b, all_b = device_ms(backward, operands, args.iters)
@@ -120,6 +130,8 @@ def main():
                "head_block": block or next(
                    n for n in gd.HEAD_BLOCKS if H % n == 0),
                "pack": gd.pack_of(T, chunk)}
+        if vector:
+            row.update(floor=floor, pairs=gd.pairs_of(floor))
         for kind, ops in (("fwd", ops_f), ("bwd", ops_b)):
             # under ``jax.vjp`` XLA wraps the name: transpose_jvp_..
             ms = sum(v for op, v in ops.items() if name + kind in op)
