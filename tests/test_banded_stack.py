@@ -136,16 +136,15 @@ def test_the_period_matches_the_reference(monkeypatch, case):
     spec = tfm.model_spec(**sizes)
     params, tokens = _params(spec), _tokens(spec, batch=1 + (case != "kernels"))
     got, grads = jax.jit(jax.value_and_grad(_loss(spec, tokens)))(params)
-    want, want_grads = jax.value_and_grad(lambda p: REF.loss(
-        p, tokens, **_shape(spec.config))[0].mean())(params)
+    reference = lambda **how: jax.jit(lambda p: REF.loss(
+        p, tokens, **_shape(spec.config, **how))[0].mean())
+    want, want_grads = jax.jit(jax.value_and_grad(reference()))(params)
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     assert _apart(grads, want_grads) <= 1e-4
     # and the reference tells the mechanisms apart: a window left off,
     # RoPE on the NoPE layer, the route taken after attention's input
-    full = REF.loss(params, tokens, **_shape(
-        spec.config, windowed=(0,) * spec.config.num_layers))[0].mean()
-    roped = REF.loss(params, tokens, **_shape(
-        spec.config, roped=(1,) * spec.config.num_layers))[0].mean()
+    full = reference(windowed=(0,) * spec.config.num_layers)(params)
+    roped = reference(roped=(1,) * spec.config.num_layers)(params)
     for other in (full, roped):
         assert abs(float(other) - float(want)) > 2e-4 * abs(float(want))
 

@@ -47,7 +47,7 @@ def _case(seed=3):
     comparison draws them."""
     spec = _spec()
     params, tokens = REF.inputs(
-        CONFIG, spec.init_fn(jax.random.PRNGKey(seed)),
+        CONFIG, jax.jit(spec.init_fn)(jax.random.PRNGKey(seed)),
         np.random.default_rng(seed))
     return spec, params, jnp.concatenate([tokens, tokens[:, ::-1]])
 
@@ -64,7 +64,7 @@ def _reference(tokens, **how):
 @functools.lru_cache(maxsize=None)
 def _wanted():
     spec, params, tokens = _case()
-    return jax.value_and_grad(_reference(tokens))(params)
+    return jax.jit(jax.value_and_grad(_reference(tokens)))(params)
 
 
 @pytest.mark.parametrize("mode", ["off", "interpret"])
@@ -77,7 +77,7 @@ def test_the_loss_and_every_gradient_leaf_match_the_recurrence(
     spec, params, tokens = _case()
     assert spec.config.remat and [k.op for k in spec.config.kinds] == list(
         "ddda")
-    got, grads = jax.value_and_grad(_product(spec, tokens))(params)
+    got, grads = jax.jit(jax.value_and_grad(_product(spec, tokens)))(params)
     want, wanted = _wanted()
     assert abs(float(got) - float(want)) <= LOSS_TOLERANCE * float(want)
     flat, _ = jax.tree_util.tree_flatten_with_path(grads)
@@ -152,7 +152,7 @@ def _uncut(kind):
     heads = 4
     spec = _spec(num_heads=heads, num_kv_heads=heads, head_shares=1,
                  num_layers=1, layer_pattern=kind)
-    params = REF.inputs(dict(CONFIG, seq_len=64), spec.init_fn(
+    params = REF.inputs(dict(CONFIG, seq_len=64), jax.jit(spec.init_fn)(
         jax.random.PRNGKey(5)), np.random.default_rng(5))[0]
     w = REF.layers_of(params)[0]
     x = jnp.asarray(np.random.default_rng(6).standard_normal((2, 64, 64)),
@@ -168,10 +168,10 @@ def test_the_shares_of_a_delta_layer_add_up_to_the_uncut_layer():
     part."""
     w, x, heads = _uncut("d")
     d_k, d_v, eps = SHAPE["d_k"], SHAPE["d_v"], SHAPE["eps"]
-    whole = REF.delta_mixer(x, w, heads, d_k, d_v, eps, True)
-    parts = [REF.delta_mixer(x, _delta_share(w, heads, d_k, d_v, i, 2),
-                             heads // 2, d_k, d_v, eps, True)
-             for i in range(2)]
+    mixer = lambda held: jax.jit(
+        lambda w: REF.delta_mixer(x, w, held, d_k, d_v, eps, True))
+    whole, part = mixer(heads)(w), mixer(heads // 2)
+    parts = [part(_delta_share(w, heads, d_k, d_v, i, 2)) for i in range(2)]
     np.testing.assert_allclose(parts[0] + parts[1], whole, atol=2e-5)
     assert float(jnp.abs(parts[0] - whole).max()) > 1e-2
     # the layer: norm of the sum, then the MLP once
@@ -341,7 +341,7 @@ def test_kept_names_change_no_gradient(monkeypatch, mode):
 
     def grads(room):
         with batch_shard.batch_axis(None, None, room):
-            return jax.grad(_product(spec, tokens))(params)
+            return jax.jit(jax.grad(_product(spec, tokens)))(params)
 
     everything = batch_shard.DeviceRoom(2 ** 40, 2 ** 40 - held)
     names = rk.choose(spec.config, params, tokens.size, everything)[0]
@@ -459,7 +459,7 @@ def _kspec(**override):
 def _kcase(seed=3):
     spec = _kspec()
     params, tokens = KREF.inputs(
-        KCONFIG, spec.init_fn(jax.random.PRNGKey(seed)),
+        KCONFIG, jax.jit(spec.init_fn)(jax.random.PRNGKey(seed)),
         np.random.default_rng(seed))
     return spec, params, jnp.concatenate([tokens, tokens[:, ::-1]])
 
@@ -471,7 +471,7 @@ def _kreference(tokens, **how):
 @functools.lru_cache(maxsize=None)
 def _kwanted():
     spec, params, tokens = _kcase()
-    return jax.value_and_grad(_kreference(tokens))(params)
+    return jax.jit(jax.value_and_grad(_kreference(tokens)))(params)
 
 
 @pytest.mark.parametrize("mode", ["off", "interpret"])
@@ -490,7 +490,7 @@ def test_the_addd_expert_stack_matches_the_recurrence_a_channel(
     assert cfg.remat and [k.op for k in cfg.kinds] == list("addd")
     assert not any(k.dense for k in cfg.kinds) and cfg.delta_kind == "kda"
     assert (cfg.num_heads, cfg.kv_heads, cfg.head_shares) == (2, 1, 8)
-    got, grads = jax.value_and_grad(_product(spec, tokens))(params)
+    got, grads = jax.jit(jax.value_and_grad(_product(spec, tokens)))(params)
     want, wanted = _kwanted()
     assert abs(float(got) - float(want)) <= LOSS_TOLERANCE * float(want)
     flat, _ = jax.tree_util.tree_flatten_with_path(grads)
@@ -555,7 +555,7 @@ def _kuncut(kind, heads, kv_heads=0, **more):
     """(the weights of one uncut layer, its normed input)."""
     spec = _kspec(num_heads=heads, num_kv_heads=kv_heads or heads,
                   head_shares=1, num_layers=1, layer_pattern=kind, **more)
-    params = KREF.inputs(dict(KCONFIG, seq_len=64), spec.init_fn(
+    params = KREF.inputs(dict(KCONFIG, seq_len=64), jax.jit(spec.init_fn)(
         jax.random.PRNGKey(5)), np.random.default_rng(5))[0]
     x = jnp.asarray(np.random.default_rng(6).standard_normal((2, 64, 64)),
                     jnp.float32)
@@ -570,9 +570,10 @@ def test_the_eight_head_shares_of_a_kda_layer_add_up_to_the_uncut_mixer():
     and the program's mixer of a share is the reference's part."""
     heads, d, eps = 8, KSHAPE["d_k"], KSHAPE["eps"]
     _, w, x = _kuncut("d", heads)
-    whole = KREF.kda_mixer(x, w, heads, d, d, eps, True)
-    parts = [KREF.kda_mixer(x, _kda_share(w, heads, d, i, 8), 1, d, d, eps,
-                            True) for i in range(8)]
+    mixer = lambda held: jax.jit(
+        lambda w: KREF.kda_mixer(x, w, held, d, d, eps, True))
+    whole, part = mixer(heads)(w), mixer(1)      # one compile, 8 shares
+    parts = [part(_kda_share(w, heads, d, i, 8)) for i in range(8)]
     np.testing.assert_allclose(sum(parts), whole, atol=3e-5)
     assert float(jnp.abs(parts[0] - whole).max()) > 1e-2
     cfg = _kspec(num_heads=1, num_layers=1, layer_pattern="d").config

@@ -6,21 +6,18 @@ Interpret mode cannot see what Mosaic refuses (a slice off the tiling, a
 transpose it has no lowering for, more scoped VMEM than a kernel may
 use); this does, at the widths the benchmark's cells run, in a few
 seconds and without chip time.  Nothing runs, so no result or time is
-checked here: ``chip_check.py`` does that on the chip.  The topology is
-described inside a fixture, never at import: only the worker that is
-given this file loads the TPU library.
+checked here: ``chip_check.py`` does that on the chip.  The cells'
+whole training steps are ``test_step_compile_tpu.py``'s and
+``test_delta_step_compile_tpu.py``'s; what the three files share is
+``tests/tpu_compile.py``.
 """
 
-import collections
-import json
 import math
-import os
 import re
 
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
 
 from elasticdl_tpu.models import transformer as tfm
 from elasticdl_tpu.ops import flash_attention as fa
@@ -29,34 +26,8 @@ from elasticdl_tpu.ops import head_loss as hl
 from elasticdl_tpu.ops import hyper_mix as hm
 from elasticdl_tpu.ops import row_moves
 
-
-@pytest.fixture(scope="module")
-def one_chip():
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-
-    try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — any refusal means no compiler
-        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
-    # A compile for a described chip is written to the persistent cache
-    # but cannot be read back without one: keep it out.
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
-
-
-def _model_params(config):
-    """A benchmark configuration's ``model_params``, as the cell's job
-    gives them to ``model_spec``."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           config + ".json")) as fh:
-        return json.load(fh)["cli"]["model_params"]
+from tests.tpu_compile import (  # noqa: F401 (one_chip: a fixture)
+    _model_params, _mosaic_calls, _names, one_chip)
 
 
 @pytest.mark.parametrize("t,d,dtype,window", [
@@ -605,219 +576,7 @@ def test_head_loss_compiles_for_a_vocabulary_off_the_lanes(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 3.2e9
 
 
-def _fused_computations(text):
-    """name -> the instructions of every fused computation of a
-    compiled program's text, each cut before its metadata."""
-    bodies, body = {}, None
-    for line in text.splitlines():
-        head = re.match(r"%?(fused_computation[\w.\-]*) .*\{$", line)
-        if head:
-            body = bodies.setdefault(head.group(1), [])
-        elif line.startswith("}"):
-            body = None
-        elif body is not None:
-            body.append(line.split(", metadata=")[0].strip())
-    return bodies
-
-
-def _updates_in_matmuls(text):
-    """The fused computations that hold both a matmul and the square
-    root of AdamW's update: a weight gradient with its update as the
-    epilogue."""
-    return [name for name, body in _fused_computations(text).items()
-            if any(" convolution(" in l for l in body)
-            and any(" sqrt(" in l for l in body)]
-
-
-def _products(text, result):
-    """How many fused computations of a compiled program's text hold a
-    matmul whose result is ``result`` (as ``bf16[16384,11008]``)."""
-    return len([body for body in _fused_computations(text).values()
-                if any(" = %s{" % result in l and " convolution(" in l
-                       for l in body)])
-
-
-def _step(spec, one_chip, batch, rows, room=None):
-    """A cell's whole training step (loss, gradients, AdamW) compiled
-    for the described chip from shapes."""
-    import optax
-
-    from elasticdl_tpu.ops import batch_shard
-
-    on_chip = lambda tree: jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        tree)
-    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
-    state = jax.eval_shape(spec.optimizer.init, params)
-    tokens = jax.ShapeDtypeStruct((batch, rows), jnp.int32,
-                                  sharding=one_chip)
-
-    def step(params, state, tokens):
-        def loss(p):
-            with batch_shard.batch_axis(None, None, room):
-                out = spec.apply_fn(p, tokens, True)
-                return spec.loss_fn(out, tokens).mean()
-
-        value, grads = jax.value_and_grad(loss)(params)
-        updates, state2 = spec.optimizer.update(grads, state, params)
-        return optax.apply_updates(params, updates), state2, value
-
-    return jax.jit(step, donate_argnums=(0, 1)).lower(
-        on_chip(params), on_chip(state), tokens)
-
-
-@pytest.fixture(scope="module")
-def banded_step(one_chip):
-    """The ``smallthinker-21b-a3b.seq16384`` cell's whole training step
-    compiled once for the tests that read it (a minute): what
-    ``remat_keep`` chose, and the compiled program."""
-    from elasticdl_tpu.models import remat_keep as rk
-    from elasticdl_tpu.ops import batch_shard
-    from elasticdl_tpu.ops.mode import SWITCH
-
-    with pytest.MonkeyPatch.context() as patch:
-        patch.setenv(SWITCH, "tpu")       # the ops' own choice on a chip
-        spec = tfm.model_spec(**_model_params("smallthinker-21b-a3b"))
-        params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
-        state = jax.eval_shape(spec.optimizer.init, params)
-        rows = 16384
-        nbytes = lambda tree: sum(
-            a.size * a.dtype.itemsize
-            for a in jax.tree_util.tree_leaves(tree))
-        assert nbytes(params) == 4 * 656529920      # 656.5 M parameters
-        limit = 16911433728       # a v5e's bytes_limit (chip run, PR 29)
-        held = 2 * nbytes(params) + nbytes(state)
-        room = batch_shard.DeviceRoom(limit, limit - held)
-        chosen = rk.choose(spec.config, params, rows, room)
-        compiled = _step(spec, one_chip, 1, rows, room).compile()
-    return limit, chosen, compiled
-
-
-def test_the_banded_stacks_step_fits_a_v5e_as_remat_keep_predicts(
-        banded_step):
-    """The ``smallthinker-21b-a3b.seq16384`` cell's whole training step
-    (one sequence of 16,384 through a full-NoPE and three windowed-RoPE
-    attention layers, heads x head size 3,584 over a hidden 2,560, 16 of
-    64 ReGLU experts at 6 a token, an untied head over 37,984 ids,
-    AdamW) through the TPU's compiler with what ``remat_keep`` chose
-    kept (every entry of its table since the step's need counts a
-    layer's kept products once and an unrolled stack's weight copies
-    two layers at a time: the sorted rows too, 4.06 GB in all): its
-    predicted peak is over the compiler's own byte count, never
-    under, and under the device's limit less the reserve (15.45 GB
-    against the compiler's 15.28; PR 35's eleven names read 15.69
-    against 14.39).  Both kinds of flash call are in the one program,
-    and no forward runs twice."""
-    from elasticdl_tpu.models import remat_keep as rk
-    from elasticdl_tpu.ops import moe_dispatch
-
-    limit, (names, kept, budget, peak), compiled = banded_step
-    assert set(names) >= set(rk.ATTN_NAMES) | {
-        rk.KEEP_Q, rk.KEEP_K, rk.KEEP_V, rk.KEEP_STREAM,
-        moe_dispatch.KEEP_ROWS}, names
-    assert kept <= budget and peak <= (1 - rk.RESERVE) * limit
-
-    stats = compiled.memory_analysis()
-    counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
-    assert counted < peak and peak - counted < 0.5e9, (peak, counted)
-    calls = [l.split(" = ")[0].strip().lstrip("%")
-             for l in compiled.as_text().splitlines()
-             if 'custom_call_target="tpu_custom_call"' in l]
-    count = lambda name: len([c for c in calls if re.search(
-        name + r"(__)?\.\d+$|" + name + "$", c)])
-    assert (count("flash_fwd"), count("flash_bwd")) == (1, 1), calls
-    assert (count("flash_fwd_w4096"), count("flash_bwd_w4096")) == (3, 3), \
-        calls
-    assert not [c for c in calls if "flash_dq" in c or "flash_dkv" in c]
-
-
-def test_the_banded_stacks_step_scatters_no_row_into_the_table(
-        banded_step):
-    """The same compiled step: the embedding table's gradient is the one
-    float32 ``[37984, 2560]`` result of the ``embed_grad`` call
-    (``ops/embed_rows.py``: the lookup's own derivative), where JAX's
-    derivative of the lookup left XLA a scatter of bfloat16 rows into
-    ``bf16[37984,2560]`` and a convert pass, 15-17 ms of the cell's
-    step on the chip (PERF.md section 6, PR 53).  The compiler's
-    arguments + temporaries are the parent's 15,224,888,320 within what
-    buffer assignment moved them by (15,225,532,416, +0.6 MB: the
-    table's gradient stands where the step's peak is not)."""
-    _, _, compiled = banded_step
-    text = compiled.as_text()
-    assert not re.findall(r" = \w+\[37984,2560\]\S* scatter\(", text)
-    calls = [l for l in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in l
-             and "embed_grad" in l.split(" = ")[0]]
-    assert len(calls) == 1 and " = f32[37984,2560]{" in calls[0], calls
-    stats = compiled.memory_analysis()
-    counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
-    assert counted <= 15224888320 + 2 ** 20, counted
-
-
-def test_the_mixed_stacks_step_fits_a_v5e_as_remat_keep_predicts(
-        one_chip, monkeypatch):
-    """The ``lfm2-24b-a2b.seq8192`` cell's whole training step (4
-    sequences of 8,192 through a dense conv layer and a period of
-    attention + 3 conv layers over 8 of 64 experts, AdamW) through the
-    TPU's compiler: ``remat_keep``'s estimate with nothing kept, and its
-    predicted peak with what it chose, are held to the compiler's own
-    byte count (arguments + temporaries; the updated state aliases the
-    donated one): over, never under.  Nothing kept: 11.80 GB against
-    the compiler's 9.80 (+2.01: it never holds all the gradients the
-    trainer counted, a layer's AdamW update runs behind its backward;
-    10.34 and +1.47 until PR 42, whose attention layer no longer makes
-    K and V at the query heads nor the token-major copies of q and the
-    output, 0.54 GB the estimate never had a term for: the band's upper
-    edge moved from 1.6 to 2.1 with it).
-    With the names chosen, the convolutions' input and the experts' up
-    product among them, 5.55 GB: 15.81 against 15.41 (+0.40, inside
-    -0.1 / +0.5; the parent read 15.87 against 13.43 with 3.53 GB kept:
-    the dense layer's kept gate and up stood in the need as well, and
-    five layers' weight copies where two stand at once).  What is left
-    over is not a term of the estimate's but their sum: by the buffer
-    assignment the peak is in the first expert layer back-propagated,
-    where no gradient of the stack exists yet (1.8 GB counted) and the
-    dispatch's temporaries and the tied head's cotangent (2.8 GB) stand
-    where the estimate has the dense layer's 1.54: PERF.md section 7."""
-    from elasticdl_tpu.models import remat_keep as rk
-    from elasticdl_tpu.ops import batch_shard, moe_dispatch, short_conv
-    from elasticdl_tpu.ops.mode import SWITCH
-
-    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
-    spec = tfm.model_spec(**_model_params("lfm2-24b-a2b"))
-    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
-    state = jax.eval_shape(spec.optimizer.init, params)
-    nbytes = lambda tree: sum(
-        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
-    limit = 16911433728           # a v5e's bytes_limit (chip run, PR 29)
-    held = 2 * nbytes(params) + nbytes(state)
-
-    def compiled(room):
-        stats = _step(spec, one_chip, 4, 8192, room).compile(
-        ).memory_analysis()
-        return stats.argument_size_in_bytes + stats.temp_size_in_bytes
-
-    estimate = held + rk.step_bytes(spec.config, params, 32768)
-    nothing_kept = compiled(None)
-    assert -0.1e9 < estimate - nothing_kept < 2.1e9, (
-        estimate, nothing_kept)
-    room = batch_shard.DeviceRoom(limit, limit - held)
-    names, kept, budget, peak = rk.choose(spec.config, params, 32768, room)
-    assert kept <= budget
-    assert set(names) >= set(rk.ATTN_NAMES) | {
-        rk.KEEP_STREAM, rk.KEEP_GATE, rk.KEEP_UP, short_conv.KEEP_IN,
-        moe_dispatch.KEEP_UP}, names
-    with_names = compiled(room)
-    assert peak <= (1 - rk.RESERVE) * limit
-    assert -0.1e9 < peak - with_names < 0.5e9, (peak, with_names, names)
-
-
 # -- the olmo-hybrid-7b cell's shapes (benchmark/configs/olmo-hybrid-7b.json)
-
-
-def _mosaic_calls(text):
-    return [l.strip() for l in text.splitlines()
-            if 'custom_call_target="tpu_custom_call"' in l]
 
 
 @pytest.mark.parametrize("chunk", [64, 128])
@@ -904,120 +663,6 @@ def test_flash_compiles_at_15_heads_of_128_on_as_many_kv_heads(one_chip):
         "flash_bwd", "flash_fwd"]
 
 
-def test_the_delta_stacks_step_fits_a_v5e_with_nothing_kept(
-        one_chip, monkeypatch):
-    """The ``olmo-hybrid-7b.seq16384`` cell's whole training step (one
-    sequence of 16,384 through three gated-delta layers and a full NoPE
-    layer at 15 of 30 heads, a SwiGLU of 11,008 in each, an untied head
-    over 12,544 ids, AdamW; 766.2 M parameters) through the TPU's
-    compiler with nothing kept: the configuration's condition for its
-    two-way head share (12.77 GB of the 16.91; 13.00 until PR 46), so
-    the three-way fallback was not taken; ``remat_keep``'s estimate is
-    over the compiler's count by 0.12 GB since PR 50 counts one layer's
-    worth of this unrolled dense stack's gradients at the layer place
-    (``grads_standing``; +2.15 while it counted them whole, as
-    ``lfm2-24b-a2b``'s +2.01 still does; with none counted it would
-    read 0.56 UNDER).  The scan runs once forward and once again in
-    each delta layer's backward, and the convolution with it.
-
-    Since PR 46 (``models/transformer._updates_apart``) no matmul
-    carries an AdamW update as its epilogue (27 did): each of the twelve
-    MLP weight gradients is a fusion that writes its float32 matrix
-    from the convolution through a convert alone, and the update is a
-    pass of its own behind it."""
-    from elasticdl_tpu.models import remat_keep as rk
-    from elasticdl_tpu.ops.mode import SWITCH
-
-    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
-    spec = tfm.model_spec(**_model_params("olmo-hybrid-7b"))
-    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
-    state = jax.eval_shape(spec.optimizer.init, params)
-    nbytes = lambda tree: sum(
-        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
-    assert nbytes(params) == 4 * 766241946
-    held = 2 * nbytes(params) + nbytes(state)
-
-    compiled = _step(spec, one_chip, 1, 16384).compile()
-    stats = compiled.memory_analysis()
-    counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
-    assert counted < 0.95 * 16911433728
-    # a barrier a leaf keeps no gradient waiting: the parent's 13.00 GB
-    assert counted < 12.998e9 + 0.1e9, counted
-    estimate = held + rk.step_bytes(spec.config, params, 16384)
-    assert -0.1e9 < estimate - counted < 0.5e9, (estimate, counted)
-    text = compiled.as_text()
-    names = [c.split(" = ")[0].lstrip("%") for c in _mosaic_calls(text)]
-    count = lambda name: len([c for c in names if re.search(
-        r"(^|_)" + name + r"(__)?\.\d+$", c)])
-    assert (count("gdn_fwd"), count("gdn_bwd")) == (6, 3), names
-    assert (count("sconv_silu_fwd"), count("sconv_silu_bwd")) == (6, 3)
-    assert (count("flash_fwd"), count("flash_bwd")) == (2, 1), names
-    assert not _updates_in_matmuls(text)
-    # a layer's gate and up products in both of its forwards and the
-    # gated product's cotangent, a layer of four (the step with the
-    # room stated makes eight fewer: tests/test_remat_compile_tpu.py)
-    assert _products(text, "bf16[16384,11008]") == 4 * (2 + 2 + 1)
-    mlp_grads = [body for body in _fused_computations(text).values()
-                 if re.search(r"ROOT \S+ = f32\[1,(3840,11008|11008,3840)\]",
-                              body[-1])
-                 and any(" convolution(" in l for l in body)]
-    assert len(mlp_grads) == 12
-    for body in mlp_grads:
-        product = next(i for i, l in enumerate(body) if " convolution(" in l)
-        assert [re.search(r" (\w+)\(", l).group(1)
-                for l in body[product + 1:]] == ["convert", "bitcast"], body
-
-
-def test_the_gated_blocks_step_holds_no_update_in_a_matmul_nor_more_bytes(
-        one_chip, monkeypatch):
-    """The ``trinity-mini.seq16384`` cell's whole training step with the
-    names ``remat_keep`` chose, for a described v5e: no weight-gradient
-    matmul carries an AdamW update (39 did until PR 46) and the
-    compiler's bytes are the parent's 14.69 GB within 0.1 (14.72): the
-    guard against holding ``embed`` and ``lm_head`` apart as well, which
-    reads 15.82."""
-    from elasticdl_tpu.ops import batch_shard
-    from elasticdl_tpu.ops.mode import SWITCH
-
-    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
-    spec = tfm.model_spec(**_model_params("trinity-mini"))
-    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
-    nbytes = lambda tree: sum(
-        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
-    limit = 16911433728           # a v5e's bytes_limit (chip run, PR 29)
-    held = 2 * nbytes(params) + nbytes(
-        jax.eval_shape(spec.optimizer.init, params))
-    compiled = _step(spec, one_chip, 1, 16384,
-                     batch_shard.DeviceRoom(limit, limit - held)).compile()
-    stats = compiled.memory_analysis()
-    counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
-    assert abs(counted - 14.693e9) < 0.1e9, counted
-    assert not _updates_in_matmuls(compiled.as_text())
-
-
-def test_a_scan_of_several_turns_is_handed_to_the_compiler_as_it_was(
-        one_chip, monkeypatch):
-    """``olmo1b.seq2048``'s step as it is handed to the compiler: the
-    stack is a scan of seven turns, whose update already runs after the
-    loop on the stacked gradient, so ``_updates_apart`` holds none of
-    its leaves and the program's ``opt-barrier`` are what they were,
-    the head's three and ``jax.checkpoint``'s own in the backward
-    loop's body (with the seven stacked gradients held as well the
-    loops' bodies were the parent's too, but for four chips the
-    compiler's bytes read 17.29 GB for 17.11 and the loop's all-reduces
-    combined otherwise: PERF.md section 6, PR 46).  Two more loops since
-    PR 53, neither the stack's: the two binary searches with which
-    ``ops/embed_rows._schedule`` lists the (block of ids, chunk of sorted
-    rows) pairs the embedding's gradient walks."""
-    from elasticdl_tpu.ops.mode import SWITCH
-
-    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
-    spec = tfm.model_spec(**_model_params("olmo1b"))
-    text = _step(spec, one_chip, 8, 2048).as_text(dialect="hlo")
-    assert text.count(" while(") == 2 + 2
-    assert text.count(" opt-barrier(") == 3 + 1
-
-
 # -- the solar-open2-250b cell's shapes (benchmark/configs/solar-open2-250b.json)
 
 
@@ -1065,63 +710,9 @@ def test_the_vector_decay_scan_compiles_at_the_cells_shape(one_chip, floor):
     assert "f32[8,256,1,64]" in results(bwd)                  # dbeta
 
 
-def test_the_kda_expert_stacks_step_fits_a_v5e_with_nothing_kept(
-        one_chip, monkeypatch):
-    """The ``solar-open2-250b.seq16384`` cell's whole training step (one
-    sequence of 16,384 through a gated NoPE GQA layer at 8 query heads
-    on 1 K/V head and three KDA layers at 8 of 64 heads, a 320-wide
-    router over 8 held experts of 1,280 and a shared expert in each, an
-    untied head over 24,576 ids, AdamW; 840,875,672 parameters) through
-    the TPU's compiler with nothing kept: the configuration's condition
-    for its 8-way head share, so the 16-way fallback is not taken.  The
-    scan runs once forward and once again in each KDA layer's backward."""
-    from elasticdl_tpu.models import remat_keep as rk
-    from elasticdl_tpu.ops.mode import SWITCH
-
-    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
-    spec = tfm.model_spec(**_model_params("solar-open2-250b"))
-    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
-    state = jax.eval_shape(spec.optimizer.init, params)
-    nbytes = lambda tree: sum(
-        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
-    assert nbytes(params) == 4 * 840875672
-    held = 2 * nbytes(params) + nbytes(state)
-
-    compiled = _step(spec, one_chip, 1, 16384).compile()
-    stats = compiled.memory_analysis()
-    counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
-    # 14.98 GB of the 16.91 (the chip's peak with 1.0 GB kept: 15.08)
-    assert counted < 0.95 * 16911433728, counted
-    assert 14.9e9 < counted < 15.1e9, counted
-    # ``remat_keep``'s estimate stands over it, by the kda layer's
-    # decays a channel (+0.11 GB; -0.16 without that term)
-    estimate = held + rk.step_bytes(spec.config, params, 16384)
-    assert 0 < estimate - counted < 0.5e9, (estimate, counted)
-    text = compiled.as_text()
-    names = [c.split(" = ")[0].lstrip("%") for c in _mosaic_calls(text)]
-    count = lambda name: len([c for c in names if re.search(
-        r"(^|_)" + name + r"(__)?\.\d+$", c)])
-    assert (count("kda_fwd"), count("kda_bwd")) == (6, 3), names
-    assert (count("gdn_fwd"), count("gdn_bwd")) == (0, 0), names
-    assert (count("sconv_silu_fwd"), count("sconv_silu_bwd")) == (6, 3)
-    assert (count("flash_fwd"), count("flash_bwd")) == (2, 1), names
-    assert not _updates_in_matmuls(text)
-
-
-# -- the hyper-connection kernels and the wide stream's step (PR 54) ---------
-# (in this file, not one of their own: a second file that describes the
-# topology can go to another worker of the suite, which cannot load the
-# TPU's library a second time)
+# -- the hyper-connection kernels (PR 54) ------------------------------------
 
 HC_ROWS, HC_N, HC_C = 8192, 4, 3584
-
-
-def _names(text):
-    """How many Mosaic calls of a compiled program carry each name."""
-    names = [c.split(" = ")[0].lstrip("%") for c in _mosaic_calls(text)]
-    return collections.Counter(
-        re.sub(r"^(checkpoint_|jvp_|transpose_)+|(__)?[._]*\d+$", "", n)
-        for n in names)
 
 
 def test_the_four_calls_compile_at_the_cells_shape(one_chip):
@@ -1159,124 +750,3 @@ def test_the_four_calls_compile_at_the_cells_shape(one_chip):
     # the stream in, its gradient out, and under two streams between
     stats = compiled.memory_analysis()
     assert stats.temp_size_in_bytes < 2.2 * HC_ROWS * HC_N * HC_C * 2
-
-
-@pytest.mark.slow
-def test_the_wide_streams_step_fits_a_v5e_with_nothing_kept(one_chip,
-                                                            monkeypatch):
-    """The cell's whole training step (two sequences of 4,096 through a
-    dense layer, four expert layers and the module's block on a stream
-    four wide, 8 of 32 heads and 8 of 64 experts held, two passes of an
-    untied head over 16,384 ids, AdamW; 807,416,462 parameters) through
-    the TPU's compiler with nothing kept: 15.54 GB of a v5e's 16.91
-    (the chip's own peak reads 15.50, my chip runs, PR 54),
-    the configuration's condition for 8 heads and two sequences, so
-    neither fallback is taken.  Scanned (``scan_periods`` at its
-    default) the same step counts 18.25 GB: the four expert layers'
-    stacked gradient stands whole.  Marked slow: the one program takes
-    two minutes to compile here (my run, PR 54)."""
-    from elasticdl_tpu.models import remat_keep as rk
-    from elasticdl_tpu.ops.mode import SWITCH
-
-    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
-    spec = tfm.model_spec(**_model_params("xing4.0-29b-a4b"))
-    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
-    state = jax.eval_shape(spec.optimizer.init, params)
-    nbytes = lambda tree: sum(
-        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
-    assert nbytes(params) == 4 * 807416462
-    held = 2 * nbytes(params) + nbytes(state)
-
-    compiled = _step(spec, one_chip, 2, 4096).compile()
-    stats = compiled.memory_analysis()
-    counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
-    assert counted < 0.95 * 16911433728, counted
-    assert 15.4e9 < counted < 15.7e9, counted
-    # ``remat_keep``'s estimate stands over it by the stack's gradients,
-    # counted whole where expert layers are unrolled (ROADMAP A3 (t))
-    estimate = held + rk.step_bytes(spec.config, params, HC_ROWS)
-    assert 0.9e9 < estimate - counted < 1.5e9, (estimate, counted)
-    text = compiled.as_text()
-    names = _names(text)
-    # twelve sublayers: read twice (the second forward), written twice
-    # but for each block's last (its result is the next block's kept
-    # input), back-propagated once; the two narrowing maps
-    assert names["hc_pre_fwd"] == 2 * 12 + 2
-    # their maps: made twice, back-propagated once, one call each; no
-    # loop of the program's turns over the rounds' [4, 4, 8192] planes
-    assert (names["hc_maps_fwd"], names["hc_maps_bwd"]) == (2 * 12, 12)
-    assert not re.search(r"f32\[4,4,8192\]", text)
-    assert names["hc_post_fwd"] == 2 * 12 - 6
-    assert (names["hc_post_bwd"], names["hc_pre_bwd"]) == (12, 12 + 2)
-    assert names["flash_fwd_qk192_v128"] == 12
-    assert names["flash_bwd_qk192_v128"] == 6
-    assert names["embed_grad"] == 1
-
-
-# -- the linear / latent hybrid's step (PR 56) --------------------------------
-
-LING_PARAMETERS = 654478128
-
-
-def test_the_hybrids_parameters_are_the_configurations_count():
-    """``ling-3.0-flash`` as ``init_params`` builds it: a leading dense
-    KDA layer (62,953,608), five KDA expert layers (70,163,080 each),
-    the latent expert layer (63,498,240), the module (76,610,560), the
-    untied 19,648-id vocabulary (100,597,760) and the last norm: the
-    count the configuration's ``reduced_why`` states, shapes alone."""
-    spec = tfm.model_spec(**_model_params("ling-3.0-flash"))
-    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
-    count = lambda tree: sum(a.size for a in jax.tree_util.tree_leaves(tree))
-    layers = params["layers"]
-    assert count(layers["lead"]["0"]) == 62953608
-    assert [count(layers["period"][str(i)]) for i in range(6)] == [
-        70163080] * 5 + [63498240]
-    assert count(params["mtp"]) == 76610560
-    assert count(params) == LING_PARAMETERS
-    mixer = lambda w, names: sum(w[name].size for name in names)
-    assert mixer(layers["period"]["0"], (
-        "w_qkv", "delta_conv", "w_a", "w_out_gate", "w_b", "A_log",
-        "dt_bias", "o_norm", "wo")) == 15762568
-    assert mixer(layers["period"]["5"], (
-        "wq", "w_kv_a", "kv_norm", "w_kv_b", "w_attn_gate", "wo")) == 9097728
-
-
-@pytest.mark.slow
-def test_the_linear_latent_hybrids_step_fits_a_v5e_with_nothing_kept(
-        one_chip, monkeypatch):
-    """The ``ling-3.0-flash.seq16384`` cell's whole training step (one
-    sequence of 16,384 through six KDA layers with full projections
-    under the bounded gate and a head-gated latent layer at 8 of 32
-    heads, a 512-wide group-limited router over 8 held experts of 768
-    and a clamped shared expert in six of them, the module's latent
-    block, two passes of an untied head over 19,648 ids, AdamW) through
-    the TPU's compiler with nothing kept: 12.13 GB of a v5e's 16.91,
-    which leaves ``remat_keep`` 4 GB to keep.  Marked slow: the one
-    program takes four minutes to compile here (my run, PR 56)."""
-    from elasticdl_tpu.models import remat_keep as rk
-    from elasticdl_tpu.ops.mode import SWITCH
-
-    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
-    spec = tfm.model_spec(**_model_params("ling-3.0-flash"))
-    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
-    state = jax.eval_shape(spec.optimizer.init, params)
-    nbytes = lambda tree: sum(
-        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
-    held = 2 * nbytes(params) + nbytes(state)
-    compiled = _step(spec, one_chip, 1, 16384).compile()
-    stats = compiled.memory_analysis()
-    counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
-    assert 12.0e9 < counted < 12.3e9, counted
-    # ``remat_keep``'s estimate stands over it (+0.69 GB: the stack's
-    # gradients counted whole where expert layers are unrolled, as in
-    # the other share cells)
-    estimate = held + rk.step_bytes(spec.config, params, 16384)
-    assert 0.4e9 < estimate - counted < 1.0e9, (estimate, counted)
-    names = _names(compiled.as_text())
-    # six scans forward, again in each layer's backward, once back
-    assert (names["kda_fwd"], names["kda_bwd"]) == (12, 6), names
-    assert (names["sconv_silu_fwd"], names["sconv_silu_bwd"]) == (12, 6)
-    # the latent layer and the module's block
-    assert names["flash_fwd_qk192_v128"] == 4, names
-    assert names["flash_bwd_qk192_v128"] == 2, names
-    assert names["embed_grad"] == 1

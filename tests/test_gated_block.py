@@ -92,8 +92,8 @@ def test_the_stack_matches_the_reference(monkeypatch, case):
     params, tokens = _case(spec, batch=1 + (mode == "off"))
     got, grads = jax.jit(jax.value_and_grad(_loss(spec, tokens)))(params)
     shape = _shape(spec.config)
-    want, want_grads = jax.value_and_grad(lambda p: REF.loss(
-        p, tokens, **shape)[0].mean())(params)
+    want, want_grads = jax.jit(jax.value_and_grad(lambda p: REF.loss(
+        p, tokens, **shape)[0].mean()))(params)
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     flat = jax.tree_util.tree_flatten_with_path(grads)[0]
     seen = set()

@@ -58,11 +58,17 @@ def weights(shape):
 
 
 def out_and_grads(fn, operands):
-    """(o, its five gradients under a fixed random cotangent)."""
-    loss = lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * weights(
-        operands[2].shape))
-    return (fn(*operands),) + jax.grad(loss, argnums=(0, 1, 2, 3, 4))(
-        *operands)
+    """(o, its five gradients under a fixed random cotangent), one
+    compiled program for the six."""
+    cotangent = weights(operands[2].shape)
+
+    def loss(*a):
+        out = fn(*a)
+        return jnp.sum(out.astype(jnp.float32) * cotangent), out
+
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(*operands)
+    return (out,) + grads
 
 
 @functools.lru_cache(maxsize=None)

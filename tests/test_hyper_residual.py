@@ -172,7 +172,7 @@ def test_the_kernels_derivatives_are_jax_grads_of_the_jnp_form(rows):
                 iters=3)
             return (out * weigh).sum() + (u * u).sum()
 
-        return jax.grad(f, argnums=(0, 1, 2, 3, 4))(
+        return jax.jit(jax.grad(f, argnums=(0, 1, 2, 3, 4)))(
             x, y, w["hc1_phi"], w["hc1_alpha"], w["hc1_bias"])
 
     for got, want in zip(scalar(True), scalar(None)):
@@ -415,8 +415,8 @@ def test_a_new_model_starts_as_the_plain_residual_on_equal_streams():
     tokens = case(wide)[1]
     strip = lambda tree: {k: strip(v) if isinstance(v, dict) else v
                           for k, v in tree.items() if not k.startswith("hc")}
-    got = product_loss(wide, tokens)(params)
-    want = product_loss(plain, tokens)(strip(params))
+    got = jax.jit(product_loss(wide, tokens))(params)
+    want = jax.jit(product_loss(plain, tokens))(strip(params))
     assert float(got) == pytest.approx(float(want), rel=5e-3)
     assert float(got) != float(want)
 

@@ -98,8 +98,9 @@ def test_the_stack_matches_the_reference(monkeypatch, case):
     params, tokens = _case(spec, batch=1 + (mode == "off"))
     got, grads = jax.jit(jax.value_and_grad(_loss(spec, tokens)))(params)
     shape = _shape(spec.config)
-    want, want_grads = jax.value_and_grad(lambda p: REF.loss(
-        p, tokens, **shape)[0].mean())(params)
+    reference = lambda **how: jax.jit(lambda p: REF.loss(
+        p, tokens, **dict(shape, **how))[0].mean())
+    want, want_grads = jax.jit(jax.value_and_grad(reference()))(params)
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     flat = jax.tree_util.tree_flatten_with_path(grads)[0]
     for (path, leaf), ref in zip(flat, jax.tree_util.tree_leaves(
@@ -110,11 +111,11 @@ def test_the_stack_matches_the_reference(monkeypatch, case):
             assert not float(jnp.abs(leaf).max())
     # and the reference tells the mechanisms apart: the shared expert
     # left out; the program's column order taken for the published one
-    bare = REF.loss(params, tokens, **dict(shape, shared=False))[0].mean()
+    bare = reference(shared=False)(params)
     assert abs(float(bare) - float(want)) > 2e-4 * abs(float(want))
     as_is = REF.published_order
     monkeypatch.setattr(REF, "published_order", lambda columns: columns)
-    other = REF.loss(params, tokens, **shape)[0].mean()
+    other = reference()(params)
     monkeypatch.setattr(REF, "published_order", as_is)
     assert abs(float(other) - float(want)) > 2e-4 * abs(float(want))
 

@@ -193,10 +193,10 @@ def test_the_full_rank_kda_layer_is_the_recurrence(monkeypatch, mode, floor):
         assert float(g.max()) < 0.0
     probe = _normal(9, 2, T, 64)
     value = lambda fn: lambda h, w: (fn(h, w) * probe).sum()
-    grads = jax.grad(value(lambda h, w: tfm._delta_mix(h, w, cfg)),
-                     argnums=(0, 1))(h, w)
-    wanted = jax.grad(value(lambda h, w: _kda_want(h, w, floor)),
-                      argnums=(0, 1))(h, w)
+    grads = jax.jit(jax.grad(value(
+        lambda h, w: tfm._delta_mix(h, w, cfg)), argnums=(0, 1)))(h, w)
+    wanted = jax.jit(jax.grad(value(
+        lambda h, w: _kda_want(h, w, floor)), argnums=(0, 1)))(h, w)
     flat, _ = jax.tree_util.tree_flatten_with_path(grads)
     for (path, g), want in zip(flat, jax.tree_util.tree_leaves(wanted)):
         assert _far(g, want) < 2e-4, jax.tree_util.keystr(path)
@@ -255,10 +255,10 @@ def test_latent_attention_with_a_gate_a_head_is_the_references(
     assert _far(want(h, w, without=("head_gate",)), want(h, w)) > 0.3
     probe = _normal(9, 2, T, 64)
     names = ("wq", "w_kv_a", "kv_norm", "w_kv_b", "w_attn_gate", "wo")
-    grads = jax.grad(lambda h, w: (mix(h, w) * probe).sum(),
-                     argnums=(0, 1))(h, w)
-    wanted = jax.grad(lambda h, w: (want(h, w) * probe).sum(),
-                      argnums=(0, 1))(h, w)
+    grads = jax.jit(jax.grad(lambda h, w: (mix(h, w) * probe).sum(),
+                             argnums=(0, 1)))(h, w)
+    wanted = jax.jit(jax.grad(lambda h, w: (want(h, w) * probe).sum(),
+                              argnums=(0, 1)))(h, w)
     assert _far(grads[0], wanted[0]) < 2e-4
     for name in names:
         assert _far(grads[1][name], wanted[1][name]) < 2e-4, name

@@ -37,7 +37,7 @@ def _case(seed=3):
     spec = load_model_spec("transformer", model_params=params_string(
         CONFIG["cli"]["model_params"]))
     params, tokens = REF.inputs(
-        CONFIG, spec.init_fn(jax.random.PRNGKey(seed)),
+        CONFIG, jax.jit(spec.init_fn)(jax.random.PRNGKey(seed)),
         np.random.default_rng(seed))
     return spec, params, jnp.concatenate([tokens, tokens[:, ::-1]])
 
@@ -58,7 +58,7 @@ def _reference(tokens, **how):
 @functools.lru_cache(maxsize=None)
 def _wanted():
     spec, params, tokens = _case()
-    return jax.value_and_grad(_reference(tokens))(params)
+    return jax.jit(jax.value_and_grad(_reference(tokens)))(params)
 
 
 def test_the_rehearsal_model_is_the_cells_with_smaller_numbers():
@@ -94,7 +94,7 @@ def test_the_dda_stack_and_its_module_match_the_reference(monkeypatch,
     reaches ``expert_bias``."""
     monkeypatch.setenv(SWITCH, mode)
     spec, params, tokens = _case()
-    got, grads = jax.value_and_grad(_product(spec, tokens))(params)
+    got, grads = jax.jit(jax.value_and_grad(_product(spec, tokens)))(params)
     want, wanted = _wanted()
     assert abs(float(got) - float(want)) <= LOSS_TOLERANCE * float(want)
     flat, _ = jax.tree_util.tree_flatten_with_path(grads)

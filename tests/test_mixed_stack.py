@@ -8,6 +8,7 @@ widths."""
 
 import collections
 import dataclasses
+import functools
 import json
 import logging
 import os
@@ -27,6 +28,7 @@ from elasticdl_tpu.ops import moe_dispatch as md
 from elasticdl_tpu.ops import short_conv as sc
 from elasticdl_tpu.worker import worker as worker_mod
 from elasticdl_tpu.worker.collective_trainer import CollectiveTrainer
+from tests.test_remat_keep import _pallas_calls
 
 REF = manifest.load_named("reference", "lfm2-24b-a2b")
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -130,9 +132,9 @@ def test_one_block_of_each_kind_matches_the_reference(op, dense):
     params = _with_bias(jax.jit(spec.init_fn)(jax.random.PRNGKey(2)))
     params["embed"] = params["embed"] * 25.0
     tokens = _tokens(spec)
-    got, grads = jax.value_and_grad(_loss(spec, tokens))(params)
-    want, want_grads = jax.value_and_grad(lambda p: REF.loss(
-        p, tokens, first=8, **SHAPE)[0].mean())(params)
+    got, grads = jax.jit(jax.value_and_grad(_loss(spec, tokens)))(params)
+    want, want_grads = jax.jit(jax.value_and_grad(lambda p: REF.loss(
+        p, tokens, first=8, **SHAPE)[0].mean()))(params)
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     flat = lambda tree: jax.tree_util.tree_leaves_with_path(tree)
     for (path, g), (_, w) in zip(flat(grads), flat(want_grads)):
@@ -154,37 +156,76 @@ def _onto_share(params, cfg, by=5.0):
                          if path[-1].key == "expert_bias" else a), params)
 
 
+# the least depth with all of STACK's wiring: its dense conv lead, one
+# whole period (a scan of one turn) and the tail, every kind of layer
+SHORT = dict(STACK, num_layers=7, layer_pattern="c" + "accc" + "ac",
+             dense_layers=1)
+# what the interpreter's case has to reach, the calls of STACK's own
+# program by the names they carry: the convolution, the grouped matmul's
+# three products, a share's row moves, the embedding's gradient (32
+# positions are no flash tile: attention takes its reference at either
+# depth, and its kernels' stacks are tests/test_banded_stack.py's and
+# tests/test_gated_block.py's)
+STACK_KERNELS = {"sconv_fwd", "sconv_bwd", "gmm_nn", "gmm_nt", "gmm_tn",
+                 "rows_pack", "rows_gather", "rows_sum", "embed_grad"}
+
+
+@functools.lru_cache(maxsize=None)
+def _whole_stack(mode):
+    """What a mode's two cases share, traced under the mode and the
+    convolution's tiles that their caller has set: (the sizes, the spec,
+    the tokens, the product's loss, load and gradients as one compiled
+    program, as the trainer's is, the kernels it calls, the reference's
+    loss and gradients), compiled once for both routers."""
+    sizes = SHORT if mode == "interpret" else STACK
+    spec = tfm.model_spec(remat=True, **sizes)
+    tokens = _tokens(spec)
+
+    def loss_and_load(p):
+        out = spec.apply_fn(p, tokens, True)
+        return spec.loss_fn(out, tokens).mean(), spec.step_stats_fn(out)
+
+    traced = jax.jit(jax.value_and_grad(loss_and_load, has_aux=True)).trace(
+        jax.eval_shape(spec.init_fn, jax.random.PRNGKey(4)))
+    reference = jax.jit(jax.value_and_grad(lambda p: REF.loss(
+        p, tokens, **dict(SHAPE, first=4))[0].mean()))
+    return (sizes, spec, tokens, traced.lower().compile(),
+            set(_pallas_calls(traced.jaxpr.jaxpr)), reference)
+
+
 @pytest.mark.parametrize("mode", ["off", "interpret"])
 @pytest.mark.parametrize("onto_share", [False, True])
 def test_the_whole_stack_matches_the_reference(monkeypatch, mode,
                                                onto_share):
-    """cc | accc x 2 | ac, a share of 4 of 16 experts, non-zero
-    ``expert_bias``: the loss, the gradients' tree and each layer's
-    choice of experts, with the jnp paths and with the kernels in
-    interpret mode.  ``onto_share``: a router biased onto the held
-    experts, so each dispatch's 256 rows are theirs and run as two
-    blocks of the bound's 128."""
+    """A share of 4 of 16 experts, non-zero ``expert_bias``: the loss,
+    the gradients' tree and each layer's choice of experts.  ``off``,
+    the jnp paths, at cc | accc x 2 | ac; ``interpret``, the kernels in
+    interpret mode, at c | accc | ac (``SHORT``: the arithmetic at depth
+    is the ``off`` case's, each kernel's own its file's; what is left to
+    show is that the model hands every kernel the right operands, and
+    the program must call every one of ``STACK_KERNELS``).
+    ``onto_share``: a router biased onto the held experts, so each
+    dispatch's 256 rows are theirs and run as two blocks of the bound's
+    128."""
     monkeypatch.setenv("ELASTICDL_FLASH", mode)
     monkeypatch.setattr(sc, "ROW_TILES", (16,))
-    spec = tfm.model_spec(remat=True, **STACK)
+    sizes, spec, tokens, product, calls, reference = _whole_stack(mode)
+    assert calls == (STACK_KERNELS if mode == "interpret" else set())
+    experts = sum(not k.dense for k in spec.config.kinds)
     params = _with_bias(jax.jit(spec.init_fn)(jax.random.PRNGKey(4)))
     params["embed"] = params["embed"] * 25.0
     if onto_share:
         params = _onto_share(params, spec.config)
-    tokens = _tokens(spec)
-    load = spec.step_stats_fn(spec.apply_fn(params, tokens, True))
+    (got, load), grads = product(params)
     assert md.row_bound(2 * 32 * 4, 4, 16) == 128
     rows = np.asarray(load["moe_load"][:, :-1].sum(axis=1))
     if onto_share:
-        np.testing.assert_array_equal(rows, [256] * 10)
+        np.testing.assert_array_equal(rows, [256] * experts)
     # moved: the bound times the blocks that held a held expert's row
     np.testing.assert_array_equal(
         load["moe_moved"], 128 * np.maximum(np.ceil(rows / 128), 1))
     np.testing.assert_array_equal(load["moe_spilled"], rows > 128)
-    got, grads = jax.jit(jax.value_and_grad(_loss(spec, tokens)))(params)
-    shape = dict(SHAPE, first=4)
-    want, want_grads = jax.value_and_grad(lambda p: REF.loss(
-        p, tokens, **shape)[0].mean())(params)
+    want, want_grads = reference(params)
     assert float(got) == pytest.approx(float(want), rel=2e-5)
     leaves = jax.tree_util.tree_leaves
     norm = lambda trees: float(jnp.sqrt(sum(
@@ -197,7 +238,7 @@ def test_the_whole_stack_matches_the_reference(monkeypatch, mode,
         num_attention_heads=4, num_key_value_heads=2, num_experts_per_tok=4,
         norm_eps=1e-5, rope_parameters={"rope_theta": 1e6},
         norm_topk_prob=True, routed_scaling_factor=1,
-        cli={"model_zoo": "transformer", "model_params": STACK})
+        cli={"model_zoo": "transformer", "model_params": sizes})
     REF.check_routing(config, params, tokens, REF.shape_of(config))
 
 
@@ -318,8 +359,8 @@ def test_rows_of_absent_experts_never_reach_the_output(monkeypatch,
                                        total=8, first=4)
             return (out * out).sum(), (out, load)
 
-        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4),
-                                  has_aux=True)(h, gates, *weights)
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(h, gates, *weights)
 
     (_, (want, want_load)), want_grads = run("off")
     monkeypatch.setattr(gm, "_gmm_call", planted)
@@ -376,11 +417,16 @@ def test_no_gradient_reaches_expert_bias_and_adamw_leaves_it():
     spec = tfm.model_spec(**STACK)
     params = _with_bias(jax.jit(spec.init_fn)(jax.random.PRNGKey(0)))
     tokens = _tokens(spec)
-    grads = jax.grad(_loss(spec, tokens))(params)
-    state = spec.optimizer.init(params)
-    for _ in range(3):
-        updates, state = spec.optimizer.update(grads, state, params)
-        after = optax.apply_updates(params, updates)
+    grads = jax.jit(jax.grad(_loss(spec, tokens)))(params)
+
+    @jax.jit
+    def third_update(params, grads):
+        state = spec.optimizer.init(params)
+        for _ in range(3):
+            updates, state = spec.optimizer.update(grads, state, params)
+        return optax.apply_updates(params, updates)
+
+    after = third_update(params, grads)
     flat = lambda tree: jax.tree_util.tree_leaves_with_path(tree)
     seen = 0
     for (path, before), (_, now), (_, g) in zip(
@@ -545,8 +591,8 @@ def test_every_matrix_of_an_unrolled_stack_hands_its_gradient_through_a_barrier(
     def run():
         """(the gradient, its program's top-level barriers by their
         operands' shapes, the barriers of every nested jaxpr too)."""
-        grads = jax.grad(_loss(spec, tokens))(params)
-        jaxpr = jax.make_jaxpr(jax.grad(_loss(spec, tokens)))(params).jaxpr
+        traced = jax.jit(jax.grad(_loss(spec, tokens))).trace(params)
+        grads, jaxpr = traced.lower().compile()(params), traced.jaxpr.jaxpr
         barrier = lambda e: e.primitive.name == "optimization_barrier"
         return grads, collections.Counter(
             tuple(v.aval.shape for v in e.invars)

@@ -4,6 +4,8 @@ tests/test_mixed_stack.py that is about rows, in a file of its own so
 that the suite's longest file is not one worker's whole run
 (``--dist loadfile``).  Float32 on the CPU at tiny widths."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -53,6 +55,40 @@ def _plain_share(h, gates, experts, w_gate, w_up, w_down, first):
     return out
 
 
+@functools.lru_cache(maxsize=None)
+def _share_layer(mode, total, first):
+    """A held share's layer with its load and gradients, traced under
+    the mode its caller has set; the choice of experts is an operand, so
+    one compile a shape serves every count of held rows."""
+    def loss(h, gates, experts, cot, *weights):
+        out, load = md.moe_experts(h, gates, experts, *weights,
+                                   total=total, first=first)
+        return (out * cot).sum(), (out, load)
+
+    return jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 4, 5, 6), has_aux=True))
+
+
+@functools.lru_cache(maxsize=None)
+def _three_layers(mode, k, total, first):
+    """(the share's layer, the whole-buffer dispatch, the plain layer),
+    each with its gradients, as ``_share_layer`` has the first."""
+    def whole(h, gates, experts, cot, *weights):
+        full = tuple(jnp.zeros((total,) + w.shape[1:]).at[
+            first:first + k].set(w) for w in weights)
+        out, _ = md.moe_experts(h, gates, experts, *full)
+        return (out * cot).sum(), out
+
+    def plain(h, gates, experts, cot, *weights):
+        out = _plain_share(h, gates, experts, *weights, first)
+        return (out * cot).sum(), out
+
+    return (_share_layer(mode, total, first),) + tuple(
+        jax.jit(jax.value_and_grad(
+            fn, argnums=(0, 1, 4, 5, 6), has_aux=True))
+        for fn in (whole, plain))
+
+
 @pytest.mark.parametrize("mode", ["off", "interpret"])
 @pytest.mark.parametrize("held_rows", [100, 128, 129, 256, 512])
 def test_a_share_multiplies_every_held_row_whatever_the_bound(
@@ -73,25 +109,8 @@ def test_a_share_multiplies_every_held_row_whatever_the_bound(
     weights = tuple(jnp.asarray(rng.standard_normal(s) * 0.2, jnp.float32)
                     for s in ((k, 32, 48), (k, 32, 48), (k, 48, 32)))
     cot = jnp.asarray(rng.standard_normal((1, n, 32)), jnp.float32)
-
-    def share(h, gates, *weights):
-        out, load = md.moe_experts(h, gates, experts, *weights,
-                                   total=total, first=first)
-        return (out * cot).sum(), (out, load)
-
-    def whole(h, gates, *weights):
-        full = tuple(jnp.zeros((total,) + w.shape[1:]).at[
-            first:first + k].set(w) for w in weights)
-        out, _ = md.moe_experts(h, gates, experts, *full)
-        return (out * cot).sum(), out
-
-    def plain(h, gates, *weights):
-        out = _plain_share(h, gates, experts, *weights, first)
-        return (out * cot).sum(), out
-
-    args = (h, gates) + weights
-    grad = lambda fn: jax.jit(jax.value_and_grad(
-        fn, argnums=tuple(range(5)), has_aux=True))(*args)
+    share, whole, plain = _three_layers(mode, k, total, first)
+    grad = lambda fn: fn(h, gates, experts, cot, *weights)
     (_, (out, load)), grads = grad(share)
     blocks = max(-(-held_rows // 128), 1)
     np.testing.assert_array_equal(
@@ -162,6 +181,37 @@ def _a_block(n, k, c, live, seed, one_tokens=True):
             jnp.asarray(claims, jnp.int32))
 
 
+@functools.lru_cache(maxsize=None)
+def _moves(n, k):
+    """{(move, by the kernel?): the move with its gradients}, the block's
+    indices and its count of live rows operands: one compile a shape for
+    every filling of the block.  Traced under the interpreter, which the
+    caller has set."""
+    def gather(kernel):
+        def loss(x, tok, pos, live, named, cot_rows):
+            out = md._gather_rows(n, x, tok, pos, live,
+                                  jnp.where(live > 0, n, 0)) \
+                if kernel else md.tokens_to_rows(n, x, tok)
+            return (jnp.where(named, out, 0) * cot_rows).sum(), out
+        return jax.jit(jax.value_and_grad(loss, has_aux=True))
+
+    def summed(kernel):
+        def loss(y, gates, tok, pos, live, claims, cot):
+            if kernel:
+                out = md._sum_rows(n, y, tok, pos, live, claims, gates,
+                                   jnp.where(live > 0, n, 0))
+            else:
+                scale = gates.reshape(n * k).at[claims].get(
+                    mode="fill", fill_value=0)
+                out = md.rows_to_tokens(n, y, tok, scale)
+            return (out * cot).sum(), out
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True))
+
+    return {(name, kernel): move(kernel) for name, move in (
+        ("gather", gather), ("sum", summed)) for kernel in (True, False)}
+
+
 @pytest.mark.parametrize("width", [128, 40])
 @pytest.mark.parametrize("k", [1, 4, 6])
 @pytest.mark.parametrize("rows", ["none", "some", "k-on-one-token", "all"])
@@ -186,29 +236,12 @@ def test_the_row_kernel_moves_rows_as_the_jnp_moves_do(monkeypatch, k,
     gates = jnp.asarray(rng.random((n, k)), jnp.float32)
     cot_rows = jnp.asarray(rng.standard_normal((c, width)), jnp.float32)
     cot = jnp.asarray(rng.standard_normal((n, width)), jnp.float32)
-    named = (np.arange(c) < live)[:, None]
-
-    def gather(kernel):
-        def loss(x):
-            out = md._gather_rows(n, x, tok, pos, jnp.int32(live),
-                                  jnp.int32(n if live else 0)) \
-                if kernel else md.tokens_to_rows(n, x, tok)
-            return (jnp.where(named, out, 0) * cot_rows).sum(), out
-        return jax.value_and_grad(loss, has_aux=True)(x)
-
-    def summed(kernel):
-        def loss(y, gates):
-            if kernel:
-                out = md._sum_rows(n, y, tok, pos, jnp.int32(live), claims,
-                                   gates, jnp.int32(n if live else 0))
-            else:
-                scale = gates.reshape(n * k).at[claims].get(
-                    mode="fill", fill_value=0)
-                out = md.rows_to_tokens(n, y, tok, scale)
-            return (out * cot).sum(), out
-        return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
-            y, gates)
-
+    named = jnp.asarray((np.arange(c) < live)[:, None])
+    moves, count = _moves(n, k), jnp.int32(live)
+    gather = lambda kernel: moves["gather", kernel](
+        x, tok, pos, count, named, cot_rows)
+    summed = lambda kernel: moves["sum", kernel](
+        y, gates, tok, pos, count, claims, cot)
     (_, got), dx = gather(True)
     (_, want), want_dx = gather(False)
     np.testing.assert_array_equal(np.asarray(got)[:live],
@@ -292,15 +325,8 @@ def test_a_shares_layer_is_one_by_the_kernel_and_by_the_jnp_moves(
         monkeypatch.setenv("ELASTICDL_FLASH", mode)
         assert md.rows_by_kernel(n, bound, e, jnp.float32, k) == (
             mode != "off")
-
-        def loss(h, gates, *weights):
-            out, load = md.moe_experts(h, gates, experts, *weights,
-                                       total=total, first=first)
-            return (out * cot).sum(), (out, load)
-
-        return jax.jit(jax.value_and_grad(
-            loss, argnums=tuple(range(5)), has_aux=True))(
-                h, gates, *weights)
+        return _share_layer(mode, total, first)(
+            h, gates, experts, cot, *weights)
 
     (_, (out, load)), grads = run("interpret")
     (_, (want, want_load)), want_grads = run("off")

@@ -34,7 +34,7 @@ def test_mobilenetv2_param_count_near_reference():
     import jax
 
     spec = mobilenet.model_spec()
-    params = spec.init_fn(jax.random.PRNGKey(0))
+    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
     count = sum(np.prod(p.shape) for p in
                 jax.tree_util.tree_leaves(params))
     assert 1.8e6 < count < 2.8e6, count
@@ -62,11 +62,13 @@ def test_resnet_s2d_stem():
 
     spec = resnet.model_spec(variant="resnet50_s2d", num_classes=10,
                              image_size=64, learning_rate=0.1)
-    params = spec.init_fn(jax.random.PRNGKey(0))
+    # shapes alone: nothing is initialised or run before the trainer's step
+    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
     stem = params["Conv_0"]["kernel"]
     assert stem.shape == (4, 4, 12, 64)  # vs (7, 7, 3, 64) baseline
-    logits = spec.apply_fn(params, np.zeros((2, 64, 64, 3), np.float32),
-                           True)
+    logits = jax.eval_shape(
+        lambda p, x: spec.apply_fn(p, x, True), params,
+        jax.ShapeDtypeStruct((2, 64, 64, 3), np.float32))
     assert logits.shape == (2, 10)
     trainer = CollectiveTrainer(spec, batch_size=4)
     xs = np.random.RandomState(0).rand(4, 64, 64, 3).astype(np.float32)

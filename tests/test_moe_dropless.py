@@ -127,16 +127,16 @@ def test_model_loss_and_gradients_match_the_plain_reference(
     sends every token to every expert."""
     monkeypatch.setenv("ELASTICDL_FLASH", mode)
     spec = tfm.model_spec(**_cfg(top_k))
-    params = spec.init_fn(jax.random.PRNGKey(0))
+    params = jax.jit(spec.init_fn)(jax.random.PRNGKey(0))
     # unit-scale activations, and a router that really discriminates
     params["embed"] = params["embed"] * 25.0
     params["layers"]["w_router"] = params["layers"]["w_router"] * 20.0
     tokens = _tokens()
-    (got, load), got_grads = jax.value_and_grad(
-        lambda p: _product_loss(spec, p, tokens), has_aux=True)(params)
-    (want, chosen), want_grads = jax.value_and_grad(
+    (got, load), got_grads = jax.jit(jax.value_and_grad(
+        lambda p: _product_loss(spec, p, tokens), has_aux=True))(params)
+    (want, chosen), want_grads = jax.jit(jax.value_and_grad(
         lambda p: (lambda l, c: (l.mean(), c))(
-            *_reference_loss(p, tokens, top_k)), has_aux=True)(params)
+            *_reference_loss(p, tokens, top_k)), has_aux=True))(params)
     np.testing.assert_allclose(got, want, rtol=1e-5)
     # identical routing: each layer's assignments per expert
     np.testing.assert_array_equal(
@@ -161,19 +161,19 @@ def test_a_collapsed_router_drops_no_row(monkeypatch, held):
     whole layer with the six absent experts' weights zeros."""
     monkeypatch.setenv("ELASTICDL_FLASH", "interpret")
     spec = tfm.model_spec(moe_experts_held=held, **_cfg(2, layers=1))
-    params = spec.init_fn(jax.random.PRNGKey(1))
+    params = jax.jit(spec.init_fn)(jax.random.PRNGKey(1))
     params["embed"] = jnp.abs(params["embed"]) * 25.0 + 0.1
     params["layers"]["ln2"] = jnp.abs(params["layers"]["ln2"])
     router = np.zeros((1, 64, 8), np.float32)
     router[..., 0], router[..., 1] = 10.0, 9.0
     params["layers"]["w_router"] = jnp.asarray(router)
     tokens = _tokens(b=4 if held else 1)
-    got, load = _product_loss(spec, params, tokens)
+    got, load = jax.jit(lambda p: _product_loss(spec, p, tokens))(params)
     whole = dict(params, layers=dict(params["layers"], **{
         name: jnp.pad(w, ((0, 0), (0, 8 - w.shape[1]), (0, 0), (0, 0)))
         for name, w in params["layers"].items()
         if name in ("w_gate", "w_up", "w_down")}))
-    want = _reference_loss(whole, tokens, 2)[0].mean()
+    want = jax.jit(lambda p: _reference_loss(p, tokens, 2)[0].mean())(whole)
     load = np.asarray(load)[0]
     if held:
         # experts 0 and 1, padded rows, moved, shards that spilled

@@ -104,7 +104,7 @@ def test_gradient_parity(remat):
     def loss_pipe(p):
         return jnp.mean((run_pipeline(mesh, p, x, 8, remat=remat) - tgt) ** 2)
 
-    g_seq = jax.grad(loss_seq)(params)
+    g_seq = jax.jit(jax.grad(loss_seq))(params)
     g_pipe = jax.jit(jax.grad(loss_pipe))(params)
     for k in g_seq:
         np.testing.assert_allclose(
@@ -173,7 +173,7 @@ def test_transformer_pipelined_matches_sequential(variant):
             tfm.forward_pipelined(p, tokens, cfg, mesh, 4), tokens
         ).mean()
 
-    l_seq, g_seq = jax.value_and_grad(loss_seq)(params)
+    l_seq, g_seq = jax.jit(jax.value_and_grad(loss_seq))(params)
     l_pipe, g_pipe = jax.jit(jax.value_and_grad(loss_pipe))(params)
     np.testing.assert_allclose(float(l_pipe), float(l_seq), rtol=1e-5)
     flat_seq = jax.tree_util.tree_leaves(g_seq)
@@ -261,7 +261,7 @@ def test_pipelined_moe_grad_parity_through_aux():
         )
         return tfm.next_token_loss(logits, tokens).mean() + 0.01 * aux
 
-    g_seq = jax.grad(loss_seq)(params)
+    g_seq = jax.jit(jax.grad(loss_seq))(params)
     g_pipe = jax.jit(jax.grad(loss_pipe))(params)
     router_grad = np.asarray(g_pipe["layers"]["w_router"])
     assert np.abs(router_grad).max() > 0, "router got no gradient"

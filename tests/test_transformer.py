@@ -70,7 +70,7 @@ def test_chunked_xent_matches_dense(params, chunk):
             p, hidden, tokens, CFG, chunk=chunk
         ).mean()
 
-    l0, g0 = jax.value_and_grad(dense_loss)(params)
+    l0, g0 = jax.jit(jax.value_and_grad(dense_loss))(params)
     l1, g1 = jax.jit(jax.value_and_grad(chunked_loss))(params)
     np.testing.assert_allclose(float(l0), float(l1), rtol=1e-6)
     jax.tree_util.tree_map(
@@ -290,8 +290,8 @@ def test_remat_preserves_gradients(params):
             return tfm.next_token_loss(logits, tokens).mean()
         return f
 
-    l0, g0 = jax.value_and_grad(loss(CFG))(params)
-    l1, g1 = jax.value_and_grad(loss(cfg_r))(params)
+    l0, g0 = jax.jit(jax.value_and_grad(loss(CFG)))(params)
+    l1, g1 = jax.jit(jax.value_and_grad(loss(cfg_r)))(params)
     np.testing.assert_allclose(float(l0), float(l1), rtol=1e-5)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-4,
@@ -398,8 +398,9 @@ def test_loss_decreases_matches_unsharded_trajectory():
     opt = tx.init(params)
     traj_single = []
     p = params
+    step = jax.jit(jax.value_and_grad(loss_single))
     for _ in range(3):
-        l, g = jax.value_and_grad(loss_single)(p, (tokens, tokens))
+        l, g = step(p, (tokens, tokens))
         u, opt = tx.update(g, opt, p)
         p = optax.apply_updates(p, u)
         traj_single.append(float(l))

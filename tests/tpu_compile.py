@@ -1,0 +1,128 @@
+"""What the tests that compile for a described v5e share
+(``test_flash_compile_tpu.py``: the kernels and single layers;
+``test_step_compile_tpu.py`` and ``test_delta_step_compile_tpu.py``:
+whole training steps): the described chip, a cell's ``model_params``,
+a step lowered from shapes, and readers of a compiled program's text.
+
+No test lives here.  Three files and not one, because under ``--dist
+loadfile`` a file is one worker's and the whole-step compiles are
+minutes each (ROADMAP C16 caps a file's seconds).  Each file's worker
+loads the TPU's library: several workers can because the driver's
+command sets ``ALLOW_MULTIPLE_LIBTPU_LOAD=1``
+(``/root/TESTS_LAST_RUN.json``); without it run the three files in one
+process, or the second worker's fixture skips.  The topology is
+described inside a fixture, never at import.
+"""
+
+import collections
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any refusal means no compiler
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep it out.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _model_params(config):
+    """A benchmark configuration's ``model_params``, as the cell's job
+    gives them to ``model_spec``."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           config + ".json")) as fh:
+        return json.load(fh)["cli"]["model_params"]
+
+
+def _mosaic_calls(text):
+    return [l.strip() for l in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in l]
+
+
+def _names(text):
+    """How many Mosaic calls of a compiled program carry each name."""
+    names = [c.split(" = ")[0].lstrip("%") for c in _mosaic_calls(text)]
+    return collections.Counter(
+        re.sub(r"^(checkpoint_|jvp_|transpose_)+|(__)?[._]*\d+$", "", n)
+        for n in names)
+
+
+def _fused_computations(text):
+    """name -> the instructions of every fused computation of a
+    compiled program's text, each cut before its metadata."""
+    bodies, body = {}, None
+    for line in text.splitlines():
+        head = re.match(r"%?(fused_computation[\w.\-]*) .*\{$", line)
+        if head:
+            body = bodies.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            body = None
+        elif body is not None:
+            body.append(line.split(", metadata=")[0].strip())
+    return bodies
+
+
+def _updates_in_matmuls(text):
+    """The fused computations that hold both a matmul and the square
+    root of AdamW's update: a weight gradient with its update as the
+    epilogue."""
+    return [name for name, body in _fused_computations(text).items()
+            if any(" convolution(" in l for l in body)
+            and any(" sqrt(" in l for l in body)]
+
+
+def _products(text, result):
+    """How many fused computations of a compiled program's text hold a
+    matmul whose result is ``result`` (as ``bf16[16384,11008]``)."""
+    return len([body for body in _fused_computations(text).values()
+                if any(" = %s{" % result in l and " convolution(" in l
+                       for l in body)])
+
+
+def _step(spec, one_chip, batch, rows, room=None):
+    """A cell's whole training step (loss, gradients, AdamW) compiled
+    for the described chip from shapes."""
+    import optax
+
+    from elasticdl_tpu.ops import batch_shard
+
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+    state = jax.eval_shape(spec.optimizer.init, params)
+    tokens = jax.ShapeDtypeStruct((batch, rows), jnp.int32,
+                                  sharding=one_chip)
+
+    def step(params, state, tokens):
+        def loss(p):
+            with batch_shard.batch_axis(None, None, room):
+                out = spec.apply_fn(p, tokens, True)
+                return spec.loss_fn(out, tokens).mean()
+
+        value, grads = jax.value_and_grad(loss)(params)
+        updates, state2 = spec.optimizer.update(grads, state, params)
+        return optax.apply_updates(params, updates), state2, value
+
+    return jax.jit(step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(state), tokens)
