@@ -19,6 +19,19 @@ backward does not make again the products it reads from the kept
 stack.  No room stated (the CPU, a model-parallel mesh, the pipelined
 forward): nothing kept, the program ``jax.checkpoint(layer)`` always
 gave.
+
+The step's need is the larger of two places: the head with the stack's
+gradients standing, and a layer's backward with its second forward.
+What a layer holds there is counted from shapes by its kind: a dense
+layer's gate, up, product and a cotangent; an expert layer that XLA
+unrolls by the inventory of its dispatch's pullback
+(``dispatch_phases``) beside the operator's second forward
+(``_expert_layer``); and of an unrolled stack's gradients one layer's
+worth stands there, not the stack's (``grads_standing``).  A group
+scanned over several turns keeps its whole stacked gradient.  The sum
+is held to the chips' peaks cell by cell (the benchmark's
+``remat.estimate_over_gb``; tests/test_remat_keep.py) and to the TPU
+compiler's count of whole steps for a described v5e.
 """
 
 import contextlib
@@ -75,7 +88,8 @@ RESERVE = 0.05
 # What a kept GB of a kda layer's scan results (output, chunk-start
 # states, inverses) saves of its second forward, ms: ``kda_fwd``'s 3.8
 # ms a call over their 0.185 GB at the ``solar-open2-250b`` cell's
-# shape (my chip run, PR 49; PERF.md section 5).
+# shape (my chip run, PR 49; PERF.md section 5).  That cell keeps them
+# since PR 60 counts its dispatch's backward from shapes.
 KDA_SCAN = 21
 
 # The ``flash`` entry's names: the kernel's output and row statistics.
@@ -87,6 +101,18 @@ ATTN_NAMES = (flash_attention.KEEP_OUT, flash_attention.KEEP_LSE)
 DENSE_PRODUCTS = ("ffn_gate", "ffn_up")
 SHARED_PRODUCTS = (KEEP_SHARED_GATE, KEEP_SHARED_UP)
 EXPERT_PRODUCTS = ("moe_out", "moe_gate", "moe_up")
+# The entries of ``table`` that a layer's operator makes in its second
+# forward (``Kind.op``; latent attention's flash, q and kv are
+# ``_latent_layer``'s), and the two every expert layer makes beside
+# them: what of them is not kept stands until the operator's backward
+# has read it.
+OPERATOR_ENTRIES = {
+    "a": ("flash", "qkv", "latent", "gate"),
+    "c": ("conv_in", "conv_out"),
+    "d": ("delta_decay", "delta", "delta_gate", "delta_in", "delta_qkv",
+          "delta_rank"),
+}
+LAYER_ENTRIES = ("route", "hc_read")
 
 
 def _entries(cfg, rows):
@@ -142,11 +168,19 @@ def _entries(cfg, rows):
     # What a kept GB is worth, ms (``table``).  The dispatch's buffers
     # have ``row_bound`` rows, of which a balanced router fills
     # ``live``: a byte of them buys that share of what a byte of full
-    # rows buys.
+    # rows buys.  The down product is the gate's and the up product's
+    # matmul the other way round, [R, f] x [f, e]: the same operations
+    # for e / f times the bytes, so its byte buys f / e of theirs.
+    # Where every expert is held the kept array is that product moved
+    # back to the tokens' order, a gather as the sorted rows' is, ~8
+    # more (~14 at the widths of the trace it was read from, f = e / 2);
+    # under a share it is the product alone (``moe_dispatch._block``:
+    # the rows' sum follows it and is not kept).
     bound, live = _routed_rows(cfg, rows)
+    share = bool(x) and cfg.experts_held[1] != x
     routed = [
-        (14 * live, "moe_out", (moe_dispatch.KEEP_OUT,), bound * e * size,
-         experts),
+        ((12 * f / e + 8 * (not share)) * live, "moe_out",
+         (moe_dispatch.KEEP_OUT,), bound * e * size, experts),
         (12 * live, "moe_gate", (moe_dispatch.KEEP_GATE,),
          bound * f * size, experts),
         (12 * live, "moe_up", (moe_dispatch.KEEP_UP,), bound * f * size,
@@ -283,7 +317,8 @@ def table(cfg, rows):
     tokens a device, in
     the order of what a kept byte saves of the second forward (ms a GB,
     from the traces of PERF.md section 5: the flash forward ~18; the
-    down product, a grouped matmul and a gather, ~14; q, k, v
+    down product, a grouped matmul and a gather, ~14 (under a share
+    the matmul alone, f / e of the up product's by its shapes); q, k, v
     and the stream ~13; the FFN's products ~12, and by their shapes a
     short convolution's input ~11; the sorted rows, one gather, ~8; the
     convolution's result, a pass bound by memory, ~5; the flash
@@ -352,6 +387,149 @@ def _weight_copies(layers, copy):
     return copy(scanned) + sum(sorted(map(copy, unrolled))[-2:])
 
 
+def _kinds_apart(cfg, layers):
+    """``_unrolled`` for the layers' kinds (``cfg.kinds``, and
+    ``cfg.mtp_kind`` for each module ``_stack`` put behind the tail):
+    (the kinds of the groups scanned over several turns, the kinds of
+    the layers XLA unrolls)."""
+    kinds = cfg.kinds
+    if "period" not in layers:
+        lead, period, tail = (), kinds[:1], ()
+    else:
+        lead = kinds[:len(layers["lead"])]
+        period = kinds[len(lead):len(lead) + len(layers["period"])]
+        modules = sum(name.startswith("mtp") for name in layers["tail"])
+        tail = kinds[len(kinds) - len(layers["tail"]) + modules:] + (
+            cfg.mtp_kind,) * modules
+    scanned, _ = _unrolled(layers)
+    return (period, lead + tail) if scanned else ((), lead + tail + period)
+
+
+def _unrolled_experts(cfg, layers):
+    """The kinds of the expert layers that XLA unrolls: the layers whose
+    backward ``_expert_layer`` counts from shapes."""
+    return {kind for kind in _kinds_apart(cfg, layers)[1] if not kind.dense}
+
+
+def _decay_channels(cfg, rows, kept_decays):
+    """Bytes of a kda layer's decays a channel in its backward: the log
+    decays, their cumulative sums and both's cotangents, [rows, heads *
+    key_dim] float32 each (a scalar decay's are [rows, heads]:
+    nothing), less the plane that ``kept_decays`` bytes of kept
+    ``delta_decay`` take out."""
+    channels = rows * cfg.num_heads * cfg.delta_key_dim * 4
+    return 4 * channels - min(kept_decays, channels)
+
+
+def dispatch_phases(cfg, rows, kept=()):
+    """The inventory of an unrolled expert layer's dispatch
+    (``ops/moe_dispatch.py``) while the layer is back-propagated, from
+    shapes: ({phase of a block's pullback: bytes of the arrays that
+    stand in it}, bytes that stand through all of them), the arrays by
+    the names the op gives them, each in the dtype it makes it in, at
+    ``row_bound``'s R rows for n tokens of width e over experts f wide,
+    h of them held:
+
+     - the second forward's results that the pullback reads: the
+       sorted rows ``xs`` [R, e], ``gate`` and ``up`` [R, f], the down
+       product ``ys`` [R, e] (the combine's pullback weighs a row's
+       gate by it); ``act`` [R, f] is made again where it is read;
+     - the cotangents: ``g`` of the float32 [n, e] sum, ``d_ys`` and
+       ``d_xs`` [R, e] (the latter twice: a product each of ``d_gate``
+       and ``d_up``, then their sum), ``d_act``, ``d_gate``, ``d_up``
+       [R, f], a block's ``dx`` [n, e];
+     - the [R, 1, 1, words] copies ``ops/row_moves.py`` packs a moved
+       array into: of ``g`` (a float32 row its own words) and of
+       ``d_xs``; where a share's rows are too odd a width for the row
+       kernel (``moe_dispatch.rows_by_kernel`` on a chip: ``moe
+       dispatch: .. rows=reference``) the jnp moves' buffers instead:
+       the float32 [R, e] rows that ``g`` is gathered into
+       (``_rows_to_tokens_bwd``), and for ``dx`` the float32 copy of
+       ``d_xs`` and the float32 [n, e] sum it is scattered into
+       (``_tokens_to_rows_bwd``); with every expert held (the jnp
+       gathers by the sort and its inverse) the [R, e] claims that
+       ``g`` is gathered into;
+     - the three weight gradients [h, e, f] in the compute dtype, as
+       ``gmm_tn`` writes them before ``_updates_apart`` hands them on;
+     - the gates' gradients: float32 [n, K] as the combine's pullback
+       gives them, and float32 [n, X] once they reach the router's
+       logits;
+     - under a share, the further blocks' accumulators
+       (``_further_blocks_bwd``: zeros like x, the gates and the three
+       matrices).
+
+    The phases are the pullback's, in its order: the combine's (``ys``
+    and the packed ``g`` in, ``d_ys`` out), the down product's, the
+    gate's, the two products' and the gather's; the term is the largest
+    phase and what stands through them.  Every array is one the TPU
+    compiler's buffer assignment of ``trinity-mini``'s step for a
+    described v5e shows live in that layer (``rows_pack`` u32[32768, 1,
+    1, 1024], ``select_add_fusion`` bf16[32768, 2048], ``gmm_tn`` x 3
+    bf16[16, 2048, 1024], three ``broadcast`` of the same shape and one
+    bf16[16384, 2048], a ``fusion`` f32[16384, 2048]: PERF.md section
+    6, PR 60).
+
+    A kept entry (``moe_rows``, ``moe_gate``, ``moe_up``, ``moe_out``)
+    is read from the stack, so the second forward does not make its
+    array, and the stack's copy is dead once its last phase has read
+    it: a phase is less by the kept arrays read before it (``ys`` by
+    the combine's; ``gate`` and ``up`` by the gate's; ``xs`` by the
+    products').  That holds where every expert is held.  Under a share
+    a kept entry changes nothing: the further blocks' loop, which runs
+    before the first block's pullback, runs each block's forward again
+    whatever the first block keeps, and XLA assigns the loop's buffers
+    whether or not it turns.  A share's weight gradients wait for the
+    loop's sums, all three; without one each leaves through its own
+    barrier (``_updates_apart``) in the phase that makes it."""
+    size = jnp.dtype(cfg.dtype).itemsize
+    bound, _ = _routed_rows(cfg, rows)
+    held = cfg.experts_held[1]
+    share = held != cfg.moe_experts
+    # a room is stated on a chip alone: the kernel's own test of the
+    # widths there decides which moves the program holds
+    kernel = share and moe_dispatch.rows_by_kernel(
+        rows, bound, cfg.dim, cfg.dtype,
+        min(cfg.moe_top_k, cfg.moe_experts), "tpu")
+    wide, narrow = bound * cfg.dim * size, bound * cfg.mlp_dim * size
+    claims = bound * cfg.dim * 4
+    tokens = rows * cfg.dim * size
+    weight = held * cfg.dim * cfg.mlp_dim * size
+    g = rows * cfg.dim * 4
+    made = {"moe_rows": wide, "moe_gate": narrow, "moe_up": narrow,
+            "moe_out": wide}
+    own = [label for label in made if label in kept and not share]
+    read = lambda *labels: sum(
+        made[label] for label in labels if label not in own)
+    gone = lambda *labels: sum(
+        made[label] for label in labels if label in own)
+    phases = {
+        "combine": read(*made) + wide + (
+            g if kernel else claims if share else wide),
+        "down": read("moe_rows", "moe_gate", "moe_up") + wide + 2 * narrow
+        + weight - gone("moe_out"),
+        "gate": read("moe_rows", "moe_gate", "moe_up") + 3 * narrow + weight
+        - gone("moe_out"),
+        "products": read("moe_rows") + 2 * narrow + 2 * wide
+        + (3 if share else 2) * weight
+        - gone("moe_out", "moe_gate", "moe_up"),
+        "gather": (2 * wide if kernel or not share else wide + claims + g)
+        + tokens + 3 * share * weight - gone(*made),
+    }
+    choices = 4 * rows * min(cfg.moe_top_k, cfg.moe_experts)
+    router = 4 * rows * cfg.moe_experts
+    return phases, g + choices + router + share * (
+        tokens + 3 * weight + choices)
+
+
+def dispatch_bytes(cfg, rows, kept=()):
+    """``dispatch_phases`` as one number: the largest phase and what
+    stands through them; 0 for a model without experts."""
+    if not cfg.moe_experts:
+        return 0
+    phases, through = dispatch_phases(cfg, rows, kept)
+    return max(phases.values()) + through
+
+
 def grads_standing(cfg, params, rows, kept=()):
     """Bytes of the layer stack's gradients, which the caller counted
     whole, that stand where a layer's backward is the step's peak, with
@@ -375,21 +553,77 @@ def grads_standing(cfg, params, rows, kept=()):
        layer's).  So: ONE layer's worth, the largest, less what a
        layer done gives back (the kept entries that every layer
        makes).
-     - But only in a stack without expert layers, where the one-layer
-       term of ``step_bytes`` describes what a backward holds.  In a
-       stack with them the whole-gradient count is all that stands in
-       for the dispatch's 1.4-2.3 GB of temporaries, which that term
-       does not have (``OVER`` in tests/test_remat_keep.py): whole, as
-       before PR 50, until the dispatch's backward has an inventory
-       from shapes (ROADMAP A3 (t), C18)."""
-    scanned, unrolled = _unrolled(_stack(params))
+       That holds with expert layers or without: an unrolled expert
+       layer's term counts what its dispatch's backward holds from
+       shapes (``dispatch_phases``; until PR 60 the whole-gradient
+       count stood in for those 1.4-2.3 GB of temporaries).  One
+       unrolled layer alone has no layer before it: none.
+     - A stack that scans a group of EXPERT layers keeps the whole
+       count, for its leading and trailing layers too: no described
+       compile tells where a scanned stack's peak stands
+       (``kanana-2-30b-a3b``, PERF.md section 7), and there the chip
+       reads 0.03 GB under the estimate as it is."""
+    stack = _stack(params)
+    scanned, unrolled = _unrolled(stack)
     each = sorted(map(_nbytes, unrolled))
-    if not each or not all(kind.dense for kind in cfg.kinds):
+    scanned_kinds, _ = _kinds_apart(cfg, stack)
+    if not each or not all(kind.dense for kind in scanned_kinds):
         return _nbytes(scanned) + sum(each)
+    if len(each) == 1 and not scanned:
+        return 0
     given_back = sum(per_layer
                      for label, _, per_layer, layers in _entries(cfg, rows)
                      if label in kept and layers == cfg.num_layers)
     return _nbytes(scanned) + max(0, each[-1] - given_back)
+
+
+def _expert_layer(cfg, rows, kept, kind, sizes):
+    """Bytes an unrolled expert layer of ``kind`` holds while it is
+    back-propagated, beside what the stack keeps of it (``sizes``: the
+    table's bytes a layer by label).
+
+    While the FFN's pullback runs: the dispatch's inventory
+    (``dispatch_bytes``), the shared expert's gate, up, product and a
+    cotangent less its kept ones, the router's results and the wide
+    stream's reads where they are not kept, and four [n, e] planes: the
+    FFN's normed input (the dispatch's operand), the stream between
+    the sublayers (read from the stack where it is kept: three), the
+    cotangent that comes in and the one that leaves.
+
+    The operator's second forward: the entries of the table that it
+    makes and the stack does not keep (``OPERATOR_ENTRIES``; latent
+    attention's by ``_latent_layer``, with its backward), and a kda
+    layer's decays a channel (the log decays, their cumulative sums and
+    both's cotangents, [rows, heads * key_dim] float32 each; the log
+    decays are the ``delta_decay`` entry's plane, counted once: with
+    the entry where it is not kept, in the stack where it is).  They
+    stand beside the FFN's pullback, which needs the
+    operator's result, unless the stream between the sublayers is kept:
+    then the FFN's second forward starts from the kept stream, XLA runs
+    the operator's where its backward reads it, behind the FFN's
+    pullback (``solar-open2-250b``'s step with the stream kept holds
+    none of a KDA layer's operands at its peak, 0.35 GB of a second
+    forward where it holds 0.96 with nothing kept), and the term is the
+    larger of the two."""
+    size = jnp.dtype(cfg.dtype).itemsize
+    own = lambda labels: sum(sizes[label] for label in labels
+                             if label in kept)
+    free = lambda labels: sum(sizes[label] for label in labels
+                              if label in sizes and label not in kept)
+    planes = 4 - ("stream" in kept)
+    ffn = (dispatch_bytes(cfg, rows, kept) + planes * rows * cfg.dim * size
+           + rows * 4 * cfg.shared_dim * size - own(SHARED_PRODUCTS)
+           + free(LAYER_ENTRIES))
+    operator, backward = free(OPERATOR_ENTRIES[kind.op]), 0
+    if kind.op == "a" and cfg.latent:
+        residuals, backward = _latent_layer(cfg, rows, kept)
+        operator += residuals
+    if kind.op == "d" and cfg.delta_kind == "kda":
+        # the log decays are the entry's plane: with ``free`` or kept
+        operator += _decay_channels(cfg, rows, sizes["delta_decay"])
+    if "stream" in kept:
+        return max(ffn, operator + backward)
+    return max(ffn, backward) + operator
 
 
 def step_bytes(cfg, params, rows, kept=()):
@@ -402,7 +636,10 @@ def step_bytes(cfg, params, rows, kept=()):
        all of a scan's at once, two layers' where XLA unrolls);
      - the carries the scan saves, one stream a layer
        (``cfg.stream_width`` wide: ``hyper_streams`` times the hidden
-       size), a multi-token-prediction module's block a layer more;
+       size), a multi-token-prediction module's block a layer more
+       and the two normed [rows, dim] operands of its projection
+       (``transformer._mtp_module``: outside the block's checkpoint,
+       saved from the forward to the module's backward);
      - the larger of the two places the peak can be: the head
        (``ops/head_loss.py``: the logits, and their cotangent where the
        head is tied), while the stack's gradients, which the caller
@@ -412,27 +649,38 @@ def step_bytes(cfg, params, rows, kept=()):
        gradients stands there (``grads_standing``).  A kept product of
        that layer's own is read from the scan's stack and not made
        again, so it leaves the term: a dense layer's gate and up leave
-       their product and a cotangent; an expert layer's term, which
-       counts no cotangent, keeps half;
-     - for latent attention, whose operands are [rows, heads, width]
+       their product and a cotangent;
+     - an expert layer XLA unrolls: ``_expert_layer``, the dispatch's
+       inventory (``dispatch_phases``) with the shared expert's planes
+       and four of the stream beside the operator's second forward;
+       and what of the head stands into the first layer
+       back-propagated.  An expert layer of a group scanned over
+       several turns (``kanana-2-30b-a3b``) keeps the term it had,
+       ``row_bound x (dim + 2 mlp_dim)`` and half of it with its
+       products kept, beside the whole-gradient count;
+     - where no expert layer is unrolled: for latent attention, whose
+       operands are [rows, heads, width]
        each at 32 heads (``_latent_layer``): the attention residuals
        of the second forward that are not kept beside that backward,
-       or attention's own backward where it is the larger;
-     - for a kda layer, its decays a channel (four float32 planes of
+       or attention's own backward where it is the larger; for a kda
+       layer, its decays a channel (four float32 planes of
        [rows, heads * key_dim]);
-     - for a wide stream, four streams more in a layer's backward;
+     - for a wide stream, four streams more in a layer's backward (read
+       against ``xing4.0-29b-a4b``'s described compile in PR 60: beside
+       the seven carries its peak holds the second forward's
+       ``hc_post_fwd``, ``hc_post_bwd``'s cotangent, a ``broadcast`` of
+       zeros and the block's own input, bf16[8192, 14336] each);
      - less, at either place, what an untied embedding was counted
        for: its copy is read by the forward's first gather alone and
        its gradient is the last thing the backward makes.
 
     Held to the chips' measured peaks for the cells of the benchmark
-    (tests/test_remat_keep.py: -0.1 / +0.9 GB; +0.95 in the two cells
-    whose unrolled stack has expert layers and 2.4-2.6 GB of
-    gradients: ``OVER`` there says where the sum is off, and it is
-    the sum, no term of it) and to the TPU compiler's own count of the
-    two share cells' whole steps and of the dense hybrid cell's
-    (tests/test_flash_compile_tpu.py, tests/test_remat_compile_tpu.py:
-    over, by under 0.5 GB)."""
+    (tests/test_remat_keep.py: -0.1 / +0.9 GB; -0.1 / +0.5 in the six
+    cells whose unrolled stack has expert layers) and to the TPU
+    compiler's own count of the share cells' whole steps and of the
+    dense hybrid cell's (tests/test_step_compile_tpu.py,
+    tests/test_delta_step_compile_tpu.py: over, by under 0.5 GB with
+    the chosen list kept)."""
     dtype = jnp.dtype(cfg.dtype)
     size = dtype.itemsize
     copy = lambda tree: sum(a.size * size * (a.dtype != dtype)
@@ -442,6 +690,9 @@ def step_bytes(cfg, params, rows, kept=()):
     stack_grads = _nbytes(stack)
     stream = rows * cfg.stream_width * size
     carries = (cfg.num_layers + cfg.mtp_modules + 1) * stream
+    # a module's projection runs outside its block's checkpoint: its two
+    # normed operands stand from the forward to the module's backward
+    carries += cfg.mtp_modules * 2 * rows * cfg.dim * size
     # a multi-token-prediction module's logits stand beside the model's
     head = rows * cfg.vocab_size * size * (
         2 if cfg.tied_embeddings else 1) * (1 + cfg.mtp_modules)
@@ -449,30 +700,40 @@ def step_bytes(cfg, params, rows, kept=()):
              for label, _, per_layer, _ in _entries(cfg, rows)}
     own = lambda labels: sum(sizes[label] for label in labels
                              if label in kept)
+    experts = _unrolled_experts(cfg, stack)
     layer = 0
-    if cfg.moe_experts:
-        term = _routed_rows(cfg, rows)[0] * (
-            cfg.dim + 2 * cfg.mlp_dim) * size
-        layer = max(term - own(EXPERT_PRODUCTS), term // 2)
-        # the shared expert's gate, up, product and a cotangent
-        layer += rows * 4 * cfg.shared_dim * size - own(SHARED_PRODUCTS)
     if any(kind.dense for kind in cfg.kinds):
         f = cfg.dense_ffn_dim if cfg.moe_experts else cfg.mlp_dim
-        layer = max(layer, rows * 4 * f * size - own(DENSE_PRODUCTS))
-    if cfg.latent and any(kind.op == "a" for kind in cfg.kinds):
-        # attention's residuals stand through the FFN's backward, and
-        # its own backward follows where the FFN's was
-        residuals, backward = _latent_layer(cfg, rows, kept)
-        layer = max(layer, backward) + residuals
-    if cfg.delta_kind == "kda" and any(kind.op == "d" for kind in cfg.kinds):
-        # a decay a channel: the log decays, their cumulative sums and
-        # both's cotangents, [rows, heads * key_dim] float32 each (a
-        # scalar decay's are [rows, heads]: nothing), beside the FFN's
-        # term, which the other cells' slack has covered for the scan's
-        # own operands (the compiler's count of the cell's step: tests/
-        # test_flash_compile_tpu.py)
-        channels = rows * cfg.num_heads * cfg.delta_key_dim * 4
-        layer += 4 * channels - min(own(("delta_decay",)), channels)
+        layer = rows * 4 * f * size - own(DENSE_PRODUCTS)
+    if experts:
+        layer = max([layer] + [_expert_layer(cfg, rows, kept, kind, sizes)
+                               for kind in experts])
+        # what of the head stands into the first layer back-propagated:
+        # an untied head's weight gradient in the compute dtype; with a
+        # multi-token-prediction module the model's own logits, whose
+        # backward the module's block does not wait for
+        layer += size * (
+            rows * cfg.vocab_size * bool(cfg.mtp_modules)
+            + cfg.vocab_size * cfg.dim * (not cfg.tied_embeddings))
+    else:
+        if cfg.moe_experts:     # a scanned group's expert layers
+            term = _routed_rows(cfg, rows)[0] * (
+                cfg.dim + 2 * cfg.mlp_dim) * size
+            # the shared expert's gate, up, product and a cotangent
+            layer = max(layer, max(term - own(EXPERT_PRODUCTS), term // 2)
+                        + rows * 4 * cfg.shared_dim * size
+                        - own(SHARED_PRODUCTS))
+        if cfg.latent and any(kind.op == "a" for kind in cfg.kinds):
+            # attention's residuals stand through the FFN's backward,
+            # and its own backward follows where the FFN's was
+            residuals, backward = _latent_layer(cfg, rows, kept)
+            layer = max(layer, backward) + residuals
+        if cfg.delta_kind == "kda" and any(
+                kind.op == "d" for kind in cfg.kinds):
+            # beside the FFN's term, which the other cells' slack has
+            # covered for the scan's own operands (the compiler's count
+            # of the cell's step: tests/test_delta_step_compile_tpu.py)
+            layer += _decay_channels(cfg, rows, own(("delta_decay",)))
     if cfg.hyper_streams:
         # a wide stream in a layer's backward: the stream the second
         # forward makes between the sublayers and the one it ends on,
@@ -507,17 +768,18 @@ def choose(cfg, params, rows, room):
 
 
 @functools.lru_cache(maxsize=None)
-def announce_keep(names, kept, budget, need, peak, standing, layers, rows,
-                  fallback):
+def announce_keep(names, kept, budget, need, peak, standing, dispatch,
+                  layers, rows, fallback):
     """Once per compiled shape, by the logger ``announce_tiles`` uses:
     what the layer stack keeps for its backward, of one shard of the
-    trainer's data axis, and what of the stack's gradients the estimate
-    took to stand at the peak (``grads_standing``)."""
+    trainer's data axis, what of the stack's gradients the estimate
+    took to stand at the peak (``grads_standing``) and what it took an
+    expert layer's dispatch to hold there (``dispatch_bytes``)."""
     flash_attention.logger.info(
         "remat keep: names=%s bytes=%d budget=%d need=%d "
-        "predicted_peak=%d grads_standing=%d layers=%d rows=%d "
-        "fallback=%d", ",".join(names) or "-", kept, budget, need, peak,
-        standing, layers, rows, fallback)
+        "predicted_peak=%d grads_standing=%d dispatch=%d layers=%d "
+        "rows=%d fallback=%d", ",".join(names) or "-", kept, budget, need,
+        peak, standing, dispatch, layers, rows, fallback)
 
 
 _KEPT = contextvars.ContextVar("elasticdl_remat_kept", default=())
@@ -553,7 +815,11 @@ def names_for(cfg, params, tokens_shape):
     need = peak - kept - (room.limit - room.free)
     labels = [label for label, entry, _ in table(cfg, rows)
               if set(entry) <= set(names)]
+    # the inventory, where it is the count that chose the list: 0 for a
+    # stack that unrolls no expert layer
+    counted = bool(_unrolled_experts(cfg, _stack(params)))
     announce_keep(names, kept, budget, need, peak,
-                  grads_standing(cfg, params, rows, labels), cfg.num_layers,
-                  rows, int(not room.free))
+                  grads_standing(cfg, params, rows, labels),
+                  counted * dispatch_bytes(cfg, rows, labels),
+                  cfg.num_layers, rows, int(not room.free))
     return names

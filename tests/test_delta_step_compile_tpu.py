@@ -14,9 +14,10 @@ import pytest
 from elasticdl_tpu.models import remat_keep as rk, transformer as tfm
 from elasticdl_tpu.ops import batch_shard
 from elasticdl_tpu.ops.mode import SWITCH
-from tests.tpu_compile import (  # noqa: F401 (one_chip: a fixture)
-    _fused_computations, _model_params, _mosaic_calls, _names, _products,
-    _step, _updates_in_matmuls, one_chip)
+from tests.tpu_compile import (  # noqa: F401 (the fixtures)
+    V5E_LIMIT, _estimate, _fused_computations, _inventory_is_held,
+    _model_params, _mosaic_calls, _names, _products, _step,
+    _updates_in_matmuls, cell_steps, one_chip)
 
 
 @pytest.fixture(scope="module")
@@ -130,7 +131,7 @@ def test_the_delta_stacks_step_keeps_its_mlps_products_in_the_room_it_has(
 
 
 def test_the_kda_expert_stacks_step_fits_a_v5e_with_nothing_kept(
-        one_chip, monkeypatch):
+        cell_steps):
     """The ``solar-open2-250b.seq16384`` cell's whole training step (one
     sequence of 16,384 through a gated NoPE GQA layer at 8 query heads
     on 1 K/V head and three KDA layers at 8 of 64 heads, a 320-wide
@@ -138,27 +139,24 @@ def test_the_kda_expert_stacks_step_fits_a_v5e_with_nothing_kept(
     untied head over 24,576 ids, AdamW; 840,875,672 parameters) through
     the TPU's compiler with nothing kept: the configuration's condition
     for its 8-way head share, so the 16-way fallback is not taken.  The
-    scan runs once forward and once again in each KDA layer's backward."""
-    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
-    spec = tfm.model_spec(**_model_params("solar-open2-250b"))
-    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
-    state = jax.eval_shape(spec.optimizer.init, params)
+    scan runs once forward and once again in each KDA layer's backward
+    (the cell itself keeps the scans' results since PR 60 and runs them
+    once: ``..keeps_its_scans_results``)."""
+    step = cell_steps("solar-open2-250b", 1, 16384, False)
     nbytes = lambda tree: sum(
         a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
-    assert nbytes(params) == 4 * 840875672
-    held = 2 * nbytes(params) + nbytes(state)
-
-    compiled = _step(spec, one_chip, 1, 16384).compile()
-    stats = compiled.memory_analysis()
-    counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
+    assert nbytes(step.params) == 4 * 840875672
+    counted = step.counted
     # 14.98 GB of the 16.91 (the chip's peak with 1.0 GB kept: 15.08)
-    assert counted < 0.95 * 16911433728, counted
+    assert counted < 0.95 * V5E_LIMIT, counted
     assert 14.9e9 < counted < 15.1e9, counted
-    # ``remat_keep``'s estimate stands over it, by the kda layer's
-    # decays a channel (+0.11 GB; -0.16 without that term)
-    estimate = held + rk.step_bytes(spec.config, params, 16384)
+    # ``remat_keep``'s estimate stands over it (+0.46 GB: a KDA expert
+    # layer's operands and decays a channel counted beside the
+    # dispatch's inventory, where the compiler's peak holds the head's
+    # logits instead, 0.81 GB whose weight gradient it makes late)
+    estimate = _estimate(step, 16384, False)
     assert 0 < estimate - counted < 0.5e9, (estimate, counted)
-    text = compiled.as_text()
+    text = step.compiled.as_text()
     names = [c.split(" = ")[0].lstrip("%") for c in _mosaic_calls(text)]
     count = lambda name: len([c for c in names if re.search(
         r"(^|_)" + name + r"(__)?\.\d+$", c)])
@@ -167,6 +165,53 @@ def test_the_kda_expert_stacks_step_fits_a_v5e_with_nothing_kept(
     assert (count("sconv_silu_fwd"), count("sconv_silu_bwd")) == (6, 3)
     assert (count("flash_fwd"), count("flash_bwd")) == (2, 1), names
     assert not _updates_in_matmuls(text)
+
+
+def test_the_kda_expert_stacks_step_keeps_its_scans_results(cell_steps):
+    """The same cell's step with the names ``remat_keep`` chooses since
+    PR 60, the scans' outputs, chunk-start states and inverses (0.55 GB
+    for the three layers) among them: ``kda_fwd`` runs three times a
+    step, once a layer, where it ran six (the second forward reads the
+    kept results), and the compiler's count, 15.87 GB, stays under the
+    limit less the reserve and under the predicted 15.97.  The one
+    whole-step compile PR 60 adds to tier-1 (67 s here, under ROADMAP
+    C16's 90 s a case); the chip's own run of the cell is its other
+    witness (PERF.md section 6, PR 60)."""
+    step = cell_steps("solar-open2-250b", 1, 16384, True)
+    names, kept, budget, peak = step.chosen
+    from elasticdl_tpu.ops import gated_delta
+
+    assert {gated_delta.KEEP_OUT, gated_delta.KEEP_STATES,
+            gated_delta.KEEP_INVERSE} <= set(names), names
+    assert kept <= budget and peak <= (1 - rk.RESERVE) * V5E_LIMIT
+    assert step.counted < (1 - rk.RESERVE) * V5E_LIMIT, step.counted
+    calls = _names(step.compiled.as_text())
+    assert (calls["kda_fwd"], calls["kda_bwd"]) == (3, 3), calls
+
+
+# (configuration, sequences, their length, whether ``choose``'s list is
+# kept) of this file's compiles of unrolled stacks with expert layers;
+# the hybrid's slow as the test is that makes its compile
+EXPERT_STEPS = [
+    ("solar-open2-250b", 1, 16384, False),
+    ("solar-open2-250b", 1, 16384, True),
+    pytest.param("ling-3.0-flash", 1, 16384, False,
+                 marks=pytest.mark.slow),
+]
+
+
+@pytest.mark.parametrize("config,batch,rows,keep", EXPERT_STEPS)
+def test_the_expert_layers_inventory_is_held_to_the_compilers_count(
+        cell_steps, config, batch, rows, keep):
+    """``tests/test_step_compile_tpu.py``'s test of the same name, for
+    the delta-rule cells with expert layers: ``remat_keep``'s predicted
+    peak against the TPU compiler's count of the whole step: over it
+    by under 0.5 GB with ``choose``'s list kept (the KDA cell +0.15),
+    by under 0.9 with nothing kept (+0.46; the linear / latent hybrid's
+    +0.08).  The compiles are this file's other tests'
+    (``cell_steps``)."""
+    _inventory_is_held(cell_steps(config, batch, rows, keep), batch, rows,
+                       keep)
 
 
 # -- the linear / latent hybrid's step (PR 56) --------------------------------
@@ -199,7 +244,7 @@ def test_the_hybrids_parameters_are_the_configurations_count():
 
 @pytest.mark.slow
 def test_the_linear_latent_hybrids_step_fits_a_v5e_with_nothing_kept(
-        one_chip, monkeypatch):
+        cell_steps):
     """The ``ling-3.0-flash.seq16384`` cell's whole training step (one
     sequence of 16,384 through six KDA layers with full projections
     under the bounded gate and a head-gated latent layer at 8 of 32
@@ -209,23 +254,16 @@ def test_the_linear_latent_hybrids_step_fits_a_v5e_with_nothing_kept(
     the TPU's compiler with nothing kept: 12.13 GB of a v5e's 16.91,
     which leaves ``remat_keep`` 4 GB to keep.  Marked slow: the one
     program takes four minutes to compile here (my run, PR 56)."""
-    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
-    spec = tfm.model_spec(**_model_params("ling-3.0-flash"))
-    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
-    state = jax.eval_shape(spec.optimizer.init, params)
-    nbytes = lambda tree: sum(
-        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
-    held = 2 * nbytes(params) + nbytes(state)
-    compiled = _step(spec, one_chip, 1, 16384).compile()
-    stats = compiled.memory_analysis()
-    counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
+    step = cell_steps("ling-3.0-flash", 1, 16384, False)
+    counted = step.counted
     assert 12.0e9 < counted < 12.3e9, counted
-    # ``remat_keep``'s estimate stands over it (+0.69 GB: the stack's
-    # gradients counted whole where expert layers are unrolled, as in
-    # the other share cells)
-    estimate = held + rk.step_bytes(spec.config, params, 16384)
-    assert 0.4e9 < estimate - counted < 1.0e9, (estimate, counted)
-    names = _names(compiled.as_text())
+    # ``remat_keep``'s estimate stands over it since PR 60 (+0.08 GB;
+    # +0.69 while the unrolled expert layers' gradients were counted
+    # whole; -0.09 without the module's two normed operands, [16384,
+    # 2560] each from the forward to the module's backward)
+    estimate = _estimate(step, 16384, False)
+    assert 0 < estimate - counted < 0.5e9, (estimate, counted)
+    names = _names(step.compiled.as_text())
     # six scans forward, again in each layer's backward, once back
     assert (names["kda_fwd"], names["kda_bwd"]) == (12, 6), names
     assert (names["sconv_silu_fwd"], names["sconv_silu_bwd"]) == (12, 6)

@@ -672,10 +672,13 @@ def test_remat_keeps_table_counts_each_kinds_layers():
     assert bound == 128 and entries["moe_gate"][1] == bound * 48 * 2
     assert entries["moe_rows"][1] == entries["moe_out"][1] == bound * 128 * 2
     # by what a GB of them is worth: a balanced router fills half the
-    # bound, so a share's go at half their worth; all held, at all of it
+    # bound, so a share's go at half their worth, its down product (the
+    # matmul alone, 48 wide into 128: 48 / 128 of the up product's a
+    # byte) last; all held, at all of it, the down product's with its
+    # gather
     order = [label for label, _, _ in rk.table(cfg, rows)]
-    assert order[4:] == ["ffn_gate", "ffn_up", "conv_in", "moe_out",
-                         "moe_gate", "moe_up", "conv_out", "moe_rows"]
+    assert order[4:] == ["ffn_gate", "ffn_up", "conv_in", "moe_gate",
+                         "moe_up", "conv_out", "moe_rows", "moe_out"]
     held_all = dataclasses.replace(cfg, moe_experts_held=0,
                                    moe_share_index=0)
     entries_all = {e[0]: e for e in rk._entries(held_all, rows)}

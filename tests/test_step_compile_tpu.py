@@ -18,34 +18,20 @@ import jax
 import pytest
 
 from elasticdl_tpu.models import transformer as tfm
-from tests.tpu_compile import (  # noqa: F401 (one_chip: a fixture)
-    _model_params, _names, _step, _updates_in_matmuls, one_chip)
+from tests.tpu_compile import (  # noqa: F401 (the fixtures)
+    V5E_LIMIT, _estimate, _inventory_is_held, _model_params, _names, _step,
+    _updates_in_matmuls, cell_steps, one_chip)
 
 @pytest.fixture(scope="module")
-def banded_step(one_chip):
+def banded_step(cell_steps):
     """The ``smallthinker-21b-a3b.seq16384`` cell's whole training step
     compiled once for the tests that read it (a minute): what
     ``remat_keep`` chose, and the compiled program."""
-    from elasticdl_tpu.models import remat_keep as rk
-    from elasticdl_tpu.ops import batch_shard
-    from elasticdl_tpu.ops.mode import SWITCH
-
-    with pytest.MonkeyPatch.context() as patch:
-        patch.setenv(SWITCH, "tpu")       # the ops' own choice on a chip
-        spec = tfm.model_spec(**_model_params("smallthinker-21b-a3b"))
-        params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
-        state = jax.eval_shape(spec.optimizer.init, params)
-        rows = 16384
-        nbytes = lambda tree: sum(
-            a.size * a.dtype.itemsize
-            for a in jax.tree_util.tree_leaves(tree))
-        assert nbytes(params) == 4 * 656529920      # 656.5 M parameters
-        limit = 16911433728       # a v5e's bytes_limit (chip run, PR 29)
-        held = 2 * nbytes(params) + nbytes(state)
-        room = batch_shard.DeviceRoom(limit, limit - held)
-        chosen = rk.choose(spec.config, params, rows, room)
-        compiled = _step(spec, one_chip, 1, rows, room).compile()
-    return limit, chosen, compiled
+    step = cell_steps("smallthinker-21b-a3b", 1, 16384, True)
+    nbytes = lambda tree: sum(
+        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
+    assert nbytes(step.params) == 4 * 656529920      # 656.5 M parameters
+    return V5E_LIMIT, step.chosen, step.compiled
 
 
 def test_the_banded_stacks_step_fits_a_v5e_as_remat_keep_predicts(
@@ -109,33 +95,8 @@ def test_the_banded_stacks_step_scatters_no_row_into_the_table(
     assert counted <= 15224888320 + 2 ** 20, counted
 
 
-@pytest.fixture(scope="module")
-def mixed_cell():
-    """The ``lfm2-24b-a2b.seq8192`` cell from shapes, for the two tests
-    that each compile its step: (the spec, its abstract parameters, the
-    bytes the trainer holds beside the step, a v5e's limit)."""
-    from elasticdl_tpu.ops.mode import SWITCH
-
-    with pytest.MonkeyPatch.context() as patch:
-        patch.setenv(SWITCH, "tpu")       # the ops' own choice on a chip
-        spec = tfm.model_spec(**_model_params("lfm2-24b-a2b"))
-    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
-    state = jax.eval_shape(spec.optimizer.init, params)
-    nbytes = lambda tree: sum(
-        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
-    limit = 16911433728           # a v5e's bytes_limit (chip run, PR 29)
-    return spec, params, 2 * nbytes(params) + nbytes(state), limit
-
-
-def _counted(lowered):
-    """The compiler's own byte count of a step: arguments + temporaries
-    (the updated state aliases the donated one)."""
-    stats = lowered.compile().memory_analysis()
-    return stats.argument_size_in_bytes + stats.temp_size_in_bytes
-
-
 def test_the_mixed_stacks_step_fits_a_v5e_as_remat_keep_predicts(
-        one_chip, monkeypatch, mixed_cell):
+        cell_steps):
     """The ``lfm2-24b-a2b.seq8192`` cell's whole training step (4
     sequences of 8,192 through a dense conv layer and a period of
     attention + 3 conv layers over 8 of 64 experts, AdamW) through the
@@ -144,86 +105,112 @@ def test_the_mixed_stacks_step_fits_a_v5e_as_remat_keep_predicts(
     temporaries; the updated state aliases the donated one): over, never
     under.  This is the band that guards the chip: the cell runs under
     these names.
-    With the names chosen, the convolutions' input and the experts' up
-    product among them, 5.55 GB: 15.81 against 15.41 (+0.40, inside
-    -0.1 / +0.5; the parent read 15.87 against 13.43 with 3.53 GB kept:
-    the dense layer's kept gate and up stood in the need as well, and
-    five layers' weight copies where two stand at once).  What is left
-    over is not a term of the estimate's but their sum: by the buffer
-    assignment the peak is in the first expert layer back-propagated,
-    where no gradient of the stack exists yet (1.8 GB counted) and the
-    dispatch's temporaries and the tied head's cotangent (2.8 GB) stand
-    where the estimate has the dense layer's 1.54: PERF.md section 7.
+    With the names chosen, the convolutions' result and the sorted rows
+    beside PR 58's ten entries since PR 60 (6.62 GB): 15.60 against
+    15.37 (+0.23; PR 58's tree read 15.81 against 15.41 with 5.55 GB
+    kept, the stack's 1.8 GB of gradients counted whole where the
+    dispatch's temporaries stood).  The count does not grow with the
+    list: 15.39 with the ten entries PR 58 kept, 14.95 with the
+    convolutions' result beside them, 15.37 with the sorted rows too;
+    in the first the tied head's cotangent (0.54 GB) still stands in
+    the first layer back-propagated, in the second it does not
+    (PERF.md section 6, PR 60).
     (The estimate with nothing kept is
     ``..step_with_nothing_kept_is_under_remat_keeps_estimate``'s, over a
     compile of its own: one compile a question.)"""
     from elasticdl_tpu.models import remat_keep as rk
-    from elasticdl_tpu.ops import batch_shard, moe_dispatch, short_conv
-    from elasticdl_tpu.ops.mode import SWITCH
+    from elasticdl_tpu.ops import moe_dispatch, short_conv
 
-    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
-    spec, params, held, limit = mixed_cell
-    room = batch_shard.DeviceRoom(limit, limit - held)
-    names, kept, budget, peak = rk.choose(spec.config, params, 32768, room)
+    step = cell_steps("lfm2-24b-a2b", 4, 8192, True)
+    names, kept, budget, peak = step.chosen
     assert kept <= budget
     assert set(names) >= set(rk.ATTN_NAMES) | {
         rk.KEEP_STREAM, rk.KEEP_GATE, rk.KEEP_UP, short_conv.KEEP_IN,
-        moe_dispatch.KEEP_UP}, names
-    with_names = _counted(_step(spec, one_chip, 4, 8192, room))
-    assert peak <= (1 - rk.RESERVE) * limit
-    assert -0.1e9 < peak - with_names < 0.5e9, (peak, with_names, names)
+        short_conv.KEEP_OUT, moe_dispatch.KEEP_UP,
+        moe_dispatch.KEEP_ROWS}, names
+    assert peak <= (1 - rk.RESERVE) * V5E_LIMIT
+    assert 0 < peak - step.counted < 0.5e9, (peak, step.counted, names)
 
 
 def test_the_mixed_stacks_step_with_nothing_kept_is_under_remat_keeps_estimate(
-        one_chip, monkeypatch, mixed_cell):
+        cell_steps):
     """The same cell's step with no room stated, so with nothing kept
     (no cell runs so: the trainer states the room): ``remat_keep``'s
     estimate of the step's own need, ``step_bytes``, the term every
     choice starts from, is held to the compiler's byte count apart from
-    what the kept names add.  11.80 GB against the compiler's 9.80
-    (+2.01: it never holds all the gradients the trainer counted, a
-    layer's AdamW update runs behind its backward; 10.34 and +1.47 until
-    PR 42, whose attention layer no longer makes K and V at the query
-    heads nor the token-major copies of q and the output, 0.54 GB the
-    estimate never had a term for: the band's upper edge moved from 1.6
-    to 2.1 with it).  A test of its own so that the durations tell its
-    compile from the kept names' (ROADMAP C16 asks what it buys)."""
-    from elasticdl_tpu.models import remat_keep as rk
-    from elasticdl_tpu.ops.mode import SWITCH
-
-    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
-    spec, params, held, _ = mixed_cell
-    estimate = held + rk.step_bytes(spec.config, params, 32768)
-    nothing_kept = _counted(_step(spec, one_chip, 4, 8192, None))
-    assert -0.1e9 < estimate - nothing_kept < 2.1e9, (
-        estimate, nothing_kept)
+    what the kept names add.  10.36 GB against the compiler's 9.80
+    (+0.57) since PR 60 counts one layer's worth of this unrolled
+    stack's gradients (11.80 and +2.01 while it counted all 1.8 GB of
+    them): what is left over is the leading dense layer's term (gate,
+    up, their product and a cotangent, 3.09 GB), which stands over the
+    expert layers' (2.51) though that layer is the last back-propagated,
+    where nothing of the head and no kept value is left.  A test of its
+    own so that the durations tell its compile from the kept names'
+    (ROADMAP C16 asks what it buys)."""
+    step = cell_steps("lfm2-24b-a2b", 4, 8192, False)
+    estimate = _estimate(step, 32768, False)
+    assert -0.1e9 < estimate - step.counted < 0.9e9, (estimate, step.counted)
 
 
 def test_the_gated_blocks_step_holds_no_update_in_a_matmul_nor_more_bytes(
-        one_chip, monkeypatch):
+        cell_steps):
     """The ``trinity-mini.seq16384`` cell's whole training step with the
     names ``remat_keep`` chose, for a described v5e: no weight-gradient
     matmul carries an AdamW update (39 did until PR 46) and the
-    compiler's bytes are the parent's 14.69 GB within 0.1 (14.72): the
-    guard against holding ``embed`` and ``lm_head`` apart as well, which
-    reads 15.82."""
-    from elasticdl_tpu.ops import batch_shard
-    from elasticdl_tpu.ops.mode import SWITCH
+    compiler's bytes are 15.33 GB: PR 58's 14.69 and the routed up
+    product and the sorted rows that PR 60's list keeps beside PR 58's
+    (0.81 GB; the guard against holding ``embed`` and ``lm_head`` apart
+    as well, which read 15.82 where this read 14.72).  No ``.remat``
+    stands in it: with the routed down product kept in the sorted rows'
+    place, the same bytes, the compiler makes the head's logits a
+    second time (``fusion.2893.remat`` and ``gte.remat``, the [16384,
+    25024] product) to count 15.40, and the chip ran that step 1.1%
+    slower than the parent where it runs this one 0.8% faster (PERF.md
+    section 6, PR 60).  A share's down product stands behind the rows by
+    what its shapes say it is worth (``remat_keep._entries``), and this
+    cell's room ends before it."""
+    from elasticdl_tpu.ops import moe_dispatch
 
-    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
-    spec = tfm.model_spec(**_model_params("trinity-mini"))
-    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
-    nbytes = lambda tree: sum(
-        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
-    limit = 16911433728           # a v5e's bytes_limit (chip run, PR 29)
-    held = 2 * nbytes(params) + nbytes(
-        jax.eval_shape(spec.optimizer.init, params))
-    compiled = _step(spec, one_chip, 1, 16384,
-                     batch_shard.DeviceRoom(limit, limit - held)).compile()
-    stats = compiled.memory_analysis()
-    counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
-    assert abs(counted - 14.693e9) < 0.1e9, counted
-    assert not _updates_in_matmuls(compiled.as_text())
+    step = cell_steps("trinity-mini", 1, 16384, True)
+    assert {moe_dispatch.KEEP_UP, moe_dispatch.KEEP_ROWS} <= set(
+        step.chosen[0])
+    assert moe_dispatch.KEEP_OUT not in step.chosen[0]
+    assert abs(step.counted - 15.325e9) < 0.1e9, step.counted
+    text = step.compiled.as_text()
+    assert not _updates_in_matmuls(text)
+    assert ".remat" not in text
+
+
+# (configuration, sequences, their length, whether ``choose``'s list is
+# kept) of this file's compiles of unrolled stacks with expert layers,
+# the wide stream's marked slow as its own test is
+EXPERT_STEPS = [
+    ("smallthinker-21b-a3b", 1, 16384, True),
+    ("lfm2-24b-a2b", 4, 8192, True),
+    ("lfm2-24b-a2b", 4, 8192, False),
+    ("trinity-mini", 1, 16384, True),
+    pytest.param("xing4.0-29b-a4b", 2, 4096, False,
+                 marks=pytest.mark.slow),
+]
+
+
+@pytest.mark.parametrize("config,batch,rows,keep", EXPERT_STEPS)
+def test_the_expert_layers_inventory_is_held_to_the_compilers_count(
+        cell_steps, config, batch, rows, keep):
+    """``remat_keep``'s predicted peak of an unrolled stack with expert
+    layers, whose layer term is the dispatch's inventory from shapes
+    (``dispatch_phases``, ``_expert_layer``) beside one layer's worth of
+    gradients (``grads_standing``), against the TPU compiler's own count
+    of the whole step, with ``choose``'s list kept and with nothing
+    kept: over it by under 0.5 GB with the list the cell runs under
+    (smallthinker +0.16, lfm2 +0.23, trinity +0.25) and by under
+    0.9 with nothing kept, a step no cell runs (lfm2 +0.57: its
+    leading dense layer's term decides, not the inventory;
+    the wide stream's +0.06).  The compiles are the ones this file's
+    other tests read (``cell_steps``): no program is compiled for this
+    test alone."""
+    _inventory_is_held(cell_steps(config, batch, rows, keep), batch, rows,
+                       keep)
 
 
 def test_a_scan_of_several_turns_is_handed_to_the_compiler_as_it_was(
@@ -250,40 +237,35 @@ def test_a_scan_of_several_turns_is_handed_to_the_compiler_as_it_was(
 
 
 @pytest.mark.slow
-def test_the_wide_streams_step_fits_a_v5e_with_nothing_kept(one_chip,
-                                                            monkeypatch):
+def test_the_wide_streams_step_fits_a_v5e_with_nothing_kept(cell_steps):
     """The cell's whole training step (two sequences of 4,096 through a
     dense layer, four expert layers and the module's block on a stream
     four wide, 8 of 32 heads and 8 of 64 experts held, two passes of an
     untied head over 16,384 ids, AdamW; 807,416,462 parameters) through
-    the TPU's compiler with nothing kept: 15.54 GB of a v5e's 16.91
-    (the chip's own peak reads 15.50, my chip runs, PR 54),
+    the TPU's compiler with nothing kept: 15.48 GB of a v5e's 16.91
+    (the chip's own peak reads 15.43: ledger, PR 58),
     the configuration's condition for 8 heads and two sequences, so
     neither fallback is taken.  Scanned (``scan_periods`` at its
     default) the same step counts 18.25 GB: the four expert layers'
     stacked gradient stands whole.  Marked slow: the one program takes
     two minutes to compile here (my run, PR 54)."""
-    from elasticdl_tpu.models import remat_keep as rk
-    from elasticdl_tpu.ops.mode import SWITCH
-
-    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
-    spec = tfm.model_spec(**_model_params("xing4.0-29b-a4b"))
-    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
-    state = jax.eval_shape(spec.optimizer.init, params)
+    step = cell_steps("xing4.0-29b-a4b", 2, 4096, False)
     nbytes = lambda tree: sum(
         a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
-    assert nbytes(params) == 4 * 807416462
-    held = 2 * nbytes(params) + nbytes(state)
-
-    compiled = _step(spec, one_chip, 2, 4096).compile()
-    stats = compiled.memory_analysis()
-    counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
-    assert counted < 0.95 * 16911433728, counted
+    assert nbytes(step.params) == 4 * 807416462
+    compiled, counted = step.compiled, step.counted
+    assert counted < 0.95 * V5E_LIMIT, counted
     assert 15.4e9 < counted < 15.7e9, counted
-    # ``remat_keep``'s estimate stands over it by the stack's gradients,
-    # counted whole where expert layers are unrolled (ROADMAP A3 (t))
-    estimate = held + rk.step_bytes(spec.config, params, 2 * 4096)
-    assert 0.9e9 < estimate - counted < 1.5e9, (estimate, counted)
+    # ``remat_keep``'s estimate stands over it since PR 60 (+0.06 GB;
+    # +1.17 while the unrolled expert layers' gradients were counted
+    # whole): the wide stream's four planes in a layer's backward are
+    # there (the second forward's ``hc_post_fwd``, ``hc_post_bwd``'s
+    # cotangent, a ``broadcast`` of zeros and the block's input beside
+    # seven carries, bf16[8192, 14336] each), both passes' logits and
+    # the module's two normed operands ([8192, 3584] each, outside its
+    # block's checkpoint: -0.06 without them)
+    estimate = _estimate(step, 2 * 4096, False)
+    assert 0 < estimate - counted < 0.5e9, (estimate, counted)
     text = compiled.as_text()
     names = _names(text)
     # twelve sublayers: read twice (the second forward), written twice

@@ -15,6 +15,7 @@ described inside a fixture, never at import.
 """
 
 import collections
+import functools
 import json
 import os
 import re
@@ -126,3 +127,74 @@ def _step(spec, one_chip, batch, rows, room=None):
 
     return jax.jit(step, donate_argnums=(0, 1)).lower(
         on_chip(params), on_chip(state), tokens)
+
+
+# a v5e's bytes_limit (chip run, PR 29)
+V5E_LIMIT = 16911433728
+
+CellStep = collections.namedtuple(
+    "CellStep", "spec params held chosen compiled counted")
+
+
+def _cell_step(one_chip, config, batch, rows, keep):
+    """A benchmark cell's whole training step compiled for the described
+    chip, with what ``remat_keep`` chooses under a v5e's room kept
+    (``keep``) or with no room stated, so with nothing kept: (the spec,
+    its abstract parameters, the bytes the trainer holds beside the
+    step, ``choose``'s answer under the room, the compiled program, the
+    compiler's own byte count of it: arguments + temporaries, the
+    updated state aliasing the donated one)."""
+    from elasticdl_tpu.models import remat_keep as rk, transformer as tfm
+    from elasticdl_tpu.ops import batch_shard
+    from elasticdl_tpu.ops.mode import SWITCH
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setenv(SWITCH, "tpu")       # the ops' own choice on a chip
+        spec = tfm.model_spec(**_model_params(config))
+        params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+        state = jax.eval_shape(spec.optimizer.init, params)
+        nbytes = lambda tree: sum(
+            a.size * a.dtype.itemsize
+            for a in jax.tree_util.tree_leaves(tree))
+        held = 2 * nbytes(params) + nbytes(state)
+        room = batch_shard.DeviceRoom(V5E_LIMIT, V5E_LIMIT - held)
+        chosen = rk.choose(spec.config, params, batch * rows, room)
+        compiled = _step(spec, one_chip, batch, rows,
+                         room if keep else None).compile()
+    stats = compiled.memory_analysis()
+    return CellStep(spec, params, held, chosen, compiled,
+                    stats.argument_size_in_bytes + stats.temp_size_in_bytes)
+
+
+@pytest.fixture(scope="module")
+def cell_steps(one_chip):
+    """``_cell_step`` of the module's chip, each (configuration, batch,
+    rows, keep) compiled once for all the tests of a file that read it
+    (a whole step is a minute or more of one worker: ROADMAP C16)."""
+    return functools.lru_cache(maxsize=None)(
+        functools.partial(_cell_step, one_chip))
+
+
+def _estimate(step, rows, keep):
+    """``remat_keep``'s predicted peak for a ``CellStep``: ``choose``'s
+    with its list kept, the trainer's state and ``step_bytes`` with
+    nothing kept."""
+    from elasticdl_tpu.models import remat_keep as rk
+
+    if keep:
+        return step.chosen[3]
+    return step.held + rk.step_bytes(step.spec.config, step.params, rows)
+
+
+def _inventory_is_held(step, batch, rows, keep):
+    """The body of the two step-compile files' test of the same name:
+    ``remat_keep``'s predicted peak of an unrolled stack with expert
+    layers against the compiler's count of a ``CellStep``, over and
+    never under: by under 0.5 GB with ``choose``'s list kept, the step a
+    cell runs; by under 0.9 with nothing kept (a step no cell runs)."""
+    from elasticdl_tpu.models import remat_keep as rk
+
+    estimate = _estimate(step, batch * rows, keep)
+    assert 0 < estimate - step.counted < (0.5e9 if keep else 0.9e9), (
+        estimate, step.counted)
+    assert rk.dispatch_bytes(step.spec.config, batch * rows) > 0
