@@ -42,7 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from elasticdl_tpu.ops import (batch_shard, flash_attention, gated_delta,
-                               hyper_mix, moe_dispatch, short_conv)
+                               hyper_mix, moe_dispatch, short_conv, ssd)
 
 # Named in models/transformer.py: q, k, v as the attention takes them
 # (after RoPE and the QK norm), the stream after the operator's
@@ -79,6 +79,12 @@ KEEP_DELTA_DECAY, KEEP_DELTA_GATE = "delta_decay", "delta_gate"
 # (none under full projections, ``delta_rank`` 0); its ``delta_decay``
 # is [rows, heads * key_dim] float32, a channel each.
 KEEP_DELTA_RANK = "delta_rank"
+# A Mamba-2 layer's (``transformer._ssm_mix``): the gate z's projection,
+# the projection of x | B | C before and after its convolution and SiLU,
+# the log decays and steps, [rows, heads] float32 each.  The scan's own
+# two are ``ssd.KEEP_OUT`` and ``ssd.KEEP_STATES``.
+KEEP_SSM_GATE, KEEP_SSM_IN, KEEP_SSM_XBC = "ssm_gate", "ssm_in", "ssm_xbc"
+KEEP_SSM_DECAY = "ssm_decay"
 
 # The share of the device's limit nothing is planned into: the
 # allocator's fragmentation, the batches in flight, whatever
@@ -111,6 +117,8 @@ OPERATOR_ENTRIES = {
     "c": ("conv_in", "conv_out"),
     "d": ("delta_decay", "delta", "delta_gate", "delta_in", "delta_qkv",
           "delta_rank"),
+    "m": ("ssm_decay", "ssm", "ssm_gate", "ssm_in", "ssm_xbc"),
+    "e": (),
 }
 LAYER_ENTRIES = ("route", "hc_read")
 
@@ -126,8 +134,12 @@ def _entries(cfg, rows):
     conv = sum(kind.op == "c" for kind in kinds)
     delta = sum(kind.op == "d" for kind in kinds)
     d_k, d_v = cfg.delta_key_dim, cfg.delta_value_dim
-    dense = sum(kind.dense for kind in kinds)
-    experts = len(kinds) - dense
+    ssm = sum(kind.op == "m" for kind in kinds)
+    # a layer without an FFN (``Kind.ffn``) counts as dense and has none
+    dense = sum(kind.dense and kind.ffn for kind in kinds)
+    experts = sum(not kind.dense for kind in kinds)
+    # an MLP of two matrices has no gate product to keep
+    gateless = not cfg.gated_mlp
     latent = cfg.latent
     if latent:
         rank, d_nope, d_rope, d = latent    # d: a value head's, out's
@@ -162,8 +174,10 @@ def _entries(cfg, rows):
         # whole stream read for a 128-lane tile of ``heads`` values a row
         entries.append(("gate", (KEEP_ATTN_GATE,), rows * size * (
             _lanes(h) if cfg.attn_gate == "head" else h * d), attention))
+    # the stream BETWEEN the sublayers: a layer of one has none
     entries.append(("stream", (KEEP_STREAM,),
-                    rows * cfg.stream_width * size, len(kinds)))
+                    rows * cfg.stream_width * size,
+                    sum(kind.ffn and kind.op != "e" for kind in kinds)))
     f, dense_f = cfg.mlp_dim, cfg.dense_ffn_dim if x else cfg.mlp_dim
     # What a kept GB is worth, ms (``table``).  The dispatch's buffers
     # have ``row_bound`` rows, of which a balanced router fills
@@ -182,14 +196,15 @@ def _entries(cfg, rows):
         ((12 * f / e + 8 * (not share)) * live, "moe_out",
          (moe_dispatch.KEEP_OUT,), bound * e * size, experts),
         (12 * live, "moe_gate", (moe_dispatch.KEEP_GATE,),
-         bound * f * size, experts),
+         bound * f * size, experts * (not gateless)),
         (12 * live, "moe_up", (moe_dispatch.KEEP_UP,), bound * f * size,
          experts),
         (8 * live, "moe_rows", (moe_dispatch.KEEP_ROWS,), bound * e * size,
          experts),
     ]
     rest = [
-        (12, "ffn_gate", (KEEP_GATE,), rows * dense_f * size, dense),
+        (12, "ffn_gate", (KEEP_GATE,), rows * dense_f * size,
+         dense * (not gateless)),
         (12, "ffn_up", (KEEP_UP,), rows * dense_f * size, dense),
         (11, "conv_in", (short_conv.KEEP_IN,), rows * 3 * e * size, conv),
         (5, "conv_out", (short_conv.KEEP_OUT,), rows * e * size, conv),
@@ -234,6 +249,24 @@ def _entries(cfg, rows):
         if kda and rank:
             rest.append((13 * e / rank / 2, "delta_rank", (KEEP_DELTA_RANK,),
                          rows * 2 * rank * size, delta))
+    if ssm:
+        # as a gated-delta layer's: the decays and steps are [rows,
+        # heads] and save a product that reads the whole stream; the
+        # gate's projection is made as q is; the scan's output with its
+        # chunk-start states saves ``ssd_fwd``; the projection of x | B |
+        # C as a convolution's input is; the convolved projection a pass
+        # bound by memory
+        inner, xbc = _ssm_widths(cfg)
+        rest += [
+            (100, "ssm_decay", (KEEP_SSM_DECAY,),
+             rows * cfg.ssm_heads * 4 * 2, ssm),
+            (11, "ssm", (ssd.KEEP_OUT, ssd.KEEP_STATES),
+             rows * inner * size + ssd.states_bytes(
+                 rows, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), ssm),
+            (13, "ssm_gate", (KEEP_SSM_GATE,), rows * inner * size, ssm),
+            (11, "ssm_in", (KEEP_SSM_IN,), rows * xbc * size, ssm),
+            (5, "ssm_xbc", (KEEP_SSM_XBC,), rows * xbc * size, ssm),
+        ]
     if cfg.hyper_streams:
         # a sublayer's mixed input and its logits (a 128-lane float32
         # tile a row), two sublayers a layer: kept, the second forward
@@ -244,7 +277,7 @@ def _entries(cfg, rows):
                      len(kinds)))
     if cfg.shared_dim:
         rest += [(12, name, (name,), rows * cfg.shared_dim * size, experts)
-                 for name in SHARED_PRODUCTS]
+                 for name in SHARED_PRODUCTS[gateless:]]
     if latent:
         # a contraction over the rank, not the hidden size: rank / dim
         # of what a byte of q buys
@@ -254,6 +287,13 @@ def _entries(cfg, rows):
     entries += [entry[1:] for entry in sorted(
         routed + rest, key=lambda entry: -entry[0])]
     return [entry for entry in entries if entry[3]]
+
+
+def _ssm_widths(cfg):
+    """(values a token of a Mamba-2 layer's z, x and y; of its x | B |
+    C side by side)."""
+    inner = cfg.ssm_heads * cfg.ssm_head_dim
+    return inner, inner + 2 * cfg.ssm_groups * cfg.ssm_state
 
 
 def _lanes(width):
@@ -485,6 +525,10 @@ def dispatch_phases(cfg, rows, kept=()):
     bound, _ = _routed_rows(cfg, rows)
     held = cfg.experts_held[1]
     share = held != cfg.moe_experts
+    # the products on the input's side: gate and up, or (an MLP of two
+    # matrices) up alone, and as many weight gradients beside the down
+    # product's
+    ins = 1 + cfg.gated_mlp
     # a room is stated on a chip alone: the kernel's own test of the
     # widths there decides which moves the program holds
     kernel = share and moe_dispatch.rows_by_kernel(
@@ -495,8 +539,8 @@ def dispatch_phases(cfg, rows, kept=()):
     tokens = rows * cfg.dim * size
     weight = held * cfg.dim * cfg.mlp_dim * size
     g = rows * cfg.dim * 4
-    made = {"moe_rows": wide, "moe_gate": narrow, "moe_up": narrow,
-            "moe_out": wide}
+    made = {"moe_rows": wide, "moe_gate": narrow * (ins - 1),
+            "moe_up": narrow, "moe_out": wide}
     own = [label for label in made if label in kept and not share]
     read = lambda *labels: sum(
         made[label] for label in labels if label not in own)
@@ -507,18 +551,18 @@ def dispatch_phases(cfg, rows, kept=()):
             g if kernel else claims if share else wide),
         "down": read("moe_rows", "moe_gate", "moe_up") + wide + 2 * narrow
         + weight - gone("moe_out"),
-        "gate": read("moe_rows", "moe_gate", "moe_up") + 3 * narrow + weight
-        - gone("moe_out"),
-        "products": read("moe_rows") + 2 * narrow + 2 * wide
-        + (3 if share else 2) * weight
+        "gate": read("moe_rows", "moe_gate", "moe_up") + (ins + 1) * narrow
+        + weight - gone("moe_out"),
+        "products": read("moe_rows") + ins * narrow + 2 * wide
+        + (ins + 1 if share else ins) * weight
         - gone("moe_out", "moe_gate", "moe_up"),
         "gather": (2 * wide if kernel or not share else wide + claims + g)
-        + tokens + 3 * share * weight - gone(*made),
+        + tokens + (ins + 1) * share * weight - gone(*made),
     }
     choices = 4 * rows * min(cfg.moe_top_k, cfg.moe_experts)
     router = 4 * rows * cfg.moe_experts
     return phases, g + choices + router + share * (
-        tokens + 3 * weight + choices)
+        tokens + (ins + 1) * weight + choices)
 
 
 def dispatch_bytes(cfg, rows, kept=()):
@@ -610,10 +654,12 @@ def _expert_layer(cfg, rows, kept, kind, sizes):
                              if label in kept)
     free = lambda labels: sum(sizes[label] for label in labels
                               if label in sizes and label not in kept)
-    planes = 4 - ("stream" in kept)
+    # a layer that is its FFN alone ("e") has no stream between
+    # sublayers; an MLP of two matrices no gate product
+    planes = 3 if kind.op == "e" else 4 - ("stream" in kept)
     ffn = (dispatch_bytes(cfg, rows, kept) + planes * rows * cfg.dim * size
-           + rows * 4 * cfg.shared_dim * size - own(SHARED_PRODUCTS)
-           + free(LAYER_ENTRIES))
+           + rows * (3 + cfg.gated_mlp) * cfg.shared_dim * size
+           - own(SHARED_PRODUCTS) + free(LAYER_ENTRIES))
     operator, backward = free(OPERATOR_ENTRIES[kind.op]), 0
     if kind.op == "a" and cfg.latent:
         residuals, backward = _latent_layer(cfg, rows, kept)
@@ -624,6 +670,22 @@ def _expert_layer(cfg, rows, kept, kind, sizes):
     if "stream" in kept:
         return max(ffn, operator + backward)
     return max(ffn, backward) + operator
+
+
+def _ssm_layer(cfg, rows, kept, sizes):
+    """Bytes an unrolled Mamba-2 layer that is its mixer alone holds
+    while it is back-propagated, beside what the stack keeps of it: the
+    entries its second forward makes and the stack does not keep, the
+    gated product and its norm ([rows, heads * head_dim] each: the
+    norm's and the output projection's operands), and the backward's
+    cotangents: of the norm, the gate and the scan's output, of x | B |
+    C behind and before the convolution, and the stream's three planes
+    (the normed input, a cotangent in and one out)."""
+    size = jnp.dtype(cfg.dtype).itemsize
+    inner, xbc = _ssm_widths(cfg)
+    made = sum(sizes[label] for label in OPERATOR_ENTRIES["m"]
+               if label not in kept)
+    return made + rows * size * (5 * inner + 2 * xbc + 3 * cfg.dim)
 
 
 def step_bytes(cfg, params, rows, kept=()):
@@ -650,6 +712,8 @@ def step_bytes(cfg, params, rows, kept=()):
        that layer's own is read from the scan's stack and not made
        again, so it leaves the term: a dense layer's gate and up leave
        their product and a cotangent;
+     - a Mamba-2 layer: ``_ssm_layer``, its second forward beside its
+       backward's cotangents;
      - an expert layer XLA unrolls: ``_expert_layer``, the dispatch's
        inventory (``dispatch_phases``) with the shared expert's planes
        and four of the stream beside the operator's second forward;
@@ -702,9 +766,11 @@ def step_bytes(cfg, params, rows, kept=()):
                              if label in kept)
     experts = _unrolled_experts(cfg, stack)
     layer = 0
-    if any(kind.dense for kind in cfg.kinds):
+    if any(kind.dense and kind.ffn for kind in cfg.kinds):
         f = cfg.dense_ffn_dim if cfg.moe_experts else cfg.mlp_dim
         layer = rows * 4 * f * size - own(DENSE_PRODUCTS)
+    if any(kind.op == "m" for kind in cfg.kinds):
+        layer = max(layer, _ssm_layer(cfg, rows, kept, sizes))
     if experts:
         layer = max([layer] + [_expert_layer(cfg, rows, kept, kind, sizes)
                                for kind in experts])

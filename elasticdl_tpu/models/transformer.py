@@ -32,13 +32,13 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from elasticdl_tpu.models import remat_keep
 from elasticdl_tpu.models.spec import ModelSpec
 from elasticdl_tpu.ops import (batch_shard, gated_delta, hyper_mix,
-                               short_conv)
+                               short_conv, ssd)
 from elasticdl_tpu.ops.embed_rows import embed_rows
 from elasticdl_tpu.ops.flash_attention import (flash_attention, flash_mode,
                                                latent_attention,
                                                latent_mode, logger)
 from elasticdl_tpu.ops.mode import kernels_off
-from elasticdl_tpu.ops.moe_dispatch import (ACTIVATIONS, gated,
+from elasticdl_tpu.ops.moe_dispatch import (ACTIVATIONS, GATELESS, gated,
                                             moe_experts)
 from elasticdl_tpu.utils import metrics
 
@@ -162,8 +162,10 @@ class TransformerConfig:
     # layer, "a" causal attention over the whole sequence, "w" causal
     # attention over the last ``window`` positions, "c" gated short
     # convolution of ``conv_kernel`` taps (ops/short_conv.py), "d" the
-    # gated delta rule (below); "" = one attention kind in every layer
-    # ("w" if ``window``, else "a").
+    # gated delta rule (below), "m" a Mamba-2 state-space mixer (below),
+    # "e" NO operator: a layer that is its FFN alone, ``x + FFN(norm(
+    # x))`` with one norm (``ln2``) and no mixer weights; "" = one
+    # attention kind in every layer ("w" if ``window``, else "a").
     # ``dense_layers``: how many leading layers have a dense MLP
     # of ``dense_ffn_dim`` in an MoE model.  With either, the stack is
     # the leading layers, then whole periods of the rest's pattern under
@@ -214,6 +216,29 @@ class TransformerConfig:
     # sigmoid(exp(A_log) (a + dt_bias))``, a log decay a channel in (F,
     # 0) (``a`` the decay's projection a channel, ``A_log`` a head's).
     delta_gate_floor: float = 0.0
+    # An "m" layer of ``layer_pattern``: a Mamba-2 mixer (``_ssm_mix``;
+    # ops/ssd.py, arXiv:2405.21060) of ``ssm_heads`` heads of
+    # ``ssm_head_dim`` values, each with a float32 state [head_dim,
+    # ``ssm_state``] carried through the sequence under one decay a
+    # head, ``S_t = exp(dt_t A) S_(t-1) + dt_t x_t B_t^T``, ``y_t = S_t
+    # C_t + D x_t``; B and C lie in ``ssm_groups`` groups that ``ssm_heads
+    # / ssm_groups`` heads share; x, B and C pass a causal convolution of
+    # ``conv_kernel`` taps (with a bias a channel under ``conv_bias``)
+    # and a SiLU; the output is ``RMSNorm(y * SiLU(z))`` over each
+    # group's ``ssm_heads * ssm_head_dim / ssm_groups`` values with one
+    # learned scale, the gate BEFORE the norm.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    conv_bias: bool = False
+    # False: a layer is ONE sublayer.  A layer whose letter names an
+    # operator (a, w, c, d, m) has no FFN, ``x + Op(norm(x))`` with one
+    # norm (``ln1``) and no FFN weights; the FFNs of the stack are its
+    # "e" layers (dense or with experts as ``dense_layers`` and
+    # ``moe_experts`` say of their positions).  True = every layer with
+    # an operator has an FFN behind it, as ever.
+    mixer_ffn: bool = True
     # How many chips share a layer's heads in the deployment this model
     # is one chip of (tensor parallel over heads): ``num_heads`` and
     # ``num_kv_heads`` are the heads held HERE, every mixer's result is
@@ -255,7 +280,11 @@ class TransformerConfig:
     moe_route_before_op: bool = False
     # The gate's activation, of the experts and of a dense FFN alike
     # (``ops/moe_dispatch.ACTIVATIONS``): "silu" (SwiGLU) | "relu"
-    # (ReGLU).
+    # (ReGLU) | "relu2": NO gate product, an MLP of TWO matrices,
+    # ``relu(h W_up) ** 2 W_down``: the experts, the shared expert and a
+    # dense FFN hold ``w_up`` and ``w_down`` alone (no ``w_gate``, no
+    # ``ws_gate`` in ``init_params``) and the grouped matmul runs two
+    # products a layer where it ran three.
     ffn_activation: str = "silu"
     # A clamp on a gated MLP's two products that is a LAYER's: "L0,L1,
     # .." one limit a layer of ``num_layers``; where a layer's L > 0 its
@@ -405,6 +434,34 @@ class TransformerConfig:
                     "%s=%r: want one limit >= 0 a layer of num_layers=%d, of "
                     "a model with experts (a dense MLP has no clamp)"
                     % (name, getattr(self, name), self.num_layers))
+        if self.conv_bias and "m" not in self.layer_pattern:
+            raise ValueError(
+                "conv_bias=true is the m layer's (a Mamba-2 mixer's "
+                "convolution): layer_pattern=%r has none"
+                % self.layer_pattern)
+        if not self.mixer_ffn and "e" not in self.layer_pattern:
+            raise ValueError(
+                "mixer_ffn=false: a layer with an operator has no FFN, the "
+                "stack's FFNs are the e layers of layer_pattern=%r"
+                % self.layer_pattern)
+        if self.plain_only and (
+                self.attn_gate or self.post_norms or not self.pre_norms
+                or self.hyper_streams or self.mtp_modules
+                or self.moe_route_before_op):
+            raise ValueError(
+                "layer_pattern=%r, mixer_ffn=%s: a stack with a Mamba-2 "
+                "layer (m) or a layer of one sublayer (e, mixer_ffn=false) "
+                "is not held to attn_gate (%s), post_norms (%s), pre_norms="
+                "false, hyper_streams (%d), mtp_modules (%d) or "
+                "moe_route_before_op (%s): none of them runs with it"
+                % (self.layer_pattern, self.mixer_ffn, self.attn_gate,
+                   self.post_norms, self.hyper_streams, self.mtp_modules,
+                   self.moe_route_before_op))
+        if not self.gated_mlp and (self.ffn_limits or self.shared_limits):
+            raise ValueError(
+                "ffn_activation=%s has no gate product: the clamps "
+                "ffn_limits=%r, shared_limits=%r are a gated MLP's"
+                % (self.ffn_activation, self.ffn_limits, self.shared_limits))
         if set(self.rope_kinds) - set("aw"):
             raise ValueError(
                 "rope_kinds %r: want letters of a (full attention) and w "
@@ -426,12 +483,25 @@ class TransformerConfig:
                     % (self.ffn_limits, self.shared_limits, turns, size))
         if pattern is None:
             return
-        if len(pattern) != self.num_layers or set(pattern) - set("awcd"):
+        if len(pattern) != self.num_layers or set(pattern) - set("awcdme"):
             raise ValueError(
                 "layer_pattern %r: want %d letters, each a (attention), w "
                 "(attention over the last `window` positions), c (short "
-                "convolution) or d (gated delta rule)"
+                "convolution), d (gated delta rule), m (Mamba-2 mixer) or "
+                "e (no operator: an FFN alone)"
                 % (pattern, self.num_layers))
+        if "m" in pattern and (
+                min(self.ssm_heads, self.ssm_head_dim, self.ssm_state,
+                    self.ssm_groups) <= 0
+                or self.ssm_heads % self.ssm_groups
+                or not 1 <= self.conv_kernel <= 8 - self.conv_bias):
+            raise ValueError(
+                "an m layer needs ssm_heads, ssm_head_dim and ssm_state > 0, "
+                "ssm_groups that divide the heads and 1 <= conv_kernel <= %d "
+                "(conv_bias=%s); got %d, %d, %d, %d and %d"
+                % (8 - self.conv_bias, self.conv_bias, self.ssm_heads,
+                   self.ssm_head_dim, self.ssm_state, self.ssm_groups,
+                   self.conv_kernel))
         if "d" in pattern and (
                 min(self.delta_key_dim, self.delta_value_dim) <= 0
                 or not 1 <= self.conv_kernel <= short_conv.HALO + 1):
@@ -501,6 +571,20 @@ class TransformerConfig:
             for i, letter in enumerate(pattern))
 
     @property
+    def plain_only(self):
+        """Whether the stack has what this repo holds to training its
+        plain block alone: a Mamba-2 layer, or a layer of one
+        sublayer."""
+        return bool(set("me") & set(self.layer_pattern)
+                    or not self.mixer_ffn)
+
+    @property
+    def gated_mlp(self):
+        """Whether an MLP has a gate product (three matrices) or is
+        ``act(h W_up) W_down`` (two: ``GATELESS``)."""
+        return self.ffn_activation not in GATELESS
+
+    @property
     def mtp_kind(self):
         """The Kind of a multi-token-prediction module's block: the
         model's last layer's, without a clamp."""
@@ -536,15 +620,18 @@ _WORDS_GONE = {
 
 
 # One layer's kind: its operator ("a" attention | "c" short
-# convolution | "d" gated delta rule), whether its FFN is dense (in an
+# convolution | "d" gated delta rule | "m" Mamba-2 mixer | "e" none),
+# whether its FFN is dense (in an
 # MoE model, a leading
 # layer's) and, of an attention layer, the window it attends over (0:
 # the whole sequence) and whether RoPE turns its q and k; of a layer
 # with experts, the clamps of its routed and of its shared experts'
-# products (``ffn_limits``, ``shared_limits``; 0: none).
+# products (``ffn_limits``, ``shared_limits``; 0: none); whether it has
+# an FFN at all (``mixer_ffn``: a layer without one counts as dense, it
+# has no experts).
 Kind = collections.namedtuple(
-    "Kind", "op dense window rope limit shared_limit",
-    defaults=(0, True, 0.0, 0.0))
+    "Kind", "op dense window rope limit shared_limit ffn",
+    defaults=(0, True, 0.0, 0.0, True))
 # ``lead`` and ``tail``: the kinds of the layers before and after the
 # scan; ``period``: the kinds of one period; ``periods``: how many the
 # scan runs.
@@ -553,10 +640,14 @@ StackPlan = collections.namedtuple("StackPlan", "lead period periods tail")
 
 def _kind(cfg, letter, dense, limit=0.0, shared_limit=0.0):
     """The Kind a letter of ``layer_pattern`` names."""
-    if letter in "cd":
-        return Kind(letter, dense, limit=limit, shared_limit=shared_limit)
+    ffn = cfg.mixer_ffn or letter == "e"
+    if not ffn:
+        dense, limit, shared_limit = True, 0.0, 0.0
+    if letter in "cdme":
+        return Kind(letter, dense, limit=limit, shared_limit=shared_limit,
+                    ffn=ffn)
     return Kind("a", dense, cfg.window if letter == "w" else 0,
-                letter in cfg.rope_kinds, limit, shared_limit)
+                letter in cfg.rope_kinds, limit, shared_limit, ffn)
 
 
 def limits_of(text):
@@ -645,11 +736,15 @@ _CANNOT = {
     "stack": (
         lambda cfg: _pattern(cfg) is not None,
         "a stack whose layers differ (layer_pattern={cfg.layer_pattern!r}"
-        ", dense_layers={cfg.dense_layers}, delta_kind={cfg.delta_kind})",
+        ", dense_layers={cfg.dense_layers}, delta_kind={cfg.delta_kind}, "
+        "mixer_ffn={cfg.mixer_ffn})",
         "a short-convolution layer needs a state cache of its own, a "
         "gated-delta layer (d; gdn or kda) a recurrent state [heads, "
         "value_dim, key_dim] and the last conv_kernel - 1 rows of its "
-        "convolution's input and no K/V cache, a "
+        "convolution's input and no K/V cache, a Mamba-2 layer (m) a "
+        "state [ssm_heads, ssm_head_dim, ssm_state] and its "
+        "convolution's tail, a layer of one sublayer (e, or any layer "
+        "under mixer_ffn=false) a block of its own in _decode_layer, a "
         "windowed layer (w) beside full ones a K/V cache that keeps its "
         "last `window` positions, a mesh specs for the weights of lead, "
         "period and tail, and the pipeline a split of them into stages"),
@@ -678,6 +773,13 @@ _CANNOT = {
         "the modules are training's second loss: decoding has no draft "
         "path that reads them, a mesh no spec for their weights, and the "
         "pipeline's stages end at the model's own hidden state"),
+    "mlp": (
+        lambda cfg: not cfg.gated_mlp,
+        "an MLP of two matrices (ffn_activation={cfg.ffn_activation}: no "
+        "w_gate, no ws_gate)",
+        "decoding and the pipeline's stages were not held to an FFN "
+        "without a gate product, and a mesh's specs name w_gate and "
+        "ws_gate"),
     "share": (
         lambda cfg: cfg.moe_experts_held,
         "one chip's share of the experts (moe_experts_held="
@@ -685,7 +787,7 @@ _CANNOT = {
         "a model-parallel mesh shards all the experts over ep"),
 }
 # what decoding and the pipelined forward cannot run
-_TRAINS_ONLY = ("latent", "block", "stack", "route", "hyper", "mtp")
+_TRAINS_ONLY = ("latent", "block", "stack", "route", "hyper", "mtp", "mlp")
 
 
 def _refuse(cfg, what, *features):
@@ -717,7 +819,11 @@ def _init_layers(k_attn, k_mlp, cfg, kind, stack):
     keys = jax.random.split(k_attn, 6)
     layers = {}
     if cfg.pre_norms:
-        layers.update(ln1=_norm_init(*stack, E), ln2=_norm_init(*stack, E))
+        # one norm a sublayer: a layer without an operator ("e") has no
+        # ln1, a layer without an FFN (``Kind.ffn``) no ln2
+        layers.update(
+            **({"ln1": _norm_init(*stack, E)} if kind.op != "e" else {}),
+            **({"ln2": _norm_init(*stack, E)} if kind.ffn else {}))
     if cfg.post_norms:
         layers.update(ln1_post=_norm_init(*stack, E),
                       ln2_post=_norm_init(*stack, E))
@@ -753,15 +859,21 @@ def _init_layers(k_attn, k_mlp, cfg, kind, stack):
             layers["k_norm"] = _norm_init(*stack, G * D if whole else D)
     elif kind.op == "d":
         layers.update(_init_delta(k_attn, cfg, stack))
-    else:
+    elif kind.op == "m":
+        layers.update(_init_ssm(k_attn, cfg, stack))
+    elif kind.op == "c":
         layers.update(
             w_in=_dense_init(keys[0], *stack, E, 3 * E),
             conv_w=_dense_init(keys[1], *stack, E, cfg.conv_kernel,
                                scale=cfg.conv_kernel ** -0.5),
             w_out=_dense_init(keys[3], *stack, E, E))
-    if kind.dense:
+    gate = cfg.gated_mlp    # an MLP of two matrices draws none
+    if not kind.ffn:
+        pass
+    elif kind.dense:
         F = cfg.dense_ffn_dim if cfg.moe_experts else cfg.mlp_dim
-        layers["w_gate"] = _dense_init(keys[4], *stack, E, F)
+        if gate:
+            layers["w_gate"] = _dense_init(keys[4], *stack, E, F)
         layers["w_up"] = _dense_init(keys[5], *stack, E, F)
         layers["w_down"] = _dense_init(jax.random.fold_in(k_mlp, 1),
                                        *stack, F, E)
@@ -770,7 +882,8 @@ def _init_layers(k_attn, k_mlp, cfg, kind, stack):
         layers["w_router"] = _dense_init(keys[4], *stack, E, X, scale=0.02)
         if cfg.moe_router == "sigmoid_bias":
             layers["expert_bias"] = jnp.zeros((*stack, X), jnp.float32)
-        layers["w_gate"] = _dense_init(keys[5], *stack, held, E, F)
+        if gate:
+            layers["w_gate"] = _dense_init(keys[5], *stack, held, E, F)
         layers["w_up"] = _dense_init(jax.random.fold_in(k_mlp, 0),
                                      *stack, held, E, F)
         layers["w_down"] = _dense_init(jax.random.fold_in(k_mlp, 1),
@@ -778,7 +891,8 @@ def _init_layers(k_attn, k_mlp, cfg, kind, stack):
         S = cfg.shared_dim
         if S:
             shared = jax.random.split(jax.random.fold_in(k_mlp, 2), 3)
-            layers["ws_gate"] = _dense_init(shared[0], *stack, E, S)
+            if gate:
+                layers["ws_gate"] = _dense_init(shared[0], *stack, E, S)
             layers["ws_up"] = _dense_init(shared[1], *stack, E, S)
             layers["ws_down"] = _dense_init(shared[2], *stack, S, E)
     if cfg.hyper_streams:
@@ -875,6 +989,46 @@ def _init_delta(key, cfg, stack):
         w_g_down=_dense_init(up[0], *stack, E, rank),
         w_g_up=_dense_init(up[1], *stack, rank, H * dv),
         b_g=jnp.zeros((*stack, H * dv), jnp.float32))
+    return layers
+
+
+# The least step a Mamba-2 layer's ``dt_bias`` is drawn for
+# (``time_step_floor``).
+SSM_STEP_FLOOR = 1e-4
+
+
+def _init_ssm(key, cfg, stack):
+    """An "m" layer's mixer (``_ssm_mix``, which states the forms), as
+    the Mamba-2 reference layer draws it: ``ssm_in`` [dim, z | x | B | C
+    | dt] one projection without a bias (z and x ``ssm_heads *
+    ssm_head_dim`` wide, B and C ``ssm_groups * ssm_state``, dt a
+    head); the taps ``ssm_conv`` over the channels of x | B | C and,
+    with ``cfg.conv_bias``, ``ssm_conv_bias`` uniform in +- taps^-1/2 (a
+    depthwise convolution's default); a step dt log-uniform in (0.001,
+    0.1) a head, floored at ``SSM_STEP_FLOOR``, ``dt_bias`` its inverse
+    softplus; a decay rate A uniform in (1, 16) a head, ``A_log`` its
+    log; the skip ``ssm_D`` ones; the gated norm's scale ``ssm_norm``
+    ones; ``ssm_out`` back to dim."""
+    E, H, P = cfg.dim, cfg.ssm_heads, cfg.ssm_head_dim
+    inner, both = H * P, 2 * cfg.ssm_groups * cfg.ssm_state
+    taps = cfg.conv_kernel
+    keys = jax.random.split(jax.random.fold_in(key, 8), 6)
+    dt = jnp.maximum(SSM_STEP_FLOOR, jnp.exp(jax.random.uniform(
+        keys[2], (*stack, H), jnp.float32, np.log(1e-3), np.log(1e-1))))
+    A = jax.random.uniform(keys[3], (*stack, H), jnp.float32, 1.0, 16.0)
+    layers = dict(
+        ssm_in=_dense_init(keys[0], *stack, E, 2 * inner + both + H),
+        ssm_conv=_dense_init(keys[1], *stack, inner + both, taps,
+                             scale=taps ** -0.5),
+        A_log=jnp.log(A),
+        dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
+        ssm_D=_norm_init(*stack, H),
+        ssm_norm=_norm_init(*stack, inner),
+        ssm_out=_dense_init(keys[4], *stack, inner, E))
+    if cfg.conv_bias:
+        layers["ssm_conv_bias"] = jax.random.uniform(
+            keys[5], (*stack, inner + both), jnp.float32, -taps ** -0.5,
+            taps ** -0.5)
     return layers
 
 
@@ -1154,7 +1308,7 @@ def _moe_ffn(h, w, cfg, mesh, route=None, limit=0.0):
     probs, gates, experts = route or moe_route(
         h, w["w_router"], cfg, w.get("expert_bias"))
     weights = tuple(w[name].astype(h.dtype)
-                    for name in ("w_gate", "w_up", "w_down"))
+                    for name in ("w_gate", "w_up", "w_down") if name in w)
     with kernels_off(mesh is not None):
         out, load = moe_experts(h, gates, experts, *weights,
                                 total=X, first=first,
@@ -1328,8 +1482,14 @@ def _gated_mlp(h, w, cfg, weights, keep, limit=0.0):
     """``(act(h W_gate) * (h W_up)) W_down`` with the three ``weights``
     named, the gate and up products named ``keep`` for a remat policy
     and clamped where the layer has a ``limit``
-    (``ops/moe_dispatch.gated``)."""
+    (``ops/moe_dispatch.gated``); under an activation without a gate
+    product (``GATELESS``) ``act(h W_up) W_down``."""
     compute_dtype = jnp.dtype(cfg.dtype)
+    if not cfg.gated_mlp:
+        # an MLP of two matrices: the first name has no weight
+        up, down = (w[name].astype(compute_dtype) for name in weights[1:])
+        up = checkpoint_name(h @ up, keep[1])
+        return gated(cfg.ffn_activation, None, up) @ down
     gate, up, down = (w[name].astype(compute_dtype) for name in weights)
     gate = checkpoint_name(h @ gate, keep[0])
     up = checkpoint_name(h @ up, keep[1])
@@ -1623,12 +1783,84 @@ def _delta_mix(h, w, cfg, with_excess=False):
     return (out, excess) if with_excess else out
 
 
+# The published ``chunk_size`` of the Mamba-2 models: what ``chunk_keep``
+# is taken over, whatever chunk the kernel walks.
+SSM_PUBLISHED_CHUNK = 128
+
+
+@functools.lru_cache(maxsize=None)
+def announce_ssm(cfg, rows, chunk, kept, mode, why):
+    """Once per compiled shape, by the logger ``announce_tiles`` uses:
+    the state-space scan one shard of the data axis runs over ``rows``
+    tokens, and whether the chunk-start states its backward reads are
+    ``kept`` from the forward or made again by the second forward of a
+    rematerialized layer."""
+    logger.info(
+        "ssm scan: rows=%d heads=%d groups=%d head_dim=%d state=%d "
+        "chunk=%d conv_taps=%d conv_bias=%d states=%s %s%s", rows,
+        cfg.ssm_heads, cfg.ssm_groups, cfg.ssm_head_dim, cfg.ssm_state,
+        chunk, cfg.conv_kernel, cfg.conv_bias,
+        "kept" if kept else "recomputed",
+        {"tpu": "kernel", "interpret": "interpreter",
+         "off": "reference"}[mode], " (%s)" % why if why else "")
+
+
+def _ssm_mix(h, w, cfg):
+    """Mamba2(h) of the normed input -> ([B, T, dim], ``chunk_keep``):
+    the operator of an "m" layer.  One projection ``ssm_in`` gives z | x
+    | B | C | dt (three products of its column blocks: splitting a
+    product by output columns changes no sum); x | B | C pass one causal
+    convolution with its bias and a SiLU (``ops/short_conv.conv_silu``);
+    ``dt = softplus(dt + dt_bias)`` and the log decay ``g = -exp(A_log)
+    dt``, float32 a head; the scan is ``ops/ssd.py``'s, which picks
+    kernel or reference, on the token-major operands as they stand; ``y
+    + D x``; ``RMSNorm(y * SiLU(z))`` over each group's values with the
+    learned scale ``ssm_norm``; ``ssm_out``.  ``chunk_keep``: the mean
+    over heads and chunks of ``SSM_PUBLISHED_CHUNK`` tokens of ``exp(sum
+    of the chunk's log decays)``, the share of a state that outlives a
+    chunk."""
+    compute_dtype = jnp.dtype(cfg.dtype)
+    B, T, _ = h.shape
+    H, P, N, G = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups
+    inner, group = H * P, G * N
+    mode, why = ssd.ssd_mode(T, H // G, P, N)
+    announce_ssm(cfg, B * T // batch_shard.shards(), ssd.CHUNK,
+                 not cfg.remat or remat_keep.keeps(ssd.KEEP_STATES), mode,
+                 why)
+    w_in = w["ssm_in"].astype(compute_dtype)
+    z = checkpoint_name(h @ w_in[:, :inner], remat_keep.KEEP_SSM_GATE)
+    xbc = checkpoint_name(h @ w_in[:, inner:2 * inner + 2 * group],
+                          remat_keep.KEEP_SSM_IN)
+    dt = (h @ w_in[:, 2 * inner + 2 * group:]).astype(jnp.float32)
+    xbc = checkpoint_name(
+        short_conv.conv_silu(xbc, w["ssm_conv"],
+                             bias=w.get("ssm_conv_bias")),
+        remat_keep.KEEP_SSM_XBC)
+    x = xbc[..., :inner].reshape(B, T, H, P)
+    b = xbc[..., inner:inner + group].reshape(B, T, G, N)
+    c = xbc[..., inner + group:].reshape(B, T, G, N)
+    dt = jax.nn.softplus(dt + w["dt_bias"].astype(jnp.float32))
+    g = -jnp.exp(w["A_log"].astype(jnp.float32)) * dt
+    g, dt = (checkpoint_name(a, remat_keep.KEEP_SSM_DECAY) for a in (g, dt))
+    size = min(SSM_PUBLISHED_CHUNK, T)
+    keep = jnp.exp(g[:, :T // size * size].reshape(
+        B, T // size, size, H).sum(axis=2)).mean()
+    y = ssd.ssd(x, b, c, g, dt)
+    y = y + x * w["ssm_D"].astype(compute_dtype)[:, None]
+    y = (y.reshape(B, T, inner) * jax.nn.silu(z)).reshape(B, T, G, -1)
+    y = _rmsnorm(y, w["ssm_norm"].astype(compute_dtype).reshape(G, -1),
+                 cfg.norm_eps)
+    out = y.reshape(B, T, inner) @ w["ssm_out"].astype(compute_dtype)
+    return out, jax.lax.stop_gradient(keep)
+
+
 def _operator(x, w, cfg, mesh, positions, kind):
     """x + post(Op(norm(x))) -> (x, what the operator hands on beside
     it: attention's (k, v); a floored kda layer's gate excess
-    (``_delta_mix``); else None), ``Op`` the operator of ``kind``:
+    (``_delta_mix``); a Mamba-2 layer's ``chunk_keep`` (``_ssm_mix``);
+    else None), ``Op`` the operator of ``kind``:
     attention, latent attention (nothing cached: decoding refuses it),
-    the short convolution or the gated delta rule."""
+    the short convolution, the gated delta rule or the Mamba-2 mixer."""
     u, x, maps = _read(x, w, cfg, "hc1")
     h = _pre(u, w, cfg, "ln1")
     kv_out = None
@@ -1638,6 +1870,8 @@ def _operator(x, w, cfg, mesh, positions, kind):
         out = _delta_mix(h, w, cfg, bool(cfg.delta_gate_floor))
         if cfg.delta_gate_floor:
             out, kv_out = out
+    elif kind.op == "m":
+        out, kv_out = _ssm_mix(h, w, cfg)
     elif cfg.latent:
         if mesh is not None:
             _refuse(cfg, "a model-parallel mesh", "latent")
@@ -1645,6 +1879,8 @@ def _operator(x, w, cfg, mesh, positions, kind):
     else:
         out, kv_out = _attention_mix(h, w, cfg, mesh, positions, kind)
     x = _residual(x, out, w, cfg, mesh, "ln1_post", maps)
+    if not kind.ffn:    # no FFN behind it: the stream is the layer's result
+        return x, kv_out
     return checkpoint_name(x, remat_keep.KEEP_STREAM), kv_out
 
 
@@ -1662,7 +1898,9 @@ def _short_conv(x, w, cfg):
 def _layer_body(x, w, cfg, mesh, positions, moe_stats=False,
                 return_kv=False, moe_load=False, kind=None):
     """One block, ``x + post(Op(norm(x)))`` then ``x + post(FFN(norm(
-    x)))``, of ``kind`` (None: attention, and the model's one FFN);
+    x)))``, of ``kind`` (None: attention, and the model's one FFN; a
+    kind without an operator, "e", or without an FFN, ``Kind.ffn``
+    False, is the other sublayer alone);
     ``post`` is the identity unless ``cfg.post_norms`` (``_residual``,
     the one place either is written).  Shared by the scanned stack
     (forward) and the per-stage slice scan (forward_pipelined).
@@ -1685,10 +1923,13 @@ def _layer_body(x, w, cfg, mesh, positions, moe_stats=False,
             _rmsnorm(x, w["ln1"].astype(jnp.dtype(cfg.dtype)),
                      cfg.norm_eps),
             w["w_router"], cfg, w.get("expert_bias"))
-    x, kv_out = _operator(x, w, cfg, mesh, positions, kind)
-    x, aux, stats, load = _ffn(x, w, cfg, mesh, dense=kind.dense,
-                               route=route,
-                               limits=(kind.limit, kind.shared_limit))
+    kv_out, aux, stats, load = None, jnp.float32(0.0), None, None
+    if kind.op != "e":      # "e": no operator, the FFN alone
+        x, kv_out = _operator(x, w, cfg, mesh, positions, kind)
+    if kind.ffn:            # one sublayer (``mixer_ffn`` False): none
+        x, aux, stats, load = _ffn(x, w, cfg, mesh, dense=kind.dense,
+                                   route=route,
+                                   limits=(kind.limit, kind.shared_limit))
     if moe_stats and not kind.dense:
         aux = stats
     elif moe_load and not kind.dense:
@@ -1699,6 +1940,10 @@ def _layer_body(x, w, cfg, mesh, positions, moe_stats=False,
         # every layer of a model with a floored gate hands on how far
         # under the floor its decays went: 0 for a layer that has none
         aux = (aux, kv_out if kind.op == "d" else jnp.float32(0.0))
+    if "m" in cfg.layer_pattern:
+        # and every layer of a model with a Mamba-2 layer its
+        # ``chunk_keep``: 0 for a layer that has no such state
+        aux = (aux, kv_out if kind.op == "m" else jnp.float32(0.0))
     return x, aux
 
 
@@ -1850,6 +2095,8 @@ def _forward_stack(params, tokens, cfg, mesh=None, with_load=False,
     "hc_err": on a wide stream the largest Sinkhorn error of the step;
     "gate_excess": under ``cfg.delta_gate_floor`` how far under it the
     step's lowest log decay lies (``_delta_mix``; 0 by construction);
+    "chunk_keep": the Mamba-2 layers' mean share of a state that
+    outlives a chunk (``_ssm_mix``; None without such a layer);
     "mtp_hidden": with ``with_mtp`` each multi-token-prediction
     module's hidden state, its block's aux and load joined to the
     stack's}."""
@@ -1885,11 +2132,11 @@ def _forward_stack(params, tokens, cfg, mesh=None, with_load=False,
     out = {"mtp_hidden": []}
     with remat_keep.keeping(names if cfg.remat and plan is not None
                             else ()):
-        excess = None
+        excess = keep = None
         if plan is None:
             x, seen = jax.lax.scan(block(), x, layers)
         else:
-            x, seen, excess = _mixed_stack(x, layers, cfg, plan, block)
+            x, seen, excess, keep = _mixed_stack(x, layers, cfg, plan, block)
         hidden, err = _narrow(x, params, cfg)
         out["hidden"] = hidden
         for k in range(len(modules or ())):
@@ -1906,6 +2153,7 @@ def _forward_stack(params, tokens, cfg, mesh=None, with_load=False,
                     lambda a, b: jnp.concatenate([a, b[None]]), seen, more)
     out["aux"], out["load"] = seen if with_load else (seen, None)
     out["hc_err"], out["gate_excess"] = err, excess
+    out["chunk_keep"] = keep
     return out
 
 
@@ -1960,7 +2208,8 @@ def _mixed_stack(x, layers, cfg, plan, block):
     with experts returned beside it, stacked in layer order: aux [L_moe]
     or (aux [L_moe], load [L_moe, ..]); a zero where none has experts,
     the largest gate excess of a layer: None without
-    ``cfg.delta_gate_floor``)."""
+    ``cfg.delta_gate_floor``, the Mamba-2 layers' mean ``chunk_keep``:
+    None without one)."""
     announce_stack("".join(map(_letter, cfg.kinds)), plan,
                    (cfg.experts_held[1], cfg.moe_experts), cfg.shared_dim,
                    (cfg.num_heads, cfg.num_heads * cfg.head_shares)
@@ -1978,41 +2227,54 @@ def _mixed_stack(x, layers, cfg, plan, block):
                         and cfg.delta_kind == "kda" and not cfg.delta_rank
                         and "full"),
                        ("gate_floor", cfg.delta_gate_floor),
+                       # a digit a layer: 1 = a mixer or an FFN alone
+                       ("sublayers", cfg.plain_only and "".join(
+                           str((k.op != "e") + k.ffn) for k in cfg.kinds)),
+                       ("mlp", not cfg.gated_mlp
+                        and "two-matrix:" + cfg.ffn_activation),
                        ("ffn_limits", cfg.ffn_limits),
                        ("shared_limits", cfg.shared_limits)) if value))
 
     tree_map = jax.tree_util.tree_map
     floored = bool(cfg.delta_gate_floor)
+    ssm = sum(kind.op == "m" for kind in cfg.kinds)
 
     def run(x, kinds, weights):
         """(x, (what the layers with experts returned, stacked; None
-        where none has, the layers' largest gate excess or None))."""
+        where none has, the layers' largest gate excess or None, the sum
+        of their ``chunk_keep`` or None))."""
         seen, off = [], jnp.float32(0.0) if floored else None
+        kept = jnp.float32(0.0) if ssm else None
         for i, kind in enumerate(kinds):
             x, out = block(kind)(x, weights[str(i)])
+            if ssm:
+                out, keep = out
+                kept = kept + keep
             if floored:
                 out, excess = out
                 off = jnp.maximum(off, excess)
             if not kind.dense:
                 seen.append(out)
         return x, (tree_map(lambda *a: jnp.stack(a), *seen) if seen
-                   else None, off)
+                   else None, off, kept)
 
-    x, (lead, off) = run(x, plan.lead, layers["lead"])
+    x, (lead, off, kept) = run(x, plan.lead, layers["lead"])
     period = None
     if plan.periods:
-        x, (period, offs) = jax.lax.scan(
+        x, (period, offs, keeps) = jax.lax.scan(
             lambda x, w: run(x, plan.period, w), x, layers["period"])
         # [periods, positions, ..] -> [periods * positions, ..]
         period = tree_map(lambda a: a.reshape((-1,) + a.shape[2:]), period)
-    x, (tail, last) = run(x, plan.tail, layers["tail"])
+    x, (tail, last, more) = run(x, plan.tail, layers["tail"])
     if floored:
         off = jnp.maximum(jnp.maximum(off, last),
                           offs.max() if plan.periods else 0.0)
+    if ssm:     # the mean over the Mamba-2 layers
+        kept = (kept + more + (keeps.sum() if plan.periods else 0.0)) / ssm
     parts = [part for part in (lead, period, tail) if part is not None]
     if not parts:
-        return x, jnp.zeros((1,), jnp.float32), off
-    return x, tree_map(lambda *a: jnp.concatenate(a), *parts), off
+        return x, jnp.zeros((1,), jnp.float32), off, kept
+    return x, tree_map(lambda *a: jnp.concatenate(a), *parts), off, kept
 
 
 def forward(params, tokens, cfg, mesh=None, return_aux=False):
@@ -2346,12 +2608,14 @@ def _decayed(params):
     ``expert_bias``, the scales of the norms on a sublayer's output
     and, of a gated-delta layer, its decay rates, its step bias, its
     output norm's scale, its convolution's taps and (kda) its output
-    gate's bias, and a hyper-connection's ``alpha`` and bias (decayed,
+    gate's bias, of a Mamba-2 layer its decay rates, step bias, skip
+    ``ssm_D``, gated norm's scale and convolution's bias, and a
+    hyper-connection's ``alpha`` and bias (decayed,
     its maps would leave the identity they start from)."""
     return jax.tree_util.tree_map_with_path(
         lambda path, _: getattr(path[-1], "key", None) not in (
             "expert_bias", "ln1_post", "ln2_post", "A_log", "dt_bias",
-            "o_norm", "delta_conv", "b_g")
+            "o_norm", "delta_conv", "b_g", "ssm_D", "ssm_norm")
         and not str(getattr(path[-1], "key", "")).endswith(
             ("_alpha", "_bias")), params)
 
@@ -2382,7 +2646,9 @@ def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
     ``moe_group_hit`` [L], the share of the tokens whose chosen groups
     reach an expert held here; with a floored kda gate
     ``kda_gate_excess``, how far under the floor the step's lowest log
-    decay lies (0 by construction).
+    decay lies (0 by construction); with a Mamba-2 layer
+    ``ssm_chunk_keep``, the mean share of a state that outlives a chunk
+    of 128 tokens.
 
     ``warmup_steps`` > 0 raises AdamW's rate from 0 to ``learning_rate``
     linearly over that many steps (0: constant, as ever).  Adam's steps
@@ -2439,7 +2705,7 @@ def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
 
     moe = not all(kind.dense for kind in cfg.kinds)   # a layer has experts
     wide = bool(cfg.hyper_streams or cfg.mtp_modules
-                or cfg.delta_gate_floor)
+                or cfg.delta_gate_floor or "m" in cfg.layer_pattern)
     if cfg.mtp_modules and (xent_chunk or pipelined):
         raise ValueError(
             "mtp_modules=%d: the modules' loss is ops/head_loss.py's at a "
@@ -2507,6 +2773,8 @@ def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
             stats["mtp_loss"] = outputs["mtp_loss"].mean()
         if outputs.get("gate_excess") is not None:
             stats["kda_gate_excess"] = outputs["gate_excess"]
+        if outputs.get("chunk_keep") is not None:
+            stats["ssm_chunk_keep"] = outputs["chunk_keep"]
         if not moe:
             return stats
         load = outputs["moe_load"]
@@ -2535,7 +2803,7 @@ def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
             (optax.linear_schedule(0.0, learning_rate, int(warmup_steps))
              if warmup_steps else learning_rate), weight_decay=0.01,
             mask=(_decayed if cfg.moe_router == "sigmoid_bias"
-                  or cfg.post_norms or "d" in cfg.layer_pattern
+                  or cfg.post_norms or set("dm") & set(cfg.layer_pattern)
                   or cfg.hyper_streams else None)),
         feed=feed,
         eval_metrics_fn=lambda: {

@@ -139,12 +139,33 @@ def padded_rows(group_sizes, m):
     return blocks.sum() * sub - sizes.sum()
 
 
+def _lane_tiles(n):
+    """The multiples of 128 up to 1024 that divide ``n``, widest first:
+    the column tiles of a width of an ODD number of 128-lane tiles
+    (2,688 = 21, 1,920 = 15), which no power of two above 128 divides."""
+    whole = n // 128
+    return sorted((128 * d for d in range(1, min(whole, 8) + 1)
+                   if whole % d == 0), reverse=True)
+
+
+def _cut(t):
+    """``t`` over its smallest factor that leaves whole 128-lane tiles
+    (a half where it has one), or 0 where it has none."""
+    whole = t // 128
+    if t % 128:
+        return 0
+    return next((t // f for f in range(2, whole + 1) if whole % f == 0), 0)
+
+
 def _column_tile(tm, k, n, itemsize):
     """Widest column tile (all of n up to 1024, else a multiple of 128
     that divides it) whose double-buffered blocks fit the budget, or
     None."""
-    for tn in [n] * (n <= 1024) + [t for t in (1024, 512, 256, 128)
-                                   if t < n and n % t == 0]:
+    tiles = [n] * (n <= 1024) + [t for t in (1024, 512, 256, 128)
+                                 if t < n and n % t == 0]
+    if n > 1024 and n % 256 == 128:
+        tiles = _lane_tiles(n)
+    for tn in tiles:
         if 2 * itemsize * (tm * k + k * tn + tm * tn) <= _VMEM_BLOCKS:
             return tn
     return None
@@ -274,16 +295,18 @@ def _tgmm_kernel(offsets, group_ids, tile_ids, count, lhs_ref, dout_ref,
 
 def _tgmm_tiles(tm, k, n, itemsize):
     """(tk, tn) of the weight gradient's [k, n] blocks: whole if the
-    float32 accumulator and the blocks fit, else halved, k first."""
+    float32 accumulator and the blocks fit, else halved (cut by its
+    smallest factor that leaves whole 128-lane tiles: ``_cut``), k
+    first."""
     tk, tn = k, n
     while True:
         blocks = (4 * tk * tn + 2 * itemsize * (tk * tn + tm * (tk + tn)))
         if blocks <= _VMEM_BLOCKS:
             return tk, tn
-        if tk >= tn and tk % 256 == 0:
-            tk //= 2
-        elif tn % 256 == 0:
-            tn //= 2
+        if tk >= tn and _cut(tk):
+            tk = _cut(tk)
+        elif _cut(tn):
+            tn = _cut(tn)
         else:
             return None
 

@@ -1,6 +1,8 @@
 """The routed FFN of a dropless mixture of experts: sort, gather, three
-grouped matmuls, un-sort (``models/transformer._moe_ffn`` routes and
-weighs the statistics; this is the multiply).
+grouped matmuls (two where the experts are MLPs of two matrices, an
+activation without a gate product: ``GATELESS``), un-sort
+(``models/transformer._moe_ffn`` routes and weighs the statistics; this
+is the multiply).
 
 ``moe_experts`` is the op: it asks ``ops/mode.py`` which product runs
 (the Pallas ``grouped_matmul`` kernels, per shard of the trainer's data
@@ -52,14 +54,21 @@ BOUND_FACTOR = 2
 
 # The gate's activation by the model's name for it
 # (``TransformerConfig.ffn_activation``): act(gate) * up is SwiGLU with
-# "silu", ReGLU with "relu".
-ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+# "silu", ReGLU with "relu".  A name of ``GATELESS`` is an MLP of TWO
+# matrices, ``act(h W_up) W_down`` with no gate product and no
+# ``w_gate`` anywhere: "relu2", the squared ReLU.
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+               "relu2": lambda x: jnp.square(jax.nn.relu(x))}
+GATELESS = ("relu2",)
 
 
 def gated(activation, gate, up, limit=0.0):
     """``act(gate) * up`` of a gated MLP's two products; with a
     ``limit`` L > 0 the clamped form, ``act(min(gate, L)) * clip(up,
-    -L, L)``: no gradient reaches a value past its bound."""
+    -L, L)``: no gradient reaches a value past its bound.  ``gate``
+    None: an MLP of two matrices (``GATELESS``), ``act(up)``."""
+    if gate is None:
+        return ACTIVATIONS[activation](up)
     if limit:
         gate, up = jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
     return ACTIVATIONS[activation](gate) * up
@@ -264,11 +273,12 @@ def _block(i, c, activation, limit, x, gates, weights, order, sizes,
         pos = jnp.where((pos >= i * c) & (pos < i * c + live),
                         pos - i * c, -1)
     matmul = functools.partial(gm.grouped_matmul, zero_tail=True)
-    w_gate, w_up, w_down = weights
+    *w_gate, w_up, w_down = weights
     xs = checkpoint_name(
         tokens_to_rows(n, x, tok) if pos is None
         else _gather_rows(n, x, tok, pos, live, tokens), KEEP_ROWS)
-    gate = checkpoint_name(matmul(xs, w_gate, sizes), KEEP_GATE)
+    gate = checkpoint_name(matmul(xs, w_gate[0], sizes),
+                           KEEP_GATE) if w_gate else None
     up = checkpoint_name(matmul(xs, w_up, sizes), KEEP_UP)
     ys = checkpoint_name(
         matmul(gated(activation, gate, up, limit), w_down, sizes),
@@ -324,11 +334,13 @@ def _further_blocks_bwd(c, activation, limit, res, g):
 _further_blocks.defvjp(_further_blocks_fwd, _further_blocks_bwd)
 
 
-def _moe_experts(h, gates, experts, w_gate, w_up, w_down, total=None,
-                 first=0, activation="silu", limit=0.0):
+def _moe_experts(h, gates, experts, *weights, total=None, first=0,
+                 activation="silu", limit=0.0):
     """The routed FFN of the rows this device holds: sort the n * K
     (token, choice) assignments by expert, gather their rows, three
-    grouped matmuls, un-sort and sum each token's K results weighted by
+    grouped matmuls (``weights``: w_gate, w_up, w_down; two for an MLP
+    of two matrices, w_up and w_down alone), un-sort and sum each
+    token's K results weighted by
     its gates.  O(n * K * width) memory, no capacity, nothing dropped.
     Returns (out [b, T, E], load [1, X + 1]: rows per expert, then the
     rows the grouped matmul computes beyond the real ones).
@@ -342,7 +354,7 @@ def _moe_experts(h, gates, experts, w_gate, w_up, w_down, total=None,
     two more numbers: the rows the blocks that ran moved, and 1 if more
     than one ran."""
     b, t, e = h.shape
-    held, k = w_gate.shape[0], experts.shape[-1]
+    held, k = weights[0].shape[0], experts.shape[-1]
     x = total or held
     share = held != x
     n, rows = b * t, b * t * k
@@ -374,8 +386,8 @@ def _moe_experts(h, gates, experts, w_gate, w_up, w_down, total=None,
         pos = checkpoint_name(
             held_positions(flat, sizes).reshape(n, k),
             KEEP_SORT) if by_kernel else None
-        operands = (h.reshape(n, e), gates.reshape(n, k),
-                    (w_gate, w_up, w_down), order, sizes, pos)
+        operands = (h.reshape(n, e), gates.reshape(n, k), weights, order,
+                    sizes, pos)
         out = _further_blocks(
             bound, activation, limit,
             _block(jnp.int32(0), bound, activation, limit, *operands),
@@ -386,7 +398,9 @@ def _moe_experts(h, gates, experts, w_gate, w_up, w_down, total=None,
         return out.astype(h.dtype).reshape(b, t, e), load[None]
     xs = checkpoint_name(
         _take_rows(k, h.reshape(n, e), order, inverse), KEEP_ROWS)
-    gate = checkpoint_name(gm.grouped_matmul(xs, w_gate, sizes), KEEP_GATE)
+    *w_gate, w_up, w_down = weights
+    gate = checkpoint_name(gm.grouped_matmul(xs, w_gate[0], sizes),
+                           KEEP_GATE) if w_gate else None
     up = checkpoint_name(gm.grouped_matmul(xs, w_up, sizes), KEEP_UP)
     act = gated(activation, gate, up, limit)
     ys = checkpoint_name(_take_rows(
@@ -418,10 +432,28 @@ def announce_dispatch(tokens, experts, top_k, kernel, share=None,
             share + ("kernel" if by_kernel else "reference",)))
 
 
-def moe_experts(h, gates, experts, w_gate, w_up, w_down, total=None,
-                first=0, activation="silu", limit=0.0):
+def whole_lanes(weights, lanes=128):
+    """The experts' ``weights`` with their inner width (the columns of
+    w_gate and w_up, the rows of w_down) padded by zeros to whole
+    128-lane tiles, which the grouped matmul's kernels tile; as they
+    are where it is one already.  An activation's value at 0 is 0
+    (``ACTIVATIONS``), so a padded column's product is 0, it meets zero
+    rows of w_down and adds nothing; the pad's pullback cuts the
+    gradients back.  A width of 1,856 = 14.5 tiles runs as 1,920."""
+    *ins, down = weights
+    pad = -down.shape[1] % lanes
+    if not pad:
+        return tuple(weights)
+    return tuple(jnp.pad(w, ((0, 0), (0, 0), (0, pad))) for w in ins) + (
+        jnp.pad(down, ((0, 0), (0, pad), (0, 0))),)
+
+
+def moe_experts(h, gates, experts, *weights, total=None, first=0,
+                activation="silu", limit=0.0):
     """h [B, T, E], gates and experts [B, T, K], the three expert
-    weights [X, ...] in h's dtype (or the share ``first .. first + X``
+    ``weights`` [X, ...] in h's dtype, w_gate, w_up, w_down, or the two
+    of an MLP without a gate product, w_up, w_down (or the share
+    ``first .. first + X``
     of ``total`` experts: ``_moe_experts``), the gate's ``activation``
     (a name of ``ACTIVATIONS``) and the layer's clamp on the two
     products (``gated``'s ``limit``) -> (out [B, T, E], load
@@ -430,7 +462,10 @@ def moe_experts(h, gates, experts, w_gate, w_up, w_down, total=None,
     whole on each); the reference partitions by itself."""
     fn = functools.partial(_moe_experts, total=total, first=first,
                            activation=activation, limit=limit)
+    if len(weights) != 3 - (activation in GATELESS):
+        raise ValueError(
+            "activation=%s takes %d expert weights, got %d" % (
+                activation, 3 - (activation in GATELESS), len(weights)))
     if kernel_mode() == "off":
-        return fn(h, gates, experts, w_gate, w_up, w_down)
-    return per_batch_shard(fn, (h, gates, experts),
-                           (w_gate, w_up, w_down))
+        return fn(h, gates, experts, *weights)
+    return per_batch_shard(fn, (h, gates, experts), whole_lanes(weights))

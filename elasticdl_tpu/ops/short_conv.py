@@ -30,7 +30,10 @@ between its q, k, v projection and its scan (models/transformer.py).
 Its calls are ``sconv_silu_fwd`` (2 passes) and ``sconv_silu_bwd``, which
 rebuilds ``conv`` of the tile and of the HALO rows below it for the
 SiLU's slope (5 passes); the tiles, the halo and the taps' layout are
-the gated op's.
+the gated op's.  With a ``bias`` [E] (a Mamba-2 layer's ``use_conv_bias``)
+it is ``silu(conv(x) + bias)``: the bias rides as row ``BIAS_ROW`` of the
+taps' [8, E] block, and its gradient comes back in that row of ``dw``'s
+parts.
 
 Reference: ``short_conv_ref``, plain ``jax.numpy`` differentiated by JAX,
 which is also what ``short_conv`` returns wherever ``ops/mode.py``
@@ -82,10 +85,13 @@ def _taps_ref(g, w):
                for k in range(taps))
 
 
-def conv_silu_ref(x, w):
-    """``silu(conv(x))`` in plain ``jax.numpy`` (float32 inside, x's
-    dtype out); x [..., T, E], w [E, K]."""
-    return jax.nn.silu(_taps_ref(x.astype(jnp.float32), w)).astype(x.dtype)
+def conv_silu_ref(x, w, bias=None):
+    """``silu(conv(x) + bias)`` in plain ``jax.numpy`` (float32 inside,
+    x's dtype out); x [..., T, E], w [E, K], bias [E] or None."""
+    conv = _taps_ref(x.astype(jnp.float32), w)
+    if bias is not None:
+        conv = conv + bias.astype(jnp.float32)
+    return jax.nn.silu(conv).astype(x.dtype)
 
 
 def tiles(seq_len, channels):
@@ -203,11 +209,19 @@ def _specs(tm, tc, e, rows):
     return column, above, below, taps
 
 
-def _taps(w):
-    """[8, E] float32: row k is tap k of every channel."""
+# The row of the taps' [8, E] block that holds a bias on the taps.
+BIAS_ROW = 7
+
+
+def _taps(w, bias=None):
+    """[8, E] float32: row k is tap k of every channel; row ``BIAS_ROW``
+    the ``bias`` where there is one."""
     e, taps = w.shape
-    return jnp.zeros((8, e), jnp.float32).at[:taps].set(
+    rows = jnp.zeros((8, e), jnp.float32).at[:taps].set(
         w.astype(jnp.float32).T)
+    if bias is not None:
+        rows = rows.at[BIAS_ROW].set(bias.astype(jnp.float32))
+    return rows
 
 
 def _fwd_call(bcu, w, seq_len, interpret, tm, tc):
@@ -290,26 +304,31 @@ def _silu_slope(conv):
     return conv * s, s * (1.0 + conv * (1.0 - s))
 
 
+def _biased(conv, w_ref, bias):
+    return conv + w_ref[BIAS_ROW:BIAS_ROW + 1, :] if bias else conv
+
+
 def _silu_fwd_kernel(x_ref, x_up_ref, w_ref, out_ref, *, taps,
-                     tiles_a_sequence):
+                     tiles_a_sequence, bias=False):
     starts = pl.program_id(0) % tiles_a_sequence == 0
     above = jnp.where(starts, 0.0, x_up_ref[...].astype(jnp.float32))
-    conv = _conv(x_ref[...].astype(jnp.float32), above, w_ref, taps)
+    conv = _biased(_conv(x_ref[...].astype(jnp.float32), above, w_ref, taps),
+                   w_ref, bias)
     out_ref[...] = _silu_slope(conv)[0].astype(out_ref.dtype)
 
 
 def _silu_bwd_kernel(x_ref, x_up_ref, x_dn_ref, w_ref, dout_ref,
                      dout_dn_ref, dx_ref, dw_ref, *, taps,
-                     tiles_a_sequence):
+                     tiles_a_sequence, bias=False):
     starts = pl.program_id(0) % tiles_a_sequence == 0
     ends = (pl.program_id(0) + 1) % tiles_a_sequence == 0
     x = x_ref[...].astype(jnp.float32)
     above = jnp.where(starts, 0.0, x_up_ref[...].astype(jnp.float32))
     dconv = dout_ref[...].astype(jnp.float32) * _silu_slope(
-        _conv(x, above, w_ref, taps))[1]
+        _biased(_conv(x, above, w_ref, taps), w_ref, bias))[1]
     # the HALO rows below: their conv reads this tile's last rows
-    conv_dn = _conv(x_dn_ref[...].astype(jnp.float32),
-                    x[x.shape[0] - HALO:], w_ref, taps)
+    conv_dn = _biased(_conv(x_dn_ref[...].astype(jnp.float32),
+                            x[x.shape[0] - HALO:], w_ref, taps), w_ref, bias)
     below = jnp.where(ends, 0.0, dout_dn_ref[...].astype(jnp.float32)
                       * _silu_slope(conv_dn)[1])
     dx = w_ref[taps - 1:taps, :] * dconv
@@ -321,13 +340,18 @@ def _silu_bwd_kernel(x_ref, x_up_ref, x_dn_ref, w_ref, dout_ref,
         dw[k] = jnp.sum(dconv * _shifted(x, above, back), axis=0,
                         keepdims=True)
     row = lax.broadcasted_iota(jnp.int32, dw_ref.shape, 0)
-    dw_ref[...] = sum(jnp.where(row == k, dw[k], 0.0) for k in range(taps))
+    parts = sum(jnp.where(row == k, dw[k], 0.0) for k in range(taps))
+    if bias:
+        parts += jnp.where(row == BIAS_ROW,
+                           jnp.sum(dconv, axis=0, keepdims=True), 0.0)
+    dw_ref[...] = parts
     dx_ref[...] = dx.astype(dx_ref.dtype)
 
 
-def _silu_call(x, w, seq_len, interpret, tm, tc, dout=None):
-    """``silu(conv(x))`` [rows, E], or with ``dout`` its backward: (dx
-    [rows, E], dw's parts [tiles * 8, E] float32)."""
+def _silu_call(x, w, seq_len, interpret, tm, tc, dout=None, bias=None):
+    """``silu(conv(x))`` [rows, E] (``silu(conv(x) + bias)``), or with
+    ``dout`` its backward: (dx [rows, E], dw's parts [tiles * 8, E]
+    float32, the bias's in row ``BIAS_ROW``)."""
     rows, e = x.shape
     column, above, below, taps = _specs(tm, tc, e, rows)
     here = pl.BlockSpec((tm, tc), lambda i, j: (i, j))
@@ -338,12 +362,14 @@ def _silu_call(x, w, seq_len, interpret, tm, tc, dout=None):
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret)
     static = dict(taps=w.shape[1], tiles_a_sequence=seq_len // tm)
+    if bias is not None:
+        static["bias"] = True
     if dout is None:
         return pl.pallas_call(
             functools.partial(_silu_fwd_kernel, **static),
             out_shape=jax.ShapeDtypeStruct((rows, e), x.dtype),
             in_specs=[column(0), above(0), taps], out_specs=here,
-            name="sconv_silu_fwd", **options)(x, x, _taps(w))
+            name="sconv_silu_fwd", **options)(x, x, _taps(w, bias))
     return pl.pallas_call(
         functools.partial(_silu_bwd_kernel, **static),
         out_shape=(jax.ShapeDtypeStruct((rows, e), x.dtype),
@@ -351,7 +377,8 @@ def _silu_call(x, w, seq_len, interpret, tm, tc, dout=None):
         in_specs=[column(0), above(0), below(0), taps, column(0),
                   below(0)],
         out_specs=(here, pl.BlockSpec((8, tc), lambda i, j: (i, j))),
-        name="sconv_silu_bwd", **options)(x, x, x, _taps(w), dout, dout)
+        name="sconv_silu_bwd", **options)(x, x, x, _taps(w, bias), dout,
+                                          dout)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
@@ -372,31 +399,54 @@ def _sconv_silu_bwd(seq_len, interpret, tm, tc, res, dout):
 _sconv_silu.defvjp(_sconv_silu_fwd, _sconv_silu_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _sconv_silu_biased(x, w, bias, seq_len, interpret, tm, tc):
+    return _silu_call(x, w, seq_len, interpret, tm, tc, bias=bias)
+
+
+def _sconv_silu_biased_fwd(x, w, bias, seq_len, interpret, tm, tc):
+    return _sconv_silu_biased(x, w, bias, seq_len, interpret, tm, tc), (
+        x, w, bias)
+
+
+def _sconv_silu_biased_bwd(seq_len, interpret, tm, tc, res, dout):
+    x, w, bias = res
+    dx, parts = _silu_call(x, w, seq_len, interpret, tm, tc, dout, bias)
+    dbias = parts.reshape(-1, 8, w.shape[0]).sum(axis=0)[BIAS_ROW]
+    return dx, _taps_gradient(parts, w), dbias.astype(bias.dtype)
+
+
+_sconv_silu_biased.defvjp(_sconv_silu_biased_fwd, _sconv_silu_biased_bwd)
+
+
 @functools.lru_cache(maxsize=None)
-def announce_conv(rows, channels, tile, kernel, silu=False):
+def announce_conv(rows, channels, tile, kernel, silu=False, bias=False):
     """Once per compiled shape, by the logger ``announce_tiles`` uses:
     what the op runs (of one shard of the trainer's data axis); with
-    ``silu`` it is ``conv_silu``, and the line says so at its end."""
+    ``silu`` it is ``conv_silu``, and the line says so at its end, and
+    ``bias=1`` where the taps carry one."""
     flash_attention.logger.info(
-        "short conv: rows=%d channels=%d tile=%s kernel=%s%s", rows,
+        "short conv: rows=%d channels=%d tile=%s kernel=%s%s%s", rows,
         channels, "%dx%d" % tile if tile else "-", kernel,
-        " epilogue=silu" if silu else "")
+        " epilogue=silu" if silu else "", " bias=1" if bias else "")
 
 
-def _plan(what, batch, seq_len, e, taps, interpret, silu=False):
+def _plan(what, batch, seq_len, e, taps, interpret, silu=False,
+          bias=False):
     """(mode, tile) an op of this file runs [batch, seq_len, e] in:
     the kernels where ``ops/mode.py`` allows them and the shape tiles,
     else ("off", None), said once."""
     mode = resolve(interpret)
     tile = None if mode == "off" else tiles(seq_len, e)
-    if mode != "off" and (tile is None or taps > 8):
+    if mode != "off" and (tile is None or taps > 8 - bias):
         flash_attention.announce_fallback(
             what, (batch, seq_len, e),
             "T %% %d, E %% %d or %d taps" % (ROW_TILES[-1],
                                              CHANNEL_TILES[-1], taps), mode)
         tile, mode = None, "off"
     if mode != "interpret":
-        announce_conv(batch * seq_len // shards(), e, tile, mode, silu)
+        announce_conv(batch * seq_len // shards(), e, tile, mode, silu,
+                      bias)
     return mode, tile
 
 
@@ -420,20 +470,23 @@ def short_conv(bcu, w, interpret=None):
     return checkpoint_name(per_batch_shard(op, (bcu,), (w,)), KEEP_OUT)
 
 
-def conv_silu(x, w, interpret=None):
+def conv_silu(x, w, interpret=None, bias=None):
     """x [B, T, E], w [E, K] -> ``silu(conv(x))`` [B, T, E] in x's
-    dtype, the taps causal within each of the B sequences.
-    Differentiable in both.  The kernels where ``ops/mode.py`` allows
-    them and the shapes tile, else ``conv_silu_ref``."""
+    dtype, the taps causal within each of the B sequences; with ``bias``
+    [E], ``silu(conv(x) + bias)``.  Differentiable in all.  The kernels
+    where ``ops/mode.py`` allows them and the shapes tile, else
+    ``conv_silu_ref``."""
     batch, seq_len, e = x.shape
     mode, tile = _plan("conv_silu", batch, seq_len, e, w.shape[1],
-                       interpret, silu=True)
+                       interpret, silu=True, bias=bias is not None)
     if mode == "off":
-        return conv_silu_ref(x, w)
+        return conv_silu_ref(x, w, bias)
 
-    def op(x, w):
-        out = _sconv_silu(x.reshape(-1, e), w, seq_len,
-                          mode == "interpret", *tile)
+    def op(x, w, *bias):
+        call = _sconv_silu_biased if bias else _sconv_silu
+        out = call(x.reshape(-1, e), w, *bias, seq_len,
+                   mode == "interpret", *tile)
         return out.reshape(-1, seq_len, e)
 
-    return per_batch_shard(op, (x,), (w,))
+    return per_batch_shard(op, (x,), (w,) + (() if bias is None
+                                             else (bias,)))

@@ -66,16 +66,20 @@ def _loss_fields(stats):
     with it: `` mtp=`` the multi-token-prediction modules' mean loss
     before its weight, `` hc_err=`` the largest ``|row or column sum -
     1|`` of any Sinkhorn map of the step, `` g_excess=`` how far under
-    its floor a kda layer's lowest log decay of the step lies
-    (``models/transformer.py``: ``mtp_modules``, ``hyper_streams``,
-    ``delta_gate_floor``); nothing for a model with none of them.
+    its floor a kda layer's lowest log decay of the step lies,
+    `` chunk_keep=`` the share of a Mamba-2 layer's state that outlives
+    a chunk of 128 tokens, the mean over the step's layers, heads and
+    chunks (``models/transformer.py``: ``mtp_modules``,
+    ``hyper_streams``, ``delta_gate_floor``, an "m" layer); nothing for
+    a model with none of them.
     Fetched after the loss: the same program made them."""
     stats = stats or {}
     return "".join(
         " %s=%s" % (name, form % float(stats[key]))
         for name, key, form in (("mtp", "mtp_loss", "%.6f"),
                                 ("hc_err", "hc_err", "%.3e"),
-                                ("g_excess", "kda_gate_excess", "%.3e"))
+                                ("g_excess", "kda_gate_excess", "%.3e"),
+                                ("chunk_keep", "ssm_chunk_keep", "%.6f"))
         if key in stats)
 
 

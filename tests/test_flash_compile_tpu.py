@@ -750,3 +750,110 @@ def test_the_four_calls_compile_at_the_cells_shape(one_chip):
     # the stream in, its gradient out, and under two streams between
     stats = compiled.memory_analysis()
     assert stats.temp_size_in_bytes < 2.2 * HC_ROWS * HC_N * HC_C * 2
+
+
+# -- the state-space hybrid's kernels and count (PR 61) -----------------------
+
+NEMOTRON3_PARAMETERS = 666963456
+
+
+def test_the_state_space_scan_compiles_at_the_cells_shape(one_chip):
+    """One sequence of 16,384 tokens, 64 heads of 64 over a state of 128,
+    B and C in 8 groups, token-major as the projection leaves them: one
+    ``ssd_fwd`` that writes y and the chunk-start states, one
+    ``ssd_bwd`` that writes dx, dB and dC (once a group) and the gates'
+    cotangents; no copy of x, B or C head-major beside them."""
+    from elasticdl_tpu.ops import ssd
+
+    on_chip = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    x = on_chip((1, 16384, 64, 64), jnp.bfloat16)
+    bc = on_chip((1, 16384, 8, 128), jnp.bfloat16)
+    gate = on_chip((1, 16384, 64), jnp.float32)
+    assert ssd.ssd_mode(16384, 8, 64, 128, interpret=False) == ("tpu", "")
+
+    def fwd_bwd(x, b, c, g, dt, cot):
+        out, pull = jax.vjp(
+            lambda *a: ssd.ssd(*a, interpret=False), x, b, c, g, dt)
+        return out, pull(cot)
+
+    text = jax.jit(fwd_bwd).lower(x, bc, bc, gate, gate, x).compile(
+        ).as_text()
+    calls = _mosaic_calls(text)
+    assert len(calls) == 2, calls
+    fwd = next(c for c in calls if "ssd_fwd" in c.split(" = ")[0])
+    bwd = next(c for c in calls if "ssd_bwd" in c.split(" = ")[0])
+    results = lambda call: call.split(" custom-call(")[0]
+    assert "bf16[1,16384,4096]" in results(fwd)
+    assert "f32[1,8,128,512,128]" in results(fwd)      # the states
+    assert results(bwd).count("bf16[1,16384,1024]") == 2      # dB, dC
+    assert "f32[1,64,128,2,128]" in results(bwd)
+
+
+def test_the_convolution_with_a_bias_compiles_at_the_cells_shape(one_chip):
+    """One sequence of 16,384 x 6,144 channels (x | B | C), four taps and
+    a bias a channel in row 7 of the taps' block."""
+    from elasticdl_tpu.ops import short_conv as sc
+
+    x = jax.ShapeDtypeStruct((1, 16384, 6144), jnp.bfloat16,
+                             sharding=one_chip)
+    w = jax.ShapeDtypeStruct((6144, 4), jnp.float32, sharding=one_chip)
+    bias = jax.ShapeDtypeStruct((6144,), jnp.float32, sharding=one_chip)
+
+    def fwd_bwd(x, w, bias, cot):
+        out, pull = jax.vjp(lambda x, w, b: sc.conv_silu(
+            x, w, interpret=False, bias=b), x, w, bias)
+        return out, pull(cot)
+
+    calls = _mosaic_calls(
+        jax.jit(fwd_bwd).lower(x, w, bias, x).compile().as_text())
+    assert len(calls) == 2 and any(
+        "sconv_silu_bwd" in c.split(" = ")[0] for c in calls), calls
+
+
+@pytest.mark.parametrize("k,n", [(2688, 1920), (1920, 2688)])
+def test_grouped_matmul_compiles_at_an_odd_number_of_lane_tiles(one_chip, k,
+                                                                n):
+    """A hidden size of 2,688 = 21 tiles of 128 lanes beside experts of
+    1,856 run as 1,920 = 15 (``moe_dispatch.whole_lanes``): no power of
+    two above 128 divides either, and the column tiles are their own
+    divisors (640 | 896), the weight gradient's blocks a third."""
+    rows, groups = 12288, 8
+    assert gm._unfriendly(k, n, gm.row_tile(rows), 2) == ""
+    assert gm._column_tile(512, k, n, 2) == {1920: 640, 2688: 896}[n]
+    assert gm._tgmm_tiles(512, 2688, 1920, 2) == (896, 1920)
+    lhs = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip)
+    rhs = jax.ShapeDtypeStruct((groups, k, n), jnp.bfloat16,
+                               sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((groups,), jnp.int32, sharding=one_chip)
+    cot = jax.ShapeDtypeStruct((rows, n), jnp.bfloat16, sharding=one_chip)
+
+    def fwd_bwd(lhs, rhs, sizes, cot):
+        out, vjp = jax.vjp(lambda lhs, rhs: gm.grouped_matmul(
+            lhs, rhs, sizes, interpret=False, zero_tail=True), lhs, rhs)
+        return out, vjp(cot)
+
+    text = jax.jit(fwd_bwd).lower(lhs, rhs, sizes, cot).compile().as_text()
+    assert _names(text) == {"gmm_nn": 1, "gmm_nt": 1, "gmm_tn": 1}
+
+
+def test_the_state_space_hybrids_parameters_are_the_configurations_count():
+    """``nemotron-3-nano-30b-a3b`` as ``init_params`` builds it: four
+    Mamba-2 layers (38,744,896 each), four expert layers of two-matrix
+    MLPs (100,125,440 each: 8 held experts of 9,977,856, the router, its
+    bias, the shared expert of 3,712 and ONE norm), the attention layer
+    (23,399,040), the untied 16,384-id vocabulary (88,080,384) and the
+    last norm: the count the configuration's ``reduced_why`` states,
+    shapes alone; no ``w_gate`` anywhere."""
+    spec = tfm.model_spec(**_model_params("nemotron-3-nano-30b-a3b"))
+    params = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))
+    count = lambda tree: sum(a.size for a in jax.tree_util.tree_leaves(tree))
+    period = params["layers"]["period"]
+    assert [count(period[str(i)]) for i in range(9)] == [
+        {"m": 38744896, "e": 100125440, "a": 23399040}[letter]
+        for letter in "mememaeme"]
+    assert count(params) == NEMOTRON3_PARAMETERS
+    names = {name for layer in period.values() for name in layer}
+    assert not names & {"w_gate", "ws_gate"}
+    assert count(period["1"]["w_up"]) + count(
+        period["1"]["w_down"]) == 8 * 9977856

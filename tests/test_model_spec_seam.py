@@ -90,6 +90,13 @@ AS_TYPED = {
                    "scan_periods=false;dense_layers=0;layer_pattern=aa"),
     "shared_limits": ("0,7", "0,7", "moe_experts=4;num_layers=2;"
                       "scan_periods=false;layer_pattern=aa"),
+    "ssm_heads": ("4", 4, ""),
+    "ssm_head_dim": ("16", 16, ""),
+    "ssm_state": ("32", 32, ""),
+    "ssm_groups": ("2", 2, ""),
+    "conv_bias": ("true", True, "num_layers=2;layer_pattern=ma;ssm_heads=2;"
+                  "ssm_head_dim=8;ssm_state=8"),
+    "mixer_ffn": ("false", False, "num_layers=2;layer_pattern=ae"),
 }
 FIELDS = [field for field in dataclasses.fields(tfm.TransformerConfig)
           if field.name != "max_seq_len"]
@@ -237,6 +244,51 @@ def test_a_kda_stack_is_refused_in_the_one_place_by_its_fields(what, call):
     said = str(refusal.value)
     assert said.startswith(what + " does not run a stack whose layers "
                            "differ (layer_pattern='addd', dense_layers=0, "
-                           "delta_kind=kda)")
+                           "delta_kind=kda, mixer_ffn=True)")
     assert "gdn or kda" in said
     assert ("moe_experts_held=2" in said) == (what == "a model-parallel mesh")
+
+
+SSM = dict(num_layers=3, layer_pattern="mae", mixer_ffn=False, rope_kinds="w",
+           num_heads=2, num_kv_heads=1, head_dim=32, dim=64, ssm_heads=4,
+           ssm_head_dim=16, ssm_state=16, ssm_groups=2, conv_kernel=4,
+           conv_bias=True, ffn_activation="relu2", moe_experts=8,
+           moe_experts_held=2, vocab_size=64, max_seq_len=64)
+
+
+@pytest.mark.parametrize("what,call", [
+    ("decode_step", lambda cfg: tfm.decode_step(None, cfg, None, 0, None)),
+    ("prefill", lambda cfg: tfm.prefill(None, cfg, None, 8)),
+    ("forward_pipelined", lambda cfg: tfm.forward_pipelined(
+        None, None, cfg, None, 2)),
+    ("a model-parallel mesh", tfm.param_specs),
+])
+def test_a_stack_of_single_sublayers_is_refused_by_its_fields(what, call):
+    """Decoding, the pipelined forward and a mesh refuse the Mamba-2
+    mixer, the layers of one sublayer and the MLP of two matrices
+    through ``_refuse``'s rows, each by its name and its field."""
+    cfg = tfm.TransformerConfig(**SSM)
+    with pytest.raises(NotImplementedError) as refusal:
+        call(cfg)
+    said = str(refusal.value)
+    assert said.startswith(what + " does not run a stack whose layers "
+                           "differ (layer_pattern='mae', dense_layers=0, "
+                           "delta_kind=gdn, mixer_ffn=False)")
+    assert "a Mamba-2 layer (m) a state [ssm_heads, ssm_head_dim" in said
+    assert "a layer of one sublayer (e, or any layer under" in said
+    assert (what + " does not run an MLP of two matrices (ffn_activation="
+            "relu2: no w_gate, no ws_gate)") in said
+    assert ("moe_experts_held=2" in said) == (what == "a model-parallel mesh")
+
+
+@pytest.mark.parametrize("more, match", [
+    (dict(attn_gate=True), "is not held to attn_gate"),
+    (dict(mtp_modules=1), "is not held to attn_gate"),
+    (dict(ssm_groups=3), "ssm_groups that divide the heads"),
+    (dict(layer_pattern="maa"), "mixer_ffn=false: a layer with an operator"),
+    (dict(ffn_limits="0,0,4"), "has no gate product"),
+])
+def test_what_a_stack_of_single_sublayers_cannot_be_is_refused(more, match):
+    with pytest.raises(ValueError, match=match):
+        tfm.TransformerConfig(**dict(SSM, **more))
+

@@ -1,0 +1,158 @@
+"""The cut ``nemotron-3-nano-30b-a3b`` model at its rehearsal size (a
+Mamba-2 layer, an expert layer, a Mamba-2 layer, an attention layer and
+an expert layer, ONE sublayer each: published layers 2-6, ``MEM*E``; 2
+of 8 two-matrix experts) against ``benchmark/reference/
+nemotron-3-nano-30b-a3b.py``: the loss and every gradient leaf in
+float32, the jnp twins and the interpreted kernels, and what the
+reference's limits can tell apart.  The mechanisms one by one:
+tests/test_nemotron3_layers.py.
+"""
+
+import functools
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.lib import manifest
+from benchmark.lib.runner import merge, params_string
+from elasticdl_tpu.models.spec import load_model_spec
+from elasticdl_tpu.ops.mode import SWITCH
+
+REF = manifest.load_named("reference", "nemotron-3-nano-30b-a3b")
+with open(os.path.join(manifest.BENCH_DIR, "configs",
+                       "nemotron-3-nano-30b-a3b.json")) as fh:
+    PUBLISHED = json.load(fh)
+CONFIG = merge(PUBLISHED, PUBLISHED["rehearsal"])
+SHAPE = REF.shape_of(CONFIG)
+LOSS_TOLERANCE = 2e-6
+GRAD_TOLERANCE = 5e-4
+
+
+@functools.lru_cache(maxsize=None)
+def _case(seed=3):
+    spec = load_model_spec("transformer", model_params=params_string(
+        CONFIG["cli"]["model_params"]))
+    params, tokens = REF.inputs(
+        CONFIG, jax.jit(spec.init_fn)(jax.random.PRNGKey(seed)),
+        np.random.default_rng(seed))
+
+    def spread(w):      # scores wider than the bias, as at 2688
+        return {k: spread(v) if isinstance(v, dict) else
+                10.0 * v if k == "w_router" else v for k, v in w.items()}
+
+    return spec, spread(params), jnp.concatenate([tokens, tokens[:, ::-1]])
+
+
+def _product(spec, tokens):
+    return lambda p: spec.loss_fn(spec.apply_fn(p, tokens, True),
+                                  tokens).mean()
+
+
+def _reference(tokens, **how):
+    return lambda p: REF.loss(p, tokens, **how, **SHAPE)[0].mean()
+
+
+@functools.lru_cache(maxsize=None)
+def _wanted():
+    spec, params, tokens = _case()
+    return jax.value_and_grad(_reference(tokens))(params)
+
+
+def test_the_rehearsal_model_is_the_cells_with_smaller_numbers():
+    spec, params, _ = _case()
+    cfg = spec.config
+    cell = load_model_spec("transformer", model_params=params_string(
+        PUBLISHED["cli"]["model_params"])).config
+    assert "".join(k.op for k in cfg.kinds) == "memae"
+    assert "".join(k.op for k in cell.kinds) == "mememaeme"
+    assert PUBLISHED["hybrid_override_pattern"][:9] == "MEMEM*EME"
+    assert all((k.op != "e") + k.ffn == 1 for k in cell.kinds)
+    assert [k.dense for k in cell.kinds] == [k.op != "e" for k in cell.kinds]
+    for field in ("moe_router", "ffn_activation", "mixer_ffn", "conv_bias",
+                  "conv_kernel", "scan_periods", "moe_route_scale",
+                  "moe_shared_experts", "rope_kinds", "norm_eps", "remat",
+                  "tied_embeddings"):
+        assert getattr(cfg, field) == getattr(cell, field), field
+    assert (cell.ssm_heads, cell.ssm_head_dim, cell.ssm_state,
+            cell.ssm_groups) == (64, 64, 128, 8)
+    assert (cell.moe_top_k, cell.moe_experts, cell.moe_experts_held,
+            cell.mlp_dim, cell.shared_dim) == (6, 128, 8, 1856, 3712)
+    assert (cell.num_heads, cell.kv_heads, cell.head_dim, cell.dim) == (
+        32, 2, 128, 2688)
+    assert not any(k.rope for k in cell.kinds if k.op == "a")
+    assert SHAPE["kinds"] == ("mamba", "experts", "mamba", "attention",
+                              "experts")
+    assert REF.shape_of(PUBLISHED)["kinds"].count("mamba") == 4
+
+
+@pytest.mark.parametrize("mode", ["off", "interpret"])
+def test_the_stack_of_single_sublayers_matches_the_reference(monkeypatch,
+                                                             mode):
+    """The loss and every gradient leaf against the plain reference:
+    ``off`` the jnp twins under ``jax.checkpoint``, ``interpret`` the
+    state-space scan's, the convolution's, the flash and the dispatch's
+    kernels in the interpreter.  No gradient reaches ``expert_bias``."""
+    monkeypatch.setenv(SWITCH, mode)
+    spec, params, tokens = _case()
+    got, grads = jax.jit(jax.value_and_grad(_product(spec, tokens)))(params)
+    want, wanted = _wanted()
+    assert abs(float(got) - float(want)) <= LOSS_TOLERANCE * float(want)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    far = {}
+    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(wanted)):
+        name = jax.tree_util.keystr(path)
+        if "expert_bias" in name:
+            assert not float(jnp.abs(g).max()), name
+            continue
+        far[name] = float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w))
+    # two Mamba-2 mixers of 9 leaves, attention's 5, two expert layers
+    # of 6 beside their bias, embed, ln_f, lm_head
+    assert len(far) == 2 * 9 + 5 + 2 * 6 + 3
+    assert max(far.values()) < GRAD_TOLERANCE, sorted(
+        far.items(), key=lambda item: -item[1])[:4]
+
+
+@pytest.mark.parametrize("piece", REF.PIECES)
+def test_the_reference_without_one_mechanism_is_another_loss(piece):
+    """Each mechanism the row forced (the skip ``D``, the bias on the
+    taps, the gate before the norm, the route's scale, the shared
+    expert) left out, or a gate product put back, moves the loss by far
+    more than the tolerance the product is held to."""
+    spec, params, tokens = _case()
+    want = float(_wanted()[0])
+    other = float(_reference(tokens, without=(piece,))(params))
+    assert not abs(other - want) <= 20 * LOSS_TOLERANCE * want, (
+        piece, other, want)
+
+
+def test_the_layer_check_passes_in_float32_and_sees_what_it_should():
+    """``case`` as ``lib/compare.py`` calls it: the routing floor and
+    every layer ceiling hold at float32; the same check refuses the
+    reference in float8 part by part, and a bfloat16 state or bfloat16
+    cumulative decays move the probe that remembers by orders."""
+    spec, params, tokens = _case()
+    tokens = tokens[:1]
+    _, seen = REF.loss(params, tokens, **SHAPE)
+    assert REF.check_routing(CONFIG, params, seen) == 1.0
+    limits = REF.ceilings()
+    # ``case`` holds the first Mamba-2 layer, the first expert layer and
+    # the probe; ``every`` layer and attention is the precision tool's
+    assert set(REF.layer_errors(CONFIG)(params, seen)) == set(
+        REF.LAYER_PARTS) - {"attention"}
+    errors = REF.layer_errors(CONFIG)(params, seen, True)
+    assert set(errors) == set(REF.LAYER_PARTS)
+    assert all(errors[part] <= 1e-4 for part in errors), errors
+    worse = REF.layer_errors(CONFIG, rounded=jnp.float8_e4m3fn)(
+        params, seen, True)
+    for part in ("mamba", "attention", "shared_expert", "routed_experts"):
+        assert worse[part] > limits[part], (part, worse)
+    # at 256 tokens a rounding has had no time to drift past the
+    # ceiling: it is further off than float32 by orders, which the chip
+    # shows at 16,384 (benchmark/tools/nemotron3_precision.py)
+    for how in (dict(state=jnp.bfloat16), dict(decays=jnp.bfloat16)):
+        coarse = REF.layer_errors(CONFIG, **how)(params, seen)
+        assert coarse["ssm_state"] > 100 * errors["ssm_state"], how
