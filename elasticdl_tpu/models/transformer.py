@@ -1144,6 +1144,25 @@ def _rmsnorm(x, scale, eps=1e-6, axis=-1):
     return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 
+def _group_rmsnorm(x, scale, groups, eps=1e-6):
+    """RMSNorm of each of ``groups`` equal runs of lanes of the last
+    axis by its own mean square (a Mamba-2 mixer's gated norm), on x as
+    it stands: ``_rmsnorm`` of the ``[.., groups, -1]`` view puts a
+    small axis on the sublanes, another tiling, and the compiler moves
+    float32 planes round it.  A group's sum is a product with the
+    ``[lanes, groups]`` 0 / 1 membership matrix and its ``rsqrt`` is
+    spread back by the transpose (exact: one term a sum), on the MXU, as
+    ``ops/ssd.py``'s ``_head_sums`` and ``_columns``; ``_rmsnorm``'s
+    roundings in ``_rmsnorm``'s places.  ``scale`` [lanes]."""
+    lanes = x.shape[-1]
+    member = (jnp.arange(lanes)[:, None] // (lanes // groups)
+              == jnp.arange(groups)).astype(jnp.float32)
+    dot = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+    var = dot(jnp.square(x.astype(jnp.float32)), member) / (lanes // groups)
+    rstd = dot(jax.lax.rsqrt(var + eps), member.T)
+    return (x * rstd).astype(x.dtype) * scale
+
+
 def yarn_ramp(d, theta, scaling):
     """[d / 2] float64: how far each of RoPE's frequencies for heads of
     ``d`` goes from itself (0) to itself / factor (1) under YaRN: a
@@ -1847,10 +1866,9 @@ def _ssm_mix(h, w, cfg):
         B, T // size, size, H).sum(axis=2)).mean()
     y = ssd.ssd(x, b, c, g, dt)
     y = y + x * w["ssm_D"].astype(compute_dtype)[:, None]
-    y = (y.reshape(B, T, inner) * jax.nn.silu(z)).reshape(B, T, G, -1)
-    y = _rmsnorm(y, w["ssm_norm"].astype(compute_dtype).reshape(G, -1),
-                 cfg.norm_eps)
-    out = y.reshape(B, T, inner) @ w["ssm_out"].astype(compute_dtype)
+    y = _group_rmsnorm(y.reshape(B, T, inner) * jax.nn.silu(z),
+                       w["ssm_norm"].astype(compute_dtype), G, cfg.norm_eps)
+    out = y @ w["ssm_out"].astype(compute_dtype)
     return out, jax.lax.stop_gradient(keep)
 
 

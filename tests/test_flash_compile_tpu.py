@@ -27,7 +27,7 @@ from elasticdl_tpu.ops import hyper_mix as hm
 from elasticdl_tpu.ops import row_moves
 
 from tests.tpu_compile import (  # noqa: F401 (one_chip: a fixture)
-    _model_params, _mosaic_calls, _names, one_chip)
+    _entry_ops, _model_params, _mosaic_calls, _moved_bytes, _names, one_chip)
 
 
 @pytest.mark.parametrize("t,d,dtype,window", [
@@ -809,6 +809,73 @@ def test_the_convolution_with_a_bias_compiles_at_the_cells_shape(one_chip):
         jax.jit(fwd_bwd).lower(x, w, bias, x).compile().as_text())
     assert len(calls) == 2 and any(
         "sconv_silu_bwd" in c.split(" = ")[0] for c in calls), calls
+
+
+def _norm_of_the_view(x, scale, groups, eps):
+    """The grouped norm as ``_ssm_mix`` wrote it before PR 62."""
+    y = x.reshape(*x.shape[:-1], groups, -1)
+    return tfm._rmsnorm(y, scale.reshape(groups, -1), eps).reshape(x.shape)
+
+
+@pytest.mark.parametrize("norm", ["rows", "view"])
+def test_a_mamba2_layers_grouped_norm_stays_on_the_rows_tiling(
+        one_chip, monkeypatch, norm):
+    """One Mamba-2 layer of ``nemotron-3-nano-30b-a3b.seq16384``
+    (``_ssm_mix`` on one sequence of 16,384: the projection, the
+    convolution, the scan, the gate, the grouped norm over 8 groups of
+    512, ``ssm_out``), forward + backward through the TPU's compiler:
+    no array of the ``[.., 8, 512]`` view's shapes anywhere in the
+    program, and no ``copy`` or ``reshape`` standing alone whose result
+    is a float32 ``[16384, 4096]`` plane.  ``view`` runs the parent's
+    expression in the norm's place and the same reader finds both (8 on
+    the sublanes is another tiling than ``[16384, 4096]``'s: the
+    compiler converts to float32, re-tiles, writes the statistic out as
+    a plane and re-tiles back; 36.9 ms of a 441.1 ms step on the chip,
+    PERF.md section 6, PR 62), so the first case cannot pass by finding
+    nothing.  No time is read here."""
+    from elasticdl_tpu.ops.mode import SWITCH
+
+    monkeypatch.setenv(SWITCH, "tpu")     # the ops' own choice on a chip
+    if norm == "view":
+        monkeypatch.setattr(tfm, "_group_rmsnorm", _norm_of_the_view)
+    spec = tfm.model_spec(**_model_params("nemotron-3-nano-30b-a3b"))
+    cfg = spec.config
+    rows, inner = 16384, cfg.ssm_heads * cfg.ssm_head_dim
+    assert (cfg.ssm_groups, inner) == (8, 4096)
+    layer = jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0))[
+        "layers"]["period"]["0"]
+    w = {name: jax.ShapeDtypeStruct(a.shape[1:], a.dtype, sharding=one_chip)
+         for name, a in layer.items() if name != "ln1"}
+    h = jax.ShapeDtypeStruct((1, rows, cfg.dim), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def fwd_bwd(h, w, g):
+        out, vjp = jax.vjp(lambda h, w: tfm._ssm_mix(h, w, cfg)[0], h, w)
+        return out, vjp(g)
+
+    text = jax.jit(fwd_bwd).lower(h, w, h).compile().as_text()
+    assert _names(text) == {"sconv_silu_fwd": 1, "sconv_silu_bwd": 1,
+                            "ssd_fwd": 1, "ssd_bwd": 1}
+    viewed = [shape for shape in ("f32[16384,8,512]", "f32[2048,8,8,512]",
+                                  "f32[1,16384,8,512]") if shape in text]
+    ops = _entry_ops(text)
+    planes = [op.name for op in ops
+              if op.op in ("copy", "reshape") and any(
+                  kind == "f32" and dims[-2:] == (rows, inner)
+                  and math.prod(dims) == rows * inner
+                  for kind, dims in op.results)]
+    # what the layer's copies, reshapes and broadcasts move, at HBM's
+    # 819 GB/s: 0.1 ms on the rows, 4.2 ms round the view (the method:
+    # docs/designs/nemotron_h_layers.md)
+    moved = _moved_bytes(ops)
+    priced = sum(moved[op.name] for op in ops
+                 if op.op in ("copy", "reshape", "broadcast")) / 819e9
+    if norm == "rows":
+        assert not viewed and not planes, (viewed, planes)
+        assert priced < 0.3e-3, priced
+    else:
+        assert len(viewed) == 3 and len(planes) >= 3, (viewed, planes)
+        assert priced > 3e-3, priced
 
 
 @pytest.mark.parametrize("k,n", [(2688, 1920), (1920, 2688)])

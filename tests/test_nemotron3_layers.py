@@ -187,6 +187,66 @@ def test_the_grouped_norm_is_a_groups_own():
                 run(dict(w, ssm_out=w_out))) > 0.1
 
 
+def _norm64(x, scale, groups, eps, cot):
+    """The grouped norm written out in float64: its value and what
+    ``cot`` pulls back to x and to the scale."""
+    x, scale, cot = (np.asarray(a, np.float64) for a in (x, scale, cot))
+    split = lambda a: a.reshape(*a.shape[:-1], groups, -1)
+    xs, ss, cs = split(x), split(scale), split(cot)
+    rstd = 1.0 / np.sqrt(np.square(xs).mean(-1, keepdims=True) + eps)
+    pulled = cs * ss
+    dx = pulled * rstd - xs * rstd ** 3 * (pulled * xs).mean(
+        -1, keepdims=True)
+    dscale = (cs * xs * rstd).reshape(-1, x.shape[-1]).sum(axis=0)
+    return (xs * rstd * ss).reshape(x.shape), dx.reshape(x.shape), dscale
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("groups, width", [(8, 512), (1, 128), (3, 128)])
+def test_the_grouped_norm_on_the_rows_is_the_norm_of_the_view(groups, width,
+                                                              dtype):
+    """``_group_rmsnorm`` on ``[.., groups x width]`` as it stands
+    against ``_rmsnorm`` of the ``[.., groups, width]`` view (what
+    ``_ssm_mix`` ran before PR 62) and against float64: the value and
+    the gradients of x and the scale; rows whose sizes differ forty
+    times, so a statistic that leaked between rows or groups shows."""
+    eps = 1e-5
+    rng = np.random.default_rng(groups)
+    x = jnp.asarray(rng.standard_normal((2, 48, groups * width))
+                    * rng.uniform(0.1, 4.0, (2, 48, 1)), dtype)
+    scale = jnp.asarray(1 + 0.25 * rng.standard_normal(groups * width),
+                        dtype)
+    cot = jnp.asarray(rng.standard_normal(x.shape), dtype)
+
+    def view(x, scale):
+        y = x.reshape(*x.shape[:-1], groups, -1)
+        return tfm._rmsnorm(y, scale.reshape(groups, -1), eps).reshape(
+            x.shape)
+
+    def pulled(norm):
+        y, pull = jax.vjp(jax.jit(norm), x, scale)
+        return (y,) + pull(cot)
+
+    got = pulled(lambda x, scale: tfm._group_rmsnorm(x, scale, groups, eps))
+    assert [a.dtype for a in got] == [x.dtype] * 3
+    for what, a, b, c in zip(("y", "dx", "dscale"), got, pulled(view),
+                             _norm64(x, scale, groups, eps, cot)):
+        a, b = (np.asarray(v, np.float64) for v in (a, b))
+        if dtype == "float32":
+            assert _far(a, b) < 1e-5 and _far(a, c) < 1e-5, what
+        else:
+            # the same roundings in the same places: where the
+            # statistic's last float32 bit tips one, one ulp of the
+            # normed value (2 ** -7 of it at most) through the scale's
+            # product and its rounding; in a gradient, one of the
+            # largest term of a row's sums
+            ulp = 2.0 ** -6 * (np.abs(b) if what == "y" else
+                               np.abs(b).max(axis=-1, keepdims=True))
+            assert (np.abs(a - b) <= ulp).all(), (
+                what, (np.abs(a - b) / ulp).max())
+            assert (a != b).mean() < 1e-3 and _far(a, c) < 2.0 ** -6, what
+
+
 # -- MLPs of two matrices ----------------------------------------------------
 
 

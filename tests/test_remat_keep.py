@@ -348,9 +348,12 @@ CELLS = {
     # projection of x | B | C and the routed up and down products (4.25
     # GB kept: ``ssm scan: .. states=kept``); the convolved x | B | C
     # (0.81 GB) and the sorted rows do not fit (my chip runs, PR 61,
-    # ``t3`` traced and ``six`` six untraced seeds: 15.666 GB each)
+    # ``t3`` traced and ``six`` six untraced seeds: 15.666 GB each;
+    # 15.625 on every run of PR 62, the grouped norm's float32 planes
+    # round its ``[.., 8, 512]`` view gone: ``c1``, one traced and two
+    # untraced seeds)
     "nemotron-3-nano-30b-a3b.seq16384": (
-        "nemotron-3-nano-30b-a3b", 1, 1, 15.666,
+        "nemotron-3-nano-30b-a3b", 1, 1, 15.625,
         ["flash", "route", "qkv", "ssm_decay", "ssm_gate", "shared_up",
          "ssm", "ssm_in", "moe_up", "moe_out"],
         ROUTED[:7] + (rk.KEEP_SSM_DECAY, rk.KEEP_SSM_GATE,

@@ -17,6 +17,7 @@ described inside a fixture, never at import.
 import collections
 import functools
 import json
+import math
 import os
 import re
 
@@ -98,6 +99,54 @@ def _products(text, result):
     return len([body for body in _fused_computations(text).values()
                 if any(" = %s{" % result in l and " convolution(" in l
                        for l in body)])
+
+
+EntryOp = collections.namedtuple("EntryOp", "name op results operands")
+
+_WIDTH = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+          "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
+          "u64": 8}
+
+
+def _entry_ops(text):
+    """The instructions of a compiled program's entry computation, in
+    order: (name, opcode, its results as (type, dims) pairs, the names
+    of its operands).  What stands here is what the chip runs as an
+    operation of its own and a trace names: a ``copy`` or a ``reshape``
+    inside a fused computation is the fusion's, and is not listed."""
+    ops, inside = [], False
+    for line in text.splitlines():
+        if line.startswith("ENTRY "):
+            inside = True
+        elif line.startswith("}"):
+            inside = False
+        elif inside:
+            made = re.match(
+                r"\s*(?:ROOT )?%?(\S+) = (.*?) ([\w-]+)\((.*?)\)(?:, |$)",
+                line.split(", metadata=")[0])
+            if made:
+                ops.append(EntryOp(
+                    made.group(1), made.group(3),
+                    [(kind, tuple(int(d) for d in dims.split(",") if d))
+                     for kind, dims in re.findall(r"(\w+)\[([\d,]*)\]",
+                                                  made.group(2))],
+                    re.findall(r"%([\w.\-]+)", made.group(4))))
+    return ops
+
+
+def _moved_bytes(ops):
+    """name -> the bytes an entry instruction reads and writes, taken
+    from shapes: its results and its operands' results, each whole (a
+    fusion that reads a slice of an operand is counted high).  Over
+    HBM's 819 GB/s this priced the copy, reshape, broadcast, slice,
+    reduce and pad classes of ``nemotron-3-nano-30b-a3b.seq16384``'s
+    step to ~10% of the chip's trace (PERF.md section 6, PR 62)."""
+    size = lambda results: sum(
+        _WIDTH.get(kind, 0) * math.prod(dims) for kind, dims in results)
+    wrote = {op.name: size(op.results) for op in ops}
+    return {op.name: wrote[op.name] + sum(wrote.get(name, 0)
+                                          for name in op.operands)
+            for op in ops}
 
 
 def _step(spec, one_chip, batch, rows, room=None):
