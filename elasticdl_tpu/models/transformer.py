@@ -1524,20 +1524,35 @@ def _shared_expert(h, w, cfg, limit=0.0):
                        remat_keep.KEEP_SHARED_UP), limit)
 
 
+# A wide stream's write that the sublayer behind it makes with its own
+# read (``_operator`` -> ``_ffn``): the stream with its error, the
+# operator's result, the maps that write.
+_Write = collections.namedtuple("_Write", "x out maps")
+
+
 def _read(x, w, cfg, name):
     """(what a sublayer computes on, the stream as ``_residual`` takes
     it back, how ``_residual`` writes to it): the stream itself, and
     None; of a stream ``cfg.hyper_streams`` wide, [B, T, n dim], its
     streams mixed by the sublayer's first map, [B, T, dim], and the two
     maps that write (``ops/hyper_mix.pre``, whose Sinkhorn error joins
-    the running maximum the stream carries beside it)."""
+    the running maximum the stream carries beside it).  Handed a
+    ``_Write``, it makes that write and reads what it wrote in one call
+    (``ops/hyper_mix.post_pre``)."""
     if not cfg.hyper_streams:
         return x, x, None
-    x, err = x
-    u, x, maps, off = hyper_mix.pre(
-        x, *(w["%s_%s" % (name, part)] for part in ("phi", "alpha", "bias")),
-        cfg.hyper_streams, cfg.hyper_sinkhorn_iters, cfg.norm_eps,
-        HYPER_SINKHORN_EPS)
+    mix = (*(w["%s_%s" % (name, part)] for part in ("phi", "alpha", "bias")),
+           cfg.hyper_streams, cfg.hyper_sinkhorn_iters, cfg.norm_eps,
+           HYPER_SINKHORN_EPS)
+    if isinstance(x, _Write):
+        (x, err), out, maps = x
+        # under the stream's name, as it is wherever X' is named
+        err = checkpoint_name(err, remat_keep.KEEP_STREAM)
+        u, x, maps, off = hyper_mix.post_pre(x, out, maps, *mix,
+                                             remat_keep.KEEP_STREAM)
+    else:
+        x, err = x
+        u, x, maps, off = hyper_mix.pre(x, *mix)
     return u, (x, jnp.maximum(err, off)), maps
 
 
@@ -1878,7 +1893,9 @@ def _operator(x, w, cfg, mesh, positions, kind):
     (``_delta_mix``); a Mamba-2 layer's ``chunk_keep`` (``_ssm_mix``);
     else None), ``Op`` the operator of ``kind``:
     attention, latent attention (nothing cached: decoding refuses it),
-    the short convolution, the gated delta rule or the Mamba-2 mixer."""
+    the short convolution, the gated delta rule or the Mamba-2 mixer.
+    On a wide stream with an FFN behind the operator x is a ``_Write``:
+    the write not yet made."""
     u, x, maps = _read(x, w, cfg, "hc1")
     h = _pre(u, w, cfg, "ln1")
     kv_out = None
@@ -1896,6 +1913,9 @@ def _operator(x, w, cfg, mesh, positions, kind):
         out = _latent_mix(h, w, cfg, positions, kind)
     else:
         out, kv_out = _attention_mix(h, w, cfg, mesh, positions, kind)
+    if maps is not None and kind.ffn:
+        # a wide stream's write is the FFN's read's to make (``_read``)
+        return _Write(x, out, maps), kv_out
     x = _residual(x, out, w, cfg, mesh, "ln1_post", maps)
     if not kind.ffn:    # no FFN behind it: the stream is the layer's result
         return x, kv_out

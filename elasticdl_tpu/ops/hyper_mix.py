@@ -17,20 +17,32 @@ scalars ``alpha`` and a bias ``[n + n + n^2]`` (arXiv:2512.24880):
 ``SK``: ``iters`` rounds of rows over their sums, then columns over
 their sums (``+ sk_eps``), float32.  ``pre(X, ..) -> (u, X, maps, err)``
 and ``post(X, y, maps) -> X'`` are the two halves a block writes round
-its sublayer; ``narrow`` is ``pre`` with the first map alone (what a
-model reads the stream through after its last layer).
+its sublayer; ``post_pre`` is a sublayer's ``post`` and the next
+sublayer's ``pre`` of what it wrote as one; ``narrow`` is ``pre`` with
+the first map alone (what a model reads the stream through after its
+last layer).
 
 Bound by memory: the stream is 4 C values a token where every other
 operand of a block is C.  The kernels make the fewest passes of it
-there are: ``hc_pre_fwd`` reads X once (the logits' matmul on the MXU
-from the same block in VMEM, ``alpha`` folded into ``phi`` outside, the
-normalization a float32 scale of the product's rows), ``hc_post_fwd``
-reads X and writes X'; three passes a sublayer.  Backward:
-``hc_post_bwd`` reads X and dX', writes dX; ``hc_pre_bwd`` reads X and
-that dX and writes their sum with its own terms (``pre`` hands X
-through, so that the stream's two readers' cotangents meet in the
-kernel and not in a pass of XLA's), and ``phi``'s gradient is one
-matmul of XLA's that reads X once more: seven.
+there are.  At a layer's edge, where a write and the next read lie on
+two sides of a checkpoint: ``hc_pre_fwd`` reads X once (the logits'
+matmul on the MXU from the same block in VMEM, ``alpha`` folded into
+``phi`` outside, the normalization a float32 scale of the product's
+rows), ``hc_post_fwd`` reads X and writes X'; three passes a sublayer.
+Backward: ``hc_post_bwd`` reads X and dX', writes dX; ``hc_pre_bwd``
+reads X and that dX and writes their sum with its own terms (``pre``
+hands X through, so that the stream's two readers' cotangents meet in
+the kernel and not in a pass of XLA's), and ``phi``'s gradient is one
+matmul of XLA's that reads X once more: seven.  Inside a layer, between
+its two sublayers (``post_pre``): ``hc_post_pre_fwd`` reads X, writes
+X' and reads the block it has just stored, still in VMEM, for the next
+sublayer's u' and logits: two passes where the pair made three;
+``hc_pre_post_bwd`` reads X', its cotangent from the next write and X,
+keeps the summed cotangent of X' in VMEM (rounded to the stream's dtype
+where ``hc_pre_bwd`` rounds it for HBM) and writes dX: four where the
+pair made six; with ``phi``'s matmul seven passes for the write and the
+read together, which apart are ten.  The fused calls' results are the
+separate calls' bit for bit.
 
 The maps between (24 values a token: the sigmoids, the Sinkhorn rounds)
 are one call each way, ``hyper_maps``: ``hc_maps_fwd`` reads a tile of
@@ -49,11 +61,12 @@ differentiates, is the reference, and what runs wherever the op cannot.
 
 The calls carry their names into the compiled program and a device
 trace: ``hc_pre_fwd``, ``hc_post_fwd``, ``hc_pre_bwd``, ``hc_post_bwd``
-(``benchmark/kernels/hyper_mix.py``), and the maps' ``hc_maps_fwd``,
-``hc_maps_bwd``.  Reference: ``pre_ref``, ``maps_of`` and ``post_ref``,
-plain ``jax.numpy`` differentiated by JAX, which is also what runs
-wherever ``ops/mode.py`` answers ``off`` or the shape does not tile (it
-says so: ``announce_fallback``).
+(``benchmark/kernels/hyper_mix.py``), the pair's ``hc_post_pre_fwd``,
+``hc_pre_post_bwd`` (``benchmark/layers/kernel.hyper_fused_share.py``),
+and the maps' ``hc_maps_fwd``, ``hc_maps_bwd``.  Reference: ``pre_ref``,
+``maps_of`` and ``post_ref``, plain ``jax.numpy`` differentiated by JAX,
+which is also what runs wherever ``ops/mode.py`` answers ``off`` or the
+shape does not tile (it says so: ``announce_fallback``).
 """
 
 import functools
@@ -270,12 +283,35 @@ def _post_bwd_kernel(x_ref, y_ref, maps_ref, dout_ref, dx_ref, dy_ref,
     dmaps_ref[...] = dmaps
 
 
+def _post_pre_fwd_kernel(x_ref, y_ref, maps_ref, phi_ref, bias_ref, out_ref,
+                         u_ref, z_ref, *, n, c, eps):
+    """A sublayer's write, then the next sublayer's read of the block
+    just written: ``out_ref`` holds X' in the stream's dtype, so u' and
+    z' are what ``hc_pre_fwd`` makes of the X' that reaches HBM."""
+    _post_fwd_kernel(x_ref, y_ref, maps_ref, out_ref, n=n, c=c)
+    _pre_fwd_kernel(out_ref, phi_ref, bias_ref, u_ref, z_ref, n=n, c=c,
+                    eps=eps)
+
+
+def _pre_post_bwd_kernel(out_ref, phi_ref, bias_ref, z_ref, du_ref, dz_ref,
+                         dout_in_ref, x_ref, y_ref, maps_ref, dx_ref, dy_ref,
+                         dmaps_ref, ds_ref, dzs_ref, dout, *, n, c, eps):
+    """The pair's way back: the read's backward leaves X's summed
+    cotangent in ``dout`` (VMEM, the stream's dtype: rounded where
+    ``hc_pre_bwd`` rounds it on its way to HBM), the write's backward
+    takes it from there."""
+    _pre_bwd_kernel(out_ref, phi_ref, bias_ref, z_ref, du_ref, dz_ref,
+                    dout_in_ref, dout, ds_ref, dzs_ref, n=n, c=c, eps=eps)
+    _post_bwd_kernel(x_ref, y_ref, maps_ref, dout, dx_ref, dy_ref, dmaps_ref,
+                     n=n, c=c)
+
+
 def _call(kernel, name, tm, interpret, ins, outs, whole=(), scratch=()):
     """One call over row blocks of ``tm``: ``ins`` and ``outs`` are
     [rows, width] (arrays, ShapeDtypeStructs), blocked by rows (an
     output of fewer rows, a block's own few, evenly); the positions
     ``whole`` of ``ins`` are given to every block entire; ``scratch``:
-    VMEM shapes the kernel takes last."""
+    the (shape, dtype) of what the kernel takes last, in VMEM."""
     grid = outs[0].shape[0] // tm
     block = lambda a: pl.BlockSpec((a.shape[0] // grid, a.shape[1]),
                                    lambda r: (r, 0))
@@ -285,7 +321,7 @@ def _call(kernel, name, tm, interpret, ins, outs, whole=(), scratch=()):
         in_specs=[entire(a) if i in whole else block(a)
                   for i, a in enumerate(ins)],
         out_specs=[block(a) for a in outs],
-        scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in scratch],
+        scratch_shapes=[pltpu.VMEM(*held) for held in scratch],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=VMEM_LIMIT),
@@ -330,11 +366,17 @@ def _pre_bwd(n, eps, tm, interpret, residuals, cotangents):
         "hc_pre_bwd", tm, interpret, (x, phi, bias, z, du, dz, dx_in),
         (_shape(rows, width, x.dtype), _shape(rows, LANES, jnp.float32),
          _shape(rows, LANES, jnp.float32)), whole=(1, 2))
+    return dx, *_read_grads(x, phi, ds, dzs, n)
+
+
+def _read_grads(x, phi, ds, dzs, n):
+    """(phi's gradient, the bias's) of a read of x: ``x^T ds``, one
+    matmul of XLA's, and the pre columns' sums of ``dzs``."""
     dphi = jnp.einsum("rk,rm->km", x, ds.astype(x.dtype),
                       preferred_element_type=jnp.float32)
     lane = lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
     dbias = jnp.where(lane < n, dzs.sum(axis=0, keepdims=True), 0.0)
-    return dx, dphi.astype(phi.dtype), dbias
+    return dphi.astype(phi.dtype), dbias
 
 
 _pre.defvjp(_pre_fwd, _pre_bwd)
@@ -365,6 +407,44 @@ def _post_bwd(n, tm, interpret, residuals, dout):
 
 
 _post.defvjp(_post_fwd, _post_bwd)
+
+
+# A write and the next sublayer's read of what it wrote, one call each
+# way: X' goes to HBM once and is not read back, its cotangent not at
+# all.  ``tiles``: the forward's rows a block, the backward's.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _post_pre(x, y, maps, phi, bias, n, eps, tiles, interpret):
+    return _post_pre_fwd(x, y, maps, phi, bias, n, eps, tiles, interpret)[0]
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
+def _post_pre_fwd(x, y, maps, phi, bias, n, eps, tiles, interpret):
+    rows, width = x.shape
+    c = width // n
+    out, u, z = _call(
+        functools.partial(_post_pre_fwd_kernel, n=n, c=c, eps=eps),
+        "hc_post_pre_fwd", tiles[0], interpret, (x, y, maps, phi, bias),
+        (_shape(rows, width, x.dtype), _shape(rows, c, x.dtype),
+         _shape(rows, LANES, jnp.float32)), whole=(3, 4))
+    return (out, u, z), (x, y, maps, out, phi, bias, z)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _post_pre_bwd(n, eps, tiles, interpret, residuals, cotangents):
+    x, y, maps, out, phi, bias, z = residuals
+    dout, du, dz = cotangents
+    rows, width = x.shape
+    dx, dy, dmaps, ds, dzs = _call(
+        functools.partial(_pre_post_bwd_kernel, n=n, c=width // n, eps=eps),
+        "hc_pre_post_bwd", tiles[1], interpret,
+        (out, phi, bias, z, du, dz, dout, x, y, maps),
+        (_shape(rows, width, x.dtype), _shape(*y.shape, y.dtype))
+        + (_shape(rows, LANES, jnp.float32),) * 3, whole=(1, 2),
+        scratch=(((tiles[1], width), x.dtype),))
+    return dx, dy, dmaps, *_read_grads(out, phi, ds, dzs, n)
+
+
+_post_pre.defvjp(_post_pre_fwd, _post_pre_bwd)
 
 
 # -- the maps' kernels --------------------------------------------------------
@@ -495,7 +575,7 @@ def _maps_fwd(z, bias, n, iters, eps, tile, interpret):
         "hc_maps_fwd", tile, interpret, (z, bias),
         (_shape(rows, LANES, jnp.float32),
          _shape(rows // tile * 8, LANES, jnp.float32)),
-        whole=(1,), scratch=((tile, LANES),))
+        whole=(1,), scratch=(((tile, LANES), jnp.float32),))
     return tuple(out), (z, bias)
 
 
@@ -508,9 +588,9 @@ def _maps_bwd(n, iters, eps, tile, interpret, residuals, cotangents):
         "hc_maps_bwd", tile, interpret, (z, bias, cotangents[0]),
         (_shape(rows, LANES, jnp.float32),
          _shape(rows // tile * 8, LANES, jnp.float32)),
-        whole=(1,), scratch=((tile, LANES),
-                             (halves * n * n, tile // LANES, LANES),
-                             (halves * n, tile // LANES, LANES)))
+        whole=(1,), scratch=[(shape, jnp.float32) for shape in (
+            (tile, LANES), (halves * n * n, tile // LANES, LANES),
+            (halves * n, tile // LANES, LANES))])
     return dz, dsum.sum(axis=0, keepdims=True)
 
 
@@ -520,15 +600,18 @@ _maps.defvjp(_maps_fwd, _maps_bwd)
 # -- the op ------------------------------------------------------------------
 
 
+# What ran, as the ``hyper residual:`` and ``hyper pair:`` lines say it.
+FORMS = {"tpu": "kernel", "interpret": "interpreter", "off": "reference"}
+
+
 @functools.lru_cache(maxsize=None)
 def announce_hyper(rows, n, c, nbytes, tile, mode, why):
     """Once per compiled shape, by the logger ``announce_tiles`` uses:
     the stream one shard of the data axis mixes, and by what."""
     flash_attention.logger.info(
         "hyper residual: tokens=%d streams=%d width=%d stream_bytes=%d "
-        "tile=%s %s%s", rows, n, c, nbytes, tile or "-",
-        {"tpu": "kernel", "interpret": "interpreter",
-         "off": "reference"}[mode], " (%s)" % why if why else "")
+        "tile=%s %s%s", rows, n, c, nbytes, tile or "-", FORMS[mode],
+        " (%s)" % why if why else "")
 
 
 def hyper_mode(rows, n, c, interpret=None):
@@ -558,24 +641,31 @@ def _plan(x, n, interpret):
 
 
 def _folded(phi, alpha, n):
-    """phi [n C, M] with each map's ``alpha`` in its columns."""
+    """phi [n C, M] with each map's ``alpha`` in its columns, float32."""
     sizes = (n, n, n * n)[:alpha.shape[0]]
-    return phi * jnp.concatenate(
-        [jnp.broadcast_to(a, (size,)) for a, size in zip(alpha, sizes)])
+    return phi.astype(jnp.float32) * jnp.concatenate(
+        [jnp.broadcast_to(a, (size,))
+         for a, size in zip(alpha.astype(jnp.float32), sizes)])
+
+
+def _tiled(phi, bias, dtype):
+    """phi and the bias as the kernels take them: the logits' columns
+    a 128-lane tile, phi in the stream's dtype."""
+    pad = LANES - phi.shape[1]
+    return (jnp.pad(phi, ((0, 0), (0, pad))).astype(dtype),
+            jnp.pad(bias, (0, pad))[None])
 
 
 def _logits(x, phi, alpha, bias, n, eps, interpret):
     """(u [B, T, C], z [B, T, >= M] float32, x handed through) of the
     stream x [B, T, n C]: ``pre_ref``, by the kernel where it runs."""
     mode, tile = _plan(x, n, interpret)
-    phi = _folded(phi.astype(jnp.float32), alpha.astype(jnp.float32), n)
+    phi = _folded(phi, alpha, n)
     bias = bias.astype(jnp.float32)
     if mode == "off":
         u, z = pre_ref(x, phi, bias, n, eps)
         return u, z, x
-    pad = LANES - phi.shape[1]
-    phi = jnp.pad(phi, ((0, 0), (0, pad))).astype(x.dtype)
-    bias = jnp.pad(bias, (0, pad))[None]
+    phi, bias = _tiled(phi, bias, x.dtype)
 
     def op(x, phi, bias):
         b, t, width = x.shape
@@ -649,6 +739,74 @@ def pre(x, phi, alpha, bias, streams, iters, eps, sk_eps, interpret=None,
     if sinkhorn_dtype != jnp.float32:
         return u, x, *maps_of(z, bias, streams, iters, sk_eps, sinkhorn_dtype)
     return u, x, *hyper_maps(z, bias, streams, iters, sk_eps, interpret)
+
+
+@functools.lru_cache(maxsize=None)
+def announce_pair(rows, n, c, tiles, mode):
+    """Once per compiled shape, beside ``announce_hyper``: a write and
+    the read behind it as one call each way, or (``reference``) apart
+    by ``post_ref`` and ``pre_ref``."""
+    flash_attention.logger.info(
+        "hyper pair: tokens=%d streams=%d width=%d tile=%s %s", rows, n, c,
+        "/".join(map(str, tiles)) if tiles else "-", FORMS[mode])
+
+
+def back_tile(rows, n, c, itemsize, tile):
+    """The rows a block of the fused pair's backward where
+    ``hyper_mode`` gave ``tile``.  It holds four wide blocks (X', its
+    cotangent in, X, its cotangent out) and three narrow ones twice
+    each, the summed cotangent between its halves once, its float32 sums
+    (two wide blocks' worth) and ``phi`` twice: the largest of
+    ``ROW_TILES`` under ``tile`` that leaves it inside ``VMEM_LIMIT``
+    (61 MB of 64 at 128 rows of 4 x 3,584 bfloat16, which the TPU's
+    compiler takes), the smallest there is where none does."""
+    def held(tm):
+        wide = tm * n * c
+        return (2 * (4 * wide + 3 * tm * c) * itemsize + wide * itemsize
+                + 2 * wide * 4 + 2 * n * c * LANES * itemsize
+                + 12 * tm * LANES * 4)
+
+    fits = [tm for tm in ROW_TILES
+            if tm <= tile and rows % tm == 0 and held(tm) <= VMEM_LIMIT]
+    return (fits or ROW_TILES[-1:])[0]
+
+
+def post_pre(x, y, maps, phi, alpha, bias, streams, iters, eps, sk_eps,
+             keep, interpret=None):
+    """A sublayer's write and the next sublayer's read of what it
+    wrote: ``pre(post(x, y, maps, ..), phi, alpha, bias, ..)`` with the
+    next sublayer's phi, alpha and bias -> (u', X', maps', err'), X'
+    (under the ``checkpoint_name`` ``keep``) leaving one call forward
+    (``hc_post_pre_fwd``) and its cotangent none backward
+    (``hc_pre_post_bwd``) where the kernels run; the two ops one after
+    the other where they do not."""
+    batch, seq_len, width = x.shape
+    rows, c = batch * seq_len // shards(), width // streams
+    mode, tile, _ = hyper_mode(rows, streams, c, interpret)
+    tiles = None if mode == "off" else (
+        tile, back_tile(rows, streams, c, x.dtype.itemsize, tile))
+    announce_pair(rows, streams, c, tiles, mode)
+    if mode == "off":
+        out = checkpoint_name(post(x, y, maps, streams, interpret), keep)
+        return pre(out, phi, alpha, bias, streams, iters, eps, sk_eps,
+                   interpret)
+    bias = bias.astype(jnp.float32)
+
+    def op(x, y, maps, phi, bias):
+        b, t, _ = x.shape
+        out, u, z = _post_pre(
+            x.reshape(b * t, width), y.reshape(b * t, -1),
+            maps.reshape(b * t, LANES), phi, bias, streams, eps, tiles,
+            mode == "interpret")
+        return (out.reshape(b, t, width), u.reshape(b, t, -1),
+                z.reshape(b, t, LANES))
+
+    out, u, z = per_batch_shard(
+        op, (x, y, maps),
+        _tiled(_folded(phi, alpha, streams), bias, x.dtype))
+    u, z = checkpoint_name(u, KEEP_U), checkpoint_name(z, KEEP_Z)
+    return (u, checkpoint_name(out, keep),
+            *hyper_maps(z, bias, streams, iters, sk_eps, interpret))
 
 
 def narrow(x, phi, alpha, bias, streams, eps, interpret=None):

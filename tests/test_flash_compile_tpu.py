@@ -752,6 +752,53 @@ def test_the_four_calls_compile_at_the_cells_shape(one_chip):
     assert stats.temp_size_in_bytes < 2.2 * HC_ROWS * HC_N * HC_C * 2
 
 
+def test_a_layers_fused_pair_compiles_at_the_cells_shape(one_chip):
+    """A layer of two sublayers at the cell's shape: its first read, the
+    fused write-and-read between the sublayers (``hc_post_pre_fwd``,
+    and ``hc_pre_post_bwd`` with four wide blocks twice over and the
+    summed cotangent in VMEM, 128 rows inside the 64 MB the calls ask
+    for), its last write; X' is neither read back forward nor its
+    cotangent written backward."""
+    on_chip = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    x = on_chip((2, 4096, HC_N * HC_C), jnp.bfloat16)
+    y = on_chip((2, 4096, HC_C), jnp.bfloat16)
+    phi = on_chip((HC_N * HC_C, hm.columns(HC_N)), jnp.float32)
+    alpha = on_chip((3,), jnp.float32)
+    bias = on_chip((hm.columns(HC_N),), jnp.float32)
+    assert hm.back_tile(HC_ROWS, HC_N, HC_C, 2, 128) == 128
+
+    def loss(x, y, phi, alpha, bias, phi2, alpha2, bias2):
+        mix = (HC_N, 20, 1e-6, 1e-6)
+        u, through, maps, err = hm.pre(x, phi, alpha, bias, *mix,
+                                       interpret=False)
+        u2, x2, maps2, err2 = hm.post_pre(through, u * y, maps, phi2, alpha2,
+                                          bias2, *mix, "attn_stream",
+                                          interpret=False)
+        out = hm.post(x2, u2 * y, maps2, HC_N, interpret=False)
+        return jnp.square(out.astype(jnp.float32)).sum() + err + err2
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(8)))).lower(
+        x, y, phi, alpha, bias, phi, alpha, bias).compile()
+    text = compiled.as_text()
+    assert _names(text) == {
+        "hc_pre_fwd": 1, "hc_post_pre_fwd": 1, "hc_post_fwd": 1,
+        "hc_post_bwd": 1, "hc_pre_post_bwd": 1, "hc_pre_bwd": 1,
+        "hc_maps_fwd": 2, "hc_maps_bwd": 2}
+    fwd, = [c for c in _mosaic_calls(text) if "hc_post_pre_fwd" in c]
+    bwd, = [c for c in _mosaic_calls(text) if "hc_pre_post_bwd" in c]
+    results = lambda call: re.findall(
+        r"\w+\[[\d,]+\]", call.split(" custom-call(")[0])
+    assert results(fwd) == ["bf16[8192,14336]", "bf16[8192,3584]",
+                            "f32[8192,128]"]
+    assert results(bwd) == ["bf16[8192,14336]", "bf16[8192,3584]"] + [
+        "f32[8192,128]"] * 3
+    # the stream in and its gradient out, X', X'' and the cotangent that
+    # the last write's backward hands the pair: under four streams
+    stats = compiled.memory_analysis()
+    assert stats.temp_size_in_bytes < 3.7 * HC_ROWS * HC_N * HC_C * 2
+
+
 # -- the state-space hybrid's kernels and count (PR 61) -----------------------
 
 NEMOTRON3_PARAMETERS = 666963456

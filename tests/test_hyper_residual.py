@@ -324,7 +324,8 @@ def test_the_cells_rehearsal_step_names_the_two_calls_and_scans_no_round(
     sublayer's maps are ``hc_maps_fwd`` twice (the second forward) and
     ``hc_maps_bwd`` once, and no loop of the program's carries the
     rounds' ``[4, 4, rows]`` planes; with the kernels off the scan is
-    there."""
+    there.  Each layer's inner write and read are the fused pair's one
+    call each way; its first read and last write keep their own."""
     from benchmark.lib.runner import merge, params_string
     from elasticdl_tpu.models.spec import load_model_spec
 
@@ -361,12 +362,176 @@ def test_the_cells_rehearsal_step_names_the_two_calls_and_scans_no_round(
     calls = collections.Counter(
         re.findall(r"call @_(\w+?_(?:fwd|bwd))(?:_\d+)?\(", text))
     assert (calls["maps_fwd"], calls["maps_bwd"]) == (16, 8)
-    assert (calls["post_bwd"], calls["pre_bwd"]) == (8, 8 + 2)
-    assert {"hc_maps_fwd", "hc_maps_bwd", "hc_pre_fwd", "hc_post_bwd"} <= set(
+    assert (calls["post_bwd"], calls["pre_bwd"]) == (4, 4 + 2)
+    assert (calls["post_pre_fwd"], calls["post_pre_bwd"]) == (2 * 4, 4)
+    assert {"hc_maps_fwd", "hc_maps_bwd", "hc_pre_fwd", "hc_post_bwd",
+            "hc_post_pre_fwd", "hc_pre_post_bwd"} <= set(
         re.findall(r'kernel_name = "(\w+)"', text))
     assert planes not in text
     off = lowered("off")
     assert planes in off and "kernel_name" not in off
+
+
+# -- a write and the read behind it: one call each way -----------------------
+
+
+def pair(fused, x, y, w, then, weigh, interpret=True):
+    """A sublayer's read and write and the next sublayer's read, the
+    inner write and read ``fused`` or apart -> ((X', u', maps', err'),
+    the gradients of a scalar of all four by x, y, the maps that write
+    (a zero added to them), the sublayer's phi, alpha and bias (through
+    those maps) and the next sublayer's)."""
+    def f(x, y, shift, phi, alpha, bias, phi2, alpha2, bias2):
+        u, through, maps, _ = hm.pre(x, phi, alpha, bias, N, 3, 1e-6, 1e-6,
+                                     interpret=interpret)
+        maps = maps + shift
+        mix = (phi2, alpha2, bias2, N, 3, 1e-6, 1e-6)
+        if fused:
+            u2, x2, maps2, err = hm.post_pre(through, y, maps, *mix,
+                                             rk.KEEP_STREAM,
+                                             interpret=interpret)
+        else:
+            u2, x2, maps2, err = hm.pre(
+                hm.post(through, y, maps, N, interpret=interpret), *mix,
+                interpret=interpret)
+        # the next sublayer's write reads X' again: two cotangents meet
+        out = hm.post(x2, u2 + u2, maps2, N, interpret=interpret)
+        scalar = ((out.astype(jnp.float32) * weigh).sum()
+                  + jnp.square(u.astype(jnp.float32)).sum())
+        return scalar, (x2, u2, maps2, err)
+
+    args = (x, y, jnp.zeros(x.shape[:2] + (hm.LANES,), jnp.float32),
+            w["hc1_phi"], w["hc1_alpha"], w["hc1_bias"],
+            then["hc1_phi"], then["hc1_alpha"], then["hc1_bias"])
+    (_, values), grads = jax.jit(jax.value_and_grad(
+        f, argnums=tuple(range(9)), has_aux=True))(*args)
+    return values, grads
+
+
+@pytest.mark.parametrize("rows", [(1, 128), (1, 16)],
+                         ids=["rows128", "rows16"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_fused_pair_is_the_two_calls_bit_for_bit(dtype, rows):
+    """``post_pre`` against ``post`` then ``pre`` in interpret mode, at
+    both ends of ``ROW_TILES``: X', u', the logits z' (the kernels'
+    own, and the maps and error made of them) and every gradient are
+    the same bits, because X' is rounded to the stream's dtype before
+    the read's half takes it and its cotangent before the write's."""
+    x, y, w = sublayer(seed=4, rows=rows)
+    then = sublayer(seed=5, rows=rows)[2]
+    x, y = x.astype(dtype), y.astype(dtype)
+    weigh = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
+    tile = hm.hyper_mode(rows[1], N, 128, True)[1]
+    assert tile == rows[1]
+    assert hm.back_tile(rows[1], N, 128, x.dtype.itemsize, tile) == tile
+    same = lambda got, want: (got.dtype == want.dtype and np.array_equal(
+        np.asarray(got, np.float32), np.asarray(want, np.float32)))
+    got, want = (pair(fused, x, y, w, then, weigh) for fused in (True, False))
+    for name, g, v in zip(("X'", "u'", "maps'", "err'"), got[0], want[0]):
+        assert same(g, v), name
+    for name, g, v in zip(("x", "y", "maps", "phi", "alpha", "bias",
+                           "next phi", "next alpha", "next bias"),
+                          got[1], want[1]):
+        assert float(jnp.abs(v.astype(jnp.float32)).max()) > 0, name
+        assert same(g, v), name
+    # the calls themselves: X', u' and z' of the same operands
+    flat = lambda a: a.reshape(-1, a.shape[-1])
+    maps = hm.pre(x, w["hc1_phi"], w["hc1_alpha"], w["hc1_bias"], N, 3, 1e-6,
+                  1e-6, interpret=True)[2]
+    phi, bias = hm._tiled(hm._folded(then["hc1_phi"], then["hc1_alpha"], N),
+                          then["hc1_bias"], x.dtype)
+    out, u, z = hm._post_pre(flat(x), flat(y), flat(maps), phi, bias, N,
+                             1e-6, (tile, tile), True)
+    apart_out = hm._post(flat(x), flat(y), flat(maps), N, tile, True)
+    for g, v in zip((out, u, z), (apart_out, *hm._pre(
+            apart_out, phi, bias, N, 1e-6, tile, True)[:2])):
+        assert same(g, v)
+
+
+def test_the_backwards_tile_steps_down_where_its_blocks_do_not_fit():
+    """The cell's shape holds the backward's four wide blocks twice
+    over at 128 rows inside the 64 MB the calls ask for (61 MB by the
+    count; the TPU's compiler agrees: tests/test_flash_compile_tpu.py);
+    a float32 stream of that width holds 64 rows, one twice as wide 32
+    (``phi`` is twice as large too), from the shapes alone; where
+    nothing fits, the smallest tile there is."""
+    assert hm.back_tile(8192, 4, 3584, 2, 128) == 128
+    assert hm.back_tile(8192, 4, 3584, 4, 128) == 64
+    assert hm.back_tile(8192, 4, 7168, 2, 128) == 32
+    assert hm.back_tile(8192, 8, 7168, 4, 128) == 16
+    assert hm.back_tile(48, 4, 128, 2, 16) == 16
+
+
+def layer_of(mode, monkeypatch, ffn=True):
+    """(loss and gradients, the jaxpr's text) of one dense layer of the
+    zoo's model on a stream four wide, with or without its FFN, under
+    ``ELASTICDL_FLASH=mode``."""
+    monkeypatch.setenv("ELASTICDL_FLASH", mode)
+    spec = tfm.model_spec(**dict(TINY, num_layers=1, mtp_modules=0, seq_len=64,
+                                 hyper_sinkhorn_iters=3))
+    cfg = spec.config
+    w = case(spec)[0]["layers"]["lead"]["0"]
+    x = sublayer(seed=6, rows=(2, 64))[0]
+    kind = cfg.kinds[0]._replace(ffn=ffn)
+
+    def loss(x, w):
+        (out, err), _ = tfm._layer_body((x, jnp.float32(0.0)), w, cfg, None,
+                                        jnp.arange(64), kind=kind)
+        return jnp.square(out).mean() + err
+
+    return (jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(x, w),
+            str(jax.make_jaxpr(jax.grad(loss))(x, w)))
+
+
+def calls_of(jaxpr):
+    """How often a jaxpr's text calls each of the op's jitted halves
+    (``_pre_fwd`` .. ``_post_pre_bwd``: a kernel is printed once however
+    often its jitted caller is called)."""
+    return collections.Counter(
+        re.findall(r"name=_(\w+_(?:fwd|bwd))\b", jaxpr))
+
+
+def test_a_layer_fuses_its_inner_write_and_read_and_matches_the_reference(
+        monkeypatch):
+    """One layer of two sublayers: the operator's write and the FFN's
+    read are the pair's one call each way (the layer's first read and
+    last write keep theirs), and loss and every gradient are the
+    reference path's to the tolerance the whole model's test holds."""
+    hm.announce_pair.cache_clear()
+    from elasticdl_tpu.ops import flash_attention
+
+    with lines_of(flash_attention.logger) as seen:
+        (got, got_grads), text = layer_of("interpret", monkeypatch)
+        (want, want_grads), plain = layer_of("off", monkeypatch)
+    assert calls_of(text) == {
+        "pre_fwd": 1, "post_pre_fwd": 1, "post_fwd": 1, "post_bwd": 1,
+        "post_pre_bwd": 1, "pre_bwd": 1, "maps_fwd": 2, "maps_bwd": 2}
+    assert {"hc_post_pre_fwd", "hc_pre_post_bwd"} <= set(
+        re.findall(r"name=(hc_\w+)", text))
+    assert not calls_of(plain) and "name=hc_p" not in plain
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    flat = jax.tree_util.tree_flatten_with_path(got_grads)[0]
+    for (path, leaf), ref in zip(flat, jax.tree_util.tree_leaves(want_grads)):
+        assert apart(leaf, ref) <= 1e-4, jax.tree_util.keystr(path)
+    # one line a compiled shape and form, however often it is traced
+    assert [m for m in seen if m.startswith("hyper pair:")] == [
+        "hyper pair: tokens=128 streams=4 width=128 tile=128/128 interpreter",
+        "hyper pair: tokens=128 streams=4 width=128 tile=- reference"]
+
+
+def test_a_layer_of_one_sublayer_makes_no_fused_call(monkeypatch):
+    """No FFN behind the operator: the write is the layer's last, and
+    stays ``hc_post_fwd``'s."""
+    hm.announce_pair.cache_clear()
+    from elasticdl_tpu.ops import flash_attention
+
+    with lines_of(flash_attention.logger) as seen:
+        _, text = layer_of("interpret", monkeypatch, ffn=False)
+    assert calls_of(text) == {
+        "pre_fwd": 1, "post_fwd": 1, "post_bwd": 1, "pre_bwd": 1,
+        "maps_fwd": 1, "maps_bwd": 1}
+    assert "hc_post_pre_fwd" not in text and "hc_pre_post_bwd" not in text
+    assert not [m for m in seen if m.startswith("hyper pair:")]
 
 
 # -- the whole model -----------------------------------------------------------

@@ -268,16 +268,19 @@ def test_the_wide_streams_step_fits_a_v5e_with_nothing_kept(cell_steps):
     assert 0 < estimate - counted < 0.5e9, (estimate, counted)
     text = compiled.as_text()
     names = _names(text)
-    # twelve sublayers: read twice (the second forward), written twice
-    # but for each block's last (its result is the next block's kept
-    # input), back-propagated once; the two narrowing maps
-    assert names["hc_pre_fwd"] == 2 * 12 + 2
+    # six layers of two sublayers: a layer's first read twice (the
+    # second forward) and its last write once (its result is the next
+    # block's kept input), each back-propagated once; the two narrowing
+    # maps; the write and the read between a layer's sublayers one call
+    # twice forward and one backward (PR 63: ``hyper_mix.post_pre``)
+    assert names["hc_pre_fwd"] == 12 + 2
+    assert (names["hc_post_pre_fwd"], names["hc_pre_post_bwd"]) == (12, 6)
     # their maps: made twice, back-propagated once, one call each; no
     # loop of the program's turns over the rounds' [4, 4, 8192] planes
     assert (names["hc_maps_fwd"], names["hc_maps_bwd"]) == (2 * 12, 12)
     assert not re.search(r"f32\[4,4,8192\]", text)
-    assert names["hc_post_fwd"] == 2 * 12 - 6
-    assert (names["hc_post_bwd"], names["hc_pre_bwd"]) == (12, 12 + 2)
+    assert names["hc_post_fwd"] == 6
+    assert (names["hc_post_bwd"], names["hc_pre_bwd"]) == (6, 6 + 2)
     assert names["flash_fwd_qk192_v128"] == 12
     assert names["flash_bwd_qk192_v128"] == 6
     assert names["embed_grad"] == 1
