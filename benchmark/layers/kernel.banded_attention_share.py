@@ -1,18 +1,14 @@
 """Share of the device's busy time spent in the flash-attention kernels'
-calls of a stack whose layers differ in their window (forward, dq and
-dk-dv, full and windowed layers together): what of the step attention's
-scores are, beside the projections, the experts and the head, which are
-XLA's and the grouped matmul's.  Nothing where the program names no such
-call."""
+calls of a stack whose layers differ in their window (forward and
+backward, full and windowed layers together): what of the step
+attention's scores are, beside the projections, the experts and the
+head, which are XLA's and the grouped matmul's.  Nothing where the
+program names no such call."""
 
-from benchmark.lib import manifest
+from benchmark.lib import kernels, manifest
 
 roofline = manifest.load_named("layers", "kernel.banded_attention_roofline")
 
 
 def read(run):
-    t = run.trace
-    seconds = sum(call[3] for call in roofline.calls(run))
-    if not seconds or not t["busy_s"]:
-        return None
-    return 100.0 * seconds / t["busy_s"]
+    return kernels.busy_share(run, roofline.calls(run))

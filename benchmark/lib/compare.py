@@ -18,13 +18,19 @@ reference's own file, ``reference/<name>.py``:
                 float32 loss per record, run here at ``highest`` matmul
                 precision.
 
-Prints one JSON line.
+Prints one JSON line; its ``seconds`` are this process's phases by the
+host's clock (import and parameter init, the reference's case, the
+product's compile and run, the reference's compile and run): what the
+runner's cap on the comparison (``runner.COMPARE_CAP_S``) was set from.
+JAX's compile cache is where the environment says
+(``JAX_COMPILATION_CACHE_DIR``: the runner hands over the job's).
 """
 
 import argparse
 import json
 import os
 import sys
+import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))))
@@ -37,6 +43,11 @@ def main():
     parser.add_argument("--seed", type=int, default=0)
     parser.add_argument("--rehearse", action="store_true")
     args = parser.parse_args()
+    seconds, last = {}, [time.time()]
+
+    def phase(name):
+        seconds[name] = time.time() - last[0]
+        last[0] = time.time()
 
     import jax
     import jax.numpy as jnp
@@ -56,8 +67,10 @@ def main():
     ref = manifest.load_named("reference", config["reference"])
     rng = np.random.default_rng(args.seed)
     key = jax.random.PRNGKey(args.seed % (2 ** 31))
-    params, x, y, reference_loss = ref.case(
-        config, jax.jit(spec.init_fn)(key), rng, key)
+    params = jax.block_until_ready(jax.jit(spec.init_fn)(key))
+    phase("import_init_s")
+    params, x, y, reference_loss = ref.case(config, params, rng, key)
+    phase("case_s")
 
     bf16 = cli.get("flags", {}).get("use_bf16", False)
 
@@ -70,15 +83,18 @@ def main():
         return spec.loss_fn(out, y).astype(jnp.float32).mean()
 
     got = float(jax.jit(product)(params, x, y))
+    phase("product_s")
     with jax.default_matmul_precision("highest"):
         want = float(jax.jit(lambda p: reference_loss(p).mean())(params))
+    phase("reference_s")
     rel = abs(got - want) / abs(want)
     device = jax.devices()[0]
     print(json.dumps({
         "reference": config["reference"], "product_loss": got,
         "reference_loss": want, "rel_diff": rel, "tolerance": ref.TOLERANCE,
         "ok": rel <= ref.TOLERANCE, "microbatch": ref.MICROBATCH,
-        "platform": device.platform, "kind": device.device_kind}),
+        "platform": device.platform, "kind": device.device_kind,
+        "seconds": seconds}),
         flush=True)
     return 0
 

@@ -9,6 +9,10 @@ or after ``--seconds`` later (benchmark/lib/slices.py).  Without a TPU
 nothing is printed and the exit code is not 0; ``--rehearse`` runs the
 whole harness at the tiny sizes the configuration and traffic files give
 for it, prints the device it found and no metric values, and exits 3.
+A run ends in its result's line or in a ``[benchmark] FAILED <cell>: ..``
+line on stderr with exit 1, the reference comparison's expiry
+(``COMPARE_CAP_S``) too; the result's last key, ``compared``, and the
+last lines of stderr hold each number ``judge`` held to a limit.
 """
 
 import argparse
@@ -17,6 +21,7 @@ import json
 import os
 import re
 import shutil
+import subprocess
 import sys
 import time
 
@@ -26,6 +31,7 @@ from benchmark.lib import xplane
 POLL_S = 0.025
 STARTUP_CAP_S = 1100     # a cold first run compiles
 CLOSE_CAP_S = 30         # the task that closes the window, past --seconds
+COMPARE_CAP_S = 900      # the reference comparison, on an empty compile cache
 WINDOW_TASKS_BEFORE = 2  # whole tasks done before the window opens
 
 
@@ -276,6 +282,17 @@ class Run:
                             % self.reference)
         return not problems
 
+    def compared(self):
+        """Each number ``judge`` held to a limit, beside that limit."""
+        out = {"tasks_failed": (self.failed, 0),
+               "compiles_in_window": (self.compiles_in_window or 0, 0),
+               "problems": (len(self.problems), 0)}
+        if self.reference is not None:
+            out["loss_rel_diff"] = (self.reference["rel_diff"],
+                                    self.reference["tolerance"])
+        return {name: {"value": value, "limit": limit}
+                for name, (value, limit) in out.items()}
+
     def memory_peak_bytes(self):
         """Peak on the fullest chip: buffers in use plus the programs'
         reserved temporaries, as each worker stated them at its exit."""
@@ -302,29 +319,53 @@ def reduce_trace(run):
                         "window; planes and lines: %s" % raw.get("lines"))
 
 
-def compare_reference(run):
-    """The product's loss against the plain float32 reference on one
-    seeded microbatch, in a process of its own now that the chip is free."""
-    import subprocess
-
-    ref = run.config.get("reference")
-    if not ref or not run.traffic.get("reference_check", True):
-        return
-    env = joblib.child_env(run.root)
-    if run.rehearse:
+def spawn_compare(root, config_file, seed, cache_dir, rehearse=False):
+    """``lib/compare.py`` in a process of its own, its compile cache at
+    ``cache_dir``: (its JSON line, the seconds it took).  Whatever goes
+    wrong there is a RunFailed, its expiry at COMPARE_CAP_S too: the
+    child is killed and waited for before this returns or raises."""
+    env = joblib.child_env(root, {"JAX_COMPILATION_CACHE_DIR": cache_dir})
+    if rehearse:
         env["JAX_PLATFORMS"] = "cpu"
     argv = [sys.executable, os.path.join(manifest.BENCH_DIR, "lib",
                                          "compare.py"),
-            "--config-file", run.cell["config_file"], "--seed", str(run.seed)]
-    if run.rehearse:
+            "--config-file", config_file, "--seed", str(seed)]
+    if rehearse:
         argv.append("--rehearse")
-    done = subprocess.run(argv, cwd=run.root, env=env, capture_output=True,
-                          text=True, timeout=300)
+    started = time.time()
+    try:
+        done = subprocess.run(argv, cwd=root, env=env, capture_output=True,
+                              text=True, timeout=COMPARE_CAP_S)
+    except subprocess.TimeoutExpired:
+        raise RunFailed(
+            "the reference comparison was not done within its cap of %d s "
+            "(COMPARE_CAP_S; stopped after %.0f s)"
+            % (COMPARE_CAP_S, time.time() - started))
+    except OSError as e:
+        raise RunFailed("the reference comparison did not start: %s" % e)
     lines = [l for l in done.stdout.splitlines() if l.startswith("{")]
-    if done.returncode != 0 or not lines:
-        raise RunFailed("the reference comparison failed (exit %d): %s"
-                        % (done.returncode, done.stderr[-1500:]))
-    run.reference = json.loads(lines[-1])
+    try:
+        if done.returncode != 0:
+            raise ValueError("exit %d" % done.returncode)
+        return json.loads(lines[-1]), time.time() - started
+    except (IndexError, ValueError) as e:
+        raise RunFailed("the reference comparison failed (%s): %s"
+                        % (e, done.stderr[-1500:]))
+
+
+def compare_reference(run):
+    """The product's loss against the plain float32 reference on one
+    seeded microbatch, in a process of its own now that the chip is free
+    and the window's compiles are counted: what it compiles goes into the
+    job's cache, so that a checkout's second traced run finds it."""
+    ref = run.config.get("reference")
+    if not ref or not run.traffic.get("reference_check", True):
+        return
+    run.reference, seconds = spawn_compare(
+        run.root, run.cell["config_file"], run.seed, run.cache_dir,
+        run.rehearse)
+    run.times["compare_s"] = seconds
+    _say("reference comparison: %.1f s of %d" % (seconds, COMPARE_CAP_S))
 
 
 def main(argv=None):
@@ -403,6 +444,10 @@ def main(argv=None):
         json.dump(detail, fh, indent=1)
     _say("detail: %s" % json.dumps(
         {k: v for k, v in detail.items() if k != "completions"}))
+    result["compared"] = run.compared()      # last in the line, and on stderr
+    for name, pair in result["compared"].items():
+        _say("compared %s: %r (limit %r)" % (name, pair["value"],
+                                             pair["limit"]))
     if args.rehearse or run.device["platform"] != "tpu":
         result["metrics"] = {}
         result["rehearsal"] = sorted(metrics)

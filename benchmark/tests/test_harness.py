@@ -107,3 +107,93 @@ def test_without_an_accelerator_there_is_no_result():
          "--trace", "0"], env=dict(os.environ, JAX_PLATFORMS="cpu"),
         capture_output=True, text=True, timeout=60)
     assert done.returncode != 0 and done.stdout.strip() == ""
+
+
+def _traced_main(monkeypatch, spawned):
+    """``runner.main`` on a traced run whose job, window and trace are
+    stubbed away, so that the reference comparison is the first thing
+    that really happens; ``spawned(argv, **kw)`` stands for the child."""
+    for name in ("launch", "measure", "finish", "throughput"):
+        monkeypatch.setattr(runner.Run, name, lambda self, *a: None)
+    monkeypatch.setattr(runner.Run, "make_data", lambda self: "origin")
+    monkeypatch.setattr(runner, "reduce_trace", lambda run: None)
+    monkeypatch.setattr(runner.subprocess, "run", spawned)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    return runner.main(["--workload", "trinity-mini.seq16384", "--seed",
+                        "3411220957", "--seconds", "20", "--trace", "1"])
+
+
+def _expires(argv, timeout=None, **kw):
+    raise subprocess.TimeoutExpired(argv, timeout)
+
+
+def _cannot_start(argv, **kw):
+    raise OSError(12, "Cannot allocate memory")
+
+
+def _ends(code, stdout, stderr="Traceback: the child's own"):
+    return lambda argv, **kw: subprocess.CompletedProcess(
+        argv, code, stdout=stdout, stderr=stderr)
+
+
+@pytest.mark.parametrize("spawned, says", [
+    (_expires, "cap of %d s" % runner.COMPARE_CAP_S),
+    (_cannot_start, "did not start: [Errno 12]"),
+    (_ends(1, ""), "failed (exit 1): Traceback: the child's own"),
+    (_ends(-9, '{"ok": true}'), "failed (exit -9)"),
+    (_ends(0, "no line of JSON"), "failed (list index out of range)"),
+    (_ends(0, "{half a line"), "the reference comparison failed ("),
+])
+def test_whatever_the_comparison_meets_is_a_failed_line(
+        monkeypatch, capsys, spawned, says):
+    """A traced run ends in a result or in a ``FAILED`` line: exit 1, no
+    result on stdout, and never a traceback of this process (PR 59 and
+    PR 64 were refused over a ``subprocess.TimeoutExpired`` that nothing
+    caught)."""
+    assert _traced_main(monkeypatch, spawned) == 1
+    out, err = capsys.readouterr()
+    assert out == ""
+    failed = [l for l in err.splitlines() if "FAILED" in l]
+    assert len(failed) == 1 and says in failed[0], err
+    assert failed[0].startswith("[benchmark] FAILED trinity-mini.seq16384: ")
+    assert "the reference comparison" in failed[0]
+
+
+def test_the_comparison_gets_the_jobs_cache_and_its_seconds_are_kept(
+        monkeypatch, capsys, tmp_path):
+    seen = {}
+
+    def spawned(argv, env=None, timeout=None, **kw):
+        seen.update(argv=argv, env=env, timeout=timeout)
+        return subprocess.CompletedProcess(argv, 0, stderr="", stdout=(
+            "a warning\n" + json.dumps({"ok": True, "rel_diff": 1e-4})))
+
+    monkeypatch.setattr(runner.subprocess, "run", spawned)
+    monkeypatch.setenv("ELASTICDL_FLASH", "off")
+    cell = manifest.Manifest(ROOT).cell("trinity-mini.seq16384")
+    run = runner.Run(ROOT, cell, 7, 20.0, True, False)
+    run.cache_dir = str(tmp_path / "cache")
+    runner.compare_reference(run)
+    assert run.reference == {"ok": True, "rel_diff": 1e-4}
+    assert seen["timeout"] == runner.COMPARE_CAP_S >= 600
+    assert seen["env"]["JAX_COMPILATION_CACHE_DIR"] == run.cache_dir
+    assert "ELASTICDL_FLASH" not in seen["env"]    # kernels on their defaults
+    assert seen["argv"][-4:] == ["--config-file", cell["config_file"],
+                                 "--seed", "7"]
+    assert 0 <= run.times["compare_s"] < 5
+    assert "[benchmark] reference comparison: %.1f s of %d" % (
+        run.times["compare_s"], runner.COMPARE_CAP_S) in capsys.readouterr().err
+
+
+def test_every_number_held_to_a_limit_is_stated_beside_it():
+    cell = manifest.Manifest(ROOT).cell("olmo1b.seq2048")
+    run = runner.Run(ROOT, cell, 7, 20.0, True, False)
+    run.failed, run.compiles_in_window = 0, 2
+    run.problems = ["2 compile(s) inside the window"]
+    assert run.compared() == {
+        "tasks_failed": {"value": 0, "limit": 0},
+        "compiles_in_window": {"value": 2, "limit": 0},
+        "problems": {"value": 1, "limit": 0}}
+    run.reference = {"rel_diff": 1.05e-4, "tolerance": 3e-3, "ok": True}
+    assert run.compared()["loss_rel_diff"] == {"value": 1.05e-4,
+                                               "limit": 3e-3}

@@ -53,10 +53,13 @@ def test_the_named_calls_are_told_by_kernel_and_widths():
             kind, latent.call(1, 32, 16384, 192, 128, kind, 0))
     for hlo in (FLASH, BANDED, UNNAMED, GMM):
         assert latent.classify(*kernels.parse_call(hlo), hlo=hlo) is None
-    # the banded reader's pattern sees the base name: its configuration
-    # does not list this kernel, and this one's does not list that
-    assert band.classify(*kernels.parse_call(LATENT["fwd"]),
-                         hlo=LATENT["fwd"]) is not None
+    # the banded and the plain readers leave a name with widths alone
+    # (PR 65; before, the banded pattern saw the base name and only the
+    # configurations' lists kept the two apart)
+    flash = manifest.load_named("kernels", "flash_attention")
+    for other in (band, flash):
+        assert other.classify(*kernels.parse_call(LATENT["fwd"]),
+                              hlo=LATENT["fwd"]) is None
     assert "banded_attention" not in CELL["config"]["kernels"]
     # no text handed over, or a named call as an operand: nothing
     assert latent.classify(*kernels.parse_call(LATENT["fwd"])) is None
@@ -129,6 +132,66 @@ def _run(custom_calls=None, config=None):
         device={"kind": "TPU v5 lite"})
 
 
+# The fused backward (PR 41) as this cell's compiled program names it (my
+# chip run, PR 53, layouts cut): dk, dv, each head's float32 part of the
+# RoPE key's gradient, dq and dq's RoPE part.
+BWD = ("%flash_bwd_qk192_v128.14 = (" + ", ".join(
+    ["bf16[32,16384,128]", "bf16[32,16384,128]", "f32[32,16384,64]",
+     "bf16[32,16384,128]", "bf16[32,16384,64]"]) + ") custom-call("
+    "s32[136] %copy-done.245, s32[136] %copy-done.247, bf16[32,16384,128] "
+    "%convolution_bitcast_fusion.14, bf16[32,16384,128] "
+    "%convolution_bitcast_fusion.15, bf16[32,16384,128] %bitcast.1574, "
+    "bf16[32,16384,128] %get-tuple-element.6852, f32[32,1,16384] "
+    "%bitcast.1626, f32[32,1,16384] %reshape.5570, bf16[1,16384,64] "
+    "%pad_maximum_fusion.14, bf16[32,16384,64] %bitcast.1575), "
+    'custom_call_target="tpu_custom_call"')
+JVP_FWD = ("%jvp_flash_fwd_qk192_v128_.1 = (" + ", ".join(
+    [OUT, STAT, STAT]) + ") " + TAIL)
+
+
+def test_the_fused_latent_backward_is_told_by_name_and_counted(capsys):
+    latent = manifest.load_named("kernels", "latent_attention")
+    H, rows = 32, 16384
+    got = latent.classify(*kernels.parse_call(BWD), hlo=BWD, heads=32,
+                          d_rope=64)
+    assert got == ("bwd", latent.call(1, 32, 16384, 192, 128, "bwd", 64))
+    assert latent.classify(*kernels.parse_call(JVP_FWD), hlo=JVP_FWD,
+                           heads=32, d_rope=64)[0] == "fwd"
+    flops, nbytes = got[1]
+    # S, dQ, dK over 192 and dP, dV over 128: five products where the
+    # forward has two and the split pair made seven
+    assert flops == 2 * H * PAIRS * (3 * 192 + 2 * 128)
+    fwd = latent.call(1, H, rows, 192, 128, "fwd", 64)[0]
+    pair = sum(latent.call(1, H, rows, 192, 128, kind, 64)[0]
+               for kind in ("dq", "dkv"))
+    assert fwd * 832 == flops * 320 and flops < pair
+    # q, dq at 192 a head; k, dk at 128 a head and ONE RoPE plane; v, o,
+    # dO, dv at 128; the two statistics; the float32 parts of dk's RoPE
+    k = rows * (H * 128 + 64)
+    assert nbytes == 2 * (2 * rows * H * 192 + 2 * k + 4 * rows * H * 128
+                          ) + 2 * 4 * rows * H + 4 * rows * H * 64
+    least, bound = peaks.roofline_seconds(flops, nbytes, "TPU v5 lite")
+    # 36.3 ms of the MXU's time on a v5e, under the 51.1 the call takes
+    assert bound == "compute" and least * 1e3 == pytest.approx(36.28, rel=1e-3)
+    # the readers on the ledger's PR 63 line of this cell: the share is
+    # every flash_* second over the busy time (15.8% while the backward
+    # went unread), forward and backward apart on stderr, none over 100%
+    calls = {BWD: [1.991911361, 39.0],
+             BWD.replace(".14 =", ".13 ="): [0.459672149, 9.0],
+             LATENT["fwd"].replace("__.2 =", ".17 ="): [0.746472143, 36.0],
+             JVP_FWD: [0.186619045, 9.0], GMM: [0.5, 10.0]}
+    run = _run(custom_calls=calls)
+    run.trace["busy_s"] = 5.97
+    share = BOOK.reader("kernel.latent_attention_share")(run)
+    assert share == pytest.approx(100 * 3.384674698 / 5.97)
+    assert share > 50
+    assert 60 < BOOK.reader("kernel.latent_attention_roofline")(run) < 100
+    lines = [l for l in capsys.readouterr().err.splitlines()
+             if "latent_attention" in l]
+    assert [l.split()[2] for l in lines] == ["bwd:", "fwd:"]
+    assert "48.0 calls" in lines[0] and "45.0 calls" in lines[1]
+
+
 def test_the_readers_take_the_named_latent_calls_alone(capsys):
     latent = manifest.load_named("kernels", "latent_attention")
     roofline = BOOK.reader("kernel.latent_attention_roofline")
@@ -175,12 +238,13 @@ def test_the_cells_metrics_hold_the_new_ones_and_the_held_shares():
                           "trainer.peak_hbm_gb", "kernel.mosaic_share"}
     assert {m["name"] for m in CELL["end_to_end"]} >= {"records_per_s",
                                                        "setup_s"}
+    # the two later cells with a latent head joined the two lists
+    latent = (NAME, "xing4.0-29b-a4b.seq4096", "ling-3.0-flash.seq16384")
     for entry in BOOK.doc["workloads"]:
-        if entry["name"] != NAME:
-            theirs = {m["name"] for m in BOOK.cell(
-                entry["name"])["per_layer"]}
-            assert not theirs & new, entry["name"]
-    # flash's older reader counts every call at one width: not this cell
+        theirs = {m["name"] for m in BOOK.cell(entry["name"])["per_layer"]}
+        assert (theirs >= new) == (entry["name"] in latent), entry["name"]
+        assert theirs >= new or not theirs & new, entry["name"]
+    # the plain reader takes the names without widths: not this cell
     assert "kernel.flash_attention_roofline" not in mine
     assert set(CELL["config"]["kernels"]) >= {"latent_attention",
                                               "grouped_matmul"}

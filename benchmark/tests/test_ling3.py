@@ -75,7 +75,8 @@ def test_the_trace_readers_classify_both_mixers_calls_in_this_cell():
     assert found == [("fwd", want_kda, 0.1, 24.0)]
     found = layer("kernel.latent_attention_roofline").calls(
         _run(custom_calls=calls))
-    assert found == [("fwd", want_latent, 0.2, 8.0)]
+    # what ``classify`` said, the seconds, the calls (lib/kernels.calls)
+    assert found == [(("fwd", want_latent), 0.2, 8.0)]
     least = peaks.roofline_seconds(*want_kda, "TPU v5 lite")[0]
     assert roofline(_run(custom_calls=calls)) == pytest.approx(
         100 * 24 * least / 0.1)

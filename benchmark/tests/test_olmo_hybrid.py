@@ -159,13 +159,14 @@ def test_the_cells_metrics_hold_the_two_new_ones_and_no_flash_reader():
                 entry["name"])["per_layer"]}
             assert not theirs & {"kernel.gated_delta_roofline",
                                  "kernel.gated_delta_share"}, entry["name"]
-    # the flash readers do not know ``flash_bwd``, the short
-    # convolution's counts a gated epilogue's passes, and nothing here
-    # routes
+    # the short convolution's reader counts a gated epilogue's passes,
+    # and nothing here routes; the flash reader knows ``flash_bwd``
+    # since PR 65 and reads the full layer's two calls
     assert not {name for name in mine if name.startswith(
-        ("kernel.flash", "kernel.banded", "kernel.short_conv", "moe.",
+        ("kernel.banded", "kernel.short_conv", "moe.",
          "kernel.row_move", "kernel.grouped"))}
-    assert CELL["config"]["kernels"] == ["gated_delta"]
+    assert "kernel.flash_attention_roofline" in mine
+    assert CELL["config"]["kernels"] == ["gated_delta", "flash_attention"]
     assert CELL["chips"] == 1
     flags = CELL["traffic"]["flags"]
     assert (flags["batch_size"], flags["num_minibatches_per_task"],

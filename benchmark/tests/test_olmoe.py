@@ -35,12 +35,12 @@ GMM = {
                'custom_call_target="tpu_custom_call"',
 }
 FLASH = {
-    "fwd": "%checkpoint.4 = (bf16[64,4096,128]{2,1,0}, f32[64,1,4096]"
+    "fwd": "%checkpoint_flash_fwd__.4 = (bf16[64,4096,128]{2,1,0}, f32[64,1,4096]"
            "{2,1,0}, f32[64,1,4096]{2,1,0}) custom-call(%q, %k, %v, %t, %u),"
            ' custom_call_target="tpu_custom_call"',
-    "dq": "%custom-call.9 = bf16[64,4096,128]{2,1,0} custom-call(%q, %k), "
+    "dq": "%flash_dq.9 = bf16[64,4096,128]{2,1,0} custom-call(%q, %k), "
           'custom_call_target="tpu_custom_call"',
-    "dkv": "%custom-call.10 = (bf16[64,4096,128]{2,1,0}, bf16[64,4096,128]"
+    "dkv": "%flash_dkv.10 = (bf16[64,4096,128]{2,1,0}, bf16[64,4096,128]"
            "{2,1,0}) custom-call(%q, %k), "
            'custom_call_target="tpu_custom_call"',
 }
@@ -51,11 +51,11 @@ def test_each_kernels_classify_leaves_the_others_calls_alone():
     flash = manifest.load_named("kernels", "flash_attention")
     gmm = manifest.load_named("kernels", "grouped_matmul")
     for name, hlo in GMM.items():
-        assert flash.classify(*kernels.parse_call(hlo)) is None, name
+        assert flash.classify(*kernels.parse_call(hlo), hlo=hlo) is None, name
         assert gmm.classify(*kernels.parse_call(hlo), hlo=hlo,
                             **SHAPES) is not None, name
     for name, hlo in FLASH.items():
-        assert flash.classify(*kernels.parse_call(hlo))[0] == name
+        assert flash.classify(*kernels.parse_call(hlo), hlo=hlo)[0] == name
         assert gmm.classify(*kernels.parse_call(hlo), hlo=hlo,
                             **SHAPES) is None, name
     # the door lib/kernels.roofline_share uses hands no text over

@@ -42,12 +42,18 @@ def test_lm_flops_at_the_cells_widths():
 
 
 def test_flash_attention_call_counts():
-    flops, nbytes = flash.call(8, 16, 2048, 128, "fwd")
-    assert flops == 2 * 2 * 8 * 16 * 2048 * 2048 * 128 // 2
-    assert nbytes == 4 * 8 * 16 * 2048 * 128 * 2
-    dq = flash.call(8, 16, 2048, 128, "dq")[0]
-    dkv = flash.call(8, 16, 2048, 128, "dkv")[0]
+    flops, nbytes = flash.call(8 * 16, 2048, 128, "fwd")
+    assert flash.pairs(2048) == 2048 * 2049 // 2
+    assert flops == 2 * 2 * 8 * 16 * flash.pairs(2048) * 128
+    # q, k, v, o and the two float32 row statistics
+    assert nbytes == 4 * 8 * 16 * 2048 * 128 * 2 + 2 * 4 * 8 * 16 * 2048
+    dq = flash.call(8 * 16, 2048, 128, "dq")[0]
+    dkv = flash.call(8 * 16, 2048, 128, "dkv")[0]
     assert (dq, dkv) == (flops * 3 // 2, flops * 2)
+    # the fused backward: five products where the pair made seven
+    bwd, bwd_bytes = flash.call(8 * 16, 2048, 128, "bwd")
+    assert bwd == flops * 5 // 2 < dq + dkv
+    assert bwd_bytes == 8 * 8 * 16 * 2048 * 128 * 2 + 2 * 4 * 8 * 16 * 2048
     seconds, bound = peaks.roofline_seconds(flops, nbytes, "TPU v5 lite")
     assert bound == "compute"
     assert seconds == pytest.approx(flops / 197e12)
@@ -63,36 +69,39 @@ def test_an_unknown_device_is_an_error():
         peaks.peaks_of("TPU v9")
 
 
-def test_kernel_calls_are_told_apart_by_results_and_operands():
-    fwd = ('%pallas_call.71 = (bf16[128,2048,128]{2,1,0}, f32[128,2048,8]'
+def test_kernel_calls_are_told_apart_by_their_names():
+    fwd = ('%flash_fwd.71 = (bf16[128,2048,128]{2,1,0}, f32[128,2048,8]'
            '{2,1,0}, f32[128,2048,8]{2,1,0}) custom-call(bf16[128,2048,128]'
            '{2,1,0} %a, bf16[128,2048,128]{2,1,0} %b, bf16[128,2048,128]'
            '{2,1,0} %c), custom_call_target="tpu_custom_call"')
-    dq = ('%checkpoint.23 = bf16[128,2048,128]{2,1,0:T(8,128)(2,1)} '
+    dq = ('%checkpoint_flash_dq__.23 = bf16[128,2048,128]{2,1,0:T(8,128)(2,1)} '
           'custom-call(bf16[128,2048,128]{2,1,0} %a, bf16[128,2048,128]'
           '{2,1,0} %b), custom_call_target="tpu_custom_call"')
     results, operands = kernels.parse_call(fwd)
     assert len(results) == 3 and operands == 3
-    kind, (flops, _) = flash.classify(results, operands)
+    kind, (flops, _) = flash.classify(results, operands, hlo=fwd)
     assert kind == "fwd"
-    assert flops == flash.call(8, 16, 2048, 128,
-                                                  "fwd")[0]
-    assert flash.classify(*kernels.parse_call(dq))[0] == "dq"
-    # four results: none of this kernel's calls
-    other = fwd.replace("(bf16[128,2048,128]{2,1,0}, f32",
-                        "(bf16[128,2048,128]{2,1,0}, f32[1]{0}, f32", 1)
-    assert flash.classify(*kernels.parse_call(other)) is None
+    assert flops == flash.call(8 * 16, 2048, 128, "fwd")[0]
+    assert flash.classify(*kernels.parse_call(dq), hlo=dq)[0] == "dq"
+    # a count of results tells nothing: the fused backward has the
+    # forward's three, and a call that carries no name is nobody's
+    bwd = fwd.replace("%flash_fwd.71", "%flash_bwd.2").replace("f32", "bf16")
+    assert flash.classify(*kernels.parse_call(bwd), hlo=bwd)[0] == "bwd"
+    for other in (fwd.replace("%flash_fwd.71", "%pallas_call.71"),
+                  dq.replace("%checkpoint_flash_dq__.23", "%checkpoint.23")):
+        assert flash.classify(*kernels.parse_call(other), hlo=other) is None
+    assert flash.classify(results, operands) is None     # no text, no name
     assert kernels.parse_call("%fusion.1 = f32[8]{0} fusion(%a)") is None
 
 
 def test_roofline_share_of_the_flash_calls_in_a_trace():
     import types
 
-    fwd = ('%pallas_call.71 = (bf16[128,2048,128]{2,1,0}, f32[128,2048,8]'
+    fwd = ('%flash_fwd.71 = (bf16[128,2048,128]{2,1,0}, f32[128,2048,8]'
            '{2,1,0}, f32[128,2048,8]{2,1,0}) custom-call(bf16[128,2048,128]'
            '{2,1,0} %a, bf16[128,2048,128]{2,1,0} %b, bf16[128,2048,128]'
            '{2,1,0} %c), custom_call_target="tpu_custom_call"')
-    least = flash.call(8, 16, 2048, 128, "fwd")[0] / 197e12
+    least = flash.call(8 * 16, 2048, 128, "fwd")[0] / 197e12
     run = types.SimpleNamespace(
         config=_config("olmo1b"), device={"kind": "TPU v5 lite"},
         trace={"custom_calls": {

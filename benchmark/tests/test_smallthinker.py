@@ -49,8 +49,9 @@ def test_the_named_calls_are_told_by_kernel_and_window():
         got = band.classify(*kernels.parse_call(hlo), hlo=hlo)
         assert got[0] == kind and got[2] == window, (kind, window)
         assert got[1] == band.call(28, 16384, 128, kind, window)
-        # the older reader still tells them by their results, as full
-        assert flash.classify(*kernels.parse_call(hlo))[0] == kind
+        # the plain reader takes a full causal layer's names alone
+        plain = flash.classify(*kernels.parse_call(hlo), hlo=hlo)
+        assert plain is None if window else plain == (kind, got[1])
         assert gmm.classify(*kernels.parse_call(hlo), hlo=hlo, rows=98304,
                             widths=(2560, 768), groups=16) is None
     for hlo in (UNNAMED, GMM, SCONV):
@@ -72,7 +73,8 @@ def test_a_bands_pairs_and_a_calls_operations_by_hand():
     assert BAND_PAIRS / FULL_PAIRS == pytest.approx(0.4375, abs=1e-3)
     flops, nbytes = band.call(28, 16384, 128, "fwd", 4096)
     assert flops == 2 * 2 * 28 * BAND_PAIRS * 128
-    assert nbytes == 4 * 28 * 16384 * 128 * 2
+    # q, k, v, o and the two float32 row statistics
+    assert nbytes == 4 * 28 * 16384 * 128 * 2 + 2 * 4 * 28 * 16384
     assert band.call(28, 16384, 128, "dq")[0] == 3 * 2 * 28 * FULL_PAIRS * 128
     assert band.call(28, 16384, 128, "dkv")[0] == 4 * 2 * 28 * (
         FULL_PAIRS * 128)
@@ -164,6 +166,97 @@ def test_the_readers_take_the_named_flash_calls_alone(capsys):
     assert roofline(_run(custom_calls=full_only)) is not None
 
 
+# Since PR 41 a layer's backward is ONE call with three results, as many
+# as the forward's.  The texts of the traces the issue names (layouts
+# cut): ``chiprun_out/c1/traced_detail.json`` (a suffix the lowering
+# gave, 32 query heads on 4) and this cell's own (ledger, PR 63: 28 on 4).
+def _fused(name, q, kv, stat):
+    fwd = name.startswith(("flash_fwd", "jvp_flash_fwd"))
+    results = [q, stat, stat] if fwd else [kv, kv, q]
+    operands = [q, kv, kv] if fwd else [kv, kv, q, q, stat, stat]
+    return "%" + name + " = (" + ", ".join(results) + ") custom-call(" + (
+        "s32[80] %constant.1544, s32[80] %constant.1545, " + ", ".join(
+            "%s %%bitcast.%d" % (shape, 4700 + i)
+            for i, shape in enumerate(operands))
+        + '), custom_call_target="tpu_custom_call"')
+
+
+Q28, KV4, ROWS28 = "bf16[28,16384,128]", "bf16[4,16384,128]", (
+    "f32[28,1,16384]")
+SUFFIXED = {name: _fused(name, "bf16[32,16384,128]", KV4, "f32[32,1,16384]")
+            for name in ("flash_bwd_b4.14", "flash_fwd_b4.48")}
+
+
+@pytest.mark.parametrize("name, kind, window, heads", [
+    ("flash_bwd_b4.14", "bwd", 0, (32, 4)),
+    ("flash_fwd_b4.48", "fwd", 0, (32, 4)),
+    ("flash_bwd.2", "bwd", 0, (28, 4)),
+    ("flash_bwd_w4096.6", "bwd", 4096, (28, 4)),
+    ("flash_fwd_w4096.26", "fwd", 4096, (28, 4)),
+    ("jvp_flash_fwd_w4096_.3", "fwd", 4096, (28, 4)),
+    ("checkpoint_flash_fwd__.8", "fwd", 0, (28, 4)),
+])
+def test_a_call_is_told_by_its_name_whatever_stands_round_it(
+        name, kind, window, heads):
+    band = manifest.load_named("kernels", "banded_attention")
+    flash = manifest.load_named("kernels", "flash_attention")
+    hlo = SUFFIXED.get(name) or _fused(name, Q28, KV4, ROWS28)
+    results, operands = kernels.parse_call(hlo)
+    assert len(results) == 3                  # whichever kind it is
+    assert flash.name_of(hlo) == (kind, window, None)
+    assert flash.heads_of(results, hlo, 16384) == heads
+    work = band.call(heads[0], 16384, 128, kind, window, kv_heads=heads[1])
+    assert band.classify(results, operands, hlo=hlo) == (kind, work, window)
+    plain = flash.classify(results, operands, hlo=hlo)
+    assert plain is None if window else plain == (kind, work)
+    # a grouped-query backward's operations: 2.5 x its forward's
+    fwd = band.call(heads[0], 16384, 128, "fwd", window, kv_heads=heads[1])
+    bwd = band.call(heads[0], 16384, 128, "bwd", window, kv_heads=heads[1])
+    assert 2 * bwd[0] == 5 * fwd[0]
+    assert bwd[0] == 5 * 2 * heads[0] * band.pairs(16384, window) * 128
+    # K, V and their cotangents at the K/V heads: under the bytes of as
+    # many K/V heads as query heads
+    assert bwd[1] < band.call(heads[0], 16384, 128, "bwd", window)[1]
+
+
+def test_the_readers_read_this_cells_fused_trace(capsys):
+    """The calls of the ledger's PR 63 line of this cell: the share was
+    9.25% while ``flash_bwd*`` went unread, and the ratio nothing."""
+    roofline = BOOK.reader("kernel.banded_attention_roofline")
+    share = BOOK.reader("kernel.banded_attention_share")
+    ratio = BOOK.reader("attn.window_over_full_time")
+    text = lambda name: _fused(name, Q28, KV4, ROWS28)
+    calls = {text("flash_bwd.2"): [0.450639246, 14.0],
+             text("flash_fwd.8"): [0.220200153, 14.0],
+             text("flash_bwd_w4096.6"): [0.220261194, 14.0],
+             text("flash_bwd_w4096.7"): [0.2202574, 14.0],
+             text("flash_bwd_w4096.8"): [0.220257388, 14.0],
+             text("flash_fwd_w4096.26"): [0.114666605, 14.0],
+             text("flash_fwd_w4096.24"): [0.108297586, 14.0],
+             text("flash_fwd_w4096.25"): [0.108297586, 14.0],
+             GMM: [0.5, 10.0], UNNAMED: [0.5, 10.0]}
+    run = _run(custom_calls=calls)
+    flash_s = sum(v[0] for k, v in calls.items() if "flash_" in k)
+    assert share(run) == pytest.approx(100 * flash_s / 6.0)
+    assert share(run) > 25
+    # a windowed layer over a full one: the tiles say 0.51, the pairs 0.44
+    assert ratio(run) == pytest.approx(
+        (0.220261194 + 0.2202574 + 0.220257388 + 0.114666605
+         + 2 * 0.108297586) / 3 / (0.450639246 + 0.220200153))
+    assert 0.44 < ratio(run) < 0.55
+    assert 60 < roofline(run) < 100
+    lines = [l for l in capsys.readouterr().err.splitlines()
+             if "banded_attention" in l]
+    assert [tuple(l.split()[2:4]) for l in lines] == [
+        ("full", "bwd:"), ("full", "fwd:"),
+        ("window=4096", "bwd:"), ("window=4096", "fwd:")]
+    for line in lines:
+        assert float(line.split("(")[1].split("%")[0]) <= 100.0, line
+    # a full layer's backward gone from the trace: no ratio
+    del calls[text("flash_bwd.2")]
+    assert ratio(_run(custom_calls=calls)) is None
+
+
 # the older metrics of a held share of the experts that list the cell
 HELD_SHARE = {"moe.dead_row_share", "moe.held_load_max_over_mean"}
 
@@ -213,9 +306,14 @@ def test_the_new_metrics_are_the_new_cells_alone():
     assert "kernel.flash_attention_roofline" not in mine
     flash = [m for m in BOOK.doc["per_layer"]
              if m["name"] == "kernel.flash_attention_roofline"][0]
-    assert flash["workloads"] == list(earlier)
+    # .. and since PR 65, which made it tell calls by name, the three
+    # cells whose stacks call the plain kernel beside a scan
+    assert flash["workloads"] == list(earlier) + [
+        "olmo-hybrid-7b.seq16384", "solar-open2-250b.seq16384",
+        "nemotron-3-nano-30b-a3b.seq16384"]
     dense = {m["name"] for m in BOOK.cell("olmo1b.seq2048")["per_layer"]}
-    assert mine - dense == new | HELD_SHARE
+    # .. and PR 34's row kernel's share, which the share cells list
+    assert mine - dense == new | HELD_SHARE | {"kernel.row_move_share"}
     assert dense - mine == {"kernel.flash_attention_roofline"}
     assert set(CELL["config"]["kernels"]) == {"banded_attention",
                                               "grouped_matmul"}

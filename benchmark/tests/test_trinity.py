@@ -53,7 +53,7 @@ def test_the_banded_readers_tell_the_calls_of_a_window_of_2048():
     # a windowed forward: 2 matmuls over the band's pairs, 32 heads
     flops, nbytes = band.call(32, 16384, 128, "fwd", 2048)
     assert flops == 2 * 2 * 32 * BAND_PAIRS * 128
-    assert nbytes == 4 * 32 * 16384 * 128 * 2
+    assert nbytes == 4 * 32 * 16384 * 128 * 2 + 2 * 4 * 32 * 16384
     least, bound = peaks.roofline_seconds(flops, nbytes, "TPU v5 lite")
     assert bound == "compute"
     assert least * 1e3 == pytest.approx(
@@ -100,6 +100,103 @@ def test_the_three_banded_readers_read_the_cells_calls(capsys):
         ("window=2048", "dkv:"), ("window=2048", "dq:"),
         ("window=2048", "fwd:")]
     assert "(25.0%)" in lines[3] and "40.0 calls" in lines[3]
+
+
+# The calls of a step since PR 41 fused the backward, as the compiled
+# program of this cell names them (my chip run, PR 60, layouts cut): a
+# grouped-query call states the query heads beside the K/V heads.
+Q, KV, ROWS = "bf16[32,16384,128]", "bf16[4,16384,128]", "f32[32,1,16384]"
+FWD_TAIL = ("custom-call(s32[45] %copy-done.1053, s32[45] %copy-done.1049, "
+            + Q + " %bitcast.4450, " + KV + " %bitcast.4536, " + KV
+            + ' %bitcast.4902), custom_call_target="tpu_custom_call"')
+BWD_TAIL = ("custom-call(s32[45] %constant.1482, s32[45] %constant.1483, "
+            + KV + " %bitcast.4533, " + KV + " %bitcast.4899, " + Q
+            + " %bitcast.4449, " + Q + " %get-tuple-element.7829, " + ROWS
+            + " %bitcast.4634, " + ROWS + " %reshape.1092), "
+            'custom_call_target="tpu_custom_call"')
+FUSED = {
+    ("fwd", 0): "%flash_fwd.8 = (" + ", ".join([Q, ROWS, ROWS]) + ") "
+                + FWD_TAIL,
+    ("bwd", 0): "%flash_bwd.2 = (" + ", ".join([KV, KV, Q]) + ") "
+                + BWD_TAIL,
+    ("fwd", 2048): "%jvp_flash_fwd_w2048_.3 = (" + ", ".join(
+        [Q, ROWS, ROWS]) + ") " + FWD_TAIL,
+    ("bwd", 2048): "%flash_bwd_w2048.8 = (" + ", ".join([KV, KV, Q]) + ") "
+                   + BWD_TAIL,
+}
+
+
+def test_the_fused_backward_is_told_by_name_and_counted_at_its_heads():
+    band = manifest.load_named("kernels", "banded_attention")
+    flash = manifest.load_named("kernels", "flash_attention")
+    plane = 16384 * 128 * 2
+    for (kind, window), hlo in FUSED.items():
+        results, operands = kernels.parse_call(hlo)
+        assert flash.name_of(hlo) == (kind, window, None)
+        assert flash.heads_of(results, hlo, 16384) == (32, 4)
+        got = band.classify(results, operands, hlo=hlo)
+        assert (got[0], got[2]) == (kind, window)
+        assert got[1] == band.call(32, 16384, 128, kind, window, kv_heads=4)
+    # three results say nothing: the backward has as many as the forward
+    assert len(kernels.parse_call(FUSED[("bwd", 0)])[0]) == len(
+        kernels.parse_call(FUSED[("fwd", 0)])[0]) == 3
+    for window, pairs in ((0, FULL_PAIRS), (2048, BAND_PAIRS)):
+        fwd, fwd_bytes = band.call(32, 16384, 128, "fwd", window, kv_heads=4)
+        bwd, bwd_bytes = band.call(32, 16384, 128, "bwd", window, kv_heads=4)
+        # S, dP, dV, dQ, dK where the forward has S and PV
+        assert bwd == 5 * 2 * 32 * pairs * 128 and 2 * bwd == 5 * fwd
+        # q, o (and dO, dq) at 32 heads, K, V (and dk, dv) at 4
+        assert fwd_bytes == (2 * 32 + 2 * 4) * plane + 2 * 4 * 32 * 16384
+        assert bwd_bytes == (4 * 32 + 4 * 4) * plane + 2 * 4 * 32 * 16384
+    # a full backward call: 27.9 ms of the MXU's time on a v5e, under
+    # the 30.3 ms it takes (ops/flash_attention.py): no reading over 100%
+    least, bound = peaks.roofline_seconds(
+        *band.call(32, 16384, 128, "bwd", kv_heads=4), "TPU v5 lite")
+    assert bound == "compute" and least * 1e3 == pytest.approx(27.9, rel=2e-3)
+
+
+def test_the_three_banded_readers_read_a_fused_trace(capsys):
+    """The traced window of the ledger's PR 60 line, call by call: the
+    share is every ``flash_*`` second over the busy time, and a
+    windowed layer's forward and backward over a full layer's."""
+    roofline = BOOK.reader("kernel.banded_attention_roofline")
+    share = BOOK.reader("kernel.banded_attention_share")
+    ratio = BOOK.reader("attn.window_over_full_time")
+    at = lambda key, n: FUSED[key].replace(".8 =", ".%d =" % n).replace(
+        ".3 =", ".%d =" % n)
+    calls = {FUSED[("fwd", 0)]: [0.1992, 14.0],
+             FUSED[("bwd", 0)]: [0.4252, 14.0],
+             at(("fwd", 2048), 3): [0.0656, 15.0],
+             at(("fwd", 2048), 2): [0.0612, 14.0],
+             at(("bwd", 2048), 8): [0.1206, 15.0],
+             at(("bwd", 2048), 9): [0.1125, 14.0],
+             GMM: [0.5, 10.0], UNNAMED: [0.5, 10.0]}
+    run = _run(custom_calls=calls)
+    flash_s = 0.1992 + 0.4252 + 0.0656 + 0.0612 + 0.1206 + 0.1125
+    assert share(run) == pytest.approx(100 * flash_s / 6.0)
+    want = ((0.0656 + 0.0612) / 29 + (0.1206 + 0.1125) / 29) / (
+        0.1992 / 14 + 0.4252 / 14)
+    assert ratio(run) == pytest.approx(want)
+    assert 0.2 < ratio(run) < 0.3        # the pairs say 0.234
+    assert 60 < roofline(run) < 100
+    lines = [l for l in capsys.readouterr().err.splitlines()
+             if "banded_attention" in l]
+    assert [tuple(l.split()[2:4]) for l in lines] == [
+        ("full", "bwd:"), ("full", "fwd:"),
+        ("window=2048", "bwd:"), ("window=2048", "fwd:")]
+    for line in lines:       # forward and backward apart, none over 100%
+        assert float(line.split("(")[1].split("%")[0]) <= 100.0, line
+    # one kind of layer lacks a kind the other has: no ratio
+    del calls[FUSED[("bwd", 0)]]
+    assert ratio(_run(custom_calls=calls)) is None
+    assert share(_run(custom_calls=calls)) is not None
+    # a path that splits one layer kind's backward alone: no ratio either
+    calls[FLASH[("dq", 0)]] = calls[FLASH[("dkv", 0)]] = [0.2, 14.0]
+    assert ratio(_run(custom_calls=calls)) is None
+    # the split names on both layer kinds are still read
+    split = {FLASH[key]: [1.0, 2.0] for key in FLASH}
+    assert ratio(_run(custom_calls=split)) == pytest.approx(1.0)
+    assert roofline(_run(custom_calls=split)) is not None
 
 
 LINE = ("[2026-09-29 21:16:51,936] [INFO] [elasticdl_tpu.ops."
@@ -202,10 +299,12 @@ def test_the_cells_metrics_hold_the_new_one_and_the_six_lists():
             flags["num_workers"], flags["log_loss_steps"]) == (1, 4, 1, 8)
     assert CELL["traffic"]["generator"] == "tokens_zipf_fixed_ids"
     assert flags["batch_size"] * CELL["config"]["seq_len"] == 16384
-    # the new entries stand last in their lists
-    assert BOOK.doc["configs"][-1]["name"] == "trinity-mini"
-    assert BOOK.doc["workloads"][-1]["name"] == NAME
-    assert BOOK.doc["per_layer"][-1]["name"] == "attn.kv_repeat_gb_per_step"
+    # the new entries stood last in their lists; later cells follow
+    assert [c["name"] for c in BOOK.doc["configs"]].index(
+        "trinity-mini") == 5
+    assert [w["name"] for w in BOOK.doc["workloads"]].index(NAME) == 6
+    assert "attn.kv_repeat_gb_per_step" in [
+        m["name"] for m in BOOK.doc["per_layer"]]
 
 
 def test_the_configuration_keeps_every_published_width():
@@ -232,8 +331,8 @@ def test_the_configuration_keeps_every_published_width():
     reduced = ["num_hidden_layers", "num_dense_layers", "num_experts",
                "vocab_size"]
     assert config["reduced"] == reduced
-    entry = BOOK.doc["configs"][-1]
-    assert entry["reduced"] == reduced
+    entry = BOOK.doc["configs"][5]
+    assert entry["name"] == "trinity-mini" and entry["reduced"] == reduced
     assert entry["source"] == (
         "https://huggingface.co/arcee-ai/Trinity-Mini/blob/main/config.json")
     assert "catalog row Trinity-Mini" in entry["why"]

@@ -185,14 +185,16 @@ def test_the_cells_metrics_hold_the_new_ones_and_the_shared_kernels():
         "kernel.mosaic_share"}
     assert {m["name"] for m in CELL["end_to_end"]} >= {"records_per_s",
                                                        "setup_s"}
+    # ``mtp.loss_over_main`` is also ``ling-3.0-flash``'s since PR 56
+    shared = {"mtp.loss_over_main": [NAME, "ling-3.0-flash.seq16384"]}
     for entry in BOOK.doc["workloads"]:
         if entry["name"] != NAME:
             theirs = {m["name"] for m in BOOK.cell(
                 entry["name"])["per_layer"]}
-            assert not theirs & new, entry["name"]
+            assert not theirs & (new - set(shared)), entry["name"]
     for metric in BOOK.doc["per_layer"]:
         if metric["name"] in new:
-            assert metric["workloads"] == [NAME]
+            assert metric["workloads"] == shared.get(metric["name"], [NAME])
             assert metric["moves"] == "records_per_s"
     assert CELL["config"]["kernels"] == ["latent_attention",
                                          "grouped_matmul", "hyper_mix"]
