@@ -85,6 +85,12 @@ KEEP_DELTA_RANK = "delta_rank"
 # two are ``ssd.KEEP_OUT`` and ``ssd.KEEP_STATES``.
 KEEP_SSM_GATE, KEEP_SSM_IN, KEEP_SSM_XBC = "ssm_gate", "ssm_in", "ssm_xbc"
 KEEP_SSM_DECAY = "ssm_decay"
+# A looped stack's (``cfg.ut_steps`` > 1; ``transformer.looped_loss``):
+# the logits of the heads on turns 1 .. R - 1, [rows, vocab] in the
+# compute dtype each.  No ``checkpoint_name`` carries it: the name on
+# the list is the loss's to read, which computes those logits again in
+# its backward where it is absent (the last turn's are read at once).
+KEEP_LOGITS = "head_logits"
 
 # The share of the device's limit nothing is planned into: the
 # allocator's fragmentation, the batches in flight, whatever
@@ -128,8 +134,9 @@ def _entries(cfg, rows):
     layers do)]: ``table`` in full."""
     size = jnp.dtype(cfg.dtype).itemsize
     e, h, g, d = cfg.dim, cfg.num_heads, cfg.kv_heads, cfg.head_dim
-    # a multi-token-prediction module's block is one layer more
-    kinds = cfg.kinds + (cfg.mtp_kind,) * cfg.mtp_modules
+    # a multi-token-prediction module's block is one layer more; a
+    # looped stack saves what a layer keeps once a turn
+    kinds = cfg.kinds * cfg.ut_steps + (cfg.mtp_kind,) * cfg.mtp_modules
     attention = sum(kind.op == "a" for kind in kinds)
     conv = sum(kind.op == "c" for kind in kinds)
     delta = sum(kind.op == "d" for kind in kinds)
@@ -146,6 +153,15 @@ def _entries(cfg, rows):
     entries = [
         ("flash", ATTN_NAMES, rows * h * (d * size + 4), attention),
     ]
+    if cfg.ut_steps > 1:
+        # a head's forward again, rows x dim x vocab multiply-adds for
+        # rows x vocab values: what a byte of q buys (~12 ms a GB), but
+        # the bytes are gone where a layer's backward is the peak, so
+        # they are tried while the head's place has the room: 28 ms a
+        # step for 0.49 GB of peak at six layers (PERF.md section 6, PR
+        # 66), behind the flash forward's 30 ms for 0.82
+        entries.append(("logits", (KEEP_LOGITS,),
+                        rows * cfg.vocab_size * size, cfg.ut_steps - 1))
     x = cfg.moe_experts
     k = min(cfg.moe_top_k, x)
     # probs f32 [rows, X]; gates f32, experts, order and (where all
@@ -582,6 +598,15 @@ def grads_standing(cfg, params, rows, kept=()):
      - A group the stack scans over several turns: its gradient is one
        stacked array that the loop fills and the update reads after it.
        Whole.
+     - A looped stack (``cfg.ut_steps`` > 1: the layers' scan inside a
+       scan over the turns): the turns' reverse loop carries the sum
+       of the turns done, whole, and the layers' reverse loop fills the
+       running turn's beside it before the two are added: TWICE, once
+       more than the caller counted, whatever the number of turns (the
+       chip's peaks of the ``ouro-2.6b`` step at 4, 6, 7 and 8 layers
+       over eleven kept lists: PERF.md section 6, PR 66; with the turns
+       written out the compiler places four such sums and counts 3.7
+       GB more at six layers).
      - The layers XLA unrolls (``_unrolled``; the test
        ``transformer._updates_apart`` makes): each matrix goes to AdamW
        behind its own layer's backward through a barrier of its own, so
@@ -608,6 +633,8 @@ def grads_standing(cfg, params, rows, kept=()):
        (``kanana-2-30b-a3b``, PERF.md section 7), and there the chip
        reads 0.03 GB under the estimate as it is."""
     stack = _stack(params)
+    if cfg.ut_steps > 1:
+        return 2 * _nbytes(stack)
     scanned, unrolled = _unrolled(stack)
     each = sorted(map(_nbytes, unrolled))
     scanned_kinds, _ = _kinds_apart(cfg, stack)
@@ -617,7 +644,8 @@ def grads_standing(cfg, params, rows, kept=()):
         return 0
     given_back = sum(per_layer
                      for label, _, per_layer, layers in _entries(cfg, rows)
-                     if label in kept and layers == cfg.num_layers)
+                     if label in kept
+                     and layers == cfg.num_layers * cfg.ut_steps)
     return _nbytes(scanned) + max(0, each[-1] - given_back)
 
 
@@ -736,7 +764,17 @@ def step_bytes(cfg, params, rows, kept=()):
        zeros and the block's own input, bf16[8192, 14336] each);
      - less, at either place, what an untied embedding was counted
        for: its copy is read by the forward's first gather alone and
-       its gradient is the last thing the backward makes.
+       its gradient is the last thing the backward makes;
+     - a looped stack (``cfg.ut_steps`` = R > 1): a carry a layer a
+       turn, and a turn's state before and behind the final norm (the
+       norm's operand and the head's, the cotangent that comes back)
+       and the gate's float32 operand; one call's logits at the head
+       beside the calls' summed head gradient (the other calls' logits
+       are the ``logits`` entry's, kept or made again one at a time),
+       which are gone again where a layer's backward is the peak: the
+       entry's kept bytes come off that place.  Held to the chip's
+       peaks of the ``ouro-2.6b`` step over eleven kept lists at four
+       depths: +0.03 / +0.32 GB (tests/test_remat_keep.py).
 
     Held to the chips' measured peaks for the cells of the benchmark
     (tests/test_remat_keep.py: -0.1 / +0.9 GB; -0.1 / +0.5 in the six
@@ -753,13 +791,22 @@ def step_bytes(cfg, params, rows, kept=()):
     copies = copy(params) - copy(stack) + _weight_copies(stack, copy)
     stack_grads = _nbytes(stack)
     stream = rows * cfg.stream_width * size
-    carries = (cfg.num_layers + cfg.mtp_modules + 1) * stream
+    turns = cfg.ut_steps
+    carries = (cfg.num_layers * turns + cfg.mtp_modules + 1) * stream
+    if turns > 1:
+        # a turn's state before and behind the final norm and the
+        # cotangent the heads and the gate hand back, [R, rows, dim]
+        # each, and the gate's float32 operand, [R - 1, rows, dim]
+        carries += 3 * turns * stream + (turns - 1) * rows * cfg.dim * 4
     # a module's projection runs outside its block's checkpoint: its two
     # normed operands stand from the forward to the module's backward
     carries += cfg.mtp_modules * 2 * rows * cfg.dim * size
     # a multi-token-prediction module's logits stand beside the model's
     head = rows * cfg.vocab_size * size * (
         2 if cfg.tied_embeddings else 1) * (1 + cfg.mtp_modules)
+    if turns > 1:
+        # the calls' summed head gradient beside the running call's own
+        head += cfg.vocab_size * cfg.dim * size
     sizes = {label: per_layer
              for label, _, per_layer, _ in _entries(cfg, rows)}
     own = lambda labels: sum(sizes[label] for label in labels
@@ -809,8 +856,11 @@ def step_bytes(cfg, params, rows, kept=()):
     embed = params["embed"]
     unread = 0 if cfg.tied_embeddings else copy(embed) + _nbytes(embed)
     absent = stack_grads - grads_standing(cfg, params, rows, kept)
+    # a looped stack's kept logits, which its caller adds whole: the
+    # heads' backward has read them before the first layer's starts
+    gone = (turns - 1) * sizes["logits"] if "logits" in kept else 0
     return copies + carries + max(head - stack_grads,
-                                  layer - absent) - unread
+                                  layer - absent - gone) - unread
 
 
 def choose(cfg, params, rows, room):
@@ -835,17 +885,20 @@ def choose(cfg, params, rows, room):
 
 @functools.lru_cache(maxsize=None)
 def announce_keep(names, kept, budget, need, peak, standing, dispatch,
-                  layers, rows, fallback):
+                  layers, rows, fallback, turns=1):
     """Once per compiled shape, by the logger ``announce_tiles`` uses:
     what the layer stack keeps for its backward, of one shard of the
     trainer's data axis, what of the stack's gradients the estimate
     took to stand at the peak (``grads_standing``) and what it took an
-    expert layer's dispatch to hold there (``dispatch_bytes``)."""
+    expert layer's dispatch to hold there (``dispatch_bytes``);
+    ``turns``: how often a looped stack runs its layers (what it keeps
+    of a layer it keeps a turn), said where it is more than once."""
     flash_attention.logger.info(
         "remat keep: names=%s bytes=%d budget=%d need=%d "
         "predicted_peak=%d grads_standing=%d dispatch=%d layers=%d "
-        "rows=%d fallback=%d", ",".join(names) or "-", kept, budget, need,
-        peak, standing, dispatch, layers, rows, fallback)
+        "rows=%d fallback=%d%s", ",".join(names) or "-", kept, budget, need,
+        peak, standing, dispatch, layers, rows, fallback,
+        " turns=%d" % turns if turns > 1 else "")
 
 
 _KEPT = contextvars.ContextVar("elasticdl_remat_kept", default=())
@@ -887,5 +940,5 @@ def names_for(cfg, params, tokens_shape):
     announce_keep(names, kept, budget, need, peak,
                   grads_standing(cfg, params, rows, labels),
                   counted * dispatch_bytes(cfg, rows, labels),
-                  cfg.num_layers, rows, int(not room.free))
+                  cfg.num_layers, rows, int(not room.free), cfg.ut_steps)
     return names

@@ -158,6 +158,18 @@ class TransformerConfig:
     # alone: no caller reads a module's logits.
     mtp_modules: int = 0
     mtp_weight: float = 0.1
+    # A looped stack (arXiv:2510.25741): R > 1 runs the whole layer
+    # stack R times on ONE set of weights, ``h_t = RMSNorm_f(Stack(
+    # h_(t-1)))`` with the model's one final norm between the turns; the
+    # head reads every ``h_t``, an exit gate ``lambda_t = sigmoid(h_t .
+    # ut_gate_w + ut_gate_b)`` (float32; turns 1 .. R - 1) makes the exit
+    # distribution ``p_t = lambda_t prod_(j<t) (1 - lambda_j)``, ``p_R``
+    # the rest, and training's loss a token is ``sum_t p_t CE_t -
+    # ut_entropy_weight H(p)``; evaluation reads turn R's logits.  The
+    # plain scanned stack alone (docs/designs/looped_stack.md).  1 = one
+    # pass, one head, one loss, as ever.
+    ut_steps: int = 1
+    ut_entropy_weight: float = 0.1
     # A stack whose layers differ.  ``layer_pattern``: one letter a
     # layer, "a" causal attention over the whole sequence, "w" causal
     # attention over the last ``window`` positions, "c" gated short
@@ -462,6 +474,18 @@ class TransformerConfig:
                 "ffn_activation=%s has no gate product: the clamps "
                 "ffn_limits=%r, shared_limits=%r are a gated MLP's"
                 % (self.ffn_activation, self.ffn_limits, self.shared_limits))
+        if self.ut_steps != 1 and (
+                self.ut_steps < 1 or _pattern(self) is not None
+                or self.moe_experts or self.hyper_streams
+                or self.mtp_modules):
+            raise ValueError(
+                "ut_steps=%d: want a count >= 1, and a looped stack is the "
+                "plain scanned one: no layer_pattern (%r) or dense_layers "
+                "(%d), no experts (moe_experts=%d), no wide stream "
+                "(hyper_streams=%d), no mtp_modules (%d); no configuration "
+                "needs the pair yet"
+                % (self.ut_steps, self.layer_pattern, self.dense_layers,
+                   self.moe_experts, self.hyper_streams, self.mtp_modules))
         if set(self.rope_kinds) - set("aw"):
             raise ValueError(
                 "rope_kinds %r: want letters of a (full attention) and w "
@@ -780,6 +804,14 @@ _CANNOT = {
         "decoding and the pipeline's stages were not held to an FFN "
         "without a gate product, and a mesh's specs name w_gate and "
         "ws_gate"),
+    "loop": (
+        lambda cfg: cfg.ut_steps > 1,
+        "a looped stack (ut_steps={cfg.ut_steps}: ut_gate_w, ut_gate_b)",
+        "decoding needs a K/V cache a turn a layer (ut_steps x num_layers "
+        "of them) and, for an early exit, a step whose cost is decided a "
+        "token (generate's loop runs one stack a token); a mesh has no "
+        "spec for the gate's weights and the pipeline's stages run their "
+        "layers once, with no final norm between turns"),
     "share": (
         lambda cfg: cfg.moe_experts_held,
         "one chip's share of the experts (moe_experts_held="
@@ -787,7 +819,8 @@ _CANNOT = {
         "a model-parallel mesh shards all the experts over ep"),
 }
 # what decoding and the pipelined forward cannot run
-_TRAINS_ONLY = ("latent", "block", "stack", "route", "hyper", "mtp", "mlp")
+_TRAINS_ONLY = ("latent", "block", "stack", "route", "hyper", "mtp", "mlp",
+                "loop")
 
 
 def _refuse(cfg, what, *features):
@@ -1061,6 +1094,10 @@ def init_params(rng, cfg):
     }
     if not cfg.tied_embeddings:
         params["lm_head"] = _dense_init(k_out, E, cfg.vocab_size, scale=0.02)
+    if cfg.ut_steps > 1:
+        # the exit gate, drawn at zero: every lambda 1/2 at the start
+        params["ut_gate_w"] = jnp.zeros((E,), jnp.float32)
+        params["ut_gate_b"] = jnp.zeros((), jnp.float32)
     if cfg.hyper_streams:
         params.update(_init_hyper(jax.random.fold_in(k_out, 1), cfg, (),
                                   "hc_out", read_only=True))
@@ -2137,7 +2174,10 @@ def _forward_stack(params, tokens, cfg, mesh=None, with_load=False,
     outlives a chunk (``_ssm_mix``; None without such a layer);
     "mtp_hidden": with ``with_mtp`` each multi-token-prediction
     module's hidden state, its block's aux and load joined to the
-    stack's}."""
+    stack's; of a looped stack (``cfg.ut_steps`` > 1) "hidden" is the
+    LAST turn's, "aux" a zero a layer a turn, "turns" every turn's
+    final-normed state [R, B, T, dim] and "logits_kept" whether the
+    heads' backward finds their logits kept (``remat_keep``'s choice)}."""
     embedded = _constrain(_embed(params, tokens, cfg, mesh), mesh,
                           P("dp", "sp", None))
     x = _widen(embedded, cfg)
@@ -2171,7 +2211,15 @@ def _forward_stack(params, tokens, cfg, mesh=None, with_load=False,
     with remat_keep.keeping(names if cfg.remat and plan is not None
                             else ()):
         excess = keep = None
-        if plan is None:
+        if cfg.ut_steps > 1:
+            x, seen, out["turns"] = _looped_stack(x, layers, params, cfg,
+                                                  block())
+            out["logits_kept"] = remat_keep.KEEP_LOGITS in names if (
+                cfg.remat) else True
+            announce_loop(cfg.ut_steps, cfg.num_layers,
+                          tokens.size // batch_shard.shards(),
+                          out["logits_kept"])
+        elif plan is None:
             x, seen = jax.lax.scan(block(), x, layers)
         else:
             x, seen, excess, keep = _mixed_stack(x, layers, cfg, plan, block)
@@ -2193,6 +2241,95 @@ def _forward_stack(params, tokens, cfg, mesh=None, with_load=False,
     out["hc_err"], out["gate_excess"] = err, excess
     out["chunk_keep"] = keep
     return out
+
+
+@functools.lru_cache(maxsize=None)
+def announce_loop(turns, layers, rows, logits_kept):
+    """Once per compiled shape, by the logger ``announce_tiles`` uses:
+    how a looped stack is run (``_looped_stack``: the turns one scan,
+    the carry final-normed; ``looped_loss``: a linear gate, a head a
+    turn, their logits kept or made again as ``remat_keep`` chose)."""
+    logger.info(
+        "loop stack: turns=%d layers=%d rows=%d carry=normed gate=linear "
+        "heads=%d turns_as=scan logits=%s", turns, layers, rows, turns,
+        "kept" if logits_kept else "recomputed")
+
+
+def _looped_stack(x, layers, params, cfg, layer):
+    """``cfg.ut_steps`` turns round the scanned stack on its one set of
+    weights, the model's final norm behind each turn: ``h_t = RMSNorm_f(
+    Stack(h_(t-1)))`` -> (the last turn's state BEFORE that norm, what
+    the layers returned beside the stream [R * L], every ``h_t`` [R, B,
+    T, dim]).  The turns are one ``lax.scan`` round the layers' scan
+    (docs/designs/looped_stack.md: why, and where the stacked weights'
+    gradient stands, ``remat_keep.grads_standing``)."""
+    scale = params["ln_f"].astype(jnp.dtype(cfg.dtype))
+    # its backward reads u alone: no float32 plane of the norm is saved
+    norm = jax.checkpoint(lambda u: _rmsnorm(u, scale, cfg.norm_eps))
+
+    def turn(carry, _):
+        u, seen = jax.lax.scan(layer, carry[0], layers)
+        h = norm(u)
+        return (h, u), (h, seen)
+
+    (_, last), (turns, seen) = jax.lax.scan(
+        turn, (x, x), None, length=cfg.ut_steps)
+    return last, seen.reshape(-1), turns
+
+
+def exit_distribution(gate_logits):
+    """The log of a looped stack's exit distribution [R, ..] (float32)
+    from its gate's logits [R - 1, ..]: ``p_t = lambda_t prod_(j<t) (1 -
+    lambda_j)`` with ``lambda = sigmoid(logit)``, and ``p_R`` what is
+    left; sums to 1 over the turns."""
+    leave = jax.nn.log_sigmoid(gate_logits)
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-gate_logits), axis=0)
+    stayed = jnp.concatenate([jnp.zeros_like(stay[:1]), stay[:-1]])
+    return jnp.concatenate([leave + stayed, stay[-1:]])
+
+
+def looped_loss(outputs, tokens, cfg):
+    """A looped stack's training loss [B] and what ``step_stats`` hands
+    out of it (``outputs["ut"]``): the head (``ops/head_loss.
+    token_loss``: one head, R calls, its gradient their sum) on every
+    turn's state, the exit gate on turns 1 .. R - 1, and a token's loss
+    the expectation of its R cross entropies under the exit
+    distribution less ``cfg.ut_entropy_weight`` times that
+    distribution's entropy, the mean over the T - 1 positions that have
+    a target.  Everything behind the head in float32."""
+    from elasticdl_tpu.ops import head_loss as op
+
+    params, turns = outputs["params"], outputs["turns"]
+    dtype = jnp.dtype(cfg.dtype)
+    # the R calls' gradients of the one head are summed behind a barrier
+    # of the sum's own: left alone, XLA makes the sum part of the
+    # optimizer's update and the R addends stand through the stack's
+    # backward (0.2 GB each at 2,048 x 49,152)
+    head = _update_apart(
+        params["embed" if cfg.tied_embeddings else "lm_head"].astype(dtype))
+    R, _, T, _ = turns.shape
+    # the last turn's logits are read by the backward that follows them
+    losses = jnp.stack([
+        op.token_loss(turns[t], head, tokens, tied=cfg.tied_embeddings,
+                      calls=R,
+                      logits_kept=outputs["logits_kept"] or t == R - 1)
+        for t in range(R)])                                     # [R, B, T]
+    gate = jnp.einsum(
+        "rbte,e->rbt", turns[:-1].astype(jnp.float32),
+        params["ut_gate_w"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST) + params["ut_gate_b"].astype(
+            jnp.float32)
+    log_p = exit_distribution(gate)
+    p = jnp.exp(log_p)
+    # a turn no token can leave at (p = 0) adds nothing: 0 log 0 = 0
+    entropy = -(p * jnp.where(p > 0, log_p, 0.0)).sum(axis=0)   # [B, T]
+    has_target = (jnp.arange(T) < T - 1).astype(jnp.float32)
+    mean = lambda a: (a * has_target).sum(axis=-1) / (T - 1)
+    outputs["ut"] = {
+        "ut_loss": mean(losses).mean(axis=-1),
+        "ut_exit": mean(p).mean(axis=-1),
+        "ut_exit_entropy": mean(entropy).mean()}
+    return mean((p * losses).sum(axis=0) - cfg.ut_entropy_weight * entropy)
 
 
 def forward_hidden(params, tokens, cfg, mesh=None, return_load=False):
@@ -2647,13 +2784,15 @@ def _decayed(params):
     and, of a gated-delta layer, its decay rates, its step bias, its
     output norm's scale, its convolution's taps and (kda) its output
     gate's bias, of a Mamba-2 layer its decay rates, step bias, skip
-    ``ssm_D``, gated norm's scale and convolution's bias, and a
+    ``ssm_D``, gated norm's scale and convolution's bias, a looped
+    stack's exit gate (``ut_gate_w``, ``ut_gate_b``), and a
     hyper-connection's ``alpha`` and bias (decayed,
     its maps would leave the identity they start from)."""
     return jax.tree_util.tree_map_with_path(
         lambda path, _: getattr(path[-1], "key", None) not in (
             "expert_bias", "ln1_post", "ln2_post", "A_log", "dt_bias",
-            "o_norm", "delta_conv", "b_g", "ssm_D", "ssm_norm")
+            "o_norm", "delta_conv", "b_g", "ssm_D", "ssm_norm",
+            "ut_gate_w", "ut_gate_b")
         and not str(getattr(path[-1], "key", "")).endswith(
             ("_alpha", "_bias")), params)
 
@@ -2743,7 +2882,14 @@ def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
 
     moe = not all(kind.dense for kind in cfg.kinds)   # a layer has experts
     wide = bool(cfg.hyper_streams or cfg.mtp_modules
-                or cfg.delta_gate_floor or "m" in cfg.layer_pattern)
+                or cfg.delta_gate_floor or "m" in cfg.layer_pattern
+                or cfg.ut_steps > 1)
+    if cfg.ut_steps > 1 and (xent_chunk or pipeline_microbatches):
+        raise ValueError(
+            "ut_steps=%d: a looped stack's loss is ops/head_loss.py's a "
+            "token, weighed by the exit distribution; xent_chunk (%d) and "
+            "a pipelined forward (pipeline_microbatches=%d) have none"
+            % (cfg.ut_steps, xent_chunk, pipeline_microbatches))
     if cfg.mtp_modules and (xent_chunk or pipelined):
         raise ValueError(
             "mtp_modules=%d: the modules' loss is ops/head_loss.py's at a "
@@ -2785,6 +2931,8 @@ def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
     def loss_fn(outputs, tokens):
         if not isinstance(outputs, dict):
             return next_token_loss(outputs, tokens)
+        if cfg.ut_steps > 1:
+            return looped_loss(outputs, tokens, cfg)
         if xent_chunk:
             loss = next_token_loss_chunked(
                 outputs["params"], outputs["hidden"], tokens, cfg,
@@ -2804,7 +2952,7 @@ def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
         return loss
 
     def step_stats(outputs):
-        stats = {}
+        stats = dict(outputs.get("ut", ()))
         if outputs.get("hc_err") is not None:
             stats["hc_err"] = outputs["hc_err"]
         if "mtp_loss" in outputs:
@@ -2842,7 +2990,7 @@ def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
              if warmup_steps else learning_rate), weight_decay=0.01,
             mask=(_decayed if cfg.moe_router == "sigmoid_bias"
                   or cfg.post_norms or set("dm") & set(cfg.layer_pattern)
-                  or cfg.hyper_streams else None)),
+                  or cfg.hyper_streams or cfg.ut_steps > 1 else None)),
         feed=feed,
         eval_metrics_fn=lambda: {
             "nll": metrics.Mean(lambda outputs, labels: outputs)

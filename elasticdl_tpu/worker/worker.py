@@ -70,17 +70,26 @@ def _loss_fields(stats):
     `` chunk_keep=`` the share of a Mamba-2 layer's state that outlives
     a chunk of 128 tokens, the mean over the step's layers, heads and
     chunks (``models/transformer.py``: ``mtp_modules``,
-    ``hyper_streams``, ``delta_gate_floor``, an "m" layer); nothing for
-    a model with none of them.
+    ``hyper_streams``, ``delta_gate_floor``, an "m" layer); of a looped
+    stack (``ut_steps`` > 1) `` ut_loss=`` each turn's mean cross
+    entropy, `` exit=`` the mean exit distribution, both one number a
+    turn with ``/`` between, and `` exit_entropy=`` that distribution's
+    mean entropy a token in nats; nothing for a model with none of
+    them.
     Fetched after the loss: the same program made them."""
     stats = stats or {}
+    turns = lambda key: "/".join("%.6f" % float(v) for v in stats[key])
     return "".join(
         " %s=%s" % (name, form % float(stats[key]))
         for name, key, form in (("mtp", "mtp_loss", "%.6f"),
                                 ("hc_err", "hc_err", "%.3e"),
                                 ("g_excess", "kda_gate_excess", "%.3e"),
                                 ("chunk_keep", "ssm_chunk_keep", "%.6f"))
-        if key in stats)
+        if key in stats) + (
+            " ut_loss=%s exit=%s exit_entropy=%.6f" % (
+                turns("ut_loss"), turns("ut_exit"),
+                float(stats["ut_exit_entropy"]))
+            if "ut_loss" in stats else "")
 
 
 class PreemptedExit(Exception):

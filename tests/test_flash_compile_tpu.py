@@ -27,7 +27,8 @@ from elasticdl_tpu.ops import hyper_mix as hm
 from elasticdl_tpu.ops import row_moves
 
 from tests.tpu_compile import (  # noqa: F401 (one_chip: a fixture)
-    _entry_ops, _model_params, _mosaic_calls, _moved_bytes, _names, one_chip)
+    V5E_LIMIT, _cell_step, _entry_ops, _model_params, _mosaic_calls,
+    _moved_bytes, _names, _products, one_chip)
 
 
 @pytest.mark.parametrize("t,d,dtype,window", [
@@ -971,3 +972,37 @@ def test_the_state_space_hybrids_parameters_are_the_configurations_count():
     assert not names & {"w_gate", "ws_gate"}
     assert count(period["1"]["w_up"]) + count(
         period["1"]["w_down"]) == 8 * 9977856
+
+
+# six layers of ``ouro-2.6b``; a layer more or fewer
+OURO_PARAMETERS, OURO_LAYER = 509661185, 51388416
+
+
+def test_the_looped_stacks_step_compiles_with_its_turns_as_one_loop(one_chip):
+    """``ouro-2.6b.seq8192``'s whole training step for the described chip
+    with ``remat_keep``'s list kept: the parameters are the
+    configuration's count (a layer's 51,388,416 a layer, the untied
+    49,152-id vocabulary, the final norm and the gate's 2,049), the
+    flash calls carry the plain names the accepted reader takes, one
+    call each way stands in the layers' loop however many turns run
+    it, the heads are four calls of ``[8192, 2048] x [2048, 49152]``,
+    and the compiler's count of the step lies over what ``remat_keep``
+    predicts by no more than what it counts twice (PERF.md section 6,
+    PR 66: a kept entry and the stacked gradient, which the chip holds
+    once and twice)."""
+    step = _cell_step(one_chip, "ouro-2.6b", 1, 8192, True)
+    cfg = step.spec.config
+    count = sum(a.size for a in jax.tree_util.tree_leaves(step.params))
+    assert count == OURO_PARAMETERS + (cfg.num_layers - 6) * OURO_LAYER
+    assert (cfg.ut_steps, cfg.dim, cfg.num_heads, cfg.head_dim,
+            cfg.mlp_dim, cfg.vocab_size) == (4, 2048, 16, 128, 5632, 49152)
+    names, kept, budget, predicted = step.chosen
+    assert names[:3] == ("flash_out", "flash_lse", "head_logits")
+    assert 0 <= kept <= budget and predicted <= 0.95 * V5E_LIMIT
+    text = step.compiled.as_text()
+    assert _names(text) == {"flash_fwd": 1, "flash_bwd": 1, "embed_grad": 1}
+    # the four heads' forward (their logits kept, none is made again),
+    # and each call's weight gradient in the compute dtype
+    assert _products(text, "bf16[8192,49152]") == 4
+    assert _products(text, "bf16[2048,49152]") == 4
+    assert 0 < step.counted - predicted < 2.0e9 + kept

@@ -97,6 +97,8 @@ AS_TYPED = {
     "conv_bias": ("true", True, "num_layers=2;layer_pattern=ma;ssm_heads=2;"
                   "ssm_head_dim=8;ssm_state=8"),
     "mixer_ffn": ("false", False, "num_layers=2;layer_pattern=ae"),
+    "ut_steps": ("4", 4, ""),
+    "ut_entropy_weight": ("0.05", 0.05, "ut_steps=2"),
 }
 FIELDS = [field for field in dataclasses.fields(tfm.TransformerConfig)
           if field.name != "max_seq_len"]

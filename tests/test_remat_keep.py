@@ -359,6 +359,18 @@ CELLS = {
         ROUTED[:7] + (rk.KEEP_SSM_DECAY, rk.KEEP_SSM_GATE,
                       rk.KEEP_SHARED_UP, ssd.KEEP_OUT, ssd.KEEP_STATES,
                       rk.KEEP_SSM_IN, md.KEEP_UP, md.KEEP_OUT)),
+    # six dense layers run four times on one set of weights, scanned
+    # inside a scan over the turns, one sequence of 8,192: a state of
+    # 8.15 GB, 37 planes of 33.5 MB, the stacked gradient twice and one
+    # call's logits leave room for the flash residuals, the three
+    # earlier calls' logits (2.42 GB, gone before the stack's backward)
+    # and q, k, v (5.65 GB kept; the head's place is the peak: the
+    # stream's 0.81 GB more would pass the reserve there, and ran 11%
+    # slower on the chip; my chip runs, PR 66, ``probe2``: 15.320 GB,
+    # and 11.443 with nothing kept where the estimate reads 11.615)
+    "ouro-2.6b.seq8192": (
+        "ouro-2.6b", 1, 1, 15.320, ["flash", "logits", "qkv"],
+        rk.ATTN_NAMES + (rk.KEEP_LOGITS, rk.KEEP_Q, rk.KEEP_K, rk.KEEP_V)),
 }
 
 # The cells whose unrolled stack has expert layers: their estimate, the
@@ -379,7 +391,8 @@ ROWS_OF = {"olmo1b": 16384, "olmoe1b7b": 16384, "lfm2-24b-a2b": 32768,
            "smallthinker-21b-a3b": 16384, "kanana-2-30b-a3b": 16384,
            "trinity-mini": 16384, "olmo-hybrid-7b": 16384,
            "solar-open2-250b": 16384, "xing4.0-29b-a4b": 8192,
-           "ling-3.0-flash": 16384, "nemotron-3-nano-30b-a3b": 16384}
+           "ling-3.0-flash": 16384, "nemotron-3-nano-30b-a3b": 16384,
+           "ouro-2.6b": 8192}
 
 
 def _cell(config, **override):
@@ -921,6 +934,55 @@ def test_the_line_says_what_of_the_gradients_stands(pattern, standing):
         "whole": sum(each), "a layer's": max(each), "none": 0}[standing]
     assert int(fields["predicted_peak"]) == (
         100 * GB - free + int(fields["need"]) + int(fields["bytes"]))
+
+
+def test_a_looped_stack_counts_a_layer_a_turn_and_says_its_turns():
+    """``ut_steps`` = R: every entry a layer makes is made R times (the
+    turns' scan stacks what the layers' scan keeps), the R - 1 earlier
+    heads' logits are an entry, tried right behind the flash kernel's,
+    that is gone where a layer's backward is the peak, the stacked
+    weights' gradient stands twice there, and the ``remat keep:`` line
+    ends ``turns=R``."""
+    rk.announce_keep.cache_clear()
+    build = lambda turns: tfm.model_spec(
+        vocab_size=96, dim=128, num_heads=2, seq_len=128, ffn_dim=256,
+        dtype="float32", remat=True, num_layers=2, tied_embeddings=False,
+        ut_steps=turns)
+    once, looped = build(1).config, build(3).config
+    params = jax.eval_shape(build(3).init_fn, jax.random.PRNGKey(0))
+    plain = {k: v for k, v in params.items() if "gate" not in k}
+    rows = 256
+    one = {label: (nbytes, layers)
+           for label, _, nbytes, layers in rk._entries(once, rows)}
+    three = {label: (nbytes, layers)
+             for label, _, nbytes, layers in rk._entries(looped, rows)}
+    assert set(three) - set(one) == {"logits"}
+    assert list(three)[:3] == ["flash", "logits", "qkv"]
+    assert three["logits"] == (rows * 96 * 4, 2)
+    assert all(three[label] == (nbytes, 3 * layers)
+               for label, (nbytes, layers) in one.items())
+    stack = ct._device_bytes(params["layers"])
+    assert rk.grads_standing(looped, params, rows) == 2 * stack
+    assert rk.grads_standing(once, plain, rows) == stack
+    need = lambda *kept: rk.step_bytes(looped, params, rows, kept)
+    stream = rows * 128 * 4
+    # a carry a layer a turn more, three [R, rows, dim] planes, the
+    # gate's float32 [R - 1, rows, dim], and the stack's gradient once
+    # more where a layer's backward is the peak
+    assert need() - rk.step_bytes(once, plain, rows) == (
+        (2 * 2 + 3 * 3) * stream + 2 * rows * 128 * 4 + stack)
+    # that place is the peak here: kept logits leave it whole
+    assert need() - need("logits") == 2 * three["logits"][0]
+    with batch_axis(None, "data", DeviceRoom(100 * GB, 99 * GB)):
+        line, = _lines(lambda: rk.names_for(looped, params, (2, 128)))
+    fields = _fields(line)
+    assert fields["turns"] == "3" and fields["layers"] == "2"
+    assert fields["names"].split(",")[:3] == ["flash_out", "flash_lse",
+                                              "head_logits"]
+    assert int(fields["grads_standing"]) == 2 * stack
+    with batch_axis(None, "data", DeviceRoom(100 * GB, 99 * GB)):
+        said, = _lines(lambda: rk.names_for(once, plain, (2, 128)))
+    assert "turns=" not in said
 
 
 @pytest.mark.parametrize("experts", [0, 4])
