@@ -978,9 +978,12 @@ def _cases(tiny):
                lambda gb=gb, gh=gh, gg=gg, gt=gt, gd=gd, gw=gw: check_flash(
                    gb, gh, gt, gd, gw, interpret,
                    ref_slice=(1, gh // gg), kv_heads=gg))
-    # The two share cells' row moves (tokens x width, choices, bound).
-    for n, w, k, bound in ((96, 256, 4, 128),) if tiny else (
-            (32768, 2048, 4, 32768), (16384, 2560, 6, 49152)):
+    # Three share cells' row moves (tokens x width, choices, bound): the
+    # last a 16-bit row of 21 lane tiles, moved as 1,408 words (tiny: 3
+    # tiles as 256).
+    for n, w, k, bound in ((96, 256, 4, 128), (96, 384, 6, 128)) if tiny else (
+            (32768, 2048, 4, 32768), (16384, 2560, 6, 49152),
+            (16384, 2688, 6, 12288)):
         yield ("row_moves/N%d.W%d.K%d.C%d" % (n, w, k, bound),
                lambda n=n, w=w, k=k, bound=bound: check_row_moves(
                    n, w, k, bound, bound * 9 // 16, interpret))

@@ -496,9 +496,13 @@ def dispatch_phases(cfg, rows, kept=()):
        [R, f], a block's ``dx`` [n, e];
      - the [R, 1, 1, words] copies ``ops/row_moves.py`` packs a moved
        array into: of ``g`` (a float32 row its own words) and of
-       ``d_xs``; where a share's rows are too odd a width for the row
-       kernel (``moe_dispatch.rows_by_kernel`` on a chip: ``moe
-       dispatch: .. rows=reference``) the jnp moves' buffers instead:
+       ``d_xs`` (counted at the row's own bytes: a 16-bit row of an odd
+       number of lane tiles is packed a tile wider, 1,408 words for
+       2,688 columns, 4.8%); where a share's rows are no whole number
+       of lanes, or too wide for the row kernel's slots
+       (``moe_dispatch.rows_by_kernel`` on a chip: ``moe dispatch: ..
+       rows=reference``; no cell of the benchmark since PR 67), the jnp
+       moves' buffers instead:
        the float32 [R, e] rows that ``g`` is gathered into
        (``_rows_to_tokens_bwd``), and for ``dx`` the float32 copy of
        ``d_xs`` and the float32 [n, e] sum it is scattered into

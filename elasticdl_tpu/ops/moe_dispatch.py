@@ -18,8 +18,10 @@ so nothing is dropped and the work follows the data.  Where a kernel
 may run, a share's rows move by ``ops/row_moves.py``: rows gathered for
 the held rows alone, and a token's rows summed in token order, with no
 float32 buffer of ``row_bound`` rows and no scatter; the jnp row moves
-below are the reference (``rows=kernel`` | ``rows=reference`` on the
-``moe dispatch:`` line).
+below are the reference, and what a chip runs only where a row is no
+whole number of 128 lanes or too wide for the kernel's slots
+(``rows=kernel`` | ``rows=reference`` on the ``moe dispatch:`` line;
+every share cell of the benchmark reads ``rows=kernel``).
 """
 
 import functools
@@ -420,7 +422,8 @@ def announce_dispatch(tokens, experts, top_k, kernel, share=None,
     (``row_bound``: the rows of one block) where the weights are a
     ``share`` (held, bound) of the experts, and which row moves stand
     round them: ``rows=kernel`` (``ops/row_moves.py``) or
-    ``rows=reference``."""
+    ``rows=reference`` (the jnp moves: ``rows_by_kernel`` said no, and
+    ``row_moves.unfriendly`` why)."""
     rows = tokens * top_k
     held, bound = share or (experts, rows)
     tile = gm.row_tile(bound)

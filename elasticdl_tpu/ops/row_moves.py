@@ -31,11 +31,16 @@ Mosaic moves whole tiles of the chip's tiled layout and nothing smaller:
 one row of a ``[R, w]`` array is an eighth of a tile (a sixteenth at 16
 bits) and cannot be the source of a DMA.  So ``src`` is handed over as
 ``[R, 1, 1, words]`` 32-bit words, whose tile is a row's 128 lanes
-(``words``): a float32 row as it is, a 16-bit row with column c in the
-low half of word c and column c + w / 2 in the high half, so both
-halves come apart again on whole lanes.  ``as_words`` makes that form
-in one pass of a kernel of its own (``rows_pack``), over the rows that
-will be read alone.
+(``words``): a float32 row as it is, a 16-bit row of w columns as W =
+128 * ceil(w / 256) words (``row_words``), column c < W in the low half
+of word c and column c + W < w in the high half, the high halves past
+them zero, so both halves come apart again on whole lanes.  Where w / 2
+is whole lanes W is w / 2 and every word holds two columns; 2,688
+columns (21 lane tiles) are 1,408 words, 11 tiles, the last of which
+holds columns 1,280 .. 1,407 alone.  A row's DMA moves whole tiles
+either way, and nothing outside this module sees the pad.  ``as_words``
+makes that form in one pass of a kernel of its own (``rows_pack``), over
+the rows that will be read alone.
 
 Every call carries its ``name`` into the compiled program and a device
 trace (``rows_gather``: k = 1, ``rows_sum``: k > 1, ``rows_pack``;
@@ -62,9 +67,19 @@ from elasticdl_tpu.ops.mode import resolve
 # A tile's indices are a 1-D int32 block in SMEM, and such a block is a
 # whole number of the 1024 words XLA tiles a 1-D int32 array by.
 INDEX_BLOCK = 1024
-# What the [k * tm, 1, words] buffer may take of VMEM, and the scoped
-# VMEM asked of Mosaic for it, the pipelined blocks and the sums.
-_VMEM_SLOTS = 16 * 1024 * 1024
+# What the [k * tm, 1, words] buffer may take of VMEM: six slots a result
+# row of 512 (``row_tile``) at twelve lane tiles of words, 18 MiB, so
+# the widest row a share cell sums six a token fits (2,688 x bfloat16,
+# eleven tiles: 17.3 MB).  Until PR 67 it was 16 MiB, a third of
+# VMEM_LIMIT; what bounds it now is the chip's VMEM, 128 MiB on a v5e:
+# ``_call`` asks Mosaic for what it reckons (the pipelined blocks, the
+# slots, _VMEM_ROOM), under 50 MB in that row's four moves and 67 MB in
+# the widest any cell makes (4,096 float32 words one a result row).
+_VMEM_SLOTS = 18 * 1024 * 1024
+# What a call asks for beside its blocks and slots, for the sums and the
+# narrow blocks.
+_VMEM_ROOM = 16 * 1024 * 1024
+# The least scoped VMEM a call of the module asks of Mosaic.
 VMEM_LIMIT = 48 * 1024 * 1024
 # Result rows the vector units sum at a time: a 16-bit tile's height.
 SUB_ROWS = 16
@@ -89,6 +104,16 @@ def row_sum_ref(src, idx, weight=None, other=None, out_dtype=None):
     return out, dots
 
 
+def row_words(width, dtype):
+    """32-bit words of a row of ``width`` x ``dtype`` as the kernels
+    move it (the module's docstring): a 32-bit row's own; a 16-bit
+    row's halves, the low one padded to whole 128-lane tiles where the
+    row is whole lanes itself (on a chip it is: ``unfriendly``)."""
+    if jnp.dtype(dtype).itemsize == 4:
+        return width
+    return width // 2 if width % 128 else -(-width // 256) * 128
+
+
 def row_tile(k, words):
     """Result rows of one grid step: the fewest whose k indices each are
     whole INDEX_BLOCKs, or None where their ``[k * tm, words]`` slots
@@ -100,17 +125,22 @@ def row_tile(k, words):
 
 def _pack_kernel(last, src_ref, out_ref, *, halves):
     words = out_ref.shape[-1]
+    # a 16-bit row's words that hold two columns: all but the pad's
+    both = src_ref.shape[-1] - words
 
     def pack(s, carry):
         at = pl.multiple_of(s * SUB_ROWS, SUB_ROWS)
-        rows = src_ref[pl.ds(at, SUB_ROWS), :]
-        if halves:
-            bits = pltpu.bitcast(rows.astype(jnp.float32), jnp.uint32)
-            rows = (bits[:, :words] >> 16) | (
+        here = pl.ds(at, SUB_ROWS)
+        rows = src_ref[here, :]
+        if not halves:
+            out_ref[here, 0, 0, :] = pltpu.bitcast(rows, jnp.uint32)
+            return carry
+        bits = pltpu.bitcast(rows.astype(jnp.float32), jnp.uint32)
+        if both:
+            out_ref[here, 0, 0, :both] = (bits[:, :both] >> 16) | (
                 bits[:, words:] & jnp.uint32(0xFFFF0000))
-        else:
-            rows = pltpu.bitcast(rows, jnp.uint32)
-        out_ref[pl.ds(at, SUB_ROWS), 0, 0, :] = rows
+        if both < words:
+            out_ref[here, 0, 0, both:] = bits[:, both:words] >> 16
         return carry
 
     @pl.when(pl.program_id(0) <= last[0])
@@ -129,7 +159,7 @@ def as_words(src, live=None, interpret=None):
     undefined."""
     rows, width = src.shape
     halves = src.dtype.itemsize == 2
-    words = width // 2 if halves else width
+    words = row_words(width, src.dtype)
     tm = min(PACK_TILE, -(-rows // SUB_ROWS) * SUB_ROWS)
     tiles = -(-rows // tm)
     if tiles * tm != rows:
@@ -167,7 +197,9 @@ def _kernel(counts, moves, idx_v, *refs, k, tm, halves, rows, bits,
     src_ref, out_ref = refs.pop(0), refs.pop(0)
     dots_ref = refs.pop(0) if dotted else None
     buf, sem = refs
-    words = buf.shape[-1]
+    words, width = buf.shape[-1], out_ref.shape[-1]
+    # the columns of the float32 planes a row's words come apart into
+    spans = ((0, words), (words, width))[:1 + (width > words)]
     count = counts[pl.program_id(0)]
 
     @pl.when(count == 0)
@@ -196,8 +228,11 @@ def _kernel(counts, moves, idx_v, *refs, k, tm, halves, rows, bits,
     def floats(bits):    # the float32 columns of [.., words] uint32
         if not halves:
             return (pltpu.bitcast(bits, jnp.float32),)
-        return (pltpu.bitcast(bits << 16, jnp.float32),
-                pltpu.bitcast(bits & jnp.uint32(0xFFFF0000), jnp.float32))
+        low = pltpu.bitcast(bits << 16, jnp.float32)
+        if width == words:    # one lane tile of columns: no high halves
+            return (low,)
+        return (low, pltpu.bitcast(
+            bits[:, :width - words] & jnp.uint32(0xFFFF0000), jnp.float32))
 
     def sum_rows(s, carry):
         at = pl.multiple_of(s * SUB_ROWS, SUB_ROWS)
@@ -215,14 +250,13 @@ def _kernel(counts, moves, idx_v, *refs, k, tm, halves, rows, bits,
                 terms = tuple(t * weight_ref[here, j:j + 1] for t in terms)
             acc = terms if acc is None else tuple(
                 a + t for a, t in zip(acc, terms))
-        for c, part in enumerate(acc):
-            out_ref[here, c * words:(c + 1) * words] = part.astype(
-                out_ref.dtype)
+        for (lo, hi), part in zip(spans, acc):
+            out_ref[here, lo:hi] = part.astype(out_ref.dtype)
         if dotted:    # a row with no source has no dot, whatever other holds
             dots_ref[here, :] = jnp.where(keep[:, :1] != 0, sum(
-                (other_ref[here, c * words:(c + 1) * words].astype(
-                    jnp.float32) * part).sum(axis=1, keepdims=True)
-                for c, part in enumerate(first)), 0.0)
+                (other_ref[here, lo:hi].astype(jnp.float32) * part).sum(
+                    axis=1, keepdims=True)
+                for (lo, hi), part in zip(spans, first)), 0.0)
         return carry
 
     @pl.when(count > 0)
@@ -249,10 +283,18 @@ def _moves(idx, rows, tiles, tm, bits):
             moves.reshape(tiles * tm * k))
 
 
-def _call(words, halves, rows, idx, weight, other, out_dtype, interpret,
-          name, tm):
-    m, k = idx.shape
-    width = words.shape[-1] * (2 if halves else 1)
+@functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
+def _call(src, idx, weight, other, live, *, out_dtype, interpret):
+    """``row_sum`` by its two kernels.  Jitted, so that a step traces
+    and lowers them once a shape and not once a call, a layer and a
+    forward (a worker lowers its step at every start: four expert
+    layers' moves by the kernel added 4.9 s to a 50 s start without it,
+    my chip runs, PR 67)."""
+    (rows, width), (m, k) = src.shape, idx.shape
+    halves = src.dtype.itemsize == 2
+    words = as_words(src, live, interpret)
+    idx = idx.astype(jnp.int32)
+    tm = row_tile(k, words.shape[-1])
     tiles = -(-m // tm)
     pad = tiles * tm - m
     if pad:
@@ -280,13 +322,13 @@ def _call(words, halves, rows, idx, weight, other, out_dtype, interpret,
         out_specs.append(tile(1))
     # What Mosaic is asked for: the pipelined [tm, width] blocks
     # (``other`` in, the result out, two buffers each), the slots, and
-    # the slots' budget once more for the sums and the narrow blocks.
+    # _VMEM_ROOM for the sums and the narrow blocks.
     # Over VMEM_LIMIT only at rows wider than the cells of hidden size
     # 2,560 and less have (4,096: 33.5 MB of blocks; the call wanted
     # 48.04 MB of a 48 MiB scope).
     blocks = 2 * tm * width * (jnp.dtype(out_dtype).itemsize + (
         other.dtype.itemsize if other is not None else 0))
-    scoped = blocks + 4 * k * tm * words.shape[-1] + _VMEM_SLOTS
+    scoped = blocks + 4 * k * tm * words.shape[-1] + _VMEM_ROOM
     out = pl.pallas_call(
         functools.partial(
             _kernel, k=k, tm=tm, halves=halves, rows=rows, bits=bits,
@@ -308,7 +350,7 @@ def _call(words, halves, rows, idx, weight, other, out_dtype, interpret,
         interpret=interpret,
         # The HLO instruction's name, so the trace's: the benchmark
         # tells the calls apart by it (benchmark/layers/).
-        name=name,
+        name="rows_gather" if k == 1 else "rows_sum",
     )(counts, *operands)
     return out[0][:m], (out[1][:m, 0] if other is not None else None)
 
@@ -319,10 +361,11 @@ def unfriendly(width, dtype, k, mode, rows=1):
     bits = 8 * jnp.dtype(dtype).itemsize
     if bits not in (16, 32) or width * bits % 32:
         return "rows of %d x %s are no whole 32-bit words" % (width, dtype)
-    words = width * bits // 32
-    if mode == "tpu" and words % 128:
-        return "rows of %d x %s are no whole 128 lanes of words" % (
-            width, dtype)
+    # a 16-bit row's two halves are sliced apart, and written, at its
+    # word count (``row_words``): the row itself has to be whole lanes
+    if mode == "tpu" and width % 128:
+        return "rows of %d x %s are no whole 128 lanes" % (width, dtype)
+    words = row_words(width, dtype)
     tm = row_tile(k, words)
     if tm is None:
         return "%d rows of %d words a result row do not fit %d MiB" % (
@@ -351,9 +394,5 @@ def row_sum(src, idx, weight=None, other=None, live=None, out_dtype=None,
     if why:
         announce_fallback("row_sum", src.shape + idx.shape, why, mode)
         return row_sum_ref(src, idx, weight, other, out_dtype)
-    return _call(as_words(src, live, mode == "interpret"),
-                 src.dtype.itemsize == 2, rows,
-                 idx.astype(jnp.int32), weight, other, out_dtype,
-                 mode == "interpret",
-                 "rows_gather" if k == 1 else "rows_sum",
-                 row_tile(k, src.shape[1] * src.dtype.itemsize // 4))
+    return _call(src, idx, weight, other, live, out_dtype=out_dtype,
+                 interpret=mode == "interpret")

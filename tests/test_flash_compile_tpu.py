@@ -207,12 +207,13 @@ def test_grouped_matmul_over_a_share_compiles_for_v5e(one_chip):
         assert len([c for c in calls if name in c]) == 1, (name, calls)
 
 
-# -- a held share's row moves at both share cells' shapes --
+# -- a held share's row moves at three share cells' shapes --
 
 
 @pytest.mark.parametrize("n,w,k,bound", [
     (32768, 2048, 4, 32768),      # lfm2-24b-a2b.seq8192
     (16384, 2560, 6, 49152),      # smallthinker-21b-a3b.seq16384
+    (16384, 2688, 6, 12288),      # nemotron-3-nano-30b-a3b.seq16384
 ])
 def test_the_row_kernel_compiles_for_v5e(one_chip, n, w, k, bound):
     """The four row moves of a share's block (``ops/row_moves.py``: a
@@ -221,7 +222,11 @@ def test_the_row_kernel_compiles_for_v5e(one_chip, n, w, k, bound):
     pullback) through Mosaic: the one-row DMAs from ``[R, 1, words]``,
     the SMEM index blocks, the VMEM the slots take.  Each move is a
     ``rows_pack`` and one named call, and no XLA op makes a float32
-    array of the buffer's rows."""
+    array of the buffer's rows.  The third shape's 16-bit row is 21
+    lane tiles, an odd number: its words are 11 tiles, the low halves'
+    last padded, and every slice of both kernels still starts and ends
+    on a tile (3,072 slots of 1,408 words: 17.3 MB of an 18 MiB
+    budget)."""
     shape = lambda rows, cols, dtype: jax.ShapeDtypeStruct(
         (rows, cols), dtype, sharding=one_chip)
     x, y = shape(n, w, jnp.bfloat16), shape(bound, w, jnp.bfloat16)

@@ -19,7 +19,9 @@ sort's inverse over the held experts) beside a second ``argsort``.
 Shapes: ``lfm2`` 32,768 tokens x 2,048, 4 a token, a bound of 32,768
 rows; ``smallthinker`` 16,384 x 2,560, 6 a token, 49,152; ``olmoe``
 16,384 x 2,048, 8 a token, all 131,072 rows live (what the all-experts
-dispatch would hand the kernel).  Exits 3 without a TPU: a CPU timing is
+dispatch would hand the kernel); ``nemotron3`` (by name alone) 16,384 x
+2,688, 6 a token, 12,288: a 16-bit row of 21 lane tiles, moved as 1,408
+words.  Exits 3 without a TPU: a CPU timing is
 no device number.
 """
 
@@ -39,6 +41,7 @@ SHAPES = {
     "lfm2": (32768, 2048, 4, 32768, 64, 8),
     "smallthinker": (16384, 2560, 6, 49152, 64, 16),
     "olmoe": (16384, 2048, 8, 131072, 64, 64),
+    "nemotron3": (16384, 2688, 6, 12288, 128, 8),
     # a rehearsal's: the one shape a run without a TPU may take
     "tiny": (256, 256, 4, 512, 8, 2),
 }
