@@ -6,6 +6,7 @@ reference of the benchmark (benchmark/reference/smallthinker-21b-a3b.py).
 Float32 on the CPU at tiny widths."""
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +18,7 @@ from elasticdl_tpu.models import remat_keep as rk
 from elasticdl_tpu.models import transformer as tfm
 from elasticdl_tpu.ops import flash_attention as fa
 from elasticdl_tpu.ops import moe_dispatch as md
+from tests import reference_check as rc
 
 REF = manifest.load_named("reference", "smallthinker-21b-a3b")
 
@@ -42,9 +44,7 @@ def _shape(cfg, **over):
         first=cfg.experts_held[0]), **over)
 
 
-def _loss(spec, tokens):
-    return lambda p: spec.loss_fn(spec.apply_fn(p, tokens, True),
-                                  tokens).mean()
+_loss, _apart = rc.loss_of, rc.apart
 
 
 def _tokens(spec, batch=2, seed=1):
@@ -57,15 +57,6 @@ def _params(spec, seed=3):
     params = jax.jit(spec.init_fn)(jax.random.PRNGKey(seed))
     params["lm_head"] = params["lm_head"] * 5.0
     return params
-
-
-def _apart(got, want):
-    """The distance of two trees over the second's norm."""
-    leaves = jax.tree_util.tree_leaves
-    norm = lambda trees: float(jnp.sqrt(sum(
-        jnp.sum(jnp.square(t)) for t in trees)))
-    return norm([g - w for g, w in zip(leaves(got), leaves(want))]) / norm(
-        leaves(want))
 
 
 # -- the plan ---------------------------------------------------------------
@@ -491,3 +482,93 @@ def test_remat_keeps_table_counts_the_share_at_six_a_token():
     assert entries["moe_out"] == (bound * 2560 * 2, 4)
     assert entries["moe_gate"] == (bound * 768 * 2, 4)
     assert "ffn_gate" not in entries and "conv_in" not in entries
+
+
+# -- the cell's whole step for a described v5e: last in the file, since
+# ``one_chip`` turns XLA's optimisations on for its module (ROADMAP C16)
+
+from tests.tpu_compile import (  # noqa: E402,F401 (the fixtures)
+    V5E_LIMIT, _inventory_is_held, cell_steps, one_chip)
+
+
+@pytest.fixture(scope="module")
+def banded_step(cell_steps):
+    """The ``smallthinker-21b-a3b.seq16384`` cell's whole training step
+    compiled once for the tests that read it (a minute): what
+    ``remat_keep`` chose, and the compiled program."""
+    step = cell_steps("smallthinker-21b-a3b", 1, 16384, True)
+    nbytes = lambda tree: sum(
+        a.size * a.dtype.itemsize for a in jax.tree_util.tree_leaves(tree))
+    assert nbytes(step.params) == 4 * 656529920      # 656.5 M parameters
+    return V5E_LIMIT, step.chosen, step.compiled
+
+
+def test_the_banded_stacks_step_fits_a_v5e_as_remat_keep_predicts(
+        banded_step):
+    """The ``smallthinker-21b-a3b.seq16384`` cell's whole training step
+    (one sequence of 16,384 through a full-NoPE and three windowed-RoPE
+    attention layers, heads x head size 3,584 over a hidden 2,560, 16 of
+    64 ReGLU experts at 6 a token, an untied head over 37,984 ids,
+    AdamW) through the TPU's compiler with what ``remat_keep`` chose
+    kept (every entry of its table since the step's need counts a
+    layer's kept products once and an unrolled stack's weight copies
+    two layers at a time: the sorted rows too, 4.06 GB in all): its
+    predicted peak is over the compiler's own byte count, never
+    under, and under the device's limit less the reserve (15.45 GB
+    against the compiler's 15.28; PR 35's eleven names read 15.69
+    against 14.39).  Both kinds of flash call are in the one program,
+    and no forward runs twice."""
+    from elasticdl_tpu.models import remat_keep as rk
+    from elasticdl_tpu.ops import moe_dispatch
+
+    limit, (names, kept, budget, peak), compiled = banded_step
+    assert set(names) >= set(rk.ATTN_NAMES) | {
+        rk.KEEP_Q, rk.KEEP_K, rk.KEEP_V, rk.KEEP_STREAM,
+        moe_dispatch.KEEP_ROWS}, names
+    assert kept <= budget and peak <= (1 - rk.RESERVE) * limit
+
+    stats = compiled.memory_analysis()
+    counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
+    assert counted < peak and peak - counted < 0.5e9, (peak, counted)
+    calls = [l.split(" = ")[0].strip().lstrip("%")
+             for l in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l]
+    count = lambda name: len([c for c in calls if re.search(
+        name + r"(__)?\.\d+$|" + name + "$", c)])
+    assert (count("flash_fwd"), count("flash_bwd")) == (1, 1), calls
+    assert (count("flash_fwd_w4096"), count("flash_bwd_w4096")) == (3, 3), \
+        calls
+    assert not [c for c in calls if "flash_dq" in c or "flash_dkv" in c]
+
+
+def test_the_banded_stacks_step_scatters_no_row_into_the_table(
+        banded_step):
+    """The same compiled step: the embedding table's gradient is the one
+    float32 ``[37984, 2560]`` result of the ``embed_grad`` call
+    (``ops/embed_rows.py``: the lookup's own derivative), where JAX's
+    derivative of the lookup left XLA a scatter of bfloat16 rows into
+    ``bf16[37984,2560]`` and a convert pass, 15-17 ms of the cell's
+    step on the chip (PERF.md section 6, PR 53).  The compiler's
+    arguments + temporaries are the parent's 15,224,888,320 within what
+    buffer assignment moved them by (15,225,532,416, +0.6 MB: the
+    table's gradient stands where the step's peak is not)."""
+    _, _, compiled = banded_step
+    text = compiled.as_text()
+    assert not re.findall(r" = \w+\[37984,2560\]\S* scatter\(", text)
+    calls = [l for l in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l
+             and "embed_grad" in l.split(" = ")[0]]
+    assert len(calls) == 1 and " = f32[37984,2560]{" in calls[0], calls
+    stats = compiled.memory_analysis()
+    counted = stats.argument_size_in_bytes + stats.temp_size_in_bytes
+    assert counted <= 15224888320 + 2 ** 20, counted
+
+
+@pytest.mark.parametrize("config,batch,rows,keep", [
+    ("smallthinker-21b-a3b", 1, 16384, True)])
+def test_the_expert_layers_inventory_is_held_to_the_compilers_count(
+        cell_steps, config, batch, rows, keep):
+    """``tests/test_step_compile_tpu.py``'s test of the same name for
+    this cell: +0.16 GB with ``choose``'s
+    list kept."""
+    _inventory_is_held(cell_steps, config, batch, rows, keep)

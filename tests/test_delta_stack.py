@@ -7,8 +7,6 @@ by token), at the configuration's rehearsal size, float32 on the CPU."""
 
 import collections
 import functools
-import json
-import os
 
 import jax
 import jax.numpy as jnp
@@ -16,79 +14,36 @@ import numpy as np
 import pytest
 
 from benchmark.lib import manifest
-from benchmark.lib.runner import merge, params_string
+from benchmark.lib.runner import params_string
 from elasticdl_tpu.models import remat_keep as rk
 from elasticdl_tpu.models import transformer as tfm
 from elasticdl_tpu.models.spec import load_model_spec
 from elasticdl_tpu.ops import batch_shard, gated_delta as gd
 from elasticdl_tpu.ops import flash_attention as fa
 from elasticdl_tpu.ops.mode import SWITCH
+from tests import reference_check as rc
 
-REF = manifest.load_named("reference", "olmo-hybrid-7b")
-with open(os.path.join(manifest.BENCH_DIR, "configs",
-                       "olmo-hybrid-7b.json")) as fh:
-    PUBLISHED = json.load(fh)
-CONFIG = merge(PUBLISHED, PUBLISHED["rehearsal"])
+NAME = "olmo-hybrid-7b"
+REF = manifest.load_named("reference", NAME)
+PUBLISHED, CONFIG = rc.configuration(NAME, None), rc.configuration(NAME)
 SHAPE = REF.shape_of(CONFIG)
+CASE = functools.partial(rc.rehearsal, NAME)
+_spec = functools.partial(rc.spec_of, NAME)
 # float32 on both sides: the chunk form against the recurrence reads
 # 1e-6 in the loss and 2e-5 .. 4e-4 in a gradient leaf (the decay's
 # projection, whose gradient is small beside the others')
 LOSS_TOLERANCE, GRAD_TOLERANCE = 2e-5, 2e-3
 
 
-def _spec(**override):
-    return load_model_spec("transformer", model_params=params_string(
-        dict(CONFIG["cli"]["model_params"], **override)))
-
-
-@functools.lru_cache(maxsize=None)
-def _case(seed=3):
-    """(spec, params with the norms' scales drawn, tokens) as the chip's
-    comparison draws them."""
-    spec = _spec()
-    params, tokens = REF.inputs(
-        CONFIG, jax.jit(spec.init_fn)(jax.random.PRNGKey(seed)),
-        np.random.default_rng(seed))
-    return spec, params, jnp.concatenate([tokens, tokens[:, ::-1]])
-
-
-def _product(spec, tokens):
-    return lambda p: spec.loss_fn(spec.apply_fn(p, tokens, True),
-                                  tokens).mean()
-
-
-def _reference(tokens, **how):
-    return lambda p: REF.loss(p, tokens, **how, **SHAPE)[0].mean()
-
-
-@functools.lru_cache(maxsize=None)
-def _wanted():
-    spec, params, tokens = _case()
-    return jax.jit(jax.value_and_grad(_reference(tokens)))(params)
-
-
 @pytest.mark.parametrize("mode", ["off", "interpret"])
-def test_the_loss_and_every_gradient_leaf_match_the_recurrence(
-        monkeypatch, mode):
+def test_the_loss_and_every_gradient_leaf_match_the_recurrence(mode):
     """``off`` + remat: the jnp twins under ``jax.checkpoint``;
     ``interpret``: the scan's and the convolution's kernels and the
     flash kernels in the interpreter."""
-    monkeypatch.setenv(SWITCH, mode)
-    spec, params, tokens = _case()
-    assert spec.config.remat and [k.op for k in spec.config.kinds] == list(
-        "ddda")
-    got, grads = jax.jit(jax.value_and_grad(_product(spec, tokens)))(params)
-    want, wanted = _wanted()
-    assert abs(float(got) - float(want)) <= LOSS_TOLERANCE * float(want)
-    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
-    far = {}
-    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(wanted)):
-        far[jax.tree_util.keystr(path)] = float(
-            jnp.linalg.norm(g - w) / (jnp.linalg.norm(w) + 1e-12))
-        assert float(jnp.linalg.norm(w)) > 0, path
-    assert len(far) == 3 * 14 + 11 + 3
-    assert max(far.values()) < GRAD_TOLERANCE, sorted(
-        far.items(), key=lambda item: -item[1])[:4]
+    cfg = _spec().config
+    assert cfg.remat and [k.op for k in cfg.kinds] == list("ddda")
+    far, still, _, _ = rc.check(CASE(), mode, LOSS_TOLERANCE, GRAD_TOLERANCE)
+    assert len(far) == 3 * 14 + 11 + 3 and not still
 
 
 @pytest.mark.parametrize("piece", ["conv", "silu", "l2norm", "beta2",
@@ -96,9 +51,9 @@ def test_the_loss_and_every_gradient_leaf_match_the_recurrence(
 def test_the_reference_without_one_piece_fails_the_tolerance(piece):
     """Each of steps 1-6 moves the loss by more than the tolerance the
     product is held to: leaving one out is not support."""
-    spec, params, tokens = _case()
-    want = float(_wanted()[0])
-    less = float(jax.jit(_reference(tokens, without=(piece,)))(params))
+    case = CASE()
+    want = float(rc.wanted(case)[0][0])
+    less = float(jax.jit(case.reference(without=(piece,)))(case.params)[0])
     # (without the L2 norm the state diverges: a NaN is not within it)
     assert not abs(less - want) <= 20 * LOSS_TOLERANCE * want, (
         piece, less, want)
@@ -108,9 +63,9 @@ def test_the_reference_without_one_piece_fails_the_tolerance(piece):
                                  dict(state=jnp.bfloat16)],
                          ids=["operands", "state"])
 def test_the_reference_in_bfloat16_fails_the_tolerance(how):
-    spec, params, tokens = _case()
-    want = float(_wanted()[0])
-    lower = float(jax.jit(_reference(tokens, **how))(params))
+    case = CASE()
+    want = float(rc.wanted(case)[0][0])
+    lower = float(jax.jit(case.reference(**how))(case.params)[0])
     assert abs(lower - want) > 5 * LOSS_TOLERANCE * want, (lower, want)
 
 
@@ -336,12 +291,12 @@ def test_remat_keeps_table_has_the_delta_layers_rows():
 @pytest.mark.parametrize("mode", ["interpret", "off"])
 def test_kept_names_change_no_gradient(monkeypatch, mode):
     monkeypatch.setenv(SWITCH, mode)
-    spec, params, tokens = _case()
+    spec, params, tokens = CASE().parts()
     held = 16 * sum(a.size for a in jax.tree_util.tree_leaves(params))
 
     def grads(room):
         with batch_shard.batch_axis(None, None, room):
-            return jax.jit(jax.grad(_product(spec, tokens)))(params)
+            return jax.jit(jax.grad(rc.loss_of(spec, tokens)))(params)
 
     everything = batch_shard.DeviceRoom(2 ** 40, 2 ** 40 - held)
     names = rk.choose(spec.config, params, tokens.size, everything)[0]
@@ -363,7 +318,7 @@ def test_a_policy_that_keeps_the_delta_row_runs_the_forward_once_a_layer(
     twice as well were the row to leave out the inverse the backward
     reads: ``gdn_bwd`` once a layer always."""
     monkeypatch.setenv(SWITCH, "interpret")
-    spec, params, tokens = _case()
+    spec, params, tokens = CASE().parts()
     held = 16 * sum(a.size for a in jax.tree_util.tree_leaves(params))
     room = None if kept == "nothing" else batch_shard.DeviceRoom(
         2 ** 40, 2 ** 40 - held)
@@ -373,7 +328,7 @@ def test_a_policy_that_keeps_the_delta_row_runs_the_forward_once_a_layer(
             (label, tuple(n for n in names if n != gd.KEEP_INVERSE), *rest)
             for label, names, *rest in entries(cfg, rows)])
     with batch_shard.batch_axis(None, None, room):
-        whole = jax.make_jaxpr(jax.grad(_product(spec, tokens)))(params)
+        whole = jax.make_jaxpr(jax.grad(rc.loss_of(spec, tokens)))(params)
     calls = collections.Counter()
 
     def walk(jaxpr):
@@ -414,14 +369,14 @@ def _lines(fn, *prefixes):
                                       ("interpret", "interpreter")])
 def test_the_delta_scan_and_layer_stack_lines(monkeypatch, mode, ran):
     monkeypatch.setenv(SWITCH, mode)
-    spec, params, tokens = _case()
+    spec, params, tokens = CASE().parts()
     held = 16 * sum(a.size for a in jax.tree_util.tree_leaves(params))
     room = batch_shard.DeviceRoom(2 ** 40, 2 ** 40 - held)
 
     def trace(room):
         def run():
             with batch_shard.batch_axis(None, None, room):
-                jax.eval_shape(_product(spec, tokens), params)
+                jax.eval_shape(rc.loss_of(spec, tokens), params)
         return _lines(run, "delta scan:", "layer stack:")
 
     stack, scan = trace(None)
@@ -442,41 +397,21 @@ def test_the_delta_scan_and_layer_stack_lines(monkeypatch, mode, ran):
 # (``layer_pattern=addd``, ``delta_kind=kda``: benchmark/reference/
 # solar-open2-250b.py, the recurrence under a decay a channel)
 
-KREF = manifest.load_named("reference", "solar-open2-250b")
-with open(os.path.join(manifest.BENCH_DIR, "configs",
-                       "solar-open2-250b.json")) as fh:
-    KPUBLISHED = json.load(fh)
-KCONFIG = merge(KPUBLISHED, KPUBLISHED["rehearsal"])
+KNAME = "solar-open2-250b"
+KREF = manifest.load_named("reference", KNAME)
+KPUBLISHED, KCONFIG = rc.configuration(KNAME, None), rc.configuration(KNAME)
 KSHAPE = KREF.shape_of(KCONFIG)
-
-
-def _kspec(**override):
-    return load_model_spec("transformer", model_params=params_string(
-        dict(KCONFIG["cli"]["model_params"], **override)))
-
-
-@functools.lru_cache(maxsize=None)
-def _kcase(seed=3):
-    spec = _kspec()
-    params, tokens = KREF.inputs(
-        KCONFIG, jax.jit(spec.init_fn)(jax.random.PRNGKey(seed)),
-        np.random.default_rng(seed))
-    return spec, params, jnp.concatenate([tokens, tokens[:, ::-1]])
-
-
-def _kreference(tokens, **how):
-    return lambda p: KREF.loss(p, tokens, **how, **KSHAPE)[0].mean()
-
-
-@functools.lru_cache(maxsize=None)
-def _kwanted():
-    spec, params, tokens = _kcase()
-    return jax.jit(jax.value_and_grad(_kreference(tokens)))(params)
+KCASE = functools.partial(rc.rehearsal, KNAME)
+_kspec = functools.partial(rc.spec_of, KNAME)
+# what the interpreter's case has to reach, as at full depth: the
+# vector-decay scan, the dispatch's products and a share's row moves (64
+# positions are no flash tile, and the convolution takes its reference)
+KDA_KERNELS = {"kda_fwd", "kda_bwd", "gmm_nn", "gmm_nt", "gmm_tn",
+               "rows_pack", "rows_gather", "rows_sum"}
 
 
 @pytest.mark.parametrize("mode", ["off", "interpret"])
-def test_the_addd_expert_stack_matches_the_recurrence_a_channel(
-        monkeypatch, mode):
+def test_the_addd_expert_stack_matches_the_recurrence_a_channel(mode):
     """Loss and every gradient leaf of the cut ``solar-open2-250b``
     model at its rehearsal size (2 of 16 heads of both kinds, the
     softmax layer's on 1 K/V head, 2 of 8 experts beside a shared one
@@ -484,35 +419,27 @@ def test_the_addd_expert_stack_matches_the_recurrence_a_channel(
     ``off`` the jnp twins under ``jax.checkpoint``, ``interpret`` the
     vector-decay scan's, the convolution's, the flash and the dispatch's
     kernels in the interpreter.  No gradient reaches ``expert_bias``."""
-    monkeypatch.setenv(SWITCH, mode)
-    spec, params, tokens = _kcase()
-    cfg = spec.config
+    cfg = _kspec().config
     assert cfg.remat and [k.op for k in cfg.kinds] == list("addd")
     assert not any(k.dense for k in cfg.kinds) and cfg.delta_kind == "kda"
     assert (cfg.num_heads, cfg.kv_heads, cfg.head_shares) == (2, 1, 8)
-    got, grads = jax.jit(jax.value_and_grad(_product(spec, tokens)))(params)
-    want, wanted = _kwanted()
-    assert abs(float(got) - float(want)) <= LOSS_TOLERANCE * float(want)
-    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
-    far = {}
-    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(wanted)):
-        name = jax.tree_util.keystr(path)
-        if "expert_bias" in name or not float(jnp.linalg.norm(w)):
-            # no gradient reaches the bias; a layer whose router sends
-            # the held experts no token leaves theirs, and the router's
-            # (which a share reaches through its experts alone), zero on
-            # both sides
-            assert not float(jnp.abs(g).max()), name
-            assert "expert_bias" in name or name.split("'")[-2] in (
-                "w_gate", "w_up", "w_down", "w_router"), name
-            continue
-        far[name] = float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w))
+    # the interpreter's case at the least depth with both kinds of
+    # layer, ``ad``: the arithmetic at depth is the ``off`` case's
+    short = mode == "interpret"
+    case = KCASE(layers_kept=(0, 1), model=(
+        ("num_layers", 2), ("layer_pattern", "ad"))) if short else KCASE()
+    far, still, _, _ = rc.check(
+        case, mode, LOSS_TOLERANCE, GRAD_TOLERANCE,
+        KDA_KERNELS if short else ())
+    # no gradient reaches the bias; a layer whose router sends the held
+    # experts no token leaves theirs, and the router's (which a share
+    # reaches through its experts alone), zero on both sides
+    assert all("expert_bias" in name or name.split("'")[-2] in (
+        "w_gate", "w_up", "w_down", "w_router") for name in still), still
     # a KDA layer's 12 mixer leaves and the softmax layer's 5, 2 norms
     # and 7 FFN leaves (the bias apart) each, and embed, ln_f, lm_head;
     # at most one layer's held experts idle
-    assert len(far) >= 3 * (12 + 9) + (5 + 9) + 3 - 4
-    assert max(far.values()) < GRAD_TOLERANCE, sorted(
-        far.items(), key=lambda item: -item[1])[:4]
+    assert len(far) >= (1 if short else 3) * (12 + 9) + (5 + 9) + 3 - 4
 
 
 @pytest.mark.parametrize("piece", KREF.PIECES)
@@ -521,9 +448,9 @@ def test_the_kda_reference_without_one_piece_fails_the_tolerance(piece):
     the loss by more than the tolerance the product is held to;
     ``channels`` puts a head's mean log decay on every channel: a scalar
     decay in the vector's place is not support."""
-    spec, params, tokens = _kcase()
-    want = float(_kwanted()[0])
-    less = float(jax.jit(_kreference(tokens, without=(piece,)))(params))
+    case = KCASE()
+    want = float(rc.wanted(case)[0][0])
+    less = float(jax.jit(case.reference(without=(piece,)))(case.params)[0])
     assert not abs(less - want) <= 20 * LOSS_TOLERANCE * want, (
         piece, less, want)
 
@@ -659,10 +586,10 @@ def test_the_kda_stacks_tree_count_and_decay_mask():
                                       ("interpret", "interpreter")])
 def test_the_kda_stacks_lines(monkeypatch, mode, ran):
     monkeypatch.setenv(SWITCH, mode)
-    spec, params, tokens = _kcase()
+    spec, params, tokens = KCASE().parts()
 
     def run():
-        jax.eval_shape(_product(spec, tokens), params)
+        jax.eval_shape(rc.loss_of(spec, tokens), params)
 
     stack, scan = _lines(run, "delta scan:", "layer stack:")
     assert stack == (

@@ -6,10 +6,9 @@ Interpret mode cannot see what Mosaic refuses (a slice off the tiling, a
 transpose it has no lowering for, more scoped VMEM than a kernel may
 use); this does, at the widths the benchmark's cells run, in a few
 seconds and without chip time.  Nothing runs, so no result or time is
-checked here: ``chip_check.py`` does that on the chip.  The cells'
-whole training steps are ``test_step_compile_tpu.py``'s and
-``test_delta_step_compile_tpu.py``'s; what the three files share is
-``tests/tpu_compile.py``.
+checked here: ``chip_check.py`` does that on the chip.  Where the
+cells' whole training steps are compiled ``test_step_compile_tpu.py``
+says; what those files share is ``tests/tpu_compile.py``.
 """
 
 import math

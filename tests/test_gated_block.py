@@ -23,10 +23,12 @@ from benchmark.lib import manifest
 from elasticdl_tpu.models import remat_keep as rk
 from elasticdl_tpu.models import transformer as tfm
 from elasticdl_tpu.ops import flash_attention as fa
+from tests import reference_check as rc
 from tests.test_latent_attention import _apart, _lines, _loss
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-REF = manifest.load_named("reference", "trinity-mini")
+NAME = "trinity-mini"
+REF = manifest.load_named("reference", NAME)
 
 # heads x head size = 128, the hidden size 64; a window of 8 in 32; a
 # leading dense layer, then windowed, full, windowed, windowed over 4 of
@@ -60,23 +62,16 @@ def _shape(cfg, **over):
         multiplier=cfg.embed_multiplier, first=cfg.experts_held[0]), **over)
 
 
-def _case(spec, batch=2, seed=3):
-    """(params, tokens) as the comparison draws them: a wider head, a
-    bias on the routers, the new norms' scales off 1."""
-    cfg = spec.config
-    params = jax.jit(spec.init_fn)(jax.random.PRNGKey(seed))
-    params, _ = REF.inputs(dict(vocab_size=cfg.vocab_size, seq_len=4),
-                           params, np.random.default_rng(seed))
-    tokens = jnp.asarray(np.random.default_rng(seed + 1).integers(
-        0, cfg.vocab_size, (batch, cfg.max_seq_len)), jnp.int32)
-    return params, tokens
+# model -> the Case of a model of these widths as the comparison draws
+# it: a wider head, a bias on the routers, the new norms' scales off 1
+DRAWN = functools.partial(rc.tiny, NAME, _shape)
 
 
 # -- against the plain reference ---------------------------------------------
 
 
 @pytest.mark.parametrize("case", ["off-remat", "interpret"])
-def test_the_stack_matches_the_reference(monkeypatch, case):
+def test_the_stack_matches_the_reference(case):
     """Loss and every gradient leaf (the gate's weight, both output
     norms' scales, q's and k's scales on layers with and without RoPE,
     the embedding under its multiplier among them) of a dense layer and
@@ -86,39 +81,23 @@ def test_the_stack_matches_the_reference(monkeypatch, case):
     windowed).  Float32 both sides: 1e-5 of the loss, 1e-4 of each
     leaf's norm (the reference sums in another order)."""
     mode, _, remat = case.partition("-")
-    monkeypatch.setenv("ELASTICDL_FLASH", mode)
-    spec = tfm.model_spec(**dict(KERNEL if mode == "interpret" else TINY,
-                                 remat=bool(remat)))
-    params, tokens = _case(spec, batch=1 + (mode == "off"))
-    got, grads = jax.jit(jax.value_and_grad(_loss(spec, tokens)))(params)
-    shape = _shape(spec.config)
-    want, want_grads = jax.jit(jax.value_and_grad(lambda p: REF.loss(
-        p, tokens, **shape)[0].mean()))(params)
-    assert float(got) == pytest.approx(float(want), rel=1e-5)
-    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
-    seen = set()
-    for (path, leaf), ref in zip(flat, jax.tree_util.tree_leaves(
-            want_grads)):
-        seen.add(path[-1].key)
-        if float(jnp.abs(ref).max()):          # expert_bias: no gradient
-            assert _apart(leaf, ref) <= 1e-4, jax.tree_util.keystr(path)
-        else:
-            assert path[-1].key == "expert_bias"
-            assert not float(jnp.abs(leaf).max())
-    assert seen >= {"w_attn_gate", "ln1_post", "ln2_post", "q_norm",
-                    "k_norm", "embed", "ws_gate", "w_router"}
+    drawn = DRAWN(KERNEL if mode == "interpret" else TINY,
+                  batch=1 + (mode == "off"))
+    far, still, _, _ = rc.check(drawn, mode, 1e-5, 1e-4, remat=bool(remat))
+    assert still and all("expert_bias" in name for name in still)
+    assert {name.split("'")[-2] for name in far} >= {
+        "w_attn_gate", "ln1_post", "ln2_post", "q_norm", "k_norm", "embed",
+        "ws_gate", "w_router"}
 
 
 @functools.lru_cache(maxsize=None)
 def _whole():
     """(params, tokens, the reference's keywords, the product's loss,
     the reference's) of the TINY model."""
-    spec = tfm.model_spec(**TINY)
-    params, tokens = _case(spec)
-    shape = _shape(spec.config)
-    return (params, tokens, shape,
-            float(jax.jit(_loss(spec, tokens))(params)),
-            float(REF.loss(params, tokens, **shape)[0].mean()))
+    drawn = DRAWN(TINY)
+    return (drawn.params, drawn.tokens, drawn.shape,
+            float(jax.jit(_loss(drawn.spec(), drawn.tokens))(drawn.params)),
+            float(rc.wanted(drawn)[0][0]))
 
 
 @pytest.mark.parametrize("piece", REF.PIECES)
@@ -138,10 +117,9 @@ def test_a_reference_without_one_piece_fails_the_tolerance(piece):
 def test_bfloat16_where_float32_is_stated_fails_the_tolerance():
     """The same weights through the product in bfloat16: ten times and
     more past the 1e-5 the float32 product is held to."""
-    spec = tfm.model_spec(**dict(TINY, dtype="bfloat16"))
-    params, tokens = _case(spec)
-    got = float(jax.jit(_loss(spec, tokens))(params))
-    want = float(REF.loss(params, tokens, **_shape(spec.config))[0].mean())
+    params, tokens, _, _, want = _whole()
+    got = float(jax.jit(_loss(DRAWN(TINY).spec(dtype="bfloat16"), tokens))(
+        params))
     assert abs(got - want) > 1e-4 * abs(want)
 
 
@@ -189,9 +167,8 @@ def test_a_layer_in_lower_precision_is_told_on_the_same_inputs(monkeypatch,
     """``layer_errors`` on the reference's own inputs: a bfloat16
     program stands between float32 (~1e-7) and the ceiling; the
     reference's own math in float8 is past it in every part."""
-    spec = tfm.model_spec(**TINY)
-    params, tokens = _case(spec)
-    seen = REF.loss(params, tokens, **_shape(spec.config))[1]
+    params = DRAWN(TINY).params
+    seen = rc.wanted(DRAWN(TINY))[0][1][0]
     assert len(seen) == 4 and seen[0].h.shape == (2, 32, 64)
     if lower == "reference-float8":
         errors = REF.layer_errors(_file(), rounded=jnp.float8_e4m3fn)(
@@ -869,8 +846,7 @@ def test_kept_names_change_no_gradient(monkeypatch):
     and gradients, and the program names the gate's projection where it
     makes it."""
     monkeypatch.setenv("ELASTICDL_FLASH", "off")
-    spec = tfm.model_spec(**dict(TINY, remat=True))
-    params, tokens = _case(spec, batch=1)
+    spec, params, tokens = DRAWN(TINY, batch=1).parts(remat=True)
     names = tuple(n for _, entry, _ in rk.table(spec.config, 64)
                   for n in entry)
     assert {rk.KEEP_ATTN_GATE, rk.KEEP_Q, rk.KEEP_SHARED_GATE} <= set(names)
@@ -886,3 +862,49 @@ def test_kept_names_change_no_gradient(monkeypatch):
     named = {e.params["name"] for e in _eqns(jaxpr.jaxpr)
              if e.primitive.name == "name"}
     assert rk.KEEP_ATTN_GATE in named and rk.KEEP_STREAM in named
+
+
+# -- the cell's whole step for a described v5e: last in the file, since
+# ``one_chip`` turns XLA's optimisations on for its module (ROADMAP C16)
+
+from tests.tpu_compile import (  # noqa: E402,F401 (the fixtures)
+    _inventory_is_held, _updates_in_matmuls, cell_steps, one_chip)
+
+
+def test_the_gated_blocks_step_holds_no_update_in_a_matmul_nor_more_bytes(
+        cell_steps):
+    """The ``trinity-mini.seq16384`` cell's whole training step with the
+    names ``remat_keep`` chose, for a described v5e: no weight-gradient
+    matmul carries an AdamW update (39 did until PR 46) and the
+    compiler's bytes are 15.33 GB: PR 58's 14.69 and the routed up
+    product and the sorted rows that PR 60's list keeps beside PR 58's
+    (0.81 GB; the guard against holding ``embed`` and ``lm_head`` apart
+    as well, which read 15.82 where this read 14.72).  No ``.remat``
+    stands in it: with the routed down product kept in the sorted rows'
+    place, the same bytes, the compiler makes the head's logits a
+    second time (``fusion.2893.remat`` and ``gte.remat``, the [16384,
+    25024] product) to count 15.40, and the chip ran that step 1.1%
+    slower than the parent where it runs this one 0.8% faster (PERF.md
+    section 6, PR 60).  A share's down product stands behind the rows by
+    what its shapes say it is worth (``remat_keep._entries``), and this
+    cell's room ends before it."""
+    from elasticdl_tpu.ops import moe_dispatch
+
+    step = cell_steps("trinity-mini", 1, 16384, True)
+    assert {moe_dispatch.KEEP_UP, moe_dispatch.KEEP_ROWS} <= set(
+        step.chosen[0])
+    assert moe_dispatch.KEEP_OUT not in step.chosen[0]
+    assert abs(step.counted - 15.325e9) < 0.1e9, step.counted
+    text = step.compiled.as_text()
+    assert not _updates_in_matmuls(text)
+    assert ".remat" not in text
+
+
+@pytest.mark.parametrize("config,batch,rows,keep", [
+    ("trinity-mini", 1, 16384, True)])
+def test_the_expert_layers_inventory_is_held_to_the_compilers_count(
+        cell_steps, config, batch, rows, keep):
+    """``tests/test_step_compile_tpu.py``'s test of the same name for
+    this cell: +0.25 GB with ``choose``'s
+    list kept, the compile of the test above."""
+    _inventory_is_held(cell_steps, config, batch, rows, keep)

@@ -6,6 +6,7 @@ widths; the kernels in interpret mode."""
 
 import collections
 import contextlib
+import functools
 import dataclasses
 import json
 import logging
@@ -22,8 +23,10 @@ from benchmark.lib import manifest
 from elasticdl_tpu.models import remat_keep as rk
 from elasticdl_tpu.models import transformer as tfm
 from elasticdl_tpu.ops import hyper_mix as hm
+from tests import reference_check as rc
 
-REF = manifest.load_named("reference", "xing4.0-29b-a4b")
+NAME = "xing4.0-29b-a4b"
+REF = manifest.load_named("reference", NAME)
 
 # a leading dense layer and two expert layers over 4 of 16 experts
 # beside a shared expert, latent attention with a query latent under
@@ -54,41 +57,10 @@ def shape_of(cfg, **over):
         sk_eps=tfm.HYPER_SINKHORN_EPS, mtp_weight=cfg.mtp_weight), **over)
 
 
-def product_loss(spec, tokens):
-    return lambda p: spec.loss_fn(spec.apply_fn(p, tokens, True),
-                                  tokens).mean()
-
-
-def reference_loss(cfg, tokens, **over):
-    shape = shape_of(cfg, **over)
-
-    def total(p):
-        main, mtp = REF.loss(p, tokens, **shape)[:2]
-        return (main + shape["mtp_weight"] * mtp).mean()
-
-    return total
-
-
-def case(spec, batch=2, seed=3):
-    """(params, tokens) as the comparison draws them: a wider head, a
-    bias on the routers, maps off their initial values."""
-    cfg = spec.config
-    params = jax.jit(spec.init_fn)(jax.random.PRNGKey(seed))
-    params, _ = REF.inputs(
-        dict(vocab_size=cfg.vocab_size, seq_len=4, hc_mult=N), params,
-        np.random.default_rng(seed))
-    tokens = jnp.asarray(np.random.default_rng(seed + 1).integers(
-        0, cfg.vocab_size, (batch, cfg.max_seq_len)), jnp.int32)
-    return params, tokens
-
-
-def apart(got, want):
-    """The distance of two trees over the second's norm."""
-    leaves = jax.tree_util.tree_leaves
-    norm = lambda trees: float(jnp.sqrt(sum(
-        jnp.sum(jnp.square(t)) for t in trees)))
-    return norm([g - w for g, w in zip(leaves(got), leaves(want))]) / norm(
-        leaves(want))
+product_loss, apart = rc.loss_of, rc.apart
+# model -> the Case of a model of these widths as the comparison draws
+# it: a wider head, a bias on the routers, maps off their initial values
+case = functools.partial(rc.tiny, NAME, shape_of, hc_mult=N)
 
 
 def sublayer(seed=0, rows=(2, 32), c=128):
@@ -467,10 +439,10 @@ def layer_of(mode, monkeypatch, ffn=True):
     zoo's model on a stream four wide, with or without its FFN, under
     ``ELASTICDL_FLASH=mode``."""
     monkeypatch.setenv("ELASTICDL_FLASH", mode)
-    spec = tfm.model_spec(**dict(TINY, num_layers=1, mtp_modules=0, seq_len=64,
-                                 hyper_sinkhorn_iters=3))
-    cfg = spec.config
-    w = case(spec)[0]["layers"]["lead"]["0"]
+    drawn = case(dict(TINY, num_layers=1, mtp_modules=0, seq_len=64,
+                      hyper_sinkhorn_iters=3))
+    cfg = drawn.spec().config
+    w = drawn.params["layers"]["lead"]["0"]
     x = sublayer(seed=6, rows=(2, 64))[0]
     kind = cfg.kinds[0]._replace(ffn=ffn)
 
@@ -538,7 +510,7 @@ def test_a_layer_of_one_sublayer_makes_no_fused_call(monkeypatch):
 
 
 @pytest.mark.parametrize("mode", ["off", "interpret-remat"])
-def test_the_model_matches_the_reference(monkeypatch, mode):
+def test_the_model_matches_the_reference(mode):
     """Loss (main + 0.1 module) and every gradient leaf of a dense layer,
     an expert layer and the module's block on a stream four wide: 1e-5
     of the loss, 1e-4 of each leaf's norm.  In interpret mode the
@@ -546,27 +518,14 @@ def test_the_model_matches_the_reference(monkeypatch, mode):
     Three Sinkhorn rounds, not twenty: each is unrolled into the
     program six times over, and the CPU compiles them one by one."""
     kernels, _, remat = mode.partition("-")
-    monkeypatch.setenv("ELASTICDL_FLASH", kernels)
-    spec = tfm.model_spec(**dict(TINY, num_layers=2, hyper_sinkhorn_iters=3,
-                                 remat=bool(remat)))
-    params, tokens = case(spec)
-    got, grads = jax.jit(jax.value_and_grad(product_loss(spec, tokens)))(
-        params)
-    want, want_grads = jax.jit(jax.value_and_grad(
-        reference_loss(spec.config, tokens)))(params)
-    assert float(got) == pytest.approx(float(want), rel=1e-5)
-    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
-    for (path, leaf), ref in zip(flat, jax.tree_util.tree_leaves(
-            want_grads)):
-        if float(jnp.abs(ref).max()):          # expert_bias: no gradient
-            assert apart(leaf, ref) <= 1e-4, jax.tree_util.keystr(path)
-        else:
-            assert not float(jnp.abs(leaf).max())
+    drawn = case(dict(TINY, num_layers=2, hyper_sinkhorn_iters=3))
+    _, still, _, _ = rc.check(drawn, kernels, 1e-5, 1e-4, remat=bool(remat))
+    assert still and all("expert_bias" in name for name in still)
+    want = rc.wanted(drawn)[0][0]
     # and the reference tells the mechanisms apart: one Sinkhorn round
     # for twenty; no YaRN; the query latent's norm left out
     for other in (dict(iters=1), dict(yarn=(1.0, 16.0, 32.0, 1.0))):
-        moved = jax.jit(reference_loss(spec.config, tokens, **other))(
-            params)
+        moved = jax.jit(drawn.reference(**other))(drawn.params)[0]
         assert abs(float(moved) - float(want)) > 1e-4 * abs(float(want))
 
 
@@ -577,7 +536,7 @@ def test_a_new_model_starts_as_the_plain_residual_on_equal_streams():
     wide = tfm.model_spec(**dict(TINY, mtp_modules=0))
     plain = tfm.model_spec(**dict(TINY, mtp_modules=0, hyper_streams=0))
     params = jax.jit(wide.init_fn)(jax.random.PRNGKey(5))
-    tokens = case(wide)[1]
+    tokens = case(TINY).tokens
     strip = lambda tree: {k: strip(v) if isinstance(v, dict) else v
                           for k, v in tree.items() if not k.startswith("hc")}
     got = jax.jit(product_loss(wide, tokens))(params)
@@ -587,8 +546,7 @@ def test_a_new_model_starts_as_the_plain_residual_on_equal_streams():
 
 
 def test_the_step_statistics_carry_the_sinkhorn_error_and_the_modules_loss():
-    spec = tfm.model_spec(**TINY)
-    params, tokens = case(spec)
+    spec, params, tokens = case(TINY).parts()
     out = spec.apply_fn(params, tokens, True)
     loss = spec.loss_fn(out, tokens)
     stats = spec.step_stats_fn(out)
@@ -654,10 +612,8 @@ def test_a_new_option_without_what_it_needs_is_refused_where_it_is_built(
 def test_latent_attention_with_a_query_latent_matches_the_reference():
     """``q = RMSNorm(h W_qa) W_qb`` under YaRN and its softmax scale,
     the product's halves against the reference's neighbours."""
-    spec = tfm.model_spec(**TINY)
-    cfg = spec.config
-    params, _ = case(spec)
-    w = params["layers"]["lead"]["0"]
+    cfg = case(TINY).spec().config
+    w = case(TINY).params["layers"]["lead"]["0"]
     h = jnp.asarray(np.random.default_rng(4).standard_normal((2, 32, 128)),
                     jnp.float32)
     got = tfm._latent_mix(h, w, cfg, jnp.arange(32), cfg.kinds[0])

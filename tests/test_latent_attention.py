@@ -21,9 +21,11 @@ from benchmark.lib import manifest
 from elasticdl_tpu.models import remat_keep as rk
 from elasticdl_tpu.models import transformer as tfm
 from elasticdl_tpu.ops import flash_attention as fa
+from tests import reference_check as rc
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-REF = manifest.load_named("reference", "kanana-2-30b-a3b")
+NAME = "kanana-2-30b-a3b"
+REF = manifest.load_named("reference", NAME)
 
 # heads x value size = 256, the hidden size 64; a leading dense layer and
 # two expert layers over 4 of 16 experts beside a shared expert of 2 x 48
@@ -50,30 +52,10 @@ def _shape(cfg, **over):
         scale=cfg.moe_route_scale, first=cfg.experts_held[0]), **over)
 
 
-def _loss(spec, tokens):
-    return lambda p: spec.loss_fn(spec.apply_fn(p, tokens, True),
-                                  tokens).mean()
-
-
-def _case(spec, batch=2, seed=3):
-    """(params, tokens) as the comparison draws them: a wider head, a
-    bias on the routers."""
-    cfg = spec.config
-    params = jax.jit(spec.init_fn)(jax.random.PRNGKey(seed))
-    params, _ = REF.inputs(dict(vocab_size=cfg.vocab_size, seq_len=4),
-                           params, np.random.default_rng(seed))
-    tokens = jnp.asarray(np.random.default_rng(seed + 1).integers(
-        0, cfg.vocab_size, (batch, cfg.max_seq_len)), jnp.int32)
-    return params, tokens
-
-
-def _apart(got, want):
-    """The distance of two trees over the second's norm."""
-    leaves = jax.tree_util.tree_leaves
-    norm = lambda trees: float(jnp.sqrt(sum(
-        jnp.sum(jnp.square(t)) for t in trees)))
-    return norm([g - w for g, w in zip(leaves(got), leaves(want))]) / norm(
-        leaves(want))
+_loss, _apart = rc.loss_of, rc.apart
+# model -> the Case of a model of these widths as the comparison draws
+# it: a wider head, a bias on the routers
+DRAWN = functools.partial(rc.tiny, NAME, _shape)
 
 
 # -- against the plain reference ---------------------------------------------
@@ -92,41 +74,29 @@ def test_the_stack_matches_the_reference(monkeypatch, case):
     product the HALVES of the permuted ones: equal only if the
     permutation is the right one."""
     mode, _, remat = case.partition("-")
-    monkeypatch.setenv("ELASTICDL_FLASH", mode)
-    spec = tfm.model_spec(**dict(KERNEL if mode == "interpret" else TINY,
-                                 remat=bool(remat)))
-    params, tokens = _case(spec, batch=1 + (mode == "off"))
-    got, grads = jax.jit(jax.value_and_grad(_loss(spec, tokens)))(params)
-    shape = _shape(spec.config)
-    reference = lambda **how: jax.jit(lambda p: REF.loss(
-        p, tokens, **dict(shape, **how))[0].mean())
-    want, want_grads = jax.jit(jax.value_and_grad(reference()))(params)
-    assert float(got) == pytest.approx(float(want), rel=1e-5)
-    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
-    for (path, leaf), ref in zip(flat, jax.tree_util.tree_leaves(
-            want_grads)):
-        if float(jnp.abs(ref).max()):          # expert_bias: no gradient
-            assert _apart(leaf, ref) <= 1e-4, jax.tree_util.keystr(path)
-        else:
-            assert not float(jnp.abs(leaf).max())
+    drawn = DRAWN(KERNEL if mode == "interpret" else TINY,
+                  batch=1 + (mode == "off"))
+    _, still, _, _ = rc.check(drawn, mode, 1e-5, 1e-4, remat=bool(remat))
+    assert still and all("expert_bias" in name for name in still)
+    if remat:      # what follows is the reference's alone: once a width
+        return
     # and the reference tells the mechanisms apart: the shared expert
     # left out; the program's column order taken for the published one
-    bare = reference(shared=False)(params)
-    assert abs(float(bare) - float(want)) > 2e-4 * abs(float(want))
-    as_is = REF.published_order
-    monkeypatch.setattr(REF, "published_order", lambda columns: columns)
-    other = reference()(params)
-    monkeypatch.setattr(REF, "published_order", as_is)
-    assert abs(float(other) - float(want)) > 2e-4 * abs(float(want))
+    want = float(rc.wanted(drawn)[0][0])
+    other = lambda **how: float(jax.jit(drawn.reference(**how))(
+        drawn.params)[0])
+    assert abs(other(shared=False) - want) > 2e-4 * abs(want)
+    monkeypatch.setattr(drawn.ref, "published_order", lambda cols: cols)
+    assert abs(other() - want) > 2e-4 * abs(want)
 
 
 def test_bfloat16_where_float32_is_stated_fails_the_tolerance():
     """The same weights through the product in bfloat16: ten times and
     more past the 1e-5 the float32 product is held to."""
-    spec = tfm.model_spec(**dict(TINY, dtype="bfloat16"))
-    params, tokens = _case(spec)
-    got = float(jax.jit(_loss(spec, tokens))(params))
-    want = float(REF.loss(params, tokens, **_shape(spec.config))[0].mean())
+    drawn = DRAWN(TINY)
+    params, tokens = drawn.params, drawn.tokens
+    got = float(jax.jit(_loss(drawn.spec(dtype="bfloat16"), tokens))(params))
+    want = float(rc.wanted(DRAWN(TINY))[0][0])
     assert abs(got - want) > 1e-4 * abs(want)
 
 
@@ -171,9 +141,8 @@ def test_a_layer_in_lower_precision_is_told_on_the_same_inputs(monkeypatch,
     stated lies a hundred times past float32's distance in every part
     and is refused by name; the reference rounded to float8 lies past
     the ceiling a bfloat16 program is held to, in every part."""
-    spec = tfm.model_spec(**TINY)
-    params, tokens = _case(spec)
-    seen = REF.loss(params, tokens, **_shape(spec.config))[1]
+    params = DRAWN(TINY).params
+    seen = rc.wanted(DRAWN(TINY))[0][1][0]
     assert len(seen) == 2 and seen[0].h.shape == (2, 32, 64)
     if lower == "reference-float8":
         errors = REF.layer_errors(_file(), rounded=jnp.float8_e4m3fn)(
@@ -624,8 +593,7 @@ def test_the_latent_attention_line_says_what_runs(monkeypatch, mode, word,
     monkeypatch.setenv("ELASTICDL_FLASH", mode)
     tfm.announce_latent.cache_clear()
     tfm.announce_stack.cache_clear()
-    spec = tfm.model_spec(**KERNEL)
-    params, tokens = _case(spec, batch=1)
+    spec, params, tokens = DRAWN(KERNEL, batch=1).parts()
     run = lambda: jax.eval_shape(_loss(spec, tokens), params)
     lines = _lines(lambda: (run(), run()), "latent attention:")
     assert lines == [
@@ -706,9 +674,8 @@ def test_kept_names_change_no_gradient(monkeypatch, mode):
     """Every name of the table kept against nothing kept: the same loss
     and gradients, and the kept program names its values."""
     monkeypatch.setenv("ELASTICDL_FLASH", mode)
-    spec = tfm.model_spec(**dict(KERNEL if mode == "interpret" else TINY,
-                                 remat=True))
-    params, tokens = _case(spec, batch=1)
+    spec, params, tokens = DRAWN(
+        KERNEL if mode == "interpret" else TINY, batch=1).parts(remat=True)
     names = tuple(n for _, entry, _ in rk.table(spec.config, 64)
                   for n in entry)
     assert {rk.KEEP_LATENT, rk.KEEP_KV, rk.KEEP_SHARED_GATE} <= set(names)

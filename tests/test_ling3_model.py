@@ -9,61 +9,25 @@ tests/test_ling3_layers.py.
 """
 
 import functools
-import json
-import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
-from benchmark.lib import manifest
-from benchmark.lib.runner import merge, params_string
+from benchmark.lib.runner import params_string
 from elasticdl_tpu.models.spec import load_model_spec
-from elasticdl_tpu.ops.mode import SWITCH
+from tests import reference_check as rc
 
-REF = manifest.load_named("reference", "ling-3.0-flash")
-with open(os.path.join(manifest.BENCH_DIR, "configs",
-                       "ling-3.0-flash.json")) as fh:
-    PUBLISHED = json.load(fh)
-CONFIG = merge(PUBLISHED, PUBLISHED["rehearsal"])
-SHAPE = REF.shape_of(CONFIG)
+NAME = "ling-3.0-flash"
+PUBLISHED, CONFIG = rc.configuration(NAME, None), rc.configuration(NAME)
+CASE = functools.partial(rc.rehearsal, NAME)
 LOSS_TOLERANCE = 2e-6
 GRAD_TOLERANCE = 5e-4
 
 
-@functools.lru_cache(maxsize=None)
-def _case(seed=3):
-    spec = load_model_spec("transformer", model_params=params_string(
-        CONFIG["cli"]["model_params"]))
-    params, tokens = REF.inputs(
-        CONFIG, jax.jit(spec.init_fn)(jax.random.PRNGKey(seed)),
-        np.random.default_rng(seed))
-    return spec, params, jnp.concatenate([tokens, tokens[:, ::-1]])
-
-
-def _product(spec, tokens):
-    return lambda p: spec.loss_fn(spec.apply_fn(p, tokens, True),
-                                  tokens).mean()
-
-
-def _reference(tokens, **how):
-    def total(p):
-        main, mtp, _, _ = REF.loss(p, tokens, **how, **SHAPE)
-        return (main + SHAPE["mtp_weight"] * mtp).mean()
-
-    return total
-
-
-@functools.lru_cache(maxsize=None)
-def _wanted():
-    spec, params, tokens = _case()
-    return jax.jit(jax.value_and_grad(_reference(tokens)))(params)
-
-
 def test_the_rehearsal_model_is_the_cells_with_smaller_numbers():
-    spec, params, _ = _case()
-    cfg = spec.config
+    case = CASE()
+    REF, SHAPE, cfg = case.ref, case.shape, case.spec().config
     cell = load_model_spec("transformer", model_params=params_string(
         PUBLISHED["cli"]["model_params"])).config
     assert "".join(k.op for k in cfg.kinds) == "dda"
@@ -85,35 +49,20 @@ def test_the_rehearsal_model_is_the_cells_with_smaller_numbers():
 
 
 @pytest.mark.parametrize("mode", ["off", "interpret"])
-def test_the_dda_stack_and_its_module_match_the_reference(monkeypatch,
-                                                          mode):
+def test_the_dda_stack_and_its_module_match_the_reference(mode):
     """The total loss and every gradient leaf against the plain
     reference: ``off`` the jnp twins under ``jax.checkpoint``,
     ``interpret`` the vector-decay scan's, the convolution's, the latent
     flash and the dispatch's kernels in the interpreter.  No gradient
     reaches ``expert_bias``."""
-    monkeypatch.setenv(SWITCH, mode)
-    spec, params, tokens = _case()
-    got, grads = jax.jit(jax.value_and_grad(_product(spec, tokens)))(params)
-    want, wanted = _wanted()
-    assert abs(float(got) - float(want)) <= LOSS_TOLERANCE * float(want)
-    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
-    far = {}
-    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(wanted)):
-        name = jax.tree_util.keystr(path)
-        if "expert_bias" in name or not float(jnp.linalg.norm(w)):
-            assert not float(jnp.abs(g).max()), name
-            assert "expert_bias" in name or name.split("'")[-2] in (
-                "w_gate", "w_up", "w_down", "w_router"), name
-            continue
-        far[name] = float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w))
+    far, still, _, _ = rc.check(CASE(), mode, LOSS_TOLERANCE, GRAD_TOLERANCE)
+    assert all("expert_bias" in name or name.split("'")[-2] in (
+        "w_gate", "w_up", "w_down", "w_router") for name in still), still
     # two KDA mixers of 9 leaves, the latent one of 6 and the module's,
     # 2 norms a block, the FFNs (a dense one of 3; 7 an expert layer, the
     # bias apart), the module's 3 and embed, ln_f, lm_head; at most two
     # layers' held experts idle
     assert len(far) >= 2 * 9 + 2 * 6 + 4 * 2 + 3 + 3 * 7 + 3 + 3 - 8
-    assert max(far.values()) < GRAD_TOLERANCE, sorted(
-        far.items(), key=lambda item: -item[1])[:4]
 
 
 @pytest.mark.parametrize("piece", ["floor", "groups", "clamp", "head_gate",
@@ -123,9 +72,9 @@ def test_the_reference_without_one_mechanism_is_another_loss(piece):
     more than the tolerance the product is held to (``inputs`` draws the
     experts' gate and up projections wide enough for the limits to
     bite)."""
-    spec, params, tokens = _case()
-    want = float(_wanted()[0])
-    less = float(jax.jit(_reference(tokens, without=(piece,)))(params))
+    case = CASE()
+    want = float(rc.wanted(case)[0][0])
+    less = float(jax.jit(case.reference(without=(piece,)))(case.params)[0])
     assert not abs(less - want) <= 20 * LOSS_TOLERANCE * want, (
         piece, less, want)
 
@@ -136,10 +85,9 @@ def test_the_layer_check_passes_in_float32_and_sees_what_it_should():
     reference in float8, with a bfloat16 gate and state (the probe that
     remembers), without the clamp and without the gate a head; the
     routing check refuses a router without the group limit."""
-    spec, params, tokens = _case()
-    params = jax.tree_util.tree_map(lambda a: a, params)
-    tokens = tokens[:1]
-    main, mtp, seen, probe = REF.loss(params, tokens, **SHAPE)
+    case = CASE()
+    REF, params = case.ref, case.params
+    main, mtp, seen, probe = REF.loss(params, case.tokens[:1], **case.shape)
     assert REF.check_routing(CONFIG, params, seen) == 1.0
     with pytest.raises(SystemExit, match="router chose other experts"):
         REF.check_routing(CONFIG, params, seen, without=("groups",))

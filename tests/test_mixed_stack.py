@@ -28,6 +28,7 @@ from elasticdl_tpu.ops import moe_dispatch as md
 from elasticdl_tpu.ops import short_conv as sc
 from elasticdl_tpu.worker import worker as worker_mod
 from elasticdl_tpu.worker.collective_trainer import CollectiveTrainer
+from tests import reference_check as rc
 from tests.test_remat_keep import _pallas_calls
 
 REF = manifest.load_named("reference", "lfm2-24b-a2b")
@@ -53,9 +54,7 @@ def _with_bias(params, seed=0, scale=0.1):
             if path[-1].key == "expert_bias" else a), params)
 
 
-def _loss(spec, tokens):
-    return lambda p: spec.loss_fn(spec.apply_fn(p, tokens, True),
-                                  tokens).mean()
+_loss = rc.loss_of
 
 
 def _tokens(spec, batch=2, seed=1):
@@ -156,9 +155,9 @@ def _onto_share(params, cfg, by=5.0):
                          if path[-1].key == "expert_bias" else a), params)
 
 
-# the least depth with all of STACK's wiring: its dense conv lead, one
-# whole period (a scan of one turn) and the tail, every kind of layer
-SHORT = dict(STACK, num_layers=7, layer_pattern="c" + "accc" + "ac",
+# the least depth with all of STACK's wiring: a dense conv lead, one
+# whole period (a scan of one turn) and a tail, every kind of layer
+SHORT = dict(STACK, num_layers=4, layer_pattern="c" + "ac" + "a",
              dense_layers=1)
 # what the interpreter's case has to reach, the calls of STACK's own
 # program by the names they carry: the convolution, the grouped matmul's
@@ -200,7 +199,7 @@ def test_the_whole_stack_matches_the_reference(monkeypatch, mode,
     """A share of 4 of 16 experts, non-zero ``expert_bias``: the loss,
     the gradients' tree and each layer's choice of experts.  ``off``,
     the jnp paths, at cc | accc x 2 | ac; ``interpret``, the kernels in
-    interpret mode, at c | accc | ac (``SHORT``: the arithmetic at depth
+    interpret mode, at c | ac | a (``SHORT``: the arithmetic at depth
     is the ``off`` case's, each kernel's own its file's; what is left to
     show is that the model hands every kernel the right operands, and
     the program must call every one of ``STACK_KERNELS``).

@@ -427,3 +427,73 @@ def test_a_shares_step_has_no_buffer_of_all_the_rows(held):
     else:
         assert {(n * k, cfg.dim), (n * k, cfg.mlp_dim),
                 (n, k, cfg.dim)} <= seen
+
+
+# -- the cell's whole step for a described v5e: last in the file, since
+# ``one_chip`` turns XLA's optimisations on for its module (ROADMAP C16)
+
+from tests.tpu_compile import (  # noqa: E402,F401 (the fixtures)
+    V5E_LIMIT, _bare_estimate_is_bounded, _inventory_is_held, cell_steps,
+    one_chip)
+
+
+def test_the_mixed_stacks_step_fits_a_v5e_as_remat_keep_predicts(
+        cell_steps):
+    """The ``lfm2-24b-a2b.seq8192`` cell's whole training step (4
+    sequences of 8,192 through a dense conv layer and a period of
+    attention + 3 conv layers over 8 of 64 experts, AdamW) through the
+    TPU's compiler with the names ``remat_keep`` chose: its predicted
+    peak is held to the compiler's own byte count (arguments +
+    temporaries; the updated state aliases the donated one): over, never
+    under.  This is the band that guards the chip: the cell runs under
+    these names.
+    With the names chosen, the convolutions' result and the sorted rows
+    beside PR 58's ten entries since PR 60 (6.62 GB): 15.60 against
+    15.37 (+0.23; PR 58's tree read 15.81 against 15.41 with 5.55 GB
+    kept, the stack's 1.8 GB of gradients counted whole where the
+    dispatch's temporaries stood).  The count does not grow with the
+    list: 15.39 with the ten entries PR 58 kept, 14.95 with the
+    convolutions' result beside them, 15.37 with the sorted rows too;
+    in the first the tied head's cotangent (0.54 GB) still stands in
+    the first layer back-propagated, in the second it does not
+    (PERF.md section 6, PR 60).
+    (The estimate with nothing kept is
+    ``..step_with_nothing_kept_is_under_remat_keeps_estimate``'s, which
+    reads this compile.)"""
+    from elasticdl_tpu.models import remat_keep as rk
+    from elasticdl_tpu.ops import moe_dispatch, short_conv
+
+    step = cell_steps("lfm2-24b-a2b", 4, 8192, True)
+    names, kept, budget, peak = step.chosen
+    assert kept <= budget
+    assert set(names) >= set(rk.ATTN_NAMES) | {
+        rk.KEEP_STREAM, rk.KEEP_GATE, rk.KEEP_UP, short_conv.KEEP_IN,
+        short_conv.KEEP_OUT, moe_dispatch.KEEP_UP,
+        moe_dispatch.KEEP_ROWS}, names
+    assert peak <= (1 - rk.RESERVE) * V5E_LIMIT
+    assert 0 < peak - step.counted < 0.5e9, (peak, step.counted, names)
+
+
+def test_the_mixed_stacks_step_with_nothing_kept_is_under_remat_keeps_estimate(
+        cell_steps):
+    """The same cell's step with no room stated, so with nothing kept
+    (no cell runs so: the trainer states the room): ``remat_keep``'s
+    estimate of the step's own need, ``step_bytes``, the term every
+    choice starts from, 10.36 GB.  A compile of that step read 9.80
+    (+0.57: the leading dense layer's term, 3.09 GB, stands over the
+    expert layers' 2.51) and was 100 s of tier-1 for a band of 0.9 GB
+    that refused nothing the kept names' band passed; the estimate is
+    held to what the compile with the names kept bounds that count by
+    (``tpu_compile._bare_estimate_is_bounded``)."""
+    _bare_estimate_is_bounded(cell_steps("lfm2-24b-a2b", 4, 8192, True),
+                              32768)
+
+
+@pytest.mark.parametrize("config,batch,rows,keep", [
+    ("lfm2-24b-a2b", 4, 8192, True), ("lfm2-24b-a2b", 4, 8192, False)])
+def test_the_expert_layers_inventory_is_held_to_the_compilers_count(
+        cell_steps, config, batch, rows, keep):
+    """``tests/test_step_compile_tpu.py``'s test of the same name for
+    this cell: +0.23 GB with ``choose``'s
+    list kept; with nothing kept what that compile bounds."""
+    _inventory_is_held(cell_steps, config, batch, rows, keep)

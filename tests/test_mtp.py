@@ -16,9 +16,13 @@ from tests.test_hyper_residual import (REF, TINY, apart, case, product_loss,
 SMALL = dict(TINY, num_layers=2, hyper_sinkhorn_iters=3)
 
 
+def _drawn(**over):
+    """(spec, params, tokens) of the SMALL model with ``over``."""
+    return case(dict(SMALL, **over)).parts()
+
+
 def test_the_modules_loss_is_the_references_apart_from_the_main_loss():
-    spec = tfm.model_spec(**SMALL)
-    params, tokens = case(spec)
+    spec, params, tokens = _drawn()
 
     @jax.jit
     def product(p):
@@ -65,22 +69,24 @@ def test_the_head_and_the_embedding_take_both_paths_gradients():
     """The shared head's and the embedding's gradients are the sums of
     the main path's and the module's: each path's alone (the other's
     loss behind ``stop_gradient``) add up to the whole's."""
-    spec = tfm.model_spec(**SMALL)
+    spec, params, tokens = _drawn(num_layers=1)   # a layer and the module
     cfg = spec.config
-    params, tokens = case(spec)
 
-    def loss(p, main=1.0, module=1.0):
+    def paths(p):
         out = spec.apply_fn(p, tokens, True)
-        first = tfm.head_loss(p, out["hidden"], tokens, cfg)
-        second = tfm.head_loss(p, out["mtp_hidden"][0], tokens, cfg,
-                               shift=2)
-        return (main * first + module * cfg.mtp_weight * second).mean()
+        return (tfm.head_loss(p, out["hidden"], tokens, cfg).mean(),
+                cfg.mtp_weight * tfm.head_loss(
+                    p, out["mtp_hidden"][0], tokens, cfg, shift=2).mean())
+
+    @jax.jit
+    def each(p):    # one forward, a pullback a path
+        _, pull = jax.vjp(paths, p)
+        return pull((1.0, 0.0))[0], pull((0.0, 1.0))[0]
 
     shared = lambda g: {"lm_head": g["lm_head"], "embed": g["embed"]}
     whole = shared(jax.jit(jax.grad(product_loss(spec, tokens)))(params))
-    alone = jax.jit(jax.grad(lambda p: loss(p, module=0.0)))(params)
-    first = shared(alone)
-    second = shared(jax.jit(jax.grad(lambda p: loss(p, main=0.0)))(params))
+    alone, module = each(params)
+    first, second = shared(alone), shared(module)
     for name in whole:
         assert float(jnp.abs(first[name]).max()) > 0
         assert float(jnp.abs(second[name]).max()) > 0
@@ -94,8 +100,7 @@ def test_without_modules_the_model_is_the_parents():
     """``mtp_modules=0``: no ``mtp`` in the tree, the loss the head's
     alone, and the step statistics without the field."""
     spec = tfm.model_spec(**dict(SMALL, mtp_modules=0))
-    with_module = tfm.model_spec(**SMALL)
-    params, tokens = case(with_module)
+    with_module, params, tokens = _drawn()
     bare = {k: v for k, v in params.items() if k != "mtp"}
     assert jax.tree_util.tree_structure(bare) == jax.tree_util.tree_structure(
         jax.eval_shape(spec.init_fn, jax.random.PRNGKey(0)))
@@ -135,8 +140,7 @@ def test_the_stack_line_says_the_new_fields():
 
     tfm.announce_stack.cache_clear()
     hyper_mix.announce_hyper.cache_clear()
-    spec = tfm.model_spec(**dict(SMALL, head_shares=4))
-    params, tokens = case(spec)
+    spec, params, tokens = _drawn(head_shares=4)
     tfm.logger.addHandler(handler)
     try:
         jax.eval_shape(product_loss(spec, tokens), params)

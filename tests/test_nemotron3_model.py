@@ -9,62 +9,31 @@ tests/test_nemotron3_layers.py.
 """
 
 import functools
-import json
-import os
 
-import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
-from benchmark.lib import manifest
-from benchmark.lib.runner import merge, params_string
+from benchmark.lib.runner import params_string
 from elasticdl_tpu.models.spec import load_model_spec
-from elasticdl_tpu.ops.mode import SWITCH
+from tests import reference_check as rc
 
-REF = manifest.load_named("reference", "nemotron-3-nano-30b-a3b")
-with open(os.path.join(manifest.BENCH_DIR, "configs",
-                       "nemotron-3-nano-30b-a3b.json")) as fh:
-    PUBLISHED = json.load(fh)
-CONFIG = merge(PUBLISHED, PUBLISHED["rehearsal"])
-SHAPE = REF.shape_of(CONFIG)
+NAME = "nemotron-3-nano-30b-a3b"
+PUBLISHED, CONFIG = rc.configuration(NAME, None), rc.configuration(NAME)
 LOSS_TOLERANCE = 2e-6
 GRAD_TOLERANCE = 5e-4
 
 
-@functools.lru_cache(maxsize=None)
-def _case(seed=3):
-    spec = load_model_spec("transformer", model_params=params_string(
-        CONFIG["cli"]["model_params"]))
-    params, tokens = REF.inputs(
-        CONFIG, jax.jit(spec.init_fn)(jax.random.PRNGKey(seed)),
-        np.random.default_rng(seed))
-
-    def spread(w):      # scores wider than the bias, as at 2688
-        return {k: spread(v) if isinstance(v, dict) else
-                10.0 * v if k == "w_router" else v for k, v in w.items()}
-
-    return spec, spread(params), jnp.concatenate([tokens, tokens[:, ::-1]])
+def _spread(w):      # scores wider than the bias, as at 2688
+    return {k: _spread(v) if isinstance(v, dict) else
+            10.0 * v if k == "w_router" else v for k, v in w.items()}
 
 
-def _product(spec, tokens):
-    return lambda p: spec.loss_fn(spec.apply_fn(p, tokens, True),
-                                  tokens).mean()
-
-
-def _reference(tokens, **how):
-    return lambda p: REF.loss(p, tokens, **how, **SHAPE)[0].mean()
-
-
-@functools.lru_cache(maxsize=None)
-def _wanted():
-    spec, params, tokens = _case()
-    return jax.value_and_grad(_reference(tokens))(params)
+CASE = functools.partial(rc.rehearsal, NAME, 3, _spread)
 
 
 def test_the_rehearsal_model_is_the_cells_with_smaller_numbers():
-    spec, params, _ = _case()
-    cfg = spec.config
+    case = CASE()
+    REF, SHAPE, cfg = case.ref, case.shape, case.spec().config
     cell = load_model_spec("transformer", model_params=params_string(
         PUBLISHED["cli"]["model_params"])).config
     assert "".join(k.op for k in cfg.kinds) == "memae"
@@ -90,41 +59,29 @@ def test_the_rehearsal_model_is_the_cells_with_smaller_numbers():
 
 
 @pytest.mark.parametrize("mode", ["off", "interpret"])
-def test_the_stack_of_single_sublayers_matches_the_reference(monkeypatch,
-                                                             mode):
+def test_the_stack_of_single_sublayers_matches_the_reference(mode):
     """The loss and every gradient leaf against the plain reference:
     ``off`` the jnp twins under ``jax.checkpoint``, ``interpret`` the
     state-space scan's, the convolution's, the flash and the dispatch's
     kernels in the interpreter.  No gradient reaches ``expert_bias``."""
-    monkeypatch.setenv(SWITCH, mode)
-    spec, params, tokens = _case()
-    got, grads = jax.jit(jax.value_and_grad(_product(spec, tokens)))(params)
-    want, wanted = _wanted()
-    assert abs(float(got) - float(want)) <= LOSS_TOLERANCE * float(want)
-    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
-    far = {}
-    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(wanted)):
-        name = jax.tree_util.keystr(path)
-        if "expert_bias" in name:
-            assert not float(jnp.abs(g).max()), name
-            continue
-        far[name] = float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w))
+    far, still, _, _ = rc.check(CASE(), mode, LOSS_TOLERANCE,
+                                GRAD_TOLERANCE)
+    assert len(still) == 2 and all("expert_bias" in name for name in still)
     # two Mamba-2 mixers of 9 leaves, attention's 5, two expert layers
     # of 6 beside their bias, embed, ln_f, lm_head
     assert len(far) == 2 * 9 + 5 + 2 * 6 + 3
-    assert max(far.values()) < GRAD_TOLERANCE, sorted(
-        far.items(), key=lambda item: -item[1])[:4]
 
 
-@pytest.mark.parametrize("piece", REF.PIECES)
+@pytest.mark.parametrize("piece", rc.manifest.load_named(
+    "reference", NAME).PIECES)
 def test_the_reference_without_one_mechanism_is_another_loss(piece):
     """Each mechanism the row forced (the skip ``D``, the bias on the
     taps, the gate before the norm, the route's scale, the shared
     expert) left out, or a gate product put back, moves the loss by far
     more than the tolerance the product is held to."""
-    spec, params, tokens = _case()
-    want = float(_wanted()[0])
-    other = float(_reference(tokens, without=(piece,))(params))
+    case = CASE()
+    want = float(rc.wanted(case)[0][0])
+    other = float(case.reference(without=(piece,))(case.params)[0])
     assert not abs(other - want) <= 20 * LOSS_TOLERANCE * want, (
         piece, other, want)
 
@@ -134,9 +91,9 @@ def test_the_layer_check_passes_in_float32_and_sees_what_it_should():
     every layer ceiling hold at float32; the same check refuses the
     reference in float8 part by part, and a bfloat16 state or bfloat16
     cumulative decays move the probe that remembers by orders."""
-    spec, params, tokens = _case()
-    tokens = tokens[:1]
-    _, seen = REF.loss(params, tokens, **SHAPE)
+    case = CASE()
+    REF, params = case.ref, case.params
+    _, seen = REF.loss(params, case.tokens[:1], **case.shape)
     assert REF.check_routing(CONFIG, params, seen) == 1.0
     limits = REF.ceilings()
     # ``case`` holds the first Mamba-2 layer, the first expert layer and

@@ -9,8 +9,6 @@ looped stack.  docs/designs/looped_stack.md has the equations.
 """
 
 import functools
-import json
-import os
 
 import jax
 import jax.numpy as jnp
@@ -18,46 +16,23 @@ import numpy as np
 import pytest
 
 from benchmark.lib import manifest
-from benchmark.lib.runner import merge, params_string
+from benchmark.lib.runner import params_string
 from elasticdl_tpu.models import transformer as tfm
 from elasticdl_tpu.models.spec import load_model_spec
 from elasticdl_tpu.ops import head_loss as op
-from elasticdl_tpu.ops.mode import SWITCH
 from elasticdl_tpu.worker.worker import _loss_fields
+from tests import reference_check as rc
 
-REF = manifest.load_named("reference", "ouro-2.6b")
-with open(os.path.join(manifest.BENCH_DIR, "configs",
-                       "ouro-2.6b.json")) as fh:
-    PUBLISHED = json.load(fh)
-CONFIG = merge(PUBLISHED, PUBLISHED["rehearsal"])
+NAME = "ouro-2.6b"
+REF = manifest.load_named("reference", NAME)
+PUBLISHED, CONFIG = rc.configuration(NAME, None), rc.configuration(NAME)
 SHAPE = REF.shape_of(CONFIG)
+_spec = functools.partial(rc.spec_of, NAME)
 LOSS_TOLERANCE = 2e-6
 GRAD_TOLERANCE = 2e-4
 # bfloat16 against float32 at 64 tokens: a gradient leaf's relative
 # distance (norms over the leaf), four to eight bfloat16 steps
 BF16_GRAD_TOLERANCE = 4e-2
-
-
-def _spec(**over):
-    return load_model_spec("transformer", model_params=params_string(
-        dict(CONFIG["cli"]["model_params"], **over)))
-
-
-@functools.lru_cache(maxsize=None)
-def _case(seed=3):
-    spec = _spec()
-    params, tokens = REF.inputs(
-        CONFIG, jax.jit(spec.init_fn)(jax.random.PRNGKey(seed)),
-        np.random.default_rng(seed))
-    return params, jnp.concatenate([tokens, tokens[:, ::-1]])
-
-
-def _product(spec, tokens):
-    def loss(p):
-        out = spec.apply_fn(p, tokens, True)
-        return spec.loss_fn(out, tokens).mean(), spec.step_stats_fn(out)
-
-    return loss
 
 
 def _first_layers(params, layers):
@@ -67,15 +42,8 @@ def _first_layers(params, layers):
         lambda a: a[:layers], params["layers"]))
 
 
-@functools.lru_cache(maxsize=None)
-def _wanted(layers=None):
-    """((the reference's loss, what it saw), its gradients), of the
-    stack's first ``layers``."""
-    params, tokens = _case()
-    return jax.value_and_grad(
-        lambda p: (lambda loss, seen: (loss.mean(), seen))(
-            *REF.loss(p, tokens, **SHAPE)), has_aux=True)(
-                _first_layers(params, layers))
+# the case, and the case of the stack's first ``layers`` alone
+CASE = lambda layers=None: rc.rehearsal(NAME, 3, _first_layers, layers)
 
 
 def test_the_rehearsal_model_is_the_cells_with_smaller_numbers():
@@ -104,37 +72,27 @@ def test_the_rehearsal_model_is_the_cells_with_smaller_numbers():
     dict(remat=True, num_layers=1, mode="interpret"),
     dict(remat=True, dtype="bfloat16")], ids=lambda how: "-".join(
         "%s=%s" % item for item in how.items()))
-def test_the_looped_stack_matches_the_reference(monkeypatch, how):
+def test_the_looped_stack_matches_the_reference(how):
     """The loss, the turns' losses, the exit distribution, its entropy
     and EVERY gradient leaf against the plain reference: float32 tight
     with and without ``remat`` (the jnp twins), the flash kernels in
     the interpreter at one layer (the wiring is a layer's), bfloat16 at
     the reference's own tolerance."""
     how = dict(how)
-    monkeypatch.setenv(SWITCH, how.pop("mode", "off"))
-    spec = _spec(**how)
-    params, tokens = _case()
-    layers = how.get("num_layers")      # the first layer alone, both sides
-    (got, stats), grads = jax.jit(jax.value_and_grad(
-        _product(spec, tokens), has_aux=True))(_first_layers(params, layers))
-    (want, seen), wanted = _wanted(layers)
+    mode = how.pop("mode", "off")
     coarse = how.get("dtype") == "bfloat16"
     loss_tol = REF.TOLERANCE if coarse else LOSS_TOLERANCE
+    # (one layer: the first layer alone, both sides)
+    far, still, stats, (seen,) = rc.check(
+        CASE(how.get("num_layers")), mode, loss_tol,
+        BF16_GRAD_TOLERANCE if coarse else GRAD_TOLERANCE, **how)
     close = lambda a, b: np.testing.assert_allclose(
         np.asarray(a), np.asarray(b), rtol=5 * loss_tol)
-    assert abs(float(got) - float(want)) <= loss_tol * float(want)
     close(stats["ut_loss"], seen.turn_losses)
     close(stats["ut_exit"], seen.exit)
     close(stats["ut_exit_entropy"], seen.entropy)
-    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
-    far = {jax.tree_util.keystr(path): float(
-        jnp.linalg.norm(g - w) / jnp.linalg.norm(w))
-        for (path, g), w in zip(flat, jax.tree_util.tree_leaves(wanted))}
     # a layer's 11 leaves, embed, ln_f, lm_head, the gate's two
-    assert len(far) == 11 + 3 + 2
-    assert max(far.values()) < (
-        BF16_GRAD_TOLERANCE if coarse else GRAD_TOLERANCE), sorted(
-            far.items(), key=lambda item: -item[1])[:4]
+    assert len(far) == 11 + 3 + 2 and not still
 
 
 # the limit of the comparison that sees each piece of ``REF.PIECES`` gone
@@ -150,8 +108,9 @@ def test_the_reference_without_one_piece_is_past_a_limit(piece, limit):
     the chip (the loss's tolerance, a turn's state, the mean exit
     distribution, the number of turns), the one named here."""
     assert set(SEEN_BY) == set(REF.PIECES)
-    params, tokens = _case()
-    (want, seen), _ = _wanted()
+    case = CASE()
+    params, tokens = case.params, case.tokens
+    (want, (seen,)), _ = rc.wanted(case)
     other, got = REF.loss(params, tokens, without=(piece,), **SHAPE)
     turns = min(len(got.states), len(seen.states))
     past = {
@@ -171,8 +130,7 @@ def test_the_turn_check_passes_in_float32_and_refuses_float8():
     under its ceiling; the reference with its matmul operands rounded to
     float8, the nearest precision below the one the configuration
     states, is past it on every turn."""
-    params, tokens = _case()
-    tokens = tokens[:1]
+    params, tokens = CASE().params, CASE().tokens[:1]
     _, seen = REF.loss(params, tokens, **SHAPE)
     REF.check_turns(CONFIG, params, tokens, seen)
     _, low = REF.loss(params, tokens, rounded=jnp.float8_e4m3fn, **SHAPE)
@@ -228,7 +186,7 @@ def test_a_gate_that_never_leaves_and_no_entropy_is_the_last_turns_loss():
     lambda 0, ``p_R`` 1): the loss is the plain next-token loss of the
     last turn's logits, which is what evaluation reads."""
     spec = _spec(ut_entropy_weight=0.0)
-    params, tokens = _case()
+    params, tokens = CASE().params, CASE().tokens
     params = dict(params, ut_gate_b=jnp.float32(-jnp.inf))
     got = spec.loss_fn(spec.apply_fn(params, tokens, True), tokens)
     logits = spec.apply_fn(params, tokens, False)
@@ -297,7 +255,7 @@ def test_the_gate_is_drawn_at_zero_and_not_decayed():
     assert mask["lm_head"] and mask["ln_f"] and not mask["layers"][
         "ln1_post"]
     # every lambda 1/2: (1/2, 1/4, 1/4), ln 2 + ln 2 / 2 nats
-    tokens = _case()[1]
+    tokens = CASE().tokens
     out = spec.apply_fn(params, tokens, True)
     spec.loss_fn(out, tokens)
     stats = spec.step_stats_fn(out)

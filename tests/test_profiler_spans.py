@@ -100,13 +100,17 @@ def test_timing_phases_land_in_a_profiler_trace_nested_and_by_thread(
 
 def test_device_trace_uses_the_workable_options(tmp_path):
     """``--profile_dir``: Python tracer off, no HLO protos, and the
-    program's spans in the trace."""
-    from elasticdl_tpu.utils.timing import device_trace
-
-    timing = Timing()
-    with device_trace(str(tmp_path)):
-        with timing.timeit("step"):
-            time.sleep(0.001)
+    program's spans in the trace.  Traced in a process of its own: a
+    process that has compiled for a described chip
+    (``tests/tpu_compile.py``) writes those programs' protos into every
+    later trace, megabytes of them, whatever the options say."""
+    subprocess.run([sys.executable, "-c", (
+        "import sys, time\n"
+        "from elasticdl_tpu.utils.timing import Timing, device_trace\n"
+        "with device_trace(sys.argv[1]):\n"
+        "    with Timing().timeit('step'):\n"
+        "        time.sleep(0.001)\n"), str(tmp_path)], check=True, cwd=ROOT,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
     lines = _host_events(str(tmp_path))
     assert [e[0] for events in lines.values() for e in events] == [
         "edl.step"]

@@ -66,16 +66,33 @@ if __name__ == "__main__":
               sys.stdout, indent=1)
     print()
 else:
+    import subprocess
+
     import pytest
 
     with open(HASHES) as fh:
         WAS = json.load(fh)
 
+    @pytest.fixture(scope="module")
+    def lowered():
+        """Every configuration's hash by the command above, in a process
+        of its own, as the file was written: what a process traced
+        before is in the text it lowers to (ROADMAP C19: behind other
+        files ``nemotron-3-nano-30b-a3b.json`` read ``3a107421..`` on
+        the driver's machines; alone, the stored ``6a59985e..``)."""
+        out = subprocess.run(
+            [sys.executable, os.path.abspath(__file__)], check=True,
+            capture_output=True, text=True,
+            env=dict(os.environ, JAX_PLATFORMS="cpu"))
+        return json.loads(out.stdout)
+
     @pytest.mark.parametrize(
         "path", [p for p in CONFIGS if os.path.basename(p) in WAS],
         ids=os.path.basename)
-    def test_a_configurations_rehearsal_step_lowers_to_the_text_it_had(path):
-        assert step_hash(path) == WAS[os.path.basename(path)]
+    def test_a_configurations_rehearsal_step_lowers_to_the_text_it_had(
+            lowered, path):
+        name = os.path.basename(path)
+        assert lowered[name] == WAS[name]
 
     def test_the_eight_configurations_before_pr_54_are_all_held():
         assert len(WAS) >= 8 and set(WAS) <= {
