@@ -978,12 +978,13 @@ def _cases(tiny):
                lambda gb=gb, gh=gh, gg=gg, gt=gt, gd=gd, gw=gw: check_flash(
                    gb, gh, gt, gd, gw, interpret,
                    ref_slice=(1, gh // gg), kv_heads=gg))
-    # Three share cells' row moves (tokens x width, choices, bound): the
-    # last a 16-bit row of 21 lane tiles, moved as 1,408 words (tiny: 3
-    # tiles as 256).
+    # Four share cells' row moves (tokens x width, choices, bound): the
+    # third a 16-bit row of 21 lane tiles, moved as 1,408 words (tiny: 3
+    # tiles as 256), the last the thinnest share's, 8 of 320 experts
+    # held: most groups of sixteen tokens sum one term of their eight.
     for n, w, k, bound in ((96, 256, 4, 128), (96, 384, 6, 128)) if tiny else (
             (32768, 2048, 4, 32768), (16384, 2560, 6, 49152),
-            (16384, 2688, 6, 12288)):
+            (16384, 2688, 6, 12288), (16384, 4096, 8, 6656)):
         yield ("row_moves/N%d.W%d.K%d.C%d" % (n, w, k, bound),
                lambda n=n, w=w, k=k, bound=bound: check_row_moves(
                    n, w, k, bound, bound * 9 // 16, interpret))

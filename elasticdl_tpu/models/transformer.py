@@ -1354,8 +1354,10 @@ def _moe_ffn(h, w, cfg, mesh, route=None, limit=0.0):
     held experts' assignments alone, the padded rows, then what the
     dispatch measured of itself (``ops/moe_dispatch._moe_experts``):
     the rows its blocks moved, and how many of its shards ran more
-    than one block; under a router limited to groups
-    (``cfg.moe_groups``) [held + 4], last the share of the tokens among
+    than one block, and where the row kernel moves the rows
+    [held + 5], the slots its sums walked and those of tokens x K a
+    call; under a router limited to groups
+    (``cfg.moe_groups``) one more, last the share of the tokens among
     whose chosen groups is one with an expert held here.
     """
     B, T = h.shape[:2]
@@ -2818,8 +2820,11 @@ def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
     assignments per expert and padded rows, ``moe_load`` [L, X + 1];
     with a share of the experts, the held experts' alone,
     ``moe_moved`` [L], the rows each layer's dispatch moved (its bound
-    times the blocks that ran), and ``moe_spilled`` [L], the shards on
-    which it ran more than one; under a router limited to groups
+    times the blocks that ran), ``moe_spilled`` [L], the shards on
+    which it ran more than one, and where the row kernel moves the rows
+    ``moe_sum_terms`` and ``moe_sum_slots`` [L], the slots its sums
+    walked and the tokens x K a call they would have; under a router
+    limited to groups
     ``moe_group_hit`` [L], the share of the tokens whose chosen groups
     reach an expert held here; with a floored kda gate
     ``kda_gate_excess``, how far under the floor the step's lowest log
@@ -2970,6 +2975,10 @@ def model_spec(seq_len=512, learning_rate=3e-4, warmup_steps=0, mesh=None,
         # and under a group limit its layer the tokens that could reach it
         if cfg.moe_groups:
             stats["moe_group_hit"], load = load[:, -1], load[:, :-1]
+        if load.shape[1] > cfg.experts_held[1] + 3:
+            # the row kernel moved the rows: what its sums walked
+            stats["moe_sum_terms"], stats["moe_sum_slots"], load = (
+                load[:, -2], load[:, -1], load[:, :-2])
         return dict(stats, moe_load=load[:, :-2], moe_moved=load[:, -2],
                     moe_spilled=load[:, -1])
 

@@ -296,6 +296,21 @@ def _blocks(c, sizes):
     return -(-sizes.sum() // c)
 
 
+def _sums_walk(c, pos, blocks):
+    """int32 [2]: the slots the vector phase of a step's ``rows_sum``
+    calls walks, and the tokens x K a call they would be without the
+    ranks (``row_moves.sum_terms``), over the ``blocks`` that ran: two
+    calls a block, ``_sum_rows`` and ``_gather_rows``' pullback, on the
+    same ``pos`` (a held row's position is under the held rows' count,
+    so block i's are those of ``i * c .. (i + 1) * c``)."""
+    def add(i, walked):
+        mine = jnp.where((pos >= i * c) & (pos < (i + 1) * c),
+                         pos - i * c, -1)
+        return walked + 2 * jnp.stack(row_moves.sum_terms(mine, c))
+
+    return lax.fori_loop(jnp.int32(0), blocks, add, jnp.zeros(2, jnp.int32))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
 def _further_blocks(c, activation, limit, out, x, gates, weights, order,
                     sizes, pos):
@@ -354,7 +369,8 @@ def _moe_experts(h, gates, experts, *weights, total=None, first=0,
     (the module's docstring); the others' are never moved.  ``load``
     counts all ``total`` experts either way, and with a share ends in
     two more numbers: the rows the blocks that ran moved, and 1 if more
-    than one ran."""
+    than one ran; where the row kernel moves the rows, in two more
+    behind them (``_sums_walk``)."""
     b, t, e = h.shape
     held, k = weights[0].shape[0], experts.shape[-1]
     x = total or held
@@ -396,7 +412,8 @@ def _moe_experts(h, gates, experts, *weights, total=None, first=0,
             *operands)
         blocks = jnp.maximum(_blocks(bound, sizes), 1)
         load = jnp.concatenate([counted, jnp.stack(
-            [padded, blocks * bound, (blocks > 1).astype(jnp.int32)])])
+            [padded, blocks * bound, (blocks > 1).astype(jnp.int32)])] + (
+                [_sums_walk(bound, pos, blocks)] if by_kernel else []))
         return out.astype(h.dtype).reshape(b, t, e), load[None]
     xs = checkpoint_name(
         _take_rows(k, h.reshape(n, e), order, inverse), KEEP_ROWS)

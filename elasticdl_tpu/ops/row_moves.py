@@ -9,23 +9,35 @@ array and the sorted rows of a share of the experts
     out[i] = sum_j weight[i, j] * src[idx[i, j]]
 
 An index outside ``0 .. R`` names no row: it adds nothing, and nothing of
-``src`` is read for it.  With ``other [m, w]`` the row dots ``<other[i],
-src[idx[i, 0]]>`` come back beside it, ``[m]`` float32.  The sum is
-float32 and lives in VMEM; what is written is the result alone, once, in
-the dtype asked for.
+``src`` is read for it.  With ``other [m, w]`` and k = 1 the row dots
+``<other[i], src[idx[i, 0]]>`` come back beside it, ``[m]`` float32.
+The sum is float32 and lives in VMEM; what is written is the result
+alone, once, in the dtype asked for.
 
 The kernel: the grid walks tiles of ``tm`` result rows.  For each tile
 the indices that name a row come to SMEM as one list, each with its slot
 (``_moves``: a sort of every tile's ``tm * k`` keys, so the kernel's
 scalar loop never looks at an index that names none: a not-taken branch
 cost it as much as a DMA, ~25 ns); the loop starts one DMA a named
-index, from ``src`` where it lies in HBM to the row's slot of a
-``[k * tm, 1, words]`` VMEM buffer; the DMAs are waited for by their
-count, and the vector units then sum the k slots of sixteen result rows
-at a time, masking the slots no DMA filled by their bits (what a skipped
-slot holds is whatever was there: it is anded away before it is a
-float).  A tile none of whose indices names a row writes its zeros and
-starts nothing.
+index, from ``src`` where it lies in HBM to its slot of a
+``[k * tm, 1, words]`` VMEM buffer, ``slot = rank * tm + row``: ``rank``
+counts the named indices before it in its own result row, so a row's
+terms lie in the first slots of its k, in the order of their columns.
+The DMAs are waited for by their count, and the vector units then sum
+sixteen result rows at a time, slots ``0 .. t - 1`` where t is the
+terms of the fullest of the sixteen (a scalar from SMEM says which
+body; past four terms the next even count, ``_bodies``: a count's terms
+are one straight line of code); a row with fewer has the slots past its
+own masked by their bits (what a skipped slot holds is whatever was
+there: it is anded away before it is a float), and its weights come
+compacted by the same ranks.  The sum of
+a row is therefore what adding all k columns in order gave, zeros where
+an index names no row, bit for bit save the sign of a zero: the named
+terms meet in the same order, and the terms left out were ``+ 0.0``.
+What is saved is the walk: a chip that holds 8 of 320 experts has a term
+in 2.5% of its tokens x 8 slots, and walked them all (PERF.md section 6,
+PR 69).  Sixteen rows none of which has a term write their zeros and
+read no slot; a tile none of whose indices names a row starts nothing.
 
 Mosaic moves whole tiles of the chip's tiled layout and nothing smaller:
 one row of a ``[R, w]`` array is an eighth of a tile (a sixteenth at 16
@@ -189,9 +201,13 @@ def as_words(src, live=None, interpret=None):
     )(last, src)
 
 
-def _kernel(counts, moves, idx_v, *refs, k, tm, halves, rows, bits,
-            weighted, dotted):
+def _kernel(*refs, k, tm, halves, rows, bits, weighted, dotted):
     refs = list(refs)
+    counts = refs.pop(0)
+    # k > 1: which of ``_bodies`` sums each SUB_ROWS result rows
+    walks = refs.pop(0) if k > 1 else None
+    # [tm, 1]: k > 1 the terms each row has; k == 1 its index
+    moves, have_v = refs.pop(0), refs.pop(0)
     weight_ref = refs.pop(0) if weighted else None
     other_ref = refs.pop(0) if dotted else None
     src_ref, out_ref = refs.pop(0), refs.pop(0)
@@ -200,7 +216,8 @@ def _kernel(counts, moves, idx_v, *refs, k, tm, halves, rows, bits,
     words, width = buf.shape[-1], out_ref.shape[-1]
     # the columns of the float32 planes a row's words come apart into
     spans = ((0, words), (words, width))[:1 + (width > words)]
-    count = counts[pl.program_id(0)]
+    tile = pl.program_id(0)
+    count = counts[tile]
 
     @pl.when(count == 0)
     def _():
@@ -237,26 +254,49 @@ def _kernel(counts, moves, idx_v, *refs, k, tm, halves, rows, bits,
     def sum_rows(s, carry):
         at = pl.multiple_of(s * SUB_ROWS, SUB_ROWS)
         here = pl.ds(at, SUB_ROWS)
-        index = idx_v[here, :]                               # [16, k]
-        keep = jnp.where((index >= 0) & (index < rows),
-                         jnp.uint32(0xFFFFFFFF), jnp.uint32(0))
-        acc = first = None
-        for j in range(k):
-            terms = floats(buf[pl.ds(j * tm + at, SUB_ROWS), 0, :]
-                           & keep[:, j:j + 1])
-            if j == 0:
-                first = terms
-            if weighted:
-                terms = tuple(t * weight_ref[here, j:j + 1] for t in terms)
-            acc = terms if acc is None else tuple(
-                a + t for a, t in zip(acc, terms))
-        for (lo, hi), part in zip(spans, acc):
-            out_ref[here, lo:hi] = part.astype(out_ref.dtype)
-        if dotted:    # a row with no source has no dot, whatever other holds
-            dots_ref[here, :] = jnp.where(keep[:, :1] != 0, sum(
-                (other_ref[here, lo:hi].astype(jnp.float32) * part).sum(
-                    axis=1, keepdims=True)
-                for (lo, hi), part in zip(spans, first)), 0.0)
+        have = have_v[here, :]                               # [16, 1]
+        named = (have >= 0) & (have < rows) if k == 1 else None
+
+        def term(j):    # the rows' j-th terms, zeros where a row has none
+            keep = jnp.where(named if k == 1 else have > j,
+                             jnp.uint32(0xFFFFFFFF), jnp.uint32(0))
+            return floats(buf[pl.ds(j * tm + at, SUB_ROWS), 0, :] & keep)
+
+        def scaled(j, planes):
+            if not weighted:
+                return planes
+            return tuple(p * weight_ref[here, j:j + 1] for p in planes)
+
+        def write(acc):
+            for (lo, hi), part in zip(spans, acc):
+                out_ref[here, lo:hi] = part.astype(out_ref.dtype)
+
+        if k == 1:
+            first = term(0)
+            write(scaled(0, first))
+            # a row with no source has no dot, whatever other holds
+            if dotted:
+                dots_ref[here, :] = jnp.where(named, sum(
+                    (other_ref[here, lo:hi].astype(jnp.float32) * part).sum(
+                        axis=1, keepdims=True)
+                    for (lo, hi), part in zip(spans, first)), 0.0)
+            return carry
+
+        def body(t):    # t terms in one straight line, as all k were
+            if t == 0:
+                return lambda: write(
+                    (jnp.zeros((SUB_ROWS, hi - lo), jnp.float32)
+                     for lo, hi in spans))
+            return lambda: write(functools.reduce(
+                lambda acc, j: tuple(
+                    a + p for a, p in zip(acc, scaled(j, term(j)))),
+                range(1, t), scaled(0, term(0))))
+
+        # a body a count, not a guard a term: a sum carried across a
+        # guard leaves the registers, ~65 ns a term of sixteen rows
+        # (PERF.md section 6, PR 69)
+        lax.switch(walks[tile * (tm // SUB_ROWS) + s],
+                   [body(t) for t in _bodies(k)])
         return carry
 
     @pl.when(count > 0)
@@ -267,20 +307,82 @@ def _kernel(counts, moves, idx_v, *refs, k, tm, halves, rows, bits,
         lax.fori_loop(0, tm // SUB_ROWS, sum_rows, 0)
 
 
-def _moves(idx, rows, tiles, tm, bits):
-    """(counts [tiles], moves [tiles * tm * k]): for each tile of ``tm``
-    result rows how many of its indices name a row, and those first, as
-    ``slot << bits | index`` with slot = j * tm + the row in the tile
-    (where the kernel's buffer keeps term j of that row)."""
+def _bodies(k):
+    """The term counts the kernel has a body for, in the order it tries
+    them (``lax.switch`` in a kernel is a chain of comparisons): all k
+    first, so that a call whose every slot holds a row runs what it ran
+    and little else, then none, then every count up to 4 and past it
+    the even ones (a body is its terms in one straight line: all k
+    counts would be k * (k + 1) / 2 terms of code)."""
+    return [k, 0] + [t for t in range(1, k) if t <= 4 or t % 2 == 0]
+
+
+def _group_terms(named):
+    """named [m, k] bool, m whole SUB_ROWS -> [m / SUB_ROWS] int32: the
+    terms the kernel's vector phase walks for each SUB_ROWS result rows,
+    those of the fullest row among them, or the next count up that has a
+    body (``_bodies``: the terms past a row's own are masked)."""
+    k = named.shape[1]
+    most = named.sum(axis=1, dtype=jnp.int32).reshape(-1, SUB_ROWS).max(
+        axis=1)
+    return jnp.where(most <= 4, most, jnp.minimum(most + most % 2, k))
+
+
+def _body_of(terms, k):
+    """Where in ``_bodies(k)`` each of ``_group_terms``' counts is."""
+    return jnp.where(terms == k, 0,
+                     1 + jnp.where(terms <= 4, terms, 2 + terms // 2))
+
+
+def sum_terms(idx, rows):
+    """(terms, slots), int32 scalars: the slots of the buffer the vector
+    phase of a ``rows_sum`` call on ``idx`` [m, k] into ``rows`` rows
+    walks, SUB_ROWS times each group's fullest row's terms, and the
+    m * k it walked before PR 69.  For a counter: the kernel's own table
+    is ``_moves``', by the same ``_group_terms``."""
+    m, k = idx.shape
+    named = jnp.pad((idx >= 0) & (idx < rows), ((0, -m % SUB_ROWS), (0, 0)))
+    return SUB_ROWS * _group_terms(named).sum(), jnp.int32(m * k)
+
+
+def _moves(idx, weight, rows, tiles, tm, bits):
+    """What the kernel reads of ``idx`` [tiles * tm, k] and ``weight``
+    (the same shape, or None): (counts [tiles], walks [tiles * tm /
+    SUB_ROWS], moves [tiles * tm * k], have [tiles * tm, 1], weight).
+
+    ``counts``: how many of a tile's indices name a row; ``moves``:
+    those first, as ``slot << bits | index`` with slot = rank * tm + the
+    row in the tile, ``rank`` the named indices before it in its own
+    row: a row's terms lie in the first slots of its k, in the order of
+    their columns.  ``have``: the terms each row has; ``walks``: which
+    of the kernel's bodies sums each SUB_ROWS rows (``_group_terms``,
+    ``_body_of``); ``weight`` with each row's named columns' first, in
+    their order.  With k = 1 a rank is 0 and nothing moves: ``have`` is
+    ``idx`` itself, ``walks`` None."""
     k = idx.shape[1]
     named = ((idx >= 0) & (idx < rows)).reshape(tiles, tm, k)
-    slot = (jnp.arange(k, dtype=jnp.int32)[None, :] * tm
-            + jnp.arange(tm, dtype=jnp.int32)[:, None])
+    have, ranks = jnp.zeros((tiles, tm), jnp.int32), []
+    for j in range(k):    # a column at a time: k is 8 at most
+        ranks.append(have)
+        have = have + named[..., j]
+    rank = jnp.stack(ranks, axis=-1)
+    slot = rank * tm + jnp.arange(tm, dtype=jnp.int32)[:, None]
     key = jnp.where(named, (slot << bits) | idx.reshape(tiles, tm, k),
                     jnp.iinfo(jnp.int32).max)
-    moves = jnp.sort(key.reshape(tiles, tm * k), axis=1)
-    return (named.sum(axis=(1, 2), dtype=jnp.int32),
-            moves.reshape(tiles * tm * k))
+    moves = jnp.sort(key.reshape(tiles, tm * k), axis=1).reshape(-1)
+    counts = have.sum(axis=1)
+    if k == 1:
+        return counts, None, moves, idx, weight
+    named, rank = named.reshape(-1, k), rank.reshape(-1, k)
+    if weight is not None:
+        # by k * k selects, not a gather (XLA gathers a scalar in ~13 ns)
+        weight = jnp.stack([functools.reduce(
+            lambda w, j: jnp.where(named[:, j] & (rank[:, j] == r),
+                                   weight[:, j], w),
+            range(r, k), jnp.zeros_like(weight[:, 0]))
+            for r in range(k)], axis=1)
+    return (counts, _body_of(_group_terms(named), k), moves,
+            have.reshape(-1, 1), weight)
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
@@ -302,13 +404,17 @@ def _call(src, idx, weight, other, live, *, out_dtype, interpret):
         weight, other = (None if a is None else jnp.pad(
             a, ((0, pad), (0, 0))) for a in (weight, other))
     bits = max(rows - 1, 1).bit_length()
-    counts, moves = _moves(idx, rows, tiles, tm, bits)
-    tile = lambda cols: pl.BlockSpec((tm, cols), lambda i, counts: (i, 0))
-    operands = [moves, idx]
-    in_specs = [pl.BlockSpec((tm * k,), lambda i, counts: (i,),
-                             memory_space=pltpu.SMEM), tile(k)]
     if weight is not None:
-        operands.append(weight.astype(jnp.float32))
+        weight = weight.astype(jnp.float32)
+    counts, walks, moves, have, weight = _moves(
+        idx, weight, rows, tiles, tm, bits)
+    prefetch = (counts,) if k == 1 else (counts, walks)
+    tile = lambda cols: pl.BlockSpec((tm, cols), lambda i, *_: (i, 0))
+    operands = [moves, have]
+    in_specs = [pl.BlockSpec((tm * k,), lambda i, *_: (i,),
+                             memory_space=pltpu.SMEM), tile(1)]
+    if weight is not None:
+        operands.append(weight)
         in_specs.append(tile(k))
     if other is not None:
         operands.append(other)
@@ -335,7 +441,7 @@ def _call(src, idx, weight, other, live, *, out_dtype, interpret):
             weighted=weight is not None, dotted=other is not None),
         out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(prefetch),
             grid=(tiles,),
             in_specs=in_specs,
             out_specs=out_specs,
@@ -351,7 +457,7 @@ def _call(src, idx, weight, other, live, *, out_dtype, interpret):
         # The HLO instruction's name, so the trace's: the benchmark
         # tells the calls apart by it (benchmark/layers/).
         name="rows_gather" if k == 1 else "rows_sum",
-    )(counts, *operands)
+    )(*prefetch, *operands)
     return out[0][:m], (out[1][:m, 0] if other is not None else None)
 
 
@@ -386,6 +492,9 @@ def row_sum(src, idx, weight=None, other=None, live=None, out_dtype=None,
     Nothing here is differentiable: the callers' pullbacks are further
     calls (``ops/moe_dispatch.py``)."""
     out_dtype = jnp.dtype(out_dtype or src.dtype)
+    if other is not None and idx.shape[1] != 1:
+        raise ValueError("row dots go with one index a result row, not %d"
+                         % idx.shape[1])
     mode = resolve(interpret)
     if mode == "off":
         return row_sum_ref(src, idx, weight, other, out_dtype)

@@ -43,7 +43,11 @@ def _log_step_stats(step, stats):
     a held expert's, ``moved`` = ``rows`` and nothing spills.
     ``moe_group_hit`` [layers], under a router limited to groups: the
     share of a layer's tokens whose chosen groups reach an expert held
-    here, `` group_hit=`` their mean."""
+    here, `` group_hit=`` their mean.  ``moe_sum_terms`` and
+    ``moe_sum_slots`` [layers], where the row kernel moves a share's
+    rows: the slots its sums' vector phase walked and the tokens x K a
+    call it would have without the ranks (``ops/row_moves.py``),
+    `` sum_terms= sum_slots=`` their sums."""
     if not stats or "moe_load" not in stats:
         return
     import numpy as np
@@ -57,8 +61,12 @@ def _log_step_stats(step, stats):
         counts.sum(), counts.max(), counts.mean(), load[:, -1].sum(),
         np.asarray(moved).sum(),
         np.asarray(stats.get("moe_spilled", 0)).sum(),
-        " group_hit=%.4f" % np.asarray(stats["moe_group_hit"]).mean()
-        if "moe_group_hit" in stats else "")
+        (" group_hit=%.4f" % np.asarray(stats["moe_group_hit"]).mean()
+         if "moe_group_hit" in stats else "")
+        + (" sum_terms=%d sum_slots=%d" % tuple(
+            np.asarray(stats[key]).sum(dtype=np.int64)
+            for key in ("moe_sum_terms", "moe_sum_slots"))
+           if "moe_sum_terms" in stats else ""))
 
 
 def _loss_fields(stats):

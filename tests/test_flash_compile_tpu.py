@@ -213,6 +213,7 @@ def test_grouped_matmul_over_a_share_compiles_for_v5e(one_chip):
     (32768, 2048, 4, 32768),      # lfm2-24b-a2b.seq8192
     (16384, 2560, 6, 49152),      # smallthinker-21b-a3b.seq16384
     (16384, 2688, 6, 12288),      # nemotron-3-nano-30b-a3b.seq16384
+    (16384, 4096, 8, 6656),       # solar-open2-250b.seq16384
 ])
 def test_the_row_kernel_compiles_for_v5e(one_chip, n, w, k, bound):
     """The four row moves of a share's block (``ops/row_moves.py``: a
@@ -225,7 +226,9 @@ def test_the_row_kernel_compiles_for_v5e(one_chip, n, w, k, bound):
     lane tiles, an odd number: its words are 11 tiles, the low halves'
     last padded, and every slice of both kernels still starts and ends
     on a tile (3,072 slots of 1,408 words: 17.3 MB of an 18 MiB
-    budget)."""
+    budget).  The fourth is the thinnest share's and the widest row,
+    eight slots a token: the sums' seven bodies (which one from
+    SMEM) beside the widest float32 gather."""
     shape = lambda rows, cols, dtype: jax.ShapeDtypeStruct(
         (rows, cols), dtype, sharding=one_chip)
     x, y = shape(n, w, jnp.bfloat16), shape(bound, w, jnp.bfloat16)

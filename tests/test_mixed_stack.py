@@ -801,3 +801,44 @@ def test_the_worker_logs_moved_rows_beside_held_rows(caplog):
                "close": job.stamp_seconds(stamped) + 1})
     reader = manifest.load_named("layers", "moe.dead_row_share")
     assert reader.read(run) == pytest.approx(100 * (1 - 42 / 192))
+
+
+def test_the_worker_logs_what_the_row_kernels_sums_walked(caplog):
+    """``sum_terms=`` and ``sum_slots=`` end the line where the step
+    handed them back (the row kernel moved the share's rows), behind
+    ``group_hit=`` where that is there; the benchmark's reader takes
+    their ratio, and nothing from a line without them."""
+    import types
+
+    from benchmark.lib import job
+
+    stats = {"moe_load": np.array([[10, 0, 30], [16, 16, 32]], np.float32),
+             "moe_moved": np.array([64., 128.]),
+             "moe_spilled": np.array([0., 1.]),
+             "moe_sum_terms": np.array([96., 160.], np.float32),
+             "moe_sum_slots": np.array([512., 1024.], np.float32)}
+    worker_mod.logger.addHandler(caplog.handler)
+    try:
+        with caplog.at_level(logging.INFO, logger=worker_mod.logger.name):
+            worker_mod._log_step_stats(8, stats)
+            worker_mod._log_step_stats(9, dict(
+                stats, moe_group_hit=np.array([0.25, 0.75])))
+            worker_mod._log_step_stats(10, {
+                key: stats[key] for key in list(stats)[:3]})
+    finally:
+        worker_mod.logger.removeHandler(caplog.handler)
+    lines = [r.getMessage() for r in caplog.records]
+    head = " layers=2 rows=42 max=16 mean=10.5 padded_rows=62 moved=192 "
+    assert lines == [
+        "moe load: step=8" + head + "spilled=1 sum_terms=256 sum_slots=1536",
+        "moe load: step=9" + head + "spilled=1 group_hit=0.5000 "
+        "sum_terms=256 sum_slots=1536",
+        "moe load: step=10" + head + "spilled=1"]
+    reader = manifest.load_named("layers", "moe.sum_term_share")
+    stamp = "[2026-09-28 02:00:20,000] [INFO] [worker-0] "
+    run = lambda line: types.SimpleNamespace(
+        job=types.SimpleNamespace(text=stamp + line + "\n"),
+        times={"open": job.stamp_seconds(stamp) - 1,
+               "close": job.stamp_seconds(stamp) + 1})
+    assert reader.read(run(lines[1])) == pytest.approx(100 * 256 / 1536)
+    assert reader.read(run(lines[2])) is None

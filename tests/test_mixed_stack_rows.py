@@ -114,7 +114,12 @@ def test_a_share_multiplies_every_held_row_whatever_the_bound(
     (_, (out, load)), grads = grad(share)
     blocks = max(-(-held_rows // 128), 1)
     np.testing.assert_array_equal(
-        load[0, total + 1:], [128 * blocks, blocks > 1])
+        load[0, total + 1:total + 3], [128 * blocks, blocks > 1])
+    # beside them, where the row kernel moved the rows, what its sums
+    # walked: two calls a block, n x k slots a call without the ranks
+    assert load.shape[1] == total + 3 + 2 * (mode == "interpret")
+    if mode == "interpret":
+        assert 0 < load[0, -2] <= load[0, -1] == 2 * blocks * n * k
     assert int(load[0, first:first + k].sum()) == held_rows
     for other in (whole, plain):
         (_, want), want_grads = grad(other)
@@ -280,7 +285,9 @@ def test_a_row_no_index_names_never_reaches_a_result(k, width, dtype):
         has_first, rng.standard_normal((m, width)), np.nan), dtype)
     weight = jnp.asarray(rng.random((m, k)), jnp.float32)
     idx = jnp.asarray(idx, jnp.int32)
-    for w, o in ((None, None), (weight, None), (weight, other)):
+    # the row dots go with one index a result row
+    for w, o in ((None, None), (weight, None), (weight, other))[
+            :2 + (k == 1)]:
         got = row_moves.row_sum(src, idx, w, o, out_dtype=jnp.float32,
                                 interpret=True)
         want = row_moves.row_sum_ref(src, idx, w, o, jnp.float32)
@@ -331,8 +338,21 @@ def test_a_shares_layer_is_one_by_the_kernel_and_by_the_jnp_moves(
     (_, (out, load)), grads = run("interpret")
     (_, (want, want_load)), want_grads = run("off")
     blocks = max(-(-rows // bound), 1)
-    np.testing.assert_array_equal(load[0, total + 1:],
+    np.testing.assert_array_equal(load[0, total + 1:total + 3],
                                   [bound * blocks, blocks > 1])
+    assert want_load.shape[1] == total + 3
+    # what the kernel's sums walked, two calls a block that ran: sixteen
+    # tokens as many terms as the fullest has rows in the block
+    flat = (np.asarray(experts).reshape(n * k) - first) % total
+    at = np.full(n * k, -1)
+    held = np.flatnonzero(flat < k)
+    at[held[np.argsort(flat[held], kind="stable")]] = np.arange(rows)
+    most = np.concatenate([
+        ((at // bound == i) & (at >= 0)).reshape(n // 16, 16, k).sum(
+            axis=2).max(axis=1) for i in range(blocks)])
+    terms = np.where(most == 5, 6, most).sum()    # ``row_moves._bodies``
+    np.testing.assert_array_equal(
+        load[0, total + 3:], [2 * 16 * terms, 2 * blocks * n * k])
     np.testing.assert_array_equal(load[0, :total], want_load[0, :total])
     np.testing.assert_allclose(out, want, rtol=1e-4, atol=1e-5)
     for g, w in zip(grads, want_grads):

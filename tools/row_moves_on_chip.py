@@ -21,8 +21,12 @@ rows; ``smallthinker`` 16,384 x 2,560, 6 a token, 49,152; ``olmoe``
 16,384 x 2,048, 8 a token, all 131,072 rows live (what the all-experts
 dispatch would hand the kernel); ``nemotron3`` (by name alone) 16,384 x
 2,688, 6 a token, 12,288: a 16-bit row of 21 lane tiles, moved as 1,408
-words.  Exits 3 without a TPU: a CPU timing is
-no device number.
+words; ``solar`` 16,384 x 4,096, 8 a token, 6,656 (8 of 320 experts
+held) and ``ling`` 16,384 x 2,560, 8 a token, 4,096 (8 of 512): the two
+thinnest shares.  ``sum_terms`` / ``sum_slots`` on a line: the slots
+the sums' vector phase walks of the tokens x K it has
+(``row_moves.sum_terms``).  Exits 3 without a TPU: a CPU timing is no
+device number.
 """
 
 import argparse
@@ -42,6 +46,8 @@ SHAPES = {
     "smallthinker": (16384, 2560, 6, 49152, 64, 16),
     "olmoe": (16384, 2048, 8, 131072, 64, 64),
     "nemotron3": (16384, 2688, 6, 12288, 128, 8),
+    "solar": (16384, 4096, 8, 6656, 320, 8),
+    "ling": (16384, 2560, 8, 4096, 512, 8),
     # a rehearsal's: the one shape a run without a TPU may take
     "tiny": (256, 256, 4, 512, 8, 2),
 }
@@ -148,6 +154,10 @@ def main():
         }
         row = {"device": dev.device_kind, "shape": name, "tokens": n,
                "width": w, "top_k": k, "rows": bound, "live_rows": live}
+        if hasattr(row_moves, "sum_terms"):    # a tree before PR 69: all
+            terms, slots = map(int, row_moves.sum_terms(pos, bound))
+            row.update(sum_terms=terms, sum_slots=slots,
+                       sum_term_share=round(terms / slots, 4))
         for move, (kernel, reference, operands, row_bytes) in moves.items():
             ops, _ = device_ms(jax.jit(kernel), operands, args.iters)
             mine = {op: ms for op, ms in ops.items()
